@@ -129,10 +129,10 @@ class GoodputConfig:
     # host-side floats).
     export_interval_secs: float = 1.0
     # Override the per-chip peak FLOP/s used for live MFU gauges; 0 =
-    # auto-detect from the device kind (monitor.device_peak_flops). On an
-    # unknown device kind the MFU gauges degrade to achieved-TFLOP/s-only
-    # with a one-time warning — set this to restore MFU (e.g. CPU tests,
-    # unlisted hardware).
+    # look up the device kind (monitor.device_peak_flops). Off the TPU the
+    # MFU gauges degrade to achieved-TFLOP/s-only with a one-time warning;
+    # a TPU kind the table lacks is an error — set this for either (CPU
+    # tests, unlisted hardware).
     peak_flops_override: float = 0.0
 
 

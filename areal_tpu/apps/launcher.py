@@ -22,36 +22,30 @@ import time
 from typing import Any, Dict, List, Optional
 
 from areal_tpu.base import logging
+from areal_tpu.base.compile_watch import enable_compilation_cache
 
 logger = logging.getLogger("apps.launcher")
 
-# Persistent XLA compilation cache shared by every worker process: the async
-# experiment spawns 4+ JAX processes that would otherwise each recompile the
-# same graphs from scratch — on a busy host that made the e2e launch a
-# 165-420s coin flip (VERDICT r2 weak #4). Override with
-# AREAL_COMPILATION_CACHE; set to "" to disable. The default path lives in
-# base/compile_watch.py so the observatory's cache-hit/miss probe watches the
-# same directory the launcher arms.
-from areal_tpu.base.compile_watch import (  # noqa: E402
-    DEFAULT_COMPILATION_CACHE, compilation_cache_dir,
-)
+
+def cpu_platform_requested() -> bool:
+    """True when the standard ``JAX_PLATFORMS`` puts the CPU first — the
+    ONE switch that runs workers on the CPU (tests export it; children
+    inherit it). Nothing else, and no experiment option, selects a
+    platform."""
+    first = os.environ.get("JAX_PLATFORMS", "").split(",")[0]
+    return first.strip().lower() == "cpu"
 
 
-def enable_compilation_cache() -> None:
-    path = compilation_cache_dir()
-    if not path:
-        return
+def _pin_cpu() -> None:
+    """Keep THIS process off the accelerator: a chip belongs to one
+    process, and one stray array in a rollout/reward/master process would
+    take it from the trainer. The env var covers a jax not imported yet
+    (and grandchildren); the config update covers a jax that argument
+    unpickling already imported (it read the variable at import time)."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
 
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        # Cache everything (default only caches >1s compiles) and never
-        # burn cycles deciding: tiny test graphs dominate the e2e launch.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception as e:  # noqa: BLE001 — cache is best-effort
-        logger.warning(f"compilation cache unavailable: {e}")
+    jax.config.update("jax_platforms", "cpu")
 
 
 # ---------------------------------------------------------------------------
@@ -105,13 +99,28 @@ def _apply_chip_env(chips: Optional[List[int]]) -> None:
     )
 
 
-def _child_init(exp_cfg, force_cpu: bool, chips: Optional[List[int]] = None) -> None:
-    _apply_chip_env(None if force_cpu else chips)
+def _child_init(exp_cfg, owns_device: bool,
+                chips: Optional[List[int]] = None) -> None:
+    """Per-process start-up. ``owns_device``: the trainer and the
+    generation fleet run on the platform ``JAX_PLATFORMS`` selects (the
+    TPU when it is unset) and refuse to start on anything else under
+    ``backend=tpu``; every other worker is pinned to the CPU."""
+    on_accelerator = owns_device and not cpu_platform_requested()
+    if on_accelerator:
+        _apply_chip_env(chips)
+    else:
+        _pin_cpu()
     import jax
 
-    if force_cpu:
-        jax.config.update("jax_platforms", "cpu")
     enable_compilation_cache()
+    if (on_accelerator and getattr(exp_cfg, "backend", "tpu") == "tpu"
+            and jax.default_backend() != "tpu"):
+        raise RuntimeError(
+            "backend=tpu but this worker got platform "
+            f"{jax.default_backend()!r} (devices: {jax.devices()}); no "
+            "model runs on a fallback device — export JAX_PLATFORMS=cpu "
+            "to run on the CPU on purpose"
+        )
     from areal_tpu.experiments import common as C
 
     C.setup_name_resolve(exp_cfg)
@@ -132,7 +141,7 @@ def _resolve_tokenizer(exp_cfg):
     return C.make_tokenizer(exp_cfg, model_path)
 
 
-def trainer_entry(exp_cfg, trainer_cfg, force_cpu: bool) -> None:
+def trainer_entry(exp_cfg, trainer_cfg) -> None:
     # Multi-process CPU testing: the virtual-device flag must land in the
     # environment BEFORE jax initializes in this (spawned, fresh) process.
     if trainer_cfg.dist_world > 1 and trainer_cfg.dist_local_devices:
@@ -143,7 +152,7 @@ def trainer_entry(exp_cfg, trainer_cfg, force_cpu: bool) -> None:
                 flags + " --xla_force_host_platform_device_count="
                 f"{trainer_cfg.dist_local_devices}"
             ).strip()
-    _child_init(exp_cfg, force_cpu, getattr(trainer_cfg, "chips", None))
+    _child_init(exp_cfg, True, getattr(trainer_cfg, "chips", None))
     from areal_tpu.system.trainer_worker import TrainerWorker
 
     trainer_cfg.tokenizer = _resolve_tokenizer(exp_cfg)
@@ -169,12 +178,44 @@ def _build_gen_model(init: Dict):
     return cfg, params
 
 
-def gen_fleet_entry(exp_cfg, server_cfgs, manager_cfg, force_cpu: bool,
+def gen_replica_meshes(exp_cfg, n_replicas: int, devices) -> List[Any]:
+    """One mesh per generation replica over ITS OWN devices: replica ``i``
+    takes devices ``[i*per, (i+1)*per)`` of this process, ``per`` being the
+    non-data part (pp·sp·tp) of the decoupled generation spec — one device
+    under ``gen.d2``. Without this every server lands on ``devices[0]`` and
+    the other generation chips hold nothing. With fewer devices than the
+    replicas need (one-device CPU runs) the servers share the default
+    device, un-meshed (``None``)."""
+    import dataclasses
+
+    from areal_tpu.experiments.common import resolve_allocation
+    from areal_tpu.parallel import mesh as pmesh
+
+    spec = resolve_allocation(exp_cfg).gen_spec or pmesh.ParallelSpec()
+    one = dataclasses.replace(spec, dp=1, fsdp=1, ep=1)
+    per = one.world_size
+    if len(devices) < n_replicas * per:
+        if devices[0].platform != "cpu":
+            raise RuntimeError(
+                f"{n_replicas} generation replicas of {per} device(s) need "
+                f"{n_replicas * per} devices; this process has {devices}"
+            )
+        return [None] * n_replicas
+    return [
+        pmesh.make_mesh(one, devices=devices[i * per:(i + 1) * per])
+        for i in range(n_replicas)
+    ]
+
+
+def gen_fleet_entry(exp_cfg, server_cfgs, manager_cfg,
                     chips: Optional[List[int]] = None) -> None:
     """All generation servers + the gserver manager in one asyncio loop."""
-    _child_init(exp_cfg, force_cpu, chips)
+    _child_init(exp_cfg, True, chips)
     import asyncio
 
+    import jax
+
+    from areal_tpu.base import monitor
     from areal_tpu.experiments.common import model_init_dict
     from areal_tpu.system.generation_server import GenerationServer
     from areal_tpu.system.gserver_manager import GserverManager
@@ -185,13 +226,17 @@ def gen_fleet_entry(exp_cfg, server_cfgs, manager_cfg, force_cpu: bool,
         cfg, params = _build_gen_model(init)
         tok = _resolve_tokenizer(exp_cfg)
         eos = getattr(tok, "eos_token_id", None)
+        meshes = gen_replica_meshes(exp_cfg, len(server_cfgs),
+                                    jax.local_devices())
         servers = []
-        for sc in server_cfgs:
+        for sc, mesh in zip(server_cfgs, meshes):
             if eos is not None:
                 sc.eos_token_id = int(eos)
-            srv = GenerationServer(sc, cfg, params)
+            srv = GenerationServer(sc, cfg, params, mesh=mesh)
             await srv.start()
             servers.append(srv)
+        monitor.log_device_report(logger, "gen_fleet",
+                                  n_servers=len(servers))
         mgr = GserverManager(manager_cfg)
         await mgr.start()
         while True:  # runs until the launcher terminates us
@@ -200,7 +245,7 @@ def gen_fleet_entry(exp_cfg, server_cfgs, manager_cfg, force_cpu: bool,
     asyncio.run(main())
 
 
-def gen_server_entry(exp_cfg, server_cfg, force_cpu: bool,
+def gen_server_entry(exp_cfg, server_cfg,
                      chips: Optional[List[int]] = None) -> None:
     """One supervised generation server — the autoscaler's scale-up unit
     (docs/fault_tolerance.md §Autoscaling).
@@ -212,7 +257,7 @@ def gen_server_entry(exp_cfg, server_cfg, force_cpu: bool,
     streamed transport (no checkpoint round-trip). It also serves a
     WorkerControl endpoint (``genserver_<server_id>``) so a drained
     cordon ends in a commanded clean exit the supervisor expects."""
-    _child_init(exp_cfg, force_cpu, chips)
+    _child_init(exp_cfg, True, chips)
     import asyncio
 
     from areal_tpu.base import name_resolve, names
@@ -268,7 +313,7 @@ def reward_worker_entry(exp_cfg, rw_cfg) -> None:
     system/reward_worker.py). Deliberately NOT _child_init: a reward
     worker is jax-free and must never initialize an accelerator —
     untrusted code grades on spare CPU, not on the chips that train."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")  # belt: even if imported
+    os.environ["JAX_PLATFORMS"] = "cpu"  # belt: even if imported
     from areal_tpu.experiments import common as C
 
     C.setup_name_resolve(exp_cfg)
@@ -277,8 +322,8 @@ def reward_worker_entry(exp_cfg, rw_cfg) -> None:
     RewardWorker(rw_cfg).run()
 
 
-def rollout_entry(exp_cfg, rollout_cfg, force_cpu: bool) -> None:
-    _child_init(exp_cfg, force_cpu)
+def rollout_entry(exp_cfg, rollout_cfg) -> None:
+    _child_init(exp_cfg, False)
     import asyncio
 
     from areal_tpu.system.rollout_worker import RolloutWorker
@@ -306,15 +351,13 @@ class LocalLauncher:
     orderly exits) instead of raw terminate().
     """
 
-    def __init__(self, exp_cfg, force_cpu: Optional[bool] = None):
+    def __init__(self, exp_cfg):
         from areal_tpu.api.train_config import FaultToleranceConfig
 
         self.exp_cfg = exp_cfg
-        # Tests force CPU everywhere; real runs use the native platform.
-        self.force_cpu = (
-            force_cpu if force_cpu is not None
-            else bool(getattr(exp_cfg, "mock_tokenizer", False))
-        )
+        # The platform follows the standard JAX_PLATFORMS alone (children
+        # inherit it) — never an experiment option such as the tokenizer.
+        self.force_cpu = cpu_platform_requested()
         self.ft = (getattr(exp_cfg, "fault_tolerance", None)
                    or FaultToleranceConfig())
         self.supervisor = None  # built in run() once the trial resolves
@@ -344,25 +387,30 @@ class LocalLauncher:
         ))
 
     @staticmethod
-    def _count_chips(exp) -> int:
-        """TPU chips on this host: probe in a subprocess so the launcher
-        process itself never initializes the TPU runtime (children own the
-        chips)."""
-        env_n = os.environ.get("AREAL_N_CHIPS")
-        if env_n:
-            return int(env_n)
+    def _count_chips() -> int:
+        """TPU chips on this host, probed in a subprocess that has exited
+        before any worker is spawned: the launcher process itself never
+        initializes the TPU runtime (children own the chips). A probe
+        that cannot count chips is an error, not a guess."""
         import subprocess
-        import sys as _sys
+        import sys
 
-        try:
-            out = subprocess.run(
-                [_sys.executable, "-c",
-                 "import jax; print(jax.device_count())"],
-                capture_output=True, text=True, timeout=120,
+        probe = ("import jax; d = jax.devices(); "
+                 "print(d[0].platform, len(d))")
+        out = subprocess.run(
+            [sys.executable, "-c", probe],
+            capture_output=True, text=True, timeout=300,
+        )
+        lines = out.stdout.strip().splitlines()
+        fields = lines[-1].split() if out.returncode == 0 and lines else []
+        if len(fields) != 2 or fields[0] != "tpu":
+            raise RuntimeError(
+                f"chip probe failed (exit {out.returncode}); a decoupled "
+                "layout needs the TPU chips of this host counted before "
+                f"they are partitioned\nstdout: {out.stdout[-500:]}\n"
+                f"stderr: {out.stderr[-2000:]}"
             )
-            return int(out.stdout.strip().splitlines()[-1])
-        except Exception:  # noqa: BLE001 — fall back to config
-            return int(getattr(exp, "n_gpus_per_node", 1))
+        return int(fields[1])
 
     def _check_children(self) -> None:
         """One supervision sweep. Stateless-domain deaths respawn in
@@ -397,7 +445,11 @@ class LocalLauncher:
         exp = self.exp_cfg
         exp.resolve_trial_name()
         C.setup_name_resolve(exp)
-        enable_compilation_cache()  # master runs in-process
+        # The master runs in this process, which spawns the workers that
+        # own the chips: whatever jax it touches stays on the CPU.
+        import jax
+
+        jax.config.update("jax_platforms", "cpu")
         self.supervisor = Supervisor(
             exp.experiment_name, exp.trial_name,
             policy=RestartPolicy.from_config(self.ft),
@@ -426,7 +478,7 @@ class LocalLauncher:
         # claim one chip. CPU-forced runs skip it.
         chips = {"trainer": None, "gen": None}
         if not self.force_cpu and "gen_servers" in setup:
-            n_chips = self._count_chips(exp)
+            n_chips = self._count_chips()
             asg = derive_chip_assignment(
                 getattr(exp, "allocation_mode", ""), n_chips
             )
@@ -469,10 +521,10 @@ class LocalLauncher:
                 tc.dist_local_devices = getattr(
                     exp, "trainer_dist_devices_per_proc", None
                 )
-                self._spawn(trainer_entry, exp, tc, self.force_cpu,
+                self._spawn(trainer_entry, exp, tc,
                             name=f"trainer{r}", kind="trainer")
         else:
-            self._spawn(trainer_entry, exp, setup["trainer"], self.force_cpu,
+            self._spawn(trainer_entry, exp, setup["trainer"],
                         name="trainer", kind="trainer")
         # Sandbox reward fleet (docs/rewards.md): CPU-only, supervised
         # as a restartable stateless domain — a crashed reward worker
@@ -487,7 +539,7 @@ class LocalLauncher:
         if "gen_servers" in setup:
             self._spawn(
                 gen_fleet_entry, exp, setup["gen_servers"],
-                setup["gserver_manager"], self.force_cpu, chips["gen"],
+                setup["gserver_manager"], chips["gen"],
                 name="gen_fleet", kind="gen_fleet",
             )
             for i, rc in enumerate(setup["rollout_workers"]):
@@ -495,7 +547,7 @@ class LocalLauncher:
                 # exits 0 by DESIGN — only unbounded workers' clean exits
                 # are the silent data-starvation failure the supervisor
                 # must catch.
-                self._spawn(rollout_entry, exp, rc, self.force_cpu,
+                self._spawn(rollout_entry, exp, rc,
                             name=f"rollout{i}", kind="rollout",
                             required=getattr(rc, "max_rollouts",
                                              None) is None)
@@ -616,7 +668,7 @@ class LocalLauncher:
             # chips=None: dynamic servers are unpinned (see the warning
             # above for the single-host TPU caveat).
             self._spawn(
-                gen_server_entry, exp, sc, self.force_cpu, None,
+                gen_server_entry, exp, sc, None,
                 name=f"genserver_{server_id}", kind="gen_server",
                 required=False, expendable=True,
             )

@@ -49,14 +49,14 @@ def run_role(
     rank: int = 0,
     world: int = 1,
     index: int = 0,
-    force_cpu: bool = False,
 ) -> None:
-    """Run one worker role to completion (the scheduler owns the process)."""
+    """Run one worker role to completion (the scheduler owns the process).
+    The platform follows ``JAX_PLATFORMS`` (apps/launcher.py)."""
     from areal_tpu.apps import launcher as L
 
     setup = exp_cfg.initial_setup()
     if role == "master":
-        L._child_init(exp_cfg, force_cpu)
+        L._child_init(exp_cfg, False)
         from areal_tpu.system.master_worker import MasterWorker
 
         MasterWorker(setup["master"], setup["dfg"]).run()
@@ -64,12 +64,12 @@ def run_role(
         tc = setup["trainer"]
         tc.dist_rank = rank
         tc.dist_world = world
-        L.trainer_entry(exp_cfg, tc, force_cpu)
+        L.trainer_entry(exp_cfg, tc)
     elif role == "gen_fleet":
         if "gen_servers" not in setup:
             raise SystemExit("experiment has no generation fleet (sync mode)")
         L.gen_fleet_entry(
-            exp_cfg, setup["gen_servers"], setup["gserver_manager"], force_cpu
+            exp_cfg, setup["gen_servers"], setup["gserver_manager"]
         )
     elif role == "rollout":
         rcs = setup.get("rollout_workers", [])
@@ -77,7 +77,7 @@ def run_role(
             raise SystemExit(
                 f"rollout index {index} out of range (have {len(rcs)})"
             )
-        L.rollout_entry(exp_cfg, rcs[index], force_cpu)
+        L.rollout_entry(exp_cfg, rcs[index])
     else:
         raise SystemExit(f"unknown role {role!r}; have {ROLES}")
 
@@ -96,8 +96,11 @@ def main(argv: Optional[List[str]] = None) -> None:
                     default=_env_int("SLURM_PROCID", 0),
                     help="worker index within the role group (rollout); "
                          "defaults to SLURM_PROCID inside srun tasks")
-    ap.add_argument("--force-cpu", action="store_true")
+    ap.add_argument("--force-cpu", action="store_true",
+                    help="same as exporting JAX_PLATFORMS=cpu")
     args = ap.parse_args(argv)
+    if args.force_cpu:
+        os.environ["JAX_PLATFORMS"] = "cpu"
 
     cfg = build_config(args.experiment_cls, args.config)
     logger.info(
@@ -106,7 +109,7 @@ def main(argv: Optional[List[str]] = None) -> None:
         f"{cfg.trial_name}"
     )
     run_role(cfg, args.role, rank=args.rank, world=args.world,
-             index=args.index, force_cpu=args.force_cpu)
+             index=args.index)
 
 
 if __name__ == "__main__":

@@ -28,12 +28,11 @@ a first-class, alertable signal:
    been shape-stable for ``storm_warmup_calls`` calls increments
    ``compile/storm_events`` and logs the offending signature once — the
    signal the sentinel's ``recompile_storm`` rate rule watches.
- - Persistent-cache accounting: when the launcher's compilation cache is
-   configured (``AREAL_COMPILATION_CACHE``), the cache directory's entry
-   count is probed around each observed compile — an entry appearing
-   means XLA really compiled (``compile/cache_misses``); none appearing
-   means the compile was served from the persistent cache
-   (``compile/cache_hits``).
+ - Persistent-cache accounting: the entry count of the persistent
+   compilation cache (:func:`compilation_cache_dir`) is probed around
+   each observed compile — an entry appearing means XLA really compiled
+   (``compile/cache_misses``); none appearing means the compile was
+   served from the persistent cache (``compile/cache_hits``).
 
 Disabled contract (mirrors telemetry/goodput): until :func:`configure`
 installs an enabled watch, :func:`watched_jit` returns the raw function
@@ -52,20 +51,87 @@ from areal_tpu.base import logging, telemetry
 
 logger = logging.getLogger("base.compile_watch")
 
-# Single source of truth for the persistent-cache location (apps/launcher
-# re-exports it): the watch and the launcher must agree on the directory
-# or hit/miss accounting probes an empty dir forever.
-DEFAULT_COMPILATION_CACHE = os.path.expanduser(
-    "~/.cache/areal_tpu/jax_compilation_cache"
+# Where JAX_COMPILATION_CACHE_DIR is unset the cache lives at ONE fixed
+# path inside the checkout: the directory is part of the cache key, so a
+# path built from a temp name, a pid or the time would never hit.
+DEFAULT_COMPILATION_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    ))),
+    ".jax_cache",
 )
 
 
-def compilation_cache_dir() -> Optional[str]:
-    """The persistent-cache directory the launcher configures, or None
-    when caching is disabled (``AREAL_COMPILATION_CACHE=""``)."""
-    path = os.environ.get("AREAL_COMPILATION_CACHE",
-                          DEFAULT_COMPILATION_CACHE)
-    return path or None
+def compilation_cache_dir() -> str:
+    """The persistent compilation cache every compiling process shares:
+    the standard ``JAX_COMPILATION_CACHE_DIR`` when set, else the fixed
+    in-checkout default. The observatory's hit/miss probe watches the
+    same directory :func:`enable_compilation_cache` arms."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or DEFAULT_COMPILATION_CACHE)
+
+
+class CacheStats:
+    """This process's persistent-cache traffic, counted from jax's own
+    monitoring events: ``hits`` (executables read back), ``misses``
+    (compiled and written) and ``compile_secs`` (wall time inside the
+    backend compile call — a cache read when it hits)."""
+
+    def __init__(self) -> None:
+        self.hits = 0
+        self.misses = 0
+        self.compile_secs = 0.0
+
+    def _on_event(self, event: str, **_: Any) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def _on_duration(self, event: str, secs: float, **_: Any) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.compile_secs += secs
+
+    def as_dict(self) -> Dict[str, Any]:
+        return {"dir": compilation_cache_dir(), "hits": self.hits,
+                "misses": self.misses,
+                "compile_secs": round(self.compile_secs, 3)}
+
+
+_CACHE_STATS: Optional[CacheStats] = None
+
+
+def cache_stats() -> Optional[Dict[str, Any]]:
+    """Cache traffic of this process so far; None where
+    :func:`enable_compilation_cache` never ran."""
+    return _CACHE_STATS.as_dict() if _CACHE_STATS is not None else None
+
+
+def enable_compilation_cache() -> None:
+    """Arm JAX's persistent compilation cache for this process (launcher
+    children, bench.py and chip_smoke.py's phases all call this one
+    helper) and start counting its hits and misses. jax reads
+    ``JAX_COMPILATION_CACHE_DIR`` itself, so a directory is set in code
+    only when the variable is not. Imports jax and sets config — never
+    creates an array or asks for devices."""
+    global _CACHE_STATS
+    import jax
+
+    if _CACHE_STATS is not None:
+        return
+    path = compilation_cache_dir()
+    os.makedirs(path, exist_ok=True)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", path)
+    # Cache everything (the default skips compiles under 1 s): the fleet
+    # spawns several processes that compile the same small graphs.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    _CACHE_STATS = CacheStats()
+    jax.monitoring.register_event_listener(_CACHE_STATS._on_event)
+    jax.monitoring.register_event_duration_secs_listener(
+        _CACHE_STATS._on_duration
+    )
 
 
 def abstract_signature(args: tuple, kwargs: dict) -> str:
@@ -315,8 +381,8 @@ def configure(cfg=None, telemetry_sink=None,
     """Install the process-global compile watch. A disabled (or absent)
     config keeps the null sink — jit sites never re-check.
 
-    ``cache_dir="auto"`` resolves the launcher's persistent-cache dir
-    from the environment; pass None to disable cache accounting."""
+    ``cache_dir="auto"`` resolves :func:`compilation_cache_dir`; pass
+    None to disable cache accounting."""
     global _GLOBAL
     if cfg is None or not getattr(cfg, "enabled", False):
         _GLOBAL = NULL

@@ -10,22 +10,77 @@ gracefully to tensorboard-only (wandb is optional on pods).
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+import json
+from typing import Any, Dict, Optional
 
-# bf16 peak FLOP/s per chip by TPU generation (public spec sheet numbers).
+# bf16 peak FLOP/s of one chip, keyed by the EXACT ``device_kind`` jax
+# reports (a v5e reports "TPU v5 lite", a v5p "TPU v5"; the second
+# spelling of each is the one jax's own tpu_info also accepts). Source:
+# Google Cloud TPU documentation, system architecture page per generation.
 TPU_PEAK_BF16 = {
-    "v5 lite": 197e12, "v5e": 197e12, "v5p": 459e12,
-    "v4": 275e12, "v6e": 918e12, "v6": 918e12, "v5": 459e12,
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12, "TPU v5e": 197e12,
+    "TPU v5": 459e12, "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12, "TPU v6e": 918e12,
 }
 
 
 def device_peak_flops(device_kind: Optional[str] = None) -> Optional[float]:
+    """Peak bf16 FLOP/s of one chip of ``device_kind`` (default: this
+    process's first device). A TPU kind that is not in the table is an
+    error — a silent miss turned MFU into an absent or zero number; any
+    other device (the CPU) has no peak: None."""
     if device_kind is None:
         import jax
 
-        device_kind = str(jax.devices()[0])
-    kind = device_kind.lower()
-    return next((v for k, v in TPU_PEAK_BF16.items() if k in kind), None)
+        device_kind = jax.devices()[0].device_kind
+    if device_kind in TPU_PEAK_BF16:
+        return TPU_PEAK_BF16[device_kind]
+    if device_kind.startswith("TPU"):
+        raise KeyError(
+            f"no bf16 peak on record for device kind {device_kind!r}; add "
+            f"it to base/monitor.TPU_PEAK_BF16 (have "
+            f"{sorted(TPU_PEAK_BF16)})"
+        )
+    return None
+
+
+def device_report() -> Dict[str, Any]:
+    """What this process's JAX runtime got, as jax reports it: platform,
+    ``device_kind``, global device count, and per local device its id,
+    coordinates and allocator counters (None where the backend has no
+    ``memory_stats``, e.g. the CPU). Initializes the backend — only for
+    processes that own their devices."""
+    import jax
+
+    local = []
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        local.append({
+            "id": d.id,
+            "coords": list(getattr(d, "coords", ()) or ()) or None,
+            "bytes_in_use": stats.get("bytes_in_use"),
+            "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+        })
+    first = jax.devices()[0]
+    return {
+        "platform": first.platform,
+        "device_kind": first.device_kind,
+        "device_count": jax.device_count(),
+        "local_devices": local,
+    }
+
+
+DEVICE_REPORT_TAG = "device_report "
+
+
+def log_device_report(logger, worker: str, **extra: Any) -> None:
+    """One machine-readable log line per device-owning worker
+    (``device_report {json}``): chip_smoke.py — which never imports jax —
+    reads the device and the counters from it."""
+    logger.info(DEVICE_REPORT_TAG + json.dumps(
+        {"worker": worker, **device_report(), **extra}
+    ))
 
 
 def transformer_flops_per_token(

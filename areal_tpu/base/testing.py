@@ -27,8 +27,11 @@ class MockTokenizer:
         return [(b % (self.vocab_size - 2)) + 2 for b in text.encode()]
 
     def decode(self, ids) -> str:
+        # A model's vocabulary may be far wider than this tokenizer's 256
+        # bytes (a real checkpoint under mock_tokenizer): wrap, don't raise.
         return bytes(
-            max(int(i) - 2, 0) for i in ids if int(i) not in (PAD_TOKEN, EOS_TOKEN)
+            (int(i) - 2) % 256 for i in ids
+            if int(i) not in (PAD_TOKEN, EOS_TOKEN)
         ).decode(errors="replace")
 
     def __call__(self, texts, **kw):

@@ -210,21 +210,10 @@ def _grouped_matmul(xs: jnp.ndarray,  # [M, K] rows sorted by group
                     ) -> jnp.ndarray:
     """Grouped GEMM over contiguous row segments: row m multiplies
     ``w[g]`` where m falls in group g's segment. Rows beyond
-    ``sum(group_sizes)`` yield zeros (ragged_dot guarantees this; the
-    fallback masks them out) — sentinel-sorted padding entries land there.
+    ``sum(group_sizes)`` yield zeros (ragged_dot guarantees this) —
+    sentinel-sorted padding entries land there.
     """
-    if hasattr(jax.lax, "ragged_dot"):
-        return jax.lax.ragged_dot(xs, w, group_sizes)
-    # Sorted-segment fallback (pre-ragged_dot jax): static unroll over
-    # groups with masked dense matmuls — correct, not fast.
-    starts = jnp.cumsum(group_sizes) - group_sizes
-    ends = starts + group_sizes
-    idx = jnp.arange(xs.shape[0])
-    out = jnp.zeros((xs.shape[0], w.shape[-1]), dtype=xs.dtype)
-    for g in range(w.shape[0]):
-        m = ((idx >= starts[g]) & (idx < ends[g])).astype(xs.dtype)
-        out = out + (xs * m[:, None]) @ w[g]
-    return out
+    return jax.lax.ragged_dot(xs, w, group_sizes)
 
 
 def _dispatch_grouped(
@@ -305,7 +294,6 @@ def _dispatch_ep(
     no-drop regime; under drops the priority is per-source-shard rather
     than global (tested/documented — docs/parallelism.md §Expert
     parallelism)."""
-    from areal_tpu.parallel.compat import shard_map
     from areal_tpu.parallel.mesh import DATA_AXES
 
     B, T, D = x.shape
@@ -348,9 +336,10 @@ def _dispatch_ep(
         return y.reshape(Bl, Tl, D), dropped
 
     tok_spec = P(DATA_AXES, "sp")
-    y, dropped_frac = shard_map(
+    y, dropped_frac = jax.shard_map(
         body,
         mesh=mesh,
+        check_vma=False,
         in_specs=(P(DATA_AXES, "sp", None), tok_spec, tok_spec, tok_spec,
                   P("ep", None, "tp"), P("ep", None, "tp"),
                   P("ep", "tp", None)),

@@ -300,7 +300,6 @@ def apply_layer_stack(
     ring_ctx=None,  # ring.RingCtx when inside a manual sp region (PP∘SP)
     rng: Optional[jnp.ndarray] = None,
     allow_ep: bool = True,  # False inside manual regions (pipeline stages)
-    unroll: bool = False,  # python loop over layers instead of lax.scan
 ):
     """Run a stacked layer dict over ``h`` via lax.scan (packed mode, no KV
     out). Returns (h, aux) where aux stacks per-layer MoE scalars ({} for
@@ -314,43 +313,7 @@ def apply_layer_stack(
 
     ``rng``: base key for MoE router input jitter — split per layer and
     scanned alongside the params so each layer perturbs independently.
-    ``rng=None`` keeps the original scan body (bit-identical off path).
-
-    ``unroll``: replace the layer scan with a python loop. The 1F1B
-    pipeline stages set this for grouped-dispatch MoE: on jax 0.4.x the
-    transpose of this scan, nested inside the 1F1B backward's step scan
-    in a shard_map manual region, silently produces wrong cotangents for
-    the sort/gather ops of the grouped path (einsum dispatch and the
-    GSPMD non-pipelined path are unaffected; parallel/pipeline.py
-    _make_stage_fn has the full story). Stages hold n_layers/pp layers,
-    so the jaxpr growth is bounded and small."""
-
-    if unroll:
-        n_layers = jax.tree_util.tree_leaves(layer_params)[0].shape[0]
-        layer_keys = (jax.random.split(rng, n_layers)
-                      if rng is not None else None)
-
-        def body_i(h, lp, key):
-            h2, _, aux = _block(
-                cfg, h, lp, cos, sin, segment_ids, positions,
-                None, None, None, attn_impl, allow_ring=allow_ring,
-                ring_ctx=ring_ctx, rng=key, allow_ep=allow_ep,
-            )
-            return h2, aux
-
-        body_i = _maybe_checkpoint(body_i, remat)
-        auxes = []
-        for i in range(n_layers):
-            lp_i = jax.tree_util.tree_map(lambda a: a[i], layer_params)
-            h, aux = body_i(
-                h, lp_i, layer_keys[i] if layer_keys is not None else None
-            )
-            auxes.append(aux)
-        if auxes and auxes[0] is not None:
-            aux = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *auxes)
-        else:
-            aux = {}
-        return h, aux
+    ``rng=None`` keeps the original scan body (bit-identical off path)."""
 
     if rng is not None:
         n_layers = jax.tree_util.tree_leaves(layer_params)[0].shape[0]

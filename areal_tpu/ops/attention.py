@@ -13,14 +13,47 @@ Shapes: q ``[B, T, Hq, D]``; k, v ``[B, S, Hkv, D]`` with Hq = G * Hkv (GQA).
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import contextvars
 from functools import partial
-from typing import Optional
+from typing import Dict, Iterator, Optional
 
 import jax
 import jax.numpy as jnp
 
 _NEG_INF = -1e30
-_WARNED_FALLBACK = False
+
+# Which implementation each packed_attention call TRACED to, by the label
+# of the compiled step it was traced for: {label: {"pallas" | "reference"
+# | "fallback": n}}. "fallback" = the TPU kernel was wanted and the O(S^2)
+# reference ran instead (a shape with no 128-multiple block, or a sliding
+# window). Counted at trace time, so it describes the compiled programs;
+# chip_smoke.py fails when the train step's fallback count is not zero.
+_DISPATCH: Dict[str, collections.Counter] = collections.defaultdict(
+    collections.Counter
+)
+_LABEL = contextvars.ContextVar("attention_dispatch_label",
+                               default="unlabelled")
+
+
+@contextlib.contextmanager
+def dispatch_label(label: str) -> Iterator[None]:
+    """Attribute the packed_attention calls traced inside the block (a jit
+    traces synchronously inside its first call) to ``label``."""
+    token = _LABEL.set(label)
+    try:
+        yield
+    finally:
+        _LABEL.reset(token)
+
+
+def count_dispatch(impl: str) -> None:
+    _DISPATCH[_LABEL.get()][impl] += 1
+
+
+def dispatch_counts() -> Dict[str, Dict[str, int]]:
+    return {label: dict(c) for label, c in _DISPATCH.items()}
 
 
 def segment_mask(
@@ -96,41 +129,39 @@ def packed_attention(
 ) -> jnp.ndarray:
     """Dispatch between the XLA reference and the Pallas TPU kernel.
 
-    The kernel wrapper itself degrades to the reference for shapes it
-    cannot tile (no 128-multiple block divisor — see
-    ops/pallas/flash_attention.pick_block_sizes) by calling back into this
-    function with ``impl="reference"``, so the except-clause below only
-    handles a missing/broken pallas import. ``scale`` defaults to
-    ``head_dim ** -0.5`` in both implementations."""
-    explicit = impl == "pallas"
-    if explicit and sliding_window is not None:
+    ``impl="auto"`` means the kernel on a TPU and the reference elsewhere.
+    On a TPU nothing quietly replaces the kernel: a failed import raises,
+    and the two cases the kernel cannot run (a sequence dim with no
+    128-multiple block — a prompt bucket, never a packed training row —
+    and a sliding window) are counted as "fallback" under the active
+    :func:`dispatch_label`. ``scale`` defaults to ``head_dim ** -0.5`` in
+    both implementations."""
+    if impl == "pallas" and sliding_window is not None:
         raise NotImplementedError(
             "pallas flash attention does not support sliding_window yet; "
             "use impl='reference'"
         )
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "reference"
-    if impl == "pallas" and sliding_window is None:
-        try:
-            from areal_tpu.ops.pallas.flash_attention import flash_attention
+    wanted_kernel = impl == "pallas" or (
+        impl == "auto" and jax.default_backend() == "tpu"
+    )
+    if wanted_kernel and sliding_window is None:
+        from areal_tpu.ops.pallas import flash_attention as fa
 
-            return flash_attention(
+        if fa.pick_block_sizes(q.shape[1], k.shape[1]) is not None:
+            from areal_tpu.parallel.sharding import current_mesh
+
+            count_dispatch("pallas")
+            mesh = current_mesh()
+            if mesh is not None and mesh.size > 1:
+                return fa.flash_attention_on_mesh(
+                    mesh, q, k, v, q_segment_ids, kv_segment_ids,
+                    causal=causal, scale=scale,
+                )
+            return fa.flash_attention(
                 q, k, v, q_segment_ids, kv_segment_ids,
-                q_positions=q_positions, kv_positions=kv_positions,
                 causal=causal, scale=scale,
             )
-        except (ImportError, NotImplementedError) as e:
-            if explicit:
-                raise
-            global _WARNED_FALLBACK
-            if not _WARNED_FALLBACK:
-                _WARNED_FALLBACK = True
-                import logging
-
-                logging.getLogger("areal_tpu").warning(
-                    "pallas flash attention unavailable (%s); falling back to "
-                    "the O(S^2) XLA reference", e,
-                )
+    count_dispatch("fallback" if wanted_kernel else "reference")
     mask = segment_mask(
         q_segment_ids, kv_segment_ids, q_positions, kv_positions, causal,
         sliding_window=sliding_window,

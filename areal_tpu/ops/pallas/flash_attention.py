@@ -31,18 +31,15 @@ Table/env entries are validated against the kernel's divisibility
 constraint and snap DOWN to the nearest dividing 128-multiple rather than
 failing at dispatch time.
 
-Sequence dims with NO 128-multiple divisor no longer raise: the call falls
-back to the XLA reference attention (ops/attention.py) with a once-per-
-process log line. Training shapes never hit this (the packing
-length_bucket guarantees 128-aligned rows); the fallback exists so ad-hoc
-shapes (eval, probes) degrade gracefully instead of crashing.
+Sequence dims with NO 128-multiple divisor cannot be tiled:
+:func:`flash_attention` raises for them, and the dispatcher
+(ops/attention.packed_attention) asks :func:`pick_block_sizes` first and
+runs — and counts — the XLA reference instead. Packed training rows never
+land there (the packer's 128-token length bucket); bucketed prompts can.
 
-CPU/testing: wrap calls in ``interpret_mode()`` — on jax versions shipping
-``pltpu.force_tpu_interpret_mode`` the parity test
-(tests/test_pallas_attention.py) runs the same kernel interpreted; on
-jax 0.4.x the pallas interpreter cannot execute this kernel (its
-load-discharge rule chokes on scalar block indices) and the helper
-returns None so tests skip with a reason instead of failing.
+CPU/testing: wrap calls in ``pltpu.force_tpu_interpret_mode()`` — the
+parity tests (tests/test_pallas_attention.py) run the same kernel
+interpreted; tests/test_tpu_compile.py compiles it for a described v5e.
 """
 
 from __future__ import annotations
@@ -75,7 +72,6 @@ logger = logging.getLogger("areal_tpu")
 _BLOCK_TABLE: Dict[Tuple[int, int], Tuple[int, int]] = {}
 _TABLE_FILE_LOADED: Optional[str] = None  # set only on a SUCCESSFUL load
 _TABLE_FILE_WARNED: set = set()
-_WARNED_REF_FALLBACK = False
 
 
 def _block(n: int, target: int) -> Optional[int]:
@@ -158,42 +154,6 @@ def pick_block_sizes(T: int, S: int) -> Optional[Tuple[int, int]]:
     return (heur_q, heur_kv)
 
 
-def interpret_mode():
-    """``pltpu.force_tpu_interpret_mode()`` when this jax ships it, else
-    None (jax 0.4.x: the pallas interpreter cannot execute this kernel —
-    ``pl.pallas_call(interpret=True)`` dies in its load-discharge rule on
-    scalar block indices — so CPU parity tests must skip, with this as the
-    single version gate they consult)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    ctx = getattr(pltpu, "force_tpu_interpret_mode", None)
-    return ctx() if ctx is not None else None
-
-
-def _reference_fallback(q, k, v, q_segment_ids, kv_segment_ids,
-                        q_positions, kv_positions, causal, scale, why):
-    global _WARNED_REF_FALLBACK
-    if not _WARNED_REF_FALLBACK:
-        _WARNED_REF_FALLBACK = True
-        logger.warning(
-            "pallas flash attention: %s; falling back to the O(S^2) XLA "
-            "reference for this shape (further fallbacks logged at debug)",
-            why,
-        )
-    else:
-        logger.debug("pallas flash attention fallback: %s", why)
-    # One definition of the reference recipe: route back through the
-    # dispatcher with impl="reference" (no recursion — that path never
-    # re-enters this module).
-    from areal_tpu.ops import attention as attn
-
-    return attn.packed_attention(
-        q, k, v, q_segment_ids, kv_segment_ids, q_positions=q_positions,
-        kv_positions=kv_positions, causal=causal, impl="reference",
-        scale=scale,
-    )
-
-
 @functools.partial(
     jax.named_call, name="pallas_flash_attention"
 )
@@ -212,13 +172,10 @@ def flash_attention(
     S, Hkv = k.shape[1], k.shape[2]
     blocks = pick_block_sizes(T, S)
     if blocks is None:
-        # No 128-multiple divisor: the kernel cannot tile this shape.
-        # Degrade to the reference instead of raising (training shapes are
-        # length_bucket-aligned and never land here).
-        return _reference_fallback(
-            q, k, v, q_segment_ids, kv_segment_ids, q_positions,
-            kv_positions, causal, scale,
-            f"sequence dims T={T} S={S} have no 128-multiple block",
+        raise ValueError(
+            f"sequence dims T={T} S={S} have no 128-multiple block; "
+            "ops/attention.packed_attention routes such shapes to the "
+            "reference"
         )
     if scale is None:
         scale = D ** -0.5
@@ -258,3 +215,49 @@ def flash_attention(
     out = out.transpose(0, 2, 1, 3)
     # Zero pad-query rows (the kernel leaves them unspecified-but-finite).
     return out * (q_segment_ids > 0)[:, :, None, None].astype(out.dtype)
+
+
+def flash_attention_on_mesh(
+    mesh,
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    q_segment_ids: jnp.ndarray,
+    kv_segment_ids: jnp.ndarray,
+    causal: bool = True,
+    scale: Optional[float] = None,
+) -> jnp.ndarray:
+    """:func:`flash_attention` under a multi-device mesh. GSPMD cannot
+    partition a Mosaic kernel ("wrap the call in a shard_map"), so the call
+    runs in a shard_map manual over every mesh axis not already manual (the
+    pipeline stages are manual over "pp"): batch rows split over the data
+    axes and heads over "tp" where the dims divide — an axis that does not
+    divide, and pp/sp, compute redundantly. Attention has no cross-row or
+    cross-head term, so the body needs no collective."""
+    from jax.sharding import PartitionSpec as P
+
+    from areal_tpu.parallel.mesh import DATA_AXES
+
+    outer = jax.sharding.get_abstract_mesh()
+    if outer.manual_axes:  # nested: shard_map wants the context's own mesh
+        mesh = outer
+    free = frozenset(mesh.axis_names) - frozenset(outer.manual_axes)
+    data = tuple(a for a in DATA_AXES if a in free)
+    n_data = 1
+    for a in data:
+        n_data *= mesh.shape[a]
+    if q.shape[0] % n_data != 0:
+        data = ()
+    heads = ("tp" if "tp" in free and k.shape[2] % mesh.shape["tp"] == 0
+             else None)
+    qkv = P(data or None, None, heads, None)
+    seg = P(data or None, None)
+    return jax.shard_map(
+        functools.partial(flash_attention, causal=causal, scale=scale),
+        mesh=mesh,
+        in_specs=(qkv, qkv, qkv, seg, seg),
+        out_specs=qkv,
+        axis_names=free,
+        check_vma=False,
+    )(q, k, v, q_segment_ids, kv_segment_ids)
+

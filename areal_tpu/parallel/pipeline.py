@@ -66,7 +66,6 @@ from areal_tpu.base import logging, telemetry
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.parallel import ring as ring_mod
 from areal_tpu.parallel import sharding as psh
-from areal_tpu.parallel.compat import shard_map
 
 logger = logging.getLogger("parallel.pipeline")
 
@@ -78,7 +77,6 @@ _FALLBACK_HINTS = {
     "layers_indivisible": "n_layers must divide the pp axis",
     "batch_too_small": "batch has no divisor in [pp, 2*pp]",
     "requested_indivisible": "requested micro-batch count must divide batch",
-    "old_jax_mixed_mesh": "this jax only pipelines pure pp/pp×sp meshes",
     "sp_seq_indivisible": "seq_len must divide the sp axis to ring",
     "sp_sliding_window": "sliding-window attention is not ring-expressible",
 }
@@ -128,18 +126,6 @@ def pick_pp_microbatches(
             return _fallback("sp_sliding_window")
     if cfg.n_layers % pp != 0:
         return _fallback("layers_indivisible")
-    if getattr(jax, "shard_map", None) is None:
-        # jax 0.4.x: partial-manual shard_map over the pipeline axes
-        # composed with auto (GSPMD) axes crashes the XLA CPU compiler on
-        # mixed meshes; only pure pp (and pp×sp — both manual) meshes
-        # pipeline there. Mixed meshes keep the correct GSPMD
-        # layer-sharding path (just not pipelined).
-        other = 1
-        for name, size in mesh.shape.items():
-            if name not in ("pp", "sp"):
-                other *= size
-        if other > 1:
-            return _fallback("old_jax_mixed_mesh")
     if requested is not None:
         n_micro = requested
         if batch % n_micro != 0:
@@ -255,23 +241,21 @@ def pipeline_apply_layers(
 
 def _stage_specs(layer_params, sp_manual):
     """(manual_axes, in_spec pieces) shared by the three shard_maps: the
-    stage iota, ring iota, layer stack, [n_micro, mb, T, ...] activations
-    and [n_micro, mb, T] token arrays. With sp manual the sequence dim
-    shards over the ring; otherwise the specs are exactly the pp-only
-    originals."""
+    layer stack, [n_micro, mb, T, ...] activations and [n_micro, mb, T]
+    token arrays. With sp manual the sequence dim shards over the ring;
+    otherwise the specs are exactly the pp-only originals."""
     layer_specs = jax.tree.map(lambda _: P("pp"), layer_params)
     if sp_manual:
-        return ({"pp", "sp"}, P("sp"), layer_specs,
+        return (frozenset({"pp", "sp"}), layer_specs,
                 P(None, None, "sp", None), P(None, None, "sp"))
-    return ({"pp"}, P(), layer_specs, P(), P())
+    return (frozenset({"pp"}), layer_specs, P(), P())
 
 
-def _ring_ctx(ring_arr, sp, ring_schedule):
-    """RingCtx from the P("sp")-sharded iota (None when sp is not manual);
-    see ring_mod.RingCtx for why the rank can't come from axis_index."""
+def _ring_ctx(sp, ring_schedule):
+    """RingCtx of a stage body (None when sp is not manual)."""
     if sp <= 1:
         return None
-    return ring_mod.RingCtx("sp", sp, ring_arr[0], ring_schedule)
+    return ring_mod.RingCtx("sp", sp, ring_schedule)
 
 
 def _gpipe_apply_layers(
@@ -295,14 +279,10 @@ def _gpipe_apply_layers(
     seg_mbs = to_mbs(segment_ids)
     pos_mbs = to_mbs(positions)
 
-    def stage_body(stage_arr, ring_arr, local_layers, h_mbs, cos_mbs,
+    def stage_body(local_layers, h_mbs, cos_mbs,
                    sin_mbs, seg_mbs, pos_mbs):
-        # Stage id arrives as a P("pp")-sharded iota rather than
-        # jax.lax.axis_index: under partial-manual shard_map on older jax
-        # the latter lowers to a PartitionId instruction the SPMD
-        # partitioner rejects when auto axes are present.
-        stage = stage_arr[0]
-        ring_ctx = _ring_ctx(ring_arr, sp, ring_schedule)
+        stage = jax.lax.axis_index("pp")
+        ring_ctx = _ring_ctx(sp, ring_schedule)
         fwd_perm = [(k, k + 1) for k in range(pp - 1)]
         Tl = h_mbs.shape[2]  # local sequence shard (T/sp when sp manual)
 
@@ -358,19 +338,19 @@ def _gpipe_apply_layers(
     # Manual over the pipeline axes only: layer stacks arrive as local
     # [L/pp, ...] slices (and activations as T/sp sequence shards when sp
     # rings); dp/fsdp/tp inside each stage stay automatic (GSPMD).
-    manual, iota_spec, layer_specs, act_spec, tok_spec = _stage_specs(
+    manual, layer_specs, act_spec, tok_spec = _stage_specs(
         layer_params, sp > 1
     )
     ys_spec = P("pp", None, "sp", None) if sp > 1 else P("pp")
-    ys, aux = shard_map(
+    ys, aux = jax.shard_map(
         stage_body,
         mesh=mesh,
-        in_specs=(P("pp"), iota_spec, layer_specs, act_spec, act_spec,
+        check_vma=False,
+        in_specs=(layer_specs, act_spec, act_spec,
                   act_spec, tok_spec, tok_spec),
         out_specs=(ys_spec, P()),
         axis_names=manual,
-    )(jnp.arange(pp, dtype=jnp.int32), jnp.arange(sp, dtype=jnp.int32),
-      layer_params, h_mbs, cos_mbs, sin_mbs, seg_mbs, pos_mbs)
+    )(layer_params, h_mbs, cos_mbs, sin_mbs, seg_mbs, pos_mbs)
 
     # ys is the per-stage step outputs concatenated over "pp":
     # [pp*steps, mb, T, D]; the finished micro-batch i left the LAST stage
@@ -405,20 +385,6 @@ def _make_stage_fn(cfg, attn_impl, remat):
                  ring_ctx=None):
         from areal_tpu.models import transformer as tfm
 
-        # Grouped-dispatch MoE stages unroll the per-stage layer loop:
-        # on jax 0.4.x CPU the layer scan's transpose, nested inside the
-        # 1F1B backward's step scan within the custom-vjp program,
-        # silently mis-computes the cotangents of the grouped path's
-        # sort/gather ops (~1e-2 off; the einsum oracle through the
-        # identical nesting is exact, as is this path with remat=True or
-        # with either scan replaced by a loop). A stage holds only
-        # n_layers/pp layers, so the unroll is cheap.
-        unroll = False
-        if cfg.moe is not None:
-            from areal_tpu.models import moe as moemod
-
-            unroll = moemod.resolve_dispatch() == "grouped"
-
         # Stage bodies trace inside a shard_map manual over {"pp"} or
         # {"pp","sp"}, but the trace POINT varies: the 1F1B custom-vjp
         # backward traces after pipeline_apply_layers' stripped-rules
@@ -431,7 +397,6 @@ def _make_stage_fn(cfg, attn_impl, remat):
                 attn_impl=attn_impl, remat=remat, allow_ring=True,
                 ring_ctx=ring_ctx,
                 allow_ep=False,  # no nested shard_map inside the pp stages
-                unroll=unroll,
             )
         # Only the scalar keys: the 1F1B backward builds cotangents from
         # _aux_keys, and vector stats (expert_load) don't pipeline.
@@ -458,10 +423,10 @@ def _1f1b_parts(cfg, mesh, n_micro, attn_impl, remat,
     aux_keys = _aux_keys(cfg)
     stage_fn = _make_stage_fn(cfg, attn_impl, remat)
 
-    def fwd_body(stage_arr, ring_arr, local_layers, h_mbs, cos_mbs,
+    def fwd_body(local_layers, h_mbs, cos_mbs,
                  sin_mbs, seg_mbs, pos_mbs):
-        stage = stage_arr[0]  # P("pp") iota; see _gpipe stage_body note
-        ring_ctx = _ring_ctx(ring_arr, sp, ring_schedule)
+        stage = jax.lax.axis_index("pp")
+        ring_ctx = _ring_ctx(sp, ring_schedule)
         fwd_perm = [(k, k + 1) for k in range(pp - 1)]
         Tl = h_mbs.shape[2]
 
@@ -513,19 +478,19 @@ def _1f1b_parts(cfg, mesh, n_micro, attn_impl, remat,
                    for k, v in aux_acc.items()}
         return out_buf, aux_out, saved_x
 
-    manual, iota_spec, layer_specs, act_spec, tok_spec = _stage_specs(
+    manual, layer_specs, act_spec, tok_spec = _stage_specs(
         layer_params, sp > 1
     )
     buf_spec = P("pp", None, "sp", None) if sp > 1 else P("pp")
-    return shard_map(
+    return jax.shard_map(
         fwd_body,
         mesh=mesh,
-        in_specs=(P("pp"), iota_spec, layer_specs, act_spec, act_spec,
+        check_vma=False,
+        in_specs=(layer_specs, act_spec, act_spec,
                   act_spec, tok_spec, tok_spec),
         out_specs=(buf_spec, P(), buf_spec),
         axis_names=manual,
-    )(jnp.arange(pp, dtype=jnp.int32), jnp.arange(sp, dtype=jnp.int32),
-      layer_params, h_mbs, cos_mbs, sin_mbs, seg_mbs, pos_mbs)
+    )(layer_params, h_mbs, cos_mbs, sin_mbs, seg_mbs, pos_mbs)
 
 
 def _1f1b_bwd_impl(cfg, mesh, n_micro, attn_impl, remat,
@@ -543,10 +508,10 @@ def _1f1b_bwd_impl(cfg, mesh, n_micro, attn_impl, remat,
     aux_keys = _aux_keys(cfg)
     stage_fn = _make_stage_fn(cfg, attn_impl, remat)
 
-    def bwd_body(stage_arr, ring_arr, local_layers, saved_x, cos_mbs,
+    def bwd_body(local_layers, saved_x, cos_mbs,
                  sin_mbs, seg_mbs, pos_mbs, d_out, d_aux):
-        stage = stage_arr[0]  # P("pp") iota; see _gpipe stage_body note
-        ring_ctx = _ring_ctx(ring_arr, sp, ring_schedule)
+        stage = jax.lax.axis_index("pp")
+        ring_ctx = _ring_ctx(sp, ring_schedule)
         bwd_perm = [(k, k - 1) for k in range(1, pp)]
         _, mb, Tl, D = saved_x.shape
 
@@ -607,19 +572,19 @@ def _1f1b_bwd_impl(cfg, mesh, n_micro, attn_impl, remat,
             )
         return dtheta, d_h_buf
 
-    manual, iota_spec, layer_specs, act_spec, tok_spec = _stage_specs(
+    manual, layer_specs, act_spec, tok_spec = _stage_specs(
         layer_params, sp > 1
     )
     buf_spec = P("pp", None, "sp", None) if sp > 1 else P("pp")
-    d_layers, d_h_blocks = shard_map(
+    d_layers, d_h_blocks = jax.shard_map(
         bwd_body,
         mesh=mesh,
-        in_specs=(P("pp"), iota_spec, layer_specs, buf_spec, act_spec,
+        check_vma=False,
+        in_specs=(layer_specs, buf_spec, act_spec,
                   act_spec, tok_spec, tok_spec, buf_spec, P()),
         out_specs=(P("pp"), buf_spec),
         axis_names=manual,
-    )(jnp.arange(pp, dtype=jnp.int32), jnp.arange(sp, dtype=jnp.int32),
-      layer_params, saved_x, cos_mbs, sin_mbs, seg_mbs, pos_mbs, d_out,
+    )(layer_params, saved_x, cos_mbs, sin_mbs, seg_mbs, pos_mbs, d_out,
       d_aux)
     # d_h_blocks concatenates per-stage buffers over "pp"; only stage 0
     # ingests h, so its block (the first) is the input cotangent — a lazy

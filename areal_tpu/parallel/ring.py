@@ -54,7 +54,6 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.parallel.compat import shard_map
 from areal_tpu.parallel.mesh import DATA_AXES
 
 _NEG_INF = -1e30
@@ -128,12 +127,9 @@ def inverse_permutation(perm: np.ndarray) -> np.ndarray:
 class RingCtx:
     """Ring parameters for callers already inside a manual shard_map region
     over ``axis_name`` (the PP∘SP pipeline stages): ``n`` is the static
-    ring size, ``my`` the traced rank of this shard — derived from a
-    sharded iota, because ``lax.axis_index`` lowers to a PartitionId
-    instruction older partial-manual partitioners reject."""
+    ring size."""
     axis_name: str
     n: int
-    my: jnp.ndarray
     schedule: str
 
 
@@ -319,11 +315,9 @@ def _ring_local_zigzag(q, k, v, q_seg, axis_name, n, my, scale):
     return out.astype(q.dtype)
 
 
-def _ring_local(q, k, v, q_seg, axis_name, n, my, causal, scale, schedule):
-    """Schedule dispatch for the per-shard body. ``my=None`` means "ask
-    the axis" (full-manual regions, where lax.axis_index lowers fine)."""
-    if my is None:
-        my = jax.lax.axis_index(axis_name)
+def _ring_local(q, k, v, q_seg, axis_name, n, causal, scale, schedule):
+    """Schedule dispatch for the per-shard body."""
+    my = jax.lax.axis_index(axis_name)
     if schedule == "zigzag" and causal:
         return _ring_local_zigzag(q, k, v, q_seg, axis_name, n, my, scale)
     return _ring_local_naive(q, k, v, q_seg, axis_name, n, my, causal, scale)
@@ -341,7 +335,7 @@ def ring_attention_inline(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     return _ring_local(
-        q, k, v, segment_ids, ctx.axis_name, ctx.n, ctx.my,
+        q, k, v, segment_ids, ctx.axis_name, ctx.n,
         causal, scale, ctx.schedule,
     )
 
@@ -392,12 +386,13 @@ def ring_attention(
     qkv_spec = P(DATA_AXES, axis_name, "tp", None)
     seg_spec = P(DATA_AXES, axis_name)
     fn = partial(
-        _ring_local, axis_name=axis_name, n=n, my=None, causal=causal,
+        _ring_local, axis_name=axis_name, n=n, causal=causal,
         scale=scale, schedule=schedule,
     )
-    out = shard_map(
+    out = jax.shard_map(
         fn,
         mesh=mesh,
+        check_vma=False,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, seg_spec),
         out_specs=qkv_spec,
     )(q, k, v, segment_ids)

@@ -52,6 +52,7 @@ from areal_tpu.base import compile_watch as compile_watch_mod
 from areal_tpu.base import logging, name_resolve, names, network, telemetry
 from areal_tpu.models import generate as genmod
 from areal_tpu.models import transformer  # noqa: F401 (engine deps)
+from areal_tpu.ops.attention import dispatch_label
 from areal_tpu.system import goodput as goodput_mod
 from areal_tpu.system import memwatch as memwatch_mod
 from areal_tpu.system import serving as serving_mod
@@ -213,7 +214,7 @@ class GenerationServer:
             self._mfu = goodput_mod.MfuEmitter(
                 self.telemetry,
                 goodput_mod.resolve_peak_flops(
-                    cfg.goodput, str(jax.devices()[0])
+                    cfg.goodput, jax.devices()[0].device_kind
                 ),
                 tflops_name="genserver/decode_tflops",
                 mfu_name="genserver/decode_mfu",
@@ -364,10 +365,11 @@ class GenerationServer:
             shapes.observe("prefill", B_pad, padded.shape[1], S)
             t_prefill_wall = time.time()
             t_prefill = time.monotonic()
-            st = self._prefill_fn(
-                params, self.model_cfg, jnp.asarray(padded),
-                jnp.asarray(plens), S,
-            )
+            with dispatch_label("prefill"):
+                st = self._prefill_fn(
+                    params, self.model_cfg, jnp.asarray(padded),
+                    jnp.asarray(plens), S,
+                )
             prefill_secs = time.monotonic() - t_prefill
             n_prefill = int(plens[:len(fresh)].sum())
             self._prefill_tokens += n_prefill
@@ -1023,10 +1025,33 @@ class GenerationServer:
             charset="utf-8", headers={"X-Prometheus-Version": "0.0.4"},
         )
 
+    def _device_info(self) -> Dict[str, Any]:
+        """Where THIS server's weights live, as jax reports it, plus the
+        process-wide trace/compile counters a chip run is judged by."""
+        import jax
+
+        from areal_tpu.ops import attention
+
+        devs = sorted(jax.tree_util.tree_leaves(self.params)[0].devices(),
+                      key=lambda d: d.id)
+        stats = [d.memory_stats() or {} for d in devs]
+        return {
+            "platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": jax.device_count(),
+            "device_ids": [d.id for d in devs],
+            "hbm_bytes_in_use": [s.get("bytes_in_use") for s in stats],
+            "hbm_peak_bytes": [s.get("peak_bytes_in_use") for s in stats],
+            "attention": attention.dispatch_counts(),
+            "compile_cache": compile_watch_mod.cache_stats(),
+        }
+
     async def handle_metrics_json(self, request):
         from aiohttp import web
 
-        return web.json_response(self._metrics_dict())
+        return web.json_response(
+            {**self._metrics_dict(), "device": self._device_info()}
+        )
 
     def build_app(self):
         from aiohttp import web

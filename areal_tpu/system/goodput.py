@@ -293,8 +293,9 @@ def make_ledger(cfg, sink, clock=time.monotonic,
 def resolve_peak_flops(cfg, device_kind: Optional[str] = None
                        ) -> Optional[float]:
     """Per-chip peak FLOP/s for live MFU: the config override when set,
-    else the per-generation table (``monitor.device_peak_flops``), else
-    None — unknown kinds degrade to achieved-TFLOP/s-only."""
+    else the table keyed by ``device_kind`` (``monitor.device_peak_flops``:
+    an unknown TPU kind raises); None off the TPU — achieved-TFLOP/s
+    only."""
     from areal_tpu.base import monitor
 
     override = float(getattr(cfg, "peak_flops_override", 0.0) or 0.0)

@@ -308,8 +308,10 @@ class MasterWorker:
         info = self._model_info.get("roles", {}).get(node.model_name)
         if info is None or not metas:
             return
-        key = next(iter(metas[0].seqlens))
-        lens = [sum(m.seqlens[key][0]) for m in metas]
+        # The MAIN token key: seqlens also carries scalar keys (rewards:
+        # [[1]] per sample), and taking whichever comes first understated
+        # the FLOPs ~170x on the first chip run (48 "tokens" for 8.1k).
+        lens = [int(m.total_lens()[0]) for m in metas]
         n_tokens = float(sum(lens))
         avg = n_tokens / max(len(lens), 1)
 

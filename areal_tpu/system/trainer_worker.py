@@ -306,7 +306,7 @@ class TrainerWorker:
                 self._mfu = goodput_mod.MfuEmitter(
                     telemetry.get(),
                     goodput_mod.resolve_peak_flops(
-                        cfg.goodput, str(jax.devices()[0])
+                        cfg.goodput, jax.devices()[0].device_kind
                     ),
                     tflops_name="train/achieved_tflops",
                     mfu_name="train/mfu", context="trainer",
@@ -322,6 +322,20 @@ class TrainerWorker:
         logger.info(
             f"trainer up (rank {cfg.dist_rank}/{cfg.dist_world}): "
             f"models={list(self.models)} mfcs={list(self.interfaces)}"
+        )
+        self._log_device_report("setup")
+
+    def _log_device_report(self, stage: str) -> None:
+        """Device, HBM counters and the trace-time/host-side facts a chip
+        run is judged by (base/monitor.log_device_report)."""
+        from areal_tpu.base import monitor
+        from areal_tpu.ops import attention, native
+
+        monitor.log_device_report(
+            logger, f"trainer{self.cfg.dist_rank}", stage=stage,
+            attention=attention.dispatch_counts(),
+            compile_cache=compile_watch.cache_stats(),
+            native_ops="g++" if native.available() else "numpy",
         )
 
     def _reshuffle(self):
@@ -908,7 +922,8 @@ class TrainerWorker:
 
         info: Dict[str, Any] = {
             "n_devices": jax.device_count(),
-            "device_kind": str(jax.devices()[0]),
+            "platform": jax.devices()[0].platform,
+            "device_kind": jax.devices()[0].device_kind,
             "roles": {},
         }
         for role, m in self.models.items():
@@ -1044,6 +1059,9 @@ class TrainerWorker:
             elif p.handle_name == "restore":
                 p.output = self._handle_restore(p)
             elif p.handle_name == "exit":
+                # Before the reply: the launcher tears the fleet down as
+                # soon as the master has its "bye".
+                self._log_device_report("exit")
                 p.output = "bye"
                 self._exiting = True
             else:

@@ -369,9 +369,8 @@ class WeightStreamConsumer:
                  timeout_secs: float = 600.0):
         # timeout_secs must cover the publisher-side d2h gather of the
         # LARGEST tensor (a chunk request blocks server-side until its
-        # tensor is gathered — minutes for a ~300 MB embedding on a slow
-        # tunnel), not just wire latency; it is a liveness backstop, not a
-        # performance bound.
+        # tensor is gathered), not just wire latency; it is a liveness
+        # backstop, not a performance bound.
         self.endpoint = endpoint
         self.pipeline_depth = max(1, int(pipeline_depth))
         self.timeout_secs = timeout_secs

@@ -35,6 +35,11 @@ import numpy as np  # noqa: E402
 
 def main():
     from areal_tpu.base import telemetry
+    from areal_tpu.base.compile_watch import enable_compilation_cache
+
+    # JAX_COMPILATION_CACHE_DIR when set, else <repo>/.jax_cache — the
+    # same directory every other compiling process of the repo uses.
+    enable_compilation_cache()
 
     use_telemetry = os.environ.get("AREAL_TELEMETRY", "") not in ("", "0")
     if use_telemetry:
@@ -411,14 +416,19 @@ def main():
     # FLOPs that never execute (dense configs: identical to param_count).
     n_params = transformer.activated_param_count(cfg)
     flops = monitor.train_flops_6nt(n_params, steps * total)
-    peak = monitor.device_peak_flops(str(jax.devices()[0]))
-    mfu = (flops / dt / n_chips / peak) if peak else 0.0
+    # None off the TPU (no peak, no MFU — never a zero); a TPU kind the
+    # table lacks raises.
+    peak = monitor.device_peak_flops(jax.devices()[0].device_kind)
+    mfu = (flops / dt / n_chips / peak) if peak else None
 
     out = {
         "metric": "ppo_trained_tokens_per_sec_per_chip",
         "value": round(tokens_per_sec_chip, 1),
         "unit": "tokens/s/chip",
-        "vs_baseline": round(mfu, 4),
+        "vs_baseline": None if mfu is None else round(mfu, 4),
+        "platform": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
+        "n_devices": n_chips,
         "pack_fill": round(pack_fill, 4),
         "warmup_compile_s": round(warmup_compile_s, 3),
         "weight_sync_latency_s": round(weight_sync_s, 3),

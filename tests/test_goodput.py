@@ -200,8 +200,12 @@ def test_resolve_peak_override_and_table():
     assert goodput.resolve_peak_flops(
         GoodputConfig(peak_flops_override=5e12), "TFRT_CPU_0"
     ) == 5e12
-    assert goodput.resolve_peak_flops(GoodputConfig(), "TPU v5e") == 197e12
-    assert goodput.resolve_peak_flops(GoodputConfig(), "TFRT_CPU_0") is None
+    assert goodput.resolve_peak_flops(
+        GoodputConfig(), "TPU v5 lite") == 197e12
+    assert goodput.resolve_peak_flops(GoodputConfig(), "cpu") is None
+    # a TPU kind the table does not know is an error, never a silent None
+    with pytest.raises(KeyError, match="TPU v9"):
+        goodput.resolve_peak_flops(GoodputConfig(), "TPU v9")
 
 
 def test_mfu_emitter_degrades_on_unknown_peak():
@@ -232,30 +236,57 @@ def test_mfu_emitter_with_known_peak():
 
 
 def test_bench_flops_accounting_parity():
-    """Satellite: bench.py now imports monitor.train_flops_6nt +
-    device_peak_flops. Pin both against the 6·N·T formula and the peak
-    table bench.py inlined before the dedup — bench output unchanged on
-    this fixture geometry."""
+    """bench.py and the live gauges share monitor.train_flops_6nt +
+    device_peak_flops: pin both against the 6·N·T formula and the peak
+    table, keyed by the exact ``device_kind`` jax reports."""
     n_params, steps, total, dt, n_chips = 494_032_768, 3, 30_000, 4.2, 1
-    # the exact inline accounting deleted from bench.py
     flops_inline = 6.0 * n_params * (steps * total)
-    peaks_inline = {
-        "v5 lite": 197e12, "v5e": 197e12, "v5p": 459e12, "v5": 459e12,
-        "v4": 275e12, "v6e": 918e12, "v6": 918e12,
-    }
     assert monitor.train_flops_6nt(n_params, steps * total) == flops_inline
-    for kind, want in [("TPU v5 lite chip", 197e12), ("tpu v5e", 197e12),
-                       ("TPU v5p", 459e12), ("TPU v4 x2", 275e12),
-                       ("tpu v6e", 918e12)]:
-        inline = next(
-            (v for k, v in peaks_inline.items() if k in kind.lower()), None
-        )
-        assert monitor.device_peak_flops(kind) == inline == want
-    assert monitor.device_peak_flops("TFRT_CPU_0") is None
-    mfu_old = flops_inline / dt / n_chips / peaks_inline["v5e"]
+    for kind, want in [("TPU v5 lite", 197e12), ("TPU v5e", 197e12),
+                       ("TPU v5", 459e12), ("TPU v4", 275e12),
+                       ("TPU v6 lite", 918e12)]:
+        assert monitor.device_peak_flops(kind) == want
+    assert monitor.device_peak_flops("cpu") is None
+    # substring look-alikes no longer match: unknown TPU kinds raise
+    for kind in ("TPU v5 lite chip", "TPU v4 x2", "TPU v7"):
+        with pytest.raises(KeyError):
+            monitor.device_peak_flops(kind)
+    mfu_old = flops_inline / dt / n_chips / 197e12
     mfu_new = (monitor.train_flops_6nt(n_params, steps * total)
-               / dt / n_chips / monitor.device_peak_flops("tpu v5e"))
+               / dt / n_chips / monitor.device_peak_flops("TPU v5 lite"))
     assert mfu_new == pytest.approx(mfu_old)
+
+
+def test_master_counts_mfc_flops_over_the_token_key():
+    """The master's per-MFC FLOPs come from the TOKEN lengths, whichever
+    order the sample's keys iterate in (a scalar key such as rewards has
+    length 1 per sample — the first chip run counted those)."""
+    import types
+
+    from areal_tpu.api.data import SequenceSample
+    from areal_tpu.api.dfg import MFCInterfaceType
+    from areal_tpu.system.master_worker import MasterWorker
+
+    info = {"n_layers": 2, "hidden_dim": 64, "q_dim": 64, "kv_dim": 32,
+            "intermediate_dim": 128, "vocab_size": 1000, "is_critic": False,
+            "moe": None, "remat": False}
+    fake = types.SimpleNamespace(
+        _model_info={"roles": {"actor": info}}, _flops=monitor.FlopsCounter()
+    )
+    metas = [
+        SequenceSample(
+            ids=[i], keys={"rewards", "packed_input_ids"},
+            seqlens={"rewards": [[1]], "packed_input_ids": [[100]]},
+            data=None,
+        ) for i in range(4)
+    ]
+    node = types.SimpleNamespace(
+        model_name="actor", interface_type=MFCInterfaceType.TRAIN_STEP
+    )
+    MasterWorker._count_mfc_flops(fake, node, metas)
+    want = monitor.FlopsCounter()
+    want.add_train(types.SimpleNamespace(**info), 400.0, 100.0)
+    assert fake._flops.pop() == want.pop() > 0
 
 
 def test_validate_config_gates_goodput():
@@ -533,16 +564,28 @@ def test_bench_compare_wrapper_form_and_method_discontinuity(tmp_path):
     assert "train_phases.fwd_bwd_s" in r.stderr
 
 
-def test_bench_compare_real_trajectory_files():
-    """The repo's own BENCH_r* records parse through the gate end to end
-    (r04→r05 is the known honesty discontinuity — we only assert the
-    tool reads the real files and renders the trajectory, with the
-    tolerance widened past the documented method change)."""
-    r = _bench_compare(
-        os.path.join(REPO, "BENCH_r04.json"),
-        os.path.join(REPO, "BENCH_r05.json"),
-        extra=("--tol", "default=1.0"),
-    )
+def test_bench_compare_driver_record_files(tmp_path):
+    """Records in the driver's wrapper form (``{"rc", "tail", "parsed"}``,
+    here with the figures of the removed BENCH_r04/r05 records — r04→r05
+    is the known honesty discontinuity) parse through the gate end to
+    end: the tool reads them and renders the trajectory, with the
+    tolerance widened past the documented method change."""
+    def record(n, **parsed):
+        parsed = {"metric": "ppo_trained_tokens_per_sec_per_chip",
+                  "unit": "tokens/s/chip", **parsed}
+        return _write(tmp_path / f"BENCH_r{n:02d}.json", {
+            "n": n, "cmd": "python bench.py", "rc": 0,
+            "tail": json.dumps(parsed) + "\n", "parsed": parsed,
+        })
+
+    r04 = record(4, value=20349.9, vs_baseline=0.3062,
+                 weight_sync_latency_s=354.942, weight_sync_io_s=22.051,
+                 weight_sync_transport_s=332.891)
+    r05 = record(5, value=18642.7, vs_baseline=0.2805,
+                 weight_sync_latency_s=116.089, weight_sync_io_s=12.774,
+                 weight_sync_transport_s=103.315,
+                 weight_sync_transport_method="2x-d2h-extrapolated")
+    r = _bench_compare(r04, r05, extra=("--tol", "default=1.0"))
     assert r.returncode == 0, r.stdout + r.stderr
     assert "trajectory" in r.stdout
 

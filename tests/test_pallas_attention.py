@@ -1,30 +1,18 @@
 """Pallas flash attention vs XLA reference parity (the reference repo's
-tests/cpp_extensions kernel-parity pattern, on the interpreter), plus the
-block-size autotuning table and the non-128-divisible reference fallback
-(both CPU-only — no interpreter needed).
-
-The interpreter parity tests are version-gated: jax 0.4.x ships neither
-``pltpu.force_tpu_interpret_mode`` nor a pallas interpreter that can
-execute this kernel (``pl.pallas_call(interpret=True)`` dies in its
-load-discharge rule on scalar block indices), so they skip there with a
-reason instead of erroring — see ops/pallas/flash_attention.interpret_mode.
+tests/cpp_extensions kernel-parity pattern, on the Pallas TPU interpreter),
+plus the block-size autotuning table and the counted reference fallback
+for non-128-divisible shapes (both CPU-only — no interpreter needed).
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from areal_tpu.models import packing
 from areal_tpu.ops import attention as attn
 from areal_tpu.ops.pallas import flash_attention as fa
-
-_INTERPRET = fa.interpret_mode()
-needs_interpreter = pytest.mark.skipif(
-    _INTERPRET is None,
-    reason="this jax lacks pltpu.force_tpu_interpret_mode and its pallas "
-    "interpreter cannot run the TPU flash kernel (jax<=0.4.x)",
-)
 
 
 def _packed_case(seqlens, Hq=4, Hkv=2, D=128, row_len=None, seed=0):
@@ -38,7 +26,6 @@ def _packed_case(seqlens, Hq=4, Hkv=2, D=128, row_len=None, seed=0):
     return layout, grid, jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
 
 
-@needs_interpreter
 @pytest.mark.parametrize(
     "seqlens",
     [[128], [60, 68], [100, 20, 120, 9],
@@ -53,7 +40,7 @@ def test_flash_matches_reference(seqlens, D):
     ref = attn.packed_attention(q, k, v, seg, seg, q_positions=pos,
                                 kv_positions=pos, causal=True,
                                 impl="reference")
-    with fa.interpret_mode():
+    with pltpu.force_tpu_interpret_mode():
         out = fa.flash_attention(q, k, v, seg, seg)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-2)
     # padding query rows are exactly zero
@@ -61,7 +48,6 @@ def test_flash_matches_reference(seqlens, D):
     assert (np.asarray(out)[pad] == 0).all()
 
 
-@needs_interpreter
 def test_flash_backward_matches_reference():
     layout, grid, q, k, v = _packed_case([96, 32], Hq=2, Hkv=2, D=128)
     seg = jnp.asarray(grid["segment_ids"])
@@ -77,13 +63,34 @@ def test_flash_backward_matches_reference():
         return jnp.sum(o * o)
 
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    with fa.interpret_mode():
+    with pltpu.force_tpu_interpret_mode():
         g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     for a, b, name in zip(g_fl, g_ref, "qkv"):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=5e-2,
             err_msg=f"grad mismatch for {name}",
         )
+
+
+@pytest.mark.parametrize("spec", ["f2", "d2t2", "t4"])
+def test_flash_on_mesh_matches_reference(spec):
+    """GSPMD cannot partition a Mosaic kernel, so under a mesh the call is
+    wrapped in a shard_map: rows over the data axes, heads over tp where
+    they divide (t4 with 2 kv heads does not — it computes redundantly)."""
+    from areal_tpu.parallel import mesh as pmesh
+    from areal_tpu.parallel import sharding as psh
+
+    layout, grid, q, k, v = _packed_case([100, 20, 120, 9, 68, 60], D=64)
+    seg = jnp.asarray(grid["segment_ids"])
+    assert q.shape[:2] == (4, 128)
+    ref = attn.packed_attention(q, k, v, seg, seg, impl="reference")
+    mesh = pmesh.make_mesh(pmesh.ParallelSpec.parse(spec))
+    with pltpu.force_tpu_interpret_mode(), psh.activation_sharding(mesh):
+        out = jax.jit(
+            lambda q, k, v: attn.packed_attention(q, k, v, seg, seg,
+                                                  impl="pallas")
+        )(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-2)
 
 
 # ---------------- block-size autotuning (CPU, no interpreter) ------------
@@ -171,9 +178,9 @@ def test_blocksweep_candidates_and_record_format():
 
 
 def test_non_divisible_shape_falls_back_to_reference():
-    """T=192 has no 128-multiple divisor: the old code raised
-    NotImplementedError; now it must produce the reference result (logged
-    fallback), bit-matching attention_reference."""
+    """T=192 has no 128-multiple divisor: the kernel wrapper refuses it,
+    and the dispatcher — asked for the kernel — runs the reference and
+    COUNTS the fallback under the active label."""
     seqlens = [100, 92]  # packs to one 192-col row with row_len=192
     layout = packing.plan_packing(seqlens, length_bucket=64, row_len=192)
     grid = packing.make_grid(layout)
@@ -186,8 +193,12 @@ def test_non_divisible_shape_falls_back_to_reference():
     seg = jnp.asarray(grid["segment_ids"])
     pos = jnp.asarray(grid["positions"])
 
-    out = fa.flash_attention(q, k, v, seg, seg, q_positions=pos,
-                             kv_positions=pos)
-    ref = attn.packed_attention(q, k, v, seg, seg, q_positions=pos,
-                                kv_positions=pos, impl="reference")
+    with pytest.raises(ValueError, match="no 128-multiple block"):
+        fa.flash_attention(q, k, v, seg, seg)
+    with attn.dispatch_label("t192"):
+        out = attn.packed_attention(q, k, v, seg, seg, q_positions=pos,
+                                    kv_positions=pos, impl="pallas")
+        ref = attn.packed_attention(q, k, v, seg, seg, q_positions=pos,
+                                    kv_positions=pos, impl="reference")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
+    assert attn.dispatch_counts()["t192"] == {"fallback": 1, "reference": 1}

@@ -33,15 +33,8 @@ def _batch(cfg, B=8, T=16, seed=0):
 
 def test_pick_pp_microbatches_gates():
     cfg = tiny_config(n_layers=4)
-    # Mixed (pp + auto axes) meshes only pipeline on jax versions whose
-    # shard_map handles partial-manual autodiff (jax.shard_map); older jax
-    # keeps the correct GSPMD path there (pipeline.py gate).
-    mixed_ok = getattr(jax, "shard_map", None) is not None
-    m = pmesh.make_mesh(pmesh.ParallelSpec.parse("d2p2t2")
-                        if mixed_ok else pmesh.ParallelSpec.parse("p2"))
-    if not mixed_ok:
-        mm = pmesh.make_mesh(pmesh.ParallelSpec.parse("d2p2t2"))
-        assert ppl.pick_pp_microbatches(mm, cfg, 8) is None
+    # Mixed (pp + auto axes) mesh: partial-manual shard_map.
+    m = pmesh.make_mesh(pmesh.ParallelSpec.parse("d2p2t2"))
     assert ppl.pick_pp_microbatches(None, cfg, 8) is None
     assert ppl.pick_pp_microbatches(m, cfg, 8) == 4  # auto: 2*pp
     assert ppl.pick_pp_microbatches(m, cfg, 6) == 3
@@ -57,13 +50,9 @@ def test_pick_pp_microbatches_gates():
     assert ppl.pick_pp_microbatches(msp, cfg, 8) is None
     assert ppl.pick_pp_microbatches(msp, cfg, 8, seq_len=31) is None
     assert ppl.pick_pp_microbatches(msp, cfg, 8, seq_len=32) == 4
-    # ... pure pp×sp pipelines on every jax; mixing in auto axes needs
-    # jax.shard_map (same old-jax gate as d2p2t2 above)
+    # ... also with auto axes mixed in
     mspt = pmesh.make_mesh(pmesh.ParallelSpec.parse("p2s2t2"))
-    if mixed_ok:
-        assert ppl.pick_pp_microbatches(mspt, cfg, 8, seq_len=32) == 4
-    else:
-        assert ppl.pick_pp_microbatches(mspt, cfg, 8, seq_len=32) is None
+    assert ppl.pick_pp_microbatches(mspt, cfg, 8, seq_len=32) == 4
     # no pp axis
     mnp = pmesh.make_mesh(pmesh.ParallelSpec.parse("d2f2t2"))
     assert ppl.pick_pp_microbatches(mnp, cfg, 8) is None

@@ -561,6 +561,18 @@ def test_async_ppo_full_loop(tmp_path):
                 pause_report["master_state"] = \
                     panel.status("master")["state"]
                 time.sleep(1.5)
+                # Hold the pause until the injected recompile storm has
+                # fired: its rule needs 10 s of sustained rate, which a
+                # warm ~10 s run only reaches by luck.
+                storm_deadline = time.monotonic() + 30
+                while time.monotonic() < storm_deadline:
+                    try:
+                        with open(tmp_path / "alerts.jsonl") as f:
+                            if '"recompile_storm"' in f.read():
+                                break
+                    except OSError:
+                        pass
+                    time.sleep(0.2)
                 pause_report["frozen"] = (master.step == s0)
                 pause_report["paused_at"] = s0
                 for w in ("master", "rollout0", "trainer"):

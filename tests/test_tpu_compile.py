@@ -1,0 +1,161 @@
+"""The main path's TPU kernel, compiled for the real chip without the chip.
+
+The TPU compiler is installed in the sandbox and compiles for a DESCRIBED,
+unattached v5e (on-chip-measurement guide §2.3): tiling alignment, the VMEM
+limit and whether a kernel can be partitioned are what interpret mode never
+checks. The flash kernel is asked for explicitly (``impl="pallas"``) — under
+a described topology ``jax.default_backend()`` is still ``cpu``, so
+``"auto"`` would take the reference branch and prove nothing. A compile that
+passes is not a chip run.
+
+libtpu admits ONE process at a time (``/tmp/libtpu_lockfile``), so the
+compiles run in one child process — this file, executed as a script, under
+conftest's ``libtpu_lock`` — whose results every test (and every xdist
+worker of the run) shares; the pytest processes themselves never load
+libtpu. The whole 24-layer train step is
+compiled the same way by a scratch script, not here (its set-up
+materialises 0.5B parameters).
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# T=512 is the row the packer emits under chip_smoke.py's config; 2048 and
+# 4096 are bench.py's packing caps.
+KERNEL_T = (512, 2048, 4096)
+# f2 is the async trainer's mesh in chip_smoke.py --chips 4; p2t2 nests the
+# kernel's shard_map inside the pipeline stages' manual-pp region.
+MESH_SPECS = ("f2", "p2t2")
+
+
+def _compile_all():
+    """Child process: every case against a described v5e:2x2."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs in /tmp
+    sys.path.insert(0, REPO)
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, SingleDeviceSharding
+    from jax.sharding import PartitionSpec as P
+
+    from areal_tpu.models import transformer
+    from areal_tpu.models.config import tiny_config
+    from areal_tpu.ops.pallas import flash_attention as fa
+    from areal_tpu.parallel import mesh as pmesh
+    from areal_tpu.parallel import sharding as psh
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this image
+        return {"skip": f"cannot describe a v5e:2x2 topology here: {e}"}
+    # Such a compile is written to the persistent cache but cannot be read
+    # back without the chip: keep the cache out of it.
+    jax.config.update("jax_enable_compilation_cache", False)
+    out = {}
+
+    def record(name, compiled):
+        out[name] = {
+            "custom_calls": compiled.as_text().count("tpu_custom_call"),
+            "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+        }
+
+    # Forward AND backward at the Qwen2.5-0.5B geometry: 14 q / 2 kv heads
+    # (repeated to 14), head_dim 64 padded to 128 lanes, block_b=1.
+    chip = SingleDeviceSharding(topo.devices[0])
+    for T in KERNEL_T:
+        def spec(*shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+        def loss(q, k, v, seg):
+            o = fa.flash_attention(q, k, v, seg, seg)
+            return jnp.sum(o.astype(jnp.float32) ** 2)
+
+        record(f"kernel-{T}", jax.jit(
+            jax.value_and_grad(loss, argnums=(0, 1, 2))
+        ).lower(
+            spec(2, T, 14, 64), spec(2, T, 2, 64), spec(2, T, 2, 64),
+            spec(2, T, dtype=jnp.int32),
+        ).compile())
+        out[f"kernel-{T}"]["blocks"] = fa.pick_block_sizes(T, T)
+
+    # A small model through transformer.forward on multi-chip meshes.
+    cfg = tiny_config(vocab_size=1024, n_layers=4, hidden_dim=256,
+                      n_q_heads=4, n_kv_heads=2)
+    shapes = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    for mesh_spec in MESH_SPECS:
+        mesh = pmesh.make_mesh(pmesh.ParallelSpec.parse(mesh_spec),
+                               devices=list(topo.devices))
+        params = jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, jnp.bfloat16,
+                                              sharding=s),
+            shapes, psh.named_shardings(mesh, psh.param_partition_specs(cfg)),
+        )
+        tok = jax.ShapeDtypeStruct((8, 512), jnp.int32,
+                                   sharding=NamedSharding(mesh, P()))
+
+        def loss_and_grad(p, tokens, pos, seg):
+            def loss(p):
+                y, _ = transformer.forward(
+                    p, cfg, tokens, pos, segment_ids=seg,
+                    attn_impl="pallas", return_kv=False,
+                )
+                return jnp.sum(y.astype(jnp.float32) ** 2)
+
+            return jax.value_and_grad(loss)(p)
+
+        with psh.activation_sharding(mesh):
+            record(f"mesh-{mesh_spec}",
+                   jax.jit(loss_and_grad).lower(params, tok, tok, tok)
+                   .compile())
+    return out
+
+
+@pytest.fixture(scope="module")
+def compiled(shared_run_dir, libtpu_lock):
+    """{case: result} from ONE child process per test run: the first
+    caller (xdist workers included) runs it under the libtpu lock, the rest
+    read its result file from the run's shared temp directory."""
+    path = shared_run_dir / "tpu_compile.json"
+    with libtpu_lock():
+        if not path.exists():
+            r = subprocess.run(
+                [sys.executable, os.path.abspath(__file__)],
+                env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                capture_output=True, text=True, timeout=300,
+            )
+            assert r.returncode == 0, r.stderr[-3000:]
+            path.write_text(r.stdout.splitlines()[-1])
+        results = json.loads(path.read_text())
+    if "skip" in results:
+        pytest.skip(results["skip"])
+    return results
+
+
+@pytest.mark.parametrize("T", KERNEL_T)
+def test_flash_attention_compiles_for_v5e(compiled, T):
+    """Forward, dq and dkv kernels all made it into the program, with the
+    block sizes pick_block_sizes returns, inside the VMEM/HBM limits."""
+    got = compiled[f"kernel-{T}"]
+    assert got["blocks"] == [512, 512]
+    assert got["custom_calls"] >= 3
+    assert got["temp_bytes"] < 2 << 30
+
+
+@pytest.mark.parametrize("spec", MESH_SPECS)
+def test_model_with_flash_kernel_compiles_on_a_v5e_mesh(compiled, spec):
+    """Mosaic kernels cannot be partitioned by GSPMD: on more than one
+    chip the lowering raises unless the call sits in a shard_map manual
+    over every mesh axis (ops/pallas flash_attention_on_mesh)."""
+    assert compiled[f"mesh-{spec}"]["custom_calls"] >= 3
+
+
+if __name__ == "__main__":
+    print(json.dumps(_compile_all()))

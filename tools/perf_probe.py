@@ -1206,11 +1206,9 @@ def blocksweep(T: int = 1792, S: int = 1792, out_path: str = None,
 
     if jax.default_backend() != "tpu":
         sys.exit(
-            "blocksweep: needs a real TPU — the Pallas kernel has no "
-            "working interpreter on this jax version "
-            "(ops/pallas/flash_attention.interpret_mode). Run on the "
-            "bench chip; results land in the JSON table for "
-            "AREAL_FLASH_BLOCK_TABLE."
+            "blocksweep: needs a real TPU — interpreted kernel timings "
+            "say nothing about the chip. Results land in the JSON table "
+            "for AREAL_FLASH_BLOCK_TABLE."
         )
     # A leftover env pin/table would override every per-candidate
     # set_block_sizes below — the sweep would time one config N times and

@@ -51,6 +51,10 @@ def main(experiment_name: str, default_cls) -> None:
     if flags["config"]:
         CA.load_yaml(cfg, flags["config"])
     CA.apply_overrides(cfg, overrides)
+    # "tpu" is a promise the device-owning workers keep: they refuse to
+    # start on any other platform unless JAX_PLATFORMS says cpu
+    # (apps/launcher._child_init). "jax" takes whatever jax finds.
+    cfg.backend = flags["backend"]
     # Fail bad modes (e.g. the descoped mode=ray) at parse time, while
     # the operator is still at the command line.
     CA.validate_config(cfg)
