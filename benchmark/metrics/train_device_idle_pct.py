@@ -1,0 +1,7 @@
+"""Idle share of the worst chip in the traced part of the window (train cell)."""
+
+from benchmark import readers
+
+
+def read(records):
+    return readers.device_idle_pct(records)
