@@ -45,7 +45,7 @@ from areal_tpu.api.model import (
     register_interface,
 )
 from areal_tpu.backend import microbatch as mbu
-from areal_tpu.base import logging
+from areal_tpu.base import logging, telemetry
 from areal_tpu.models import packing
 
 logger = logging.getLogger("algorithms.ppo")
@@ -222,6 +222,7 @@ def make_advantage_prep(hp: PPOHyperparameters):
     for the grad steps). Global advantage whitening only — group_adv_norm
     keeps the host path."""
 
+    @jax.named_scope("gae")
     def prep(grids, seq, R, scalars):
         seg = grids["segment_ids"]
         amask = F.action_token_mask(seg, grids["prompt_mask"])
@@ -334,6 +335,7 @@ class PPOActorInterface(ModelInterface):
         self._gen_calls = 0
         hp_ = self.hp
 
+        @jax.named_scope("ppo_loss")
         def actor_loss_fn(logits, batch):
             # With the engine's chunked-logprob head (wants_token_logprobs)
             # this receives the [B, L] logprobs directly; otherwise raw
@@ -394,7 +396,9 @@ class PPOActorInterface(ModelInterface):
     ) -> SequenceSample:
         """Recompute logprobs under the current policy → prox_logprobs."""
         engine = model.module
-        per_sample = engine.forward(data, mb_spec, post_hook=_logprob_hook)
+        with telemetry.span("ppo/inference", **_sample_attrs(data)):
+            per_sample = engine.forward(data, mb_spec,
+                                        post_hook=_logprob_hook)
         return SequenceSample(
             ids=list(data.ids),
             keys={"prox_logprobs"},
@@ -404,6 +408,12 @@ class PPOActorInterface(ModelInterface):
         )
 
     def train_step(
+        self, model: Model, data: SequenceSample, mb_spec: MicroBatchSpec
+    ) -> Dict[str, float]:
+        with telemetry.span("ppo/train_step", **_sample_attrs(data)):
+            return self._train_step(model, data, mb_spec)
+
+    def _train_step(
         self, model: Model, data: SequenceSample, mb_spec: MicroBatchSpec
     ) -> Dict[str, float]:
         hp = self.hp
@@ -566,6 +576,14 @@ class PPOActorInterface(ModelInterface):
             self.kl_ctl._value = d["kl_ctl"]
 
 
+def _sample_attrs(data: SequenceSample) -> Dict[str, int]:
+    """Attributes of the ``ppo/`` root spans: what the step was given."""
+    return {
+        "sequences": data.bs,
+        "real_tokens": int(sum(data.total_lens("packed_input_ids"))),
+    }
+
+
 def _logprob_hook(logits, batch):
     if logits.ndim == 2:  # engine's chunked-logprob head already did it
         return logits
@@ -606,6 +624,7 @@ class PPOCriticInterface(ModelInterface):
         self.rms = RunningMoments(self.hp.value_norm_beta, self.hp.value_norm_eps)
         hp_ = self.hp
 
+        @jax.named_scope("ppo_loss")
         def critic_loss_fn(values, batch):
             amask = F.action_token_mask(
                 batch["segment_ids"], batch["prompt_mask"]

@@ -126,6 +126,17 @@ def scale_by_adam_mixed(
     return optax.GradientTransformation(init, update)
 
 
+def _scoped(name: str, tx: optax.GradientTransformation):
+    """``tx`` with its update under ``jax.named_scope(name)`` (metadata
+    only; the state tree is ``tx``'s own)."""
+
+    def update(updates, state, params=None):
+        with jax.named_scope(name):
+            return tx.update(updates, state, params)
+
+    return optax.GradientTransformation(tx.init, update)
+
+
 def build_optimizer(
     cfg: OptimizerConfig, total_steps: int
 ) -> Tuple[optax.GradientTransformation, Callable]:
@@ -143,14 +154,50 @@ def build_optimizer(
         )
     else:
         opt = optax.sgd(sched)
-    chain = [opt]
+    chain = [_scoped("adam", opt)]
     if cfg.gradient_clipping and cfg.gradient_clipping > 0:
-        chain = [optax.clip_by_global_norm(cfg.gradient_clipping)] + chain
+        chain = [_scoped(
+            "grad_clip", optax.clip_by_global_norm(cfg.gradient_clipping)
+        )] + chain
     return optax.chain(*chain), sched
 
 
 # Loss functions receive (logits, batch) and return (loss_sum, stats-sums).
 LossFn = Callable[[jnp.ndarray, Dict[str, jnp.ndarray]], Tuple[jnp.ndarray, Dict]]
+
+
+def _pack_attrs(mbs: List[mbu.MicroBatch],
+                n_mbs: Optional[int] = None) -> Dict[str, Any]:
+    """What the packer decided, as the attributes of the upload span that
+    follows it: counted once, where the trace and the registry both see
+    it. ``mbs`` are the micro-batches this span uploads, ``n_mbs`` how
+    many the packer made in all (default: these)."""
+    R, L = mbs[0].layout.shape
+    return {
+        "real_tokens": sum(mb.n_tokens for mb in mbs),
+        "padded_tokens": len(mbs) * R * L,
+        "n_mbs": n_mbs or len(mbs),
+        "grid": f"{R}x{L}",
+    }
+
+
+def _accumulate(loss, stats, grads, scale, carry):
+    """Tail of both grad programs: scale this micro-batch's loss and grads
+    and add them to the carry of the micro-batches before it."""
+    loss = loss * scale
+    with jax.named_scope("grad_accum"):
+        # Cast the scale into each leaf's dtype: a f32 scalar would
+        # silently promote bf16 grads to f32 (2x grad + carry HBM).
+        grads = jax.tree.map(lambda g: g * scale.astype(g.dtype), grads)
+        if carry is not None:
+            c_loss, c_stats, c_grads = carry
+            loss = loss + c_loss
+            stats = {
+                k: stats[k] + c_stats[k] if k in c_stats else stats[k]
+                for k in stats
+            }
+            grads = jax.tree.map(jnp.add, grads, c_grads)
+    return loss, stats, grads
 
 
 @dataclasses.dataclass
@@ -237,8 +284,12 @@ class JaxTrainEngine(TrainableEngine):
         if opt_cfg is not None:
             total = ft_spec.total_train_steps if ft_spec is not None else 1000
             self.tx, self.lr_schedule = build_optimizer(opt_cfg, total)
+
+            def opt_init(params):
+                return self.tx.init(params)
+
             self.opt_state = compile_watch.watched_jit(
-                "train/opt_init", jax.jit(self.tx.init)
+                "train/opt_init", jax.jit(opt_init)
             )(self.params)
         self._grad_fns: Dict[int, Callable] = {}
         self._fwd_fns: Dict[int, Callable] = {}
@@ -266,7 +317,8 @@ class JaxTrainEngine(TrainableEngine):
         def c(x):
             return x.astype(cd) if jnp.issubdtype(x.dtype, jnp.floating) else x
 
-        return jax.tree.map(c, params)
+        with jax.named_scope("param_cast"):
+            return jax.tree.map(c, params)
 
     def _model_forward(
         self, params, batch: Dict[str, jnp.ndarray], with_aux: bool = False
@@ -311,7 +363,6 @@ class JaxTrainEngine(TrainableEngine):
             rng=batch.get("rng"),
         )
         R, L, D = h.shape
-        labels = F.next_token_labels(batch["tokens"])
         C = self.logprob_chunk or L
         if L % C != 0:
             C = L  # bucketing guarantees divisibility in practice
@@ -323,15 +374,19 @@ class JaxTrainEngine(TrainableEngine):
 
             return gather_logprobs(logits_c, lab_c)
 
-        if C == L:
-            s = chunk_scores(h, labels)
-        else:
-            n = L // C
-            hs = h.reshape(R, n, C, D).transpose(1, 0, 2, 3)
-            ls = labels.reshape(R, n, C).transpose(1, 0, 2)
-            s = jax.lax.map(lambda args: chunk_scores(*args), (hs, ls))
-            s = s.transpose(1, 0, 2).reshape(R, L)
-        return F.shift_mask_scores(s, batch["segment_ids"]), aux
+        # "xent" names the label shifts and the chunking around the head
+        # (apply_head's own "head" scope is the innermost inside it).
+        with jax.named_scope("xent"):
+            labels = F.next_token_labels(batch["tokens"])
+            if C == L:
+                s = chunk_scores(h, labels)
+            else:
+                n = L // C
+                hs = h.reshape(R, n, C, D).transpose(1, 0, 2, 3)
+                ls = labels.reshape(R, n, C).transpose(1, 0, 2)
+                s = jax.lax.map(lambda args: chunk_scores(*args), (hs, ls))
+                s = s.transpose(1, 0, 2).reshape(R, L)
+            return F.shift_mask_scores(s, batch["segment_ids"]), aux
 
     def _use_chunked_logprobs(self, fn) -> bool:
         return (
@@ -360,7 +415,8 @@ class JaxTrainEngine(TrainableEngine):
         use_lp = self._use_chunked_logprobs(loss_fn)
         if key not in self._grad_fns:
 
-            def f(params, batch, denom, scale, aux_scale, carry=None):
+            def train_grad(params, batch, denom, scale, aux_scale,
+                           carry=None):
                 def lf(p):
                     if use_lp:
                         out, aux = self._forward_token_logprobs(p, batch)
@@ -379,25 +435,11 @@ class JaxTrainEngine(TrainableEngine):
                     return loss, stats
 
                 (loss, stats), grads = jax.value_and_grad(lf, has_aux=True)(params)
-                loss = loss * scale
-                # Cast the scale into each leaf's dtype: a f32 scalar would
-                # silently promote bf16 grads to f32 (2x grad + carry HBM).
-                grads = jax.tree.map(
-                    lambda g: g * scale.astype(g.dtype), grads
-                )
-                if carry is not None:
-                    c_loss, c_stats, c_grads = carry
-                    loss = loss + c_loss
-                    stats = {
-                        k: stats[k] + c_stats[k] if k in c_stats else stats[k]
-                        for k in stats
-                    }
-                    grads = jax.tree.map(jnp.add, grads, c_grads)
-                return loss, stats, grads
+                return _accumulate(loss, stats, grads, scale, carry)
 
             donate = (5,) if with_carry else ()
             self._grad_fns[key] = compile_watch.watched_jit(
-                "train/grad", jax.jit(f, donate_argnums=donate)
+                "train/grad", jax.jit(train_grad, donate_argnums=donate)
             )
         return self._grad_fns[key]
 
@@ -421,25 +463,28 @@ class JaxTrainEngine(TrainableEngine):
         if key in self._grad_fns:
             return self._grad_fns[key]
 
-        def f(params, opt_state, grads, stats, cap):
-            gnorm = optax.global_norm(grads)
+        def train_apply(params, opt_state, grads, stats, cap):
+            with jax.named_scope("grad_clip"):
+                gnorm = optax.global_norm(grads)
+            # grad_clip and adam are scoped in build_optimizer's chain.
             updates, new_opt = self.tx.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-            if skip_rule is not None:
-                num, den = skip_rule
-                ratio = stats[num] / jnp.maximum(stats[den], 1.0)
-                apply = (cap <= 0.0) | (ratio <= cap)
-                new_params = jax.tree.map(
-                    lambda new, old: jnp.where(apply, new, old),
-                    new_params, params,
-                )
-                new_opt = jax.tree.map(
-                    lambda new, old: jnp.where(apply, new, old)
-                    if hasattr(new, "dtype") else new,
-                    new_opt, opt_state,
-                )
-            else:
-                apply = jnp.asarray(True)
+            with jax.named_scope("param_update"):
+                new_params = optax.apply_updates(params, updates)
+                if skip_rule is not None:
+                    num, den = skip_rule
+                    ratio = stats[num] / jnp.maximum(stats[den], 1.0)
+                    apply = (cap <= 0.0) | (ratio <= cap)
+                    new_params = jax.tree.map(
+                        lambda new, old: jnp.where(apply, new, old),
+                        new_params, params,
+                    )
+                    new_opt = jax.tree.map(
+                        lambda new, old: jnp.where(apply, new, old)
+                        if hasattr(new, "dtype") else new,
+                        new_opt, opt_state,
+                    )
+                else:
+                    apply = jnp.asarray(True)
             return new_params, new_opt, gnorm, apply
 
         # Donate params + opt_state (aliased into new_params/new_opt) AND
@@ -449,7 +494,7 @@ class JaxTrainEngine(TrainableEngine):
         # measured on the 16G bench chip, withdrawing the grads donation
         # OOMs the apply step.
         self._grad_fns[key] = compile_watch.watched_jit(
-            "train/apply", jax.jit(f, donate_argnums=(0, 1, 2))
+            "train/apply", jax.jit(train_apply, donate_argnums=(0, 1, 2))
         )
         return self._grad_fns[key]
 
@@ -480,10 +525,6 @@ class JaxTrainEngine(TrainableEngine):
         S = max(len(mb.seq_mask) for mb in mbs)
         S = mbu.packing.round_up(S, self.seqs_bucket)
         grids: Dict[str, jnp.ndarray] = {}
-        for k in mbs[0].grids:
-            grids[k] = jnp.asarray(
-                np.concatenate([mb.grids[k] for mb in mbs], axis=0)
-            )
         seq: Dict[str, jnp.ndarray] = {}
 
         def pad_stack(key, getter, dtype=None):
@@ -495,12 +536,17 @@ class JaxTrainEngine(TrainableEngine):
                 rows.append(pad)
             seq[key] = jnp.asarray(np.stack(rows))
 
-        pad_stack("seq_rows", lambda mb: mb.seq_rows)
-        pad_stack("seq_first_cols", lambda mb: mb.seq_first_cols)
-        pad_stack("seq_last_cols", lambda mb: mb.seq_last_cols)
-        pad_stack("seq_mask", lambda mb: mb.seq_mask)
-        for k in mbs[0].scalars:
-            pad_stack(k, lambda mb, k=k: mb.scalars[k])
+        with telemetry.span("train/upload", **_pack_attrs(mbs)):
+            for k in mbs[0].grids:
+                grids[k] = jnp.asarray(
+                    np.concatenate([mb.grids[k] for mb in mbs], axis=0)
+                )
+            pad_stack("seq_rows", lambda mb: mb.seq_rows)
+            pad_stack("seq_first_cols", lambda mb: mb.seq_first_cols)
+            pad_stack("seq_last_cols", lambda mb: mb.seq_last_cols)
+            pad_stack("seq_mask", lambda mb: mb.seq_mask)
+            for k in mbs[0].scalars:
+                pad_stack(k, lambda mb, k=k: mb.scalars[k])
         return UniformBatch(mbs=mbs, R=R, L=L, S=S, grids=grids, seq=seq)
 
     def run_prep(
@@ -518,18 +564,22 @@ class JaxTrainEngine(TrainableEngine):
         their drift never retraces."""
         key = ("prep", prep_key, ub.n_mbs, ub.R)
         if key not in self._grad_fns:
+            R = ub.R
+
+            def adv_prep(grids, seq, sc):
+                return prep_fn(grids, seq, R, sc)
+
             self._grad_fns[key] = compile_watch.watched_jit(
-                "train/prep",
-                jax.jit(
-                    lambda grids, seq, sc: prep_fn(grids, seq, ub.R, sc)
-                ),
+                "train/prep", jax.jit(adv_prep)
             )
-        sc = {
-            k: jnp.asarray(v, jnp.float32) for k, v in (scalars or {}).items()
-        }
-        with self._mesh_ctx():
-            extra, out_scalars = self._grad_fns[key](ub.grids, ub.seq, sc)
-        ub.grids.update(extra)
+        with telemetry.span("train/adv_prep"):
+            sc = {
+                k: jnp.asarray(v, jnp.float32)
+                for k, v in (scalars or {}).items()
+            }
+            with self._mesh_ctx():
+                extra, out_scalars = self._grad_fns[key](ub.grids, ub.seq, sc)
+            ub.grids.update(extra)
         return out_scalars
 
     def _get_sliced_grad_fn(
@@ -543,8 +593,8 @@ class JaxTrainEngine(TrainableEngine):
         use_lp = self._use_chunked_logprobs(loss_fn)
         if key not in self._grad_fns:
 
-            def f(params, grids, seq, mb_idx, denom, scale, aux_scale,
-                  carry=None):
+            def train_grad_sliced(params, grids, seq, mb_idx, denom, scale,
+                                  aux_scale, carry=None):
                 batch = {
                     k: jax.lax.dynamic_slice_in_dim(g, mb_idx * R, R, 0)
                     for k, g in grids.items()
@@ -569,23 +619,12 @@ class JaxTrainEngine(TrainableEngine):
                     return loss, stats
 
                 (loss, stats), grads = jax.value_and_grad(lf, has_aux=True)(params)
-                loss = loss * scale
-                grads = jax.tree.map(
-                    lambda g: g * scale.astype(g.dtype), grads
-                )
-                if carry is not None:
-                    c_loss, c_stats, c_grads = carry
-                    loss = loss + c_loss
-                    stats = {
-                        k: stats[k] + c_stats[k] if k in c_stats else stats[k]
-                        for k in stats
-                    }
-                    grads = jax.tree.map(jnp.add, grads, c_grads)
-                return loss, stats, grads
+                return _accumulate(loss, stats, grads, scale, carry)
 
             donate = (7,) if with_carry else ()
             self._grad_fns[key] = compile_watch.watched_jit(
-                "train/grad_sliced", jax.jit(f, donate_argnums=donate)
+                "train/grad_sliced",
+                jax.jit(train_grad_sliced, donate_argnums=donate),
             )
         return self._grad_fns[key]
 
@@ -628,7 +667,8 @@ class JaxTrainEngine(TrainableEngine):
                     jax.random.PRNGKey(self.opt_step_count), ub.n_mbs
                 ),
             )
-        with telemetry.span("train/fwd_bwd", n_mbs=len(idxs)), \
+        with telemetry.span("train/fwd_bwd", n_mbs=len(idxs),
+                            grid=f"{ub.R}x{ub.L}"), \
                 memwatch.watermark("train/fwd_bwd"):
             for i, w in zip(idxs, weights):
                 denom = total_w if glob else w
@@ -646,32 +686,58 @@ class JaxTrainEngine(TrainableEngine):
                     args.append(carry)
                 with self._mesh_ctx(), dispatch_label("train"):
                     carry = fn(*args)
-            if telemetry.enabled():
-                # Honest fwd-bwd/optimizer split; without telemetry this
-                # sync does not exist (one-host-sync-per-step contract).
-                jax.block_until_ready(carry)
+        return self._apply_and_fetch(
+            carry, rule, cap, extra_fetch, n_mbs=len(idxs),
+            total_tokens=float(sum(ub.mbs[i].n_tokens for i in idxs)),
+            total_w=total_w,
+        )
+
+    def _apply_and_fetch(
+        self, carry, rule, cap: float,
+        extra_fetch: Optional[Dict[str, jnp.ndarray]],
+        n_mbs: int, total_tokens: float, total_w: float,
+    ) -> Dict[str, float]:
+        """The end of an optimizer step, shared by train_uniform and
+        train_batch: dispatch the apply, then the step's ONE blocking
+        fetch of every scalar, then host-side stats. Nothing syncs between
+        the last grad dispatch and the fetch — the device's own timeline
+        (programs ``train_grad*`` / ``train_apply`` in a capture) gives
+        the gradient / apply split."""
         loss_acc, stats_acc, grads_acc = carry
         with telemetry.span("train/optimizer"):
-            with self._mesh_ctx():
+            with telemetry.span("train/apply_dispatch"), self._mesh_ctx():
                 self.params, self.opt_state, gnorm, applied = \
                     self._get_apply_fn(rule)(
                         self.params, self.opt_state, grads_acc,
                         dict(stats_acc), jnp.asarray(cap, jnp.float32),
                     )
-            applied_lr = float(self.lr_schedule(self.opt_step_count))
-            fetched = jax.device_get({
-                **stats_acc, **(extra_fetch or {}), "loss": loss_acc,
-                "grad_norm": gnorm, "update_applied": applied,
-            })
-        if bool(fetched["update_applied"]):
-            self.opt_step_count += 1
-        out = self._finish_stats(fetched, len(idxs))
-        out["lr"] = applied_lr
-        out["total_tokens"] = float(sum(ub.mbs[i].n_tokens for i in idxs))
-        out["loss_weight"] = total_w
-        telemetry.inc("train/tokens", out["total_tokens"])
-        telemetry.inc("train/optimizer_steps",
-                      1.0 if bool(fetched["update_applied"]) else 0.0)
+            with telemetry.span("train/fetch_stats"):
+                # optax evaluated the schedule at the PRE-increment count.
+                applied_lr = float(self.lr_schedule(self.opt_step_count))
+                # ONE host round trip for all scalars (each float() would
+                # be a separate device→host sync).
+                fetched = jax.device_get({
+                    **stats_acc, **(extra_fetch or {}), "loss": loss_acc,
+                    "grad_norm": gnorm, "update_applied": applied,
+                })
+            with telemetry.span("train/finish_stats"):
+                # A skipped (early-stopped) update must not advance the LR
+                # schedule: optax's internal count is an array leaf and
+                # was reverted by the gate; keep the host-side mirror in
+                # lockstep (reference abandon-minibatch semantics).
+                if bool(fetched["update_applied"]):
+                    self.opt_step_count += 1
+                # Engine bookkeeping keys are written AFTER the user stats
+                # and would clobber same-named loss_fn stats — keep them
+                # namespaced.
+                out = self._finish_stats(fetched, n_mbs)
+                out["lr"] = applied_lr
+                out["total_tokens"] = total_tokens
+                out["loss_weight"] = total_w
+                telemetry.inc("train/tokens", total_tokens)
+                telemetry.inc(
+                    "train/optimizer_steps",
+                    1.0 if bool(fetched["update_applied"]) else 0.0)
         return out
 
     def _ep_engagement(self, batch: int, seq_len: int, pp_on: float) -> float:
@@ -794,11 +860,14 @@ class JaxTrainEngine(TrainableEngine):
             jax.random.PRNGKey(self.opt_step_count)
             if self._router_jitter else None
         )
-        with telemetry.span("train/fwd_bwd", n_mbs=n_mbs), \
+        with telemetry.span("train/fwd_bwd", n_mbs=n_mbs,
+                            grid=f"{mb_rows}x{mb_len}"), \
                 memwatch.watermark("train/fwd_bwd"):
             for i, (mb, w) in enumerate(zip(mbs, weights)):
                 denom = total_w if glob else w
-                batch = self._device_batch(mb)
+                with telemetry.span("train/upload",
+                                    **_pack_attrs([mb], n_mbs)):
+                    batch = self._device_batch(mb)
                 if jitter_key is not None:
                     batch["rng"] = jax.random.fold_in(jitter_key, i)
                 grad_fn = self._get_grad_fn(loss_fn,
@@ -813,44 +882,11 @@ class JaxTrainEngine(TrainableEngine):
                     args.append(carry)
                 with self._mesh_ctx(), dispatch_label("train"):
                     carry = grad_fn(*args)
-            if telemetry.enabled():
-                # Drain the async dispatch so the fwd-bwd/optimizer split is
-                # honest; without telemetry nothing syncs here (no passive
-                # overhead on the hot path).
-                jax.block_until_ready(carry)
-        loss_acc, stats_acc, grads_acc = carry
-
-        with telemetry.span("train/optimizer"):
-            with self._mesh_ctx():
-                self.params, self.opt_state, gnorm, applied = \
-                    self._get_apply_fn(rule)(
-                        self.params, self.opt_state, grads_acc,
-                        dict(stats_acc), jnp.asarray(cap, jnp.float32),
-                    )
-            # optax evaluated the schedule at the PRE-increment count.
-            applied_lr = float(self.lr_schedule(self.opt_step_count))
-            # ONE host round trip for all scalars (each float() would be a
-            # separate device→host sync).
-            fetched = jax.device_get({
-                **stats_acc, "loss": loss_acc, "grad_norm": gnorm,
-                "update_applied": applied,
-            })
-        # A skipped (early-stopped) update must not advance the LR schedule:
-        # optax's internal count is an array leaf and was reverted by the
-        # gate; keep the host-side mirror in lockstep (reference
-        # abandon-minibatch semantics).
-        if bool(fetched["update_applied"]):
-            self.opt_step_count += 1
-        # Engine bookkeeping keys are written AFTER the user stats and would
-        # clobber same-named loss_fn stats — keep them namespaced.
-        out = self._finish_stats(fetched, len(mbs))
-        out["lr"] = applied_lr
-        out["total_tokens"] = float(sum(mb.n_tokens for mb in mbs))
-        out["loss_weight"] = total_w
-        telemetry.inc("train/tokens", out["total_tokens"])
-        telemetry.inc("train/optimizer_steps",
-                      1.0 if bool(fetched["update_applied"]) else 0.0)
-        return out
+        return self._apply_and_fetch(
+            carry, rule, cap, None, n_mbs=n_mbs,
+            total_tokens=float(sum(mb.n_tokens for mb in mbs)),
+            total_w=total_w,
+        )
 
     # -------------- train-state checkpointing --------------
     #
@@ -956,12 +992,13 @@ class JaxTrainEngine(TrainableEngine):
         maps raw model output (logits/values) to the per-token quantity —
         applied on device so [B, L, V] logits never reach the host. Returns
         per-sample packed arrays in input order."""
-        mbs = mbu.split_into_microbatches(
-            input_, mb_spec, length_bucket=self.length_bucket,
-            rows_bucket=self.rows_bucket, seqs_bucket=self.seqs_bucket,
-            fill_bucket=self.fill_bucket,
-        )
-        telemetry.set_gauge("infer/pack_fill", mbu.pack_fill(mbs))
+        with telemetry.span("infer/split_pack"):
+            mbs = mbu.split_into_microbatches(
+                input_, mb_spec, length_bucket=self.length_bucket,
+                rows_bucket=self.rows_bucket, seqs_bucket=self.seqs_bucket,
+                fill_bucket=self.fill_bucket,
+            )
+            telemetry.set_gauge("infer/pack_fill", mbu.pack_fill(mbs))
         use_lp = self._use_chunked_logprobs(post_hook)
         # use_lp is part of the key: id() of a GC'd hook can be reused by a
         # new hook with a different wants_token_logprobs, which would route
@@ -969,7 +1006,7 @@ class JaxTrainEngine(TrainableEngine):
         key = (id(post_hook), use_lp)
         if key not in self._fwd_fns:
 
-            def f(params, batch):
+            def infer_forward(params, batch):
                 if use_lp:
                     out, _ = self._forward_token_logprobs(params, batch)
                 else:
@@ -978,15 +1015,23 @@ class JaxTrainEngine(TrainableEngine):
                         if post_hook is not None else out)
 
             self._fwd_fns[key] = compile_watch.watched_jit(
-                "train/forward", jax.jit(f)
+                "train/forward", jax.jit(infer_forward)
             )
         fn = self._fwd_fns[key]
         outs = []
+        # Upload, dispatch and fetch are a span each: they are the places
+        # where the host can make the device wait.
         for mb in mbs:
-            db = self._device_batch(mb)
-            with self._mesh_ctx(), dispatch_label("forward"):
-                outs.append(np.asarray(fn(self.params, db)))
-        return mbu.scatter_back(mbs, outs, input_.bs)
+            with telemetry.span("infer/upload",
+                                **_pack_attrs([mb], len(mbs))):
+                db = self._device_batch(mb)
+            with telemetry.span("infer/dispatch"), self._mesh_ctx(), \
+                    dispatch_label("forward"):
+                out = fn(self.params, db)
+            with telemetry.span("infer/fetch"):
+                outs.append(np.asarray(out))
+        with telemetry.span("infer/scatter_back"):
+            return mbu.scatter_back(mbs, outs, input_.bs)
 
     def generate(
         self,

@@ -74,13 +74,23 @@ def compilation_cache_dir() -> str:
 class CacheStats:
     """This process's persistent-cache traffic, counted from jax's own
     monitoring events: ``hits`` (executables read back), ``misses``
-    (compiled and written) and ``compile_secs`` (wall time inside the
-    backend compile call — a cache read when it hits)."""
+    (compiled and written), and the seconds a program costs before it
+    runs: ``trace_secs`` (Python → jaxpr), ``lower_secs`` (jaxpr → MLIR
+    module), ``compile_secs`` (wall time inside the backend compile call —
+    a cache read when it hits) and ``cache_read_secs`` (the part of that
+    spent reading the cache)."""
+
+    _DURATIONS = {
+        "/jax/core/compile/jaxpr_trace_duration": "trace_secs",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_secs",
+        "/jax/core/compile/backend_compile_duration": "compile_secs",
+        "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read_secs",
+    }
 
     def __init__(self) -> None:
         self.hits = 0
         self.misses = 0
-        self.compile_secs = 0.0
+        self.secs = dict.fromkeys(self._DURATIONS.values(), 0.0)
 
     def _on_event(self, event: str, **_: Any) -> None:
         if event == "/jax/compilation_cache/cache_hits":
@@ -89,13 +99,14 @@ class CacheStats:
             self.misses += 1
 
     def _on_duration(self, event: str, secs: float, **_: Any) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_secs += secs
+        field = self._DURATIONS.get(event)
+        if field is not None:
+            self.secs[field] += secs
 
     def as_dict(self) -> Dict[str, Any]:
         return {"dir": compilation_cache_dir(), "hits": self.hits,
                 "misses": self.misses,
-                "compile_secs": round(self.compile_secs, 3)}
+                **{k: round(v, 3) for k, v in self.secs.items()}}
 
 
 _CACHE_STATS: Optional[CacheStats] = None

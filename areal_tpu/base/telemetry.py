@@ -39,6 +39,15 @@ Disabled-by-default contract (tier-1 + bench honesty): until
 (:func:`inc`, :func:`set_gauge`, :func:`observe`, :func:`span`) routes to
 a shared null object — no locks taken beyond one attribute read, no ZMQ
 sockets, no HTTP servers, no span allocation.
+
+One exception, on purpose: :func:`span` ALWAYS opens a
+``jax.profiler.TraceAnnotation`` named ``areal/<name>`` with the span's
+attributes as the event's stats, registry or not, so every span shows in
+any ``jax.profiler`` capture on the same clock as the device's ops,
+nested as the code nests. Outside a capture that is a flag check (under
+a microsecond, PERF.md section 6). A span's seconds are HOST time: the
+device's split comes from a capture, never from a sync added for the
+span's sake.
 """
 
 from __future__ import annotations
@@ -225,6 +234,53 @@ def extract_payload(obj: Any) -> Optional[TraceContext]:
 
 
 # --------------------------------------------------------------------------
+# spans on the profiler's clock
+# --------------------------------------------------------------------------
+
+ANNOTATION_PREFIX = "areal/"
+_ANNOTATION_CLS: Any = None
+
+# The names the trainer puts on the DEVICE's timeline, listed here once
+# (docs/observability.md, section Timeline). A capture's ``XLA Modules``
+# line reads ``jit_<program>``; an op's framework name carries the
+# innermost ``jax.named_scope`` it was traced under — metadata only, the
+# program that runs is unchanged. Call sites use the literal names;
+# tests/test_step_timeline.py holds them to this list.
+DEVICE_PROGRAMS = (
+    "infer_forward", "train_grad", "train_grad_sliced", "train_apply",
+    "adv_prep", "opt_init",
+)
+DEVICE_SCOPES = (
+    # models/transformer.py, once a block unless said
+    "embed", "attn_norm", "qkv_proj", "rope", "attention", "o_proj",
+    "mlp_norm", "mlp", "moe", "layer_scan", "final_norm", "head",
+    "xent",                                   # ops/xent.py
+    "param_cast", "grad_accum",               # backend/jax_train.py
+    "grad_clip", "adam", "param_update",      # the apply program
+    "ppo_loss", "gae",                        # algorithms/ppo.py
+)
+
+
+def _annotation(name: str, attrs: Dict[str, Any]):
+    """``jax.profiler.TraceAnnotation("areal/<name>", **attrs)`` as a
+    context manager that yields a dict, as a registry span does (callers
+    may write ``attrs["k"] = v`` mid-span; without a registry it is thrown
+    away). jax is imported at the first span, once; a TraceMe never
+    touches a device."""
+    global _ANNOTATION_CLS
+    if _ANNOTATION_CLS is None:
+        from jax.profiler import TraceAnnotation
+
+        class _Annotation(TraceAnnotation):
+            def __enter__(self):
+                super().__enter__()
+                return {}
+
+        _ANNOTATION_CLS = _Annotation
+    return _ANNOTATION_CLS(ANNOTATION_PREFIX + name, **attrs)
+
+
+# --------------------------------------------------------------------------
 # registry
 # --------------------------------------------------------------------------
 
@@ -401,7 +457,10 @@ class TelemetryRegistry:
         t_wall = time.time()
         t0 = time.monotonic()
         try:
-            yield attrs  # callers may add attrs["key"] = ... mid-span
+            # The annotation carries the attrs known at entry; what a
+            # caller adds mid-span reaches the registry only.
+            with _annotation(name, attrs):
+                yield attrs  # callers may add attrs["key"] = ... mid-span
         finally:
             _CUR_SPAN.reset(token)
             s = Span(name=name, span_id=sid, parent_id=parent,
@@ -1283,20 +1342,6 @@ class TelemetryAggregator:
 # --------------------------------------------------------------------------
 
 
-class _NullSpanCtx:
-    """Reusable no-op span context (allocation-free disabled path)."""
-
-    _attrs: Dict[str, Any] = {}
-
-    def __enter__(self):
-        return {}
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpanCtx()
-
 # Live enabled Telemetry instances in this process (the gen-fleet process
 # hosts several) — the crash hooks dump every ring at once.
 _LIVE: "weakref.WeakSet" = weakref.WeakSet()
@@ -1448,7 +1493,8 @@ class Telemetry:
 
 
 class _NullTelemetry:
-    """Shared disabled sink: no sockets, no threads, no span objects."""
+    """Shared disabled sink: no sockets, no threads, no span objects —
+    a span is its profiler annotation and nothing else."""
 
     enabled = False
     registry = None
@@ -1466,7 +1512,7 @@ class _NullTelemetry:
         pass
 
     def span(self, name: str, **attrs):
-        return _NULL_SPAN
+        return _annotation(name, attrs)
 
     def add_span(self, name: str, t_start: float, dur_secs: float,
                  trace=None, **attrs) -> int:
@@ -1614,7 +1660,13 @@ class ProfilerTriggerWatcher:
             return
         import jax
 
-        jax.profiler.start_trace(out_dir)
+        # The options the benchmark's TraceWindow uses, so an operator's
+        # capture has the size and shape of the benchmark's: no Python
+        # tracer (traces are large), host TraceMes (the areal/ spans) kept.
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(out_dir, profiler_options=opts)
 
     def _stop(self) -> None:
         if self._stop_fn is not None:

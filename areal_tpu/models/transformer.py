@@ -177,24 +177,85 @@ def _block(
     B, T, D = h.shape
     dh = cfg.head_dim
 
-    x = _norm(cfg, h, lp, "ln1")
-    q = x @ lp["wq"]
-    k = x @ lp["wk"]
-    v = x @ lp["wv"]
-    if "bq" in lp:
-        q = q + lp["bq"]
-        k = k + lp["bk"]
-        v = v + lp["bv"]
-    q = q.reshape(B, T, cfg.n_q_heads, dh)
-    k = k.reshape(B, T, cfg.n_kv_heads, dh)
-    v = v.reshape(B, T, cfg.n_kv_heads, dh)
-    if cfg.use_qk_norm:
-        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
-        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
+    # The jax.named_scope names below are the device-side names of a
+    # profiler capture (base/telemetry.DEVICE_SCOPES): metadata on the
+    # ops, no change to the program that runs.
+    with jax.named_scope("attn_norm"):
+        x = _norm(cfg, h, lp, "ln1")
+    with jax.named_scope("qkv_proj"):
+        q = x @ lp["wq"]
+        k = x @ lp["wk"]
+        v = x @ lp["wv"]
+        if "bq" in lp:
+            q = q + lp["bq"]
+            k = k + lp["bk"]
+            v = v + lp["bv"]
+        q = q.reshape(B, T, cfg.n_q_heads, dh)
+        k = k.reshape(B, T, cfg.n_kv_heads, dh)
+        v = v.reshape(B, T, cfg.n_kv_heads, dh)
+        if cfg.use_qk_norm:
+            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
     if cfg.pos_embedding == "rope":
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        with jax.named_scope("rope"):
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
 
+    with jax.named_scope("attention"):
+        attn, new_kv = _attend(
+            cfg, q, k, v, segment_ids, positions, cache_kv,
+            cache_write_index, kv_valid, attn_impl, allow_ring, ring_ctx,
+        )
+
+    hid = "hidden" if cache_kv is None else "hidden_decode"
+    with jax.named_scope("o_proj"):
+        attn = attn.reshape(B, T, cfg.q_dim) @ lp["wo"]
+        if "bo" in lp:
+            attn = attn + lp["bo"]
+        h = constrain(h + attn, hid)
+
+    with jax.named_scope("mlp_norm"):
+        x = _norm(cfg, h, lp, "ln2")
+    act = _ACTIVATIONS[cfg.hidden_act]
+    if cfg.moe is not None:
+        from areal_tpu.models import moe as moemod
+
+        # Expert-parallel all-to-all path: only from GSPMD-auto regions
+        # (a pipeline stage is already manual — nested shard_map is
+        # rejected there; GSPMD still handles its ep-sharded weights) and
+        # only for shard_map-divisible shapes; decode keeps the tolerant
+        # single-shard paths (generation never expert-parallels,
+        # api/cli_args.validate_config rejects it).
+        ep_mesh = current_mesh() if (
+            allow_ep and ring_ctx is None and cache_kv is None
+        ) else None
+        if ep_mesh is not None and not moemod.ep_eligible(
+                ep_mesh, cfg.moe, B, T):
+            ep_mesh = None
+        with jax.named_scope("moe"):
+            mlp, aux = moemod.moe_mlp(
+                x, lp, cfg.moe, rng=rng,
+                mask=(segment_ids > 0) if segment_ids is not None else None,
+                mesh=ep_mesh,
+            )
+            return constrain(h + mlp, hid), new_kv, aux
+    with jax.named_scope("mlp"):
+        if cfg.mlp_type == "plain":
+            mlp = (act(x @ lp["w_up"] + lp["b_up"]) @ lp["w_down"]
+                   + lp["b_down"])
+        else:
+            mlp = (act(x @ lp["w_gate"]) * (x @ lp["w_up"])) @ lp["w_down"]
+        return constrain(h + mlp, hid), new_kv, None
+
+
+def _attend(
+    cfg: TransformerConfig, q, k, v, segment_ids, positions, cache_kv,
+    cache_write_index, kv_valid, attn_impl: str, allow_ring: bool, ring_ctx,
+):
+    """One block's attention proper (everything between RoPE and the
+    output projection): packed / ring attention in packed mode, the cache
+    write and decode attention in decode mode. Returns (attn, new_kv)."""
+    B, T = q.shape[:2]
     if cache_kv is None:
         from areal_tpu.parallel import ring as ring_mod
 
@@ -248,40 +309,7 @@ def _block(
         attn = decode_attention(q, k_cache, v_cache, kv_valid)
         new_kv = (k_cache, v_cache)
 
-    hid = "hidden" if cache_kv is None else "hidden_decode"
-    attn = attn.reshape(B, T, cfg.q_dim) @ lp["wo"]
-    if "bo" in lp:
-        attn = attn + lp["bo"]
-    h = constrain(h + attn, hid)
-
-    x = _norm(cfg, h, lp, "ln2")
-    aux = None
-    act = _ACTIVATIONS[cfg.hidden_act]
-    if cfg.moe is not None:
-        from areal_tpu.models import moe as moemod
-
-        # Expert-parallel all-to-all path: only from GSPMD-auto regions
-        # (a pipeline stage is already manual — nested shard_map is
-        # rejected there; GSPMD still handles its ep-sharded weights) and
-        # only for shard_map-divisible shapes; decode keeps the tolerant
-        # single-shard paths (generation never expert-parallels,
-        # api/cli_args.validate_config rejects it).
-        ep_mesh = current_mesh() if (
-            allow_ep and ring_ctx is None and cache_kv is None
-        ) else None
-        if ep_mesh is not None and not moemod.ep_eligible(
-                ep_mesh, cfg.moe, B, T):
-            ep_mesh = None
-        mlp, aux = moemod.moe_mlp(
-            x, lp, cfg.moe, rng=rng,
-            mask=(segment_ids > 0) if segment_ids is not None else None,
-            mesh=ep_mesh,
-        )
-    elif cfg.mlp_type == "plain":
-        mlp = act(x @ lp["w_up"] + lp["b_up"]) @ lp["w_down"] + lp["b_down"]
-    else:
-        mlp = (act(x @ lp["w_gate"]) * (x @ lp["w_up"])) @ lp["w_down"]
-    return constrain(h + mlp, hid), new_kv, aux
+    return attn, new_kv
 
 
 # ---------------- layer-stack application ----------------
@@ -329,7 +357,8 @@ def apply_layer_stack(
             return h2, aux
 
         body = _maybe_checkpoint(body, remat)
-        h, aux = jax.lax.scan(body, h, (layer_params, layer_keys))
+        with jax.named_scope("layer_scan"):
+            h, aux = jax.lax.scan(body, h, (layer_params, layer_keys))
         return h, (aux if aux is not None else {})
 
     def body(h, lp):
@@ -341,7 +370,11 @@ def apply_layer_stack(
         return h2, aux
 
     body = _maybe_checkpoint(body, remat)
-    h, aux = jax.lax.scan(body, h, layer_params)
+    # "layer_scan" names the scan's own work: slicing each layer's
+    # parameters out of the stacked arrays and, in the backward pass,
+    # writing each layer's gradients back into them.
+    with jax.named_scope("layer_scan"):
+        h, aux = jax.lax.scan(body, h, layer_params)
     return h, (aux if aux is not None else {})
 
 
@@ -387,13 +420,15 @@ def forward(
     ``kv_valid`` cache slots.
     """
     decode = kv_cache is not None
-    h = params["embedding"][tokens]
-    if cfg.scale_embeddings:  # gemma normalizer
-        h = h * jnp.asarray(cfg.hidden_dim ** 0.5, h.dtype)
-    if cfg.pos_embedding == "learned":
-        h = h + params["pos_embedding"][positions]
-    h = constrain(h, "hidden" if not decode else "hidden_decode")
-    cos, sin = rope_tables(positions, cfg.head_dim, cfg.rotary_base)
+    with jax.named_scope("embed"):
+        h = params["embedding"][tokens]
+        if cfg.scale_embeddings:  # gemma normalizer
+            h = h * jnp.asarray(cfg.hidden_dim ** 0.5, h.dtype)
+        if cfg.pos_embedding == "learned":
+            h = h + params["pos_embedding"][positions]
+        h = constrain(h, "hidden" if not decode else "hidden_decode")
+    with jax.named_scope("rope"):
+        cos, sin = rope_tables(positions, cfg.head_dim, cfg.rotary_base)
     layer_params = params["layers"]
 
     if decode:
@@ -405,9 +440,10 @@ def forward(
             )
             return h2, ((kc2, vc2), aux)
 
-        h, ((ks, vs), aux) = jax.lax.scan(
-            body, h, (layer_params, (kv_cache["k"], kv_cache["v"]))
-        )
+        with jax.named_scope("layer_scan"):
+            h, ((ks, vs), aux) = jax.lax.scan(
+                body, h, (layer_params, (kv_cache["k"], kv_cache["v"]))
+            )
     elif return_kv:
         def body(h, lp):
             h2, kv, aux = _block(
@@ -417,7 +453,8 @@ def forward(
             return h2, (kv, aux)
 
         body = _maybe_checkpoint(body, remat)
-        h, ((ks, vs), aux) = jax.lax.scan(body, h, layer_params)
+        with jax.named_scope("layer_scan"):
+            h, ((ks, vs), aux) = jax.lax.scan(body, h, layer_params)
     else:
         ks = vs = None
         from areal_tpu.parallel import pipeline as pp_mod
@@ -461,12 +498,13 @@ def forward(
         else {}
     )
 
-    if cfg.norm_type == "layer":
-        h = layer_norm(
-            h, params["final_ln"], params["final_ln_b"], cfg.rms_norm_eps
-        )
-    else:
-        h = rms_norm(h, params["final_ln"], cfg.rms_norm_eps)
+    with jax.named_scope("final_norm"):
+        if cfg.norm_type == "layer":
+            h = layer_norm(
+                h, params["final_ln"], params["final_ln_b"], cfg.rms_norm_eps
+            )
+        else:
+            h = rms_norm(h, params["final_ln"], cfg.rms_norm_eps)
     if return_hidden:
         out = h  # caller applies the head (e.g. chunked-logprob loss)
     else:
@@ -483,11 +521,12 @@ def apply_head(params: Params, cfg: TransformerConfig, h, lg="logits"):
     """Final-hidden → logits (or values). Shared by forward and the
     engine's chunked-logprob path (backend/jax_train.py) so the head math
     has exactly one definition."""
-    if cfg.is_critic:
-        return (h @ params["value_head"])[..., 0]
-    if cfg.tie_word_embeddings:
-        return constrain(h @ params["embedding"].T, lg)
-    return constrain(h @ params["lm_head"], lg)
+    with jax.named_scope("head"):
+        if cfg.is_critic:
+            return (h @ params["value_head"])[..., 0]
+        if cfg.tie_word_embeddings:
+            return constrain(h @ params["embedding"].T, lg)
+        return constrain(h @ params["lm_head"], lg)
 
 
 def init_kv_cache(

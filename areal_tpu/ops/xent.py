@@ -20,13 +20,14 @@ def gather_logprobs(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     the MXU); only the label-shaped outputs are f32. With a 152k vocab this
     is the difference between fitting in HBM and not.
     """
-    tok = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    m = jax.lax.stop_gradient(jnp.max(logits, axis=-1))
-    # XLA fuses exp(astype(f32)) into the reduce; the f32 tensor never lands.
-    lse = (
-        jnp.log(
-            jnp.sum(jnp.exp((logits - m[..., None]).astype(jnp.float32)), axis=-1)
+    with jax.named_scope("xent"):
+        tok = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+        m = jax.lax.stop_gradient(jnp.max(logits, axis=-1))
+        # XLA fuses exp(astype(f32)) into the reduce; the f32 tensor never lands.
+        lse = (
+            jnp.log(
+                jnp.sum(jnp.exp((logits - m[..., None]).astype(jnp.float32)), axis=-1)
+            )
+            + m.astype(jnp.float32)
         )
-        + m.astype(jnp.float32)
-    )
-    return tok.astype(jnp.float32) - lse
+        return tok.astype(jnp.float32) - lse

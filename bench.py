@@ -7,9 +7,9 @@ AREAL_TELEMETRY=1 additionally enables the in-process telemetry registry
 breakdown — split_pack / fwd_bwd / optimizer seconds per timed step — as
 a "train_phases" field, so the BENCH trajectory records where each step's
 wall clock went instead of one opaque scalar. Telemetry stays OFF by
-default: the headline number always measures the uninstrumented path
-(enabling it adds a device sync between fwd-bwd and optimizer to make
-the split honest).
+default: the headline number always measures the uninstrumented path.
+The phases are HOST seconds (fwd_bwd is dispatch time; the device's work
+shows as the wait in fetch_stats): no span syncs the device.
 
 Protocol (mirrors the reference's "effective trained tokens/sec",
 benchmark/verl_v0_3_0_post1_76084d3/README.md:27-34): time full PPO actor
