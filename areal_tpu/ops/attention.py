@@ -48,8 +48,12 @@ def dispatch_label(label: str) -> Iterator[None]:
         _LABEL.reset(token)
 
 
+def active_label() -> str:
+    return _LABEL.get()
+
+
 def count_dispatch(impl: str) -> None:
-    _DISPATCH[_LABEL.get()][impl] += 1
+    _DISPATCH[active_label()][impl] += 1
 
 
 def dispatch_counts() -> Dict[str, Dict[str, int]]:

@@ -25,13 +25,20 @@ docs/benchmarks.md "Where the time goes"): ``pick_block_sizes`` resolves
     :func:`set_block_sizes`, or loaded from the JSON file named by
     ``AREAL_FLASH_BLOCK_TABLE`` (written by ``perf_probe blocksweep``,
     format ``{"T,S": [bq, bkv]}``);
- 3. the built-in heuristic — the largest 128-multiple divisor ≤ 512.
+ 3. :func:`pick_tile` — the tile of ``TILE_COST`` that makes the row
+    cheapest once the row is PADDED up to a multiple of it.
 
-Table/env entries are validated against the kernel's divisibility
-constraint and snap DOWN to the nearest dividing 128-multiple rather than
-failing at dispatch time.
+A pin or table entry means "these blocks, no padding": it is validated
+against the kernel's divisibility constraint and snaps DOWN to the nearest
+dividing 128-multiple rather than failing at dispatch time. The tile of
+rule 3 need not divide the row: :func:`flash_attention` pads T and S up to
+it with segment id 0 (what a row's own tail padding already carries), runs
+the kernel at the padded length and slices the output back, so a row of
+6016 = 47 x 128 tokens runs 512-blocks at 6144 instead of 128-blocks.
+:func:`geometry_counts` says, per compiled step, which (length, padded
+length, tile) each call was traced with.
 
-Sequence dims with NO 128-multiple divisor cannot be tiled:
+Sequence dims that are NOT a multiple of 128 are not tiled:
 :func:`flash_attention` raises for them, and the dispatcher
 (ops/attention.packed_attention) asks :func:`pick_block_sizes` first and
 runs — and counts — the XLA reference instead. Packed training rows never
@@ -44,6 +51,7 @@ interpreted; tests/test_tpu_compile.py compiles it for a described v5e.
 
 from __future__ import annotations
 
+import collections
 import functools
 import json
 import logging
@@ -60,15 +68,23 @@ from jax.experimental.pallas.ops.tpu.flash_attention import (
     flash_attention as _jax_flash,
 )
 
+from areal_tpu.ops import attention as _attention
+
 LANE = 128
 DEFAULT_BLOCK_TARGET = 512
+
+# c(t): ns per row·token² that the kernels of one training step (3 forward
+# passes, dKV, dQ) take at tile t — measured on a TPU v5e, the kernels
+# alone, 6 rows x 6144 tokens, 14 heads, bf16 (PERF.md §5, PR 25).
+# pick_tile uses only their ratios.
+TILE_COST = {512: 0.2724, 384: 0.4054, 256: 0.5587, 128: 1.5418}
 
 logger = logging.getLogger("areal_tpu")
 
 # Geometry-keyed (T, S) -> (block_q, block_kv). Populated by
 # set_block_sizes() / the AREAL_FLASH_BLOCK_TABLE JSON (perf_probe
-# blocksweep writes it); empty by default — the heuristic below is the
-# fallback, and recorded sweep results override it per geometry.
+# blocksweep writes it); empty by default — pick_tile below is the
+# default, and recorded sweep results override it per geometry.
 _BLOCK_TABLE: Dict[Tuple[int, int], Tuple[int, int]] = {}
 _TABLE_FILE_LOADED: Optional[str] = None  # set only on a SUCCESSFUL load
 _TABLE_FILE_WARNED: set = set()
@@ -82,6 +98,29 @@ def _block(n: int, target: int) -> Optional[int]:
         if n % b == 0 and b % LANE == 0:
             return b
     return None
+
+
+def _round_up(n: int, tile: int) -> int:
+    return -(-n // tile) * tile
+
+
+def pick_tile(n: int) -> int:
+    """The tile a sequence dim of n tokens runs: the one whose padded
+    length costs least, ``round_up(n, t)² · c(t)``; ties to the larger."""
+    return min(TILE_COST,
+               key=lambda t: (_round_up(n, t) ** 2 * TILE_COST[t], -t))
+
+
+# Which (length, padded length, tile) each flash_attention call TRACED
+# with, per sequence dim, by the label of the compiled step (the label of
+# ops/attention.dispatch_label): {label: {(n, n_pad, tile): calls}}.
+_GEOMETRY: Dict[str, collections.Counter] = collections.defaultdict(
+    collections.Counter
+)
+
+
+def geometry_counts() -> Dict[str, Dict[Tuple[int, int, int], int]]:
+    return {label: dict(c) for label, c in _GEOMETRY.items()}
 
 
 def set_block_sizes(T: int, S: int, block_q: int, block_kv: int) -> None:
@@ -119,22 +158,24 @@ def _load_table_file() -> None:
         if path not in _TABLE_FILE_WARNED:
             _TABLE_FILE_WARNED.add(path)
             logger.warning("AREAL_FLASH_BLOCK_TABLE %r unreadable (%s); "
-                           "using heuristic block sizes until it appears",
+                           "using pick_tile's block sizes until it appears",
                            path, e)
 
 
 def pick_block_sizes(T: int, S: int) -> Optional[Tuple[int, int]]:
-    """Resolve (block_q, block_kv) for a geometry; None when either dim has
-    no 128-multiple divisor (caller must use the reference path). Env pin >
-    table (runtime or file) > heuristic; every source is snapped down to
-    the nearest dividing 128-multiple."""
+    """Resolve (block_q, block_kv) for a geometry; None when either dim is
+    not a multiple of 128 (caller must use the reference path). Env pin >
+    table (runtime or file) > :func:`pick_tile`. A pin or a table entry is
+    snapped down to the nearest dividing 128-multiple (no padding); the
+    tile of pick_tile may exceed a divisor — flash_attention pads the dim
+    up to it."""
     if _block(T, T) is None or _block(S, S) is None:
         return None
     # Any 128-multiple divisor of n implies 128 | n, so once the checks
-    # above pass the heuristic (target 512 >= 128) can never miss — it is
-    # the safe landing spot for out-of-range pins/table entries (a sub-128
-    # pin must NOT snap up to a whole-sequence tile: bq*bkv scores alone
-    # would blow VMEM).
+    # above pass the largest divisor <= 512 can never miss — it is the safe
+    # landing spot for out-of-range pins/table entries (a sub-128 pin must
+    # NOT snap up to a whole-sequence tile: bq*bkv scores alone would blow
+    # VMEM).
     heur_q = _block(T, DEFAULT_BLOCK_TARGET)
     heur_kv = _block(S, DEFAULT_BLOCK_TARGET)
     env = os.environ.get("AREAL_FLASH_BLOCKS")
@@ -151,7 +192,7 @@ def pick_block_sizes(T: int, S: int) -> Optional[Tuple[int, int]]:
     if hit is not None:
         return (_block(T, min(hit[0], T)) or heur_q,
                 _block(S, min(hit[1], S)) or heur_kv)
-    return (heur_q, heur_kv)
+    return (pick_tile(T), pick_tile(S))
 
 
 @functools.partial(
@@ -177,6 +218,10 @@ def flash_attention(
             "ops/attention.packed_attention routes such shapes to the "
             "reference"
         )
+    bq, bkv = blocks
+    T_pad, S_pad = _round_up(T, bq), _round_up(S, bkv)
+    for geom in {(T, T_pad, bq), (S, S_pad, bkv)}:
+        _GEOMETRY[_attention.active_label()][geom] += 1
     if scale is None:
         scale = D ** -0.5
     if Hq != Hkv:
@@ -188,18 +233,30 @@ def flash_attention(
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    if D < LANE:
-        pad = [(0, 0), (0, 0), (0, 0), (0, LANE - D)]
-        qt, kt, vt = (jnp.pad(x, pad) for x in (qt, kt, vt))
+    # Pad heads up to the lane width and each sequence dim up to its tile
+    # (a pin or table entry divides its dim: nothing to pad), in one pass.
+    lanes = max(LANE - D, 0)
+
+    def pad(x, n):  # [B, H, L, D]: n more tokens, `lanes` more lanes
+        if not (n or lanes):
+            return x
+        return jnp.pad(x, [(0, 0), (0, 0), (0, n), (0, lanes)])
+
+    def pad_ids(ids, n):  # [B, L]
+        return jnp.pad(ids, [(0, 0), (0, n)]) if n else ids
+
+    qt = pad(qt, T_pad - T)
+    kt, vt = pad(kt, S_pad - S), pad(vt, S_pad - S)
 
     # Padding rows (segment id 0) must not alias into a real segment; the
     # kernel's segment mask handles it as long as pad ids differ between a
     # q pad and kv real token — id 0 == id 0 would attend pad→pad only,
     # which is harmless (output rows for pad queries are discarded), but we
-    # keep them NaN-free by masking afterwards instead.
-    seg = SegmentIds(q=q_segment_ids, kv=kv_segment_ids)
+    # keep them NaN-free by masking afterwards instead. The tokens added
+    # above carry id 0 like the row's own tail padding.
+    seg = SegmentIds(q=pad_ids(q_segment_ids, T_pad - T),
+                     kv=pad_ids(kv_segment_ids, S_pad - S))
 
-    bq, bkv = blocks
     sizes = BlockSizes(
         block_q=bq, block_k_major=bkv, block_k=bkv, block_b=1,
         block_q_major_dkv=bq, block_k_major_dkv=bkv,
@@ -210,9 +267,7 @@ def flash_attention(
         qt, kt, vt, segment_ids=seg, causal=causal, sm_scale=scale,
         block_sizes=sizes,
     )
-    if D < LANE:
-        out = out[..., :D]
-    out = out.transpose(0, 2, 1, 3)
+    out = out[:, :, :T, :D].transpose(0, 2, 1, 3)
     # Zero pad-query rows (the kernel leaves them unspecified-but-finite).
     return out * (q_segment_ids > 0)[:, :, None, None].astype(out.dtype)
 

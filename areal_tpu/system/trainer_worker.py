@@ -330,10 +330,16 @@ class TrainerWorker:
         run is judged by (base/monitor.log_device_report)."""
         from areal_tpu.base import monitor
         from areal_tpu.ops import attention, native
+        from areal_tpu.ops.pallas import flash_attention
 
         monitor.log_device_report(
             logger, f"trainer{self.cfg.dist_rank}", stage=stage,
             attention=attention.dispatch_counts(),
+            # {label: {"length>padded/tile": calls traced}}
+            flash_geometry={
+                label: {"%d>%d/%d" % geom: n for geom, n in counts.items()}
+                for label, counts in flash_attention.geometry_counts().items()
+            },
             compile_cache=compile_watch.cache_stats(),
             native_ops="g++" if native.available() else "numpy",
         )

@@ -26,14 +26,30 @@ def _packed_case(seqlens, Hq=4, Hkv=2, D=128, row_len=None, seed=0):
     return layout, grid, jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
 
 
+# Row lengths with no divisor above 128 (5 x 128, 7 x 128) -> the tile the
+# wrapper pads them to (640 -> 768, 896 -> 1024) before it slices back.
+PADDED_ROWS = {640: 384, 896: 512}
+
+
+def _check_row(layout, row_len):
+    """The case packs into rows of the length it is meant to exercise, and
+    a PADDED_ROWS length runs the tile recorded there."""
+    L = layout.shape[1]
+    assert row_len in (None, L)
+    if L in PADDED_ROWS:
+        assert fa.pick_block_sizes(L, L) == (PADDED_ROWS[L],) * 2
+
+
 @pytest.mark.parametrize(
-    "seqlens",
-    [[128], [60, 68], [100, 20, 120, 9],
-     [300, 340]],  # T=640: 128-aligned but NOT a multiple of 512
+    "seqlens,row_len",
+    [([128], None), ([60, 68], None), ([100, 20, 120, 9], None),
+     ([300, 340], None),  # two rows of 384
+     ([300, 330], 640), ([500, 60, 300], 896)],
 )
 @pytest.mark.parametrize("D", [64, 128])
-def test_flash_matches_reference(seqlens, D):
-    layout, grid, q, k, v = _packed_case(seqlens, D=D)
+def test_flash_matches_reference(seqlens, row_len, D):
+    layout, grid, q, k, v = _packed_case(seqlens, D=D, row_len=row_len)
+    _check_row(layout, row_len)
     seg = jnp.asarray(grid["segment_ids"])
     pos = jnp.asarray(grid["positions"])
 
@@ -48,8 +64,16 @@ def test_flash_matches_reference(seqlens, D):
     assert (np.asarray(out)[pad] == 0).all()
 
 
-def test_flash_backward_matches_reference():
-    layout, grid, q, k, v = _packed_case([96, 32], Hq=2, Hkv=2, D=128)
+@pytest.mark.parametrize(
+    "seqlens,row_len,D",
+    [([96, 32], None, 128), ([90, 30], None, 128),
+     ([300, 330], 640, 64), ([300, 330], 640, 128),
+     ([500, 60, 300], 896, 64), ([500, 60, 300], 896, 128)],
+)
+def test_flash_backward_matches_reference(seqlens, row_len, D):
+    layout, grid, q, k, v = _packed_case(seqlens, Hq=2, Hkv=2, D=D,
+                                         row_len=row_len)
+    _check_row(layout, row_len)
     seg = jnp.asarray(grid["segment_ids"])
     pos = jnp.asarray(grid["positions"])
 
@@ -70,19 +94,31 @@ def test_flash_backward_matches_reference():
             np.asarray(a), np.asarray(b), atol=5e-2,
             err_msg=f"grad mismatch for {name}",
         )
+        # pad tokens get exactly nothing: as queries (dq) and as keys (the
+        # only queries that see them are pad queries, whose dO is zero)
+        pad = np.asarray(seg) == 0
+        assert (np.asarray(a)[pad] == 0).all(), name
 
 
-@pytest.mark.parametrize("spec", ["f2", "d2t2", "t4"])
-def test_flash_on_mesh_matches_reference(spec):
+# four rows of each length
+MESH_ROWS = {128: [100, 20, 120, 9, 68, 60], 640: [300, 330, 600, 610, 620, 10]}
+
+
+@pytest.mark.parametrize(
+    "spec,row_len", [("f2", 128), ("d2t2", 128), ("t4", 128), ("d2t2", 640)])
+def test_flash_on_mesh_matches_reference(spec, row_len):
     """GSPMD cannot partition a Mosaic kernel, so under a mesh the call is
     wrapped in a shard_map: rows over the data axes, heads over tp where
-    they divide (t4 with 2 kv heads does not — it computes redundantly)."""
+    they divide (t4 with 2 kv heads does not — it computes redundantly).
+    The pad of a 640-token row to its tile happens inside the body."""
     from areal_tpu.parallel import mesh as pmesh
     from areal_tpu.parallel import sharding as psh
 
-    layout, grid, q, k, v = _packed_case([100, 20, 120, 9, 68, 60], D=64)
+    layout, grid, q, k, v = _packed_case(MESH_ROWS[row_len], D=64,
+                                         row_len=row_len)
+    _check_row(layout, row_len)
     seg = jnp.asarray(grid["segment_ids"])
-    assert q.shape[:2] == (4, 128)
+    assert q.shape[:2] == (4, row_len)
     ref = attn.packed_attention(q, k, v, seg, seg, impl="reference")
     mesh = pmesh.make_mesh(pmesh.ParallelSpec.parse(spec))
     with pltpu.force_tpu_interpret_mode(), psh.activation_sharding(mesh):
@@ -106,13 +142,49 @@ def _clean_block_state(monkeypatch):
 
 
 def test_pick_block_sizes_heuristic():
-    # the default: largest dividing 128-multiple <= 512
+    # the default: the tile that is cheapest once the dim is padded to it
     assert fa.pick_block_sizes(1024, 1024) == (512, 512)
-    assert fa.pick_block_sizes(640, 640) == (128, 128)  # 512∤640, 256∤640
+    assert fa.pick_block_sizes(640, 640) == (384, 384)  # padded to 768
     assert fa.pick_block_sizes(384, 768) == (384, 384)
-    # no 128-multiple divisor at all -> None (callers fall back)
+    # not a multiple of 128 -> None (callers fall back)
     assert fa.pick_block_sizes(192, 1024) is None
     assert fa.pick_block_sizes(1024, 100) is None
+
+
+# The row lengths the benchmark's two train cells produce -> (tile, padded
+# length), as PERF.md records them.
+CELL_GEOMETRY = {512: (512, 512), 2688: (512, 3072), 3072: (512, 3072),
+                 6016: (512, 6144), 7296: (512, 7680)}
+
+
+@pytest.mark.parametrize("L", range(128, 8192 + 1, 128))
+def test_pick_tile_rule(L):
+    tile = fa.pick_tile(L)
+    assert fa.pick_block_sizes(L, L) == (tile, tile)
+    L_pad = fa._round_up(L, tile)
+    assert tile in fa.TILE_COST and L_pad % tile == 0 and L <= L_pad < L + tile
+    if L >= 512:
+        assert tile > 128
+    # the padding never costs more than the tile saves over blocks of 128
+    assert L_pad ** 2 / L ** 2 <= fa.TILE_COST[128] / fa.TILE_COST[tile]
+    if L in CELL_GEOMETRY:
+        assert (tile, L_pad) == CELL_GEOMETRY[L]
+
+
+def test_geometry_counts_under_the_active_label():
+    """A 6016-token row (47 x 128) is traced at 6144 with blocks of 512,
+    counted beside — not in — the dispatch counts."""
+    q = jax.ShapeDtypeStruct((1, 6016, 14, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 6016, 2, 64), jnp.bfloat16)
+    seg = jax.ShapeDtypeStruct((1, 6016), jnp.int32)
+    with attn.dispatch_label("t6016"):
+        out = jax.eval_shape(
+            lambda q, k, v, s: attn.packed_attention(q, k, v, s, s,
+                                                     impl="pallas"),
+            q, kv, kv, seg)
+    assert out.shape == q.shape
+    assert fa.geometry_counts()["t6016"] == {(6016, 6144, 512): 1}
+    assert attn.dispatch_counts()["t6016"] == {"pallas": 1}
 
 
 def test_pick_block_sizes_table_and_env(monkeypatch, tmp_path):

@@ -1268,7 +1268,7 @@ def blocksweep(T: int = 1792, S: int = 1792, out_path: str = None,
     dt, bq, bkv = results[0]
     heur = fa.pick_block_sizes(T, S)
     print(f"[blocksweep] winner: bq={bq} bkv={bkv} ({dt * 1e3:.2f} ms; "
-          f"heuristic default was {heur})")
+          f"pick_tile default is {heur})")
     out_path = out_path or _os.path.join("profiles", "flash_blocks.json")
     _os.makedirs(_os.path.dirname(out_path) or ".", exist_ok=True)
     table = {}
