@@ -38,6 +38,7 @@ import numpy as np
 
 from areal_tpu.algorithms import ppo_functional as F
 from areal_tpu.api.data import MicroBatchSpec, SequenceSample
+from areal_tpu.models.moe import SUMMED_AUX
 from areal_tpu.api.model import (
     GenerationHyperparameters,
     Model,
@@ -529,9 +530,9 @@ class PPOActorInterface(ModelInterface):
             )
         model.inc_version()
         n = max(agg.get("n_action_tokens", 1.0), 1.0)
-        moe_stats = {
-            k: v / max(n_steps, 1) for k, v in agg.items()
-            if k.startswith("moe_")
+        moe_stats = {  # means over the optimizer steps, but for the sums
+            k: v if k[4:] in SUMMED_AUX else v / max(n_steps, 1)
+            for k, v in agg.items() if k.startswith("moe_")
         }
         rewards_np = np.asarray(data.data["rewards"], np.float32).reshape(-1)
         return {

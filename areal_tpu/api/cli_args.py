@@ -444,6 +444,37 @@ KNOWN_MFCS = (
 )
 
 
+def _actor_moe(cfg):
+    """(MoEConfig, where it was read) of the actor model, or (None, None)
+    for a dense one: ``actor.tiny.moe`` for a fabricated model, else the
+    expert keys of the ``config.json`` beside ``actor.path`` through the
+    family mapping a checkpoint takes (models/hf.config_from_hf)."""
+    import json
+    import os
+    import types
+
+    from areal_tpu.models.config import MoEConfig
+
+    actor = getattr(cfg, "actor", None)
+    tiny = getattr(actor, "tiny", None)
+    if isinstance(tiny, dict) and tiny:
+        moe = tiny.get("moe")
+        return ((MoEConfig(**moe), "actor.tiny.moe")
+                if isinstance(moe, dict) else (None, None))
+    path = os.path.join(str(getattr(actor, "path", "") or ""), "config.json")
+    if not os.path.isfile(path):
+        return None, None
+    from areal_tpu.models import hf
+
+    with open(path) as f:
+        hf_cfg = types.SimpleNamespace(**json.load(f))
+    try:
+        model_cfg = hf.config_from_hf(hf_cfg)
+    except NotImplementedError:
+        return None, None
+    return model_cfg.moe, path
+
+
 def validate_config(cfg) -> None:
     """Config-parse-time sanity checks, called right after overrides/YAML
     merge (training/_cli.py) and again by the launcher: a bad ``mode``
@@ -521,7 +552,7 @@ def validate_config(cfg) -> None:
                     f"allocation_mode {label} spec '{spec}' sets ep="
                     f"{spec.ep}, but expert parallelism only applies to "
                     "training: the decode hot loop runs the replicated "
-                    "einsum dispatch (models/moe.py never all-to-alls "
+                    "one-shard dispatch (models/moe.py never exchanges "
                     "under a KV cache). Move the ep factor into dp or tp "
                     "for the generation fleet — e.g. e2 -> d2 "
                     "(docs/parallelism.md §Expert parallelism)."
@@ -529,34 +560,34 @@ def validate_config(cfg) -> None:
         # Expert-parallel train specs need a MoE model whose expert count
         # divides over the axis; anything else silently replicates or
         # crashes inside shard_map at step time, so fail at parse time.
-        moe_dict = getattr(getattr(cfg, "actor", None), "tiny", None)
-        moe_dict = moe_dict.get("moe") if isinstance(moe_dict, dict) else None
+        moe_cfg, moe_src = _actor_moe(cfg)
         train_specs = [("global", alloc.global_spec)]
         train_specs += [(f"MFC '{m}'", s) for m, s in
                         sorted(alloc.per_mfc.items()) if m != "actor_gen"]
         for label, spec in train_specs:
             if spec is None or spec.ep <= 1:
                 continue
-            if not isinstance(moe_dict, dict):
+            if moe_cfg is None:
                 raise ConfigError(
                     f"allocation_mode {label} spec '{spec}' sets ep="
                     f"{spec.ep} but the model is dense (actor.tiny.moe is "
-                    "unset): there are no experts to shard. Drop the ep "
-                    "factor or configure actor.tiny.moe "
+                    "unset and no config.json beside actor.path names "
+                    "experts): there are no experts to shard. Drop the ep "
+                    "factor or use a MoE model "
                     "(docs/parallelism.md §Expert parallelism)."
                 )
-            n_exp = int(moe_dict.get("num_experts", 8))
-            if n_exp % spec.ep != 0:
+            if moe_cfg.num_experts % spec.ep != 0:
                 raise ConfigError(
                     f"allocation_mode {label} spec '{spec}' sets ep="
                     f"{spec.ep}, which does not divide "
-                    f"actor.tiny.moe.num_experts={n_exp}: every ep shard "
-                    "must own the same number of experts "
+                    f"{moe_src}.num_experts={moe_cfg.num_experts}: every "
+                    "ep shard must own the same number of experts "
                     "(docs/parallelism.md §Expert parallelism)."
                 )
     moe_dict = getattr(getattr(cfg, "actor", None), "tiny", None)
     moe_dict = moe_dict.get("moe") if isinstance(moe_dict, dict) else None
-    if isinstance(moe_dict, dict):
+    if isinstance(moe_dict, dict) and moe_dict.get(
+            "capacity_factor", 2.0) is not None:
         cf = float(moe_dict.get("capacity_factor", 2.0))
         if cf <= 0:
             raise ConfigError(

@@ -38,6 +38,7 @@ from areal_tpu.api.model import (
 from areal_tpu.backend import microbatch as mbu
 from areal_tpu.base import compile_watch, logging, telemetry
 from areal_tpu.models import generate as genmod
+from areal_tpu.models import moe as moe_mod
 from areal_tpu.models import transformer
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.ops.attention import dispatch_label
@@ -200,6 +201,23 @@ def _accumulate(loss, stats, grads, scale, carry):
     return loss, stats, grads
 
 
+# "moe_" statistics that add up over a step's micro-batches; the others
+# are per-micro-batch means carried as sums.
+_MOE_SUMMED = tuple(f"moe_{k}" for k in moe_mod.SUMMED_AUX)
+
+
+def _moe_step_stats(fetched: Dict[str, Any], n_mbs: int) -> Dict[str, float]:
+    """A step's routing health out of its fetched statistics: the (token,
+    expert) pairs routed per layer over the step, and the micro-batch
+    means of the load ratio and the dropped share. {} for a dense model."""
+    n = max(n_mbs, 1)
+    return {
+        k: float(fetched[k]) / (1 if k in _MOE_SUMMED else n)
+        for k in ("moe_routed_rows", "moe_expert_load_ratio",
+                  "moe_dropped_frac") if k in fetched
+    }
+
+
 @dataclasses.dataclass
 class UniformBatch:
     """A whole batch resident on device as one [n_mbs·R, L] grid set.
@@ -253,7 +271,16 @@ class JaxTrainEngine(TrainableEngine):
         # Column-chunk size for the chunked-logprob head (None disables);
         # only used by losses/hooks that declare wants_token_logprobs.
         self.logprob_chunk = logprob_chunk
+        # Rows of a micro-batch split over the mesh's data axes: the packer
+        # makes their count a multiple of that degree, or the row-wise
+        # shard_maps (flash attention, the expert-parallel MoE layer) cannot
+        # split them and every chip computes every row.
+        self.rows_multiple = 1
         if mesh is not None:
+            from areal_tpu.parallel.mesh import DATA_AXES
+
+            self.rows_multiple = int(np.prod(
+                [mesh.shape[a] for a in DATA_AXES]))
             params = psh.shard_params(params, mesh, cfg)
         else:
             params = jax.tree.map(jnp.asarray, params)
@@ -289,7 +316,8 @@ class JaxTrainEngine(TrainableEngine):
                 return self.tx.init(params)
 
             self.opt_state = compile_watch.watched_jit(
-                "train/opt_init", jax.jit(opt_init)
+                "train/opt_init",
+                jax.jit(opt_init, out_shardings=self._opt_shardings(opt_init)),
             )(self.params)
         self._grad_fns: Dict[int, Callable] = {}
         self._fwd_fns: Dict[int, Callable] = {}
@@ -303,6 +331,25 @@ class JaxTrainEngine(TrainableEngine):
         )
 
     # -------------- internals --------------
+
+    def _opt_shardings(self, opt_init: Callable):
+        """Where the optimizer state is born: each moment like the
+        parameter it belongs to, everything else replicated. The moments
+        are zeros that depend on no input, so left to itself the
+        partitioner puts every one of them whole on the first chip
+        (RESOURCE_EXHAUSTED for a model that only fits sharded). None
+        without a mesh."""
+        if self.mesh is None:
+            return None
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        replicated = NamedSharding(self.mesh, PartitionSpec())
+        return optax.tree_utils.tree_map_params(
+            self.tx, lambda _, sharding: sharding,
+            jax.eval_shape(opt_init, self.params),
+            jax.tree.map(lambda x: x.sharding, self.params),
+            transform_non_params=lambda _: replicated,
+        )
 
     def _mesh_ctx(self):
         if self.mesh is not None:
@@ -319,6 +366,19 @@ class JaxTrainEngine(TrainableEngine):
 
         with jax.named_scope("param_cast"):
             return jax.tree.map(c, params)
+
+    def _value_and_grad(self, lf: Callable, params):
+        """``jax.value_and_grad(lf, has_aux=True)(params)`` taken through
+        the compute-dtype copy: the gradient of the copy IS the gradient
+        of the masters (the cast's transpose only widens it), so it is
+        produced in the compute dtype — half the bytes of a float32 tree
+        between the backward pass and the accumulation — and widened to
+        the masters' dtype leaf by leaf, where the add into the carry
+        reads it. ``lf`` casts again inside, which is then the identity."""
+        out, grads = jax.value_and_grad(lf, has_aux=True)(self._cast(params))
+        with jax.named_scope("grad_accum"):
+            grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads, params)
+        return out, grads
 
     def _model_forward(
         self, params, batch: Dict[str, jnp.ndarray], with_aux: bool = False
@@ -434,7 +494,7 @@ class JaxTrainEngine(TrainableEngine):
                         })
                     return loss, stats
 
-                (loss, stats), grads = jax.value_and_grad(lf, has_aux=True)(params)
+                (loss, stats), grads = self._value_and_grad(lf, params)
                 return _accumulate(loss, stats, grads, scale, carry)
 
             donate = (5,) if with_carry else ()
@@ -513,7 +573,7 @@ class JaxTrainEngine(TrainableEngine):
             mbs = mbu.split_into_microbatches(
                 input_, mb_spec, length_bucket=self.length_bucket,
                 rows_bucket=self.rows_bucket, seqs_bucket=self.seqs_bucket,
-                fill_bucket=self.fill_bucket,
+                fill_bucket=self.fill_bucket, rows_multiple=self.rows_multiple,
             )
             telemetry.set_gauge("train/pack_fill", mbu.pack_fill(mbs))
         R, L = mbs[0].layout.shape
@@ -618,7 +678,7 @@ class JaxTrainEngine(TrainableEngine):
                         })
                     return loss, stats
 
-                (loss, stats), grads = jax.value_and_grad(lf, has_aux=True)(params)
+                (loss, stats), grads = self._value_and_grad(lf, params)
                 return _accumulate(loss, stats, grads, scale, carry)
 
             donate = (7,) if with_carry else ()
@@ -720,7 +780,10 @@ class JaxTrainEngine(TrainableEngine):
                     **stats_acc, **(extra_fetch or {}), "loss": loss_acc,
                     "grad_norm": gnorm, "update_applied": applied,
                 })
-            with telemetry.span("train/finish_stats"):
+            # The span that closes the step carries the step's routing
+            # health (MoE models; nothing for a dense one).
+            with telemetry.span("train/finish_stats",
+                                **_moe_step_stats(fetched, n_mbs)):
                 # A skipped (early-stopped) update must not advance the LR
                 # schedule: optax's internal count is an array leaf and
                 # was reverted by the gate; keep the host-side mirror in
@@ -741,13 +804,11 @@ class JaxTrainEngine(TrainableEngine):
         return out
 
     def _ep_engagement(self, batch: int, seq_len: int, pp_on: float) -> float:
-        """0/1 gauge: will the MoE all-to-all expert-parallel path engage
+        """0/1 gauge: will the MoE expert-parallel path engage
         for this shape? Mirrors the forward gate (transformer._block):
         never inside pipeline stages (already-manual regions — there GSPMD
         alone handles the ep-sharded weights), otherwise moe.ep_eligible
         on the engine mesh."""
-        from areal_tpu.models import moe as moe_mod
-
         if pp_on:
             return 0.0
         return float(moe_mod.ep_eligible(
@@ -765,15 +826,16 @@ class JaxTrainEngine(TrainableEngine):
         histogram — are split off BEFORE scalar conversion (float() on a
         vector raises) and published as a telemetry distribution; "moe_"
         stats are per-mb means accumulated as sums, so divide by the mb
-        count; the routing-health scalars also land on the scrape as
-        ``train/moe_*`` gauges (docs/observability.md; the sentinel
-        ``expert_collapse`` rule baselines the load ratio)."""
+        count (but for ``_MOE_SUMMED``); the routing-health scalars also
+        land on the scrape as ``train/moe_*`` gauges
+        (docs/observability.md; the sentinel ``expert_collapse`` rule
+        baselines the load ratio)."""
         n_mbs = max(n_mbs, 1)
         vec = {k: v for k, v in fetched.items()
                if getattr(v, "ndim", 0) > 0 and np.size(v) > 1}
         out = {k: float(v) for k, v in fetched.items() if k not in vec}
         for k in out:
-            if k.startswith("moe_"):
+            if k.startswith("moe_") and k not in _MOE_SUMMED:
                 out[k] /= n_mbs
         load = vec.get("moe_expert_load")
         if load is not None:
@@ -829,7 +891,7 @@ class JaxTrainEngine(TrainableEngine):
             mbs = mbu.split_into_microbatches(
                 input_, mb_spec, length_bucket=self.length_bucket,
                 rows_bucket=self.rows_bucket, seqs_bucket=self.seqs_bucket,
-                fill_bucket=self.fill_bucket,
+                fill_bucket=self.fill_bucket, rows_multiple=self.rows_multiple,
             )
             telemetry.set_gauge("train/pack_fill", mbu.pack_fill(mbs))
         mb_rows, mb_len = mbs[0].layout.shape
@@ -996,7 +1058,7 @@ class JaxTrainEngine(TrainableEngine):
             mbs = mbu.split_into_microbatches(
                 input_, mb_spec, length_bucket=self.length_bucket,
                 rows_bucket=self.rows_bucket, seqs_bucket=self.seqs_bucket,
-                fill_bucket=self.fill_bucket,
+                fill_bucket=self.fill_bucket, rows_multiple=self.rows_multiple,
             )
             telemetry.set_gauge("infer/pack_fill", mbu.pack_fill(mbs))
         use_lp = self._use_chunked_logprobs(post_hook)

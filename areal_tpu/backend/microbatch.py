@@ -88,6 +88,7 @@ def split_into_microbatches(
     seqs_bucket: int = 8,
     row_len: Optional[int] = None,
     fill_bucket: Optional[int] = None,
+    rows_multiple: int = 1,
 ) -> List[MicroBatch]:
     """Pack ``sample`` into micro-batches of IDENTICAL ``[R, L]`` grid shape.
 
@@ -114,7 +115,10 @@ def split_into_microbatches(
     ``length_bucket`` to trade fill for shape stability.
 
     ``rows_bucket`` is kept for API compatibility; uniform grouping already
-    pins the compiled shape set.
+    pins the compiled shape set. ``rows_multiple`` (the engine's mesh: the
+    degree of its data axes) restricts R to its multiples, so that a
+    micro-batch's rows split evenly over the chips; where the token cap
+    allows fewer rows than that, R is ``rows_multiple`` itself.
     """
     if sample.bs == 0:
         return []
@@ -124,7 +128,7 @@ def split_into_microbatches(
     total = sum(seqlens)
     cap = int(mb_spec.max_tokens_per_mb or total)
     base = packing.round_up(max(seqlens), fill_bucket)
-    cap = max(cap, base)
+    cap = max(cap, base * rows_multiple)
     if row_len is not None:
         L0 = packing.round_up(row_len, length_bucket)
         if max(seqlens) > L0:
@@ -136,7 +140,7 @@ def split_into_microbatches(
         # Bound the sweep: rows much longer than a few multiples of the
         # longest sequence stop improving fill, and an uncapped token
         # budget must not turn into an O(total/fill_bucket) FFD sweep.
-        hi = min(cap, max(2 * base, 64 * fill_bucket))
+        hi = min(cap // rows_multiple, max(2 * base, 64 * fill_bucket))
         cands = list(range(base, hi + 1, fill_bucket))
     min_mbs = mb_spec.n_mbs or 1
     best = None
@@ -147,7 +151,8 @@ def split_into_microbatches(
         # swept downward because ceil(len(rows)/R) rounding can pad the
         # last micro-batch with up to R-1 dead rows.
         max_R = max(min(cap // L, len(rows) // min_mbs), 1)
-        for R in range(max_R, 0, -1):
+        max_R = max(max_R // rows_multiple, 1) * rows_multiple
+        for R in range(max_R, 0, -rows_multiple):
             n_mbs = -(-len(rows) // R)
             cells = n_mbs * R * L
             # Strict < keeps the FIRST optimum: the smaller row length

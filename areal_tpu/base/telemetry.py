@@ -259,6 +259,12 @@ DEVICE_SCOPES = (
     "grad_clip", "adam", "param_update",      # the apply program
     "ppo_loss", "gae",                        # algorithms/ppo.py
 )
+# Inside "moe" (models/moe.py): the router (matmul, softmax, top-k and the
+# balancing statistics), the sort / gather / un-permute / combine around
+# the experts, the collectives over "ep", and the grouped GEMMs. Listed
+# apart because they nest: a reader that knows only DEVICE_SCOPES sees
+# their ops under "moe".
+MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_exchange", "moe_experts")
 
 
 def _annotation(name: str, attrs: Dict[str, Any]):

@@ -21,8 +21,9 @@ class MoEConfig:
     # Expert-buffer size multiplier: capacity per expert is
     # ceil(top_k * n_tokens * capacity_factor / num_experts); overflow
     # tokens are dropped (contribute nothing), mirroring the reference's
-    # token_dispatcher capacity drop.
-    capacity_factor: float = 2.0
+    # token_dispatcher capacity drop. None = dropless (olmoe): no
+    # capacity, every chosen (token, expert) pair is computed.
+    capacity_factor: Optional[float] = 2.0
     routed_intermediate_dim: Optional[int] = None
     # qwen-moe style always-on shared expert; None = no shared expert
     shared_intermediate_dim: Optional[int] = None
@@ -45,7 +46,12 @@ class TransformerConfig:
     rms_norm_eps: float = 1e-6
     use_attention_bias: bool = False  # qwen2: True on qkv
     use_attn_output_bias: bool = False
-    use_qk_norm: bool = False  # qwen3
+    use_qk_norm: bool = False  # qwen3, olmoe
+    # What one q/k RMSNorm spans — a property of the family, set by the HF
+    # mapping (models/hf.py): "head" = each head's head_dim after the
+    # split into heads (qwen3), "proj" = the whole projected vector
+    # before it (olmoe: q_norm [q_dim], k_norm [kv_dim]).
+    qk_norm_extent: str = "head"
     tie_word_embeddings: bool = False
     is_critic: bool = False  # scalar head instead of lm head
     moe: Optional[MoEConfig] = None
@@ -74,6 +80,14 @@ class TransformerConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_dim
+
+    @property
+    def q_norm_dim(self) -> int:
+        return self.q_dim if self.qk_norm_extent == "proj" else self.head_dim
+
+    @property
+    def k_norm_dim(self) -> int:
+        return self.kv_dim if self.qk_norm_extent == "proj" else self.head_dim
 
     @property
     def group_size(self) -> int:

@@ -4,7 +4,7 @@ Parity target: the reference's per-family converter registry
 (``realhf/impl/model/conversion/hf_registry.py:32`` +
 ``realhf/api/from_hf/{llama,qwen2,qwen3,gemma,gpt2,mistral,mixtral}.py``).
 Families covered: llama, qwen2 (qwen2.5), qwen3, mistral, gemma, gpt2,
-mixtral, qwen3_moe.
+mixtral, qwen3_moe, olmoe.
 
 Weights are stacked on a leading layer axis (see models/transformer.py), so
 conversion transposes HF's ``[out, in]`` linear layout to ``[in, out]`` and
@@ -142,6 +142,31 @@ def _qwen3_moe_config(hf_config: Any) -> TransformerConfig:
     )
 
 
+@register_hf_family("olmoe")
+def _olmoe_config(hf_config: Any) -> TransformerConfig:
+    """OLMoE (``OlmoeForCausalLM``): every layer is an expert layer whose
+    experts have ``intermediate_size`` (there is no dense MLP and no shared
+    expert), q and k are RMS-normalised over the whole projected vector
+    before the split into heads, the top-k gates are used as the softmax
+    gave them unless ``norm_topk_prob``, and no token is ever dropped."""
+    return TransformerConfig(
+        **_base_kwargs(hf_config),
+        use_qk_norm=True,
+        qk_norm_extent="proj",
+        max_position_embeddings=getattr(
+            hf_config, "max_position_embeddings", None),
+        moe=MoEConfig(
+            num_experts=hf_config.num_experts,
+            top_k=hf_config.num_experts_per_tok,
+            capacity_factor=None,
+            routed_intermediate_dim=hf_config.intermediate_size,
+            aux_loss_coeff=getattr(hf_config, "router_aux_loss_coef", 0.01),
+            norm_topk_prob=getattr(hf_config, "norm_topk_prob", False),
+        ),
+        hf_family="olmoe",
+    )
+
+
 def config_from_hf(hf_config: Any) -> TransformerConfig:
     """Build a TransformerConfig from a transformers PretrainedConfig."""
     mt = getattr(hf_config, "model_type", "llama")
@@ -201,7 +226,7 @@ def _moe_names(cfg: TransformerConfig) -> Dict[str, str]:
             "e_up": "model.layers.{i}.block_sparse_moe.experts.{e}.w3.weight",
             "e_down": "model.layers.{i}.block_sparse_moe.experts.{e}.w2.weight",
         }
-    # qwen3_moe layout
+    # qwen3_moe / olmoe layout
     return {
         "router": "model.layers.{i}.mlp.gate.weight",
         "e_gate": "model.layers.{i}.mlp.experts.{e}.gate_proj.weight",
@@ -398,6 +423,7 @@ _HF_ARCH = {
     "gpt2": "GPT2LMHeadModel",
     "mixtral": "MixtralForCausalLM",
     "qwen3_moe": "Qwen3MoeForCausalLM",
+    "olmoe": "OlmoeForCausalLM",
 }
 
 
@@ -448,15 +474,20 @@ def hf_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
             d["num_experts_per_tok"] = cfg.moe.top_k
             d["router_aux_loss_coef"] = cfg.moe.aux_loss_coeff
         else:
+            width = cfg.moe.routed_intermediate_dim or cfg.intermediate_dim
             d["num_experts"] = cfg.moe.num_experts
             d["num_experts_per_tok"] = cfg.moe.top_k
-            d["moe_intermediate_size"] = (
-                cfg.moe.routed_intermediate_dim or cfg.intermediate_dim
-            )
             d["norm_topk_prob"] = cfg.moe.norm_topk_prob
             d["router_aux_loss_coef"] = cfg.moe.aux_loss_coeff
-            d["decoder_sparse_step"] = 1
-            d["mlp_only_layers"] = []
+            if fam == "olmoe":  # intermediate_size IS the expert width
+                del d["head_dim"]
+                d["intermediate_size"] = width
+                d["attention_bias"] = False
+                d["clip_qkv"] = None
+            else:
+                d["moe_intermediate_size"] = width
+                d["decoder_sparse_step"] = 1
+                d["mlp_only_layers"] = []
     return d
 
 

@@ -3,28 +3,32 @@
 Parity target: ``realhf/impl/model/modules/moe/`` — ``TopKRouter``
 (router.py:24; aux-loss load balancing :78, z-loss :146, input jitter
 :170), token dispatcher (token_dispatcher.py: permute + capacity drop) and
-``GroupedMLP`` (experts.py:99, grouped_gemm). TPU-first differences:
+``GroupedMLP`` (experts.py:99, grouped_gemm). What runs here:
 
- - the production dispatch is **grouped** (MegaBlocks/dropless-MoE style):
-   flatten (token, choice) entries, stable-argsort by expert id, and run
-   the expert MLPs as grouped GEMMs over contiguous per-expert segments
-   via ``jax.lax.ragged_dot`` (sorted-segment fallback on jax versions
-   without it). Expert FLOPs/HBM scale with the tokens actually routed —
-   no dense ``[E, C]`` capacity buffers on the compute path;
- - the original GShard one-hot-einsum dispatch is kept VERBATIM as the
-   parity ORACLE behind ``AREAL_MOE_DISPATCH=einsum`` (same contract as
-   ``AREAL_RING_SCHEDULE`` / ``AREAL_PP_SCHEDULE``). Both paths share the
-   router/aux code and implement the identical Switch-style capacity/drop
-   policy (priority = token order then choice order), so outputs and
-   grads agree including dropped tokens and padding masks;
- - expert parallelism is a REAL mesh axis ("ep", parallel/mesh.py):
-   expert weights shard over it (parallel/sharding.py) and
-   :func:`moe_mlp` given a mesh with ep > 1 runs an all-to-all path —
-   tokens dispatch into per-source capacity buffers, all-to-all to the
-   shard owning their expert, batched expert GEMMs, and all-to-all back
-   (GShard §3.2). Capacity/drop applies at the SHARD boundary (per-source
-   ``capacity(N/ep)``), so the a2a payload is static-shape; the reference
-   itself ships with ep_size=1 only;
+ - **grouped dispatch** (the default on one shard): flatten the (token,
+   choice) entries, stable-argsort them by expert id, and run the expert
+   MLPs as grouped GEMMs over the contiguous per-expert segments
+   (``jax.lax.ragged_dot``). Work scales with the rows actually routed;
+   there is no ``[E, C]`` capacity buffer on the compute path;
+ - **capacity or none** (``MoEConfig.capacity_factor``): a number keeps
+   the Switch-style drop of the reference (an entry past its expert's
+   ``capacity`` slots contributes nothing; priority = token order, then
+   choice order); ``None`` is a dropless model (olmoe): every chosen
+   (token, expert) pair is computed, ``dropped_frac`` is 0 by
+   construction and no path builds a keep mask;
+ - **expert parallelism** over the mesh's "ep" axis (parallel/mesh.py),
+   whose shards own ``E/ep`` experts each (parallel/sharding.py) and a
+   slice of the batch: :func:`_dispatch_ep` all-gathers a micro-batch's
+   tokens and routing over "ep"; each shard, one source shard's tokens at
+   a time, sorts the entries that chose ITS experts to the front and runs
+   the grouped GEMMs over them, and the gate-weighted per-token sums are
+   reduce-scattered back. Shapes are static under any routing skew (the
+   row bound is the worst case, every entry local) and nothing of shape
+   ``[N, E, C]`` is built. With a capacity the drop is decided at the
+   shard boundary (per source shard); a dropless model drops nothing;
+ - the GShard one-hot-einsum dispatch is kept as the parity ORACLE behind
+   ``AREAL_MOE_DISPATCH=einsum`` (same contract as ``AREAL_RING_SCHEDULE``
+   / ``AREAL_PP_SCHEDULE``); it shares the router and the drop policy;
  - sinkhorn routing is not implemented (the reference defaults to aux-loss
    balancing for its shipped configs).
 
@@ -32,10 +36,17 @@ Weights per layer (stacked on the leading layer axis by the transformer):
 ``router [D, E]``, ``e_gate/e_up [E, D, F]``, ``e_down [E, F, D]``, and an
 optional always-on shared expert ``s_gate/s_up [D, Fs]``, ``s_down [Fs, D]``.
 
+Device scopes inside the transformer's ``moe`` scope
+(base/telemetry.MOE_SCOPES): ``moe_router`` (matmul, softmax, top-k, the
+balancing statistics), ``moe_dispatch`` (sort, gather, un-permute,
+combine), ``moe_exchange`` (the collectives over "ep"), ``moe_experts``
+(the grouped GEMMs).
+
 Routing-health aux (exported as ``train/moe_*`` telemetry by
 backend/jax_train.py; docs/observability.md): ``dropped_frac``,
-``expert_load`` ([E] fraction of routed assignments per expert, pre-drop)
-and ``expert_load_ratio`` (max/mean of that — 1.0 is perfectly balanced,
+``routed_rows`` (the (token, expert) pairs routed), ``expert_load`` ([E]
+fraction of routed assignments per expert, pre-drop) and
+``expert_load_ratio`` (max/mean of that — 1.0 is perfectly balanced,
 → E is total collapse; the sentinel ``expert_collapse`` rule baselines it).
 """
 
@@ -53,6 +64,9 @@ from jax.sharding import PartitionSpec as P
 from areal_tpu.models.config import MoEConfig
 
 DISPATCH_METHODS = ("grouped", "einsum")
+# Aux entries that add up over micro-batches and optimizer steps; every
+# other scalar is a mean (backend/jax_train.py, algorithms/ppo.py).
+SUMMED_AUX = ("routed_rows",)
 
 
 def resolve_dispatch(method: Optional[str] = None) -> str:
@@ -68,13 +82,17 @@ def resolve_dispatch(method: Optional[str] = None) -> str:
 
 
 def capacity(n_tokens: int, moe: MoEConfig) -> int:
+    """Slots per expert for ``n_tokens`` tokens. A dropless model has no
+    capacity: an expert can be chosen by every token, once."""
+    if moe.capacity_factor is None:
+        return n_tokens
     c = math.ceil(moe.top_k * n_tokens * moe.capacity_factor / moe.num_experts)
     return max(int(c), 1)
 
 
 def ep_eligible(mesh: Optional[Mesh], moe: Optional[MoEConfig],
                 batch: int, seq_len: int = 1) -> bool:
-    """Whether the all-to-all expert-parallel path can run: a real "ep"
+    """Whether the expert-parallel path can run: a real "ep"
     mesh axis, experts dividing over it, and batch/seq dims that divide
     their mesh axes (the full-manual shard_map needs exact blocks — e.g.
     generate()'s unbucketed batch dim does not divide, mirroring
@@ -144,6 +162,7 @@ def _routing(
         "aux_total": aux_total,
         "load_balance_loss": load_balance,
         "z_loss": z,
+        "routed_rows": jnp.sum(valid) * k,
         "expert_load": expert_load,
         "expert_load_ratio": load_ratio,
     }
@@ -210,10 +229,70 @@ def _grouped_matmul(xs: jnp.ndarray,  # [M, K] rows sorted by group
                     ) -> jnp.ndarray:
     """Grouped GEMM over contiguous row segments: row m multiplies
     ``w[g]`` where m falls in group g's segment. Rows beyond
-    ``sum(group_sizes)`` yield zeros (ragged_dot guarantees this) —
-    sentinel-sorted padding entries land there.
-    """
-    return jax.lax.ragged_dot(xs, w, group_sizes)
+    ``sum(group_sizes)`` — the sentinel-sorted entries — come back as
+    zeros, and take no gradient: the TPU's ``ragged_dot`` leaves those
+    output rows unwritten (NaN among them, measured on a v5e), in the
+    backward pass as in the forward, so both ends are selected, never
+    multiplied, to zero. The select on the input is what zeroes the
+    cotangent of the rows on the way back."""
+    live = (jnp.arange(xs.shape[0]) < jnp.sum(group_sizes))[:, None]
+    out = jax.lax.ragged_dot(jnp.where(live, xs, 0), w, group_sizes)
+    return jnp.where(live, out, 0)
+
+
+def _sorted_expert_ffn(
+    xf: jnp.ndarray,  # [N, D] tokens
+    eid: jnp.ndarray,  # [N·k] group of each (token, choice) entry; G = none
+    gates: jnp.ndarray,  # [N·k] gate of each entry
+    cap: Optional[int],  # slots per group; None = dropless
+    gate_w: jnp.ndarray,  # [G, D, F]
+    up_w: jnp.ndarray,  # [G, D, F]
+    down_w: jnp.ndarray,  # [G, F, D]
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Sort-based grouped expert compute over the ``G`` experts whose
+    weights are given: one stable argsort of the ``M = N·k`` entries by
+    group id makes each expert's rows contiguous, so the expert MLP is
+    three grouped GEMMs over ``[M, D]``. Entries with the sentinel id
+    ``G`` (padding tokens; under expert parallelism, another shard's
+    experts) sort to the tail beyond ``sum(group_sizes)`` and come back as
+    zero rows. Returns (the gate-weighted sum per token [N, D], the number
+    of entries kept).
+
+    With a capacity the drop matches the einsum oracle structurally: a
+    stable sort preserves flat (token-major, then choice) order within
+    each expert, so an entry's position inside its segment IS the oracle's
+    capacity slot — entries at ``pos >= cap`` keep a zero gate (their rows
+    are computed and contribute nothing). Dropless (``cap=None``) builds
+    no positions and no keep mask."""
+    N, D = xf.shape
+    M = eid.shape[0]
+    k = M // N
+    G = gate_w.shape[0]
+    with jax.named_scope("moe_dispatch"):
+        order = jnp.argsort(eid)  # jnp argsort is stable
+        counts = jnp.bincount(eid, length=G + 1)  # sentinel bin last
+        group_sizes = counts[:G].astype(jnp.int32)
+        gate = jnp.take(gates, order)
+        if cap is None:
+            kept = jnp.sum(group_sizes)
+        else:
+            sorted_eid = jnp.take(eid, order)
+            starts = jnp.cumsum(counts) - counts
+            pos = jnp.arange(M) - jnp.take(starts, sorted_eid)
+            keep = (pos < cap) & (sorted_eid < G)
+            kept = jnp.sum(keep)
+            gate = gate * keep
+        xs = jnp.take(xf, order // k, axis=0)  # [M, D] sorted expert inputs
+    with jax.named_scope("moe_experts"):
+        h = jax.nn.silu(
+            _grouped_matmul(xs, gate_w, group_sizes)
+        ) * _grouped_matmul(xs, up_w, group_sizes)
+        ys = _grouped_matmul(h, down_w, group_sizes)  # [M, D]
+    with jax.named_scope("moe_dispatch"):
+        ys = ys * gate.astype(ys.dtype)[:, None]
+        inv = jnp.argsort(order)  # inverse permutation
+        y = jnp.sum(jnp.take(ys, inv, axis=0).reshape(N, k, D), axis=1)
+    return y, kept.astype(jnp.float32)
 
 
 def _dispatch_grouped(
@@ -225,47 +304,22 @@ def _dispatch_grouped(
     moe: MoEConfig,
     n_valid: jnp.ndarray,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Sort-based grouped expert compute: one stable argsort of the
-    ``M = N·top_k`` (token, choice) entries by expert id makes each
-    expert's rows contiguous, so the expert MLP is three grouped GEMMs
-    over ``[M, D]`` instead of one-hot einsums over ``[E, C, D]`` buffers.
-
-    Drop parity with the oracle is structural: a stable sort preserves
-    flat (token-major, then choice) order within each expert, so an
-    entry's position inside its segment IS the oracle's capacity-slot
-    ``pos`` — ``pos >= C`` entries keep their gate zeroed (their GEMM rows
-    are computed but contribute nothing, exactly like the oracle's
-    unslotted tokens). Padding entries get sentinel id E, sort to the
-    tail beyond ``sum(group_sizes)``, and come back as zeros."""
-    N, D = xf.shape
+    """The one-shard path: every expert is here, padding entries are the
+    sentinel (:func:`_sorted_expert_ffn`)."""
+    N = xf.shape[0]
     E, k = moe.num_experts, moe.top_k
-    M = N * k
-    C = capacity(N, moe)
-
-    valid_b = valid.reshape(N, 1) > 0
-    eid = jnp.where(valid_b, top_i, E).reshape(M)  # sentinel E = padding
-    order = jnp.argsort(eid)  # jnp argsort is stable
-    sorted_eid = jnp.take(eid, order)
-    counts = jnp.bincount(eid, length=E + 1)  # [E+1], sentinel bin last
-    starts = jnp.cumsum(counts) - counts
-    pos = jnp.arange(M) - jnp.take(starts, sorted_eid)  # slot within segment
-    keep = (pos < C) & (sorted_eid < E)
-    dropped_frac = 1.0 - jnp.sum(keep) / jnp.maximum(n_valid * k, 1.0)
-    gate = jnp.take(top_p.reshape(M), order) * keep
-
-    xs = jnp.take(xf, order // k, axis=0)  # [M, D] sorted expert inputs
-    group_sizes = counts[:E].astype(jnp.int32)
-    h = jax.nn.silu(
-        _grouped_matmul(xs, lp["e_gate"], group_sizes)
-    ) * _grouped_matmul(xs, lp["e_up"], group_sizes)
-    ys = _grouped_matmul(h, lp["e_down"], group_sizes)  # [M, D]
-    ys = ys * gate.astype(ys.dtype)[:, None]
-    inv = jnp.argsort(order)  # inverse permutation
-    y = jnp.sum(jnp.take(ys, inv, axis=0).reshape(N, k, D), axis=1)
-    return y, dropped_frac
+    eid = jnp.where(valid.reshape(N, 1) > 0, top_i, E).reshape(N * k)
+    dropless = moe.capacity_factor is None
+    y, kept = _sorted_expert_ffn(
+        xf, eid, top_p.reshape(N * k), None if dropless else capacity(N, moe),
+        lp["e_gate"], lp["e_up"], lp["e_down"],
+    )
+    if dropless:
+        return y, jnp.zeros((), jnp.float32)
+    return y, 1.0 - kept / jnp.maximum(n_valid * k, 1.0)
 
 
-# ---------------- expert-parallel dispatch (all-to-all over "ep") ----------------
+# ---------------- expert-parallel dispatch (gather, sort, reduce-scatter over "ep") ----------------
 
 def _dispatch_ep(
     x: jnp.ndarray,  # [B, T, D] global
@@ -276,63 +330,78 @@ def _dispatch_ep(
     moe: MoEConfig,
     mesh: Mesh,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """GShard §3.2 expert parallelism over the mesh's "ep" axis: each ep
-    shard dispatches its LOCAL tokens into per-destination capacity
-    buffers (``capacity(N/ep)`` per source — the drop/pad happens at the
-    shard boundary, so the exchange is static-shape), all-to-alls rows to
-    the shard owning the expert, runs the batched expert GEMMs on its
-    ``E/ep`` local experts × ``ep·C`` rows, and all-to-alls back for the
-    local gate-weighted combine.
+    """Expert parallelism over the mesh's "ep" axis: each ep shard holds
+    ``E/ep`` experts and a slice of the tokens. The shards all-gather
+    their tokens, expert choices and gates over "ep" (16k tokens of width
+    2048 in bf16 are 67 MB). Each then takes the gathered tokens one
+    source shard at a time: it marks the (token, choice) entries that
+    chose one of ITS experts, sorts those to the front and runs the
+    grouped GEMMs over them (:func:`_sorted_expert_ffn`; an entry of
+    another shard's expert is the sentinel and costs a zero row). The
+    gate-weighted per-token sums — each shard's partial over its own
+    experts — are reduce-scattered back over "ep", which both adds the
+    shards' parts and returns every token to its owner.
 
-    Full-manual shard_map (the ring_attention pattern — 0.4.x's partial-
-    manual partitioner miscompiles auto axes sharing a dim with manual
-    ones): tokens split over DATA_AXES × sp, expert weights over ep with
-    their ffn dim over tp (Megatron column→row: the ``e_down`` partial
-    sums psum over "tp"); the ZeRO-3 fsdp shard of the weights
-    all-gathers at the region boundary, exactly what GSPMD does for the
-    dense paths. Numerics match the replicated paths exactly in the
-    no-drop regime; under drops the priority is per-source-shard rather
-    than global (tested/documented — docs/parallelism.md §Expert
-    parallelism)."""
+    Shapes are static under any routing skew: a pass's sorted buffer has
+    all ``N_local·k`` rows, the worst case in which every entry of that
+    source is local, and the grouped GEMM does the work of the rows that
+    are. Nothing of shape ``[N, E, C]`` is built. With a capacity, the
+    drop is decided per source shard (``capacity(N_local)`` slots, that
+    shard's token order) — the shard-boundary rule the exchange has
+    always had; without one nothing drops.
+
+    Full-manual shard_map (the ring_attention pattern): tokens split over
+    DATA_AXES × sp, expert weights over ep with their ffn dim over tp
+    (Megatron column→row: the ``e_down`` partial sums psum over "tp",
+    after the combine); the ZeRO-3 fsdp shard of the weights all-gathers
+    at the region boundary, exactly what GSPMD does for the dense
+    paths."""
     from areal_tpu.parallel.mesh import DATA_AXES
 
     B, T, D = x.shape
     E, k = moe.num_experts, moe.top_k
+    ep = mesh.shape["ep"]
+    E_l = E // ep
     tok_axes = DATA_AXES + ("sp",)
+    dropless = moe.capacity_factor is None
 
     def body(xl, gl, il, vl, gate_w, up_w, down_w):
         # Local shapes: xl [B/(dp·fsdp·ep), T/sp, D], gl/il [..., Tl, k],
         # vl [..., Tl]; weights [E/ep, D, F/tp] / [E/ep, F/tp, D].
         Bl, Tl = xl.shape[0], xl.shape[1]
         Nl = Bl * Tl
-        xf = xl.reshape(Nl, D)
-        vf = vl.reshape(Nl).astype(jnp.float32)
-        onehot = jax.nn.one_hot(il.reshape(Nl, k), E, dtype=jnp.float32)
-        onehot = onehot * vf[:, None, None]
-        C = capacity(Nl, moe)  # per-SOURCE-shard capacity
-        pos, keep = _capacity_keep(onehot, C)
-        gate = gl.reshape(Nl, k) * keep
-        slot_oh = jax.nn.one_hot(pos.astype(jnp.int32), C, dtype=jnp.float32)
-        combine = jnp.einsum("nke,nkc,nk->nec", onehot, slot_oh, gate)
-        dispatch = (combine > 0).astype(xl.dtype)
+        cap = None if dropless else capacity(Nl, moe)
+        with jax.named_scope("moe_exchange"):
+            gathered = tuple(
+                jax.lax.all_gather(a, "ep", axis=0)  # [ep, Nl, ...] by source
+                for a in (xl.reshape(Nl, D), gl.reshape(Nl, k),
+                          il.reshape(Nl, k), vl.reshape(Nl))
+            )
+        first = jax.lax.axis_index("ep") * E_l  # this shard's experts
 
-        xe = jnp.einsum("nec,nd->ecd", dispatch, xf)  # [E, C, D]
-        # Ship each destination its experts' rows: [E, C, D] → split the
-        # expert axis into ep blocks, concat received by source along the
-        # row axis → [E/ep, ep·C, D] (rows grouped by source shard).
-        xin = jax.lax.all_to_all(xe, "ep", split_axis=0, concat_axis=1,
-                                 tiled=True)
-        ye = _expert_ffn(xin, gate_w, up_w, down_w)  # [E/ep, ep·C, D]
-        ye = jax.lax.psum(ye, "tp")  # row-parallel e_down partial sums
-        # Inverse exchange: row-block s back to source s, concat received
-        # by owner along the expert axis → [E, C, D] in global expert order.
-        ye = jax.lax.all_to_all(ye, "ep", split_axis=1, concat_axis=0,
-                                tiled=True)
-        y = jnp.einsum("nec,ecd->nd", combine.astype(ye.dtype), ye)
+        def one_source(src):
+            xs, gs, ids, vs = src
+            with jax.named_scope("moe_dispatch"):
+                local = ids - first
+                mine = (local >= 0) & (local < E_l) & (vs[:, None] > 0)
+                eid = jnp.where(mine, local, E_l).reshape(Nl * k)
+            return _sorted_expert_ffn(
+                xs, eid, gs.reshape(Nl * k), cap, gate_w, up_w, down_w)
 
-        kept = jax.lax.psum(jnp.sum(keep.astype(jnp.float32)), tok_axes)
-        nv = jax.lax.psum(jnp.sum(vf), tok_axes)
-        dropped = 1.0 - kept / jnp.maximum(nv * k, 1.0)
+        # One source shard's tokens at a time, each pass recomputed in the
+        # backward pass: the sorted buffers of a pass (Nl·k rows) are the
+        # most that is ever alive, not the whole exchange's.
+        y, kept = jax.lax.map(jax.checkpoint(one_source), gathered)
+        with jax.named_scope("moe_exchange"):
+            y = jax.lax.psum(y, "tp")  # row-parallel e_down partial sums
+            y = jax.lax.psum_scatter(y.reshape(ep * Nl, D), "ep",
+                                     scatter_dimension=0, tiled=True)
+            if dropless:
+                dropped = jnp.zeros((), jnp.float32)
+            else:
+                kept = jax.lax.psum(jnp.sum(kept), tok_axes)
+                nv = jax.lax.psum(jnp.sum(vl.astype(jnp.float32)), tok_axes)
+                dropped = 1.0 - kept / jnp.maximum(nv * k, 1.0)
         return y.reshape(Bl, Tl, D), dropped
 
     tok_spec = P(DATA_AXES, "sp")
@@ -363,7 +432,7 @@ def moe_mlp(
     rng: jnp.ndarray = None,  # jitter noise (training only); None = off
     mask: jnp.ndarray = None,  # [B, T] bool/int — True for real tokens
     dispatch: Optional[str] = None,  # None → AREAL_MOE_DISPATCH → "grouped"
-    mesh: Optional[Mesh] = None,  # a mesh with ep > 1 → all-to-all EP path
+    mesh: Optional[Mesh] = None,  # a mesh with ep > 1 → the EP path
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Returns (output [B, T, D], aux dict with load_balance_loss / z_loss /
     aux_total / dropped_frac / expert_load / expert_load_ratio).
@@ -373,8 +442,8 @@ def moe_mlp(
     (the reference runs on unpadded packed tokens, so padding never exists
     there; with [B, T] grids it must be masked out explicitly).
 
-    ``mesh``: pass the active mesh to take the expert-parallel all-to-all
-    path; callers must gate on :func:`ep_eligible` (and must NOT pass a
+    ``mesh``: pass the active mesh to take the expert-parallel path;
+    callers must gate on :func:`ep_eligible` (and must NOT pass a
     mesh from inside an already-manual shard_map region — the pipeline
     stages fall back to the single-shard paths with GSPMD handling the
     ep-sharded weights)."""
@@ -387,7 +456,8 @@ def moe_mlp(
     )
     n_valid = jnp.maximum(jnp.sum(valid), 1.0)
 
-    top_p, top_i, onehot, aux = _routing(xf, lp, moe, rng, valid)
+    with jax.named_scope("moe_router"):
+        top_p, top_i, onehot, aux = _routing(xf, lp, moe, rng, valid)
 
     if mesh is not None and ep_eligible(mesh, moe, B, T):
         y, dropped_frac = _dispatch_ep(x, top_p, top_i, valid, lp, moe, mesh)

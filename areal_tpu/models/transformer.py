@@ -14,7 +14,8 @@ with an idiomatic-JAX design:
    of the same structure (areal_tpu/parallel/sharding.py).
 
 Supports GQA, RoPE (HF llama-style rotate-half), RMSNorm, gated-SiLU MLP,
-optional qk-norm (qwen3), optional attention biases (qwen2), tied embeddings,
+optional qk-norm (per head: qwen3; whole vector: olmoe), optional attention
+biases (qwen2), tied embeddings,
 critic (scalar) head, and a KV-cache decode mode.
 """
 
@@ -75,8 +76,8 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     if cfg.use_attn_output_bias:
         layers["bo"] = jnp.zeros((n, d), dtype)
     if cfg.use_qk_norm:
-        layers["q_norm"] = jnp.ones((n, dh), dtype)
-        layers["k_norm"] = jnp.ones((n, dh), dtype)
+        layers["q_norm"] = jnp.ones((n, cfg.q_norm_dim), dtype)
+        layers["k_norm"] = jnp.ones((n, cfg.k_norm_dim), dtype)
     if cfg.norm_type == "layer":
         layers["ln1_b"] = jnp.zeros((n, d), dtype)
         layers["ln2_b"] = jnp.zeros((n, d), dtype)
@@ -190,10 +191,16 @@ def _block(
             q = q + lp["bq"]
             k = k + lp["bk"]
             v = v + lp["bv"]
+        # The q/k norm spans the whole projected vector (olmoe) or each
+        # head (qwen3): before or after the split into heads.
+        qk_norm = cfg.use_qk_norm and cfg.qk_norm_extent
+        if qk_norm == "proj":
+            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
         q = q.reshape(B, T, cfg.n_q_heads, dh)
         k = k.reshape(B, T, cfg.n_kv_heads, dh)
         v = v.reshape(B, T, cfg.n_kv_heads, dh)
-        if cfg.use_qk_norm:
+        if qk_norm == "head":
             q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
             k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
     if cfg.pos_embedding == "rope":
@@ -220,7 +227,7 @@ def _block(
     if cfg.moe is not None:
         from areal_tpu.models import moe as moemod
 
-        # Expert-parallel all-to-all path: only from GSPMD-auto regions
+        # Expert-parallel path (moe._dispatch_ep): only from GSPMD-auto regions
         # (a pipeline stage is already manual — nested shard_map is
         # rejected there; GSPMD still handles its ep-sharded weights) and
         # only for shard_map-divisible shapes; decode keeps the tolerant

@@ -13,7 +13,7 @@ Axis convention (order fixed so ICI-neighbour axes get the innermost dims):
  - ``dp``    pure data parallel (params replicated)
  - ``fsdp``  data parallel with params/opt-state sharded (ZeRO-3 style)
  - ``ep``    expert parallel: a slice of the data dimension whose shards
-             own disjoint experts (models/moe.py all-to-alls tokens over it)
+             own disjoint experts (models/moe.py gathers tokens over it)
  - ``pp``    pipeline stages over the stacked-layer axis
  - ``sp``    sequence/context parallel (ring attention over this axis)
  - ``tp``    tensor parallel (heads / ffn sharded)
@@ -48,8 +48,9 @@ class ParallelSpec:
 
     ``ep`` (expert parallel) is a REAL mesh axis: the batch dim shards over
     it like dp/fsdp (DATA_AXES), expert weights shard their expert axis
-    over it (sharding.py), and models/moe.py all-to-alls tokens to the
-    shard owning their expert. Validated against num_experts at parse time
+    over it (sharding.py), and models/moe.py gathers a micro-batch's tokens
+    over it so that each shard computes its own experts' rows. Validated
+    against the model's expert count at parse time
     (api/cli_args.validate_config).
     """
 
@@ -127,7 +128,7 @@ def make_mesh(
 # Composite axis names used in PartitionSpecs (sharding.py): the batch dim
 # shards over every DP flavour — ep included, since expert parallelism is
 # a slice of the data dimension (tokens arrive ep-partitioned and the MoE
-# all-to-all moves them to their expert's shard).
+# layer gathers them over ep for each shard's experts).
 DATA_AXES = ("dp", "fsdp", "ep")
 
 

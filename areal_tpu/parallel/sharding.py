@@ -11,9 +11,10 @@ all-reduces/all-gathers/reduce-scatters that Megatron hand-writes.
 Conventions (axes from mesh.AXIS_ORDER):
  - batch dim of activations: ("dp", "fsdp", "ep")
  - sequence dim: "sp" (ring attention over this axis, parallel/ring.py)
- - heads / ffn dim of weights: "tp"; hidden dim of weights: "fsdp" (ZeRO-3)
+ - heads / ffn dim of weights: "tp"; hidden dim of dense weights: "fsdp"
+   and "ep" (ZeRO-3), of expert weights: "fsdp"
  - stacked-layer axis: "pp"; expert axis of MoE weights: "ep" (the MoE
-   layer all-to-alls tokens to their expert's shard, models/moe.py)
+   layer gathers tokens over it for each shard's experts, models/moe.py)
 """
 
 from __future__ import annotations
@@ -42,16 +43,22 @@ def param_partition_specs(cfg: TransformerConfig) -> Params:
     vocab on "tp". The *other* matrix dim goes to "fsdp" (ZeRO-3; the
     reference's DistributedOptimizer ZeRO-1 analogue, strengthened).
     """
+    # ZeRO-3 axes of the dense weights' hidden dim: "fsdp", and "ep" too —
+    # an ep shard owns its experts outright but only a slice of everything
+    # else (masters, gradients, Adam moments), all-gathered where it is
+    # used, as under fsdp. With the dense part replicated over ep instead,
+    # OLMoE's step did not fit a 16 GB chip (PERF.md, PR 26).
+    zero = ("fsdp", "ep")
     layers: Params = {
         "ln1": P("pp", None),
         "ln2": P("pp", None),
-        "wq": P("pp", "fsdp", "tp"),
-        "wk": P("pp", "fsdp", "tp"),
-        "wv": P("pp", "fsdp", "tp"),
-        "wo": P("pp", "tp", "fsdp"),
-        "w_gate": P("pp", "fsdp", "tp"),
-        "w_up": P("pp", "fsdp", "tp"),
-        "w_down": P("pp", "tp", "fsdp"),
+        "wq": P("pp", zero, "tp"),
+        "wk": P("pp", zero, "tp"),
+        "wv": P("pp", zero, "tp"),
+        "wo": P("pp", "tp", zero),
+        "w_gate": P("pp", zero, "tp"),
+        "w_up": P("pp", zero, "tp"),
+        "w_down": P("pp", "tp", zero),
     }
     if cfg.use_attention_bias:
         layers["bq"] = P("pp", "tp")
@@ -73,7 +80,7 @@ def param_partition_specs(cfg: TransformerConfig) -> Params:
     if cfg.moe is not None:
         # Experts stack on a leading axis [n, E, ...]; shard E over the
         # REAL "ep" axis (expert parallelism — each ep shard owns E/ep
-        # experts, moe.py all-to-alls tokens to them), the ffn dim on tp,
+        # experts, moe.py gathers the tokens for them), the ffn dim on tp,
         # and ZeRO-3 the remaining matrix dim over fsdp.
         layers["router"] = P("pp", None, None)
         layers["e_gate"] = P("pp", "ep", "fsdp", "tp")
@@ -88,18 +95,18 @@ def param_partition_specs(cfg: TransformerConfig) -> Params:
             del layers[k]
 
     specs: Params = {
-        "embedding": P("tp", "fsdp"),
+        "embedding": P("tp", zero),
         "layers": layers,
         "final_ln": P(None),
     }
     if cfg.norm_type == "layer":
         specs["final_ln_b"] = P(None)
     if cfg.pos_embedding == "learned":
-        specs["pos_embedding"] = P(None, "fsdp")
+        specs["pos_embedding"] = P(None, zero)
     if cfg.is_critic:
-        specs["value_head"] = P("fsdp", None)
+        specs["value_head"] = P(zero, None)
     elif not cfg.tie_word_embeddings:
-        specs["lm_head"] = P("fsdp", "tp")
+        specs["lm_head"] = P(zero, "tp")
     return specs
 
 

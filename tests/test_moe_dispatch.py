@@ -254,3 +254,85 @@ def test_validate_config_rejects_bad_ep():
     # the happy path passes
     validate_config(cfg("e2", moe={"num_experts": 4, "top_k": 2}))
     validate_config(cfg("d2f2t2"))
+
+
+def test_validate_config_reads_experts_from_a_checkpoints_config(tmp_path):
+    """A model that comes from a checkpoint can be expert-parallel: the
+    expert count is read from the ``config.json`` beside ``actor.path``
+    through the family mapping; a dense checkpoint is still refused."""
+    import json
+
+    from areal_tpu.api.cli_args import ConfigError, validate_config
+
+    class _NS:
+        def __init__(self, **kw):
+            self.__dict__.update(kw)
+
+    def cfg(alloc, hf_keys):
+        d = tmp_path / f"ckpt{len(list(tmp_path.iterdir()))}"
+        d.mkdir()
+        (d / "config.json").write_text(json.dumps(hf_keys))
+        return _NS(mode="local", allocation_mode=alloc, n_nodes=1,
+                   n_gpus_per_node=8, actor=_NS(tiny={}, path=str(d)))
+
+    base = dict(num_hidden_layers=2, hidden_size=32, num_attention_heads=4,
+                num_key_value_heads=4, intermediate_size=16, vocab_size=97)
+    olmoe = dict(base, model_type="olmoe", num_experts=64,
+                 num_experts_per_tok=8)
+    validate_config(cfg("e4", olmoe))
+    validate_config(cfg("d2e4", olmoe))
+    with pytest.raises(ConfigError, match="num_experts=64"):
+        validate_config(cfg("e3", olmoe))
+    with pytest.raises(ConfigError, match="dense"):
+        validate_config(cfg("e4", dict(base, model_type="qwen2")))
+    with pytest.raises(ConfigError, match="ep"):  # never on the fleet's side
+        validate_config(cfg("gen.e2+train.d2", olmoe))
+    # a dropless tiny model: no capacity to check
+    validate_config(_NS(mode="local", allocation_mode="e2", n_nodes=1,
+                        n_gpus_per_node=8, actor=_NS(tiny={"moe": {
+                            "num_experts": 4, "top_k": 2,
+                            "capacity_factor": None}})))
+
+
+@pytest.mark.parametrize("spec", ["e2", "e4"])
+def test_ep_dropless_matches_replicated_under_any_skew(spec):
+    """No capacity: the expert-parallel path computes every chosen pair —
+    output, loss and gradients equal to the one-shard layer's, with a
+    router that spreads the tokens and with one that sends them all to the
+    experts of a single shard; nothing is dropped."""
+    ps = pmesh.ParallelSpec.parse(spec)
+    if ps.world_size > len(jax.devices()):
+        pytest.skip(f"needs {ps.world_size} devices")
+    mesh = pmesh.make_mesh(ps)
+    rng = np.random.RandomState(11)
+    D, F, E, B, T = 16, 32, 8, 8, 8
+    moe = MoEConfig(num_experts=E, top_k=2, capacity_factor=None)
+    x = jnp.asarray(rng.randn(B, T, D).astype(np.float32))
+    for skew in (False, True):
+        lp = _layer_params(rng, D, F, E)
+        if skew:  # every token picks experts 0 and 1
+            lp["router"] = jnp.zeros_like(lp["router"])
+
+        def loss_ep(lp):
+            y, aux = moemod.moe_mlp(x, lp, moe, mesh=mesh)
+            return jnp.sum(y * y) + aux["aux_total"], (y, aux)
+
+        def loss_one(lp):
+            y, aux = moemod.moe_mlp(x, lp, moe)
+            return jnp.sum(y * y) + aux["aux_total"], (y, aux)
+
+        (l_ep, (y_ep, a_ep)), g_ep = jax.value_and_grad(
+            loss_ep, has_aux=True)(lp)
+        (l_1, (y_1, a_1)), g_1 = jax.value_and_grad(
+            loss_one, has_aux=True)(lp)
+        assert float(a_ep["dropped_frac"]) == float(a_1["dropped_frac"]) == 0
+        assert float(a_ep["routed_rows"]) == B * T * 2
+        np.testing.assert_allclose(y_ep, y_1, rtol=1e-5, atol=1e-6)
+        assert float(l_ep) == pytest.approx(float(l_1), rel=1e-5)
+        for name in g_1:
+            np.testing.assert_allclose(g_ep[name], g_1[name], rtol=2e-4,
+                                       atol=1e-6, err_msg=name)
+        # the einsum oracle, dropless, agrees too
+        y_o, a_o = moemod.moe_mlp(x, lp, moe, dispatch="einsum")
+        np.testing.assert_allclose(y_o, y_1, rtol=1e-5, atol=1e-6)
+        assert float(a_o["dropped_frac"]) == 0.0

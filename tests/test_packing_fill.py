@@ -140,3 +140,34 @@ def test_pack_fill_telemetry_export():
         assert 0.5 < snap["gauges"]["train/pack_fill"] <= 1.0
     finally:
         telemetry.shutdown()
+
+
+@pytest.mark.parametrize("m", [1, 2, 4])
+def test_rows_are_a_multiple_of_the_meshs_data_degree(m):
+    """``rows_multiple`` (the engine passes its mesh's data degree): R is
+    a multiple of it and a micro-batch stays inside the token cap, so its
+    rows split evenly over the chips; 1 changes nothing."""
+    for seed in range(3):
+        batch, _ = _bench_batch(seed=seed, n_seq=48)
+        spec = MicroBatchSpec(max_tokens_per_mb=8192, n_mbs=4)
+        mbs = mbu.split_into_microbatches(batch, spec, rows_multiple=m)
+        R, L = mbs[0].layout.shape
+        assert R % m == 0 and R * L <= 8192
+        assert all(mb.layout.shape == (R, L) for mb in mbs)
+        assert sum(mb.n_tokens for mb in mbs) == sum(
+            batch.total_lens("packed_input_ids"))
+        if m == 1:
+            plain = mbu.split_into_microbatches(batch, spec)
+            assert plain[0].layout.shape == (R, L) and len(plain) == len(mbs)
+
+
+def test_rows_multiple_when_the_cap_allows_fewer_rows():
+    """A sequence longer than cap / rows_multiple: R is rows_multiple
+    itself and L the sequence's bucket (over the cap, as one long sequence
+    always was)."""
+    batch, seqlens = _bench_batch(seed=1, n_seq=8)
+    longest = max(seqlens)
+    spec = MicroBatchSpec(max_tokens_per_mb=2 * longest)
+    mbs = mbu.split_into_microbatches(batch, spec, rows_multiple=4)
+    R, L = mbs[0].layout.shape
+    assert R == 4 and L == mbu.packing.round_up(longest, 128)
