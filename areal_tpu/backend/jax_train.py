@@ -41,7 +41,7 @@ from areal_tpu.models import generate as genmod
 from areal_tpu.models import moe as moe_mod
 from areal_tpu.models import transformer
 from areal_tpu.models.config import TransformerConfig
-from areal_tpu.ops.attention import dispatch_label
+from areal_tpu.ops.attention import dispatch_label, kernel_padded_len
 from areal_tpu.parallel import pipeline as ppl
 from areal_tpu.parallel import sharding as psh
 from areal_tpu.system import memwatch
@@ -218,6 +218,41 @@ def _moe_step_stats(fetched: Dict[str, Any], n_mbs: int) -> Dict[str, float]:
     }
 
 
+# The constants of the remat budget (JaxTrainEngine._remat_budget_bytes),
+# calibrated on the chip compiler's ``memory_analysis()`` of the
+# benchmark's seven grids under every entry (PERF.md §5, PR 28).
+# Of the chip's limit, kept free: the allocator wants a program's
+# temporaries in one piece beside what is resident.
+_LIMIT_MARGIN = 0.05
+# Bytes of program heap a kept byte costs (the compiler reports 28 % of
+# fragmentation; measured 1.2-2.6, 2.0 at the grids that decide).
+_HEAP_PER_KEPT_BYTE = 2.0
+# Bytes a logit of the head's chunk costs (compute-dtype logits, their
+# float32 softmax, the cotangent): measured 2.8-4.3.
+_HEAD_BYTES_PER_LOGIT = 4.3
+# Copies of a layer's widest activation that its backward holds: dense
+# (measured 8.7) and through the expert exchange (22.9, one configuration).
+_LAYER_COPIES = 9
+_MOE_LAYER_COPIES = 24
+
+
+def _bytes_on_chip(tree) -> int:
+    """Bytes of a tree's arrays on one chip: each leaf's shard."""
+    return sum(
+        int(np.prod(x.sharding.shard_shape(x.shape))) * x.dtype.itemsize
+        for x in jax.tree.leaves(tree) if isinstance(x, jax.Array))
+
+
+def choose_remat(kept: Dict[str, int], budget: int) -> str:
+    """The entry of transformer.REMAT_ENTRIES that re-runs least among
+    those whose kept bytes fit ``budget``; "full" when none does (it is
+    what a program must keep at the least)."""
+    for entry in reversed(transformer.REMAT_ENTRIES):
+        if kept[entry] <= budget:
+            return entry
+    return transformer.REMAT_ENTRIES[0]
+
+
 @dataclasses.dataclass
 class UniformBatch:
     """A whole batch resident on device as one [n_mbs·R, L] grid set.
@@ -267,6 +302,9 @@ class JaxTrainEngine(TrainableEngine):
         # (None = packer default, min(length_bucket, 128)).
         self.fill_bucket = fill_bucket
         self.attn_impl = attn_impl
+        # False: the backward pass finds everything kept, unconditionally.
+        # True: it re-runs what does not fit, chosen per packed grid from
+        # the grid's tokens and the chip's free memory (_remat_for).
         self.remat = remat
         # Column-chunk size for the chunked-logprob head (None disables);
         # only used by losses/hooks that declare wants_token_logprobs.
@@ -322,6 +360,9 @@ class JaxTrainEngine(TrainableEngine):
         self._grad_fns: Dict[int, Callable] = {}
         self._fwd_fns: Dict[int, Callable] = {}
         self._apply_fn = None
+        # {(R, L): what the grad programs of that packed grid keep for
+        # their backward pass, and why} — see _remat_for / remat_plan.
+        self._remat_plan: Dict[Tuple[int, int], Dict[str, Any]] = {}
         # Static gate for MoE router input jitter: train steps thread a
         # per-micro-batch rng key through the batch dict iff this is set
         # (key presence is part of the jit trace, so the gate must not
@@ -381,8 +422,12 @@ class JaxTrainEngine(TrainableEngine):
         return out, grads
 
     def _model_forward(
-        self, params, batch: Dict[str, jnp.ndarray], with_aux: bool = False
+        self, params, batch: Dict[str, jnp.ndarray], with_aux: bool = False,
+        remat=False,
     ):
+        """``remat``: what a grad program's backward re-runs (an entry of
+        ``transformer.REMAT_ENTRIES``, see :meth:`_remat_for`); a program
+        that is not differentiated keeps nothing either way."""
         out, _, aux = transformer.forward(
             self._cast(params),
             self.cfg,
@@ -390,7 +435,7 @@ class JaxTrainEngine(TrainableEngine):
             batch["positions"],
             segment_ids=batch["segment_ids"],
             attn_impl=self.attn_impl,
-            remat=self.remat,
+            remat=remat,
             return_kv=False,
             return_aux=True,
             rng=batch.get("rng"),
@@ -401,11 +446,12 @@ class JaxTrainEngine(TrainableEngine):
         out = out.astype(jnp.float32) if self.cfg.is_critic else out
         return (out, aux) if with_aux else out
 
-    def _forward_token_logprobs(self, params, batch: Dict[str, jnp.ndarray]):
+    def _forward_token_logprobs(self, params, batch: Dict[str, jnp.ndarray],
+                                remat=False):
         """[R, L] per-token logprobs with a CHUNKED head: the [R, L, V]
         logits grid never materializes (at a 152k vocab it is the single
-        biggest activation, ~2.4GB at [8,1024] incl. its cotangent — the
-        reason remat had to be on). Each column-chunk computes its logits
+        biggest activation, ~2.4GB at [8,1024] incl. its cotangent).
+        Each column-chunk computes its logits
         and gathers its scores under jax.checkpoint, so backward recomputes
         chunk logits instead of storing them — the head matmul is redone
         once (~25% of forward FLOPs at 0.5B) to free the grid; role parity:
@@ -418,7 +464,7 @@ class JaxTrainEngine(TrainableEngine):
             cast, self.cfg,
             batch["tokens"], batch["positions"],
             segment_ids=batch["segment_ids"],
-            attn_impl=self.attn_impl, remat=self.remat,
+            attn_impl=self.attn_impl, remat=remat,
             return_kv=False, return_aux=True, return_hidden=True,
             rng=batch.get("rng"),
         )
@@ -455,7 +501,34 @@ class JaxTrainEngine(TrainableEngine):
             and bool(getattr(fn, "wants_token_logprobs", False))
         )
 
-    def _get_grad_fn(self, loss_fn: LossFn, with_carry: bool) -> Callable:
+    def _loss_and_grads(self, loss_fn: LossFn, remat, params, batch,
+                        denom, aux_scale):
+        """((loss, stats), grads) of one micro-batch: the body both grad
+        programs share."""
+        use_lp = self._use_chunked_logprobs(loss_fn)
+
+        def lf(p):
+            if use_lp:
+                out, aux = self._forward_token_logprobs(p, batch, remat)
+            else:
+                out, aux = self._model_forward(p, batch, with_aux=True,
+                                               remat=remat)
+            loss_sum, stats = loss_fn(out, batch)
+            loss = loss_sum / jnp.maximum(denom, 1.0)
+            if aux:
+                # MoE balancing losses (reference utils/moe.py aux
+                # tracker), surfaced under a reserved "moe_" prefix
+                # (train_batch divides the stats by the mb count).
+                loss = loss + aux["aux_total"] * aux_scale
+                stats = dict(stats, **{
+                    f"moe_{k}": v for k, v in aux.items()
+                })
+            return loss, stats
+
+        return self._value_and_grad(lf, params)
+
+    def _get_grad_fn(self, loss_fn: LossFn, with_carry: bool,
+                     remat=False) -> Callable:
         """Fused grad + accumulate step, one dispatch per micro-batch.
 
         ``with_carry``: the (loss, stats, grads) accumulators from the
@@ -468,33 +541,20 @@ class JaxTrainEngine(TrainableEngine):
         loss so its total contribution over the whole batch equals one
         aux_total regardless of the micro-batch count.
 
+        ``remat``: what the backward pass re-runs, chosen per packed grid
+        by :meth:`_remat_for`; part of the key, so a grid that changes its
+        entry is traced again.
+
         Keyed by the function OBJECT (keeps it alive): an id() key could
         be reused by a new closure after GC and silently run stale code.
         """
-        key = (loss_fn, with_carry)
-        use_lp = self._use_chunked_logprobs(loss_fn)
+        key = (loss_fn, with_carry, remat)
         if key not in self._grad_fns:
 
             def train_grad(params, batch, denom, scale, aux_scale,
                            carry=None):
-                def lf(p):
-                    if use_lp:
-                        out, aux = self._forward_token_logprobs(p, batch)
-                    else:
-                        out, aux = self._model_forward(p, batch, with_aux=True)
-                    loss_sum, stats = loss_fn(out, batch)
-                    loss = loss_sum / jnp.maximum(denom, 1.0)
-                    if aux:
-                        # MoE balancing losses (reference utils/moe.py aux
-                        # tracker), surfaced under a reserved "moe_" prefix
-                        # (train_batch divides the stats by the mb count).
-                        loss = loss + aux["aux_total"] * aux_scale
-                        stats = dict(stats, **{
-                            f"moe_{k}": v for k, v in aux.items()
-                        })
-                    return loss, stats
-
-                (loss, stats), grads = self._value_and_grad(lf, params)
+                (loss, stats), grads = self._loss_and_grads(
+                    loss_fn, remat, params, batch, denom, aux_scale)
                 return _accumulate(loss, stats, grads, scale, carry)
 
             donate = (5,) if with_carry else ()
@@ -557,6 +617,132 @@ class JaxTrainEngine(TrainableEngine):
             "train/apply", jax.jit(train_apply, donate_argnums=(0, 1, 2))
         )
         return self._grad_fns[key]
+
+    # -------------- what the backward pass re-runs --------------
+    #
+    # With ``remat`` on, a grad program keeps between its forward and its
+    # backward pass the most that fits (an entry of
+    # transformer.REMAT_ENTRIES), chosen once per packed grid by
+    # arithmetic — no trial compile: tracing and lowering are most of a
+    # warm start. The constants are calibrated against the chip compiler's
+    # ``memory_analysis()`` of the benchmark's grids (PERF.md §5, PR 28).
+
+    def _device_bytes_limit(self) -> Optional[int]:
+        """The memory one chip gives this process; None where the device
+        does not say (the CPU), and then nothing more than today is kept."""
+        stats = jax.local_devices()[0].memory_stats() or {}
+        return stats.get("bytes_limit")
+
+    def _rows_on_chip(self, R: int) -> int:
+        """Rows of an [R, L] grid that one chip holds (split over the data
+        axes)."""
+        return -(-R // self.rows_multiple)
+
+    def _remat_kept_bytes(self, R: int, L: int) -> Dict[str, int]:
+        """Estimated bytes each entry keeps on ONE chip for the grid
+        [R, L]: its rows split over the data axes (an axis that splits
+        the sequence or the widths is not counted: an over-estimate)."""
+        rows = self._rows_on_chip(R)
+        padded = kernel_padded_len(self.attn_impl, L, self.cfg.sliding_window)
+        return transformer.remat_kept_bytes(
+            self.cfg, rows * L, self.compute_dtype.itemsize,
+            flash_tokens=rows * (padded or 0))
+
+    def _remat_budget_bytes(self, R: int, L: int) -> int:
+        """The bytes of kept activations one chip has room for: its limit
+        less a margin, less the trees the engine holds there (masters,
+        optimizer state, the gradient carry), less the rest of a grad
+        program — over what the compiler's heap takes per kept byte."""
+        limit = self._device_bytes_limit()
+        if limit is None:
+            return 0
+        params = _bytes_on_chip(self.params)
+        resident = 2 * params + _bytes_on_chip(self.opt_state)
+        free = limit * (1.0 - _LIMIT_MARGIN) - resident
+        return int((free - self._remat_reserve_bytes(R, L, params))
+                   / _HEAP_PER_KEPT_BYTE)
+
+    def _remat_reserve_bytes(self, R: int, L: int, params: int) -> int:
+        """A grad program's temporaries besides the kept activations: the
+        compute-dtype copy of the weights, and the larger of its two
+        phases — the head (one chunk's logits with their softmax, or the
+        whole [rows, L, vocab] grid where the chunk does not divide L) or
+        the layers' backward (the gradient in the compute dtype, and one
+        layer's working set by its widest activation)."""
+        cfg, size = self.cfg, self.compute_dtype.itemsize
+        rows = self._rows_on_chip(R)
+        masters = jax.tree.leaves(self.params)[0].dtype.itemsize
+        weights = params * size // masters
+        chunk = self.logprob_chunk if (
+            self.logprob_chunk and L % self.logprob_chunk == 0) else L
+        head = rows * chunk * cfg.vocab_size * _HEAD_BYTES_PER_LOGIT
+        if cfg.moe is None:
+            layer = (max(cfg.intermediate_dim, cfg.q_dim, cfg.hidden_dim)
+                     * _LAYER_COPIES)
+        else:  # a token's top_k rows through the expert exchange
+            layer = cfg.moe.top_k * cfg.hidden_dim * _MOE_LAYER_COPIES
+        return int(weights + max(head, weights + rows * L * layer * size))
+
+    def _remat_for(self, R: int, L: int):
+        """What the grad programs of the packed grid [R, L] keep for their
+        backward pass: False (``remat`` off: everything), else the entry
+        of transformer.REMAT_ENTRIES that re-runs least among those whose
+        kept bytes fit the chip — decided at the grid's first dispatch and
+        recorded in ``remat_plan``. Pipeline stages rematerialise by their
+        own schedule and keep "full"."""
+        if not self.remat:
+            return False
+        plan = self._remat_plan.get((R, L))
+        if plan is None:
+            kept = self._remat_kept_bytes(R, L)
+            budget = self._remat_budget_bytes(R, L)
+            pp_on, _ = ppl.pp_engagement(self.mesh, self.cfg, R, L)
+            entry = "full" if pp_on else choose_remat(kept, budget)
+            plan = self._remat_plan[(R, L)] = {
+                "entry": entry, "kept_bytes_estimate": kept[entry],
+                "budget_bytes": budget, "fell_back": False,
+            }
+            logger.info(f"remat plan for grid {R}x{L}: {plan}")
+        return plan["entry"]
+
+    def _remat_fall_back(self, R: int, L: int) -> bool:
+        """The estimate was wrong — a grad program of this grid did not
+        fit: drop the grid one entry towards "full" and say so in the
+        record. False where there is nothing left to drop."""
+        plan = self._remat_plan.get((R, L))
+        entries = transformer.REMAT_ENTRIES
+        if plan is None or plan["entry"] == entries[0]:
+            return False
+        entry = entries[entries.index(plan["entry"]) - 1]
+        logger.warning(
+            f"grad program of grid {R}x{L} does not fit keeping "
+            f"{plan['entry']!r}; falling back to {entry!r}")
+        plan.update(entry=entry, fell_back=True,
+                    kept_bytes_estimate=self._remat_kept_bytes(R, L)[entry])
+        return True
+
+    def remat_plan(self) -> Dict[str, Dict[str, Any]]:
+        """{"RxL": {entry, kept_bytes_estimate, budget_bytes, fell_back}}
+        of every packed grid a grad program was dispatched for (read like
+        flash_attention.geometry_counts(); in the trainer worker's
+        ``device_report``). Empty with ``remat`` off."""
+        return {f"{R}x{L}": dict(plan)
+                for (R, L), plan in self._remat_plan.items()}
+
+    def _dispatch_grad(self, get_fn: Callable, args: list, R: int, L: int):
+        """Run one grad program of the grid [R, L]. ``get_fn(remat)``
+        gives the jitted program. Compiling it can fail for memory — the
+        estimate behind the grid's entry is arithmetic; then the grid
+        falls back one entry and is traced again (only ever in warm-up:
+        a grid compiles once)."""
+        while True:
+            try:
+                with self._mesh_ctx(), dispatch_label("train"):
+                    return get_fn(self._remat_for(R, L))(*args)
+            except jax.errors.JaxRuntimeError as e:
+                if ("RESOURCE_EXHAUSTED" not in str(e)
+                        or not self._remat_fall_back(R, L)):
+                    raise
 
     # -------------- upload-once uniform batches --------------
     #
@@ -643,14 +829,13 @@ class JaxTrainEngine(TrainableEngine):
         return out_scalars
 
     def _get_sliced_grad_fn(
-        self, loss_fn: LossFn, with_carry: bool, R: int
+        self, loss_fn: LossFn, with_carry: bool, R: int, remat=False,
     ) -> Callable:
         """Like _get_grad_fn but takes the FULL uploaded batch and a traced
         micro-batch index; slices its rows/seq-entries on device. ``R`` (rows
         per micro-batch) is part of the cache key: two packings can share the
         total grid shape while slicing differently."""
-        key = (loss_fn, with_carry, "sliced", R)
-        use_lp = self._use_chunked_logprobs(loss_fn)
+        key = (loss_fn, with_carry, "sliced", R, remat)
         if key not in self._grad_fns:
 
             def train_grad_sliced(params, grids, seq, mb_idx, denom, scale,
@@ -663,22 +848,8 @@ class JaxTrainEngine(TrainableEngine):
                     batch[k] = jax.lax.dynamic_index_in_dim(
                         v, mb_idx, 0, keepdims=False
                     )
-
-                def lf(p):
-                    if use_lp:
-                        out, aux = self._forward_token_logprobs(p, batch)
-                    else:
-                        out, aux = self._model_forward(p, batch, with_aux=True)
-                    loss_sum, stats = loss_fn(out, batch)
-                    loss = loss_sum / jnp.maximum(denom, 1.0)
-                    if aux:
-                        loss = loss + aux["aux_total"] * aux_scale
-                        stats = dict(stats, **{
-                            f"moe_{k}": v for k, v in aux.items()
-                        })
-                    return loss, stats
-
-                (loss, stats), grads = self._value_and_grad(lf, params)
+                (loss, stats), grads = self._loss_and_grads(
+                    loss_fn, remat, params, batch, denom, aux_scale)
                 return _accumulate(loss, stats, grads, scale, carry)
 
             donate = (7,) if with_carry else ()
@@ -728,13 +899,11 @@ class JaxTrainEngine(TrainableEngine):
                 ),
             )
         with telemetry.span("train/fwd_bwd", n_mbs=len(idxs),
-                            grid=f"{ub.R}x{ub.L}"), \
+                            grid=f"{ub.R}x{ub.L}",
+                            remat=str(self._remat_for(ub.R, ub.L))), \
                 memwatch.watermark("train/fwd_bwd"):
             for i, w in zip(idxs, weights):
                 denom = total_w if glob else w
-                fn = self._get_sliced_grad_fn(
-                    loss_fn, with_carry=carry is not None, R=ub.R
-                )
                 args = [
                     self.params, ub.grids, seq,
                     jnp.asarray(i, jnp.int32),
@@ -744,8 +913,10 @@ class JaxTrainEngine(TrainableEngine):
                 ]
                 if carry is not None:
                     args.append(carry)
-                with self._mesh_ctx(), dispatch_label("train"):
-                    carry = fn(*args)
+                carry = self._dispatch_grad(
+                    lambda remat, with_carry=carry is not None:
+                    self._get_sliced_grad_fn(loss_fn, with_carry, ub.R, remat),
+                    args, ub.R, ub.L)
         return self._apply_and_fetch(
             carry, rule, cap, extra_fetch, n_mbs=len(idxs),
             total_tokens=float(sum(ub.mbs[i].n_tokens for i in idxs)),
@@ -923,7 +1094,8 @@ class JaxTrainEngine(TrainableEngine):
             if self._router_jitter else None
         )
         with telemetry.span("train/fwd_bwd", n_mbs=n_mbs,
-                            grid=f"{mb_rows}x{mb_len}"), \
+                            grid=f"{mb_rows}x{mb_len}",
+                            remat=str(self._remat_for(mb_rows, mb_len))), \
                 memwatch.watermark("train/fwd_bwd"):
             for i, (mb, w) in enumerate(zip(mbs, weights)):
                 denom = total_w if glob else w
@@ -932,8 +1104,6 @@ class JaxTrainEngine(TrainableEngine):
                     batch = self._device_batch(mb)
                 if jitter_key is not None:
                     batch["rng"] = jax.random.fold_in(jitter_key, i)
-                grad_fn = self._get_grad_fn(loss_fn,
-                                            with_carry=carry is not None)
                 args = [
                     self.params, batch,
                     jnp.asarray(denom, jnp.float32),
@@ -942,8 +1112,10 @@ class JaxTrainEngine(TrainableEngine):
                 ]
                 if carry is not None:
                     args.append(carry)
-                with self._mesh_ctx(), dispatch_label("train"):
-                    carry = grad_fn(*args)
+                carry = self._dispatch_grad(
+                    lambda remat, with_carry=carry is not None:
+                    self._get_grad_fn(loss_fn, with_carry, remat),
+                    args, *mb.layout.shape)
         return self._apply_and_fetch(
             carry, rule, cap, None, n_mbs=n_mbs,
             total_tokens=float(sum(mb.n_tokens for mb in mbs)),

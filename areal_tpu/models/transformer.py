@@ -342,9 +342,9 @@ def apply_layer_stack(
     (parallel/pipeline.py, which passes each stage's LOCAL slice — plus a
     ``ring_ctx`` under PP∘SP so attention rings inside the stage).
 
-    ``remat``: False | True/"full" (recompute the whole layer in backward)
-    | "dots" (save matmul outputs, recompute elementwise/norm/cast —
-    near-free recompute, releases the non-GEMM residuals).
+    ``remat``: False (keep everything) | True/"full" (recompute the whole
+    layer in backward) | another entry of ``REMAT_ENTRIES`` (what the
+    backward finds kept; the train engine picks one per packed grid).
 
     ``rng``: base key for MoE router input jitter — split per layer and
     scanned alongside the params so each layer perturbs independently.
@@ -385,15 +385,89 @@ def apply_layer_stack(
     return h, (aux if aux is not None else {})
 
 
+# What a layer's backward pass finds kept from its forward pass, from the
+# least kept to the most; each entry keeps what the one before it keeps.
+#   full      — the layer's input only: the backward re-runs the layer.
+#   attention — and what the flash kernel's backward reads of its forward
+#               (its output and softmax statistics): the forward kernel is
+#               not re-run. The padded, repeated q/k/v it was handed are
+#               recomputed; the XLA reference attention keeps nothing.
+#   matmuls   — and the outputs of the layer's matmuls (q/k/v, o_proj,
+#               gate, up, the router; down's output nobody reads). Norms,
+#               rope, silu·up, casts and the kernel's glue are recomputed.
+#               The expert layer is never kept: ``ragged_dot`` is not a
+#               ``dot_general``.
+# No entry keeps everything (that is ``remat=False``): at the benchmark's
+# grids it does not fit a 16 GB chip beside the optimizer state (PERF.md
+# §6, PR 28).
+REMAT_ENTRIES = ("full", "attention", "matmuls")
+
+
+def _flash_residuals_saveable(prim, *_, **params) -> bool:
+    """Checkpoint policy: keep the outputs of the flash kernel's forward
+    rule. jax's kernel is a ``custom_vjp`` whose forward rule calls the
+    kernel once more as a ``custom_vjp_call`` that also returns the
+    softmax statistics — those three outputs are all of the rule's
+    residuals that are not its inputs."""
+    return prim.name == "custom_vjp_call" and any(
+        eqn.primitive.name == "pallas_call"
+        for eqn in params["call_jaxpr"].eqns)
+
+
+def _remat_policy(entry: str):
+    policies = jax.checkpoint_policies
+    if entry == "full":
+        return None
+    if entry == "attention":
+        return _flash_residuals_saveable
+    if entry == "matmuls":
+        return policies.save_from_both_policies(
+            _flash_residuals_saveable,
+            policies.dots_with_no_batch_dims_saveable)
+    raise ValueError(f"remat={entry!r}: not one of {REMAT_ENTRIES}")
+
+
 def _maybe_checkpoint(body, remat):
+    """``remat``: False (keep everything) | True (= "full") | an entry of
+    ``REMAT_ENTRIES``."""
     if not remat:
         return body
-    if remat == "dots":
-        return jax.checkpoint(
-            body,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        )
-    return jax.checkpoint(body)
+    return jax.checkpoint(
+        body, policy=_remat_policy("full" if remat is True else remat))
+
+
+def remat_kept_bytes(
+    cfg: TransformerConfig, tokens: int, itemsize: int,
+    flash_tokens: int = 0,
+) -> Dict[str, int]:
+    """Bytes the layer scan keeps between its forward and its backward
+    pass under each entry of ``REMAT_ENTRIES``, for ``tokens`` tokens in
+    a compute dtype of ``itemsize`` bytes. ``flash_tokens``: the tokens
+    of the flash kernel's output, rows x PADDED length (0 where attention
+    takes the XLA reference). Arithmetic on the widths in ``cfg``,
+    checked against what jax really keeps in tests/test_remat_plan.py and
+    against the chip's compiler in PERF.md §5."""
+    from areal_tpu.ops.pallas.flash_attention import LANE
+
+    full = tokens * cfg.hidden_dim * itemsize
+    # The kernel writes heads padded to the lane width, and two float32
+    # statistics a head.
+    lanes = -(-cfg.head_dim // LANE) * LANE
+    attention = flash_tokens * cfg.n_q_heads * (lanes * itemsize + 2 * 4)
+    # q/k/v, o_proj, and the MLP's matmuls into the hidden width (gate and
+    # up, or up) — nothing in the backward reads the last matmul's output;
+    # an MoE layer keeps the router's logits and its shared expert's pair.
+    widths = cfg.q_dim + 2 * cfg.kv_dim + cfg.hidden_dim
+    if cfg.moe is None:
+        widths += (1 if cfg.mlp_type == "plain" else 2) * cfg.intermediate_dim
+    else:
+        widths += (cfg.moe.num_experts
+                   + 2 * (cfg.moe.shared_intermediate_dim or 0))
+    matmuls = tokens * widths * itemsize
+    per_layer = {"full": full, "attention": full + attention,
+                 "matmuls": full + attention + matmuls}
+    return {entry: cfg.n_layers * per_layer[entry]
+            for entry in REMAT_ENTRIES}
 
 
 # ---------------- forward ----------------
@@ -408,7 +482,7 @@ def forward(
     cache_write_index: Optional[jnp.ndarray] = None,
     kv_valid: Optional[jnp.ndarray] = None,
     attn_impl: str = "auto",
-    remat: bool = False,  # rematerialize each layer in the backward pass
+    remat=False,  # False | True | an entry of REMAT_ENTRIES
     return_kv: bool = True,  # False in training: don't stack per-layer K/V
     return_aux: bool = False,  # also return MoE aux losses (layer means)
     pp_microbatches: Optional[int] = None,  # pipeline depth (None = auto)

@@ -118,6 +118,27 @@ def attention_reference(
     return out.reshape(B, T, Hq, D)
 
 
+def _wants_kernel(impl: str) -> bool:
+    return impl == "pallas" or (
+        impl == "auto" and jax.default_backend() == "tpu"
+    )
+
+
+def kernel_padded_len(
+    impl: str, length: int, sliding_window: Optional[int] = None,
+) -> Optional[int]:
+    """The padded row length at which packed self-attention over rows of
+    ``length`` tokens runs the Pallas kernel — what its output and softmax
+    statistics span; None where :func:`packed_attention` takes the XLA
+    reference."""
+    if not _wants_kernel(impl) or sliding_window is not None:
+        return None
+    from areal_tpu.ops.pallas import flash_attention as fa
+
+    blocks = fa.pick_block_sizes(length, length)
+    return None if blocks is None else fa._round_up(length, blocks[0])
+
+
 def packed_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -145,9 +166,7 @@ def packed_attention(
             "pallas flash attention does not support sliding_window yet; "
             "use impl='reference'"
         )
-    wanted_kernel = impl == "pallas" or (
-        impl == "auto" and jax.default_backend() == "tpu"
-    )
+    wanted_kernel = _wants_kernel(impl)
     if wanted_kernel and sliding_window is None:
         from areal_tpu.ops.pallas import flash_attention as fa
 

@@ -340,6 +340,12 @@ class TrainerWorker:
                 label: {"%d>%d/%d" % geom: n for geom, n in counts.items()}
                 for label, counts in flash_attention.geometry_counts().items()
             },
+            # {model: {"RxL": {entry, kept_bytes_estimate, budget_bytes,
+            # fell_back}}}: what each grid's backward pass re-runs
+            remat_plan={
+                role: m.module.remat_plan() for role, m in self.models.items()
+                if hasattr(m.module, "remat_plan")
+            },
             compile_cache=compile_watch.cache_stats(),
             native_ops="g++" if native.available() else "numpy",
         )

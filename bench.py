@@ -80,10 +80,11 @@ def main():
         # cap-2048 no-remat within noise — but at the packer's old 0.84
         # fill; the 128-grain fill sweep (backend/microbatch.py) packs the
         # same trajectories at ≥0.96, so the 4096 cap now buys ~14% more
-        # real tokens per padded FLOP. "dots" keeps matmul outputs and
-        # recomputes only elementwise/norm in backward; the chunked head
+        # real tokens per padded FLOP. Since PR 28 the engine chooses per
+        # grid what the backward re-runs ("matmuls" where it fits is what
+        # "dots" was, plus the flash kernel's residuals); the chunked head
         # drops the [R, L, V] logits grid that no longer fits at L≈1792.
-        remat="dots", logprob_chunk=512,
+        remat=True, logprob_chunk=512,
     )
     model = backend.initialize(model, FinetuneSpec(1, 512, 64))
     # HONESTY NOTE vs BENCH_r04: r4's engine silently trained fully in

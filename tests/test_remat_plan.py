@@ -1,0 +1,431 @@
+"""What the backward pass re-runs: the entries of
+``transformer.REMAT_ENTRIES`` compute the same loss and gradients, the
+engine's chooser picks by arithmetic, its estimate matches what jax keeps,
+and a program that does not fit falls back one entry."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from areal_tpu.api.data import MicroBatchSpec, SequenceSample
+from areal_tpu.api.model import FinetuneSpec
+from areal_tpu.backend import jax_train
+from areal_tpu.backend.jax_train import (
+    JaxTrainEngine,
+    OptimizerConfig,
+    choose_remat,
+)
+from areal_tpu.models import transformer
+from areal_tpu.models.config import MoEConfig, tiny_config
+from areal_tpu.parallel import mesh as pmesh
+from areal_tpu.parallel import sharding as psh
+
+ENTRIES = transformer.REMAT_ENTRIES
+DENSE = dict(vocab_size=64, n_layers=3)
+MOE = dict(vocab_size=64, n_layers=2, moe=MoEConfig(
+    num_experts=4, top_k=2, capacity_factor=None))
+GB = 1 << 30
+
+
+def _grid(rng, rows, length, vocab=64):
+    tokens = rng.randint(2, vocab, (rows, length)).astype(np.int32)
+    seg = np.ones((rows, length), np.int32)
+    seg[:, length // 2:] = 2  # two documents a row
+    pos = np.concatenate([np.arange(length // 2),
+                          np.arange(length - length // 2)])
+    return (jnp.asarray(tokens), jnp.asarray(np.tile(pos, (rows, 1))),
+            jnp.asarray(seg))
+
+
+def _loss_and_grads(cfg, params, grid, remat, mesh=None):
+    tokens, pos, seg = grid
+
+    def loss(p):
+        y, _, aux = transformer.forward(
+            p, cfg, tokens, pos, segment_ids=seg, remat=remat,
+            return_kv=False, return_aux=True)
+        out = jnp.sum(jax.nn.log_softmax(y.astype(jnp.float32)) ** 2)
+        return out + (aux["aux_total"] if aux else 0.0)
+
+    fn = jax.jit(jax.value_and_grad(loss))
+    if mesh is None:
+        return fn(params)
+    with psh.activation_sharding(mesh):
+        return fn(psh.shard_params(params, mesh, cfg))
+
+
+# ---- (a) every entry computes what "full" computes ----
+
+@pytest.mark.parametrize("entry", [False, True, *ENTRIES[1:]])
+@pytest.mark.parametrize("kind", ["dense", "moe", "moe_ep"])
+def test_entries_agree_with_full(kind, entry):
+    cfg = tiny_config(**(DENSE if kind == "dense" else MOE))
+    mesh = None
+    if kind == "moe_ep":
+        if len(jax.devices()) < 4:
+            pytest.skip("needs 4 devices")
+        mesh = pmesh.make_mesh(pmesh.ParallelSpec.parse("d2e2"))
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    grid = _grid(np.random.RandomState(1), 4, 16)
+    want_loss, want = _loss_and_grads(cfg, params, grid, "full", mesh)
+    got_loss, got = _loss_and_grads(cfg, params, grid, entry, mesh)
+    assert float(got_loss) == pytest.approx(float(want_loss), rel=1e-6)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), rtol=5e-4, atol=2e-5,
+            err_msg=jax.tree_util.keystr(path))
+
+
+def test_unknown_entry_is_refused():
+    cfg = tiny_config(**DENSE)
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    with pytest.raises(ValueError, match="dots"):
+        _loss_and_grads(cfg, params,
+                        _grid(np.random.RandomState(0), 2, 16), "dots")
+
+
+@pytest.mark.parametrize("entry,calls", [("full", 4), ("attention", 3),
+                                         ("matmuls", 3), (False, 3)])
+@pytest.mark.parametrize("on_mesh", [False, True])
+def test_flash_forward_is_kept_not_rerun(entry, calls, on_mesh):
+    """The gradient's jaxpr holds the forward kernel twice under "full"
+    (forward, recomputation) beside dKV and dQ, and once where its
+    residuals are kept — also through the kernel's shard_map on a mesh.
+    Traced only: the kernel does not run on the CPU."""
+    cfg = tiny_config(vocab_size=64, n_layers=2, hidden_dim=128)
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    tokens, pos, seg = _grid(np.random.RandomState(0), 4, 256)
+
+    def loss(p):
+        y, _ = transformer.forward(p, cfg, tokens, pos, segment_ids=seg,
+                                   attn_impl="pallas", remat=entry,
+                                   return_kv=False)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    if on_mesh:
+        if len(jax.devices()) < 4:
+            pytest.skip("needs 4 devices")
+        mesh = pmesh.make_mesh(pmesh.ParallelSpec.parse("d2f2"))
+        with psh.activation_sharding(mesh):
+            jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
+    else:
+        jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
+    assert str(jaxpr).count("pallas_call[") == calls
+
+
+def test_expert_layer_is_never_kept():
+    """``ragged_dot`` is not a ``dot_general``: under every entry the
+    gradient holds the same grouped GEMMs (the recomputed forward's
+    among them), so the benchmark's count of expert passes stays true."""
+    cfg = tiny_config(**MOE)
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    tokens, pos, seg = _grid(np.random.RandomState(0), 2, 16)
+    counts = {}
+    for entry in ENTRIES:
+        def loss(p):
+            y, _ = transformer.forward(p, cfg, tokens, pos, segment_ids=seg,
+                                       remat=entry, return_kv=False)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        counts[entry] = str(jax.make_jaxpr(jax.grad(loss))(params)).count(
+            "ragged_dot")
+    assert counts["full"] > 0
+    assert counts["attention"] == counts["matmuls"] == counts["full"]
+
+
+# ---- (c) the estimate against what jax really keeps ----
+
+def _saved_bytes(cfg, rows, length, entry, attn_impl):
+    """Bytes of the residuals the layer scan stacks (leading dim
+    n_layers), as ``jax.ad_checkpoint.print_saved_residuals`` lists them."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    shapes = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.bfloat16), shapes)
+    tok = jax.ShapeDtypeStruct((rows, length), jnp.int32)
+
+    def loss(p, tokens, pos, seg):
+        y, _ = transformer.forward(p, cfg, tokens, pos, segment_ids=seg,
+                                   attn_impl=attn_impl, remat=entry,
+                                   return_kv=False, return_hidden=True)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    return sum(
+        int(np.prod(aval.shape)) * aval.dtype.itemsize
+        for aval, src in saved_residuals(loss, params, tok, tok, tok)
+        if "output of scan" in src and aval.shape[0] == cfg.n_layers
+        and aval.ndim > 2)
+
+
+QWEN_WIDTHS = dict(vocab_size=512, n_layers=3, hidden_dim=896, n_q_heads=14,
+                   n_kv_heads=2, head_dim=64, intermediate_dim=4864)
+MOE_WIDTHS = dict(
+    vocab_size=512, n_layers=3, hidden_dim=256, n_q_heads=2, n_kv_heads=2,
+    head_dim=128, intermediate_dim=128, moe=MoEConfig(
+        num_experts=8, top_k=2, capacity_factor=None,
+        routed_intermediate_dim=128))
+
+
+@pytest.mark.parametrize("entry", ENTRIES)
+@pytest.mark.parametrize("widths,attn_impl", [
+    (QWEN_WIDTHS, "pallas"), (QWEN_WIDTHS, "reference"),
+    (MOE_WIDTHS, "pallas")], ids=["qwen-flash", "qwen-xla", "moe-flash"])
+def test_estimate_matches_what_jax_keeps(widths, attn_impl, entry):
+    """Within 5 %: the arithmetic names every kept array (per token and
+    layer in the compute dtype; the kernel's output at the PADDED length,
+    heads of 64 in 128 lanes, two float32 statistics a head)."""
+    cfg = dataclasses.replace(tiny_config(), **widths)
+    rows, length = 2, 640  # the kernel pads 640 to 768 (tile 384)
+    from areal_tpu.ops.attention import kernel_padded_len
+
+    padded = kernel_padded_len(attn_impl, length) or 0
+    assert padded == (768 if attn_impl == "pallas" else 0)
+    est = transformer.remat_kept_bytes(cfg, rows * length, 2,
+                                       flash_tokens=rows * padded)
+    got = _saved_bytes(cfg, rows, length, entry, attn_impl)
+    assert got == pytest.approx(est[entry], rel=0.05)
+    assert est["full"] <= est["attention"] <= est["matmuls"]
+    if attn_impl == "reference":  # the XLA attention keeps nothing
+        assert est["attention"] == est["full"]
+
+
+def test_estimate_at_the_benchmarks_widths():
+    """The issue's arithmetic: per token and layer ~3.7 KB under
+    "attention" and ~27 KB of matmul outputs at Qwen2.5-0.5B's widths."""
+    cfg = dataclasses.replace(tiny_config(), **{**QWEN_WIDTHS, "n_layers": 1})
+    est = transformer.remat_kept_bytes(cfg, 1, 2, flash_tokens=1)
+    assert est["full"] == 896 * 2
+    assert est["attention"] - est["full"] == 14 * (128 * 2 + 8)
+    assert est["matmuls"] - est["attention"] == 2 * (
+        896 + 128 + 128 + 896 + 4864 + 4864)
+
+
+# ---- (b) the chooser ----
+
+def test_choose_remat_takes_the_lightest_that_fits():
+    kept = {"full": 10, "attention": 20, "matmuls": 100}
+    assert choose_remat(kept, 100) == "matmuls"
+    assert choose_remat(kept, 99) == "attention"
+    assert choose_remat(kept, 20) == "attention"
+    assert choose_remat(kept, 19) == "full"  # a byte short
+    assert choose_remat(kept, -5) == "full"  # nothing fits: the least
+
+
+def _engine(cfg=None, remat=True, mesh=None, limit=16 * GB, **kw):
+    cfg = cfg or dataclasses.replace(tiny_config(), **QWEN_WIDTHS)
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    eng = JaxTrainEngine(
+        cfg, params, OptimizerConfig(lr=1e-3), FinetuneSpec(1, 8, 4),
+        mesh=mesh, remat=remat, attn_impl="pallas", **kw)
+    eng._device_bytes_limit = lambda: limit
+    return eng
+
+
+def test_plan_is_monotone_in_tokens_and_budget():
+    eng = _engine()
+    rank = {e: i for i, e in enumerate(ENTRIES)}
+    by_tokens = [rank[eng._remat_for(1, n)]
+                 for n in (1024, 8192, 65536, 262144, 1 << 21)]
+    assert by_tokens == sorted(by_tokens, reverse=True)
+    assert by_tokens[0] == rank["matmuls"] and by_tokens[-1] == rank["full"]
+    by_limit = []
+    for limit in (1 * GB, 2 * GB, 4 * GB, 8 * GB, 64 * GB):
+        e = _engine(limit=limit)
+        by_limit.append(rank[e._remat_for(1, 65536)])
+    assert by_limit == sorted(by_limit)
+    assert by_limit[0] == rank["full"] and by_limit[-1] == rank["matmuls"]
+
+
+def test_plan_records_entry_bytes_and_budget():
+    eng = _engine()
+    entry = eng._remat_for(1, 4096)
+    plan = eng.remat_plan()
+    assert set(plan) == {"1x4096"}
+    rec = plan["1x4096"]
+    assert rec["entry"] == entry and rec["fell_back"] is False
+    assert rec["kept_bytes_estimate"] == eng._remat_kept_bytes(1, 4096)[entry]
+    assert rec["kept_bytes_estimate"] <= rec["budget_bytes"]
+    # the budget counts what the engine holds: a fuller chip keeps less
+    assert (_engine(limit=64 * GB)._remat_budget_bytes(1, 4096)
+            > rec["budget_bytes"])
+
+
+def test_full_when_the_device_names_no_limit():
+    eng = _engine(limit=None)  # the CPU
+    assert eng._remat_for(1, 1024) == "full"
+    assert eng.remat_plan()["1x1024"]["budget_bytes"] == 0
+
+
+def test_tokens_are_counted_per_chip_under_a_mesh():
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 devices")
+    cfg = dataclasses.replace(tiny_config(), **QWEN_WIDTHS)
+    one = _engine(cfg)
+    mesh = pmesh.make_mesh(pmesh.ParallelSpec.parse("d4"))
+    four = _engine(cfg, mesh=mesh)
+    assert four.rows_multiple == 4
+    assert (four._remat_kept_bytes(4, 2048)
+            == one._remat_kept_bytes(1, 2048))
+    assert (four._remat_kept_bytes(8, 2048)
+            == one._remat_kept_bytes(2, 2048))
+
+
+def test_gradient_checkpointing_false_bypasses_the_chooser():
+    eng = _engine(remat=False)
+    assert eng._remat_for(1, 1 << 21) is False
+    assert eng.remat_plan() == {}
+    assert eng._remat_fall_back(1, 1 << 21) is False
+
+
+def test_pipeline_stages_keep_full():
+    if len(jax.devices()) < 2:
+        pytest.skip("needs 2 devices")
+    cfg = tiny_config(vocab_size=64, n_layers=4)
+    mesh = pmesh.make_mesh(pmesh.ParallelSpec.parse("p2"))
+    eng = _engine(cfg, mesh=mesh, limit=64 * GB)
+    from areal_tpu.parallel import pipeline as ppl
+
+    assert ppl.pp_engagement(mesh, cfg, 4, 128)[0] == 1.0
+    assert eng._remat_for(4, 128) == "full"
+    # the same grid with no pipeline axis keeps all it can
+    assert _engine(cfg, limit=64 * GB)._remat_for(4, 128) == "matmuls"
+
+
+def test_backend_args_pass_gradient_checkpointing_through():
+    from areal_tpu.api.cli_args import ModelTrainEvalConfig
+    from areal_tpu.experiments.common import backend_args_for
+
+    on = backend_args_for(ModelTrainEvalConfig(path="x"), None, 10)
+    off = backend_args_for(
+        ModelTrainEvalConfig(path="x", gradient_checkpointing=False),
+        None, 10)
+    assert on["remat"] is True and off["remat"] is False
+
+
+# ---- (d) the guard, and the engine's own grad call ----
+
+def _sample(rng, n=6, vocab=64):
+    lens = rng.randint(6, 14, n)
+    total = int(lens.sum())
+    return SequenceSample.from_default(
+        ids=[f"s{i}" for i in range(n)],
+        data={"packed_input_ids": rng.randint(2, vocab, total).astype(
+                  np.int32),
+              "loss_mask": np.ones(total, np.float32)},
+        seqlens=lens.tolist())
+
+
+def _sq_loss(logits, batch):
+    w = (batch["segment_ids"] > 0).astype(jnp.float32)
+    lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    return jnp.sum(jnp.sum(lp * lp, axis=-1) * w), {"n": jnp.sum(w)}
+
+
+def _train_engine(remat, moe=False):
+    cfg = tiny_config(**(MOE if moe else DENSE))
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    return JaxTrainEngine(
+        cfg, params, OptimizerConfig(type="sgd", lr=1e-2),
+        FinetuneSpec(1, 8, 4), compute_dtype="float32", length_bucket=16, rows_bucket=2,
+        seqs_bucket=4, remat=remat)
+
+
+@pytest.mark.parametrize("moe", [False, True], ids=["dense", "moe"])
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_train_batch_under_every_entry_matches_no_remat(entry, moe,
+                                                        monkeypatch):
+    """One optimizer step through the engine's own grad programs: the
+    same loss, gradient norm and updated weights whatever is kept."""
+    sample = _sample(np.random.RandomState(3))
+    spec = MicroBatchSpec(max_tokens_per_mb=64)
+    ref = _train_engine(False, moe)
+    want = ref.train_batch(sample, spec, _sq_loss, lambda mb: mb.n_tokens)
+    monkeypatch.setattr(jax_train, "choose_remat", lambda kept, b: entry)
+    eng = _train_engine(True, moe)
+    got = eng.train_batch(sample, spec, _sq_loss, lambda mb: mb.n_tokens)
+    assert {p["entry"] for p in eng.remat_plan().values()} == {entry}
+    assert got["loss"] == pytest.approx(want["loss"], rel=1e-5)
+    assert got["grad_norm"] == pytest.approx(want["grad_norm"], rel=1e-4)
+    for g, w in zip(jax.tree.leaves(eng.params), jax.tree.leaves(ref.params)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-6)
+
+
+def test_a_program_that_does_not_fit_falls_back_one_entry(monkeypatch):
+    """A compile that raises for memory drops the grid one entry towards
+    "full", says so in the plan, and traces again; the step completes."""
+    monkeypatch.setattr(jax_train, "choose_remat", lambda kept, b: "matmuls")
+    eng = _train_engine(True)
+    tried = []
+    real = eng._get_grad_fn
+
+    def get_fn(loss_fn, with_carry, remat=False):
+        fn = real(loss_fn, with_carry, remat)
+
+        def call(*args):
+            tried.append(remat)
+            if remat == "matmuls":
+                raise jax.errors.JaxRuntimeError(
+                    "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. "
+                    "Ran out of memory in memory space hbm.")
+            return fn(*args)
+
+        return call
+
+    monkeypatch.setattr(eng, "_get_grad_fn", get_fn)
+    stats = eng.train_batch(_sample(np.random.RandomState(3)),
+                            MicroBatchSpec(max_tokens_per_mb=64), _sq_loss,
+                            lambda mb: mb.n_tokens)
+    assert np.isfinite(stats["loss"])
+    assert tried[:2] == ["matmuls", "attention"]
+    assert "matmuls" not in tried[2:]  # the grid stays where it fell
+    (rec,) = eng.remat_plan().values()
+    assert rec["entry"] == "attention" and rec["fell_back"] is True
+    assert rec["kept_bytes_estimate"] <= eng._remat_kept_bytes(
+        *map(int, next(iter(eng.remat_plan())).split("x")))["matmuls"]
+
+
+def test_other_errors_and_the_last_entry_are_not_swallowed(monkeypatch):
+    eng = _train_engine(True)  # the CPU names no limit: "full"
+    sample = _sample(np.random.RandomState(3))
+    spec = MicroBatchSpec(max_tokens_per_mb=64)
+
+    def boom(msg):
+        def get_fn(loss_fn, with_carry, remat=False):
+            def call(*args):
+                raise jax.errors.JaxRuntimeError(msg)
+            return call
+        return get_fn
+
+    monkeypatch.setattr(eng, "_get_grad_fn", boom("RESOURCE_EXHAUSTED: hbm"))
+    with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE"):
+        eng.train_batch(sample, spec, _sq_loss, lambda mb: mb.n_tokens)
+    assert all(not p["fell_back"] for p in eng.remat_plan().values())
+    monkeypatch.setattr(eng, "_get_grad_fn", boom("INTERNAL: other"))
+    with pytest.raises(jax.errors.JaxRuntimeError, match="INTERNAL"):
+        eng.train_batch(sample, spec, _sq_loss, lambda mb: mb.n_tokens)
+
+
+def test_fwd_bwd_span_names_the_entry():
+    from areal_tpu.api.train_config import TelemetryConfig
+    from areal_tpu.base import telemetry
+
+    tel = telemetry.configure("remat", "t", "trainer",
+                              cfg=TelemetryConfig(enabled=True), push=False)
+    try:
+        for remat, want in ((True, "full"), (False, "False")):
+            _train_engine(remat).train_batch(
+                _sample(np.random.RandomState(3)),
+                MicroBatchSpec(max_tokens_per_mb=64), _sq_loss,
+                lambda mb: mb.n_tokens)
+            spans = [s for s in tel.registry.snapshot(reset=True)["spans"]
+                     if s["name"] == "train/fwd_bwd"]
+            assert spans and all(s["attrs"]["remat"] == want for s in spans)
+    finally:
+        telemetry.shutdown()

@@ -117,6 +117,27 @@ def _compile_all():
             record(f"mesh-{mesh_spec}",
                    jax.jit(loss_and_grad).lower(params, tok, tok, tok)
                    .compile())
+
+    # What the backward pass re-runs (transformer.REMAT_ENTRIES): the
+    # layer scan's body appears once in the program text, so the kernels
+    # it holds are the kernels of one layer.
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.bfloat16, sharding=chip),
+        shapes)
+    tok = jax.ShapeDtypeStruct((2, 512), jnp.int32, sharding=chip)
+    for entry in transformer.REMAT_ENTRIES:
+        def loss_and_grad(p, tokens, pos, seg, entry=entry):
+            def loss(p):
+                y, _ = transformer.forward(
+                    p, cfg, tokens, pos, segment_ids=seg,
+                    attn_impl="pallas", remat=entry, return_kv=False,
+                )
+                return jnp.sum(y.astype(jnp.float32) ** 2)
+
+            return jax.value_and_grad(loss)(p)
+
+        record(f"remat-{entry}",
+               jax.jit(loss_and_grad).lower(params, tok, tok, tok).compile())
     return out
 
 
@@ -157,6 +178,16 @@ def test_model_with_flash_kernel_compiles_on_a_v5e_mesh(compiled, spec):
     chip the lowering raises unless the call sits in a shard_map manual
     over every mesh axis (ops/pallas flash_attention_on_mesh)."""
     assert compiled[f"mesh-{spec}"]["custom_calls"] >= 3
+
+
+@pytest.mark.parametrize("entry,calls", [("full", 4), ("attention", 3),
+                                         ("matmuls", 3)])
+def test_kept_flash_residuals_spare_the_forward_kernel(compiled, entry,
+                                                       calls):
+    """A layer of the compiled grad program holds the forward kernel
+    twice under "full" (forward and recomputation, beside dKV and dQ) and
+    once where the kernel's residuals are kept."""
+    assert compiled[f"remat-{entry}"]["custom_calls"] == calls
 
 
 if __name__ == "__main__":
