@@ -428,7 +428,7 @@ class PPOActorInterface(ModelInterface):
         mean_kl = 0.0
         adv_scale = 0.0
 
-        if not hp.group_adv_norm and hasattr(engine, "upload_uniform"):
+        if not hp.group_adv_norm:
             # Fast path: ONE h2d upload of the whole batch, GAE + advantage
             # whitening fused on device (make_advantage_prep), micro-batches
             # sliced on device by index — per step this is n_mb dispatches,

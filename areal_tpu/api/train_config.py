@@ -34,7 +34,7 @@ class OptimizerConfig:
     # moments halve optimizer HBM (the update math still runs in f32 per
     # step), but a bf16 default would silently lossy-cast f32 optimizer
     # states on resume — so BOTH default to exact f32; HBM-constrained
-    # configs (bench.py on a 16G chip) opt into bf16 explicitly.
+    # configs opt into bf16 explicitly.
     mu_dtype: Optional[str] = "float32"
     nu_dtype: Optional[str] = "float32"
 

@@ -333,8 +333,7 @@ class JaxTrainEngine(TrainableEngine):
             # keeps f32 masters for the same reason). Compute still runs
             # in compute_dtype via _cast. (No buffer donation here: the
             # caller's tree must stay valid — callers that need the
-            # transient peak gone should drop their reference, as
-            # bench.py does.)
+            # transient peak gone should drop their reference.)
             params = jax.tree.map(
                 lambda x: x.astype(jnp.float32)
                 if jnp.issubdtype(x.dtype, jnp.floating) else x,
@@ -527,42 +526,6 @@ class JaxTrainEngine(TrainableEngine):
 
         return self._value_and_grad(lf, params)
 
-    def _get_grad_fn(self, loss_fn: LossFn, with_carry: bool,
-                     remat=False) -> Callable:
-        """Fused grad + accumulate step, one dispatch per micro-batch.
-
-        ``with_carry``: the (loss, stats, grads) accumulators from the
-        previous micro-batch ride through the jit (donated) and the adds
-        happen on device instead of as eager tree-map adds between
-        dispatches.
-
-        ``scale`` multiplies this micro-batch's loss/grads ("mb" normalize
-        scope passes 1/n_mbs); ``aux_scale`` multiplies the MoE balancing
-        loss so its total contribution over the whole batch equals one
-        aux_total regardless of the micro-batch count.
-
-        ``remat``: what the backward pass re-runs, chosen per packed grid
-        by :meth:`_remat_for`; part of the key, so a grid that changes its
-        entry is traced again.
-
-        Keyed by the function OBJECT (keeps it alive): an id() key could
-        be reused by a new closure after GC and silently run stale code.
-        """
-        key = (loss_fn, with_carry, remat)
-        if key not in self._grad_fns:
-
-            def train_grad(params, batch, denom, scale, aux_scale,
-                           carry=None):
-                (loss, stats), grads = self._loss_and_grads(
-                    loss_fn, remat, params, batch, denom, aux_scale)
-                return _accumulate(loss, stats, grads, scale, carry)
-
-            donate = (5,) if with_carry else ()
-            self._grad_fns[key] = compile_watch.watched_jit(
-                "train/grad", jax.jit(train_grad, donate_argnums=donate)
-            )
-        return self._grad_fns[key]
-
     def _get_apply_fn(self, skip_rule) -> Callable:
         """Optimizer update with donated buffers and an optional on-device
         early-stop gate.
@@ -611,8 +574,8 @@ class JaxTrainEngine(TrainableEngine):
         # grads: no output aliases the grad buffers (XLA warns they are
         # "not usable" as outputs), but donating them still lets the
         # optimizer's f32 transients reuse those 2 bytes/param in place —
-        # measured on the 16G bench chip, withdrawing the grads donation
-        # OOMs the apply step.
+        # measured on a 16 GB v5e with the 0.5B model, withdrawing the
+        # grads donation OOMs the apply step.
         self._grad_fns[key] = compile_watch.watched_jit(
             "train/apply", jax.jit(train_apply, donate_argnums=(0, 1, 2))
         )
@@ -729,16 +692,21 @@ class JaxTrainEngine(TrainableEngine):
         return {f"{R}x{L}": dict(plan)
                 for (R, L), plan in self._remat_plan.items()}
 
-    def _dispatch_grad(self, get_fn: Callable, args: list, R: int, L: int):
-        """Run one grad program of the grid [R, L]. ``get_fn(remat)``
-        gives the jitted program. Compiling it can fail for memory — the
-        estimate behind the grid's entry is arithmetic; then the grid
-        falls back one entry and is traced again (only ever in warm-up:
-        a grid compiles once)."""
+    def _dispatch_grad(self, loss_fn: LossFn, args: list, carry,
+                       R: int, L: int):
+        """Run one grad program of the grid [R, L] on ``args`` and the
+        carry of the micro-batches before it (None for the first).
+        Compiling it can fail for memory — the estimate behind the grid's
+        entry is arithmetic; then the grid falls back one entry and is
+        traced again (only ever in warm-up: a grid compiles once)."""
+        if carry is not None:
+            args = args + [carry]
         while True:
             try:
                 with self._mesh_ctx(), dispatch_label("train"):
-                    return get_fn(self._remat_for(R, L))(*args)
+                    return self._get_sliced_grad_fn(
+                        loss_fn, carry is not None, R,
+                        self._remat_for(R, L))(*args)
             except jax.errors.JaxRuntimeError as e:
                 if ("RESOURCE_EXHAUSTED" not in str(e)
                         or not self._remat_fall_back(R, L)):
@@ -831,11 +799,28 @@ class JaxTrainEngine(TrainableEngine):
     def _get_sliced_grad_fn(
         self, loss_fn: LossFn, with_carry: bool, R: int, remat=False,
     ) -> Callable:
-        """Like _get_grad_fn but takes the FULL uploaded batch and a traced
-        micro-batch index; slices its rows/seq-entries on device. ``R`` (rows
-        per micro-batch) is part of the cache key: two packings can share the
-        total grid shape while slicing differently."""
-        key = (loss_fn, with_carry, "sliced", R, remat)
+        """The grad program: fused grad + accumulate of ONE micro-batch, one
+        dispatch each. It takes the FULL uploaded batch and a traced
+        micro-batch index and slices its rows/seq-entries on device.
+
+        ``with_carry``: the (loss, stats, grads) accumulators from the
+        previous micro-batch ride through the jit (donated) and the adds
+        happen on device instead of as eager tree-map adds between
+        dispatches.
+
+        ``scale`` multiplies this micro-batch's loss/grads ("mb" normalize
+        scope passes 1/n_mbs); ``aux_scale`` multiplies the MoE balancing
+        loss so its total contribution over the whole batch equals one
+        aux_total regardless of the micro-batch count.
+
+        ``R`` (rows per micro-batch) is part of the cache key: two packings
+        can share the total grid shape while slicing differently. So is
+        ``remat``: what the backward pass re-runs, chosen per packed grid
+        by :meth:`_remat_for` — a grid that changes its entry is traced
+        again. Keyed by the function OBJECT (keeps it alive): an id() key
+        could be reused by a new closure after GC and silently run stale
+        code."""
+        key = (loss_fn, with_carry, R, remat)
         if key not in self._grad_fns:
 
             def train_grad_sliced(params, grids, seq, mb_idx, denom, scale,
@@ -889,8 +874,8 @@ class JaxTrainEngine(TrainableEngine):
         if self._router_jitter:
             # Stacked per-mb jitter keys ride the seq dict: the sliced grad
             # fn's dynamic_index_in_dim over axis 0 hands each micro-batch
-            # its own [2] key (same derivation as train_batch: one base key
-            # per optimizer step). ub.seq itself stays untouched so the
+            # its own [2] key (one base key per optimizer step, the only
+            # derivation). ub.seq itself stays untouched so the
             # run_prep jit (keyed on the seq structure) never retraces.
             seq = dict(
                 ub.seq,
@@ -911,12 +896,8 @@ class JaxTrainEngine(TrainableEngine):
                     jnp.asarray(scale, jnp.float32),
                     jnp.asarray(aux_scale, jnp.float32),
                 ]
-                if carry is not None:
-                    args.append(carry)
-                carry = self._dispatch_grad(
-                    lambda remat, with_carry=carry is not None:
-                    self._get_sliced_grad_fn(loss_fn, with_carry, ub.R, remat),
-                    args, ub.R, ub.L)
+                carry = self._dispatch_grad(loss_fn, args, carry,
+                                            ub.R, ub.L)
         return self._apply_and_fetch(
             carry, rule, cap, extra_fetch, n_mbs=len(idxs),
             total_tokens=float(sum(ub.mbs[i].n_tokens for i in idxs)),
@@ -1022,18 +1003,6 @@ class JaxTrainEngine(TrainableEngine):
                 telemetry.set_gauge(gauge, out[stat])
         return out
 
-    def _device_batch(self, mb: mbu.MicroBatch) -> Dict[str, jnp.ndarray]:
-        d: Dict[str, jnp.ndarray] = {}
-        for k, v in mb.grids.items():
-            d[k] = jnp.asarray(v)
-        for k, v in mb.scalars.items():
-            d[k] = jnp.asarray(v)
-        d["seq_rows"] = jnp.asarray(mb.seq_rows)
-        d["seq_first_cols"] = jnp.asarray(mb.seq_first_cols)
-        d["seq_last_cols"] = jnp.asarray(mb.seq_last_cols)
-        d["seq_mask"] = jnp.asarray(mb.seq_mask)
-        return d
-
     # -------------- TrainableEngine API --------------
 
     def train_batch(
@@ -1046,8 +1015,9 @@ class JaxTrainEngine(TrainableEngine):
         version_steps: int = 0,
         skip_update_rule: Optional[Tuple[str, str, float]] = None,
     ) -> Dict[str, float]:
-        """Grad-accumulate over micro-batches, single optimizer step — one
-        jitted dispatch (scan over stacked micro-batches, donated buffers).
+        """One optimizer step over the whole of ``input_``: pack it into
+        micro-batches of one shape, upload them once, accumulate their
+        gradients on device, apply — ``train_uniform(upload_uniform(...))``.
 
         ``loss_fn`` must return the SUM of per-token losses; it is divided by
         the total ``loss_weight_fn`` mass of the whole batch ("global" scope,
@@ -1057,69 +1027,10 @@ class JaxTrainEngine(TrainableEngine):
         update when stats[num]/stats[den] > cap (the reference's PPO
         early-stop checks the importance ratio BEFORE stepping). The
         returned stats carry ``update_applied`` ∈ {0.0, 1.0}."""
-        assert self.tx is not None, "engine built without an optimizer"
-        with telemetry.span("train/split_pack"):
-            mbs = mbu.split_into_microbatches(
-                input_, mb_spec, length_bucket=self.length_bucket,
-                rows_bucket=self.rows_bucket, seqs_bucket=self.seqs_bucket,
-                fill_bucket=self.fill_bucket, rows_multiple=self.rows_multiple,
-            )
-            telemetry.set_gauge("train/pack_fill", mbu.pack_fill(mbs))
-        mb_rows, mb_len = mbs[0].layout.shape
-        pp_on, ring_on = ppl.pp_engagement(self.mesh, self.cfg, mb_rows,
-                                           mb_len)
-        telemetry.set_gauge("train/pp_engaged", pp_on)
-        telemetry.set_gauge("train/ring_engaged", ring_on)
-        telemetry.set_gauge("train/moe_ep_engaged",
-                            self._ep_engagement(mb_rows, mb_len, pp_on))
-        weights = [float(loss_weight_fn(mb)) for mb in mbs]
-        total_w = sum(weights)
-        rule = None
-        cap = 0.0
-        if skip_update_rule is not None and skip_update_rule[2]:
-            rule = (skip_update_rule[0], skip_update_rule[1])
-            cap = float(skip_update_rule[2])
-
-        n_mbs = len(mbs)
-        glob = token_normalize_scope == "global"
-        scale = 1.0 if glob else 1.0 / n_mbs
-        aux_scale = (1.0 / n_mbs) if glob else 1.0
-        carry = None
-        # Router jitter: one deterministic base key per optimizer step,
-        # folded with the micro-batch index so every mb perturbs the router
-        # input independently (moe_mlp). batch["rng"] is only present when
-        # the model config enables jitter — key presence is trace-static.
-        jitter_key = (
-            jax.random.PRNGKey(self.opt_step_count)
-            if self._router_jitter else None
-        )
-        with telemetry.span("train/fwd_bwd", n_mbs=n_mbs,
-                            grid=f"{mb_rows}x{mb_len}",
-                            remat=str(self._remat_for(mb_rows, mb_len))), \
-                memwatch.watermark("train/fwd_bwd"):
-            for i, (mb, w) in enumerate(zip(mbs, weights)):
-                denom = total_w if glob else w
-                with telemetry.span("train/upload",
-                                    **_pack_attrs([mb], n_mbs)):
-                    batch = self._device_batch(mb)
-                if jitter_key is not None:
-                    batch["rng"] = jax.random.fold_in(jitter_key, i)
-                args = [
-                    self.params, batch,
-                    jnp.asarray(denom, jnp.float32),
-                    jnp.asarray(scale, jnp.float32),
-                    jnp.asarray(aux_scale, jnp.float32),
-                ]
-                if carry is not None:
-                    args.append(carry)
-                carry = self._dispatch_grad(
-                    lambda remat, with_carry=carry is not None:
-                    self._get_grad_fn(loss_fn, with_carry, remat),
-                    args, *mb.layout.shape)
-        return self._apply_and_fetch(
-            carry, rule, cap, None, n_mbs=n_mbs,
-            total_tokens=float(sum(mb.n_tokens for mb in mbs)),
-            total_w=total_w,
+        return self.train_uniform(
+            self.upload_uniform(input_, mb_spec), loss_fn, loss_weight_fn,
+            token_normalize_scope=token_normalize_scope,
+            skip_update_rule=skip_update_rule,
         )
 
     # -------------- train-state checkpointing --------------
@@ -1258,7 +1169,12 @@ class JaxTrainEngine(TrainableEngine):
         for mb in mbs:
             with telemetry.span("infer/upload",
                                 **_pack_attrs([mb], len(mbs))):
-                db = self._device_batch(mb)
+                db = {k: jnp.asarray(v) for k, v in (
+                    *mb.grids.items(), *mb.scalars.items(),
+                    ("seq_rows", mb.seq_rows),
+                    ("seq_first_cols", mb.seq_first_cols),
+                    ("seq_last_cols", mb.seq_last_cols),
+                    ("seq_mask", mb.seq_mask))}
             with telemetry.span("infer/dispatch"), self._mesh_ctx(), \
                     dispatch_label("forward"):
                 out = fn(self.params, db)

@@ -103,11 +103,11 @@ def split_into_microbatches(
     ``fill_bucket`` (default ``min(length_bucket, 128)``) is the candidate
     row-length granularity — decoupled from ``length_bucket`` in round 8
     because stepping candidates by a coarse 512 bucket was itself a fill
-    ceiling: at the bench distribution (~700-1000-token trajectories) the
-    only coarse candidates were 1536/2048-token rows at ≤0.85 fill, while
-    the 128-grain sweep finds rows ≥0.92 full under a cap-4096 budget. 128
-    is the floor the Pallas flash kernel's lane width imposes on row
-    lengths. The rows-per-micro-batch choice is swept as well (the old
+    ceiling: at ~700-1000-token trajectories (the distribution of
+    tests/test_packing_fill.py) the only coarse candidates were
+    1536/2048-token rows at ≤0.85 fill, while the 128-grain sweep finds
+    rows ≥0.92 full under a cap-4096 budget. 128 is the floor the Pallas
+    flash kernel's lane width imposes on row lengths. The rows-per-micro-batch choice is swept as well (the old
     fixed ``cap // L`` wasted up to R-1 padding rows in the last
     micro-batch). Finer candidates mean the compiled [R, L] shape tracks
     the length distribution more closely — more distinct shapes across
@@ -191,8 +191,7 @@ def split_into_microbatches(
 def pack_fill(mbs: List[MicroBatch]) -> float:
     """Achieved packing fill of a micro-batch split: real tokens over
     allocated [R, L] cells — the padding factor the reported MFU divides
-    by. Exported as the ``train/pack_fill`` telemetry gauge and in
-    bench.py output (ISSUE 8 / ROADMAP item 1)."""
+    by. Exported as the ``train/pack_fill`` telemetry gauge."""
     ntok = sum(mb.n_tokens for mb in mbs)
     ncells = sum(int(np.prod(mb.layout.shape)) for mb in mbs)
     return (ntok / ncells) if ncells else 0.0

@@ -120,8 +120,8 @@ def cache_stats() -> Optional[Dict[str, Any]]:
 
 def enable_compilation_cache() -> None:
     """Arm JAX's persistent compilation cache for this process (launcher
-    children, bench.py and chip_smoke.py's phases all call this one
-    helper) and start counting its hits and misses. jax reads
+    children, the benchmark's drivers and chip_smoke.py's phases all call
+    this one helper) and start counting its hits and misses. jax reads
     ``JAX_COMPILATION_CACHE_DIR`` itself, so a directory is set in code
     only when the variable is not. Imports jax and sets config — never
     creates an array or asks for devices."""

@@ -127,12 +127,11 @@ def transformer_flops_per_token(
 
 def train_flops_6nt(n_params: float, n_tokens: float) -> float:
     """The classic ``6·N·T`` train-FLOPs estimate (fwd 2·N·T + bwd 4·N·T)
-    over parameter count alone — the roofline bench.py reports its MFU
-    against. Coarser than :func:`model_flops_per_token` (no attention
-    quadratic term, no remat factor) but geometry-free, which is what a
-    cross-round trajectory number wants; both live HERE so bench.py and
-    the live trainer gauges share one accounting (no duplicated
-    formulas to drift apart)."""
+    over parameter count alone. Coarser than
+    :func:`model_flops_per_token` (no attention quadratic term, no remat
+    factor) but geometry-free, which is what a number compared across
+    packings wants; both live HERE so every caller shares one accounting
+    (no duplicated formulas to drift apart)."""
     return 6.0 * float(n_params) * float(n_tokens)
 
 

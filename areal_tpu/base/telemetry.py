@@ -247,8 +247,8 @@ _ANNOTATION_CLS: Any = None
 # program that runs is unchanged. Call sites use the literal names;
 # tests/test_step_timeline.py holds them to this list.
 DEVICE_PROGRAMS = (
-    "infer_forward", "train_grad", "train_grad_sliced", "train_apply",
-    "adv_prep", "opt_init",
+    "infer_forward", "train_grad_sliced", "train_apply", "adv_prep",
+    "opt_init",
 )
 DEVICE_SCOPES = (
     # models/transformer.py, once a block unless said

@@ -138,13 +138,11 @@ def make_mixed_jsonl(path: str, n_math: int = 6, n_code: int = 2,
 
 
 def bench_trajectory_dist(seed: int = 0, n_seq: int = 32):
-    """The bench.py PPO trajectory length distribution — ~250-token prompts
-    + ~640-token generations — as ``(rng, plens, glens)``. The SINGLE
-    source of the recipe: bench.py continues drawing tokens/logprobs from
-    the returned rng (bit-identical to the historical inline code), while
-    ``tools/perf_probe.py packfill`` and tests/test_packing_fill.py build
-    packing-only samples from it. Change it here and every fill number,
-    probe, and the ≥0.92 gate move together."""
+    """A PPO trajectory length distribution — ~250-token prompts +
+    ~640-token generations — as ``(rng, plens, glens)``:
+    tests/test_packing_fill.py builds packing-only samples from it.
+    Change it here and the fill numbers and the ≥0.92 gate move
+    together."""
     import numpy as np
 
     rng = np.random.RandomState(seed)

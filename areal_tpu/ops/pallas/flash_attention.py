@@ -16,25 +16,14 @@ areal_tpu's packed-batch semantics:
    contiguous within a row (models/packing.py);
  - head_dim is padded up to the lane width (128) when needed.
 
-Block-size selection (the device-efficiency lever named in
-docs/benchmarks.md "Where the time goes"): ``pick_block_sizes`` resolves
-(block_q, block_kv) for a (T, S) geometry from, in precedence order,
-
- 1. ``AREAL_FLASH_BLOCKS="bq,bkv"`` — a global pin (debug/experiments);
- 2. a geometry-keyed table: entries recorded at runtime via
-    :func:`set_block_sizes`, or loaded from the JSON file named by
-    ``AREAL_FLASH_BLOCK_TABLE`` (written by ``perf_probe blocksweep``,
-    format ``{"T,S": [bq, bkv]}``);
- 3. :func:`pick_tile` — the tile of ``TILE_COST`` that makes the row
-    cheapest once the row is PADDED up to a multiple of it.
-
-A pin or table entry means "these blocks, no padding": it is validated
-against the kernel's divisibility constraint and snaps DOWN to the nearest
-dividing 128-multiple rather than failing at dispatch time. The tile of
-rule 3 need not divide the row: :func:`flash_attention` pads T and S up to
-it with segment id 0 (what a row's own tail padding already carries), runs
-the kernel at the padded length and slices the output back, so a row of
-6016 = 47 x 128 tokens runs 512-blocks at 6144 instead of 128-blocks.
+Block-size selection has one rule and one padding regime:
+``pick_block_sizes`` gives each sequence dim of a (T, S) geometry the tile
+of :func:`pick_tile` — the tile of ``TILE_COST`` that makes the row
+cheapest once the row is PADDED up to a multiple of it. The tile need not
+divide the row: :func:`flash_attention` pads T and S up to it with segment
+id 0 (what a row's own tail padding already carries), runs the kernel at
+the padded length and slices the output back, so a row of 6016 = 47 x 128
+tokens runs 512-blocks at 6144 instead of 128-blocks.
 :func:`geometry_counts` says, per compiled step, which (length, padded
 length, tile) each call was traced with.
 
@@ -53,9 +42,6 @@ from __future__ import annotations
 
 import collections
 import functools
-import json
-import logging
-import os
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -71,33 +57,12 @@ from jax.experimental.pallas.ops.tpu.flash_attention import (
 from areal_tpu.ops import attention as _attention
 
 LANE = 128
-DEFAULT_BLOCK_TARGET = 512
 
 # c(t): ns per row·token² that the kernels of one training step (3 forward
 # passes, dKV, dQ) take at tile t — measured on a TPU v5e, the kernels
 # alone, 6 rows x 6144 tokens, 14 heads, bf16 (PERF.md §5, PR 25).
 # pick_tile uses only their ratios.
 TILE_COST = {512: 0.2724, 384: 0.4054, 256: 0.5587, 128: 1.5418}
-
-logger = logging.getLogger("areal_tpu")
-
-# Geometry-keyed (T, S) -> (block_q, block_kv). Populated by
-# set_block_sizes() / the AREAL_FLASH_BLOCK_TABLE JSON (perf_probe
-# blocksweep writes it); empty by default — pick_tile below is the
-# default, and recorded sweep results override it per geometry.
-_BLOCK_TABLE: Dict[Tuple[int, int], Tuple[int, int]] = {}
-_TABLE_FILE_LOADED: Optional[str] = None  # set only on a SUCCESSFUL load
-_TABLE_FILE_WARNED: set = set()
-
-
-def _block(n: int, target: int) -> Optional[int]:
-    """Largest multiple of 128 that divides n and is ≤ target (the kernel
-    requires block sizes to divide the sequence dims exactly). None when no
-    such divisor exists — callers fall back to the reference path."""
-    for b in range(min(target, n), 0, -LANE):
-        if n % b == 0 and b % LANE == 0:
-            return b
-    return None
 
 
 def _round_up(n: int, tile: int) -> int:
@@ -123,75 +88,12 @@ def geometry_counts() -> Dict[str, Dict[Tuple[int, int, int], int]]:
     return {label: dict(c) for label, c in _GEOMETRY.items()}
 
 
-def set_block_sizes(T: int, S: int, block_q: int, block_kv: int) -> None:
-    """Record tuned block sizes for a (T, S) geometry (process-local)."""
-    _BLOCK_TABLE[(int(T), int(S))] = (int(block_q), int(block_kv))
-
-
-def clear_block_table() -> None:
-    """Drop runtime + file-loaded entries (tests / re-sweeps)."""
-    global _TABLE_FILE_LOADED
-    _BLOCK_TABLE.clear()
-    _TABLE_FILE_LOADED = None
-
-
-def _load_table_file() -> None:
-    """Merge ``AREAL_FLASH_BLOCK_TABLE`` (if set) into the table once per
-    path; runtime set_block_sizes entries win over file entries. A missing
-    or unreadable file warns once but is retried on later calls (the
-    documented workflow writes the file with ``perf_probe blocksweep``
-    AFTER the env var is already exported), and only a successful load
-    pins the path as done."""
-    global _TABLE_FILE_LOADED
-    path = os.environ.get("AREAL_FLASH_BLOCK_TABLE")
-    if not path or path == _TABLE_FILE_LOADED:
-        return
-    try:
-        with open(path) as f:
-            raw = json.load(f)
-        for key, val in raw.items():
-            t, s = (int(x) for x in key.split(","))
-            _BLOCK_TABLE.setdefault((t, s), (int(val[0]), int(val[1])))
-        _TABLE_FILE_LOADED = path
-        _TABLE_FILE_WARNED.discard(path)
-    except (OSError, ValueError, KeyError, IndexError) as e:
-        if path not in _TABLE_FILE_WARNED:
-            _TABLE_FILE_WARNED.add(path)
-            logger.warning("AREAL_FLASH_BLOCK_TABLE %r unreadable (%s); "
-                           "using pick_tile's block sizes until it appears",
-                           path, e)
-
-
 def pick_block_sizes(T: int, S: int) -> Optional[Tuple[int, int]]:
-    """Resolve (block_q, block_kv) for a geometry; None when either dim is
-    not a multiple of 128 (caller must use the reference path). Env pin >
-    table (runtime or file) > :func:`pick_tile`. A pin or a table entry is
-    snapped down to the nearest dividing 128-multiple (no padding); the
-    tile of pick_tile may exceed a divisor — flash_attention pads the dim
-    up to it."""
-    if _block(T, T) is None or _block(S, S) is None:
+    """(block_q, block_kv) for a geometry: each dim's :func:`pick_tile`,
+    which flash_attention pads the dim up to. None when either dim is not
+    a multiple of 128 (the caller must use the reference path)."""
+    if T % LANE or S % LANE:
         return None
-    # Any 128-multiple divisor of n implies 128 | n, so once the checks
-    # above pass the largest divisor <= 512 can never miss — it is the safe
-    # landing spot for out-of-range pins/table entries (a sub-128 pin must
-    # NOT snap up to a whole-sequence tile: bq*bkv scores alone would blow
-    # VMEM).
-    heur_q = _block(T, DEFAULT_BLOCK_TARGET)
-    heur_kv = _block(S, DEFAULT_BLOCK_TARGET)
-    env = os.environ.get("AREAL_FLASH_BLOCKS")
-    if env:
-        try:
-            bq, bkv = (int(x) for x in env.split(","))
-            return (_block(T, min(bq, T)) or heur_q,
-                    _block(S, min(bkv, S)) or heur_kv)
-        except ValueError:
-            logger.warning("AREAL_FLASH_BLOCKS=%r not 'bq,bkv'; ignoring",
-                           env)
-    _load_table_file()
-    hit = _BLOCK_TABLE.get((T, S))
-    if hit is not None:
-        return (_block(T, min(hit[0], T)) or heur_q,
-                _block(S, min(hit[1], S)) or heur_kv)
     return (pick_tile(T), pick_tile(S))
 
 
@@ -233,8 +135,8 @@ def flash_attention(
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    # Pad heads up to the lane width and each sequence dim up to its tile
-    # (a pin or table entry divides its dim: nothing to pad), in one pass.
+    # Pad heads up to the lane width and each sequence dim up to its tile,
+    # in one pass.
     lanes = max(LANE - D, 0)
 
     def pad(x, n):  # [B, H, L, D]: n more tokens, `lanes` more lanes

@@ -200,10 +200,13 @@ class RewardService:
             self.telemetry.set_gauge("reward/inflight", self._inflight)
             t0 = time.monotonic()
             try:
-                fut = loop.run_in_executor(self._pool, self._grade_fn, task)
+                # The THREAD's future: the asyncio wrapper awaited below is
+                # cancelled — done — the moment its wait times out, while
+                # the thread runs on; only this one says when it ends.
+                fut = self._pool.submit(self._grade_fn, task)
                 try:
                     out = await asyncio.wait_for(
-                        fut,
+                        asyncio.wrap_future(fut),
                         timeout=task_budget_secs(
                             task, self.cfg.grade_timeout_secs
                         ),
@@ -215,10 +218,13 @@ class RewardService:
                     # finishes — releasing now would admit a grade with
                     # no free thread, which would burn its wall budget
                     # in executor-queue wait and time out spuriously.
+                    # (A grade no thread had picked up yet is cancelled
+                    # by the wait: done, nothing to withhold.)
                     self._timeouts += 1
                     self.telemetry.inc("reward/timeouts")
-                    self._withhold_permit(fut, loop)
-                    withheld = True
+                    if not fut.done():
+                        self._withhold_permit(fut, loop)
+                        withheld = True
                     out = {"score": 0.0, "verdict": "timeout"}
                 except asyncio.CancelledError:
                     # Client disconnect / handler cancellation: the

@@ -1,9 +1,9 @@
 """Goodput ledger: live per-chip utilization truth for the async pipeline.
 
 The paper's core claim — decoupling generation from training keeps every
-chip busy — was only measurable at bench time: ``bench.py`` computed one
-aggregate MFU after the fact, live runs exported phase *durations* (PR 4
-spans) but no achieved-FLOP/s and no idle/compute decomposition. This
+chip busy — was only measurable after the fact, as one aggregate MFU:
+live runs exported phase *durations* (PR 4 spans) but no achieved-FLOP/s
+and no idle/compute decomposition. This
 module turns the existing telemetry into a continuously exported
 utilization signal, in three layers (docs/observability.md §Goodput):
 
@@ -19,8 +19,8 @@ utilization signal, in three layers (docs/observability.md §Goodput):
    fractions without any server-side windowing.
  - :class:`MfuEmitter` + :func:`resolve_peak_flops` — live achieved
    FLOP/s and MFU gauges against the per-generation peak table
-   (``base/monitor.py`` — the ONE home of the FLOPs formulas, shared
-   with ``bench.py``). On an unknown device kind the emitter degrades to
+   (``base/monitor.py`` — the ONE home of the FLOPs formulas). On an
+   unknown device kind the emitter degrades to
    achieved-TFLOP/s-only with a one-time warning instead of exporting
    ``mfu=0.0`` (a hard zero would trip baseline sentinel rules as a
    false divergence).

@@ -172,7 +172,7 @@ class MemWatch:
     # ---- views ----
 
     def peak_gb(self) -> float:
-        """Highest HBM occupancy seen by any sample (bench.py field)."""
+        """Highest HBM occupancy seen by any sample."""
         with self._lock:
             return self._peak_bytes / (1 << 30)
 

@@ -607,8 +607,8 @@ class TrainerWorker:
     def _emit_mfu(self, role: str, batch: SequenceSample,
                   dur_secs: float) -> None:
         """Live achieved-FLOP/s + MFU for one train MFC: packed token
-        counts fed through the SAME analytic formulas bench.py reports
-        against (base/monitor.py FlopsCounter — the llama formula family
+        counts fed through the one set of analytic formulas
+        (base/monitor.py FlopsCounter — the llama formula family
         with the engine's real remat factor), divided by the step's wall
         clock and the chip count. ``train/mfu`` degrades to
         achieved-TFLOP/s-only on unknown device kinds (MfuEmitter).

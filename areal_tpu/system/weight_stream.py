@@ -321,7 +321,11 @@ class WeightStreamPublisher:
         return True
 
     def _serve_loop(self) -> None:
-        # Requests waiting on the gather thread: [(ident, frames, deadline)].
+        # Requests not answered yet, in arrival order: [(ident, frames,
+        # deadline)]. A consumer reads its replies in the order it asked
+        # (iter_tensors), so once one of its requests waits here for the
+        # gather thread, its later ones wait behind it even when their own
+        # data is ready; other consumers' requests keep flowing.
         pending: List[tuple] = []
         while not self._closing:
             if self._sock.poll(20 if pending else 100):
@@ -332,14 +336,14 @@ class WeightStreamPublisher:
                         )
                     except zmq.Again:
                         break
-                    if not self._try_serve(ident, frames):
-                        pending.append((
-                            ident, frames,
-                            time.monotonic() + self.chunk_wait_secs,
-                        ))
+                    pending.append((
+                        ident, frames,
+                        time.monotonic() + self.chunk_wait_secs,
+                    ))
             still = []
+            waiting = set()  # consumers with an earlier request deferred
             for ident, frames, deadline in pending:
-                if self._try_serve(ident, frames):
+                if ident not in waiting and self._try_serve(ident, frames):
                     continue
                 if time.monotonic() > deadline:
                     self._reply(ident, [
@@ -347,6 +351,7 @@ class WeightStreamPublisher:
                         b"timed out waiting for the gather thread",
                     ])
                     continue
+                waiting.add(ident)
                 still.append((ident, frames, deadline))
             pending = still
 

@@ -1,17 +1,13 @@
 """Goodput ledger (system/goodput.py, docs/observability.md §Goodput).
 
 Fake clocks everywhere for the ledger state machine (transitions sum to
-wall clock, counters monotonic, export rate-limiting), in-process fakes
-for the aggregator fleet stitch, and subprocess smoke for the jax-free
-tools/bench_compare.py regression gate. The disabled path is pinned
+wall clock, counters monotonic, export rate-limiting) and in-process fakes
+for the aggregator fleet stitch. The disabled path is pinned
 bit-identical: a null ledger must leave the Prometheus scrape byte-equal
 to a build without the ledger.
 """
 
 import json
-import os
-import subprocess
-import sys
 
 import pytest
 
@@ -20,8 +16,6 @@ from areal_tpu.base import monitor, telemetry
 from areal_tpu.system import goodput
 
 pytestmark = pytest.mark.goodput
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class FakeClock:
@@ -236,7 +230,7 @@ def test_mfu_emitter_with_known_peak():
 
 
 def test_bench_flops_accounting_parity():
-    """bench.py and the live gauges share monitor.train_flops_6nt +
+    """The live MFU gauges divide monitor.train_flops_6nt by
     device_peak_flops: pin both against the 6·N·T formula and the peak
     table, keyed by the exact ``device_kind`` jax reports."""
     n_params, steps, total, dt, n_chips = 494_032_768, 3, 30_000, 4.2, 1
@@ -489,124 +483,3 @@ def test_aggregator_without_goodput_renders_no_fleet_row(tmp_name_resolve):
         if p is not None:
             p.close()
         agg.close()
-
-
-# ---------------------------------------------------------------------------
-# bench_compare regression gate (jax-free CLI, run as a subprocess)
-# ---------------------------------------------------------------------------
-
-
-BENCH_BASE = {
-    "metric": "ppo_trained_tokens_per_sec_per_chip",
-    "value": 10000.0, "unit": "tokens/s/chip", "vs_baseline": 0.30,
-    "pack_fill": 0.95, "weight_sync_latency_s": 10.0,
-    "weight_sync_io_s": 2.0, "weight_sync_transport_s": 8.0,
-    "weight_sync_transport_method": "streamed-measured",
-    "train_phases": {"fwd_bwd_s": 1.0, "optimizer_s": 0.2},
-}
-
-
-def _bench_compare(*paths, extra=()):
-    return subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "bench_compare.py"),
-         *map(str, paths), *extra],
-        capture_output=True, text=True, timeout=60,
-    )
-
-
-def _write(path, record):
-    path.write_text(json.dumps(record))
-    return path
-
-
-def test_bench_compare_passes_within_tolerance(tmp_path):
-    a = _write(tmp_path / "r1.json", BENCH_BASE)
-    b = _write(tmp_path / "r2.json",
-               dict(BENCH_BASE, value=9800.0, pack_fill=0.96))
-    r = _bench_compare(a, b)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "no regression" in r.stdout
-
-
-def test_bench_compare_flags_injected_regression(tmp_path):
-    a = _write(tmp_path / "r1.json", BENCH_BASE)
-    # injected 20% tokens/s drop (tol 5%) + a weight-sync blowup
-    b = _write(tmp_path / "r2.json",
-               dict(BENCH_BASE, value=8000.0,
-                    weight_sync_latency_s=20.0))
-    r = _bench_compare(a, b)
-    assert r.returncode == 1, r.stdout + r.stderr
-    assert "value" in r.stderr
-    assert "weight_sync_latency_s" in r.stderr
-    # a tolerance override waives the gated fields
-    r = _bench_compare(a, b, extra=("--tol", "value=0.5",
-                                    "--tol", "weight_sync_latency_s=2.0"))
-    assert r.returncode == 0, r.stdout + r.stderr
-
-
-def test_bench_compare_wrapper_form_and_method_discontinuity(tmp_path):
-    # driver wrapper form ({"parsed": ...}, what BENCH_r*.json are) +
-    # a transport-method change: weight_sync_* numbers measure different
-    # things across the discontinuity and must not gate
-    a = _write(tmp_path / "r1.json", {"n": 1, "parsed": dict(
-        BENCH_BASE, weight_sync_latency_s=500.0,
-        weight_sync_transport_method="2x-d2h-extrapolated")})
-    b = _write(tmp_path / "r2.json", BENCH_BASE)
-    r = _bench_compare(a, b)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "skipped-method-change" in r.stdout
-    # train_phases sub-fields flatten and gate (25% tol): a 2x fwd_bwd
-    # blowup regresses
-    c = _write(tmp_path / "r3.json", dict(
-        BENCH_BASE, train_phases={"fwd_bwd_s": 2.0, "optimizer_s": 0.2}))
-    r = _bench_compare(b, c)
-    assert r.returncode == 1
-    assert "train_phases.fwd_bwd_s" in r.stderr
-
-
-def test_bench_compare_driver_record_files(tmp_path):
-    """Records in the driver's wrapper form (``{"rc", "tail", "parsed"}``,
-    here with the figures of the removed BENCH_r04/r05 records — r04→r05
-    is the known honesty discontinuity) parse through the gate end to
-    end: the tool reads them and renders the trajectory, with the
-    tolerance widened past the documented method change."""
-    def record(n, **parsed):
-        parsed = {"metric": "ppo_trained_tokens_per_sec_per_chip",
-                  "unit": "tokens/s/chip", **parsed}
-        return _write(tmp_path / f"BENCH_r{n:02d}.json", {
-            "n": n, "cmd": "python bench.py", "rc": 0,
-            "tail": json.dumps(parsed) + "\n", "parsed": parsed,
-        })
-
-    r04 = record(4, value=20349.9, vs_baseline=0.3062,
-                 weight_sync_latency_s=354.942, weight_sync_io_s=22.051,
-                 weight_sync_transport_s=332.891)
-    r05 = record(5, value=18642.7, vs_baseline=0.2805,
-                 weight_sync_latency_s=116.089, weight_sync_io_s=12.774,
-                 weight_sync_transport_s=103.315,
-                 weight_sync_transport_method="2x-d2h-extrapolated")
-    r = _bench_compare(r04, r05, extra=("--tol", "default=1.0"))
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "trajectory" in r.stdout
-
-
-def test_bench_compare_zero_baseline_still_gates(tmp_path):
-    # a zero previous value has no relative scale — a lower-better field
-    # going 0 -> 3s must regress, not report "0% change, ok"
-    a = _write(tmp_path / "r1.json", dict(BENCH_BASE,
-                                          weight_sync_io_s=0.0))
-    b = _write(tmp_path / "r2.json", dict(BENCH_BASE,
-                                          weight_sync_io_s=3.0))
-    r = _bench_compare(a, b)
-    assert r.returncode == 1, r.stdout + r.stderr
-    assert "weight_sync_io_s" in r.stderr
-    # equal zeros are fine
-    b = _write(tmp_path / "r2.json", dict(BENCH_BASE,
-                                          weight_sync_io_s=0.0))
-    assert _bench_compare(a, b).returncode == 0
-
-
-def test_bench_compare_needs_two_files(tmp_path):
-    a = _write(tmp_path / "r1.json", BENCH_BASE)
-    r = _bench_compare(a)
-    assert r.returncode == 2

@@ -1,7 +1,6 @@
 """Host-side packing-fill regression (ISSUE 8): the micro-batch packer's
-fill on a bench-shaped length distribution must be >= 0.92 — the MFU lever
-docs/benchmarks.md "Where the time goes" measured at 0.84 with the coarse
-512-bucket candidates — and the finer bucketing must keep the python and
+fill on a PPO-shaped length distribution must be >= 0.92 — it was 0.84
+with the coarse 512-bucket candidates — and the finer bucketing must keep the python and
 native-C FFD paths bit-identical. CPU-only; no model, no device work
 except one tiny engine step that checks the telemetry export."""
 
@@ -14,9 +13,8 @@ from areal_tpu.base import datapack
 
 
 def _bench_batch(seed=0, n_seq=32):
-    """The bench.py trajectory distribution, from the canonical shared
-    recipe (base/testing.bench_trajectory_dist) so this gate can never
-    silently desynchronize from what bench.py actually packs."""
+    """~250-token prompts + ~640-token generations, from the shared
+    recipe (base/testing.bench_trajectory_dist)."""
     from areal_tpu.base.testing import bench_trajectory_sample
 
     return bench_trajectory_sample(seed, n_seq)

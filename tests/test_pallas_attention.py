@@ -129,26 +129,20 @@ def test_flash_on_mesh_matches_reference(spec, row_len):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-2)
 
 
-# ---------------- block-size autotuning (CPU, no interpreter) ------------
+# ---------------- tile selection (CPU, no interpreter) ------------
 
 
-@pytest.fixture(autouse=True)
-def _clean_block_state(monkeypatch):
-    fa.clear_block_table()
-    monkeypatch.delenv("AREAL_FLASH_BLOCKS", raising=False)
-    monkeypatch.delenv("AREAL_FLASH_BLOCK_TABLE", raising=False)
-    yield
-    fa.clear_block_table()
-
-
-def test_pick_block_sizes_heuristic():
-    # the default: the tile that is cheapest once the dim is padded to it
-    assert fa.pick_block_sizes(1024, 1024) == (512, 512)
-    assert fa.pick_block_sizes(640, 640) == (384, 384)  # padded to 768
-    assert fa.pick_block_sizes(384, 768) == (384, 384)
+@pytest.mark.parametrize("T,S,want", [
+    # the tile that is cheapest once the dim is padded to it
+    (1024, 1024, (512, 512)),
+    (640, 640, (384, 384)),  # padded to 768
+    (384, 768, (384, 384)),
     # not a multiple of 128 -> None (callers fall back)
-    assert fa.pick_block_sizes(192, 1024) is None
-    assert fa.pick_block_sizes(1024, 100) is None
+    (192, 1024, None),
+    (1024, 100, None),
+])
+def test_pick_block_sizes_heuristic(T, S, want):
+    assert fa.pick_block_sizes(T, S) == want
 
 
 # The row lengths the benchmark's two train cells produce -> (tile, padded
@@ -185,68 +179,6 @@ def test_geometry_counts_under_the_active_label():
     assert out.shape == q.shape
     assert fa.geometry_counts()["t6016"] == {(6016, 6144, 512): 1}
     assert attn.dispatch_counts()["t6016"] == {"pallas": 1}
-
-
-def test_pick_block_sizes_table_and_env(monkeypatch, tmp_path):
-    # runtime-recorded entry wins over the heuristic
-    fa.set_block_sizes(1024, 1024, 256, 1024)
-    assert fa.pick_block_sizes(1024, 1024) == (256, 1024)
-    # ... but snaps down to a legal divisor when the entry is invalid
-    fa.set_block_sizes(640, 640, 512, 512)
-    assert fa.pick_block_sizes(640, 640) == (128, 128)
-    # file-loaded table (the blocksweep output format)
-    p = tmp_path / "blocks.json"
-    p.write_text('{"2048,2048": [512, 1024]}')
-    monkeypatch.setenv("AREAL_FLASH_BLOCK_TABLE", str(p))
-    assert fa.pick_block_sizes(2048, 2048) == (512, 1024)
-    # env pin beats everything
-    monkeypatch.setenv("AREAL_FLASH_BLOCKS", "128,256")
-    assert fa.pick_block_sizes(1024, 1024) == (128, 256)
-    assert fa.pick_block_sizes(2048, 2048) == (128, 256)
-    # a sub-128 pin has no legal divisor: it must land on the heuristic,
-    # NOT snap up to a whole-sequence tile (VMEM blowup)
-    monkeypatch.setenv("AREAL_FLASH_BLOCKS", "64,64")
-    assert fa.pick_block_sizes(1792, 1792) == (256, 256)
-
-
-def test_blocksweep_candidates_and_record_format():
-    """The perf_probe blocksweep pieces that don't need a TPU: candidate
-    enumeration respects the kernel's divisibility constraint, and the
-    recorded JSON round-trips through pick_block_sizes."""
-    import json
-    import os
-    import sys
-
-    tools_dir = os.path.join(os.path.dirname(__file__), "..", "tools")
-    sys.path.insert(0, tools_dir)
-    try:
-        from perf_probe import _blocksweep_candidates
-    finally:
-        sys.path.remove(tools_dir)
-
-    cands = _blocksweep_candidates(1792, 1792)
-    assert (256, 1792) in cands and (1792, 256) in cands
-    for bq, bkv in cands:
-        assert 1792 % bq == 0 and bq % 128 == 0
-        assert 1792 % bkv == 0 and bkv % 128 == 0
-    assert _blocksweep_candidates(192, 1792) == []  # no legal bq
-
-    # the exact record the sweep writes is what the table loader reads
-    rec = {"1792,1792": [256, 1792]}
-    import tempfile
-
-    with tempfile.NamedTemporaryFile("w", suffix=".json",
-                                     delete=False) as f:
-        json.dump(rec, f)
-        path = f.name
-    import os
-
-    os.environ["AREAL_FLASH_BLOCK_TABLE"] = path
-    try:
-        assert fa.pick_block_sizes(1792, 1792) == (256, 1792)
-    finally:
-        del os.environ["AREAL_FLASH_BLOCK_TABLE"]
-        os.unlink(path)
 
 
 def test_non_divisible_shape_falls_back_to_reference():

@@ -363,10 +363,10 @@ def test_a_program_that_does_not_fit_falls_back_one_entry(monkeypatch):
     monkeypatch.setattr(jax_train, "choose_remat", lambda kept, b: "matmuls")
     eng = _train_engine(True)
     tried = []
-    real = eng._get_grad_fn
+    real = eng._get_sliced_grad_fn
 
-    def get_fn(loss_fn, with_carry, remat=False):
-        fn = real(loss_fn, with_carry, remat)
+    def get_fn(loss_fn, with_carry, R, remat=False):
+        fn = real(loss_fn, with_carry, R, remat)
 
         def call(*args):
             tried.append(remat)
@@ -378,7 +378,7 @@ def test_a_program_that_does_not_fit_falls_back_one_entry(monkeypatch):
 
         return call
 
-    monkeypatch.setattr(eng, "_get_grad_fn", get_fn)
+    monkeypatch.setattr(eng, "_get_sliced_grad_fn", get_fn)
     stats = eng.train_batch(_sample(np.random.RandomState(3)),
                             MicroBatchSpec(max_tokens_per_mb=64), _sq_loss,
                             lambda mb: mb.n_tokens)
@@ -397,17 +397,17 @@ def test_other_errors_and_the_last_entry_are_not_swallowed(monkeypatch):
     spec = MicroBatchSpec(max_tokens_per_mb=64)
 
     def boom(msg):
-        def get_fn(loss_fn, with_carry, remat=False):
+        def get_fn(loss_fn, with_carry, R, remat=False):
             def call(*args):
                 raise jax.errors.JaxRuntimeError(msg)
             return call
         return get_fn
 
-    monkeypatch.setattr(eng, "_get_grad_fn", boom("RESOURCE_EXHAUSTED: hbm"))
+    monkeypatch.setattr(eng, "_get_sliced_grad_fn", boom("RESOURCE_EXHAUSTED: hbm"))
     with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE"):
         eng.train_batch(sample, spec, _sq_loss, lambda mb: mb.n_tokens)
     assert all(not p["fell_back"] for p in eng.remat_plan().values())
-    monkeypatch.setattr(eng, "_get_grad_fn", boom("INTERNAL: other"))
+    monkeypatch.setattr(eng, "_get_sliced_grad_fn", boom("INTERNAL: other"))
     with pytest.raises(jax.errors.JaxRuntimeError, match="INTERNAL"):
         eng.train_batch(sample, spec, _sq_loss, lambda mb: mb.n_tokens)
 

@@ -668,9 +668,49 @@ def test_aggregator_hosts_sentinel_end_to_end(tmp_name_resolve, tmp_path):
 # ---------------------------------------------------------------------------
 
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PERF_PROBE = os.path.join(REPO, "tools", "perf_probe.py")
+
+
+def _perf_probe_commands():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location("_perf_probe", PERF_PROBE)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.COMMANDS
+
+
+def _no_jax_env(tmp_path, **extra):
+    """An environment in which ``import jax`` raises: a poisoned module
+    at the head of the path."""
+    poison = tmp_path / "poison"
+    poison.mkdir(exist_ok=True)
+    (poison / "jax.py").write_text(
+        "raise ImportError('perf_probe must not import jax')\n")
+    return dict(os.environ, PYTHONPATH=str(poison), **extra)
+
+
+@pytest.mark.parametrize("command", _perf_probe_commands())
+def test_perf_probe_is_jax_free(tmp_path, command):
+    """Every command is for a live run and needs operands: without them
+    it prints the usage and exits 1 — with jax unimportable."""
+    import subprocess
+    import sys as _sys
+
+    out = subprocess.run(
+        [_sys.executable, PERF_PROBE, command], capture_output=True,
+        text=True, cwd=REPO, env=_no_jax_env(tmp_path), timeout=60,
+    )
+    assert out.returncode == 1, out.stderr
+    assert f"missing operand for {command!r}" in out.stderr
+    assert "Usage: python tools/perf_probe.py" in out.stderr
+    assert "must not import jax" not in out.stderr
+
+
 def test_perf_probe_alerts_and_silence_cli(tmp_path):
     """`alerts` filters a recorded stream and `silence` writes the
-    name-resolve key — both exit before perf_probe ever imports jax."""
+    name-resolve key — with jax unimportable."""
     import subprocess
     import sys as _sys
 
@@ -684,22 +724,20 @@ def test_perf_probe_alerts_and_silence_cli(tmp_path):
                             "severity": "warn", "metric":
                             "train/task_reward", "value": 0.1,
                             "ts": 1001.0}) + "\n")
-    env = dict(os.environ,
-               AREAL_NAME_RESOLVE_ROOT=str(tmp_path / "nr"),
-               JAX_PLATFORMS="cpu")
+    env = _no_jax_env(tmp_path,
+                      AREAL_NAME_RESOLVE_ROOT=str(tmp_path / "nr"))
     out = subprocess.run(
-        [_sys.executable, "tools/perf_probe.py", "alerts", str(stream),
-         "critical"],
-        capture_output=True, text=True, cwd="/root/repo", env=env,
+        [_sys.executable, PERF_PROBE, "alerts", str(stream), "critical"],
+        capture_output=True, text=True, cwd=REPO, env=env,
     )
     assert out.returncode == 0, out.stderr
     assert "kl_blowup" in out.stdout
     assert "reward_drift" not in out.stdout
     assert "(1/2 records" in out.stdout
     out = subprocess.run(
-        [_sys.executable, "tools/perf_probe.py", "silence",
+        [_sys.executable, PERF_PROBE, "silence",
          "sentexp", "t0", "kl_blowup", "10m"],
-        capture_output=True, text=True, cwd="/root/repo", env=env,
+        capture_output=True, text=True, cwd=REPO, env=env,
     )
     assert out.returncode == 0, out.stderr
     assert "600s" in out.stdout

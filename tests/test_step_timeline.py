@@ -30,7 +30,7 @@ SPEC = MicroBatchSpec(max_tokens_per_mb=64)
 HP = dict(ppo_n_minibatches=2, adv_norm=True, kl_ctl=0.0, disable_value=True)
 PREFIX = telemetry.ANNOTATION_PREFIX
 
-# child span -> the span it must lie inside, for the uniform train path
+# child span -> the span it must lie inside
 NESTING = {
     "infer/split_pack": "ppo/inference",
     "infer/upload": "ppo/inference",
@@ -85,11 +85,17 @@ def registry():
     telemetry.shutdown()
 
 
+@pytest.mark.parametrize("group_adv_norm", [False, True])
 @pytest.mark.parametrize("registry_on", [False, True])
-def test_spans_of_one_step_in_a_capture(tmp_path, registry_on, request):
+def test_spans_of_one_step_in_a_capture(tmp_path, registry_on,
+                                        group_adv_norm, request):
+    """Group normalization keeps the advantage prep on the host and takes
+    a ``train_batch`` per PPO minibatch: the same tree but for
+    ``train/adv_prep``, with a pack and an upload per minibatch."""
     reg = request.getfixturevalue("registry") if registry_on else None
     assert telemetry.enabled() is registry_on
-    model, iface = _engine(), PPOActorInterface(PPOHyperparameters(**HP))
+    hp = PPOHyperparameters(**HP, group_adv_norm=group_adv_norm)
+    model, iface = _engine(), PPOActorInterface(hp)
     batch = _make_batch()
     _step(model, iface, batch)  # compile outside the capture
     if reg is not None:
@@ -98,8 +104,12 @@ def test_spans_of_one_step_in_a_capture(tmp_path, registry_on, request):
     by_name = {}
     for name, s, e, stats in events:
         by_name.setdefault(name, []).append((s, e, stats))
-    assert set(NESTING) | {"ppo/inference", "ppo/train_step"} <= set(by_name)
-    for child, parent in NESTING.items():
+    nesting = dict(NESTING)
+    if group_adv_norm:
+        del nesting["train/adv_prep"]
+        assert "train/adv_prep" not in by_name
+    assert set(nesting) | {"ppo/inference", "ppo/train_step"} <= set(by_name)
+    for child, parent in nesting.items():
         for s, e, _ in by_name[child]:
             assert any(ps <= s and e <= pe for ps, pe, _ in by_name[parent]), \
                 f"{child} is not inside {parent}"
@@ -111,15 +121,21 @@ def test_spans_of_one_step_in_a_capture(tmp_path, registry_on, request):
     for k in ("train/fwd_bwd", "train/apply_dispatch", "train/fetch_stats",
               "train/finish_stats"):
         assert len(by_name[k]) == HP["ppo_n_minibatches"]
+    # the whole batch goes up before its first grad program is dispatched
+    ups, loops = by_name["train/upload"], by_name["train/fwd_bwd"]
+    assert len(ups) == (HP["ppo_n_minibatches"] if group_adv_norm else 1)
+    for (_, up_end, _), (loop_start, _, _) in zip(ups, loops):
+        assert up_end <= loop_start
     root = by_name["ppo/train_step"][0][2]
     assert root["sequences"] == batch.bs
     assert root["real_tokens"] == sum(batch.total_lens("packed_input_ids"))
+    assert sum(u[2]["real_tokens"] for u in ups) == root["real_tokens"]
     if reg is not None:
         # the registry records the same spans, with the same attributes
         spans = reg.snapshot(reset=True)["spans"]
-        assert {s["name"] for s in spans} >= set(NESTING)
-        (up,) = [s for s in spans if s["name"] == "train/upload"]
-        assert up["attrs"] == by_name["train/upload"][0][2]
+        assert {s["name"] for s in spans} >= set(nesting)
+        recorded = [s for s in spans if s["name"] == "train/upload"]
+        assert [s["attrs"] for s in recorded] == [u[2] for u in ups]
 
 
 def test_upload_span_counts_are_the_packers(tmp_path):
@@ -192,28 +208,13 @@ def test_telemetry_adds_no_host_sync_to_a_step(monkeypatch, registry):
     assert counts[True]["device_get"] == HP["ppo_n_minibatches"]
 
 
-def test_legacy_train_batch_has_the_same_tree(tmp_path):
-    # group normalization keeps the advantage prep on the host and the
-    # step on the per-micro-batch path (``train_batch``)
-    hp = PPOHyperparameters(**{**HP, "group_adv_norm": True})
-    model, iface, batch = _engine(), PPOActorInterface(hp), _make_batch()
-    _step(model, iface, batch)
-    events = _capture(tmp_path, lambda: _step(model, iface, batch))
-    names = {n for n, _, _, _ in events}
-    assert {"train/split_pack", "train/upload", "train/fwd_bwd",
-            "train/optimizer", "train/apply_dispatch", "train/fetch_stats",
-            "train/finish_stats"} <= names
-    ups = [(s, e) for n, s, e, _ in events if n == "train/upload"]
-    loops = [(s, e) for n, s, e, _ in events if n == "train/fwd_bwd"]
-    assert all(any(ls <= s and e <= le for ls, le in loops) for s, e in ups)
-
-
 # ---- names on the device ----
 
 @pytest.fixture(scope="module")
 def programs():
     """HloModule name -> set of framework op names, for every program one
-    inference + train_step of the tiny model builds (both train paths)."""
+    inference + train_step of the tiny model builds (advantages on the
+    device and, with group normalization, on the host)."""
     seen = {}
     orig = jax.jit
 
@@ -270,7 +271,6 @@ BLOCK = {"attn_norm", "qkv_proj", "rope", "attention", "o_proj", "mlp_norm",
 FORWARD = BLOCK | {"embed", "final_norm", "head", "xent", "param_cast"}
 EXPECTED_SCOPES = {
     "jit_infer_forward": FORWARD,
-    "jit_train_grad": FORWARD | {"ppo_loss", "grad_accum"},
     "jit_train_grad_sliced": FORWARD | {"ppo_loss", "grad_accum"},
     "jit_train_apply": {"grad_clip", "adam", "param_update"},
     "jit_adv_prep": {"gae"},

@@ -27,7 +27,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # {T: rows}. T=512 is the row the packer emits under chip_smoke.py's config;
-# 2048 and 4096 are bench.py's packing caps; 6016 = 47 x 128 is the
+# 2048 and 4096 are common packing caps; 6016 = 47 x 128 is the
 # benchmark's train-long row that no tile above 128 divides: the wrapper
 # pads it to 6144 and runs blocks of 512.
 KERNEL_T = {512: 2, 2048: 2, 4096: 2, 6016: 1}

@@ -1,9 +1,10 @@
 """Device-side advantage prep (make_advantage_prep over an uploaded
 UniformBatch) must match the host path (compute_advantages_and_returns +
-normalize_advantages) exactly, and the uniform train path must take the
-same optimizer step as the legacy per-micro-batch path."""
+normalize_advantages) exactly, and a train step must take the same
+optimizer step whichever side computed the advantages."""
 
 import copy
+import dataclasses
 
 import numpy as np
 import pytest
@@ -102,12 +103,14 @@ def test_device_prep_matches_host_path(kl_coef, with_values):
     )
 
 
-def test_train_step_uniform_matches_legacy_params():
-    """The fast path and the legacy path must produce the same updated
-    parameters for the same inputs (same grads → same adamw step)."""
-    # One minibatch: with k>1 the two paths partition differently (token-
-    # balanced vs contiguous-rows), which is a legitimate semantic
-    # difference; with k=1 both take one step over identical data.
+def test_train_step_device_prep_matches_host_advantages():
+    """The device advantage prep and the host-side advantage branch
+    (``group_adv_norm``) must produce the same updated parameters for the
+    same inputs (same grads → same adamw step)."""
+    # One minibatch: with k>1 the two branches partition differently
+    # (token-balanced vs contiguous-rows), which is a legitimate semantic
+    # difference; with k=1 both take one step over identical data. One
+    # group holding every sequence: whitening per group IS global whitening.
     hp = PPOHyperparameters(ppo_n_minibatches=1, adv_norm=True, kl_ctl=0.0,
                             disable_value=True)
     batch = _make_batch()
@@ -115,23 +118,16 @@ def test_train_step_uniform_matches_legacy_params():
 
     m1 = _engine()
     i1 = PPOActorInterface(copy.deepcopy(hp))
-    s1 = i1.train_step(m1, batch, spec)  # fast path (upload_uniform exists)
+    s1 = i1.train_step(m1, batch, spec)  # advantages on device (run_prep)
 
     m2 = _engine()
-    i2 = PPOActorInterface(copy.deepcopy(hp))
-    # Force the legacy path by hiding upload_uniform.
-    eng2 = m2.module
-    legacy = type("L", (), {})()
-    for attr in ("train_batch", "forward", "params", "cfg", "opt_state"):
-        setattr(legacy, attr, getattr(eng2, attr))
-    legacy.train_batch = eng2.train_batch
-    m2.module = legacy
-    s2 = i2.train_step(m2, batch, spec)
-    m2.module = eng2  # engine still holds the updated params
+    i2 = PPOActorInterface(dataclasses.replace(hp, group_adv_norm=True))
+    batch.metadata["group"] = ["g"] * batch.bs
+    s2 = i2.train_step(m2, batch, spec)  # advantages on the host
 
     for a, b in zip(
         jax.tree_util.tree_leaves(m1.module.params),
-        jax.tree_util.tree_leaves(eng2.params),
+        jax.tree_util.tree_leaves(m2.module.params),
     ):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=2e-6, rtol=2e-5

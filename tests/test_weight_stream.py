@@ -178,6 +178,55 @@ def test_reordered_stream_rejected(tmp_name_resolve):
         pub.close()
 
 
+def test_replies_keep_a_consumers_order_while_the_gather_lags(
+        tmp_name_resolve):
+    """A consumer that outruns the gather thread has requests deferred on
+    the publisher; a later request of the SAME consumer whose data is
+    ready must not overtake them (iter_tensors reads replies in request
+    order: "out-of-order chunk" on a busy machine). Another consumer's
+    servable request is not held up by them."""
+    import threading
+
+    gate = threading.Event()
+
+    class Gated:  # a leaf whose d2h gather blocks until the gate opens
+        shape, dtype = (4,), np.dtype(np.float32)
+
+        def __array__(self, dtype=None, copy=None):
+            assert gate.wait(30)
+            return np.arange(4, dtype=np.float32)
+
+    pub = WeightStreamPublisher(EXP, TRIAL, "actor")
+    pub.publish([("a_ready", np.ones(4, np.float32)), ("b_late", Gated())],
+                1)
+    assert pub._cache[1].ready[0].wait(10)
+    ours, other = WeightStreamConsumer(pub.endpoint, timeout_secs=1), \
+        WeightStreamConsumer(pub.endpoint, timeout_secs=10)
+
+    def ask(consumer, tensor):
+        consumer._request(b"chunk", {"version": 1, "tensor": tensor,
+                                     "chunk": 0})
+
+    def answered(consumer):
+        return json.loads(consumer._recv()[0])["tensor"]
+
+    try:
+        ask(ours, 1)   # not gathered yet: deferred
+        ask(ours, 0)   # gathered, but behind the deferred one
+        ask(other, 0)
+        assert answered(other) == 0  # no head-of-line block across consumers
+        with pytest.raises(WeightStreamError, match="no reply"):
+            ours._recv()
+        gate.set()
+        ours.timeout_secs = 10
+        assert [answered(ours), answered(ours)] == [1, 0]
+    finally:
+        gate.set()
+        ours.close()
+        other.close()
+        pub.close()
+
+
 def test_digest_catches_divergent_crcs(tmp_name_resolve):
     """Even if per-chunk checks were fooled, the final digest compare
     against the publisher's complete CRC list gates the swap."""

@@ -1,57 +1,9 @@
-"""Perf probe for the bench workload: isolates device kernel time from host
-dispatch/packing overhead and sweeps the knobs that plausibly gate MFU.
+"""Operator CLI for a LIVE run: every command talks to running workers over
+HTTP or name-resolve and none of them builds an engine or touches a device
+(the file imports no jax). What the trainer's step costs is measured by
+``benchmark/run.py`` (PERF.md).
 
-Usage: python tools/perf_probe.py [probe ...]
-Probes: e2e, grad, phases, mbsweep, remat, trace  (default: e2e grad)
-
-Standalone probes (docs/benchmarks.md Tools):
-  packfill [cap ...]                  HOST-ONLY (no TPU, no jax): packing
-                                      fill of the bench-shaped length
-                                      distribution at each token cap
-                                      (default 2048 4096 8192), new
-                                      128-grain sweep vs the coarse
-                                      512-bucket candidates
-  blocksweep [T] [S] [out.json]       sweep flash-attention (block_q,
-                                      block_kv) at a geometry (default
-                                      the bench grid, 1792x1792) and
-                                      record the winner to out.json
-                                      (default profiles/flash_blocks.json;
-                                      load it via AREAL_FLASH_BLOCK_TABLE)
-                                      — needs a real TPU: the kernel has
-                                      no interpreter on this jax
-  reshard-bench [src] [dst] [mb] [layers] [dim]
-                                      time the mesh→mesh on-device
-                                      reshard (parallel/reshard.py):
-                                      build a synthetic stacked-layer
-                                      tree, move it src-spec → dst-spec
-                                      (default f2t2 → d4) and report the
-                                      plan plus per-transfer-group
-                                      throughput at the given group
-                                      budget (default 64 MB); runs on
-                                      CPU test meshes or real chips
-                                      (docs/weight_sync.md §device)
-  ring-bench [sp,sp,...] [seq,seq,...]
-                                      sweep ring attention v2
-                                      (parallel/ring.py) over
-                                      (sp, seq_len): fwd+bwd step time
-                                      zigzag vs the naive v1 oracle plus
-                                      the structural causal-skip ratio
-                                      ((n+1)/2n at sp=n); runs on CPU
-                                      host meshes (JAX_PLATFORMS=cpu +
-                                      --xla_force_host_platform_device_
-                                      count=N) or real chips
-                                      (docs/parallelism.md §PP∘SP)
-  moe-bench [E,E,...] [k,k,...] [cf,cf,...]
-                                      sweep MoE dispatch (models/moe.py)
-                                      over (num_experts, top_k,
-                                      capacity_factor): one MoE layer's
-                                      fwd+bwd step time, sort-based
-                                      grouped path (default) vs the
-                                      one-hot einsum oracle, plus the
-                                      routed dropped fraction; runs on
-                                      CPU or real chips
-                                      (docs/parallelism.md §Expert
-                                      parallelism)
+Usage: python tools/perf_probe.py <command> [operands ...]
 
 Live-fleet commands (docs/observability.md; name-resolve root via
 AREAL_NAME_RESOLVE_ROOT when not the default):
@@ -181,9 +133,6 @@ AREAL_NAME_RESOLVE_ROOT when not the default):
                                       ask the live trainer for an
                                       on-demand jax.profiler capture
   profile-status <exp> <trial>        last capture outcome
-
-Writes findings to stdout; `trace` saves a jax.profiler trace under
-profiles/ for offline inspection.
 """
 
 import sys
@@ -1149,359 +1098,16 @@ def profile_status(experiment: str, trial: str) -> None:
     print(st if st is not None else "no capture recorded")
 
 
-def packfill(caps=None) -> None:
-    """Host-only packing-fill probe (ISSUE 8 / ROADMAP item 1): what fill
-    the micro-batch packer achieves on the bench trajectory distribution
-    at each token cap — the padding factor the reported MFU divides by.
-    No TPU and no jax needed; safe to run anywhere."""
-    from areal_tpu.api.data import MicroBatchSpec
-    from areal_tpu.backend import microbatch as mbu
-    from areal_tpu.base.testing import bench_trajectory_sample
-
-    caps = [int(c) for c in caps] if caps else [2048, 4096, 8192]
-    n_seq = 32
-    batch, seqlens = bench_trajectory_sample(0, n_seq)
-    print(f"[packfill] {n_seq} bench-shaped seqs, "
-          f"{int(seqlens.sum())} tokens, lens "
-          f"{int(seqlens.min())}..{int(seqlens.max())}")
-    for cap in caps:
-        spec = MicroBatchSpec(max_tokens_per_mb=cap)
-        for label, fb in (("fine(128)", None), ("coarse(512)", 512)):
-            mbs = mbu.split_into_microbatches(
-                batch, spec, length_bucket=512, rows_bucket=4,
-                seqs_bucket=16, fill_bucket=fb,
-            )
-            R, L = mbs[0].layout.shape
-            print(f"[packfill] cap={cap:<6} {label:<12} "
-                  f"n_mbs={len(mbs):<3} R={R:<2} L={L:<5} "
-                  f"fill={mbu.pack_fill(mbs):.4f}")
+COMMANDS = ("scrape", "trace", "flight-dump", "fleet-status", "spool-status",
+            "compile-status", "mem-status", "cordon", "uncordon", "drain",
+            "alerts", "silence", "goodput", "profile-trigger",
+            "profile-status", "decode-bench", "reward-bench")
 
 
-def _blocksweep_candidates(T: int, S: int):
-    """All (block_q, block_kv) the kernel accepts at this geometry:
-    128-multiples dividing the respective dim, bounded to keep q/kv tiles
-    within a sane VMEM envelope. Pure + CPU-testable."""
-    from areal_tpu.ops.pallas.flash_attention import LANE
-
-    def divs(n):
-        return [b for b in range(LANE, min(n, 2048) + 1, LANE) if n % b == 0]
-
-    return [(bq, bkv) for bq in divs(T) for bkv in divs(S)]
-
-
-def blocksweep(T: int = 1792, S: int = 1792, out_path: str = None,
-               Hq: int = 14, Hkv: int = 2, D: int = 64, B: int = 2) -> None:
-    """Sweep flash-attention block sizes at a (T, S) geometry — default
-    the bench grid after the r08 fill sweep (L=1792, R=2, Qwen2.5-0.5B
-    heads) — timing fwd+bwd per candidate, and record the winner as a
-    geometry-keyed JSON table consumable via AREAL_FLASH_BLOCK_TABLE."""
-    import json as _json
-    import os as _os
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from areal_tpu.ops.pallas import flash_attention as fa
-
-    if jax.default_backend() != "tpu":
-        sys.exit(
-            "blocksweep: needs a real TPU — interpreted kernel timings "
-            "say nothing about the chip. Results land in the JSON table "
-            "for AREAL_FLASH_BLOCK_TABLE."
-        )
-    # A leftover env pin/table would override every per-candidate
-    # set_block_sizes below — the sweep would time one config N times and
-    # record a meaningless winner. Clear both for the sweep's lifetime.
-    for var in ("AREAL_FLASH_BLOCKS", "AREAL_FLASH_BLOCK_TABLE"):
-        if _os.environ.pop(var, None) is not None:
-            print(f"[blocksweep] ignoring {var} for the sweep", flush=True)
-    fa.clear_block_table()
-    cands = _blocksweep_candidates(T, S)
-    if not cands:
-        sys.exit(f"blocksweep: no 128-multiple blocks divide T={T} S={S}")
-    rng = np.random.RandomState(0)
-    q = jnp.asarray(rng.randn(B, T, Hq, D).astype(np.float32) * 0.3,
-                    jnp.bfloat16)
-    k = jnp.asarray(rng.randn(B, S, Hkv, D).astype(np.float32) * 0.3,
-                    jnp.bfloat16)
-    v = jnp.asarray(rng.randn(B, S, Hkv, D).astype(np.float32) * 0.3,
-                    jnp.bfloat16)
-    # bench-like packing: two docs per row
-    seg = np.ones((B, T), np.int32)
-    seg[:, T // 2:] = 2
-    pos = np.concatenate([np.arange(T // 2), np.arange(T - T // 2)])
-    pos = np.tile(pos, (B, 1)).astype(np.int32)
-    seg, pos = jnp.asarray(seg), jnp.asarray(pos)
-
-    def run(bq, bkv):
-        fa.set_block_sizes(T, S, bq, bkv)
-
-        def loss(q):
-            o = fa.flash_attention(q, k, v, seg, seg, q_positions=pos,
-                                   kv_positions=pos)
-            return jnp.sum(o.astype(jnp.float32) ** 2)
-
-        g = jax.jit(jax.grad(loss))
-        g(q).block_until_ready()  # compile
-        t0 = time.perf_counter()
-        for _ in range(10):
-            out = g(q)
-        out.block_until_ready()
-        return (time.perf_counter() - t0) / 10
-
-    results = []
-    for bq, bkv in cands:
-        try:
-            dt = run(bq, bkv)
-        except Exception as e:  # noqa: BLE001 — kernel may reject a combo
-            print(f"[blocksweep] bq={bq:<5} bkv={bkv:<5} FAILED: "
-                  f"{type(e).__name__}", flush=True)
-            continue
-        results.append((dt, bq, bkv))
-        print(f"[blocksweep] bq={bq:<5} bkv={bkv:<5} {dt * 1e3:8.2f} ms",
-              flush=True)
-    fa.clear_block_table()
-    if not results:
-        sys.exit("blocksweep: every candidate failed")
-    results.sort()
-    dt, bq, bkv = results[0]
-    heur = fa.pick_block_sizes(T, S)
-    print(f"[blocksweep] winner: bq={bq} bkv={bkv} ({dt * 1e3:.2f} ms; "
-          f"pick_tile default is {heur})")
-    out_path = out_path or _os.path.join("profiles", "flash_blocks.json")
-    _os.makedirs(_os.path.dirname(out_path) or ".", exist_ok=True)
-    table = {}
-    if _os.path.exists(out_path):
-        try:
-            with open(out_path) as f:
-                table = _json.load(f)
-        except (OSError, ValueError):
-            pass
-    table[f"{T},{S}"] = [bq, bkv]
-    with open(out_path, "w") as f:
-        _json.dump(table, f, indent=1, sort_keys=True)
-    print(f"[blocksweep] recorded to {out_path} "
-          f"(use: AREAL_FLASH_BLOCK_TABLE={out_path})")
-
-
-def reshard_bench(src_spec: str = "f2t2", dst_spec: str = "d4",
-                  group_mb: int = 64, n_layers: int = 8,
-                  dim: int = 1024) -> None:
-    """Time the mesh→mesh on-device reshard (parallel/reshard.py) between
-    two ParallelSpecs on whatever devices this process has (CPU test
-    meshes under JAX_PLATFORMS=cpu, real chips otherwise): per
-    transfer-group dispatch→barrier latency and MB/s, plus the end-to-end
-    publish figure the ``device`` weight-sync transport would pay."""
-    import jax
-    import jax.numpy as jnp
-
-    from areal_tpu.parallel import mesh as pm
-    from areal_tpu.parallel import reshard as rsh
-    from areal_tpu.parallel import sharding as psh
-
-    src = pm.ParallelSpec.parse(src_spec)
-    dst = pm.ParallelSpec.parse(dst_spec)
-    n_dev = len(jax.devices())
-    for label, spec in (("src", src), ("dst", dst)):
-        if spec.world_size > n_dev:
-            sys.exit(f"reshard-bench: {label} spec '{spec}' needs "
-                     f"{spec.world_size} devices, have {n_dev} "
-                     f"(JAX_PLATFORMS=cpu + "
-                     f"XLA_FLAGS=--xla_force_host_platform_device_count=N "
-                     f"for a host-mesh dry run)")
-    src_mesh, dst_mesh = pm.make_mesh(src), pm.make_mesh(dst)
-    # Transformer-shaped synthetic tree: a stacked layer dict sharded the
-    # way training shards it, so the plan exercises the real per-leaf
-    # PartitionSpecs rather than a flat blob.
-    tree = {
-        "layers": {
-            "wq": jnp.zeros((n_layers, dim, dim), jnp.bfloat16),
-            "wo": jnp.zeros((n_layers, dim, dim), jnp.bfloat16),
-            "w_up": jnp.zeros((n_layers, dim, 4 * dim), jnp.bfloat16),
-            "w_down": jnp.zeros((n_layers, 4 * dim, dim), jnp.bfloat16),
-        },
-        "embedding": jnp.zeros((4096, dim), jnp.bfloat16),
-    }
-    specs = jax.tree.map(lambda _: None, tree)
-    specs["layers"] = {
-        "wq": psh.P(None, "fsdp", "tp"), "wo": psh.P(None, "tp", "fsdp"),
-        "w_up": psh.P(None, "fsdp", "tp"),
-        "w_down": psh.P(None, "tp", "fsdp"),
-    }
-    specs["embedding"] = psh.P("fsdp", "tp")
-    src_sh = jax.tree.map(
-        lambda s: jax.sharding.NamedSharding(src_mesh, s or psh.P()), specs,
-        is_leaf=lambda x: x is None or isinstance(x, psh.P),
-    )
-    dst_sh = jax.tree.map(
-        lambda s: jax.sharding.NamedSharding(dst_mesh, s or psh.P()), specs,
-        is_leaf=lambda x: x is None or isinstance(x, psh.P),
-    )
-    tree = jax.tree.map(jax.device_put, tree, src_sh)
-    jax.block_until_ready(tree)
-    flat_src = rsh._flatten(tree)
-    flat_dst = rsh._flatten(dst_sh)
-    plan = rsh.plan_reshard(flat_src, flat_dst,
-                            group_bytes=int(group_mb) << 20)
-    print(f"[reshard-bench] {src} -> {dst} on {n_dev} "
-          f"{jax.devices()[0].platform} devices: "
-          f"{plan.total_bytes >> 20} MB total, plan {plan.describe()}")
-    t_all = time.perf_counter()
-    for gi, group in enumerate(plan.groups):
-        g_bytes = sum(rsh._leaf_nbytes(flat_src[n]) for n in group)
-        t0 = time.perf_counter()
-        rsh._move_group(group, flat_src, flat_dst)
-        dt = time.perf_counter() - t0
-        print(f"[reshard-bench] group {gi}: {len(group)} leaves, "
-              f"{g_bytes >> 20:>5} MB, {dt * 1e3:8.2f} ms, "
-              f"{g_bytes / dt / 2 ** 20:10.1f} MB/s")
-    dt_all = time.perf_counter() - t_all
-    t0 = time.perf_counter()
-    _, plan2 = rsh.reshard_pytree(tree, dst_sh, group_mb=int(group_mb))
-    dt_pub = time.perf_counter() - t0
-    mbs = plan.moved_bytes / 2 ** 20
-    print(f"[reshard-bench] grouped total: {dt_all * 1e3:.2f} ms "
-          f"({mbs / max(dt_all, 1e-9):.1f} MB/s moved); "
-          f"end-to-end reshard_pytree: {dt_pub * 1e3:.2f} ms "
-          f"(zero-copy leaves: {len(plan2.identical)})")
-
-
-def ring_bench(sp_list=None, seq_list=None, reps: int = 3) -> None:
-    """Sweep ring attention v2 (parallel/ring.py) over (sp, seq_len) on
-    whatever devices this process has (host meshes under JAX_PLATFORMS=cpu
-    + XLA_FLAGS=--xla_force_host_platform_device_count=N, real chips
-    otherwise): fwd+bwd step time for the zig-zag schedule vs the
-    contiguous v1 oracle, plus the structural causal-skip ratio from the
-    trace-time area counters ((n+1)/2n at sp=n)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from areal_tpu.parallel import mesh as pm
-    from areal_tpu.parallel import ring as ring_mod
-
-    n_dev = len(jax.devices())
-    sp_list = sp_list or [s for s in (1, 2, 4, 8) if s <= n_dev]
-    seq_list = seq_list or [1024, 2048, 4096]
-    Hq, Hkv, Dh = 4, 2, 64
-    print(f"[ring-bench] {n_dev} {jax.devices()[0].platform} devices; "
-          f"B=1 Hq={Hq} Hkv={Hkv} Dh={Dh}; fwd+bwd attention step, "
-          f"zigzag (active) vs naive (v1 oracle)")
-    print(f"[ring-bench] {'sp':>3} {'seq_len':>8} {'zigzag_ms':>10} "
-          f"{'naive_ms':>9} {'speedup':>8} {'skip_ratio':>10}")
-    rng = np.random.RandomState(0)
-    for sp in sp_list:
-        mesh = pm.make_mesh(pm.ParallelSpec(sp=sp))
-        for T in seq_list:
-            if T % max(2 * sp, 1):
-                continue
-            q = jnp.asarray(rng.randn(1, T, Hq, Dh).astype(np.float32) * .1)
-            k = jnp.asarray(rng.randn(1, T, Hkv, Dh).astype(np.float32) * .1)
-            v = jnp.asarray(rng.randn(1, T, Hkv, Dh).astype(np.float32) * .1)
-            seg = jnp.ones((1, T), jnp.int32)
-            res = {}
-            for sched in ("zigzag", "naive"):
-                def loss(q, k, v, sched=sched):
-                    o = ring_mod.ring_attention(q, k, v, seg, mesh,
-                                                schedule=sched)
-                    return jnp.sum(o * o)
-
-                f = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-                ring_mod.reset_ring_counters()
-                jax.block_until_ready(f(q, k, v))  # compile; fill counters
-                ratio = ring_mod.ring_skip_ratio()
-                t0 = time.perf_counter()
-                for _ in range(reps):
-                    g = f(q, k, v)
-                jax.block_until_ready(g)
-                res[sched] = ((time.perf_counter() - t0) / reps * 1e3, ratio)
-            zz, nv = res["zigzag"], res["naive"]
-            print(f"[ring-bench] {sp:>3} {T:>8} {zz[0]:>10.2f} "
-                  f"{nv[0]:>9.2f} {nv[0] / max(zz[0], 1e-9):>7.2f}x "
-                  f"{zz[1]:>10.3f}")
-
-
-def moe_bench(e_list=None, k_list=None, cf_list=None, reps: int = 3,
-              n_tokens: int = 4096, dim: int = 256) -> None:
-    """Sweep the MoE dispatch paths (models/moe.py) over (num_experts,
-    top_k, capacity_factor): one MoE layer's fwd+bwd step time for the
-    sort-based grouped-GEMM path (the default) vs the one-hot einsum
-    oracle (AREAL_MOE_DISPATCH=einsum), plus the fraction of routed
-    assignments dropped at the capacity boundary. The einsum oracle pays
-    O(tokens x E x capacity) ~ O(k*cf*tokens^2) one-hot dispatch/combine
-    contractions plus dense [E, C] buffers; grouped replaces them with a
-    sort + ragged GEMMs. Caveat: ragged_dot's CPU lowering scales with E,
-    so host-mesh sweeps understate the grouped win at large E — the TPU
-    kernel does not."""
-    import dataclasses as _dc
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from areal_tpu.models import config as mcfg
-    from areal_tpu.models import moe as moe_mod
-
-    e_list = e_list or [4, 8, 16, 32]
-    k_list = k_list or [2]
-    cf_list = cf_list or [1.0, 2.0]
-    print(f"[moe-bench] {len(jax.devices())} "
-          f"{jax.devices()[0].platform} devices; tokens={n_tokens} "
-          f"dim={dim} ffn={dim * 2}; fwd+bwd one MoE layer, "
-          f"grouped (active) vs einsum (oracle)")
-    print(f"[moe-bench] {'E':>4} {'top_k':>5} {'cap_f':>5} "
-          f"{'grouped_ms':>10} {'einsum_ms':>10} {'speedup':>8} "
-          f"{'dropped':>8}")
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(8, n_tokens // 8, dim)
-                    .astype(np.float32) * 0.1)
-    for E in e_list:
-        for k in k_list:
-            if k > E:
-                continue
-            for cf in cf_list:
-                moe = mcfg.MoEConfig(num_experts=E, top_k=k,
-                                     capacity_factor=cf,
-                                     routed_intermediate_dim=dim * 2)
-                tcfg = mcfg.tiny_config(hidden_dim=dim, n_q_heads=4,
-                                        n_kv_heads=2, moe=_dc.asdict(moe))
-                stacked = moe_mod.init_moe_params(
-                    _dc.replace(tcfg, n_layers=1), jax.random.PRNGKey(0),
-                    jnp.float32)
-                lp = {name: w[0] for name, w in stacked.items()}
-                res = {}
-                for disp in ("grouped", "einsum"):
-                    def loss(lp, x, disp=disp):
-                        y, aux = moe_mod.moe_mlp(x, lp, moe, dispatch=disp)
-                        return jnp.sum(y * y), aux["dropped_frac"]
-
-                    f = jax.jit(jax.grad(loss, has_aux=True))
-                    _, dropped = f(lp, x)
-                    jax.block_until_ready(dropped)  # compile
-                    t0 = time.perf_counter()
-                    for _ in range(reps):
-                        g, dropped = f(lp, x)
-                    jax.block_until_ready(g)
-                    res[disp] = ((time.perf_counter() - t0) / reps * 1e3,
-                                 float(dropped))
-                gr, ei = res["grouped"], res["einsum"]
-                print(f"[moe-bench] {E:>4} {k:>5} {cf:>5.2f} "
-                      f"{gr[0]:>10.2f} {ei[0]:>10.2f} "
-                      f"{ei[0] / max(gr[0], 1e-9):>7.2f}x {gr[1]:>8.3f}")
-
-
-def _dispatch_fleet_commands(argv) -> bool:
-    if not argv or argv[0] not in ("scrape", "decode-bench", "trace",
-                                   "flight-dump", "packfill", "blocksweep",
-                                   "profile-trigger", "profile-status",
-                                   "fleet-status", "drain", "cordon",
-                                   "uncordon", "reward-bench", "alerts",
-                                   "silence", "goodput", "reshard-bench",
-                                   "ring-bench", "moe-bench",
-                                   "spool-status", "compile-status",
-                                   "mem-status"):
-        return False
+def main(argv) -> int:
+    if not argv or argv[0] not in COMMANDS:
+        print(__doc__, file=sys.stderr)
+        return 1
     cmd = argv[0]
     try:
         if cmd == "fleet-status":
@@ -1541,14 +1147,6 @@ def _dispatch_fleet_commands(argv) -> bool:
             else:
                 reward_bench(argv[1], argv[2],
                              int(argv[3]) if len(argv) > 3 else 32)
-        elif cmd == "packfill":
-            packfill(argv[1:])
-        elif cmd == "blocksweep":
-            blocksweep(
-                int(argv[1]) if len(argv) > 1 else 1792,
-                int(argv[2]) if len(argv) > 2 else 1792,
-                argv[3] if len(argv) > 3 else None,
-            )
         elif cmd == "alerts":
             alerts(argv[1],
                    argv[2] if len(argv) > 2 else "",
@@ -1563,30 +1161,6 @@ def _dispatch_fleet_commands(argv) -> bool:
             else:
                 goodput_view(argv[1], argv[2], window_secs=(
                     float(argv[3]) if len(argv) > 3 else 5.0))
-        elif cmd == "reshard-bench":
-            reshard_bench(
-                argv[1] if len(argv) > 1 else "f2t2",
-                argv[2] if len(argv) > 2 else "d4",
-                int(argv[3]) if len(argv) > 3 else 64,
-                int(argv[4]) if len(argv) > 4 else 8,
-                int(argv[5]) if len(argv) > 5 else 1024,
-            )
-        elif cmd == "ring-bench":
-            ring_bench(
-                [int(x) for x in argv[1].split(",")] if len(argv) > 1
-                else None,
-                [int(x) for x in argv[2].split(",")] if len(argv) > 2
-                else None,
-            )
-        elif cmd == "moe-bench":
-            moe_bench(
-                [int(x) for x in argv[1].split(",")] if len(argv) > 1
-                else None,
-                [int(x) for x in argv[2].split(",")] if len(argv) > 2
-                else None,
-                [float(x) for x in argv[3].split(",")] if len(argv) > 3
-                else None,
-            )
         elif cmd == "profile-trigger":
             profile_trigger(argv[1], argv[2], argv[3],
                             float(argv[4]) if len(argv) > 4 else 5.0)
@@ -1594,210 +1168,9 @@ def _dispatch_fleet_commands(argv) -> bool:
             profile_status(argv[1], argv[2])
     except IndexError:
         print(f"missing operand for {cmd!r}\n\n{__doc__}", file=sys.stderr)
-        sys.exit(1)
-    return True
-
-
-if _dispatch_fleet_commands(sys.argv[1:]):
-    sys.exit(0)
-
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-
-def build(remat=True, length_bucket=512, rows_bucket=4, seqs_bucket=16,
-          attn_impl="auto"):
-    from areal_tpu.algorithms.ppo import PPOActorInterface, PPOHyperparameters
-    from areal_tpu.api.data import MicroBatchSpec, SequenceSample
-    from areal_tpu.api.model import FinetuneSpec, Model
-    from areal_tpu.backend.jax_train import JaxTrainBackend, OptimizerConfig
-    from areal_tpu.models import transformer
-    from areal_tpu.models.config import TransformerConfig
-
-    cfg = TransformerConfig(
-        n_layers=24, hidden_dim=896, n_q_heads=14, n_kv_heads=2, head_dim=64,
-        intermediate_dim=4864, vocab_size=151936, rotary_base=1e6,
-        tie_word_embeddings=True, use_attention_bias=True, dtype="bfloat16",
-    )
-    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
-    model = Model("actor", (cfg, params), tokenizer=None)
-    backend = JaxTrainBackend(
-        optimizer=OptimizerConfig(lr=1e-5, lr_scheduler_type="constant",
-                                  warmup_steps_proportion=0.0),
-        compute_dtype="bfloat16", length_bucket=length_bucket,
-        rows_bucket=rows_bucket, seqs_bucket=seqs_bucket, remat=remat,
-        attn_impl=attn_impl,
-    )
-    model = backend.initialize(model, FinetuneSpec(1, 512, 64))
-    hp = PPOHyperparameters(ppo_n_minibatches=1, adv_norm=True,
-                            kl_ctl=0.0, disable_value=True)
-    iface = PPOActorInterface(hp)
-
-    rng = np.random.RandomState(0)
-    n_seq = 32
-    plens = rng.randint(200, 257, n_seq)
-    glens = rng.randint(512, 769, n_seq)
-    seqlens = (plens + glens).astype(int)
-    total = int(seqlens.sum())
-    toks = rng.randint(2, cfg.vocab_size, total).astype(np.int32)
-    pmask, lps = [], []
-    for p, g in zip(plens, glens):
-        pmask.append(np.concatenate([np.ones(p, np.int32), np.zeros(g, np.int32)]))
-        lps.append(np.concatenate([np.zeros(p, np.float32),
-                                   -rng.rand(g).astype(np.float32)]))
-    batch = SequenceSample.from_default(
-        ids=[f"b{i}" for i in range(n_seq)],
-        data={
-            "packed_input_ids": toks,
-            "prompt_mask": np.concatenate(pmask),
-            "packed_logprobs": np.concatenate(lps),
-            "rewards": rng.rand(n_seq).astype(np.float32),
-            "seq_no_eos_mask": np.zeros(n_seq, np.float32),
-        },
-        seqlens=seqlens.tolist(),
-    )
-    return cfg, model, iface, batch, total
-
-
-PEAK = 197e12  # v5e bf16
-
-
-def report(tag, total, dt, steps, cfg_nparams, remat):
-    tps = steps * total / dt
-    mfu = 6.0 * cfg_nparams * total * steps / dt / PEAK
-    print(f"[{tag}] {tps:,.0f} tok/s  step={dt/steps*1e3:.0f}ms  "
-          f"MFU(6N)={mfu:.3f}", flush=True)
-
-
-def main():
-    probes = sys.argv[1:] or ["e2e", "grad"]
-    from areal_tpu.api.data import MicroBatchSpec
-    from areal_tpu.backend import microbatch as mbu
-    from areal_tpu.models import transformer
-
-    spec = MicroBatchSpec(max_tokens_per_mb=4096)
-
-    if "e2e" in probes or "grad" in probes or "trace" in probes:
-        cfg, model, iface, batch, total = build()
-        nparams = transformer.param_count(cfg)
-        eng = model.module
-        iface.train_step(model, batch, spec)  # compile
-        jax.block_until_ready(eng.params)
-
-        if "e2e" in probes:
-            t0 = time.perf_counter()
-            for _ in range(3):
-                iface.train_step(model, batch, spec)
-            jax.block_until_ready(eng.params)
-            report("e2e remat=T mb=4096", total, time.perf_counter() - t0, 3,
-                   nparams, True)
-
-        if "grad" in probes or "trace" in probes:
-            # Device-only: one microbatch's grad step, timed in a tight loop
-            # with a single final sync → pure kernel throughput.
-            from areal_tpu.algorithms import ppo as ppomod
-            extra = ppomod.compute_advantages_and_returns(batch, iface.hp, 0.0)
-            extra.pop("_mean_kl")
-            b2 = ppomod.attach_keys(batch, extra)
-            ppomod.normalize_advantages(b2, iface.hp)
-            mbs = mbu.split_into_microbatches(
-                b2, spec, length_bucket=512, rows_bucket=4, seqs_bucket=16)
-            gfn = eng._get_grad_fn(iface._loss_fn, with_carry=False)
-            dbs = [eng._device_batch(mb) for mb in mbs]
-            ntok = sum(mb.n_tokens for mb in mbs)
-            ncells = sum(int(np.prod(mb.grids["tokens"].shape)) for mb in mbs)
-            print(f"[pack] {len(mbs)} mbs, fill={ntok/ncells:.2f} "
-                  f"({ntok} tok / {ncells} cells)", flush=True)
-            denom = jnp.asarray(1000.0, jnp.float32)
-            one = jnp.asarray(1.0, jnp.float32)
-            for db in dbs:
-                gfn(eng.params, db, denom, one, one)  # compile each shape
-            jax.block_until_ready(eng.params)
-
-            if "grad" in probes:
-                t0 = time.perf_counter()
-                outs = None
-                for _ in range(3):
-                    for db in dbs:
-                        outs = gfn(eng.params, db, denom, one, one)
-                jax.block_until_ready(outs)
-                report("grad-only (fwd+bwd, no opt)", ntok,
-                       time.perf_counter() - t0, 3, nparams, True)
-
-            if "trace" in probes:
-                import os
-                os.makedirs("profiles", exist_ok=True)
-                with jax.profiler.trace("profiles/bench_step"):
-                    iface.train_step(model, batch, spec)
-                    jax.block_until_ready(eng.params)
-                print("[trace] saved to profiles/bench_step", flush=True)
-
-    if "phases" in probes:
-        cfg, model, iface, batch, total = build()
-        eng = model.module
-        iface.train_step(model, batch, spec)
-        jax.block_until_ready(eng.params)
-        from areal_tpu.algorithms import ppo as ppomod
-        t = {}
-        for _ in range(3):
-            t0 = time.perf_counter()
-            extra = ppomod.compute_advantages_and_returns(batch, iface.hp, 0.0)
-            extra.pop("_mean_kl")
-            b2 = ppomod.attach_keys(batch, extra)
-            ppomod.normalize_advantages(b2, iface.hp)
-            t["adv+norm"] = t.get("adv+norm", 0) + time.perf_counter() - t0
-            t0 = time.perf_counter()
-            mbs = mbu.split_into_microbatches(
-                b2, spec, length_bucket=512, rows_bucket=4, seqs_bucket=16)
-            t["split+pack"] = t.get("split+pack", 0) + time.perf_counter() - t0
-            t0 = time.perf_counter()
-            dbs = [eng._device_batch(mb) for mb in mbs]
-            t["transfer"] = t.get("transfer", 0) + time.perf_counter() - t0
-            gfn = eng._get_grad_fn(iface._loss_fn, with_carry=False)
-            t0 = time.perf_counter()
-            denom = jnp.asarray(1000.0, jnp.float32)
-            one = jnp.asarray(1.0, jnp.float32)
-            o = None
-            ga = None
-            for db in dbs:
-                loss, stats, grads = gfn(eng.params, db, denom, one, one)
-                ga = grads if ga is None else jax.tree.map(jnp.add, ga, grads)
-                o = loss
-            jax.block_until_ready(o)
-            t["grad+acc"] = t.get("grad+acc", 0) + time.perf_counter() - t0
-            t0 = time.perf_counter()
-            jax.block_until_ready(ga)
-            t["acc_drain"] = t.get("acc_drain", 0) + time.perf_counter() - t0
-        for k, v in t.items():
-            print(f"[phase] {k}: {v/3*1e3:.0f}ms", flush=True)
-
-    if "remat" in probes:
-        cfg, model, iface, batch, total = build(remat=False)
-        nparams = transformer.param_count(cfg)
-        iface.train_step(model, batch, spec)
-        jax.block_until_ready(model.module.params)
-        t0 = time.perf_counter()
-        for _ in range(3):
-            iface.train_step(model, batch, spec)
-        jax.block_until_ready(model.module.params)
-        report("e2e remat=F mb=4096", total, time.perf_counter() - t0, 3,
-               nparams, False)
-
-    if "mbsweep" in probes:
-        for cap in (8192, 16384, 32768):
-            cfg, model, iface, batch, total = build()
-            nparams = transformer.param_count(cfg)
-            sp = MicroBatchSpec(max_tokens_per_mb=cap)
-            iface.train_step(model, batch, sp)
-            jax.block_until_ready(model.module.params)
-            t0 = time.perf_counter()
-            for _ in range(3):
-                iface.train_step(model, batch, sp)
-            jax.block_until_ready(model.module.params)
-            report(f"e2e remat=T mb={cap}", total, time.perf_counter() - t0,
-                   3, nparams, True)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main(sys.argv[1:]))
