@@ -11,8 +11,9 @@ Engine). TPU-first differences:
  - No pipeline-schedule VM (instruction.py/pipe_runner.py): micro-batches
    exist only to bound activation HBM; each one is a full jitted step and
    gradients accumulate across them on device.
- - Mixed precision: params live in f32 (or cfg dtype), compute is cast per
-   step to ``compute_dtype`` (bf16 on the MXU); no loss scaling needed.
+ - Mixed precision: params live in f32 (or cfg dtype); the programs compute
+   with a ``compute_dtype`` copy (bf16 on the MXU) that the optimizer step
+   writes once per weights version; no loss scaling needed.
 """
 
 from __future__ import annotations
@@ -331,14 +332,19 @@ class JaxTrainEngine(TrainableEngine):
             # choice: bf16's ~3 significant digits round away small
             # Adam updates (the reference's Megatron DistributedOptimizer
             # keeps f32 masters for the same reason). Compute still runs
-            # in compute_dtype via _cast. (No buffer donation here: the
-            # caller's tree must stay valid — callers that need the
-            # transient peak gone should drop their reference.)
+            # in compute_dtype, on the copy (compute_params). (No buffer
+            # donation here: the caller's tree must stay valid — callers
+            # that need the transient peak gone should drop their
+            # reference.)
             params = jax.tree.map(
                 lambda x: x.astype(jnp.float32)
                 if jnp.issubdtype(x.dtype, jnp.floating) else x,
                 params,
             )
+        self._compute = None  # the compute-dtype copy; None = to rebuild
+        self._compute_shared = False
+        self._cast_fn = None
+        self.param_cast_rebuilds = 0
         self.params = params
         self.opt_cfg = opt_cfg
         self.tx = None
@@ -398,37 +404,88 @@ class JaxTrainEngine(TrainableEngine):
 
         return contextlib.nullcontext()
 
-    def _cast(self, params):
+    # -------------- the compute-dtype copy of the weights --------------
+    #
+    # The programs compute with the masters' floating leaves in
+    # ``compute_dtype``. That tree has the lifetime of a weights version:
+    # the program that changes the weights (train_apply) writes it beside
+    # the new masters, and every grad, inference and generate call reads
+    # it. Any other write of ``params`` drops it, and the next reader
+    # rebuilds it with one ``param_cast`` program.
+
+    @property
+    def params(self):
+        """The masters (float32 under an optimizer)."""
+        return self._params
+
+    @params.setter
+    def params(self, tree):
+        self._params = tree
+        self._compute = None
+        # Nothing to cast (float32 compute; bf16 weights without an
+        # optimizer): the copy IS the masters' tree, read off the leaves.
+        self._copy_is_params = all(
+            x.dtype == self.compute_dtype for x in jax.tree.leaves(tree)
+            if jnp.issubdtype(x.dtype, jnp.floating))
+
+    def _to_compute(self, x):
+        """One leaf in the compute dtype (a non-floating one as it is)."""
         cd = self.compute_dtype
+        return x.astype(cd) if jnp.issubdtype(x.dtype, jnp.floating) else x
 
-        def c(x):
-            return x.astype(cd) if jnp.issubdtype(x.dtype, jnp.floating) else x
-
+    def _cast(self, params):
         with jax.named_scope("param_cast"):
-            return jax.tree.map(c, params)
+            return jax.tree.map(self._to_compute, params)
 
-    def _value_and_grad(self, lf: Callable, params):
-        """``jax.value_and_grad(lf, has_aux=True)(params)`` taken through
-        the compute-dtype copy: the gradient of the copy IS the gradient
-        of the masters (the cast's transpose only widens it), so it is
-        produced in the compute dtype — half the bytes of a float32 tree
-        between the backward pass and the accumulation — and widened to
-        the masters' dtype leaf by leaf, where the add into the carry
-        reads it. ``lf`` casts again inside, which is then the identity."""
-        out, grads = jax.value_and_grad(lf, has_aux=True)(self._cast(params))
+    def compute_params(self, share: bool = False):
+        """The tree the programs compute with, sharded leaf by leaf like
+        the masters. ``share``: the caller keeps it beyond this call (a
+        weight publish gathers it in the background, the device transport
+        hands its buffers to the decoders), so the next train_apply must
+        not donate it and writes a new one instead."""
+        if self._copy_is_params:
+            return self._params
+        if self._compute is None:
+            if self._cast_fn is None:
+
+                def param_cast(params):
+                    return self._cast(params)
+
+                self._cast_fn = compile_watch.watched_jit(
+                    "train/param_cast", jax.jit(param_cast))
+            self._compute = self._cast_fn(self._params)
+            self._compute_shared = False
+            self.param_cast_rebuilds += 1
+            telemetry.inc("train/param_cast_rebuilds")
+            logger.info("compute-dtype copy of the weights rebuilt outside "
+                        f"train_apply (#{self.param_cast_rebuilds})")
+        self._compute_shared |= share
+        return self._compute
+
+    def _value_and_grad(self, lf: Callable, compute_params):
+        """``jax.value_and_grad(lf, has_aux=True)`` at the compute-dtype
+        copy: the gradient of the copy IS the gradient of the masters (the
+        cast's transpose only widens it), so it is produced in the compute
+        dtype — half the bytes of a float32 tree between the backward pass
+        and the accumulation — and widened to the masters' dtype leaf by
+        leaf, where the add into the carry reads it. Only the masters'
+        dtypes are read, at trace time: the float32 tree is no input."""
+        out, grads = jax.value_and_grad(lf, has_aux=True)(compute_params)
         with jax.named_scope("grad_accum"):
-            grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads, params)
+            grads = jax.tree.map(
+                lambda g, p: g.astype(p.dtype), grads, self._params)
         return out, grads
 
     def _model_forward(
         self, params, batch: Dict[str, jnp.ndarray], with_aux: bool = False,
         remat=False,
     ):
-        """``remat``: what a grad program's backward re-runs (an entry of
+        """``params``: the compute-dtype copy (:meth:`compute_params`).
+        ``remat``: what a grad program's backward re-runs (an entry of
         ``transformer.REMAT_ENTRIES``, see :meth:`_remat_for`); a program
         that is not differentiated keeps nothing either way."""
         out, _, aux = transformer.forward(
-            self._cast(params),
+            params,
             self.cfg,
             batch["tokens"],
             batch["positions"],
@@ -458,9 +515,8 @@ class JaxTrainEngine(TrainableEngine):
         (tensor_parallel/modules.py:1060) exists for the same reason."""
         from areal_tpu.algorithms import ppo_functional as F
 
-        cast = self._cast(params)
         h, _, aux = transformer.forward(
-            cast, self.cfg,
+            params, self.cfg,
             batch["tokens"], batch["positions"],
             segment_ids=batch["segment_ids"],
             attn_impl=self.attn_impl, remat=remat,
@@ -474,7 +530,7 @@ class JaxTrainEngine(TrainableEngine):
 
         @jax.checkpoint
         def chunk_scores(h_c, lab_c):
-            logits_c = transformer.apply_head(cast, self.cfg, h_c)
+            logits_c = transformer.apply_head(params, self.cfg, h_c)
             from areal_tpu.ops.xent import gather_logprobs
 
             return gather_logprobs(logits_c, lab_c)
@@ -502,8 +558,8 @@ class JaxTrainEngine(TrainableEngine):
 
     def _loss_and_grads(self, loss_fn: LossFn, remat, params, batch,
                         denom, aux_scale):
-        """((loss, stats), grads) of one micro-batch: the body both grad
-        programs share."""
+        """((loss, stats), grads) of one micro-batch at ``params``, the
+        compute-dtype copy."""
         use_lp = self._use_chunked_logprobs(loss_fn)
 
         def lf(p):
@@ -546,7 +602,8 @@ class JaxTrainEngine(TrainableEngine):
         if key in self._grad_fns:
             return self._grad_fns[key]
 
-        def train_apply(params, opt_state, grads, stats, cap):
+        def train_apply(params, opt_state, grads, stats, cap, old_copy):
+            del old_copy  # donated: the new copy is written in its place
             with jax.named_scope("grad_clip"):
                 gnorm = optax.global_norm(grads)
             # grad_clip and adam are scoped in build_optimizer's chain.
@@ -568,16 +625,27 @@ class JaxTrainEngine(TrainableEngine):
                     )
                 else:
                     apply = jnp.asarray(True)
-            return new_params, new_opt, gnorm, apply
+                # The next weights version's compute copy, cast where the
+                # masters are written (of a skipped update: the old ones):
+                # one more output of the fused update, which is ONE kernel
+                # under one name — so it stays in this scope, and
+                # "param_cast" names the program that only casts.
+                new_copy = (None if self._copy_is_params else jax.tree.map(
+                    self._to_compute, new_params))
+            return new_params, new_opt, new_copy, gnorm, apply
 
         # Donate params + opt_state (aliased into new_params/new_opt) AND
         # grads: no output aliases the grad buffers (XLA warns they are
         # "not usable" as outputs), but donating them still lets the
         # optimizer's f32 transients reuse those 2 bytes/param in place —
         # measured on a 16 GB v5e with the 0.5B model, withdrawing the
-        # grads donation OOMs the apply step.
+        # grads donation OOMs the apply step. The previous compute copy is
+        # donated so the new one takes its buffers (kept as an argument
+        # though no op reads it); None where there is none to give.
         self._grad_fns[key] = compile_watch.watched_jit(
-            "train/apply", jax.jit(train_apply, donate_argnums=(0, 1, 2))
+            "train/apply",
+            jax.jit(train_apply, donate_argnums=(0, 1, 2, 5),
+                    keep_unused=True),
         )
         return self._grad_fns[key]
 
@@ -614,28 +682,32 @@ class JaxTrainEngine(TrainableEngine):
     def _remat_budget_bytes(self, R: int, L: int) -> int:
         """The bytes of kept activations one chip has room for: its limit
         less a margin, less the trees the engine holds there (masters,
-        optimizer state, the gradient carry), less the rest of a grad
-        program — over what the compiler's heap takes per kept byte."""
+        optimizer state, the gradient carry, the compute-dtype copy of the
+        weights), less the rest of a grad program — over what the
+        compiler's heap takes per kept byte."""
         limit = self._device_bytes_limit()
         if limit is None:
             return 0
         params = _bytes_on_chip(self.params)
-        resident = 2 * params + _bytes_on_chip(self.opt_state)
+        # a tree of the masters' shapes in the compute dtype: the copy
+        # (resident, unless the masters are it) and a program's gradient
+        masters = jax.tree.leaves(self.params)[0].dtype.itemsize
+        weights = params * self.compute_dtype.itemsize // masters
+        copy = 0 if self._copy_is_params else weights
+        resident = 2 * params + _bytes_on_chip(self.opt_state) + copy
         free = limit * (1.0 - _LIMIT_MARGIN) - resident
-        return int((free - self._remat_reserve_bytes(R, L, params))
+        return int((free - self._remat_reserve_bytes(R, L, weights))
                    / _HEAP_PER_KEPT_BYTE)
 
-    def _remat_reserve_bytes(self, R: int, L: int, params: int) -> int:
+    def _remat_reserve_bytes(self, R: int, L: int, weights: int) -> int:
         """A grad program's temporaries besides the kept activations: the
-        compute-dtype copy of the weights, and the larger of its two
-        phases — the head (one chunk's logits with their softmax, or the
-        whole [rows, L, vocab] grid where the chunk does not divide L) or
-        the layers' backward (the gradient in the compute dtype, and one
-        layer's working set by its widest activation)."""
+        larger of its two phases — the head (one chunk's logits with their
+        softmax, or the whole [rows, L, vocab] grid where the chunk does
+        not divide L) or the layers' backward (the gradient in the compute
+        dtype, ``weights`` bytes, and one layer's working set by its
+        widest activation)."""
         cfg, size = self.cfg, self.compute_dtype.itemsize
         rows = self._rows_on_chip(R)
-        masters = jax.tree.leaves(self.params)[0].dtype.itemsize
-        weights = params * size // masters
         chunk = self.logprob_chunk if (
             self.logprob_chunk and L % self.logprob_chunk == 0) else L
         head = rows * chunk * cfg.vocab_size * _HEAD_BYTES_PER_LOGIT
@@ -644,7 +716,7 @@ class JaxTrainEngine(TrainableEngine):
                      * _LAYER_COPIES)
         else:  # a token's top_k rows through the expert exchange
             layer = cfg.moe.top_k * cfg.hidden_dim * _MOE_LAYER_COPIES
-        return int(weights + max(head, weights + rows * L * layer * size))
+        return int(max(head, weights + rows * L * layer * size))
 
     def _remat_for(self, R: int, L: int):
         """What the grad programs of the packed grid [R, L] keep for their
@@ -890,7 +962,7 @@ class JaxTrainEngine(TrainableEngine):
             for i, w in zip(idxs, weights):
                 denom = total_w if glob else w
                 args = [
-                    self.params, ub.grids, seq,
+                    self.compute_params(), ub.grids, seq,
                     jnp.asarray(i, jnp.int32),
                     jnp.asarray(denom, jnp.float32),
                     jnp.asarray(scale, jnp.float32),
@@ -918,11 +990,16 @@ class JaxTrainEngine(TrainableEngine):
         loss_acc, stats_acc, grads_acc = carry
         with telemetry.span("train/optimizer"):
             with telemetry.span("train/apply_dispatch"), self._mesh_ctx():
-                self.params, self.opt_state, gnorm, applied = \
-                    self._get_apply_fn(rule)(
-                        self.params, self.opt_state, grads_acc,
+                # A copy someone else holds (compute_params(share=True))
+                # is theirs to keep: not donated.
+                old_copy = None if self._compute_shared else self._compute
+                self._params, self.opt_state, self._compute, gnorm, \
+                    applied = self._get_apply_fn(rule)(
+                        self._params, self.opt_state, grads_acc,
                         dict(stats_acc), jnp.asarray(cap, jnp.float32),
+                        old_copy,
                     )
+                self._compute_shared = False
             with telemetry.span("train/fetch_stats"):
                 # optax evaluated the schedule at the PRE-increment count.
                 applied_lr = float(self.lr_schedule(self.opt_step_count))
@@ -1177,7 +1254,7 @@ class JaxTrainEngine(TrainableEngine):
                     ("seq_mask", mb.seq_mask))}
             with telemetry.span("infer/dispatch"), self._mesh_ctx(), \
                     dispatch_label("forward"):
-                out = fn(self.params, db)
+                out = fn(self.compute_params(), db)
             with telemetry.span("infer/fetch"):
                 outs.append(np.asarray(out))
         with telemetry.span("infer/scatter_back"):
@@ -1208,8 +1285,7 @@ class JaxTrainEngine(TrainableEngine):
         padded, plens = genmod.pad_prompts(prompts, pad_token_id)
         with self._mesh_ctx(), dispatch_label("generate"):
             out = genmod.generate_batch(
-                self.params if self.compute_dtype == jnp.float32
-                else self._cast(self.params),
+                self.compute_params(),
                 self.cfg,
                 jnp.asarray(padded),
                 jnp.asarray(plens),

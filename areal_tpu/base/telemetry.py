@@ -248,15 +248,16 @@ _ANNOTATION_CLS: Any = None
 # tests/test_step_timeline.py holds them to this list.
 DEVICE_PROGRAMS = (
     "infer_forward", "train_grad_sliced", "train_apply", "adv_prep",
-    "opt_init",
+    "opt_init", "param_cast",
 )
 DEVICE_SCOPES = (
     # models/transformer.py, once a block unless said
     "embed", "attn_norm", "qkv_proj", "rope", "attention", "o_proj",
     "mlp_norm", "mlp", "moe", "layer_scan", "final_norm", "head",
     "xent",                                   # ops/xent.py
-    "param_cast", "grad_accum",               # backend/jax_train.py
+    "grad_accum",                             # backend/jax_train.py
     "grad_clip", "adam", "param_update",      # the apply program
+    "param_cast",                             # program param_cast only
     "ppo_loss", "gae",                        # algorithms/ppo.py
 )
 # Inside "moe" (models/moe.py): the router (matmul, softmax, top-k and the

@@ -346,6 +346,13 @@ class TrainerWorker:
                 role: m.module.remat_plan() for role, m in self.models.items()
                 if hasattr(m.module, "remat_plan")
             },
+            # {model: casts of the whole tree outside train_apply}: one at
+            # first use, one more per external write of the weights
+            param_cast_rebuilds={
+                role: m.module.param_cast_rebuilds
+                for role, m in self.models.items()
+                if hasattr(m.module, "param_cast_rebuilds")
+            },
             compile_cache=compile_watch.cache_stats(),
             native_ops="g++" if native.available() else "numpy",
         )
@@ -725,23 +732,12 @@ class TrainerWorker:
         )
 
     def _compute_dtype_params(self, role: str):
-        """The role's params cast (on device) to the compute dtype —
-        weight-sync payloads travel in bf16: the generation fleet computes
-        in bf16 anyway, and casting before the d2h halves transport bytes
-        vs shipping the f32 masters."""
-        import jax
-        import jax.numpy as jnp
-
-        engine = self.models[role].module
-        params = engine.params
-        cd = getattr(engine, "compute_dtype", jnp.float32)
-        if cd != jnp.float32:
-            params = jax.tree.map(
-                lambda x: x.astype(cd)
-                if jnp.issubdtype(x.dtype, jnp.floating) else x,
-                params,
-            )
-        return params
+        """The role's compute-dtype copy of its weights, as the engine
+        keeps it — weight-sync payloads travel in bf16: the generation
+        fleet computes in bf16 anyway, and halves the transport bytes of
+        the f32 masters. Shared: a publish reads it after this step ends,
+        so the engine's next apply leaves it alone."""
+        return self.models[role].module.compute_params(share=True)
 
     def publish_weights(self, role: str) -> None:
         """The §3.5 weight-sync path: make the role's weights visible to

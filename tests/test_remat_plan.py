@@ -256,6 +256,74 @@ def test_plan_records_entry_bytes_and_budget():
             > rec["budget_bytes"])
 
 
+# What the chip recorded for the benchmark's seven grids, parent and change
+# alike (PERF.md §6, PR 28 and 29; `bytes_limit` of `memory_stats()` there):
+# (configuration, mesh, bytes_limit, R, L) -> (entry, kept estimate, budget).
+BENCHMARK_GRIDS = {
+    ("qwen2.5-0.5b", None, 16909336064, 8, 512):
+        ("attention", 539492352, 2247630524),
+    ("qwen2.5-0.5b", None, 16909336064, 1, 2688):
+        ("matmuls", 1907490816, 2707571183),
+    ("qwen2.5-0.5b", None, 16909336064, 1, 3072):
+        ("matmuls", 2141061120, 2957127074),
+    ("qwen2.5-0.5b", None, 16909336064, 1, 6016):
+        ("attention", 803733504, 1620438716),
+    ("qwen2.5-0.5b", None, 16909336064, 1, 7296):
+        ("attention", 995033088, 1202310844),
+    ("olmoe-1b-7b", "e4", 16909334528, 4, 3712):
+        ("matmuls", 375193600, 1857308104),
+    ("olmoe-1b-7b", "e4", 16909334528, 4, 3968):
+        ("matmuls", 396296192, 1756644808),
+}
+
+
+@pytest.mark.parametrize(
+    "grid", sorted(BENCHMARK_GRIDS, key=str),
+    ids=lambda g: f"{g[0]}-{g[3]}x{g[4]}")
+def test_benchmark_grids_keep_their_entries(grid, monkeypatch):
+    """The compute copy moved from a grad program's heap to the engine's
+    trees: the budget's sum is the same, so every grid of the benchmark
+    keeps the entry — and to a byte the budget — the chip recorded. The
+    engine's arithmetic at the real widths, on the bytes of the real
+    trees as their shardings cut them (nothing that size is allocated)."""
+    import json
+    import os
+
+    from benchmark import harness, weights
+
+    name, spec, limit, R, L = grid
+    with open(os.path.join(harness.BENCH_DIR, "configs", name + ".json")) as f:
+        cfg = weights.model_config(json.load(f))
+    mesh = None
+    if spec is not None:
+        if len(jax.devices()) < 4:
+            pytest.skip("needs 4 devices")
+        mesh = pmesh.make_mesh(pmesh.ParallelSpec.parse(spec))
+    small = dataclasses.replace(
+        tiny_config(), **(MOE_WIDTHS if cfg.moe is not None else QWEN_WIDTHS))
+    eng = _engine(small, mesh=mesh, limit=limit)  # as the trainer: bf16
+    shapes = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    if mesh is None:
+        masters = sum(x.size for x in jax.tree.leaves(shapes)) * 4
+    else:
+        masters = sum(
+            int(np.prod(sh.shard_shape(x.shape))) * 4
+            for x, sh in zip(jax.tree.leaves(shapes), jax.tree.leaves(
+                psh.named_shardings(mesh, psh.param_partition_specs(cfg)))))
+    counts = 8  # Adam's and the schedule's step counters, int32 each
+    trees = {id(eng.params): masters, id(eng.opt_state): 2 * masters + counts}
+    monkeypatch.setattr(jax_train, "_bytes_on_chip",
+                        lambda tree: trees[id(tree)])
+    eng.cfg = cfg
+    assert not eng._copy_is_params
+    entry, kept, budget = BENCHMARK_GRIDS[grid]
+    assert eng._remat_for(R, L) == entry
+    rec = eng.remat_plan()[f"{R}x{L}"]
+    assert rec["kept_bytes_estimate"] == kept
+    assert abs(rec["budget_bytes"] - budget) <= 1  # float order of the sum
+
+
 def test_full_when_the_device_names_no_limit():
     eng = _engine(limit=None)  # the CPU
     assert eng._remat_for(1, 1024) == "full"

@@ -3,10 +3,14 @@ an ``areal/<name>`` annotation in any ``jax.profiler`` capture, registry on
 or off; the spans of one ``inference`` + ``train_step`` nest as the code
 nests and carry the packer's counts; tracing adds no host sync; every
 jitted program of the train path has a name of its own and carries the
-``jax.named_scope`` names of ``telemetry.DEVICE_SCOPES``."""
+``jax.named_scope`` names of ``telemetry.DEVICE_SCOPES``; the weights are
+cast where they are written (``train_apply``) and in no program that
+reads them."""
 
+import collections
 import dataclasses
 import glob
+import math
 import os
 import re
 
@@ -211,11 +215,12 @@ def test_telemetry_adds_no_host_sync_to_a_step(monkeypatch, registry):
 # ---- names on the device ----
 
 @pytest.fixture(scope="module")
-def programs():
-    """HloModule name -> set of framework op names, for every program one
-    inference + train_step of the tiny model builds (advantages on the
-    device and, with group normalization, on the host)."""
-    seen = {}
+def recorded():
+    """For every program one inference + train_step of the tiny model
+    builds (advantages on the device and, with group normalization, on
+    the host), by HloModule name: the set of framework op names, and call
+    by call a count of the (dtype, shape) of the arrays it was given."""
+    seen, inputs = {}, {}
     orig = jax.jit
 
     def recording_jit(fn, *a, **kw):
@@ -228,12 +233,16 @@ def programs():
                 name = re.search(r"HloModule (\S+?),", text).group(1)
                 seen.setdefault(name, set()).update(
                     re.findall(r'op_name="([^"]*)"', text))
+                inputs.setdefault(name, []).append(
+                    collections.Counter(
+                        (str(x.dtype), x.shape)
+                        for x in jax.tree.leaves((args, kwargs))))
             return jitted(*args, **kwargs)
 
         return call
 
     def bf16_engine():
-        # as the trainer runs: f32 masters cast to bf16 every program call
+        # as the trainer runs: f32 masters, bf16 compute
         cfg = tiny_config(vocab_size=128)
         params = transformer.init_params(cfg, jax.random.PRNGKey(0))
         backend = jax_train.JaxTrainBackend(
@@ -253,7 +262,17 @@ def programs():
             _step(model, iface, _make_batch())
     finally:
         mp.undo()
-    return seen
+    return seen, inputs
+
+
+@pytest.fixture(scope="module")
+def programs(recorded):
+    return recorded[0]
+
+
+@pytest.fixture(scope="module")
+def program_inputs(recorded):
+    return recorded[1]
 
 
 def _scopes_in(op_names):
@@ -268,18 +287,47 @@ def _scopes_in(op_names):
 
 BLOCK = {"attn_norm", "qkv_proj", "rope", "attention", "o_proj", "mlp_norm",
          "mlp", "layer_scan"}
-FORWARD = BLOCK | {"embed", "final_norm", "head", "xent", "param_cast"}
+FORWARD = BLOCK | {"embed", "final_norm", "head", "xent"}
 EXPECTED_SCOPES = {
     "jit_infer_forward": FORWARD,
     "jit_train_grad_sliced": FORWARD | {"ppo_loss", "grad_accum"},
     "jit_train_apply": {"grad_clip", "adam", "param_update"},
     "jit_adv_prep": {"gae"},
     "jit_opt_init": set(),
+    "jit_param_cast": {"param_cast"},
 }
 
 
 def test_every_program_has_its_own_name(programs):
     assert set(programs) == {"jit_" + p for p in telemetry.DEVICE_PROGRAMS}
+
+
+@pytest.mark.parametrize("program", ["jit_infer_forward",
+                                     "jit_train_grad_sliced"])
+def test_programs_that_read_the_weights_do_not_cast_them(
+        programs, program_inputs, program):
+    """No ``param_cast`` op; the weights come in once, in bfloat16, and in
+    float32 comes nothing of their shapes but the gradient carry (the
+    matrices: the model's vectors share shapes with the batch's grids).
+    The copy is written where the weights are: an output of the update."""
+    assert "param_cast" not in _scopes_in(programs[program])
+    assert any(n.endswith("param_update/convert_element_type")
+               for n in programs["jit_train_apply"])
+    # the apply is handed the copy it replaces: that is the weights
+    weights = collections.Counter({
+        (dtype, shape): n
+        for call in program_inputs["jit_train_apply"]
+        for (dtype, shape), n in call.items()
+        if dtype == "bfloat16" and math.prod(shape) > 1000})
+    assert weights
+    carries = set()
+    for given in program_inputs[program]:
+        assert all(given[k] == n for k, n in weights.items())
+        wide = sum(given[("float32", shape)] for _, shape in weights)
+        assert wide in (0, sum(weights.values()))  # nothing, or the carry
+        carries.add(bool(wide))
+    assert False in carries  # a step's first grad program has no carry
+    assert program != "jit_infer_forward" or carries == {False}
 
 
 @pytest.mark.parametrize("program", sorted(EXPECTED_SCOPES))
