@@ -209,13 +209,15 @@ _MOE_SUMMED = tuple(f"moe_{k}" for k in moe_mod.SUMMED_AUX)
 
 def _moe_step_stats(fetched: Dict[str, Any], n_mbs: int) -> Dict[str, float]:
     """A step's routing health out of its fetched statistics: the (token,
-    expert) pairs routed per layer over the step, and the micro-batch
+    expert) pairs routed per layer over the step (on a share of an expert
+    layer also those that chose an expert held here), and the micro-batch
     means of the load ratio and the dropped share. {} for a dense model."""
     n = max(n_mbs, 1)
     return {
         k: float(fetched[k]) / (1 if k in _MOE_SUMMED else n)
-        for k in ("moe_routed_rows", "moe_expert_load_ratio",
-                  "moe_dropped_frac") if k in fetched
+        for k in ("moe_routed_rows", "moe_local_rows",
+                  "moe_expert_load_ratio", "moe_dropped_frac")
+        if k in fetched
     }
 
 
@@ -232,9 +234,13 @@ _HEAP_PER_KEPT_BYTE = 2.0
 # float32 softmax, the cotangent): measured 2.8-4.3.
 _HEAD_BYTES_PER_LOGIT = 4.3
 # Copies of a layer's widest activation that its backward holds: dense
-# (measured 8.7) and through the expert exchange (22.9, one configuration).
+# (measured 8.7), through the expert exchange (22.9, one configuration),
+# and of an expert layer whose experts are all on the chip that runs it —
+# a share, or no "ep" axis: no gathered tokens (6.0: the compiler's 1.59 GB
+# for Mellum 2's share at 1 x 6656 under "full", PERF.md §5, PR 32).
 _LAYER_COPIES = 9
 _MOE_LAYER_COPIES = 24
+_MOE_LOCAL_LAYER_COPIES = 7
 
 
 def _bytes_on_chip(tree) -> int:
@@ -368,6 +374,9 @@ class JaxTrainEngine(TrainableEngine):
         # {(R, L): what the grad programs of that packed grid keep for
         # their backward pass, and why} — see _remat_for / remat_plan.
         self._remat_plan: Dict[Tuple[int, int], Dict[str, Any]] = {}
+        # One period of the layer pattern, for the train/fwd_bwd span:
+        # "full" for most families, "sliding,sliding,sliding,full" (mellum).
+        self._layer_kinds = ",".join(self.cfg.period_kinds)
         # Static gate for MoE router input jitter: train steps thread a
         # per-micro-batch rng key through the batch dict iff this is set
         # (key presence is part of the jit trace, so the gate must not
@@ -674,10 +683,12 @@ class JaxTrainEngine(TrainableEngine):
         [R, L]: its rows split over the data axes (an axis that splits
         the sequence or the widths is not counted: an over-estimate)."""
         rows = self._rows_on_chip(R)
-        padded = kernel_padded_len(self.attn_impl, L, self.cfg.sliding_window)
+        flash = kernel_padded_len(self.attn_impl, L)
+        window = kernel_padded_len(self.attn_impl, L, self.cfg.sliding_window)
         return transformer.remat_kept_bytes(
             self.cfg, rows * L, self.compute_dtype.itemsize,
-            flash_tokens=rows * (padded or 0))
+            flash_tokens=rows * (flash or 0),
+            window_tokens=rows * (window or 0))
 
     def _remat_budget_bytes(self, R: int, L: int) -> int:
         """The bytes of kept activations one chip has room for: its limit
@@ -714,8 +725,12 @@ class JaxTrainEngine(TrainableEngine):
         if cfg.moe is None:
             layer = (max(cfg.intermediate_dim, cfg.q_dim, cfg.hidden_dim)
                      * _LAYER_COPIES)
-        else:  # a token's top_k rows through the expert exchange
-            layer = cfg.moe.top_k * cfg.hidden_dim * _MOE_LAYER_COPIES
+        else:  # a token's top_k rows, through the expert exchange or not
+            exchange = (self.mesh is not None
+                        and dict(self.mesh.shape).get("ep", 1) > 1
+                        and not cfg.moe.is_share)
+            layer = cfg.moe.top_k * cfg.hidden_dim * (
+                _MOE_LAYER_COPIES if exchange else _MOE_LOCAL_LAYER_COPIES)
         return int(max(head, weights + rows * L * layer * size))
 
     def _remat_for(self, R: int, L: int):
@@ -957,7 +972,8 @@ class JaxTrainEngine(TrainableEngine):
             )
         with telemetry.span("train/fwd_bwd", n_mbs=len(idxs),
                             grid=f"{ub.R}x{ub.L}",
-                            remat=str(self._remat_for(ub.R, ub.L))), \
+                            remat=str(self._remat_for(ub.R, ub.L)),
+                            layer_kinds=self._layer_kinds), \
                 memwatch.watermark("train/fwd_bwd"):
             for i, w in zip(idxs, weights):
                 denom = total_w if glob else w
@@ -1075,6 +1091,7 @@ class JaxTrainEngine(TrainableEngine):
         for stat, gauge in (
             ("moe_dropped_frac", "train/moe_dropped_frac"),
             ("moe_expert_load_ratio", "train/moe_expert_load_ratio"),
+            ("moe_local_rows", "train/moe_local_rows"),
         ):
             if stat in out:
                 telemetry.set_gauge(gauge, out[stat])

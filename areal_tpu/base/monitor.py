@@ -112,7 +112,11 @@ def transformer_flops_per_token(
     attn_score = 2 * 2 * q_dim * avg_seqlen  # QK^T and PV, causal avg ≈ L/2·2
     if moe is not None:
         fr = moe.routed_intermediate_dim or f
-        mlp = moe.top_k * 3 * 2 * d * fr + 2 * d * moe.num_experts
+        # on a share of the layer: the router scores all the published
+        # experts, and held / routed of a token's top_k run here
+        routed = getattr(moe, "n_routed", moe.num_experts)
+        mlp = (moe.top_k * moe.num_experts / routed * 3 * 2 * d * fr
+               + 2 * d * routed)
         if moe.shared_intermediate_dim:
             mlp += 3 * 2 * d * moe.shared_intermediate_dim
     else:

@@ -266,6 +266,11 @@ DEVICE_SCOPES = (
 # apart because they nest: a reader that knows only DEVICE_SCOPES sees
 # their ops under "moe".
 MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_exchange", "moe_experts")
+# Inside "attention" (ops/attention.py): the windowed kernel of a
+# sliding-window layer with its layout glue (ops/pallas/window_attention.py).
+# Listed apart like MOE_SCOPES: a reader that knows only DEVICE_SCOPES sees
+# its ops under "attention", beside the flash kernel's.
+WINDOW_SCOPES = ("window_attention",)
 
 
 def _annotation(name: str, attrs: Dict[str, Any]):

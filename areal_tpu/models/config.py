@@ -9,15 +9,29 @@ separate model classes.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+import math
+from typing import Optional, Tuple
+
+# The attention kinds a layer can have (``TransformerConfig.layer_kinds``).
+FULL, SLIDING = "full", "sliding"
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
     """Mirrors ReaLMoEConfig (reference model_api.py:294)."""
 
+    # Experts whose weights this model holds (``e_gate`` [E, ...]).
     num_experts: int = 8
     top_k: int = 2
+    # A SHARE of an expert layer: the model was published with
+    # ``router_experts`` experts (the router's width) and this process
+    # holds ``num_experts`` of them, from index ``first_expert`` on — one
+    # rank's part of an expert-parallel group, run without the others.
+    # The router scores all of them, the gates are normalised over all
+    # chosen ones, and a pair that chose an expert held elsewhere adds
+    # nothing here. None = every expert is held.
+    router_experts: Optional[int] = None
+    first_expert: int = 0
     # Expert-buffer size multiplier: capacity per expert is
     # ceil(top_k * n_tokens * capacity_factor / num_experts); overflow
     # tokens are dropped (contribute nothing), mirroring the reference's
@@ -31,6 +45,37 @@ class MoEConfig:
     z_loss_coeff: float = 0.0
     input_jitter_eps: float = 0.0
     norm_topk_prob: bool = True
+
+    @property
+    def n_routed(self) -> int:
+        """Experts the router chooses among (its width)."""
+        return self.router_experts or self.num_experts
+
+    @property
+    def is_share(self) -> bool:
+        return self.n_routed != self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeConfig:
+    """One RoPE table: plain (``factor`` None) or YaRN as ``transformers``
+    computes it (``_compute_yarn_parameters``)."""
+
+    base: float = 10000.0
+    factor: Optional[float] = None
+    original_max_position: Optional[int] = None
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    # multiplies cos and sin; None = 0.1 * ln(factor) + 1
+    attention_factor: Optional[float] = None
+
+    @property
+    def scale(self) -> float:
+        if self.factor is None:
+            return 1.0
+        if self.attention_factor is not None:
+            return self.attention_factor
+        return 0.1 * math.log(self.factor) + 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,6 +102,12 @@ class TransformerConfig:
     moe: Optional[MoEConfig] = None
     # sliding window attention (mistral/gemma2); None = full attention
     sliding_window: Optional[int] = None
+    # The attention kind of each layer, FULL or SLIDING (HF ``layer_types``);
+    # None = every layer alike: SLIDING where ``sliding_window`` is set.
+    layer_types: Optional[Tuple[str, ...]] = None
+    # (kind, RopeConfig) for the kinds whose RoPE is not the plain table
+    # at ``rotary_base`` (HF ``rope_parameters``, one block a layer type).
+    layer_rope: Optional[Tuple[Tuple[str, RopeConfig], ...]] = None
     # MLP activation: "silu" (llama family), "gelu_tanh" (gemma/gpt2),
     # "gelu" (exact)
     hidden_act: str = "silu"
@@ -93,6 +144,35 @@ class TransformerConfig:
     def group_size(self) -> int:
         assert self.n_q_heads % self.n_kv_heads == 0
         return self.n_q_heads // self.n_kv_heads
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """The attention kind of every layer."""
+        if self.layer_types is not None:
+            assert len(self.layer_types) == self.n_layers, (
+                f"{len(self.layer_types)} layer_types for "
+                f"{self.n_layers} layers")
+            return tuple(self.layer_types)
+        kind = SLIDING if self.sliding_window is not None else FULL
+        return (kind,) * self.n_layers
+
+    @property
+    def period_kinds(self) -> Tuple[str, ...]:
+        """One period of the layer pattern: the shortest prefix of
+        ``layer_kinds`` that, repeated, gives all of it. The layer scan
+        runs over periods (models/transformer.py); a model whose layers
+        are alike has a period of one layer."""
+        kinds = self.layer_kinds
+        return next(
+            kinds[:p] for p in range(1, len(kinds) + 1)
+            if len(kinds) % p == 0 and kinds[:p] * (len(kinds) // p) == kinds)
+
+    def window_of(self, kind: str) -> Optional[int]:
+        return self.sliding_window if kind == SLIDING else None
+
+    def rope_of(self, kind: str) -> RopeConfig:
+        return dict(self.layer_rope or ()).get(
+            kind, RopeConfig(base=self.rotary_base))
 
 
 def tiny_config(

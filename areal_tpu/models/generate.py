@@ -21,7 +21,11 @@ import numpy as np
 
 from areal_tpu.api.model import GenerationHyperparameters
 from areal_tpu.models.config import TransformerConfig
-from areal_tpu.models.transformer import forward, init_kv_cache
+from areal_tpu.models.transformer import (
+    forward,
+    init_kv_cache,
+    kv_valid_by_kind,
+)
 from areal_tpu.ops.sampling import (
     sample_token,
     sample_token_rows,
@@ -100,14 +104,14 @@ def generate_batch(
         valid = (slot_ids[None, :] < prompt_lens[:, None]) | (
             (slot_ids[None, :] >= P) & (slot_ids[None, :] <= P + n)
         )
-        if cfg.sliding_window is not None:
-            # Cache slot j holds position j (prompt) or plen + (j - P) (decode).
-            slot_pos = jnp.where(
-                slot_ids[None, :] < P,
-                slot_ids[None, :],
-                prompt_lens[:, None] + (slot_ids[None, :] - P),
-            )
-            valid = valid & ((pos[:, None] - slot_pos) < cfg.sliding_window)
+        # Cache slot j holds position j (prompt) or plen + (j - P) (decode);
+        # a sliding-window layer reads only the slots inside its window.
+        slot_pos = jnp.where(
+            slot_ids[None, :] < P,
+            slot_ids[None, :],
+            prompt_lens[:, None] + (slot_ids[None, :] - P),
+        )
+        valid = kv_valid_by_kind(cfg, valid, pos[:, None] - slot_pos)
         logits_step, kv_cache = forward(
             params,
             cfg,
@@ -244,10 +248,7 @@ def decode_chunk_rows(
 
         pos = cur_len  # [B] slot & RoPE position of the new token
         valid = slot_ids[None, :] <= pos[:, None]
-        if cfg.sliding_window is not None:
-            valid = valid & (
-                (pos[:, None] - slot_ids[None, :]) < cfg.sliding_window
-            )
+        valid = kv_valid_by_kind(cfg, valid, pos[:, None] - slot_ids[None, :])
         logits_step, kv = forward(
             params, cfg, token[:, None], pos[:, None],
             kv_cache={"k": kv_k, "v": kv_v},
@@ -360,11 +361,8 @@ def extend_state(
     # slots j ≤ cur[b] + t (its own slot included — written above before
     # attention — but never its padded/future siblings).
     kv_valid = slot_ids[None, None, :] <= positions[:, :, None]  # [B, T, S]
-    if cfg.sliding_window is not None:
-        kv_valid = kv_valid & (
-            (positions[:, :, None] - slot_ids[None, None, :])
-            < cfg.sliding_window
-        )
+    kv_valid = kv_valid_by_kind(
+        cfg, kv_valid, positions[:, :, None] - slot_ids[None, None, :])
     logits, kv = forward(
         params, cfg, tokens, positions,
         kv_cache={"k": state["kv_k"], "v": state["kv_v"]},

@@ -4,7 +4,7 @@ Parity target: the reference's per-family converter registry
 (``realhf/impl/model/conversion/hf_registry.py:32`` +
 ``realhf/api/from_hf/{llama,qwen2,qwen3,gemma,gpt2,mistral,mixtral}.py``).
 Families covered: llama, qwen2 (qwen2.5), qwen3, mistral, gemma, gpt2,
-mixtral, qwen3_moe, olmoe.
+mixtral, qwen3_moe, olmoe, mellum.
 
 Weights are stacked on a leading layer axis (see models/transformer.py), so
 conversion transposes HF's ``[out, in]`` linear layout to ``[in, out]`` and
@@ -25,7 +25,13 @@ from typing import Any, Callable, Dict, List, Optional
 import numpy as np
 
 from areal_tpu.base import logging
-from areal_tpu.models.config import MoEConfig, TransformerConfig
+from areal_tpu.models.config import (
+    FULL,
+    SLIDING,
+    MoEConfig,
+    RopeConfig,
+    TransformerConfig,
+)
 
 logger = logging.getLogger("models.hf")
 
@@ -167,6 +173,97 @@ def _olmoe_config(hf_config: Any) -> TransformerConfig:
     )
 
 
+# HF ``layer_types`` entry <-> attention kind.
+_HF_LAYER_TYPES = {"full_attention": FULL, "sliding_attention": SLIDING}
+
+
+def _get(block: Any, key: str, default=None):
+    """A key of a nested config block: a dict (config.json) or an object."""
+    if isinstance(block, dict):
+        return block.get(key, default)
+    return getattr(block, key, default)
+
+
+def _rope_config(block: Any) -> RopeConfig:
+    """One block of HF ``rope_parameters``."""
+    if _get(block, "rope_type", "default") == "default":
+        return RopeConfig(base=float(_get(block, "rope_theta")))
+    if _get(block, "rope_type") != "yarn":
+        raise NotImplementedError(
+            f"rope_type {_get(block, 'rope_type')!r} (default and yarn are "
+            "supported)")
+    return RopeConfig(
+        base=float(_get(block, "rope_theta")),
+        factor=float(_get(block, "factor")),
+        original_max_position=int(
+            _get(block, "original_max_position_embeddings")),
+        beta_fast=float(_get(block, "beta_fast", 32.0)),
+        beta_slow=float(_get(block, "beta_slow", 1.0)),
+        attention_factor=_get(block, "attention_factor"),
+    )
+
+
+@register_hf_family("mellum")
+def _mellum_config(hf_config: Any) -> TransformerConfig:
+    """Mellum 2 (JetBrains): qwen3_moe's expert layer (``num_experts`` of
+    ``moe_intermediate_size``, top-k gates renormalised, no shared expert,
+    no dropped token) under attention whose kind changes by layer —
+    ``layer_types`` mixes sliding-window and full attention, and
+    ``rope_parameters`` gives each kind its RoPE (plain on sliding layers,
+    YaRN on full ones). No q/k norm, no biases.
+
+    A SHARE of the model — one rank of the expert-parallel group that
+    holds a layer — is a config whose ``num_experts`` is the experts held,
+    with the scalar keys ``num_routed_experts`` (the published count, the
+    router's width), ``expert_shard_count`` (the ranks sharing a layer)
+    and ``expert_shard_index`` (this rank): it holds the experts from
+    ``index * num_experts`` on."""
+    kw = _base_kwargs(hf_config)
+    ropes = getattr(hf_config, "rope_parameters", None) or {}
+    layer_rope = tuple(
+        (_HF_LAYER_TYPES[name], _rope_config(block))
+        for name, block in sorted(
+            (ropes if isinstance(ropes, dict) else vars(ropes)).items()))
+    sliding = dict(layer_rope).get(SLIDING)
+    if sliding is not None:
+        kw["rotary_base"] = sliding.base
+    types = getattr(hf_config, "layer_types", None)
+    if types is not None:
+        # a config cut in depth keeps the first layers' types
+        types = tuple(_HF_LAYER_TYPES[t] for t in types)[:kw["n_layers"]]
+        if len(types) != kw["n_layers"]:
+            raise ValueError(
+                f"{len(types)} layer_types for {kw['n_layers']} layers")
+    held = hf_config.num_experts
+    routed = getattr(hf_config, "num_routed_experts", None) or held
+    shards = getattr(hf_config, "expert_shard_count", None) or routed // held
+    if held * shards != routed:
+        raise ValueError(
+            f"{shards} shards of {held} experts are not the {routed} "
+            "the router scores")
+    return TransformerConfig(
+        **kw,
+        sliding_window=getattr(hf_config, "sliding_window", None)
+        if getattr(hf_config, "use_sliding_window", True) else None,
+        layer_types=types,
+        layer_rope=layer_rope or None,
+        max_position_embeddings=getattr(
+            hf_config, "max_position_embeddings", None),
+        moe=MoEConfig(
+            num_experts=held,
+            top_k=hf_config.num_experts_per_tok,
+            capacity_factor=None,
+            routed_intermediate_dim=hf_config.moe_intermediate_size,
+            aux_loss_coeff=getattr(hf_config, "router_aux_loss_coef", 1e-3),
+            norm_topk_prob=getattr(hf_config, "norm_topk_prob", True),
+            router_experts=routed if routed != held else None,
+            first_expert=held * int(
+                getattr(hf_config, "expert_shard_index", 0) or 0),
+        ),
+        hf_family="mellum",
+    )
+
+
 def config_from_hf(hf_config: Any) -> TransformerConfig:
     """Build a TransformerConfig from a transformers PretrainedConfig."""
     mt = getattr(hf_config, "model_type", "llama")
@@ -226,7 +323,7 @@ def _moe_names(cfg: TransformerConfig) -> Dict[str, str]:
             "e_up": "model.layers.{i}.block_sparse_moe.experts.{e}.w3.weight",
             "e_down": "model.layers.{i}.block_sparse_moe.experts.{e}.w2.weight",
         }
-    # qwen3_moe / olmoe layout
+    # qwen3_moe / olmoe / mellum layout
     return {
         "router": "model.layers.{i}.mlp.gate.weight",
         "e_gate": "model.layers.{i}.mlp.experts.{e}.gate_proj.weight",
@@ -424,6 +521,7 @@ _HF_ARCH = {
     "mixtral": "MixtralForCausalLM",
     "qwen3_moe": "Qwen3MoeForCausalLM",
     "olmoe": "OlmoeForCausalLM",
+    "mellum": "MellumForCausalLM",
 }
 
 
@@ -484,11 +582,42 @@ def hf_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
                 d["intermediate_size"] = width
                 d["attention_bias"] = False
                 d["clip_qkv"] = None
+            elif fam == "mellum":
+                d["moe_intermediate_size"] = width
+                d["mlp_layer_types"] = ["sparse"] * cfg.n_layers
+                if cfg.moe.is_share:
+                    shards = cfg.moe.n_routed // cfg.moe.num_experts
+                    d["num_routed_experts"] = cfg.moe.n_routed
+                    d["expert_shard_count"] = shards
+                    d["expert_shard_index"] = (
+                        cfg.moe.first_expert // cfg.moe.num_experts)
             else:
                 d["moe_intermediate_size"] = width
                 d["decoder_sparse_step"] = 1
                 d["mlp_only_layers"] = []
+    if fam == "mellum":
+        names = {kind: name for name, kind in _HF_LAYER_TYPES.items()}
+        del d["rope_theta"]
+        d["attention_bias"] = False
+        d["use_sliding_window"] = cfg.sliding_window is not None
+        d["layer_types"] = [names[k] for k in cfg.layer_kinds]
+        d["rope_parameters"] = {
+            names[kind]: _rope_dict(cfg.rope_of(kind))
+            for kind in dict.fromkeys(cfg.layer_kinds)
+        }
     return d
+
+
+def _rope_dict(rope: RopeConfig) -> Dict[str, Any]:
+    """One block of HF ``rope_parameters`` (the inverse of _rope_config)."""
+    if rope.factor is None:
+        return {"rope_type": "default", "rope_theta": rope.base}
+    return {
+        "rope_type": "yarn", "rope_theta": rope.base, "factor": rope.factor,
+        "original_max_position_embeddings": rope.original_max_position,
+        "beta_fast": rope.beta_fast, "beta_slow": rope.beta_slow,
+        "attention_factor": rope.scale,
+    }
 
 
 # ---------------- sharded safetensors IO ----------------

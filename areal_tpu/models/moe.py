@@ -26,6 +26,13 @@ Parity target: ``realhf/impl/model/modules/moe/`` — ``TopKRouter``
    row bound is the worst case, every entry local) and nothing of shape
    ``[N, E, C]`` is built. With a capacity the drop is decided at the
    shard boundary (per source shard); a dropless model drops nothing;
+ - **a share** (``MoEConfig.router_experts`` / ``first_expert``): one
+   rank's part of such a group run alone, on a mesh with no "ep" axis.
+   The router scores all the published experts and normalises the gates
+   over all the chosen ones; the held experts' rows are sorted to the
+   front exactly as an ep shard sorts its own (:func:`_held_eid`), and a
+   pair that chose an expert held elsewhere is the sentinel: it adds
+   nothing, and no collective or stand-in for one is set up;
  - the GShard one-hot-einsum dispatch is kept as the parity ORACLE behind
    ``AREAL_MOE_DISPATCH=einsum`` (same contract as ``AREAL_RING_SCHEDULE``
    / ``AREAL_PP_SCHEDULE``); it shares the router and the drop policy;
@@ -44,8 +51,10 @@ combine), ``moe_exchange`` (the collectives over "ep"), ``moe_experts``
 
 Routing-health aux (exported as ``train/moe_*`` telemetry by
 backend/jax_train.py; docs/observability.md): ``dropped_frac``,
-``routed_rows`` (the (token, expert) pairs routed), ``expert_load`` ([E]
-fraction of routed assignments per expert, pre-drop) and
+``routed_rows`` (the (token, expert) pairs routed, over all experts),
+on a share ``local_rows`` (those of them that chose an expert held
+here), ``expert_load`` ([E] fraction of routed assignments per expert,
+pre-drop) and
 ``expert_load_ratio`` (max/mean of that — 1.0 is perfectly balanced,
 → E is total collapse; the sentinel ``expert_collapse`` rule baselines it).
 """
@@ -66,7 +75,7 @@ from areal_tpu.models.config import MoEConfig
 DISPATCH_METHODS = ("grouped", "einsum")
 # Aux entries that add up over micro-batches and optimizer steps; every
 # other scalar is a mean (backend/jax_train.py, algorithms/ppo.py).
-SUMMED_AUX = ("routed_rows",)
+SUMMED_AUX = ("routed_rows", "local_rows")
 
 
 def resolve_dispatch(method: Optional[str] = None) -> str:
@@ -97,7 +106,7 @@ def ep_eligible(mesh: Optional[Mesh], moe: Optional[MoEConfig],
     their mesh axes (the full-manual shard_map needs exact blocks — e.g.
     generate()'s unbucketed batch dim does not divide, mirroring
     ring_eligible)."""
-    if mesh is None or moe is None:
+    if mesh is None or moe is None or moe.is_share:
         return False
     ep = dict(mesh.shape).get("ep", 1)
     if ep <= 1 or moe.num_experts % ep:
@@ -119,7 +128,7 @@ def _routing(
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Returns (top_p [N, k] post-norm gates, top_i [N, k], onehot
     [N, k, E] with padding rows zeroed, aux dict sans dropped_frac)."""
-    E, k = moe.num_experts, moe.top_k
+    E, k = moe.n_routed, moe.top_k
     n_valid = jnp.maximum(jnp.sum(valid), 1.0)
 
     router_in = xf
@@ -304,19 +313,41 @@ def _dispatch_grouped(
     moe: MoEConfig,
     n_valid: jnp.ndarray,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The one-shard path: every expert is here, padding entries are the
-    sentinel (:func:`_sorted_expert_ffn`)."""
+    """The one-shard path: padding entries are the sentinel
+    (:func:`_sorted_expert_ffn`), and on a share so are the entries of
+    experts held elsewhere. Returns (y, dropped_frac, on a share the
+    entries that chose an expert held here — else None: all of them)."""
     N = xf.shape[0]
     E, k = moe.num_experts, moe.top_k
-    eid = jnp.where(valid.reshape(N, 1) > 0, top_i, E).reshape(N * k)
+    if moe.is_share:
+        with jax.named_scope("moe_dispatch"):
+            eid = _held_eid(top_i, valid, moe.first_expert, E)
+            local = jnp.sum(eid < E).astype(jnp.float32)
+    else:
+        eid = jnp.where(valid.reshape(N, 1) > 0, top_i, E).reshape(N * k)
+        local = None
     dropless = moe.capacity_factor is None
     y, kept = _sorted_expert_ffn(
         xf, eid, top_p.reshape(N * k), None if dropless else capacity(N, moe),
         lp["e_gate"], lp["e_up"], lp["e_down"],
     )
     if dropless:
-        return y, jnp.zeros((), jnp.float32)
-    return y, 1.0 - kept / jnp.maximum(n_valid * k, 1.0)
+        return y, jnp.zeros((), jnp.float32), local
+    pairs = n_valid * k if local is None else local
+    return y, 1.0 - kept / jnp.maximum(pairs, 1.0), local
+
+
+def _held_eid(ids: jnp.ndarray,  # [N, k] expert chosen by each entry
+              valid: jnp.ndarray,  # [N]
+              first,  # index of the first expert held here
+              held: int) -> jnp.ndarray:
+    """The group of each (token, choice) entry among the ``held`` experts
+    from ``first`` on: 0..held-1, or the sentinel ``held`` for a padding
+    token and for an expert held elsewhere. Shared by an ep shard (its
+    ``first`` is its index on the axis) and a share run alone."""
+    local = ids - first
+    mine = (local >= 0) & (local < held) & (valid[:, None] > 0)
+    return jnp.where(mine, local, held).reshape(ids.shape[0] * ids.shape[1])
 
 
 # ---------------- expert-parallel dispatch (gather, sort, reduce-scatter over "ep") ----------------
@@ -382,9 +413,7 @@ def _dispatch_ep(
         def one_source(src):
             xs, gs, ids, vs = src
             with jax.named_scope("moe_dispatch"):
-                local = ids - first
-                mine = (local >= 0) & (local < E_l) & (vs[:, None] > 0)
-                eid = jnp.where(mine, local, E_l).reshape(Nl * k)
+                eid = _held_eid(ids, vs, first, E_l)
             return _sorted_expert_ffn(
                 xs, eid, gs.reshape(Nl * k), cap, gate_w, up_w, down_w)
 
@@ -462,11 +491,17 @@ def moe_mlp(
     if mesh is not None and ep_eligible(mesh, moe, B, T):
         y, dropped_frac = _dispatch_ep(x, top_p, top_i, valid, lp, moe, mesh)
     elif resolve_dispatch(dispatch) == "einsum":
+        if moe.is_share:
+            raise NotImplementedError(
+                "the einsum oracle holds every expert; a share runs the "
+                "grouped dispatch")
         y, dropped_frac = _dispatch_einsum(xf, top_p, onehot, lp, moe, n_valid)
     else:
-        y, dropped_frac = _dispatch_grouped(
+        y, dropped_frac, local_rows = _dispatch_grouped(
             xf, top_p, top_i, valid, lp, moe, n_valid
         )
+        if moe.is_share:
+            aux = dict(aux, local_rows=local_rows)
 
     if "s_gate" in lp:  # always-on shared expert (qwen-moe)
         y = y + (jax.nn.silu(xf @ lp["s_gate"]) * (xf @ lp["s_up"])) @ lp["s_down"]
@@ -481,7 +516,7 @@ def init_moe_params(cfg, key: jnp.ndarray, dtype) -> Dict[str, jnp.ndarray]:
     moe = cfg.moe
     n, d = cfg.n_layers, cfg.hidden_dim
     f = moe.routed_intermediate_dim or cfg.intermediate_dim
-    E = moe.num_experts
+    E = moe.num_experts  # held; the router scores all of ``n_routed``
     # One key per weight actually initialized — adding a weight grows the
     # split instead of silently reusing a neighbour's key.
     names = ["router", "e_gate", "e_up", "e_down"]
@@ -493,7 +528,7 @@ def init_moe_params(cfg, key: jnp.ndarray, dtype) -> Dict[str, jnp.ndarray]:
         return (jax.random.normal(k, shape) * scale).astype(dtype)
 
     out = {
-        "router": nrm(ks["router"], (n, d, E)),
+        "router": nrm(ks["router"], (n, d, moe.n_routed)),
         "e_gate": nrm(ks["e_gate"], (n, E, d, f)),
         "e_up": nrm(ks["e_up"], (n, E, d, f)),
         "e_down": nrm(ks["e_down"], (n, E, f, d)),

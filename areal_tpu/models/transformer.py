@@ -17,16 +17,23 @@ Supports GQA, RoPE (HF llama-style rotate-half), RMSNorm, gated-SiLU MLP,
 optional qk-norm (per head: qwen3; whole vector: olmoe), optional attention
 biases (qwen2), tied embeddings,
 critic (scalar) head, and a KV-cache decode mode.
+
+Layers need not be alike: ``cfg.layer_kinds`` gives each its attention
+kind (full or sliding-window, each with its own RoPE table), and the scan
+runs over PERIODS of that pattern (:func:`_scan_layers`) — one layer a
+step for a model whose layers are alike, which is every family but
+mellum.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+import math
+from typing import Any, Callable, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 
-from areal_tpu.models.config import TransformerConfig
+from areal_tpu.models.config import RopeConfig, TransformerConfig
 from areal_tpu.ops.attention import decode_attention, packed_attention
 from areal_tpu.parallel.sharding import constrain, current_mesh
 
@@ -135,16 +142,59 @@ _ACTIVATIONS = {
 }
 
 
+def yarn_inv_freq(head_dim: int, rope: RopeConfig) -> jnp.ndarray:
+    """YaRN's inverse frequencies [head_dim/2] as ``transformers`` computes
+    them (``_compute_yarn_parameters``): dimensions that turn more than
+    ``beta_fast`` times over the original context keep their frequency,
+    those that turn less than ``beta_slow`` times have it divided by
+    ``factor``, and a linear ramp blends the ones between."""
+    half = head_dim // 2
+    pos_freqs = rope.base ** (
+        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+
+    def dim_of(turns: float) -> float:  # the dimension that turns so often
+        return (head_dim * math.log(
+            rope.original_max_position / (turns * 2 * math.pi))
+        ) / (2 * math.log(rope.base))
+
+    low = max(math.floor(dim_of(rope.beta_fast)), 0)
+    high = min(math.ceil(dim_of(rope.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001  # transformers' guard against a zero-width ramp
+    ramp = jnp.clip(
+        (jnp.arange(half, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    return (1 - ramp) / pos_freqs + ramp / (rope.factor * pos_freqs)
+
+
 def rope_tables(
-    positions: jnp.ndarray, head_dim: int, base: float
+    positions: jnp.ndarray, head_dim: int, rope: Union[float, RopeConfig]
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """cos/sin [..., head_dim] for HF-style rotate-half RoPE."""
-    inv_freq = 1.0 / (
-        base ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
-    )
+    """cos/sin [..., head_dim] for HF-style rotate-half RoPE. ``rope``: a
+    base (plain RoPE) or a RopeConfig (YaRN where it has a ``factor``:
+    blended frequencies, cos and sin times the attention factor)."""
+    if isinstance(rope, RopeConfig) and rope.factor is not None:
+        inv_freq = yarn_inv_freq(head_dim, rope)
+    else:
+        base = rope.base if isinstance(rope, RopeConfig) else rope
+        inv_freq = 1.0 / (
+            base ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+        )
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [..., dh/2]
     emb = jnp.concatenate([angles, angles], axis=-1)
+    if isinstance(rope, RopeConfig) and rope.scale != 1.0:
+        return jnp.cos(emb) * rope.scale, jnp.sin(emb) * rope.scale
     return jnp.cos(emb), jnp.sin(emb)
+
+
+def rope_tables_by_kind(
+    cfg: TransformerConfig, positions: jnp.ndarray,
+) -> Dict[str, Tuple[jnp.ndarray, jnp.ndarray]]:
+    """{attention kind: (cos, sin)} for the kinds the model's layers have
+    — one table for a model whose layers are alike."""
+    return {
+        kind: rope_tables(positions, cfg.head_dim, cfg.rope_of(kind))
+        for kind in dict.fromkeys(cfg.period_kinds)
+    }
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
@@ -174,8 +224,15 @@ def _block(
     ring_ctx=None,  # ring.RingCtx — already inside a manual sp region (PP∘SP)
     rng: Optional[jnp.ndarray] = None,  # per-layer key for MoE router jitter
     allow_ep: bool = True,  # False inside manual regions (pipeline stages)
+    kind: Optional[str] = None,  # this layer's attention kind (cfg.layer_kinds)
 ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, jnp.ndarray], Optional[Dict[str, jnp.ndarray]]]:
     B, T, D = h.shape
+    if kind is None:
+        kind = cfg.period_kinds[0]
+    if isinstance(cos, dict):  # a table per attention kind
+        cos, sin = cos[kind], sin[kind]
+    if isinstance(kv_valid, dict):
+        kv_valid = kv_valid[kind]
     dh = cfg.head_dim
 
     # The jax.named_scope names below are the device-side names of a
@@ -212,6 +269,7 @@ def _block(
         attn, new_kv = _attend(
             cfg, q, k, v, segment_ids, positions, cache_kv,
             cache_write_index, kv_valid, attn_impl, allow_ring, ring_ctx,
+            kind,
         )
 
     hid = "hidden" if cache_kv is None else "hidden_decode"
@@ -258,10 +316,12 @@ def _block(
 def _attend(
     cfg: TransformerConfig, q, k, v, segment_ids, positions, cache_kv,
     cache_write_index, kv_valid, attn_impl: str, allow_ring: bool, ring_ctx,
+    kind: str,
 ):
     """One block's attention proper (everything between RoPE and the
     output projection): packed / ring attention in packed mode, the cache
-    write and decode attention in decode mode. Returns (attn, new_kv)."""
+    write and decode attention in decode mode; ``kind`` says whether the
+    layer sees its window or its whole document. Returns (attn, new_kv)."""
     B, T = q.shape[:2]
     if cache_kv is None:
         from areal_tpu.parallel import ring as ring_mod
@@ -273,7 +333,7 @@ def _attend(
         use_ring = (
             allow_ring
             and segment_ids is not None
-            and ring_mod.ring_eligible(mesh, cfg, B, T)
+            and ring_mod.ring_eligible(mesh, cfg, B, T, kind=kind)
         )
         if allow_ring and ring_ctx is not None:
             # Already inside a manual region over the ring axis (the PP∘SP
@@ -288,7 +348,8 @@ def _attend(
             attn = packed_attention(
                 q, k, v, segment_ids, segment_ids,
                 q_positions=positions, kv_positions=positions,
-                causal=True, sliding_window=cfg.sliding_window, impl=attn_impl,
+                causal=True, sliding_window=cfg.window_of(kind),
+                impl=attn_impl,
             )
         new_kv = (k, v)
     else:
@@ -354,44 +415,85 @@ def apply_layer_stack(
         n_layers = jax.tree_util.tree_leaves(layer_params)[0].shape[0]
         layer_keys = jax.random.split(rng, n_layers)
 
-        def body(h, xs):
+        def body(kind, h, xs):
             lp, key = xs
             h2, _, aux = _block(
                 cfg, h, lp, cos, sin, segment_ids, positions,
                 None, None, None, attn_impl, allow_ring=allow_ring,
-                ring_ctx=ring_ctx, rng=key, allow_ep=allow_ep,
+                ring_ctx=ring_ctx, rng=key, allow_ep=allow_ep, kind=kind,
             )
             return h2, aux
 
-        body = _maybe_checkpoint(body, remat)
         with jax.named_scope("layer_scan"):
-            h, aux = jax.lax.scan(body, h, (layer_params, layer_keys))
+            h, aux = _scan_layers(cfg, body, h, (layer_params, layer_keys),
+                                  remat)
         return h, (aux if aux is not None else {})
 
-    def body(h, lp):
+    def body(kind, h, lp):
         h2, _, aux = _block(
             cfg, h, lp, cos, sin, segment_ids, positions,
             None, None, None, attn_impl, allow_ring=allow_ring,
-            ring_ctx=ring_ctx, allow_ep=allow_ep,
+            ring_ctx=ring_ctx, allow_ep=allow_ep, kind=kind,
         )
         return h2, aux
 
-    body = _maybe_checkpoint(body, remat)
     # "layer_scan" names the scan's own work: slicing each layer's
     # parameters out of the stacked arrays and, in the backward pass,
     # writing each layer's gradients back into them.
     with jax.named_scope("layer_scan"):
-        h, aux = jax.lax.scan(body, h, layer_params)
+        h, aux = _scan_layers(cfg, body, h, layer_params, remat)
     return h, (aux if aux is not None else {})
+
+
+def _scan_layers(cfg: TransformerConfig, layer: Callable, h, xs, remat=False):
+    """``lax.scan`` of ``layer(kind, h, x) -> (h, y)`` over the stacked
+    per-layer ``xs`` (leading axis = layers, any whole number of periods),
+    one PERIOD of ``cfg.period_kinds`` a step: the kind of each layer of a
+    period is static, so a sliding-window layer and a full one trace their
+    own attention. Each layer is checkpointed by itself (``remat``). A
+    period of one layer is the plain scan over layers. Returns (h, ys) with
+    ys stacked per layer."""
+    kinds = cfg.period_kinds
+    if len(kinds) == 1:
+        def body(h, x):
+            return layer(kinds[0], h, x)
+
+        return jax.lax.scan(_maybe_checkpoint(body, remat), h, xs)
+    P = len(kinds)
+    L = jax.tree_util.tree_leaves(xs)[0].shape[0]
+    assert L % P == 0, f"{L} layers are no whole number of {P}-layer periods"
+    # Each layer takes its slice of the period's parameters INSIDE its
+    # checkpoint: what the backward finds kept is then the scan's own
+    # input, not a copy of every layer's weights.
+    steps = [
+        _maybe_checkpoint(
+            lambda h, xp, j=j, kind=kind: layer(
+                kind, h, jax.tree.map(lambda a: a[j], xp)), remat)
+        for j, kind in enumerate(kinds)
+    ]
+
+    def period(h, xp):
+        ys = []
+        for step in steps:
+            h, y = step(h, xp)
+            ys.append(y)
+        return h, jax.tree.map(lambda *a: jnp.stack(a), *ys)
+
+    h, ys = jax.lax.scan(
+        period, h,
+        jax.tree.map(lambda a: a.reshape(L // P, P, *a.shape[1:]), xs))
+    return h, jax.tree.map(lambda a: a.reshape(L, *a.shape[2:]), ys)
 
 
 # What a layer's backward pass finds kept from its forward pass, from the
 # least kept to the most; each entry keeps what the one before it keeps.
 #   full      — the layer's input only: the backward re-runs the layer.
-#   attention — and what the flash kernel's backward reads of its forward
-#               (its output and softmax statistics): the forward kernel is
-#               not re-run. The padded, repeated q/k/v it was handed are
-#               recomputed; the XLA reference attention keeps nothing.
+#   attention — and what the attention kernel's backward reads of its
+#               forward (its output and softmax statistics; the flash
+#               kernel's or, on a sliding-window layer, the windowed
+#               kernel's): the forward kernel is not re-run. The padded
+#               (flash: and repeated) q/k/v it was handed are recomputed;
+#               the XLA reference attention keeps nothing.
 #   matmuls   — and the outputs of the layer's matmuls (q/k/v, o_proj,
 #               gate, up, the router; down's output nobody reads). Norms,
 #               rope, silu·up, casts and the kernel's glue are recomputed.
@@ -415,15 +517,19 @@ def _flash_residuals_saveable(prim, *_, **params) -> bool:
 
 
 def _remat_policy(entry: str):
+    from areal_tpu.ops.pallas.window_attention import RESIDUALS
+
     policies = jax.checkpoint_policies
     if entry == "full":
         return None
+    # The windowed (splash) kernel names its output and statistics.
+    kernels = policies.save_from_both_policies(
+        _flash_residuals_saveable, policies.save_only_these_names(RESIDUALS))
     if entry == "attention":
-        return _flash_residuals_saveable
+        return kernels
     if entry == "matmuls":
         return policies.save_from_both_policies(
-            _flash_residuals_saveable,
-            policies.dots_with_no_batch_dims_saveable)
+            kernels, policies.dots_with_no_batch_dims_saveable)
     raise ValueError(f"remat={entry!r}: not one of {REMAT_ENTRIES}")
 
 
@@ -438,22 +544,27 @@ def _maybe_checkpoint(body, remat):
 
 def remat_kept_bytes(
     cfg: TransformerConfig, tokens: int, itemsize: int,
-    flash_tokens: int = 0,
+    flash_tokens: int = 0, window_tokens: int = 0,
 ) -> Dict[str, int]:
     """Bytes the layer scan keeps between its forward and its backward
     pass under each entry of ``REMAT_ENTRIES``, for ``tokens`` tokens in
     a compute dtype of ``itemsize`` bytes. ``flash_tokens``: the tokens
     of the flash kernel's output, rows x PADDED length (0 where attention
-    takes the XLA reference). Arithmetic on the widths in ``cfg``,
+    takes the XLA reference), on the full-attention layers;
+    ``window_tokens`` likewise of the windowed kernel's, on the
+    sliding-window layers. Arithmetic on the widths in ``cfg``,
     checked against what jax really keeps in tests/test_remat_plan.py and
     against the chip's compiler in PERF.md §5."""
+    from areal_tpu.models.config import SLIDING
     from areal_tpu.ops.pallas.flash_attention import LANE
 
     full = tokens * cfg.hidden_dim * itemsize
-    # The kernel writes heads padded to the lane width, and two float32
-    # statistics a head.
+    # The flash kernel writes heads padded to the lane width, and two
+    # float32 statistics a head; the windowed kernel one (a logsumexp).
     lanes = -(-cfg.head_dim // LANE) * LANE
-    attention = flash_tokens * cfg.n_q_heads * (lanes * itemsize + 2 * 4)
+    n_sliding = cfg.layer_kinds.count(SLIDING)
+    flash = flash_tokens * cfg.n_q_heads * (lanes * itemsize + 2 * 4)
+    window = window_tokens * cfg.n_q_heads * (lanes * itemsize + 4)
     # q/k/v, o_proj, and the MLP's matmuls into the hidden width (gate and
     # up, or up) — nothing in the backward reads the last matmul's output;
     # an MoE layer keeps the router's logits and its shared expert's pair.
@@ -461,13 +572,14 @@ def remat_kept_bytes(
     if cfg.moe is None:
         widths += (1 if cfg.mlp_type == "plain" else 2) * cfg.intermediate_dim
     else:
-        widths += (cfg.moe.num_experts
+        widths += (cfg.moe.n_routed
                    + 2 * (cfg.moe.shared_intermediate_dim or 0))
     matmuls = tokens * widths * itemsize
-    per_layer = {"full": full, "attention": full + attention,
-                 "matmuls": full + attention + matmuls}
-    return {entry: cfg.n_layers * per_layer[entry]
-            for entry in REMAT_ENTRIES}
+    attention = (cfg.n_layers - n_sliding) * flash + n_sliding * window
+    kept = {"full": cfg.n_layers * full}
+    kept["attention"] = kept["full"] + attention
+    kept["matmuls"] = kept["attention"] + cfg.n_layers * matmuls
+    return kept
 
 
 # ---------------- forward ----------------
@@ -480,7 +592,7 @@ def forward(
     segment_ids: Optional[jnp.ndarray] = None,  # [B, T], 0 = pad (packed mode)
     kv_cache: Optional[Dict[str, jnp.ndarray]] = None,  # decode mode
     cache_write_index: Optional[jnp.ndarray] = None,
-    kv_valid: Optional[jnp.ndarray] = None,
+    kv_valid=None,  # [B, S] / [B, T, S] bool, or {attention kind: that}
     attn_impl: str = "auto",
     remat=False,  # False | True | an entry of REMAT_ENTRIES
     return_kv: bool = True,  # False in training: don't stack per-layer K/V
@@ -498,7 +610,8 @@ def forward(
     Packed mode: ``segment_ids`` given, no cache — block-causal attention.
     Decode mode: ``kv_cache`` given — T is the new-token count (typically 1),
     cache slots are written at ``cache_write_index`` and attention runs over
-    ``kv_valid`` cache slots.
+    ``kv_valid`` cache slots — one set for every layer, or one per
+    attention kind (:func:`kv_valid_by_kind`).
     """
     decode = kv_cache is not None
     with jax.named_scope("embed"):
@@ -509,33 +622,38 @@ def forward(
             h = h + params["pos_embedding"][positions]
         h = constrain(h, "hidden" if not decode else "hidden_decode")
     with jax.named_scope("rope"):
-        cos, sin = rope_tables(positions, cfg.head_dim, cfg.rotary_base)
+        ropes = rope_tables_by_kind(cfg, positions)
+        if len(ropes) == 1:  # layers alike: the one table
+            (cos, sin), = ropes.values()
+        else:
+            cos = {kind: cs[0] for kind, cs in ropes.items()}
+            sin = {kind: cs[1] for kind, cs in ropes.items()}
     layer_params = params["layers"]
 
     if decode:
-        def body(h, xs):
+        def body(kind, h, xs):
             lp, (kc, vc) = xs
             h2, (kc2, vc2), aux = _block(
                 cfg, h, lp, cos, sin, None, None, (kc, vc),
-                cache_write_index, kv_valid, attn_impl,
+                cache_write_index, kv_valid, attn_impl, kind=kind,
             )
             return h2, ((kc2, vc2), aux)
 
         with jax.named_scope("layer_scan"):
-            h, ((ks, vs), aux) = jax.lax.scan(
-                body, h, (layer_params, (kv_cache["k"], kv_cache["v"]))
+            h, ((ks, vs), aux) = _scan_layers(
+                cfg, body, h, (layer_params, (kv_cache["k"], kv_cache["v"]))
             )
     elif return_kv:
-        def body(h, lp):
+        def body(kind, h, lp):
             h2, kv, aux = _block(
                 cfg, h, lp, cos, sin, segment_ids, positions,
-                None, None, None, attn_impl,
+                None, None, None, attn_impl, kind=kind,
             )
             return h2, (kv, aux)
 
-        body = _maybe_checkpoint(body, remat)
         with jax.named_scope("layer_scan"):
-            h, ((ks, vs), aux) = jax.lax.scan(body, h, layer_params)
+            h, ((ks, vs), aux) = _scan_layers(cfg, body, h, layer_params,
+                                              remat)
     else:
         ks = vs = None
         from areal_tpu.parallel import pipeline as pp_mod
@@ -598,6 +716,24 @@ def forward(
     return out, kv_out
 
 
+def kv_valid_by_kind(cfg: TransformerConfig, valid: jnp.ndarray,
+                     distance: jnp.ndarray):
+    """The cache slots each attention kind of the model may read: ``valid``
+    (causal, written) for a full layer, and of those the slots less than
+    the window behind the query (``distance`` = query position - slot
+    position, broadcastable to ``valid``) for a sliding-window layer.
+    A model whose layers are alike gets the one array."""
+    by_kind = {
+        kind: valid if cfg.window_of(kind) is None
+        else valid & (distance < cfg.window_of(kind))
+        for kind in dict.fromkeys(cfg.period_kinds)
+    }
+    if len(by_kind) == 1:
+        (valid,) = by_kind.values()
+        return valid
+    return by_kind
+
+
 def apply_head(params: Params, cfg: TransformerConfig, h, lg="logits"):
     """Final-hidden → logits (or values). Shared by forward and the
     engine's chunked-logprob path (backend/jax_train.py) so the head math
@@ -622,7 +758,7 @@ def param_count(cfg: TransformerConfig) -> int:
     attn = d * (cfg.q_dim + 2 * cfg.kv_dim) + cfg.q_dim * d
     if cfg.moe is not None:
         fr = cfg.moe.routed_intermediate_dim or f
-        mlp = cfg.moe.num_experts * 3 * d * fr + d * cfg.moe.num_experts
+        mlp = cfg.moe.num_experts * 3 * d * fr + d * cfg.moe.n_routed
         if cfg.moe.shared_intermediate_dim:
             mlp += 3 * d * cfg.moe.shared_intermediate_dim
     elif cfg.mlp_type == "plain":
@@ -649,5 +785,7 @@ def activated_param_count(cfg: TransformerConfig) -> int:
     n, d, f = cfg.n_layers, cfg.hidden_dim, cfg.intermediate_dim
     fr = cfg.moe.routed_intermediate_dim or f
     total_mlp = cfg.moe.num_experts * 3 * d * fr
-    active_mlp = cfg.moe.top_k * 3 * d * fr
+    # on a share, the part of a token's top_k that is held here
+    held_k = cfg.moe.top_k * cfg.moe.num_experts // cfg.moe.n_routed
+    active_mlp = held_k * 3 * d * fr
     return param_count(cfg) - n * (total_mlp - active_mlp)

@@ -26,9 +26,10 @@ _NEG_INF = -1e30
 
 # Which implementation each packed_attention call TRACED to, by the label
 # of the compiled step it was traced for: {label: {"pallas" | "reference"
-# | "fallback": n}}. "fallback" = the TPU kernel was wanted and the O(S^2)
-# reference ran instead (a shape with no 128-multiple block, or a sliding
-# window). Counted at trace time, so it describes the compiled programs;
+# | "window" | "fallback": n}}. "pallas" = the flash kernel, "window" = the
+# windowed kernel of a sliding-window layer, "fallback" = a TPU kernel was
+# wanted and the O(S^2) reference ran instead (a shape with no
+# 128-multiple block). Counted at trace time, so it describes the compiled programs;
 # chip_smoke.py fails when the train step's fallback count is not zero.
 _DISPATCH: Dict[str, collections.Counter] = collections.defaultdict(
     collections.Counter
@@ -128,11 +129,15 @@ def kernel_padded_len(
     impl: str, length: int, sliding_window: Optional[int] = None,
 ) -> Optional[int]:
     """The padded row length at which packed self-attention over rows of
-    ``length`` tokens runs the Pallas kernel — what its output and softmax
-    statistics span; None where :func:`packed_attention` takes the XLA
-    reference."""
-    if not _wants_kernel(impl) or sliding_window is not None:
+    ``length`` tokens runs its Pallas kernel (the windowed one under a
+    ``sliding_window``) — what the kernel's output and softmax statistics
+    span; None where :func:`packed_attention` takes the XLA reference."""
+    if not _wants_kernel(impl):
         return None
+    if sliding_window is not None:
+        from areal_tpu.ops.pallas import window_attention as wa
+
+        return wa.padded_len(length, sliding_window)
     from areal_tpu.ops.pallas import flash_attention as fa
 
     blocks = fa.pick_block_sizes(length, length)
@@ -154,28 +159,28 @@ def packed_attention(
 ) -> jnp.ndarray:
     """Dispatch between the XLA reference and the Pallas TPU kernel.
 
-    ``impl="auto"`` means the kernel on a TPU and the reference elsewhere.
-    On a TPU nothing quietly replaces the kernel: a failed import raises,
-    and the two cases the kernel cannot run (a sequence dim with no
-    128-multiple block — a prompt bucket, never a packed training row —
-    and a sliding window) are counted as "fallback" under the active
-    :func:`dispatch_label`. ``scale`` defaults to ``head_dim ** -0.5`` in
-    both implementations."""
-    if impl == "pallas" and sliding_window is not None:
-        raise NotImplementedError(
-            "pallas flash attention does not support sliding_window yet; "
-            "use impl='reference'"
-        )
+    ``impl="auto"`` means a kernel on a TPU and the reference elsewhere:
+    the flash kernel, or under a ``sliding_window`` (causal self-attention
+    over one packed row) the windowed kernel, which skips the key blocks
+    outside the window and is counted as "window". On a TPU nothing
+    quietly replaces a kernel: a failed import raises, and the one case
+    the kernels cannot run (a sequence dim with no 128-multiple block — a
+    prompt bucket, never a packed training row) is counted as "fallback"
+    under the active :func:`dispatch_label`. ``scale`` defaults to
+    ``head_dim ** -0.5`` in every implementation."""
     wanted_kernel = _wants_kernel(impl)
-    if wanted_kernel and sliding_window is None:
+    if wanted_kernel:
         from areal_tpu.ops.pallas import flash_attention as fa
 
-        if fa.pick_block_sizes(q.shape[1], k.shape[1]) is not None:
-            from areal_tpu.parallel.sharding import current_mesh
+    if wanted_kernel and fa.pick_block_sizes(
+            q.shape[1], k.shape[1]) is not None:
+        from areal_tpu.parallel.sharding import current_mesh
 
+        mesh = current_mesh()
+        on_mesh = mesh is not None and mesh.size > 1
+        if sliding_window is None:
             count_dispatch("pallas")
-            mesh = current_mesh()
-            if mesh is not None and mesh.size > 1:
+            if on_mesh:
                 return fa.flash_attention_on_mesh(
                     mesh, q, k, v, q_segment_ids, kv_segment_ids,
                     causal=causal, scale=scale,
@@ -184,6 +189,17 @@ def packed_attention(
                 q, k, v, q_segment_ids, kv_segment_ids,
                 causal=causal, scale=scale,
             )
+        if causal and q.shape[1] == k.shape[1]:
+            from areal_tpu.ops.pallas import window_attention as wa
+
+            count_dispatch("window")
+            kernel = partial(wa.window_attention, window=sliding_window,
+                             scale=scale)
+            with jax.named_scope(wa.SCOPE):
+                if on_mesh:
+                    return fa.kernel_on_mesh(
+                        kernel, mesh, q, k, v, q_segment_ids, kv_segment_ids)
+                return kernel(q, k, v, q_segment_ids, kv_segment_ids)
     count_dispatch("fallback" if wanted_kernel else "reference")
     mask = segment_mask(
         q_segment_ids, kv_segment_ids, q_positions, kv_positions, causal,

@@ -184,7 +184,15 @@ def flash_attention_on_mesh(
     causal: bool = True,
     scale: Optional[float] = None,
 ) -> jnp.ndarray:
-    """:func:`flash_attention` under a multi-device mesh. GSPMD cannot
+    """:func:`flash_attention` under a multi-device mesh."""
+    return kernel_on_mesh(
+        functools.partial(flash_attention, causal=causal, scale=scale),
+        mesh, q, k, v, q_segment_ids, kv_segment_ids)
+
+
+def kernel_on_mesh(kernel, mesh, q, k, v, q_segment_ids, kv_segment_ids):
+    """``kernel(q, k, v, q_segment_ids, kv_segment_ids)`` — this module's
+    or window_attention's — under a multi-device mesh. GSPMD cannot
     partition a Mosaic kernel ("wrap the call in a shard_map"), so the call
     runs in a shard_map manual over every mesh axis not already manual (the
     pipeline stages are manual over "pp"): batch rows split over the data
@@ -210,7 +218,7 @@ def flash_attention_on_mesh(
     qkv = P(data or None, None, heads, None)
     seg = P(data or None, None)
     return jax.shard_map(
-        functools.partial(flash_attention, causal=causal, scale=scale),
+        kernel,
         mesh=mesh,
         in_specs=(qkv, qkv, qkv, seg, seg),
         out_specs=qkv,

@@ -63,7 +63,7 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from areal_tpu.base import logging, telemetry
-from areal_tpu.models.config import TransformerConfig
+from areal_tpu.models.config import SLIDING, TransformerConfig
 from areal_tpu.parallel import ring as ring_mod
 from areal_tpu.parallel import sharding as psh
 
@@ -79,6 +79,8 @@ _FALLBACK_HINTS = {
     "requested_indivisible": "requested micro-batch count must divide batch",
     "sp_seq_indivisible": "seq_len must divide the sp axis to ring",
     "sp_sliding_window": "sliding-window attention is not ring-expressible",
+    "layer_pattern": "layers of more than one attention kind are not "
+                     "pipelined (a stage takes one RoPE table)",
 }
 
 
@@ -110,7 +112,8 @@ def pick_pp_microbatches(
     too (PP∘SP): ring attention runs *inside* each stage, manual over
     {"pp","sp"}, which additionally needs the sequence to shard over the
     ring (``seq_len % sp == 0``) and a ring-expressible attention pattern
-    (no sliding window). Every fallback WARNs once and bumps the
+    (no sliding-window layer). Layers of more than one attention kind are
+    not pipelined. Every fallback WARNs once and bumps the
     ``parallel/pp_fallback{reason=...}`` counter.
     """
     if mesh is None:
@@ -122,8 +125,10 @@ def pick_pp_microbatches(
     if sp > 1:
         if seq_len is None or seq_len % sp != 0:
             return _fallback("sp_seq_indivisible")
-        if cfg.sliding_window is not None:
+        if SLIDING in cfg.layer_kinds:
             return _fallback("sp_sliding_window")
+    if len(set(cfg.layer_kinds)) > 1:
+        return _fallback("layer_pattern")
     if cfg.n_layers % pp != 0:
         return _fallback("layers_indivisible")
     if requested is not None:

@@ -54,6 +54,7 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
+from areal_tpu.models.config import SLIDING
 from areal_tpu.parallel.mesh import DATA_AXES
 
 _NEG_INF = -1e30
@@ -341,14 +342,18 @@ def ring_attention_inline(
 
 
 def ring_eligible(mesh: Optional[Mesh], cfg, batch: int, seq_len: int,
-                  axis_name: str = "sp") -> bool:
+                  axis_name: str = "sp", kind: Optional[str] = None) -> bool:
     """Whether the shapes admit ring attention on this mesh: shard_map
     needs divisible shapes (e.g. generate()'s unbucketed batch dim does
-    not divide), and sliding-window attention is not ring-expressible."""
+    not divide), and sliding-window attention is not ring-expressible —
+    asked of one layer's attention ``kind``, or (None) of the model: then
+    no layer may have a window."""
     if mesh is None or mesh.shape.get(axis_name, 1) <= 1:
         return False
+    windowed = (SLIDING in cfg.layer_kinds if kind is None
+                else cfg.window_of(kind) is not None)
     return (
-        cfg.sliding_window is None
+        not windowed
         and batch % (mesh.shape["dp"] * mesh.shape["fsdp"]
                      * dict(mesh.shape).get("ep", 1)) == 0
         and seq_len % mesh.shape[axis_name] == 0

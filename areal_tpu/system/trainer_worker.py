@@ -330,7 +330,7 @@ class TrainerWorker:
         run is judged by (base/monitor.log_device_report)."""
         from areal_tpu.base import monitor
         from areal_tpu.ops import attention, native
-        from areal_tpu.ops.pallas import flash_attention
+        from areal_tpu.ops.pallas import flash_attention, window_attention
 
         monitor.log_device_report(
             logger, f"trainer{self.cfg.dist_rank}", stage=stage,
@@ -339,6 +339,14 @@ class TrainerWorker:
             flash_geometry={
                 label: {"%d>%d/%d" % geom: n for geom, n in counts.items()}
                 for label, counts in flash_attention.geometry_counts().items()
+            },
+            # {label: {"length>padded/tile/window": {calls, blocks_visited,
+            # blocks_causal}}}: the windowed kernel's calls, and the key
+            # blocks they visit against a causal kernel's
+            window_geometry={
+                label: {"%d>%d/%d/w%d" % geom: c for geom, c in counts.items()}
+                for label, counts in
+                window_attention.geometry_counts().items()
             },
             # {model: {"RxL": {entry, kept_bytes_estimate, budget_bytes,
             # fell_back}}}: what each grid's backward pass re-runs

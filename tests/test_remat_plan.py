@@ -139,9 +139,12 @@ def test_expert_layer_is_never_kept():
 
 # ---- (c) the estimate against what jax really keeps ----
 
-def _saved_bytes(cfg, rows, length, entry, attn_impl):
+def _saved_bytes(cfg, rows, length, entry, attn_impl, cfg_run=None):
     """Bytes of the residuals the layer scan stacks (leading dim
-    n_layers), as ``jax.ad_checkpoint.print_saved_residuals`` lists them."""
+    ``cfg.n_layers``: its steps), as
+    ``jax.ad_checkpoint.print_saved_residuals`` lists them, for the model
+    ``cfg_run`` (default ``cfg``)."""
+    n_steps, cfg = cfg.n_layers, cfg_run or cfg
     from jax._src.ad_checkpoint import saved_residuals
 
     shapes = jax.eval_shape(
@@ -159,7 +162,7 @@ def _saved_bytes(cfg, rows, length, entry, attn_impl):
     return sum(
         int(np.prod(aval.shape)) * aval.dtype.itemsize
         for aval, src in saved_residuals(loss, params, tok, tok, tok)
-        if "output of scan" in src and aval.shape[0] == cfg.n_layers
+        if "output of scan" in src and aval.shape[0] == n_steps
         and aval.ndim > 2)
 
 
@@ -497,3 +500,88 @@ def test_fwd_bwd_span_names_the_entry():
             assert spans and all(s["attrs"]["remat"] == want for s in spans)
     finally:
         telemetry.shutdown()
+
+
+# ---- (e) a layer pattern: the windowed kernel under "attention" ----
+
+MELLUM_WIDTHS = dict(
+    vocab_size=512, n_layers=4, hidden_dim=256, n_q_heads=8, n_kv_heads=2,
+    head_dim=128, intermediate_dim=128, sliding_window=256,
+    layer_types=("sliding", "sliding", "sliding", "full"),
+    moe=MoEConfig(num_experts=4, top_k=2, capacity_factor=None,
+                  routed_intermediate_dim=128, router_experts=16,
+                  first_expert=4))
+
+
+def _kernel_calls(jaxpr):
+    """The name of every ``pallas_call`` in a jaxpr, through its nested
+    jaxprs (a scan's body counts once: one period of the pattern)."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(str(eqn.params["name"]))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    names += _kernel_calls(sub)
+    return names
+
+
+@pytest.mark.parametrize("entry,calls", [("full", 4), ("attention", 3),
+                                         ("matmuls", 3), (False, 3)])
+def test_window_forward_is_kept_not_rerun(entry, calls):
+    """A period of the pattern in the gradient's jaxpr: each of its four
+    layers holds its kernel's forward twice under "full" and once where
+    the residuals are kept — the flash kernel's by its forward rule, the
+    windowed (splash) kernel's by their checkpoint name."""
+    cfg = dataclasses.replace(tiny_config(), **MELLUM_WIDTHS)
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    tokens, pos, seg = _grid(np.random.RandomState(0), 2, 512, vocab=512)
+
+    def loss(p):
+        y, _ = transformer.forward(p, cfg, tokens, pos, segment_ids=seg,
+                                   attn_impl="pallas", remat=entry,
+                                   return_kv=False)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    names = _kernel_calls(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+    assert len(names) == 4 * calls
+    assert sum("splash_mqa_fwd" in n for n in names) == 3 * (calls - 2)
+    assert sum("splash" not in n for n in names) == calls  # the full layer
+
+
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_estimate_matches_what_jax_keeps_under_a_pattern(entry):
+    """Three windowed layers (output in 128 lanes and ONE float32
+    statistic a head, at the windowed kernel's padded length) and a flash
+    one (two statistics); the router's logits are 16 wide on a share that
+    holds 4 experts."""
+    from areal_tpu.ops.attention import kernel_padded_len
+
+    cfg = dataclasses.replace(  # three periods
+        tiny_config(), **{**MELLUM_WIDTHS, "n_layers": 12,
+                          "layer_types": MELLUM_WIDTHS["layer_types"] * 3})
+    rows, length = 2, 640
+    flash = kernel_padded_len("pallas", length)
+    window = kernel_padded_len("pallas", length, cfg.sliding_window)
+    assert (flash, window) == (768, 768)
+    est = transformer.remat_kept_bytes(
+        cfg, rows * length, 2, flash_tokens=rows * flash,
+        window_tokens=rows * window)
+    assert est["attention"] - est["full"] == 3 * rows * 768 * 8 * (
+        3 * (128 * 2 + 4) + (128 * 2 + 8))
+    # the scan runs over PERIODS: what it stacks has a leading dim of 3,
+    # and each layer of a period keeps its own arrays
+    periods = dataclasses.replace(cfg, n_layers=3, layer_types=None)
+    got = _saved_bytes(periods, rows, length, entry, "pallas", cfg_run=cfg)
+    assert got == pytest.approx(est[entry], rel=0.05)
+
+
+def test_plan_counts_both_kernels_of_a_pattern():
+    eng = _engine(dataclasses.replace(tiny_config(), **MELLUM_WIDTHS))
+    kept = eng._remat_kept_bytes(2, 640)
+    assert kept == transformer.remat_kept_bytes(
+        eng.cfg, 2 * 640, 2, flash_tokens=2 * 768, window_tokens=2 * 768)
+    assert eng._remat_for(2, 640) in ENTRIES
+    assert eng._layer_kinds == "sliding,sliding,sliding,full"
