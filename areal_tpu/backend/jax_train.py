@@ -128,6 +128,24 @@ def scale_by_adam_mixed(
     return optax.GradientTransformation(init, update)
 
 
+def add_decayed_weights(weight_decay: float) -> optax.GradientTransformation:
+    """``optax.add_decayed_weights`` — its (empty) state, its arithmetic —
+    that passes over a model's buffers (``moe.BUFFER_LEAVES``: the
+    router's choice bias takes no gradient and is the publisher's to
+    move), so that what the optimizer adds to such a leaf is exactly 0."""
+
+    def update(updates, state, params):
+        def one(path, g, p):
+            if getattr(path[-1], "key", None) in moe_mod.BUFFER_LEAVES:
+                return g
+            return g + weight_decay * p
+
+        return jax.tree_util.tree_map_with_path(one, updates, params), state
+
+    return optax.GradientTransformation(
+        lambda params: optax.AddDecayedWeightsState(), update)
+
+
 def _scoped(name: str, tx: optax.GradientTransformation):
     """``tx`` with its update under ``jax.named_scope(name)`` (metadata
     only; the state tree is ``tx``'s own)."""
@@ -151,7 +169,7 @@ def build_optimizer(
                 mu_dtype=getattr(cfg, "mu_dtype", None),
                 nu_dtype=getattr(cfg, "nu_dtype", None),
             ),
-            optax.add_decayed_weights(cfg.weight_decay),
+            add_decayed_weights(cfg.weight_decay),
             optax.scale_by_learning_rate(sched),
         )
     else:
@@ -244,6 +262,13 @@ _HEAD_BYTES_PER_LOGIT = 4.3
 _LAYER_COPIES = 9
 _MOE_LAYER_COPIES = 24
 _MOE_LOCAL_LAYER_COPIES = 7
+# ... and of an expert layer whose experts work in a LATENT width, in
+# copies of top_k x latent: the router scores 512 experts for 22 choices a
+# token, so the sorted buffers are long and narrow and the routing's own
+# arrays weigh beside them (measured 10.3 and 10.7: the compiler's 4.53 /
+# 2.34 GB for the Nemotron 3 share's forward + backward at 1 x 8192 /
+# 1 x 4096 under "full", less the kept layer inputs; PERF.md §5, PR 34).
+_LATENT_MOE_LAYER_COPIES = 11
 
 
 def _bytes_on_chip(tree) -> int:
@@ -725,7 +750,9 @@ class JaxTrainEngine(TrainableEngine):
         chunk = self.logprob_chunk if (
             self.logprob_chunk and L % self.logprob_chunk == 0) else L
         head = rows * chunk * cfg.vocab_size * _HEAD_BYTES_PER_LOGIT
-        if cfg.moe is None:
+        if cfg.is_hybrid:
+            layer = self._mixer_layer_width()
+        elif cfg.moe is None:
             layer = (max(cfg.intermediate_dim, cfg.q_dim, cfg.hidden_dim)
                      * _LAYER_COPIES)
         else:  # a token's top_k rows, through the expert exchange or not
@@ -735,6 +762,27 @@ class JaxTrainEngine(TrainableEngine):
             layer = cfg.moe.top_k * cfg.hidden_dim * (
                 _MOE_LAYER_COPIES if exchange else _MOE_LOCAL_LAYER_COPIES)
         return int(max(head, weights + rows * L * layer * size))
+
+    def _mixer_layer_width(self) -> int:
+        """Elements a token costs the backward of the costliest layer of a
+        hybrid model — a layer is one mixer, so the widest of: an expert
+        layer's ``top_k`` rows at the width its experts read (the latent
+        one where the model has it; never exchanged) or its shared
+        expert's width, a Mamba-2 mixer's in-projection or its scan's
+        [heads, chunk] decays (float32: two elements), attention's q."""
+        cfg, widths = self.cfg, [self.cfg.q_dim * _LAYER_COPIES]
+        if cfg.moe is not None:
+            widths += [
+                cfg.moe.top_k * (
+                    cfg.moe.latent_dim * _LATENT_MOE_LAYER_COPIES
+                    if cfg.moe.latent_dim
+                    else cfg.hidden_dim * _MOE_LOCAL_LAYER_COPIES),
+                (cfg.moe.shared_intermediate_dim or 0) * _LAYER_COPIES]
+        if cfg.ssm is not None:
+            widths.append(max(cfg.ssm.in_proj_dim,
+                              2 * cfg.ssm.n_heads * cfg.ssm.chunk_size)
+                          * _LAYER_COPIES)
+        return max(widths)
 
     def _remat_for(self, R: int, L: int):
         """What the grad programs of the packed grid [R, L] keep for their
@@ -973,10 +1021,17 @@ class JaxTrainEngine(TrainableEngine):
                     jax.random.PRNGKey(self.opt_step_count), ub.n_mbs
                 ),
             )
+        span_attrs = {}
+        if self.cfg.ssm is not None:
+            # documents that begin inside a row: where the scans reset
+            starts = sum(col > 0 for i in idxs
+                         for _, col in ub.mbs[i].layout.placements)
+            telemetry.set_gauge("train/ssm_segment_starts", starts)
+            span_attrs["ssm_segment_starts"] = starts
         with telemetry.span("train/fwd_bwd", n_mbs=len(idxs),
                             grid=f"{ub.R}x{ub.L}",
                             remat=str(self._remat_for(ub.R, ub.L)),
-                            layer_kinds=self._layer_kinds), \
+                            layer_kinds=self._layer_kinds, **span_attrs), \
                 memwatch.watermark("train/fwd_bwd"):
             for i, w in zip(idxs, weights):
                 denom = total_w if glob else w

@@ -271,6 +271,13 @@ MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_exchange", "moe_experts")
 # Listed apart like MOE_SCOPES: a reader that knows only DEVICE_SCOPES sees
 # its ops under "attention", beside the flash kernel's.
 WINDOW_SCOPES = ("window_attention",)
+# A hybrid model's Mamba-2 mixer (models/ssm.py), one scope a stage; no
+# DEVICE_SCOPES name lies between them and "layer_scan".
+SSM_SCOPES = ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",
+              "ssm_out_proj")
+# Inside "moe", beside MOE_SCOPES: the two projections around experts that
+# work in a latent width, and the shared expert (models/moe.py).
+LATENT_MOE_SCOPES = ("latent_down", "latent_up", "shared_expert")
 
 
 def _annotation(name: str, attrs: Dict[str, Any]):

@@ -12,8 +12,14 @@ import dataclasses
 import math
 from typing import Optional, Tuple
 
-# The attention kinds a layer can have (``TransformerConfig.layer_kinds``).
+# The kinds a layer can have (``TransformerConfig.layer_kinds``). FULL and
+# SLIDING are whole blocks (attention of that kind, then the MLP). The
+# others are ONE mixer alone, ``h + f(norm(h))`` (nemotron_h's hybrid
+# pattern): a Mamba-2 state-space mixer, an expert layer, or attention
+# with no position embedding.
 FULL, SLIDING = "full", "sliding"
+MAMBA, MOE_ONLY, ATTENTION_ONLY = "mamba", "moe_only", "attention_only"
+MIXER_KINDS = (MAMBA, MOE_ONLY, ATTENTION_ONLY)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,6 +51,23 @@ class MoEConfig:
     z_loss_coeff: float = 0.0
     input_jitter_eps: float = 0.0
     norm_topk_prob: bool = True
+    # What the router makes of its logits — a property of the family, set
+    # by the HF mapping: "softmax" (gates are the top-k probabilities), or
+    # "sigmoid" (nemotron_h, deepseek-v3: each expert's score is a sigmoid
+    # of its own logit; the choice is the top-k of score + ``router_bias``,
+    # a buffer that takes no gradient; the gates are the chosen SCORES).
+    router_score: str = "softmax"
+    # multiplies the (renormalised) gates (HF ``routed_scaling_factor``)
+    routed_scaling_factor: float = 1.0
+    # Experts that work in a LATENT width (HF ``moe_latent_size``): the
+    # tokens are projected hidden -> latent before the dispatch and the
+    # combined expert outputs latent -> hidden after it; the router and
+    # the shared expert read the hidden width. None = experts at hidden.
+    latent_dim: Optional[int] = None
+    # The experts' (and the shared expert's) MLP: gated (act(x·gate) *
+    # x·up) · down, or plain act(x·up) · down; "silu" | "relu2".
+    gated_experts: bool = True
+    expert_act: str = "silu"
 
     @property
     def n_routed(self) -> int:
@@ -54,6 +77,40 @@ class MoEConfig:
     @property
     def is_share(self) -> bool:
         return self.n_routed != self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class SSMConfig:
+    """A Mamba-2 mixer's sizes (HF nemotron_h keys ``mamba_num_heads``,
+    ``mamba_head_dim``, ``n_groups``, ``ssm_state_size``, ``conv_kernel``,
+    ``chunk_size``) and the ranges its init draws from
+    (``time_step_{min,max,floor}``). A head reads the B/C group
+    ``head // (n_heads / n_groups)``; the gated norm spans each group's
+    ``d_inner / n_groups`` channels."""
+
+    n_heads: int
+    head_dim: int
+    n_groups: int
+    state_dim: int
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the depthwise convolution runs over: x, B and C."""
+        return self.d_inner + 2 * self.n_groups * self.state_dim
+
+    @property
+    def in_proj_dim(self) -> int:
+        """[z | xBC | dt]."""
+        return self.d_inner + self.conv_dim + self.n_heads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,9 +157,11 @@ class TransformerConfig:
     tie_word_embeddings: bool = False
     is_critic: bool = False  # scalar head instead of lm head
     moe: Optional[MoEConfig] = None
+    ssm: Optional[SSMConfig] = None  # the MAMBA layers' mixer
     # sliding window attention (mistral/gemma2); None = full attention
     sliding_window: Optional[int] = None
-    # The attention kind of each layer, FULL or SLIDING (HF ``layer_types``);
+    # The kind of each layer: FULL or SLIDING (HF ``layer_types``), or one
+    # of MIXER_KINDS (HF ``hybrid_override_pattern``);
     # None = every layer alike: SLIDING where ``sliding_window`` is set.
     layer_types: Optional[Tuple[str, ...]] = None
     # (kind, RopeConfig) for the kinds whose RoPE is not the plain table
@@ -115,7 +174,8 @@ class TransformerConfig:
     # with biases (gpt2)
     mlp_type: str = "gated"
     norm_type: str = "rms"  # "rms" | "layer" (gpt2 LayerNorm with bias)
-    # "rope" | "learned" (gpt2 absolute position table)
+    # "rope" | "learned" (gpt2 absolute position table) | "none" (the
+    # attention layers of a hybrid model carry no position embedding)
     pos_embedding: str = "rope"
     max_position_embeddings: Optional[int] = None  # learned-pos table size
     scale_embeddings: bool = False  # gemma: hidden *= sqrt(hidden_dim)
@@ -166,6 +226,15 @@ class TransformerConfig:
         return next(
             kinds[:p] for p in range(1, len(kinds) + 1)
             if len(kinds) % p == 0 and kinds[:p] * (len(kinds) // p) == kinds)
+
+    @property
+    def is_hybrid(self) -> bool:
+        """Layers that are one mixer alone: ``params["layers"]`` is then a
+        tree per KIND, each stacked over that kind's layers."""
+        return any(k in MIXER_KINDS for k in self.layer_kinds)
+
+    def n_layers_of(self, kind: str) -> int:
+        return self.layer_kinds.count(kind)
 
     def window_of(self, kind: str) -> Optional[int]:
         return self.sliding_window if kind == SLIDING else None

@@ -22,6 +22,7 @@ import numpy as np
 from areal_tpu.api.model import GenerationHyperparameters
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models.transformer import (
+    DECODE_REFUSAL,
     forward,
     init_kv_cache,
     kv_valid_by_kind,
@@ -31,6 +32,15 @@ from areal_tpu.ops.sampling import (
     sample_token_rows,
     sampling_from_gconfigs,
 )
+
+
+def decode_refusal(cfg: TransformerConfig) -> Optional[str]:
+    """Why this model cannot be decoded here, by name
+    (``transformer.DECODE_REFUSAL``), or None: a layer that is one mixer
+    alone — a state-space layer among them — has no cache to decode
+    from. Every entry point below prefills through ``forward``, which
+    raises it."""
+    return DECODE_REFUSAL if cfg.is_hybrid else None
 
 
 @partial(

@@ -4,7 +4,7 @@ Parity target: the reference's per-family converter registry
 (``realhf/impl/model/conversion/hf_registry.py:32`` +
 ``realhf/api/from_hf/{llama,qwen2,qwen3,gemma,gpt2,mistral,mixtral}.py``).
 Families covered: llama, qwen2 (qwen2.5), qwen3, mistral, gemma, gpt2,
-mixtral, qwen3_moe, olmoe, mellum.
+mixtral, qwen3_moe, olmoe, mellum, nemotron_h.
 
 Weights are stacked on a leading layer axis (see models/transformer.py), so
 conversion transposes HF's ``[out, in]`` linear layout to ``[in, out]`` and
@@ -26,10 +26,14 @@ import numpy as np
 
 from areal_tpu.base import logging
 from areal_tpu.models.config import (
+    ATTENTION_ONLY,
     FULL,
+    MAMBA,
+    MOE_ONLY,
     SLIDING,
     MoEConfig,
     RopeConfig,
+    SSMConfig,
     TransformerConfig,
 )
 
@@ -235,12 +239,7 @@ def _mellum_config(hf_config: Any) -> TransformerConfig:
             raise ValueError(
                 f"{len(types)} layer_types for {kw['n_layers']} layers")
     held = hf_config.num_experts
-    routed = getattr(hf_config, "num_routed_experts", None) or held
-    shards = getattr(hf_config, "expert_shard_count", None) or routed // held
-    if held * shards != routed:
-        raise ValueError(
-            f"{shards} shards of {held} experts are not the {routed} "
-            "the router scores")
+    routed, first = _expert_share(hf_config, held)
     return TransformerConfig(
         **kw,
         sliding_window=getattr(hf_config, "sliding_window", None)
@@ -256,11 +255,97 @@ def _mellum_config(hf_config: Any) -> TransformerConfig:
             routed_intermediate_dim=hf_config.moe_intermediate_size,
             aux_loss_coeff=getattr(hf_config, "router_aux_loss_coef", 1e-3),
             norm_topk_prob=getattr(hf_config, "norm_topk_prob", True),
-            router_experts=routed if routed != held else None,
-            first_expert=held * int(
-                getattr(hf_config, "expert_shard_index", 0) or 0),
+            router_experts=routed,
+            first_expert=first,
         ),
         hf_family="mellum",
+    )
+
+
+def _expert_share(hf_config: Any, held: int):
+    """(router_experts, first_expert) of a SHARE of an expert layer — this
+    repo's scalar keys ``num_routed_experts`` (the published count, the
+    router's width), ``expert_shard_count`` (the ranks sharing a layer)
+    and ``expert_shard_index`` (this rank, which holds the experts from
+    ``index * held`` on); (None, 0) where every expert is held."""
+    routed = getattr(hf_config, "num_routed_experts", None) or held
+    shards = getattr(hf_config, "expert_shard_count", None) or routed // held
+    if held * shards != routed:
+        raise ValueError(
+            f"{shards} shards of {held} experts are not the {routed} "
+            "the router scores")
+    first = held * int(getattr(hf_config, "expert_shard_index", 0) or 0)
+    return (routed if routed != held else None), first
+
+
+# HF ``hybrid_override_pattern`` letter <-> mixer kind.
+_HYBRID_LETTERS = {"M": MAMBA, "E": MOE_ONLY, "*": ATTENTION_ONLY}
+
+
+@register_hf_family("nemotron_h")
+def _nemotron_h_config(hf_config: Any) -> TransformerConfig:
+    """Nemotron-H / Nemotron 3 (``NemotronHForCausalLM``): every layer is
+    ONE mixer, ``h + f(norm(h))``, its kind a letter of
+    ``hybrid_override_pattern`` — ``M`` a Mamba-2 mixer (``d_inner =
+    mamba_num_heads * mamba_head_dim``), ``E`` an expert layer (sigmoid
+    scores, choice by score + ``e_score_correction_bias``, gates
+    renormalised and scaled; ``relu2`` experts that are not gated, in a
+    latent width where ``moe_latent_size`` is set; one shared expert on
+    every token; no token dropped), ``*`` attention with no position
+    embedding. A dense ``-`` (MLP) layer is not supported. A SHARE holds
+    ``n_routed_experts`` of ``num_routed_experts`` (:func:`_expert_share`)."""
+    pattern = hf_config.hybrid_override_pattern[:hf_config.num_hidden_layers]
+    if len(pattern) != hf_config.num_hidden_layers or set(pattern) - set(
+            _HYBRID_LETTERS):
+        raise NotImplementedError(
+            f"hybrid_override_pattern {pattern!r} for "
+            f"{hf_config.num_hidden_layers} layers (letters "
+            f"{sorted(_HYBRID_LETTERS)} are supported)")
+    if getattr(hf_config, "n_group", 1) != 1:
+        raise NotImplementedError("group-limited routing (n_group > 1)")
+    act = getattr(hf_config, "mlp_hidden_act", "relu2")
+    held = hf_config.n_routed_experts
+    routed, first = _expert_share(hf_config, held)
+    shared = (getattr(hf_config, "n_shared_experts", 0) or 0) * (
+        getattr(hf_config, "moe_shared_expert_intermediate_size", 0) or 0)
+    kw = _base_kwargs(hf_config)
+    kw["rms_norm_eps"] = getattr(
+        hf_config, "norm_eps", getattr(hf_config, "layer_norm_epsilon", 1e-5))
+    return TransformerConfig(
+        **kw,
+        pos_embedding="none",
+        layer_types=tuple(_HYBRID_LETTERS[c] for c in pattern),
+        max_position_embeddings=getattr(
+            hf_config, "max_position_embeddings", None),
+        ssm=SSMConfig(
+            n_heads=hf_config.mamba_num_heads,
+            head_dim=hf_config.mamba_head_dim,
+            n_groups=hf_config.n_groups,
+            state_dim=hf_config.ssm_state_size,
+            conv_kernel=hf_config.conv_kernel,
+            chunk_size=hf_config.chunk_size,
+            time_step_min=getattr(hf_config, "time_step_min", 0.001),
+            time_step_max=getattr(hf_config, "time_step_max", 0.1),
+            time_step_floor=getattr(hf_config, "time_step_floor", 1e-4),
+        ),
+        moe=MoEConfig(
+            num_experts=held,
+            top_k=hf_config.num_experts_per_tok,
+            capacity_factor=None,
+            routed_intermediate_dim=hf_config.moe_intermediate_size,
+            shared_intermediate_dim=shared or None,
+            aux_loss_coeff=0.0,
+            norm_topk_prob=getattr(hf_config, "norm_topk_prob", True),
+            router_experts=routed,
+            first_expert=first,
+            router_score="sigmoid",
+            routed_scaling_factor=float(
+                getattr(hf_config, "routed_scaling_factor", 1.0)),
+            latent_dim=getattr(hf_config, "moe_latent_size", None),
+            gated_experts=False,
+            expert_act=act,
+        ),
+        hf_family="nemotron_h",
     )
 
 
@@ -491,12 +576,97 @@ def _gpt2_to_sd(
     return sd
 
 
+# nemotron_h: (mixer kind, pytree key, HF name under
+# ``backbone.layers.{i}.``, transpose). The depthwise convolution is
+# ``[channels, 1, K]`` there and ``[K, channels]`` here.
+_NEMOTRON_H_NAMES = [
+    (MAMBA, "in_proj", "mixer.in_proj.weight", True),
+    (MAMBA, "conv_b", "mixer.conv1d.bias", False),
+    (MAMBA, "dt_bias", "mixer.dt_bias", False),
+    (MAMBA, "A_log", "mixer.A_log", False),
+    (MAMBA, "D", "mixer.D", False),
+    (MAMBA, "norm", "mixer.norm.weight", False),
+    (MAMBA, "out_proj", "mixer.out_proj.weight", True),
+    (ATTENTION_ONLY, "wq", "mixer.q_proj.weight", True),
+    (ATTENTION_ONLY, "wk", "mixer.k_proj.weight", True),
+    (ATTENTION_ONLY, "wv", "mixer.v_proj.weight", True),
+    (ATTENTION_ONLY, "wo", "mixer.o_proj.weight", True),
+    (MOE_ONLY, "router", "mixer.gate.weight", True),
+    (MOE_ONLY, "router_bias", "mixer.gate.e_score_correction_bias", False),
+    (MOE_ONLY, "latent_down", "mixer.fc1_latent_proj.weight", True),
+    (MOE_ONLY, "latent_up", "mixer.fc2_latent_proj.weight", True),
+    (MOE_ONLY, "s_up", "mixer.shared_experts.up_proj.weight", True),
+    (MOE_ONLY, "s_down", "mixer.shared_experts.down_proj.weight", True),
+]
+_NEMOTRON_H_EXPERTS = {"e_up": "mixer.experts.{e}.up_proj.weight",
+                       "e_down": "mixer.experts.{e}.down_proj.weight"}
+
+
+def _nemotron_h_to_sd(
+    params: Dict[str, Any], cfg: TransformerConfig
+) -> Dict[str, np.ndarray]:
+    sd = {
+        "backbone.embeddings.weight": np.asarray(params["embedding"]),
+        "backbone.norm_f.weight": np.asarray(params["final_ln"]),
+        "lm_head.weight": np.asarray(params["lm_head"]).T,
+    }
+    seen = {kind: 0 for kind in params["layers"]}
+    for i, kind in enumerate(cfg.layer_kinds):
+        lp = {k: np.asarray(v[seen[kind]])
+              for k, v in params["layers"][kind].items()}
+        seen[kind] += 1
+        pre = f"backbone.layers.{i}."
+        sd[pre + "norm.weight"] = lp["ln"]
+        for _, key, name, tr in (
+                m for m in _NEMOTRON_H_NAMES if m[0] == kind and m[1] in lp):
+            sd[pre + name] = lp[key].T if tr else lp[key]
+        if kind == MAMBA:
+            sd[pre + "mixer.conv1d.weight"] = lp["conv_w"].T[:, None, :]
+        if kind == MOE_ONLY:
+            for key, name in _NEMOTRON_H_EXPERTS.items():
+                for e in range(cfg.moe.num_experts):
+                    sd[pre + name.format(e=e)] = lp[key][e].T
+    return sd
+
+
+def _nemotron_h_from_sd(
+    sd: Dict[str, Any], cfg: TransformerConfig, dtype: str
+) -> Dict[str, Any]:
+    per_kind: Dict[str, Dict[str, list]] = {}
+    for i, kind in enumerate(cfg.layer_kinds):
+        pre = f"backbone.layers.{i}."
+        lp = per_kind.setdefault(kind, {})
+        lp.setdefault("ln", []).append(_np(sd[pre + "norm.weight"]))
+        for _, key, name, tr in (m for m in _NEMOTRON_H_NAMES
+                                 if m[0] == kind and pre + m[2] in sd):
+            w = _np(sd[pre + name])
+            lp.setdefault(key, []).append(w.T if tr else w)
+        if kind == MAMBA:
+            lp.setdefault("conv_w", []).append(
+                _np(sd[pre + "mixer.conv1d.weight"])[:, 0, :].T)
+        if kind == MOE_ONLY:
+            for key, name in _NEMOTRON_H_EXPERTS.items():
+                lp.setdefault(key, []).append(np.stack([
+                    _np(sd[pre + name.format(e=e)]).T
+                    for e in range(cfg.moe.num_experts)]))
+    return {
+        "embedding": _np(sd["backbone.embeddings.weight"]).astype(dtype),
+        "layers": {kind: {k: np.stack(v).astype(dtype)
+                          for k, v in lp.items()}
+                   for kind, lp in per_kind.items()},
+        "final_ln": _np(sd["backbone.norm_f.weight"]).astype(dtype),
+        "lm_head": _np(sd["lm_head.weight"]).T.astype(dtype),
+    }
+
+
 def params_from_hf_state_dict(
     sd: Dict[str, Any], cfg: TransformerConfig, dtype: str = "float32"
 ) -> Dict[str, Any]:
     """HF causal-LM state dict → stacked areal_tpu param pytree (numpy)."""
     if cfg.hf_family == "gpt2":
         return _gpt2_from_sd(sd, cfg, dtype)
+    if cfg.hf_family == "nemotron_h":
+        return _nemotron_h_from_sd(sd, cfg, dtype)
     return _llama_from_sd(sd, cfg, dtype)
 
 
@@ -506,6 +676,8 @@ def params_to_hf_state_dict(
     """Inverse conversion (for publishing weights / HF-format checkpoints)."""
     if cfg.hf_family == "gpt2":
         return _gpt2_to_sd(params, cfg)
+    if cfg.hf_family == "nemotron_h":
+        return _nemotron_h_to_sd(params, cfg)
     return _llama_to_sd(params, cfg)
 
 
@@ -522,6 +694,7 @@ _HF_ARCH = {
     "qwen3_moe": "Qwen3MoeForCausalLM",
     "olmoe": "OlmoeForCausalLM",
     "mellum": "MellumForCausalLM",
+    "nemotron_h": "NemotronHForCausalLM",
 }
 
 
@@ -543,6 +716,8 @@ def hf_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
             "activation_function": "gelu_new",
             "tie_word_embeddings": True,
         }
+    if fam == "nemotron_h":
+        return _nemotron_h_config_dict(cfg)
     d: Dict[str, Any] = {
         "model_type": fam,
         "architectures": [_HF_ARCH.get(fam, "LlamaForCausalLM")],
@@ -605,6 +780,63 @@ def hf_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
             names[kind]: _rope_dict(cfg.rope_of(kind))
             for kind in dict.fromkeys(cfg.layer_kinds)
         }
+    return d
+
+
+def _nemotron_h_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
+    """The inverse of :func:`_nemotron_h_config`."""
+    ssm, moe = cfg.ssm, cfg.moe
+    letters = {kind: c for c, kind in _HYBRID_LETTERS.items()}
+    d = {
+        "model_type": "nemotron_h",
+        "architectures": [_HF_ARCH["nemotron_h"]],
+        "num_hidden_layers": cfg.n_layers,
+        "hybrid_override_pattern": "".join(
+            letters[k] for k in cfg.layer_kinds),
+        "hidden_size": cfg.hidden_dim,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "head_dim": cfg.head_dim,
+        "attention_bias": False,
+        "intermediate_size": cfg.intermediate_dim,
+        "vocab_size": cfg.vocab_size,
+        "norm_eps": cfg.rms_norm_eps,
+        "layer_norm_epsilon": cfg.rms_norm_eps,
+        "tie_word_embeddings": cfg.tie_word_embeddings,
+        "max_position_embeddings": cfg.max_position_embeddings or 32768,
+        "mamba_num_heads": ssm.n_heads,
+        "mamba_head_dim": ssm.head_dim,
+        "n_groups": ssm.n_groups,
+        "ssm_state_size": ssm.state_dim,
+        "conv_kernel": ssm.conv_kernel,
+        "chunk_size": ssm.chunk_size,
+        "expand": ssm.d_inner // cfg.hidden_dim or 1,
+        "use_conv_bias": True,
+        "mamba_proj_bias": False,
+        "mamba_hidden_act": "silu",
+        "time_step_min": ssm.time_step_min,
+        "time_step_max": ssm.time_step_max,
+        "time_step_floor": ssm.time_step_floor,
+        "n_routed_experts": moe.num_experts,
+        "num_experts_per_tok": moe.top_k,
+        "moe_intermediate_size": moe.routed_intermediate_dim,
+        "moe_shared_expert_intermediate_size":
+            moe.shared_intermediate_dim or 0,
+        "n_shared_experts": 1 if moe.shared_intermediate_dim else 0,
+        "norm_topk_prob": moe.norm_topk_prob,
+        "routed_scaling_factor": moe.routed_scaling_factor,
+        "n_group": 1,
+        "topk_group": 1,
+        "mlp_hidden_act": moe.expert_act,
+        "mlp_bias": False,
+        "torch_dtype": "float32",
+    }
+    if moe.latent_dim:
+        d["moe_latent_size"] = moe.latent_dim
+    if moe.is_share:
+        d["num_routed_experts"] = moe.n_routed
+        d["expert_shard_count"] = moe.n_routed // moe.num_experts
+        d["expert_shard_index"] = moe.first_expert // moe.num_experts
     return d
 
 

@@ -158,12 +158,24 @@ def _routing(
             rng, xf.shape, minval=1 - eps, maxval=1 + eps, dtype=xf.dtype
         )
     logits = (router_in @ lp["router"]).astype(jnp.float32)  # [N, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, k)  # [N, k]
+    if moe.router_score == "sigmoid":
+        # Each expert's score is a sigmoid of its own logit; the choice is
+        # the top-k of score + bias (the publisher's load-balancing
+        # buffer: no gradient), the gates are the chosen scores.
+        scores = jax.nn.sigmoid(logits)
+        bias = jax.lax.stop_gradient(lp["router_bias"].astype(jnp.float32))
+        _, top_i = jax.lax.top_k(scores + bias, k)
+        top_p = jnp.take_along_axis(scores, top_i, axis=-1)
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_i = jax.lax.top_k(probs, k)  # [N, k]
     if moe.norm_topk_prob:
         top_p = top_p / jnp.maximum(
             jnp.sum(top_p, axis=-1, keepdims=True), 1e-9
         )
+    if moe.routed_scaling_factor != 1.0:
+        top_p = top_p * moe.routed_scaling_factor
 
     # ---- balancing losses (reference router.py:78,146) ----
     # f_e: fraction of (real) tokens routed to expert e; P_e: mean prob.
@@ -205,6 +217,18 @@ def _capacity_keep(onehot: jnp.ndarray, C: int) -> Tuple[jnp.ndarray, jnp.ndarra
     pos = jnp.sum(pos * onehot, axis=-1)  # [N, k] slot per choice
     keep = (pos < C) & (jnp.sum(onehot, axis=-1) > 0)
     return pos, keep
+
+
+# Leaves of an expert layer that are no weights: they take no gradient
+# (:func:`_routing` stops it) and the optimizer does not decay them.
+BUFFER_LEAVES = ("router_bias",)
+
+
+def relu2(x: jnp.ndarray) -> jnp.ndarray:
+    return jnp.square(jax.nn.relu(x))
+
+
+EXPERT_ACTS = {"silu": jax.nn.silu, "relu2": relu2}
 
 
 def _expert_ffn(xe, gate_w, up_w, down_w):
@@ -295,30 +319,49 @@ def sorted_rows(entries: int, held: int, n_routed: int) -> int:
     return min(entries, math.ceil(want / _ROW_TILE) * _ROW_TILE)
 
 
+def _whole_row_tiles(xe: jnp.ndarray) -> jnp.ndarray:
+    """The latent tokens ``[N, latent]`` with zero rows up to a whole
+    number of row tiles. They are what the expert pass gathers its rows
+    from, and a source this narrow is small enough for the TPU compiler to
+    hold in VMEM: its in-VMEM gather ran out of scoped vmem ("16.25M of
+    16.00M") for a [3456, 1024] bfloat16 source, whatever the rows
+    gathered, the mode or the ``cond`` around it, and compiles at every
+    packed length once the source is whole tiles (3584 rows there; my
+    compiles for a described v5e and my chip runs, PR 34; PERF.md §6;
+    tests/test_tpu_compile.py compiles the benchmark's grids). No entry
+    points past the tokens, so the rows added are never read."""
+    return jnp.pad(xe, ((0, -xe.shape[0] % _ROW_TILE), (0, 0)))
+
+
 def _rows_pass(
     rows: int,  # static: the rows of the sort the pass runs on
-    xf: jnp.ndarray,  # [N, D] tokens
+    act,  # static: the experts' activation
+    k: int,  # static: entries a token
+    xf: jnp.ndarray,  # [N, D] tokens (rows past N = M // k are never read)
     order: jnp.ndarray,  # [M] the entries sorted by group
     gate: jnp.ndarray,  # [M] gate of each entry, in sorted order
     group_sizes: jnp.ndarray,  # [G] int32
-    gate_w: jnp.ndarray,  # [G, D, F]
+    gate_w: Optional[jnp.ndarray],  # [G, D, F]; None = experts not gated
     up_w: jnp.ndarray,  # [G, D, F]
     down_w: jnp.ndarray,  # [G, F, D]
 ) -> jnp.ndarray:
     """The expert pass on the first ``rows`` rows of the sort: row gather,
-    three grouped GEMMs, gate multiply. Returns the gate-weighted expert
+    the grouped GEMMs (three, or two where the experts are not gated),
+    gate multiply. Returns the gate-weighted expert
     outputs of all ``M`` sorted entries [M, D], zero past ``rows``. Exact
     where ``sum(group_sizes) <= rows``: the live rows are the sort's head,
     and a zero row is what an entry past them has."""
     M = order.shape[0]
-    k = M // xf.shape[0]
     with jax.named_scope("moe_dispatch"):
         head = order if rows == M else order[:rows]
         xs = jnp.take(xf, head // k, axis=0)  # [rows, D] sorted inputs
     with jax.named_scope("moe_experts"):
-        h = jax.nn.silu(
-            _grouped_matmul(xs, gate_w, group_sizes)
-        ) * _grouped_matmul(xs, up_w, group_sizes)
+        if gate_w is None:
+            h = act(_grouped_matmul(xs, up_w, group_sizes))
+        else:
+            h = act(
+                _grouped_matmul(xs, gate_w, group_sizes)
+            ) * _grouped_matmul(xs, up_w, group_sizes)
         ys = _grouped_matmul(h, down_w, group_sizes)  # [rows, D]
     with jax.named_scope("moe_dispatch"):
         ys = ys * (gate if rows == M else gate[:rows]).astype(
@@ -326,8 +369,8 @@ def _rows_pass(
         return ys if rows == M else jnp.pad(ys, ((0, M - rows), (0, 0)))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _bounded_pass(rows: int, xf, order, gate, group_sizes,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _bounded_pass(rows: int, act, k: int, xf, order, gate, group_sizes,
                   gate_w, up_w, down_w) -> jnp.ndarray:
     """:func:`_rows_pass` on ``rows`` rows where the live rows fit them,
     else on all of them. Its own VJP, so that the backward pass is the
@@ -339,21 +382,22 @@ def _bounded_pass(rows: int, xf, order, gate, group_sizes,
     args = (xf, order, gate, group_sizes, gate_w, up_w, down_w)
     return jax.lax.cond(
         jnp.sum(group_sizes) <= rows,
-        functools.partial(_rows_pass, rows),
-        functools.partial(_rows_pass, order.shape[0]), *args)
+        functools.partial(_rows_pass, rows, act, k),
+        functools.partial(_rows_pass, order.shape[0], act, k), *args)
 
 
-def _bounded_pass_fwd(rows, *args):
-    return _bounded_pass(rows, *args), args
+def _bounded_pass_fwd(rows, act, k, *args):
+    return _bounded_pass(rows, act, k, *args), args
 
 
-def _bounded_pass_bwd(rows, args, ct):
+def _bounded_pass_bwd(rows, act, k, args, ct):
     xf, order, gate, group_sizes, gate_w, up_w, down_w = args
 
     def pull(rows, ct):
         _, vjp = jax.vjp(
             lambda xf, gate, gate_w, up_w, down_w: _rows_pass(
-                rows, xf, order, gate, group_sizes, gate_w, up_w, down_w),
+                rows, act, k, xf, order, gate, group_sizes, gate_w, up_w,
+                down_w),
             xf, gate, gate_w, up_w, down_w)
         return vjp(ct)
 
@@ -374,10 +418,12 @@ def _sorted_expert_ffn(
     eid: jnp.ndarray,  # [N·k] group of each (token, choice) entry; G = none
     gates: jnp.ndarray,  # [N·k] gate of each entry
     cap: Optional[int],  # slots per group; None = dropless
-    gate_w: jnp.ndarray,  # [G, D, F]
+    gate_w: Optional[jnp.ndarray],  # [G, D, F]; None = experts not gated
     up_w: jnp.ndarray,  # [G, D, F]
     down_w: jnp.ndarray,  # [G, F, D]
     rows: int,  # sorted rows the pass runs on; N·k = all of them, no bound
+    act=jax.nn.silu,  # the experts' activation
+    k: Optional[int] = None,  # entries a token; None = xf holds just the N
 ) -> Tuple[jnp.ndarray, jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Sort-based grouped expert compute over the ``G`` experts whose
     weights are given: one stable argsort of the ``M = N·k`` entries by
@@ -404,10 +450,11 @@ def _sorted_expert_ffn(
     are computed and contribute nothing); the positions are taken over all
     ``M`` entries whatever ``R``. Dropless (``cap=None``) builds no
     positions and no keep mask."""
-    N, D = xf.shape
+    D = xf.shape[1]
     M = eid.shape[0]
-    k = M // N
-    G = gate_w.shape[0]
+    k = M // xf.shape[0] if k is None else k
+    N = M // k  # xf may hold rows past them (moe_mlp's latent source)
+    G = up_w.shape[0]
     R = rows
     with jax.named_scope("moe_dispatch"):
         order = jnp.argsort(eid)  # jnp argsort is stable
@@ -424,7 +471,8 @@ def _sorted_expert_ffn(
             kept = jnp.sum(keep)
             gate = gate * keep
     args = (xf, order, gate, group_sizes, gate_w, up_w, down_w)
-    ys = _rows_pass(M, *args) if R == M else _bounded_pass(R, *args)
+    ys = (_rows_pass(M, act, k, *args) if R == M
+          else _bounded_pass(R, act, k, *args))
     with jax.named_scope("moe_dispatch"):
         inv = jnp.argsort(order)  # inverse permutation
         y = jnp.sum(jnp.take(ys, inv, axis=0).reshape(N, k, D), axis=1)
@@ -448,7 +496,7 @@ def _dispatch_grouped(
     experts held elsewhere. Returns (y, dropped_frac, the aux of this
     path: on a share ``local_rows``, the entries that chose an expert held
     here, and the counts of its bounded pass)."""
-    N = xf.shape[0]
+    N = top_i.shape[0]
     E, k = moe.num_experts, moe.top_k
     extra = {}
     if moe.is_share:
@@ -460,8 +508,8 @@ def _dispatch_grouped(
     dropless = moe.capacity_factor is None
     y, kept, counts = _sorted_expert_ffn(
         xf, eid, top_p.reshape(N * k), None if dropless else capacity(N, moe),
-        lp["e_gate"], lp["e_up"], lp["e_down"],
-        sorted_rows(N * k, E, moe.n_routed),
+        lp.get("e_gate"), lp["e_up"], lp["e_down"],
+        sorted_rows(N * k, E, moe.n_routed), EXPERT_ACTS[moe.expert_act], k,
     )
     extra.update(counts)
     if dropless:
@@ -633,54 +681,88 @@ def moe_mlp(
     with jax.named_scope("moe_router"):
         top_p, top_i, onehot, aux = _routing(xf, lp, moe, rng, valid)
 
+    latent = "latent_down" in lp  # the experts work in a latent width
+    grouped_only = latent or "e_gate" not in lp
     if mesh is not None and ep_eligible(mesh, moe, B, T):
+        if grouped_only:
+            raise NotImplementedError(
+                "expert parallelism over 'ep' runs gated experts at the "
+                "hidden width; latent or ungated experts run as a share")
         y, dropped_frac = _dispatch_ep(x, top_p, top_i, valid, lp, moe, mesh)
         extra = {}
     elif resolve_dispatch(dispatch) == "einsum":
-        if moe.is_share:
+        if moe.is_share or grouped_only:
             raise NotImplementedError(
-                "the einsum oracle holds every expert; a share runs the "
-                "grouped dispatch")
+                "the einsum oracle holds every expert, gated, at the "
+                "hidden width; anything else runs the grouped dispatch")
         y, dropped_frac = _dispatch_einsum(xf, top_p, onehot, lp, moe, n_valid)
         extra = {}
     else:
+        xe = xf
+        if latent:
+            with jax.named_scope("latent_down"):
+                xe = _whole_row_tiles(xf @ lp["latent_down"])
         y, dropped_frac, extra = _dispatch_grouped(
-            xf, top_p, top_i, valid, lp, moe, n_valid
+            xe, top_p, top_i, valid, lp, moe, n_valid
         )
+        if latent:
+            with jax.named_scope("latent_up"):
+                y = y.astype(xf.dtype) @ lp["latent_up"]
 
-    if "s_gate" in lp:  # always-on shared expert (qwen-moe)
-        y = y + (jax.nn.silu(xf @ lp["s_gate"]) * (xf @ lp["s_up"])) @ lp["s_down"]
+    if "s_up" in lp:  # always-on shared expert, on every token
+        act = EXPERT_ACTS[moe.expert_act]
+        with jax.named_scope("shared_expert"):
+            if "s_gate" in lp:  # gated (qwen-moe)
+                s_h = act(xf @ lp["s_gate"]) * (xf @ lp["s_up"])
+            else:
+                s_h = act(xf @ lp["s_up"])
+            y = y + s_h @ lp["s_down"]
 
     aux = dict(aux, **extra)
     aux["dropped_frac"] = dropped_frac
     return y.reshape(B, T, D).astype(x.dtype), aux
 
 
-def init_moe_params(cfg, key: jnp.ndarray, dtype) -> Dict[str, jnp.ndarray]:
-    """Per-layer-stacked MoE weights ([n_layers, ...])."""
+def moe_param_shapes(cfg) -> Dict[str, Tuple[int, ...]]:
+    """{name: shape} of ONE expert layer's weights, read off the config:
+    the router over all the published experts (with its choice bias where
+    the scores are sigmoids), the held experts at the width they work in
+    (the latent one where the model has it, with the two projections
+    around them), gated or not, and the shared expert."""
     moe = cfg.moe
-    n, d = cfg.n_layers, cfg.hidden_dim
+    d = cfg.hidden_dim
+    de = moe.latent_dim or d  # the width the experts read and write
     f = moe.routed_intermediate_dim or cfg.intermediate_dim
     E = moe.num_experts  # held; the router scores all of ``n_routed``
-    # One key per weight actually initialized — adding a weight grows the
-    # split instead of silently reusing a neighbour's key.
-    names = ["router", "e_gate", "e_up", "e_down"]
-    if moe.shared_intermediate_dim:
-        names += ["s_gate", "s_up", "s_down"]
-    ks = dict(zip(names, jax.random.split(key, len(names))))
-
-    def nrm(k, shape, scale=0.02):
-        return (jax.random.normal(k, shape) * scale).astype(dtype)
-
-    out = {
-        "router": nrm(ks["router"], (n, d, moe.n_routed)),
-        "e_gate": nrm(ks["e_gate"], (n, E, d, f)),
-        "e_up": nrm(ks["e_up"], (n, E, d, f)),
-        "e_down": nrm(ks["e_down"], (n, E, f, d)),
-    }
+    shapes = {"router": (d, moe.n_routed)}
+    if moe.router_score == "sigmoid":
+        shapes["router_bias"] = (moe.n_routed,)
+    if moe.gated_experts:
+        shapes["e_gate"] = (E, de, f)
+    shapes["e_up"] = (E, de, f)
+    shapes["e_down"] = (E, f, de)
+    if moe.latent_dim:
+        shapes["latent_down"] = (d, de)
+        shapes["latent_up"] = (de, d)
     if moe.shared_intermediate_dim:
         fs = moe.shared_intermediate_dim
-        out["s_gate"] = nrm(ks["s_gate"], (n, d, fs))
-        out["s_up"] = nrm(ks["s_up"], (n, d, fs))
-        out["s_down"] = nrm(ks["s_down"], (n, fs, d))
-    return out
+        if moe.gated_experts:
+            shapes["s_gate"] = (d, fs)
+        shapes["s_up"] = (d, fs)
+        shapes["s_down"] = (fs, d)
+    return shapes
+
+
+def init_moe_params(cfg, key: jnp.ndarray, dtype,
+                    n: Optional[int] = None) -> Dict[str, jnp.ndarray]:
+    """Per-layer-stacked MoE weights ([n, ...]; every layer of the model
+    unless ``n`` says how many are expert layers)."""
+    n = cfg.n_layers if n is None else n
+    shapes = moe_param_shapes(cfg)
+    # One key per weight actually initialized — adding a weight grows the
+    # split instead of silently reusing a neighbour's key.
+    ks = dict(zip(shapes, jax.random.split(key, len(shapes))))
+    return {
+        name: (jax.random.normal(ks[name], (n,) + shape) * 0.02).astype(dtype)
+        for name, shape in shapes.items()
+    }

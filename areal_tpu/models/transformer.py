@@ -18,11 +18,20 @@ optional qk-norm (per head: qwen3; whole vector: olmoe), optional attention
 biases (qwen2), tied embeddings,
 critic (scalar) head, and a KV-cache decode mode.
 
-Layers need not be alike: ``cfg.layer_kinds`` gives each its attention
-kind (full or sliding-window, each with its own RoPE table), and the scan
-runs over PERIODS of that pattern (:func:`_scan_layers`) — one layer a
-step for a model whose layers are alike, which is every family but
-mellum.
+Layers need not be alike: ``cfg.layer_kinds`` gives each its kind, and
+the scan runs over PERIODS of that pattern (:func:`_scan_layers`) — one
+layer a step for a model whose layers are alike, which is every family
+but mellum and nemotron_h. Two sorts of pattern:
+
+ - attention kinds (full or sliding-window, each with its own RoPE
+   table): every layer is attention + MLP with the same parameter shapes,
+   ``params["layers"]`` is ONE tree stacked ``[n_layers, ...]`` (mellum);
+ - mixer kinds (``config.MIXER_KINDS``, nemotron_h): a layer is one mixer
+   alone, ``h + f(norm(h))`` with ``f`` a Mamba-2 mixer (models/ssm.py),
+   an expert layer (models/moe.py) or attention without a position
+   embedding — the kinds differ in parameter SHAPES, so
+   ``params["layers"]`` is a tree per kind, ``{kind: {name: [n_kind,
+   ...]}}``, and a period's layers slice their kind's stack.
 """
 
 from __future__ import annotations
@@ -33,7 +42,14 @@ from typing import Any, Callable, Dict, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-from areal_tpu.models.config import RopeConfig, TransformerConfig
+from areal_tpu.models.config import (
+    ATTENTION_ONLY,
+    MAMBA,
+    MIXER_KINDS,
+    MOE_ONLY,
+    RopeConfig,
+    TransformerConfig,
+)
 from areal_tpu.ops.attention import decode_attention, packed_attention
 from areal_tpu.parallel.sharding import constrain, current_mesh
 
@@ -41,6 +57,38 @@ Params = Dict[str, Any]
 
 
 # ---------------- init ----------------
+
+def _init_mixer_layers(cfg: TransformerConfig, keys, dtype) -> Params:
+    """``params["layers"]`` of a hybrid model: a tree per kind, stacked
+    over that kind's layers."""
+    from areal_tpu.models import moe as moemod
+    from areal_tpu.models import ssm as ssmmod
+
+    d, qd, kvd = cfg.hidden_dim, cfg.q_dim, cfg.kv_dim
+
+    def nrm(k, shape, scale=0.02):
+        return (jax.random.normal(k, shape) * scale).astype(dtype)
+
+    layers: Params = {}
+    n = cfg.n_layers_of(MAMBA)
+    if n:
+        layers[MAMBA] = ssmmod.init_mamba_params(cfg.ssm, n, d, keys[10], dtype)
+    n = cfg.n_layers_of(MOE_ONLY)
+    if n:
+        layers[MOE_ONLY] = {
+            "ln": jnp.ones((n, d), dtype),
+            **moemod.init_moe_params(cfg, keys[4], dtype, n)}
+    n = cfg.n_layers_of(ATTENTION_ONLY)
+    if n:
+        layers[ATTENTION_ONLY] = {
+            "ln": jnp.ones((n, d), dtype),
+            "wq": nrm(keys[0], (n, d, qd)),
+            "wk": nrm(keys[1], (n, d, kvd)),
+            "wv": nrm(keys[2], (n, d, kvd)),
+            "wo": nrm(keys[3], (n, qd, d)),
+        }
+    return layers
+
 
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     dtype = jnp.dtype(cfg.dtype)
@@ -50,6 +98,17 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
 
     def nrm(k, shape, scale=0.02):
         return (jax.random.normal(k, shape) * scale).astype(dtype)
+
+    if cfg.is_hybrid:
+        assert cfg.norm_type == "rms" and not cfg.is_critic
+        params = {
+            "embedding": nrm(keys[7], (cfg.vocab_size, d)),
+            "layers": _init_mixer_layers(cfg, keys, dtype),
+            "final_ln": jnp.ones((d,), dtype),
+        }
+        if not cfg.tie_word_embeddings:
+            params["lm_head"] = nrm(keys[8], (d, cfg.vocab_size))
+        return params
 
     layers: Dict[str, jnp.ndarray] = {
         "ln1": jnp.ones((n, d), dtype),
@@ -191,6 +250,8 @@ def rope_tables_by_kind(
 ) -> Dict[str, Tuple[jnp.ndarray, jnp.ndarray]]:
     """{attention kind: (cos, sin)} for the kinds the model's layers have
     — one table for a model whose layers are alike."""
+    if cfg.pos_embedding == "none":
+        return {None: (None, None)}
     return {
         kind: rope_tables(positions, cfg.head_dim, cfg.rope_of(kind))
         for kind in dict.fromkeys(cfg.period_kinds)
@@ -229,6 +290,11 @@ def _block(
     B, T, D = h.shape
     if kind is None:
         kind = cfg.period_kinds[0]
+    if kind in MIXER_KINDS:
+        assert cache_kv is None, DECODE_REFUSAL
+        return _mixer_block(
+            cfg, kind, h, lp, segment_ids, positions, attn_impl, allow_ring,
+            ring_ctx)
     if isinstance(cos, dict):  # a table per attention kind
         cos, sin = cos[kind], sin[kind]
     if isinstance(kv_valid, dict):
@@ -311,6 +377,52 @@ def _block(
         else:
             mlp = (act(x @ lp["w_gate"]) * (x @ lp["w_up"])) @ lp["w_down"]
         return constrain(h + mlp, hid), new_kv, None
+
+
+# Why a model with mixer-only layers has no decode mode (models/generate.py
+# refuses it by this name).
+DECODE_REFUSAL = (
+    "recurrent_decode_state: a state-space layer decodes from a recurrent "
+    "state (its convolution's last taps and S), which no cache here holds")
+
+
+def _mixer_block(
+    cfg: TransformerConfig, kind: str, h: jnp.ndarray,
+    lp: Dict[str, jnp.ndarray], segment_ids, positions, attn_impl: str,
+    allow_ring: bool, ring_ctx,
+):
+    """A layer that is ONE mixer: ``h + f(norm(h))``, ``f`` by ``kind``.
+    Same returns as :func:`_block`; only the attention kind has K/V."""
+    B, T, D = h.shape
+    with jax.named_scope("attn_norm" if kind == ATTENTION_ONLY
+                         else "mlp_norm"):
+        x = rms_norm(h, lp["ln"], cfg.rms_norm_eps)
+    if kind == MAMBA:
+        from areal_tpu.models import ssm as ssmmod
+
+        out = ssmmod.mamba_mixer(x, lp, cfg.ssm, cfg.rms_norm_eps,
+                                 segment_ids)
+        return constrain(h + out, "hidden"), None, None
+    if kind == MOE_ONLY:
+        from areal_tpu.models import moe as moemod
+
+        with jax.named_scope("moe"):
+            out, aux = moemod.moe_mlp(
+                x, lp, cfg.moe,
+                mask=(segment_ids > 0) if segment_ids is not None else None)
+        return constrain(h + out, "hidden"), None, aux
+    dh = cfg.head_dim
+    with jax.named_scope("qkv_proj"):
+        q = (x @ lp["wq"]).reshape(B, T, cfg.n_q_heads, dh)
+        k = (x @ lp["wk"]).reshape(B, T, cfg.n_kv_heads, dh)
+        v = (x @ lp["wv"]).reshape(B, T, cfg.n_kv_heads, dh)
+    with jax.named_scope("attention"):
+        attn, new_kv = _attend(
+            cfg, q, k, v, segment_ids, positions, None, None, None,
+            attn_impl, allow_ring, ring_ctx, kind)
+    with jax.named_scope("o_proj"):
+        out = attn.reshape(B, T, cfg.q_dim) @ lp["wo"]
+    return constrain(h + out, "hidden"), new_kv, None
 
 
 def _attend(
@@ -412,6 +524,7 @@ def apply_layer_stack(
     ``rng=None`` keeps the original scan body (bit-identical off path)."""
 
     if rng is not None:
+        assert not cfg.is_hybrid, "router jitter under a mixer pattern"
         n_layers = jax.tree_util.tree_leaves(layer_params)[0].shape[0]
         layer_keys = jax.random.split(rng, n_layers)
 
@@ -454,6 +567,8 @@ def _scan_layers(cfg: TransformerConfig, layer: Callable, h, xs, remat=False):
     period of one layer is the plain scan over layers. Returns (h, ys) with
     ys stacked per layer."""
     kinds = cfg.period_kinds
+    if cfg.is_hybrid:
+        return _scan_mixer_layers(cfg, layer, h, xs, remat)
     if len(kinds) == 1:
         def body(h, x):
             return layer(kinds[0], h, x)
@@ -483,6 +598,96 @@ def _scan_layers(cfg: TransformerConfig, layer: Callable, h, xs, remat=False):
         period, h,
         jax.tree.map(lambda a: a.reshape(L // P, P, *a.shape[1:]), xs))
     return h, jax.tree.map(lambda a: a.reshape(L, *a.shape[2:]), ys)
+
+
+def period_runs(kinds: Tuple[str, ...]) -> Tuple[Tuple[Tuple[str, ...], int], ...]:
+    """A period of layer kinds as RUNS ``(unit, n)``: the unit's kinds,
+    ``n`` times over — at each position the repetition that covers most
+    (``E M E M E M E M E M *`` is ``((E, M), 5), ((*,), 1)``). A run is
+    traced once and scanned ``n`` times, so a period's program holds one
+    copy of each repeated layer, not ``n``."""
+    runs, i = [], 0
+    while i < len(kinds):
+        best = (kinds[i:i + 1], 1)
+        for u in range(1, (len(kinds) - i) // 2 + 1):
+            n = 1
+            while kinds[i + n * u:i + (n + 1) * u] == kinds[i:i + u]:
+                n += 1
+            if n > 1 and n * u > len(best[0]) * best[1]:
+                best = (kinds[i:i + u], n)
+        runs.append(best)
+        i += len(best[0]) * best[1]
+    return tuple(runs)
+
+
+def _scan_mixer_layers(cfg: TransformerConfig, layer: Callable, h, xs,
+                       remat=False):
+    """:func:`_scan_layers` for a hybrid model: ``xs`` is a tree per kind,
+    each stacked over that kind's layers. A scan over periods whose body
+    is, for each run of the period (:func:`period_runs`), a scan over the
+    run's repetitions of its unit; each layer checkpointed by itself
+    (``remat``), taking its slice of the unit's parameters inside its
+    checkpoint. The stacks are cut into the runs' ``[periods, n, layers of
+    the kind in a unit, ...]`` out here, so that every scan's ``xs`` is an
+    input of the scan around it and no copy of the weights is kept for
+    the backward pass. Returns (h, ys) with ys stacked over the layers
+    that return one (the expert layers' aux); None where no layer does."""
+    kinds = cfg.period_kinds
+    n_periods = cfg.n_layers // len(kinds)
+    runs = period_runs(kinds)
+
+    xs_runs, used = [], {kind: 0 for kind in xs}
+    for unit, n in runs:
+        xr = {}
+        for kind in dict.fromkeys(unit):
+            c, a0 = unit.count(kind), used[kind]
+            used[kind] += c * n
+
+            def cut(a, c=c, a0=a0, n=n):
+                a = a.reshape(n_periods, a.shape[0] // n_periods,
+                              *a.shape[1:])[:, a0:a0 + c * n]
+                shape = (n_periods,) + ((n,) if n > 1 else ()) + (c,)
+                return a.reshape(shape + a.shape[2:])
+
+            xr[kind] = jax.tree.map(cut, xs[kind])
+        xs_runs.append(xr)
+
+    def unit_body(unit):
+        steps = [
+            _maybe_checkpoint(
+                lambda h, xu, i=unit[:j].count(kind), kind=kind: layer(
+                    kind, h, jax.tree.map(lambda a: a[i], xu[kind])), remat)
+            for j, kind in enumerate(unit)
+        ]
+
+        def body(h, xu):
+            ys = []
+            for step in steps:
+                h, y = step(h, xu)
+                if y is not None:
+                    ys.append(y)
+            return h, (jax.tree.map(lambda *a: jnp.stack(a), *ys) if ys
+                       else None)
+
+        return body
+
+    bodies = [unit_body(unit) for unit, _ in runs]
+
+    def period(h, xrs):
+        ys = []
+        for (_, n), body, xr in zip(runs, bodies, xrs):
+            h, y = jax.lax.scan(body, h, xr) if n > 1 else body(h, xr)
+            if y is not None:  # [n, layers with a y in the unit, ...]
+                ys.append(jax.tree.map(
+                    lambda a: a.reshape(-1, *a.shape[2:]), y) if n > 1 else y)
+        return h, (jax.tree.map(lambda *a: jnp.concatenate(a), *ys) if ys
+                   else None)
+
+    h, ys = jax.lax.scan(period, h, xs_runs)
+    if ys is None:
+        return h, None
+    return h, jax.tree.map(
+        lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), ys)
 
 
 # What a layer's backward pass finds kept from its forward pass, from the
@@ -565,6 +770,25 @@ def remat_kept_bytes(
     n_sliding = cfg.layer_kinds.count(SLIDING)
     flash = flash_tokens * cfg.n_q_heads * (lanes * itemsize + 2 * 4)
     window = window_tokens * cfg.n_q_heads * (lanes * itemsize + 4)
+    if cfg.is_hybrid:
+        # A mixer layer's matmuls whose outputs its backward reads: the
+        # Mamba in-projection (the scan's einsums carry batch dimensions
+        # and are never kept); q/k/v; the router's logits, the latent
+        # down-projection and the shared expert's first matmul. The last
+        # matmul of each mixer feeds the residual sum alone.
+        moe = cfg.moe
+        widths = {
+            MAMBA: cfg.ssm.in_proj_dim if cfg.ssm else 0,
+            ATTENTION_ONLY: cfg.q_dim + 2 * cfg.kv_dim,
+            MOE_ONLY: (moe.n_routed + (moe.latent_dim or 0)
+                       + (moe.shared_intermediate_dim or 0)) if moe else 0,
+        }
+        kept = {"full": cfg.n_layers * full}
+        kept["attention"] = (
+            kept["full"] + cfg.n_layers_of(ATTENTION_ONLY) * flash)
+        kept["matmuls"] = kept["attention"] + tokens * itemsize * sum(
+            widths[kind] for kind in cfg.layer_kinds)
+        return kept
     # q/k/v, o_proj, and the MLP's matmuls into the hidden width (gate and
     # up, or up) — nothing in the backward reads the last matmul's output;
     # an MoE layer keeps the router's logits and its shared expert's pair.
@@ -614,6 +838,8 @@ def forward(
     attention kind (:func:`kv_valid_by_kind`).
     """
     decode = kv_cache is not None
+    if cfg.is_hybrid and (decode or return_kv):
+        raise NotImplementedError(DECODE_REFUSAL)
     with jax.named_scope("embed"):
         h = params["embedding"][tokens]
         if cfg.scale_embeddings:  # gemma normalizer
@@ -749,12 +975,37 @@ def apply_head(params: Params, cfg: TransformerConfig, h, lg="logits"):
 def init_kv_cache(
     cfg: TransformerConfig, batch: int, length: int, dtype=jnp.float32
 ) -> Dict[str, jnp.ndarray]:
+    if cfg.is_hybrid:
+        raise NotImplementedError(DECODE_REFUSAL)
     shape = (cfg.n_layers, batch, length, cfg.n_kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+def _mixer_param_counts(cfg: TransformerConfig) -> Dict[str, int]:
+    """{mixer kind: parameters of one such layer, its norm included}."""
+    from areal_tpu.models import moe as moemod
+
+    d = cfg.hidden_dim
+    counts = {ATTENTION_ONLY:
+              d * (cfg.q_dim + 2 * cfg.kv_dim) + cfg.q_dim * d + d}
+    if cfg.ssm is not None:
+        ssm = cfg.ssm
+        counts[MAMBA] = (
+            d * ssm.in_proj_dim + (ssm.conv_kernel + 1) * ssm.conv_dim
+            + 3 * ssm.n_heads + ssm.d_inner + ssm.d_inner * d + d)
+    if cfg.moe is not None:
+        counts[MOE_ONLY] = d + sum(
+            math.prod(shape) for shape in moemod.moe_param_shapes(cfg).values())
+    return counts
+
+
 def param_count(cfg: TransformerConfig) -> int:
     n, d, f, v = cfg.n_layers, cfg.hidden_dim, cfg.intermediate_dim, cfg.vocab_size
+    if cfg.is_hybrid:
+        counts = _mixer_param_counts(cfg)
+        head = 0 if cfg.tie_word_embeddings else d * v
+        return v * d + d + head + sum(
+            counts[kind] for kind in cfg.layer_kinds)
     attn = d * (cfg.q_dim + 2 * cfg.kv_dim) + cfg.q_dim * d
     if cfg.moe is not None:
         fr = cfg.moe.routed_intermediate_dim or f
@@ -784,8 +1035,12 @@ def activated_param_count(cfg: TransformerConfig) -> int:
         return param_count(cfg)
     n, d, f = cfg.n_layers, cfg.hidden_dim, cfg.intermediate_dim
     fr = cfg.moe.routed_intermediate_dim or f
-    total_mlp = cfg.moe.num_experts * 3 * d * fr
-    # on a share, the part of a token's top_k that is held here
-    held_k = cfg.moe.top_k * cfg.moe.num_experts // cfg.moe.n_routed
-    active_mlp = held_k * 3 * d * fr
+    if cfg.is_hybrid:  # the expert layers only, at the width they work in
+        n, d = cfg.n_layers_of(MOE_ONLY), cfg.moe.latent_dim or d
+    one = (3 if cfg.moe.gated_experts else 2) * d * fr
+    total_mlp = cfg.moe.num_experts * one
+    # on a share, the part of a token's top_k that is held here on average
+    # (a fraction of an expert where few of many are held: 22 x 8 / 512)
+    held_k = cfg.moe.top_k * cfg.moe.num_experts / cfg.moe.n_routed
+    active_mlp = round(held_k * one)
     return param_count(cfg) - n * (total_mlp - active_mlp)

@@ -81,6 +81,9 @@ _FALLBACK_HINTS = {
     "sp_sliding_window": "sliding-window attention is not ring-expressible",
     "layer_pattern": "layers of more than one attention kind are not "
                      "pipelined (a stage takes one RoPE table)",
+    "mixer_layers": "layers that are one mixer each (state-space, expert, "
+                    "attention) make stages of unequal cost and have no "
+                    "one stacked tree to split over pp",
 }
 
 
@@ -121,6 +124,8 @@ def pick_pp_microbatches(
     pp = mesh.shape.get("pp", 1)
     if pp <= 1:
         return None  # no pipeline requested — not a fallback
+    if cfg.is_hybrid:
+        return _fallback("mixer_layers")
     sp = mesh.shape.get("sp", 1)
     if sp > 1:
         if seq_len is None or seq_len % sp != 0:

@@ -54,7 +54,7 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.models.config import SLIDING
+from areal_tpu.models.config import MAMBA, SLIDING
 from areal_tpu.parallel.mesh import DATA_AXES
 
 _NEG_INF = -1e30
@@ -341,19 +341,39 @@ def ring_attention_inline(
     )
 
 
+# Why a model's layers (or one layer's kind) cannot run with the sequence
+# split over the ring axis, by name.
+RING_REFUSALS = {
+    "sliding_window": "sliding-window attention is not ring-expressible",
+    "state_space_scan": "the chunked state-space scan has no ring form: a "
+                        "chunk's entering state is a sum over every chunk "
+                        "before it, on whichever rank",
+}
+
+
+def ring_refusal(cfg, kind: Optional[str] = None) -> Optional[str]:
+    """The name in ``RING_REFUSALS`` of what keeps ring attention off —
+    asked of one layer's ``kind``, or (None) of the model: then no layer
+    may have a window — or None. A model with a state-space layer is
+    refused whole: its other layers would need the sequence split that
+    the scan cannot take."""
+    if MAMBA in cfg.layer_kinds:
+        return "state_space_scan"
+    windowed = (SLIDING in cfg.layer_kinds if kind is None
+                else cfg.window_of(kind) is not None)
+    return "sliding_window" if windowed else None
+
+
 def ring_eligible(mesh: Optional[Mesh], cfg, batch: int, seq_len: int,
                   axis_name: str = "sp", kind: Optional[str] = None) -> bool:
     """Whether the shapes admit ring attention on this mesh: shard_map
     needs divisible shapes (e.g. generate()'s unbucketed batch dim does
-    not divide), and sliding-window attention is not ring-expressible —
-    asked of one layer's attention ``kind``, or (None) of the model: then
-    no layer may have a window."""
+    not divide), and the layers must be ring-expressible
+    (:func:`ring_refusal`)."""
     if mesh is None or mesh.shape.get(axis_name, 1) <= 1:
         return False
-    windowed = (SLIDING in cfg.layer_kinds if kind is None
-                else cfg.window_of(kind) is not None)
     return (
-        not windowed
+        ring_refusal(cfg, kind) is None
         and batch % (mesh.shape["dp"] * mesh.shape["fsdp"]
                      * dict(mesh.shape).get("ep", 1)) == 0
         and seq_len % mesh.shape[axis_name] == 0

@@ -49,6 +49,8 @@ def param_partition_specs(cfg: TransformerConfig) -> Params:
     # used, as under fsdp. With the dense part replicated over ep instead,
     # OLMoE's step did not fit a 16 GB chip (PERF.md, PR 26).
     zero = ("fsdp", "ep")
+    if cfg.is_hybrid:
+        return _hybrid_partition_specs(cfg, zero)
     layers: Params = {
         "ln1": P("pp", None),
         "ln2": P("pp", None),
@@ -106,6 +108,52 @@ def param_partition_specs(cfg: TransformerConfig) -> Params:
     if cfg.is_critic:
         specs["value_head"] = P(zero, None)
     elif not cfg.tie_word_embeddings:
+        specs["lm_head"] = P(zero, "tp")
+    return specs
+
+
+def _hybrid_partition_specs(cfg: TransformerConfig, zero) -> Params:
+    """The spec tree of a model whose layers are one mixer each
+    (``params["layers"]`` a tree per kind): the matrices ZeRO-3 over
+    ``zero`` on their hidden dim, attention's heads and the shared
+    expert's width on "tp", everything else whole. A kind's stack is not
+    split over "pp" (such a model is not pipelined: stages of unequal
+    cost) and the held experts not over "ep" (latent, ungated experts run
+    as a share, models/moe.py)."""
+    from areal_tpu.models.config import ATTENTION_ONLY, MAMBA, MOE_ONLY
+    from areal_tpu.models.moe import moe_param_shapes
+
+    layers: Params = {}
+    if cfg.n_layers_of(MAMBA):
+        layers[MAMBA] = {
+            "ln": P(None, None),
+            "in_proj": P(None, zero, None), "out_proj": P(None, None, zero),
+            "conv_w": P(None, None, None), "conv_b": P(None, None),
+            "dt_bias": P(None, None), "A_log": P(None, None),
+            "D": P(None, None), "norm": P(None, None),
+        }
+    if cfg.n_layers_of(ATTENTION_ONLY):
+        layers[ATTENTION_ONLY] = {
+            "ln": P(None, None),
+            "wq": P(None, zero, "tp"), "wk": P(None, zero, "tp"),
+            "wv": P(None, zero, "tp"), "wo": P(None, "tp", zero),
+        }
+    if cfg.n_layers_of(MOE_ONLY):
+        by_name = {
+            "router": P(None, None, None), "router_bias": P(None, None),
+            "e_gate": P(None, None, "fsdp", "tp"),
+            "e_up": P(None, None, "fsdp", "tp"),
+            "e_down": P(None, None, "tp", "fsdp"),
+            "latent_down": P(None, zero, None),
+            "latent_up": P(None, None, zero),
+            "s_gate": P(None, None, "tp"), "s_up": P(None, None, "tp"),
+            "s_down": P(None, "tp", None),
+        }
+        layers[MOE_ONLY] = {"ln": P(None, None), **{
+            name: by_name[name] for name in moe_param_shapes(cfg)}}
+    specs: Params = {"embedding": P("tp", zero), "layers": layers,
+                     "final_ln": P(None)}
+    if not cfg.tie_word_embeddings:
         specs["lm_head"] = P(zero, "tp")
     return specs
 

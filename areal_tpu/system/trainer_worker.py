@@ -330,6 +330,7 @@ class TrainerWorker:
         run is judged by (base/monitor.log_device_report)."""
         from areal_tpu.base import monitor
         from areal_tpu.ops import attention, native
+        from areal_tpu.models import ssm
         from areal_tpu.ops.pallas import flash_attention, window_attention
 
         monitor.log_device_report(
@@ -348,6 +349,10 @@ class TrainerWorker:
                 for label, counts in
                 window_attention.geometry_counts().items()
             },
+            # {"rows x length/chunk/hHgG": scans traced}: a hybrid model's
+            # state-space layers
+            ssm_geometry={"%dx%d/%d/h%dg%d" % geom: n
+                          for geom, n in ssm.geometry_counts().items()},
             # {model: {"RxL": {entry, kept_bytes_estimate, budget_bytes,
             # fell_back}}}: what each grid's backward pass re-runs
             remat_plan={

@@ -171,6 +171,41 @@ def test_scale_by_adam_mixed_matches_optax():
     assert str(jax.tree.leaves(s_ours[0].mu)[0].dtype) == "float32"
 
 
+def test_weight_decay_passes_over_a_models_buffers():
+    """``build_optimizer``'s decay is optax's — the same state tree, the
+    same updates — on every leaf but a model's buffers
+    (``moe.BUFFER_LEAVES``: the router's choice bias), which a step with
+    no gradient leaves bit for bit."""
+    import optax
+
+    from areal_tpu.api.train_config import OptimizerConfig
+    from areal_tpu.backend import jax_train
+
+    rng = np.random.RandomState(0)
+    params = {"layers": {"moe_only": {
+        "router": jnp.asarray(rng.randn(2, 8, 4).astype(np.float32)),
+        "router_bias": jnp.asarray(rng.randn(2, 4).astype(np.float32))}},
+        "final_ln": jnp.ones(8)}
+    ours = jax_train.add_decayed_weights(0.05)
+    theirs = optax.add_decayed_weights(0.05)
+    assert (jax.tree.structure(ours.init(params))
+            == jax.tree.structure(theirs.init(params)))
+    grads = jax.tree.map(lambda p: 0.1 * jnp.ones_like(p), params)
+    got, _ = ours.update(grads, ours.init(params), params)
+    want, _ = theirs.update(grads, theirs.init(params), params)
+    want["layers"]["moe_only"]["router_bias"] = grads[
+        "layers"]["moe_only"]["router_bias"]
+    jax.tree.map(np.testing.assert_array_equal, got, want)
+
+    tx, _ = jax_train.build_optimizer(
+        OptimizerConfig(lr=1e-2, weight_decay=0.1), total_steps=10)
+    zero = jax.tree.map(jnp.zeros_like, params)
+    updates, _ = tx.update(zero, tx.init(params), params)
+    moe_updates = updates["layers"]["moe_only"]
+    assert not np.any(np.asarray(moe_updates["router_bias"]))
+    assert np.all(np.asarray(moe_updates["router"]) != 0)
+
+
 @pytest.mark.parametrize("mesh_spec", [None, "d2f2t2"])
 def test_train_batch_reduces_loss(mesh_spec):
     rng = np.random.RandomState(1)

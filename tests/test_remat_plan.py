@@ -19,7 +19,7 @@ from areal_tpu.backend.jax_train import (
     choose_remat,
 )
 from areal_tpu.models import transformer
-from areal_tpu.models.config import MoEConfig, tiny_config
+from areal_tpu.models.config import MoEConfig, SSMConfig, tiny_config
 from areal_tpu.parallel import mesh as pmesh
 from areal_tpu.parallel import sharding as psh
 
@@ -585,3 +585,99 @@ def test_plan_counts_both_kernels_of_a_pattern():
         eng.cfg, 2 * 640, 2, flash_tokens=2 * 768, window_tokens=2 * 768)
     assert eng._remat_for(2, 640) in ENTRIES
     assert eng._layer_kinds == "sliding,sliding,sliding,full"
+
+
+# ---- (f) layers that are one mixer each: a tree per kind ----
+
+HYBRID_WIDTHS = dict(
+    vocab_size=512, n_layers=6, hidden_dim=256, n_q_heads=2, n_kv_heads=1,
+    head_dim=128, intermediate_dim=128, pos_embedding="none",
+    layer_types=("moe_only", "mamba", "attention_only") * 2,
+    ssm=SSMConfig(n_heads=4, head_dim=32, n_groups=1, state_dim=32,
+                  chunk_size=128),
+    moe=MoEConfig(num_experts=2, top_k=4, capacity_factor=None,
+                  routed_intermediate_dim=128, router_experts=16,
+                  shared_intermediate_dim=384, router_score="sigmoid",
+                  routed_scaling_factor=2.5, latent_dim=128,
+                  gated_experts=False, expert_act="relu2",
+                  aux_loss_coeff=0.0))
+
+
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_estimate_matches_what_jax_keeps_of_mixer_layers(entry):
+    """Two periods of (expert layer, Mamba-2, attention): every layer
+    keeps its input; the attention layer the flash kernel's output and
+    statistics; under ``matmuls`` the in-projection, q/k/v, and the
+    router's logits, the latent down-projection and the shared expert's
+    first matmul — the scan's products carry batch dimensions and the last
+    matmul of a mixer feeds the residual sum alone."""
+    from areal_tpu.ops.attention import kernel_padded_len
+
+    cfg = dataclasses.replace(tiny_config(), **HYBRID_WIDTHS)
+    rows, length = 2, 640
+    flash = kernel_padded_len("pallas", length)
+    est = transformer.remat_kept_bytes(cfg, rows * length, 2,
+                                       flash_tokens=rows * flash)
+    tok = rows * length
+    assert est["full"] == 6 * tok * 256 * 2
+    assert est["attention"] - est["full"] == 2 * rows * 768 * 2 * (
+        128 * 2 + 8)
+    assert est["matmuls"] - est["attention"] == 2 * tok * 2 * (
+        (16 + 128 + 384) + cfg.ssm.in_proj_dim + (256 + 2 * 128))
+    # the scan runs over the two PERIODS
+    periods = dataclasses.replace(cfg, n_layers=2, layer_types=None)
+    got = _saved_bytes(periods, rows, length, entry, "pallas", cfg_run=cfg)
+    assert got == pytest.approx(est[entry], rel=0.06)
+
+
+def test_plan_of_mixer_layers_reads_its_costs_off_the_config():
+    eng = _engine(dataclasses.replace(tiny_config(), **HYBRID_WIDTHS))
+    assert eng._layer_kinds == "moe_only,mamba,attention_only"
+    # the costliest mixer of: the expert layer's top_k rows at the latent
+    # width, its shared expert, the scan's float32 [heads, chunk] decays
+    # (wider than the in-projection here), attention's q
+    assert eng._mixer_layer_width() == max(
+        4 * 128 * jax_train._LATENT_MOE_LAYER_COPIES,
+        384 * jax_train._LAYER_COPIES,
+        2 * 4 * 128 * jax_train._LAYER_COPIES,
+        256 * jax_train._LAYER_COPIES) == 9216
+    wide = dataclasses.replace(eng.cfg, moe=dataclasses.replace(
+        eng.cfg.moe, top_k=22, latent_dim=1024))
+    assert _engine(wide)._mixer_layer_width() == 22 * 1024 * 11
+    assert eng._remat_for(2, 640) in ENTRIES
+    assert eng.remat_plan()["2x640"]["kept_bytes_estimate"] == (
+        eng._remat_kept_bytes(2, 640)[eng._remat_for(2, 640)])
+
+
+def test_fwd_bwd_span_counts_the_documents_that_begin_inside_a_row():
+    from areal_tpu.api.train_config import TelemetryConfig
+    from areal_tpu.base import telemetry
+
+    cfg = dataclasses.replace(
+        tiny_config(vocab_size=64), pos_embedding="none",
+        layer_types=("mamba", "attention_only"),
+        ssm=SSMConfig(n_heads=2, head_dim=8, n_groups=1, state_dim=8,
+                      chunk_size=8))
+    eng = JaxTrainEngine(
+        cfg, transformer.init_params(cfg, jax.random.PRNGKey(0)),
+        OptimizerConfig(type="sgd", lr=1e-2), FinetuneSpec(1, 8, 4),
+        compute_dtype="float32", length_bucket=16, rows_bucket=2,
+        seqs_bucket=4, remat=True)
+    tel = telemetry.configure("ssm", "t", "trainer",
+                              cfg=TelemetryConfig(enabled=True), push=False)
+    try:
+        eng.train_batch(_sample(np.random.RandomState(3)),
+                        MicroBatchSpec(max_tokens_per_mb=64), _sq_loss,
+                        lambda mb: mb.n_tokens)
+        snap = tel.registry.snapshot(reset=True)
+        spans = [s for s in snap["spans"] if s["name"] == "train/fwd_bwd"]
+        assert spans and all(
+            s["attrs"]["layer_kinds"] == "mamba,attention_only"
+            for s in spans)
+        starts = sum(s["attrs"]["ssm_segment_starts"] for s in spans)
+        assert starts > 0  # 6 sequences in rows of at most 64 tokens
+    finally:
+        telemetry.shutdown()
+    from areal_tpu.models import ssm
+
+    assert any(g[2:] == (8, 2, 1) for g in ssm.geometry_counts())

@@ -49,6 +49,14 @@ EXPERT_GRIDS = {
     "olmoe": ("olmoe-1b-7b", "e4", (4, 3968)),
 }
 EXPERT_PARENT = {"mellum": (1.8047, 236), "olmoe": (2.4754, 56)}
+# The hybrid cell's packed rows (its three grids are 1 x these): ONE latent
+# expert layer, forward + backward. The pass gathers its rows from the
+# latent tokens [T, 1024], a source small enough for the compiler to hold
+# in VMEM, and at [3456, 1024] its in-VMEM gather ran out of scoped vmem
+# (the layer compiled at every other multiple of 128 up to 4096); the
+# source is therefore padded to whole row tiles (moe._whole_row_tiles),
+# with which every multiple of 128 up to 4096 compiles (PERF.md §6, PR 34).
+LATENT_T = (3072, 3456, 3712)
 
 
 def _compile_all():
@@ -250,6 +258,31 @@ def _compile_all():
             "ragged-dot")
         out[f"experts-{name}"]["conditionals"] = compiled.as_text().count(
             " conditional(")
+
+    # One latent expert layer of the hybrid configuration at the cell's rows.
+    from areal_tpu.models import moe as moemod
+
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "nemotron-3-super-120b-a12b.json")) as f:
+        hybrid = weights.model_config(json.load(f))
+    lp = {k: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+          for k, shape in moemod.moe_param_shapes(hybrid).items()}
+
+    def latent_grad(lp, x, valid):
+        def loss(lp, x):
+            y, _ = moemod.moe_mlp(x, lp, hybrid.moe, mask=valid > 0)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        return jax.grad(jax.checkpoint(loss), argnums=(0, 1))(lp, x)
+
+    for T in LATENT_T:
+        record(f"latent-{T}", jax.jit(latent_grad).lower(
+            lp, jax.ShapeDtypeStruct((1, T, hybrid.hidden_dim), jnp.bfloat16,
+                                     sharding=chip),
+            jax.ShapeDtypeStruct((1, T), jnp.int32, sharding=chip)).compile())
+        out[f"latent-{T}"]["rows"] = moemod.sorted_rows(
+            T * hybrid.moe.top_k, hybrid.moe.num_experts,
+            hybrid.moe.n_routed)
     return out
 
 
@@ -340,3 +373,17 @@ def test_the_bounded_expert_pass_keeps_the_programs_temporaries(compiled,
 
 if __name__ == "__main__":
     print(json.dumps(_compile_all()))
+
+
+@pytest.mark.parametrize("T", LATENT_T)
+def test_a_latent_expert_layer_compiles_at_the_hybrid_cells_rows(compiled,
+                                                                T):
+    """Forward + backward of one latent expert layer on the share (top-22
+    of 512, 8 held, latent 1024), the pass bounded to 2560 of its 22 x T
+    sorted rows: the gather from the latent tokens compiles — at 3456
+    tokens it did not before the source was padded to whole row tiles —
+    within the temporaries the remat budget reckons (11 copies of
+    ``top_k x latent`` x tokens)."""
+    got = compiled[f"latent-{T}"]
+    assert got["rows"] == 2560
+    assert got["temp_bytes"] <= 11 * 22 * 1024 * T * 2
