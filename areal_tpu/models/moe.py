@@ -31,14 +31,17 @@ Parity target: ``realhf/impl/model/modules/moe/`` — ``TopKRouter``
    wired the same way and held back, :func:`_dispatch_ep`), the live
    rows are the head of the sorted buffer and about ``G/E`` of it.
    The pass — row gather, grouped GEMMs with their selects, gate
-   multiply, and all their cotangents; not the sorts and the un-permute
-   — runs on :func:`sorted_rows` rows
+   multiply, the combine, and all their cotangents; not the one sort —
+   runs on :func:`sorted_rows` rows
    (twice that share, in row tiles) where the live rows fit them, and on
    the whole buffer, the worst case in which every entry is local, where
    they do not: one function at two static row counts under a
-   ``lax.cond`` (:func:`_bounded_pass`), exact either way. Where the two
-   counts coincide (every expert held, a decode step's short buffer)
-   there is no ``cond``;
+   ``lax.cond`` (:func:`_bounded_pass`), exact either way. Its combine
+   adds the rows it ran on into their tokens (float32, rounded once): no
+   inverse permutation, and no ``[top_k x tokens, D]`` array forward or
+   backward. Where the two counts coincide (every expert held, a decode
+   step's short buffer) there is no ``cond`` and the entries are
+   un-permuted and summed (:func:`combine_counts` records which);
  - **a share** (``MoEConfig.router_experts`` / ``first_expert``): one
    rank's part of such a group run alone, on a mesh with no "ep" axis.
    The router scores all the published experts and normalises the gates
@@ -58,9 +61,9 @@ optional always-on shared expert ``s_gate/s_up [D, Fs]``, ``s_down [Fs, D]``.
 
 Device scopes inside the transformer's ``moe`` scope
 (base/telemetry.MOE_SCOPES): ``moe_router`` (matmul, softmax, top-k, the
-balancing statistics), ``moe_dispatch`` (sort, gather, un-permute,
-combine), ``moe_exchange`` (the collectives over "ep"), ``moe_experts``
-(the grouped GEMMs).
+balancing statistics), ``moe_dispatch`` (sort, gather, combine),
+``moe_exchange`` (the collectives over "ep"), ``moe_experts`` (the grouped
+GEMMs).
 
 Routing-health aux (exported as ``train/moe_*`` telemetry by
 backend/jax_train.py; docs/observability.md): ``dropped_frac``,
@@ -333,28 +336,43 @@ def _whole_row_tiles(xe: jnp.ndarray) -> jnp.ndarray:
     return jnp.pad(xe, ((0, -xe.shape[0] % _ROW_TILE), (0, 0)))
 
 
-def _rows_pass(
-    rows: int,  # static: the rows of the sort the pass runs on
+# How each expert pass traced adds its rows into their tokens, recorded
+# where it is traced (as flash_attention.geometry_counts): {(entries M,
+# rows R, token rows written, width): "rows" | "entries"}.
+_COMBINES: Dict[Tuple[int, int, int, int], str] = {}
+
+
+def combine_counts() -> Dict[Tuple[int, int, int, int], str]:
+    """The combine of every expert pass traced: ``"rows"`` where a bounded
+    pass adds the ``R`` rows it ran on into their tokens
+    (:func:`_rows_pass`), ``"entries"`` where the pass is whole and all
+    ``M`` entries are un-permuted and summed (:func:`_sorted_expert_ffn`).
+    ``moe_combine`` in the trainer's ``device_report``; how often the
+    ``R``-row branch is the one taken at run time is ``passes -
+    full_passes``."""
+    return dict(_COMBINES)
+
+
+def _expert_rows(
+    rows: int,  # static: the rows of the sort the experts run on
     act,  # static: the experts' activation
     k: int,  # static: entries a token
-    xf: jnp.ndarray,  # [N, D] tokens (rows past N = M // k are never read)
+    xf: jnp.ndarray,  # [T, D] tokens (rows past N = M // k are never read)
     order: jnp.ndarray,  # [M] the entries sorted by group
     gate: jnp.ndarray,  # [M] gate of each entry, in sorted order
     group_sizes: jnp.ndarray,  # [G] int32
     gate_w: Optional[jnp.ndarray],  # [G, D, F]; None = experts not gated
     up_w: jnp.ndarray,  # [G, D, F]
     down_w: jnp.ndarray,  # [G, F, D]
-) -> jnp.ndarray:
-    """The expert pass on the first ``rows`` rows of the sort: row gather,
-    the grouped GEMMs (three, or two where the experts are not gated),
-    gate multiply. Returns the gate-weighted expert
-    outputs of all ``M`` sorted entries [M, D], zero past ``rows``. Exact
-    where ``sum(group_sizes) <= rows``: the live rows are the sort's head,
-    and a zero row is what an entry past them has."""
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The experts on the first ``rows`` rows of the sort: row gather, the
+    grouped GEMMs (three, or two where the experts are not gated), gate
+    multiply. Returns (the token of each row [rows], the gate-weighted
+    expert outputs [rows, D], zero past ``sum(group_sizes)``)."""
     M = order.shape[0]
     with jax.named_scope("moe_dispatch"):
-        head = order if rows == M else order[:rows]
-        xs = jnp.take(xf, head // k, axis=0)  # [rows, D] sorted inputs
+        tok = (order if rows == M else order[:rows]) // k
+        xs = jnp.take(xf, tok, axis=0)  # [rows, D] sorted inputs
     with jax.named_scope("moe_experts"):
         if gate_w is None:
             h = act(_grouped_matmul(xs, up_w, group_sizes))
@@ -364,21 +382,41 @@ def _rows_pass(
             ) * _grouped_matmul(xs, up_w, group_sizes)
         ys = _grouped_matmul(h, down_w, group_sizes)  # [rows, D]
     with jax.named_scope("moe_dispatch"):
-        ys = ys * (gate if rows == M else gate[:rows]).astype(
+        return tok, ys * (gate if rows == M else gate[:rows]).astype(
             ys.dtype)[:, None]
-        return ys if rows == M else jnp.pad(ys, ((0, M - rows), (0, 0)))
+
+
+def _rows_pass(rows: int, act, k: int, xf, *args) -> jnp.ndarray:
+    """The expert pass on the first ``rows`` rows of the sort
+    (:func:`_expert_rows`, its arguments) with its combine: row ``i`` is
+    added into token ``order[i] // k`` — a scatter-add of ``rows`` rows,
+    accumulated in float32 and rounded once, as a sum over ``k`` is; its
+    backward GATHERS ``rows`` rows from the cotangent. Returns the
+    gate-weighted sum per token of the rows it ran on, ``[T, D]``: as many
+    rows as ``xf``, so that the cotangent is whole row tiles where the
+    source is (:func:`_whole_row_tiles`); rows past the tokens stay zero.
+    Exact where ``sum(group_sizes) <= rows``: the live rows are the sort's
+    head, and a row past them adds zero. Nothing of ``M`` rows is built
+    where ``rows < M``, forward or backward."""
+    tok, ys = _expert_rows(rows, act, k, xf, *args)
+    with jax.named_scope("moe_dispatch"):
+        return jnp.zeros(xf.shape, jnp.float32).at[tok].add(
+            ys.astype(jnp.float32)).astype(ys.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
 def _bounded_pass(rows: int, act, k: int, xf, order, gate, group_sizes,
                   gate_w, up_w, down_w) -> jnp.ndarray:
     """:func:`_rows_pass` on ``rows`` rows where the live rows fit them,
-    else on all of them. Its own VJP, so that the backward pass is the
-    same choice again — each branch the transpose of its own forward,
-    re-run inside it — and nothing but the arguments is kept between the
-    two: differentiating through the ``cond`` would carry both branches'
-    residuals, the untaken one's as zeros (6.6 GB of temporaries for 1.8
-    in Mellum 2's 1x6656 grad program; PERF.md, PR 33)."""
+    else on all of them: the per-token sums ``[T, D]`` (``xf``'s rows) of
+    the rows it ran on, either way. Its own VJP, so that the backward pass
+    is the same choice again — each branch the transpose of its own
+    forward, re-run inside it: the cotangent's rows are GATHERED, ``rows``
+    of them, where the forward added — and nothing but the arguments is
+    kept between the two: differentiating through the ``cond`` would carry
+    both branches' residuals, the untaken one's as zeros (6.6 GB of
+    temporaries for 1.8 in Mellum 2's 1x6656 grad program; PERF.md, PR
+    33)."""
     args = (xf, order, gate, group_sizes, gate_w, up_w, down_w)
     return jax.lax.cond(
         jnp.sum(group_sizes) <= rows,
@@ -414,7 +452,7 @@ _bounded_pass.defvjp(_bounded_pass_fwd, _bounded_pass_bwd)
 
 
 def _sorted_expert_ffn(
-    xf: jnp.ndarray,  # [N, D] tokens
+    xf: jnp.ndarray,  # [T, D] tokens
     eid: jnp.ndarray,  # [N·k] group of each (token, choice) entry; G = none
     gates: jnp.ndarray,  # [N·k] gate of each entry
     cap: Optional[int],  # slots per group; None = dropless
@@ -428,20 +466,24 @@ def _sorted_expert_ffn(
     """Sort-based grouped expert compute over the ``G`` experts whose
     weights are given: one stable argsort of the ``M = N·k`` entries by
     group id makes each expert's rows contiguous, so the expert MLP is
-    three grouped GEMMs over the sorted rows (:func:`_rows_pass`). Entries
+    three grouped GEMMs over the sorted rows (:func:`_expert_rows`). Entries
     with the sentinel id ``G`` (padding tokens; under expert parallelism,
     another shard's experts) sort to the tail beyond ``sum(group_sizes)``
-    and come back as zero rows. Returns (the gate-weighted sum per token
+    and add nothing. Returns (the gate-weighted sum per token
     [N, D], the number of entries kept, and the aux of a bounded pass:
     ``passes`` 1 and ``full_passes`` 1 if it took the whole buffer, else
     0; {} where the pass is not bounded).
 
     The pass runs on the first ``R = rows`` rows of the sort (the caller's
-    :func:`sorted_rows`). No row is ever lost to that: where the live rows
-    do not fit ``R`` (a skewed router) the same pass runs on all ``M``
-    rows, a ``lax.cond`` on ``sum(group_sizes)`` that no collective sits
-    in (:func:`_bounded_pass`). Where ``R == M`` there is no ``cond`` at
-    all.
+    :func:`sorted_rows`) and adds those ``R`` rows into their tokens: one
+    sort of ``M`` entries, and no ``[M, D]`` array, forward or backward.
+    No row is ever lost to that: where the live rows do not fit ``R`` (a
+    skewed router) the same pass runs on all ``M`` rows, a ``lax.cond``
+    on ``sum(group_sizes)`` that no collective sits in
+    (:func:`_bounded_pass`). Where ``R == M`` there is no ``cond`` at
+    all, and the ``M`` rows are un-permuted by a second sort and summed
+    over each token's ``k`` — an add of ``M`` rows is no cheaper than a
+    gather of ``M`` rows. :func:`combine_counts` records which.
 
     With a capacity the drop matches the einsum oracle structurally: a
     stable sort preserves flat (token-major, then choice) order within
@@ -450,9 +492,9 @@ def _sorted_expert_ffn(
     are computed and contribute nothing); the positions are taken over all
     ``M`` entries whatever ``R``. Dropless (``cap=None``) builds no
     positions and no keep mask."""
-    D = xf.shape[1]
+    T, D = xf.shape
     M = eid.shape[0]
-    k = M // xf.shape[0] if k is None else k
+    k = M // T if k is None else k
     N = M // k  # xf may hold rows past them (moe_mlp's latent source)
     G = up_w.shape[0]
     R = rows
@@ -471,15 +513,19 @@ def _sorted_expert_ffn(
             kept = jnp.sum(keep)
             gate = gate * keep
     args = (xf, order, gate, group_sizes, gate_w, up_w, down_w)
-    ys = (_rows_pass(M, act, k, *args) if R == M
-          else _bounded_pass(R, act, k, *args))
+    _COMBINES[(M, R, T, D)] = "entries" if R == M else "rows"
+    if R == M:
+        _, ys = _expert_rows(M, act, k, *args)
+        with jax.named_scope("moe_dispatch"):
+            inv = jnp.argsort(order)  # inverse permutation
+            y = jnp.sum(jnp.take(ys, inv, axis=0).reshape(N, k, D), axis=1)
+        return y, kept.astype(jnp.float32), {}
+    y = _bounded_pass(R, act, k, *args)
     with jax.named_scope("moe_dispatch"):
-        inv = jnp.argsort(order)  # inverse permutation
-        y = jnp.sum(jnp.take(ys, inv, axis=0).reshape(N, k, D), axis=1)
-    counts = {} if R == M else {
+        y = y if T == N else y[:N]
+    return y, kept.astype(jnp.float32), {
         "passes": jnp.ones((), jnp.float32),
         "full_passes": (jnp.sum(group_sizes) > R).astype(jnp.float32)}
-    return y, kept.astype(jnp.float32), counts
 
 
 def _dispatch_grouped(

@@ -330,7 +330,7 @@ class TrainerWorker:
         run is judged by (base/monitor.log_device_report)."""
         from areal_tpu.base import monitor
         from areal_tpu.ops import attention, native
-        from areal_tpu.models import ssm
+        from areal_tpu.models import moe, ssm
         from areal_tpu.ops.pallas import flash_attention, window_attention
 
         monitor.log_device_report(
@@ -353,6 +353,10 @@ class TrainerWorker:
             # state-space layers
             ssm_geometry={"%dx%d/%d/h%dg%d" % geom: n
                           for geom, n in ssm.geometry_counts().items()},
+            # {"entries>rows/token rows x width": "rows" | "entries"}: how
+            # each expert pass traced adds its rows into their tokens
+            moe_combine={"%d>%d/%dx%d" % key: how
+                         for key, how in moe.combine_counts().items()},
             # {model: {"RxL": {entry, kept_bytes_estimate, budget_bytes,
             # fell_back}}}: what each grid's backward pass re-runs
             remat_plan={

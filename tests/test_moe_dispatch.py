@@ -464,6 +464,124 @@ def test_sorted_rows_rule():
     assert moemod.sorted_rows(1000, 1, 64) == 512
 
 
+# ---- the bounded pass's combine: rows added into their tokens ----
+
+_COMBINE_N, _COMBINE_K, _COMBINE_ROWS = 64, 4, 96  # M = 256 entries
+# {case: (live entries, capacity)}: the live rows against the bound R = 96
+# (one past it is the whole-buffer branch), every entry live on a skewed
+# router (that branch adding all M rows), and a capacity that drops.
+COMBINE_CASES = {
+    "live0": (0, None), "liveR-1": (95, None), "liveR": (96, None),
+    "liveR+1": (97, None), "skewed": (256, None), "capacity": (90, 10),
+}
+
+
+def _combine_inputs(kind, case, rng):
+    """(xf, eid, gates, cap, (gate_w, up_w, down_w), act, k) of one pass
+    over 4 held experts: gated ``silu`` experts reading the tokens
+    themselves, or ungated ``relu2`` experts reading a latent source that
+    holds rows past the tokens (as moe._whole_row_tiles leaves it)."""
+    N, k, G, D, F = _COMBINE_N, _COMBINE_K, 4, 16, 32
+    M = N * k
+    live, cap = COMBINE_CASES[case]
+    T = N if kind == "gated" else N + 24
+    xf = jnp.asarray(rng.randn(T, D).astype(np.float32))
+    groups = rng.randint(0, G, size=M)
+    if case == "skewed":
+        groups = np.where(rng.rand(M) < 0.9, 0, groups)
+    eid = np.full(M, G)
+    here = rng.permutation(M)[:live]
+    eid[here] = groups[here]
+    gates = jnp.asarray(rng.rand(M).astype(np.float32))
+    w = [jnp.asarray(rng.randn(*shape).astype(np.float32) * 0.2)
+         for shape in ((G, D, F), (G, D, F), (G, F, D))]
+    if kind == "latent":
+        return (xf, jnp.asarray(eid), gates, cap, [None] + w[1:],
+                moemod.relu2, k)
+    return xf, jnp.asarray(eid), gates, cap, w, jax.nn.silu, k
+
+
+@pytest.mark.parametrize("case", list(COMBINE_CASES))
+@pytest.mark.parametrize("kind", ["gated", "latent"])
+def test_rows_added_into_tokens_equal_the_unpermuted_entries(kind, case):
+    """The bounded pass's combine — the R rows it ran on, added into their
+    tokens — against the whole pass's (``take(ys, argsort(order))
+    .reshape(N, k, D).sum(1)``, what ``rows == M`` runs): the per-token
+    sums, the entries kept and the gradient of every differentiable
+    argument, to float32 rounding; a latent source's rows past the tokens
+    are never read and take a zero gradient; the counts say which branch
+    ran; and moe.combine_counts() says which combine was traced."""
+    rng = np.random.RandomState(len(kind) * 100 + len(case))
+    xf, eid, gates, cap, w, act, k = _combine_inputs(kind, case, rng)
+    M, N, R = eid.shape[0], _COMBINE_N, _COMBINE_ROWS
+    diff = [xf, gates] + [a for a in w if a is not None]
+
+    def run(rows):
+        def loss(xf, gates, *w):
+            w = ([None] if kind == "latent" else []) + list(w)
+            y, kept, counts = moemod._sorted_expert_ffn(
+                xf, eid, gates, cap, *w, rows, act, k)
+            return jnp.sum(jnp.sin(y)), (y, kept, counts)
+
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(len(diff))), has_aux=True))(*diff)
+
+    (_, (y_r, kept_r, counts)), g_r = run(R)
+    (_, (y_e, kept_e, none)), g_e = run(M)
+    live = COMBINE_CASES[case][0]
+    assert none == {} and float(counts["passes"]) == 1
+    assert float(counts["full_passes"]) == (live > R)
+    assert y_r.shape == y_e.shape == (N, xf.shape[1])
+    assert float(kept_r) == float(kept_e) <= live
+    assert (float(kept_r) < live) == (cap is not None)
+    np.testing.assert_allclose(y_r, y_e, rtol=1e-5, atol=1e-5)
+    assert bool(jnp.any(y_e != 0)) == (live > 0)
+    for name, r, e in zip(("xf", "gates", "w0", "w1", "w2"), g_r, g_e):
+        np.testing.assert_allclose(r, e, rtol=1e-5, atol=1e-5, err_msg=name)
+    assert not np.any(np.asarray(g_r[0][N:]))  # rows past the tokens
+    counts = moemod.combine_counts()
+    assert counts[(M, R, xf.shape[0], xf.shape[1])] == "rows"
+    assert counts[(M, M, xf.shape[0], xf.shape[1])] == "entries"
+
+
+def test_rows_are_added_in_float32_and_rounded_once():
+    """22 bfloat16 rows a token: the bounded pass's sums are the rows
+    summed in float32 and rounded once, to the bit — as the sum over ``k``
+    of the whole pass is — where a running bfloat16 sum of the same rows
+    is ulps off. Every row is exact in bfloat16 (eighths times a power of
+    two through diagonal experts, unit gates), so that only the combine
+    rounds."""
+    rng = np.random.RandomState(22)
+    N, k, G, D = 16, 22, 4, 16
+    M = N * k
+    bf = jnp.bfloat16
+    x = rng.randint(-32, 33, size=(N, D)) / 8.0
+    scale = np.array([0.5, 1.0, 2.0, 4.0])
+    eid = np.full((N, k), G)
+    eid[:8] = rng.randint(0, G, size=(8, k))  # 8 tokens, every choice here
+    up_w = jnp.asarray(np.tile(np.eye(D), (G, 1, 1)), bf)
+    down_w = jnp.asarray(scale[:, None, None] * np.eye(D), bf)
+
+    def sums(rows):
+        y, _, counts = moemod._sorted_expert_ffn(
+            jnp.asarray(x, bf), jnp.asarray(eid.reshape(M)),
+            jnp.ones((M,), jnp.float32), None, None, up_w, down_w, rows,
+            act=lambda h: h, k=k)
+        assert y.dtype == bf
+        assert (rows == M) == (counts == {})
+        return np.asarray(y.astype(jnp.float32))
+
+    terms = x[:, None, :] * np.append(scale, 0.0)[eid][:, :, None]
+    once = np.asarray(jnp.asarray(
+        terms.astype(np.float32).sum(axis=1), bf).astype(jnp.float32))
+    np.testing.assert_array_equal(sums(M // 2), once)
+    np.testing.assert_array_equal(sums(M), once)
+    running = jnp.zeros((N, D), bf)
+    for j in range(k):
+        running = running + jnp.asarray(terms[:, j], bf)
+    assert np.any(np.asarray(running.astype(jnp.float32)) != once)
+
+
 # sha256 of the lowered text of the layer (output and aux) where the pass
 # is NOT bounded, as the commit before the bound (9987316) lowers it.
 UNBOUNDED_TEXT = {

@@ -7,13 +7,22 @@ One micro-batch's expert pass on a shard that holds ``--held`` of
 ``--routed`` experts (default: Mellum 2's share — 6656 tokens of width
 2304, 8 choices a token, 16 of 64 experts of width 896; ``--olmoe`` for
 one source's pass of an OLMoE ``e4`` shard: 3968 tokens of 2048, 16 of 64
-of width 1024), bf16, a uniform random router, so a quarter of the
-``M = tokens x choices`` entries are live. Per ``--tokens``:
+of width 1024; ``--nemotron`` for Nemotron 3 Super's share: 3712 tokens in
+a latent of 1024 padded to whole row tiles, 22 choices, 8 of 512 ungated
+``relu2`` experts of 2688), bf16, a uniform random router, so the held
+share of the ``M = tokens x choices`` entries is live. Per ``--tokens``:
 ``moe._sorted_expert_ffn`` as shipped but for the head-room — none (the
 whole buffer, no ``cond``), then each of ``--factors`` — forward alone
 and forward + backward, host clock around ``block_until_ready`` over
-``--iters`` calls. Prints one JSON line per case and writes them to
-``chiprun_out/expert_rows_sweep.jsonl``.
+``--iters`` calls. Then the COMBINE alone at the shipped head-room's row
+count ``R``, each way (the two costs a rule that chose between them would
+compare): ``"rows"`` — ``R`` rows added into their tokens in float32, whose
+backward gathers ``R`` rows — and ``"entries"`` — the ``R`` rows padded to
+``M``, un-permuted by the inverse permutation (a second sort) and summed
+over each token's choices, whose backward scatter-adds ``M`` rows — with
+``ns_per_row`` = forward + backward over the rows moved (``R`` / ``M``).
+Prints one JSON line per case and writes them to
+``chiprun_out/expert_rows_sweep.jsonl`` (``--out`` for another name).
 """
 
 from __future__ import annotations
@@ -48,14 +57,20 @@ def main() -> int:
     ap.add_argument("--factors", type=float, nargs="+",
                     default=[1.25, 1.5, 2.0, 3.0])
     ap.add_argument("--olmoe", action="store_true")
-    ap.add_argument("--held", type=int, default=16)
-    ap.add_argument("--routed", type=int, default=64)
+    ap.add_argument("--nemotron", action="store_true")
+    ap.add_argument("--out", default="expert_rows_sweep.jsonl")
+    ap.add_argument("--held", type=int, default=None)
+    ap.add_argument("--routed", type=int, default=None)
     ap.add_argument("--iters", type=int, default=20)
     args = ap.parse_args()
-    D, F, k = (2048, 1024, 8) if args.olmoe else (2304, 896, 8)
-    tokens = args.tokens or ([3968] if args.olmoe else [6656])
-    G, E = args.held, args.routed
-    out_path = os.path.join("chiprun_out", "expert_rows_sweep.jsonl")
+    # width, experts' width, choices, held, routed, tokens, gated, act
+    D, F, k, G, E, N0, gated, act = (
+        (1024, 2688, 22, 8, 512, 3712, False, moe.relu2) if args.nemotron
+        else (2048, 1024, 8, 16, 64, 3968, True, jax.nn.silu) if args.olmoe
+        else (2304, 896, 8, 16, 64, 6656, True, jax.nn.silu))
+    tokens = args.tokens or [N0]
+    G, E = args.held or G, args.routed or E
+    out_path = os.path.join("chiprun_out", args.out)
     os.makedirs("chiprun_out", exist_ok=True)
     dev = jax.devices()[0]
     lines = []
@@ -63,14 +78,21 @@ def main() -> int:
         M = N * k
         keys = jax.random.split(jax.random.PRNGKey(N), 6)
         xf = jax.random.normal(keys[0], (N, D), jnp.bfloat16)
+        if args.nemotron:  # a latent source is whole row tiles
+            xf = moe._whole_row_tiles(xf)
         top_i = jax.random.randint(keys[1], (N, k), 0, E)
         gates = jax.random.uniform(keys[2], (M,), jnp.float32)
         w = [jax.random.normal(kk, s, jnp.bfloat16) * 0.02 for kk, s in zip(
             keys[3:], ((G, D, F), (G, D, F), (G, F, D)))]
+        if not gated:
+            w[0] = None
         eid = moe._held_eid(top_i, jnp.ones((N,)), 0, G)
         live = int(jnp.sum(eid < G))
+        case = {"tokens": N, "entries": M, "live": live, "held": G,
+                "routed": E, "D": D, "F": F, "device": dev.device_kind,
+                "platform": dev.platform}
 
-        a = (xf, gates, *w)
+        a = (xf, gates, *[m for m in w if m is not None])
         shipped = moe._ROW_HEADROOM
         try:
             for factor in [None] + sorted(args.factors):
@@ -81,8 +103,10 @@ def main() -> int:
 
                 # jit caches by function: new functions per row count.
                 def ffn(xf, gates, *w, rows=rows):
+                    if not gated:
+                        w = (None,) + w
                     return moe._sorted_expert_ffn(
-                        xf, eid, gates, None, *w, rows)[0]
+                        xf, eid, gates, None, *w, rows, act, k)[0]
 
                 def loss(*a):
                     return jnp.sum(ffn(*a).astype(jnp.float32) ** 2)
@@ -90,15 +114,41 @@ def main() -> int:
                 fwd = timed(jax.jit(ffn), a, args.iters)
                 both = timed(jax.jit(jax.value_and_grad(
                     loss, argnums=tuple(range(len(a))))), a, args.iters)
-                line = {"headroom": factor, "tokens": N, "entries": M,
-                        "live": live, "rows": rows, "held": G, "routed": E,
-                        "D": D, "F": F, "fwd_ms": round(fwd * 1e3, 4),
-                        "fwd_bwd_ms": round(both * 1e3, 4),
-                        "device": dev.device_kind, "platform": dev.platform}
+                line = dict(case, headroom=factor, rows=rows,
+                            fwd_ms=round(fwd * 1e3, 4),
+                            fwd_bwd_ms=round(both * 1e3, 4))
                 print(json.dumps(line), flush=True)
                 lines.append(line)
         finally:
             moe._ROW_HEADROOM = shipped
+
+        # The combine alone, each way, at the shipped bound's row count.
+        R = moe.sorted_rows(M, G, E)
+        order = jnp.argsort(eid)
+        ys = jax.random.normal(keys[0], (R, D), jnp.bfloat16)
+        T = xf.shape[0]
+
+        def add_rows(ys):
+            return jnp.zeros((T, D), jnp.float32).at[order[:R] // k].add(
+                ys.astype(jnp.float32)).astype(ys.dtype)
+
+        def unpermute_entries(ys):
+            ys = jnp.pad(ys, ((0, M - R), (0, 0)))
+            return jnp.sum(jnp.take(ys, jnp.argsort(order), axis=0).reshape(
+                N, k, D), axis=1)
+
+        for combine, fn, moved in (("rows", add_rows, R),
+                                   ("entries", unpermute_entries, M)):
+            fwd = timed(jax.jit(fn), (ys,), args.iters)
+            both = timed(jax.jit(jax.value_and_grad(
+                lambda ys, fn=fn: jnp.sum(fn(ys).astype(jnp.float32) ** 2))),
+                (ys,), args.iters)
+            line = dict(case, combine=combine, rows=R, token_rows=T,
+                        rows_moved=moved, fwd_ms=round(fwd * 1e3, 4),
+                        fwd_bwd_ms=round(both * 1e3, 4),
+                        ns_per_row=round(both * 1e9 / moved, 2))
+            print(json.dumps(line), flush=True)
+            lines.append(line)
     with open(out_path, "w") as f:
         for line in lines:
             f.write(json.dumps(line) + "\n")
