@@ -1,5 +1,6 @@
-"""Compile-event observatory: jit entry-point tracing, recompile-storm
-detection, and persistent-cache accounting.
+"""Compile-event observatory: the compile ledger (what every program cost
+before it first ran), jit entry-point tracing and recompile-storm
+detection.
 
 The observability stack can say where wall-clock goes (telemetry spans,
 goodput states) but was blind to the failure mode that actually dominates
@@ -17,7 +18,7 @@ a first-class, alertable signal:
    compile + the initial dispatch). Signature sets are PER WRAPPER, not
    per name: a fresh ``jax.jit`` object (new grad-fn cache entry, a
    reshard identity built per group) recompiles even for a shape some
-   other wrapper saw, and the ledger must say so.
+   other wrapper saw, and the watch must say so.
  - Per-function families on the PR-4 telemetry registry:
    ``compile/events{fn=...}`` / ``compile/secs{fn=...}`` counters, a
    ``compile/inflight`` gauge (nonzero while any wrapped call is tracing)
@@ -28,11 +29,15 @@ a first-class, alertable signal:
    been shape-stable for ``storm_warmup_calls`` calls increments
    ``compile/storm_events`` and logs the offending signature once — the
    signal the sentinel's ``recompile_storm`` rate rule watches.
- - Persistent-cache accounting: the entry count of the persistent
-   compilation cache (:func:`compilation_cache_dir`) is probed around
-   each observed compile — an entry appearing means XLA really compiled
-   (``compile/cache_misses``); none appearing means the compile was
-   served from the persistent cache (``compile/cache_hits``).
+ - Persistent-cache accounting: ``compile/cache_hits`` /
+   ``compile/cache_misses`` are the compile ledger's counts (jax's own
+   events) on the calling thread around each observed compile.
+
+The compile ledger (:class:`CacheStats`, :func:`cache_stats`) is the other
+instrument and needs no switch: :func:`enable_compilation_cache` arms it
+in every compiling process, and it runs only when jax traces, lowers or
+compiles. An enabled watch also gets each program's stage spans as
+``compile/<stage>`` spans of its telemetry sink.
 
 Disabled contract (mirrors telemetry/goodput): until :func:`configure`
 installs an enabled watch, :func:`watched_jit` returns the raw function
@@ -42,10 +47,12 @@ scrape is bit-identical to a build without this module.
 
 from __future__ import annotations
 
+import collections
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, Optional, Set
+import weakref
+from typing import Any, Callable, Deque, Dict, Optional, Set, Tuple
 
 from areal_tpu.base import logging, telemetry
 
@@ -65,63 +72,260 @@ DEFAULT_COMPILATION_CACHE = os.path.join(
 def compilation_cache_dir() -> str:
     """The persistent compilation cache every compiling process shares:
     the standard ``JAX_COMPILATION_CACHE_DIR`` when set, else the fixed
-    in-checkout default. The observatory's hit/miss probe watches the
-    same directory :func:`enable_compilation_cache` arms."""
+    in-checkout default."""
     return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
             or DEFAULT_COMPILATION_CACHE)
 
 
-class CacheStats:
-    """This process's persistent-cache traffic, counted from jax's own
-    monitoring events: ``hits`` (executables read back), ``misses``
-    (compiled and written), and the seconds a program costs before it
-    runs: ``trace_secs`` (Python → jaxpr), ``lower_secs`` (jaxpr → MLIR
-    module), ``compile_secs`` (wall time inside the backend compile call —
-    a cache read when it hits) and ``cache_read_secs`` (the part of that
-    spent reading the cache)."""
+# jax's three stage events (dispatch.log_elapsed_time): each is sent as a
+# scalar when the stage is ENTERED (its start on the wall clock) and as a
+# duration and a time span when it ENDS, all with ``fun_name``.
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+# Cache traffic that no open stage span claims (none seen so far: both of
+# jax's compile paths run inside a ``compile`` span).
+UNNAMED_PROGRAM = "(unnamed)"
+SPAN_RING = 1024
 
-    _DURATIONS = {
-        "/jax/core/compile/jaxpr_trace_duration": "trace_secs",
-        "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_secs",
-        "/jax/core/compile/backend_compile_duration": "compile_secs",
-        "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read_secs",
-    }
+
+def _program_name(fun_name: str) -> str:
+    """``train_apply`` for both ``train_apply`` (trace) and
+    ``jit(train_apply)`` (lower, compile)."""
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        return fun_name[4:-1]
+    return fun_name
+
+
+class _OpenSpan:
+    """A stage jax has entered and not left, on one thread's stack. What
+    ran INSIDE it (the callees a trace traces, a small program compiled
+    while lowering) is folded in as it ends: ``secs`` holds the union of
+    the inner spans per stage, ``hits`` / ``misses`` / ``cache_read_secs``
+    the cache traffic under it."""
+
+    __slots__ = ("stage", "fn", "t_start", "n_children", "secs", "hits",
+                 "misses", "cache_read_secs")
+
+    def __init__(self, stage: str, fn: str, t_start: float) -> None:
+        self.stage = stage
+        self.fn = fn
+        self.t_start = t_start
+        self.n_children = 0
+        self.secs = {"trace_secs": 0.0, "lower_secs": 0.0,
+                     "compile_secs": 0.0}
+        self.hits = 0
+        self.misses = 0
+        self.cache_read_secs = 0.0
+
+
+class CacheStats:
+    """The compile ledger: what every program of this process cost before
+    it first ran, from jax's own monitoring events — by name, by stage
+    (``trace`` Python → jaxpr, ``lower`` jaxpr → MLIR module, ``compile``
+    the backend compile call: a real compile on a cache miss, a cache read
+    on a hit) and on the wall clock (``time.time()``). It runs only when
+    jax traces, lowers or compiles, so it is always on.
+
+    A span that ends while another of its thread is open is that one's
+    child (``sin`` inside ``train_grad_sliced``'s trace) and is folded
+    into it; a span with no parent is a PROGRAM's own, and only those are
+    filed: in ``spans`` (a ring of the newest ``SPAN_RING``) and, for
+    good, in ``programs[fn]`` and the process totals. Seconds are unions:
+    a callee's trace inside its caller's counts once. The totals are the
+    sums over ``programs``, per thread, of
+
+    ``hits`` / ``misses``  executables read back / compiled and written;
+    ``trace_secs`` ``lower_secs`` ``compile_secs``  union of that stage's
+                       spans; ``cache_read_secs`` the part of
+                       ``compile_secs`` spent reading the cache;
+    ``busy_secs``      union of ALL stage spans (a small program compiled
+                       inside a trace is in two stage unions, once here).
+
+    ``programs[fn]`` adds ``n_trace`` / ``n_lower`` / ``n_compile`` (the
+    program's own spans: ``n_compile`` is how many executables it needed),
+    ``n_children`` (spans folded into them) and ``max_secs`` (the largest
+    trace + lower + compile of ONE of its compilations)."""
 
     def __init__(self) -> None:
         self.hits = 0
         self.misses = 0
-        self.secs = dict.fromkeys(self._DURATIONS.values(), 0.0)
+        self.secs = {"trace_secs": 0.0, "lower_secs": 0.0,
+                     "compile_secs": 0.0, "cache_read_secs": 0.0}
+        self.busy_secs = 0.0
+        self.programs: Dict[str, Dict[str, Any]] = {}
+        self.spans: Deque[Dict[str, Any]] = collections.deque(
+            maxlen=SPAN_RING)
+        self._lock = threading.Lock()
+        self._local = threading.local()
+
+    def _thread(self):
+        """This thread's open spans, the running seconds of each program's
+        compilation in progress, and its own cache counts."""
+        t = self._local
+        if not hasattr(t, "open"):
+            t.open, t.compiling, t.hits, t.misses = [], {}, 0, 0
+        return t
+
+    def thread_counts(self) -> Tuple[int, int]:
+        """(hits, misses) of the calling thread so far: a jit call
+        compiles on the thread that makes it."""
+        t = self._thread()
+        return t.hits, t.misses
+
+    # ---- jax.monitoring listeners ----
+
+    def _on_enter(self, event: str, value: float, fun_name: str = "",
+                  **_: Any) -> None:
+        stage = _STAGES.get(event)
+        if stage is not None:
+            self._thread().open.append(_OpenSpan(stage, fun_name, value))
 
     def _on_event(self, event: str, **_: Any) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
+        if event == _CACHE_HIT:
+            self._cache_traffic(hits=1)
+        elif event == _CACHE_MISS:
+            self._cache_traffic(misses=1)
 
     def _on_duration(self, event: str, secs: float, **_: Any) -> None:
-        field = self._DURATIONS.get(event)
-        if field is not None:
-            self.secs[field] += secs
+        if event == _CACHE_READ:
+            self._cache_traffic(read_secs=secs)
+
+    def _cache_traffic(self, hits: int = 0, misses: int = 0,
+                       read_secs: float = 0.0) -> None:
+        """The cache's events carry no name: they belong to the span open
+        on their thread (the ``compile`` they fire inside)."""
+        t = self._thread()
+        t.hits += hits
+        t.misses += misses
+        with self._lock:
+            self.hits += hits
+            self.misses += misses
+            self.secs["cache_read_secs"] += read_secs
+            if not t.open:
+                row = self._row(UNNAMED_PROGRAM)
+                row["hits"] += hits
+                row["misses"] += misses
+                row["cache_read_secs"] += read_secs
+                return
+        span = t.open[-1]
+        span.hits += hits
+        span.misses += misses
+        span.cache_read_secs += read_secs
+
+    def _on_span(self, event: str, t_start: float, t_end: float,
+                 fun_name: str = "", **_: Any) -> None:
+        stage = _STAGES.get(event)
+        if stage is None:
+            return
+        t = self._thread()
+        top = t.open[-1] if t.open else None
+        if (top is not None and top.stage == stage
+                and top.t_start == t_start):
+            span = t.open.pop()
+        else:  # entered before the listeners were registered
+            span = _OpenSpan(stage, fun_name, t_start)
+        secs = max(t_end - t_start, 0.0)
+        span.secs[stage + "_secs"] = secs  # covers its children's of it
+        if t.open:
+            parent = t.open[-1]
+            parent.n_children += 1 + span.n_children
+            for key, v in span.secs.items():
+                parent.secs[key] += v
+            parent.hits += span.hits
+            parent.misses += span.misses
+            parent.cache_read_secs += span.cache_read_secs
+        else:
+            self._file(t, span, secs)
+
+    # ---- filing a program's own span ----
+
+    def _row(self, fn: str) -> Dict[str, Any]:
+        row = self.programs.get(fn)
+        if row is None:
+            row = self.programs[fn] = {
+                "n_trace": 0, "n_lower": 0, "n_compile": 0, "n_children": 0,
+                "hits": 0, "misses": 0, "trace_secs": 0.0, "lower_secs": 0.0,
+                "compile_secs": 0.0, "cache_read_secs": 0.0, "max_secs": 0.0,
+            }
+        return row
+
+    def _file(self, t, span: _OpenSpan, secs: float) -> None:
+        fn = _program_name(span.fn)
+        entry: Dict[str, Any] = {
+            "fn": fn, "stage": span.stage,
+            "t_start": round(span.t_start, 6), "secs": round(secs, 6),
+            "thread": threading.current_thread().name,
+            "n_children": span.n_children,
+        }
+        if span.stage == "compile":
+            entry["cache"] = ("hit" if span.hits else
+                              "miss" if span.misses else "uncached")
+            entry["cache_read_secs"] = round(span.cache_read_secs, 6)
+        # One compilation = a trace (where jax had none cached), a lower
+        # and a compile, one after the other on one thread.
+        running = secs + (0.0 if span.stage == "trace"
+                          else t.compiling.get(fn, 0.0))
+        if span.stage == "compile":
+            t.compiling.pop(fn, None)
+        else:
+            t.compiling[fn] = running
+        with self._lock:
+            row = self._row(fn)
+            row["n_" + span.stage] += 1
+            row["n_children"] += span.n_children
+            for key, v in span.secs.items():
+                row[key] += v
+                self.secs[key] += v
+            row["hits"] += span.hits
+            row["misses"] += span.misses
+            row["cache_read_secs"] += span.cache_read_secs
+            row["max_secs"] = max(row["max_secs"], running)
+            self.busy_secs += secs
+            self.spans.append(entry)
+        for watch in list(_WATCHES):
+            watch._on_stage_span(entry)
 
     def as_dict(self) -> Dict[str, Any]:
-        return {"dir": compilation_cache_dir(), "hits": self.hits,
+        """Plain data (the drivers and ``/metrics.json`` dump it as JSON):
+        the seven keys this has always had, ``busy_secs``, ``programs``
+        and ``spans`` (oldest first; in each ``fn`` is its ``programs``
+        key)."""
+        with self._lock:
+            return {
+                "dir": compilation_cache_dir(), "hits": self.hits,
                 "misses": self.misses,
-                **{k: round(v, 3) for k, v in self.secs.items()}}
+                **{k: round(v, 3) for k, v in self.secs.items()},
+                "busy_secs": round(self.busy_secs, 6),
+                "programs": {
+                    fn: {k: round(v, 6) if isinstance(v, float) else v
+                         for k, v in row.items()}
+                    for fn, row in self.programs.items()},
+                "spans": [dict(e) for e in self.spans],
+            }
 
 
 _CACHE_STATS: Optional[CacheStats] = None
+# The live CompileWatches (several generation servers share a process):
+# each filed span also goes to their telemetry sinks.
+_WATCHES: "weakref.WeakSet[CompileWatch]" = weakref.WeakSet()
 
 
 def cache_stats() -> Optional[Dict[str, Any]]:
-    """Cache traffic of this process so far; None where
-    :func:`enable_compilation_cache` never ran."""
+    """The compile ledger of this process so far (CacheStats.as_dict);
+    None where :func:`enable_compilation_cache` never ran."""
     return _CACHE_STATS.as_dict() if _CACHE_STATS is not None else None
 
 
 def enable_compilation_cache() -> None:
     """Arm JAX's persistent compilation cache for this process (launcher
     children, the benchmark's drivers and chip_smoke.py's phases all call
-    this one helper) and start counting its hits and misses. jax reads
+    this one helper) and start the compile ledger. jax reads
     ``JAX_COMPILATION_CACHE_DIR`` itself, so a directory is set in code
     only when the variable is not. Imports jax and sets config — never
     creates an array or asks for devices."""
@@ -139,10 +343,19 @@ def enable_compilation_cache() -> None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     _CACHE_STATS = CacheStats()
+    jax.monitoring.register_scalar_listener(_CACHE_STATS._on_enter)
+    jax.monitoring.register_event_time_span_listener(_CACHE_STATS._on_span)
     jax.monitoring.register_event_listener(_CACHE_STATS._on_event)
     jax.monitoring.register_event_duration_secs_listener(
         _CACHE_STATS._on_duration
     )
+
+
+def thread_cache_counts() -> Tuple[int, int]:
+    """The calling thread's (hits, misses) in the compile ledger; zeros
+    where :func:`enable_compilation_cache` never ran."""
+    return (_CACHE_STATS.thread_counts() if _CACHE_STATS is not None
+            else (0, 0))
 
 
 def abstract_signature(args: tuple, kwargs: dict) -> str:
@@ -226,38 +439,40 @@ class _WatchedFn:
             return self._fn(*args, **kwargs)
         self._seen.add(sig)
         self._watch._compile_begin()
+        hits0, misses0 = thread_cache_counts()
         t0 = self._watch._clock()
         try:
             return self._fn(*args, **kwargs)
         finally:
-            self._watch._compile_end(
-                self._name, sig, self._watch._clock() - t0
-            )
+            secs = self._watch._clock() - t0
+            hits, misses = thread_cache_counts()
+            self._watch._compile_end(self._name, sig, secs,
+                                     hits - hits0, misses - misses0)
 
 
 class CompileWatch:
     """Process-wide (or per-server) compile-event registry.
 
     ``telemetry_sink`` is any Telemetry-like object (``inc`` /
-    ``set_gauge`` / ``event``); ``clock`` is injectable for fake-clock
-    tests. ``cache_dir=None`` disables persistent-cache accounting."""
+    ``set_gauge`` / ``event`` / ``add_span``); ``clock`` is injectable
+    for fake-clock tests. While it is installed the compile ledger hands
+    it every program's stage spans (``compile/<stage>``, on the clock of
+    the sink's other spans)."""
 
     enabled = True
 
     def __init__(self, telemetry_sink=None, *,
                  storm_warmup_calls: int = 16,
-                 cache_dir: Optional[str] = None,
                  clock: Callable[[], float] = time.monotonic):
         self.tel = telemetry_sink if telemetry_sink is not None \
             else telemetry.get()
         self.storm_warmup_calls = max(int(storm_warmup_calls), 1)
-        self.cache_dir = cache_dir
         self._clock = clock
         self._lock = threading.Lock()
         self._fns: Dict[str, _FnRecord] = {}
         self._inflight = 0
         self._warned_storms: Set[str] = set()
-        self._cache_entries = self._count_cache_entries()
+        _WATCHES.add(self)
 
     # ---- wrapping ----
 
@@ -285,7 +500,10 @@ class CompileWatch:
             self._inflight += 1
             self.tel.set_gauge("compile/inflight", float(self._inflight))
 
-    def _compile_end(self, name: str, sig: str, secs: float) -> None:
+    def _compile_end(self, name: str, sig: str, secs: float,
+                     cache_hits: int = 0, cache_misses: int = 0) -> None:
+        """``cache_hits`` / ``cache_misses``: the compile ledger's counts
+        on the calling thread around the observed call — jax's own."""
         storm = False
         with self._lock:
             self._inflight -= 1
@@ -320,32 +538,17 @@ class CompileWatch:
                     f"— offending signature: {sig[:512]}"
                 )
             self.tel.event("compile/storm", fn=name, sig=sig[:512])
-        self._probe_cache()
+        if cache_hits:
+            self.tel.inc("compile/cache_hits", float(cache_hits))
+        if cache_misses:
+            self.tel.inc("compile/cache_misses", float(cache_misses))
 
-    # ---- persistent-cache accounting ----
-
-    def _count_cache_entries(self) -> Optional[int]:
-        if not self.cache_dir:
-            return None
-        try:
-            return len(os.listdir(self.cache_dir))
-        except OSError:
-            return None
-
-    def _probe_cache(self) -> None:
-        """Around each observed compile: a new entry in the persistent
-        cache dir means XLA really compiled (miss — it wrote the result);
-        no new entry means the compile was served from cache (hit)."""
-        if self.cache_dir is None:
-            return
-        count = self._count_cache_entries()
-        if count is None:
-            return
-        prev, self._cache_entries = self._cache_entries, count
-        if prev is not None and count > prev:
-            self.tel.inc("compile/cache_misses", float(count - prev))
-        else:
-            self.tel.inc("compile/cache_hits")
+    def _on_stage_span(self, entry: Dict[str, Any]) -> None:
+        attrs = {"fn": entry["fn"]}
+        if "cache" in entry:
+            attrs["cache"] = entry["cache"]
+        self.tel.add_span("compile/" + entry["stage"], entry["t_start"],
+                          entry["secs"], **attrs)
 
     # ---- views ----
 
@@ -360,7 +563,7 @@ class CompileWatch:
             }
 
     def close(self) -> None:
-        pass
+        _WATCHES.discard(self)
 
 
 class _NullCompileWatch:
@@ -387,23 +590,16 @@ _GLOBAL: Any = NULL
 
 
 def configure(cfg=None, telemetry_sink=None,
-              cache_dir: Optional[str] = "auto",
               clock: Callable[[], float] = time.monotonic):
     """Install the process-global compile watch. A disabled (or absent)
-    config keeps the null sink — jit sites never re-check.
-
-    ``cache_dir="auto"`` resolves :func:`compilation_cache_dir`; pass
-    None to disable cache accounting."""
+    config keeps the null sink — jit sites never re-check."""
     global _GLOBAL
     if cfg is None or not getattr(cfg, "enabled", False):
         _GLOBAL = NULL
         return NULL
-    if cache_dir == "auto":
-        cache_dir = compilation_cache_dir()
     _GLOBAL = CompileWatch(
         telemetry_sink,
         storm_warmup_calls=getattr(cfg, "storm_warmup_calls", 16),
-        cache_dir=cache_dir,
         clock=clock,
     )
     return _GLOBAL

@@ -230,7 +230,6 @@ class GenerationServer:
             compile_watch_mod.CompileWatch(
                 self.telemetry,
                 storm_warmup_calls=cfg.compile_watch.storm_warmup_calls,
-                cache_dir=compile_watch_mod.compilation_cache_dir(),
             ) if arm_watch else compile_watch_mod.NULL
         )
         self.memwatch = (

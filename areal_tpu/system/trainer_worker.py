@@ -370,7 +370,12 @@ class TrainerWorker:
                 for role, m in self.models.items()
                 if hasattr(m.module, "param_cast_rebuilds")
             },
-            compile_cache=compile_watch.cache_stats(),
+            # the compile ledger less its ring of spans (a log line: the
+            # per-program table says what start-up cost; /metrics.json
+            # and telemetry.jsonl carry the spans)
+            compile_cache={k: v for k, v in
+                           (compile_watch.cache_stats() or {}).items()
+                           if k != "spans"} or None,
             native_ops="g++" if native.available() else "numpy",
         )
 

@@ -584,7 +584,8 @@ def phase_gen_server(args: argparse.Namespace) -> None:
             "max_abs_logprob_err_vs_f32_reference": err,
             "hbm_peak_bytes": dev["hbm_peak_bytes"],
             "attention": dev["attention"],
-            "compile_cache": dev["compile_cache"],
+            "compile_cache": {k: v for k, v in dev["compile_cache"].items()
+                              if k != "spans"},
             "server_metrics": {k: metrics[k] for k in (
                 "generated_tokens", "prefill_tokens", "compiled_shapes",
                 "last_weight_update_latency_s")},

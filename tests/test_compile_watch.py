@@ -11,6 +11,7 @@ sentinel's compile/HBM rule pack is validated through the same
 """
 
 import json
+import time
 
 import pytest
 
@@ -188,19 +189,261 @@ def test_fresh_wrappers_recompiling_known_shapes_are_not_storms():
 
 
 # ---------------------------------------------------------------------------
-# persistent-cache accounting
+# the compile ledger (CacheStats): jax's monitoring events, fed by hand
 # ---------------------------------------------------------------------------
 
+TRACE = "/jax/core/compile/jaxpr_trace_duration"
+LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+COMPILE = "/jax/core/compile/backend_compile_duration"
+HIT = "/jax/compilation_cache/cache_hits"
+MISS = "/jax/compilation_cache/cache_misses"
+READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+OLD_KEYS = ["dir", "hits", "misses", "trace_secs", "lower_secs",
+            "compile_secs", "cache_read_secs"]
 
-def test_cache_hit_miss_probing(tmp_path):
-    cache = tmp_path / "xla_cache"
-    cache.mkdir()
-    watch, reg, t = make_watch(cache_dir=str(cache))
 
-    def cold(x):
-        # XLA really compiled: it wrote a new persistent-cache entry
-        n = len(list(cache.iterdir()))
-        (cache / f"entry-{n}").write_text("xla")
+class Feed:
+    """Sends a ledger what jax's ``dispatch.log_elapsed_time`` sends: a
+    scalar when a stage is entered, a duration and a time span when it is
+    left (the ledger listens to the first and the last)."""
+
+    def __init__(self, ledger):
+        self.ledger = ledger
+
+    def enter(self, event, fn, t_start):
+        self.ledger._on_enter(event, t_start, fun_name=fn)
+
+    def leave(self, event, fn, t_start, t_end):
+        self.ledger._on_duration(event, t_end - t_start, fun_name=fn)
+        self.ledger._on_span(event, t_start, t_end, fun_name=fn)
+
+    def stage(self, event, fn, t_start, t_end, inside=()):
+        self.enter(event, fn, t_start)
+        for step in inside:
+            step()
+        self.leave(event, fn, t_start, t_end)
+
+    def hit(self, read_secs):
+        self.ledger._on_event(HIT)
+        self.ledger._on_duration(READ, read_secs)
+
+    def miss(self):
+        self.ledger._on_event(MISS)
+
+    def program(self, fn, t, trace=1.0, lower=0.5, compile=2.0,
+                read_secs=None):
+        """One compilation of ``fn`` from time ``t``: a miss, or a hit
+        that took ``read_secs`` to read; returns when it ended."""
+        outcome = (self.miss if read_secs is None
+                   else lambda: self.hit(read_secs))
+        self.stage(TRACE, fn, t, t + trace)
+        t += trace
+        self.stage(LOWER, f"jit({fn})", t, t + lower)
+        t += lower
+        self.stage(COMPILE, f"jit({fn})", t, t + compile, [outcome])
+        return t + compile
+
+
+def test_ledger_files_a_program_by_name_with_its_outcome():
+    ledger = cw.CacheStats()
+    feed = Feed(ledger)
+    t = feed.program("train_apply", 100.0)
+    feed.program("train_apply", t, trace=0.25, lower=0.25, compile=0.5,
+                 read_secs=0.125)
+    d = ledger.as_dict()
+    row = d["programs"]["train_apply"]
+    assert (row["n_trace"], row["n_lower"], row["n_compile"]) == (2, 2, 2)
+    assert (row["hits"], row["misses"]) == (1, 1) == (d["hits"], d["misses"])
+    assert row["trace_secs"] == 1.25 and row["lower_secs"] == 0.75
+    assert row["compile_secs"] == 2.5 and row["cache_read_secs"] == 0.125
+    assert row["max_secs"] == 3.5  # the first compilation, not the sum
+    assert d["busy_secs"] == 4.5
+    assert [(e["fn"], e["stage"], e["t_start"], e["secs"])
+            for e in d["spans"][:3]] == [
+        ("train_apply", "trace", 100.0, 1.0),
+        ("train_apply", "lower", 101.0, 0.5),
+        ("train_apply", "compile", 101.5, 2.0)]
+    first, second = d["spans"][2], d["spans"][5]
+    assert first["cache"] == "miss" and first["cache_read_secs"] == 0.0
+    assert second["cache"] == "hit" and second["cache_read_secs"] == 0.125
+    assert "cache" not in d["spans"][0]
+    # a backend compile the cache never saw (jax gave it no key)
+    feed.stage(COMPILE, "jit(callback_fn)", 200.0, 201.0)
+    assert ledger.as_dict()["spans"][-1]["cache"] == "uncached"
+
+
+def test_ledger_counts_nested_spans_once_and_files_them_under_the_program():
+    ledger = cw.CacheStats()
+    feed = Feed(ledger)
+    # train_grad_sliced's trace calls a jitted inner twice, which calls
+    # sin; lowering it traces one more helper.
+    inner1 = lambda: feed.stage(  # noqa: E731
+        TRACE, "inner", 10.5, 11.5,
+        [lambda: feed.stage(TRACE, "sin", 10.75, 11.0)])
+    inner2 = lambda: feed.stage(TRACE, "inner", 12.0, 13.0)  # noqa: E731
+    feed.stage(TRACE, "train_grad_sliced", 10.0, 14.0, [inner1, inner2])
+    feed.stage(LOWER, "jit(train_grad_sliced)", 14.0, 15.0,
+               [lambda: feed.stage(TRACE, "helper", 14.25, 14.5)])
+    feed.stage(COMPILE, "jit(train_grad_sliced)", 15.0, 17.0, [feed.miss])
+    d = ledger.as_dict()
+    assert sorted(d["programs"]) == ["train_grad_sliced"]
+    row = d["programs"]["train_grad_sliced"]
+    assert row["n_trace"] == 1 and row["n_children"] == 4
+    # the plain sum of the trace events would be 4 + 1 + 0.25 + 1 + 0.25
+    assert d["trace_secs"] == 4.25 == row["trace_secs"]
+    assert d["lower_secs"] == 1.0 and d["compile_secs"] == 2.0
+    # the helper traced while lowering is in two stage unions, once here
+    assert d["busy_secs"] == 7.0
+    assert row["max_secs"] == 7.0
+    assert [e["n_children"] for e in d["spans"]] == [3, 1, 0]
+
+
+def test_ledger_cache_traffic_lands_on_its_own_threads_compile_span():
+    import threading
+
+    ledger = cw.CacheStats()
+    feed = Feed(ledger)
+    other_in_compile = threading.Event()
+    main_done = threading.Event()
+
+    def other():
+        feed.enter(COMPILE, "jit(decode)", 50.0)
+        other_in_compile.set()
+        assert main_done.wait(10)
+        feed.hit(0.25)
+        feed.leave(COMPILE, "jit(decode)", 50.0, 53.0)
+
+    th = threading.Thread(target=other, name="gen-0")
+    th.start()
+    assert other_in_compile.wait(10)
+    # while gen-0 sits in its compile, this thread's compile misses
+    feed.stage(COMPILE, "jit(prefill)", 51.0, 52.0, [feed.miss])
+    assert ledger.thread_counts() == (0, 1)
+    main_done.set()
+    th.join(10)
+    assert not th.is_alive()
+    d = ledger.as_dict()
+    by_fn = {e["fn"]: e for e in d["spans"]}
+    assert by_fn["prefill"]["cache"] == "miss"
+    assert by_fn["prefill"]["cache_read_secs"] == 0.0
+    assert by_fn["decode"]["cache"] == "hit"
+    assert by_fn["decode"]["cache_read_secs"] == 0.25
+    assert by_fn["decode"]["thread"] == "gen-0"
+    assert d["programs"]["prefill"]["misses"] == 1
+    assert d["programs"]["prefill"]["hits"] == 0
+    assert d["programs"]["decode"]["hits"] == 1
+    # per thread, summed: two threads compiled at once
+    assert d["compile_secs"] == 4.0 == d["busy_secs"]
+    # traffic no span claims still reaches the totals, under one name
+    ledger._on_event(HIT)
+    d = ledger.as_dict()
+    assert d["hits"] == 2
+    assert d["programs"][cw.UNNAMED_PROGRAM]["hits"] == 1
+
+
+def test_ledger_ring_drops_the_oldest_and_the_aggregates_keep_counting():
+    ledger = cw.CacheStats()
+    feed = Feed(ledger)
+    n = cw.SPAN_RING + 100
+    for i in range(n):
+        feed.stage(COMPILE, f"jit(step{i % 7})", float(i), i + 0.5,
+                   [feed.miss])
+    d = ledger.as_dict()
+    assert len(d["spans"]) == cw.SPAN_RING
+    assert d["spans"][0]["t_start"] == 100.0  # oldest first, 100 dropped
+    assert d["spans"][-1]["t_start"] == float(n - 1)
+    assert sum(r["n_compile"] for r in d["programs"].values()) == n
+    assert d["misses"] == n == sum(
+        r["misses"] for r in d["programs"].values())
+    assert d["compile_secs"] == d["busy_secs"] == 0.5 * n
+    # a span that was entered before the ledger listened still counts
+    ledger._on_span(TRACE, 5000.0, 5001.0, fun_name="late")
+    assert ledger.as_dict()["programs"]["late"]["trace_secs"] == 1.0
+
+
+def test_ledger_loses_no_update_under_many_threads():
+    import sys
+    import threading
+
+    ledger = cw.CacheStats()
+    n_threads, n_programs = 16, 150
+    start = threading.Barrier(n_threads)
+
+    def worker(k):
+        feed = Feed(ledger)
+        start.wait(10)
+        t = 1000.0 * k
+        for i in range(n_programs):
+            t = feed.program(f"step{i % 5}", t, trace=0.5, lower=0.25,
+                             compile=1.0,
+                             read_secs=(0.125 if i % 2 else None))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=worker, args=(k,))
+                   for k in range(n_threads)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(th.is_alive() for th in threads)
+    d = ledger.as_dict()
+    n = n_threads * n_programs
+    assert (d["hits"], d["misses"]) == (n // 2, n // 2)
+    assert sum(r["n_compile"] for r in d["programs"].values()) == n
+    assert sum(r["hits"] for r in d["programs"].values()) == n // 2
+    assert d["trace_secs"] == 0.5 * n and d["compile_secs"] == 1.0 * n
+    assert d["busy_secs"] == 1.75 * n
+    assert d["cache_read_secs"] == 0.125 * (n // 2)
+    assert all(r["max_secs"] == 1.75 for r in d["programs"].values())
+    assert len(d["spans"]) == cw.SPAN_RING
+
+
+def test_ledger_totals_are_the_sums_over_programs():
+    ledger = cw.CacheStats()
+    feed = Feed(ledger)
+    t = 0.0
+    for i, fn in enumerate(["opt_init", "infer_forward", "train_apply",
+                            "infer_forward", "add"]):
+        t = feed.program(fn, t, trace=0.5 + i, lower=0.25, compile=1.0 + i,
+                         read_secs=(0.5 if i % 2 else None))
+    d = ledger.as_dict()
+    for key in OLD_KEYS[1:]:
+        assert d[key] == pytest.approx(
+            sum(r[key] for r in d["programs"].values())), key
+    assert d["programs"]["infer_forward"]["n_compile"] == 2
+    assert d["busy_secs"] == pytest.approx(
+        d["trace_secs"] + d["lower_secs"] + d["compile_secs"])
+
+
+def test_cache_stats_keeps_its_keys_and_is_plain_data(monkeypatch):
+    assert cw.cache_stats() is None or set(OLD_KEYS) <= set(cw.cache_stats())
+    ledger = cw.CacheStats()
+    monkeypatch.setattr(cw, "_CACHE_STATS", ledger)
+    Feed(ledger).program("adv_prep", 1.5e9, read_secs=0.01)
+    d = cw.cache_stats()
+    assert list(d)[:7] == OLD_KEYS
+    assert set(d) == set(OLD_KEYS) | {"busy_secs", "programs", "spans"}
+    assert json.loads(json.dumps(d)) == d
+    # a snapshot: what the ledger files later does not reach into it
+    Feed(ledger).program("adv_prep", 1.5e9 + 10)
+    assert len(d["spans"]) == 3 and d["programs"]["adv_prep"]["misses"] == 0
+
+
+def test_cache_hits_and_misses_come_from_the_ledger(monkeypatch):
+    """The watch's ``compile/cache_hits|misses`` are jax's own events as
+    the ledger counted them on the calling thread around the observed
+    call (no listing of the cache directory)."""
+    ledger = cw.CacheStats()
+    monkeypatch.setattr(cw, "_CACHE_STATS", ledger)
+    feed = Feed(ledger)
+    watch, reg, t = make_watch()
+
+    def cold(x):  # XLA really compiled: jax reports a miss
+        feed.program("train_grad_sliced", 10.0)
         return x
 
     f = watch.wrap("train/grad", cold)
@@ -208,12 +451,131 @@ def test_cache_hit_miss_probing(tmp_path):
     counters = reg.snapshot(reset=False)["counters"]
     assert counters["compile/cache_misses"] == 1.0
     assert "compile/cache_hits" not in counters
-    # a compile that produces no new entry was served from the cache
-    g = watch.wrap("train/grad", lambda x: x)
+
+    def warm(x):  # two executables read back from the cache
+        end = feed.program("train_grad_sliced", 20.0, read_secs=0.1)
+        feed.program("train_apply", end, read_secs=0.1)
+        return x
+
+    g = watch.wrap("train/grad", warm)
     g(Arr((4, 128)))
     counters = reg.snapshot(reset=False)["counters"]
     assert counters["compile/cache_misses"] == 1.0
-    assert counters["compile/cache_hits"] == 1.0
+    assert counters["compile/cache_hits"] == 2.0
+    # a first call that compiled nothing (jax had the executable) adds none
+    h = watch.wrap("train/grad", lambda x: x)
+    h(Arr((4, 128)))
+    assert reg.snapshot(reset=False)["counters"] == counters | {
+        "compile/events{fn=train/grad}": 3.0}
+    # with no ledger armed the watch still counts its compile events
+    monkeypatch.setattr(cw, "_CACHE_STATS", None)
+    watch.wrap("train/grad", lambda x: x)(Arr((4, 128)))
+
+
+def test_stage_spans_reach_the_watchs_telemetry_and_only_while_it_lives():
+    ledger = cw.CacheStats()
+    feed = Feed(ledger)
+    watch, reg, t = make_watch()
+    feed.program("infer_forward", 1000.0, read_secs=0.05)
+    spans = [s for s in reg.snapshot(reset=False)["spans"]
+             if s["name"].startswith("compile/")]
+    assert [(s["name"], s["t_start"], s["dur_secs"]) for s in spans] == [
+        ("compile/trace", 1000.0, 1.0), ("compile/lower", 1001.0, 0.5),
+        ("compile/compile", 1001.5, 2.0)]
+    assert [s["attrs"] for s in spans] == [
+        {"fn": "infer_forward"}, {"fn": "infer_forward"},
+        {"fn": "infer_forward", "cache": "hit"}]
+    watch.close()
+    feed.program("infer_forward", 2000.0)
+    assert len([s for s in reg.snapshot(reset=False)["spans"]
+                if s["name"].startswith("compile/")]) == 3
+
+
+# ---------------------------------------------------------------------------
+# the compile ledger under jax itself (CPU, a cache directory of the test's)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def jax_ledger(tmp_path):
+    """A ledger listening to this process's jax, and the persistent cache
+    in a directory of the test's own; both undone afterwards."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    ledger = cw.CacheStats()
+    jax.monitoring.register_scalar_listener(ledger._on_enter)
+    jax.monitoring.register_event_time_span_listener(ledger._on_span)
+    jax.monitoring.register_event_listener(ledger._on_event)
+    jax.monitoring.register_event_duration_secs_listener(ledger._on_duration)
+    keys = ["jax_compilation_cache_dir", "jax_enable_compilation_cache",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes"]
+    before = {k: getattr(jax.config, k) for k in keys}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cc.reset_cache()
+    try:
+        yield ledger
+    finally:
+        jax.monitoring.unregister_scalar_listener(ledger._on_enter)
+        jax.monitoring.unregister_event_time_span_listener(ledger._on_span)
+        jax.monitoring.unregister_event_listener(ledger._on_event)
+        jax.monitoring.unregister_event_duration_listener(
+            ledger._on_duration)
+        for k, v in before.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+def test_ledger_under_jax_miss_then_hit_then_nothing(jax_ledger):
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def inner(x):
+        return jnp.sin(x) * 2.0
+
+    def ledger_probe_program(x):
+        return (inner(x) + inner(x * x)).sum()
+
+    step = jax.jit(ledger_probe_program)
+    x = jnp.arange(8, dtype=jnp.float32)
+    t0 = time.time()
+    step(x).block_until_ready()
+    d = jax_ledger.as_dict()
+    row = d["programs"]["ledger_probe_program"]
+    assert (row["n_trace"], row["n_lower"], row["n_compile"]) == (1, 1, 1)
+    assert (row["hits"], row["misses"]) == (0, 1)
+    assert row["n_children"] >= 2  # inner (and what it calls) folded in
+    assert "inner" not in d["programs"] and "sin" not in d["programs"]
+    mine = [e for e in d["spans"] if e["fn"] == "ledger_probe_program"]
+    assert [e["stage"] for e in mine] == ["trace", "lower", "compile"]
+    assert mine[2]["cache"] == "miss"
+    assert all(t0 <= e["t_start"] <= time.time() for e in mine)
+    assert row["max_secs"] == pytest.approx(
+        sum(e["secs"] for e in mine), abs=1e-4)
+    # the same program after jax forgot its executables: read back
+    jax.clear_caches()
+    step(x).block_until_ready()
+    d = jax_ledger.as_dict()
+    row = d["programs"]["ledger_probe_program"]
+    assert (row["n_compile"], row["hits"], row["misses"]) == (2, 1, 1)
+    last = [e for e in d["spans"] if e["fn"] == "ledger_probe_program"][-1]
+    assert last["stage"] == "compile" and last["cache"] == "hit"
+    assert last["cache_read_secs"] > 0
+    assert row["cache_read_secs"] > 0
+    # a warm call sends nothing
+    n_spans, busy = len(d["spans"]), d["busy_secs"]
+    step(x).block_until_ready()
+    d = jax_ledger.as_dict()
+    assert len(d["spans"]) == n_spans and d["busy_secs"] == busy
+    # every filed span is some program's own: the totals are their sums
+    for key in ("hits", "misses"):
+        assert d[key] == sum(r[key] for r in d["programs"].values())
+    assert json.loads(json.dumps(d)) == d
 
 
 # ---------------------------------------------------------------------------
@@ -350,8 +712,7 @@ def test_configure_enabled_installs_and_shutdown_restores_null():
     reg = telemetry.TelemetryRegistry()
     try:
         watch = cw.configure(
-            CompileWatchConfig(enabled=True, storm_warmup_calls=3),
-            reg, cache_dir=None,
+            CompileWatchConfig(enabled=True, storm_warmup_calls=3), reg,
         )
         assert watch is cw.get() and cw.enabled()
         assert watch.storm_warmup_calls == 3

@@ -72,8 +72,7 @@ CALLERS = {"sft": _sft, "rm": _rm, "critic": _critic,
 @pytest.mark.parametrize("caller", sorted(CALLERS))
 def test_every_caller_runs_one_grad_program(caller, tmp_path):
     watch = compile_watch.configure(
-        CompileWatchConfig(enabled=True), telemetry.TelemetryRegistry(),
-        cache_dir=None)
+        CompileWatchConfig(enabled=True), telemetry.TelemetryRegistry())
     try:
         model, iface, data = CALLERS[caller](tmp_path)
         eng = model.module
