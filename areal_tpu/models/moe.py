@@ -8,8 +8,26 @@ Parity target: ``realhf/impl/model/modules/moe/`` — ``TopKRouter``
  - **grouped dispatch** (the default on one shard): flatten the (token,
    choice) entries, stable-argsort them by expert id, and run the expert
    MLPs as grouped GEMMs over the contiguous per-expert segments
-   (``jax.lax.ragged_dot``). Work scales with the rows actually routed;
+   (:func:`_grouped_matmul`). Work scales with the rows actually routed;
    there is no ``[E, C]`` capacity buffer on the compute path;
+ - **which grouped GEMM runs where — one rule, read off a static
+   shape**: where a pass runs on FEWER rows than the sort has (``rows <
+   M``: the bounded branch of :func:`_bounded_pass`, the one a share's
+   every step takes) and the kernels are wanted (a TPU backend, or
+   ``impl="pallas"`` as for the attention kernels), its GEMMs are jax's
+   Pallas grouped GEMM (``megablox`` ``gmm``, with ``tgmm`` and the
+   transposed ``gmm`` in its backward) at a tile :func:`gemm_tiling`
+   reads off ``(rows, K, N, G)``. Where the pass runs on ALL the rows
+   (``rows == M``: the whole-buffer fallback branch, which a trainer
+   compiles for every grid and hardly ever takes; every pass of an ep
+   shard, :func:`_dispatch_ep`; a decode step's short buffer; every
+   expert held) they stay ``jax.lax.ragged_dot``, as on every other
+   backend: the branch that is cold is the one that is cheap to set up
+   (every kernel a program holds is traced, lowered and read back with
+   its executable on every start; PERF.md §6, PR 37-38). Same
+   arithmetic either way — bf16 operands, float32 sums, rounded once —
+   in another order, so the two are within a rounding step of each other
+   and not bit-identical. :func:`gemm_counts` records which;
  - **capacity or none** (``MoEConfig.capacity_factor``): a number keeps
    the Switch-style drop of the reference (an entry past its expert's
    ``capacity`` slots contributes nothing; priority = token order, then
@@ -90,6 +108,7 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from areal_tpu.models.config import MoEConfig
+from areal_tpu.ops.attention import _wants_kernel
 
 DISPATCH_METHODS = ("grouped", "einsum")
 # Aux entries that add up over micro-batches and optimizer steps; every
@@ -275,19 +294,155 @@ def _dispatch_einsum(
 
 # ---------------- grouped dispatch (sorted segments, the default) ----------------
 
+# The tile rule of the Pallas grouped GEMM (:func:`gemm_tiling`); the
+# constants are read off tools/expert_rows_sweep.py on a v5e at the three
+# shipped shapes and off the cells' own set-up (PERF.md §5, PR 37-38).
+# The row tile: the kernel takes whole tiles only, and visits a tile once
+# more, at a full tile's cost, for every group boundary inside it.
+_GEMM_ROW_TILE = 512
+_GEMM_LANE = 128
+# The rows a group must be able to expect (``rows // groups``) for the
+# kernel to be worth its set-up: every kernel a program holds is traced,
+# lowered and read back with it on every start (~80 ms a kernel function a
+# program on the chip's host), a fixed cost that does not shrink with the
+# rows. Measured, the bounded pass forward + backward, kernel against
+# ``ragged_dot``: 1,504-1,664 rows a group (Mellum 2: 2304 -> 896) 14.6-15.8
+# for 22.3-24.4 ms; 992 (OLMoE's, were its bound engaged: 2048 -> 1024)
+# 9.5-10.3 for 9.2 ms — no gain at any tile tried; 320 (Nemotron 3:
+# 1024 -> 2688, 5 expert layers x 3 grids) 2.6-3.0 for 3.6 ms — +5 % in
+# its cell for +9 s of a 70 s start. Two row tiles a group lies between.
+_GEMM_MIN_GROUP_ROWS = 1024
+# What one grid step may hold of VMEM, by :func:`_tile_bytes`. Not Mosaic's
+# limit (blocks of 12 MiB compile for a v5e and their kernels are the
+# fastest alone, by a tenth) but what a block costs in CODE: Mosaic unrolls
+# it into every instance of a kernel a program holds — 48 in a Mellum 2
+# grad program — and an executable is read back on every start.
+_GEMM_VMEM_BYTES = 6 * 2 ** 20
+
+
+def _tile_bytes(tm: int, tk: int, tn: int) -> int:
+    """VMEM of one grid step at tile ``(tm, tk, tn)``, the larger of the
+    two kernels that run at it: the three bfloat16 blocks ``[tm, tk]``,
+    ``[tk, tn]``, ``[tm, tn]`` twice (the pipeline's two buffers) and the
+    float32 accumulator — ``[tm, tn]`` where the rows are kept (``gmm``),
+    ``[tk, tn]`` where they are contracted (``tgmm``)."""
+    return 2 * 2 * (tm * tk + tk * tn + tm * tn) + 4 * tn * max(tm, tk)
+
+
+def gemm_tiling(rows: int, k: int, n: int,
+                groups: int) -> Optional[Tuple[int, int, int]]:
+    """The tile ``(tm, tk, tn)`` of the Pallas grouped GEMM ``[rows, k] x
+    [groups, k, n]``, read off its static shape; None where
+    ``ragged_dot`` stays: a width that is no whole number of lanes, rows
+    that are no whole row tiles, or groups that can expect under
+    ``_GEMM_MIN_GROUP_ROWS`` rows each (``rows // groups``). ``tk`` and
+    ``tn`` divide the
+    widths in whole lanes and make the largest weight block that fits
+    ``_GEMM_VMEM_BYTES``, ties to the longer contraction: the fewest grid
+    steps and the most work per byte of weights read. The weights'
+    gradient (``tgmm``: ``[tk, tn]`` is its output block, the rows are
+    contracted) runs at the forward's tile — no second tiling; the rows'
+    gradient is the GEMM ``[rows, n] x [groups, k, n]ᵀ`` and asks this
+    rule for ``(rows, n, k)``."""
+    tm = _GEMM_ROW_TILE
+    if (k % _GEMM_LANE or n % _GEMM_LANE or rows % tm
+            or rows // groups < _GEMM_MIN_GROUP_ROWS):
+        return None
+
+    def lanes(width):  # the multiples of the lane width that divide it
+        return [t for t in range(_GEMM_LANE, width + 1, _GEMM_LANE)
+                if width % t == 0]
+
+    blocks = [(tk * tn, tk, tn) for tk in lanes(k) for tn in lanes(n)
+              if _tile_bytes(tm, tk, tn) <= _GEMM_VMEM_BYTES]
+    return (tm, *max(blocks)[1:]) if blocks else None
+
+
+def _live_rows(rows: int, group_sizes: jnp.ndarray) -> jnp.ndarray:
+    """[rows, 1] bool: the rows of a sorted buffer that a group owns."""
+    return (jnp.arange(rows) < jnp.sum(group_sizes))[:, None]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm_rows(xs, w, group_sizes, interpret):
+    """``xs [R, K] x w [G, K, N]`` by group as jax's Pallas kernel
+    (``jax.experimental.pallas.ops.tpu.megablox``), zero past
+    ``sum(group_sizes)`` on the way in and out as
+    :func:`_grouped_matmul` says: bf16 operands, a float32 accumulator a
+    tile, the output rounded once to the operands' dtype. Its own VJP and
+    not the package's, which hands all three kernels one tile whatever
+    their shapes."""
+    return _gmm_rows_fwd(xs, w, group_sizes, interpret)[0]
+
+
+def _gmm_rows_fwd(xs, w, group_sizes, interpret):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    (R, K), (G, _, N) = xs.shape, w.shape
+    live = _live_rows(R, group_sizes)
+    xs = jnp.where(live, xs, 0)
+    out = gmm(xs, w, group_sizes, xs.dtype, gemm_tiling(R, K, N, G),
+              interpret=interpret)
+    return jnp.where(live, out, 0), (xs, w, group_sizes)
+
+
+def _gmm_rows_bwd(interpret, kept, ct):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    xs, w, group_sizes = kept
+    (R, K), (G, _, N) = xs.shape, w.shape
+    live = _live_rows(R, group_sizes)
+    ct = jnp.where(live, ct, 0)
+    d_xs = gmm(ct, w, group_sizes, xs.dtype, gemm_tiling(R, N, K, G),
+               transpose_rhs=True, interpret=interpret)
+    d_w = tgmm(xs.swapaxes(0, 1), ct, group_sizes, w.dtype,
+               gemm_tiling(R, K, N, G), interpret=interpret)
+    return jnp.where(live, d_xs, 0), d_w, None
+
+
+_gmm_rows.defvjp(_gmm_rows_fwd, _gmm_rows_bwd)
+
+# Which grouped GEMM each traced call runs as, recorded where it is traced
+# (as :func:`combine_counts`): {(rows, K, N, groups): "gmm" | "ragged_dot"}.
+_GEMMS: Dict[Tuple[int, int, int, int], str] = {}
+
+
+def gemm_counts() -> Dict[Tuple[int, int, int, int], str]:
+    """The grouped GEMMs traced, by shape ``(rows, K, N, groups)``:
+    ``"gmm"`` where the Pallas kernel runs (a bounded pass on a TPU;
+    :func:`gemm_tiling` of the shape is its tile), ``"ragged_dot"``
+    everywhere else. ``moe_gemm`` in the trainer's ``device_report``; how
+    often the kernel's branch is the one taken at run time is ``passes -
+    full_passes``."""
+    return dict(_GEMMS)
+
+
 def _grouped_matmul(xs: jnp.ndarray,  # [M, K] rows sorted by group
                     w: jnp.ndarray,  # [G, K, F]
                     group_sizes: jnp.ndarray,  # [G] int32
+                    kernel: bool = False,  # the Pallas kernel, if a tile fits
+                    interpret: bool = False,  # ... in its interpreter (CPU)
                     ) -> jnp.ndarray:
     """Grouped GEMM over contiguous row segments: row m multiplies
     ``w[g]`` where m falls in group g's segment. Rows beyond
     ``sum(group_sizes)`` — the sentinel-sorted entries — come back as
     zeros, and take no gradient: the TPU's ``ragged_dot`` leaves those
     output rows unwritten (NaN among them, measured on a v5e), in the
-    backward pass as in the forward, so both ends are selected, never
-    multiplied, to zero. The select on the input is what zeroes the
-    cotangent of the rows on the way back."""
-    live = (jnp.arange(xs.shape[0]) < jnp.sum(group_sizes))[:, None]
+    backward pass as in the forward, and so does the Pallas kernel, so
+    both ends are selected, never multiplied, to zero. The select on the
+    input is what zeroes the cotangent of the rows on the way back.
+
+    ``kernel`` asks for the Pallas kernel (:func:`_gmm_rows`; the caller's
+    rule is :func:`_expert_rows`'): bfloat16 operands whose shape
+    :func:`gemm_tiling` has a tile for run it, anything else
+    ``jax.lax.ragged_dot``. :func:`gemm_counts` records which."""
+    (R, K), (G, _, N) = xs.shape, w.shape
+    kernel = (kernel and xs.dtype == w.dtype == jnp.bfloat16
+              and gemm_tiling(R, K, N, G) is not None)
+    _GEMMS[(R, K, N, G)] = "gmm" if kernel else "ragged_dot"
+    if kernel:
+        return _gmm_rows(xs, w, group_sizes, interpret)
+    live = _live_rows(R, group_sizes)
     out = jax.lax.ragged_dot(jnp.where(live, xs, 0), w, group_sizes)
     return jnp.where(live, out, 0)
 
@@ -357,6 +512,7 @@ def _expert_rows(
     rows: int,  # static: the rows of the sort the experts run on
     act,  # static: the experts' activation
     k: int,  # static: entries a token
+    gemm: str,  # static: "ragged_dot" | "gmm" | "gmm_interpret"
     xf: jnp.ndarray,  # [T, D] tokens (rows past N = M // k are never read)
     order: jnp.ndarray,  # [M] the entries sorted by group
     gate: jnp.ndarray,  # [M] gate of each entry, in sorted order
@@ -368,25 +524,35 @@ def _expert_rows(
     """The experts on the first ``rows`` rows of the sort: row gather, the
     grouped GEMMs (three, or two where the experts are not gated), gate
     multiply. Returns (the token of each row [rows], the gate-weighted
-    expert outputs [rows, D], zero past ``sum(group_sizes)``)."""
+    expert outputs [rows, D], zero past ``sum(group_sizes)``).
+
+    THE RULE of which grouped GEMM runs: the Pallas kernel where ``gemm``
+    (handed down from :func:`moe_mlp`) allows it — ``"gmm"``: compiled by
+    Mosaic, ``"gmm_interpret"``: in Pallas's interpreter, for the CPU
+    tests — and the pass is bounded (``rows < M``); ``ragged_dot`` on the
+    whole buffer — the fallback branch of :func:`_bounded_pass`, an ep
+    shard's pass, a decode step's — and wherever ``gemm`` says so."""
     M = order.shape[0]
+    matmul = functools.partial(
+        _grouped_matmul, kernel=gemm != "ragged_dot" and rows < M,
+        interpret=gemm == "gmm_interpret")
     with jax.named_scope("moe_dispatch"):
         tok = (order if rows == M else order[:rows]) // k
         xs = jnp.take(xf, tok, axis=0)  # [rows, D] sorted inputs
     with jax.named_scope("moe_experts"):
         if gate_w is None:
-            h = act(_grouped_matmul(xs, up_w, group_sizes))
+            h = act(matmul(xs, up_w, group_sizes))
         else:
-            h = act(
-                _grouped_matmul(xs, gate_w, group_sizes)
-            ) * _grouped_matmul(xs, up_w, group_sizes)
-        ys = _grouped_matmul(h, down_w, group_sizes)  # [rows, D]
+            h = act(matmul(xs, gate_w, group_sizes)) * matmul(
+                xs, up_w, group_sizes)
+        ys = matmul(h, down_w, group_sizes)  # [rows, D]
     with jax.named_scope("moe_dispatch"):
         return tok, ys * (gate if rows == M else gate[:rows]).astype(
             ys.dtype)[:, None]
 
 
-def _rows_pass(rows: int, act, k: int, xf, *args) -> jnp.ndarray:
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _rows_pass(rows: int, act, k: int, gemm: str, xf, *args) -> jnp.ndarray:
     """The expert pass on the first ``rows`` rows of the sort
     (:func:`_expert_rows`, its arguments) with its combine: row ``i`` is
     added into token ``order[i] // k`` — a scatter-add of ``rows`` rows,
@@ -397,16 +563,24 @@ def _rows_pass(rows: int, act, k: int, xf, *args) -> jnp.ndarray:
     source is (:func:`_whole_row_tiles`); rows past the tokens stay zero.
     Exact where ``sum(group_sizes) <= rows``: the live rows are the sort's
     head, and a row past them adds zero. Nothing of ``M`` rows is built
-    where ``rows < M``, forward or backward."""
-    tok, ys = _expert_rows(rows, act, k, xf, *args)
+    where ``rows < M``, forward or backward.
+
+    ONE jitted function for every call site of a shape: a trainer's
+    program calls the pass from both branches of :func:`_bounded_pass`,
+    forward and backward, in every run of layers, and a process holds a
+    dozen such programs. Jitted, the pass — its grouped GEMMs, the Pallas
+    kernels with their VJPs among them — is traced and differentiated once
+    a shape and a process, and a call site costs one equation: set-up is
+    part of what a kernel costs (PERF.md §6, PR 37-38)."""
+    tok, ys = _expert_rows(rows, act, k, gemm, xf, *args)
     with jax.named_scope("moe_dispatch"):
         return jnp.zeros(xf.shape, jnp.float32).at[tok].add(
             ys.astype(jnp.float32)).astype(ys.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _bounded_pass(rows: int, act, k: int, xf, order, gate, group_sizes,
-                  gate_w, up_w, down_w) -> jnp.ndarray:
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _bounded_pass(rows: int, act, k: int, gemm: str, xf, order, gate,
+                  group_sizes, gate_w, up_w, down_w) -> jnp.ndarray:
     """:func:`_rows_pass` on ``rows`` rows where the live rows fit them,
     else on all of them: the per-token sums ``[T, D]`` (``xf``'s rows) of
     the rows it ran on, either way. Its own VJP, so that the backward pass
@@ -420,22 +594,22 @@ def _bounded_pass(rows: int, act, k: int, xf, order, gate, group_sizes,
     args = (xf, order, gate, group_sizes, gate_w, up_w, down_w)
     return jax.lax.cond(
         jnp.sum(group_sizes) <= rows,
-        functools.partial(_rows_pass, rows, act, k),
-        functools.partial(_rows_pass, order.shape[0], act, k), *args)
+        functools.partial(_rows_pass, rows, act, k, gemm),
+        functools.partial(_rows_pass, order.shape[0], act, k, gemm), *args)
 
 
-def _bounded_pass_fwd(rows, act, k, *args):
-    return _bounded_pass(rows, act, k, *args), args
+def _bounded_pass_fwd(rows, act, k, gemm, *args):
+    return _bounded_pass(rows, act, k, gemm, *args), args
 
 
-def _bounded_pass_bwd(rows, act, k, args, ct):
+def _bounded_pass_bwd(rows, act, k, gemm, args, ct):
     xf, order, gate, group_sizes, gate_w, up_w, down_w = args
 
     def pull(rows, ct):
         _, vjp = jax.vjp(
             lambda xf, gate, gate_w, up_w, down_w: _rows_pass(
-                rows, act, k, xf, order, gate, group_sizes, gate_w, up_w,
-                down_w),
+                rows, act, k, gemm, xf, order, gate, group_sizes, gate_w,
+                up_w, down_w),
             xf, gate, gate_w, up_w, down_w)
         return vjp(ct)
 
@@ -462,6 +636,7 @@ def _sorted_expert_ffn(
     rows: int,  # sorted rows the pass runs on; N·k = all of them, no bound
     act=jax.nn.silu,  # the experts' activation
     k: Optional[int] = None,  # entries a token; None = xf holds just the N
+    gemm: str = "ragged_dot",  # how a bounded pass's GEMMs run
 ) -> Tuple[jnp.ndarray, jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Sort-based grouped expert compute over the ``G`` experts whose
     weights are given: one stable argsort of the ``M = N·k`` entries by
@@ -515,12 +690,12 @@ def _sorted_expert_ffn(
     args = (xf, order, gate, group_sizes, gate_w, up_w, down_w)
     _COMBINES[(M, R, T, D)] = "entries" if R == M else "rows"
     if R == M:
-        _, ys = _expert_rows(M, act, k, *args)
+        _, ys = _expert_rows(M, act, k, gemm, *args)
         with jax.named_scope("moe_dispatch"):
             inv = jnp.argsort(order)  # inverse permutation
             y = jnp.sum(jnp.take(ys, inv, axis=0).reshape(N, k, D), axis=1)
         return y, kept.astype(jnp.float32), {}
-    y = _bounded_pass(R, act, k, *args)
+    y = _bounded_pass(R, act, k, gemm, *args)
     with jax.named_scope("moe_dispatch"):
         y = y if T == N else y[:N]
     return y, kept.astype(jnp.float32), {
@@ -536,6 +711,7 @@ def _dispatch_grouped(
     lp: Dict[str, jnp.ndarray],
     moe: MoEConfig,
     n_valid: jnp.ndarray,
+    gemm: str = "ragged_dot",  # as :func:`_expert_rows`'
 ) -> Tuple[jnp.ndarray, jnp.ndarray, Dict[str, jnp.ndarray]]:
     """The one-shard path: padding entries are the sentinel
     (:func:`_sorted_expert_ffn`), and on a share so are the entries of
@@ -556,6 +732,7 @@ def _dispatch_grouped(
         xf, eid, top_p.reshape(N * k), None if dropless else capacity(N, moe),
         lp.get("e_gate"), lp["e_up"], lp["e_down"],
         sorted_rows(N * k, E, moe.n_routed), EXPERT_ACTS[moe.expert_act], k,
+        gemm,
     )
     extra.update(counts)
     if dropless:
@@ -650,7 +827,8 @@ def _dispatch_ep(
             # 20 steps into the cell's window where the parent fits 18
             # read 0.0144 against the reference's limit 0.011 at one seed
             # of four (PERF.md §6-7, PR 33): held back until that is
-            # understood.
+            # understood. A whole pass's GEMMs are ``ragged_dot``
+            # (:func:`_expert_rows`).
             y, kept, _ = _sorted_expert_ffn(
                 xs, eid, gs.reshape(Nl * k), cap, gate_w, up_w, down_w,
                 Nl * k)
@@ -701,6 +879,8 @@ def moe_mlp(
     mask: jnp.ndarray = None,  # [B, T] bool/int — True for real tokens
     dispatch: Optional[str] = None,  # None → AREAL_MOE_DISPATCH → "grouped"
     mesh: Optional[Mesh] = None,  # a mesh with ep > 1 → the EP path
+    impl: str = "auto",  # the transformer's ``attn_impl``: kernels or XLA
+    interpret: bool = False,  # run a Pallas kernel in its interpreter (CPU)
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Returns (output [B, T, D], aux dict with load_balance_loss / z_loss /
     aux_total / dropped_frac / expert_load / expert_load_ratio).
@@ -714,7 +894,13 @@ def moe_mlp(
     callers must gate on :func:`ep_eligible` (and must NOT pass a
     mesh from inside an already-manual shard_map region — the pipeline
     stages fall back to the single-shard paths with GSPMD handling the
-    ep-sharded weights)."""
+    ep-sharded weights).
+
+    ``impl``: whether a bounded pass's grouped GEMMs may be the Pallas
+    kernel — as for the attention kernels (``ops/attention``): ``"auto"``
+    takes it on a TPU backend and ``jax.lax.ragged_dot`` elsewhere,
+    ``"pallas"`` asks for it (a compile for a described TPU, where the
+    default backend is still the CPU), ``"reference"`` never does."""
     B, T, D = x.shape
     N = B * T
     xf = x.reshape(N, D)
@@ -749,7 +935,9 @@ def moe_mlp(
             with jax.named_scope("latent_down"):
                 xe = _whole_row_tiles(xf @ lp["latent_down"])
         y, dropped_frac, extra = _dispatch_grouped(
-            xe, top_p, top_i, valid, lp, moe, n_valid
+            xe, top_p, top_i, valid, lp, moe, n_valid,
+            "ragged_dot" if not _wants_kernel(impl)
+            else "gmm_interpret" if interpret else "gmm",
         )
         if latent:
             with jax.named_scope("latent_up"):

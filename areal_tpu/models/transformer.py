@@ -367,7 +367,7 @@ def _block(
             mlp, aux = moemod.moe_mlp(
                 x, lp, cfg.moe, rng=rng,
                 mask=(segment_ids > 0) if segment_ids is not None else None,
-                mesh=ep_mesh,
+                mesh=ep_mesh, impl=attn_impl,
             )
             return constrain(h + mlp, hid), new_kv, aux
     with jax.named_scope("mlp"):
@@ -409,7 +409,8 @@ def _mixer_block(
         with jax.named_scope("moe"):
             out, aux = moemod.moe_mlp(
                 x, lp, cfg.moe,
-                mask=(segment_ids > 0) if segment_ids is not None else None)
+                mask=(segment_ids > 0) if segment_ids is not None else None,
+                impl=attn_impl)
         return constrain(h + out, "hidden"), None, aux
     dh = cfg.head_dim
     with jax.named_scope("qkv_proj"):

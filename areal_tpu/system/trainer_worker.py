@@ -357,6 +357,11 @@ class TrainerWorker:
             # each expert pass traced adds its rows into their tokens
             moe_combine={"%d>%d/%dx%d" % key: how
                          for key, how in moe.combine_counts().items()},
+            # {"rows x K x N/groups": "gmm" | "ragged_dot"}: which grouped
+            # GEMM each one traced runs as (the Pallas kernel in a bounded
+            # pass on a TPU; its tile is moe.gemm_tiling of the shape)
+            moe_gemm={"%dx%dx%d/%d" % key: how
+                      for key, how in moe.gemm_counts().items()},
             # {model: {"RxL": {entry, kept_bytes_estimate, budget_bytes,
             # fell_back}}}: what each grid's backward pass re-runs
             remat_plan={

@@ -19,6 +19,7 @@ materialises 0.5B parameters).
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -43,7 +44,9 @@ MESH_SPECS = ("f2", "p2t2")
 # grid), and what the commit before the row bound (9987316) compiled to by
 # the same program — GB of temporaries, ``ragged-dot`` calls in the text.
 # Mellum's is PERF.md §5's 1.80 GB; OLMoE's 4.625 GB there is the engine's
-# own program, with the head and the gradient carry.
+# own program, with the head and the gradient carry. ``attn_impl="pallas"``
+# asks for every kernel a TPU process takes, the experts' grouped GEMM
+# among them (models/moe.py: the bounded branch of a share's pass).
 EXPERT_GRIDS = {
     "mellum": ("mellum2-12b-a2.5b", None, (1, 6656)),
     "mellum-6016": ("mellum2-12b-a2.5b", None, (1, 6016)),
@@ -94,9 +97,17 @@ def _compile_all():
     out = {}
 
     def record(name, compiled):
+        text = compiled.as_text()
         out[name] = {
-            "custom_calls": compiled.as_text().count("tpu_custom_call"),
+            "custom_calls": text.count("tpu_custom_call"),
             "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+            # kernel INSTANCES by the name the device's op line shows (what
+            # benchmark/moe_trace.py counts as the experts' GEMMs): the
+            # Pallas grouped GEMM, and XLA's own ragged dot
+            "gmm_calls": len(re.findall(
+                r"%t?gmm[.\d]* = [^\n]*custom-call\(", text)),
+            "ragged_dot_calls": len(re.findall(
+                r"%ragged-dot-none[.\d]* = [^\n]*custom-call\(", text)),
         }
 
     # Forward AND backward at the Qwen2.5-0.5B geometry: 14 q / 2 kv heads
@@ -265,10 +276,14 @@ def _compile_all():
             "ragged-dot")
         out[f"experts-{name}"]["conditionals"] = compiled.as_text().count(
             " conditional(")
-
-    # One latent expert layer of the hybrid configuration at the cell's rows.
     from areal_tpu.models import moe as moemod
 
+    # {"rows x K x N/groups": "gmm" | "ragged_dot"} as those programs traced
+    out["gemms"] = {"%dx%dx%d/%d" % key: how
+                    for key, how in moemod.gemm_counts().items()}
+
+    # One latent expert layer of the hybrid configuration at the cell's
+    # rows, asked for the kernels as a TPU process asks.
     with open(os.path.join(REPO, "benchmark", "configs",
                            "nemotron-3-super-120b-a12b.json")) as f:
         hybrid = weights.model_config(json.load(f))
@@ -277,7 +292,8 @@ def _compile_all():
 
     def latent_grad(lp, x, valid):
         def loss(lp, x):
-            y, _ = moemod.moe_mlp(x, lp, hybrid.moe, mask=valid > 0)
+            y, _ = moemod.moe_mlp(x, lp, hybrid.moe, mask=valid > 0,
+                                  impl="pallas")
             return jnp.sum(y.astype(jnp.float32) ** 2)
 
         return jax.grad(jax.checkpoint(loss), argnums=(0, 1))(lp, x)
@@ -290,7 +306,7 @@ def _compile_all():
                jax.jit(latent_grad).lower(lp, x, valid).compile())
         M = T * hybrid.moe.top_k
         forward = jax.jit(lambda lp, x, valid: moemod.moe_mlp(
-            x, lp, hybrid.moe, mask=valid > 0)[0]).lower(
+            x, lp, hybrid.moe, mask=valid > 0, impl="pallas")[0]).lower(
                 lp, x, valid).as_text().splitlines()
         out[f"latent-{T}"].update(
             rows=moemod.sorted_rows(M, hybrid.moe.num_experts,
@@ -304,6 +320,9 @@ def _compile_all():
             entry_gathers=sum(
                 "stablehlo.gather" in line and "-> tensor<%dx%dx" % (
                     M, hybrid.moe.latent_dim) in line for line in forward))
+    out["latent-gemms"] = {"%dx%dx%d/%d" % key: how
+                           for key, how in moemod.gemm_counts().items()
+                           if key[-1] == hybrid.moe.num_experts}
     return out
 
 
@@ -377,20 +396,55 @@ def test_kept_flash_residuals_spare_the_forward_kernel(compiled, entry,
 def test_the_bounded_expert_pass_keeps_the_programs_temporaries(compiled,
                                                                 name):
     """The grad program of a Mellum grid holds the expert pass at
-    both row counts — twice the grouped GEMMs, a forward and a backward
-    ``cond`` a layer in the text — and needs no more memory for it than
-    the program before the bound did (within 5 %): the two branches are
-    never alive together, and nothing but the pass's arguments is kept
-    between its forward and its backward. OLMoE's, expert-parallel, holds
-    no ``cond`` and the grouped GEMMs it had."""
+    both row counts — a forward and a backward ``cond`` a layer in the
+    text, the bounded branch's grouped GEMMs as the Pallas kernel and the
+    whole-buffer branch's as ``ragged_dot``, one for one: as many kernel
+    instances as ragged dots (3 a forward pass, 9 a backward pass, 4
+    layers) — and needs no more memory for it than the program before the
+    bound did (within 5 %): the two branches are never alive together, and
+    nothing but the pass's arguments is kept between its forward and its
+    backward. OLMoE's, expert-parallel, holds no ``cond``, no kernel and
+    the grouped GEMMs it had."""
     got = compiled[f"experts-{name}"]
     parent_gb, parent_calls = EXPERT_PARENT[name]
     bounded = name.startswith("mellum")
-    if parent_calls is not None:
-        assert got["ragged_dot"] == (2 if bounded else 1) * parent_calls
+    if bounded:
+        assert got["gmm_calls"] == got["ragged_dot_calls"] == 4 * (3 + 9)
+    else:
+        assert got["gmm_calls"] == 0
+        assert got["ragged_dot"] == parent_calls
     assert (got["conditionals"] >= 2) == bounded
     assert bounded or got["conditionals"] == 0
     assert got["temp_bytes"] <= 1.05 * parent_gb * 1e9
+
+
+def test_the_kernel_is_in_the_bounded_branch_and_nowhere_else(compiled):
+    """What those programs traced (``moe.gemm_counts()``): ``gmm`` at the
+    bounded row counts of Mellum's two grids, both orientations,
+    ``ragged_dot`` at their whole buffers and at every pass of an OLMoE
+    ``e4`` shard; Nemotron's bounded pass (320 rows a group) is refused by
+    the tile rule and stays ``ragged_dot`` though it was asked."""
+    want = {f"{rows}x{k}x{n}/16": "gmm" for rows in (24064, 26624)
+            for k, n in ((2304, 896), (896, 2304))}
+    got = compiled["gemms"]
+    assert {key: how for key, how in got.items() if how == "gmm"} == want
+    assert {key.split("x")[0] for key, how in got.items()
+            if how == "ragged_dot"} == {"48128", "53248", "31744"}
+    latent = compiled["latent-gemms"]
+    assert set(latent) == {"2560x1024x2688/8", "2560x2688x1024/8",
+                           *(f"{22 * T}x{k}x{n}/8" for T in LATENT_T
+                             for k, n in ((1024, 2688), (2688, 1024)))}
+    assert set(latent.values()) == {"ragged_dot"}
+
+
+@pytest.mark.parametrize("name", [
+    "experts-olmoe", "mesh-f2", "mesh-p2t2", "mesh-f2-pattern", "remat-full",
+    "latent-3072", "latent-3456", "latent-3712"])
+def test_programs_that_hold_no_grouped_gemm_kernel(compiled, name):
+    """An OLMoE ``e4`` grad program, the dense (Qwen-family) programs and
+    Nemotron's latent expert layer compile without a Pallas grouped GEMM:
+    their device programs are the parent's."""
+    assert compiled[name]["gmm_calls"] == 0
 
 
 if __name__ == "__main__":

@@ -23,11 +23,22 @@ over each token's choices, whose backward scatter-adds ``M`` rows — with
 ``ns_per_row`` = forward + backward over the rows moved (``R`` / ``M``).
 Prints one JSON line per case and writes them to
 ``chiprun_out/expert_rows_sweep.jsonl`` (``--out`` for another name).
+
+The grouped GEMMs themselves (the measurement behind ``moe.gemm_tiling``):
+``--gemm ragged_dot gmm`` times the pass once per way its GEMMs can run —
+``jax.lax.ragged_dot``, and the Pallas kernel under the shipped tile rule
+and then at each tile of ``--tiling 512x384x896 ...`` (``tm x tD x tF``:
+the row tile, the tile along the tokens' width and the tile along the
+experts' width, for all three kernels of every GEMM of the pass) — with
+``gemm``, ``tiling`` and ``roofline_pct`` (the benchmark's cost of the
+live rows at the chip's peaks over the time) beside ``fwd_ms`` /
+``fwd_bwd_ms``. Default: as shipped on this backend.
 """
 
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
 import os
 import sys
@@ -40,6 +51,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from areal_tpu.models import moe  # noqa: E402
+from areal_tpu.ops import attention  # noqa: E402
+from benchmark import moe_cost, peaks, ssm_cost  # noqa: E402
 
 
 def timed(fn, args, iters):
@@ -62,6 +75,10 @@ def main() -> int:
     ap.add_argument("--held", type=int, default=None)
     ap.add_argument("--routed", type=int, default=None)
     ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--gemm", nargs="+", default=["shipped"],
+                    choices=["shipped", "ragged_dot", "gmm"])
+    ap.add_argument("--tiling", nargs="+", default=[],
+                    help="tm x tD x tF, e.g. 512x384x896")
     args = ap.parse_args()
     # width, experts' width, choices, held, routed, tokens, gated, act
     D, F, k, G, E, N0, gated, act = (
@@ -70,6 +87,15 @@ def main() -> int:
         else (2304, 896, 8, 16, 64, 6656, True, jax.nn.silu))
     tokens = args.tokens or [N0]
     G, E = args.held or G, args.routed or E
+    # How the pass's GEMMs run: (moe_mlp's impl, tile) — as shipped on this
+    # backend, ragged_dot, the kernel under the shipped rule, the kernel at
+    # one tile.
+    ways = [way for gemm in args.gemm for way in {
+        "shipped": [("auto", None)], "ragged_dot": [("reference", None)],
+        "gmm": [("pallas", None)] + [
+            ("pallas", tuple(int(x) for x in t.split("x")))
+            for t in args.tiling]}[gemm]]
+    cost = moe_cost.grouped_ffn_cost if gated else ssm_cost.latent_ffn_cost
     out_path = os.path.join("chiprun_out", args.out)
     os.makedirs("chiprun_out", exist_ok=True)
     dev = jax.devices()[0]
@@ -93,34 +119,68 @@ def main() -> int:
                 "platform": dev.platform}
 
         a = (xf, gates, *[m for m in w if m is not None])
-        shipped = moe._ROW_HEADROOM
+        shipped = moe._ROW_HEADROOM, getattr(moe, "gemm_tiling", None)
         try:
-            for factor in [None] + sorted(args.factors):
+            for (impl, tile), factor in itertools.product(
+                    ways, [None] + sorted(args.factors)):
+                if impl == "pallas" and factor is None:
+                    continue  # the whole buffer never runs the kernel
+                if tile is not None:  # (rows, k, n, groups) -> tm, tk, tn
+                    moe.gemm_tiling = lambda rows, k, n, groups, t=tile: (
+                        t[0], *((t[1], t[2]) if (k, n) == (D, F)
+                                else (t[2], t[1])))
+                elif shipped[1] is not None:
+                    moe.gemm_tiling = shipped[1]
+                jax.clear_caches()  # the pass is jitted: by shape, not tile
+                gemm = ("ragged_dot" if not attention._wants_kernel(impl)
+                        else "gmm")
                 moe._ROW_HEADROOM = E / G if factor is None else factor
                 rows = moe.sorted_rows(M, G, E)
                 if rows < live:
                     continue  # would time the fallback
 
-                # jit caches by function: new functions per row count.
-                def ffn(xf, gates, *w, rows=rows):
+                # jit caches by function: new functions per case.
+                def ffn(xf, gates, *w, rows=rows, gemm=gemm):
                     if not gated:
                         w = (None,) + w
                     return moe._sorted_expert_ffn(
-                        xf, eid, gates, None, *w, rows, act, k)[0]
+                        xf, eid, gates, None, *w, rows, act, k,
+                        **({"gemm": gemm} if shipped[1] else {}))[0]
 
                 def loss(*a):
                     return jnp.sum(ffn(*a).astype(jnp.float32) ** 2)
 
-                fwd = timed(jax.jit(ffn), a, args.iters)
-                both = timed(jax.jit(jax.value_and_grad(
-                    loss, argnums=tuple(range(len(a))))), a, args.iters)
-                line = dict(case, headroom=factor, rows=rows,
-                            fwd_ms=round(fwd * 1e3, 4),
-                            fwd_bwd_ms=round(both * 1e3, 4))
+                line = dict(case, headroom=factor, rows=rows, gemm=gemm,
+                            tiling=tile and "%dx%dx%d" % tile)
+                try:
+                    fwd = timed(jax.jit(ffn), a, args.iters)
+                    both = timed(jax.jit(jax.value_and_grad(
+                        loss, argnums=tuple(range(len(a))))), a, args.iters)
+                except Exception as e:  # a tile the compiler refuses
+                    line["error"] = str(e).strip().splitlines()[-1][:200]
+                else:
+                    # forward + backward: the algorithm's least time for
+                    # the LIVE rows at the chip's peaks, over the time
+                    least = sum(peaks.least_time(
+                        *cost(live, 1, G, D, F, bwd), dev.device_kind)[0]
+                        for bwd in (False, True)
+                    ) if dev.device_kind in peaks.PEAKS else None
+                    line.update(
+                        fwd_ms=round(fwd * 1e3, 4),
+                        fwd_bwd_ms=round(both * 1e3, 4),
+                        roofline_pct=least and round(100 * least / both, 2))
+                    if shipped[1] is not None:
+                        line["gemms"] = {
+                            "%dx%dx%d/%d" % key: how if how != "gmm"
+                            else "gmm:%dx%dx%d" % moe.gemm_tiling(*key)
+                            for key, how in moe.gemm_counts().items()}
+                        moe._GEMMS.clear()
                 print(json.dumps(line), flush=True)
                 lines.append(line)
         finally:
-            moe._ROW_HEADROOM = shipped
+            moe._ROW_HEADROOM = shipped[0]
+            if shipped[1] is not None:
+                moe.gemm_tiling = shipped[1]
 
         # The combine alone, each way, at the shipped bound's row count.
         R = moe.sorted_rows(M, G, E)
