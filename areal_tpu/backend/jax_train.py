@@ -41,7 +41,7 @@ from areal_tpu.base import compile_watch, logging, telemetry
 from areal_tpu.models import generate as genmod
 from areal_tpu.models import moe as moe_mod
 from areal_tpu.models import transformer
-from areal_tpu.models.config import TransformerConfig
+from areal_tpu.models.config import TransformerConfig, has_dense_ffn
 from areal_tpu.ops.attention import dispatch_label, kernel_padded_len
 from areal_tpu.parallel import pipeline as ppl
 from areal_tpu.parallel import sharding as psh
@@ -765,12 +765,15 @@ class JaxTrainEngine(TrainableEngine):
 
     def _mixer_layer_width(self) -> int:
         """Elements a token costs the backward of the costliest layer of a
-        hybrid model — a layer is one mixer, so the widest of: an expert
+        hybrid model — a layer is one mixer (or a whole block, costed by
+        its wider half), so the widest of: a dense MLP's width, an expert
         layer's ``top_k`` rows at the width its experts read (the latent
         one where the model has it; never exchanged) or its shared
         expert's width, a Mamba-2 mixer's in-projection or its scan's
         [heads, chunk] decays (float32: two elements), attention's q."""
         cfg, widths = self.cfg, [self.cfg.q_dim * _LAYER_COPIES]
+        if any(has_dense_ffn(k) for k in cfg.layer_kinds):
+            widths.append(cfg.intermediate_dim * _LAYER_COPIES)
         if cfg.moe is not None:
             widths += [
                 cfg.moe.top_k * (

@@ -271,6 +271,13 @@ MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_exchange", "moe_experts")
 # Listed apart like MOE_SCOPES: a reader that knows only DEVICE_SCOPES sees
 # its ops under "attention", beside the flash kernel's.
 WINDOW_SCOPES = ("window_attention",)
+# A block with gated attention and sandwich norms (afmoe,
+# models/transformer.py): the gate's projection (inside "qkv_proj") and
+# its sigmoid-multiply (inside "o_proj"), the norm on the attention
+# branch's output (inside "o_proj") and on the FFN's (inside "mlp" or
+# "moe"). They nest like MOE_SCOPES: a reader that knows only
+# DEVICE_SCOPES sees their ops under the scope around them.
+SANDWICH_SCOPES = ("attn_gate", "post_attn_norm", "post_mlp_norm")
 # A hybrid model's Mamba-2 mixer (models/ssm.py), one scope a stage; no
 # DEVICE_SCOPES name lies between them and "layer_scan".
 SSM_SCOPES = ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",

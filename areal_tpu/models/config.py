@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 # The kinds a layer can have (``TransformerConfig.layer_kinds``). FULL and
 # SLIDING are whole blocks (attention of that kind, then the MLP). The
@@ -20,6 +20,23 @@ from typing import Optional, Tuple
 FULL, SLIDING = "full", "sliding"
 MAMBA, MOE_ONLY, ATTENTION_ONLY = "mamba", "moe_only", "attention_only"
 MIXER_KINDS = (MAMBA, MOE_ONLY, ATTENTION_ONLY)
+# A whole block's FFN kind (HF ``mlp_layer_types``): the model's dense MLP
+# or its expert layer. A block has an attention kind AND an FFN kind; the
+# kind of a block whose FFN is the dense MLP of a model that has experts
+# (afmoe's leading blocks) is its attention kind with ``DENSE_SUFFIX``.
+DENSE_FFN, SPARSE_FFN = "dense", "sparse"
+DENSE_SUFFIX = "_dense"
+
+
+def attention_kind(kind: str) -> str:
+    """The attention kind of a whole block's kind."""
+    return kind.removesuffix(DENSE_SUFFIX)
+
+
+def has_dense_ffn(kind: str) -> bool:
+    """A whole block whose FFN is the dense MLP though the model has
+    experts."""
+    return kind.endswith(DENSE_SUFFIX)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -164,9 +181,21 @@ class TransformerConfig:
     # of MIXER_KINDS (HF ``hybrid_override_pattern``);
     # None = every layer alike: SLIDING where ``sliding_window`` is set.
     layer_types: Optional[Tuple[str, ...]] = None
-    # (kind, RopeConfig) for the kinds whose RoPE is not the plain table
-    # at ``rotary_base`` (HF ``rope_parameters``, one block a layer type).
-    layer_rope: Optional[Tuple[Tuple[str, RopeConfig], ...]] = None
+    # (attention kind, RopeConfig) for the kinds whose RoPE is not the
+    # plain table at ``rotary_base`` (HF ``rope_parameters``, one block a
+    # layer type); None in place of a RopeConfig = the layers of that kind
+    # carry no position embedding (afmoe's full-attention layers).
+    layer_rope: Optional[Tuple[Tuple[str, Optional[RopeConfig]], ...]] = None
+    # The FFN kind of each whole block of a model that has experts,
+    # DENSE_FFN or SPARSE_FFN (HF ``mlp_layer_types``; afmoe's
+    # ``num_dense_layers``); None = every block runs the experts.
+    mlp_layer_types: Optional[Tuple[str, ...]] = None
+    # Gated attention (afmoe): a fifth projection ``wg`` [D, q_dim]; the
+    # attention output is multiplied by sigmoid(x wg) before ``wo``.
+    gated_attention: bool = False
+    # Sandwich norms (afmoe): a second norm on each branch's OUTPUT before
+    # it is added to the residual stream (``ln1_post``, ``ln2_post``).
+    sandwich_norm: bool = False
     # MLP activation: "silu" (llama family), "gelu_tanh" (gemma/gpt2),
     # "gelu" (exact)
     hidden_act: str = "silu"
@@ -207,14 +236,23 @@ class TransformerConfig:
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
-        """The attention kind of every layer."""
+        """The kind of every layer: its attention (or mixer) kind, with
+        ``DENSE_SUFFIX`` where a model with experts runs its dense MLP."""
         if self.layer_types is not None:
             assert len(self.layer_types) == self.n_layers, (
                 f"{len(self.layer_types)} layer_types for "
                 f"{self.n_layers} layers")
-            return tuple(self.layer_types)
-        kind = SLIDING if self.sliding_window is not None else FULL
-        return (kind,) * self.n_layers
+            kinds = tuple(self.layer_types)
+        else:
+            kind = SLIDING if self.sliding_window is not None else FULL
+            kinds = (kind,) * self.n_layers
+        if self.mlp_layer_types is None or self.moe is None:
+            return kinds
+        assert len(self.mlp_layer_types) == self.n_layers, (
+            f"{len(self.mlp_layer_types)} mlp_layer_types for "
+            f"{self.n_layers} layers")
+        return tuple(k + DENSE_SUFFIX if f == DENSE_FFN else k
+                     for k, f in zip(kinds, self.mlp_layer_types))
 
     @property
     def period_kinds(self) -> Tuple[str, ...]:
@@ -228,20 +266,50 @@ class TransformerConfig:
             if len(kinds) % p == 0 and kinds[:p] * (len(kinds) // p) == kinds)
 
     @property
-    def is_hybrid(self) -> bool:
-        """Layers that are one mixer alone: ``params["layers"]`` is then a
-        tree per KIND, each stacked over that kind's layers."""
+    def has_mixer_layers(self) -> bool:
+        """Layers that are one mixer alone (no cache to decode from)."""
         return any(k in MIXER_KINDS for k in self.layer_kinds)
+
+    @property
+    def is_hybrid(self) -> bool:
+        """Layers whose parameter SHAPES differ by kind — one mixer alone,
+        or whole blocks some of which run a dense MLP and some the
+        experts: ``params["layers"]`` is then a tree per KIND, each
+        stacked over that kind's layers."""
+        return any(k in MIXER_KINDS or has_dense_ffn(k)
+                   for k in self.layer_kinds)
 
     def n_layers_of(self, kind: str) -> int:
         return self.layer_kinds.count(kind)
 
-    def window_of(self, kind: str) -> Optional[int]:
-        return self.sliding_window if kind == SLIDING else None
+    @property
+    def n_expert_layers(self) -> int:
+        if self.moe is None:
+            return 0
+        return sum(k not in (MAMBA, ATTENTION_ONLY) and not has_dense_ffn(k)
+                   for k in self.layer_kinds)
 
-    def rope_of(self, kind: str) -> RopeConfig:
+    def block_counts(self) -> Dict[str, int]:
+        """{"<attention or mixer kind>/<dense | experts | ->": layers}."""
+        def ffn(kind):
+            if kind in (MAMBA, ATTENTION_ONLY):
+                return "-"
+            return "experts" if self.moe is not None and not has_dense_ffn(
+                kind) else "dense"
+
+        counts: Dict[str, int] = {}
+        for kind in self.layer_kinds:
+            key = f"{attention_kind(kind)}/{ffn(kind)}"
+            counts[key] = counts.get(key, 0) + 1
+        return counts
+
+    def window_of(self, kind: str) -> Optional[int]:
+        return self.sliding_window if attention_kind(kind) == SLIDING else None
+
+    def rope_of(self, kind: str) -> Optional[RopeConfig]:
+        """The RoPE table of a layer kind; None = no position embedding."""
         return dict(self.layer_rope or ()).get(
-            kind, RopeConfig(base=self.rotary_base))
+            attention_kind(kind), RopeConfig(base=self.rotary_base))
 
 
 def tiny_config(

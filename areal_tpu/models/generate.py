@@ -40,7 +40,7 @@ def decode_refusal(cfg: TransformerConfig) -> Optional[str]:
     alone — a state-space layer among them — has no cache to decode
     from. Every entry point below prefills through ``forward``, which
     raises it."""
-    return DECODE_REFUSAL if cfg.is_hybrid else None
+    return DECODE_REFUSAL if cfg.has_mixer_layers else None
 
 
 @partial(

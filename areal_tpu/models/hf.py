@@ -4,7 +4,7 @@ Parity target: the reference's per-family converter registry
 (``realhf/impl/model/conversion/hf_registry.py:32`` +
 ``realhf/api/from_hf/{llama,qwen2,qwen3,gemma,gpt2,mistral,mixtral}.py``).
 Families covered: llama, qwen2 (qwen2.5), qwen3, mistral, gemma, gpt2,
-mixtral, qwen3_moe, olmoe, mellum, nemotron_h.
+mixtral, qwen3_moe, olmoe, mellum, nemotron_h, afmoe.
 
 Weights are stacked on a leading layer axis (see models/transformer.py), so
 conversion transposes HF's ``[out, in]`` linear layout to ``[in, out]`` and
@@ -27,14 +27,17 @@ import numpy as np
 from areal_tpu.base import logging
 from areal_tpu.models.config import (
     ATTENTION_ONLY,
+    DENSE_FFN,
     FULL,
     MAMBA,
     MOE_ONLY,
     SLIDING,
+    SPARSE_FFN,
     MoEConfig,
     RopeConfig,
     SSMConfig,
     TransformerConfig,
+    attention_kind,
 )
 
 logger = logging.getLogger("models.hf")
@@ -238,6 +241,13 @@ def _mellum_config(hf_config: Any) -> TransformerConfig:
         if len(types) != kw["n_layers"]:
             raise ValueError(
                 f"{len(types)} layer_types for {kw['n_layers']} layers")
+    ffn_types = (getattr(hf_config, "mlp_layer_types", None)
+                 or ())[:kw["n_layers"]]
+    if set(ffn_types) - {SPARSE_FFN}:
+        raise NotImplementedError(
+            f"mlp_layer_types {sorted(set(ffn_types))}: a mellum "
+            f"checkpoint whose layers are not all {SPARSE_FFN!r} (dense "
+            "blocks beside expert blocks are read for afmoe only)")
     held = hf_config.num_experts
     routed, first = _expert_share(hf_config, held)
     return TransformerConfig(
@@ -346,6 +356,81 @@ def _nemotron_h_config(hf_config: Any) -> TransformerConfig:
             expert_act=act,
         ),
         hf_family="nemotron_h",
+    )
+
+
+@register_hf_family("afmoe")
+def _afmoe_config(hf_config: Any) -> TransformerConfig:
+    """Arcee Trinity (``AfmoeForCausalLM``): whole blocks of attention +
+    FFN under sandwich norms (a second RMSNorm on each branch's output),
+    the first ``num_dense_layers`` with a dense SwiGLU of
+    ``intermediate_size``, the others with ``num_experts`` routed experts
+    of ``moe_intermediate_size`` beside ``num_shared_experts`` shared ones
+    (sigmoid scores, choice by score + ``expert_bias``, gates renormalised
+    where ``route_norm`` and scaled by ``route_scale``, no token dropped);
+    attention gated by ``sigmoid(x W_g)``, q/k RMS-normalised per head,
+    its kind by ``layer_types`` (else full every
+    ``global_attn_every_n_layers``-th layer), RoPE on the sliding layers
+    and no position embedding on the full ones; the embedding times
+    ``sqrt(hidden_size)`` where ``mup_enabled``. No auxiliary loss (the
+    published ``load_balance_coeff`` is the pre-training recipe's). A
+    SHARE holds ``num_experts`` of ``num_routed_experts``
+    (:func:`_expert_share`)."""
+    for key in ("n_group", "topk_group", "num_expert_groups",
+                "num_limited_groups"):
+        if getattr(hf_config, key, 1) not in (None, 1):
+            raise NotImplementedError(
+                f"group-limited routing ({key} = {getattr(hf_config, key)})")
+    if getattr(hf_config, "score_func", "sigmoid") != "sigmoid":
+        raise NotImplementedError(
+            f"score_func {hf_config.score_func!r} (sigmoid is supported)")
+    if getattr(hf_config, "rope_scaling", None) is not None:
+        raise NotImplementedError(f"rope_scaling {hf_config.rope_scaling!r}")
+    kw = _base_kwargs(hf_config)
+    n = kw["n_layers"]
+    types = getattr(hf_config, "layer_types", None)
+    if types is None:
+        every = hf_config.global_attn_every_n_layers
+        types = ["full_attention" if (i + 1) % every == 0
+                 else "sliding_attention" for i in range(n)]
+    # a config cut in depth keeps the first layers' types
+    types = tuple(_HF_LAYER_TYPES[t] for t in types)[:n]
+    if len(types) != n:
+        raise ValueError(f"{len(types)} layer_types for {n} layers")
+    dense = int(getattr(hf_config, "num_dense_layers", 0) or 0)
+    held = hf_config.num_experts
+    routed, first = _expert_share(hf_config, held)
+    shared = (getattr(hf_config, "num_shared_experts", 0) or 0
+              ) * hf_config.moe_intermediate_size
+    return TransformerConfig(
+        **kw,
+        sliding_window=getattr(hf_config, "sliding_window", None),
+        layer_types=types,
+        layer_rope=((FULL, None),),
+        mlp_layer_types=tuple(
+            DENSE_FFN if i < dense else SPARSE_FFN for i in range(n))
+        if dense else None,
+        use_qk_norm=True,
+        gated_attention=True,
+        sandwich_norm=True,
+        scale_embeddings=bool(getattr(hf_config, "mup_enabled", False)),
+        max_position_embeddings=getattr(
+            hf_config, "max_position_embeddings", None),
+        moe=MoEConfig(
+            num_experts=held,
+            top_k=hf_config.num_experts_per_tok,
+            capacity_factor=None,
+            routed_intermediate_dim=hf_config.moe_intermediate_size,
+            shared_intermediate_dim=shared or None,
+            aux_loss_coeff=0.0,
+            norm_topk_prob=bool(getattr(hf_config, "route_norm", True)),
+            router_experts=routed,
+            first_expert=first,
+            router_score="sigmoid",
+            routed_scaling_factor=float(
+                getattr(hf_config, "route_scale", 1.0)),
+        ),
+        hf_family="afmoe",
     )
 
 
@@ -602,6 +687,19 @@ _NEMOTRON_H_EXPERTS = {"e_up": "mixer.experts.{e}.up_proj.weight",
                        "e_down": "mixer.experts.{e}.down_proj.weight"}
 
 
+def _layers_in_order(params: Dict[str, Any], cfg: TransformerConfig):
+    """(layer index, kind, that layer's leaves) in layer order — out of a
+    tree per kind, or out of the one tree of a model whose layers share
+    their shapes."""
+    seen: Dict[str, int] = {}
+    for i, kind in enumerate(cfg.layer_kinds):
+        tree, j = params["layers"], i
+        if cfg.is_hybrid:
+            tree, j = tree[kind], seen.get(kind, 0)
+            seen[kind] = j + 1
+        yield i, kind, {k: np.asarray(v[j]) for k, v in tree.items()}
+
+
 def _nemotron_h_to_sd(
     params: Dict[str, Any], cfg: TransformerConfig
 ) -> Dict[str, np.ndarray]:
@@ -610,11 +708,7 @@ def _nemotron_h_to_sd(
         "backbone.norm_f.weight": np.asarray(params["final_ln"]),
         "lm_head.weight": np.asarray(params["lm_head"]).T,
     }
-    seen = {kind: 0 for kind in params["layers"]}
-    for i, kind in enumerate(cfg.layer_kinds):
-        lp = {k: np.asarray(v[seen[kind]])
-              for k, v in params["layers"][kind].items()}
-        seen[kind] += 1
+    for i, kind, lp in _layers_in_order(params, cfg):
         pre = f"backbone.layers.{i}."
         sd[pre + "norm.weight"] = lp["ln"]
         for _, key, name, tr in (
@@ -659,6 +753,81 @@ def _nemotron_h_from_sd(
     }
 
 
+# afmoe: (pytree key, HF name under ``model.layers.{i}.``, transpose) of
+# a block's leaves — a dense block has the ``mlp.*_proj`` three, an expert
+# block the router, its bias, the shared expert and ``_AFMOE_EXPERTS``.
+_AFMOE_NAMES = [
+    ("ln1", "input_layernorm.weight", False),
+    ("ln1_post", "post_attention_layernorm.weight", False),
+    ("ln2", "pre_mlp_layernorm.weight", False),
+    ("ln2_post", "post_mlp_layernorm.weight", False),
+    ("wq", "self_attn.q_proj.weight", True),
+    ("wk", "self_attn.k_proj.weight", True),
+    ("wv", "self_attn.v_proj.weight", True),
+    ("wo", "self_attn.o_proj.weight", True),
+    ("wg", "self_attn.gate_proj.weight", True),
+    ("q_norm", "self_attn.q_norm.weight", False),
+    ("k_norm", "self_attn.k_norm.weight", False),
+    ("w_gate", "mlp.gate_proj.weight", True),
+    ("w_up", "mlp.up_proj.weight", True),
+    ("w_down", "mlp.down_proj.weight", True),
+    ("router", "mlp.router.gate.weight", True),
+    ("router_bias", "mlp.expert_bias", False),
+    ("s_gate", "mlp.shared_experts.gate_proj.weight", True),
+    ("s_up", "mlp.shared_experts.up_proj.weight", True),
+    ("s_down", "mlp.shared_experts.down_proj.weight", True),
+]
+_AFMOE_EXPERTS = {"e_gate": "mlp.experts.{e}.gate_proj.weight",
+                  "e_up": "mlp.experts.{e}.up_proj.weight",
+                  "e_down": "mlp.experts.{e}.down_proj.weight"}
+
+
+def _afmoe_to_sd(
+    params: Dict[str, Any], cfg: TransformerConfig
+) -> Dict[str, np.ndarray]:
+    sd = {
+        "model.embed_tokens.weight": np.asarray(params["embedding"]),
+        "model.norm.weight": np.asarray(params["final_ln"]),
+        "lm_head.weight": np.asarray(params["lm_head"]).T,
+    }
+    for i, _, lp in _layers_in_order(params, cfg):
+        pre = f"model.layers.{i}."
+        for key, name, tr in _AFMOE_NAMES:
+            if key in lp:
+                sd[pre + name] = lp[key].T if tr else lp[key]
+        for key, name in _AFMOE_EXPERTS.items():
+            if key in lp:
+                for e in range(cfg.moe.num_experts):
+                    sd[pre + name.format(e=e)] = lp[key][e].T
+    return sd
+
+
+def _afmoe_from_sd(
+    sd: Dict[str, Any], cfg: TransformerConfig, dtype: str
+) -> Dict[str, Any]:
+    per_kind: Dict[str, Dict[str, list]] = {}
+    for i, kind in enumerate(cfg.layer_kinds):
+        pre = f"model.layers.{i}."
+        lp = per_kind.setdefault(kind if cfg.is_hybrid else "", {})
+        for key, name, tr in _AFMOE_NAMES:
+            if pre + name in sd:
+                w = _np(sd[pre + name])
+                lp.setdefault(key, []).append(w.T if tr else w)
+        for key, name in _AFMOE_EXPERTS.items():
+            if pre + name.format(e=0) in sd:
+                lp.setdefault(key, []).append(np.stack([
+                    _np(sd[pre + name.format(e=e)]).T
+                    for e in range(cfg.moe.num_experts)]))
+    stacked = {kind: {k: np.stack(v).astype(dtype) for k, v in lp.items()}
+               for kind, lp in per_kind.items()}
+    return {
+        "embedding": _np(sd["model.embed_tokens.weight"]).astype(dtype),
+        "layers": stacked if cfg.is_hybrid else stacked[""],
+        "final_ln": _np(sd["model.norm.weight"]).astype(dtype),
+        "lm_head": _np(sd["lm_head.weight"]).T.astype(dtype),
+    }
+
+
 def params_from_hf_state_dict(
     sd: Dict[str, Any], cfg: TransformerConfig, dtype: str = "float32"
 ) -> Dict[str, Any]:
@@ -667,6 +836,8 @@ def params_from_hf_state_dict(
         return _gpt2_from_sd(sd, cfg, dtype)
     if cfg.hf_family == "nemotron_h":
         return _nemotron_h_from_sd(sd, cfg, dtype)
+    if cfg.hf_family == "afmoe":
+        return _afmoe_from_sd(sd, cfg, dtype)
     return _llama_from_sd(sd, cfg, dtype)
 
 
@@ -678,6 +849,8 @@ def params_to_hf_state_dict(
         return _gpt2_to_sd(params, cfg)
     if cfg.hf_family == "nemotron_h":
         return _nemotron_h_to_sd(params, cfg)
+    if cfg.hf_family == "afmoe":
+        return _afmoe_to_sd(params, cfg)
     return _llama_to_sd(params, cfg)
 
 
@@ -695,6 +868,7 @@ _HF_ARCH = {
     "olmoe": "OlmoeForCausalLM",
     "mellum": "MellumForCausalLM",
     "nemotron_h": "NemotronHForCausalLM",
+    "afmoe": "AfmoeForCausalLM",
 }
 
 
@@ -718,6 +892,8 @@ def hf_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
         }
     if fam == "nemotron_h":
         return _nemotron_h_config_dict(cfg)
+    if fam == "afmoe":
+        return _afmoe_config_dict(cfg)
     d: Dict[str, Any] = {
         "model_type": fam,
         "architectures": [_HF_ARCH.get(fam, "LlamaForCausalLM")],
@@ -833,6 +1009,54 @@ def _nemotron_h_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
     }
     if moe.latent_dim:
         d["moe_latent_size"] = moe.latent_dim
+    if moe.is_share:
+        d["num_routed_experts"] = moe.n_routed
+        d["expert_shard_count"] = moe.n_routed // moe.num_experts
+        d["expert_shard_index"] = moe.first_expert // moe.num_experts
+    return d
+
+
+def _afmoe_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
+    """The inverse of :func:`_afmoe_config`."""
+    moe = cfg.moe
+    names = {kind: name for name, kind in _HF_LAYER_TYPES.items()}
+    kinds = [attention_kind(k) for k in cfg.layer_kinds]
+    full = [i + 1 for i, k in enumerate(kinds) if k == FULL]
+    d = {
+        "model_type": "afmoe",
+        "architectures": [_HF_ARCH["afmoe"]],
+        "num_hidden_layers": cfg.n_layers,
+        "num_dense_layers": (cfg.mlp_layer_types or ()).count(DENSE_FFN),
+        "layer_types": [names[k] for k in kinds],
+        "global_attn_every_n_layers": full[0] if full else cfg.n_layers + 1,
+        "hidden_size": cfg.hidden_dim,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "head_dim": cfg.head_dim,
+        "intermediate_size": cfg.intermediate_dim,
+        "moe_intermediate_size": moe.routed_intermediate_dim,
+        "vocab_size": cfg.vocab_size,
+        "hidden_act": "silu",
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "rope_theta": cfg.rotary_base,
+        "rope_scaling": None,
+        "sliding_window": cfg.sliding_window,
+        "tie_word_embeddings": cfg.tie_word_embeddings,
+        "max_position_embeddings": cfg.max_position_embeddings or 32768,
+        "mup_enabled": cfg.scale_embeddings,
+        "num_experts": moe.num_experts,
+        "num_experts_per_tok": moe.top_k,
+        "num_shared_experts": (moe.shared_intermediate_dim or 0)
+        // moe.routed_intermediate_dim,
+        "score_func": moe.router_score,
+        "route_norm": moe.norm_topk_prob,
+        "route_scale": moe.routed_scaling_factor,
+        "n_group": 1, "topk_group": 1,
+        "num_expert_groups": 1, "num_limited_groups": 1,
+        "load_balance_coeff": moe.aux_loss_coeff,
+        "use_grouped_mm": True,
+        "torch_dtype": "float32",
+    }
     if moe.is_share:
         d["num_routed_experts"] = moe.n_routed
         d["expert_shard_count"] = moe.n_routed // moe.num_experts
@@ -1033,13 +1257,28 @@ def is_native_checkpoint(load_dir: str) -> bool:
     return os.path.exists(os.path.join(load_dir, "areal_tpu_native.json"))
 
 
+def config_from_dict(cd: Dict[str, Any]) -> TransformerConfig:
+    """``dataclasses.asdict(cfg)`` after a trip through JSON → the config:
+    the nested dataclasses rebuilt, the lists tuples again."""
+    cd = dict(cd)
+    if cd.get("moe"):
+        cd["moe"] = MoEConfig(**cd["moe"])
+    if cd.get("ssm"):
+        cd["ssm"] = SSMConfig(**cd["ssm"])
+    for key in ("layer_types", "mlp_layer_types"):
+        if cd.get(key) is not None:
+            cd[key] = tuple(cd[key])
+    if cd.get("layer_rope") is not None:
+        cd["layer_rope"] = tuple(
+            (kind, None if rope is None else RopeConfig(**rope))
+            for kind, rope in cd["layer_rope"])
+    return TransformerConfig(**cd)
+
+
 def load_native_checkpoint(load_dir: str):
     with open(os.path.join(load_dir, "areal_tpu_native.json")) as f:
         d = json.load(f)
-    cd = d["areal_tpu_config"]
-    if cd.get("moe"):
-        cd["moe"] = MoEConfig(**cd["moe"])
-    cfg = TransformerConfig(**cd)
+    cfg = config_from_dict(d["areal_tpu_config"])
     params = _unflatten_pytree(load_hf_state_dict(load_dir))
     return cfg, params
 
@@ -1058,10 +1297,7 @@ def load_hf_checkpoint(load_dir: str):
         acfg_path = os.path.join(load_dir, "config.json")
     with open(acfg_path) as f:
         d = json.load(f)
-    cd = d["areal_tpu_config"]
-    if cd.get("moe"):
-        cd["moe"] = MoEConfig(**cd["moe"])
-    cfg = TransformerConfig(**cd)
+    cfg = config_from_dict(d["areal_tpu_config"])
     sd = load_hf_state_dict(load_dir)
     params = params_from_hf_state_dict(sd, cfg, cfg.dtype)
     return cfg, params

@@ -44,11 +44,15 @@ import jax.numpy as jnp
 
 from areal_tpu.models.config import (
     ATTENTION_ONLY,
+    FULL,
     MAMBA,
     MIXER_KINDS,
     MOE_ONLY,
+    SLIDING,
     RopeConfig,
     TransformerConfig,
+    attention_kind,
+    has_dense_ffn,
 )
 from areal_tpu.ops.attention import decode_attention, packed_attention
 from areal_tpu.parallel.sharding import constrain, current_mesh
@@ -87,28 +91,26 @@ def _init_mixer_layers(cfg: TransformerConfig, keys, dtype) -> Params:
             "wv": nrm(keys[2], (n, d, kvd)),
             "wo": nrm(keys[3], (n, qd, d)),
         }
+    # whole blocks whose FFN kinds differ: a stack a kind, keyed apart
+    blocks = [k for k in dict.fromkeys(cfg.layer_kinds)
+              if k not in MIXER_KINDS]
+    for j, kind in enumerate(blocks):
+        layers[kind] = _init_block_layers(
+            cfg, cfg.n_layers_of(kind),
+            jax.random.split(jax.random.fold_in(keys[11], j), 16), dtype,
+            dense_ffn=has_dense_ffn(kind))
     return layers
 
 
-def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
-    dtype = jnp.dtype(cfg.dtype)
-    n, d, dh = cfg.n_layers, cfg.hidden_dim, cfg.head_dim
+def _init_block_layers(cfg: TransformerConfig, n: int, keys, dtype,
+                       dense_ffn: bool) -> Dict[str, jnp.ndarray]:
+    """``n`` whole blocks (attention + FFN) stacked ``[n, ...]``; the FFN
+    is the expert layer where the model has one, unless ``dense_ffn``."""
+    d = cfg.hidden_dim
     qd, kvd, f = cfg.q_dim, cfg.kv_dim, cfg.intermediate_dim
-    keys = jax.random.split(key, 16)
 
     def nrm(k, shape, scale=0.02):
         return (jax.random.normal(k, shape) * scale).astype(dtype)
-
-    if cfg.is_hybrid:
-        assert cfg.norm_type == "rms" and not cfg.is_critic
-        params = {
-            "embedding": nrm(keys[7], (cfg.vocab_size, d)),
-            "layers": _init_mixer_layers(cfg, keys, dtype),
-            "final_ln": jnp.ones((d,), dtype),
-        }
-        if not cfg.tie_word_embeddings:
-            params["lm_head"] = nrm(keys[8], (d, cfg.vocab_size))
-        return params
 
     layers: Dict[str, jnp.ndarray] = {
         "ln1": jnp.ones((n, d), dtype),
@@ -118,10 +120,10 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
         "wv": nrm(keys[2], (n, d, kvd)),
         "wo": nrm(keys[3], (n, qd, d)),
     }
-    if cfg.moe is not None:
+    if cfg.moe is not None and not dense_ffn:
         from areal_tpu.models import moe as moemod
 
-        layers.update(moemod.init_moe_params(cfg, keys[4], dtype))
+        layers.update(moemod.init_moe_params(cfg, keys[4], dtype, n))
     elif cfg.mlp_type == "plain":
         layers.update({
             "w_up": nrm(keys[5], (n, d, f)),
@@ -147,6 +149,34 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     if cfg.norm_type == "layer":
         layers["ln1_b"] = jnp.zeros((n, d), dtype)
         layers["ln2_b"] = jnp.zeros((n, d), dtype)
+    if cfg.gated_attention:
+        layers["wg"] = nrm(keys[12], (n, d, qd))
+    if cfg.sandwich_norm:
+        layers["ln1_post"] = jnp.ones((n, d), dtype)
+        layers["ln2_post"] = jnp.ones((n, d), dtype)
+    return layers
+
+
+def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
+    dtype = jnp.dtype(cfg.dtype)
+    n, d = cfg.n_layers, cfg.hidden_dim
+    keys = jax.random.split(key, 16)
+
+    def nrm(k, shape, scale=0.02):
+        return (jax.random.normal(k, shape) * scale).astype(dtype)
+
+    if cfg.is_hybrid:
+        assert cfg.norm_type == "rms" and not cfg.is_critic
+        params = {
+            "embedding": nrm(keys[7], (cfg.vocab_size, d)),
+            "layers": _init_mixer_layers(cfg, keys, dtype),
+            "final_ln": jnp.ones((d,), dtype),
+        }
+        if not cfg.tie_word_embeddings:
+            params["lm_head"] = nrm(keys[8], (d, cfg.vocab_size))
+        return params
+
+    layers = _init_block_layers(cfg, n, keys, dtype, dense_ffn=False)
 
     params: Params = {
         "embedding": nrm(keys[7], (cfg.vocab_size, d)),
@@ -252,9 +282,12 @@ def rope_tables_by_kind(
     — one table for a model whose layers are alike."""
     if cfg.pos_embedding == "none":
         return {None: (None, None)}
+    ropes = {kind: cfg.rope_of(kind) for kind in dict.fromkeys(
+        map(attention_kind, cfg.period_kinds))}
     return {
-        kind: rope_tables(positions, cfg.head_dim, cfg.rope_of(kind))
-        for kind in dict.fromkeys(cfg.period_kinds)
+        kind: (None, None) if rope is None  # no position embedding
+        else rope_tables(positions, cfg.head_dim, rope)
+        for kind, rope in ropes.items()
     }
 
 
@@ -295,10 +328,11 @@ def _block(
         return _mixer_block(
             cfg, kind, h, lp, segment_ids, positions, attn_impl, allow_ring,
             ring_ctx)
+    akind = attention_kind(kind)
     if isinstance(cos, dict):  # a table per attention kind
-        cos, sin = cos[kind], sin[kind]
+        cos, sin = cos[akind], sin[akind]
     if isinstance(kv_valid, dict):
-        kv_valid = kv_valid[kind]
+        kv_valid = kv_valid[akind]
     dh = cfg.head_dim
 
     # The jax.named_scope names below are the device-side names of a
@@ -326,7 +360,10 @@ def _block(
         if qk_norm == "head":
             q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
             k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-    if cfg.pos_embedding == "rope":
+        if cfg.gated_attention:
+            with jax.named_scope("attn_gate"):
+                gate = x @ lp["wg"]
+    if cfg.pos_embedding == "rope" and cos is not None:
         with jax.named_scope("rope"):
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
@@ -340,15 +377,22 @@ def _block(
 
     hid = "hidden" if cache_kv is None else "hidden_decode"
     with jax.named_scope("o_proj"):
-        attn = attn.reshape(B, T, cfg.q_dim) @ lp["wo"]
+        attn = attn.reshape(B, T, cfg.q_dim)
+        if cfg.gated_attention:
+            with jax.named_scope("attn_gate"):
+                attn = attn * jax.nn.sigmoid(gate)
+        attn = attn @ lp["wo"]
         if "bo" in lp:
             attn = attn + lp["bo"]
+        if cfg.sandwich_norm:
+            with jax.named_scope("post_attn_norm"):
+                attn = _norm(cfg, attn, lp, "ln1_post")
         h = constrain(h + attn, hid)
 
     with jax.named_scope("mlp_norm"):
         x = _norm(cfg, h, lp, "ln2")
     act = _ACTIVATIONS[cfg.hidden_act]
-    if cfg.moe is not None:
+    if cfg.moe is not None and not has_dense_ffn(kind):
         from areal_tpu.models import moe as moemod
 
         # Expert-parallel path (moe._dispatch_ep): only from GSPMD-auto regions
@@ -369,6 +413,9 @@ def _block(
                 mask=(segment_ids > 0) if segment_ids is not None else None,
                 mesh=ep_mesh, impl=attn_impl,
             )
+            if cfg.sandwich_norm:
+                with jax.named_scope("post_mlp_norm"):
+                    mlp = _norm(cfg, mlp, lp, "ln2_post")
             return constrain(h + mlp, hid), new_kv, aux
     with jax.named_scope("mlp"):
         if cfg.mlp_type == "plain":
@@ -376,6 +423,9 @@ def _block(
                    + lp["b_down"])
         else:
             mlp = (act(x @ lp["w_gate"]) * (x @ lp["w_up"])) @ lp["w_down"]
+        if cfg.sandwich_norm:
+            with jax.named_scope("post_mlp_norm"):
+                mlp = _norm(cfg, mlp, lp, "ln2_post")
         return constrain(h + mlp, hid), new_kv, None
 
 
@@ -748,6 +798,24 @@ def _maybe_checkpoint(body, remat):
         body, policy=_remat_policy("full" if remat is True else remat))
 
 
+def _block_matmul_widths(cfg: TransformerConfig, dense_ffn: bool) -> int:
+    """Widths of a whole block's matmul outputs that its backward reads:
+    q/k/v (and the attention gate), o_proj, and the MLP's matmuls into
+    the hidden width (gate and up, or up) — nothing in the backward reads
+    the last matmul's output, unless a sandwich norm does; an MoE layer
+    keeps the router's logits and its shared expert's pair."""
+    widths = cfg.q_dim + 2 * cfg.kv_dim + cfg.hidden_dim
+    if cfg.gated_attention:
+        widths += cfg.q_dim
+    if cfg.sandwich_norm:  # the post-norm reads the FFN's last matmul
+        widths += cfg.hidden_dim
+    if cfg.moe is None or dense_ffn:
+        return widths + (
+            1 if cfg.mlp_type == "plain" else 2) * cfg.intermediate_dim
+    return widths + (cfg.moe.n_routed
+                     + 2 * (cfg.moe.shared_intermediate_dim or 0))
+
+
 def remat_kept_bytes(
     cfg: TransformerConfig, tokens: int, itemsize: int,
     flash_tokens: int = 0, window_tokens: int = 0,
@@ -761,7 +829,6 @@ def remat_kept_bytes(
     sliding-window layers. Arithmetic on the widths in ``cfg``,
     checked against what jax really keeps in tests/test_remat_plan.py and
     against the chip's compiler in PERF.md §5."""
-    from areal_tpu.models.config import SLIDING
     from areal_tpu.ops.pallas.flash_attention import LANE
 
     full = tokens * cfg.hidden_dim * itemsize
@@ -784,22 +851,17 @@ def remat_kept_bytes(
             MOE_ONLY: (moe.n_routed + (moe.latent_dim or 0)
                        + (moe.shared_intermediate_dim or 0)) if moe else 0,
         }
+        kernel = {ATTENTION_ONLY: flash, FULL: flash, SLIDING: window}
+        for kind in cfg.layer_kinds:  # whole blocks whose FFN kinds differ
+            if kind not in MIXER_KINDS:
+                widths[kind] = _block_matmul_widths(cfg, has_dense_ffn(kind))
         kept = {"full": cfg.n_layers * full}
-        kept["attention"] = (
-            kept["full"] + cfg.n_layers_of(ATTENTION_ONLY) * flash)
+        kept["attention"] = kept["full"] + sum(
+            kernel.get(attention_kind(kind), 0) for kind in cfg.layer_kinds)
         kept["matmuls"] = kept["attention"] + tokens * itemsize * sum(
             widths[kind] for kind in cfg.layer_kinds)
         return kept
-    # q/k/v, o_proj, and the MLP's matmuls into the hidden width (gate and
-    # up, or up) — nothing in the backward reads the last matmul's output;
-    # an MoE layer keeps the router's logits and its shared expert's pair.
-    widths = cfg.q_dim + 2 * cfg.kv_dim + cfg.hidden_dim
-    if cfg.moe is None:
-        widths += (1 if cfg.mlp_type == "plain" else 2) * cfg.intermediate_dim
-    else:
-        widths += (cfg.moe.n_routed
-                   + 2 * (cfg.moe.shared_intermediate_dim or 0))
-    matmuls = tokens * widths * itemsize
+    matmuls = tokens * _block_matmul_widths(cfg, False) * itemsize
     attention = (cfg.n_layers - n_sliding) * flash + n_sliding * window
     kept = {"full": cfg.n_layers * full}
     kept["attention"] = kept["full"] + attention
@@ -839,7 +901,7 @@ def forward(
     attention kind (:func:`kv_valid_by_kind`).
     """
     decode = kv_cache is not None
-    if cfg.is_hybrid and (decode or return_kv):
+    if cfg.has_mixer_layers and (decode or return_kv):
         raise NotImplementedError(DECODE_REFUSAL)
     with jax.named_scope("embed"):
         h = params["embedding"][tokens]
@@ -857,6 +919,9 @@ def forward(
             sin = {kind: cs[1] for kind, cs in ropes.items()}
     layer_params = params["layers"]
 
+    # Blocks whose FFN kinds differ (a tree per kind) stack their K/V in
+    # layer order like any whole-block model, and give no aux with them:
+    # a dense block has none, and inference asks for no balancing loss.
     if decode:
         def body(kind, h, xs):
             lp, (kc, vc) = xs
@@ -864,19 +929,21 @@ def forward(
                 cfg, h, lp, cos, sin, None, None, (kc, vc),
                 cache_write_index, kv_valid, attn_impl, kind=kind,
             )
-            return h2, ((kc2, vc2), aux)
+            return h2, ((kc2, vc2), None if cfg.is_hybrid else aux)
 
+        cache = (kv_cache["k"], kv_cache["v"])
         with jax.named_scope("layer_scan"):
             h, ((ks, vs), aux) = _scan_layers(
-                cfg, body, h, (layer_params, (kv_cache["k"], kv_cache["v"]))
-            )
+                cfg, body, h,
+                _zip_by_kind(cfg, layer_params, cache) if cfg.is_hybrid
+                else (layer_params, cache))
     elif return_kv:
         def body(kind, h, lp):
             h2, kv, aux = _block(
                 cfg, h, lp, cos, sin, segment_ids, positions,
                 None, None, None, attn_impl, kind=kind,
             )
-            return h2, (kv, aux)
+            return h2, (kv, None if cfg.is_hybrid else aux)
 
         with jax.named_scope("layer_scan"):
             h, ((ks, vs), aux) = _scan_layers(cfg, body, h, layer_params,
@@ -943,6 +1010,21 @@ def forward(
     return out, kv_out
 
 
+def _zip_by_kind(cfg: TransformerConfig, layer_params: Params, per_layer):
+    """``{kind: (that kind's stack, its layers' slices of per_layer)}``:
+    the scan's ``xs`` where something stacked ``[n_layers, ...]`` in
+    layer order (the K/V cache) rides beside a tree per kind."""
+    out = {}
+    for kind, lp in layer_params.items():
+        idx = [i for i, k in enumerate(cfg.layer_kinds) if k == kind]
+        if idx == list(range(idx[0], idx[-1] + 1)):
+            idx = slice(idx[0], idx[-1] + 1)
+        else:
+            idx = jnp.asarray(idx)
+        out[kind] = (lp, jax.tree.map(lambda a: a[idx], per_layer))
+    return out
+
+
 def kv_valid_by_kind(cfg: TransformerConfig, valid: jnp.ndarray,
                      distance: jnp.ndarray):
     """The cache slots each attention kind of the model may read: ``valid``
@@ -953,7 +1035,7 @@ def kv_valid_by_kind(cfg: TransformerConfig, valid: jnp.ndarray,
     by_kind = {
         kind: valid if cfg.window_of(kind) is None
         else valid & (distance < cfg.window_of(kind))
-        for kind in dict.fromkeys(cfg.period_kinds)
+        for kind in dict.fromkeys(map(attention_kind, cfg.period_kinds))
     }
     if len(by_kind) == 1:
         (valid,) = by_kind.values()
@@ -976,7 +1058,7 @@ def apply_head(params: Params, cfg: TransformerConfig, h, lg="logits"):
 def init_kv_cache(
     cfg: TransformerConfig, batch: int, length: int, dtype=jnp.float32
 ) -> Dict[str, jnp.ndarray]:
-    if cfg.is_hybrid:
+    if cfg.has_mixer_layers:
         raise NotImplementedError(DECODE_REFUSAL)
     shape = (cfg.n_layers, batch, length, cfg.n_kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -989,6 +1071,9 @@ def _mixer_param_counts(cfg: TransformerConfig) -> Dict[str, int]:
     d = cfg.hidden_dim
     counts = {ATTENTION_ONLY:
               d * (cfg.q_dim + 2 * cfg.kv_dim) + cfg.q_dim * d + d}
+    for kind in cfg.layer_kinds:
+        if kind not in MIXER_KINDS:
+            counts[kind] = _block_param_count(cfg, has_dense_ffn(kind))
     if cfg.ssm is not None:
         ssm = cfg.ssm
         counts[MAMBA] = (
@@ -1000,24 +1085,37 @@ def _mixer_param_counts(cfg: TransformerConfig) -> Dict[str, int]:
     return counts
 
 
+def _block_param_count(cfg: TransformerConfig, dense_ffn: bool) -> int:
+    """Parameters of one whole block (the biases of the qwen2 / gpt2
+    families are not counted), whose FFN is the expert layer where the
+    model has one, unless ``dense_ffn``."""
+    from areal_tpu.models import moe as moemod
+
+    d, f = cfg.hidden_dim, cfg.intermediate_dim
+    attn = d * (cfg.q_dim + 2 * cfg.kv_dim) + cfg.q_dim * d
+    if cfg.gated_attention:
+        attn += d * cfg.q_dim
+    norms = (4 if cfg.sandwich_norm else 2) * d
+    if cfg.use_qk_norm:
+        norms += cfg.q_norm_dim + cfg.k_norm_dim
+    if cfg.moe is not None and not dense_ffn:
+        mlp = sum(math.prod(shape) for shape in
+                  moemod.moe_param_shapes(cfg).values())
+    elif cfg.mlp_type == "plain":
+        mlp = 2 * d * f
+    else:
+        mlp = 3 * d * f
+    return attn + mlp + norms
+
+
 def param_count(cfg: TransformerConfig) -> int:
-    n, d, f, v = cfg.n_layers, cfg.hidden_dim, cfg.intermediate_dim, cfg.vocab_size
+    n, d, v = cfg.n_layers, cfg.hidden_dim, cfg.vocab_size
     if cfg.is_hybrid:
         counts = _mixer_param_counts(cfg)
         head = 0 if cfg.tie_word_embeddings else d * v
         return v * d + d + head + sum(
             counts[kind] for kind in cfg.layer_kinds)
-    attn = d * (cfg.q_dim + 2 * cfg.kv_dim) + cfg.q_dim * d
-    if cfg.moe is not None:
-        fr = cfg.moe.routed_intermediate_dim or f
-        mlp = cfg.moe.num_experts * 3 * d * fr + d * cfg.moe.n_routed
-        if cfg.moe.shared_intermediate_dim:
-            mlp += 3 * d * cfg.moe.shared_intermediate_dim
-    elif cfg.mlp_type == "plain":
-        mlp = 2 * d * f
-    else:
-        mlp = 3 * d * f
-    per_layer = attn + mlp + 2 * d
+    per_layer = _block_param_count(cfg, dense_ffn=False)
     head = d * v if not (cfg.tie_word_embeddings or cfg.is_critic) else 0
     pos = (
         cfg.max_position_embeddings * d
@@ -1037,7 +1135,7 @@ def activated_param_count(cfg: TransformerConfig) -> int:
     n, d, f = cfg.n_layers, cfg.hidden_dim, cfg.intermediate_dim
     fr = cfg.moe.routed_intermediate_dim or f
     if cfg.is_hybrid:  # the expert layers only, at the width they work in
-        n, d = cfg.n_layers_of(MOE_ONLY), cfg.moe.latent_dim or d
+        n, d = cfg.n_expert_layers, cfg.moe.latent_dim or d
     one = (3 if cfg.moe.gated_experts else 2) * d * fr
     total_mlp = cfg.moe.num_experts * one
     # on a share, the part of a token's top_k that is held here on average

@@ -54,7 +54,7 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.models.config import MAMBA, SLIDING
+from areal_tpu.models.config import MAMBA
 from areal_tpu.parallel.mesh import DATA_AXES
 
 _NEG_INF = -1e30
@@ -359,8 +359,8 @@ def ring_refusal(cfg, kind: Optional[str] = None) -> Optional[str]:
     the scan cannot take."""
     if MAMBA in cfg.layer_kinds:
         return "state_space_scan"
-    windowed = (SLIDING in cfg.layer_kinds if kind is None
-                else cfg.window_of(kind) is not None)
+    kinds = cfg.layer_kinds if kind is None else (kind,)
+    windowed = any(cfg.window_of(k) is not None for k in kinds)
     return "sliding_window" if windowed else None
 
 

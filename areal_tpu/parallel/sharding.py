@@ -51,50 +51,7 @@ def param_partition_specs(cfg: TransformerConfig) -> Params:
     zero = ("fsdp", "ep")
     if cfg.is_hybrid:
         return _hybrid_partition_specs(cfg, zero)
-    layers: Params = {
-        "ln1": P("pp", None),
-        "ln2": P("pp", None),
-        "wq": P("pp", zero, "tp"),
-        "wk": P("pp", zero, "tp"),
-        "wv": P("pp", zero, "tp"),
-        "wo": P("pp", "tp", zero),
-        "w_gate": P("pp", zero, "tp"),
-        "w_up": P("pp", zero, "tp"),
-        "w_down": P("pp", "tp", zero),
-    }
-    if cfg.use_attention_bias:
-        layers["bq"] = P("pp", "tp")
-        layers["bk"] = P("pp", "tp")
-        layers["bv"] = P("pp", "tp")
-    if cfg.use_attn_output_bias:
-        layers["bo"] = P("pp", None)
-    if cfg.use_qk_norm:
-        layers["q_norm"] = P("pp", None)
-        layers["k_norm"] = P("pp", None)
-    if cfg.norm_type == "layer":
-        layers["ln1_b"] = P("pp", None)
-        layers["ln2_b"] = P("pp", None)
-    if cfg.mlp_type == "plain" and cfg.moe is None:
-        layers["b_up"] = P("pp", "tp")
-        layers["b_down"] = P("pp", None)
-        for k in ("w_gate",):
-            layers.pop(k, None)
-    if cfg.moe is not None:
-        # Experts stack on a leading axis [n, E, ...]; shard E over the
-        # REAL "ep" axis (expert parallelism — each ep shard owns E/ep
-        # experts, moe.py gathers the tokens for them), the ffn dim on tp,
-        # and ZeRO-3 the remaining matrix dim over fsdp.
-        layers["router"] = P("pp", None, None)
-        layers["e_gate"] = P("pp", "ep", "fsdp", "tp")
-        layers["e_up"] = P("pp", "ep", "fsdp", "tp")
-        layers["e_down"] = P("pp", "ep", "tp", "fsdp")
-        if cfg.moe.shared_intermediate_dim:
-            layers["s_gate"] = P("pp", None, "tp")
-            layers["s_up"] = P("pp", None, "tp")
-            layers["s_down"] = P("pp", "tp", None)
-        # Dense-MLP weights are absent in MoE layers.
-        for k in ("w_gate", "w_up", "w_down"):
-            del layers[k]
+    layers = _block_partition_specs(cfg, zero, "pp", dense_ffn=False)
 
     specs: Params = {
         "embedding": P("tp", zero),
@@ -112,6 +69,65 @@ def param_partition_specs(cfg: TransformerConfig) -> Params:
     return specs
 
 
+def _block_partition_specs(cfg: TransformerConfig, zero, lead,
+                           dense_ffn: bool) -> Params:
+    """The specs of whole blocks stacked on a leading axis split over
+    ``lead`` ("pp", or None: a kind's stack of a tree per kind); the FFN
+    is the expert layer where the model has one, unless ``dense_ffn``."""
+    layers: Params = {
+        "ln1": P(lead, None),
+        "ln2": P(lead, None),
+        "wq": P(lead, zero, "tp"),
+        "wk": P(lead, zero, "tp"),
+        "wv": P(lead, zero, "tp"),
+        "wo": P(lead, "tp", zero),
+        "w_gate": P(lead, zero, "tp"),
+        "w_up": P(lead, zero, "tp"),
+        "w_down": P(lead, "tp", zero),
+    }
+    if cfg.use_attention_bias:
+        layers["bq"] = P(lead, "tp")
+        layers["bk"] = P(lead, "tp")
+        layers["bv"] = P(lead, "tp")
+    if cfg.use_attn_output_bias:
+        layers["bo"] = P(lead, None)
+    if cfg.use_qk_norm:
+        layers["q_norm"] = P(lead, None)
+        layers["k_norm"] = P(lead, None)
+    if cfg.norm_type == "layer":
+        layers["ln1_b"] = P(lead, None)
+        layers["ln2_b"] = P(lead, None)
+    if cfg.gated_attention:
+        layers["wg"] = P(lead, zero, "tp")
+    if cfg.sandwich_norm:
+        layers["ln1_post"] = P(lead, None)
+        layers["ln2_post"] = P(lead, None)
+    if cfg.mlp_type == "plain" and cfg.moe is None:
+        layers["b_up"] = P(lead, "tp")
+        layers["b_down"] = P(lead, None)
+        for k in ("w_gate",):
+            layers.pop(k, None)
+    if cfg.moe is not None and not dense_ffn:
+        # Experts stack on a leading axis [n, E, ...]; shard E over the
+        # REAL "ep" axis (expert parallelism — each ep shard owns E/ep
+        # experts, moe.py gathers the tokens for them), the ffn dim on tp,
+        # and ZeRO-3 the remaining matrix dim over fsdp.
+        layers["router"] = P(lead, None, None)
+        if cfg.moe.router_score == "sigmoid":
+            layers["router_bias"] = P(lead, None)
+        layers["e_gate"] = P(lead, "ep", "fsdp", "tp")
+        layers["e_up"] = P(lead, "ep", "fsdp", "tp")
+        layers["e_down"] = P(lead, "ep", "tp", "fsdp")
+        if cfg.moe.shared_intermediate_dim:
+            layers["s_gate"] = P(lead, None, "tp")
+            layers["s_up"] = P(lead, None, "tp")
+            layers["s_down"] = P(lead, "tp", None)
+        # Dense-MLP weights are absent in MoE layers.
+        for k in ("w_gate", "w_up", "w_down"):
+            del layers[k]
+    return layers
+
+
 def _hybrid_partition_specs(cfg: TransformerConfig, zero) -> Params:
     """The spec tree of a model whose layers are one mixer each
     (``params["layers"]`` a tree per kind): the matrices ZeRO-3 over
@@ -120,10 +136,14 @@ def _hybrid_partition_specs(cfg: TransformerConfig, zero) -> Params:
     split over "pp" (such a model is not pipelined: stages of unequal
     cost) and the held experts not over "ep" (latent, ungated experts run
     as a share, models/moe.py)."""
-    from areal_tpu.models.config import ATTENTION_ONLY, MAMBA, MOE_ONLY
+    from areal_tpu.models.config import (
+        ATTENTION_ONLY, MAMBA, MIXER_KINDS, MOE_ONLY, has_dense_ffn)
     from areal_tpu.models.moe import moe_param_shapes
 
-    layers: Params = {}
+    # whole blocks whose FFN kinds differ (afmoe): a stack a kind
+    layers: Params = {
+        kind: _block_partition_specs(cfg, zero, None, has_dense_ffn(kind))
+        for kind in dict.fromkeys(cfg.layer_kinds) if kind not in MIXER_KINDS}
     if cfg.n_layers_of(MAMBA):
         layers[MAMBA] = {
             "ln": P(None, None),

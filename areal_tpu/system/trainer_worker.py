@@ -362,6 +362,13 @@ class TrainerWorker:
             # pass on a TPU; its tile is moe.gemm_tiling of the shape)
             moe_gemm={"%dx%dx%d/%d" % key: how
                       for key, how in moe.gemm_counts().items()},
+            # {model: {"<attention or mixer kind>/<dense | experts | ->":
+            # layers}}: the model's blocks by what they are made of
+            blocks={
+                role: m.module.cfg.block_counts()
+                for role, m in self.models.items()
+                if hasattr(getattr(m.module, "cfg", None), "block_counts")
+            },
             # {model: {"RxL": {entry, kept_bytes_estimate, budget_bytes,
             # fell_back}}}: what each grid's backward pass re-runs
             remat_plan={

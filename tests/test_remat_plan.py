@@ -649,6 +649,68 @@ def test_plan_of_mixer_layers_reads_its_costs_off_the_config():
         eng._remat_kept_bytes(2, 640)[eng._remat_for(2, 640)])
 
 
+# ---- (g) whole blocks whose FFN kinds differ: a tree per kind ----
+
+AFMOE_WIDTHS = dict(
+    vocab_size=512, n_layers=10, hidden_dim=256, n_q_heads=4, n_kv_heads=2,
+    head_dim=128, intermediate_dim=384, sliding_window=256,
+    layer_types=("sliding", "sliding", "sliding", "sliding", "full") * 2,
+    mlp_layer_types=("dense", "sparse", "sparse", "sparse", "sparse") * 2,
+    layer_rope=(("full", None),), use_qk_norm=True, gated_attention=True,
+    sandwich_norm=True, scale_embeddings=True,
+    moe=MoEConfig(num_experts=2, top_k=4, capacity_factor=None,
+                  routed_intermediate_dim=128, router_experts=16,
+                  shared_intermediate_dim=128, router_score="sigmoid",
+                  routed_scaling_factor=2.826, aux_loss_coeff=0.0))
+
+
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_estimate_matches_what_jax_keeps_of_blocks_that_differ_in_ffn(entry):
+    """Two periods of (S·dense, S, S, S, F): every block keeps its input;
+    the sliding ones the windowed kernel's output and statistic, the full
+    ones the flash kernel's; under ``matmuls`` q/k/v, the attention gate
+    and o_proj of every block, gate and up of the dense FFN, on an expert
+    block the router's logits and the shared expert's pair, and the
+    FFN's last matmul, which the sandwich norm behind it reads."""
+    from areal_tpu.ops.attention import kernel_padded_len
+
+    cfg = dataclasses.replace(tiny_config(), **AFMOE_WIDTHS)
+    assert cfg.layer_kinds[:5] == ("sliding_dense", "sliding", "sliding",
+                                   "sliding", "full")
+    rows, length = 2, 640
+    flash = kernel_padded_len("pallas", length)
+    window = kernel_padded_len("pallas", length, cfg.sliding_window)
+    est = transformer.remat_kept_bytes(
+        cfg, rows * length, 2, flash_tokens=rows * flash,
+        window_tokens=rows * window)
+    tok = rows * length
+    assert est["full"] == 10 * tok * 256 * 2
+    assert est["attention"] - est["full"] == 2 * rows * 768 * 4 * (
+        4 * (128 * 2 + 4) + (128 * 2 + 8))
+    # q, k and v, o_proj, the gate, and the FFN's last matmul (dense: down;
+    # experts: the shared expert's), which its post-norm reads
+    attn = 512 + 2 * 256 + 256 + 512 + 256
+    assert est["matmuls"] - est["attention"] == 2 * tok * 2 * (
+        5 * attn + 2 * 384 + 4 * (16 + 2 * 128))
+    # the scan runs over the two PERIODS
+    periods = dataclasses.replace(cfg, n_layers=2, layer_types=None,
+                                  mlp_layer_types=None)
+    got = _saved_bytes(periods, rows, length, entry, "pallas", cfg_run=cfg)
+    assert got == pytest.approx(est[entry], rel=0.06)
+
+
+def test_plan_of_blocks_that_differ_in_ffn_reads_its_costs_off_the_config():
+    eng = _engine(dataclasses.replace(tiny_config(), **AFMOE_WIDTHS))
+    assert eng._layer_kinds == "sliding_dense,sliding,sliding,sliding,full"
+    # the costliest half of a block: the dense FFN's width, an expert
+    # layer's top_k rows at the hidden width, the shared expert, q
+    assert eng._mixer_layer_width() == max(
+        384 * jax_train._LAYER_COPIES,
+        4 * 256 * jax_train._MOE_LOCAL_LAYER_COPIES,
+        128 * jax_train._LAYER_COPIES, 512 * jax_train._LAYER_COPIES)
+    assert eng._remat_for(2, 640) in ENTRIES
+
+
 def test_fwd_bwd_span_counts_the_documents_that_begin_inside_a_row():
     from areal_tpu.api.train_config import TelemetryConfig
     from areal_tpu.base import telemetry

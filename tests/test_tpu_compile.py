@@ -36,6 +36,9 @@ KERNEL_T = {512: 2, 2048: 2, 4096: 2, 6016: 1}
 # of 128, window 1024): the benchmark's rows of 6016 (padded to 6144) and
 # 6656 tokens, and the longest row a micro-batch can be.
 WINDOW_T = {6016: 1, 6656: 1, 8192: 1}
+# rows of the Trinity-Mini cell (padded 9216, 11776) and the longest a
+# 16,384-token micro-batch can hold, at ITS window
+WINDOW_2K_T = {8832: 1, 11776: 1, 16384: 1}
 # f2 is the async trainer's mesh in chip_smoke.py --chips 4; p2t2 nests the
 # kernel's shard_map inside the pipeline stages' manual-pp region.
 MESH_SPECS = ("f2", "p2t2")
@@ -130,12 +133,13 @@ def _compile_all():
         out[f"kernel-{T}"]["blocks"] = fa.pick_block_sizes(T, T)
 
     # The windowed kernel, forward AND backward, K/V at their 4 heads.
-    for T, rows in WINDOW_T.items():
+    for T, rows, window in [(T, r, 1024) for T, r in WINDOW_T.items()] + [
+            (T, r, 2048) for T, r in WINDOW_2K_T.items()]:
         def spec(*shape, dtype=jnp.bfloat16):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-        def loss(q, k, v, seg):
-            o = wa.window_attention(q, k, v, seg, seg, window=1024)
+        def loss(q, k, v, seg, window=window):
+            o = wa.window_attention(q, k, v, seg, seg, window=window)
             return jnp.sum(o.astype(jnp.float32) ** 2)
 
         compiled = jax.jit(
@@ -144,11 +148,12 @@ def _compile_all():
             spec(rows, T, 32, 128), spec(rows, T, 4, 128),
             spec(rows, T, 4, 128), spec(rows, T, dtype=jnp.int32),
         ).compile()
-        record(f"window-{T}", compiled)
-        out[f"window-{T}"]["splash_kernels"] = sorted(
+        name = f"window-{T}" if window == 1024 else f"window{window}-{T}"
+        record(name, compiled)
+        out[name]["splash_kernels"] = sorted(
             {k for k in ("splash_mqa_fwd", "splash_mqa_dkv", "splash_mqa_dq")
              if k in compiled.as_text()})
-        out[f"window-{T}"]["tile"] = wa.pick_tile(T, 1024)
+        out[name]["tile"] = wa.pick_tile(T, window)
 
     # A small model through transformer.forward on multi-chip meshes.
     cfg = tiny_config(vocab_size=1024, n_layers=4, hidden_dim=256,
@@ -362,6 +367,18 @@ def test_window_attention_compiles_for_v5e(compiled, T):
     """The windowed kernel's forward, dKV and dQ at the published heads
     and window, at the measured tile, inside the VMEM/HBM limits."""
     got = compiled[f"window-{T}"]
+    assert got["tile"] == 512
+    assert got["splash_kernels"] == ["splash_mqa_dkv", "splash_mqa_dq",
+                                     "splash_mqa_fwd"]
+    assert got["temp_bytes"] < 2 << 30
+
+
+@pytest.mark.parametrize("T", WINDOW_2K_T)
+def test_window_attention_at_window_2048_compiles_for_v5e(compiled, T):
+    """The same kernel at window 2048 in rows up to 16,384 tokens: the
+    tile the rule picks (512: the sweep at that window, PERF.md §5, PR 39)
+    fits the chip's fast memory, forward and backward."""
+    got = compiled[f"window2048-{T}"]
     assert got["tile"] == 512
     assert got["splash_kernels"] == ["splash_mqa_dkv", "splash_mqa_dq",
                                      "splash_mqa_fwd"]
