@@ -18,8 +18,10 @@ Engine). TPU-first differences:
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import os
+import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -271,6 +273,17 @@ _MOE_LOCAL_LAYER_COPIES = 7
 _LATENT_MOE_LAYER_COPIES = 11
 
 
+# Bytes of inference results that may wait on the device for their fetch
+# (JaxTrainEngine.forward). A post-hooked pass returns [R, L] float32 a
+# micro-batch, 0.1-0.2 MB a whole step: every micro-batch is in flight and
+# the device never waits for the host. A pass without a hook returns
+# logits ([1, 4096, 151936] = 1.2 GB in bfloat16, 2.5 GB in float32): ONE
+# such result is over the bound, so it is fetched before the next
+# dispatch. Three orders above the first case, most of one below the
+# second.
+_INFLIGHT_RESULT_BYTES = 256 << 20
+
+
 def _bytes_on_chip(tree) -> int:
     """Bytes of a tree's arrays on one chip: each leaf's shard."""
     return sum(
@@ -379,6 +392,12 @@ class JaxTrainEngine(TrainableEngine):
         self._compute_shared = False
         self._cast_fn = None
         self.param_cast_rebuilds = 0
+        # forward()'s micro-batches over the engine's life, and those of
+        # them dispatched while an earlier one's result was unfetched;
+        # under a lock: several threads call forward() (algorithms/fused).
+        self._infer_lock = threading.Lock()
+        self.infer_mbs = 0
+        self.infer_mbs_run_ahead = 0
         self.params = params
         self.opt_cfg = opt_cfg
         self.tx = None
@@ -1325,25 +1344,67 @@ class JaxTrainEngine(TrainableEngine):
                 "train/forward", jax.jit(infer_forward)
             )
         fn = self._fwd_fns[key]
-        outs = []
-        # Upload, dispatch and fetch are a span each: they are the places
-        # where the host can make the device wait.
-        for mb in mbs:
-            with telemetry.span("infer/upload",
-                                **_pack_attrs([mb], len(mbs))):
-                db = {k: jnp.asarray(v) for k, v in (
-                    *mb.grids.items(), *mb.scalars.items(),
-                    ("seq_rows", mb.seq_rows),
-                    ("seq_first_cols", mb.seq_first_cols),
-                    ("seq_last_cols", mb.seq_last_cols),
-                    ("seq_mask", mb.seq_mask))}
-            with telemetry.span("infer/dispatch"), self._mesh_ctx(), \
-                    dispatch_label("forward"):
-                out = fn(self.compute_params(), db)
-            with telemetry.span("infer/fetch"):
+        outs: List[np.ndarray] = []
+        # Dispatched and not yet fetched, oldest first. A local of the
+        # call: several threads run this method at once (algorithms/fused).
+        pending: collections.deque = collections.deque()
+        run_ahead = peak = 0
+
+        def fetch_oldest():
+            out = pending.popleft()
+            # the call's last fetch says how far the call ran ahead
+            last = len(outs) + 1 == len(mbs)
+            with telemetry.span("infer/fetch",
+                                **({"run_ahead": run_ahead} if last else {})):
                 outs.append(np.asarray(out))
+
+        # Upload, dispatch and fetch are a span each: they are the places
+        # where the host can make the device wait. The host runs ahead of
+        # the device's results: a micro-batch is uploaded and dispatched
+        # while the ones before it still run, so the device's queue is
+        # empty under the call's first upload only.
+        try:
+            for mb in mbs:
+                with telemetry.span("infer/upload",
+                                    **_pack_attrs([mb], len(mbs))):
+                    db = jax.device_put({
+                        **mb.grids, **mb.scalars,
+                        "seq_rows": mb.seq_rows,
+                        "seq_first_cols": mb.seq_first_cols,
+                        "seq_last_cols": mb.seq_last_cols,
+                        "seq_mask": mb.seq_mask})
+                run_ahead += bool(pending)
+                with telemetry.span("infer/dispatch"), self._mesh_ctx(), \
+                        dispatch_label("forward"):
+                    out = fn(self.compute_params(), db)
+                out.copy_to_host_async()
+                pending.append(out)
+                peak = max(peak, len(pending))
+                # nbytes is the aval's: no sync
+                while sum(o.nbytes for o in pending) > _INFLIGHT_RESULT_BYTES:
+                    fetch_oldest()
+            while pending:
+                fetch_oldest()
+        finally:
+            pending.clear()  # a dispatch that raised: its results go with it
+        telemetry.inc("infer/mbs", len(mbs))
+        telemetry.inc("infer/mbs_run_ahead", run_ahead)
+        telemetry.set_gauge("infer/inflight_peak", peak)
+        with self._infer_lock:
+            self.infer_mbs += len(mbs)
+            self.infer_mbs_run_ahead += run_ahead
         with telemetry.span("infer/scatter_back"):
             return mbu.scatter_back(mbs, outs, input_.bs)
+
+    def infer_run_ahead(self) -> Optional[float]:
+        """Share of forward()'s micro-batches that were dispatched while
+        an earlier one's result was still unfetched: (n - 1) / n of a
+        hooked pass of n micro-batches, 0 of a pass that returns logits
+        (in the trainer worker's ``device_report``). None before the
+        first call."""
+        with self._infer_lock:
+            n, m = self.infer_mbs, self.infer_mbs_run_ahead
+        return m / n if n else None
 
     def generate(
         self,

@@ -382,6 +382,15 @@ class TrainerWorker:
                 for role, m in self.models.items()
                 if hasattr(m.module, "param_cast_rebuilds")
             },
+            # {model: share of forward()'s micro-batches dispatched while
+            # an earlier one's result was unfetched}: (n - 1) / n where
+            # the inference pass keeps the device fed, 0 where each result
+            # is fetched before the next dispatch (logits)
+            infer_run_ahead={
+                role: m.module.infer_run_ahead()
+                for role, m in self.models.items()
+                if hasattr(m.module, "infer_run_ahead")
+            },
             # the compile ledger less its ring of spans (a log line: the
             # per-program table says what start-up cost; /metrics.json
             # and telemetry.jsonl carry the spans)
