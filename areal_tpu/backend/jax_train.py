@@ -791,8 +791,14 @@ class JaxTrainEngine(TrainableEngine):
         expert's width, a Mamba-2 mixer's in-projection or its scan's
         [heads, chunk] decays (float32: two elements), attention's q."""
         cfg, widths = self.cfg, [self.cfg.q_dim * _LAYER_COPIES]
-        if any(has_dense_ffn(k) for k in cfg.layer_kinds):
+        if cfg.moe is None or any(has_dense_ffn(k) for k in cfg.layer_kinds):
             widths.append(cfg.intermediate_dim * _LAYER_COPIES)
+        if cfg.s6 is not None:
+            # an S6 mixer's in-projection; its scan's inputs and their
+            # gradients in float32 (x, Δ, y and the three back: twelve
+            # compute-dtype elements a channel)
+            widths.append(max(2 * cfg.s6.d_inner * _LAYER_COPIES,
+                              12 * cfg.s6.d_inner))
         if cfg.moe is not None:
             widths += [
                 cfg.moe.top_k * (
@@ -1044,7 +1050,7 @@ class JaxTrainEngine(TrainableEngine):
                 ),
             )
         span_attrs = {}
-        if self.cfg.ssm is not None:
+        if self.cfg.ssm is not None or self.cfg.s6 is not None:
             # documents that begin inside a row: where the scans reset
             starts = sum(col > 0 for i in idxs
                          for _, col in ub.mbs[i].layout.placements)

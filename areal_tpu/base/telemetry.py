@@ -282,6 +282,17 @@ SANDWICH_SCOPES = ("attn_gate", "post_attn_norm", "post_mlp_norm")
 # DEVICE_SCOPES name lies between them and "layer_scan".
 SSM_SCOPES = ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",
               "ssm_out_proj")
+# A decoder-hybrid-decoder model's own blocks (phi4flash; models/ssm.py,
+# models/transformer.py): the S6 mixer a stage (the scan's kernels under
+# "s6_scan"), a gated memory unit, everything of a cross-attention layer
+# (its q and o projections and the flash kernel over another layer's
+# K/V), and differential attention's lambda-combine with its sub-norm
+# (behind "attention" or "cross_attention"). No DEVICE_SCOPES name lies
+# between them and "layer_scan", but "window_attention" and the flash
+# kernel's ops inside "cross_attention".
+SAMBAY_SCOPES = ("s6_in_proj", "s6_conv", "s6_xdt_proj", "s6_scan",
+                 "s6_out_proj", "gmu", "cross_attention",
+                 "diff_attn_combine")
 # Inside "moe", beside MOE_SCOPES: the two projections around experts that
 # work in a latent width, and the shared expert (models/moe.py).
 LATENT_MOE_SCOPES = ("latent_down", "latent_up", "shared_expert")

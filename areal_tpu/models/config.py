@@ -20,6 +20,16 @@ from typing import Dict, Optional, Tuple
 FULL, SLIDING = "full", "sliding"
 MAMBA, MOE_ONLY, ATTENTION_ONLY = "mamba", "moe_only", "attention_only"
 MIXER_KINDS = (MAMBA, MOE_ONLY, ATTENTION_ONLY)
+# Whole blocks (a mixer, then the dense MLP) of a decoder-hybrid-decoder
+# model (phi4flash / SambaY), beside FULL and SLIDING: S6 a selective-scan
+# (Mamba-1) mixer; GMU a gated memory unit, which has no scan of its own
+# and gates the scan output ``m`` that ONE earlier S6 layer made; CROSS
+# attention with a query projection alone, over the K/V that ONE earlier
+# FULL layer made (``TransformerConfig.memory_source`` / ``kv_source``).
+S6, GMU, CROSS = "s6", "gmu", "cross"
+SAMBAY_KINDS = (S6, GMU, CROSS)
+# What a layer hands on to later layers, by the name the readers ask for.
+MEMORY, SHARED_KV = "memory", "kv"
 # A whole block's FFN kind (HF ``mlp_layer_types``): the model's dense MLP
 # or its expert layer. A block has an attention kind AND an FFN kind; the
 # kind of a block whose FFN is the dense MLP of a model that has experts
@@ -131,6 +141,29 @@ class SSMConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class S6Config:
+    """A Mamba-1 (selective scan, S6) mixer's sizes: ``d_inner`` channels,
+    each with ``state_dim`` states whose decay is its own (``A`` is
+    ``[d_inner, state_dim]``: nothing here is a head or a group, and no
+    chunk size is the model's), a depthwise convolution of ``conv_kernel``
+    taps, and Δ through a bottleneck of ``dt_rank``; the ranges its init
+    draws Δ's bias from as Mamba-1's defaults."""
+
+    d_inner: int
+    state_dim: int = 16
+    conv_kernel: int = 4
+    dt_rank: int = 0
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+
+    @property
+    def x_proj_dim(self) -> int:
+        """[δ | B | C]."""
+        return self.dt_rank + 2 * self.state_dim
+
+
+@dataclasses.dataclass(frozen=True)
 class RopeConfig:
     """One RoPE table: plain (``factor`` None) or YaRN as ``transformers``
     computes it (``_compute_yarn_parameters``)."""
@@ -175,6 +208,7 @@ class TransformerConfig:
     is_critic: bool = False  # scalar head instead of lm head
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None  # the MAMBA layers' mixer
+    s6: Optional[S6Config] = None  # the S6 blocks' mixer (GMU reads its width)
     # sliding window attention (mistral/gemma2); None = full attention
     sliding_window: Optional[int] = None
     # The kind of each layer: FULL or SLIDING (HF ``layer_types``), or one
@@ -193,6 +227,17 @@ class TransformerConfig:
     # Gated attention (afmoe): a fifth projection ``wg`` [D, q_dim]; the
     # attention output is multiplied by sigmoid(x wg) before ``wo``.
     gated_attention: bool = False
+    # Differential attention (phi4flash): q's heads are pairs (2p, 2p+1),
+    # k's likewise, v's pairs one value of twice the head size; a pair's
+    # output is softmax(q1 k1) v - lambda * softmax(q2 k2) v, RMS-normed
+    # over the value's width (``subln``) and scaled by 1 - lambda_init,
+    # with lambda_init = 0.8 - 0.6 exp(-0.3 l) at the PUBLISHED layer
+    # index l = ``first_layer_index`` + the layer's index here, and lambda
+    # from four learned vectors of head_dim a layer.
+    differential_attention: bool = False
+    # The published index of this model's layer 0 (a cut in depth that
+    # starts inside the published stack).
+    first_layer_index: int = 0
     # Sandwich norms (afmoe): a second norm on each branch's OUTPUT before
     # it is added to the residual stream (``ln1_post``, ``ln2_post``).
     sandwich_norm: bool = False
@@ -261,6 +306,8 @@ class TransformerConfig:
         runs over periods (models/transformer.py); a model whose layers
         are alike has a period of one layer."""
         kinds = self.layer_kinds
+        if self.cross_layer_reads:  # a source is ONE layer of the pattern
+            return kinds
         return next(
             kinds[:p] for p in range(1, len(kinds) + 1)
             if len(kinds) % p == 0 and kinds[:p] * (len(kinds) // p) == kinds)
@@ -271,13 +318,58 @@ class TransformerConfig:
         return any(k in MIXER_KINDS for k in self.layer_kinds)
 
     @property
+    def has_cacheless_layers(self) -> bool:
+        """Layers no K/V cache can decode: a mixer alone, a selective
+        scan, or a layer that reads what another layer made."""
+        return self.has_mixer_layers or any(
+            k in SAMBAY_KINDS for k in self.layer_kinds)
+
+    @property
     def is_hybrid(self) -> bool:
         """Layers whose parameter SHAPES differ by kind — one mixer alone,
         or whole blocks some of which run a dense MLP and some the
         experts: ``params["layers"]`` is then a tree per KIND, each
         stacked over that kind's layers."""
-        return any(k in MIXER_KINDS or has_dense_ffn(k)
+        return any(k in MIXER_KINDS or k in SAMBAY_KINDS or has_dense_ffn(k)
                    for k in self.layer_kinds)
+
+    @property
+    def memory_source(self) -> Optional[int]:
+        """The layer whose scan output the GMU layers gate: the last S6
+        layer before the first GMU one. None where no layer reads it."""
+        kinds = self.layer_kinds
+        if GMU not in kinds:
+            return None
+        before = [i for i in range(kinds.index(GMU)) if kinds[i] == S6]
+        assert before, "a gated memory unit with no S6 layer before it"
+        return before[-1]
+
+    @property
+    def kv_source(self) -> Optional[int]:
+        """The layer whose K/V the CROSS layers attend over: the last
+        FULL layer before the first CROSS one. None where none reads it."""
+        kinds = self.layer_kinds
+        if CROSS not in kinds:
+            return None
+        before = [i for i in range(kinds.index(CROSS)) if kinds[i] == FULL]
+        assert before, "a cross-attention layer with no full one before it"
+        return before[-1]
+
+    @property
+    def cross_layer_reads(self) -> Dict[str, int]:
+        """{what is handed on: layers that read it} — empty for a model
+        whose layers read the residual stream alone."""
+        reads = {MEMORY: self.layer_kinds.count(GMU),
+                 SHARED_KV: self.layer_kinds.count(CROSS)}
+        return {name: n for name, n in reads.items() if n}
+
+    def handed_on_by(self, layer: int) -> Optional[str]:
+        """What layer ``layer`` hands on to later layers, or None."""
+        if layer == self.memory_source:
+            return MEMORY
+        if layer == self.kv_source:
+            return SHARED_KV
+        return None
 
     def n_layers_of(self, kind: str) -> int:
         return self.layer_kinds.count(kind)

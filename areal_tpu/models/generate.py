@@ -37,10 +37,11 @@ from areal_tpu.ops.sampling import (
 def decode_refusal(cfg: TransformerConfig) -> Optional[str]:
     """Why this model cannot be decoded here, by name
     (``transformer.DECODE_REFUSAL``), or None: a layer that is one mixer
-    alone — a state-space layer among them — has no cache to decode
-    from. Every entry point below prefills through ``forward``, which
-    raises it."""
-    return DECODE_REFUSAL if cfg.has_mixer_layers else None
+    alone — a state-space layer among them — a selective-scan block, or
+    a layer that reads another layer's memory or K/V has no cache to
+    decode from. Every entry point below prefills through ``forward``,
+    which raises it."""
+    return DECODE_REFUSAL if cfg.has_cacheless_layers else None
 
 
 @partial(

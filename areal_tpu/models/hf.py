@@ -27,14 +27,18 @@ import numpy as np
 from areal_tpu.base import logging
 from areal_tpu.models.config import (
     ATTENTION_ONLY,
+    CROSS,
     DENSE_FFN,
     FULL,
+    GMU,
     MAMBA,
     MOE_ONLY,
+    S6,
     SLIDING,
     SPARSE_FFN,
     MoEConfig,
     RopeConfig,
+    S6Config,
     SSMConfig,
     TransformerConfig,
     attention_kind,
@@ -434,6 +438,63 @@ def _afmoe_config(hf_config: Any) -> TransformerConfig:
     )
 
 
+# phi4flash: the letter of each layer kind in ``layer_pattern``.
+_SAMBAY_LETTERS = {"M": S6, "S": SLIDING, "F": FULL, "G": GMU, "X": CROSS}
+
+
+def sambay_pattern(n_layers: int, mb_per_layer: int) -> str:
+    """The published layer pattern of a decoder-hybrid-decoder model of
+    ``n_layers`` layers: a Mamba-1 block every ``mb_per_layer``-th layer
+    of the self-decoder (up to and with layer n/2) and a gated memory
+    unit in its place behind it; between them window attention, ONE full
+    attention at layer n/2 + 1, and cross attention behind that."""
+    half = n_layers // 2
+    return "".join(
+        ("M" if i <= half else "G") if i % mb_per_layer == 0
+        else "S" if i < half else "F" if i == half + 1 else "X"
+        for i in range(n_layers))
+
+
+@register_hf_family("phi4flash")
+def _phi4flash_config(hf_config: Any) -> TransformerConfig:
+    """Phi-4-mini-flash (``Phi4FlashForCausalLM``; SambaY): whole blocks
+    under LayerNorm with bias, no position embedding; the mixer by layer
+    (:func:`sambay_pattern`, or this repo's key ``layer_pattern`` for a
+    cut in depth that starts at the published layer ``first_layer_index``)
+    a Mamba-1 selective scan (``d_inner = 2 hidden``, state 16, conv 4,
+    ``dt_rank = ceil(hidden / 16)``: Mamba-1's defaults, which have no
+    key), window or full differential attention, a gated memory unit over
+    the last Mamba layer's scan output, or cross attention over the full
+    layer's K/V; q/k/v/o carry biases."""
+    kw = _base_kwargs(hf_config)
+    n, d = kw["n_layers"], kw["hidden_dim"]
+    # a config cut further in depth keeps the pattern's first layers
+    pattern = (getattr(hf_config, "layer_pattern", None) or sambay_pattern(
+        n, hf_config.mb_per_layer))[:n]
+    if len(pattern) != n or set(pattern) - set(_SAMBAY_LETTERS):
+        raise NotImplementedError(
+            f"layer_pattern {pattern!r} for {n} layers (letters "
+            f"{sorted(_SAMBAY_LETTERS)} are supported)")
+    kw["rms_norm_eps"] = getattr(hf_config, "layer_norm_eps", 1e-5)
+    return TransformerConfig(
+        **kw,
+        pos_embedding="none",
+        norm_type="layer",
+        hidden_act=getattr(hf_config, "hidden_act", "silu"),
+        use_attention_bias=True,
+        use_attn_output_bias=True,
+        differential_attention=True,
+        first_layer_index=int(getattr(hf_config, "first_layer_index", 0)),
+        sliding_window=hf_config.sliding_window,
+        layer_types=tuple(_SAMBAY_LETTERS[c] for c in pattern),
+        max_position_embeddings=getattr(
+            hf_config, "max_position_embeddings", None),
+        s6=S6Config(d_inner=2 * d, state_dim=16, conv_kernel=4,
+                    dt_rank=-(-d // 16)),
+        hf_family="phi4flash",
+    )
+
+
 def config_from_hf(hf_config: Any) -> TransformerConfig:
     """Build a TransformerConfig from a transformers PretrainedConfig."""
     mt = getattr(hf_config, "model_type", "llama")
@@ -828,6 +889,115 @@ def _afmoe_from_sd(
     }
 
 
+# phi4flash: (pytree key, HF name under ``model.layers.{i}.``, transpose).
+# A block holds the leaves of its kind; ``Wqkv`` ([q | k | v] rows, q
+# alone on a cross layer), the gated MLP's fused ``gate_up_proj`` and the
+# depthwise convolution ``[channels, 1, K]`` are split and joined below.
+_PHI4FLASH_NAMES = [
+    ("ln1", "input_layernorm.weight", False),
+    ("ln1_b", "input_layernorm.bias", False),
+    ("ln2", "post_attention_layernorm.weight", False),
+    ("ln2_b", "post_attention_layernorm.bias", False),
+    ("w_down", "mlp.down_proj.weight", True),
+    ("in_proj", "attn.in_proj.weight", True),
+    ("conv_b", "attn.conv1d.bias", False),
+    ("x_proj", "attn.x_proj.weight", True),
+    ("dt_proj", "attn.dt_proj.weight", True),
+    ("dt_bias", "attn.dt_proj.bias", False),
+    ("A_log", "attn.A_log", False),
+    ("D", "attn.D", False),
+    ("out_proj", "attn.out_proj.weight", True),
+    ("gmu_in", "attn.in_proj.weight", True),
+    ("gmu_out", "attn.out_proj.weight", True),
+    ("wo", "attn.out_proj.weight", True),
+    ("bo", "attn.out_proj.bias", False),
+    ("lambda_q1", "attn.inner_cross_attn.lambda_q1", False),
+    ("lambda_k1", "attn.inner_cross_attn.lambda_k1", False),
+    ("lambda_q2", "attn.inner_cross_attn.lambda_q2", False),
+    ("lambda_k2", "attn.inner_cross_attn.lambda_k2", False),
+    ("subln", "attn.inner_cross_attn.subln.weight", False),
+]
+
+
+def _phi4flash_to_sd(
+    params: Dict[str, Any], cfg: TransformerConfig
+) -> Dict[str, np.ndarray]:
+    sd = {
+        "model.embed_tokens.weight": np.asarray(params["embedding"]),
+        "model.final_layernorm.weight": np.asarray(params["final_ln"]),
+        "model.final_layernorm.bias": np.asarray(params["final_ln_b"]),
+    }
+    if not cfg.tie_word_embeddings:
+        sd["lm_head.weight"] = np.asarray(params["lm_head"]).T
+    for i, kind, lp in _layers_in_order(params, cfg):
+        pre = f"model.layers.{i}."
+        for key, name, tr in _PHI4FLASH_NAMES:
+            if key in lp:
+                sd[pre + name] = lp[key].T if tr else lp[key]
+        sd[pre + "mlp.gate_up_proj.weight"] = np.concatenate(
+            [lp["w_gate"].T, lp["w_up"].T])
+        if kind == S6:
+            sd[pre + "attn.conv1d.weight"] = lp["conv_w"].T[:, None, :]
+        if "wq" in lp:
+            qkv = [k for k in ("wq", "wk", "wv") if k in lp]
+            sd[pre + "attn.Wqkv.weight"] = np.concatenate(
+                [lp[k].T for k in qkv])
+            sd[pre + "attn.Wqkv.bias"] = np.concatenate(
+                [lp["b" + k[1]] for k in qkv])
+    return sd
+
+
+def _phi4flash_from_sd(
+    sd: Dict[str, Any], cfg: TransformerConfig, dtype: str
+) -> Dict[str, Any]:
+    per_kind: Dict[str, Dict[str, list]] = {}
+    qd, kvd, f = cfg.q_dim, cfg.kv_dim, cfg.intermediate_dim
+    # in_proj / out_proj name an S6 mixer's, a memory unit's and
+    # attention's matrices alike: a kind reads its own keys
+    every = {"ln1", "ln1_b", "ln2", "ln2_b", "w_down"}
+    mixer = {
+        S6: {"in_proj", "conv_b", "x_proj", "dt_proj", "dt_bias", "A_log",
+             "D", "out_proj"},
+        GMU: {"gmu_in", "gmu_out"},
+    }
+    attention = {"wo", "bo", "lambda_q1", "lambda_k1", "lambda_q2",
+                 "lambda_k2", "subln"}
+    for i, kind in enumerate(cfg.layer_kinds):
+        pre = f"model.layers.{i}."
+        lp = per_kind.setdefault(kind, {})
+        attends = kind not in (S6, GMU)
+        keys = every | mixer.get(kind, attention)
+        for key, name, tr in _PHI4FLASH_NAMES:
+            if key in keys:
+                w = _np(sd[pre + name])
+                lp.setdefault(key, []).append(w.T if tr else w)
+        gate_up = _np(sd[pre + "mlp.gate_up_proj.weight"])
+        lp.setdefault("w_gate", []).append(gate_up[:f].T)
+        lp.setdefault("w_up", []).append(gate_up[f:].T)
+        if kind == S6:
+            lp.setdefault("conv_w", []).append(
+                _np(sd[pre + "attn.conv1d.weight"])[:, 0, :].T)
+        if attends:
+            w, b = _np(sd[pre + "attn.Wqkv.weight"]), _np(
+                sd[pre + "attn.Wqkv.bias"])
+            cuts = [("q", 0, qd)] + ([] if kind == CROSS else [
+                ("k", qd, qd + kvd), ("v", qd + kvd, qd + 2 * kvd)])
+            for c, lo, hi in cuts:
+                lp.setdefault("w" + c, []).append(w[lo:hi].T)
+                lp.setdefault("b" + c, []).append(b[lo:hi])
+    out = {
+        "embedding": _np(sd["model.embed_tokens.weight"]).astype(dtype),
+        "layers": {kind: {k: np.stack(v).astype(dtype)
+                          for k, v in lp.items()}
+                   for kind, lp in per_kind.items()},
+        "final_ln": _np(sd["model.final_layernorm.weight"]).astype(dtype),
+        "final_ln_b": _np(sd["model.final_layernorm.bias"]).astype(dtype),
+    }
+    if not cfg.tie_word_embeddings:
+        out["lm_head"] = _np(sd["lm_head.weight"]).T.astype(dtype)
+    return out
+
+
 def params_from_hf_state_dict(
     sd: Dict[str, Any], cfg: TransformerConfig, dtype: str = "float32"
 ) -> Dict[str, Any]:
@@ -838,6 +1008,8 @@ def params_from_hf_state_dict(
         return _nemotron_h_from_sd(sd, cfg, dtype)
     if cfg.hf_family == "afmoe":
         return _afmoe_from_sd(sd, cfg, dtype)
+    if cfg.hf_family == "phi4flash":
+        return _phi4flash_from_sd(sd, cfg, dtype)
     return _llama_from_sd(sd, cfg, dtype)
 
 
@@ -851,6 +1023,8 @@ def params_to_hf_state_dict(
         return _nemotron_h_to_sd(params, cfg)
     if cfg.hf_family == "afmoe":
         return _afmoe_to_sd(params, cfg)
+    if cfg.hf_family == "phi4flash":
+        return _phi4flash_to_sd(params, cfg)
     return _llama_to_sd(params, cfg)
 
 
@@ -869,6 +1043,7 @@ _HF_ARCH = {
     "mellum": "MellumForCausalLM",
     "nemotron_h": "NemotronHForCausalLM",
     "afmoe": "AfmoeForCausalLM",
+    "phi4flash": "Phi4FlashForCausalLM",
 }
 
 
@@ -894,6 +1069,8 @@ def hf_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
         return _nemotron_h_config_dict(cfg)
     if fam == "afmoe":
         return _afmoe_config_dict(cfg)
+    if fam == "phi4flash":
+        return _phi4flash_config_dict(cfg)
     d: Dict[str, Any] = {
         "model_type": fam,
         "architectures": [_HF_ARCH.get(fam, "LlamaForCausalLM")],
@@ -1013,6 +1190,35 @@ def _nemotron_h_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
         d["num_routed_experts"] = moe.n_routed
         d["expert_shard_count"] = moe.n_routed // moe.num_experts
         d["expert_shard_index"] = moe.first_expert // moe.num_experts
+    return d
+
+
+def _phi4flash_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
+    """The inverse of :func:`_phi4flash_config`."""
+    letters = {kind: c for c, kind in _SAMBAY_LETTERS.items()}
+    pattern = "".join(letters[k] for k in cfg.layer_kinds)
+    d = {
+        "model_type": "phi4flash",
+        "architectures": [_HF_ARCH["phi4flash"]],
+        "num_hidden_layers": cfg.n_layers,
+        "hidden_size": cfg.hidden_dim,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "intermediate_size": cfg.intermediate_dim,
+        "vocab_size": cfg.vocab_size,
+        "layer_norm_eps": cfg.rms_norm_eps,
+        "hidden_act": cfg.hidden_act,
+        "sliding_window": cfg.sliding_window,
+        "mb_per_layer": 2,
+        "tie_word_embeddings": cfg.tie_word_embeddings,
+        "mlp_bias": False,
+        "lm_head_bias": False,
+        "max_position_embeddings": cfg.max_position_embeddings or 262144,
+        "torch_dtype": "float32",
+    }
+    if pattern != sambay_pattern(cfg.n_layers, 2) or cfg.first_layer_index:
+        d["layer_pattern"] = pattern
+        d["first_layer_index"] = cfg.first_layer_index
     return d
 
 

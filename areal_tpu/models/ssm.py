@@ -1,5 +1,6 @@
-"""The Mamba-2 state-space mixer of a hybrid model's ``mamba`` layers —
-packed rows, XLA einsums, no kernel.
+"""The state-space mixers: Mamba-2 (SSD) of a hybrid model's ``mamba``
+layers — packed rows, XLA einsums, no kernel — and, at the end of the
+file, Mamba-1's selective scan (S6) of the ``s6`` blocks.
 
 One mixer, ``u = norm(h)`` [B, T, D] (models/transformer.py adds the
 residual):
@@ -36,13 +37,14 @@ is the trace-time count of the scans a compiled program holds.
 from __future__ import annotations
 
 import collections
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from areal_tpu.models.config import SSMConfig
+from areal_tpu.models.config import S6Config, SSMConfig
 
 # Scans per compiled program, counted where they are traced (as
 # flash_attention.geometry_counts): {(rows, length, chunk, heads, groups):
@@ -226,3 +228,264 @@ def mamba_mixer(u: jnp.ndarray,  # [B, T, D] the normed residual stream
         y = group_rms_norm(y, lp["norm"].astype(f32), G, eps).astype(u.dtype)
     with jax.named_scope("ssm_out_proj"):
         return y @ lp["out_proj"]
+
+
+# ---------------- Mamba-1: the selective scan (S6) ----------------
+#
+# One mixer, ``u = norm(h)`` [B, T, D] (the block adds the residual and
+# the MLP, models/transformer.py):
+#
+#     [x | z] = u · in_proj                       d_inner | d_inner
+#     x = silu(conv1d(x) + b)                     depthwise, causal, K taps
+#     [δ | B | C] = x · x_proj                    dt_rank | N | N
+#     Δ = softplus(δ · dt_proj + dt_bias)         [d_inner]
+#     A = -exp(A_log)                             [d_inner, N]
+#     h_t = exp(Δ_t ⊙ A) ⊙ h_{t-1} + (Δ_t ⊙ x_t) ⊗ B_t        (float32)
+#     y_t = h_t · C_t + D ⊙ x_t
+#     out = (y ⊙ silu(z)) · out_proj
+#
+# ``y`` (before the gate) is the MEMORY a later gated memory unit reads:
+# :func:`s6_mixer` returns it beside ``out``. The state is zero before a
+# document's first token and the convolution's taps stop there, as above.
+#
+# The scan is NOT the chunked matmul form: the decay is a channel's and a
+# state's own, so it runs as a recurrence, in chunks whose entering states
+# are all the backward pass keeps (:func:`selective_scan`): a Pallas
+# kernel on a TPU (ops/pallas/selective_scan.py), the same algorithm as
+# two ``lax.scan`` elsewhere. Device scopes (base/telemetry.SAMBAY_SCOPES):
+# ``s6_in_proj``, ``s6_conv``, ``s6_xdt_proj``, ``s6_scan``, ``s6_out_proj``.
+
+# Scans per compiled program: {(rows, length, d_inner, state, impl): calls}.
+_S6_GEOMETRY: collections.Counter = collections.Counter()
+# Tokens between two kept states of the XLA form (the kernel has its own).
+S6_CHUNK = 64
+
+
+def s6_geometry_counts() -> Dict[Tuple[int, int, int, int, str], int]:
+    return dict(_S6_GEOMETRY)
+
+
+def init_s6_params(s6: S6Config, n: int, hidden_dim: int, key: jax.Array,
+                   dtype) -> Dict[str, jnp.ndarray]:
+    """``n`` stacked S6 mixers, drawn as Mamba-1 draws them: Δ's bias the
+    inverse softplus of logUniform(time_step_min, time_step_max) floored
+    at time_step_floor; ``dt_proj`` uniform in ±dt_rank^-1/2; ``A_log =
+    log(1..N)`` on every channel; ``D = 1``; the convolution uniform in
+    ±1/sqrt(K); every other matrix as the program draws matrices."""
+    k_in, k_conv, k_x, k_dtw, k_dt, k_out = jax.random.split(key, 6)
+    di, N, K, r = s6.d_inner, s6.state_dim, s6.conv_kernel, s6.dt_rank
+
+    def nrm(k, shape, scale=0.02):
+        return (jax.random.normal(k, shape) * scale).astype(dtype)
+
+    dt0 = jnp.exp(
+        jax.random.uniform(k_dt, (n, di)) * (
+            math.log(s6.time_step_max) - math.log(s6.time_step_min))
+        + math.log(s6.time_step_min))
+    dt0 = jnp.maximum(dt0, s6.time_step_floor)
+    bound, dt_bound = 1.0 / math.sqrt(K), r ** -0.5
+    return {
+        "in_proj": nrm(k_in, (n, hidden_dim, 2 * di)),
+        "conv_w": jax.random.uniform(
+            k_conv, (n, K, di), minval=-bound, maxval=bound).astype(dtype),
+        "conv_b": jnp.zeros((n, di), dtype),
+        "x_proj": nrm(k_x, (n, di, s6.x_proj_dim)),
+        "dt_proj": jax.random.uniform(
+            k_dtw, (n, r, di), minval=-dt_bound, maxval=dt_bound
+        ).astype(dtype),
+        "dt_bias": (dt0 + jnp.log(-jnp.expm1(-dt0))).astype(dtype),
+        "A_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32)), (n, di, N)
+        ).astype(dtype),
+        "D": jnp.ones((n, di), dtype),
+        "out_proj": nrm(k_out, (n, di, hidden_dim)),
+    }
+
+
+def document_starts(seg: jnp.ndarray) -> jnp.ndarray:
+    """[B, T] int32: 0 at a document's first token (and on padding), where
+    the state before it is zero; 1 elsewhere."""
+    prev = jnp.pad(seg, ((0, 0), (1, 0)), constant_values=-1)[:, :-1]
+    return ((seg == prev) & (seg > 0)).astype(jnp.int32)
+
+
+def _chunks(a: jnp.ndarray, Q: int) -> jnp.ndarray:
+    """[B, T, ...] -> [T / Q, Q, B, ...]: chunks, then tokens, lead."""
+    B_, T = a.shape[:2]
+    return jnp.moveaxis(a.reshape(B_, T // Q, Q, *a.shape[2:]), 0, 2)
+
+
+def _unchunk(a: jnp.ndarray) -> jnp.ndarray:
+    Z, Q, B_ = a.shape[:3]
+    return jnp.moveaxis(a, 2, 0).reshape(B_, Z * Q, *a.shape[3:])
+
+
+def _xla_scan_fwd(x, dt, A, Bm, Cm, keep):
+    """The kernel's forward as two nested ``lax.scan``: over chunks (whose
+    entering states are kept) and over a chunk's tokens."""
+    B_, T, D = x.shape
+    Q = S6_CHUNK
+
+    def token(h, xs):  # h [B, D, N]
+        x_t, dt_t, b_t, c_t, k_t = xs
+        a = jnp.exp(dt_t[..., None] * A) * k_t[:, None, None]
+        h = a * h + (dt_t * x_t)[..., None] * b_t[:, None, :]
+        return h, jnp.einsum("bdn,bn->bd", h, c_t)
+
+    def chunk(h, xs):
+        h_out, y = jax.lax.scan(token, h, xs)
+        return h_out, (y, h)
+
+    xs = tuple(_chunks(a, Q) for a in (x, dt, Bm, Cm, keep))
+    _, (y, h0) = jax.lax.scan(
+        chunk, jnp.zeros((B_, D, A.shape[1]), jnp.float32), xs)
+    return _unchunk(y), h0  # h0 [Z, B, D, N]
+
+
+def _xla_scan_bwd(x, dt, A, Bm, Cm, keep, h0, dy):
+    B_, T, D = x.shape
+    Q = S6_CHUNK
+
+    def decay(dt_t, k_t):
+        return jnp.exp(dt_t[..., None] * A) * k_t[:, None, None]
+
+    def token(h, xs):  # the states again: ys = the state BEFORE a token
+        x_t, dt_t, b_t, k_t = xs
+        h2 = decay(dt_t, k_t) * h + (dt_t * x_t)[..., None] * b_t[:, None, :]
+        return h2, (h, h2)
+
+    def back(carry, xs):
+        g, dA = carry
+        x_t, dt_t, b_t, c_t, k_t, dy_t, h_prev, h_t = xs
+        a = decay(dt_t, k_t)
+        g = g + dy_t[..., None] * c_t[:, None, :]
+        dc = jnp.einsum("bdn,bd->bn", h_t, dy_t)
+        db = jnp.einsum("bdn,bd->bn", g, dt_t * x_t)
+        s = jnp.einsum("bdn,bn->bd", g, b_t)
+        e = g * h_prev * a
+        ddt = s * x_t + jnp.sum(e * A, axis=-1)
+        dA = dA + jnp.sum(e * dt_t[..., None], axis=0)
+        return (a * g, dA), (s * dt_t, ddt, db, dc)
+
+    def chunk(carry, xs):
+        x_c, dt_c, b_c, c_c, k_c, dy_c, h_in = xs
+        _, (h_prev, h_t) = jax.lax.scan(token, h_in, (x_c, dt_c, b_c, k_c))
+        return jax.lax.scan(
+            back, carry, (x_c, dt_c, b_c, c_c, k_c, dy_c, h_prev, h_t),
+            reverse=True)
+
+    xs = tuple(_chunks(a, Q) for a in (x, dt, Bm, Cm, keep, dy)) + (h0,)
+    zero = jnp.zeros((B_, D, A.shape[1]), jnp.float32)
+    (_, dA), grads = jax.lax.scan(chunk, (zero, jnp.zeros_like(A)), xs,
+                                  reverse=True)
+    dx, ddt, db, dc = (_unchunk(a) for a in grads)
+    return dx, ddt, dA, db, dc
+
+
+def _scan_impl(impl: str, D: int) -> str:
+    """"pallas" | "pallas_interpret" | "xla": the kernel on a TPU (or
+    where it is asked for), for the widths it takes; else the XLA form."""
+    from areal_tpu.ops.attention import _wants_kernel
+    from areal_tpu.ops.pallas import selective_scan as kernel
+
+    if impl == "pallas_interpret":
+        return impl
+    return "pallas" if _wants_kernel(impl) and kernel.supported(D) else "xla"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _selective_scan(x, dt, A, Bm, Cm, keep, how):
+    return _selective_scan_fwd(x, dt, A, Bm, Cm, keep, how)[0]
+
+
+def _selective_scan_fwd(x, dt, A, Bm, Cm, keep, how):
+    if how == "xla":
+        y, h0 = _xla_scan_fwd(x, dt, A, Bm, Cm, keep.astype(jnp.float32))
+    else:
+        from areal_tpu.ops.pallas import selective_scan as kernel
+
+        y, h0 = kernel.scan_fwd(x, dt, A, Bm, Cm, keep,
+                                interpret=how == "pallas_interpret")
+    return y, (x, dt, A, Bm, Cm, keep, h0)
+
+
+def _selective_scan_bwd(how, res, dy):
+    x, dt, A, Bm, Cm, keep, h0 = res
+    if how == "xla":
+        grads = _xla_scan_bwd(x, dt, A, Bm, Cm, keep.astype(jnp.float32),
+                              h0, dy)
+    else:
+        from areal_tpu.ops.pallas import selective_scan as kernel
+
+        grads = kernel.scan_bwd(x, dt, A, Bm, Cm, keep, h0, dy,
+                                interpret=how == "pallas_interpret")
+    return (*grads, None)
+
+
+_selective_scan.defvjp(_selective_scan_fwd, _selective_scan_bwd)
+
+
+def selective_scan(x: jnp.ndarray,  # [B, T, D]
+                   dt: jnp.ndarray,  # [B, T, D], after softplus
+                   A: jnp.ndarray,  # [D, N], negative
+                   Bm: jnp.ndarray,  # [B, T, N]
+                   Cm: jnp.ndarray,  # [B, T, N]
+                   D_skip: jnp.ndarray,  # [D]
+                   seg: jnp.ndarray,  # [B, T] int; 0 = padding
+                   impl: str = "auto") -> jnp.ndarray:
+    """``h_t = exp(Δ_t ⊙ A) ⊙ h_{t-1} + (Δ_t ⊙ x_t) ⊗ B_t``, ``y_t = h_t ·
+    C_t + D ⊙ x_t``, ``h`` zero before each document's first token, in
+    float32. Returns y [B, T, D] float32. Only the state entering every
+    chunk outlives the forward pass: the backward pass (a custom one)
+    re-runs a chunk's recurrence from it, so no [T, D, N] array exists in
+    either pass."""
+    f32 = jnp.float32
+    B_, T, D = x.shape
+    how = _scan_impl(impl, D)
+    _S6_GEOMETRY[(B_, T, D, A.shape[1], how)] += 1
+    x, dt, Bm, Cm = (a.astype(f32) for a in (x, dt, Bm, Cm))
+    keep = document_starts(seg)
+    pad = -T % S6_CHUNK
+    if pad:  # a padded token is its row's padding: it resets and adds 0
+        x, dt, Bm, Cm = (jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+                         for a in (x, dt, Bm, Cm))
+        keep = jnp.pad(keep, ((0, 0), (0, pad)))
+    y = _selective_scan(x, dt, A.astype(f32), Bm, Cm, keep, how)[:, :T]
+    return y + D_skip.astype(f32) * x[:, :T]
+
+
+def s6_mixer(u: jnp.ndarray,  # [B, T, D] the normed residual stream
+             lp: Dict[str, jnp.ndarray],  # this layer's parameters
+             s6: S6Config,
+             segment_ids: Optional[jnp.ndarray],  # [B, T]; None = one document a row
+             impl: str = "auto",
+             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(the mixer's output [B, T, D], the scan's output ``y`` before the
+    gate [B, T, d_inner] — the memory a gated memory unit reads)."""
+    B_, T, _ = u.shape
+    di, N, r = s6.d_inner, s6.state_dim, s6.dt_rank
+    f32 = jnp.float32
+    seg = (jnp.ones((B_, T), jnp.int32) if segment_ids is None
+           else segment_ids)
+    with jax.named_scope("s6_in_proj"):
+        x, z = jnp.split(u @ lp["in_proj"], [di], axis=-1)
+    with jax.named_scope("s6_conv"):
+        x = jax.nn.silu(causal_conv(x, lp["conv_w"], lp["conv_b"], seg))
+    with jax.named_scope("s6_xdt_proj"):
+        delta, Bm, Cm = jnp.split(x @ lp["x_proj"], [r, r + N], axis=-1)
+        dt = jax.nn.softplus(
+            (delta @ lp["dt_proj"]).astype(f32) + lp["dt_bias"].astype(f32))
+    with jax.named_scope("s6_scan"):
+        y = selective_scan(
+            x, dt, -jnp.exp(lp["A_log"].astype(f32)), Bm, Cm, lp["D"], seg,
+            impl).astype(u.dtype)
+    with jax.named_scope("s6_out_proj"):
+        return (y * jax.nn.silu(z)) @ lp["out_proj"], y
+
+
+def gated_memory_unit(u: jnp.ndarray,  # [B, T, D] the normed residual stream
+                      memory: jnp.ndarray,  # [B, T, d_inner], an S6 layer's y
+                      lp: Dict[str, jnp.ndarray]) -> jnp.ndarray:
+    """``(m ⊙ silu(u W_1)) W_2``: nothing across tokens."""
+    with jax.named_scope("gmu"):
+        return (memory * jax.nn.silu(u @ lp["gmu_in"])) @ lp["gmu_out"]

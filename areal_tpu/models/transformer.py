@@ -44,17 +44,29 @@ import jax.numpy as jnp
 
 from areal_tpu.models.config import (
     ATTENTION_ONLY,
+    CROSS,
     FULL,
+    GMU,
     MAMBA,
+    MEMORY,
     MIXER_KINDS,
     MOE_ONLY,
+    S6,
+    SAMBAY_KINDS,
+    SHARED_KV,
     SLIDING,
     RopeConfig,
     TransformerConfig,
     attention_kind,
     has_dense_ffn,
 )
-from areal_tpu.ops.attention import decode_attention, packed_attention
+from areal_tpu.ops.attention import (
+    decode_attention,
+    differential_combine,
+    differential_q,
+    differential_v,
+    packed_attention,
+)
 from areal_tpu.parallel.sharding import constrain, current_mesh
 
 Params = Dict[str, Any]
@@ -98,16 +110,21 @@ def _init_mixer_layers(cfg: TransformerConfig, keys, dtype) -> Params:
         layers[kind] = _init_block_layers(
             cfg, cfg.n_layers_of(kind),
             jax.random.split(jax.random.fold_in(keys[11], j), 16), dtype,
-            dense_ffn=has_dense_ffn(kind))
+            dense_ffn=has_dense_ffn(kind), kind=kind)
     return layers
 
 
 def _init_block_layers(cfg: TransformerConfig, n: int, keys, dtype,
-                       dense_ffn: bool) -> Dict[str, jnp.ndarray]:
-    """``n`` whole blocks (attention + FFN) stacked ``[n, ...]``; the FFN
-    is the expert layer where the model has one, unless ``dense_ffn``."""
+                       dense_ffn: bool, kind: str = FULL,
+                       ) -> Dict[str, jnp.ndarray]:
+    """``n`` whole blocks (a mixer + FFN) stacked ``[n, ...]``: attention,
+    or by ``kind`` an S6 mixer, a gated memory unit (two matrices and no
+    scan) or cross attention (q and o alone: its K/V are another
+    layer's); the FFN is the expert layer where the model has one, unless
+    ``dense_ffn``."""
     d = cfg.hidden_dim
     qd, kvd, f = cfg.q_dim, cfg.kv_dim, cfg.intermediate_dim
+    attends = kind not in (S6, GMU)
 
     def nrm(k, shape, scale=0.02):
         return (jax.random.normal(k, shape) * scale).astype(dtype)
@@ -115,11 +132,28 @@ def _init_block_layers(cfg: TransformerConfig, n: int, keys, dtype,
     layers: Dict[str, jnp.ndarray] = {
         "ln1": jnp.ones((n, d), dtype),
         "ln2": jnp.ones((n, d), dtype),
-        "wq": nrm(keys[0], (n, d, qd)),
-        "wk": nrm(keys[1], (n, d, kvd)),
-        "wv": nrm(keys[2], (n, d, kvd)),
-        "wo": nrm(keys[3], (n, qd, d)),
     }
+    if kind == S6:
+        from areal_tpu.models import ssm as ssmmod
+
+        layers.update(ssmmod.init_s6_params(cfg.s6, n, d, keys[13], dtype))
+    elif kind == GMU:
+        layers["gmu_in"] = nrm(keys[13], (n, d, cfg.s6.d_inner))
+        layers["gmu_out"] = nrm(keys[14], (n, cfg.s6.d_inner, d))
+    else:
+        layers["wq"] = nrm(keys[0], (n, d, qd))
+        layers["wo"] = nrm(keys[3], (n, qd, d))
+        if kind != CROSS:
+            layers["wk"] = nrm(keys[1], (n, d, kvd))
+            layers["wv"] = nrm(keys[2], (n, d, kvd))
+        if cfg.differential_attention:
+            # the four vectors of lambda as the Differential Transformer
+            # draws them (N(0, 0.1)) and the sub-norm's weight
+            for j, name in enumerate(("lambda_q1", "lambda_k1", "lambda_q2",
+                                      "lambda_k2")):
+                layers[name] = nrm(jax.random.fold_in(keys[15], j),
+                                   (n, cfg.head_dim), 0.1)
+            layers["subln"] = jnp.ones((n, 2 * cfg.head_dim), dtype)
     if cfg.moe is not None and not dense_ffn:
         from areal_tpu.models import moe as moemod
 
@@ -137,11 +171,12 @@ def _init_block_layers(cfg: TransformerConfig, n: int, keys, dtype,
             "w_up": nrm(keys[5], (n, d, f)),
             "w_down": nrm(keys[6], (n, f, d)),
         })
-    if cfg.use_attention_bias:
+    if cfg.use_attention_bias and attends:
         layers["bq"] = jnp.zeros((n, qd), dtype)
-        layers["bk"] = jnp.zeros((n, kvd), dtype)
-        layers["bv"] = jnp.zeros((n, kvd), dtype)
-    if cfg.use_attn_output_bias:
+        if kind != CROSS:
+            layers["bk"] = jnp.zeros((n, kvd), dtype)
+            layers["bv"] = jnp.zeros((n, kvd), dtype)
+    if cfg.use_attn_output_bias and attends:
         layers["bo"] = jnp.zeros((n, d), dtype)
     if cfg.use_qk_norm:
         layers["q_norm"] = jnp.ones((n, cfg.q_norm_dim), dtype)
@@ -166,12 +201,15 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
         return (jax.random.normal(k, shape) * scale).astype(dtype)
 
     if cfg.is_hybrid:
-        assert cfg.norm_type == "rms" and not cfg.is_critic
+        assert not cfg.is_critic
+        assert cfg.norm_type == "rms" or not cfg.has_mixer_layers
         params = {
             "embedding": nrm(keys[7], (cfg.vocab_size, d)),
             "layers": _init_mixer_layers(cfg, keys, dtype),
             "final_ln": jnp.ones((d,), dtype),
         }
+        if cfg.norm_type == "layer":
+            params["final_ln_b"] = jnp.zeros((d,), dtype)
         if not cfg.tie_word_embeddings:
             params["lm_head"] = nrm(keys[8], (d, cfg.vocab_size))
         return params
@@ -319,7 +357,13 @@ def _block(
     rng: Optional[jnp.ndarray] = None,  # per-layer key for MoE router jitter
     allow_ep: bool = True,  # False inside manual regions (pipeline stages)
     kind: Optional[str] = None,  # this layer's attention kind (cfg.layer_kinds)
-) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, jnp.ndarray], Optional[Dict[str, jnp.ndarray]]]:
+    shared: Optional[Dict[str, Any]] = None,  # what earlier layers handed on
+    layer_index=0,  # this layer's index (lambda_init), unless lp["_layer"]
+) -> Tuple[jnp.ndarray, Any, Optional[Dict[str, jnp.ndarray]]]:
+    """Returns (h, made, aux). ``made`` is what the layer made that another
+    may read: an attention layer's (K, V) — the cache's, or a later cross
+    layer's, then as the kernel takes them — and an S6 layer's scan
+    output, the memory of a later gated memory unit; None otherwise."""
     B, T, D = h.shape
     if kind is None:
         kind = cfg.period_kinds[0]
@@ -340,14 +384,33 @@ def _block(
     # ops, no change to the program that runs.
     with jax.named_scope("attn_norm"):
         x = _norm(cfg, h, lp, "ln1")
-    with jax.named_scope("qkv_proj"):
+    if akind in (S6, GMU):
+        assert cache_kv is None, DECODE_REFUSAL
+        from areal_tpu.models import ssm as ssmmod
+
+        if akind == S6:
+            attn, new_kv = ssmmod.s6_mixer(x, lp, cfg.s6, segment_ids,
+                                           attn_impl)
+        else:
+            attn, new_kv = ssmmod.gated_memory_unit(
+                x, shared[MEMORY], lp), None
+        return _block_ffn(cfg, kind, constrain(h + attn, "hidden"), lp,
+                          new_kv, segment_ids, rng, allow_ep, ring_ctx,
+                          attn_impl, decode=False)
+    with jax.named_scope("cross_attention" if akind == CROSS else "qkv_proj"):
         q = x @ lp["wq"]
-        k = x @ lp["wk"]
-        v = x @ lp["wv"]
-        if "bq" in lp:
-            q = q + lp["bq"]
-            k = k + lp["bk"]
-            v = v + lp["bv"]
+        if akind == CROSS:  # K and V are another layer's, as it made them
+            assert cache_kv is None, DECODE_REFUSAL
+            if "bq" in lp:
+                q = q + lp["bq"]
+            k, v = shared[SHARED_KV]
+        else:
+            k = x @ lp["wk"]
+            v = x @ lp["wv"]
+            if "bq" in lp:
+                q = q + lp["bq"]
+                k = k + lp["bk"]
+                v = v + lp["bv"]
         # The q/k norm spans the whole projected vector (olmoe) or each
         # head (qwen3): before or after the split into heads.
         qk_norm = cfg.use_qk_norm and cfg.qk_norm_extent
@@ -355,28 +418,47 @@ def _block(
             q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
             k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
         q = q.reshape(B, T, cfg.n_q_heads, dh)
-        k = k.reshape(B, T, cfg.n_kv_heads, dh)
-        v = v.reshape(B, T, cfg.n_kv_heads, dh)
+        if akind != CROSS:
+            k = k.reshape(B, T, cfg.n_kv_heads, dh)
+            v = v.reshape(B, T, cfg.n_kv_heads, dh)
         if qk_norm == "head":
             q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
             k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
         if cfg.gated_attention:
             with jax.named_scope("attn_gate"):
                 gate = x @ lp["wg"]
+        if cfg.differential_attention:
+            assert cache_kv is None, DECODE_REFUSAL
+            q = differential_q(q, cfg.n_kv_heads)
+            if akind != CROSS:
+                v = differential_v(v)
     if cfg.pos_embedding == "rope" and cos is not None:
         with jax.named_scope("rope"):
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
 
-    with jax.named_scope("attention"):
+    with jax.named_scope("cross_attention" if akind == CROSS
+                         else "attention"):
         attn, new_kv = _attend(
             cfg, q, k, v, segment_ids, positions, cache_kv,
             cache_write_index, kv_valid, attn_impl, allow_ring, ring_ctx,
             kind,
         )
+    if cfg.differential_attention:
+        with jax.named_scope("diff_attn_combine"):
+            f32 = jnp.float32
+            lam = (jnp.exp(jnp.sum(lp["lambda_q1"].astype(f32)
+                                   * lp["lambda_k1"].astype(f32)))
+                   - jnp.exp(jnp.sum(lp["lambda_q2"].astype(f32)
+                                     * lp["lambda_k2"].astype(f32))))
+            layer = lp.get("_layer", layer_index) + cfg.first_layer_index
+            lam_init = 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(layer, f32))
+            attn = differential_combine(
+                attn, cfg.n_kv_heads, lam + lam_init, lam_init, lp["subln"],
+                cfg.rms_norm_eps)
 
     hid = "hidden" if cache_kv is None else "hidden_decode"
-    with jax.named_scope("o_proj"):
+    with jax.named_scope("cross_attention" if akind == CROSS else "o_proj"):
         attn = attn.reshape(B, T, cfg.q_dim)
         if cfg.gated_attention:
             with jax.named_scope("attn_gate"):
@@ -388,7 +470,17 @@ def _block(
             with jax.named_scope("post_attn_norm"):
                 attn = _norm(cfg, attn, lp, "ln1_post")
         h = constrain(h + attn, hid)
+    return _block_ffn(cfg, kind, h, lp, new_kv, segment_ids, rng, allow_ep,
+                      ring_ctx, attn_impl, decode=cache_kv is not None)
 
+
+def _block_ffn(cfg: TransformerConfig, kind: str, h, lp, new_kv,
+               segment_ids, rng, allow_ep: bool, ring_ctx, attn_impl: str,
+               decode: bool):
+    """The second half of a whole block: ``h + ffn(norm(h))``, the FFN the
+    dense MLP or the expert layer. Returns :func:`_block`'s triple."""
+    B, T, _ = h.shape
+    hid = "hidden_decode" if decode else "hidden"
     with jax.named_scope("mlp_norm"):
         x = _norm(cfg, h, lp, "ln2")
     act = _ACTIVATIONS[cfg.hidden_act]
@@ -402,7 +494,7 @@ def _block(
         # single-shard paths (generation never expert-parallels,
         # api/cli_args.validate_config rejects it).
         ep_mesh = current_mesh() if (
-            allow_ep and ring_ctx is None and cache_kv is None
+            allow_ep and ring_ctx is None and not decode
         ) else None
         if ep_mesh is not None and not moemod.ep_eligible(
                 ep_mesh, cfg.moe, B, T):
@@ -433,7 +525,8 @@ def _block(
 # refuses it by this name).
 DECODE_REFUSAL = (
     "recurrent_decode_state: a state-space layer decodes from a recurrent "
-    "state (its convolution's last taps and S), which no cache here holds")
+    "state (its convolution's last taps and S), which no cache here holds "
+    "(nor one layer's K/V for the cross layers that read it)")
 
 
 def _mixer_block(
@@ -593,13 +686,15 @@ def apply_layer_stack(
                                   remat)
         return h, (aux if aux is not None else {})
 
-    def body(kind, h, lp):
-        h2, _, aux = _block(
+    def body(kind, h, lp, shared=None):
+        h2, made, aux = _block(
             cfg, h, lp, cos, sin, segment_ids, positions,
             None, None, None, attn_impl, allow_ring=allow_ring,
-            ring_ctx=ring_ctx, allow_ep=allow_ep, kind=kind,
+            ring_ctx=ring_ctx, allow_ep=allow_ep, kind=kind, shared=shared,
         )
-        return h2, aux
+        # a model whose layers read earlier layers' tensors is handed
+        # them, and hands back what this layer made
+        return (h2, aux) if shared is None else (h2, aux, made)
 
     # "layer_scan" names the scan's own work: slicing each layer's
     # parameters out of the stacked arrays and, in the backward pass,
@@ -682,10 +777,29 @@ def _scan_mixer_layers(cfg: TransformerConfig, layer: Callable, h, xs,
     the kind in a unit, ...]`` out here, so that every scan's ``xs`` is an
     input of the scan around it and no copy of the weights is kept for
     the backward pass. Returns (h, ys) with ys stacked over the layers
-    that return one (the expert layers' aux); None where no layer does."""
+    that return one (the expert layers' aux); None where no layer does.
+
+    **The hand-over** (``cfg.cross_layer_reads``): a tensor made by layer
+    i and read by layers j > i — the scan output of ``cfg.memory_source``,
+    the K/V of ``cfg.kv_source``. Then ``layer(kind, h, x, shared) -> (h,
+    y, made)``: a source layer is a run of its own (never inside a
+    repeated unit), what it ``made`` goes into ``shared`` under the name
+    ``cfg.handed_on_by`` gives it, and every later layer is handed
+    ``shared`` — as an argument of its checkpoint and a constant of its
+    run's scan: kept for the backward pass once whatever ``remat`` is, not
+    recomputed a reader, and its gradient is the sum over the readers."""
     kinds = cfg.period_kinds
     n_periods = cfg.n_layers // len(kinds)
-    runs = period_runs(kinds)
+    hands_over = bool(cfg.cross_layer_reads)
+    # a source layer's kind is tagged, so that no repeated unit holds it
+    tagged = tuple(k + "#" + cfg.handed_on_by(i) if cfg.handed_on_by(i)
+                   else k for i, k in enumerate(kinds))
+    runs = tuple((tuple(k.split("#")[0] for k in unit), n)
+                 for unit, n in period_runs(tagged))
+    if cfg.differential_attention:  # lambda_init reads a layer's index
+        xs = {kind: {**tree, "_layer": jnp.asarray(
+            [i for i, k in enumerate(cfg.layer_kinds) if k == kind],
+            jnp.int32)} for kind, tree in xs.items()}
 
     xs_runs, used = [], {kind: 0 for kind in xs}
     for unit, n in runs:
@@ -703,31 +817,46 @@ def _scan_mixer_layers(cfg: TransformerConfig, layer: Callable, h, xs,
             xr[kind] = jax.tree.map(cut, xs[kind])
         xs_runs.append(xr)
 
-    def unit_body(unit):
-        steps = [
-            _maybe_checkpoint(
-                lambda h, xu, i=unit[:j].count(kind), kind=kind: layer(
-                    kind, h, jax.tree.map(lambda a: a[i], xu[kind])), remat)
-            for j, kind in enumerate(unit)
-        ]
+    def unit_body(unit, first):
+        def step(j, kind):
+            i = unit[:j].count(kind)
 
-        def body(h, xu):
-            ys = []
-            for step in steps:
-                h, y = step(h, xu)
+            def run(h, xu, *shared):
+                return layer(kind, h, jax.tree.map(lambda a: a[i], xu[kind]),
+                             *shared)
+
+            return _maybe_checkpoint(run, remat)
+
+        steps = [step(j, kind) for j, kind in enumerate(unit)]
+
+        def body(h, xu, *shared):
+            ys, made = [], {}
+            for j, step in enumerate(steps):
+                h, y, *m = step(h, xu, *shared)
                 if y is not None:
                     ys.append(y)
-            return h, (jax.tree.map(lambda *a: jnp.stack(a), *ys) if ys
-                       else None)
+                if m and cfg.handed_on_by(first + j):
+                    made[cfg.handed_on_by(first + j)] = m[0]
+            ys = jax.tree.map(lambda *a: jnp.stack(a), *ys) if ys else None
+            return (h, ys, made) if shared else (h, ys)
 
         return body
 
-    bodies = [unit_body(unit) for unit, _ in runs]
+    firsts = [sum(len(u) * n for u, n in runs[:r]) for r in range(len(runs))]
+    bodies = [unit_body(unit, first) for (unit, _), first in zip(runs, firsts)]
 
     def period(h, xrs):
-        ys = []
+        ys, shared = [], {}
         for (_, n), body, xr in zip(runs, bodies, xrs):
-            h, y = jax.lax.scan(body, h, xr) if n > 1 else body(h, xr)
+            if not hands_over:
+                h, y = jax.lax.scan(body, h, xr) if n > 1 else body(h, xr)
+            elif n > 1:  # readers only: ``shared`` is the scan's constant
+                h, y = jax.lax.scan(
+                    lambda h, xu, body=body, shared=dict(shared):
+                    body(h, xu, shared)[:2], h, xr)
+            else:
+                h, y, made = body(h, xr, dict(shared))
+                shared.update(made)
             if y is not None:  # [n, layers with a y in the unit, ...]
                 ys.append(jax.tree.map(
                     lambda a: a.reshape(-1, *a.shape[2:]), y) if n > 1 else y)
@@ -798,13 +927,23 @@ def _maybe_checkpoint(body, remat):
         body, policy=_remat_policy("full" if remat is True else remat))
 
 
-def _block_matmul_widths(cfg: TransformerConfig, dense_ffn: bool) -> int:
+def _block_matmul_widths(cfg: TransformerConfig, dense_ffn: bool,
+                         kind: str = FULL) -> int:
     """Widths of a whole block's matmul outputs that its backward reads:
     q/k/v (and the attention gate), o_proj, and the MLP's matmuls into
     the hidden width (gate and up, or up) — nothing in the backward reads
     the last matmul's output, unless a sandwich norm does; an MoE layer
-    keeps the router's logits and its shared expert's pair."""
-    widths = cfg.q_dim + 2 * cfg.kv_dim + cfg.hidden_dim
+    keeps the router's logits and its shared expert's pair. By ``kind``
+    the mixer's are an S6 mixer's (in-projection, [δ | B | C], Δ), a
+    gated memory unit's one, or cross attention's q and o."""
+    if kind == S6:
+        widths = 3 * cfg.s6.d_inner + cfg.s6.x_proj_dim + cfg.hidden_dim
+    elif kind == GMU:
+        widths = cfg.s6.d_inner + cfg.hidden_dim
+    elif kind == CROSS:
+        widths = cfg.q_dim + cfg.hidden_dim
+    else:
+        widths = cfg.q_dim + 2 * cfg.kv_dim + cfg.hidden_dim
     if cfg.gated_attention:
         widths += cfg.q_dim
     if cfg.sandwich_norm:  # the post-norm reads the FFN's last matmul
@@ -851,11 +990,21 @@ def remat_kept_bytes(
             MOE_ONLY: (moe.n_routed + (moe.latent_dim or 0)
                        + (moe.shared_intermediate_dim or 0)) if moe else 0,
         }
-        kernel = {ATTENTION_ONLY: flash, FULL: flash, SLIDING: window}
+        kernel = {ATTENTION_ONLY: flash, FULL: flash, CROSS: flash,
+                  SLIDING: window}
         for kind in cfg.layer_kinds:  # whole blocks whose FFN kinds differ
             if kind not in MIXER_KINDS:
-                widths[kind] = _block_matmul_widths(cfg, has_dense_ffn(kind))
-        kept = {"full": cfg.n_layers * full}
+                widths[kind] = _block_matmul_widths(cfg, has_dense_ffn(kind),
+                                                    kind)
+        # what one layer hands on to later ones is kept once, whatever
+        # the entry: the memory [d_inner] and the K/V as the kernel takes
+        # them (the value repeated for each k of a pair)
+        reads = cfg.cross_layer_reads
+        handed = tokens * itemsize * (
+            (cfg.s6.d_inner if MEMORY in reads else 0)
+            + (cfg.kv_dim * (3 if cfg.differential_attention else 2)
+               if SHARED_KV in reads else 0))
+        kept = {"full": cfg.n_layers * full + handed}
         kept["attention"] = kept["full"] + sum(
             kernel.get(attention_kind(kind), 0) for kind in cfg.layer_kinds)
         kept["matmuls"] = kept["attention"] + tokens * itemsize * sum(
@@ -901,7 +1050,7 @@ def forward(
     attention kind (:func:`kv_valid_by_kind`).
     """
     decode = kv_cache is not None
-    if cfg.has_mixer_layers and (decode or return_kv):
+    if cfg.has_cacheless_layers and (decode or return_kv):
         raise NotImplementedError(DECODE_REFUSAL)
     with jax.named_scope("embed"):
         h = params["embedding"][tokens]
@@ -1058,7 +1207,7 @@ def apply_head(params: Params, cfg: TransformerConfig, h, lg="logits"):
 def init_kv_cache(
     cfg: TransformerConfig, batch: int, length: int, dtype=jnp.float32
 ) -> Dict[str, jnp.ndarray]:
-    if cfg.has_mixer_layers:
+    if cfg.has_cacheless_layers:
         raise NotImplementedError(DECODE_REFUSAL)
     shape = (cfg.n_layers, batch, length, cfg.n_kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -1073,7 +1222,7 @@ def _mixer_param_counts(cfg: TransformerConfig) -> Dict[str, int]:
               d * (cfg.q_dim + 2 * cfg.kv_dim) + cfg.q_dim * d + d}
     for kind in cfg.layer_kinds:
         if kind not in MIXER_KINDS:
-            counts[kind] = _block_param_count(cfg, has_dense_ffn(kind))
+            counts[kind] = _block_param_count(cfg, has_dense_ffn(kind), kind)
     if cfg.ssm is not None:
         ssm = cfg.ssm
         counts[MAMBA] = (
@@ -1085,14 +1234,27 @@ def _mixer_param_counts(cfg: TransformerConfig) -> Dict[str, int]:
     return counts
 
 
-def _block_param_count(cfg: TransformerConfig, dense_ffn: bool) -> int:
+def _block_param_count(cfg: TransformerConfig, dense_ffn: bool,
+                       kind: str = FULL) -> int:
     """Parameters of one whole block (the biases of the qwen2 / gpt2
     families are not counted), whose FFN is the expert layer where the
-    model has one, unless ``dense_ffn``."""
+    model has one, unless ``dense_ffn``; its mixer by ``kind``."""
     from areal_tpu.models import moe as moemod
 
     d, f = cfg.hidden_dim, cfg.intermediate_dim
-    attn = d * (cfg.q_dim + 2 * cfg.kv_dim) + cfg.q_dim * d
+    if kind == S6:
+        s6 = cfg.s6
+        attn = (d * 2 * s6.d_inner + s6.d_inner * (
+            s6.conv_kernel + 1 + s6.x_proj_dim + s6.dt_rank + 1
+            + s6.state_dim + 1 + d))
+    elif kind == GMU:
+        attn = 2 * d * cfg.s6.d_inner
+    elif kind == CROSS:
+        attn = 2 * d * cfg.q_dim
+    else:
+        attn = d * (cfg.q_dim + 2 * cfg.kv_dim) + cfg.q_dim * d
+    if cfg.differential_attention and kind not in (S6, GMU):
+        attn += 6 * cfg.head_dim
     if cfg.gated_attention:
         attn += d * cfg.q_dim
     norms = (4 if cfg.sandwich_norm else 2) * d

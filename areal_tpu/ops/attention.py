@@ -106,6 +106,7 @@ def attention_reference(
     if scale is None:
         scale = D ** -0.5
     qg = q.reshape(B, T, Hkv, G, D)
+    D = v.shape[-1]  # the output's width is the value's
     # scores: [B, Hkv, G, T, S]
     scores = jnp.einsum("btkgd,bskd->bkgts", qg * scale, k)
     m = jnp.broadcast_to(mask[:, :, None, :, :], scores.shape)
@@ -172,6 +173,15 @@ def packed_attention(
     if wanted_kernel:
         from areal_tpu.ops.pallas import flash_attention as fa
 
+        if v.shape[-1] > q.shape[-1]:
+            # A value wider than q/k (differential attention: 128 over
+            # 64): the kernels take ONE head size, and pad a smaller one
+            # to the 128 lanes anyway — q and k get the zeros here.
+            if scale is None:
+                scale = q.shape[-1] ** -0.5
+            wider = [(0, 0)] * 3 + [(0, v.shape[-1] - q.shape[-1])]
+            q, k = jnp.pad(q, wider), jnp.pad(k, wider)
+
     if wanted_kernel and fa.pick_block_sizes(
             q.shape[1], k.shape[1]) is not None:
         from areal_tpu.parallel.sharding import current_mesh
@@ -223,3 +233,50 @@ def decode_attention(
     else:
         mask = kv_valid[:, None, None, :]  # [B, 1, 1, S]
     return attention_reference(q, k_cache, v_cache, mask)
+
+
+# ---- differential attention (Differential Transformer; phi4flash) ----
+#
+# q's heads are pairs (2p, 2p+1) = (q1, q2) of pair p, k's heads pairs
+# (2j, 2j+1) = (k1, k2), v's heads pairs one value [v1 | v2] of twice the
+# head size; q's pair p reads k's and v's pair p // (pairs of q / pairs of
+# k). out_p = softmax(q1 k1) v - lambda * softmax(q2 k2) v. Both softmaxes
+# of every pair run in ONE attention call over heads laid out (k pair, 1 |
+# 2, q pair within it) against k's heads as they are and the value
+# repeated for k1 and k2 — grouped-query attention with a value wider
+# than q/k, which is what :func:`packed_attention` is handed.
+
+def differential_q(q: jnp.ndarray, n_kv_heads: int) -> jnp.ndarray:
+    """[B, T, 2P, D] -> the same heads in the order (k pair, 1 | 2, q pair
+    of the k pair), so that consecutive groups of q heads share a k head."""
+    B, T, H, D = q.shape
+    J = n_kv_heads // 2
+    G = H // n_kv_heads
+    return q.reshape(B, T, J, G, 2, D).transpose(0, 1, 2, 4, 3, 5).reshape(
+        B, T, H, D)
+
+
+def differential_v(v: jnp.ndarray) -> jnp.ndarray:
+    """[B, S, 2J, D] -> [B, S, 2J, 2D]: pair j's value [v1 | v2], once for
+    k1 and once for k2."""
+    B, S, H, D = v.shape
+    v = v.reshape(B, S, H // 2, 1, 2 * D)
+    return jnp.broadcast_to(v, (B, S, H // 2, 2, 2 * D)).reshape(
+        B, S, H, 2 * D)
+
+
+def differential_combine(out: jnp.ndarray,  # [B, T, 2P, 2D], differential_q's order
+                         n_kv_heads: int,
+                         lam: jnp.ndarray,  # scalar
+                         lambda_init,  # scalar
+                         subln: jnp.ndarray,  # [2D]
+                         eps: float) -> jnp.ndarray:
+    """``(o1 - lambda o2)``, RMS-normed over the value's width, times
+    ``1 - lambda_init``: [B, T, P, 2D] in q's pair order."""
+    B, T, H, D2 = out.shape
+    J = n_kv_heads // 2
+    o = out.reshape(B, T, J, 2, H // n_kv_heads, D2).astype(jnp.float32)
+    o = (o[:, :, :, 0] - lam * o[:, :, :, 1]).reshape(B, T, H // 2, D2)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+    return (o * subln.astype(jnp.float32) * (1.0 - lambda_init)).astype(
+        out.dtype)

@@ -81,6 +81,9 @@ _FALLBACK_HINTS = {
     "sp_sliding_window": "sliding-window attention is not ring-expressible",
     "layer_pattern": "layers of more than one attention kind are not "
                      "pipelined (a stage takes one RoPE table)",
+    "cross_layer_state": "a layer reads a tensor another layer made (a "
+                         "memory, one layer's K/V): it would have to "
+                         "travel with the micro-batch from stage to stage",
     "mixer_layers": "layers that are one mixer each (state-space, expert, "
                     "attention) make stages of unequal cost and have no "
                     "one stacked tree to split over pp",
@@ -124,6 +127,8 @@ def pick_pp_microbatches(
     pp = mesh.shape.get("pp", 1)
     if pp <= 1:
         return None  # no pipeline requested — not a fallback
+    if cfg.cross_layer_reads:
+        return _fallback("cross_layer_state")
     if cfg.is_hybrid:
         return _fallback("mixer_layers")
     sp = mesh.shape.get("sp", 1)

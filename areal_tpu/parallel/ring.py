@@ -54,7 +54,7 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.models.config import MAMBA
+from areal_tpu.models.config import MAMBA, S6
 from areal_tpu.parallel.mesh import DATA_AXES
 
 _NEG_INF = -1e30
@@ -348,6 +348,9 @@ RING_REFUSALS = {
     "state_space_scan": "the chunked state-space scan has no ring form: a "
                         "chunk's entering state is a sum over every chunk "
                         "before it, on whichever rank",
+    "selective_scan": "the selective scan (S6) is a recurrence over the "
+                      "row's tokens in order: a rank's first state is the "
+                      "rank before's last",
 }
 
 
@@ -359,6 +362,8 @@ def ring_refusal(cfg, kind: Optional[str] = None) -> Optional[str]:
     the scan cannot take."""
     if MAMBA in cfg.layer_kinds:
         return "state_space_scan"
+    if S6 in cfg.layer_kinds:
+        return "selective_scan"
     kinds = cfg.layer_kinds if kind is None else (kind,)
     windowed = any(cfg.window_of(k) is not None for k in kinds)
     return "sliding_window" if windowed else None

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.models.config import TransformerConfig
+from areal_tpu.models.config import CROSS, FULL, GMU, S6, TransformerConfig
 from areal_tpu.parallel.mesh import DATA_AXES
 
 Params = Dict[str, Any]
@@ -70,26 +70,49 @@ def param_partition_specs(cfg: TransformerConfig) -> Params:
 
 
 def _block_partition_specs(cfg: TransformerConfig, zero, lead,
-                           dense_ffn: bool) -> Params:
+                           dense_ffn: bool, kind: str = FULL) -> Params:
     """The specs of whole blocks stacked on a leading axis split over
     ``lead`` ("pp", or None: a kind's stack of a tree per kind); the FFN
-    is the expert layer where the model has one, unless ``dense_ffn``."""
+    is the expert layer where the model has one, unless ``dense_ffn``;
+    the mixer by ``kind``: attention (a cross layer has q and o alone),
+    an S6 mixer or a gated memory unit — their matrices ZeRO-3 on the
+    hidden dim, the channels whole (the scan and the memory it hands on
+    are not split)."""
     layers: Params = {
         "ln1": P(lead, None),
         "ln2": P(lead, None),
-        "wq": P(lead, zero, "tp"),
-        "wk": P(lead, zero, "tp"),
-        "wv": P(lead, zero, "tp"),
-        "wo": P(lead, "tp", zero),
         "w_gate": P(lead, zero, "tp"),
         "w_up": P(lead, zero, "tp"),
         "w_down": P(lead, "tp", zero),
     }
-    if cfg.use_attention_bias:
+    attends = kind not in (S6, GMU)
+    if kind == S6:
+        layers.update({
+            "in_proj": P(lead, zero, None), "out_proj": P(lead, None, zero),
+            "conv_w": P(lead, None, None), "conv_b": P(lead, None),
+            "x_proj": P(lead, None, None), "dt_proj": P(lead, None, None),
+            "dt_bias": P(lead, None), "A_log": P(lead, None, None),
+            "D": P(lead, None),
+        })
+    elif kind == GMU:
+        layers["gmu_in"] = P(lead, zero, None)
+        layers["gmu_out"] = P(lead, None, zero)
+    else:
+        layers["wq"] = P(lead, zero, "tp")
+        layers["wo"] = P(lead, "tp", zero)
+        if kind != CROSS:
+            layers["wk"] = P(lead, zero, "tp")
+            layers["wv"] = P(lead, zero, "tp")
+        if cfg.differential_attention:
+            for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2",
+                         "subln"):
+                layers[name] = P(lead, None)
+    if cfg.use_attention_bias and attends:
         layers["bq"] = P(lead, "tp")
-        layers["bk"] = P(lead, "tp")
-        layers["bv"] = P(lead, "tp")
-    if cfg.use_attn_output_bias:
+        if kind != CROSS:
+            layers["bk"] = P(lead, "tp")
+            layers["bv"] = P(lead, "tp")
+    if cfg.use_attn_output_bias and attends:
         layers["bo"] = P(lead, None)
     if cfg.use_qk_norm:
         layers["q_norm"] = P(lead, None)
@@ -142,7 +165,8 @@ def _hybrid_partition_specs(cfg: TransformerConfig, zero) -> Params:
 
     # whole blocks whose FFN kinds differ (afmoe): a stack a kind
     layers: Params = {
-        kind: _block_partition_specs(cfg, zero, None, has_dense_ffn(kind))
+        kind: _block_partition_specs(cfg, zero, None, has_dense_ffn(kind),
+                                     kind)
         for kind in dict.fromkeys(cfg.layer_kinds) if kind not in MIXER_KINDS}
     if cfg.n_layers_of(MAMBA):
         layers[MAMBA] = {
@@ -173,6 +197,8 @@ def _hybrid_partition_specs(cfg: TransformerConfig, zero) -> Params:
             name: by_name[name] for name in moe_param_shapes(cfg)}}
     specs: Params = {"embedding": P("tp", zero), "layers": layers,
                      "final_ln": P(None)}
+    if cfg.norm_type == "layer":
+        specs["final_ln_b"] = P(None)
     if not cfg.tie_word_embeddings:
         specs["lm_head"] = P(zero, "tp")
     return specs

@@ -353,6 +353,18 @@ class TrainerWorker:
             # state-space layers
             ssm_geometry={"%dx%d/%d/h%dg%d" % geom: n
                           for geom, n in ssm.geometry_counts().items()},
+            # {"rows x length/dD nN/impl": scans traced}: a model's selective
+            # scans (S6), and which form each runs as
+            s6_geometry={"%dx%d/d%dn%d/%s" % geom: n
+                         for geom, n in ssm.s6_geometry_counts().items()},
+            # {model: {"memory" | "kv": layers that read what ONE earlier
+            # layer made}}: empty for layers that read the stream alone
+            cross_layer_reads={
+                role: m.module.cfg.cross_layer_reads
+                for role, m in self.models.items()
+                if hasattr(getattr(m.module, "cfg", None),
+                           "cross_layer_reads")
+            },
             # {"entries>rows/token rows x width": "rows" | "entries"}: how
             # each expert pass traced adds its rows into their tokens
             moe_combine={"%d>%d/%dx%d" % key: how

@@ -70,6 +70,10 @@ EXPERT_PARENT = {"mellum": (1.8047, 236), "mellum-6016": (1.9004, None),
 # source is therefore padded to whole row tiles (moe._whole_row_tiles),
 # with which every multiple of 128 up to 4096 compiles (PERF.md §6, PR 34).
 LATENT_T = (3072, 3456, 3712)
+# The phi4flash (SambaY) cell: its selective scan (rows, length, d_inner,
+# states) and its packed grid, at the published widths.
+SAMBAY_SCAN = (1, 8192, 5120, 16)
+SAMBAY_GRID = (1, 8192)
 
 
 def _compile_all():
@@ -328,6 +332,48 @@ def _compile_all():
     out["latent-gemms"] = {"%dx%dx%d/%d" % key: how
                            for key, how in moemod.gemm_counts().items()
                            if key[-1] == hybrid.moe.num_experts}
+
+    # The phi4flash (SambaY) cell at the published widths: the selective
+    # scan's kernels alone, forward + backward, and the whole six-layer
+    # cut forward + backward (no head) at the cell's grid.
+    from areal_tpu.models import ssm as ssmmod
+
+    def f32(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def scan_loss(x, dt, A, Bm, Cm, Dk, seg):
+        return jnp.sum(ssmmod.selective_scan(x, dt, A, Bm, Cm, Dk, seg,
+                                             "pallas") ** 2)
+
+    R, T, Dn, N = SAMBAY_SCAN
+    record("s6-scan", jax.jit(
+        jax.value_and_grad(scan_loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+            f32(R, T, Dn, dtype=jnp.bfloat16), f32(R, T, Dn), f32(Dn, N),
+            f32(R, T, N, dtype=jnp.bfloat16), f32(R, T, N, dtype=jnp.bfloat16),
+            f32(Dn), f32(R, T, dtype=jnp.int32)).compile())
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "phi-4-mini-flash-reasoning.json")) as f:
+        sambay = weights.model_config(json.load(f))
+    shapes = jax.eval_shape(
+        lambda: transformer.init_params(sambay, jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, jnp.bfloat16, sharding=chip), shapes)
+    tok = jax.ShapeDtypeStruct(SAMBAY_GRID, jnp.int32, sharding=chip)
+
+    def sambay_grad(p, tokens, pos, seg):
+        def loss(p):
+            y, _ = transformer.forward(
+                p, sambay, tokens, pos, segment_ids=seg, attn_impl="pallas",
+                remat="full", return_kv=False, return_hidden=True)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        return jax.value_and_grad(loss)(p)
+
+    scans = sum(ssmmod.s6_geometry_counts().values())
+    record("sambay-cell", jax.jit(sambay_grad).lower(
+        params, tok, tok, tok).compile())
+    out["sambay-cell"]["scans_traced"] = sum(
+        ssmmod.s6_geometry_counts().values()) - scans
     return out
 
 
@@ -485,3 +531,26 @@ def test_a_latent_expert_layer_compiles_at_the_hybrid_cells_rows(compiled,
     # width only the whole-buffer branch's row gather — the parent sorted
     # twice (the inverse permutation) and un-permuted [22 x T, 1024] too
     assert got["entry_sorts"] == 1 and got["entry_gathers"] == 1
+
+
+def test_the_selective_scan_kernels_compile_for_v5e(compiled):
+    """Forward and backward at the phi4flash cell's row (1 x 8192, 5120
+    channels of 16 states): two Mosaic kernels, and no [T, d_inner, N]
+    array beside them — the temporaries are the float32 copies of x, Δ,
+    y and their gradients and the column layouts of B and C."""
+    got = compiled["s6-scan"]
+    assert got["custom_calls"] == 2
+    R, T, Dn, N = SAMBAY_SCAN
+    assert got["temp_bytes"] < R * T * Dn * N * 4 / 2
+
+
+def test_the_sambay_cell_compiles_at_the_published_widths(compiled):
+    """The six-layer cut (M S M F G X) forward + backward under "full" at
+    1 x 8192: every attention layer a kernel, both scans the Pallas
+    kernels, in the temporaries the cell has room for beside 12.6 GB of
+    state and a 1.4 GB gradient."""
+    got = compiled["sambay-cell"]
+    # 2 M layers x (forward, the forward the checkpoint re-runs, backward)
+    assert got["scans_traced"] == 2
+    assert got["custom_calls"] >= 6 + 3 * 4
+    assert got["temp_bytes"] < 2.4e9
