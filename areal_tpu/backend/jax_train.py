@@ -730,11 +730,11 @@ class JaxTrainEngine(TrainableEngine):
         [R, L]: its rows split over the data axes (an axis that splits
         the sequence or the widths is not counted: an over-estimate)."""
         rows = self._rows_on_chip(R)
-        flash = kernel_padded_len(self.attn_impl, L)
+        full = kernel_padded_len(self.attn_impl, L)
         window = kernel_padded_len(self.attn_impl, L, self.cfg.sliding_window)
         return transformer.remat_kept_bytes(
             self.cfg, rows * L, self.compute_dtype.itemsize,
-            flash_tokens=rows * (flash or 0),
+            full_tokens=rows * (full or 0),
             window_tokens=rows * (window or 0))
 
     def _remat_budget_bytes(self, R: int, L: int) -> int:

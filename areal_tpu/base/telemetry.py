@@ -266,11 +266,12 @@ DEVICE_SCOPES = (
 # apart because they nest: a reader that knows only DEVICE_SCOPES sees
 # their ops under "moe".
 MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_exchange", "moe_experts")
-# Inside "attention" (ops/attention.py): the windowed kernel of a
-# sliding-window layer with its layout glue (ops/pallas/window_attention.py).
-# Listed apart like MOE_SCOPES: a reader that knows only DEVICE_SCOPES sees
-# its ops under "attention", beside the flash kernel's.
-WINDOW_SCOPES = ("window_attention",)
+# Inside "attention" (ops/attention.py): the grouped-head kernel with its
+# layout glue (ops/pallas/window_attention.py) — a sliding-window layer's
+# call, and a full-causal one's. Listed apart like MOE_SCOPES: a reader
+# that knows only DEVICE_SCOPES sees their ops under "attention", beside
+# the flash kernel's.
+WINDOW_SCOPES = ("window_attention", "causal_attention")
 # A block with gated attention and sandwich norms (afmoe,
 # models/transformer.py): the gate's projection (inside "qkv_proj") and
 # its sigmoid-multiply (inside "o_proj"), the norm on the attention

@@ -874,8 +874,8 @@ def _scan_mixer_layers(cfg: TransformerConfig, layer: Callable, h, xs,
 # least kept to the most; each entry keeps what the one before it keeps.
 #   full      — the layer's input only: the backward re-runs the layer.
 #   attention — and what the attention kernel's backward reads of its
-#               forward (its output and softmax statistics; the flash
-#               kernel's or, on a sliding-window layer, the windowed
+#               forward (its output and softmax statistics; the
+#               grouped-head kernel's, full or windowed, or the flash
 #               kernel's): the forward kernel is not re-run. The padded
 #               (flash: and repeated) q/k/v it was handed are recomputed;
 #               the XLA reference attention keeps nothing.
@@ -907,7 +907,7 @@ def _remat_policy(entry: str):
     policies = jax.checkpoint_policies
     if entry == "full":
         return None
-    # The windowed (splash) kernel names its output and statistics.
+    # The grouped-head (splash) kernel names its output and statistic.
     kernels = policies.save_from_both_policies(
         _flash_residuals_saveable, policies.save_only_these_names(RESIDUALS))
     if entry == "attention":
@@ -957,26 +957,26 @@ def _block_matmul_widths(cfg: TransformerConfig, dense_ffn: bool,
 
 def remat_kept_bytes(
     cfg: TransformerConfig, tokens: int, itemsize: int,
-    flash_tokens: int = 0, window_tokens: int = 0,
+    full_tokens: int = 0, window_tokens: int = 0,
 ) -> Dict[str, int]:
     """Bytes the layer scan keeps between its forward and its backward
     pass under each entry of ``REMAT_ENTRIES``, for ``tokens`` tokens in
-    a compute dtype of ``itemsize`` bytes. ``flash_tokens``: the tokens
-    of the flash kernel's output, rows x PADDED length (0 where attention
-    takes the XLA reference), on the full-attention layers;
-    ``window_tokens`` likewise of the windowed kernel's, on the
-    sliding-window layers. Arithmetic on the widths in ``cfg``,
+    a compute dtype of ``itemsize`` bytes. ``full_tokens``: the tokens of
+    the attention kernel's output, rows x PADDED length (0 where
+    attention takes the XLA reference), on the full-attention layers;
+    ``window_tokens`` likewise on the sliding-window layers, whose tile
+    and so padded length may differ. Arithmetic on the widths in ``cfg``,
     checked against what jax really keeps in tests/test_remat_plan.py and
     against the chip's compiler in PERF.md §5."""
     from areal_tpu.ops.pallas.flash_attention import LANE
 
     full = tokens * cfg.hidden_dim * itemsize
-    # The flash kernel writes heads padded to the lane width, and two
-    # float32 statistics a head; the windowed kernel one (a logsumexp).
+    # The kernel writes heads padded to the lane width, and one float32
+    # statistic a head (a logsumexp).
     lanes = -(-cfg.head_dim // LANE) * LANE
     n_sliding = cfg.layer_kinds.count(SLIDING)
-    flash = flash_tokens * cfg.n_q_heads * (lanes * itemsize + 2 * 4)
-    window = window_tokens * cfg.n_q_heads * (lanes * itemsize + 4)
+    per_token = cfg.n_q_heads * (lanes * itemsize + 4)
+    causal, window = full_tokens * per_token, window_tokens * per_token
     if cfg.is_hybrid:
         # A mixer layer's matmuls whose outputs its backward reads: the
         # Mamba in-projection (the scan's einsums carry batch dimensions
@@ -990,7 +990,7 @@ def remat_kept_bytes(
             MOE_ONLY: (moe.n_routed + (moe.latent_dim or 0)
                        + (moe.shared_intermediate_dim or 0)) if moe else 0,
         }
-        kernel = {ATTENTION_ONLY: flash, FULL: flash, CROSS: flash,
+        kernel = {ATTENTION_ONLY: causal, FULL: causal, CROSS: causal,
                   SLIDING: window}
         for kind in cfg.layer_kinds:  # whole blocks whose FFN kinds differ
             if kind not in MIXER_KINDS:
@@ -1011,7 +1011,7 @@ def remat_kept_bytes(
             widths[kind] for kind in cfg.layer_kinds)
         return kept
     matmuls = tokens * _block_matmul_widths(cfg, False) * itemsize
-    attention = (cfg.n_layers - n_sliding) * flash + n_sliding * window
+    attention = (cfg.n_layers - n_sliding) * causal + n_sliding * window
     kept = {"full": cfg.n_layers * full}
     kept["attention"] = kept["full"] + attention
     kept["matmuls"] = kept["attention"] + cfg.n_layers * matmuls

@@ -4,9 +4,10 @@ Replaces the reference's flash-attn varlen path
 (``realhf/impl/model/modules/attn.py:24-27``): instead of 1-D ragged batches,
 areal_tpu packs sequences into ``[B, L]`` rows with per-token segment ids
 (0 = padding) and uses block-causal same-segment masking — the layout TPU
-splash-attention kernels natively support. A Pallas flash kernel backs the
-TPU path (``areal_tpu/ops/pallas/flash_attention.py``); this module holds the
-pure-XLA reference used on CPU and for parity tests.
+splash-attention kernels natively support. Pallas kernels back the TPU
+path (``areal_tpu/ops/pallas/window_attention.py``: causal self-attention,
+full or windowed; ``flash_attention.py``: the rest); this module holds the
+dispatch and the pure-XLA reference used on CPU and for parity tests.
 
 Shapes: q ``[B, T, Hq, D]``; k, v ``[B, S, Hkv, D]`` with Hq = G * Hkv (GQA).
 """
@@ -26,12 +27,23 @@ _NEG_INF = -1e30
 
 # Which implementation each packed_attention call TRACED to, by the label
 # of the compiled step it was traced for: {label: {"pallas" | "reference"
-# | "window" | "fallback": n}}. "pallas" = the flash kernel, "window" = the
-# windowed kernel of a sliding-window layer, "fallback" = a TPU kernel was
-# wanted and the O(S^2) reference ran instead (a shape with no
-# 128-multiple block). Counted at trace time, so it describes the compiled programs;
-# chip_smoke.py fails when the train step's fallback count is not zero.
+# | "window" | "fallback": n}}. "pallas" = a Pallas kernel over a full
+# layer, "window" = the windowed kernel of a sliding-window layer,
+# "fallback" = a TPU kernel was wanted and the O(S^2) reference ran
+# instead (a shape with no 128-multiple block). Counted at trace time, so
+# it describes the compiled programs; chip_smoke.py fails when the train
+# step's fallback count is not zero.
 _DISPATCH: Dict[str, collections.Counter] = collections.defaultdict(
+    collections.Counter
+)
+# Which KERNEL each of those kernel calls ran, the same way: {label:
+# {"causal" | "window" | "flash": n}}. "causal" = the grouped-head kernel
+# under a causal mask (a packed row over itself, no window), "window" =
+# the same kernel under a window, "flash" = jax's flash kernel (what the
+# grouped kernel does not take: a non-causal call, T != S). A count beside
+# _DISPATCH and not a label in it: every `correct` of the benchmark holds
+# a step's labels to exactly {"pallas"} or {"pallas", "window"}.
+_KERNELS: Dict[str, collections.Counter] = collections.defaultdict(
     collections.Counter
 )
 _LABEL = contextvars.ContextVar("attention_dispatch_label",
@@ -53,12 +65,18 @@ def active_label() -> str:
     return _LABEL.get()
 
 
-def count_dispatch(impl: str) -> None:
+def count_dispatch(impl: str, kernel: Optional[str] = None) -> None:
     _DISPATCH[active_label()][impl] += 1
+    if kernel is not None:
+        _KERNELS[active_label()][kernel] += 1
 
 
 def dispatch_counts() -> Dict[str, Dict[str, int]]:
     return {label: dict(c) for label, c in _DISPATCH.items()}
+
+
+def kernel_counts() -> Dict[str, Dict[str, int]]:
+    return {label: dict(c) for label, c in _KERNELS.items()}
 
 
 def segment_mask(
@@ -129,20 +147,16 @@ def _wants_kernel(impl: str) -> bool:
 def kernel_padded_len(
     impl: str, length: int, sliding_window: Optional[int] = None,
 ) -> Optional[int]:
-    """The padded row length at which packed self-attention over rows of
-    ``length`` tokens runs its Pallas kernel (the windowed one under a
-    ``sliding_window``) — what the kernel's output and softmax statistics
-    span; None where :func:`packed_attention` takes the XLA reference."""
+    """The padded row length at which packed causal self-attention over
+    rows of ``length`` tokens runs its Pallas kernel (the grouped-head
+    one, under a ``sliding_window`` or without) — what the kernel's output
+    and softmax statistic span; None where :func:`packed_attention` takes
+    the XLA reference."""
     if not _wants_kernel(impl):
         return None
-    if sliding_window is not None:
-        from areal_tpu.ops.pallas import window_attention as wa
+    from areal_tpu.ops.pallas import window_attention as wa
 
-        return wa.padded_len(length, sliding_window)
-    from areal_tpu.ops.pallas import flash_attention as fa
-
-    blocks = fa.pick_block_sizes(length, length)
-    return None if blocks is None else fa._round_up(length, blocks[0])
+    return wa.padded_len(length, sliding_window)
 
 
 def packed_attention(
@@ -158,17 +172,20 @@ def packed_attention(
     impl: str = "auto",
     scale: Optional[float] = None,
 ) -> jnp.ndarray:
-    """Dispatch between the XLA reference and the Pallas TPU kernel.
+    """Dispatch between the XLA reference and the Pallas TPU kernels.
 
-    ``impl="auto"`` means a kernel on a TPU and the reference elsewhere:
-    the flash kernel, or under a ``sliding_window`` (causal self-attention
-    over one packed row) the windowed kernel, which skips the key blocks
-    outside the window and is counted as "window". On a TPU nothing
-    quietly replaces a kernel: a failed import raises, and the one case
-    the kernels cannot run (a sequence dim with no 128-multiple block — a
-    prompt bucket, never a packed training row) is counted as "fallback"
-    under the active :func:`dispatch_label`. ``scale`` defaults to
-    ``head_dim ** -0.5`` in every implementation."""
+    ``impl="auto"`` means a kernel on a TPU and the reference elsewhere.
+    Which kernel follows from the call itself: a causal call of a packed
+    row over itself (``T == S``) takes the grouped-head kernel — K and V
+    at their own head count — under a causal mask, or under a
+    ``sliding_window`` a windowed one, which skips the key blocks outside
+    the window (counted as "window"); a non-causal call or one with
+    ``T != S`` takes jax's flash kernel, K and V repeated. On a TPU
+    nothing quietly replaces a kernel: a failed import raises, and the
+    one case the kernels cannot run (a sequence dim with no 128-multiple
+    block — a prompt bucket, never a packed training row) is counted as
+    "fallback" under the active :func:`dispatch_label`. ``scale`` defaults
+    to ``head_dim ** -0.5`` in every implementation."""
     wanted_kernel = _wants_kernel(impl)
     if wanted_kernel:
         from areal_tpu.ops.pallas import flash_attention as fa
@@ -188,8 +205,25 @@ def packed_attention(
 
         mesh = current_mesh()
         on_mesh = mesh is not None and mesh.size > 1
+        if causal and q.shape[1] == k.shape[1]:
+            # A packed row over itself: the grouped-head kernel, K/V at
+            # their own head count, under a causal or a windowed mask.
+            from areal_tpu.ops.pallas import window_attention as wa
+
+            impl, which, scope = (
+                ("pallas", "causal", wa.CAUSAL_SCOPE)
+                if sliding_window is None else
+                ("window", "window", wa.SCOPE))
+            count_dispatch(impl, kernel=which)
+            kernel = partial(wa.window_attention, window=sliding_window,
+                             scale=scale)
+            with jax.named_scope(scope):
+                if on_mesh:
+                    return fa.kernel_on_mesh(
+                        kernel, mesh, q, k, v, q_segment_ids, kv_segment_ids)
+                return kernel(q, k, v, q_segment_ids, kv_segment_ids)
         if sliding_window is None:
-            count_dispatch("pallas")
+            count_dispatch("pallas", kernel="flash")
             if on_mesh:
                 return fa.flash_attention_on_mesh(
                     mesh, q, k, v, q_segment_ids, kv_segment_ids,
@@ -199,17 +233,6 @@ def packed_attention(
                 q, k, v, q_segment_ids, kv_segment_ids,
                 causal=causal, scale=scale,
             )
-        if causal and q.shape[1] == k.shape[1]:
-            from areal_tpu.ops.pallas import window_attention as wa
-
-            count_dispatch("window")
-            kernel = partial(wa.window_attention, window=sliding_window,
-                             scale=scale)
-            with jax.named_scope(wa.SCOPE):
-                if on_mesh:
-                    return fa.kernel_on_mesh(
-                        kernel, mesh, q, k, v, q_segment_ids, kv_segment_ids)
-                return kernel(q, k, v, q_segment_ids, kv_segment_ids)
     count_dispatch("fallback" if wanted_kernel else "reference")
     mask = segment_mask(
         q_segment_ids, kv_segment_ids, q_positions, kv_positions, causal,

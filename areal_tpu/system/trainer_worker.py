@@ -336,7 +336,16 @@ class TrainerWorker:
         monitor.log_device_report(
             logger, f"trainer{self.cfg.dist_rank}", stage=stage,
             attention=attention.dispatch_counts(),
-            # {label: {"length>padded/tile": calls traced}}
+            # {label: {"causal" | "window" | "flash": calls traced}}: which
+            # kernel the "pallas" and "window" calls above ran
+            attention_kernels=attention.kernel_counts(),
+            # {label: {"length>padded/tile": calls traced}}: the grouped
+            # kernel's full-causal calls, and the flash kernel's
+            causal_geometry={
+                label: {"%d>%d/%d" % geom: n for geom, n in counts.items()}
+                for label, counts in
+                window_attention.causal_geometry_counts().items()
+            },
             flash_geometry={
                 label: {"%d>%d/%d" % geom: n for geom, n in counts.items()}
                 for label, counts in flash_attention.geometry_counts().items()

@@ -123,10 +123,42 @@ def test_flash_on_mesh_matches_reference(spec, row_len):
     mesh = pmesh.make_mesh(pmesh.ParallelSpec.parse(spec))
     with pltpu.force_tpu_interpret_mode(), psh.activation_sharding(mesh):
         out = jax.jit(
+            lambda q, k, v: fa.flash_attention_on_mesh(mesh, q, k, v, seg,
+                                                       seg)
+        )(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-2)
+
+
+@pytest.mark.parametrize(
+    "spec,row_len", [("f2", 128), ("d2t2", 128), ("t4", 128), ("d2t2", 640)])
+def test_the_dispatch_on_a_mesh_matches_reference(spec, row_len, monkeypatch):
+    """The same through ``packed_attention``: a causal call of a row over
+    itself takes the grouped-head kernel inside the same shard_map (rows
+    over the data axes, key/value heads over tp where they divide), K/V
+    at their 2 heads."""
+    import functools
+
+    from areal_tpu.ops.pallas import window_attention as wa
+    from areal_tpu.parallel import mesh as pmesh
+    from areal_tpu.parallel import sharding as psh
+
+    monkeypatch.setattr(wa, "CAUSAL_TILE_COST", {128: 1.0, 256: 0.5})
+    monkeypatch.setattr(wa, "window_attention", functools.partial(
+        wa.window_attention, interpret=True))
+    layout, grid, q, k, v = _packed_case(MESH_ROWS[row_len], D=64,
+                                         row_len=row_len)
+    seg = jnp.asarray(grid["segment_ids"])
+    assert wa.padded_len(row_len) == {128: 128, 640: 768}[row_len]
+    ref = attn.packed_attention(q, k, v, seg, seg, impl="reference")
+    mesh = pmesh.make_mesh(pmesh.ParallelSpec.parse(spec))
+    with attn.dispatch_label(f"mesh-{spec}-{row_len}"), \
+            psh.activation_sharding(mesh):
+        out = jax.jit(
             lambda q, k, v: attn.packed_attention(q, k, v, seg, seg,
                                                   impl="pallas")
         )(q, k, v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-2)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
+    assert attn.kernel_counts()[f"mesh-{spec}-{row_len}"] == {"causal": 1}
 
 
 # ---------------- tile selection (CPU, no interpreter) ------------
@@ -167,18 +199,28 @@ def test_pick_tile_rule(L):
 
 def test_geometry_counts_under_the_active_label():
     """A 6016-token row (47 x 128) is traced at 6144 with blocks of 512,
-    counted beside — not in — the dispatch counts."""
+    counted beside — not in — the dispatch counts: by the flash kernel
+    for a non-causal call, by the grouped-head kernel (its own tile rule,
+    its own count) for a causal one."""
+    from areal_tpu.ops.pallas import window_attention as wa
+
     q = jax.ShapeDtypeStruct((1, 6016, 14, 64), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((1, 6016, 2, 64), jnp.bfloat16)
     seg = jax.ShapeDtypeStruct((1, 6016), jnp.int32)
-    with attn.dispatch_label("t6016"):
-        out = jax.eval_shape(
-            lambda q, k, v, s: attn.packed_attention(q, k, v, s, s,
-                                                     impl="pallas"),
-            q, kv, kv, seg)
-    assert out.shape == q.shape
-    assert fa.geometry_counts()["t6016"] == {(6016, 6144, 512): 1}
-    assert attn.dispatch_counts()["t6016"] == {"pallas": 1}
+    for causal in (False, True):
+        with attn.dispatch_label(f"t6016-{causal}"):
+            out = jax.eval_shape(
+                lambda q, k, v, s: attn.packed_attention(
+                    q, k, v, s, s, causal=causal, impl="pallas"),
+                q, kv, kv, seg)
+        assert out.shape == q.shape
+        assert attn.dispatch_counts()[f"t6016-{causal}"] == {"pallas": 1}
+    assert fa.geometry_counts()["t6016-False"] == {(6016, 6144, 512): 1}
+    assert "t6016-True" not in fa.geometry_counts()
+    tile = wa.pick_tile(6016)
+    assert wa.causal_geometry_counts()["t6016-True"] == {
+        (6016, -(-6016 // tile) * tile, tile): 1}
+    assert "t6016-False" not in wa.causal_geometry_counts()
 
 
 def test_non_divisible_shape_falls_back_to_reference():

@@ -88,14 +88,14 @@ def test_unknown_entry_is_refused():
                         _grid(np.random.RandomState(0), 2, 16), "dots")
 
 
-@pytest.mark.parametrize("entry,calls", [("full", 4), ("attention", 3),
-                                         ("matmuls", 3), (False, 3)])
+@pytest.mark.parametrize("entry,calls", [("full", 3), ("attention", 2),
+                                         ("matmuls", 2), (False, 2)])
 @pytest.mark.parametrize("on_mesh", [False, True])
-def test_flash_forward_is_kept_not_rerun(entry, calls, on_mesh):
+def test_the_forward_kernel_is_kept_not_rerun(entry, calls, on_mesh):
     """The gradient's jaxpr holds the forward kernel twice under "full"
-    (forward, recomputation) beside dKV and dQ, and once where its
-    residuals are kept — also through the kernel's shard_map on a mesh.
-    Traced only: the kernel does not run on the CPU."""
+    (forward, recomputation) beside the fused backward kernel, and once
+    where its residuals are kept — also through the kernel's shard_map on
+    a mesh. Traced only: the kernel does not run on the CPU."""
     cfg = tiny_config(vocab_size=64, n_layers=2, hidden_dim=128)
     params = transformer.init_params(cfg, jax.random.PRNGKey(0))
     tokens, pos, seg = _grid(np.random.RandomState(0), 4, 256)
@@ -114,7 +114,34 @@ def test_flash_forward_is_kept_not_rerun(entry, calls, on_mesh):
             jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
     else:
         jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
-    assert str(jaxpr).count("pallas_call[") == calls
+    text = str(jaxpr)
+    assert text.count("pallas_call[") == calls
+    assert text.count("name=splash_mqa_fwd") == calls - 1
+    assert "flash" not in text
+
+
+@pytest.mark.parametrize("entry,calls", [("full", 4), ("attention", 3)])
+def test_the_flash_kernels_residuals_are_kept_where_it_still_runs(entry,
+                                                                  calls):
+    """A call the grouped kernel does not take (non-causal) runs jax's
+    flash kernel, whose forward rule's outputs the same policy keeps:
+    forward twice beside dKV and dQ under "full", once under
+    "attention"."""
+    from areal_tpu.ops import attention
+
+    q = jnp.zeros((2, 256, 4, 64), jnp.bfloat16)
+    kv = jnp.zeros((2, 256, 2, 64), jnp.bfloat16)
+    seg = jnp.ones((2, 256), jnp.int32)
+
+    def loss(q, k, v):
+        out = attention.packed_attention(q, k, v, seg, seg, causal=False,
+                                         impl="pallas")
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    body = jax.checkpoint(loss, policy=transformer._remat_policy(entry))
+    text = str(jax.make_jaxpr(jax.grad(body, argnums=(0, 1, 2)))(q, kv, kv))
+    assert text.count("pallas_call[") == calls
+    assert "splash" not in text
 
 
 def test_expert_layer_is_never_kept():
@@ -182,15 +209,15 @@ MOE_WIDTHS = dict(
 def test_estimate_matches_what_jax_keeps(widths, attn_impl, entry):
     """Within 5 %: the arithmetic names every kept array (per token and
     layer in the compute dtype; the kernel's output at the PADDED length,
-    heads of 64 in 128 lanes, two float32 statistics a head)."""
+    heads of 64 in 128 lanes, one float32 statistic a head)."""
     cfg = dataclasses.replace(tiny_config(), **widths)
-    rows, length = 2, 640  # the kernel pads 640 to 768 (tile 384)
+    rows, length = 2, 640  # the kernel pads 640 to 768 (its tile)
     from areal_tpu.ops.attention import kernel_padded_len
 
     padded = kernel_padded_len(attn_impl, length) or 0
     assert padded == (768 if attn_impl == "pallas" else 0)
     est = transformer.remat_kept_bytes(cfg, rows * length, 2,
-                                       flash_tokens=rows * padded)
+                                       full_tokens=rows * padded)
     got = _saved_bytes(cfg, rows, length, entry, attn_impl)
     assert got == pytest.approx(est[entry], rel=0.05)
     assert est["full"] <= est["attention"] <= est["matmuls"]
@@ -202,9 +229,9 @@ def test_estimate_at_the_benchmarks_widths():
     """The issue's arithmetic: per token and layer ~3.7 KB under
     "attention" and ~27 KB of matmul outputs at Qwen2.5-0.5B's widths."""
     cfg = dataclasses.replace(tiny_config(), **{**QWEN_WIDTHS, "n_layers": 1})
-    est = transformer.remat_kept_bytes(cfg, 1, 2, flash_tokens=1)
+    est = transformer.remat_kept_bytes(cfg, 1, 2, full_tokens=1)
     assert est["full"] == 896 * 2
-    assert est["attention"] - est["full"] == 14 * (128 * 2 + 8)
+    assert est["attention"] - est["full"] == 14 * (128 * 2 + 4)
     assert est["matmuls"] - est["attention"] == 2 * (
         896 + 128 + 128 + 896 + 4864 + 4864)
 
@@ -262,21 +289,24 @@ def test_plan_records_entry_bytes_and_budget():
 # What the chip recorded for the benchmark's seven grids, parent and change
 # alike (PERF.md §6, PR 28 and 29; `bytes_limit` of `memory_stats()` there):
 # (configuration, mesh, bytes_limit, R, L) -> (entry, kept estimate, budget).
+# The estimates are PR 45's: a full layer keeps ONE float32 statistic a head
+# at the grouped kernel's padded length (7296 runs at 8192, 3712 at 3840);
+# entries and budgets are as recorded.
 BENCHMARK_GRIDS = {
     ("qwen2.5-0.5b", None, 16909336064, 8, 512):
-        ("attention", 539492352, 2247630524),
+        ("attention", 533987328, 2247630524),
     ("qwen2.5-0.5b", None, 16909336064, 1, 2688):
-        ("matmuls", 1907490816, 2707571183),
+        ("matmuls", 1903362048, 2707571183),
     ("qwen2.5-0.5b", None, 16909336064, 1, 3072):
-        ("matmuls", 2141061120, 2957127074),
+        ("matmuls", 2136932352, 2957127074),
     ("qwen2.5-0.5b", None, 16909336064, 1, 6016):
-        ("attention", 803733504, 1620438716),
+        ("attention", 795475968, 1620438716),
     ("qwen2.5-0.5b", None, 16909336064, 1, 7296):
-        ("attention", 995033088, 1202310844),
+        ("attention", 1029439488, 1202310844),
     ("olmoe-1b-7b", "e4", 16909334528, 4, 3712):
-        ("matmuls", 375193600, 1857308104),
+        ("matmuls", 369885184, 1857308104),
     ("olmoe-1b-7b", "e4", 16909334528, 4, 3968):
-        ("matmuls", 396296192, 1756644808),
+        ("matmuls", 395247616, 1756644808),
 }
 
 
@@ -533,8 +563,9 @@ def _kernel_calls(jaxpr):
 def test_window_forward_is_kept_not_rerun(entry, calls):
     """A period of the pattern in the gradient's jaxpr: each of its four
     layers holds its kernel's forward twice under "full" and once where
-    the residuals are kept — the flash kernel's by its forward rule, the
-    windowed (splash) kernel's by their checkpoint name."""
+    the residuals are kept, by their checkpoint name — beside dKV and dQ
+    on a windowed layer, and the one fused backward kernel on the full
+    one. No flash kernel is in it."""
     cfg = dataclasses.replace(tiny_config(), **MELLUM_WIDTHS)
     params = transformer.init_params(cfg, jax.random.PRNGKey(0))
     tokens, pos, seg = _grid(np.random.RandomState(0), 2, 512, vocab=512)
@@ -546,31 +577,32 @@ def test_window_forward_is_kept_not_rerun(entry, calls):
         return jnp.sum(y.astype(jnp.float32) ** 2)
 
     names = _kernel_calls(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
-    assert len(names) == 4 * calls
-    assert sum("splash_mqa_fwd" in n for n in names) == 3 * (calls - 2)
-    assert sum("splash" not in n for n in names) == calls  # the full layer
+    assert len(names) == 3 * calls + (calls - 1)
+    assert sum("splash_mqa_fwd" in n for n in names) == 4 * (calls - 2)
+    assert sum("splash_mqa_dkv" in n for n in names) == 4
+    assert sum("splash_mqa_dq" in n for n in names) == 3  # the windowed
+    assert all("splash" in n for n in names)
 
 
 @pytest.mark.parametrize("entry", ENTRIES)
 def test_estimate_matches_what_jax_keeps_under_a_pattern(entry):
-    """Three windowed layers (output in 128 lanes and ONE float32
-    statistic a head, at the windowed kernel's padded length) and a flash
-    one (two statistics); the router's logits are 16 wide on a share that
-    holds 4 experts."""
+    """Three windowed layers and a full one (output in 128 lanes and ONE
+    float32 statistic a head, each at its own tile rule's padded length);
+    the router's logits are 16 wide on a share that holds 4 experts."""
     from areal_tpu.ops.attention import kernel_padded_len
 
     cfg = dataclasses.replace(  # three periods
         tiny_config(), **{**MELLUM_WIDTHS, "n_layers": 12,
                           "layer_types": MELLUM_WIDTHS["layer_types"] * 3})
     rows, length = 2, 640
-    flash = kernel_padded_len("pallas", length)
+    full = kernel_padded_len("pallas", length)
     window = kernel_padded_len("pallas", length, cfg.sliding_window)
-    assert (flash, window) == (768, 768)
+    assert (full, window) == (768, 768)
     est = transformer.remat_kept_bytes(
-        cfg, rows * length, 2, flash_tokens=rows * flash,
+        cfg, rows * length, 2, full_tokens=rows * full,
         window_tokens=rows * window)
     assert est["attention"] - est["full"] == 3 * rows * 768 * 8 * (
-        3 * (128 * 2 + 4) + (128 * 2 + 8))
+        4 * (128 * 2 + 4))
     # the scan runs over PERIODS: what it stacks has a leading dim of 3,
     # and each layer of a period keeps its own arrays
     periods = dataclasses.replace(cfg, n_layers=3, layer_types=None)
@@ -582,7 +614,7 @@ def test_plan_counts_both_kernels_of_a_pattern():
     eng = _engine(dataclasses.replace(tiny_config(), **MELLUM_WIDTHS))
     kept = eng._remat_kept_bytes(2, 640)
     assert kept == transformer.remat_kept_bytes(
-        eng.cfg, 2 * 640, 2, flash_tokens=2 * 768, window_tokens=2 * 768)
+        eng.cfg, 2 * 640, 2, full_tokens=2 * 768, window_tokens=2 * 768)
     assert eng._remat_for(2, 640) in ENTRIES
     assert eng._layer_kinds == "sliding,sliding,sliding,full"
 
@@ -606,8 +638,8 @@ HYBRID_WIDTHS = dict(
 @pytest.mark.parametrize("entry", ENTRIES)
 def test_estimate_matches_what_jax_keeps_of_mixer_layers(entry):
     """Two periods of (expert layer, Mamba-2, attention): every layer
-    keeps its input; the attention layer the flash kernel's output and
-    statistics; under ``matmuls`` the in-projection, q/k/v, and the
+    keeps its input; the attention layer the kernel's output and
+    statistic; under ``matmuls`` the in-projection, q/k/v, and the
     router's logits, the latent down-projection and the shared expert's
     first matmul — the scan's products carry batch dimensions and the last
     matmul of a mixer feeds the residual sum alone."""
@@ -615,13 +647,13 @@ def test_estimate_matches_what_jax_keeps_of_mixer_layers(entry):
 
     cfg = dataclasses.replace(tiny_config(), **HYBRID_WIDTHS)
     rows, length = 2, 640
-    flash = kernel_padded_len("pallas", length)
+    full = kernel_padded_len("pallas", length)
     est = transformer.remat_kept_bytes(cfg, rows * length, 2,
-                                       flash_tokens=rows * flash)
+                                       full_tokens=rows * full)
     tok = rows * length
     assert est["full"] == 6 * tok * 256 * 2
     assert est["attention"] - est["full"] == 2 * rows * 768 * 2 * (
-        128 * 2 + 8)
+        128 * 2 + 4)
     assert est["matmuls"] - est["attention"] == 2 * tok * 2 * (
         (16 + 128 + 384) + cfg.ssm.in_proj_dim + (256 + 2 * 128))
     # the scan runs over the two PERIODS
@@ -667,8 +699,8 @@ AFMOE_WIDTHS = dict(
 @pytest.mark.parametrize("entry", ENTRIES)
 def test_estimate_matches_what_jax_keeps_of_blocks_that_differ_in_ffn(entry):
     """Two periods of (S·dense, S, S, S, F): every block keeps its input;
-    the sliding ones the windowed kernel's output and statistic, the full
-    ones the flash kernel's; under ``matmuls`` q/k/v, the attention gate
+    the kernel's output and statistic, sliding and full ones alike; under
+    ``matmuls`` q/k/v, the attention gate
     and o_proj of every block, gate and up of the dense FFN, on an expert
     block the router's logits and the shared expert's pair, and the
     FFN's last matmul, which the sandwich norm behind it reads."""
@@ -678,15 +710,15 @@ def test_estimate_matches_what_jax_keeps_of_blocks_that_differ_in_ffn(entry):
     assert cfg.layer_kinds[:5] == ("sliding_dense", "sliding", "sliding",
                                    "sliding", "full")
     rows, length = 2, 640
-    flash = kernel_padded_len("pallas", length)
+    full = kernel_padded_len("pallas", length)
     window = kernel_padded_len("pallas", length, cfg.sliding_window)
     est = transformer.remat_kept_bytes(
-        cfg, rows * length, 2, flash_tokens=rows * flash,
+        cfg, rows * length, 2, full_tokens=rows * full,
         window_tokens=rows * window)
     tok = rows * length
     assert est["full"] == 10 * tok * 256 * 2
     assert est["attention"] - est["full"] == 2 * rows * 768 * 4 * (
-        4 * (128 * 2 + 4) + (128 * 2 + 8))
+        5 * (128 * 2 + 4))
     # q, k and v, o_proj, the gate, and the FFN's last matmul (dense: down;
     # experts: the shared expert's), which its post-norm reads
     attn = 512 + 2 * 256 + 256 + 512 + 256

@@ -39,6 +39,12 @@ WINDOW_T = {6016: 1, 6656: 1, 8192: 1}
 # rows of the Trinity-Mini cell (padded 9216, 11776) and the longest a
 # 16,384-token micro-batch can hold, at ITS window
 WINDOW_2K_T = {8832: 1, 11776: 1, 16384: 1}
+# The grouped-head kernel under a causal mask (a full layer) at
+# Qwen2.5-0.5B's 14 query / 2 key-value heads of 64: train-long's longest
+# row and train-packed's 8 x 512 grid; and at OLMoE's 16 / 16 heads of 128
+# on the four-chip mesh, a row a chip.
+CAUSAL_T = {7296: 1, 512: 8}
+CAUSAL_MESH = ("e4", (4, 3968), (16, 16, 128))
 # f2 is the async trainer's mesh in chip_smoke.py --chips 4; p2t2 nests the
 # kernel's shard_map inside the pipeline stages' manual-pp region.
 MESH_SPECS = ("f2", "p2t2")
@@ -158,6 +164,57 @@ def _compile_all():
             {k for k in ("splash_mqa_fwd", "splash_mqa_dkv", "splash_mqa_dq")
              if k in compiled.as_text()})
         out[name]["tile"] = wa.pick_tile(T, window)
+
+    # The same kernel under a causal mask, K/V at their 2 heads.
+    splash_names = ("splash_mqa_fwd", "splash_mqa_dkv", "splash_mqa_dq")
+    for T, rows in CAUSAL_T.items():
+        def spec(*shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+        def loss(q, k, v, seg):
+            o = wa.window_attention(q, k, v, seg, seg)
+            return jnp.sum(o.astype(jnp.float32) ** 2)
+
+        compiled = jax.jit(
+            jax.value_and_grad(loss, argnums=(0, 1, 2))
+        ).lower(
+            spec(rows, T, 14, 64), spec(rows, T, 2, 64),
+            spec(rows, T, 2, 64), spec(rows, T, dtype=jnp.int32),
+        ).compile()
+        record(f"causal-{T}", compiled)
+        out[f"causal-{T}"].update(
+            splash_kernels=sorted(
+                k for k in splash_names if k in compiled.as_text()),
+            flash_kernels="flash_attention" in compiled.as_text(),
+            tile=wa.pick_tile(T), padded=wa.padded_len(T))
+
+    # ... and through the dispatch on the four-chip mesh: the kernel in a
+    # shard_map, a row a chip.
+    from areal_tpu.ops import attention as attn_ops
+
+    mesh_spec, (rows, T), (hq, hkv, dh) = CAUSAL_MESH
+    mesh = pmesh.make_mesh(pmesh.ParallelSpec.parse(mesh_spec),
+                           devices=list(topo.devices))
+    by_row = NamedSharding(mesh, P(pmesh.DATA_AXES))
+
+    def mesh_loss(q, k, v, seg):
+        o = attn_ops.packed_attention(q, k, v, seg, seg, impl="pallas")
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    with psh.activation_sharding(mesh), attn_ops.dispatch_label(
+            "causal-mesh"):
+        compiled = jax.jit(
+            jax.value_and_grad(mesh_loss, argnums=(0, 1, 2))).lower(*(
+                jax.ShapeDtypeStruct((rows, T, h, dh), jnp.bfloat16,
+                                     sharding=by_row)
+                for h in (hq, hkv, hkv)),
+                jax.ShapeDtypeStruct((rows, T), jnp.int32, sharding=by_row),
+            ).compile()
+    record("causal-mesh", compiled)
+    out["causal-mesh"].update(
+        splash_kernels=sorted(
+            k for k in splash_names if k in compiled.as_text()),
+        kernels=attn_ops.kernel_counts()["causal-mesh"])
 
     # A small model through transformer.forward on multi-chip meshes.
     cfg = tiny_config(vocab_size=1024, n_layers=4, hidden_dim=256,
@@ -419,6 +476,29 @@ def test_window_attention_compiles_for_v5e(compiled, T):
     assert got["temp_bytes"] < 2 << 30
 
 
+@pytest.mark.parametrize("T", CAUSAL_T)
+def test_causal_attention_compiles_for_v5e(compiled, T):
+    """A full layer's call at Qwen2.5-0.5B's heads: the grouped-head
+    kernel's forward and its fused backward — and no flash kernel — at
+    the tile the causal rule picks, inside the VMEM/HBM limits."""
+    got = compiled[f"causal-{T}"]
+    assert got["splash_kernels"] == ["splash_mqa_dkv", "splash_mqa_fwd"]
+    assert (got["tile"], got["padded"]) == {7296: (1024, 8192),
+                                            512: (512, 512)}[T]
+    assert not got["flash_kernels"]
+    assert got["padded"] % got["tile"] == 0 and got["padded"] >= T
+    assert got["temp_bytes"] < 2 << 30
+
+
+def test_causal_attention_compiles_on_the_v5e_mesh_at_olmoes_heads(compiled):
+    """16 query / 16 key-value heads of 128 (groups of one), a row a chip
+    of the 2 x 2 mesh, through ``packed_attention``'s dispatch."""
+    got = compiled["causal-mesh"]
+    assert got["kernels"] == {"causal": 1}
+    assert got["splash_kernels"] == ["splash_mqa_dkv", "splash_mqa_fwd"]
+    assert got["temp_bytes"] < 2 << 30
+
+
 @pytest.mark.parametrize("T", WINDOW_2K_T)
 def test_window_attention_at_window_2048_compiles_for_v5e(compiled, T):
     """The same kernel at window 2048 in rows up to 16,384 tokens: the
@@ -432,26 +512,28 @@ def test_window_attention_at_window_2048_compiles_for_v5e(compiled, T):
 
 
 def test_a_layer_pattern_compiles_on_a_v5e_mesh(compiled):
-    """Three windowed layers and a full one a period, each kernel forward,
-    dKV and dQ inside the kernels' shard_map."""
-    assert compiled["mesh-f2-pattern"]["custom_calls"] >= 4 * 3
+    """Three windowed layers (forward, dKV, dQ) and a full one (forward,
+    fused backward) a period, inside the kernels' shard_map."""
+    assert compiled["mesh-f2-pattern"]["custom_calls"] >= 3 * 3 + 2
 
 
 @pytest.mark.parametrize("spec", MESH_SPECS)
 def test_model_with_flash_kernel_compiles_on_a_v5e_mesh(compiled, spec):
     """Mosaic kernels cannot be partitioned by GSPMD: on more than one
     chip the lowering raises unless the call sits in a shard_map manual
-    over every mesh axis (ops/pallas flash_attention_on_mesh)."""
-    assert compiled[f"mesh-{spec}"]["custom_calls"] >= 3
+    over every mesh axis (ops/pallas/flash_attention.kernel_on_mesh) —
+    the model's causal layers run the grouped-head kernel there, forward
+    and fused backward."""
+    assert compiled[f"mesh-{spec}"]["custom_calls"] >= 2
 
 
-@pytest.mark.parametrize("entry,calls", [("full", 4), ("attention", 3),
-                                         ("matmuls", 3)])
+@pytest.mark.parametrize("entry,calls", [("full", 3), ("attention", 2),
+                                         ("matmuls", 2)])
 def test_kept_flash_residuals_spare_the_forward_kernel(compiled, entry,
                                                        calls):
     """A layer of the compiled grad program holds the forward kernel
-    twice under "full" (forward and recomputation, beside dKV and dQ) and
-    once where the kernel's residuals are kept."""
+    twice under "full" (forward and recomputation, beside the fused
+    backward) and once where the kernel's residuals are kept."""
     assert compiled[f"remat-{entry}"]["custom_calls"] == calls
 
 
@@ -552,5 +634,7 @@ def test_the_sambay_cell_compiles_at_the_published_widths(compiled):
     got = compiled["sambay-cell"]
     # 2 M layers x (forward, the forward the checkpoint re-runs, backward)
     assert got["scans_traced"] == 2
-    assert got["custom_calls"] >= 6 + 3 * 4
+    # ... and S (forward twice, dKV, dQ), F and X (forward twice, the
+    # fused backward)
+    assert got["custom_calls"] >= 6 + 4 + 2 * 3
     assert got["temp_bytes"] < 2.4e9

@@ -1,24 +1,31 @@
-"""Time the windowed attention kernels alone, a tile at a time — the
-measurement behind ``ops/pallas/window_attention.TILE_COST``.
+"""Time the grouped-head attention wrapper alone, a tile at a time — the
+measurement behind ``ops/pallas/window_attention.TILE_COST`` (windowed)
+and ``CAUSAL_TILE_COST`` (``--window 0``: full causal).
 
     python tools/window_tile_sweep.py            # on the chip
     python tools/window_tile_sweep.py --compile  # here, for a described v5e
 
-One row of ``--length`` tokens in one document, ``--window``, 32 query / 4
-key-value heads of 128, bf16. Per tile: the forward alone (what an
-inference pass and a recomputation run) and forward + backward (the
-residual-saving forward, dKV, dQ), host clock around
-``block_until_ready`` over ``--iters`` calls; then c(t) = (3 forward + 1
-backward) / (query tokens x visited key tokens), in ns. The flash kernel
-(causal, no window, K/V repeated) is timed at the same shapes beside it.
-Prints one JSON line per tile and writes them to
-``chiprun_out/window_tile_sweep.jsonl``.
+``--rows`` rows of ``--length`` tokens, one document each, ``--window``
+(0 = none), ``--heads`` query / key-value heads of ``--head-dim``, bf16.
+Per block shape of ``--blocks`` (``Q``, ``QxKV`` or ``QxKVxCOMPUTE``: the
+query block, the key block fetched, the key block computed at a time) and
+per backward of ``--fused`` (0 = dKV and dQ kernels, 1 = the one fused
+kernel): the forward alone (what an inference pass and a recomputation
+run) and forward + backward (the residual-saving forward and the
+backward), through the wrapper — its layout glue included — host clock
+around ``block_until_ready`` over ``--iters`` calls; then c = (2 forward
++ 1 forward-and-backward) / (rows x query tokens x visited key tokens at
+the padded length), in ns. The flash wrapper (causal, no window, K/V
+repeated, its own tile rule) is timed at the same shapes beside it.
+Prints one JSON line per shape and writes them to
+``chiprun_out/<--out>.jsonl``.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import sys
 import time
@@ -28,6 +35,9 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+from jax.experimental.pallas.ops.tpu.splash_attention import (  # noqa: E402
+    splash_attention_kernel as splash,
+)
 
 from areal_tpu.ops.pallas import flash_attention as fa  # noqa: E402
 from areal_tpu.ops.pallas import window_attention as wa  # noqa: E402
@@ -42,17 +52,37 @@ def timed(fn, args, iters):
     return (time.perf_counter() - t0) / iters
 
 
+def block_sizes(spec: str, fused: bool) -> splash.BlockSizes:
+    """``Q``, ``QxKV`` or ``QxKVxCOMPUTE`` for all three kernels."""
+    parts = [int(x) for x in spec.split("x")]
+    bq = parts[0]
+    bkv = parts[1] if len(parts) > 1 else bq
+    compute = parts[2] if len(parts) > 2 else bkv
+    dq = {} if fused else {"block_q_dq": bq, "block_kv_dq": bkv}
+    return splash.BlockSizes(
+        block_q=bq, block_kv=bkv, block_kv_compute=compute,
+        block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=compute,
+        use_fused_bwd_kernel=fused, **dq)
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--length", type=int, nargs="+", default=[8192])
-    ap.add_argument("--window", type=int, default=1024)
-    ap.add_argument("--tiles", type=int, nargs="+",
-                    default=[256, 512, 1024, 2048])
+    ap.add_argument("--rows", type=int, default=1)
+    ap.add_argument("--window", type=int, default=1024,
+                    help="0: full causal")
+    ap.add_argument("--blocks", "--tiles", nargs="+",
+                    default=["256", "512", "1024", "2048"])
+    ap.add_argument("--fused", type=int, nargs="+", default=[0])
     ap.add_argument("--heads", type=int, nargs=2, default=[32, 4])
+    ap.add_argument("--head-dim", type=int, default=128)
     ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--out", default="window_tile_sweep")
     ap.add_argument("--compile", action="store_true")
     args = ap.parse_args()
     hq, hkv = args.heads
+    window = args.window or None
+    table = "CAUSAL_TILE_COST" if window is None else "TILE_COST"
     sharding = None
     if args.compile:
         from jax.experimental import topologies
@@ -62,24 +92,26 @@ def main() -> int:
                                             topology_name="v5e:2x2")
         sharding = SingleDeviceSharding(topo.devices[0])
     lines = []
+    R = args.rows
     for L in args.length:
-        shapes = [jax.ShapeDtypeStruct((1, L, h, 128), jnp.bfloat16,
+        shapes = [jax.ShapeDtypeStruct((R, L, h, args.head_dim), jnp.bfloat16,
                                        sharding=sharding)
                   for h in (hq, hkv, hkv)]
-        seg_shape = jax.ShapeDtypeStruct((1, L), jnp.int32, sharding=sharding)
+        seg_shape = jax.ShapeDtypeStruct((R, L), jnp.int32, sharding=sharding)
         if not args.compile:
             keys = jax.random.split(jax.random.PRNGKey(0), 3)
             q, k, v = (jax.random.normal(kk, s.shape, jnp.float32).astype(
                 jnp.bfloat16) for kk, s in zip(keys, shapes))
-            seg = jnp.ones((1, L), jnp.int32)
+            seg = jnp.ones((R, L), jnp.int32)
 
-        def run(name, attend, tile, visited_tokens):
+        def run(name, attend, visited_tokens, **what):
             fwd = jax.jit(lambda q, k, v, s: attend(q, k, v, s, s))
             both = jax.jit(jax.grad(
                 lambda q, k, v, s: attend(q, k, v, s, s).astype(
                     jnp.float32).sum(), argnums=(0, 1, 2)))
-            line = {"kernel": name, "length": L, "tile": tile,
-                    "window": args.window}
+            line = {"kernel": name, "rows": R, "length": L,
+                    "heads": [hq, hkv], "head_dim": args.head_dim,
+                    "window": args.window, **what}
             try:
                 if args.compile:
                     for f in (fwd, both):
@@ -88,27 +120,35 @@ def main() -> int:
                 else:
                     t_f = timed(fwd, (q, k, v, seg), args.iters)
                     t_fb = timed(both, (q, k, v, seg), args.iters)
-                    step = 2 * t_f + t_fb  # 3 forwards, dKV, dQ
+                    step = 2 * t_f + t_fb  # 3 forwards, 1 backward
                     line.update(fwd_ms=t_f * 1e3, fwd_bwd_ms=t_fb * 1e3,
                                 step_ms=step * 1e3,
-                                c_ns=step * 1e9 / visited_tokens)
+                                c_ns=step * 1e9 / (R * visited_tokens))
             except Exception as e:  # a tile the compiler refuses
                 line["error"] = f"{type(e).__name__}: {str(e)[:300]}"
             print(json.dumps(line), flush=True)
             lines.append(line)
 
-        for tile in args.tiles:
-            if L % tile:
-                continue
-            wa.TILE_COST = {tile: 1.0}
-            visited, _ = wa.blocks_visited(L, tile, args.window)
-            run("window", lambda q, k, v, s, s2: wa.window_attention(
-                q, k, v, s, s2, window=args.window), tile,
-                visited * tile * tile)
-        run("flash", fa.flash_attention, fa.pick_tile(L), L * L / 2)
+        for spec in args.blocks:
+            for fused in args.fused:
+                sizes = block_sizes(spec, bool(fused))
+                # the row is padded to a multiple of both blocks, and the
+                # blocks visited are counted at the larger
+                tile = math.lcm(sizes.block_q, sizes.block_kv)
+                setattr(wa, table, {tile: 1.0})
+                wa._block_sizes = lambda t, w, sizes=sizes: sizes
+                n_pad = wa.padded_len(L, window)
+                visited, _ = wa.blocks_visited(n_pad, tile, window)
+                run("grouped", lambda q, k, v, s, s2: wa.window_attention(
+                    q, k, v, s, s2, window=window), visited * tile * tile,
+                    blocks=spec, fused_bwd=fused, padded=n_pad)
+        tile = fa.pick_tile(L)
+        n_pad = fa._round_up(L, tile)
+        run("flash", fa.flash_attention, n_pad * (n_pad + tile) / 2,
+            blocks=str(tile), padded=n_pad)
     if not args.compile:
         os.makedirs("chiprun_out", exist_ok=True)
-        with open("chiprun_out/window_tile_sweep.jsonl", "w") as f:
+        with open(f"chiprun_out/{args.out}.jsonl", "w") as f:
             f.writelines(json.dumps(x) + "\n" for x in lines)
     return 0
 
