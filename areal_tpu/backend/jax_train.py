@@ -886,6 +886,29 @@ class JaxTrainEngine(TrainableEngine):
     # same [R, L] shape, so the WHOLE batch uploads once as [n_mbs*R, L]
     # grids and each grad step slices its rows on device by a traced index.
 
+    def _gauge_blocks_needed(self, role: str,
+                             mbs: List[mbu.MicroBatch]) -> None:
+        """``<role>/attn_blocks_needed_frac``: the share of the static
+        mask's key blocks that the attention kernel runs on these packed
+        grids, over every attention layer (1.0 = it skips nothing) — where
+        the kernel runs them (not on the XLA reference)."""
+        windows = self.cfg.attention_windows()
+        grids = [mb.grids["segment_ids"] for mb in mbs]
+        if not windows or any(
+                kernel_padded_len(self.attn_impl, seg.shape[1], w) is None
+                for seg in grids for w in windows):
+            return
+        from areal_tpu.ops.pallas import window_attention
+
+        needed = static = 0
+        for window, layers in windows.items():
+            for seg in grids:
+                n, s = window_attention.count_needed(seg, window)
+                needed += layers * n
+                static += layers * s
+        telemetry.set_gauge(f"{role}/attn_blocks_needed_frac",
+                            needed / static)
+
     def upload_uniform(
         self, input_: SequenceSample, mb_spec: MicroBatchSpec
     ) -> "UniformBatch":
@@ -896,6 +919,7 @@ class JaxTrainEngine(TrainableEngine):
                 fill_bucket=self.fill_bucket, rows_multiple=self.rows_multiple,
             )
             telemetry.set_gauge("train/pack_fill", mbu.pack_fill(mbs))
+            self._gauge_blocks_needed("train", mbs)
         R, L = mbs[0].layout.shape
         pp_on, ring_on = ppl.pp_engagement(self.mesh, self.cfg, R, L)
         telemetry.set_gauge("train/pp_engaged", pp_on)
@@ -1331,6 +1355,7 @@ class JaxTrainEngine(TrainableEngine):
                 fill_bucket=self.fill_bucket, rows_multiple=self.rows_multiple,
             )
             telemetry.set_gauge("infer/pack_fill", mbu.pack_fill(mbs))
+            self._gauge_blocks_needed("infer", mbs)
         use_lp = self._use_chunked_logprobs(post_hook)
         # use_lp is part of the key: id() of a GC'd hook can be reused by a
         # new hook with a different wants_token_logprobs, which would route

@@ -398,6 +398,16 @@ class TransformerConfig:
     def window_of(self, kind: str) -> Optional[int]:
         return self.sliding_window if attention_kind(kind) == SLIDING else None
 
+    def attention_windows(self) -> Dict[Optional[int], int]:
+        """{window (None: full causal): layers that run causal
+        self-attention over a packed row under it}."""
+        counts: Dict[Optional[int], int] = {}
+        for kind in self.layer_kinds:
+            if attention_kind(kind) in (FULL, SLIDING, ATTENTION_ONLY, CROSS):
+                window = self.window_of(kind)
+                counts[window] = counts.get(window, 0) + 1
+        return counts
+
     def rope_of(self, kind: str) -> Optional[RopeConfig]:
         """The RoPE table of a layer kind; None = no position embedding."""
         return dict(self.layer_rope or ()).get(

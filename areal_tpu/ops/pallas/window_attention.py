@@ -8,16 +8,29 @@ contiguous in its row, so the distance inside the document is the
 distance in the row). The hot op is jax's splash-attention kernel
 (``jax.experimental.pallas.ops.tpu.splash_attention``) in its MQA form,
 under a ``CausalMask`` or a ``LocalMask`` — the grid of a windowed call
-holds only the key blocks the window touches: blocks wholly outside it
-are never visited, forward or backward. Wrapped with areal_tpu's
-packed-batch semantics:
+holds only the key blocks the window touches. Of the blocks the static
+mask leaves, a query block runs only those its own documents reach
+(:func:`blocks_needed`, from the row's segment ids): a key block that
+holds only OTHER documents, and every key block of a query block that is
+nothing but padding, is skipped — no matmul, no exponential, no DMA —
+in the forward, the dKV and the dQ kernels alike. The kernel's
+scalar-prefetched ``block_mask`` / ``data_next`` are computed in the
+graph for that (:func:`_narrowed`); a skipped block's scores were all
+masked and added exact zeros, so outputs and gradients of real tokens are
+bit for bit what the static schedule gives. A row of ONE block keeps the
+static schedule (nothing to skip; a batch of such rows then stays one
+kernel call). Wrapped with areal_tpu's packed-batch semantics:
 
  - inputs are [B, T, H, D]; self-attention only (queries and keys of one
    packed row);
  - GQA runs the kernel's MQA form once a key/value head (vmapped over
    rows and key/value heads): K and V are NOT repeated, and dK / dV come
    back at their own head count;
- - document masking via the kernel's segment ids, 0 = padding;
+ - document masking via the kernel's segment ids, 0 = padding; a padding
+   query's row comes back as zeros — inside the kernel it holds what the
+   query attended of the padding before it (a block with real tokens) or
+   ``0 * (1 / 0)`` (a block of nothing but padding, which ran no key
+   block: its softmax statistic is -inf, and no backward block reads it);
  - head_dim is padded up to the lane width (128) when needed, and the row
    up to a multiple of the tile of :func:`pick_tile`, with segment id 0.
 
@@ -26,9 +39,11 @@ The kernels' device ops are named ``splash_mqa_{fwd,dkv,dq}_segmented_*``
 kernel) — not ``flash_attention`` / ``flash_mha_bwd_*``, so a reader of
 the flash kernels' time does not count them. Per compiled step,
 :func:`geometry_counts` says which (length, padded length, tile, window)
-each WINDOWED call was traced with, and how many key blocks it visits
-against a causal kernel; :func:`causal_geometry_counts` which (length,
-padded length, tile) each full-causal call was traced with.
+each WINDOWED call was traced with, and how many key blocks its static
+mask visits against a causal kernel's; :func:`causal_geometry_counts`
+which (length, padded length, tile) each full-causal call was traced
+with; :func:`needed_counts` what the packed grids the engine ran needed of
+those static blocks (counted on the host, by :func:`count_needed`).
 
 CPU/testing: ``interpret=True`` runs the kernels in Pallas's plain
 interpreter (tests/test_window_attention.py) — the TPU interpreter of
@@ -40,10 +55,11 @@ from __future__ import annotations
 
 import collections
 import functools
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental.pallas.ops.tpu.splash_attention import (
     splash_attention_kernel as _splash,
 )
@@ -87,18 +103,43 @@ CAUSAL_TILE_COST = {1024: 0.2804, 768: 0.3314, 512: 0.3593, 256: 0.8073,
 _CAUSAL_KV_COMPUTE = 512
 
 
+def _static_blocks(n: int, tile: int, window: Optional[int]) -> np.ndarray:
+    """bool [n, n]: the blocks the static mask leaves — query block i
+    reaches back to key i*tile - window + 1, or with no window to key 0."""
+    i = np.arange(n)[:, None]
+    j = i.T
+    if window is None:
+        return j <= i
+    return (j <= i) & (j >= np.maximum(i * tile - window + 1, 0) // tile)
+
+
 def blocks_visited(n_pad: int, tile: int,
                    window: Optional[int]) -> Tuple[int, int]:
-    """(key blocks a call over a padded row of ``n_pad`` tokens visits at
-    ``tile``, key blocks a causal kernel would visit): query block i
-    reaches back to key i*tile - window + 1, or with no window to key 0."""
+    """(key blocks the static mask of a call over a padded row of
+    ``n_pad`` tokens visits at ``tile``, key blocks a causal mask would)."""
     n = n_pad // tile
-    causal = n * (n + 1) // 2
-    if window is None:
-        return causal, causal
-    visited = sum(i - max(i * tile - window + 1, 0) // tile + 1
-                  for i in range(n))
-    return visited, causal
+    return int(_static_blocks(n, tile, window).sum()), n * (n + 1) // 2
+
+
+def blocks_needed(segment_ids, tile: int, window: Optional[int] = None):
+    """bool [..., n, n] over the ``tile``-token blocks of a packed row
+    (``segment_ids`` [..., n * tile], 0 = padding; numpy or traced): query
+    block i needs key block j when j is in the static mask's range for i
+    (:func:`blocks_visited`'s) and a token of i that is not padding
+    belongs to a document with a token in j. A document is contiguous in
+    its row, so the earliest document of block i — its first real token's
+    — reaches back into block j < i exactly when block j ENDS in it: the
+    needed blocks are one range up to i. A query block of nothing but
+    padding needs no block."""
+    xp = jnp if isinstance(segment_ids, jax.Array) else np
+    rows = segment_ids.reshape(*segment_ids.shape[:-1], -1, tile)
+    real = rows > 0
+    first = xp.take_along_axis(  # [..., n, 1]
+        rows, xp.argmax(real, axis=-1)[..., None], axis=-1)
+    last = rows[..., None, :, -1]  # [..., 1, n]
+    n = rows.shape[-2]
+    return (_static_blocks(n, tile, window) & real.any(axis=-1)[..., None]
+            & (np.eye(n, dtype=bool) | (last == first)))
 
 
 def pick_tile(n: int, window: Optional[int] = None) -> int:
@@ -135,6 +176,12 @@ _CAUSAL_GEOMETRY: Dict[str, collections.Counter] = collections.defaultdict(
     collections.Counter)
 
 
+# What the packed grids a step ran needed of the static mask's blocks, by
+# grid — counted on the HOST from the packer's layouts (count_needed):
+# {(rows, n, n_pad, tile, window or 0): [grids, needed, static]}.
+_NEEDED: Dict[Tuple[int, int, int, int, int], list] = {}
+
+
 def geometry_counts() -> Dict[str, Dict[Tuple[int, int, int, int], Dict]]:
     return {
         label: {g: dict(zip(("calls", "blocks_visited", "blocks_causal"), c))
@@ -145,6 +192,35 @@ def geometry_counts() -> Dict[str, Dict[Tuple[int, int, int, int], Dict]]:
 
 def causal_geometry_counts() -> Dict[str, Dict[Tuple[int, int, int], int]]:
     return {label: dict(c) for label, c in _CAUSAL_GEOMETRY.items()}
+
+
+def count_needed(segment_ids: np.ndarray,  # [R, L] on the host
+                 window: Optional[int] = None) -> Tuple[int, int]:
+    """(key blocks the rows of a packed grid need, key blocks the static
+    mask visits) at the tile and padded length :func:`window_attention`
+    runs them at — the same :func:`blocks_needed` the kernel's schedule is
+    narrowed by; a row of ONE block keeps the static schedule. Added to
+    :func:`needed_counts` under the grid's shape."""
+    R, L = segment_ids.shape
+    tile = pick_tile(L, window)
+    n_pad = _round_up(L, tile)
+    visited = needed = R * blocks_visited(n_pad, tile, window)[0]
+    if n_pad > tile:
+        needed = int(blocks_needed(
+            np.pad(segment_ids, [(0, 0), (0, n_pad - L)]), tile,
+            window).sum())
+    c = _NEEDED.setdefault((R, L, n_pad, tile, window or 0), [0, 0, 0])
+    c[0] += 1
+    c[1] += needed
+    c[2] += visited
+    return needed, visited
+
+
+def needed_counts() -> Dict[Tuple[int, int, int, int, int], Dict]:
+    """{(rows, length, padded length, tile, window or 0): {grids counted,
+    blocks_needed, blocks_static}} of the grids :func:`count_needed` saw."""
+    return {g: dict(zip(("grids", "blocks_needed", "blocks_static"), c))
+            for g, c in _NEEDED.items()}
 
 
 def _count(n: int, n_pad: int, tile: int, window: Optional[int]) -> None:
@@ -166,7 +242,11 @@ def _block_sizes(tile: int, window: Optional[int]) -> _splash.BlockSizes:
     ONE fused backward kernel (5 matmuls a block pair where dKV + dQ do
     7; dQ leaves it a partial sum a key block, in the compute dtype, and
     is added up outside — under a window its grid would hold every causal
-    block, so the windowed call keeps the two kernels)."""
+    block, so the windowed call keeps the two kernels). These fix the
+    GRID; which of its steps run is the static mask's schedule narrowed by
+    the row's segment ids (:func:`_narrowed`): a skipped step costs a grid
+    step and no DMA, and the fused backward still writes that step's dQ
+    partial (zeros)."""
     if window is not None:
         return _splash.BlockSizes(
             block_q=tile, block_kv=tile, block_kv_compute=tile,
@@ -181,19 +261,111 @@ def _block_sizes(tile: int, window: Optional[int]) -> _splash.BlockSizes:
     )
 
 
-def _kernel(n_pad: int, tile: int, window: Optional[int], group: int,
-            interpret: bool = False):
-    """The splash MQA kernel of one key/value head: ``group`` query heads
-    over a row of ``n_pad`` tokens, causality (and the window: a
-    ``LocalMask`` reaching ``window - 1`` back and 0 ahead) in the mask."""
+class _Walk(NamedTuple):
+    """One kernel's static schedule a grid step at a time, in the order
+    its grid walks (a forward / dQ grid a query block at a time, a dKV
+    grid a key block at a time): does the step run, its ``block_mask``
+    entry, the (query, key) block pair it is — an index into
+    ``blocks_needed(...).reshape(-1)`` — and ``key``, the step's number
+    and its ``data_next`` entry in one integer, ``step * base + entry``."""
+    live: np.ndarray
+    block: np.ndarray
+    pair: np.ndarray
+    key: np.ndarray
+    base: int
+    by_key: bool
+
+
+def _walk(info, by_key: bool) -> Optional[_Walk]:
+    """An entry (r, c) of a forward / dQ mask info is query block r
+    against the key block its ``data_next`` names; of a dKV info
+    (``by_key``) the query block its ``data_next`` names against key
+    block c (the other side of either may be shrunk to the blocks the
+    static mask visits)."""
+    if info is None:
+        return None
+    block, data = (np.asarray(x)[0].astype(np.int32)
+                   for x in (info.block_mask, info.data_next))
+    r, c = np.indices(block.shape)
+    qi, ki = (data, c) if by_key else (r, data)
+    n = block.shape[1 if by_key else 0]  # blocks a side
+    order = (lambda x: x.T.reshape(-1)) if by_key else (
+        lambda x: x.reshape(-1))
+    live = order(block > 0)
+    return _Walk(live, order(block), np.where(live, order(qi * n + ki), 0),
+                 np.arange(block.size) * n + order(data), n, by_key)
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel(n_pad: int, window: Optional[int], group: int,
+            sizes: _splash.BlockSizes, interpret: bool = False):
+    """(the splash MQA kernel of one key/value head, the walks of its
+    forward, dQ and dKV schedules): ``group`` query heads over a row of
+    ``n_pad`` tokens in blocks of ``sizes``, causality (and the window: a
+    ``LocalMask`` reaching ``window - 1`` back and 0 ahead) in the mask.
+    Made once a geometry; its mask infos — the static block schedule —
+    are concrete arrays also when made under a trace."""
     shape = (n_pad, n_pad)
     mask = (_mask.CausalMask(shape) if window is None
             else _mask.LocalMask(shape, (window - 1, 0), 0))
-    return _splash.make_splash_mqa_single_device(
-        _mask.MultiHeadMask([mask] * group),
-        block_sizes=_block_sizes(tile, window),
-        residual_checkpoint_name=RESIDUALS, interpret=interpret,
-    )
+    with jax.ensure_compile_time_eval():
+        kernel = _splash.make_splash_mqa_single_device(
+            _mask.MultiHeadMask([mask] * group), block_sizes=sizes,
+            residual_checkpoint_name=RESIDUALS, interpret=interpret,
+        )
+    return kernel, (_walk(kernel.fwd_mask_info, False),
+                    _walk(kernel.dq_mask_info, False),
+                    _walk(kernel.dkv_mask_info, True))
+
+
+def _narrow(walk: _Walk, needed: jnp.ndarray, like):
+    """(``block_mask``, ``data_next``) of one kernel, of the static
+    ``like``'s shape [1, rows, columns] and types: its schedule with the
+    entries of the blocks that ``needed`` (:func:`blocks_needed`, traced:
+    [n, n]) leaves out zeroed, and every grid step fetching the block of
+    the next step that runs (past the last one: of the first)."""
+    past = len(walk.live) * walk.base
+    run = walk.live & jnp.take(needed.reshape(-1), walk.pair)
+    nxt = jax.lax.cummin(jnp.where(run, walk.key, past), reverse=True)
+    nxt = jnp.where(nxt == past, jax.lax.index_in_dim(nxt, 0), nxt)
+    shape = like.block_mask.shape
+
+    def back(x, dtype):  # the walk's order -> [1, rows, columns]
+        x = x.astype(dtype)
+        if walk.by_key:  # the walk went down the columns
+            return x.reshape(shape[0], shape[2], shape[1]).swapaxes(1, 2)
+        return x.reshape(shape)
+
+    return (back(jnp.where(run, walk.block, 0), like.block_mask.dtype),
+            back(nxt % walk.base, like.data_next.dtype))
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
+def _schedules(segment_ids: jnp.ndarray,  # [n_pad]: one packed row
+               n_pad, window, group, sizes, interpret):
+    """The narrowed (``block_mask``, ``data_next``) of the geometry's
+    forward, dQ (None under the fused backward) and dKV kernels. A program
+    of its own, traced once a geometry: the callers' traces (a layer's
+    forward, its backward, its recomputation, a program after the other)
+    bind it as one equation."""
+    kernel, walks = _kernel(n_pad, window, group, sizes, interpret)
+    needed = blocks_needed(segment_ids, sizes.block_q, window)
+    infos = (kernel.fwd_mask_info, kernel.dq_mask_info, kernel.dkv_mask_info)
+    return tuple(None if walk is None else _narrow(walk, needed, info)
+                 for info, walk in zip(infos, walks))
+
+
+def _narrowed(segment_ids: jnp.ndarray, *geometry):
+    """The geometry's kernel (:func:`_kernel`'s arguments) with the
+    schedules of its forward and backward kernels narrowed to the blocks
+    the row needs: the mask function and the segment ids still mask
+    INSIDE a block, as under the static schedule."""
+    kernel, _ = _kernel(*geometry)
+    infos = (kernel.fwd_mask_info, kernel.dq_mask_info, kernel.dkv_mask_info)
+    return _splash.SplashAttentionKernel(
+        *(info and info._replace(block_mask=now[0], data_next=now[1])
+          for info, now in zip(infos, _schedules(segment_ids, *geometry))),
+        **kernel.kwargs)
 
 
 @functools.partial(jax.named_call, name="pallas_window_attention")
@@ -243,9 +415,17 @@ def window_attention(
     seg = _splash.SegmentIds(q=pad_ids(q_segment_ids).astype(jnp.int32),
                              kv=pad_ids(kv_segment_ids).astype(jnp.int32))
 
-    kernel = _kernel(T_pad, tile, window, G, interpret)
-    per_head = jax.vmap(kernel, in_axes=(0, 0, 0, None))  # key/value heads
-    out = jax.vmap(per_head)(qt, kt, vt, seg)  # [B, Hkv, G, T_pad, D+]
+    geometry = (T_pad, window, G, _block_sizes(tile, window), interpret)
+
+    def row(q, k, v, seg):  # one packed row, a key/value head at a time
+        # A row of ONE block has nothing to skip: the static schedule.
+        kern = (_kernel(*geometry)[0] if T_pad == tile
+                else _narrowed(seg.q, *geometry))
+        return jax.vmap(kern, in_axes=(0, 0, 0, None))(q, k, v, seg)
+
+    out = jax.vmap(row)(qt, kt, vt, seg)  # [B, Hkv, G, T_pad, D+]
     out = out[:, :, :, :T, :D].transpose(0, 3, 1, 2, 4).reshape(B, T, Hq, D)
-    # Zero pad-query rows (they attended the row's other padding).
-    return out * (q_segment_ids > 0)[:, :, None, None].astype(out.dtype)
+    # A padding query's row is zero: inside a block of real tokens it
+    # attended the padding before it, and a block of nothing but padding
+    # ran no key block, so its row is 0 * (1 / 0).
+    return jnp.where((q_segment_ids > 0)[:, :, None, None], out, 0)

@@ -346,6 +346,13 @@ class TrainerWorker:
                 for label, counts in
                 window_attention.causal_geometry_counts().items()
             },
+            # {"rows x length>padded/tile/window": {grids, blocks_needed,
+            # blocks_static}}: what the packed grids the engine ran needed
+            # of the static mask's key blocks (the kernel skips the rest)
+            attn_blocks_needed={
+                "%dx%d>%d/%d/w%d" % grid: c for grid, c in
+                window_attention.needed_counts().items()
+            },
             flash_geometry={
                 label: {"%d>%d/%d" % geom: n for geom, n in counts.items()}
                 for label, counts in flash_attention.geometry_counts().items()

@@ -44,6 +44,13 @@ WINDOW_2K_T = {8832: 1, 11776: 1, 16384: 1}
 # row and train-packed's 8 x 512 grid; and at OLMoE's 16 / 16 heads of 128
 # on the four-chip mesh, a row a chip.
 CAUSAL_T = {7296: 1, 512: 8}
+# Rows whose schedule is narrowed by their segment ids (every row of more
+# than one block: the block mask is then a traced operand of the kernels,
+# not a constant): train-long's and train-packed's other grids at Qwen's
+# heads, and the phi4flash cell's F / X call — 40 query / 20 key-value
+# heads, q and k of 64 under a value of 128 — through the dispatch.
+NARROWED_T = {6016: 1, 3072: 1, 2688: 1}
+WIDE_VALUE = ((1, 7424), (40, 20, 64, 128))
 CAUSAL_MESH = ("e4", (4, 3968), (16, 16, 128))
 # f2 is the async trainer's mesh in chip_smoke.py --chips 4; p2t2 nests the
 # kernel's shard_map inside the pipeline stages' manual-pp region.
@@ -108,10 +115,15 @@ def _compile_all():
     # back without the chip: keep the cache out of it.
     jax.config.update("jax_enable_compilation_cache", False)
     out = {}
+    # how often a call's schedule was narrowed while a case was traced
+    narrowed = []
+    narrow = wa._narrowed
+    wa._narrowed = lambda *a: narrowed.append(1) or narrow(*a)
 
     def record(name, compiled):
         text = compiled.as_text()
         out[name] = {
+            "narrowed": len(narrowed),
             "custom_calls": text.count("tpu_custom_call"),
             "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
             # kernel INSTANCES by the name the device's op line shows (what
@@ -122,6 +134,7 @@ def _compile_all():
             "ragged_dot_calls": len(re.findall(
                 r"%ragged-dot-none[.\d]* = [^\n]*custom-call\(", text)),
         }
+        narrowed.clear()
 
     # Forward AND backward at the Qwen2.5-0.5B geometry: 14 q / 2 kv heads
     # (repeated to 14), head_dim 64 padded to 128 lanes, block_b=1.
@@ -167,7 +180,7 @@ def _compile_all():
 
     # The same kernel under a causal mask, K/V at their 2 heads.
     splash_names = ("splash_mqa_fwd", "splash_mqa_dkv", "splash_mqa_dq")
-    for T, rows in CAUSAL_T.items():
+    for T, rows in {**CAUSAL_T, **NARROWED_T}.items():
         def spec(*shape, dtype=jnp.bfloat16):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
@@ -215,6 +228,23 @@ def _compile_all():
         splash_kernels=sorted(
             k for k in splash_names if k in compiled.as_text()),
         kernels=attn_ops.kernel_counts()["causal-mesh"])
+
+    # A value wider than q and k on a row with blocks to skip.
+    (rows, T), (hq, hkv, dh, dv) = WIDE_VALUE
+
+    def wide_loss(q, k, v, seg):
+        o = attn_ops.packed_attention(q, k, v, seg, seg, impl="pallas")
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(
+        jax.value_and_grad(wide_loss, argnums=(0, 1, 2))).lower(*(
+            jax.ShapeDtypeStruct((rows, T, h, d), jnp.bfloat16, sharding=chip)
+            for h, d in ((hq, dh), (hkv, dh), (hkv, dv))),
+            jax.ShapeDtypeStruct((rows, T), jnp.int32, sharding=chip),
+        ).compile()
+    record("causal-wide-value", compiled)
+    out["causal-wide-value"].update(splash_kernels=sorted(
+        k for k in splash_names if k in compiled.as_text()))
 
     # A small model through transformer.forward on multi-chip meshes.
     cfg = tiny_config(vocab_size=1024, n_layers=4, hidden_dim=256,
@@ -487,6 +517,33 @@ def test_causal_attention_compiles_for_v5e(compiled, T):
                                             512: (512, 512)}[T]
     assert not got["flash_kernels"]
     assert got["padded"] % got["tile"] == 0 and got["padded"] >= T
+    assert got["temp_bytes"] < 2 << 30
+
+
+@pytest.mark.parametrize("T", NARROWED_T)
+def test_a_narrowed_causal_schedule_compiles_for_v5e(compiled, T):
+    """Rows of more than one block take their block mask from the row's
+    segment ids — a traced operand of the forward and the fused backward
+    — at the cells' other Qwen grids."""
+    got = compiled[f"causal-{T}"]
+    assert got["narrowed"] > 0
+    assert got["splash_kernels"] == ["splash_mqa_dkv", "splash_mqa_fwd"]
+    assert (got["tile"], got["padded"]) == {
+        6016: (1024, 6144), 3072: (1024, 3072), 2688: (1024, 3072)}[T]
+    assert got["temp_bytes"] < 2 << 30
+
+
+@pytest.mark.parametrize("name,narrowed", [
+    ("causal-7296", True), ("causal-512", False), ("window-6656", True),
+    ("window2048-11776", True), ("causal-mesh", True),
+    ("causal-wide-value", True)])
+def test_which_compiled_schedules_are_narrowed(compiled, name, narrowed):
+    """The narrowing is built where a row has more than one block — the
+    windowed call's three kernels, the mesh's row a chip and the wide
+    value's call among them — and not for the 8 x 512 grid."""
+    got = compiled[name]
+    assert (got["narrowed"] > 0) == narrowed
+    assert "splash_mqa_fwd" in got["splash_kernels"]
     assert got["temp_bytes"] < 2 << 30
 
 

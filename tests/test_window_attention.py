@@ -367,3 +367,284 @@ def test_the_kernels_scope_is_on_the_compiled_ops(window, scope):
     assert f"{scope}/pallas_window_attention" in text
     other = ({wa.SCOPE, wa.CAUSAL_SCOPE} - {scope}).pop()
     assert f"{other}/pallas_window_attention" not in text
+
+
+# ---- blocks skipped by the row's segment ids ----
+
+# (row length, [document lengths] per row, tile): what the kernel's
+# schedule is narrowed by — all at a toy tile of 128
+LAYOUTS = {
+    # (a) five documents in a multi-block row, boundaries on a block edge
+    # (128, 384) and off it (228, 520); 20 tokens of tail padding
+    "five_docs": (640, [[128, 100, 156, 136, 100]]),
+    # (b) a tail of two WHOLE blocks of padding, and of one
+    "whole_pad_blocks": (512, [[200, 56], [200, 184]]),
+    # (c) a short document padded inside the last real block, then a
+    # block of nothing but padding
+    "short_doc_padded": (384, [[128, 30]]),
+    # (d) two rows with different layouts in one call
+    "two_rows": (384, [[128, 100, 100], [380]]),
+    # (e) a one-block row: the static schedule
+    "one_block": (128, [[50, 60], [128]]),
+}
+HEADS = {"14q2kv64": (14, 2, 64), "4q2kv128": (4, 2, 128)}
+
+
+def _row(T, docs):
+    """Segment ids of a row of T tokens: the documents, then padding."""
+    return np.pad(np.repeat(np.arange(1, len(docs) + 1), docs),
+                  (0, T - sum(docs))).astype(np.int32)
+
+
+def make_layout(layout, heads, seed=0, dv=None):
+    T, docs = LAYOUTS[layout]
+    Hq, Hkv, D = HEADS[heads]
+    B = len(docs)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (B, T, Hq, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, T, Hkv, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, T, Hkv, dv or D), jnp.float32)
+    w = jax.random.normal(ks[3], (B, T, Hq, dv or D), jnp.float32)
+    seg = np.stack([_row(T, lens) for lens in docs])
+    return q, k, v, jnp.asarray(seg), w
+
+
+def _out_and_grads(attend, q, k, v, w):
+    """(out, dq, dk, dv) of sum(out * w), in ONE jitted program (an
+    interpreted kernel compiles a second a program)."""
+    out, grads = jax.jit(jax.value_and_grad(
+        lambda *a: (lambda o: (jnp.sum(o * w), o))(attend(*a)),
+        argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    return (out[1], *grads)
+
+
+@pytest.mark.parametrize("heads", sorted(HEADS))
+@pytest.mark.parametrize("window", [None, 160])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_skipped_blocks_change_nothing(monkeypatch, layout, window, heads):
+    """Forward AND gradients of the narrowed kernel: the reference's under
+    ``segment_mask``, finite everywhere, zero in padding rows, and EQUAL to
+    what the same kernel gives under the static schedule."""
+    monkeypatch.setattr(wa, "TILE_COST", {128: 1.0})
+    monkeypatch.setattr(wa, "CAUSAL_TILE_COST", {128: 1.0})
+    q, k, v, seg, w = make_layout(layout, heads)
+    mask = segment_mask(seg, seg, causal=True, sliding_window=window)
+    want = _out_and_grads(
+        lambda *a: attention_reference(*a, mask), q, k, v, w)
+
+    def kernel(*a):
+        return wa.window_attention(*a, seg, seg, window=window,
+                                   interpret=True)
+
+    got = _out_and_grads(kernel, q, k, v, w)
+    monkeypatch.setattr(wa, "_narrowed",
+                        lambda seg, *geometry: wa._kernel(*geometry)[0])
+    static = _out_and_grads(kernel, q, k, v, w)
+    real = np.asarray(seg > 0)
+    for g, r, s, name in zip(got, want, static, ("out", "dq", "dk", "dv")):
+        assert bool(jnp.isfinite(g).all()), name
+        np.testing.assert_allclose(g, r, atol=5e-5, rtol=5e-5, err_msg=name)
+        np.testing.assert_array_equal(g, s, err_msg=name)
+        assert float(jnp.abs(g)[~real].max(initial=0.0)) == 0.0, name
+
+
+@pytest.mark.parametrize("layout", ["five_docs", "whole_pad_blocks"])
+def test_skipped_blocks_under_a_value_wider_than_q_and_k(monkeypatch,
+                                                         layout):
+    """Differential attention's call (a value of 128 over q / k of 64)
+    through ``packed_attention``, on rows with blocks to skip."""
+    monkeypatch.setattr(wa, "CAUSAL_TILE_COST", {128: 1.0})
+    _interpreted(monkeypatch)
+    q, k, v, seg, w = make_layout(layout, "14q2kv64", seed=4, dv=128)
+
+    def run(impl):
+        return _out_and_grads(lambda *a: attention.packed_attention(
+            *a, seg, seg, impl=impl), q, k, v, w)
+
+    for g, r, name in zip(run("pallas"), run("reference"),
+                          ("out", "dq", "dk", "dv")):
+        assert bool(jnp.isfinite(g).all()), name
+        np.testing.assert_allclose(g, r, atol=5e-5, rtol=5e-5, err_msg=name)
+
+
+def _allowed_blocks(seg, tile, window):
+    """The blocks that hold a (query, key) pair ``segment_mask`` allows."""
+    mask = np.asarray(segment_mask(seg[None], seg[None], causal=True,
+                                   sliding_window=window))[0, 0]
+    n = len(seg) // tile
+    return mask.reshape(n, tile, n, tile).any(axis=(1, 3))
+
+
+def _random_row(rng, n_blocks, tile):
+    """Contiguous documents of random lengths, ids in a random order,
+    then padding (sometimes none, sometimes whole blocks)."""
+    T = n_blocks * tile
+    real = int(rng.integers(1, T + 1))
+    cuts = np.sort(rng.choice(np.arange(1, real), size=min(
+        int(rng.integers(0, 7)), real - 1), replace=False))
+    lens = np.diff(np.concatenate([[0], cuts, [real]]))
+    ids = rng.permutation(len(lens)) + 1
+    return np.pad(np.repeat(ids, lens), (0, T - real)).astype(np.int32)
+
+
+@pytest.mark.parametrize("seed", range(24))
+def test_blocks_needed_leaves_out_no_pair_the_mask_allows(seed):
+    """On random contiguous layouts: every (query, key) pair that
+    ``segment_mask`` allows lies in a needed block, a needed block is one
+    of the static mask's, under a causal mask every needed block holds
+    such a pair, and a traced row gives what a numpy row gives."""
+    rng = np.random.default_rng(seed)
+    tile = int(rng.choice([8, 16, 32]))
+    n = int(rng.integers(2, 10))
+    window = [None, int(rng.integers(1, 3 * tile))][seed % 2]
+    seg = _random_row(rng, n, tile)
+    needed = wa.blocks_needed(seg, tile, window)
+    allowed = _allowed_blocks(seg, tile, window)
+    assert needed.shape == (n, n) and needed.dtype == bool
+    assert not (allowed & ~needed).any(), (seg, tile, window)
+    i, j = np.indices((n, n))
+    static = j <= i
+    if window is not None:
+        static &= j >= np.maximum(i * tile - window + 1, 0) // tile
+    assert int(static.sum()) == wa.blocks_visited(n * tile, tile, window)[0]
+    assert not (needed & ~static).any()
+    if window is None:
+        np.testing.assert_array_equal(needed, allowed)
+    traced = jax.jit(lambda s: wa.blocks_needed(s, tile, window))(seg)
+    np.testing.assert_array_equal(np.asarray(traced), needed)
+
+
+# (row length, documents, window): blocks needed / the static mask's at
+# the tile and padded length the kernel runs (ISSUE 46's hand counts)
+HAND_COUNTS = {
+    # a 5,930-token trajectory in train-long's 7,296-row, padded to 8,192
+    "long_5930_of_7296": (7296, [5930], None, (21, 36)),
+    # two 2,356-token documents in its 6,016-row, padded to 6,144
+    "long_2x2356_of_6016": (6016, [2356, 2356], None, (11, 21)),
+    # Phi's 3,140 + 4,197 in a 7,424-row, padded to 8,192
+    "phi_3140_4197_of_7424": (7424, [3140, 4197], None, (24, 36)),
+    # Mellum's 4,931-token trajectory in a 6,656-row: a full layer (padded
+    # to 7,168 at tile 1024) and a window-1024 layer (tile 512)
+    "mellum_4931_of_6656_full": (6656, [4931], None, (15, 28)),
+    "mellum_4931_of_6656_window": (6656, [4931], 1024, (27, 36)),
+    # a trajectory that fills its row: nothing to skip
+    "trinity_8717_of_8832": (8832, [8717], None, (45, 45)),
+    # train-packed's 8 x 512 grid: one block a row, the static schedule
+    "packed_one_block": (512, [100, 100], None, (1, 1)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(HAND_COUNTS))
+def test_count_needed_at_the_cells_layouts(name):
+    L, docs, window, want = HAND_COUNTS[name]
+    seg = _row(L, docs)[None]
+    before = dict(wa.needed_counts().get(
+        (1, L, wa.padded_len(L, window), wa.pick_tile(L, window),
+         window or 0), {"grids": 0}))
+    assert wa.count_needed(seg, window) == want
+    after = wa.needed_counts()[
+        (1, L, wa.padded_len(L, window), wa.pick_tile(L, window),
+         window or 0)]
+    assert after["grids"] == before["grids"] + 1
+    assert after["blocks_needed"] - before.get("blocks_needed", 0) == want[0]
+    assert after["blocks_static"] - before.get("blocks_static", 0) == want[1]
+
+
+@pytest.mark.parametrize("window", [None, 160, 300])
+@pytest.mark.parametrize("layout", ["five_docs", "whole_pad_blocks",
+                                    "short_doc_padded"])
+def test_the_narrowed_schedule_runs_the_needed_blocks_and_no_other(
+        layout, window):
+    """Each kernel's ``block_mask`` after narrowing: non-zero exactly at
+    the needed blocks (forward and dQ by query block, dKV by key block),
+    and every step's ``data_next`` is the block of the next step that
+    runs, in the order the grid walks."""
+    T, docs = LAYOUTS[layout]
+    tile, n = 128, T // 128
+    seg = _row(T, docs[0])
+    needed = wa.blocks_needed(seg, tile, window)
+    geometry = (T, window, 1, wa._block_sizes(tile, window), True)
+    static, _ = wa._kernel(*geometry)
+    narrow = wa._narrowed(jnp.asarray(seg), *geometry)
+    infos = [(static.fwd_mask_info, narrow.fwd_mask_info, False),
+             (static.dkv_mask_info, narrow.dkv_mask_info, True)]
+    if window is not None:
+        infos.append((static.dq_mask_info, narrow.dq_mask_info, False))
+    else:
+        assert narrow.dq_mask_info is None  # the fused backward
+    for was, now, by_key in infos:
+        block, data = (np.asarray(x)[0] for x in (now.block_mask,
+                                                  now.data_next))
+        was_block, was_data = (np.asarray(x)[0] for x in (was.block_mask,
+                                                          was.data_next))
+        assert block.dtype == was_block.dtype and data.dtype == was_data.dtype
+        runs = np.zeros((n, n), bool)
+        r, c = np.nonzero(block)
+        qi, ki = (was_data[r, c], c) if by_key else (r, was_data[r, c])
+        runs[qi, ki] = True
+        np.testing.assert_array_equal(runs, needed)
+        assert len(r) == int(needed.sum())  # no block twice
+        np.testing.assert_array_equal(block[r, c], was_block[r, c])
+        np.testing.assert_array_equal(data[r, c], was_data[r, c])
+        walk = (lambda x: x.T.reshape(-1)) if by_key else (
+            lambda x: x.reshape(-1))
+        live, nxt, src = walk(block) > 0, walk(data), walk(was_data)
+        at = np.flatnonzero(live)
+        for step in range(block.size):
+            later = at[at >= step]
+            assert nxt[step] == src[later[0] if len(later) else at[0]]
+
+
+@pytest.mark.parametrize("pattern,want", [
+    # every layer full causal: 2 layers x (3 + 6) of 2 x (6 + 6) blocks
+    (None, 18 / 24),
+    # one sliding layer (window 100: 5 static blocks a row, 3 + 5 needed)
+    # beside one full layer (3 + 6 of 6 + 6)
+    (("sliding", "full"), 17 / 22),
+])
+def test_the_engines_gauge_is_the_kernels_count(monkeypatch, pattern, want):
+    """``train/attn_blocks_needed_frac`` over the layers' windows, from
+    ``count_needed`` on the packer's grids — and no gauge where the XLA
+    reference runs the rows (the CPU's "auto")."""
+    from areal_tpu.api.data import MicroBatchSpec, SequenceSample
+    from areal_tpu.api.model import FinetuneSpec
+    from areal_tpu.backend import microbatch as mbu
+    from areal_tpu.backend.jax_train import JaxTrainEngine, OptimizerConfig
+    from areal_tpu.base import telemetry
+    from areal_tpu.models import transformer
+    from areal_tpu.models.config import tiny_config
+
+    monkeypatch.setattr(wa, "TILE_COST", {128: 1.0})
+    monkeypatch.setattr(wa, "CAUSAL_TILE_COST", {128: 1.0})
+    kw = {} if pattern is None else {"layer_types": pattern,
+                                     "sliding_window": 100}
+    cfg = tiny_config(vocab_size=64, n_layers=2, **kw)
+    assert cfg.attention_windows() == (
+        {None: 2} if pattern is None else {100: 1, None: 1})
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    lens = [130, 120, 384]  # rows of 384: two documents + padding, one
+    sample = SequenceSample.from_default(
+        ids=["a", "b", "c"],
+        data={"packed_input_ids": np.ones(sum(lens), np.int32)},
+        seqlens=lens)
+    telemetry.configure("t", "t0", "trainer", 0, push=False)
+    try:
+        for impl, gauged in (("auto", False), ("pallas", True)):
+            eng = JaxTrainEngine(
+                cfg, params, opt_cfg=OptimizerConfig(lr=1e-4),
+                ft_spec=FinetuneSpec(1, 8, 4), compute_dtype="float32",
+                length_bucket=128, rows_bucket=1, attn_impl=impl)
+            mbs = mbu.split_into_microbatches(
+                sample, MicroBatchSpec(max_tokens_per_mb=384),
+                length_bucket=128, rows_bucket=1)
+            assert sorted(mb.grids["segment_ids"].shape for mb in mbs) == [
+                (1, 384), (1, 384)]
+            eng._gauge_blocks_needed("train", mbs)
+            gauges = telemetry.get().snapshot(reset=True)["gauges"]
+            if gauged:
+                assert gauges["train/attn_blocks_needed_frac"] == (
+                    pytest.approx(want))
+            else:
+                assert "train/attn_blocks_needed_frac" not in gauges
+    finally:
+        telemetry.shutdown()

@@ -5,8 +5,12 @@ and ``CAUSAL_TILE_COST`` (``--window 0``: full causal).
     python tools/window_tile_sweep.py            # on the chip
     python tools/window_tile_sweep.py --compile  # here, for a described v5e
 
-``--rows`` rows of ``--length`` tokens, one document each, ``--window``
-(0 = none), ``--heads`` query / key-value heads of ``--head-dim``, bf16.
+``--rows`` rows of ``--length`` tokens, ``--window`` (0 = none),
+``--heads`` query / key-value heads of ``--head-dim``, bf16. Each layout
+of ``--documents`` is the lengths of a row's documents, comma-separated
+(``1876,288,511``; what is left of the row is padding; default: the row is
+one document): the kernel skips the key blocks a query block's documents
+do not reach, so a cell's table is measured at its layout.
 Per block shape of ``--blocks`` (``Q``, ``QxKV`` or ``QxKVxCOMPUTE``: the
 query block, the key block fetched, the key block computed at a time) and
 per backward of ``--fused`` (0 = dKV and dQ kernels, 1 = the one fused
@@ -15,7 +19,9 @@ run) and forward + backward (the residual-saving forward and the
 backward), through the wrapper — its layout glue included — host clock
 around ``block_until_ready`` over ``--iters`` calls; then c = (2 forward
 + 1 forward-and-backward) / (rows x query tokens x visited key tokens at
-the padded length), in ns. The flash wrapper (causal, no window, K/V
+the padded length), in ns — visited by the STATIC mask
+(``blocks_visited``); ``needed`` is what the layout leaves of them
+(``blocks_needed``). The flash wrapper (causal, no window, K/V
 repeated, its own tile rule) is timed at the same shapes beside it.
 Prints one JSON line per shape and writes them to
 ``chiprun_out/<--out>.jsonl``.
@@ -35,6 +41,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
 from jax.experimental.pallas.ops.tpu.splash_attention import (  # noqa: E402
     splash_attention_kernel as splash,
 )
@@ -69,6 +76,9 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--length", type=int, nargs="+", default=[8192])
     ap.add_argument("--rows", type=int, default=1)
+    ap.add_argument("--documents", nargs="+", default=[None],
+                    help="layouts: a row's document lengths, "
+                         "comma-separated (default: one document)")
     ap.add_argument("--window", type=int, default=1024,
                     help="0: full causal")
     ap.add_argument("--blocks", "--tiles", nargs="+",
@@ -93,7 +103,10 @@ def main() -> int:
         sharding = SingleDeviceSharding(topo.devices[0])
     lines = []
     R = args.rows
-    for L in args.length:
+    for L, layout in ((L, d) for L in args.length for d in args.documents):
+        docs = [L] if layout is None else [int(n) for n in layout.split(",")]
+        row = np.repeat(np.arange(1, len(docs) + 1), docs)
+        row = np.pad(row, (0, L - len(row))).astype(np.int32)
         shapes = [jax.ShapeDtypeStruct((R, L, h, args.head_dim), jnp.bfloat16,
                                        sharding=sharding)
                   for h in (hq, hkv, hkv)]
@@ -102,7 +115,7 @@ def main() -> int:
             keys = jax.random.split(jax.random.PRNGKey(0), 3)
             q, k, v = (jax.random.normal(kk, s.shape, jnp.float32).astype(
                 jnp.bfloat16) for kk, s in zip(keys, shapes))
-            seg = jnp.ones((R, L), jnp.int32)
+            seg = jnp.tile(row, (R, 1))
 
         def run(name, attend, visited_tokens, **what):
             fwd = jax.jit(lambda q, k, v, s: attend(q, k, v, s, s))
@@ -111,7 +124,7 @@ def main() -> int:
                     jnp.float32).sum(), argnums=(0, 1, 2)))
             line = {"kernel": name, "rows": R, "length": L,
                     "heads": [hq, hkv], "head_dim": args.head_dim,
-                    "window": args.window, **what}
+                    "window": args.window, "documents": docs, **what}
             try:
                 if args.compile:
                     for f in (fwd, both):
@@ -139,9 +152,14 @@ def main() -> int:
                 wa._block_sizes = lambda t, w, sizes=sizes: sizes
                 n_pad = wa.padded_len(L, window)
                 visited, _ = wa.blocks_visited(n_pad, tile, window)
+                # (a tree from before the kernel skipped blocks has none)
+                needed = int(wa.blocks_needed(
+                    np.pad(row, (0, n_pad - L)), tile, window).sum()
+                ) if hasattr(wa, "blocks_needed") else visited
                 run("grouped", lambda q, k, v, s, s2: wa.window_attention(
                     q, k, v, s, s2, window=window), visited * tile * tile,
-                    blocks=spec, fused_bwd=fused, padded=n_pad)
+                    blocks=spec, fused_bwd=fused, padded=n_pad,
+                    visited=visited, needed=needed)
         tile = fa.pick_tile(L)
         n_pad = fa._round_up(L, tile)
         run("flash", fa.flash_attention, n_pad * (n_pad + tile) / 2,
