@@ -359,8 +359,8 @@ class JaxTrainEngine(TrainableEngine):
         self.logprob_chunk = logprob_chunk
         # Rows of a micro-batch split over the mesh's data axes: the packer
         # makes their count a multiple of that degree, or the row-wise
-        # shard_maps (flash attention, the expert-parallel MoE layer) cannot
-        # split them and every chip computes every row.
+        # shard_maps (the attention kernel, the expert-parallel MoE layer)
+        # cannot split them and every chip computes every row.
         self.rows_multiple = 1
         if mesh is not None:
             from areal_tpu.parallel.mesh import DATA_AXES
@@ -853,7 +853,7 @@ class JaxTrainEngine(TrainableEngine):
     def remat_plan(self) -> Dict[str, Dict[str, Any]]:
         """{"RxL": {entry, kept_bytes_estimate, budget_bytes, fell_back}}
         of every packed grid a grad program was dispatched for (read like
-        flash_attention.geometry_counts(); in the trainer worker's
+        window_attention.geometry_counts(); in the trainer worker's
         ``device_report``). Empty with ``remat`` off."""
         return {f"{R}x{L}": dict(plan)
                 for (R, L), plan in self._remat_plan.items()}

@@ -107,7 +107,7 @@ def split_into_microbatches(
     tests/test_packing_fill.py) the only coarse candidates were
     1536/2048-token rows at ≤0.85 fill, while the 128-grain sweep finds
     rows ≥0.92 full under a cap-4096 budget. 128 is the floor the Pallas
-    flash kernel's lane width imposes on row lengths. The rows-per-micro-batch choice is swept as well (the old
+    attention kernel's lane width imposes on row lengths. The rows-per-micro-batch choice is swept as well (the old
     fixed ``cap // L`` wasted up to R-1 padding rows in the last
     micro-batch). Finer candidates mean the compiled [R, L] shape tracks
     the length distribution more closely — more distinct shapes across

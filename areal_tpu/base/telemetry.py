@@ -269,8 +269,7 @@ MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_exchange", "moe_experts")
 # Inside "attention" (ops/attention.py): the grouped-head kernel with its
 # layout glue (ops/pallas/window_attention.py) — a sliding-window layer's
 # call, and a full-causal one's. Listed apart like MOE_SCOPES: a reader
-# that knows only DEVICE_SCOPES sees their ops under "attention", beside
-# the flash kernel's.
+# that knows only DEVICE_SCOPES sees their ops under "attention".
 WINDOW_SCOPES = ("window_attention", "causal_attention")
 # A block with gated attention and sandwich norms (afmoe,
 # models/transformer.py): the gate's projection (inside "qkv_proj") and
@@ -286,11 +285,11 @@ SSM_SCOPES = ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",
 # A decoder-hybrid-decoder model's own blocks (phi4flash; models/ssm.py,
 # models/transformer.py): the S6 mixer a stage (the scan's kernels under
 # "s6_scan"), a gated memory unit, everything of a cross-attention layer
-# (its q and o projections and the flash kernel over another layer's
+# (its q and o projections and the causal kernel over another layer's
 # K/V), and differential attention's lambda-combine with its sub-norm
 # (behind "attention" or "cross_attention"). No DEVICE_SCOPES name lies
-# between them and "layer_scan", but "window_attention" and the flash
-# kernel's ops inside "cross_attention".
+# between them and "layer_scan", but "causal_attention" lies inside
+# "cross_attention".
 SAMBAY_SCOPES = ("s6_in_proj", "s6_conv", "s6_xdt_proj", "s6_scan",
                  "s6_out_proj", "gmu", "cross_attention",
                  "diff_attn_combine")

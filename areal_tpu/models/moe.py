@@ -67,9 +67,9 @@ Parity target: ``realhf/impl/model/modules/moe/`` — ``TopKRouter``
    front exactly as an ep shard sorts its own (:func:`_held_eid`), and a
    pair that chose an expert held elsewhere is the sentinel: it adds
    nothing, and no collective or stand-in for one is set up;
- - the GShard one-hot-einsum dispatch is kept as the parity ORACLE behind
-   ``AREAL_MOE_DISPATCH=einsum`` (same contract as ``AREAL_RING_SCHEDULE``
-   / ``AREAL_PP_SCHEDULE``); it shares the router and the drop policy;
+ - the GShard one-hot-einsum dispatch (``dispatch="einsum"``) is kept as
+   the parity ORACLE of gated experts without a latent projection; it
+   shares the router and the drop policy;
  - sinkhorn routing is not implemented (the reference defaults to aux-loss
    balancing for its shipped configs).
 
@@ -99,7 +99,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -117,10 +116,10 @@ SUMMED_AUX = ("routed_rows", "local_rows", "passes", "full_passes")
 
 
 def resolve_dispatch(method: Optional[str] = None) -> str:
-    """The dispatch actually run: explicit arg > ``AREAL_MOE_DISPATCH`` >
-    "grouped". "einsum" is the GShard one-hot oracle kept for parity."""
+    """The dispatch actually run: the explicit arg, else "grouped".
+    "einsum" is the GShard one-hot oracle kept for parity."""
     if method is None:
-        method = os.environ.get("AREAL_MOE_DISPATCH", "").strip() or "grouped"
+        method = "grouped"
     if method not in DISPATCH_METHODS:
         raise ValueError(
             f"unknown MoE dispatch {method!r} (one of {DISPATCH_METHODS})"
@@ -273,7 +272,7 @@ def _dispatch_einsum(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The original one-hot capacity-buffer dispatch — every op a
     static-shape batched matmul, FLOPs/HBM scale with E × capacity.
-    Kept as the parity oracle (``AREAL_MOE_DISPATCH=einsum``)."""
+    Kept as the parity oracle (``dispatch="einsum"``)."""
     N, D = xf.shape
     k = moe.top_k
     C = capacity(N, moe)
@@ -492,7 +491,7 @@ def _whole_row_tiles(xe: jnp.ndarray) -> jnp.ndarray:
 
 
 # How each expert pass traced adds its rows into their tokens, recorded
-# where it is traced (as flash_attention.geometry_counts): {(entries M,
+# where it is traced (as window_attention.geometry_counts): {(entries M,
 # rows R, token rows written, width): "rows" | "entries"}.
 _COMBINES: Dict[Tuple[int, int, int, int], str] = {}
 
@@ -877,7 +876,7 @@ def moe_mlp(
     moe: MoEConfig,
     rng: jnp.ndarray = None,  # jitter noise (training only); None = off
     mask: jnp.ndarray = None,  # [B, T] bool/int — True for real tokens
-    dispatch: Optional[str] = None,  # None → AREAL_MOE_DISPATCH → "grouped"
+    dispatch: Optional[str] = None,  # None → "grouped"
     mesh: Optional[Mesh] = None,  # a mesh with ep > 1 → the EP path
     impl: str = "auto",  # the transformer's ``attn_impl``: kernels or XLA
     interpret: bool = False,  # run a Pallas kernel in its interpreter (CPU)

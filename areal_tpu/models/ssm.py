@@ -47,7 +47,7 @@ import jax.numpy as jnp
 from areal_tpu.models.config import S6Config, SSMConfig
 
 # Scans per compiled program, counted where they are traced (as
-# flash_attention.geometry_counts): {(rows, length, chunk, heads, groups):
+# window_attention.geometry_counts): {(rows, length, chunk, heads, groups):
 # calls}.
 _GEOMETRY: collections.Counter = collections.Counter()
 

@@ -874,11 +874,10 @@ def _scan_mixer_layers(cfg: TransformerConfig, layer: Callable, h, xs,
 # least kept to the most; each entry keeps what the one before it keeps.
 #   full      — the layer's input only: the backward re-runs the layer.
 #   attention — and what the attention kernel's backward reads of its
-#               forward (its output and softmax statistics; the
-#               grouped-head kernel's, full or windowed, or the flash
-#               kernel's): the forward kernel is not re-run. The padded
-#               (flash: and repeated) q/k/v it was handed are recomputed;
-#               the XLA reference attention keeps nothing.
+#               forward (its output and softmax statistics, full or
+#               windowed): the forward kernel is not re-run. The padded
+#               q/k/v it was handed are recomputed; the XLA reference
+#               attention keeps nothing.
 #   matmuls   — and the outputs of the layer's matmuls (q/k/v, o_proj,
 #               gate, up, the router; down's output nobody reads). Norms,
 #               rope, silu·up, casts and the kernel's glue are recomputed.
@@ -890,17 +889,6 @@ def _scan_mixer_layers(cfg: TransformerConfig, layer: Callable, h, xs,
 REMAT_ENTRIES = ("full", "attention", "matmuls")
 
 
-def _flash_residuals_saveable(prim, *_, **params) -> bool:
-    """Checkpoint policy: keep the outputs of the flash kernel's forward
-    rule. jax's kernel is a ``custom_vjp`` whose forward rule calls the
-    kernel once more as a ``custom_vjp_call`` that also returns the
-    softmax statistics — those three outputs are all of the rule's
-    residuals that are not its inputs."""
-    return prim.name == "custom_vjp_call" and any(
-        eqn.primitive.name == "pallas_call"
-        for eqn in params["call_jaxpr"].eqns)
-
-
 def _remat_policy(entry: str):
     from areal_tpu.ops.pallas.window_attention import RESIDUALS
 
@@ -908,8 +896,7 @@ def _remat_policy(entry: str):
     if entry == "full":
         return None
     # The grouped-head (splash) kernel names its output and statistic.
-    kernels = policies.save_from_both_policies(
-        _flash_residuals_saveable, policies.save_only_these_names(RESIDUALS))
+    kernels = policies.save_only_these_names(RESIDUALS)
     if entry == "attention":
         return kernels
     if entry == "matmuls":
@@ -968,7 +955,7 @@ def remat_kept_bytes(
     and so padded length may differ. Arithmetic on the widths in ``cfg``,
     checked against what jax really keeps in tests/test_remat_plan.py and
     against the chip's compiler in PERF.md §5."""
-    from areal_tpu.ops.pallas.flash_attention import LANE
+    from areal_tpu.ops.pallas.window_attention import LANE
 
     full = tokens * cfg.hidden_dim * itemsize
     # The kernel writes heads padded to the lane width, and one float32

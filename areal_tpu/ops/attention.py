@@ -5,9 +5,10 @@ Replaces the reference's flash-attn varlen path
 areal_tpu packs sequences into ``[B, L]`` rows with per-token segment ids
 (0 = padding) and uses block-causal same-segment masking — the layout TPU
 splash-attention kernels natively support. Pallas kernels back the TPU
-path (``areal_tpu/ops/pallas/window_attention.py``: causal self-attention,
-full or windowed; ``flash_attention.py``: the rest); this module holds the
-dispatch and the pure-XLA reference used on CPU and for parity tests.
+path (``areal_tpu/ops/pallas/window_attention.py``: causal self-attention
+of a packed row, full or windowed); this module holds the dispatch and the
+pure-XLA reference — the CPU's path, the parity tests' oracle, and what
+every call the kernel does not take runs.
 
 Shapes: q ``[B, T, Hq, D]``; k, v ``[B, S, Hkv, D]`` with Hq = G * Hkv (GQA).
 """
@@ -27,23 +28,13 @@ _NEG_INF = -1e30
 
 # Which implementation each packed_attention call TRACED to, by the label
 # of the compiled step it was traced for: {label: {"pallas" | "reference"
-# | "window" | "fallback": n}}. "pallas" = a Pallas kernel over a full
-# layer, "window" = the windowed kernel of a sliding-window layer,
-# "fallback" = a TPU kernel was wanted and the O(S^2) reference ran
-# instead (a shape with no 128-multiple block). Counted at trace time, so
-# it describes the compiled programs; chip_smoke.py fails when the train
-# step's fallback count is not zero.
+# | "window" | "fallback": n}}. "pallas" = the grouped-head kernel under
+# a causal mask (a full layer), "window" = the same kernel under a sliding
+# window, "fallback" = a TPU kernel was wanted and the O(S^2) reference
+# ran instead (a row off the 128-token lane grid, a non-causal call, T !=
+# S). Counted at trace time, so it describes the compiled programs;
+# chip_smoke.py fails when the train step's fallback count is not zero.
 _DISPATCH: Dict[str, collections.Counter] = collections.defaultdict(
-    collections.Counter
-)
-# Which KERNEL each of those kernel calls ran, the same way: {label:
-# {"causal" | "window" | "flash": n}}. "causal" = the grouped-head kernel
-# under a causal mask (a packed row over itself, no window), "window" =
-# the same kernel under a window, "flash" = jax's flash kernel (what the
-# grouped kernel does not take: a non-causal call, T != S). A count beside
-# _DISPATCH and not a label in it: every `correct` of the benchmark holds
-# a step's labels to exactly {"pallas"} or {"pallas", "window"}.
-_KERNELS: Dict[str, collections.Counter] = collections.defaultdict(
     collections.Counter
 )
 _LABEL = contextvars.ContextVar("attention_dispatch_label",
@@ -65,18 +56,12 @@ def active_label() -> str:
     return _LABEL.get()
 
 
-def count_dispatch(impl: str, kernel: Optional[str] = None) -> None:
+def count_dispatch(impl: str) -> None:
     _DISPATCH[active_label()][impl] += 1
-    if kernel is not None:
-        _KERNELS[active_label()][kernel] += 1
 
 
 def dispatch_counts() -> Dict[str, Dict[str, int]]:
     return {label: dict(c) for label, c in _DISPATCH.items()}
-
-
-def kernel_counts() -> Dict[str, Dict[str, int]]:
-    return {label: dict(c) for label, c in _KERNELS.items()}
 
 
 def segment_mask(
@@ -172,67 +157,49 @@ def packed_attention(
     impl: str = "auto",
     scale: Optional[float] = None,
 ) -> jnp.ndarray:
-    """Dispatch between the XLA reference and the Pallas TPU kernels.
+    """Dispatch between the XLA reference and the Pallas TPU kernel.
 
-    ``impl="auto"`` means a kernel on a TPU and the reference elsewhere.
-    Which kernel follows from the call itself: a causal call of a packed
-    row over itself (``T == S``) takes the grouped-head kernel — K and V
-    at their own head count — under a causal mask, or under a
-    ``sliding_window`` a windowed one, which skips the key blocks outside
-    the window (counted as "window"); a non-causal call or one with
-    ``T != S`` takes jax's flash kernel, K and V repeated. On a TPU
-    nothing quietly replaces a kernel: a failed import raises, and the
-    one case the kernels cannot run (a sequence dim with no 128-multiple
-    block — a prompt bucket, never a packed training row) is counted as
+    ``impl="auto"`` means the kernel on a TPU and the reference elsewhere.
+    The kernel takes a causal call of a packed row over itself (``T ==
+    S``) on the 128-token lane grid: the grouped-head kernel — K and V at
+    their own head count — under a causal mask (counted as "pallas"), or
+    under a ``sliding_window`` a windowed one, which skips the key blocks
+    outside the window (counted as "window"). On a TPU nothing quietly
+    replaces it: a failed import raises, and every call it does not take
+    (a row off the lane grid — a prompt bucket, never a packed training
+    row —, a non-causal call, ``T != S``) runs the reference counted as
     "fallback" under the active :func:`dispatch_label`. ``scale`` defaults
     to ``head_dim ** -0.5`` in every implementation."""
     wanted_kernel = _wants_kernel(impl)
     if wanted_kernel:
-        from areal_tpu.ops.pallas import flash_attention as fa
+        from areal_tpu.ops.pallas import window_attention as wa
 
         if v.shape[-1] > q.shape[-1]:
             # A value wider than q/k (differential attention: 128 over
-            # 64): the kernels take ONE head size, and pad a smaller one
+            # 64): the kernel takes ONE head size, and pads a smaller one
             # to the 128 lanes anyway — q and k get the zeros here.
             if scale is None:
                 scale = q.shape[-1] ** -0.5
             wider = [(0, 0)] * 3 + [(0, v.shape[-1] - q.shape[-1])]
             q, k = jnp.pad(q, wider), jnp.pad(k, wider)
 
-    if wanted_kernel and fa.pick_block_sizes(
-            q.shape[1], k.shape[1]) is not None:
+    if (wanted_kernel and causal and q.shape[1] == k.shape[1]
+            and wa.padded_len(q.shape[1], sliding_window) is not None):
+        # A packed row over itself: the grouped-head kernel, K/V at their
+        # own head count, under a causal or a windowed mask.
         from areal_tpu.parallel.sharding import current_mesh
 
+        counted, scope = (("pallas", wa.CAUSAL_SCOPE)
+                          if sliding_window is None else ("window", wa.SCOPE))
+        count_dispatch(counted)
+        kernel = partial(wa.window_attention, window=sliding_window,
+                         scale=scale)
         mesh = current_mesh()
-        on_mesh = mesh is not None and mesh.size > 1
-        if causal and q.shape[1] == k.shape[1]:
-            # A packed row over itself: the grouped-head kernel, K/V at
-            # their own head count, under a causal or a windowed mask.
-            from areal_tpu.ops.pallas import window_attention as wa
-
-            impl, which, scope = (
-                ("pallas", "causal", wa.CAUSAL_SCOPE)
-                if sliding_window is None else
-                ("window", "window", wa.SCOPE))
-            count_dispatch(impl, kernel=which)
-            kernel = partial(wa.window_attention, window=sliding_window,
-                             scale=scale)
-            with jax.named_scope(scope):
-                if on_mesh:
-                    return fa.kernel_on_mesh(
-                        kernel, mesh, q, k, v, q_segment_ids, kv_segment_ids)
-                return kernel(q, k, v, q_segment_ids, kv_segment_ids)
-        if sliding_window is None:
-            count_dispatch("pallas", kernel="flash")
-            if on_mesh:
-                return fa.flash_attention_on_mesh(
-                    mesh, q, k, v, q_segment_ids, kv_segment_ids,
-                    causal=causal, scale=scale,
-                )
-            return fa.flash_attention(
-                q, k, v, q_segment_ids, kv_segment_ids,
-                causal=causal, scale=scale,
-            )
+        with jax.named_scope(scope):
+            if mesh is not None and mesh.size > 1:
+                return wa.kernel_on_mesh(
+                    kernel, mesh, q, k, v, q_segment_ids, kv_segment_ids)
+            return kernel(q, k, v, q_segment_ids, kv_segment_ids)
     count_dispatch("fallback" if wanted_kernel else "reference")
     mask = segment_mask(
         q_segment_ids, kv_segment_ids, q_positions, kv_positions, causal,

@@ -36,8 +36,7 @@ kernel call). Wrapped with areal_tpu's packed-batch semantics:
 
 The kernels' device ops are named ``splash_mqa_{fwd,dkv,dq}_segmented_*``
 (a full-causal call runs no ``dq``: its backward is the one fused ``dkv``
-kernel) — not ``flash_attention`` / ``flash_mha_bwd_*``, so a reader of
-the flash kernels' time does not count them. Per compiled step,
+kernel). Per compiled step,
 :func:`geometry_counts` says which (length, padded length, tile, window)
 each WINDOWED call was traced with, and how many key blocks its static
 mask visits against a causal kernel's; :func:`causal_geometry_counts`
@@ -68,8 +67,10 @@ from jax.experimental.pallas.ops.tpu.splash_attention import (
 )
 
 from areal_tpu.ops import attention as _attention
-from areal_tpu.ops.pallas.flash_attention import LANE, _round_up
 
+# The TPU's lane width: heads are padded to it, and a row the kernel takes
+# is a multiple of it.
+LANE = 128
 # The device scope around the kernel and its layout glue, inside the
 # transformer's "attention" (base/telemetry.WINDOW_SCOPES): a windowed
 # call's, and a full-causal call's.
@@ -101,6 +102,10 @@ CAUSAL_TILE_COST = {1024: 0.2804, 768: 0.3314, 512: 0.3593, 256: 0.8073,
 # A causal call's key block is computed 512 keys at a time where that
 # divides it (tile 1024: 5-6 % cheaper than whole).
 _CAUSAL_KV_COMPUTE = 512
+
+
+def _round_up(n: int, tile: int) -> int:
+    return -(-n // tile) * tile
 
 
 def _static_blocks(n: int, tile: int, window: Optional[int]) -> np.ndarray:
@@ -429,3 +434,40 @@ def window_attention(
     # attended the padding before it, and a block of nothing but padding
     # ran no key block, so its row is 0 * (1 / 0).
     return jnp.where((q_segment_ids > 0)[:, :, None, None], out, 0)
+
+
+def kernel_on_mesh(kernel, mesh, q, k, v, q_segment_ids, kv_segment_ids):
+    """``kernel(q, k, v, q_segment_ids, kv_segment_ids)`` — a partial of
+    :func:`window_attention` — under a multi-device mesh. GSPMD cannot
+    partition a Mosaic kernel ("wrap the call in a shard_map"), so the call
+    runs in a shard_map manual over every mesh axis not already manual (the
+    pipeline stages are manual over "pp"): batch rows split over the data
+    axes and heads over "tp" where the dims divide — an axis that does not
+    divide, and pp/sp, compute redundantly. Attention has no cross-row or
+    cross-head term, so the body needs no collective."""
+    from jax.sharding import PartitionSpec as P
+
+    from areal_tpu.parallel.mesh import DATA_AXES
+
+    outer = jax.sharding.get_abstract_mesh()
+    if outer.manual_axes:  # nested: shard_map wants the context's own mesh
+        mesh = outer
+    free = frozenset(mesh.axis_names) - frozenset(outer.manual_axes)
+    data = tuple(a for a in DATA_AXES if a in free)
+    n_data = 1
+    for a in data:
+        n_data *= mesh.shape[a]
+    if q.shape[0] % n_data != 0:
+        data = ()
+    heads = ("tp" if "tp" in free and k.shape[2] % mesh.shape["tp"] == 0
+             else None)
+    qkv = P(data or None, None, heads, None)
+    seg = P(data or None, None)
+    return jax.shard_map(
+        kernel,
+        mesh=mesh,
+        in_specs=(qkv, qkv, qkv, seg, seg),
+        out_specs=qkv,
+        axis_names=free,
+        check_vma=False,
+    )(q, k, v, q_segment_ids, kv_segment_ids)

@@ -52,7 +52,6 @@ the server).
 
 from __future__ import annotations
 
-import os
 from contextlib import nullcontext
 from typing import Dict, Optional, Tuple
 
@@ -201,7 +200,7 @@ def pipeline_apply_layers(
     n_micro: int,
     attn_impl: str = "auto",
     remat: bool = False,
-    schedule: Optional[str] = None,  # "1f1b" (default) | "gpipe" (oracle)
+    schedule: str = "1f1b",  # | "gpipe" (the oracle)
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Run the stacked layers as a ``pp``-stage pipeline.
 
@@ -209,7 +208,7 @@ def pipeline_apply_layers(
     that downstream's sum/mean post-processing is an identity.
 
     ``schedule`` selects the memory-bounded 1F1B custom-vjp path (default)
-    or the GPipe scan oracle; ``AREAL_PP_SCHEDULE`` overrides the default.
+    or the GPipe scan oracle.
 
     PP∘SP: on meshes with sp > 1 the stages are manual over {"pp","sp"}
     and run ring attention inline (ring_mod.ring_attention_inline). The
@@ -217,8 +216,6 @@ def pipeline_apply_layers(
     sequence dim, inverted on the way out — so the stage bodies see the
     striped shard order while callers keep natural-order semantics.
     """
-    if schedule is None:
-        schedule = os.environ.get("AREAL_PP_SCHEDULE", "1f1b")
     if schedule not in ("1f1b", "gpipe"):
         raise ValueError(f"unknown pipeline schedule {schedule!r}")
     sp = mesh.shape.get("sp", 1)

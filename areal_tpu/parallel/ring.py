@@ -4,8 +4,8 @@ Fills the reference's explicit long-context gap (SURVEY §5: "No ring
 attention, no Ulysses, no context parallelism anywhere in the repo" — the
 reference leans on Megatron-SP + flash-attn only). The v1 contiguous
 schedule (every step computes the full local attention einsum) is kept as
-the parity ORACLE behind ``AREAL_RING_SCHEDULE=naive``; the default
-``zigzag`` schedule is the production path:
+the parity ORACLE (``schedule="naive"``); the default ``zigzag`` schedule
+is the production path:
 
  - **zig-zag (striped) layout** — the global sequence splits into ``2n``
    chunks of ``c = T/(2n)``; ring rank ``r`` holds chunk ``r`` (early) and
@@ -43,7 +43,6 @@ build a :class:`RingCtx` from their sharded-iota rank.
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 from functools import partial
 from typing import Dict, Optional
@@ -60,6 +59,9 @@ from areal_tpu.parallel.mesh import DATA_AXES
 _NEG_INF = -1e30
 
 SCHEDULES = ("zigzag", "naive")
+# What a call that names no schedule runs (the pipeline stages' inline
+# ring among them).
+DEFAULT_SCHEDULE = "zigzag"
 
 # Trace-time structural counters: incremented while the schedule is being
 # traced (plain Python), so tests can prove the masked-block skip without
@@ -89,11 +91,12 @@ def ring_skip_ratio() -> float:
 
 def resolve_schedule(schedule: Optional[str], seq_len: int, n: int,
                      causal: bool = True) -> str:
-    """The schedule actually run: explicit arg > ``AREAL_RING_SCHEDULE`` >
-    "zigzag"; downgrades to "naive" when zig-zag can't apply (non-causal
-    attention skips nothing; the layout needs ``T % 2n == 0``)."""
+    """The schedule actually run: the explicit arg, else
+    ``DEFAULT_SCHEDULE``; downgrades to "naive" when zig-zag can't apply
+    (non-causal attention skips nothing; the layout needs ``T % 2n ==
+    0``)."""
     if schedule is None:
-        schedule = os.environ.get("AREAL_RING_SCHEDULE", "zigzag")
+        schedule = DEFAULT_SCHEDULE
     if schedule not in SCHEDULES:
         raise ValueError(
             f"unknown ring schedule {schedule!r} (one of {SCHEDULES})"
@@ -396,7 +399,7 @@ def ring_attention(
     axis_name: str = "sp",
     causal: bool = True,
     scale: Optional[float] = None,
-    schedule: Optional[str] = None,  # None → AREAL_RING_SCHEDULE → "zigzag"
+    schedule: Optional[str] = None,  # None → DEFAULT_SCHEDULE
 ) -> jnp.ndarray:
     """Context-parallel attention: sequence dim sharded over ``axis_name``."""
     if scale is None:

@@ -331,16 +331,13 @@ class TrainerWorker:
         from areal_tpu.base import monitor
         from areal_tpu.ops import attention, native
         from areal_tpu.models import moe, ssm
-        from areal_tpu.ops.pallas import flash_attention, window_attention
+        from areal_tpu.ops.pallas import window_attention
 
         monitor.log_device_report(
             logger, f"trainer{self.cfg.dist_rank}", stage=stage,
             attention=attention.dispatch_counts(),
-            # {label: {"causal" | "window" | "flash": calls traced}}: which
-            # kernel the "pallas" and "window" calls above ran
-            attention_kernels=attention.kernel_counts(),
-            # {label: {"length>padded/tile": calls traced}}: the grouped
-            # kernel's full-causal calls, and the flash kernel's
+            # {label: {"length>padded/tile": calls traced}}: the kernel's
+            # full-causal calls (the "pallas" count above)
             causal_geometry={
                 label: {"%d>%d/%d" % geom: n for geom, n in counts.items()}
                 for label, counts in
@@ -352,10 +349,6 @@ class TrainerWorker:
             attn_blocks_needed={
                 "%dx%d>%d/%d/w%d" % grid: c for grid, c in
                 window_attention.needed_counts().items()
-            },
-            flash_geometry={
-                label: {"%d>%d/%d" % geom: n for geom, n in counts.items()}
-                for label, counts in flash_attention.geometry_counts().items()
             },
             # {label: {"length>padded/tile/window": {calls, blocks_visited,
             # blocks_causal}}}: the windowed kernel's calls, and the key

@@ -59,8 +59,8 @@ QWEN25_05B = dict(
 # PPO shape: 12 prompts x group 4 = 48 trajectories of 13..15 prompt + 155
 # generated tokens (min_new_tokens pins the length), which the packer lays
 # three to a row into two [8, 512] micro-batches at 0.99 fill — packed
-# 128-multiple rows with several documents each, what the flash kernel
-# tiles and masks.
+# 128-multiple rows with several documents each, what the attention
+# kernel tiles and masks.
 N_PROMPTS, GROUP, NEW_TOKENS, TRAIN_STEPS = 12, 4, 155, 3
 # generation phase: 2 prompts x group 4, 96 new tokens in 32-token chunks.
 GEN_PROMPTS, GEN_NEW_TOKENS, GEN_CHUNK = 2, 96, 32
@@ -274,7 +274,7 @@ def run_default(r: Runner, args: argparse.Namespace,
     rec = check_trainer(r.wait(p, 800), "sync_ppo", 1, device)
     first = rec["steps"][0]
     # Same weights generated and scored the step-1 batch: the decode path
-    # (KV cache, XLA attention) and the packed train path (flash kernel)
+    # (KV cache, XLA attention) and the packed train path (Pallas kernel)
     # must agree — importance ratio 1, actor-vs-ref KL 0.
     check(abs(first["importance_weight"] - 1.0) < 0.05,
           f"sync_ppo: step-1 importance weight {first['importance_weight']}")

@@ -83,22 +83,12 @@ def test_grouped_matches_einsum_fwd_and_grad(E, k, cf):
             rtol=2e-4, atol=1e-6, err_msg=f"grad mismatch on {name}")
 
 
-def test_grouped_is_default_and_env_oracle():
+def test_grouped_is_default_and_the_oracle_is_asked_for_by_name():
     assert moemod.resolve_dispatch(None) == "grouped"
+    assert moemod.resolve_dispatch("grouped") == "grouped"
     assert moemod.resolve_dispatch("einsum") == "einsum"
     with pytest.raises(ValueError, match="unknown MoE dispatch"):
         moemod.resolve_dispatch("scatter")
-    old = dict(__import__("os").environ)
-    import os
-
-    try:
-        os.environ["AREAL_MOE_DISPATCH"] = "einsum"
-        assert moemod.resolve_dispatch(None) == "einsum"
-        # explicit arg wins over the env var
-        assert moemod.resolve_dispatch("grouped") == "grouped"
-    finally:
-        os.environ.clear()
-        os.environ.update(old)
 
 
 def test_routing_health_aux():

@@ -17,6 +17,7 @@ from areal_tpu.models import transformer
 from areal_tpu.models.config import tiny_config
 from areal_tpu.parallel import mesh as pmesh
 from areal_tpu.parallel import pipeline as ppl
+from areal_tpu.parallel import ring as ring_mod
 from areal_tpu.parallel import sharding as psh
 
 
@@ -333,7 +334,7 @@ def test_ppsp_matches_gspmd_oracle(sched, ring_schedule, monkeypatch):
     ring attention running inside each stage (both ring schedules), must
     reproduce the dense scan oracle's loss AND gradients at the existing
     pipeline parity tolerances."""
-    monkeypatch.setenv("AREAL_RING_SCHEDULE", ring_schedule)
+    monkeypatch.setattr(ring_mod, "DEFAULT_SCHEDULE", ring_schedule)
     cfg = tiny_config(n_layers=4, hidden_dim=32, n_q_heads=4, n_kv_heads=2)
     params = transformer.init_params(cfg, jax.random.PRNGKey(7))
     tokens, positions, seg = _batch(cfg, seed=7)

@@ -120,30 +120,6 @@ def test_the_forward_kernel_is_kept_not_rerun(entry, calls, on_mesh):
     assert "flash" not in text
 
 
-@pytest.mark.parametrize("entry,calls", [("full", 4), ("attention", 3)])
-def test_the_flash_kernels_residuals_are_kept_where_it_still_runs(entry,
-                                                                  calls):
-    """A call the grouped kernel does not take (non-causal) runs jax's
-    flash kernel, whose forward rule's outputs the same policy keeps:
-    forward twice beside dKV and dQ under "full", once under
-    "attention"."""
-    from areal_tpu.ops import attention
-
-    q = jnp.zeros((2, 256, 4, 64), jnp.bfloat16)
-    kv = jnp.zeros((2, 256, 2, 64), jnp.bfloat16)
-    seg = jnp.ones((2, 256), jnp.int32)
-
-    def loss(q, k, v):
-        out = attention.packed_attention(q, k, v, seg, seg, causal=False,
-                                         impl="pallas")
-        return jnp.sum(out.astype(jnp.float32) ** 2)
-
-    body = jax.checkpoint(loss, policy=transformer._remat_policy(entry))
-    text = str(jax.make_jaxpr(jax.grad(body, argnums=(0, 1, 2)))(q, kv, kv))
-    assert text.count("pallas_call[") == calls
-    assert "splash" not in text
-
-
 def test_expert_layer_is_never_kept():
     """``ragged_dot`` is not a ``dot_general``: under every entry the
     gradient holds the same grouped GEMMs (the recomputed forward's
@@ -205,7 +181,7 @@ MOE_WIDTHS = dict(
 @pytest.mark.parametrize("entry", ENTRIES)
 @pytest.mark.parametrize("widths,attn_impl", [
     (QWEN_WIDTHS, "pallas"), (QWEN_WIDTHS, "reference"),
-    (MOE_WIDTHS, "pallas")], ids=["qwen-flash", "qwen-xla", "moe-flash"])
+    (MOE_WIDTHS, "pallas")], ids=["qwen-kernel", "qwen-xla", "moe-kernel"])
 def test_estimate_matches_what_jax_keeps(widths, attn_impl, entry):
     """Within 5 %: the arithmetic names every kept array (per token and
     layer in the compute dtype; the kernel's output at the PADDED length,

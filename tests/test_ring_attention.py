@@ -103,10 +103,8 @@ def test_zigzag_matches_naive_oracle():
                                atol=1e-6)
 
 
-def test_resolve_schedule_env_and_downgrades(monkeypatch):
-    monkeypatch.setenv("AREAL_RING_SCHEDULE", "naive")
-    assert ring_mod.resolve_schedule(None, 32, 4) == "naive"
-    monkeypatch.delenv("AREAL_RING_SCHEDULE")
+def test_resolve_schedule_default_and_downgrades():
+    assert ring_mod.resolve_schedule("naive", 32, 4) == "naive"
     assert ring_mod.resolve_schedule(None, 32, 4) == "zigzag"
     with pytest.raises(ValueError):
         ring_mod.resolve_schedule("bogus", 32, 4)

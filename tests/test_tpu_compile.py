@@ -3,7 +3,7 @@
 The TPU compiler is installed in the sandbox and compiles for a DESCRIBED,
 unattached v5e (on-chip-measurement guide §2.3): tiling alignment, the VMEM
 limit and whether a kernel can be partitioned are what interpret mode never
-checks. The flash kernel is asked for explicitly (``impl="pallas"``) — under
+checks. The kernel is asked for explicitly (``impl="pallas"``) — under
 a described topology ``jax.default_backend()`` is still ``cpu``, so
 ``"auto"`` would take the reference branch and prove nothing. A compile that
 passes is not a chip run.
@@ -27,11 +27,6 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# {T: rows}. T=512 is the row the packer emits under chip_smoke.py's config;
-# 2048 and 4096 are common packing caps; 6016 = 47 x 128 is the
-# benchmark's train-long row that no tile above 128 divides: the wrapper
-# pads it to 6144 and runs blocks of 512.
-KERNEL_T = {512: 2, 2048: 2, 4096: 2, 6016: 1}
 # The windowed kernel at Mellum 2's geometry (32 query / 4 key-value heads
 # of 128, window 1024): the benchmark's rows of 6016 (padded to 6144) and
 # 6656 tokens, and the longest row a micro-batch can be.
@@ -101,7 +96,6 @@ def _compile_all():
 
     from areal_tpu.models import transformer
     from areal_tpu.models.config import tiny_config
-    from areal_tpu.ops.pallas import flash_attention as fa
     from areal_tpu.ops.pallas import window_attention as wa
     from areal_tpu.parallel import mesh as pmesh
     from areal_tpu.parallel import sharding as psh
@@ -136,25 +130,7 @@ def _compile_all():
         }
         narrowed.clear()
 
-    # Forward AND backward at the Qwen2.5-0.5B geometry: 14 q / 2 kv heads
-    # (repeated to 14), head_dim 64 padded to 128 lanes, block_b=1.
     chip = SingleDeviceSharding(topo.devices[0])
-    for T, rows in KERNEL_T.items():
-        def spec(*shape, dtype=jnp.bfloat16):
-            return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-
-        def loss(q, k, v, seg):
-            o = fa.flash_attention(q, k, v, seg, seg)
-            return jnp.sum(o.astype(jnp.float32) ** 2)
-
-        record(f"kernel-{T}", jax.jit(
-            jax.value_and_grad(loss, argnums=(0, 1, 2))
-        ).lower(
-            spec(rows, T, 14, 64), spec(rows, T, 2, 64),
-            spec(rows, T, 2, 64), spec(rows, T, dtype=jnp.int32),
-        ).compile())
-        out[f"kernel-{T}"]["blocks"] = fa.pick_block_sizes(T, T)
-
     # The windowed kernel, forward AND backward, K/V at their 4 heads.
     for T, rows, window in [(T, r, 1024) for T, r in WINDOW_T.items()] + [
             (T, r, 2048) for T, r in WINDOW_2K_T.items()]:
@@ -227,7 +203,7 @@ def _compile_all():
     out["causal-mesh"].update(
         splash_kernels=sorted(
             k for k in splash_names if k in compiled.as_text()),
-        kernels=attn_ops.kernel_counts()["causal-mesh"])
+        dispatch=attn_ops.dispatch_counts()["causal-mesh"])
 
     # A value wider than q and k on a row with blocks to skip.
     (rows, T), (hq, hkv, dh, dv) = WIDE_VALUE
@@ -278,7 +254,7 @@ def _compile_all():
                    .compile())
 
     # The same under a layer pattern on the data-parallel mesh: the windowed
-    # kernel sits in the same shard_map as the flash kernel.
+    # call sits in the same shard_map as the causal one.
     import contextlib
     import dataclasses
 
@@ -485,16 +461,6 @@ def compiled(shared_run_dir, libtpu_lock):
     return results
 
 
-@pytest.mark.parametrize("T", KERNEL_T)
-def test_flash_attention_compiles_for_v5e(compiled, T):
-    """Forward, dq and dkv kernels all made it into the program, with the
-    block sizes pick_block_sizes returns, inside the VMEM/HBM limits."""
-    got = compiled[f"kernel-{T}"]
-    assert got["blocks"] == [512, 512]
-    assert got["custom_calls"] >= 3
-    assert got["temp_bytes"] < 2 << 30
-
-
 @pytest.mark.parametrize("T", WINDOW_T)
 def test_window_attention_compiles_for_v5e(compiled, T):
     """The windowed kernel's forward, dKV and dQ at the published heads
@@ -551,7 +517,7 @@ def test_causal_attention_compiles_on_the_v5e_mesh_at_olmoes_heads(compiled):
     """16 query / 16 key-value heads of 128 (groups of one), a row a chip
     of the 2 x 2 mesh, through ``packed_attention``'s dispatch."""
     got = compiled["causal-mesh"]
-    assert got["kernels"] == {"causal": 1}
+    assert got["dispatch"] == {"pallas": 1}
     assert got["splash_kernels"] == ["splash_mqa_dkv", "splash_mqa_fwd"]
     assert got["temp_bytes"] < 2 << 30
 
@@ -575,10 +541,10 @@ def test_a_layer_pattern_compiles_on_a_v5e_mesh(compiled):
 
 
 @pytest.mark.parametrize("spec", MESH_SPECS)
-def test_model_with_flash_kernel_compiles_on_a_v5e_mesh(compiled, spec):
+def test_model_with_the_kernel_compiles_on_a_v5e_mesh(compiled, spec):
     """Mosaic kernels cannot be partitioned by GSPMD: on more than one
     chip the lowering raises unless the call sits in a shard_map manual
-    over every mesh axis (ops/pallas/flash_attention.kernel_on_mesh) —
+    over every mesh axis (ops/pallas/window_attention.kernel_on_mesh) —
     the model's causal layers run the grouped-head kernel there, forward
     and fused backward."""
     assert compiled[f"mesh-{spec}"]["custom_calls"] >= 2
@@ -586,8 +552,7 @@ def test_model_with_flash_kernel_compiles_on_a_v5e_mesh(compiled, spec):
 
 @pytest.mark.parametrize("entry,calls", [("full", 3), ("attention", 2),
                                          ("matmuls", 2)])
-def test_kept_flash_residuals_spare_the_forward_kernel(compiled, entry,
-                                                       calls):
+def test_kept_residuals_spare_the_forward_kernel(compiled, entry, calls):
     """A layer of the compiled grad program holds the forward kernel
     twice under "full" (forward and recomputation, beside the fused
     backward) and once where the kernel's residuals are kept."""

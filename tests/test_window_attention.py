@@ -303,15 +303,12 @@ def test_a_value_wider_than_q_and_k(monkeypatch):
 
 
 def test_packed_attention_picks_the_kernel_by_what_it_is_handed(monkeypatch):
-    """impl="pallas": a causal call of a row over itself runs the grouped
-    kernel — with a window counted as "window", without as "pallas", kernel
-    "causal"; a non-causal call or T != S runs the flash kernel ("pallas",
-    kernel "flash"); off the lane grid every kernel gives way to the
+    """impl="pallas": a causal call of a row over itself runs the kernel —
+    with a window counted as "window", without as "pallas"; every other
+    call (non-causal, T != S, off the lane grid) gives way to the
     reference, counted as "fallback"; on the CPU ("auto") the reference
     is the path. No call adds a label to ``dispatch_counts`` beyond the
     four the checks of a step's kernels know."""
-    from areal_tpu.ops.pallas import flash_attention as fa
-
     q, k, v, seg, pos, W, _ = make("mixed_docs")
     seen = []
 
@@ -319,33 +316,26 @@ def test_packed_attention_picks_the_kernel_by_what_it_is_handed(monkeypatch):
         seen.append(window)
         return jnp.zeros_like(q)
 
-    def fake_flash(q, k, v, qs, ks, causal=True, scale=None):
-        seen.append(("flash", causal, q.shape[1], k.shape[1]))
-        return jnp.zeros_like(q)
-
     monkeypatch.setattr(wa, "window_attention", fake)
-    monkeypatch.setattr(fa, "flash_attention", fake_flash)
-    short = [x[:, :200] for x in (q, k, v, seg, seg, pos, pos)]
+    whole = (q, k, v, seg, seg, pos, pos)
+    short = [x[:, :200] for x in whole]
+    # what the kernel does not take: non-causal, T != S, off the lane grid
+    others = [(whole, dict(causal=False)),
+              ((q[:, :128], k, v, seg[:, :128], seg, pos[:, :128], pos), {}),
+              (short, dict(sliding_window=W)), (short, {})]
     with attention.dispatch_label("test-dispatch"):
-        attention.packed_attention(q, k, v, seg, seg, pos, pos,
-                                   sliding_window=W, impl="pallas")
-        attention.packed_attention(q, k, v, seg, seg, pos, pos,
-                                   impl="pallas")
-        attention.packed_attention(q, k, v, seg, seg, pos, pos,
-                                   causal=False, impl="pallas")
-        attention.packed_attention(q[:, :128], k, v, seg[:, :128], seg,
-                                   pos[:, :128], pos, impl="pallas")
-        attention.packed_attention(*short, sliding_window=W, impl="pallas")
-        attention.packed_attention(*short, impl="pallas")
-        attention.packed_attention(q, k, v, seg, seg, pos, pos,
-                                   sliding_window=W, impl="auto")
-        attention.packed_attention(q, k, v, seg, seg, pos, pos, impl="auto")
-    assert seen == [W, None, ("flash", False, 384, 384),
-                    ("flash", True, 128, 384)]
+        attention.packed_attention(*whole, sliding_window=W, impl="pallas")
+        attention.packed_attention(*whole, impl="pallas")
+        fell_back = [attention.packed_attention(*args, **kw, impl="pallas")
+                     for args, kw in others]
+        attention.packed_attention(*whole, sliding_window=W, impl="auto")
+        attention.packed_attention(*whole, impl="auto")
+    assert seen == [W, None]
     assert attention.dispatch_counts()["test-dispatch"] == {
-        "window": 1, "pallas": 3, "fallback": 2, "reference": 2}
-    assert attention.kernel_counts()["test-dispatch"] == {
-        "window": 1, "causal": 1, "flash": 2}
+        "window": 1, "pallas": 1, "fallback": 4, "reference": 2}
+    for got, (args, kw) in zip(fell_back, others):
+        np.testing.assert_array_equal(got, attention.packed_attention(
+            *args, **kw, impl="reference"))
     for w in (W, None):
         assert attention.kernel_padded_len("pallas", 384, w) == wa.padded_len(
             384, w)

@@ -6,7 +6,7 @@ cell's rows (1 x 8192, 40 / 20 heads of 64, d_inner 5120 x 16 states):
    the 128 lanes the kernels pad a head of 64 to anyway,
    ``ops/attention.packed_attention``) against FOUR calls at 64 (the
    family's flash-diff form: q1/q2 x v1/v2), forward + backward, for the
-   flash kernel (F, X) and the windowed one (S, window 512);
+   causal kernel (F, X) and the windowed one (S, window 512);
  - the selective scan: the Pallas kernels (``ops/pallas/selective_scan``)
    forward, and forward + backward.
 
@@ -70,7 +70,7 @@ def main() -> int:
         print(json.dumps(kw), flush=True)
 
     for name, window in (() if a.scan_only else (
-            ("flash", None), ("window512", 512))):
+            ("causal", None), ("window512", 512))):
         def attend(q, k, v):
             return att.packed_attention(q, k, v, seg, seg, causal=True,
                                         sliding_window=window, impl="pallas")

@@ -21,8 +21,7 @@ around ``block_until_ready`` over ``--iters`` calls; then c = (2 forward
 + 1 forward-and-backward) / (rows x query tokens x visited key tokens at
 the padded length), in ns — visited by the STATIC mask
 (``blocks_visited``); ``needed`` is what the layout leaves of them
-(``blocks_needed``). The flash wrapper (causal, no window, K/V
-repeated, its own tile rule) is timed at the same shapes beside it.
+(``blocks_needed``).
 Prints one JSON line per shape and writes them to
 ``chiprun_out/<--out>.jsonl``.
 """
@@ -46,7 +45,6 @@ from jax.experimental.pallas.ops.tpu.splash_attention import (  # noqa: E402
     splash_attention_kernel as splash,
 )
 
-from areal_tpu.ops.pallas import flash_attention as fa  # noqa: E402
 from areal_tpu.ops.pallas import window_attention as wa  # noqa: E402
 
 
@@ -160,10 +158,6 @@ def main() -> int:
                     q, k, v, s, s2, window=window), visited * tile * tile,
                     blocks=spec, fused_bwd=fused, padded=n_pad,
                     visited=visited, needed=needed)
-        tile = fa.pick_tile(L)
-        n_pad = fa._round_up(L, tile)
-        run("flash", fa.flash_attention, n_pad * (n_pad + tile) / 2,
-            blocks=str(tile), padded=n_pad)
     if not args.compile:
         os.makedirs("chiprun_out", exist_ok=True)
         with open(f"chiprun_out/{args.out}.jsonl", "w") as f:
