@@ -919,6 +919,7 @@ class JaxTrainEngine(TrainableEngine):
                 fill_bucket=self.fill_bucket, rows_multiple=self.rows_multiple,
             )
             telemetry.set_gauge("train/pack_fill", mbu.pack_fill(mbs))
+            telemetry.set_gauge("train/docs_per_row", mbu.docs_per_row(mbs))
             self._gauge_blocks_needed("train", mbs)
         R, L = mbs[0].layout.shape
         pp_on, ring_on = ppl.pp_engagement(self.mesh, self.cfg, R, L)

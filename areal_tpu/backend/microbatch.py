@@ -197,6 +197,15 @@ def pack_fill(mbs: List[MicroBatch]) -> float:
     return (ntok / ncells) if ncells else 0.0
 
 
+def docs_per_row(mbs: List[MicroBatch]) -> float:
+    """Documents over the rows that hold any, of a micro-batch split: how
+    many times a row's scans, convolutions and attention masks start over.
+    Exported as the ``train/docs_per_row`` telemetry gauge."""
+    docs = sum(len(mb.layout.placements) for mb in mbs)
+    rows = sum(len({row for row, _ in mb.layout.placements}) for mb in mbs)
+    return (docs / rows) if rows else 0.0
+
+
 def make_microbatch(
     sample: SequenceSample,
     token_key: str = "packed_input_ids",

@@ -28,6 +28,13 @@ MIXER_KINDS = (MAMBA, MOE_ONLY, ATTENTION_ONLY)
 # FULL layer made (``TransformerConfig.memory_source`` / ``kv_source``).
 S6, GMU, CROSS = "s6", "gmu", "cross"
 SAMBAY_KINDS = (S6, GMU, CROSS)
+# A whole block whose mixer is the Mamba-2 (SSD) mixer that MAMBA runs
+# alone, then the dense MLP (granitemoehybrid's ``mamba`` layers, beside
+# FULL blocks without a position embedding).
+SSD = "ssd"
+# Whole blocks whose mixer is not self-attention: no K/V cache decodes
+# them, and their parameter shapes are their kind's own.
+BLOCK_MIXER_KINDS = SAMBAY_KINDS + (SSD,)
 # What a layer hands on to later layers, by the name the readers ask for.
 MEMORY, SHARED_KV = "memory", "kv"
 # A whole block's FFN kind (HF ``mlp_layer_types``): the model's dense MLP
@@ -207,7 +214,7 @@ class TransformerConfig:
     tie_word_embeddings: bool = False
     is_critic: bool = False  # scalar head instead of lm head
     moe: Optional[MoEConfig] = None
-    ssm: Optional[SSMConfig] = None  # the MAMBA layers' mixer
+    ssm: Optional[SSMConfig] = None  # the MAMBA layers' / SSD blocks' mixer
     s6: Optional[S6Config] = None  # the S6 blocks' mixer (GMU reads its width)
     # sliding window attention (mistral/gemma2); None = full attention
     sliding_window: Optional[int] = None
@@ -253,6 +260,17 @@ class TransformerConfig:
     pos_embedding: str = "rope"
     max_position_embeddings: Optional[int] = None  # learned-pos table size
     scale_embeddings: bool = False  # gemma: hidden *= sqrt(hidden_dim)
+    # The granite family's four multipliers, each the identity by default:
+    # the embedding's output times ``embedding_multiplier``; BOTH branches
+    # of every block times ``residual_multiplier`` before they are added
+    # to the residual stream; ``attention_multiplier`` the softmax scale
+    # IN PLACE of head_dim ** -0.5 (None); the logits divided by
+    # ``logits_scaling`` — in the head, and so in every loss and logprob
+    # read from it. A tied embedding is then read at two scales.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
     # HF family tag driving weight-name mapping + config.json emission
     # (models/hf.py); None for fabricated test configs.
     hf_family: Optional[str] = None
@@ -319,19 +337,20 @@ class TransformerConfig:
 
     @property
     def has_cacheless_layers(self) -> bool:
-        """Layers no K/V cache can decode: a mixer alone, a selective
-        scan, or a layer that reads what another layer made."""
+        """Layers no K/V cache can decode: a mixer alone, a state-space
+        block, or a layer that reads what another layer made."""
         return self.has_mixer_layers or any(
-            k in SAMBAY_KINDS for k in self.layer_kinds)
+            k in BLOCK_MIXER_KINDS for k in self.layer_kinds)
 
     @property
     def is_hybrid(self) -> bool:
         """Layers whose parameter SHAPES differ by kind — one mixer alone,
-        or whole blocks some of which run a dense MLP and some the
-        experts: ``params["layers"]`` is then a tree per KIND, each
+        whole blocks whose mixers differ, or whole blocks some of which
+        run a dense MLP and some the experts: ``params["layers"]`` is
+        then a tree per KIND, each
         stacked over that kind's layers."""
-        return any(k in MIXER_KINDS or k in SAMBAY_KINDS or has_dense_ffn(k)
-                   for k in self.layer_kinds)
+        return any(k in MIXER_KINDS or k in BLOCK_MIXER_KINDS
+                   or has_dense_ffn(k) for k in self.layer_kinds)
 
     @property
     def memory_source(self) -> Optional[int]:

@@ -4,7 +4,8 @@ Parity target: the reference's per-family converter registry
 (``realhf/impl/model/conversion/hf_registry.py:32`` +
 ``realhf/api/from_hf/{llama,qwen2,qwen3,gemma,gpt2,mistral,mixtral}.py``).
 Families covered: llama, qwen2 (qwen2.5), qwen3, mistral, gemma, gpt2,
-mixtral, qwen3_moe, olmoe, mellum, nemotron_h, afmoe.
+mixtral, qwen3_moe, olmoe, mellum, nemotron_h, afmoe, phi4flash,
+granitemoehybrid.
 
 Weights are stacked on a leading layer axis (see models/transformer.py), so
 conversion transposes HF's ``[out, in]`` linear layout to ``[in, out]`` and
@@ -36,6 +37,7 @@ from areal_tpu.models.config import (
     S6,
     SLIDING,
     SPARSE_FFN,
+    SSD,
     MoEConfig,
     RopeConfig,
     S6Config,
@@ -492,6 +494,75 @@ def _phi4flash_config(hf_config: Any) -> TransformerConfig:
         s6=S6Config(d_inner=2 * d, state_dim=16, conv_kernel=4,
                     dt_rank=-(-d // 16)),
         hf_family="phi4flash",
+    )
+
+
+# granitemoehybrid: HF ``layer_types`` to the block kinds.
+_GRANITE_LAYER_TYPES = {"mamba": SSD, "attention": FULL}
+# Why the family's siblings with experts are not loaded, by name.
+GRANITE_EXPERT_REFUSAL = (
+    "expert_layers_beside_shared_mlp: num_local_experts > 0 puts a routed "
+    "expert layer beside the shared MLP in every block, which no block "
+    "here runs (only the family's dense models load)")
+
+
+@register_hf_family("granitemoehybrid")
+def _granitemoehybrid_config(hf_config: Any) -> TransformerConfig:
+    """Granite 4.0-H (``GraniteMoeHybridForCausalLM``) with
+    ``num_local_experts`` 0: whole blocks under RMSNorm, the mixer by
+    ``layer_types`` — ``mamba`` a Mamba-2 mixer (``d_inner = mamba_n_heads
+    * mamba_d_head``, ``mamba_n_groups`` B/C groups, a gated norm over
+    each group's channels), ``attention`` GQA without bias — then the
+    gated MLP ``shared_mlp`` of width ``shared_intermediate_size``; no
+    position embedding where ``position_embedding_type`` is ``nope``; a
+    tied head; and the family's four multipliers. A config cut in depth
+    keeps ``num_hidden_layers`` entries of ``layer_types``, from this
+    repo's key ``first_layer_index`` on (0: the first ones)."""
+    if (getattr(hf_config, "num_local_experts", 0) or 0) > 0:
+        raise NotImplementedError(GRANITE_EXPERT_REFUSAL)
+    kw = _base_kwargs(hf_config)
+    first = int(getattr(hf_config, "first_layer_index", 0))
+    types = tuple(hf_config.layer_types)[first:first + kw["n_layers"]]
+    if len(types) != kw["n_layers"] or set(types) - set(_GRANITE_LAYER_TYPES):
+        raise NotImplementedError(
+            f"layer_types {types!r} for {kw['n_layers']} layers "
+            f"({sorted(_GRANITE_LAYER_TYPES)} are supported)")
+    pos = getattr(hf_config, "position_embedding_type", "nope")
+    if pos not in ("nope", "rope"):
+        raise NotImplementedError(f"position_embedding_type {pos!r}")
+    if getattr(hf_config, "normalization_function", "rmsnorm") != "rmsnorm":
+        raise NotImplementedError(
+            f"normalization_function {hf_config.normalization_function!r}")
+    if getattr(hf_config, "mamba_proj_bias", False) or not getattr(
+            hf_config, "mamba_conv_bias", True):
+        raise NotImplementedError(
+            "a Mamba-2 mixer with projection biases, or a convolution "
+            "without one")
+    kw["intermediate_dim"] = hf_config.shared_intermediate_size
+    return TransformerConfig(
+        **kw,
+        pos_embedding="none" if pos == "nope" else "rope",
+        hidden_act=getattr(hf_config, "hidden_act", "silu"),
+        use_attention_bias=bool(getattr(hf_config, "attention_bias", False)),
+        layer_types=tuple(_GRANITE_LAYER_TYPES[t] for t in types),
+        max_position_embeddings=getattr(
+            hf_config, "max_position_embeddings", None),
+        ssm=SSMConfig(
+            n_heads=hf_config.mamba_n_heads,
+            head_dim=hf_config.mamba_d_head,
+            n_groups=hf_config.mamba_n_groups,
+            state_dim=hf_config.mamba_d_state,
+            conv_kernel=hf_config.mamba_d_conv,
+            chunk_size=hf_config.mamba_chunk_size,
+        ),
+        embedding_multiplier=float(
+            getattr(hf_config, "embedding_multiplier", 1.0)),
+        residual_multiplier=float(
+            getattr(hf_config, "residual_multiplier", 1.0)),
+        attention_multiplier=float(hf_config.attention_multiplier)
+        if getattr(hf_config, "attention_multiplier", None) else None,
+        logits_scaling=float(getattr(hf_config, "logits_scaling", 1.0)),
+        hf_family="granitemoehybrid",
     )
 
 
@@ -998,6 +1069,82 @@ def _phi4flash_from_sd(
     return out
 
 
+# granitemoehybrid: (pytree key, HF name under ``model.layers.{i}.``,
+# transpose). A block holds the leaves of its kind; the gated MLP's fused
+# ``shared_mlp.input_linear`` ([gate | up] rows) and the depthwise
+# convolution ``[channels, 1, K]`` are split and joined below.
+_GRANITE_NAMES = [
+    ("ln1", "input_layernorm.weight", False),
+    ("ln2", "post_attention_layernorm.weight", False),
+    ("w_down", "shared_mlp.output_linear.weight", True),
+    ("in_proj", "mamba.in_proj.weight", True),
+    ("conv_b", "mamba.conv1d.bias", False),
+    ("dt_bias", "mamba.dt_bias", False),
+    ("A_log", "mamba.A_log", False),
+    ("D", "mamba.D", False),
+    ("norm", "mamba.norm.weight", False),
+    ("out_proj", "mamba.out_proj.weight", True),
+    ("wq", "self_attn.q_proj.weight", True),
+    ("wk", "self_attn.k_proj.weight", True),
+    ("wv", "self_attn.v_proj.weight", True),
+    ("wo", "self_attn.o_proj.weight", True),
+    ("bq", "self_attn.q_proj.bias", False),
+    ("bk", "self_attn.k_proj.bias", False),
+    ("bv", "self_attn.v_proj.bias", False),
+]
+
+
+def _granitemoehybrid_to_sd(
+    params: Dict[str, Any], cfg: TransformerConfig
+) -> Dict[str, np.ndarray]:
+    sd = {
+        "model.embed_tokens.weight": np.asarray(params["embedding"]),
+        "model.norm.weight": np.asarray(params["final_ln"]),
+    }
+    if not cfg.tie_word_embeddings:
+        sd["lm_head.weight"] = np.asarray(params["lm_head"]).T
+    for i, kind, lp in _layers_in_order(params, cfg):
+        pre = f"model.layers.{i}."
+        for key, name, tr in _GRANITE_NAMES:
+            if key in lp:
+                sd[pre + name] = lp[key].T if tr else lp[key]
+        sd[pre + "shared_mlp.input_linear.weight"] = np.concatenate(
+            [lp["w_gate"].T, lp["w_up"].T])
+        if kind == SSD:
+            sd[pre + "mamba.conv1d.weight"] = lp["conv_w"].T[:, None, :]
+    return sd
+
+
+def _granitemoehybrid_from_sd(
+    sd: Dict[str, Any], cfg: TransformerConfig, dtype: str
+) -> Dict[str, Any]:
+    per_kind: Dict[str, Dict[str, list]] = {}
+    f = cfg.intermediate_dim
+    for i, kind in enumerate(cfg.layer_kinds):
+        pre = f"model.layers.{i}."
+        lp = per_kind.setdefault(kind, {})
+        for key, name, tr in _GRANITE_NAMES:
+            if pre + name in sd:
+                w = _np(sd[pre + name])
+                lp.setdefault(key, []).append(w.T if tr else w)
+        gate_up = _np(sd[pre + "shared_mlp.input_linear.weight"])
+        lp.setdefault("w_gate", []).append(gate_up[:f].T)
+        lp.setdefault("w_up", []).append(gate_up[f:].T)
+        if kind == SSD:
+            lp.setdefault("conv_w", []).append(
+                _np(sd[pre + "mamba.conv1d.weight"])[:, 0, :].T)
+    out = {
+        "embedding": _np(sd["model.embed_tokens.weight"]).astype(dtype),
+        "layers": {kind: {k: np.stack(v).astype(dtype)
+                          for k, v in lp.items()}
+                   for kind, lp in per_kind.items()},
+        "final_ln": _np(sd["model.norm.weight"]).astype(dtype),
+    }
+    if not cfg.tie_word_embeddings:
+        out["lm_head"] = _np(sd["lm_head.weight"]).T.astype(dtype)
+    return out
+
+
 def params_from_hf_state_dict(
     sd: Dict[str, Any], cfg: TransformerConfig, dtype: str = "float32"
 ) -> Dict[str, Any]:
@@ -1010,6 +1157,8 @@ def params_from_hf_state_dict(
         return _afmoe_from_sd(sd, cfg, dtype)
     if cfg.hf_family == "phi4flash":
         return _phi4flash_from_sd(sd, cfg, dtype)
+    if cfg.hf_family == "granitemoehybrid":
+        return _granitemoehybrid_from_sd(sd, cfg, dtype)
     return _llama_from_sd(sd, cfg, dtype)
 
 
@@ -1025,6 +1174,8 @@ def params_to_hf_state_dict(
         return _afmoe_to_sd(params, cfg)
     if cfg.hf_family == "phi4flash":
         return _phi4flash_to_sd(params, cfg)
+    if cfg.hf_family == "granitemoehybrid":
+        return _granitemoehybrid_to_sd(params, cfg)
     return _llama_to_sd(params, cfg)
 
 
@@ -1044,6 +1195,7 @@ _HF_ARCH = {
     "nemotron_h": "NemotronHForCausalLM",
     "afmoe": "AfmoeForCausalLM",
     "phi4flash": "Phi4FlashForCausalLM",
+    "granitemoehybrid": "GraniteMoeHybridForCausalLM",
 }
 
 
@@ -1071,6 +1223,8 @@ def hf_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
         return _afmoe_config_dict(cfg)
     if fam == "phi4flash":
         return _phi4flash_config_dict(cfg)
+    if fam == "granitemoehybrid":
+        return _granitemoehybrid_config_dict(cfg)
     d: Dict[str, Any] = {
         "model_type": fam,
         "architectures": [_HF_ARCH.get(fam, "LlamaForCausalLM")],
@@ -1220,6 +1374,51 @@ def _phi4flash_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
         d["layer_pattern"] = pattern
         d["first_layer_index"] = cfg.first_layer_index
     return d
+
+
+def _granitemoehybrid_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
+    """The inverse of :func:`_granitemoehybrid_config`."""
+    ssm = cfg.ssm
+    names = {kind: t for t, kind in _GRANITE_LAYER_TYPES.items()}
+    return {
+        "model_type": "granitemoehybrid",
+        "architectures": [_HF_ARCH["granitemoehybrid"]],
+        "num_hidden_layers": cfg.n_layers,
+        "layer_types": [names[k] for k in cfg.layer_kinds],
+        "hidden_size": cfg.hidden_dim,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "head_dim": cfg.head_dim,
+        "attention_bias": cfg.use_attention_bias,
+        "intermediate_size": cfg.intermediate_dim,
+        "shared_intermediate_size": cfg.intermediate_dim,
+        "num_local_experts": 0,
+        "num_experts_per_tok": 0,
+        "hidden_act": cfg.hidden_act,
+        "vocab_size": cfg.vocab_size,
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "normalization_function": "rmsnorm",
+        "position_embedding_type":
+            "nope" if cfg.pos_embedding == "none" else "rope",
+        "rope_theta": cfg.rotary_base,
+        "tie_word_embeddings": cfg.tie_word_embeddings,
+        "max_position_embeddings": cfg.max_position_embeddings or 131072,
+        "mamba_n_heads": ssm.n_heads,
+        "mamba_d_head": ssm.head_dim,
+        "mamba_n_groups": ssm.n_groups,
+        "mamba_d_state": ssm.state_dim,
+        "mamba_d_conv": ssm.conv_kernel,
+        "mamba_chunk_size": ssm.chunk_size,
+        "mamba_expand": ssm.d_inner // cfg.hidden_dim or 1,
+        "mamba_conv_bias": True,
+        "mamba_proj_bias": False,
+        "embedding_multiplier": cfg.embedding_multiplier,
+        "residual_multiplier": cfg.residual_multiplier,
+        "attention_multiplier":
+            cfg.attention_multiplier or cfg.head_dim ** -0.5,
+        "logits_scaling": cfg.logits_scaling,
+        "torch_dtype": "float32",
+    }
 
 
 def _afmoe_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:

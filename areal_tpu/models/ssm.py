@@ -1,6 +1,8 @@
 """The state-space mixers: Mamba-2 (SSD) of a hybrid model's ``mamba``
-layers — packed rows, XLA einsums, no kernel — and, at the end of the
-file, Mamba-1's selective scan (S6) of the ``s6`` blocks.
+layers (a mixer alone: nemotron_h) and ``ssd`` blocks (the mixer, then a
+dense MLP: granitemoehybrid) — packed rows, XLA einsums, no kernel — and,
+at the end of the file, Mamba-1's selective scan (S6) of the ``s6``
+blocks.
 
 One mixer, ``u = norm(h)`` [B, T, D] (models/transformer.py adds the
 residual):
@@ -58,7 +60,8 @@ def geometry_counts() -> Dict[Tuple[int, int, int, int, int], int]:
 
 def init_mamba_params(ssm: SSMConfig, n: int, hidden_dim: int,
                       key: jax.Array, dtype) -> Dict[str, jnp.ndarray]:
-    """``n`` stacked Mamba-2 mixers, drawn as the published keys say so
+    """``n`` stacked Mamba-2 mixers (the norm in front is the layer's or
+    the block's own), drawn as the published keys say so
     that the state remembers: Δ0 log-uniform in [time_step_min,
     time_step_max] floored at time_step_floor, ``dt_bias`` its inverse
     softplus; ``A_log = log(U(1, 16))``; ``D = 1``; the convolution as a
@@ -77,7 +80,6 @@ def init_mamba_params(ssm: SSMConfig, n: int, hidden_dim: int,
     dt0 = jnp.maximum(dt0, ssm.time_step_floor)
     bound = 1.0 / math.sqrt(K)
     return {
-        "ln": jnp.ones((n, hidden_dim), dtype),
         "in_proj": nrm(k_in, (n, hidden_dim, ssm.in_proj_dim)),
         "conv_w": jax.random.uniform(
             k_conv, (n, K, ssm.conv_dim), minval=-bound, maxval=bound

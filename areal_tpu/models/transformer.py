@@ -31,7 +31,10 @@ but mellum and nemotron_h. Two sorts of pattern:
    an expert layer (models/moe.py) or attention without a position
    embedding — the kinds differ in parameter SHAPES, so
    ``params["layers"]`` is a tree per kind, ``{kind: {name: [n_kind,
-   ...]}}``, and a period's layers slice their kind's stack.
+   ...]}}``, and a period's layers slice their kind's stack. So is a
+   model whose whole blocks differ in their MIXER (phi4flash's S6 / GMU /
+   CROSS blocks; granitemoehybrid's SSD blocks — the Mamba-2 mixer, then
+   the dense MLP — beside FULL ones).
 """
 
 from __future__ import annotations
@@ -55,6 +58,7 @@ from areal_tpu.models.config import (
     SAMBAY_KINDS,
     SHARED_KV,
     SLIDING,
+    SSD,
     RopeConfig,
     TransformerConfig,
     attention_kind,
@@ -88,7 +92,9 @@ def _init_mixer_layers(cfg: TransformerConfig, keys, dtype) -> Params:
     layers: Params = {}
     n = cfg.n_layers_of(MAMBA)
     if n:
-        layers[MAMBA] = ssmmod.init_mamba_params(cfg.ssm, n, d, keys[10], dtype)
+        layers[MAMBA] = {
+            "ln": jnp.ones((n, d), dtype),
+            **ssmmod.init_mamba_params(cfg.ssm, n, d, keys[10], dtype)}
     n = cfg.n_layers_of(MOE_ONLY)
     if n:
         layers[MOE_ONLY] = {
@@ -118,13 +124,14 @@ def _init_block_layers(cfg: TransformerConfig, n: int, keys, dtype,
                        dense_ffn: bool, kind: str = FULL,
                        ) -> Dict[str, jnp.ndarray]:
     """``n`` whole blocks (a mixer + FFN) stacked ``[n, ...]``: attention,
-    or by ``kind`` an S6 mixer, a gated memory unit (two matrices and no
-    scan) or cross attention (q and o alone: its K/V are another
+    or by ``kind`` an S6 mixer, a Mamba-2 mixer (SSD), a gated memory unit
+    (two matrices and no scan) or cross attention (q and o alone: its K/V
+    are another
     layer's); the FFN is the expert layer where the model has one, unless
     ``dense_ffn``."""
     d = cfg.hidden_dim
     qd, kvd, f = cfg.q_dim, cfg.kv_dim, cfg.intermediate_dim
-    attends = kind not in (S6, GMU)
+    attends = kind not in (S6, GMU, SSD)
 
     def nrm(k, shape, scale=0.02):
         return (jax.random.normal(k, shape) * scale).astype(dtype)
@@ -137,6 +144,10 @@ def _init_block_layers(cfg: TransformerConfig, n: int, keys, dtype,
         from areal_tpu.models import ssm as ssmmod
 
         layers.update(ssmmod.init_s6_params(cfg.s6, n, d, keys[13], dtype))
+    elif kind == SSD:
+        from areal_tpu.models import ssm as ssmmod
+
+        layers.update(ssmmod.init_mamba_params(cfg.ssm, n, d, keys[13], dtype))
     elif kind == GMU:
         layers["gmu_in"] = nrm(keys[13], (n, d, cfg.s6.d_inner))
         layers["gmu_out"] = nrm(keys[14], (n, cfg.s6.d_inner, d))
@@ -338,6 +349,18 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarra
     return x * c + rot * s
 
 
+def _residual(cfg: TransformerConfig, h: jnp.ndarray,
+              branch: jnp.ndarray) -> jnp.ndarray:
+    """``h + branch``, the branch times ``cfg.residual_multiplier`` where
+    the family has one (in float32: one fused multiply-add, the product
+    never rounded to the compute dtype by itself)."""
+    if cfg.residual_multiplier == 1.0:
+        return h + branch
+    f32 = jnp.float32
+    return (h.astype(f32) + branch.astype(f32) * cfg.residual_multiplier
+            ).astype(h.dtype)
+
+
 # ---------------- one block ----------------
 
 def _block(
@@ -384,17 +407,21 @@ def _block(
     # ops, no change to the program that runs.
     with jax.named_scope("attn_norm"):
         x = _norm(cfg, h, lp, "ln1")
-    if akind in (S6, GMU):
+    if akind in (S6, GMU, SSD):
         assert cache_kv is None, DECODE_REFUSAL
         from areal_tpu.models import ssm as ssmmod
 
         if akind == S6:
             attn, new_kv = ssmmod.s6_mixer(x, lp, cfg.s6, segment_ids,
                                            attn_impl)
+        elif akind == SSD:
+            attn, new_kv = ssmmod.mamba_mixer(
+                x, lp, cfg.ssm, cfg.rms_norm_eps, segment_ids), None
         else:
             attn, new_kv = ssmmod.gated_memory_unit(
                 x, shared[MEMORY], lp), None
-        return _block_ffn(cfg, kind, constrain(h + attn, "hidden"), lp,
+        return _block_ffn(cfg, kind, constrain(_residual(cfg, h, attn),
+                                               "hidden"), lp,
                           new_kv, segment_ids, rng, allow_ep, ring_ctx,
                           attn_impl, decode=False)
     with jax.named_scope("cross_attention" if akind == CROSS else "qkv_proj"):
@@ -469,7 +496,7 @@ def _block(
         if cfg.sandwich_norm:
             with jax.named_scope("post_attn_norm"):
                 attn = _norm(cfg, attn, lp, "ln1_post")
-        h = constrain(h + attn, hid)
+        h = constrain(_residual(cfg, h, attn), hid)
     return _block_ffn(cfg, kind, h, lp, new_kv, segment_ids, rng, allow_ep,
                       ring_ctx, attn_impl, decode=cache_kv is not None)
 
@@ -508,7 +535,7 @@ def _block_ffn(cfg: TransformerConfig, kind: str, h, lp, new_kv,
             if cfg.sandwich_norm:
                 with jax.named_scope("post_mlp_norm"):
                     mlp = _norm(cfg, mlp, lp, "ln2_post")
-            return constrain(h + mlp, hid), new_kv, aux
+            return constrain(_residual(cfg, h, mlp), hid), new_kv, aux
     with jax.named_scope("mlp"):
         if cfg.mlp_type == "plain":
             mlp = (act(x @ lp["w_up"] + lp["b_up"]) @ lp["w_down"]
@@ -518,7 +545,7 @@ def _block_ffn(cfg: TransformerConfig, kind: str, h, lp, new_kv,
         if cfg.sandwich_norm:
             with jax.named_scope("post_mlp_norm"):
                 mlp = _norm(cfg, mlp, lp, "ln2_post")
-        return constrain(h + mlp, hid), new_kv, None
+        return constrain(_residual(cfg, h, mlp), hid), new_kv, None
 
 
 # Why a model with mixer-only layers has no decode mode (models/generate.py
@@ -545,7 +572,7 @@ def _mixer_block(
 
         out = ssmmod.mamba_mixer(x, lp, cfg.ssm, cfg.rms_norm_eps,
                                  segment_ids)
-        return constrain(h + out, "hidden"), None, None
+        return constrain(_residual(cfg, h, out), "hidden"), None, None
     if kind == MOE_ONLY:
         from areal_tpu.models import moe as moemod
 
@@ -554,7 +581,7 @@ def _mixer_block(
                 x, lp, cfg.moe,
                 mask=(segment_ids > 0) if segment_ids is not None else None,
                 impl=attn_impl)
-        return constrain(h + out, "hidden"), None, aux
+        return constrain(_residual(cfg, h, out), "hidden"), None, aux
     dh = cfg.head_dim
     with jax.named_scope("qkv_proj"):
         q = (x @ lp["wq"]).reshape(B, T, cfg.n_q_heads, dh)
@@ -566,7 +593,7 @@ def _mixer_block(
             attn_impl, allow_ring, ring_ctx, kind)
     with jax.named_scope("o_proj"):
         out = attn.reshape(B, T, cfg.q_dim) @ lp["wo"]
-    return constrain(h + out, "hidden"), new_kv, None
+    return constrain(_residual(cfg, h, out), "hidden"), new_kv, None
 
 
 def _attend(
@@ -577,7 +604,9 @@ def _attend(
     """One block's attention proper (everything between RoPE and the
     output projection): packed / ring attention in packed mode, the cache
     write and decode attention in decode mode; ``kind`` says whether the
-    layer sees its window or its whole document. Returns (attn, new_kv)."""
+    layer sees its window or its whole document; the softmax scale is
+    ``cfg.attention_multiplier`` where the family sets one. Returns
+    (attn, new_kv)."""
     B, T = q.shape[:2]
     if cache_kv is None:
         from areal_tpu.parallel import ring as ring_mod
@@ -595,17 +624,19 @@ def _attend(
             # Already inside a manual region over the ring axis (the PP∘SP
             # pipeline stages): run the local ring body directly — a
             # nested shard_map would be rejected there.
-            attn = ring_mod.ring_attention_inline(q, k, v, segment_ids,
-                                                  ring_ctx)
+            attn = ring_mod.ring_attention_inline(
+                q, k, v, segment_ids, ring_ctx,
+                scale=cfg.attention_multiplier)
         elif use_ring:
             # Sequence dim sharded → context-parallel ring attention.
-            attn = ring_mod.ring_attention(q, k, v, segment_ids, mesh)
+            attn = ring_mod.ring_attention(q, k, v, segment_ids, mesh,
+                                           scale=cfg.attention_multiplier)
         else:
             attn = packed_attention(
                 q, k, v, segment_ids, segment_ids,
                 q_positions=positions, kv_positions=positions,
                 causal=True, sliding_window=cfg.window_of(kind),
-                impl=attn_impl,
+                impl=attn_impl, scale=cfg.attention_multiplier,
             )
         new_kv = (k, v)
     else:
@@ -630,7 +661,8 @@ def _attend(
             v_cache = jax.lax.dynamic_update_slice_in_dim(
                 v_cache, v, cache_write_index, axis=1
             )
-        attn = decode_attention(q, k_cache, v_cache, kv_valid)
+        attn = decode_attention(q, k_cache, v_cache, kv_valid,
+                                scale=cfg.attention_multiplier)
         new_kv = (k_cache, v_cache)
 
     return attn, new_kv
@@ -922,9 +954,12 @@ def _block_matmul_widths(cfg: TransformerConfig, dense_ffn: bool,
     the last matmul's output, unless a sandwich norm does; an MoE layer
     keeps the router's logits and its shared expert's pair. By ``kind``
     the mixer's are an S6 mixer's (in-projection, [δ | B | C], Δ), a
-    gated memory unit's one, or cross attention's q and o."""
+    Mamba-2 mixer's two projections, a gated memory unit's one, or cross
+    attention's q and o."""
     if kind == S6:
         widths = 3 * cfg.s6.d_inner + cfg.s6.x_proj_dim + cfg.hidden_dim
+    elif kind == SSD:  # the scan's einsums carry batch dimensions
+        widths = cfg.ssm.in_proj_dim + cfg.hidden_dim
     elif kind == GMU:
         widths = cfg.s6.d_inner + cfg.hidden_dim
     elif kind == CROSS:
@@ -1043,6 +1078,8 @@ def forward(
         h = params["embedding"][tokens]
         if cfg.scale_embeddings:  # gemma normalizer
             h = h * jnp.asarray(cfg.hidden_dim ** 0.5, h.dtype)
+        if cfg.embedding_multiplier != 1.0:  # granite
+            h = h * jnp.asarray(cfg.embedding_multiplier, h.dtype)
         if cfg.pos_embedding == "learned":
             h = h + params["pos_embedding"][positions]
         h = constrain(h, "hidden" if not decode else "hidden_decode")
@@ -1182,10 +1219,15 @@ def kv_valid_by_kind(cfg: TransformerConfig, valid: jnp.ndarray,
 def apply_head(params: Params, cfg: TransformerConfig, h, lg="logits"):
     """Final-hidden → logits (or values). Shared by forward and the
     engine's chunked-logprob path (backend/jax_train.py) so the head math
-    has exactly one definition."""
+    has exactly one definition — ``cfg.logits_scaling`` included: every
+    loss and logprob reads the logits here."""
     with jax.named_scope("head"):
         if cfg.is_critic:
             return (h @ params["value_head"])[..., 0]
+        if cfg.logits_scaling != 1.0:
+            # logits / logits_scaling, on the hidden width and not on the
+            # vocabulary's (the product is linear in h)
+            h = (h.astype(jnp.float32) / cfg.logits_scaling).astype(h.dtype)
         if cfg.tie_word_embeddings:
             return constrain(h @ params["embedding"].T, lg)
         return constrain(h @ params["lm_head"], lg)
@@ -1210,15 +1252,21 @@ def _mixer_param_counts(cfg: TransformerConfig) -> Dict[str, int]:
     for kind in cfg.layer_kinds:
         if kind not in MIXER_KINDS:
             counts[kind] = _block_param_count(cfg, has_dense_ffn(kind), kind)
-    if cfg.ssm is not None:
-        ssm = cfg.ssm
-        counts[MAMBA] = (
-            d * ssm.in_proj_dim + (ssm.conv_kernel + 1) * ssm.conv_dim
-            + 3 * ssm.n_heads + ssm.d_inner + ssm.d_inner * d + d)
+    if cfg.ssm is not None and MAMBA in cfg.layer_kinds:
+        counts[MAMBA] = _mamba_param_count(cfg) + d
     if cfg.moe is not None:
         counts[MOE_ONLY] = d + sum(
             math.prod(shape) for shape in moemod.moe_param_shapes(cfg).values())
     return counts
+
+
+def _mamba_param_count(cfg: TransformerConfig) -> int:
+    """Parameters of one Mamba-2 mixer, the norm in front not counted:
+    the two projections, the convolution and its bias, dt_bias / A_log /
+    D a head, the gated norm's weight."""
+    ssm, d = cfg.ssm, cfg.hidden_dim
+    return (d * ssm.in_proj_dim + (ssm.conv_kernel + 1) * ssm.conv_dim
+            + 3 * ssm.n_heads + ssm.d_inner + ssm.d_inner * d)
 
 
 def _block_param_count(cfg: TransformerConfig, dense_ffn: bool,
@@ -1234,13 +1282,15 @@ def _block_param_count(cfg: TransformerConfig, dense_ffn: bool,
         attn = (d * 2 * s6.d_inner + s6.d_inner * (
             s6.conv_kernel + 1 + s6.x_proj_dim + s6.dt_rank + 1
             + s6.state_dim + 1 + d))
+    elif kind == SSD:
+        attn = _mamba_param_count(cfg)
     elif kind == GMU:
         attn = 2 * d * cfg.s6.d_inner
     elif kind == CROSS:
         attn = 2 * d * cfg.q_dim
     else:
         attn = d * (cfg.q_dim + 2 * cfg.kv_dim) + cfg.q_dim * d
-    if cfg.differential_attention and kind not in (S6, GMU):
+    if cfg.differential_attention and kind not in (S6, GMU, SSD):
         attn += 6 * cfg.head_dim
     if cfg.gated_attention:
         attn += d * cfg.q_dim

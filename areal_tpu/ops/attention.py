@@ -213,6 +213,7 @@ def decode_attention(
     k_cache: jnp.ndarray,  # [B, S, Hkv, D]
     v_cache: jnp.ndarray,  # [B, S, Hkv, D]
     kv_valid: jnp.ndarray,  # [B, S] bool — or [B, T, S] per-query-token
+    scale: Optional[float] = None,  # None = head_dim ** -0.5
 ) -> jnp.ndarray:
     # A [B, T, S] kv_valid gives each of the T new tokens its own valid
     # set — the causal mask of a multi-token cache extension (prefix
@@ -222,7 +223,7 @@ def decode_attention(
         mask = kv_valid[:, None, :, :]  # [B, 1, T, S]
     else:
         mask = kv_valid[:, None, None, :]  # [B, 1, 1, S]
-    return attention_reference(q, k_cache, v_cache, mask)
+    return attention_reference(q, k_cache, v_cache, mask, scale=scale)
 
 
 # ---- differential attention (Differential Transformer; phi4flash) ----

@@ -53,7 +53,7 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.models.config import MAMBA, S6
+from areal_tpu.models.config import MAMBA, S6, SSD
 from areal_tpu.parallel.mesh import DATA_AXES
 
 _NEG_INF = -1e30
@@ -363,7 +363,7 @@ def ring_refusal(cfg, kind: Optional[str] = None) -> Optional[str]:
     may have a window — or None. A model with a state-space layer is
     refused whole: its other layers would need the sequence split that
     the scan cannot take."""
-    if MAMBA in cfg.layer_kinds:
+    if MAMBA in cfg.layer_kinds or SSD in cfg.layer_kinds:
         return "state_space_scan"
     if S6 in cfg.layer_kinds:
         return "selective_scan"

@@ -27,7 +27,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.models.config import CROSS, FULL, GMU, S6, TransformerConfig
+from areal_tpu.models.config import (
+    CROSS, FULL, GMU, S6, SSD, TransformerConfig)
 from areal_tpu.parallel.mesh import DATA_AXES
 
 Params = Dict[str, Any]
@@ -75,9 +76,10 @@ def _block_partition_specs(cfg: TransformerConfig, zero, lead,
     ``lead`` ("pp", or None: a kind's stack of a tree per kind); the FFN
     is the expert layer where the model has one, unless ``dense_ffn``;
     the mixer by ``kind``: attention (a cross layer has q and o alone),
-    an S6 mixer or a gated memory unit — their matrices ZeRO-3 on the
-    hidden dim, the channels whole (the scan and the memory it hands on
-    are not split)."""
+    an S6 or a Mamba-2 (SSD) mixer or a gated memory unit — their
+    matrices ZeRO-3 on the hidden dim, the channels whole (the scan and
+    the memory it hands on are not split; nor are the heads under the
+    one B/C group and the gated norm that spans them all)."""
     layers: Params = {
         "ln1": P(lead, None),
         "ln2": P(lead, None),
@@ -85,8 +87,10 @@ def _block_partition_specs(cfg: TransformerConfig, zero, lead,
         "w_up": P(lead, zero, "tp"),
         "w_down": P(lead, "tp", zero),
     }
-    attends = kind not in (S6, GMU)
-    if kind == S6:
+    attends = kind not in (S6, GMU, SSD)
+    if kind == SSD:
+        layers.update(_mamba_specs(lead, zero))
+    elif kind == S6:
         layers.update({
             "in_proj": P(lead, zero, None), "out_proj": P(lead, None, zero),
             "conv_w": P(lead, None, None), "conv_b": P(lead, None),
@@ -151,6 +155,16 @@ def _block_partition_specs(cfg: TransformerConfig, zero, lead,
     return layers
 
 
+def _mamba_specs(lead, zero) -> Params:
+    """A Mamba-2 mixer's leaves (models/ssm.init_mamba_params)."""
+    return {
+        "in_proj": P(lead, zero, None), "out_proj": P(lead, None, zero),
+        "conv_w": P(lead, None, None), "conv_b": P(lead, None),
+        "dt_bias": P(lead, None), "A_log": P(lead, None),
+        "D": P(lead, None), "norm": P(lead, None),
+    }
+
+
 def _hybrid_partition_specs(cfg: TransformerConfig, zero) -> Params:
     """The spec tree of a model whose layers are one mixer each
     (``params["layers"]`` a tree per kind): the matrices ZeRO-3 over
@@ -169,13 +183,7 @@ def _hybrid_partition_specs(cfg: TransformerConfig, zero) -> Params:
                                      kind)
         for kind in dict.fromkeys(cfg.layer_kinds) if kind not in MIXER_KINDS}
     if cfg.n_layers_of(MAMBA):
-        layers[MAMBA] = {
-            "ln": P(None, None),
-            "in_proj": P(None, zero, None), "out_proj": P(None, None, zero),
-            "conv_w": P(None, None, None), "conv_b": P(None, None),
-            "dt_bias": P(None, None), "A_log": P(None, None),
-            "D": P(None, None), "norm": P(None, None),
-        }
+        layers[MAMBA] = {"ln": P(None, None), **_mamba_specs(None, zero)}
     if cfg.n_layers_of(ATTENTION_ONLY):
         layers[ATTENTION_ONLY] = {
             "ln": P(None, None),
