@@ -1081,6 +1081,15 @@ class JaxTrainEngine(TrainableEngine):
                          for _, col in ub.mbs[i].layout.placements)
             telemetry.set_gauge("train/ssm_segment_starts", starts)
             span_attrs["ssm_segment_starts"] = starts
+        if self.cfg.ssm is not None:
+            from areal_tpu.models import ssm as ssmmod
+
+            # the share of the traced Mamba-2 scans that run the kernel
+            # (models/ssm.scan_impl_counts; None before the first trace)
+            frac = ssmmod.ssd_kernel_frac()
+            if frac is not None:
+                telemetry.set_gauge("train/ssd_kernel_frac", frac)
+                span_attrs["ssd_kernel_frac"] = frac
         with telemetry.span("train/fwd_bwd", n_mbs=len(idxs),
                             grid=f"{ub.R}x{ub.L}",
                             remat=str(self._remat_for(ub.R, ub.L)),

@@ -1,8 +1,8 @@
 """The state-space mixers: Mamba-2 (SSD) of a hybrid model's ``mamba``
 layers (a mixer alone: nemotron_h) and ``ssd`` blocks (the mixer, then a
-dense MLP: granitemoehybrid) — packed rows, XLA einsums, no kernel — and,
-at the end of the file, Mamba-1's selective scan (S6) of the ``s6``
-blocks.
+dense MLP: granitemoehybrid) — packed rows; the scan a Pallas kernel on a
+TPU (ops/pallas/ssd_scan.py), XLA einsums elsewhere — and, at the end of
+the file, Mamba-1's selective scan (S6) of the ``s6`` blocks.
 
 One mixer, ``u = norm(h)`` [B, T, D] (models/transformer.py adds the
 residual):
@@ -30,6 +30,9 @@ leaves a state [H, P, N]; the states pass between chunks through one small
 matmul over the chunk axis (decays masked where a document ends between
 two chunks). The cumulative decays and the states between chunks are
 float32; the matmuls take the compute dtype and accumulate in float32.
+On a TPU the same algorithm runs as one kernel a pass (:func:`ssd_scan`,
+``impl``): the [Q, Q] blocks stay in VMEM and the state rides the chunk
+axis; :func:`scan_impl_counts` says which form each traced scan took.
 
 Device scopes (base/telemetry.SSM_SCOPES): ``ssm_in_proj``, ``ssm_conv``,
 ``ssm_scan``, ``ssm_gate_norm``, ``ssm_out_proj``. :func:`geometry_counts`
@@ -54,8 +57,24 @@ from areal_tpu.models.config import S6Config, SSMConfig
 _GEOMETRY: collections.Counter = collections.Counter()
 
 
+# The same scans by what runs them: {"pallas" | "pallas_interpret" | "xla":
+# calls} (as attention.dispatch_counts).
+_SCAN_IMPL: collections.Counter = collections.Counter()
+
+
 def geometry_counts() -> Dict[Tuple[int, int, int, int, int], int]:
     return dict(_GEOMETRY)
+
+
+def scan_impl_counts() -> Dict[str, int]:
+    return dict(_SCAN_IMPL)
+
+
+def ssd_kernel_frac() -> Optional[float]:
+    """The share of the traced Mamba-2 scans that run the kernel; None
+    where none was traced."""
+    total = sum(_SCAN_IMPL.values())
+    return (total - _SCAN_IMPL["xla"]) / total if total else None
 
 
 def init_mamba_params(ssm: SSMConfig, n: int, hidden_dim: int,
@@ -122,15 +141,21 @@ def ssd_scan(x: jnp.ndarray,  # [B, T, H, P]
              Bm: jnp.ndarray,  # [B, T, G, N]
              Cm: jnp.ndarray,  # [B, T, G, N]
              seg: jnp.ndarray,  # [B, T] int; 0 = padding
-             chunk: int) -> jnp.ndarray:
+             chunk: int, impl: str = "auto") -> jnp.ndarray:
     """The recurrence ``S_t = exp(Δ_t A) S_{t-1} + Δ_t x_t ⊗ B_t``,
     ``y_t = S_t C_t`` in chunks of ``chunk`` tokens, ``S`` zero before
-    each document's first token. Returns y [B, T, H, P] float32."""
+    each document's first token. Returns y [B, T, H, P] float32. ``impl``
+    as :func:`selective_scan`'s: the kernel on a TPU for the widths it
+    takes, the einsums below elsewhere."""
     B_, T, H, P = x.shape
     G, N = Bm.shape[2:]
     Hg = H // G
     Q = chunk
+    how = _ssd_impl(impl, Q, H, P, G, N)
     _GEOMETRY[(B_, T, Q, H, G)] += 1
+    _SCAN_IMPL[how] += 1
+    if how != "xla":  # the kernel takes a row of any length
+        return _ssd_kernel(x, dt, dt * A, Bm, Cm, seg, Q, how)
     pad = -T % Q
     if pad:  # a padded token is its row's padding: Δ = 0 moves nothing
         x, dt, Bm, Cm = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
@@ -191,6 +216,53 @@ def ssd_scan(x: jnp.ndarray,  # [B, T, H, P]
     return y.reshape(B_, Z * Q, H, P)[:, :T]
 
 
+def _ssd_impl(impl: str, chunk: int, H: int, P: int, G: int, N: int) -> str:
+    """"pallas" | "pallas_interpret" | "xla", as :func:`_scan_impl`."""
+    from areal_tpu.ops.attention import _wants_kernel
+    from areal_tpu.ops.pallas import ssd_scan as kernel
+
+    if not kernel.supported(chunk, H, P, G, N):
+        return "xla"
+    if impl == "pallas_interpret":
+        return impl
+    # a chip without the VMEM the kernel asks for: the einsums, counted
+    return "pallas" if _wants_kernel(impl) and kernel.fits_device() else "xla"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _ssd_kernel(x, dt, a, Bm, Cm, seg, chunk, how):
+    """The kernel's scan of x [B, T, H, P] under Δ and the log-decays ``a =
+    Δ·A`` [B, T, H] float32; T any length."""
+    from areal_tpu.ops.pallas import ssd_scan as kernel
+
+    with jax.named_scope("ssm_scan"):
+        return kernel.scan_fwd(x, dt, a, Bm, Cm, seg, chunk,
+                               interpret=how == "pallas_interpret")[0]
+
+
+def _ssd_kernel_fwd(x, dt, a, Bm, Cm, seg, chunk, how):
+    from areal_tpu.ops.pallas import ssd_scan as kernel
+
+    with jax.named_scope("ssm_scan"):
+        y, states = kernel.scan_fwd(x, dt, a, Bm, Cm, seg, chunk, keep=True,
+                                    interpret=how == "pallas_interpret")
+    return y, (x, dt, a, Bm, Cm, seg, states, y)
+
+
+def _ssd_kernel_bwd(chunk, how, res, dy):
+    from areal_tpu.ops.pallas import ssd_scan as kernel
+
+    x, dt, a, Bm, Cm, seg, states, y = res
+    with jax.named_scope("ssm_scan"):
+        dx, ddt, da, dB, dC = kernel.scan_bwd(
+            x, dt, a, Bm, Cm, seg, states, y, dy, chunk,
+            interpret=how == "pallas_interpret")
+    return dx, ddt, da, dB.astype(Bm.dtype), dC.astype(Cm.dtype), None
+
+
+_ssd_kernel.defvjp(_ssd_kernel_fwd, _ssd_kernel_bwd)
+
+
 def group_rms_norm(y: jnp.ndarray,  # [..., d_inner] float32
                    w: jnp.ndarray, groups: int, eps: float) -> jnp.ndarray:
     """RMSNorm over each of ``groups`` groups of channels, then the
@@ -205,6 +277,7 @@ def mamba_mixer(u: jnp.ndarray,  # [B, T, D] the normed residual stream
                 lp: Dict[str, jnp.ndarray],  # this layer's parameters
                 ssm: SSMConfig, eps: float,
                 segment_ids: Optional[jnp.ndarray],  # [B, T]; None = one document a row
+                impl: str = "auto",
                 ) -> jnp.ndarray:
     B_, T, _ = u.shape
     H, P, G, N = ssm.n_heads, ssm.head_dim, ssm.n_groups, ssm.state_dim
@@ -217,16 +290,18 @@ def mamba_mixer(u: jnp.ndarray,  # [B, T, D] the normed residual stream
     with jax.named_scope("ssm_conv"):
         xBC = jax.nn.silu(causal_conv(xBC, lp["conv_w"], lp["conv_b"], seg))
         x, Bm, Cm = jnp.split(xBC, [di, di + G * N], axis=-1)
-        x = x.reshape(B_, T, H, P)
     with jax.named_scope("ssm_scan"):
         f32 = jnp.float32
         dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
         A = -jnp.exp(lp["A_log"].astype(f32))
-        y = ssd_scan(x, dt, A, Bm.reshape(B_, T, G, N),
-                     Cm.reshape(B_, T, G, N), seg, ssm.chunk_size)
-        y = y + lp["D"].astype(f32)[:, None] * x.astype(f32)
+        y = ssd_scan(x.reshape(B_, T, H, P), dt, A, Bm.reshape(B_, T, G, N),
+                     Cm.reshape(B_, T, G, N), seg, ssm.chunk_size, impl)
+        # D · x a head, on the [B, T, d_inner] rows the kernel reads and
+        # writes: a [.., H, 64] array in between would be a second layout
+        y = y.reshape(B_, T, di) + jnp.repeat(
+            lp["D"].astype(f32), P) * x.astype(f32)
     with jax.named_scope("ssm_gate_norm"):
-        y = y.reshape(B_, T, di) * jax.nn.silu(z.astype(f32))
+        y = y * jax.nn.silu(z.astype(f32))
         y = group_rms_norm(y, lp["norm"].astype(f32), G, eps).astype(u.dtype)
     with jax.named_scope("ssm_out_proj"):
         return y @ lp["out_proj"]

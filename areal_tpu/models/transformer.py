@@ -416,7 +416,8 @@ def _block(
                                            attn_impl)
         elif akind == SSD:
             attn, new_kv = ssmmod.mamba_mixer(
-                x, lp, cfg.ssm, cfg.rms_norm_eps, segment_ids), None
+                x, lp, cfg.ssm, cfg.rms_norm_eps, segment_ids,
+                attn_impl), None
         else:
             attn, new_kv = ssmmod.gated_memory_unit(
                 x, shared[MEMORY], lp), None
@@ -571,7 +572,7 @@ def _mixer_block(
         from areal_tpu.models import ssm as ssmmod
 
         out = ssmmod.mamba_mixer(x, lp, cfg.ssm, cfg.rms_norm_eps,
-                                 segment_ids)
+                                 segment_ids, attn_impl)
         return constrain(_residual(cfg, h, out), "hidden"), None, None
     if kind == MOE_ONLY:
         from areal_tpu.models import moe as moemod

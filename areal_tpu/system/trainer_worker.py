@@ -362,6 +362,9 @@ class TrainerWorker:
             # state-space layers
             ssm_geometry={"%dx%d/%d/h%dg%d" % geom: n
                           for geom, n in ssm.geometry_counts().items()},
+            # {"pallas" | "pallas_interpret" | "xla": scans traced}: what
+            # runs them (the kernel of ops/pallas/ssd_scan.py, or einsums)
+            ssm_scan_impl=ssm.scan_impl_counts(),
             # {"rows x length/dD nN/impl": scans traced}: a model's selective
             # scans (S6), and which form each runs as
             s6_geometry={"%dx%d/d%dn%d/%s" % geom: n

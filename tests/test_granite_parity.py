@@ -561,6 +561,84 @@ def test_the_scopes_the_benchmark_reads_are_the_programs():
         assert scope in text, scope
 
 
+# A Granite-like program at the widths the scan's kernel takes: 2 heads of
+# 64 over one group of 128 states, chunks of 128, two blocks.
+KERNEL_KEYS = {**HF_KEYS, "num_hidden_layers": 2,
+               "layer_types": ["mamba", "mamba"], "mamba_n_heads": 2,
+               "mamba_d_head": 64, "mamba_d_state": 128,
+               "mamba_chunk_size": 128}
+
+
+def kernel_width_grad(cfg, params, tok, seg, impl):
+    """The jitted gradient of Σ logits² of a packed grid under ``impl``."""
+    B, T = tok.shape
+    pos = jnp.broadcast_to(jnp.arange(T), (B, T))
+
+    def loss(p):
+        out, _ = transformer.forward(p, cfg, tok, pos, segment_ids=seg,
+                                     attn_impl=impl, return_kv=False,
+                                     remat="full")
+        return jnp.sum(out.astype(jnp.float32) ** 2), out
+
+    return jax.jit(jax.value_and_grad(loss, has_aux=True))
+
+
+def test_the_scans_kernel_runs_the_blocks_and_keeps_the_geometry_key():
+    """The same program through the Pallas kernel (interpreted) and
+    through the XLA einsums: equal logits and gradients; ``geometry_
+    counts()`` counts the same scans under the same five-field key with
+    the TRUE row length (150: no multiple of the chunk) — the benchmark's
+    ``kernel_ok`` and roofline read it — and ``scan_impl_counts()`` alone
+    tells the two apart."""
+    cfg, params = model(KERNEL_KEYS)
+    row, seg, _ = packed_row([61, 40, 37], 150)
+    key = (1, 150, 128, 2, 1)
+    seen = {}
+    for impl, how in (("pallas_interpret", "pallas_interpret"),
+                      ("reference", "xla")):
+        geom = ssm.geometry_counts().get(key, 0)
+        hows = ssm.scan_impl_counts().get(how, 0)
+        (_, logits), grads = kernel_width_grad(cfg, params, row, seg, impl)(
+            params)
+        # one scanned run of blocks, traced as often under either form
+        traced = ssm.geometry_counts()[key] - geom
+        assert traced >= 1 and traced == seen.get("traced", traced)
+        assert ssm.scan_impl_counts()[how] - hows == traced
+        seen["traced"] = traced
+        seen[impl] = (logits, grads)
+    assert all(len(k) == 5 for k in ssm.geometry_counts())
+    np.testing.assert_allclose(seen["pallas_interpret"][0],
+                               seen["reference"][0], **TOL)
+    flat = [jax.tree.leaves(seen[i][1]) for i in ("pallas_interpret",
+                                                  "reference")]
+    for got, want in zip(*flat):
+        np.testing.assert_allclose(
+            got, want, atol=3e-4 * float(jnp.max(jnp.abs(want))) + 1e-6,
+            rtol=3e-3)
+
+
+def test_the_scans_kernels_sit_under_the_scope_the_benchmark_reads():
+    """Lowered for a TPU, a block's forward kernel, the one its backward
+    re-runs and the backward kernel are all filed under ``ssm_scan`` by
+    the benchmark's own reduction (``granite_scan_busy_pct`` /
+    ``granite_scan_roofline``), none elsewhere."""
+    import re
+
+    from benchmark import granite_trace, ssm_trace
+    from areal_tpu.ops.pallas import ssd_scan
+
+    assert granite_trace.SCOPES == ssm_trace.SSM_SCOPES
+    cfg, params = model(KERNEL_KEYS)
+    row, seg, _ = packed_row([150, 106], 256)
+    text = kernel_width_grad(cfg, params, row, seg, "pallas").trace(
+        params).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    names = re.findall(r'loc\("([^"]*pallas_call)"', text)
+    for kernel_name in (ssd_scan.FWD_NAME, ssd_scan.BWD_NAME):
+        mine = [n for n in names if f"/{kernel_name}/" in n]
+        assert mine, (kernel_name, names)
+        assert {ssm_trace.scope_of(n) for n in mine} == {"ssm_scan"}, mine
+
+
 def test_documents_per_row_is_a_gauge_of_the_train_step():
     """``train/docs_per_row``: documents over the rows that hold any, of
     the packed grids of one train batch."""

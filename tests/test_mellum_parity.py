@@ -299,6 +299,16 @@ PARENT_TEXT = {
     "qwen": "7a5e8784b462f38be131fdc520e47b30e231745693b614898a64891e08f06e1e",
     "olmoe": "ea4a23430165ecb88be0ebce87b99de108998b02a8a1104356179fd604486821",
 }
+# ... and the programs beside the Mamba-2 scan's kernel (PR 50), which
+# must not see it: the OLMoE-like grid under the four-chip cell's ``e4``
+# mesh (four of the host platform's devices) and a phi4flash-like grid
+# whose scans are S6, taken the same way on 47ab89e.
+PARENT_TEXT.update({
+    "olmoe-e4":
+        "6d1e87740353d4124c23828ce8cdb1123f9a01cd313b7f68de0ca31778d5f64d",
+    "phi-s6":
+        "19c49f246c20808df28b1b1fca7e894ba5136ae40e536dbf681d999432d1d8e2",
+})
 
 
 def lowered_grad_text(which: str) -> str:
@@ -309,19 +319,31 @@ def lowered_grad_text(which: str) -> str:
 
     kw = dict(vocab_size=64, n_layers=2, hidden_dim=32, n_q_heads=4,
               n_kv_heads=2)
+    mesh = None
     if which == "qwen":
         cfg = tiny_config(**kw, use_attention_bias=True,
                           tie_word_embeddings=True)
+    elif which == "phi-s6":  # Mamba-1, window, Mamba-1 -> memory, full
+        cfg = hf.config_from_hf(types.SimpleNamespace(
+            model_type="phi4flash", num_hidden_layers=4,
+            layer_pattern="MSMF", hidden_size=32, num_attention_heads=4,
+            num_key_value_heads=2, intermediate_size=48, vocab_size=64,
+            sliding_window=8, mb_per_layer=2, layer_norm_eps=1e-5,
+            tie_word_embeddings=True, max_position_embeddings=4096))
     else:
         cfg = tiny_config(**kw, use_qk_norm=True, qk_norm_extent="proj",
                           moe=dict(num_experts=4, top_k=2,
                                    capacity_factor=None,
                                    norm_topk_prob=False))
+        if which == "olmoe-e4":  # the four-chip cell's mesh, on the host
+            from areal_tpu.parallel import mesh as pmesh
+
+            mesh = pmesh.make_mesh(pmesh.ParallelSpec.parse("e4"))
     eng = JaxTrainEngine(
         cfg, transformer.init_params(cfg, jax.random.PRNGKey(0)),
         OptimizerConfig(type="sgd", lr=1e-2), FinetuneSpec(1, 8, 4),
-        compute_dtype="float32", length_bucket=16, rows_bucket=2,
-        seqs_bucket=4, remat=True)
+        mesh=mesh, compute_dtype="float32", length_bucket=16,
+        rows_bucket=4 if mesh is not None else 2, seqs_bucket=4, remat=True)
     rng = np.random.RandomState(3)
     lens = rng.randint(6, 14, 6)
     total = int(lens.sum())

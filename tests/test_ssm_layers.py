@@ -329,3 +329,46 @@ def test_the_scopes_the_benchmark_reads_are_the_programs():
         params).as_text(debug_info=True)
     for scope in new | {"moe_router", "moe_dispatch", "moe_experts"}:
         assert scope in text, scope
+
+
+def test_a_mamba_layers_kernels_sit_under_the_scans_scope():
+    """A Mamba layer at the widths the scan's kernel takes (2 heads of 64,
+    one group of 128 states, chunks of 128), lowered for a TPU: the
+    forward and the backward kernel are filed under ``ssm_scan`` by the
+    benchmark's own reduction (``ssm_scan_busy_pct`` / ``ssm_scan_
+    roofline``); interpreted on the CPU the layer answers as the XLA form
+    does, under the same five-field geometry key."""
+    import re
+
+    from areal_tpu.ops.pallas import ssd_scan
+    from benchmark import ssm_trace
+
+    H, P, G, N, Q = 2, 64, 1, 128, 128
+    cfg = SSMConfig(n_heads=H, head_dim=P, n_groups=G, state_dim=N,
+                    chunk_size=Q)
+    lp = {k: v[0] for k, v in ssmmod.init_mamba_params(
+        cfg, 1, 32, jax.random.PRNGKey(0), jnp.float32).items()}
+    u = jax.random.normal(jax.random.PRNGKey(1), (1, 200, 32))
+    seg = jnp.asarray([[1] * 90 + [2] * 100 + [0] * 10])
+
+    def grad(impl):
+        return jax.jit(jax.value_and_grad(lambda lp, u: jnp.sum(
+            ssmmod.mamba_mixer(u, lp, cfg, 1e-5, seg, impl) ** 2),
+            argnums=(0, 1)))
+
+    text = grad("pallas").trace(lp, u).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    names = re.findall(r'loc\("([^"]*pallas_call)"', text)
+    for kernel_name in (ssd_scan.FWD_NAME, ssd_scan.BWD_NAME):
+        mine = [n for n in names if f"/{kernel_name}/" in n]
+        assert mine and {ssm_trace.scope_of(n) for n in mine} == {
+            "ssm_scan"}, (kernel_name, names)
+    key = (1, 200, Q, H, G)
+    before = ssmmod.geometry_counts().get(key, 0)
+    (got, dgot), (want, dwant) = (grad(i)(lp, u) for i in (
+        "pallas_interpret", "reference"))
+    assert ssmmod.geometry_counts()[key] == before + 2
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+    for a, b in zip(jax.tree.leaves(dgot), jax.tree.leaves(dwant)):
+        np.testing.assert_allclose(
+            a, b, atol=2e-4 * float(jnp.max(jnp.abs(b))) + 1e-7, rtol=2e-3)

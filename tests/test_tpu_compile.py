@@ -82,6 +82,10 @@ LATENT_T = (3072, 3456, 3712)
 # states) and its packed grid, at the published widths.
 SAMBAY_SCAN = (1, 8192, 5120, 16)
 SAMBAY_GRID = (1, 8192)
+# The Mamba-2 scans of the Granite and the Nemotron cell: (rows, length —
+# no multiple of Granite's chunk —, heads, head_dim, groups, states, chunk).
+SSD_SCANS = {"ssd-scan-granite": (1, 7040, 32, 64, 1, 128, 256),
+             "ssd-scan-nemotron": (1, 4096, 16, 64, 1, 128, 128)}
 
 
 def _compile_all():
@@ -408,6 +412,22 @@ def _compile_all():
         return jnp.sum(ssmmod.selective_scan(x, dt, A, Bm, Cm, Dk, seg,
                                              "pallas") ** 2)
 
+    # The Mamba-2 (SSD) scan's kernels alone, forward + backward, at the
+    # two cells' rows: (rows, length, heads, head_dim, groups, states,
+    # chunk), bfloat16.
+    def ssd_loss(x, dt, A, Bm, Cm, seg, chunk):
+        return jnp.sum(ssmmod.ssd_scan(x, dt, A, Bm, Cm, seg, chunk,
+                                       "pallas") ** 2)
+
+    for name, (R, T, H, P, G, N, Q) in SSD_SCANS.items():
+        bf = jnp.bfloat16
+        record(name, jax.jit(
+            jax.value_and_grad(ssd_loss, argnums=(0, 1, 2, 3, 4)),
+            static_argnums=6).lower(
+                f32(R, T, H, P, dtype=bf), f32(R, T, H), f32(H),
+                f32(R, T, G, N, dtype=bf), f32(R, T, G, N, dtype=bf),
+                f32(R, T, dtype=jnp.int32), Q).compile())
+
     R, T, Dn, N = SAMBAY_SCAN
     record("s6-scan", jax.jit(
         jax.value_and_grad(scan_loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
@@ -646,6 +666,20 @@ def test_the_selective_scan_kernels_compile_for_v5e(compiled):
     assert got["custom_calls"] == 2
     R, T, Dn, N = SAMBAY_SCAN
     assert got["temp_bytes"] < R * T * Dn * N * 4 / 2
+
+
+@pytest.mark.parametrize("name", sorted(SSD_SCANS))
+def test_the_ssd_scan_kernels_compile_for_v5e(compiled, name):
+    """Forward and backward of the Mamba-2 scan at a cell's row: two
+    Mosaic kernels inside the VMEM they ask for, and no [heads, chunk,
+    chunk] block beside them — the temporaries are y in float32, the
+    states entering each chunk, the gradients and the small [T, heads]
+    arrays of the decays, far under the 32 KB a token of the XLA form's
+    float32 decay block alone."""
+    got = compiled[name]
+    assert got["custom_calls"] == 2
+    R, T, H, P, G, N, Q = SSD_SCANS[name]
+    assert got["temp_bytes"] < R * T * H * Q * 4
 
 
 def test_the_sambay_cell_compiles_at_the_published_widths(compiled):
