@@ -231,15 +231,17 @@ def _moe_step_stats(fetched: Dict[str, Any], n_mbs: int) -> Dict[str, float]:
     """A step's routing health out of its fetched statistics: the (token,
     expert) pairs routed per layer over the step (on a share of an expert
     layer also those that chose an expert held here), where the sorted
-    pass is bounded (models/moe.sorted_rows) its passes per layer and
-    those of them that ran on the whole buffer, and the micro-batch means
+    pass is bounded (models/moe.sorted_rows) its passes per layer, those
+    of them that ran on the whole buffer, the rows they ran on and the
+    rows their gathers and combines walked (models/moe.walked_rows), and
+    the micro-batch means
     of the load ratio and the dropped share. {} for a dense model."""
     n = max(n_mbs, 1)
     return {
         k: float(fetched[k]) / (1 if k in _MOE_SUMMED else n)
         for k in ("moe_routed_rows", "moe_local_rows", "moe_passes",
-                  "moe_full_passes", "moe_expert_load_ratio",
-                  "moe_dropped_frac")
+                  "moe_full_passes", "moe_bound_rows", "moe_walked_rows",
+                  "moe_expert_load_ratio", "moe_dropped_frac")
         if k in fetched
     }
 
@@ -392,6 +394,9 @@ class JaxTrainEngine(TrainableEngine):
         self._compute_shared = False
         self._cast_fn = None
         self.param_cast_rebuilds = 0
+        # {"local" | "bound" | "walked": rows per layer since start}: the
+        # bounded expert passes' rows (``moe_rows`` in the device_report)
+        self.moe_rows: Dict[str, float] = {}
         # forward()'s micro-batches over the engine's life, and those of
         # them dispatched while an earlier one's result was unfetched;
         # under a lock: several threads call forward() (algorithms/fused).
@@ -1213,9 +1218,14 @@ class JaxTrainEngine(TrainableEngine):
             ("moe_expert_load_ratio", "train/moe_expert_load_ratio"),
             ("moe_local_rows", "train/moe_local_rows"),
             ("moe_full_passes", "train/moe_full_passes"),
+            ("moe_walked_rows", "train/moe_walked_rows"),
         ):
             if stat in out:
                 telemetry.set_gauge(gauge, out[stat])
+        if "moe_walked_rows" in out:
+            for rows in ("local", "bound", "walked"):
+                self.moe_rows[rows] = (self.moe_rows.get(rows, 0.0)
+                                       + out.get(f"moe_{rows}_rows", 0.0))
         if out.get("moe_full_passes"):
             logger.warning(
                 f"{out['moe_full_passes']:g} of {out['moe_passes']:g} expert "

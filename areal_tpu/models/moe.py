@@ -57,9 +57,12 @@ Parity target: ``realhf/impl/model/modules/moe/`` — ``TopKRouter``
    ``lax.cond`` (:func:`_bounded_pass`), exact either way. Its combine
    adds the rows it ran on into their tokens (float32, rounded once): no
    inverse permutation, and no ``[top_k x tokens, D]`` array forward or
-   backward. Where the two counts coincide (every expert held, a decode
-   step's short buffer) there is no ``cond`` and the entries are
-   un-permuted and summed (:func:`combine_counts` records which);
+   backward — and of those rows only the LIVE ones, a row tile at a time
+   (:func:`_add_live`; so does the transpose of its row gather): the dead
+   half of the bound's rows is never added. Where the two counts
+   coincide (every expert held, a decode step's short buffer) there is no
+   ``cond`` and the entries are un-permuted and summed
+   (:func:`combine_counts` records which);
  - **a share** (``MoEConfig.router_experts`` / ``first_expert``): one
    rank's part of such a group run alone, on a mesh with no "ep" axis.
    The router scores all the published experts and normalises the gates
@@ -89,6 +92,9 @@ backend/jax_train.py; docs/observability.md): ``dropped_frac``,
 on a share ``local_rows`` (those of them that chose an expert held
 here), where the pass is bounded ``passes`` and ``full_passes`` (the
 bounded passes, and those of them that ran on the whole buffer),
+``bound_rows`` and ``walked_rows`` (the rows those passes ran on, and
+those of them that the row gather and the combine walked: whole steps
+over the live head, :func:`walked_rows`),
 ``expert_load`` ([E] fraction of routed assignments per expert,
 pre-drop) and
 ``expert_load_ratio`` (max/mean of that — 1.0 is perfectly balanced,
@@ -112,7 +118,8 @@ from areal_tpu.ops.attention import _wants_kernel
 DISPATCH_METHODS = ("grouped", "einsum")
 # Aux entries that add up over micro-batches and optimizer steps; every
 # other scalar is a mean (backend/jax_train.py, algorithms/ppo.py).
-SUMMED_AUX = ("routed_rows", "local_rows", "passes", "full_passes")
+SUMMED_AUX = ("routed_rows", "local_rows", "passes", "full_passes",
+              "bound_rows", "walked_rows")
 
 
 def resolve_dispatch(method: Optional[str] = None) -> str:
@@ -507,23 +514,114 @@ def combine_counts() -> Dict[Tuple[int, int, int, int], str]:
     return dict(_COMBINES)
 
 
+# Rows a step of the live walk (:func:`_add_live`): a whole number of row
+# tiles. Set from tools/expert_rows_sweep.py on a v5e at the three shipped
+# shapes (PERF.md §5, PR 51; device ms of the float32 add of Mellum 2's
+# 32,768-row bound, 16.4k rows live, 2304 wide): all the rows at once 3.94;
+# steps of 256 / 512 / 1024 rows 1.94 / 1.93 / 1.95 — a row costs what it
+# cost (117 ns) and a step next to nothing; steps of 1536 / 2048 rows 8.93 /
+# 7.86 — a step's float32 rows at 14 MB and over ran 4-5 x slower a row
+# (9 MB and under did not, at any width tried). The shortest step that is
+# whole row tiles wastes the fewest rows past the last live one.
+_WALK_ROWS = _ROW_TILE
+
+
+def _walk(rows: int, live: jnp.ndarray) -> Tuple[int, jnp.ndarray]:
+    """(rows a step, steps) of a walk over the ``live`` head of ``rows``
+    sorted rows: whole steps of ``_WALK_ROWS`` rows (of all the rows, where
+    they are fewer), as many as hold a live row. A last step that would
+    pass the end starts early instead, at ``rows - step``."""
+    step = min(_WALK_ROWS, rows)
+    return step, (jnp.minimum(live, rows) + step - 1) // step
+
+
+def walked_rows(rows: int, live: jnp.ndarray) -> jnp.ndarray:
+    """Rows the walk of :func:`_walk` moves: ``steps x step``, all the rows
+    at most — what a pass's combine, and the transpose of its row gather,
+    each cost in rows."""
+    step, steps = _walk(rows, live)
+    return jnp.minimum(steps * step, rows)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _gather_live(T: int, xf, tok, live) -> jnp.ndarray:
+    """Row ``i`` of the result is ``xf[tok[i]]`` (``xf [T, D]``, ``tok
+    [rows]``): the ``live`` head of the sort and the dead rows behind it
+    alike, whose cotangent is DROPPED — the grouped GEMMs select them to
+    zero on the way in and back (:func:`_grouped_matmul`). ONE gather of
+    all the rows: XLA's row gather writes at the speed of the memory (7 ns
+    a row of 2304, dead or live; a loop over the live steps was 3 x
+    slower, and a select of the dead rows costs twice the gather: PERF.md
+    §5, PR 51). What a dead row costs dear is the TRANSPOSE, a scatter-add
+    of zeros into a real token, so the VJP is :func:`_add_live` on the
+    cotangent."""
+    return jnp.take(xf, tok, axis=0)
+
+
+def _gather_live_fwd(T, xf, tok, live):
+    return _gather_live(T, xf, tok, live), (tok, live)
+
+
+def _gather_live_bwd(T, kept, ct):
+    return _add_live(T, ct, *kept), None, None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _add_live(T: int, ys, tok, live) -> jnp.ndarray:
+    """``[T, D]``: row ``i < live`` of ``ys [rows, D]`` added into row
+    ``tok[i]``, accumulated in float32 and rounded once to ``ys``' dtype;
+    the rows from ``live`` on are not added. The transpose of
+    :func:`_gather_live`, as a loop over the live head of ``tok``,
+    :func:`_walk`'s steps, in ascending row order: a step scatter-adds its
+    rows into the carried float32 sums in place, so a dead row past the
+    last live step costs nothing (a dead row's token is a real one: a
+    scatter-add of all ``rows`` adds its zeros at a live row's 117 ns).
+    Its VJP is :func:`_gather_live` on the cotangent (its dead rows are
+    the caller's to zero, as they always were): nothing differentiates
+    through a loop whose trip count is traced."""
+    rows, D = ys.shape
+    step, steps = _walk(rows, live)
+
+    def one(i, sums):
+        start = jnp.minimum(i * step, rows - step)
+        row = start + jnp.arange(step)
+        at = jax.lax.dynamic_slice(tok, (start,), (step,))
+        # rows an earlier step added, and dead ones: index T adds nowhere
+        at = jnp.where((row >= i * step) & (row < live), at, T)
+        part = jax.lax.dynamic_slice(ys, (start, 0), (step, D))
+        return sums.at[at].add(part.astype(jnp.float32), mode="drop")
+
+    return jax.lax.fori_loop(0, steps, one,
+                             jnp.zeros((T, D), jnp.float32)).astype(ys.dtype)
+
+
+def _add_live_fwd(T, ys, tok, live):
+    return _add_live(T, ys, tok, live), (tok, live)
+
+
+def _add_live_bwd(T, kept, ct):
+    return _gather_live(T, ct, *kept), None, None
+
+
+_gather_live.defvjp(_gather_live_fwd, _gather_live_bwd)
+_add_live.defvjp(_add_live_fwd, _add_live_bwd)
+
+
 def _expert_rows(
     rows: int,  # static: the rows of the sort the experts run on
     act,  # static: the experts' activation
-    k: int,  # static: entries a token
     gemm: str,  # static: "ragged_dot" | "gmm" | "gmm_interpret"
-    xf: jnp.ndarray,  # [T, D] tokens (rows past N = M // k are never read)
-    order: jnp.ndarray,  # [M] the entries sorted by group
+    xs: jnp.ndarray,  # [rows, D] the tokens of the sort's first rows
     gate: jnp.ndarray,  # [M] gate of each entry, in sorted order
     group_sizes: jnp.ndarray,  # [G] int32
     gate_w: Optional[jnp.ndarray],  # [G, D, F]; None = experts not gated
     up_w: jnp.ndarray,  # [G, D, F]
     down_w: jnp.ndarray,  # [G, F, D]
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The experts on the first ``rows`` rows of the sort: row gather, the
-    grouped GEMMs (three, or two where the experts are not gated), gate
-    multiply. Returns (the token of each row [rows], the gate-weighted
-    expert outputs [rows, D], zero past ``sum(group_sizes)``).
+) -> jnp.ndarray:
+    """The experts on the first ``rows`` rows of the sort, gathered by the
+    caller: the grouped GEMMs (three, or two where the experts are not
+    gated) and the gate multiply. Returns the gate-weighted expert outputs
+    ``[rows, D]``, zero past ``sum(group_sizes)``.
 
     THE RULE of which grouped GEMM runs: the Pallas kernel where ``gemm``
     (handed down from :func:`moe_mlp`) allows it — ``"gmm"``: compiled by
@@ -531,13 +629,10 @@ def _expert_rows(
     tests — and the pass is bounded (``rows < M``); ``ragged_dot`` on the
     whole buffer — the fallback branch of :func:`_bounded_pass`, an ep
     shard's pass, a decode step's — and wherever ``gemm`` says so."""
-    M = order.shape[0]
+    M = gate.shape[0]
     matmul = functools.partial(
         _grouped_matmul, kernel=gemm != "ragged_dot" and rows < M,
         interpret=gemm == "gmm_interpret")
-    with jax.named_scope("moe_dispatch"):
-        tok = (order if rows == M else order[:rows]) // k
-        xs = jnp.take(xf, tok, axis=0)  # [rows, D] sorted inputs
     with jax.named_scope("moe_experts"):
         if gate_w is None:
             h = act(matmul(xs, up_w, group_sizes))
@@ -546,23 +641,28 @@ def _expert_rows(
                 xs, up_w, group_sizes)
         ys = matmul(h, down_w, group_sizes)  # [rows, D]
     with jax.named_scope("moe_dispatch"):
-        return tok, ys * (gate if rows == M else gate[:rows]).astype(
+        return ys * (gate if rows == M else gate[:rows]).astype(
             ys.dtype)[:, None]
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
-def _rows_pass(rows: int, act, k: int, gemm: str, xf, *args) -> jnp.ndarray:
+def _rows_pass(rows: int, act, k: int, gemm: str, xf, order, gate,
+               group_sizes, *weights) -> jnp.ndarray:
     """The expert pass on the first ``rows`` rows of the sort
-    (:func:`_expert_rows`, its arguments) with its combine: row ``i`` is
-    added into token ``order[i] // k`` — a scatter-add of ``rows`` rows,
-    accumulated in float32 and rounded once, as a sum over ``k`` is; its
-    backward GATHERS ``rows`` rows from the cotangent. Returns the
-    gate-weighted sum per token of the rows it ran on, ``[T, D]``: as many
-    rows as ``xf``, so that the cotangent is whole row tiles where the
-    source is (:func:`_whole_row_tiles`); rows past the tokens stay zero.
-    Exact where ``sum(group_sizes) <= rows``: the live rows are the sort's
-    head, and a row past them adds zero. Nothing of ``M`` rows is built
-    where ``rows < M``, forward or backward.
+    (:func:`_expert_rows`) between its two row movements:
+    :func:`_gather_live` reads row ``i``'s token ``order[i] // k``,
+    :func:`_add_live` adds row ``i`` into it — accumulated in float32 and
+    rounded once, as a sum over ``k`` is. Each is the other's transpose,
+    and every ADD of the two, forward and backward, walks only the LIVE
+    rows (``sum(group_sizes)``, the sort's head) a row tile at a time: the
+    dead rows of the bound are gathered, at a memory write each, and never
+    added. Returns the gate-weighted sum per token of the rows it ran on,
+    ``[T, D]``: as many rows as ``xf``, so that the cotangent is whole row
+    tiles where the source is (:func:`_whole_row_tiles`); rows past the
+    tokens stay zero.
+    Exact where ``sum(group_sizes) <= rows``: a row past the live ones
+    adds zero, and is not added. Nothing of ``M`` rows is built where
+    ``rows < M``, forward or backward.
 
     ONE jitted function for every call site of a shape: a trainer's
     program calls the pass from both branches of :func:`_bounded_pass`,
@@ -571,10 +671,14 @@ def _rows_pass(rows: int, act, k: int, gemm: str, xf, *args) -> jnp.ndarray:
     kernels with their VJPs among them — is traced and differentiated once
     a shape and a process, and a call site costs one equation: set-up is
     part of what a kernel costs (PERF.md §6, PR 37-38)."""
-    tok, ys = _expert_rows(rows, act, k, gemm, xf, *args)
+    T = xf.shape[0]
     with jax.named_scope("moe_dispatch"):
-        return jnp.zeros(xf.shape, jnp.float32).at[tok].add(
-            ys.astype(jnp.float32)).astype(ys.dtype)
+        tok = (order if rows == order.shape[0] else order[:rows]) // k
+        live = jnp.sum(group_sizes)
+        xs = _gather_live(T, xf, tok, live)  # [rows, D] sorted inputs
+    ys = _expert_rows(rows, act, gemm, xs, gate, group_sizes, *weights)
+    with jax.named_scope("moe_dispatch"):
+        return _add_live(T, ys, tok, live)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
@@ -645,8 +749,10 @@ def _sorted_expert_ffn(
     another shard's experts) sort to the tail beyond ``sum(group_sizes)``
     and add nothing. Returns (the gate-weighted sum per token
     [N, D], the number of entries kept, and the aux of a bounded pass:
-    ``passes`` 1 and ``full_passes`` 1 if it took the whole buffer, else
-    0; {} where the pass is not bounded).
+    ``passes`` 1, ``full_passes`` 1 if it took the whole buffer, else 0,
+    ``bound_rows`` the rows of the branch it took and ``walked_rows``
+    those of them its gather and its combine each moved
+    (:func:`walked_rows`); {} where the pass is not bounded).
 
     The pass runs on the first ``R = rows`` rows of the sort (the caller's
     :func:`sorted_rows`) and adds those ``R`` rows into their tokens: one
@@ -689,7 +795,9 @@ def _sorted_expert_ffn(
     args = (xf, order, gate, group_sizes, gate_w, up_w, down_w)
     _COMBINES[(M, R, T, D)] = "entries" if R == M else "rows"
     if R == M:
-        _, ys = _expert_rows(M, act, k, gemm, *args)
+        with jax.named_scope("moe_dispatch"):
+            xs = jnp.take(xf, order // k, axis=0)  # [M, D] sorted inputs
+        ys = _expert_rows(M, act, gemm, xs, *args[2:])
         with jax.named_scope("moe_dispatch"):
             inv = jnp.argsort(order)  # inverse permutation
             y = jnp.sum(jnp.take(ys, inv, axis=0).reshape(N, k, D), axis=1)
@@ -697,9 +805,13 @@ def _sorted_expert_ffn(
     y = _bounded_pass(R, act, k, gemm, *args)
     with jax.named_scope("moe_dispatch"):
         y = y if T == N else y[:N]
+    live = jnp.sum(group_sizes)
     return y, kept.astype(jnp.float32), {
         "passes": jnp.ones((), jnp.float32),
-        "full_passes": (jnp.sum(group_sizes) > R).astype(jnp.float32)}
+        "full_passes": (live > R).astype(jnp.float32),
+        "bound_rows": jnp.where(live <= R, R, M).astype(jnp.float32),
+        "walked_rows": jnp.where(live <= R, walked_rows(R, live),
+                                 walked_rows(M, live)).astype(jnp.float32)}
 
 
 def _dispatch_grouped(

@@ -386,6 +386,14 @@ class TrainerWorker:
             # pass on a TPU; its tile is moe.gemm_tiling of the shape)
             moe_gemm={"%dx%dx%d/%d" % key: how
                       for key, how in moe.gemm_counts().items()},
+            # {model: {"local" | "bound" | "walked": rows per layer since
+            # start}}: of the rows a bounded expert pass runs on (bound),
+            # those that landed here (local) and those its row gather and
+            # its combine moved (walked: whole steps over the live head)
+            moe_rows={
+                role: m.module.moe_rows for role, m in self.models.items()
+                if getattr(m.module, "moe_rows", None)
+            },
             # {model: {"<attention or mixer kind>/<dense | experts | ->":
             # layers}}: the model's blocks by what they are made of
             blocks={

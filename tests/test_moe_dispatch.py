@@ -572,6 +572,69 @@ def test_rows_are_added_in_float32_and_rounded_once():
     assert np.any(np.asarray(running.astype(jnp.float32)) != once)
 
 
+# ---- the live walk: moe._gather_live / moe._add_live ----
+
+_WALK_STEP, _WALK_R, _WALK_T = 32, 112, 40  # 3.5 steps: the last starts early
+WALK_LIVE = {"0": 0, "1": 1, "step-1": 31, "step": 32, "step+1": 33,
+             "R-1": 111, "R": 112}
+
+
+@pytest.mark.parametrize("layout", ["uniform", "skewed"])
+@pytest.mark.parametrize("live", list(WALK_LIVE))
+def test_the_live_walk_moves_the_live_rows_and_no_other(live, layout,
+                                                        monkeypatch):
+    """The two row movements of a bounded pass against ``jnp.take`` and
+    ``.at[].add`` on the live rows alone: the gather reads every row, the
+    add leaves out the rows past the live ones (NaN there reaches
+    nothing) and sums in float32, rounded once; each one's VJP is the
+    other's forward, so the gather's drops the dead rows' cotangent."""
+    monkeypatch.setattr(moemod, "_WALK_ROWS", _WALK_STEP)
+    rng = np.random.RandomState(51)
+    n, (R, T, D) = WALK_LIVE[live], (_WALK_R, _WALK_T, 16)
+    tok = rng.randint(0, T, size=R)
+    if layout == "skewed":  # nine rows in ten are two tokens'
+        tok = np.where(rng.rand(R) < 0.9, rng.randint(0, 2, size=R), tok)
+    tok, n_live = jnp.asarray(tok), jnp.asarray(n, jnp.int32)
+    xf = jnp.asarray(rng.randn(T, D), jnp.bfloat16)
+    ys = jnp.asarray(rng.randn(R, D), jnp.bfloat16)
+    head = (np.arange(R) < n)[:, None]
+
+    gather = jax.jit(lambda xf: moemod._gather_live(T, xf, tok, n_live))
+    add = jax.jit(lambda ys: moemod._add_live(T, ys, tok, n_live))
+    xs, gather_vjp = jax.vjp(gather, xf)
+    np.testing.assert_array_equal(xs, jnp.take(xf, tok, axis=0))
+    want = jnp.zeros((T, D), jnp.float32).at[tok].add(
+        jnp.where(head, ys, 0).astype(jnp.float32)).astype(jnp.bfloat16)
+    y, add_vjp = jax.vjp(add, jnp.where(head, ys, jnp.nan))
+    np.testing.assert_array_equal(y, want)
+    np.testing.assert_array_equal(gather_vjp(ys)[0], want)
+    np.testing.assert_array_equal(add_vjp(xf)[0], xs)
+    assert int(moemod.walked_rows(R, n_live)) == min(
+        -(-n // _WALK_STEP) * _WALK_STEP, R)
+
+
+def test_walked_rows_lie_between_the_live_rows_and_the_bound():
+    """``walked_rows`` of a bounded layer: whole steps of the walk, so at
+    least the rows that landed here (``local_rows``) and at most the
+    bound's (``sorted_rows``) where the pass fits it — the whole buffer's
+    where it does not; an unbounded layer carries none."""
+    rng = np.random.RandomState(37)
+    kw, lp, x, _ = _bound_case("share", rng)
+    moe = MoEConfig(capacity_factor=None, **kw)
+    M = x.shape[0] * x.shape[1] * kw["top_k"]
+    R = moemod.sorted_rows(M, kw["num_experts"], kw["router_experts"])
+    _, aux = jax.jit(lambda lp, x: moemod.moe_mlp(x, lp, moe))(lp, x)
+    assert float(aux["full_passes"]) == 0 and float(aux["bound_rows"]) == R
+    assert float(aux["local_rows"]) <= float(aux["walked_rows"]) <= R < M
+    assert float(aux["walked_rows"]) % min(moemod._WALK_ROWS, R) == 0
+    lp["router"] = jnp.zeros_like(lp["router"])  # every entry lands here
+    _, aux = jax.jit(lambda lp, x: moemod.moe_mlp(x, lp, moe))(lp, x)
+    assert float(aux["full_passes"]) == 1 and float(aux["bound_rows"]) == M
+    assert float(aux["local_rows"]) == float(aux["walked_rows"]) == M
+    fn, lp, x = _unbounded_layer("all_held")
+    assert "walked_rows" not in jax.eval_shape(fn, lp, x)[1]
+
+
 # sha256 of the lowered text of the layer (output and aux) where the pass
 # is NOT bounded, as the commit before the bound (9987316) lowers it.
 UNBOUNDED_TEXT = {
@@ -643,7 +706,8 @@ def test_a_bounded_layer_holds_both_branches_under_every_entry(
 
 
 def test_full_passes_reach_the_statistics_the_gauge_and_the_log(monkeypatch):
-    """The engine's step tail: ``moe_passes`` / ``moe_full_passes`` are sums
+    """The engine's step tail: ``moe_passes`` / ``moe_full_passes`` /
+    ``moe_walked_rows`` are sums
     over the step's micro-batches (never divided by their count), ride on
     ``train/finish_stats``, set ``train/moe_full_passes`` and, where not
     0, leave a warning in the trainer's log."""
@@ -655,20 +719,27 @@ def test_full_passes_reach_the_statistics_the_gauge_and_the_log(monkeypatch):
     def fetched(full):
         return {"loss": np.float32(1.0), "moe_routed_rows": np.float32(4096),
                 "moe_passes": np.float32(8), "moe_full_passes": np.float32(full),
+                "moe_local_rows": np.float32(1000),
+                "moe_bound_rows": np.float32(2048),
+                "moe_walked_rows": np.float32(1536),
                 "moe_dropped_frac": np.float32(0.0),
                 "moe_expert_load_ratio": np.float32(3.0)}
 
     attrs = jax_train._moe_step_stats(fetched(2), n_mbs=2)
     assert attrs["moe_passes"] == 8 and attrs["moe_full_passes"] == 2
     assert attrs["moe_routed_rows"] == 4096
+    assert attrs["moe_local_rows"] == 1000 <= attrs["moe_walked_rows"] == 1536
     assert attrs["moe_expert_load_ratio"] == 1.5  # a mean over the two
     gauges, warned = {}, []
     monkeypatch.setattr(telemetry, "set_gauge", gauges.__setitem__)
     monkeypatch.setattr(jax_train.logger, "warning", warned.append)
-    engine = types.SimpleNamespace(_EXPERT_LOAD_BUCKETS=(0.5, 1.0))
+    engine = types.SimpleNamespace(_EXPERT_LOAD_BUCKETS=(0.5, 1.0),
+                                   moe_rows={})
     out = jax_train.JaxTrainEngine._finish_stats(engine, fetched(0), 2)
     assert gauges["train/moe_full_passes"] == 0 and not warned
+    assert gauges["train/moe_walked_rows"] == out["moe_walked_rows"] == 1536
     out = jax_train.JaxTrainEngine._finish_stats(engine, fetched(2), 2)
     assert out["moe_full_passes"] == 2 and out["moe_passes"] == 8
     assert gauges["train/moe_full_passes"] == 2
     assert len(warned) == 1 and "2 of 8 expert passes" in warned[0]
+    assert engine.moe_rows == {"local": 2000, "bound": 4096, "walked": 3072}

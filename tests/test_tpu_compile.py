@@ -17,6 +17,7 @@ compiled the same way by a scratch script, not here (its set-up
 materialises 0.5B parameters).
 """
 
+import functools
 import json
 import os
 import re
@@ -78,6 +79,14 @@ EXPERT_PARENT = {"mellum": (1.8047, 236), "mellum-6016": (1.9004, None),
 # source is therefore padded to whole row tiles (moe._whole_row_tiles),
 # with which every multiple of 128 up to 4096 compiles (PERF.md §6, PR 34).
 LATENT_T = (3072, 3456, 3712)
+# One expert layer, forward + backward, whose bounded pass adds only its
+# live rows (moe._add_live: a loop with a traced trip count) at rows no
+# whole program above holds: the Trinity cell's two grids, and the
+# [12288, 2304] source at which XLA's row gather of a bound ran out of
+# scoped vmem before (ROADMAP S10 (g)).
+WALK_LAYERS = {"trinity-8832": ("trinity-mini", 8832),
+               "trinity-11776": ("trinity-mini", 11776),
+               "mellum-12288": ("mellum2-12b-a2.5b", 12288)}
 # The phi4flash (SambaY) cell: its selective scan (rows, length, d_inner,
 # states) and its packed grid, at the published widths.
 SAMBAY_SCAN = (1, 8192, 5120, 16)
@@ -366,9 +375,9 @@ def _compile_all():
     lp = {k: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
           for k, shape in moemod.moe_param_shapes(hybrid).items()}
 
-    def latent_grad(lp, x, valid):
+    def layer_grad(lp, x, valid, cfg=hybrid):
         def loss(lp, x):
-            y, _ = moemod.moe_mlp(x, lp, hybrid.moe, mask=valid > 0,
+            y, _ = moemod.moe_mlp(x, lp, cfg.moe, mask=valid > 0,
                                   impl="pallas")
             return jnp.sum(y.astype(jnp.float32) ** 2)
 
@@ -379,7 +388,7 @@ def _compile_all():
                                  sharding=chip)
         valid = jax.ShapeDtypeStruct((1, T), jnp.int32, sharding=chip)
         record(f"latent-{T}",
-               jax.jit(latent_grad).lower(lp, x, valid).compile())
+               jax.jit(layer_grad).lower(lp, x, valid).compile())
         M = T * hybrid.moe.top_k
         forward = jax.jit(lambda lp, x, valid: moemod.moe_mlp(
             x, lp, hybrid.moe, mask=valid > 0, impl="pallas")[0]).lower(
@@ -399,6 +408,23 @@ def _compile_all():
     out["latent-gemms"] = {"%dx%dx%d/%d" % key: how
                            for key, how in moemod.gemm_counts().items()
                            if key[-1] == hybrid.moe.num_experts}
+
+    for name, (config, T) in WALK_LAYERS.items():
+        with open(os.path.join(REPO, "benchmark", "configs",
+                               config + ".json")) as f:
+            cfg = weights.model_config(json.load(f))
+        lp = {k: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+              for k, shape in moemod.moe_param_shapes(cfg).items()}
+        x = jax.ShapeDtypeStruct((1, T, cfg.hidden_dim), jnp.bfloat16,
+                                 sharding=chip)
+        valid = jax.ShapeDtypeStruct((1, T), jnp.int32, sharding=chip)
+        compiled = jax.jit(functools.partial(layer_grad, cfg=cfg)).lower(
+            lp, x, valid).compile()
+        record(f"walk-{name}", compiled)
+        out[f"walk-{name}"].update(
+            rows=moemod.sorted_rows(T * cfg.moe.top_k, cfg.moe.num_experts,
+                                    cfg.moe.n_routed),
+            whiles=len(re.findall(r" while\(", compiled.as_text())))
 
     # The phi4flash (SambaY) cell at the published widths: the selective
     # scan's kernels alone, forward + backward, and the whole six-layer
@@ -655,6 +681,23 @@ def test_a_latent_expert_layer_compiles_at_the_hybrid_cells_rows(compiled,
     # width only the whole-buffer branch's row gather — the parent sorted
     # twice (the inverse permutation) and un-permuted [22 x T, 1024] too
     assert got["entry_sorts"] == 1 and got["entry_gathers"] == 1
+
+
+@pytest.mark.parametrize("name", sorted(WALK_LAYERS))
+def test_an_expert_layers_live_walk_compiles_for_v5e(compiled, name):
+    """Forward + backward of one expert layer of the Trinity cell at its
+    two grids' rows, and of Mellum's at the 12,288 tokens whose row gather
+    XLA refused once ("ran out of scoped vmem"): each branch's combine,
+    forward and re-run, and the transpose of its row gather are loops over
+    the live rows (three a branch: 6 loops at least), the bounded branch's
+    GEMMs the Pallas kernel."""
+    got = compiled[f"walk-{name}"]
+    config, T = WALK_LAYERS[name]
+    # twice the held share of the ``8 x T`` entries, in row tiles of 512
+    share = {"trinity-mini": 1, "mellum2-12b-a2.5b": 4}[config]
+    assert got["rows"] == -(-share * T // 512) * 512 < 8 * T
+    assert got["whiles"] >= 6
+    assert got["gmm_calls"] > 0 and got["ragged_dot_calls"] > 0
 
 
 def test_the_selective_scan_kernels_compile_for_v5e(compiled):
