@@ -735,8 +735,10 @@ class JaxTrainEngine(TrainableEngine):
         [R, L]: its rows split over the data axes (an axis that splits
         the sequence or the widths is not counted: an over-estimate)."""
         rows = self._rows_on_chip(R)
-        full = kernel_padded_len(self.attn_impl, L)
-        window = kernel_padded_len(self.attn_impl, L, self.cfg.sliding_window)
+        full = kernel_padded_len(self.attn_impl, L,
+                                 head_dim=self.cfg.head_dim)
+        window = kernel_padded_len(self.attn_impl, L, self.cfg.sliding_window,
+                                   self.cfg.head_dim)
         return transformer.remat_kept_bytes(
             self.cfg, rows * L, self.compute_dtype.itemsize,
             full_tokens=rows * (full or 0),
@@ -908,7 +910,8 @@ class JaxTrainEngine(TrainableEngine):
         needed = static = 0
         for window, layers in windows.items():
             for seg in grids:
-                n, s = window_attention.count_needed(seg, window)
+                n, s = window_attention.count_needed(seg, window,
+                                                     self.cfg.head_dim)
                 needed += layers * n
                 static += layers * s
         telemetry.set_gauge(f"{role}/attn_blocks_needed_frac",
@@ -925,6 +928,10 @@ class JaxTrainEngine(TrainableEngine):
             )
             telemetry.set_gauge("train/pack_fill", mbu.pack_fill(mbs))
             telemetry.set_gauge("train/docs_per_row", mbu.docs_per_row(mbs))
+            if self.cfg.gdn is not None:
+                telemetry.set_gauge(
+                    "train/gdn_resets_in_chunk_per_row",
+                    mbu.resets_in_chunk_per_row(mbs, self.cfg.gdn.chunk_size))
             self._gauge_blocks_needed("train", mbs)
         R, L = mbs[0].layout.shape
         pp_on, ring_on = ppl.pp_engagement(self.mesh, self.cfg, R, L)

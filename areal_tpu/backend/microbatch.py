@@ -206,6 +206,20 @@ def docs_per_row(mbs: List[MicroBatch]) -> float:
     return (docs / rows) if rows else 0.0
 
 
+def resets_in_chunk_per_row(mbs: List[MicroBatch], chunk: int) -> float:
+    """Document starts that fall INSIDE a chunk of ``chunk`` tokens (off
+    the chunk grid, the row's first document not counted) over the rows
+    that hold any document, of a micro-batch split: how often a chunk of
+    the gated delta rule masks its triangular matrices and the state it
+    reads. Exported as the ``train/gdn_resets_in_chunk_per_row`` gauge."""
+    from areal_tpu.models.gdn import resets_in_chunk
+
+    inside = sum(resets_in_chunk((col for _, col in mb.layout.placements),
+                                 mb.layout.shape[1], chunk) for mb in mbs)
+    rows = sum(len({row for row, _ in mb.layout.placements}) for mb in mbs)
+    return (inside / rows) if rows else 0.0
+
+
 def make_microbatch(
     sample: SequenceSample,
     token_key: str = "packed_input_ids",

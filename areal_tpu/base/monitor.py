@@ -11,6 +11,7 @@ gracefully to tensorboard-only (wandb is optional on pods).
 from __future__ import annotations
 
 import json
+import math
 from typing import Any, Dict, Optional
 
 # bf16 peak FLOP/s of one chip, keyed by the EXACT ``device_kind`` jax
@@ -142,13 +143,33 @@ def train_flops_6nt(n_params: float, n_tokens: float) -> float:
 def model_flops_per_token(
     cfg, avg_seqlen: float, backward: bool = True, remat: bool = False
 ) -> float:
-    """FLOPs/token from a models.config.TransformerConfig."""
-    return transformer_flops_per_token(
+    """FLOPs/token from a models.config.TransformerConfig. A Gated
+    DeltaNet block's mixer is counted in place of attention's: its three
+    projections, and the rule in chunks of Q tokens a value head — the two
+    [Q, Q] products and the inverse's 2 log2(Q) - 1 of them inside a
+    chunk, the two triangular applications and the five products against
+    the carried state."""
+    flops = transformer_flops_per_token(
         cfg.n_layers, cfg.hidden_dim, cfg.q_dim, cfg.kv_dim,
         cfg.intermediate_dim, 1 if cfg.is_critic else cfg.vocab_size,
         avg_seqlen, backward=backward, remat=remat,
         moe=getattr(cfg, "moe", None),
     )
+    gdn = getattr(cfg, "gdn", None)
+    n_gdn = cfg.layer_kinds.count("gdn") if gdn is not None else 0
+    if not n_gdn:
+        return flops
+    d, Q = cfg.hidden_dim, gdn.chunk_size
+    dk, dv = gdn.k_head_dim, gdn.v_head_dim
+    attention = (2 * d * (cfg.q_dim + 2 * cfg.kv_dim) + 2 * cfg.q_dim * d
+                 + 2 * 2 * cfg.q_dim * avg_seqlen)
+    rule = (gdn.n_k_heads * 2 * 2 * Q * dk + gdn.n_v_heads * 2 * (
+        (2 * math.log2(Q) - 1) * Q * Q + Q * (dk + dv) + dk * dv * 3
+        + Q * dv))
+    mixer = (2 * d * (gdn.qkvz_dim + gdn.ba_dim) + 2 * gdn.value_dim * d
+             + rule)
+    factor = 1.0 if not backward else 4.0 if remat else 3.0
+    return flops + n_gdn * (mixer - attention) * factor
 
 
 class FlopsCounter:

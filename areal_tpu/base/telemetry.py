@@ -296,6 +296,14 @@ SAMBAY_SCOPES = ("s6_in_proj", "s6_conv", "s6_xdt_proj", "s6_scan",
 # Inside "moe", beside MOE_SCOPES: the two projections around experts that
 # work in a latent width, and the shared expert (models/moe.py).
 LATENT_MOE_SCOPES = ("latent_down", "latent_up", "shared_expert")
+# A Gated DeltaNet mixer (qwen3_next; models/gdn.py), one scope a stage:
+# the two in-projections, the convolution with its SiLU, the gates (beta,
+# g, the l2 norms of q and k), the gated delta rule, the gated output norm
+# and the out-projection; no DEVICE_SCOPES name lies between them and
+# "layer_scan". "shared_expert_gate" is the sigmoid gate of that family's
+# shared expert, inside "shared_expert".
+GDN_SCOPES = ("gdn_in_proj", "gdn_conv", "gdn_gates", "gdn_rule",
+              "gdn_gate_norm", "gdn_out_proj", "shared_expert_gate")
 
 
 def _annotation(name: str, attrs: Dict[str, Any]):

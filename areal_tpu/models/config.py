@@ -32,9 +32,16 @@ SAMBAY_KINDS = (S6, GMU, CROSS)
 # alone, then the dense MLP (granitemoehybrid's ``mamba`` layers, beside
 # FULL blocks without a position embedding).
 SSD = "ssd"
+# A whole block whose mixer is a Gated DeltaNet linear-attention mixer
+# (models/gdn.py), then the model's FFN — the expert layer where it has
+# one (qwen3_next's ``linear_attention`` layers, beside FULL blocks).
+GDN = "gdn"
 # Whole blocks whose mixer is not self-attention: no K/V cache decodes
 # them, and their parameter shapes are their kind's own.
-BLOCK_MIXER_KINDS = SAMBAY_KINDS + (SSD,)
+BLOCK_MIXER_KINDS = SAMBAY_KINDS + (SSD, GDN)
+# Of those, the blocks that run no attention at all: no q/k/v/o, no q/k
+# norm, no attention gate (a CROSS block still has q and o).
+ATTENTION_FREE_KINDS = (S6, GMU, SSD, GDN)
 # What a layer hands on to later layers, by the name the readers ask for.
 MEMORY, SHARED_KV = "memory", "kv"
 # A whole block's FFN kind (HF ``mlp_layer_types``): the model's dense MLP
@@ -85,6 +92,9 @@ class MoEConfig:
     z_loss_coeff: float = 0.0
     input_jitter_eps: float = 0.0
     norm_topk_prob: bool = True
+    # The shared expert's output times ``sigmoid(x · s_sig)``, a gate a
+    # token (qwen2_moe, qwen3_next: ``shared_expert_gate`` [D, 1]).
+    shared_expert_gate: bool = False
     # What the router makes of its logits — a property of the family, set
     # by the HF mapping: "softmax" (gates are the top-k probabilities), or
     # "sigmoid" (nemotron_h, deepseek-v3: each expert's score is a sigmoid
@@ -145,6 +155,46 @@ class SSMConfig:
     def in_proj_dim(self) -> int:
         """[z | xBC | dt]."""
         return self.d_inner + self.conv_dim + self.n_heads
+
+
+@dataclasses.dataclass(frozen=True)
+class GDNConfig:
+    """A Gated DeltaNet mixer's sizes (HF qwen3_next keys
+    ``linear_num_key_heads``, ``linear_num_value_heads``,
+    ``linear_key_head_dim``, ``linear_value_head_dim``,
+    ``linear_conv_kernel_dim``). Value head ``i`` reads key head ``i //
+    (n_v_heads / n_k_heads)``; the rule runs in chunks of ``chunk_size``
+    tokens (the program's choice: no key of the model)."""
+
+    n_k_heads: int
+    n_v_heads: int
+    k_head_dim: int
+    v_head_dim: int
+    conv_kernel: int = 4
+    chunk_size: int = 64
+
+    @property
+    def key_dim(self) -> int:
+        return self.n_k_heads * self.k_head_dim
+
+    @property
+    def value_dim(self) -> int:
+        return self.n_v_heads * self.v_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the depthwise convolution runs over: q, k and v."""
+        return 2 * self.key_dim + self.value_dim
+
+    @property
+    def qkvz_dim(self) -> int:
+        """[q | k | v | z] (by key head in HF's layout)."""
+        return 2 * self.key_dim + 2 * self.value_dim
+
+    @property
+    def ba_dim(self) -> int:
+        """[b | a], one of each a value head."""
+        return 2 * self.n_v_heads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -216,6 +266,7 @@ class TransformerConfig:
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None  # the MAMBA layers' / SSD blocks' mixer
     s6: Optional[S6Config] = None  # the S6 blocks' mixer (GMU reads its width)
+    gdn: Optional[GDNConfig] = None  # the GDN blocks' mixer
     # sliding window attention (mistral/gemma2); None = full attention
     sliding_window: Optional[int] = None
     # The kind of each layer: FULL or SLIDING (HF ``layer_types``), or one
@@ -234,6 +285,14 @@ class TransformerConfig:
     # Gated attention (afmoe): a fifth projection ``wg`` [D, q_dim]; the
     # attention output is multiplied by sigmoid(x wg) before ``wo``.
     gated_attention: bool = False
+    # RoPE on the first ``partial_rotary_factor`` of each head's dims
+    # (rotate-half inside them), the others untouched (qwen3_next: 0.25).
+    partial_rotary_factor: float = 1.0
+    # Every RMSNorm weight of the model — the residual norms, the final
+    # norm, the per-head q/k norms — is ZERO-CENTRED: ``x̂ (1 + w)``, ``w``
+    # drawn 0 (qwen3_next; the GDN mixer's gated output norm alone is a
+    # plain ``x̂ w``).
+    zero_centered_norm: bool = False
     # Differential attention (phi4flash): q's heads are pairs (2p, 2p+1),
     # k's likewise, v's pairs one value of twice the head size; a pair's
     # output is softmax(q1 k1) v - lambda * softmax(q2 k2) v, RMS-normed
@@ -291,6 +350,11 @@ class TransformerConfig:
     @property
     def k_norm_dim(self) -> int:
         return self.kv_dim if self.qk_norm_extent == "proj" else self.head_dim
+
+    @property
+    def rotary_dim(self) -> int:
+        """Dims of a head that RoPE turns (the table's width)."""
+        return int(self.head_dim * self.partial_rotary_factor)
 
     @property
     def group_size(self) -> int:

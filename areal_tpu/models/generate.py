@@ -21,8 +21,8 @@ import numpy as np
 
 from areal_tpu.api.model import GenerationHyperparameters
 from areal_tpu.models.config import TransformerConfig
+from areal_tpu.models import transformer
 from areal_tpu.models.transformer import (
-    DECODE_REFUSAL,
     forward,
     init_kv_cache,
     kv_valid_by_kind,
@@ -39,9 +39,10 @@ def decode_refusal(cfg: TransformerConfig) -> Optional[str]:
     (``transformer.DECODE_REFUSAL``), or None: a layer that is one mixer
     alone — a state-space layer among them — a selective-scan block, or
     a layer that reads another layer's memory or K/V has no cache to
-    decode from. Every entry point below prefills through ``forward``,
-    which raises it."""
-    return DECODE_REFUSAL if cfg.has_cacheless_layers else None
+    decode from; a Gated DeltaNet block its own name (its state is a
+    recurrent matrix and its convolution's last taps: ``gdn.DECODE_REFUSAL``).
+    Every entry point below prefills through ``forward``, which raises it."""
+    return transformer.decode_refusal(cfg)
 
 
 @partial(

@@ -31,6 +31,7 @@ from areal_tpu.models.config import (
     CROSS,
     DENSE_FFN,
     FULL,
+    GDN,
     GMU,
     MAMBA,
     MOE_ONLY,
@@ -38,6 +39,7 @@ from areal_tpu.models.config import (
     SLIDING,
     SPARSE_FFN,
     SSD,
+    GDNConfig,
     MoEConfig,
     RopeConfig,
     S6Config,
@@ -563,6 +565,94 @@ def _granitemoehybrid_config(hf_config: Any) -> TransformerConfig:
         if getattr(hf_config, "attention_multiplier", None) else None,
         logits_scaling=float(getattr(hf_config, "logits_scaling", 1.0)),
         hf_family="granitemoehybrid",
+    )
+
+
+# qwen3_next: HF ``layer_types`` to the block kinds.
+_QWEN3_NEXT_LAYER_TYPES = {"linear_attention": GDN, "full_attention": FULL}
+# Keys of the family that no block here runs, by name: (key, the value
+# that is run, why any other is refused).
+QWEN3_NEXT_REFUSALS = (
+    ("mlp_only_layers", [], "dense_mlp_blocks: blocks whose FFN is a dense "
+     "MLP beside expert blocks (mlp_only_layers) are not read for this "
+     "family"),
+    ("decoder_sparse_step", 1, "dense_mlp_blocks: an expert layer every "
+     "decoder_sparse_step-th block only, dense MLPs between"),
+    ("rope_scaling", None, "rope_scaling: a scaled RoPE under partial "
+     "rotary"),
+    ("attention_bias", False, "attention_bias: biases on q/k/v/o"),
+    ("use_sliding_window", False, "use_sliding_window: a window on the "
+     "full-attention blocks"),
+    ("hidden_act", "silu", "hidden_act: experts that are not SwiGLU"),
+)
+
+
+@register_hf_family("qwen3_next")
+def _qwen3_next_config(hf_config: Any) -> TransformerConfig:
+    """Qwen3-Next (``Qwen3NextForCausalLM``): whole blocks under
+    zero-centred RMSNorms (``x̂ (1 + w)``), the mixer by ``layer_types``
+    (else full attention every ``full_attention_interval``-th block) —
+    ``linear_attention`` a Gated DeltaNet mixer (models/gdn.py),
+    ``full_attention`` GQA gated by ``sigmoid`` of a second half of
+    ``q_proj``, q/k normed a head, RoPE on the first
+    ``partial_rotary_factor`` of each head's dims — then in EVERY block
+    an expert layer: softmax scores, the top ``num_experts_per_tok``
+    renormalised, no token dropped, beside one shared expert scaled a
+    token by ``sigmoid(x · shared_expert_gate)``. The multi-token-
+    prediction head (``mtp.*``) is not read. A SHARE holds ``num_experts``
+    of ``num_routed_experts`` (:func:`_expert_share`); a config cut in
+    depth keeps ``num_hidden_layers`` layers from this repo's key
+    ``first_layer_index`` on."""
+    for key, run, why in QWEN3_NEXT_REFUSALS:
+        if getattr(hf_config, key, None) not in (None, run):
+            raise NotImplementedError(why)
+    kw = _base_kwargs(hf_config)
+    n = kw["n_layers"]
+    first = int(getattr(hf_config, "first_layer_index", 0) or 0)
+    types = getattr(hf_config, "layer_types", None)
+    if types is None:
+        every = int(getattr(hf_config, "full_attention_interval", 4))
+        types = ["full_attention" if (i + 1) % every == 0
+                 else "linear_attention" for i in range(first + n)]
+    types = tuple(types)[first:first + n]
+    if len(types) != n or set(types) - set(_QWEN3_NEXT_LAYER_TYPES):
+        raise NotImplementedError(
+            f"layer_types {types!r} for {n} layers "
+            f"({sorted(_QWEN3_NEXT_LAYER_TYPES)} are supported)")
+    held = hf_config.num_experts
+    routed, first_expert = _expert_share(hf_config, held)
+    shared = getattr(hf_config, "shared_expert_intermediate_size", None) or None
+    return TransformerConfig(
+        **kw,
+        layer_types=tuple(_QWEN3_NEXT_LAYER_TYPES[t] for t in types),
+        first_layer_index=first,
+        use_qk_norm=True,
+        gated_attention=True,
+        zero_centered_norm=True,
+        partial_rotary_factor=float(
+            getattr(hf_config, "partial_rotary_factor", 1.0)),
+        max_position_embeddings=getattr(
+            hf_config, "max_position_embeddings", None),
+        gdn=GDNConfig(
+            n_k_heads=hf_config.linear_num_key_heads,
+            n_v_heads=hf_config.linear_num_value_heads,
+            k_head_dim=hf_config.linear_key_head_dim,
+            v_head_dim=hf_config.linear_value_head_dim,
+            conv_kernel=hf_config.linear_conv_kernel_dim,
+        ),
+        moe=MoEConfig(
+            num_experts=held,
+            top_k=hf_config.num_experts_per_tok,
+            capacity_factor=None,
+            routed_intermediate_dim=hf_config.moe_intermediate_size,
+            shared_intermediate_dim=shared,
+            shared_expert_gate=shared is not None,
+            aux_loss_coeff=getattr(hf_config, "router_aux_loss_coef", 1e-3),
+            norm_topk_prob=getattr(hf_config, "norm_topk_prob", True),
+            router_experts=routed,
+            first_expert=first_expert,
+        ),
+        hf_family="qwen3_next",
     )
 
 
@@ -1145,6 +1235,140 @@ def _granitemoehybrid_from_sd(
     return out
 
 
+# qwen3_next: (pytree key, HF name under ``model.layers.{i}.``, transpose).
+# A block holds the leaves of its kind. Three HF matrices are INTERLEAVED
+# and are split and joined below: ``linear_attn.in_proj_qkvz`` and
+# ``in_proj_ba`` by KEY head (:func:`_gdn_split` / :func:`_gdn_join`), and
+# ``self_attn.q_proj`` by head, ``[q_h | gate_h]`` (``wq`` / ``wg``); the
+# depthwise convolution is ``[channels, 1, K]``.
+_QWEN3_NEXT_NAMES = [
+    ("ln1", "input_layernorm.weight", False),
+    ("ln2", "post_attention_layernorm.weight", False),
+    ("gdn_dt_bias", "linear_attn.dt_bias", False),
+    ("gdn_A_log", "linear_attn.A_log", False),
+    ("gdn_norm", "linear_attn.norm.weight", False),
+    ("gdn_out", "linear_attn.out_proj.weight", True),
+    ("wk", "self_attn.k_proj.weight", True),
+    ("wv", "self_attn.v_proj.weight", True),
+    ("wo", "self_attn.o_proj.weight", True),
+    ("q_norm", "self_attn.q_norm.weight", False),
+    ("k_norm", "self_attn.k_norm.weight", False),
+    ("router", "mlp.gate.weight", True),
+    ("s_gate", "mlp.shared_expert.gate_proj.weight", True),
+    ("s_up", "mlp.shared_expert.up_proj.weight", True),
+    ("s_down", "mlp.shared_expert.down_proj.weight", True),
+    ("s_sig", "mlp.shared_expert_gate.weight", True),
+]
+
+
+def _gdn_split(gdn: GDNConfig, qkvz: np.ndarray, ba: np.ndarray):
+    """HF's ``in_proj_qkvz`` [2 G dk + 2 H dv, D] and ``in_proj_ba`` [2 H,
+    D], whose rows go BY KEY HEAD j — ``[q_j | k_j | v of its r value
+    heads | z of them]`` and ``[b of its r value heads | a of them]`` — to
+    this repo's ``gdn_qkvz`` [D, q | k | v | z] and ``gdn_ba`` [D, b | a]."""
+    G, dk = gdn.n_k_heads, gdn.k_head_dim
+    rv = gdn.value_dim // G  # a key head's r value heads' channels
+    r = gdn.n_v_heads // G
+    D = qkvz.shape[-1]
+    by_head = qkvz.reshape(G, 2 * dk + 2 * rv, D)
+    parts = np.split(by_head, [dk, 2 * dk, 2 * dk + rv], axis=1)
+    ba_head = ba.reshape(G, 2 * r, D)
+    return (np.concatenate([p.reshape(-1, D) for p in parts]).T,
+            np.concatenate([ba_head[:, :r].reshape(-1, D),
+                            ba_head[:, r:].reshape(-1, D)]).T)
+
+
+def _gdn_join(gdn: GDNConfig, qkvz: np.ndarray, ba: np.ndarray):
+    """The inverse of :func:`_gdn_split`."""
+    G, dk = gdn.n_k_heads, gdn.k_head_dim
+    rv, r = gdn.value_dim // G, gdn.n_v_heads // G
+    D = qkvz.shape[0]
+    q, k, v, z = np.split(qkvz.T, [G * dk, 2 * G * dk,
+                                   2 * G * dk + gdn.value_dim])
+    b, a = np.split(ba.T, 2)
+    return (np.concatenate([q.reshape(G, dk, D), k.reshape(G, dk, D),
+                            v.reshape(G, rv, D), z.reshape(G, rv, D)],
+                           axis=1).reshape(-1, D),
+            np.concatenate([b.reshape(G, r, D), a.reshape(G, r, D)],
+                           axis=1).reshape(-1, D))
+
+
+def _qwen3_next_to_sd(
+    params: Dict[str, Any], cfg: TransformerConfig
+) -> Dict[str, np.ndarray]:
+    sd = {
+        "model.embed_tokens.weight": np.asarray(params["embedding"]),
+        "model.norm.weight": np.asarray(params["final_ln"]),
+        "lm_head.weight": np.asarray(params["lm_head"]).T,
+    }
+    H, dh = cfg.n_q_heads, cfg.head_dim
+    for i, kind, lp in _layers_in_order(params, cfg):
+        pre = f"model.layers.{i}."
+        for key, name, tr in _QWEN3_NEXT_NAMES:
+            if key in lp:
+                sd[pre + name] = lp[key].T if tr else lp[key]
+        for key, name in _AFMOE_EXPERTS.items():
+            for e in range(cfg.moe.num_experts):
+                sd[pre + name.format(e=e)] = lp[key][e].T
+        if kind == GDN:
+            qkvz, ba = _gdn_join(cfg.gdn, lp["gdn_qkvz"], lp["gdn_ba"])
+            sd[pre + "linear_attn.in_proj_qkvz.weight"] = qkvz
+            sd[pre + "linear_attn.in_proj_ba.weight"] = ba
+            sd[pre + "linear_attn.conv1d.weight"] = lp["gdn_conv"].T[
+                :, None, :]
+        else:  # rows by head: [q_h | gate_h]
+            D = lp["wq"].shape[0]
+            sd[pre + "self_attn.q_proj.weight"] = np.concatenate(
+                [lp["wq"].T.reshape(H, dh, D), lp["wg"].T.reshape(H, dh, D)],
+                axis=1).reshape(-1, D)
+    return sd
+
+
+def _qwen3_next_from_sd(
+    sd: Dict[str, Any], cfg: TransformerConfig, dtype: str
+) -> Dict[str, Any]:
+    mtp = sorted(k for k in sd if k.startswith("mtp."))
+    if mtp:
+        logger.warning("qwen3_next: %d multi-token-prediction tensors "
+                       "(mtp.*) are not read", len(mtp))
+    per_kind: Dict[str, Dict[str, list]] = {}
+    H, dh = cfg.n_q_heads, cfg.head_dim
+    for i, kind in enumerate(cfg.layer_kinds):
+        pre = f"model.layers.{cfg.first_layer_index + i}."
+        if pre + "input_layernorm.weight" not in sd:
+            pre = f"model.layers.{i}."
+        lp = per_kind.setdefault(kind, {})
+        for key, name, tr in _QWEN3_NEXT_NAMES:
+            if pre + name in sd:
+                w = _np(sd[pre + name])
+                lp.setdefault(key, []).append(w.T if tr else w)
+        for key, name in _AFMOE_EXPERTS.items():
+            lp.setdefault(key, []).append(np.stack([
+                _np(sd[pre + name.format(e=e)]).T
+                for e in range(cfg.moe.num_experts)]))
+        if kind == GDN:
+            qkvz, ba = _gdn_split(
+                cfg.gdn, _np(sd[pre + "linear_attn.in_proj_qkvz.weight"]),
+                _np(sd[pre + "linear_attn.in_proj_ba.weight"]))
+            lp.setdefault("gdn_qkvz", []).append(qkvz)
+            lp.setdefault("gdn_ba", []).append(ba)
+            lp.setdefault("gdn_conv", []).append(
+                _np(sd[pre + "linear_attn.conv1d.weight"])[:, 0, :].T)
+        else:
+            qg = _np(sd[pre + "self_attn.q_proj.weight"])
+            qg = qg.reshape(H, 2, dh, qg.shape[-1])
+            lp.setdefault("wq", []).append(qg[:, 0].reshape(H * dh, -1).T)
+            lp.setdefault("wg", []).append(qg[:, 1].reshape(H * dh, -1).T)
+    return {
+        "embedding": _np(sd["model.embed_tokens.weight"]).astype(dtype),
+        "layers": {kind: {k: np.stack(v).astype(dtype)
+                          for k, v in lp.items()}
+                   for kind, lp in per_kind.items()},
+        "final_ln": _np(sd["model.norm.weight"]).astype(dtype),
+        "lm_head": _np(sd["lm_head.weight"]).T.astype(dtype),
+    }
+
+
 def params_from_hf_state_dict(
     sd: Dict[str, Any], cfg: TransformerConfig, dtype: str = "float32"
 ) -> Dict[str, Any]:
@@ -1159,6 +1383,8 @@ def params_from_hf_state_dict(
         return _phi4flash_from_sd(sd, cfg, dtype)
     if cfg.hf_family == "granitemoehybrid":
         return _granitemoehybrid_from_sd(sd, cfg, dtype)
+    if cfg.hf_family == "qwen3_next":
+        return _qwen3_next_from_sd(sd, cfg, dtype)
     return _llama_from_sd(sd, cfg, dtype)
 
 
@@ -1176,6 +1402,8 @@ def params_to_hf_state_dict(
         return _phi4flash_to_sd(params, cfg)
     if cfg.hf_family == "granitemoehybrid":
         return _granitemoehybrid_to_sd(params, cfg)
+    if cfg.hf_family == "qwen3_next":
+        return _qwen3_next_to_sd(params, cfg)
     return _llama_to_sd(params, cfg)
 
 
@@ -1196,6 +1424,7 @@ _HF_ARCH = {
     "afmoe": "AfmoeForCausalLM",
     "phi4flash": "Phi4FlashForCausalLM",
     "granitemoehybrid": "GraniteMoeHybridForCausalLM",
+    "qwen3_next": "Qwen3NextForCausalLM",
 }
 
 
@@ -1225,6 +1454,8 @@ def hf_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
         return _phi4flash_config_dict(cfg)
     if fam == "granitemoehybrid":
         return _granitemoehybrid_config_dict(cfg)
+    if fam == "qwen3_next":
+        return _qwen3_next_config_dict(cfg)
     d: Dict[str, Any] = {
         "model_type": fam,
         "architectures": [_HF_ARCH.get(fam, "LlamaForCausalLM")],
@@ -1419,6 +1650,56 @@ def _granitemoehybrid_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
         "logits_scaling": cfg.logits_scaling,
         "torch_dtype": "float32",
     }
+
+
+def _qwen3_next_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
+    """The inverse of :func:`_qwen3_next_config`."""
+    gdn, moe = cfg.gdn, cfg.moe
+    names = {kind: t for t, kind in _QWEN3_NEXT_LAYER_TYPES.items()}
+    full = [i + 1 for i, k in enumerate(cfg.layer_kinds) if k == FULL]
+    d = {
+        "model_type": "qwen3_next",
+        "architectures": [_HF_ARCH["qwen3_next"]],
+        "num_hidden_layers": cfg.n_layers,
+        "layer_types": [names[k] for k in cfg.layer_kinds],
+        "full_attention_interval": full[0] if full else cfg.n_layers + 1,
+        "hidden_size": cfg.hidden_dim,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "head_dim": cfg.head_dim,
+        "attention_bias": False,
+        "partial_rotary_factor": cfg.partial_rotary_factor,
+        "rope_theta": cfg.rotary_base,
+        "rope_scaling": None,
+        "intermediate_size": cfg.intermediate_dim,
+        "hidden_act": "silu",
+        "vocab_size": cfg.vocab_size,
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "tie_word_embeddings": cfg.tie_word_embeddings,
+        "max_position_embeddings": cfg.max_position_embeddings or 262144,
+        "use_sliding_window": False,
+        "linear_num_key_heads": gdn.n_k_heads,
+        "linear_num_value_heads": gdn.n_v_heads,
+        "linear_key_head_dim": gdn.k_head_dim,
+        "linear_value_head_dim": gdn.v_head_dim,
+        "linear_conv_kernel_dim": gdn.conv_kernel,
+        "decoder_sparse_step": 1,
+        "mlp_only_layers": [],
+        "moe_intermediate_size": moe.routed_intermediate_dim,
+        "shared_expert_intermediate_size": moe.shared_intermediate_dim,
+        "num_experts": moe.num_experts,
+        "num_experts_per_tok": moe.top_k,
+        "norm_topk_prob": moe.norm_topk_prob,
+        "router_aux_loss_coef": moe.aux_loss_coeff,
+        "torch_dtype": "float32",
+    }
+    if cfg.first_layer_index:
+        d["first_layer_index"] = cfg.first_layer_index
+    if moe.is_share:
+        d["num_routed_experts"] = moe.n_routed
+        d["expert_shard_count"] = moe.n_routed // moe.num_experts
+        d["expert_shard_index"] = moe.first_expert // moe.num_experts
+    return d
 
 
 def _afmoe_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:

@@ -78,7 +78,9 @@ Parity target: ``realhf/impl/model/modules/moe/`` — ``TopKRouter``
 
 Weights per layer (stacked on the leading layer axis by the transformer):
 ``router [D, E]``, ``e_gate/e_up [E, D, F]``, ``e_down [E, F, D]``, and an
-optional always-on shared expert ``s_gate/s_up [D, Fs]``, ``s_down [Fs, D]``.
+optional always-on shared expert ``s_gate/s_up [D, Fs]``, ``s_down [Fs, D]``
+(its output times ``sigmoid(x · s_sig)``, ``s_sig [D, 1]``, where the
+family gates it a token: ``MoEConfig.shared_expert_gate``).
 
 Device scopes inside the transformer's ``moe`` scope
 (base/telemetry.MOE_SCOPES): ``moe_router`` (matmul, softmax, top-k, the
@@ -1061,7 +1063,14 @@ def moe_mlp(
                 s_h = act(xf @ lp["s_gate"]) * (xf @ lp["s_up"])
             else:
                 s_h = act(xf @ lp["s_up"])
-            y = y + s_h @ lp["s_down"]
+            s_out = s_h @ lp["s_down"]
+            if "s_sig" in lp:  # times a sigmoid gate a token (qwen3_next)
+                with jax.named_scope("shared_expert_gate"):
+                    gate = jax.nn.sigmoid(
+                        (xf @ lp["s_sig"]).astype(jnp.float32))
+                    s_out = (s_out.astype(jnp.float32) * gate
+                             ).astype(s_out.dtype)
+            y = y + s_out
 
     aux = dict(aux, **extra)
     aux["dropped_frac"] = dropped_frac
@@ -1095,6 +1104,8 @@ def moe_param_shapes(cfg) -> Dict[str, Tuple[int, ...]]:
             shapes["s_gate"] = (d, fs)
         shapes["s_up"] = (d, fs)
         shapes["s_down"] = (fs, d)
+        if moe.shared_expert_gate:
+            shapes["s_sig"] = (d, 1)
     return shapes
 
 

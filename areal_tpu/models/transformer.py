@@ -34,7 +34,9 @@ but mellum and nemotron_h. Two sorts of pattern:
    ...]}}``, and a period's layers slice their kind's stack. So is a
    model whose whole blocks differ in their MIXER (phi4flash's S6 / GMU /
    CROSS blocks; granitemoehybrid's SSD blocks — the Mamba-2 mixer, then
-   the dense MLP — beside FULL ones).
+   the dense MLP — beside FULL ones; qwen3_next's GDN blocks — a Gated
+   DeltaNet mixer, models/gdn.py, then the expert layer — beside FULL
+   ones).
 """
 
 from __future__ import annotations
@@ -46,9 +48,11 @@ import jax
 import jax.numpy as jnp
 
 from areal_tpu.models.config import (
+    ATTENTION_FREE_KINDS,
     ATTENTION_ONLY,
     CROSS,
     FULL,
+    GDN,
     GMU,
     MAMBA,
     MEMORY,
@@ -131,14 +135,15 @@ def _init_block_layers(cfg: TransformerConfig, n: int, keys, dtype,
     ``dense_ffn``."""
     d = cfg.hidden_dim
     qd, kvd, f = cfg.q_dim, cfg.kv_dim, cfg.intermediate_dim
-    attends = kind not in (S6, GMU, SSD)
+    attends = kind not in ATTENTION_FREE_KINDS
+    one = _norm_init(cfg)
 
     def nrm(k, shape, scale=0.02):
         return (jax.random.normal(k, shape) * scale).astype(dtype)
 
     layers: Dict[str, jnp.ndarray] = {
-        "ln1": jnp.ones((n, d), dtype),
-        "ln2": jnp.ones((n, d), dtype),
+        "ln1": one((n, d), dtype),
+        "ln2": one((n, d), dtype),
     }
     if kind == S6:
         from areal_tpu.models import ssm as ssmmod
@@ -148,6 +153,10 @@ def _init_block_layers(cfg: TransformerConfig, n: int, keys, dtype,
         from areal_tpu.models import ssm as ssmmod
 
         layers.update(ssmmod.init_mamba_params(cfg.ssm, n, d, keys[13], dtype))
+    elif kind == GDN:
+        from areal_tpu.models import gdn as gdnmod
+
+        layers.update(gdnmod.init_gdn_params(cfg.gdn, n, d, keys[13], dtype))
     elif kind == GMU:
         layers["gmu_in"] = nrm(keys[13], (n, d, cfg.s6.d_inner))
         layers["gmu_out"] = nrm(keys[14], (n, cfg.s6.d_inner, d))
@@ -189,18 +198,24 @@ def _init_block_layers(cfg: TransformerConfig, n: int, keys, dtype,
             layers["bv"] = jnp.zeros((n, kvd), dtype)
     if cfg.use_attn_output_bias and attends:
         layers["bo"] = jnp.zeros((n, d), dtype)
-    if cfg.use_qk_norm:
-        layers["q_norm"] = jnp.ones((n, cfg.q_norm_dim), dtype)
-        layers["k_norm"] = jnp.ones((n, cfg.k_norm_dim), dtype)
+    if cfg.use_qk_norm and attends:
+        layers["q_norm"] = one((n, cfg.q_norm_dim), dtype)
+        layers["k_norm"] = one((n, cfg.k_norm_dim), dtype)
     if cfg.norm_type == "layer":
         layers["ln1_b"] = jnp.zeros((n, d), dtype)
         layers["ln2_b"] = jnp.zeros((n, d), dtype)
-    if cfg.gated_attention:
+    if cfg.gated_attention and attends:
         layers["wg"] = nrm(keys[12], (n, d, qd))
     if cfg.sandwich_norm:
         layers["ln1_post"] = jnp.ones((n, d), dtype)
         layers["ln2_post"] = jnp.ones((n, d), dtype)
     return layers
+
+
+def _norm_init(cfg: TransformerConfig):
+    """What draws a norm weight that reads as the identity: zeros where the
+    family's weights are zero-centred (``x̂ (1 + w)``), else ones."""
+    return jnp.zeros if cfg.zero_centered_norm else jnp.ones
 
 
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
@@ -217,7 +232,7 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
         params = {
             "embedding": nrm(keys[7], (cfg.vocab_size, d)),
             "layers": _init_mixer_layers(cfg, keys, dtype),
-            "final_ln": jnp.ones((d,), dtype),
+            "final_ln": _norm_init(cfg)((d,), dtype),
         }
         if cfg.norm_type == "layer":
             params["final_ln_b"] = jnp.zeros((d,), dtype)
@@ -230,7 +245,7 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     params: Params = {
         "embedding": nrm(keys[7], (cfg.vocab_size, d)),
         "layers": layers,
-        "final_ln": jnp.ones((d,), dtype),
+        "final_ln": _norm_init(cfg)((d,), dtype),
     }
     if cfg.norm_type == "layer":
         params["final_ln_b"] = jnp.zeros((d,), dtype)
@@ -267,10 +282,26 @@ def layer_norm(
     return (w * ((x32 - mu) * jax.lax.rsqrt(var + eps)).astype(dt) + b).astype(dt)
 
 
+def rms_norm_zero_centered(x: jnp.ndarray, w: jnp.ndarray,
+                           eps: float) -> jnp.ndarray:
+    """``x̂ (1 + w)``, the product in float32 (qwen3_next)."""
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps)
+            * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
+
+
+def _rms(cfg: TransformerConfig, x, w) -> jnp.ndarray:
+    """The model's RMSNorm: its weight zero-centred where the family's is."""
+    if cfg.zero_centered_norm:
+        return rms_norm_zero_centered(x, w, cfg.rms_norm_eps)
+    return rms_norm(x, w, cfg.rms_norm_eps)
+
+
 def _norm(cfg: TransformerConfig, x, lp, key: str) -> jnp.ndarray:
     if cfg.norm_type == "layer":
         return layer_norm(x, lp[key], lp[key + "_b"], cfg.rms_norm_eps)
-    return rms_norm(x, lp[key], cfg.rms_norm_eps)
+    return _rms(cfg, x, lp[key])
 
 
 _ACTIVATIONS = {
@@ -335,13 +366,19 @@ def rope_tables_by_kind(
         map(attention_kind, cfg.period_kinds))}
     return {
         kind: (None, None) if rope is None  # no position embedding
-        else rope_tables(positions, cfg.head_dim, rope)
+        else rope_tables(positions, cfg.rotary_dim, rope)
         for kind, rope in ropes.items()
     }
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
-    """x: [B, T, H, Dh]; cos/sin: [B, T, Dh]."""
+    """x: [B, T, H, Dh]; cos/sin: [B, T, Dr]. Where the table is narrower
+    than the head (``Dr < Dh``: partial rotary), the first ``Dr`` dims are
+    turned, rotate-half inside them, and the others pass untouched."""
+    rd = cos.shape[-1]
+    if rd < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rd], cos, sin), x[..., rd:]], axis=-1)
     c = cos[:, :, None, :].astype(x.dtype)
     s = sin[:, :, None, :].astype(x.dtype)
     half = x.shape[-1] // 2
@@ -407,11 +444,16 @@ def _block(
     # ops, no change to the program that runs.
     with jax.named_scope("attn_norm"):
         x = _norm(cfg, h, lp, "ln1")
-    if akind in (S6, GMU, SSD):
+    if akind in ATTENTION_FREE_KINDS:
         assert cache_kv is None, DECODE_REFUSAL
         from areal_tpu.models import ssm as ssmmod
 
-        if akind == S6:
+        if akind == GDN:
+            from areal_tpu.models import gdn as gdnmod
+
+            attn, new_kv = gdnmod.gdn_mixer(
+                x, lp, cfg.gdn, cfg.rms_norm_eps, segment_ids), None
+        elif akind == S6:
             attn, new_kv = ssmmod.s6_mixer(x, lp, cfg.s6, segment_ids,
                                            attn_impl)
         elif akind == SSD:
@@ -450,8 +492,8 @@ def _block(
             k = k.reshape(B, T, cfg.n_kv_heads, dh)
             v = v.reshape(B, T, cfg.n_kv_heads, dh)
         if qk_norm == "head":
-            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
-            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
+            q = _rms(cfg, q, lp["q_norm"])
+            k = _rms(cfg, k, lp["k_norm"])
         if cfg.gated_attention:
             with jax.named_scope("attn_gate"):
                 gate = x @ lp["wg"]
@@ -555,6 +597,17 @@ DECODE_REFUSAL = (
     "recurrent_decode_state: a state-space layer decodes from a recurrent "
     "state (its convolution's last taps and S), which no cache here holds "
     "(nor one layer's K/V for the cross layers that read it)")
+
+
+def decode_refusal(cfg: TransformerConfig) -> Optional[str]:
+    """Why this model has no decode mode, by name, or None: the Gated
+    DeltaNet blocks' own reason where it has them, ``DECODE_REFUSAL`` for
+    any other layer no K/V cache can decode."""
+    if GDN in cfg.layer_kinds:
+        from areal_tpu.models.gdn import DECODE_REFUSAL as gdn_refusal
+
+        return gdn_refusal
+    return DECODE_REFUSAL if cfg.has_cacheless_layers else None
 
 
 def _mixer_block(
@@ -955,26 +1008,28 @@ def _block_matmul_widths(cfg: TransformerConfig, dense_ffn: bool,
     the last matmul's output, unless a sandwich norm does; an MoE layer
     keeps the router's logits and its shared expert's pair. By ``kind``
     the mixer's are an S6 mixer's (in-projection, [δ | B | C], Δ), a
-    Mamba-2 mixer's two projections, a gated memory unit's one, or cross
-    attention's q and o."""
+    Mamba-2 mixer's two projections, a Gated DeltaNet mixer's three, a
+    gated memory unit's one, or cross attention's q and o."""
     if kind == S6:
         widths = 3 * cfg.s6.d_inner + cfg.s6.x_proj_dim + cfg.hidden_dim
     elif kind == SSD:  # the scan's einsums carry batch dimensions
         widths = cfg.ssm.in_proj_dim + cfg.hidden_dim
+    elif kind == GDN:  # the rule's einsums carry batch dimensions
+        widths = cfg.gdn.qkvz_dim + cfg.gdn.ba_dim + cfg.hidden_dim
     elif kind == GMU:
         widths = cfg.s6.d_inner + cfg.hidden_dim
     elif kind == CROSS:
         widths = cfg.q_dim + cfg.hidden_dim
     else:
         widths = cfg.q_dim + 2 * cfg.kv_dim + cfg.hidden_dim
-    if cfg.gated_attention:
+    if cfg.gated_attention and kind not in ATTENTION_FREE_KINDS:
         widths += cfg.q_dim
     if cfg.sandwich_norm:  # the post-norm reads the FFN's last matmul
         widths += cfg.hidden_dim
     if cfg.moe is None or dense_ffn:
         return widths + (
             1 if cfg.mlp_type == "plain" else 2) * cfg.intermediate_dim
-    return widths + (cfg.moe.n_routed
+    return widths + (cfg.moe.n_routed + int(cfg.moe.shared_expert_gate)
                      + 2 * (cfg.moe.shared_intermediate_dim or 0))
 
 
@@ -1074,7 +1129,7 @@ def forward(
     """
     decode = kv_cache is not None
     if cfg.has_cacheless_layers and (decode or return_kv):
-        raise NotImplementedError(DECODE_REFUSAL)
+        raise NotImplementedError(decode_refusal(cfg))
     with jax.named_scope("embed"):
         h = params["embedding"][tokens]
         if cfg.scale_embeddings:  # gemma normalizer
@@ -1171,7 +1226,7 @@ def forward(
                 h, params["final_ln"], params["final_ln_b"], cfg.rms_norm_eps
             )
         else:
-            h = rms_norm(h, params["final_ln"], cfg.rms_norm_eps)
+            h = _rms(cfg, h, params["final_ln"])
     if return_hidden:
         out = h  # caller applies the head (e.g. chunked-logprob loss)
     else:
@@ -1238,7 +1293,7 @@ def init_kv_cache(
     cfg: TransformerConfig, batch: int, length: int, dtype=jnp.float32
 ) -> Dict[str, jnp.ndarray]:
     if cfg.has_cacheless_layers:
-        raise NotImplementedError(DECODE_REFUSAL)
+        raise NotImplementedError(decode_refusal(cfg))
     shape = (cfg.n_layers, batch, length, cfg.n_kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
@@ -1285,18 +1340,23 @@ def _block_param_count(cfg: TransformerConfig, dense_ffn: bool,
             + s6.state_dim + 1 + d))
     elif kind == SSD:
         attn = _mamba_param_count(cfg)
+    elif kind == GDN:
+        from areal_tpu.models.gdn import gdn_param_count
+
+        attn = gdn_param_count(cfg.gdn, d)
     elif kind == GMU:
         attn = 2 * d * cfg.s6.d_inner
     elif kind == CROSS:
         attn = 2 * d * cfg.q_dim
     else:
         attn = d * (cfg.q_dim + 2 * cfg.kv_dim) + cfg.q_dim * d
-    if cfg.differential_attention and kind not in (S6, GMU, SSD):
+    attends = kind not in ATTENTION_FREE_KINDS
+    if cfg.differential_attention and attends:
         attn += 6 * cfg.head_dim
-    if cfg.gated_attention:
+    if cfg.gated_attention and attends:
         attn += d * cfg.q_dim
     norms = (4 if cfg.sandwich_norm else 2) * d
-    if cfg.use_qk_norm:
+    if cfg.use_qk_norm and attends:
         norms += cfg.q_norm_dim + cfg.k_norm_dim
     if cfg.moe is not None and not dense_ffn:
         mlp = sum(math.prod(shape) for shape in

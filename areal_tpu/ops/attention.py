@@ -131,6 +131,7 @@ def _wants_kernel(impl: str) -> bool:
 
 def kernel_padded_len(
     impl: str, length: int, sliding_window: Optional[int] = None,
+    head_dim: int = 128,
 ) -> Optional[int]:
     """The padded row length at which packed causal self-attention over
     rows of ``length`` tokens runs its Pallas kernel (the grouped-head
@@ -141,7 +142,7 @@ def kernel_padded_len(
         return None
     from areal_tpu.ops.pallas import window_attention as wa
 
-    return wa.padded_len(length, sliding_window)
+    return wa.padded_len(length, sliding_window, head_dim)
 
 
 def packed_attention(

@@ -102,6 +102,14 @@ CAUSAL_TILE_COST = {1024: 0.2804, 768: 0.3314, 512: 0.3593, 256: 0.8073,
 # A causal call's key block is computed 512 keys at a time where that
 # divides it (tile 1024: 5-6 % cheaper than whole).
 _CAUSAL_KV_COMPUTE = 512
+# Heads wider than the lane width (qwen3_next: 256) run at tiles up to
+# this: at tile 1024 the backward of 8 grouped query heads of 256 asks for
+# more than the chip's 16 MB of scoped VMEM — the fused kernel for 17.7 MB
+# at a row of 14,336 (my chip run, PR 52), the dKV kernel alone at some
+# lengths (11,776: a compile for a described v5e). The tables above were
+# measured at heads of 64 and 128; their RATIOS are used at 256 too,
+# unmeasured there (ROADMAP R6).
+_WIDE_HEAD_MAX_TILE = 512
 
 
 def _round_up(n: int, tile: int) -> int:
@@ -147,11 +155,14 @@ def blocks_needed(segment_ids, tile: int, window: Optional[int] = None):
             & (np.eye(n, dtype=bool) | (last == first)))
 
 
-def pick_tile(n: int, window: Optional[int] = None) -> int:
+def pick_tile(n: int, window: Optional[int] = None,
+              head_dim: int = LANE) -> int:
     """The tile a row of n tokens runs: the one whose visited blocks at
     the padded length cost least, ``visited · t² · c(t)``; ties to the
-    larger."""
+    larger; at heads wider than the lanes, of the tiles that fit."""
     costs = CAUSAL_TILE_COST if window is None else TILE_COST
+    if head_dim > LANE:
+        costs = {t: c for t, c in costs.items() if t <= _WIDE_HEAD_MAX_TILE}
 
     def cost(t):
         visited, _ = blocks_visited(_round_up(n, t), t, window)
@@ -160,12 +171,13 @@ def pick_tile(n: int, window: Optional[int] = None) -> int:
     return min(costs, key=cost)
 
 
-def padded_len(n: int, window: Optional[int] = None) -> Optional[int]:
+def padded_len(n: int, window: Optional[int] = None,
+               head_dim: int = LANE) -> Optional[int]:
     """The padded length the kernel runs a row of n tokens at; None when
     n is not a multiple of 128 (the caller takes the reference)."""
     if n % LANE:
         return None
-    return _round_up(n, pick_tile(n, window))
+    return _round_up(n, pick_tile(n, window, head_dim))
 
 
 # Which (length, padded length, tile, window) each WINDOWED call TRACED
@@ -200,14 +212,15 @@ def causal_geometry_counts() -> Dict[str, Dict[Tuple[int, int, int], int]]:
 
 
 def count_needed(segment_ids: np.ndarray,  # [R, L] on the host
-                 window: Optional[int] = None) -> Tuple[int, int]:
+                 window: Optional[int] = None,
+                 head_dim: int = LANE) -> Tuple[int, int]:
     """(key blocks the rows of a packed grid need, key blocks the static
     mask visits) at the tile and padded length :func:`window_attention`
     runs them at — the same :func:`blocks_needed` the kernel's schedule is
     narrowed by; a row of ONE block keeps the static schedule. Added to
     :func:`needed_counts` under the grid's shape."""
     R, L = segment_ids.shape
-    tile = pick_tile(L, window)
+    tile = pick_tile(L, window, head_dim)
     n_pad = _round_up(L, tile)
     visited = needed = R * blocks_visited(n_pad, tile, window)[0]
     if n_pad > tile:
@@ -240,7 +253,8 @@ def _count(n: int, n_pad: int, tile: int, window: Optional[int]) -> None:
     c[2] += causal
 
 
-def _block_sizes(tile: int, window: Optional[int]) -> _splash.BlockSizes:
+def _block_sizes(tile: int, window: Optional[int],
+                 head_dim: int = LANE) -> _splash.BlockSizes:
     """The kernels' blocks at ``tile``, as each table was measured: square
     and computed whole, dKV and dQ kernels, under a window; under a causal
     mask the key block computed ``_CAUSAL_KV_COMPUTE`` keys at a time and
@@ -252,7 +266,11 @@ def _block_sizes(tile: int, window: Optional[int]) -> _splash.BlockSizes:
     the row's segment ids (:func:`_narrowed`): a skipped step costs a grid
     step and no DMA, and the fused backward still writes that step's dQ
     partial (zeros)."""
-    if window is not None:
+    if window is not None or head_dim > LANE:
+        # heads wider than the lanes keep the two kernels under a causal
+        # mask too: the fused kernel's dQ partial sums, one a key block,
+        # are [key blocks, heads, row, head_dim] — 4.3 GB at 16 heads of
+        # 256 over a row of 16,384 at tile 512
         return _splash.BlockSizes(
             block_q=tile, block_kv=tile, block_kv_compute=tile,
             block_q_dkv=tile, block_kv_dkv=tile, block_kv_dkv_compute=tile,
@@ -394,7 +412,7 @@ def window_attention(
             f"row length T={T} is no multiple of 128; "
             "ops/attention.packed_attention routes such shapes to the "
             "reference")
-    tile = pick_tile(T, window)
+    tile = pick_tile(T, window, D)
     T_pad = _round_up(T, tile)
     _count(T, T_pad, tile, window)
     if scale is None:
@@ -420,7 +438,7 @@ def window_attention(
     seg = _splash.SegmentIds(q=pad_ids(q_segment_ids).astype(jnp.int32),
                              kv=pad_ids(kv_segment_ids).astype(jnp.int32))
 
-    geometry = (T_pad, window, G, _block_sizes(tile, window), interpret)
+    geometry = (T_pad, window, G, _block_sizes(tile, window, D), interpret)
 
     def row(q, k, v, seg):  # one packed row, a key/value head at a time
         # A row of ONE block has nothing to skip: the static schedule.

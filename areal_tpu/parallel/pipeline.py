@@ -62,7 +62,7 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from areal_tpu.base import logging, telemetry
-from areal_tpu.models.config import SLIDING, TransformerConfig
+from areal_tpu.models.config import GDN, SLIDING, TransformerConfig
 from areal_tpu.parallel import ring as ring_mod
 from areal_tpu.parallel import sharding as psh
 
@@ -83,6 +83,9 @@ _FALLBACK_HINTS = {
     "cross_layer_state": "a layer reads a tensor another layer made (a "
                          "memory, one layer's K/V): it would have to "
                          "travel with the micro-batch from stage to stage",
+    "gated_delta_rule": "Gated DeltaNet blocks beside attention blocks: "
+                        "a tree per kind has no one stacked axis to split "
+                        "over pp, and a period's stages cost unequally",
     "mixer_layers": "layers that are one mixer each (state-space, expert, "
                     "attention) make stages of unequal cost and have no "
                     "one stacked tree to split over pp",
@@ -128,6 +131,8 @@ def pick_pp_microbatches(
         return None  # no pipeline requested — not a fallback
     if cfg.cross_layer_reads:
         return _fallback("cross_layer_state")
+    if GDN in cfg.layer_kinds:
+        return _fallback("gated_delta_rule")
     if cfg.is_hybrid:
         return _fallback("mixer_layers")
     sp = mesh.shape.get("sp", 1)

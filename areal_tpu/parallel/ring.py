@@ -53,7 +53,7 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.models.config import MAMBA, S6, SSD
+from areal_tpu.models.config import GDN, MAMBA, S6, SSD
 from areal_tpu.parallel.mesh import DATA_AXES
 
 _NEG_INF = -1e30
@@ -351,6 +351,10 @@ RING_REFUSALS = {
     "state_space_scan": "the chunked state-space scan has no ring form: a "
                         "chunk's entering state is a sum over every chunk "
                         "before it, on whichever rank",
+    "gated_delta_rule": "the gated delta rule carries a state matrix a "
+                        "value head from chunk to chunk in order: a rank's "
+                        "first state is the rank before's last, and its "
+                        "convolution reads the rank before's last taps",
     "selective_scan": "the selective scan (S6) is a recurrence over the "
                       "row's tokens in order: a rank's first state is the "
                       "rank before's last",
@@ -367,6 +371,8 @@ def ring_refusal(cfg, kind: Optional[str] = None) -> Optional[str]:
         return "state_space_scan"
     if S6 in cfg.layer_kinds:
         return "selective_scan"
+    if GDN in cfg.layer_kinds:
+        return "gated_delta_rule"
     kinds = cfg.layer_kinds if kind is None else (kind,)
     windowed = any(cfg.window_of(k) is not None for k in kinds)
     return "sliding_window" if windowed else None

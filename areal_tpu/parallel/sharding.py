@@ -28,7 +28,8 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from areal_tpu.models.config import (
-    CROSS, FULL, GMU, S6, SSD, TransformerConfig)
+    ATTENTION_FREE_KINDS, CROSS, FULL, GDN, GMU, S6, SSD,
+    TransformerConfig)
 from areal_tpu.parallel.mesh import DATA_AXES
 
 Params = Dict[str, Any]
@@ -87,9 +88,16 @@ def _block_partition_specs(cfg: TransformerConfig, zero, lead,
         "w_up": P(lead, zero, "tp"),
         "w_down": P(lead, "tp", zero),
     }
-    attends = kind not in (S6, GMU, SSD)
+    attends = kind not in ATTENTION_FREE_KINDS
     if kind == SSD:
         layers.update(_mamba_specs(lead, zero))
+    elif kind == GDN:  # as the Mamba-2 mixer: matrices ZeRO-3, heads whole
+        layers.update({
+            "gdn_qkvz": P(lead, zero, None), "gdn_ba": P(lead, zero, None),
+            "gdn_out": P(lead, None, zero), "gdn_conv": P(lead, None, None),
+            "gdn_dt_bias": P(lead, None), "gdn_A_log": P(lead, None),
+            "gdn_norm": P(lead, None),
+        })
     elif kind == S6:
         layers.update({
             "in_proj": P(lead, zero, None), "out_proj": P(lead, None, zero),
@@ -118,13 +126,13 @@ def _block_partition_specs(cfg: TransformerConfig, zero, lead,
             layers["bv"] = P(lead, "tp")
     if cfg.use_attn_output_bias and attends:
         layers["bo"] = P(lead, None)
-    if cfg.use_qk_norm:
+    if cfg.use_qk_norm and attends:
         layers["q_norm"] = P(lead, None)
         layers["k_norm"] = P(lead, None)
     if cfg.norm_type == "layer":
         layers["ln1_b"] = P(lead, None)
         layers["ln2_b"] = P(lead, None)
-    if cfg.gated_attention:
+    if cfg.gated_attention and attends:
         layers["wg"] = P(lead, zero, "tp")
     if cfg.sandwich_norm:
         layers["ln1_post"] = P(lead, None)
@@ -149,6 +157,8 @@ def _block_partition_specs(cfg: TransformerConfig, zero, lead,
             layers["s_gate"] = P(lead, None, "tp")
             layers["s_up"] = P(lead, None, "tp")
             layers["s_down"] = P(lead, "tp", None)
+            if cfg.moe.shared_expert_gate:
+                layers["s_sig"] = P(lead, None, None)
         # Dense-MLP weights are absent in MoE layers.
         for k in ("w_gate", "w_up", "w_down"):
             del layers[k]

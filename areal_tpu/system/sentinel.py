@@ -107,7 +107,7 @@ METRIC_CATALOG = frozenset({
     "train/loss_weight", "train/total_tokens",
     # train engine counters/gauges (backend/jax_train.py)
     "train/tokens", "train/optimizer_steps", "train/pack_fill",
-    "train/docs_per_row",
+    "train/docs_per_row", "train/gdn_resets_in_chunk_per_row",
     # parallelism engagement (parallel/pipeline.py gates, exported per
     # batch by backend/jax_train.py): 0/1 gauges for whether the pipeline
     # schedule and ring attention actually engaged, plus the per-reason

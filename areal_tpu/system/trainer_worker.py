@@ -330,7 +330,7 @@ class TrainerWorker:
         run is judged by (base/monitor.log_device_report)."""
         from areal_tpu.base import monitor
         from areal_tpu.ops import attention, native
-        from areal_tpu.models import moe, ssm
+        from areal_tpu.models import gdn, moe, ssm
         from areal_tpu.ops.pallas import window_attention
 
         monitor.log_device_report(
@@ -362,6 +362,10 @@ class TrainerWorker:
             # state-space layers
             ssm_geometry={"%dx%d/%d/h%dg%d" % geom: n
                           for geom, n in ssm.geometry_counts().items()},
+            # {"rows x length/chunk/kG vH/dk x dv": rules traced}: a
+            # model's Gated DeltaNet blocks (models/gdn.py)
+            gdn_geometry={"%dx%d/%d/k%dv%d/%dx%d" % geom: n
+                          for geom, n in gdn.geometry_counts().items()},
             # {"pallas" | "pallas_interpret" | "xla": scans traced}: what
             # runs them (the kernel of ops/pallas/ssd_scan.py, or einsums)
             ssm_scan_impl=ssm.scan_impl_counts(),

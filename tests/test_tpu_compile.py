@@ -93,6 +93,11 @@ SAMBAY_SCAN = (1, 8192, 5120, 16)
 SAMBAY_GRID = (1, 8192)
 # The Mamba-2 scans of the Granite and the Nemotron cell: (rows, length —
 # no multiple of Granite's chunk —, heads, head_dim, groups, states, chunk).
+# The Qwen3-Next cell: its attention block's call at 16 query / 2
+# key-value heads of 256 (rows of the cell's two grids) and its grad
+# program's packed grid, at the published widths.
+WIDE_HEAD_T = (14336, 8704)
+QNEXT_GRID = (1, 16384)
 SSD_SCANS = {"ssd-scan-granite": (1, 7040, 32, 64, 1, 128, 256),
              "ssd-scan-nemotron": (1, 4096, 16, 64, 1, 128, 128)}
 
@@ -483,6 +488,54 @@ def _compile_all():
         params, tok, tok, tok).compile())
     out["sambay-cell"]["scans_traced"] = sum(
         ssmmod.s6_geometry_counts().values()) - scans
+
+    # The causal kernel at heads of 256: forward, dKV and dQ.
+    for T in WIDE_HEAD_T:
+        def wide_loss(q, k, v, seg):
+            o = wa.window_attention(q, k, v, seg, seg)
+            return jnp.sum(o.astype(jnp.float32) ** 2)
+
+        def wide(*shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+        compiled = jax.jit(
+            jax.value_and_grad(wide_loss, argnums=(0, 1, 2))).lower(
+                wide(1, T, 16, 256), wide(1, T, 2, 256), wide(1, T, 2, 256),
+                wide(1, T, dtype=jnp.int32)).compile()
+        record(f"causal-256-{T}", compiled)
+        out[f"causal-256-{T}"].update(
+            splash_kernels=sorted(
+                k for k in splash_names if k in compiled.as_text()),
+            tile=wa.pick_tile(T, None, 256))
+
+    # The Qwen3-Next cell's cut (configs/qwen3-next-80b-a3b.json): the
+    # whole model's forward + backward on a 1 x 16,384 row under full remat.
+    from areal_tpu.models import gdn as gdnmod
+
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "qwen3-next-80b-a3b.json")) as f:
+        qnext = weights.model_config(json.load(f))
+    shapes = jax.eval_shape(
+        lambda: transformer.init_params(qnext, jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, jnp.bfloat16, sharding=chip), shapes)
+    tok = jax.ShapeDtypeStruct(QNEXT_GRID, jnp.int32, sharding=chip)
+
+    def qnext_grad(p, tokens, pos, seg):
+        def loss(p):
+            y, _ = transformer.forward(
+                p, qnext, tokens, pos, segment_ids=seg, attn_impl="pallas",
+                remat="full", return_kv=False, return_hidden=True)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        return jax.value_and_grad(loss)(p)
+
+    rules = sum(gdnmod.geometry_counts().values())
+    record("qnext-cell", jax.jit(qnext_grad).lower(
+        params, tok, tok, tok).compile())
+    out["qnext-cell"].update(
+        rules_traced=sum(gdnmod.geometry_counts().values()) - rules,
+        param_bytes=18 * transformer.param_count(qnext))
     return out
 
 
@@ -723,6 +776,34 @@ def test_the_ssd_scan_kernels_compile_for_v5e(compiled, name):
     assert got["custom_calls"] == 2
     R, T, H, P, G, N, Q = SSD_SCANS[name]
     assert got["temp_bytes"] < R * T * H * Q * 4
+
+
+@pytest.mark.parametrize("T", WIDE_HEAD_T)
+def test_the_causal_kernel_compiles_at_heads_of_256(compiled, T):
+    """Qwen3-Next's attention block: 16 query / 2 key-value heads of 256.
+    Tile 512 and the two backward kernels, not the fused one: at tile 1024
+    the backward asks for more than the chip's scoped VMEM, and the fused
+    kernel's dQ partial sums (one a key block) for gigabytes."""
+    got = compiled[f"causal-256-{T}"]
+    assert got["tile"] == 512
+    assert got["splash_kernels"] == ["splash_mqa_dkv", "splash_mqa_dq",
+                                     "splash_mqa_fwd"]
+    assert got["temp_bytes"] < 0.6e9
+
+
+def test_the_qwen3_next_cut_compiles_inside_the_memory_it_leaves(compiled):
+    """The grad program of the cut (16 of 512 experts held: 424.7 M
+    parameters) on a 1 x 16,384 row, at the published widths, beside the
+    state: 18 B a parameter, the bf16 gradient the program returns, its
+    temporaries — the number the cut was chosen by (with 32 held the same
+    program needs 4.9 GB beside 12.5 GB: PERF.md section 4)."""
+    got = compiled["qnext-cell"]
+    assert got["rules_traced"] == 1  # L L L is one run, scanned
+    # attention: forward twice, dKV, dQ; the experts' grouped GEMMs
+    assert got["custom_calls"] >= 4
+    assert got["temp_bytes"] < 5.0e9
+    gradient = got["param_bytes"] // 9  # 2 B a parameter
+    assert got["param_bytes"] + gradient + got["temp_bytes"] < 15.0e9
 
 
 def test_the_sambay_cell_compiles_at_the_published_widths(compiled):

@@ -147,7 +147,7 @@ def main() -> int:
                 # blocks visited are counted at the larger
                 tile = math.lcm(sizes.block_q, sizes.block_kv)
                 setattr(wa, table, {tile: 1.0})
-                wa._block_sizes = lambda t, w, sizes=sizes: sizes
+                wa._block_sizes = lambda t, w, d=None, sizes=sizes: sizes
                 n_pad = wa.padded_len(L, window)
                 visited, _ = wa.blocks_visited(n_pad, tile, window)
                 # (a tree from before the kernel skipped blocks has none)
