@@ -1102,6 +1102,15 @@ class JaxTrainEngine(TrainableEngine):
             if frac is not None:
                 telemetry.set_gauge("train/ssd_kernel_frac", frac)
                 span_attrs["ssd_kernel_frac"] = frac
+        if self.cfg.gdn is not None:
+            from areal_tpu.models import gdn as gdnmod
+
+            # the share of the traced gated delta rules that run the
+            # kernel pair (models/gdn.rule_impl_counts)
+            frac = gdnmod.rule_kernel_frac()
+            if frac is not None:
+                telemetry.set_gauge("train/gdn_kernel_frac", frac)
+                span_attrs["gdn_kernel_frac"] = frac
         with telemetry.span("train/fwd_bwd", n_mbs=len(idxs),
                             grid=f"{ub.R}x{ub.L}",
                             remat=str(self._remat_for(ub.R, ub.L)),

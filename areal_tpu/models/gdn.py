@@ -1,6 +1,9 @@
 """The Gated DeltaNet linear-attention mixer of a ``gdn`` block
 (qwen3_next's ``linear_attention`` layers) — packed rows, the gated delta
-rule in chunks as XLA matmuls and one ``lax.scan`` over the chunks.
+rule in chunks: on a TPU, at the published head sizes, the Pallas kernel
+pair of ``ops/pallas/gated_delta_rule.py`` (:func:`_rule_impl` chooses, by
+what it can see, and :func:`rule_impl_counts` says what it chose);
+elsewhere XLA matmuls and one ``lax.scan`` over the chunks.
 
 One mixer, ``u = norm(h)`` [B, T, D] (models/transformer.py adds the
 residual and the block's FFN); ``G`` key heads of ``dk``, ``H = r·G``
@@ -30,10 +33,12 @@ cumulated ``g`` (``ssm._masked_exp``: exactly 0 where masked).
 and ``S₀`` the state entering it, the chunk's ``δ`` solve ``(I + A) Δ =
 β ⊙ (V − e^c ⊙ K̂ S₀)`` where ``A_ij = β_i e^{c_i − c_j} k̂_i·k̂_j`` for ``j
 < i`` in the same document — a unit lower-triangular system a chunk a
-value head. ``(I + A)^-1`` is a product of ``log2 chunk`` matrices
-(:func:`_unit_lower_inverse`: A is nilpotent), applied once to ``β V`` and
-once to ``β e^c K̂`` (the WY / UT transform); the states are then carried
-chunk to chunk by a ``lax.scan`` whose step is five small matmuls a head.
+value head. In the XLA form ``(I + A)^-1`` is a product of ``log2 chunk``
+matrices (:func:`_unit_lower_inverse`: A is nilpotent; the kernels invert
+by blocks, which also holds where a chunk's keys resemble each other and
+the powers of ``A`` grow), applied once to ``β V`` and once to ``β e^c K̂``
+(the WY / UT transform); the states are then carried chunk to chunk by a
+``lax.scan`` whose step is five small matmuls a head.
 A document start inside a chunk masks the chunk's two triangular
 matrices, which tokens read the entering state, and what of the chunk the
 state it leaves holds. ``A``, its inverse, the decays and the carried
@@ -48,6 +53,7 @@ program holds.
 from __future__ import annotations
 
 import collections
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -74,6 +80,22 @@ L2_EPS = 1e-6  # inside the rsqrt of q's and k's l2 norm (fla's, HF's)
 
 def geometry_counts() -> Dict[Tuple[int, int, int, int, int, int, int], int]:
     return dict(_GEOMETRY)
+
+
+# Which form each traced rule took (:func:`_rule_impl`): "pallas" |
+# "pallas_interpret" | "xla".
+_RULE_IMPL: collections.Counter = collections.Counter()
+
+
+def rule_impl_counts() -> Dict[str, int]:
+    return dict(_RULE_IMPL)
+
+
+def rule_kernel_frac() -> Optional[float]:
+    """Of the rules traced so far, the share that took the Pallas kernels;
+    None before the first trace."""
+    total = sum(_RULE_IMPL.values())
+    return (total - _RULE_IMPL["xla"]) / total if total else None
 
 
 def init_gdn_params(gdn: GDNConfig, n: int, hidden_dim: int, key: jax.Array,
@@ -158,14 +180,17 @@ def gated_delta_rule(q: jnp.ndarray,  # [B, T, G, dk], l2-normed and scaled
                      g: jnp.ndarray,  # [B, T, H] float32 log-decay, <= 0
                      beta: jnp.ndarray,  # [B, T, H] float32 in (0, 1)
                      seg: jnp.ndarray,  # [B, T] int; 0 = padding
-                     chunk: int) -> jnp.ndarray:
+                     chunk: int, impl: str = "auto") -> jnp.ndarray:
     """The gated delta rule of the module's docstring in chunks of
     ``chunk`` tokens, ``S`` zero before each document's first token.
-    Returns o [B, T, H, dv] float32."""
+    Returns o [B, T, H, dv] float32. ``impl`` as ``attn_impl``: on a TPU
+    the Pallas kernel pair where :func:`_rule_impl` finds it can run."""
     B_, T, G, dk = q.shape
     H, dv = v.shape[2:]
     r = H // G
     Q = chunk
+    how = _rule_impl(impl, Q, G, H, dk, dv, v.dtype)
+    _RULE_IMPL[how] += 1
     pad = -T % Q
     if pad:  # a padded token is its row's padding: beta = 0 writes nothing
         q, k, v, g, beta = (
@@ -174,6 +199,9 @@ def gated_delta_rule(q: jnp.ndarray,  # [B, T, G, dk], l2-normed and scaled
         seg = jnp.pad(seg, ((0, 0), (0, pad)))
     Z = (T + pad) // Q
     cd, f32 = v.dtype, jnp.float32
+    if how != "xla":
+        return _rule_kernel(q.astype(cd), k.astype(cd), v, g.astype(f32),
+                            beta.astype(f32), seg, Q, how)[:, :T]
     seg = seg.reshape(B_, Z, Q)
     # heads before tokens, so that every array's two minor dims are a
     # chunk's tokens and a head's channels (or tokens and tokens):
@@ -242,12 +270,64 @@ def gated_delta_rule(q: jnp.ndarray,  # [B, T, G, dk], l2-normed and scaled
     return o[:, :T]
 
 
+def _rule_impl(impl: str, chunk: int, G: int, H: int, dk: int, dv: int,
+               dtype) -> str:
+    """"pallas" | "pallas_interpret" | "xla" (as ``ssm._ssd_impl``): the
+    kernels where they take the shapes and, compiled, where ``impl`` and
+    the platform ask for a kernel and the chip has the VMEM."""
+    from areal_tpu.ops.attention import _wants_kernel
+    from areal_tpu.ops.pallas import gated_delta_rule as kernel
+
+    if not kernel.supported(chunk, G, H, dk, dv, dtype):
+        return "xla"
+    if impl == "pallas_interpret":
+        return impl
+    return "pallas" if _wants_kernel(impl) and kernel.fits_device() else "xla"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _rule_kernel(q, k, v, g, beta, seg, chunk, how):
+    """The kernels' rule: q, k, v in one compute dtype, g and beta
+    float32, T a whole number of chunks."""
+    from areal_tpu.ops.pallas import gated_delta_rule as kernel
+
+    with jax.named_scope("gdn_rule"):
+        return kernel.rule_fwd(q, k, v, g, beta, seg, chunk,
+                               interpret=how == "pallas_interpret")[0]
+
+
+def _rule_kernel_fwd(q, k, v, g, beta, seg, chunk, how):
+    from areal_tpu.ops.pallas import gated_delta_rule as kernel
+
+    with jax.named_scope("gdn_rule"):
+        o, states = kernel.rule_fwd(q, k, v, g, beta, seg, chunk, keep=True,
+                                    interpret=how == "pallas_interpret")
+    return o, (q, k, v, g, beta, seg, states)
+
+
+def _rule_kernel_bwd(chunk, how, res, do):
+    from areal_tpu.ops.pallas import gated_delta_rule as kernel
+
+    q, k, v, g, beta, seg, states = res
+    with jax.named_scope("gdn_rule"):
+        return kernel.rule_bwd(q, k, v, g, beta, seg, states, do, chunk,
+                               interpret=how == "pallas_interpret") + (None,)
+
+
+_rule_kernel.defvjp(_rule_kernel_fwd, _rule_kernel_bwd)
+
+
 # The mixer's per-head work — convolution, gates, the rule, the gated norm
 # — runs a group of key heads at a time (``lax.map``), each group under
 # its own checkpoint: the float32 blocks of a chunk (A, its inverse, the
 # decays) and the states the backward pass reads then exist for one group
 # at a time, at the price of one more forward of the group in the backward
-# pass. Groups of a model: gcd(key heads, _HEAD_GROUPS).
+# pass. Groups of a model: gcd(key heads, _HEAD_GROUPS). The XLA form's
+# only: the kernels keep those blocks in VMEM, and the mixer then runs all
+# heads at once with nothing run twice (a checkpoint around the
+# convolution, the gates and the norms — their float32 copies of q, k, v
+# and o are 1.8 GB at a 16,384-token row — cost 3.8 % of the Qwen3-Next
+# cell's rate, PERF.md §6 PR 53, and the cell's peak did not need it).
 _HEAD_GROUPS = 4
 
 
@@ -255,6 +335,7 @@ def gdn_mixer(u: jnp.ndarray,  # [B, T, D] the normed residual stream
               lp: Dict[str, jnp.ndarray],  # this layer's parameters
               gdn: GDNConfig, eps: float,
               segment_ids: Optional[jnp.ndarray],  # None = one document a row
+              impl: str = "auto",  # the model's ``attn_impl``
               ) -> jnp.ndarray:
     B_, T, _ = u.shape
     G, H, dk, dv = gdn.n_k_heads, gdn.n_v_heads, gdn.k_head_dim, gdn.v_head_dim
@@ -263,7 +344,8 @@ def gdn_mixer(u: jnp.ndarray,  # [B, T, D] the normed residual stream
     seg = (jnp.ones((B_, T), jnp.int32) if segment_ids is None
            else segment_ids)
     _GEOMETRY[(B_, T, gdn.chunk_size, G, H, dk, dv)] += 1
-    n = math.gcd(G, _HEAD_GROUPS)
+    kernel = _rule_impl(impl, gdn.chunk_size, G, H, dk, dv, u.dtype) != "xla"
+    n = 1 if kernel else math.gcd(G, _HEAD_GROUPS)
     Gn, Hn = G // n, H // n
 
     def by_group(a):  # [..., n * width] -> [n, ..., width]
@@ -294,7 +376,7 @@ def gdn_mixer(u: jnp.ndarray,  # [B, T, D] the normed residual stream
             k = l2_normalize(k.reshape(B_, T, Gn, dk)).astype(u.dtype)
         with jax.named_scope("gdn_rule"):
             o = gated_delta_rule(q, k, v.reshape(B_, T, Hn, dv), g, beta,
-                                 seg, gdn.chunk_size)
+                                 seg, gdn.chunk_size, impl)
         with jax.named_scope("gdn_gate_norm"):
             o = o * jax.lax.rsqrt(
                 jnp.mean(o * o, axis=-1, keepdims=True) + eps)

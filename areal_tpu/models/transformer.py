@@ -452,7 +452,8 @@ def _block(
             from areal_tpu.models import gdn as gdnmod
 
             attn, new_kv = gdnmod.gdn_mixer(
-                x, lp, cfg.gdn, cfg.rms_norm_eps, segment_ids), None
+                x, lp, cfg.gdn, cfg.rms_norm_eps, segment_ids,
+                attn_impl), None
         elif akind == S6:
             attn, new_kv = ssmmod.s6_mixer(x, lp, cfg.s6, segment_ids,
                                            attn_impl)

@@ -1,0 +1,622 @@
+"""TPU chunked gated delta rule (Gated DeltaNet) for packed segment batches.
+
+    S ← e^{g_t} S;  δ_t = β_t (v_t − Sᵀ k_t);  S ← S + k_t δ_tᵀ;  o_t = Sᵀ q_t
+
+(S [dk, dv] float32 a value head, zero before a document's first token) —
+the chunked algorithm of ``models/gdn.gated_delta_rule`` at the same chunk
+Q, one grid step a (row, key head, run of chunks), the chunk axis innermost
+and sequential. With ``c`` the cumulated ``g`` inside a chunk and ``S₀``
+the state entering it, per value head:
+
+    A  = β_i (k_i·k_j) e^{c_i − c_j}      j < i, one document; else exactly 0
+    M  = (I + A)^-1
+    Δ  = M · [β ⊙ (v − e ⊙ (k S₀))]       e_i = e^{c_i} on the tokens still in
+                                           the document the row entered in
+    o  = e ⊙ (q S₀) + P Δ                 P = q·kᵀ ⊙ e^{c_i − c_j}, j <= i
+    S₁ = κ S₀ + (t ⊙ k)ᵀ Δ                t_j = e^{c_Q − c_j} on the tokens of
+                                           the document the chunk ends in, κ =
+                                           e^{c_Q} where that is the entering one
+
+Every exponent is that of a non-positive difference (the argument is 0
+where the mask drops it and the result is replaced by exactly 0: the
+contract of ``ssm._masked_exp``). Nothing [Q, Q] leaves VMEM. ``A``, ``M``,
+the decays and the state are float32; matmul operands are the compute
+dtype and every sum float32.
+
+**A key head's value heads ride as a PAIR** along the lanes: ``[A₀ | A₁]``
+[Q, 2 Q] is one full-lane array, ``k·[k; k]ᵀ`` makes ``k·kᵀ`` twice, and a
+pair times ``[[x₀, 0], [0, x₁]]`` (:func:`_blocks`) is ``[a₀ x₀ | a₁ x₁]`` —
+one MXU product of 128 deep where two of 64 stood; a head's [Q, dv] arrays
+sit side by side the same way ([Q, r · dv], as v and o are laid out in HBM).
+
+**A step works in two phases.** First everything no state enters, for ALL
+the step's chunks at once ([chunks, ., .] arrays and one batched product an
+operation: the chunks' chains of dependent products run side by side, and
+the kernel's text does not grow with the chunks a step holds): the masks
+and decays, ``A``, ``M`` and — forward — with ``U = M (β v)``, ``W = M (β e
+k)`` (so that ``Δ = U − W S₀``): ``G = (t ⊙ k)ᵀ W``, ``C = (t ⊙ k)ᵀ U``,
+``q̃ = e ⊙ q − P W`` and ``P U``, into VMEM scratches. Then the states'
+chain, a ``fori_loop`` over the chunks with ONE product a chunk and value
+head: ``[G; q̃] · S₀``, ``S₁ = κ S₀ + C − G S₀``, ``o = q̃ S₀ + P U``. The
+state is a VMEM scratch that rides the chunk axis.
+
+**The inverse** goes by blocks (:func:`_inverses`): ``M − M L M`` from
+blocks of 2 up to Q, ten products whose factors are no larger than the
+inverse's own blocks. Float32 operands multiply at ``HIGHEST``; under a
+16-bit compute dtype the left factor is split into two bfloat16 parts
+(2^-16) and the right one rounded (as ``M`` itself is, before it is used).
+
+**The gates' layout.** ``c``, β, the segment ids, ``c_Q`` and the
+documents at the chunk's two ends ride ONE [8, 128] float32 tile a chunk
+and key head (:func:`gate_tiles`: tokens in the lanes, a quantity a
+sublane); its transpose gives the same quantities down the sublanes. The
+backward writes d c and d β into a tile of its own.
+
+ - forward (:func:`rule_fwd`): q, k [R, T, G·dk], v [R, T, H·dv] as the
+   mixer has them (tokens major, heads in the lanes) and the gate tiles
+   in; o float32 out and — for a backward pass — the state ENTERING each chunk
+   in the compute dtype (the state enters every product rounded to it;
+   the carried one is float32);
+ - backward (:func:`rule_bwd`): the same grid with the chunks reversed,
+   ``dS`` as its carry; the step's ``M`` are made as in the forward, then a
+   loop over the chunks rebuilds each chunk's blocks from q, k, v, the
+   tile and the kept state. The inverse needs no cotangent of its own:
+   with ``dR = Mᵀ dΔ``, ``dA = −Mᵀ (dΔ Rᵀ) Mᵀ = −dR Δᵀ``. The decays'
+   gradient is row sums less column sums of ``dA ⊙ A + dP ⊙ P`` plus three
+   sums over a token's channels, made on the MXU against 0/1 rows (so
+   they come out with tokens in the lanes).
+
+The kernels' device ops are named ``gdn_rule_fwd`` / ``gdn_rule_bwd``
+under the caller's scope (not jitted by themselves: the benchmark reads
+the rule by its scope). CPU/testing: ``interpret=True``;
+tests/test_tpu_compile.py compiles them for a described v5e.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# fits_device: the same VMEM_LIMIT, asked of the attached chip
+from areal_tpu.ops.pallas.ssd_scan import chunk_cumsum, fits_device  # noqa: F401
+
+LANE = 128
+SUBLANE = 8
+FWD_NAME, BWD_NAME = "gdn_rule_fwd", "gdn_rule_bwd"
+# Chunks a grid step at most (tools/gdn_rule_sweep.py; PERF.md §5, PR 53):
+# the first phase of a step runs its chunks' chains side by side, and at 1
+# x 14,336 the forward / backward kernels read 3.89 / 8.68 ms at 4, 2.87 /
+# 7.68 at 8, 2.77 / 7.45 at 16 (whose first phase holds twice the VMEM).
+CHUNKS_PER_STEP = 8
+VMEM_LIMIT = 64 * 1024 * 1024
+
+_NT = (((1,), (1,)), ((), ()))  # a · bᵀ
+_TN = (((0,), (0,)), ((), ()))  # aᵀ · b
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def supported(chunk: int, k_heads: int, v_heads: int, dk: int, dv: int,
+              dtype) -> bool:
+    """What the kernels take: chunks of 64, heads of one lane tile, one or
+    two value heads a key head (a chunk's gates ride one [8, 128] tile: 3r
+    + 1 rows), float32 or bfloat16."""
+    r = v_heads // max(k_heads, 1)
+    return (chunk == 64 and dk == LANE and dv == LANE
+            and v_heads == r * k_heads and 1 <= r <= 2
+            and jnp.dtype(dtype) in (jnp.dtype(jnp.float32),
+                                     jnp.dtype(jnp.bfloat16)))
+
+
+def chunks_per_step(chunks: int) -> int:
+    """The most chunks a step (<= ``CHUNKS_PER_STEP``, a power of two) that
+    divide a row's."""
+    n = CHUNKS_PER_STEP
+    while chunks % n:
+        n //= 2
+    return n
+
+
+def _dot(a, b, dims=None, exact=False):
+    kw = dict(preferred_element_type=jnp.float32,
+              precision=_HIGHEST if exact else None)
+    if dims is None:
+        return jnp.dot(a, b, **kw)
+    return jax.lax.dot_general(a, b, dims, **kw)
+
+
+def _dots(spec: str, a, b, exact: bool):
+    """A product a chunk of a step: [chunks, ., .] operands, one op."""
+    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32,
+                      precision=_HIGHEST if exact else None)
+
+
+def _lane(shape):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+
+
+def _by_head(vals, shape, width: int):
+    """Per-head [.., Q, 1] columns or [.., 1, 1]-free rows -> ``shape``,
+    head h's on its own ``width`` lanes."""
+    if len(vals) == 1:
+        return jnp.broadcast_to(vals[0], shape)
+    return jnp.where(_lane(shape) < width, vals[0], vals[1])
+
+
+def _blocks(x, r: int, width: int):
+    """Head h's lanes of x [.., Q, r · width] in the rows of block h, zeros
+    elsewhere: [.., 2 Q, r · width] = [[x₀, 0], [0, x₁]] — the right factor
+    under which a PAIR [a₀ | a₁] [Q, 2 Q] multiplies each head's own: ``[a₀
+    | a₁] · blocks(x) = [a₀ x₀ | a₁ x₁]`` (one head: ``[[x₀], [0]]``)."""
+    zero = jnp.zeros_like(x)
+    if r == 1:
+        return jnp.concatenate([x, zero], axis=-2)
+    left = _lane(x.shape) < width
+    return jnp.concatenate([jnp.where(left, x, zero),
+                            jnp.where(left, zero, x)], axis=-2)
+
+
+def _diagonal(x, r: int, width: int):
+    """[2 Q, r · width] -> [Q, r · width]: block (h, h) of each head."""
+    Q = x.shape[0] // 2
+    if r == 1:
+        return x[:Q]
+    return jnp.concatenate([x[:Q, :width], x[Q:, width:]], axis=1)
+
+
+def _products(a, b, exact: bool):
+    """PAIRS [a₀ | a₁] of float32 [Q, Q] matrices, a pair a chunk ([chunks,
+    Q, 2 Q]), twice -> their products [a₀ b₀ | a₁ b₁]. Float32 at
+    ``HIGHEST`` where the compute dtype is float32 (``exact``); else the
+    left factor as two bfloat16 parts (one product of 2 Q rows) against
+    the right one rounded."""
+    Q = a.shape[1]
+    if exact:
+        return _dots("nij,njk->nik", a, _blocks(b, 2, Q), True)
+    hi = a.astype(jnp.bfloat16)
+    lo = (a - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    both = _dots("nij,njk->nik", jnp.concatenate([hi, lo], axis=1),
+                 _blocks(b.astype(jnp.bfloat16), 2, Q), False)
+    return both[:, :Q] + both[:, Q:]
+
+
+def _inverses(A, exact: bool):
+    """``(I + A)^-1`` of pairs [A₀ | A₁] of strictly lower-triangular
+    float32 [Q, Q] matrices, a pair a chunk of the step ([chunks, Q, 2
+    Q]; the chunks' chains of dependent products overlap). By blocks: with
+    the inverse ``M`` of the diagonal blocks of width w known and ``L``
+    what ``A`` holds between the two halves of each block of 2w, the
+    inverse at 2w is ``M − M L M`` (``[[M₁, 0], [−M₂ A₂₁ M₁, M₂]]``); at w
+    = 2 it is ``I − L``. Ten products for Q = 64, each of factors no
+    larger than the inverse's own blocks — the sum of ``(−A)ⁿ`` that the
+    XLA form makes in as many takes powers whose entries grow with the
+    binomials where a chunk's keys resemble each other, and cancels
+    them."""
+    Q = A.shape[1]
+    ii = jax.lax.broadcasted_iota(jnp.int32, A.shape, 1)
+    jj = _lane(A.shape) & (Q - 1)
+    apart = ii ^ jj  # < w: one block of width w (a power of two)
+    M = jnp.where(ii == jj, 1.0, 0.0) - jnp.where(apart < 2, A, 0.0)
+    w = 2
+    while w < Q:
+        L = jnp.where((apart >= w) & (apart < 2 * w), A, 0.0)
+        M = M - _products(M, _products(L, M, exact), exact)
+        w *= 2
+    return M
+
+
+def _lane_sums(F, ranges, exact: bool):
+    """float32 F [Q, W] -> [16, Q] float32 whose row a is the sum of F over
+    the lanes ``ranges[a]`` = (first, width), a token a lane: one 0/1
+    product, F as three bfloat16 parts (or at ``HIGHEST``)."""
+    shape = (2 * SUBLANE, F.shape[1])
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    sel = jnp.zeros(shape, jnp.bool_)
+    for a, (first, width) in enumerate(ranges):
+        sel = sel | ((row == a) & (lane >= first) & (lane < first + width))
+    if exact:
+        return _dot(sel.astype(jnp.float32), F, _NT, True)
+    pick = sel.astype(jnp.bfloat16)
+    out = None
+    for _ in range(3):
+        part = F.astype(jnp.bfloat16)
+        F = F - part.astype(jnp.float32)
+        d = _dot(pick, part, _NT)
+        out = d if out is None else out + d
+    return out
+
+
+# The rows of a chunk's gate tile (:func:`gate_tiles`).
+_C, _BETA, _SEG, _C_END, _C_SECOND, _PREV, _LAST = 0, 1, 2, 3, 5, 6, 7
+
+
+def _row(tile, row: int):
+    return tile[..., row:row + 1, :]
+
+
+def _column(cols, Q: int, h: int, row: int):
+    """Head h's [.., Q, 1] of a PAIRED row of the tile, from its
+    transpose (``cols``: the same quantities, a token a sublane)."""
+    return cols[..., h * Q:(h + 1) * Q, row:row + 1]
+
+
+def _pair_blocks(tile, cols, Q: int, r: int):
+    """What the gate tiles ([.., 8, 128], one chunk or all of a step's)
+    give BEFORE any state is known, the value heads paired along the lanes
+    ([.., Q, 2 Q]): β down the sublanes and the two masked decay blocks (a
+    single head: zeros on the second's lanes)."""
+    shape = tile.shape[:-2] + (Q, 2 * Q)
+    ii = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 2)
+    lane = _lane(shape)
+    jj = lane & (Q - 1)
+    same = _column(cols, Q, 0, _SEG) == _row(tile, _SEG)
+    if r == 1:
+        same = same & (lane < Q)
+    lo, up = same & (ii > jj), same & (ii >= jj)
+    c_c = _by_head([_column(cols, Q, h, _C) for h in range(r)], shape, Q)
+    E = jnp.exp(jnp.where(up, c_c - _row(tile, _C), 0.0))
+    b_c = _by_head([_column(cols, Q, h, _BETA) for h in range(r)], shape, Q)
+    return b_c, jnp.where(lo, E, 0.0), jnp.where(up, E, 0.0)
+
+
+def _end_blocks(tile, cols, Q: int, r: int, dv: int):
+    """... and what ties a chunk to the states, a head on its own dv lanes
+    ([.., Q, r · dv] / [.., 1, r · dv]): β, e, t down the sublanes and κ
+    of the module's docstring; and per head t down the sublanes and, for
+    the backward's sums, e and t along the lanes ([.., 1, Q])."""
+    prev_r, last_r = _row(tile, _PREV), _row(tile, _LAST)
+    prev_c, last_c = (_column(cols, Q, 0, a) for a in (_PREV, _LAST))
+    seg_r, seg_c = _row(tile, _SEG)[..., :Q], _column(cols, Q, 0, _SEG)
+    wide = tile.shape[:-2] + (Q, r * dv)
+    e_c, t_c, kappa, e_r, t_r = [], [], [], [], []
+    for h in range(r):
+        c_c = _column(cols, Q, h, _C)
+        c_r = _row(tile, _C if h == 0 else _C_SECOND)[..., :Q]
+        end_r = _row(tile, _C_END + h)  # c_Q on every lane
+        e_c.append(jnp.where(seg_c == prev_c, jnp.exp(c_c), 0.0))
+        t_c.append(jnp.where(
+            seg_c == last_c,
+            jnp.exp(_column(cols, Q, 0, _C_END + h) - c_c), 0.0))
+        kappa.append(jnp.where(prev_r == last_r, jnp.exp(end_r), 0.0))
+        e_r.append(jnp.where(seg_r == prev_r[..., :Q], jnp.exp(c_r), 0.0))
+        t_r.append(jnp.where(seg_r == last_r[..., :Q],
+                             jnp.exp(end_r[..., :Q] - c_r), 0.0))
+    return dict(
+        b=_by_head([_column(cols, Q, h, _BETA) for h in range(r)], wide, dv),
+        e=_by_head(e_c, wide, dv), t=_by_head(t_c, wide, dv), t_c=t_c,
+        kappa=kappa[0] if r == 1 else jnp.concatenate(kappa, axis=-1),
+        e_r=e_r, t_r=t_r)
+
+
+def _prepare(q_ref, k_ref, gate_ref, Q: int, r: int, with_q: bool):
+    """The part of a step no state enters, for all its chunks at once
+    ([chunks, ., .] arrays: one basic block, the chunks' chains of products
+    side by side): (the gate tiles, their transposes, k, [k; k], each
+    chunk's pair ``[M₀ | M₁]`` in the compute dtype and — ``with_q`` —
+    ``q·kᵀ`` under each head's decays, paired the same way, else None)."""
+    cd = q_ref.dtype
+    exact = cd == jnp.float32
+    nc = gate_ref.shape[2]
+    tiles = gate_ref[0, 0]  # [chunks, 8, 128]
+    cols = jnp.swapaxes(tiles, 1, 2)
+    k = k_ref[0].reshape(nc, Q, k_ref.shape[2])
+    k2 = jnp.concatenate([k, k], axis=1)  # k·kᵀ twice along the lanes
+    b_c, D_lo, D_up = _pair_blocks(tiles, cols, Q, r)
+    M = _inverses(b_c * _dots("nik,njk->nij", k, k2, exact) * D_lo,
+                  exact).astype(cd)
+    P = None
+    if with_q:
+        q = q_ref[0].reshape(nc, Q, q_ref.shape[2])
+        P = (_dots("nik,njk->nij", q, k2, exact) * D_up).astype(cd)
+    return tiles, cols, k, M, P
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, gate_ref, o_ref, *rest, Q: int, r: int,
+                keep: bool):
+    """A step of the forward: first everything no state enters, for all the
+    step's chunks at once — with ``U = M (β v)``, ``W = M (β e k)`` (so
+    that ``Δ = U − W S₀``): ``G = (t ⊙ k)ᵀ W``, ``C = (t ⊙ k)ᵀ U``, ``q̃ =
+    e ⊙ q − P W`` and ``P U`` —; then the states' chain, ONE product a
+    chunk and value head: ``[G; q̃] · S₀``, ``S₁ = κ S₀ + C − G S₀``, ``o
+    = q̃ S₀ + P U``."""
+    s_ref = rest[0] if keep else None
+    state, gq_ref, c_ref, pu_ref, kap_ref = rest[-5:]
+    z = pl.program_id(2)
+    nc = gate_ref.shape[2]
+    dk, dv = q_ref.shape[2], v_ref.shape[2] // r
+    W = r * dv
+    cd, f32 = q_ref.dtype, jnp.float32
+    exact = cd == f32
+
+    @pl.when(z == 0)
+    def _():
+        state[...] = jnp.zeros(state.shape, state.dtype)
+
+    tiles, cols, k, M, P = _prepare(q_ref, k_ref, gate_ref, Q, r, True)
+    hd = _end_blocks(tiles, cols, Q, r, dv)
+    kf, qf = k.astype(f32), q_ref[0].reshape(nc, Q, dk).astype(f32)
+    if r > 1:  # a head's copy on its own lanes
+        kf, qf = (jnp.concatenate([a] * r, axis=2) for a in (kf, qf))
+    v = v_ref[0].reshape(nc, Q, W).astype(f32)
+    UW = _dots("nij,njk->nik", M, jnp.concatenate(
+        [_blocks((hd["b"] * v).astype(cd), r, dv),
+         _blocks((hd["b"] * hd["e"] * kf).astype(cd), r, dv)], axis=2),
+        exact)  # [chunks, Q, 2 W]: U | W
+    GC = _dots("nqk,nqw->nkw", k, jnp.concatenate(
+        [(hd["t"] * UW[..., W:]).astype(cd),
+         (hd["t"] * UW[..., :W]).astype(cd)], axis=2), exact)  # G | C
+    PWU = _dots("nij,njk->nik", P, jnp.concatenate(
+        [_blocks(UW[..., W:].astype(cd), r, dv),
+         _blocks(UW[..., :W].astype(cd), r, dv)], axis=2), exact)
+    qe = (hd["e"] * qf - PWU[..., :W]).astype(cd)  # q̃
+    for h in range(r):
+        at = slice(h * dv, (h + 1) * dv)
+        gq_ref[:, h, :dk, :] = GC[:, :, at].astype(cd)
+        gq_ref[:, h, dk:, :] = qe[:, :, at]
+    c_ref[...] = GC[..., W:]
+    pu_ref[...] = PWU[..., W:]
+    kap_ref[...] = jnp.broadcast_to(hd["kappa"], kap_ref.shape)
+
+    def chunk(c, carry):
+        rows = pl.ds(pl.multiple_of(c * Q, Q), Q)
+        S0 = state[...]  # [dk, r · dv]: a head on its own dv lanes
+        S0c = S0.astype(cd)
+        if keep:
+            s_ref[0, c, 0] = S0c
+        outs = [_dot(gq_ref[c, h], S0c[:, h * dv:(h + 1) * dv], None, exact)
+                for h in range(r)]  # [G; q̃] · S₀
+        out = jnp.concatenate(outs, axis=1) if r > 1 else outs[0]
+        state[...] = kap_ref[c, :1, :] * S0 + c_ref[c] - out[:dk]
+        o_ref[0, rows, :] = out[dk:] + pu_ref[c]
+        return carry
+
+    jax.lax.fori_loop(0, nc, chunk, 0)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, s_ref, do_ref, dq_ref, dk_ref,
+                dv_ref, dgate_ref, dstate, m_ref, *, Q: int, r: int):
+    zr = pl.program_id(2)
+    nc = gate_ref.shape[2]
+    dv = v_ref.shape[2] // r
+    cd, f32 = q_ref.dtype, jnp.float32
+    exact = cd == f32
+
+    @pl.when(zr == 0)
+    def _():
+        dstate[...] = jnp.zeros(dstate.shape, f32)
+
+    *_, M, _ = _prepare(q_ref, k_ref, gate_ref, Q, r, False)
+    m_ref[...] = M
+
+    sub = jax.lax.broadcasted_iota(jnp.int32, (SUBLANE, Q), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (SUBLANE, Q), 1)
+    # the lanes of :func:`_lane_sums`' operand [Zs | Yp | d e | d t | d β]
+    # (a pair, a pair, three of a head's dv lanes) that make row 5 h + a
+    W = r * dv
+    ranges = [(first + h * width, width) for h in range(r)
+              for first, width in ((0, Q), (2 * Q, Q), (4 * Q, dv),
+                                   (4 * Q + W, dv), (4 * Q + 2 * W, dv))]
+
+    def chunk(ci, carry):
+        c = nc - 1 - ci
+        rows = pl.ds(pl.multiple_of(c * Q, Q), Q)
+        q, k = q_ref[0, rows, :], k_ref[0, rows, :]
+        kq = jnp.concatenate([k, q], axis=0)
+        k2 = jnp.concatenate([k, k], axis=0)
+        kf = k.astype(f32)
+        tile = gate_ref[0, 0, c]
+        cols = tile.T
+        b_c, D_lo, D_up = _pair_blocks(tile, cols, Q, r)
+        hd = _end_blocks(tile, cols, Q, r, dv)
+        kk, qk = _dot(k, k2, _NT, exact), _dot(q, k2, _NT, exact)
+        # ---- the forward's chunk again, from the kept state
+        S0c = s_ref[0, c, 0]
+        S0 = S0c.astype(f32)
+        v = v_ref[0, rows, :].astype(f32)
+        M = m_ref[c]
+        kqS = _dot(kq, S0c, None, exact)
+        kS, qS = kqS[:Q], kqS[Q:]
+        Rv = v - hd["e"] * kS  # R = β ⊙ Rv
+        delta = _dot(M, _blocks((hd["b"] * Rv).astype(cd), r, dv), None,
+                     exact).astype(cd)
+        deltas = _blocks(delta, r, dv)
+        P = qk * D_up
+        # ---- Δ enters o through P and the leaving state through t ⊙ k
+        do, dS1 = do_ref[0, rows, :].astype(f32), dstate[...]
+        doc, dS1c = do.astype(cd), dS1.astype(cd)
+        d_delta = (_diagonal(_dot(P.astype(cd), doc, _TN, exact), r, dv)
+                   + hd["t"] * _dot(k, dS1c, None, exact))
+        dP = _dot(doc, deltas, _NT, exact)  # [Q, 2 Q], a pair
+        dR = _diagonal(_dot(M, d_delta.astype(cd), _TN, exact), r, dv)
+        dA = -_dot(dR.astype(cd), deltas, _NT, exact)
+        Yp = dA * kk * D_lo  # d β's part through A, a row sum
+        Zs = dP * P + b_c * Yp  # d (c_i − c_j) of both decay blocks
+        dqk, dkk = (dP * D_up).astype(cd), (b_c * dA * D_lo).astype(cd)
+        dv_ref[0, rows, :] = (hd["b"] * dR).astype(dv_ref.dtype)
+        g_kS = (-(hd["b"] * hd["e"]) * dR).astype(cd)
+        g_qS = (hd["e"] * do).astype(cd)
+        dtk = _dot(deltas, dS1c, _NT, exact)  # [2 Q, dk]: Δ_h · dS₁_hᵀ
+        dtks = [dtk[h * Q:(h + 1) * Q] for h in range(r)]
+        # the products against a pair sum over its heads by themselves
+        halves = (_dot(dqk, q, _TN, exact) + _dot(dkk, k, _TN, exact))
+        dq_ref[0, rows, :] = (_dot(g_qS, S0c, _NT, exact)
+                              + _dot(dqk, k2, None, exact)).astype(
+                                  dq_ref.dtype)
+        dkey = (_dot(g_kS, S0c, _NT, exact) + _dot(dkk, k2, None, exact)
+                + halves[:Q] + halves[Q:])
+        for h in range(r):
+            dkey = dkey + hd["t_c"][h] * dtks[h]
+        dk_ref[0, rows, :] = dkey.astype(dk_ref.dtype)
+        # ---- sums over a token's channels, tokens in the lanes; row 5 h +
+        #      0 Σ_j Zs, 1 Σ_j Yp, 2 d e, 3 d t, 4 d β through R
+        sums = _lane_sums(jnp.concatenate(
+            [Zs, Yp, do * qS - hd["b"] * dR * kS,
+             jnp.concatenate([kf * d for d in dtks], axis=1) if r > 1
+             else kf * dtks[0], dR * Rv], axis=1), ranges, exact)
+        down = jnp.sum(Zs, axis=0, keepdims=True)  # [1, 2 Q]
+        held = jnp.sum(dS1 * S0, axis=0, keepdims=True)  # [1, r · dv]
+        out = jnp.zeros((SUBLANE, Q), f32)
+        for h in range(r):
+            at = 5 * h
+            d_kappa = jnp.sum(held[:, h * dv:(h + 1) * dv], axis=1,
+                              keepdims=True)  # [1, 1]
+            dt_t = sums[at + 3:at + 4] * hd["t_r"][h]
+            d_last = (jnp.sum(dt_t, axis=1, keepdims=True)
+                      + d_kappa * hd["kappa"][:, h * dv:h * dv + 1])
+            d_c = (sums[at:at + 1] - down[:, h * Q:(h + 1) * Q]
+                   + sums[at + 2:at + 3] * hd["e_r"][h] - dt_t)
+            d_c = d_c + jnp.where(lane[:1] == Q - 1, d_last, 0.0)
+            out = jnp.where(sub == h, d_c, out)
+            out = jnp.where(sub == r + h,
+                            sums[at + 1:at + 2] + sums[at + 4:at + 5], out)
+        dgate_ref[0, 0, c] = jnp.concatenate(
+            [out, jnp.zeros((SUBLANE, LANE - Q), f32)], axis=1)
+        dstate[...] = hd["kappa"] * dS1 + _dot(
+            kq, jnp.concatenate([g_kS, g_qS], axis=0), _TN, exact)
+        return carry
+
+    jax.lax.fori_loop(0, nc, chunk, 0)
+
+
+def _params(interpret: bool):
+    kw = dict(interpret=interpret)
+    if not interpret:
+        kw["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT)
+    return kw
+
+
+def gate_tiles(g, beta, seg, chunk: int, k_heads: int):
+    """g, beta [R, T, H] float32 and seg [R, T] (T whole chunks) -> [R, G,
+    chunks, 8, 128] float32, a chunk's tile a key head: row 0 the
+    cumulated g of the key head's r value heads, head h on lanes h · Q ..;
+    1 their β the same way; 2 the segment ids, twice; 3, 4 each head's
+    cumulated g at the chunk's end on every lane; 5 the second head's
+    cumulated g on the first lanes; 6, 7 the document the row is in
+    before the chunk (−1 in front of the first) and at its end, on every
+    lane."""
+    R, T, H = g.shape
+    Q, Z, G = chunk, T // chunk, k_heads
+    r = H // G
+    f32 = jnp.float32
+
+    def heads(a):  # [R, T, H] -> [R, G, Z, r, Q]
+        return a.astype(f32).reshape(R, Z, Q, G, r).transpose(0, 3, 1, 4, 2)
+
+    def lanes(a):  # [R, G, Z, rows, w] -> [R, G, Z, rows, 128], zeros behind
+        return jnp.pad(a, ((0, 0),) * 4 + ((0, LANE - a.shape[-1]),))
+
+    def every(a, rows=1):  # [R, Z] -> [R, G, Z, rows, 128], on every lane
+        return jnp.broadcast_to(a[:, None, :, None, None],
+                                (R, G, Z, rows, LANE))
+
+    cs = heads(chunk_cumsum(g.astype(f32), Q))
+    segz = seg.astype(jnp.int32).reshape(R, Z, Q).astype(f32)
+    last = segz[:, :, -1]
+    prev = jnp.pad(last, ((0, 0), (1, 0)), constant_values=-1.0)[:, :Z]
+    return jnp.concatenate(
+        [lanes(cs.reshape(R, G, Z, 1, r * Q)),
+         lanes(heads(beta).reshape(R, G, Z, 1, r * Q)),
+         lanes(jnp.broadcast_to(jnp.tile(segz, (1, 1, 2))[:, None, :, None],
+                                (R, G, Z, 1, 2 * Q))),
+         jnp.broadcast_to(cs[..., -1:], (R, G, Z, r, LANE)),
+         jnp.zeros((R, G, Z, _C_SECOND - _C_END - r, LANE), f32),
+         lanes(cs[:, :, :, 1:2]) if r > 1 else every(last * 0.0),
+         every(prev), every(last)], axis=3)
+
+
+def _dims(q, v, chunk: int):
+    R, T, G, dk = q.shape
+    H, dv = v.shape[2:]
+    Z = T // chunk
+    return R, T, G, H, dk, dv, H // G, Z, chunks_per_step(Z)
+
+
+def _specs(dims, Q: int, reverse: bool):
+    R, T, G, H, dk, dv, r, Z, nc = dims
+    steps = Z // nc
+
+    def at(z):
+        return steps - 1 - z if reverse else z
+
+    key = pl.BlockSpec((1, nc * Q, dk), lambda b, j, z, *_: (b, at(z), j))
+    val = pl.BlockSpec((1, nc * Q, r * dv), lambda b, j, z, *_: (b, at(z), j))
+    gate = pl.BlockSpec((1, 1, nc, SUBLANE, LANE),
+                        lambda b, j, z, *_: (b, j, at(z), 0, 0))
+    st = pl.BlockSpec((1, nc, 1, dk, r * dv),
+                      lambda b, j, z, *_: (b, at(z), j, 0, 0))
+    return key, val, gate, st, steps
+
+
+def rule_fwd(q, k, v, g, beta, seg, chunk: int, keep: bool = False,
+             interpret: bool = False):
+    """q, k [R, T, G, dk] and v [R, T, H, dv] in the compute dtype; g, beta
+    [R, T, H] float32; seg [R, T] int; T a whole number of chunks. Returns
+    (o [R, T, H, dv] float32, the state entering each chunk [R, chunks, G,
+    dk, r · dv] in the compute dtype or, without ``keep``, None)."""
+    dims = _dims(q, v, chunk)
+    R, T, G, H, dk, dv, r, Z, nc = dims
+    key, val, gate, st, steps = _specs(dims, chunk, reverse=False)
+    tile = gate_tiles(g, beta, seg, chunk, G)
+    f32 = jnp.float32
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, Q=chunk, r=r, keep=keep),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0, grid=(R, G, steps),
+            in_specs=[key, key, val, gate],
+            out_specs=[val] + ([st] if keep else []),
+            scratch_shapes=[pltpu.VMEM((dk, r * dv), f32),
+                            pltpu.VMEM((nc, r, dk + chunk, dk), q.dtype),
+                            pltpu.VMEM((nc, dk, r * dv), f32),
+                            pltpu.VMEM((nc, chunk, r * dv), f32),
+                            pltpu.VMEM((nc, SUBLANE, r * dv), f32)]),
+        out_shape=[jax.ShapeDtypeStruct((R, T, H * dv), f32)] + (
+            [jax.ShapeDtypeStruct((R, Z, G, dk, r * dv), q.dtype)]
+            if keep else []),
+        name=FWD_NAME, **_params(interpret),
+    )(q.reshape(R, T, G * dk), k.reshape(R, T, G * dk),
+      v.reshape(R, T, H * dv), tile)
+    return out[0].reshape(R, T, H, dv), (out[1] if keep else None)
+
+
+def rule_bwd(q, k, v, g, beta, seg, states, do, chunk: int,
+             interpret: bool = False):
+    """Gradients (dq, dk, dv in the compute dtype; dg, dbeta [R, T, H]
+    float32) from the forward's operands, its kept states and do."""
+    dims = _dims(q, v, chunk)
+    R, T, G, H, dk, dv, r, Z, nc = dims
+    key, val, gate, st, steps = _specs(dims, chunk, reverse=True)
+    tile = gate_tiles(g, beta, seg, chunk, G)
+    cd = q.dtype
+    dq, dkey, dval, dgate = pl.pallas_call(
+        functools.partial(_bwd_kernel, Q=chunk, r=r),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0, grid=(R, G, steps),
+            in_specs=[key, key, val, gate, st, val],
+            out_specs=[key, key, val, gate],
+            scratch_shapes=[pltpu.VMEM((dk, r * dv), jnp.float32),
+                            pltpu.VMEM((nc, chunk, 2 * chunk), cd)]),
+        out_shape=[jax.ShapeDtypeStruct((R, T, G * dk), cd),
+                   jax.ShapeDtypeStruct((R, T, G * dk), cd),
+                   jax.ShapeDtypeStruct((R, T, H * dv), cd),
+                   jax.ShapeDtypeStruct(tile.shape, jnp.float32)],
+        name=BWD_NAME, **_params(interpret),
+    )(q.reshape(R, T, G * dk), k.reshape(R, T, G * dk),
+      v.reshape(R, T, H * dv), tile, states, do.reshape(R, T, H * dv))
+
+    def tokens(a):  # [R, G, Z, r, Q] -> [R, Z, Q, H]
+        return a.transpose(0, 2, 4, 1, 3).reshape(R, Z, chunk, H)
+
+    dcs = tokens(dgate[:, :, :, :r, :chunk])
+    # the cumulated sum's transpose: a token's own and every later one of
+    # its chunk, as the chunk's total less the sum before it
+    dg = (jnp.sum(dcs, axis=2, keepdims=True) - jnp.cumsum(dcs, axis=2)
+          + dcs).reshape(R, T, H)
+    dbeta = tokens(dgate[:, :, :, r:2 * r, :chunk]).reshape(R, T, H)
+    return (dq.reshape(q.shape), dkey.reshape(k.shape), dval.reshape(v.shape),
+            dg, dbeta)

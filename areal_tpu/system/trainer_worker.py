@@ -369,6 +369,9 @@ class TrainerWorker:
             # {"pallas" | "pallas_interpret" | "xla": scans traced}: what
             # runs them (the kernel of ops/pallas/ssd_scan.py, or einsums)
             ssm_scan_impl=ssm.scan_impl_counts(),
+            # the same of the gated delta rules (ops/pallas/
+            # gated_delta_rule.py, or the XLA form of models/gdn.py)
+            gdn_rule_impl=gdn.rule_impl_counts(),
             # {"rows x length/dD nN/impl": scans traced}: a model's selective
             # scans (S6), and which form each runs as
             s6_geometry={"%dx%d/d%dn%d/%s" % geom: n

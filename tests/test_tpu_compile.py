@@ -98,6 +98,10 @@ SAMBAY_GRID = (1, 8192)
 # program's packed grid, at the published widths.
 WIDE_HEAD_T = (14336, 8704)
 QNEXT_GRID = (1, 16384)
+# ... and its gated delta rule alone, forward + backward: (rows, length,
+# key heads, value heads, head size, chunk), bfloat16 as the cell times it
+# and float32 as its ``rule_error`` calls it.
+GDN_RULE = (1, 16384, 16, 32, 128, 64)
 SSD_SCANS = {"ssd-scan-granite": (1, 7040, 32, 64, 1, 128, 256),
              "ssd-scan-nemotron": (1, 4096, 16, 64, 1, 128, 128)}
 
@@ -530,11 +534,31 @@ def _compile_all():
 
         return jax.value_and_grad(loss)(p)
 
+    def rule_loss(q, k, v, g, beta, seg, chunk):
+        return jnp.sum(gdnmod.gated_delta_rule(q, k, v, g, beta, seg, chunk,
+                                               "pallas") ** 2)
+
+    R, T, G, H, D, Q = GDN_RULE
+    for name, dt in (("gdn-rule-bfloat16", jnp.bfloat16),
+                     ("gdn-rule-float32", jnp.float32)):
+        record(name, jax.jit(
+            jax.value_and_grad(rule_loss, argnums=(0, 1, 2, 3, 4)),
+            static_argnums=6).lower(
+                f32(R, T, G, D, dtype=dt), f32(R, T, G, D, dtype=dt),
+                f32(R, T, H, D, dtype=dt), f32(R, T, H), f32(R, T, H),
+                f32(R, T, dtype=jnp.int32), Q).compile())
+
     rules = sum(gdnmod.geometry_counts().values())
-    record("qnext-cell", jax.jit(qnext_grad).lower(
-        params, tok, tok, tok).compile())
+    impls = dict(gdnmod.rule_impl_counts())
+    cell = jax.jit(qnext_grad).lower(params, tok, tok, tok).compile()
+    record("qnext-cell", cell)
     out["qnext-cell"].update(
         rules_traced=sum(gdnmod.geometry_counts().values()) - rules,
+        rule_impl={k: n - impls.get(k, 0)
+                   for k, n in gdnmod.rule_impl_counts().items()
+                   if n - impls.get(k, 0)},
+        rule_kernels=[n for n in ("gdn_rule_fwd", "gdn_rule_bwd")
+                      if n in cell.as_text()],
         param_bytes=18 * transformer.param_count(qnext))
     return out
 
@@ -791,6 +815,21 @@ def test_the_causal_kernel_compiles_at_heads_of_256(compiled, T):
     assert got["temp_bytes"] < 0.6e9
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_gated_delta_rule_kernels_compile_for_v5e(compiled, dtype):
+    """Forward and backward of the rule at the Qwen3-Next cell's geometry
+    (1 x 16,384, 16 / 32 heads of 128, chunk 64): two Mosaic kernels inside
+    the VMEM they ask for and nothing [chunk, chunk] beside them — the
+    temporaries are o in float32, the state entering each chunk in the
+    compute dtype, the gradients and the gates' tiles: under what a dozen
+    float32 [64, 64] blocks a chunk and value head take, which the XLA
+    form writes."""
+    got = compiled[f"gdn-rule-{dtype}"]
+    assert got["custom_calls"] == 2
+    R, T, G, H, D, Q = GDN_RULE
+    assert got["temp_bytes"] < R * T * H * Q * 4 * 12
+
+
 def test_the_qwen3_next_cut_compiles_inside_the_memory_it_leaves(compiled):
     """The grad program of the cut (16 of 512 experts held: 424.7 M
     parameters) on a 1 x 16,384 row, at the published widths, beside the
@@ -799,9 +838,17 @@ def test_the_qwen3_next_cut_compiles_inside_the_memory_it_leaves(compiled):
     program needs 4.9 GB beside 12.5 GB: PERF.md section 4)."""
     got = compiled["qnext-cell"]
     assert got["rules_traced"] == 1  # L L L is one run, scanned
+    # ... and it runs the kernel pair (the backward re-runs the forward)
+    assert got["rule_impl"] == {"pallas": 1}
+    assert got["rule_kernels"] == ["gdn_rule_fwd", "gdn_rule_bwd"]
     # attention: forward twice, dKV, dQ; the experts' grouped GEMMs
     assert got["custom_calls"] >= 4
-    assert got["temp_bytes"] < 5.0e9
+    # 4.70 GB in the XLA form (a quarter of the heads at a time under a
+    # checkpoint); 6.47 GB since the kernels run all heads at once and the
+    # mixer keeps what its convolution, gates and norms leave for the
+    # backward (a checkpoint around them holds 4.99 GB and costs the cell
+    # 3.8 % of its rate: PERF.md section 6, PR 53)
+    assert got["temp_bytes"] < 6.6e9
     gradient = got["param_bytes"] // 9  # 2 B a parameter
     assert got["param_bytes"] + gradient + got["temp_bytes"] < 15.0e9
 
