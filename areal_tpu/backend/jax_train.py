@@ -273,6 +273,9 @@ _MOE_LOCAL_LAYER_COPIES = 7
 # 2.34 GB for the Nemotron 3 share's forward + backward at 1 x 8192 /
 # 1 x 4096 under "full", less the kept layer inputs; PERF.md §5, PR 34).
 _LATENT_MOE_LAYER_COPIES = 11
+# The engine's side of a grid's plan that a grad program's label carries
+# into the compile ledger, beside the compiler's side.
+_PLAN_LABEL = ("kept_bytes_estimate", "budget_bytes", "reckoned_heap_bytes")
 
 
 # Bytes of inference results that may wait on the device for their fetch
@@ -754,15 +757,30 @@ class JaxTrainEngine(TrainableEngine):
         if limit is None:
             return 0
         params = _bytes_on_chip(self.params)
-        # a tree of the masters' shapes in the compute dtype: the copy
-        # (resident, unless the masters are it) and a program's gradient
-        masters = jax.tree.leaves(self.params)[0].dtype.itemsize
-        weights = params * self.compute_dtype.itemsize // masters
+        weights = self._weights_bytes()
         copy = 0 if self._copy_is_params else weights
         resident = 2 * params + _bytes_on_chip(self.opt_state) + copy
         free = limit * (1.0 - _LIMIT_MARGIN) - resident
         return int((free - self._remat_reserve_bytes(R, L, weights))
                    / _HEAP_PER_KEPT_BYTE)
+
+    def _weights_bytes(self) -> int:
+        """Bytes on one chip of a tree of the masters' shapes in the
+        compute dtype: the copy (resident, unless the masters are it) and
+        a program's gradient."""
+        masters = jax.tree.leaves(self.params)[0].dtype.itemsize
+        return (_bytes_on_chip(self.params) * self.compute_dtype.itemsize
+                // masters)
+
+    def _reckoned_heap_bytes(self, R: int, L: int, kept: int) -> int:
+        """What the budget's arithmetic takes a grad program of the grid
+        [R, L] that keeps ``kept`` bytes to need of the chip beside what
+        is resident: the sum :meth:`_remat_budget_bytes` sets against the
+        free bytes. It answers to the compiler's ``temp_bytes`` of that
+        program (the gradient carry it returns is counted resident) — the
+        compile ledger files both, ``remat_plan`` shows both."""
+        return int(kept * _HEAP_PER_KEPT_BYTE + self._remat_reserve_bytes(
+            R, L, self._weights_bytes()))
 
     def _remat_reserve_bytes(self, R: int, L: int, weights: int) -> int:
         """A grad program's temporaries besides the kept activations: the
@@ -836,7 +854,10 @@ class JaxTrainEngine(TrainableEngine):
             entry = "full" if pp_on else choose_remat(kept, budget)
             plan = self._remat_plan[(R, L)] = {
                 "entry": entry, "kept_bytes_estimate": kept[entry],
-                "budget_bytes": budget, "fell_back": False,
+                "budget_bytes": budget,
+                "reckoned_heap_bytes": self._reckoned_heap_bytes(
+                    R, L, kept[entry]),
+                "fell_back": False,
             }
             logger.info(f"remat plan for grid {R}x{L}: {plan}")
         return plan["entry"]
@@ -853,17 +874,43 @@ class JaxTrainEngine(TrainableEngine):
         logger.warning(
             f"grad program of grid {R}x{L} does not fit keeping "
             f"{plan['entry']!r}; falling back to {entry!r}")
-        plan.update(entry=entry, fell_back=True,
-                    kept_bytes_estimate=self._remat_kept_bytes(R, L)[entry])
+        kept = self._remat_kept_bytes(R, L)[entry]
+        # what was reckoned for the entry that did not fit stays on record
+        failed = plan.get("failed", []) + [
+            {k: plan[k] for k in ("entry",) + _PLAN_LABEL}]
+        plan.update(entry=entry, fell_back=True, kept_bytes_estimate=kept,
+                    reckoned_heap_bytes=self._reckoned_heap_bytes(R, L, kept),
+                    failed=failed)
         return True
 
     def remat_plan(self) -> Dict[str, Dict[str, Any]]:
-        """{"RxL": {entry, kept_bytes_estimate, budget_bytes, fell_back}}
-        of every packed grid a grad program was dispatched for (read like
+        """{"RxL": {entry, kept_bytes_estimate, budget_bytes,
+        reckoned_heap_bytes, fell_back}} of every packed grid a grad
+        program was dispatched for (read like
         window_attention.geometry_counts(); in the trainer worker's
-        ``device_report``). Empty with ``remat`` off."""
-        return {f"{R}x{L}": dict(plan)
-                for (R, L), plan in self._remat_plan.items()}
+        ``device_report``): the engine's side of the sum. Beside it, once
+        the grid's grad programs have compiled, the compiler's:
+        ``compiled`` = {temp_bytes, peak_bytes, cache} of the one that
+        needs most, as the compile ledger filed it under this grid's label
+        (absent on a backend that gives no statistics; engines of one
+        process that share a grid and an entry share the records).
+        ``failed``: the entries that did not fit, each with what was
+        reckoned for it. Empty with ``remat`` off."""
+        records = [r for r in compile_watch.executables("train_grad_sliced")
+                   if r["temp_bytes"] is not None]
+        out = {}
+        for (R, L), plan in self._remat_plan.items():
+            grid = out[f"{R}x{L}"] = dict(plan)
+            mine = [r for r in records
+                    if r["label"].get("grid") == f"{R}x{L}"
+                    and r["label"].get("remat") == plan["entry"]]
+            if mine:
+                top = max(mine, key=lambda r: r["temp_bytes"])
+                grid["compiled"] = {
+                    "temp_bytes": top["temp_bytes"],
+                    "peak_bytes": max(r["peak_bytes"] for r in mine),
+                    "cache": top["cache"]}
+        return out
 
     def _dispatch_grad(self, loss_fn: LossFn, args: list, carry,
                        R: int, L: int):
@@ -875,11 +922,17 @@ class JaxTrainEngine(TrainableEngine):
         if carry is not None:
             args = args + [carry]
         while True:
+            remat = self._remat_for(R, L)
+            fn = self._get_sliced_grad_fn(loss_fn, carry is not None, R, remat)
+            # which grid and entry this executable is, and what the
+            # engine reckoned for it (read only if jax compiles it now)
+            plan = self._remat_plan.get((R, L)) or {}
+            compile_watch.label(
+                "train_grad_sliced", grid=f"{R}x{L}", carry=carry is not None,
+                remat=remat, **{k: plan[k] for k in _PLAN_LABEL if k in plan})
             try:
                 with self._mesh_ctx(), dispatch_label("train"):
-                    return self._get_sliced_grad_fn(
-                        loss_fn, carry is not None, R,
-                        self._remat_for(R, L))(*args)
+                    return fn(*args)
             except jax.errors.JaxRuntimeError as e:
                 if ("RESOURCE_EXHAUSTED" not in str(e)
                         or not self._remat_fall_back(R, L)):
@@ -994,6 +1047,8 @@ class JaxTrainEngine(TrainableEngine):
                 k: jnp.asarray(v, jnp.float32)
                 for k, v in (scalars or {}).items()
             }
+            compile_watch.label("adv_prep", grid=f"{ub.R}x{ub.L}",
+                                n_mbs=ub.n_mbs)
             with self._mesh_ctx():
                 extra, out_scalars = self._grad_fns[key](ub.grids, ub.seq, sc)
             ub.grids.update(extra)
@@ -1150,6 +1205,7 @@ class JaxTrainEngine(TrainableEngine):
                 # A copy someone else holds (compute_params(share=True))
                 # is theirs to keep: not donated.
                 old_copy = None if self._compute_shared else self._compute
+                compile_watch.label("train_apply", skip_rule=rule is not None)
                 self._params, self.opt_state, self._compute, gnorm, \
                     applied = self._get_apply_fn(rule)(
                         self._params, self.opt_state, grads_acc,
@@ -1411,6 +1467,7 @@ class JaxTrainEngine(TrainableEngine):
                 "train/forward", jax.jit(infer_forward)
             )
         fn = self._fwd_fns[key]
+        hooked = post_hook is not None
         outs: List[np.ndarray] = []
         # Dispatched and not yet fetched, oldest first. A local of the
         # call: several threads run this method at once (algorithms/fused).
@@ -1443,7 +1500,12 @@ class JaxTrainEngine(TrainableEngine):
                 run_ahead += bool(pending)
                 with telemetry.span("infer/dispatch"), self._mesh_ctx(), \
                         dispatch_label("forward"):
-                    out = fn(self.compute_params(), db)
+                    params = self.compute_params()
+                    # which call-site state and grid this executable is
+                    compile_watch.label(
+                        "infer_forward", use_lp=use_lp, hook=hooked,
+                        grid="%dx%d" % mb.layout.shape)
+                    out = fn(params, db)
                 out.copy_to_host_async()
                 pending.append(out)
                 peak = max(peak, len(pending))

@@ -36,7 +36,12 @@ a first-class, alertable signal:
 The compile ledger (:class:`CacheStats`, :func:`cache_stats`) is the other
 instrument and needs no switch: :func:`enable_compilation_cache` arms it
 in every compiling process, and it runs only when jax traces, lowers or
-compiles. An enabled watch also gets each program's stage spans as
+compiles. It also keeps one record per EXECUTABLE: what the engine said of
+it before it dispatched it (:func:`label`: the packed grid, what the
+backward re-runs, the engine's own reckoning of its heap) and what the
+compiler says it needs (``get_compiled_memory_stats()`` of the executable
+the ``compile`` span produced — the program heap that ``memory_stats()``
+does not see). An enabled watch also gets each program's stage spans as
 ``compile/<stage>`` spans of its telemetry sink.
 
 Disabled contract (mirrors telemetry/goodput): until :func:`configure`
@@ -49,6 +54,7 @@ from __future__ import annotations
 
 import collections
 import os
+import re
 import threading
 import time
 import weakref
@@ -92,6 +98,57 @@ _CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
 # jax's compile paths run inside a ``compile`` span).
 UNNAMED_PROGRAM = "(unnamed)"
 SPAN_RING = 1024
+# What the compiler says an executable needs, under the ledger's names and
+# under ``CompiledMemoryStats``'. Bytes of ONE chip: an SPMD executable's
+# statistics are per device. ``temp_bytes`` is the program's heap (its
+# temporaries); ``peak_bytes`` the compiler's own peak of arguments,
+# outputs and temporaries alive at once.
+MEMORY_FIELDS = {
+    "temp_bytes": "temp_size_in_bytes",
+    "argument_bytes": "argument_size_in_bytes",
+    "output_bytes": "output_size_in_bytes",
+    "alias_bytes": "alias_size_in_bytes",
+    "code_bytes": "generated_code_size_in_bytes",
+    "peak_bytes": "peak_memory_in_bytes",
+}
+
+
+_MODULE_NAME_RE = re.compile(r"[^\w.-]")
+
+
+def _module_name(fun_name: str) -> str:
+    """The name jax gives the HLO module of a lower / compile span's
+    ``fun_name`` (interpreters/mlir.py): ``jit_train_apply`` for
+    ``jit(train_apply)``."""
+    return _MODULE_NAME_RE.sub("_", fun_name).rstrip("_")
+
+
+def jax_live_executables() -> list:
+    """The default backend's live executables (newest first), as the
+    client lists them: microseconds, no compile."""
+    from jax.extend import backend
+
+    return backend.get_backend().live_executables()
+
+
+_LABEL = threading.local()
+_MISSING = object()
+
+
+def label(fn: str, **fields: Any) -> None:
+    """What the caller knows of the program ``fn`` (the jitted function's
+    name) that it is about to dispatch on this thread, and jax's name for
+    it does not say: the packed grid, what its backward re-runs, what the
+    engine reckons it needs. One attribute store; READ only if jax then
+    compiles ``fn`` on this thread (the executable's record keeps it), and
+    replaced by the next store. A compile of another program, or on
+    another thread, does not see it."""
+    _LABEL.value = (fn, fields)
+
+
+def _label_of(fn: str) -> Dict[str, Any]:
+    value = getattr(_LABEL, "value", None)
+    return dict(value[1]) if value is not None and value[0] == fn else {}
 
 
 def _program_name(fun_name: str) -> str:
@@ -110,12 +167,15 @@ class _OpenSpan:
     the cache traffic under it."""
 
     __slots__ = ("stage", "fn", "t_start", "n_children", "secs", "hits",
-                 "misses", "cache_read_secs")
+                 "misses", "cache_read_secs", "before")
 
     def __init__(self, stage: str, fn: str, t_start: float) -> None:
         self.stage = stage
         self.fn = fn
         self.t_start = t_start
+        # a program's own compile span: the executables that lived when
+        # it began ({id: fingerprint}); None: not known
+        self.before: Optional[Dict[int, Any]] = None
         self.n_children = 0
         self.secs = {"trace_secs": 0.0, "lower_secs": 0.0,
                      "compile_secs": 0.0}
@@ -150,9 +210,29 @@ class CacheStats:
     ``programs[fn]`` adds ``n_trace`` / ``n_lower`` / ``n_compile`` (the
     program's own spans: ``n_compile`` is how many executables it needed),
     ``n_children`` (spans folded into them) and ``max_secs`` (the largest
-    trace + lower + compile of ONE of its compilations)."""
+    trace + lower + compile of ONE of its compilations).
 
-    def __init__(self) -> None:
+    One record per EXECUTABLE, for good, in ``programs[fn]["executables"]``
+    in the order compiled: ``label`` (what :func:`label` said of ``fn`` on
+    the compiling thread: which grid it is), ``cache`` (``hit`` / ``miss``
+    / ``uncached``), ``secs`` (that compilation's trace + lower + compile)
+    and the compiler's ``MEMORY_FIELDS`` of the executable the ``compile``
+    span produced — the one ``live_executables()`` lists when the span
+    ends and did not when it began (where several were born meanwhile, on
+    other threads, the one of its module name); one the client lists
+    late is found at the next :meth:`as_dict`. Until then, and on a
+    backend that gives no statistics, the fields are None
+    (``executables_unmatched`` counts those still looked for). The row
+    keeps ``max_temp_bytes`` / ``max_peak_bytes``, the totals
+    ``max_temp_bytes`` and the ``max_temp_program`` that holds it;
+    ``executables_read_secs`` is what the looking and reading cost. The
+    ring's entry of that ``compile`` span carries the same fields.
+
+    ``live_executables``: the backend's list (:func:`jax_live_executables`
+    for the process's ledger); None keeps the byte fields None."""
+
+    def __init__(self, live_executables: Optional[Callable[[], list]] = None,
+                 ) -> None:
         self.hits = 0
         self.misses = 0
         self.secs = {"trace_secs": 0.0, "lower_secs": 0.0,
@@ -163,6 +243,22 @@ class CacheStats:
             maxlen=SPAN_RING)
         self._lock = threading.Lock()
         self._local = threading.local()
+        self._live = live_executables
+        self._exe_lock = threading.Lock()
+        # {id(executable): fingerprint} of those a record has claimed, and
+        # {id: (fingerprint, module name)} of unclaimed ones whose name was
+        # read (``hlo_modules()`` parses the program: 70 ms for a grad
+        # program on a TPU, so only where the birth alone does not decide).
+        # No reference is kept: an executable dies with its jit, and a new
+        # one at its address has another fingerprint.
+        self._claimed: Dict[int, Any] = {}
+        self._names: Dict[int, Tuple[Any, str]] = {}
+        # (module name, fn, record, ring entry, executables that lived
+        # before its compile span) compiled and not found yet
+        self._pending: list = []
+        self.max_temp_bytes: Optional[int] = None
+        self.max_temp_program: Optional[Dict[str, Any]] = None
+        self.executables_read_secs = 0.0
 
     def _thread(self):
         """This thread's open spans, the running seconds of each program's
@@ -184,7 +280,11 @@ class CacheStats:
                   **_: Any) -> None:
         stage = _STAGES.get(event)
         if stage is not None:
-            self._thread().open.append(_OpenSpan(stage, fun_name, value))
+            t = self._thread()
+            span = _OpenSpan(stage, fun_name, value)
+            if stage == "compile" and not t.open:
+                span.before = self._alive()
+            t.open.append(span)
 
     def _on_event(self, event: str, **_: Any) -> None:
         if event == _CACHE_HIT:
@@ -252,6 +352,8 @@ class CacheStats:
                 "n_trace": 0, "n_lower": 0, "n_compile": 0, "n_children": 0,
                 "hits": 0, "misses": 0, "trace_secs": 0.0, "lower_secs": 0.0,
                 "compile_secs": 0.0, "cache_read_secs": 0.0, "max_secs": 0.0,
+                "executables": [], "max_temp_bytes": None,
+                "max_peak_bytes": None,
             }
         return row
 
@@ -271,12 +373,20 @@ class CacheStats:
         # and a compile, one after the other on one thread.
         running = secs + (0.0 if span.stage == "trace"
                           else t.compiling.get(fn, 0.0))
+        record = None
         if span.stage == "compile":
             t.compiling.pop(fn, None)
+            record = {"label": _label_of(fn), "cache": entry["cache"],
+                      "secs": round(running, 6),
+                      **dict.fromkeys(MEMORY_FIELDS)}
+            entry["label"] = dict(record["label"])
+            entry.update(dict.fromkeys(MEMORY_FIELDS))
         else:
             t.compiling[fn] = running
         with self._lock:
             row = self._row(fn)
+            if record is not None:
+                row["executables"].append(record)
             row["n_" + span.stage] += 1
             row["n_children"] += span.n_children
             for key, v in span.secs.items():
@@ -288,14 +398,128 @@ class CacheStats:
             row["max_secs"] = max(row["max_secs"], running)
             self.busy_secs += secs
             self.spans.append(entry)
+        if record is not None:
+            self._reconcile((_module_name(span.fn), fn, record, entry,
+                             span.before))
         for watch in list(_WATCHES):
             watch._on_stage_span(entry)
+
+    # ---- what the compiler says each executable needs ----
+
+    def _alive(self) -> Optional[Dict[int, Any]]:
+        """{id: fingerprint} of the executables the backend lists now;
+        None where there is none to ask."""
+        if self._live is None:
+            return None
+        t0 = time.perf_counter()
+        try:
+            return {id(exe): exe.fingerprint for exe in self._live()}
+        except Exception as e:  # noqa: BLE001 — whatever the backend raises
+            self._degrade(e)
+            return None
+        finally:
+            with self._lock:
+                self.executables_read_secs += time.perf_counter() - t0
+
+    def _degrade(self, err: Exception) -> None:
+        logger.warning(
+            "the backend's executables give no memory statistics "
+            f"({err!r}): the compile ledger's byte fields stay null")
+        self._live = None
+        self._pending = []
+
+    def _born_since(self, live: list, before: Optional[Dict[int, Any]],
+                    module: str, by_name: bool):
+        """The oldest executable of ``live`` (newest first) that no record
+        has claimed and that was not alive in ``before``; where that
+        leaves several, or ``by_name``, the oldest whose module is
+        ``module``. None: not listed (yet)."""
+        born = []
+        for exe in reversed(live):
+            key, fingerprint = id(exe), exe.fingerprint
+            if (self._claimed.get(key, _MISSING) != fingerprint
+                    and (before is None
+                         or before.get(key, _MISSING) != fingerprint)):
+                born.append(exe)
+        if len(born) == 1 and before is not None and not by_name:
+            return born[0]
+        for exe in born:
+            known = self._names.get(id(exe))
+            if known is None or known[0] != exe.fingerprint:
+                known = self._names[id(exe)] = (
+                    exe.fingerprint, exe.hlo_modules()[0].name)
+            if known[1] == module:
+                return exe
+        return None
+
+    def _reconcile(self, born: Optional[tuple] = None) -> None:
+        """Give every record that waits for its executable (``born``: the
+        one whose ``compile`` span just ended) the compiler's statistics
+        of the executable born inside its span, in compile order. One
+        that is not listed yet keeps waiting for the next call; a backend
+        that gives no statistics is asked once."""
+        if self._live is None or (born is None and not self._pending):
+            return
+        t0 = time.perf_counter()
+        found = []
+        with self._exe_lock:
+            if self._live is None:
+                return
+            waiting = self._pending + ([born] if born is not None else [])
+            self._pending = []
+            try:
+                live = self._live()
+                for item in waiting:
+                    # the record whose span just ended takes the one
+                    # executable born in it; a record that waited, or
+                    # one behind others, goes by the module's name
+                    exe = self._born_since(
+                        live, item[4], item[0],
+                        by_name=item is not born or len(waiting) > 1)
+                    if exe is None:
+                        self._pending.append(item)
+                        continue
+                    stats = exe.get_compiled_memory_stats()
+                    found.append((item, {
+                        k: int(getattr(stats, attr))
+                        for k, attr in MEMORY_FIELDS.items()}))
+                    self._claimed[id(exe)] = exe.fingerprint
+                alive = {id(exe) for exe in live}
+                for table in (self._claimed, self._names):
+                    for key in [k for k in table if k not in alive]:
+                        del table[key]
+            except Exception as e:  # noqa: BLE001 — whatever the backend raises
+                self._degrade(e)
+                return
+        with self._lock:
+            for (_, fn, record, entry, _), stats in found:
+                record.update(stats)
+                entry.update(stats)
+                row = self.programs[fn]
+                for key in ("temp_bytes", "peak_bytes"):
+                    row["max_" + key] = max(row["max_" + key] or 0,
+                                            stats[key])
+                if (self.max_temp_bytes is None
+                        or stats["temp_bytes"] > self.max_temp_bytes):
+                    self.max_temp_bytes = stats["temp_bytes"]
+                    self.max_temp_program = {"fn": fn,
+                                             "label": record["label"]}
+            self.executables_read_secs += time.perf_counter() - t0
+
+    def executables(self, fn: str) -> list:
+        """Copies of the executables' records of program ``fn``, in the
+        order compiled."""
+        self._reconcile()
+        with self._lock:
+            row = self.programs.get(fn)
+            return [] if row is None else _copy_records(row["executables"])
 
     def as_dict(self) -> Dict[str, Any]:
         """Plain data (the drivers and ``/metrics.json`` dump it as JSON):
         the seven keys this has always had, ``busy_secs``, ``programs``
         and ``spans`` (oldest first; in each ``fn`` is its ``programs``
-        key)."""
+        key), then the executables' totals."""
+        self._reconcile()
         with self._lock:
             return {
                 "dir": compilation_cache_dir(), "hits": self.hits,
@@ -305,9 +529,24 @@ class CacheStats:
                 "programs": {
                     fn: {k: round(v, 6) if isinstance(v, float) else v
                          for k, v in row.items()}
+                    | {"executables": _copy_records(row["executables"])}
                     for fn, row in self.programs.items()},
-                "spans": [dict(e) for e in self.spans],
+                "spans": _copy_records(self.spans),
+                "max_temp_bytes": self.max_temp_bytes,
+                "max_temp_program": self.max_temp_program and dict(
+                    self.max_temp_program,
+                    label=dict(self.max_temp_program["label"])),
+                "executables_unmatched": len(self._pending),
+                "executables_read_secs": round(
+                    self.executables_read_secs, 6),
             }
+
+
+def _copy_records(records) -> list:
+    """Snapshots of ring entries or executables' records (a ``label`` is a
+    flat dict of its own)."""
+    return [dict(r, label=dict(r["label"])) if "label" in r else dict(r)
+            for r in records]
 
 
 _CACHE_STATS: Optional[CacheStats] = None
@@ -320,6 +559,12 @@ def cache_stats() -> Optional[Dict[str, Any]]:
     """The compile ledger of this process so far (CacheStats.as_dict);
     None where :func:`enable_compilation_cache` never ran."""
     return _CACHE_STATS.as_dict() if _CACHE_STATS is not None else None
+
+
+def executables(fn: str) -> list:
+    """The ledger's records of program ``fn``'s executables, in the order
+    compiled (CacheStats.executables); [] where no ledger runs."""
+    return _CACHE_STATS.executables(fn) if _CACHE_STATS is not None else []
 
 
 def enable_compilation_cache() -> None:
@@ -342,7 +587,7 @@ def enable_compilation_cache() -> None:
     # spawns several processes that compile the same small graphs.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    _CACHE_STATS = CacheStats()
+    _CACHE_STATS = CacheStats(jax_live_executables)
     jax.monitoring.register_scalar_listener(_CACHE_STATS._on_enter)
     jax.monitoring.register_event_time_span_listener(_CACHE_STATS._on_span)
     jax.monitoring.register_event_listener(_CACHE_STATS._on_event)
@@ -547,6 +792,10 @@ class CompileWatch:
         attrs = {"fn": entry["fn"]}
         if "cache" in entry:
             attrs["cache"] = entry["cache"]
+            # the executable's label and bytes, where it was found
+            attrs.update(entry["label"])
+            attrs.update({k: entry[k] for k in MEMORY_FIELDS
+                          if entry[k] is not None})
         self.tel.add_span("compile/" + entry["stage"], entry["t_start"],
                           entry["secs"], **attrs)
 

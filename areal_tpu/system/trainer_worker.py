@@ -409,7 +409,10 @@ class TrainerWorker:
                 if hasattr(getattr(m.module, "cfg", None), "block_counts")
             },
             # {model: {"RxL": {entry, kept_bytes_estimate, budget_bytes,
-            # fell_back}}}: what each grid's backward pass re-runs
+            # reckoned_heap_bytes, fell_back, compiled: {temp_bytes,
+            # peak_bytes, cache}}}}: what each grid's backward pass
+            # re-runs, the engine's reckoning of its grad program's heap
+            # and, beside it, what the compiler says that program needs
             remat_plan={
                 role: m.module.remat_plan() for role, m in self.models.items()
                 if hasattr(m.module, "remat_plan")
@@ -431,8 +434,9 @@ class TrainerWorker:
                 if hasattr(m.module, "infer_run_ahead")
             },
             # the compile ledger less its ring of spans (a log line: the
-            # per-program table says what start-up cost; /metrics.json
-            # and telemetry.jsonl carry the spans)
+            # per-program table says what start-up cost and, a record an
+            # executable, what each program needs of the chip;
+            # /metrics.json and telemetry.jsonl carry the spans)
             compile_cache={k: v for k, v in
                            (compile_watch.cache_stats() or {}).items()
                            if k != "spans"} or None,

@@ -200,6 +200,11 @@ MISS = "/jax/compilation_cache/cache_misses"
 READ = "/jax/compilation_cache/cache_retrieval_time_sec"
 OLD_KEYS = ["dir", "hits", "misses", "trace_secs", "lower_secs",
             "compile_secs", "cache_read_secs"]
+OLD_ROW_KEYS = ["n_trace", "n_lower", "n_compile", "n_children", "hits",
+                "misses", "trace_secs", "lower_secs", "compile_secs",
+                "cache_read_secs", "max_secs"]
+NEW_TOTALS = {"max_temp_bytes", "max_temp_program", "executables_unmatched",
+              "executables_read_secs"}
 
 
 class Feed:
@@ -231,16 +236,19 @@ class Feed:
         self.ledger._on_event(MISS)
 
     def program(self, fn, t, trace=1.0, lower=0.5, compile=2.0,
-                read_secs=None):
+                read_secs=None, inside=()):
         """One compilation of ``fn`` from time ``t``: a miss, or a hit
-        that took ``read_secs`` to read; returns when it ended."""
+        that took ``read_secs`` to read; ``inside`` the compile call,
+        more steps (the backend gives birth to the executable there);
+        returns when it ended."""
         outcome = (self.miss if read_secs is None
                    else lambda: self.hit(read_secs))
         self.stage(TRACE, fn, t, t + trace)
         t += trace
         self.stage(LOWER, f"jit({fn})", t, t + lower)
         t += lower
-        self.stage(COMPILE, f"jit({fn})", t, t + compile, [outcome])
+        self.stage(COMPILE, f"jit({fn})", t, t + compile,
+                   [outcome, *inside])
         return t + compile
 
 
@@ -425,8 +433,11 @@ def test_cache_stats_keeps_its_keys_and_is_plain_data(monkeypatch):
     monkeypatch.setattr(cw, "_CACHE_STATS", ledger)
     Feed(ledger).program("adv_prep", 1.5e9, read_secs=0.01)
     d = cw.cache_stats()
-    assert list(d)[:7] == OLD_KEYS
-    assert set(d) == set(OLD_KEYS) | {"busy_secs", "programs", "spans"}
+    assert list(d)[:10] == OLD_KEYS + ["busy_secs", "programs", "spans"]
+    assert set(d) == set(list(d)[:10]) | NEW_TOTALS
+    assert list(d["programs"]["adv_prep"])[:11] == OLD_ROW_KEYS
+    assert set(d["programs"]["adv_prep"]) == set(OLD_ROW_KEYS) | {
+        "executables", "max_temp_bytes", "max_peak_bytes"}
     assert json.loads(json.dumps(d)) == d
     # a snapshot: what the ledger files later does not reach into it
     Feed(ledger).program("adv_prep", 1.5e9 + 10)
@@ -503,7 +514,7 @@ def jax_ledger(tmp_path):
     import jax
     from jax.experimental.compilation_cache import compilation_cache as cc
 
-    ledger = cw.CacheStats()
+    ledger = cw.CacheStats(cw.jax_live_executables)
     jax.monitoring.register_scalar_listener(ledger._on_enter)
     jax.monitoring.register_event_time_span_listener(ledger._on_span)
     jax.monitoring.register_event_listener(ledger._on_event)
@@ -576,6 +587,368 @@ def test_ledger_under_jax_miss_then_hit_then_nothing(jax_ledger):
     for key in ("hits", "misses"):
         assert d[key] == sum(r[key] for r in d["programs"].values())
     assert json.loads(json.dumps(d)) == d
+
+
+# ---------------------------------------------------------------------------
+# one record per executable: the label and the compiler's statistics
+# ---------------------------------------------------------------------------
+
+
+class FakeStats:
+    def __init__(self, temp, peak=None):
+        self.temp_size_in_bytes = temp
+        self.argument_size_in_bytes = 16
+        self.output_size_in_bytes = 8
+        self.alias_size_in_bytes = 4
+        self.generated_code_size_in_bytes = 2
+        self.peak_memory_in_bytes = temp + 20 if peak is None else peak
+
+
+class FakeExecutable:
+    """LoadedExecutable stand-in: a module name, a fingerprint and what
+    ``get_compiled_memory_stats()`` answers (an exception is raised)."""
+
+    def __init__(self, name, stats, fingerprint=None):
+        self.name, self.stats = name, stats
+        self.fingerprint = fingerprint or name.encode()
+        self.asked = self.parsed = 0
+
+    def hlo_modules(self):
+        self.parsed += 1
+        return [self]
+
+    def get_compiled_memory_stats(self):
+        self.asked += 1
+        if isinstance(self.stats, Exception):
+            raise self.stats
+        return self.stats
+
+
+class FakeBackend:
+    """``live`` is the client's list, newest first like jax's."""
+
+    def __init__(self):
+        self.live, self.calls = [], 0
+
+    def __call__(self):
+        self.calls += 1
+        return list(self.live)
+
+    def born(self, *exes):
+        self.live[:0] = reversed(exes)
+
+    def bears(self, *exes):
+        """A step for ``Feed.program(inside=...)``: the compile call
+        leaves these executables behind."""
+        return [lambda: self.born(*exes)]
+
+
+BYTES = list(cw.MEMORY_FIELDS)
+
+
+def test_executable_record_holds_label_outcome_and_bytes():
+    backend = FakeBackend()
+    ledger = cw.CacheStats(backend)
+    feed = Feed(ledger)
+    cw.label("train_grad_sliced", grid="2x128", remat="full",
+             reckoned_heap_bytes=1000)
+    backend.born(FakeExecutable("jit_sin", FakeStats(9)))  # lived before
+    end = feed.program("train_grad_sliced", 10.0, inside=backend.bears(
+        FakeExecutable("jit_train_grad_sliced", FakeStats(700))))
+    feed.program("convert_element_type", end, read_secs=0.1,
+                 inside=backend.bears(
+                     FakeExecutable("jit_convert_element_type", FakeStats(0))))
+    d = ledger.as_dict()
+    (rec,) = d["programs"]["train_grad_sliced"]["executables"]
+    assert rec == {
+        "label": {"grid": "2x128", "remat": "full",
+                  "reckoned_heap_bytes": 1000},
+        "cache": "miss", "secs": 3.5, "temp_bytes": 700,
+        "argument_bytes": 16, "output_bytes": 8, "alias_bytes": 4,
+        "code_bytes": 2, "peak_bytes": 720}
+    # a compile of another program does not read the label
+    (other,) = d["programs"]["convert_element_type"]["executables"]
+    assert other["label"] == {} and other["cache"] == "hit"
+    assert other["temp_bytes"] == 0
+    row = d["programs"]["train_grad_sliced"]
+    assert (row["max_temp_bytes"], row["max_peak_bytes"]) == (700, 720)
+    assert d["max_temp_bytes"] == 700
+    assert d["max_temp_program"] == {"fn": "train_grad_sliced",
+                                     "label": rec["label"]}
+    assert d["executables_unmatched"] == 0
+    # the ring's entry of that compile span carries the same fields
+    ring = [e for e in d["spans"] if e["fn"] == "train_grad_sliced"
+            and e["stage"] == "compile"][0]
+    assert {k: ring[k] for k in ["label"] + BYTES} == {
+        k: rec[k] for k in ["label"] + BYTES}
+    assert all("label" not in e for e in d["spans"]
+               if e["stage"] != "compile")
+    assert json.loads(json.dumps(d)) == d
+
+
+def test_one_name_at_two_grids_gives_two_records_in_compile_order():
+    backend = FakeBackend()
+    ledger = cw.CacheStats(backend)
+    feed = Feed(ledger)
+    t = 0.0
+    for grid, temp in (("1x256", 300), ("1x512", 900), ("1x128", 100)):
+        cw.label("infer_forward", grid=grid, use_lp=True)
+        t = feed.program("infer_forward", t, inside=backend.bears(
+            FakeExecutable("jit_infer_forward", FakeStats(temp),
+                           fingerprint=grid.encode())))
+    assert [(r["label"]["grid"], r["temp_bytes"])
+            for r in ledger.executables("infer_forward")] == [
+        ("1x256", 300), ("1x512", 900), ("1x128", 100)]
+    d = ledger.as_dict()
+    assert d["programs"]["infer_forward"]["max_temp_bytes"] == 900
+    assert d["max_temp_program"]["label"]["grid"] == "1x512"
+    # every executable was read once, however often the list was walked,
+    # and none was parsed for its name: its birth in the span says whose
+    assert [exe.asked for exe in backend.live] == [1, 1, 1]
+    assert [exe.parsed for exe in backend.live] == [0, 0, 0]
+    # a snapshot: the caller's copy is its own
+    ledger.executables("infer_forward")[0]["label"]["grid"] = "mine"
+    assert ledger.executables("infer_forward")[0]["label"]["grid"] == "1x256"
+    assert ledger.executables("never_compiled") == []
+
+
+def test_label_stays_on_its_thread_and_the_next_store_replaces_it():
+    import threading
+
+    ledger = cw.CacheStats()
+    feed = Feed(ledger)
+    cw.label("train_grad_sliced", grid="4x64")
+    worker = threading.Thread(
+        target=lambda: feed.program("train_grad_sliced", 0.0))
+    worker.start()
+    worker.join()
+    feed.program("train_grad_sliced", 10.0)
+    cw.label("train_grad_sliced", grid="8x64", remat=False)
+    feed.program("train_grad_sliced", 20.0)
+    cw.label("infer_forward", grid="8x64")  # the stale label is gone
+    feed.program("train_grad_sliced", 30.0)
+    assert [r["label"] for r in ledger.executables("train_grad_sliced")] == [
+        {}, {"grid": "4x64"}, {"grid": "8x64", "remat": False}, {}]
+
+
+def test_several_born_in_one_span_are_told_apart_by_module_name():
+    """Another thread's compile ends inside this one's: two executables
+    are new when the span ends, and only then is a name read."""
+    backend = FakeBackend()
+    ledger = cw.CacheStats(backend)
+    theirs = FakeExecutable("jit_infer_forward", FakeStats(11))
+    mine = FakeExecutable("jit_train_apply", FakeStats(22))
+    Feed(ledger).program("train_apply", 0.0,
+                         inside=backend.bears(theirs, mine))
+    (rec,) = ledger.executables("train_apply")
+    assert rec["temp_bytes"] == 22
+    assert (theirs.asked, mine.asked) == (0, 1)
+    assert (theirs.parsed, mine.parsed) == (1, 1)
+    # the other one is still there for its own record, its name kept
+    Feed(ledger).program("infer_forward", 10.0)
+    assert ledger.executables("infer_forward")[0]["temp_bytes"] is None
+    ledger._pending[0] = ledger._pending[0][:4] + (None,)  # span unseen
+    assert ledger.executables("infer_forward")[0]["temp_bytes"] == 11
+    assert theirs.parsed == 1 and ledger.as_dict()[
+        "executables_unmatched"] == 0
+
+
+def test_threads_compiling_at_once_each_get_their_own_executable():
+    """More threads than cores compile programs of their own names at
+    once: births interleave inside each other's spans, and every record
+    still ends with its own executable's bytes, claimed once."""
+    import sys
+    import threading
+
+    backend = FakeBackend()
+    lock = threading.Lock()
+    ledger = cw.CacheStats(backend)
+    n_threads, n_programs = 12, 40
+    start = threading.Barrier(n_threads)
+
+    def worker(k):
+        feed = Feed(ledger)
+        start.wait(10)
+        t = 1000.0 * k
+        for i in range(n_programs):
+            exe = FakeExecutable(f"jit_step{k}", FakeStats(1000 * k + i),
+                                 fingerprint=f"{k}/{i}".encode())
+
+            def bear(exe=exe):
+                with lock:  # the client's list is its own to keep whole
+                    backend.born(exe)
+
+            cw.label(f"step{k}", i=i)
+            t = feed.program(f"step{k}", t, inside=[bear])
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=worker, args=(k,))
+                   for k in range(n_threads)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(th.is_alive() for th in threads)
+    d = ledger.as_dict()
+    assert d["executables_unmatched"] == 0
+    for k in range(n_threads):
+        assert [(r["label"]["i"], r["temp_bytes"]) for r in
+                d["programs"][f"step{k}"]["executables"]] == [
+            (i, 1000 * k + i) for i in range(n_programs)]
+    assert all(exe.asked == 1 for exe in backend.live)
+    assert d["max_temp_bytes"] == 1000 * (n_threads - 1) + n_programs - 1
+
+
+def test_executable_listed_late_is_reconciled_in_compile_order():
+    """The client lists an executable only after the span's listener ran:
+    the record waits, the next dump finds it; one that never shows keeps
+    its nulls and is counted."""
+    backend = FakeBackend()
+    ledger = cw.CacheStats(backend)
+    feed = Feed(ledger)
+    t = 0.0
+    for grid in ("1x64", "1x128", "1x256"):
+        cw.label("train_grad_sliced", grid=grid)
+        t = feed.program("train_grad_sliced", t)
+    d = ledger.as_dict()
+    assert d["executables_unmatched"] == 3 and d["max_temp_bytes"] is None
+    assert all(r["temp_bytes"] is None
+               for r in d["programs"]["train_grad_sliced"]["executables"])
+    # two of the three show up, oldest first in the client's list
+    backend.born(FakeExecutable("jit_train_grad_sliced", FakeStats(64),
+                                fingerprint=b"a"),
+                 FakeExecutable("jit_train_grad_sliced", FakeStats(128),
+                                fingerprint=b"b"),
+                 FakeExecutable("jit_sin", FakeStats(1)))  # nobody's
+    d = ledger.as_dict()
+    assert [(r["label"]["grid"], r["temp_bytes"]) for r in
+            d["programs"]["train_grad_sliced"]["executables"]] == [
+        ("1x64", 64), ("1x128", 128), ("1x256", None)]
+    assert d["executables_unmatched"] == 1 and d["max_temp_bytes"] == 128
+    ring = [e["temp_bytes"] for e in d["spans"] if e["stage"] == "compile"]
+    assert ring == [64, 128, None]
+    assert json.loads(json.dumps(d)) == d
+    assert d["executables_read_secs"] > 0
+
+
+@pytest.mark.parametrize("answer", [RuntimeError("UNIMPLEMENTED"), None],
+                         ids=["raises", "none"])
+def test_backend_without_statistics_leaves_nulls_and_is_asked_once(answer):
+    backend = FakeBackend()
+    ledger = cw.CacheStats(backend)
+    feed = Feed(ledger)
+    exe = FakeExecutable("jit_train_apply", answer)
+    t = feed.program("train_apply", 0.0, inside=backend.bears(exe))
+    calls = backend.calls
+    feed.program("train_apply", t, inside=backend.bears(
+        FakeExecutable("jit_train_apply", answer, fingerprint=b"2")))
+    d = ledger.as_dict()
+    assert exe.asked == 1 and backend.calls == calls
+    assert backend.live[0].asked == 0
+    recs = d["programs"]["train_apply"]["executables"]
+    assert len(recs) == 2
+    assert all(r[k] is None for r in recs for k in BYTES)
+    assert d["programs"]["train_apply"]["max_temp_bytes"] is None
+    assert d["max_temp_bytes"] is None and d["executables_unmatched"] == 0
+    assert json.loads(json.dumps(d)) == d
+
+
+def test_ledger_without_a_backend_files_records_and_asks_nothing():
+    ledger = cw.CacheStats()
+    cw.label("opt_init", note="x")
+    Feed(ledger).program("opt_init", 0.0)
+    (rec,) = ledger.executables("opt_init")
+    assert rec["label"] == {"note": "x"} and rec["cache"] == "miss"
+    assert all(rec[k] is None for k in BYTES)
+    assert ledger.as_dict()["executables_unmatched"] == 0
+
+
+def test_stage_span_attrs_carry_the_executables_label_and_bytes():
+    backend = FakeBackend()
+    ledger = cw.CacheStats(backend)
+    watch, reg, t = make_watch()
+    cw.label("infer_forward", grid="1x64", hook=True)
+    Feed(ledger).program(
+        "infer_forward", 1000.0, read_secs=0.05, inside=backend.bears(
+            FakeExecutable("jit_infer_forward", FakeStats(5))))
+    spans = [s for s in reg.snapshot(reset=False)["spans"]
+             if s["name"] == "compile/compile"]
+    assert [s["attrs"] for s in spans] == [{
+        "fn": "infer_forward", "cache": "hit", "grid": "1x64", "hook": True,
+        "temp_bytes": 5, "argument_bytes": 16, "output_bytes": 8,
+        "alias_bytes": 4, "code_bytes": 2, "peak_bytes": 25}]
+    watch.close()
+
+
+def test_executables_under_jax_miss_then_hit_give_the_same_bytes(jax_ledger):
+    import jax
+    import jax.numpy as jnp
+
+    def ledger_bytes_probe(x):
+        return (x @ x).sum() + jnp.tanh(x @ x.T).sum()
+
+    step = jax.jit(ledger_bytes_probe)
+    cw.label("ledger_bytes_probe", grid="64x64")
+    step(jnp.ones((64, 64), jnp.float32)).block_until_ready()
+    cw.label("ledger_bytes_probe", grid="32x32")
+    step(jnp.ones((32, 32), jnp.float32)).block_until_ready()
+    jax.clear_caches()  # jax forgets its executables: read back
+    cw.label("ledger_bytes_probe", grid="64x64")
+    step(jnp.ones((64, 64), jnp.float32)).block_until_ready()
+    d = jax_ledger.as_dict()
+    row = d["programs"]["ledger_bytes_probe"]
+    big, small, again = row["executables"]
+    assert [r["cache"] for r in row["executables"]] == ["miss", "miss", "hit"]
+    assert [r["label"]["grid"] for r in row["executables"]] == [
+        "64x64", "32x32", "64x64"]
+    for r in (big, small):
+        assert r["temp_bytes"] > 0 and r["argument_bytes"] > 0
+        assert r["peak_bytes"] > 0
+    assert big["argument_bytes"] == 64 * 64 * 4 == 4 * small["argument_bytes"]
+    assert big["temp_bytes"] > small["temp_bytes"]
+    assert {k: again[k] for k in BYTES} == {k: big[k] for k in BYTES}
+    assert row["max_temp_bytes"] == big["temp_bytes"]
+    assert row["n_compile"] == 3 and d["executables_unmatched"] == 0
+    # found when the span ended: the ring's entries have their bytes
+    ring = [e for e in d["spans"] if e["fn"] == "ledger_bytes_probe"
+            and e["stage"] == "compile"]
+    assert [e["temp_bytes"] for e in ring] == [
+        r["temp_bytes"] for r in row["executables"]]
+    assert json.loads(json.dumps(d)) == d
+
+
+def test_perf_probe_tabulates_the_ledgers_executables_largest_first():
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "perf_probe.py")
+    spec = importlib.util.spec_from_file_location("_perf_probe_cw", path)
+    probe = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(probe)
+    backend = FakeBackend()
+    ledger = cw.CacheStats(backend)
+    feed = Feed(ledger)
+    t = 0.0
+    for fn, temp, label in [("prefill", 2_000_000_000, dict(bucket=512)),
+                            ("decode", 3_500_000_000, dict(bucket=64)),
+                            ("never_listed", None, {})]:
+        cw.label(fn, **label)
+        t = feed.program(fn, t, read_secs=0.1, inside=[] if temp is None
+                         else backend.bears(
+                             FakeExecutable("jit_" + fn, FakeStats(temp))))
+    d = json.loads(json.dumps(ledger.as_dict()))
+    assert probe.program_memory_rows(d) == [
+        ("decode", "bucket=64", "hit", 3.5, 3.50000002),
+        ("prefill", "bucket=512", "hit", 2.0, 2.00000002)]
+    assert probe.program_memory_rows(d, top=1)[0][0] == "decode"
+    assert probe.program_memory_rows({}) == []
+    assert probe.program_memory_rows({"programs": {"f": {"n_compile": 1}}}
+                                     ) == []  # a ledger without the records
 
 
 # ---------------------------------------------------------------------------

@@ -751,3 +751,106 @@ def test_fwd_bwd_span_counts_the_documents_that_begin_inside_a_row():
     from areal_tpu.models import ssm
 
     assert any(g[2:] == (8, 2, 1) for g in ssm.geometry_counts())
+
+
+# ---- (h) the plan shows the engine's reckoning beside the compiler's ----
+
+@pytest.fixture
+def ledger(monkeypatch):
+    """A compile ledger of the test's own as the process's: it listens to
+    jax and asks the backend for its executables' statistics."""
+    from areal_tpu.base import compile_watch as cw
+
+    led = cw.CacheStats(cw.jax_live_executables)
+    jax.monitoring.register_scalar_listener(led._on_enter)
+    jax.monitoring.register_event_time_span_listener(led._on_span)
+    monkeypatch.setattr(cw, "_CACHE_STATS", led)
+    try:
+        yield led
+    finally:
+        jax.monitoring.unregister_scalar_listener(led._on_enter)
+        jax.monitoring.unregister_event_time_span_listener(led._on_span)
+
+
+def _grad_compiles(led):
+    return led.as_dict()["programs"]["train_grad_sliced"]["n_compile"]
+
+
+def test_plan_holds_reckoned_and_compiled_and_the_label_compiles_nothing(
+        ledger, monkeypatch):
+    from areal_tpu.base import compile_watch as cw
+
+    sample = _sample(np.random.RandomState(3))
+    spec = MicroBatchSpec(max_tokens_per_mb=32)  # two micro-batches
+    eng = _train_engine(True)
+    eng.train_batch(sample, spec, _sq_loss, lambda mb: mb.n_tokens)
+    ((grid, rec),) = eng.remat_plan().items()
+    R, L = map(int, grid.split("x"))
+    assert rec["entry"] == "full"  # the CPU names no limit
+    assert rec["reckoned_heap_bytes"] == eng._reckoned_heap_bytes(
+        R, L, rec["kept_bytes_estimate"]) > rec["kept_bytes_estimate"] > 0
+    # the compiler's side, of the grid's grad program that needs most
+    records = ledger.executables("train_grad_sliced")
+    assert [r["label"]["carry"] for r in records] == [False, True]
+    for r in records:
+        assert r["label"] == {
+            "grid": grid, "carry": r["label"]["carry"], "remat": "full",
+            **{k: rec[k] for k in jax_train._PLAN_LABEL}}
+        assert r["temp_bytes"] > 0 and r["peak_bytes"] > 0
+    assert rec["compiled"] == {
+        "temp_bytes": max(r["temp_bytes"] for r in records),
+        "peak_bytes": max(r["peak_bytes"] for r in records),
+        "cache": records[0]["cache"]}
+    # every program the step compiled was found; the other labelled ones
+    d = ledger.as_dict()
+    assert d["executables_unmatched"] == 0
+    (apply,) = d["programs"]["train_apply"]["executables"]
+    assert apply["label"] == {"skip_rule": False}
+    # a second step compiles nothing and leaves the record as it is
+    n = _grad_compiles(ledger)
+    eng.train_batch(sample, spec, _sq_loss, lambda mb: mb.n_tokens)
+    assert _grad_compiles(ledger) == n == 2
+    assert eng.remat_plan()[grid] == rec
+    # an engine whose label is never stored compiles as many programs
+    monkeypatch.setattr(cw, "label", lambda fn, **fields: None)
+    other = _train_engine(True)
+    other.train_batch(sample, spec, _sq_loss, lambda mb: mb.n_tokens)
+    assert _grad_compiles(ledger) == 2 * n
+    assert [r["label"] for r in ledger.executables("train_grad_sliced")[n:]
+            ] == [{}, {}]
+
+
+def test_inference_programs_are_labelled_by_call_site_state(ledger):
+    eng = _train_engine(False)
+    sample = _sample(np.random.RandomState(3))
+    spec = MicroBatchSpec(max_tokens_per_mb=64)
+
+    def hook(out, batch):
+        return out.sum(-1)
+
+    eng.forward(sample, spec)
+    eng.forward(sample, spec, post_hook=hook)
+    labels = [r["label"] for r in ledger.executables("infer_forward")]
+    grids = {lab["grid"] for lab in labels}
+    assert len(grids) == 1 and len(next(iter(grids)).split("x")) == 2
+    assert [(lab["use_lp"], lab["hook"]) for lab in labels] == [
+        (False, False), (False, True)]
+    assert eng.remat_plan() == {}  # remat off: nothing reckoned
+
+
+def test_fall_back_keeps_what_was_reckoned_for_the_entry_that_failed(
+        monkeypatch):
+    monkeypatch.setattr(jax_train, "choose_remat", lambda kept, b: "matmuls")
+    eng = _train_engine(True)
+    eng._remat_for(2, 64)
+    first = dict(eng.remat_plan()["2x64"])
+    assert eng._remat_fall_back(2, 64) and eng._remat_fall_back(2, 64)
+    rec = eng.remat_plan()["2x64"]
+    assert rec["entry"] == "full" and rec["fell_back"] is True
+    assert [f["entry"] for f in rec["failed"]] == ["matmuls", "attention"]
+    assert rec["failed"][0] == {k: first[k] for k in (
+        "entry", "kept_bytes_estimate", "budget_bytes",
+        "reckoned_heap_bytes")}
+    assert (rec["failed"][0]["reckoned_heap_bytes"]
+            > rec["failed"][1]["reckoned_heap_bytes"]
+            >= rec["reckoned_heap_bytes"] > 0)

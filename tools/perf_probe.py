@@ -572,13 +572,65 @@ def _merged_metric_rows(experiment: str, trial: str, command: str):
     return rows
 
 
+def program_memory_rows(ledger: dict, top: int = 12) -> list:
+    """``[(fn, label, cache, temp GB, peak GB)]`` of a compile ledger's
+    (``compile_cache``) executables whose statistics it found, the ones
+    that need most of a chip first."""
+    found = [(fn, rec) for fn, row in (ledger.get("programs") or {}).items()
+             for rec in row.get("executables") or []
+             if rec.get("temp_bytes") is not None]
+    found.sort(key=lambda x: -x[1]["temp_bytes"])
+    return [(fn, " ".join(f"{k}={v}" for k, v in rec["label"].items()),
+             rec["cache"], rec["temp_bytes"] / 1e9,
+             (rec.get("peak_bytes") or 0) / 1e9) for fn, rec in found[:top]]
+
+
+def _print_program_memory(experiment: str, trial: str) -> None:
+    """What each generation server's programs need of a chip, from the
+    compile ledger in its ``/metrics.json`` (always on; the merged scrape
+    has no ``compile_cache``). The trainer's table is in its
+    ``device_report`` log line."""
+    import json as _json
+    import urllib.request
+
+    from areal_tpu.base import name_resolve, names
+
+    try:
+        mgr = name_resolve.get(names.gen_server_manager(experiment, trial))
+        with urllib.request.urlopen(f"{mgr.rstrip('/')}/metrics.json",
+                                    timeout=10) as r:
+            servers = sorted(_json.loads(r.read().decode()).get("fleet") or {})
+    except Exception as e:  # noqa: BLE001 — no fleet / manager down
+        print(f"per-executable memory: no generation fleet to ask ({e}); "
+              f"the trainer's table is in its device_report log line")
+        return
+    for url in servers:
+        try:
+            with urllib.request.urlopen(f"{url.rstrip('/')}/metrics.json",
+                                        timeout=10) as r:
+                ledger = (_json.loads(r.read().decode()).get("device")
+                          or {}).get("compile_cache") or {}
+        except Exception as e:  # noqa: BLE001 — server down
+            print(f"per-executable memory of {url}: unreachable ({e})")
+            continue
+        rows = program_memory_rows(ledger)
+        print(f"per-executable memory of {url} (the compiler's temporaries "
+              f"and peak, GB a chip; {ledger.get('executables_unmatched', 0)}"
+              f" unmatched):" + ("" if rows else " none reported"))
+        for fn, label, cache, temp, peak in rows:
+            print(f"  {fn:<24} {cache:<8} temp {temp:7.3f}  peak {peak:7.3f}"
+                  f"  {label}")
+
+
 def compile_status(experiment: str, trial: str) -> None:
     """Compile observatory view of a live run (jax-free), from the merged
     Prometheus scrape: per-jit-entry-point compile counts / total compile
     seconds / distinct compiled shapes across the fleet, the persistent-
     cache hit ratio, recompile-storm events, and which workers have a
     compile in flight RIGHT NOW — the first stop of the "my run is wedged
-    in warmup / my step got slow" runbook (docs/operations.md)."""
+    in warmup / my step got slow" runbook (docs/operations.md). Then,
+    from each generation server's ``/metrics.json``, the compile ledger's
+    per-executable table: what every program needs of a chip."""
     rows = _merged_metric_rows(experiment, trial, "compile-status")
     per_fn = {}  # fn -> {events, secs, shapes}
     inflight = []
@@ -606,6 +658,7 @@ def compile_status(experiment: str, trial: str) -> None:
         elif base == "areal_compile_cache_misses_total":
             cache_misses += val
     if not per_fn:
+        _print_program_memory(experiment, trial)  # the ledger needs no switch
         sys.exit(
             "compile-status: no compile metrics on the merged scrape — "
             "the observatory is off (compile_watch.enabled=false) or no "
@@ -635,6 +688,7 @@ def compile_status(experiment: str, trial: str) -> None:
               f"workers compile.")
     else:
         print("no compiles in flight.")
+    _print_program_memory(experiment, trial)
 
 
 def mem_status(experiment: str, trial: str) -> None:
