@@ -1166,6 +1166,12 @@ class JaxTrainEngine(TrainableEngine):
             if frac is not None:
                 telemetry.set_gauge("train/gdn_kernel_frac", frac)
                 span_attrs["gdn_kernel_frac"] = frac
+            # ... and of the traced mixers, those whose two norms ran
+            # inside that pair (models/gdn.mixer_norm_counts)
+            frac = gdnmod.norms_in_kernel_frac()
+            if frac is not None:
+                telemetry.set_gauge("train/gdn_norms_in_kernel_frac", frac)
+                span_attrs["gdn_norms_in_kernel_frac"] = frac
         with telemetry.span("train/fwd_bwd", n_mbs=len(idxs),
                             grid=f"{ub.R}x{ub.L}",
                             remat=str(self._remat_for(ub.R, ub.L)),

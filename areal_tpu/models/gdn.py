@@ -1,8 +1,9 @@
 """The Gated DeltaNet linear-attention mixer of a ``gdn`` block
 (qwen3_next's ``linear_attention`` layers) — packed rows, the gated delta
 rule in chunks: on a TPU, at the published head sizes, the Pallas kernel
-pair of ``ops/pallas/gated_delta_rule.py`` (:func:`_rule_impl` chooses, by
-what it can see, and :func:`rule_impl_counts` says what it chose);
+pair of ``ops/pallas/gated_delta_rule.py``, which then does the mixer's two
+per-head norms too (:func:`_rule_impl` chooses, by what it can see;
+:func:`rule_impl_counts` and :func:`mixer_norm_counts` say what it chose);
 elsewhere XLA matmuls and one ``lax.scan`` over the chunks.
 
 One mixer, ``u = norm(h)`` [B, T, D] (models/transformer.py adds the
@@ -96,6 +97,22 @@ def rule_kernel_frac() -> Optional[float]:
     None before the first trace."""
     total = sum(_RULE_IMPL.values())
     return (total - _RULE_IMPL["xla"]) / total if total else None
+
+
+# Where each traced mixer's two norms ran (:func:`gdn_mixer`): "kernel" —
+# inside the rule's kernels (:func:`rule_with_norms`) — or "xla".
+_MIXER_NORMS: collections.Counter = collections.Counter()
+
+
+def mixer_norm_counts() -> Dict[str, int]:
+    return dict(_MIXER_NORMS)
+
+
+def norms_in_kernel_frac() -> Optional[float]:
+    """Of the mixers traced so far, the share whose l2 norms and gated RMS
+    norm ran inside the rule's kernels; None before the first trace."""
+    total = sum(_MIXER_NORMS.values())
+    return _MIXER_NORMS["kernel"] / total if total else None
 
 
 def init_gdn_params(gdn: GDNConfig, n: int, hidden_dim: int, key: jax.Array,
@@ -317,17 +334,86 @@ def _rule_kernel_bwd(chunk, how, res, do):
 _rule_kernel.defvjp(_rule_kernel_fwd, _rule_kernel_bwd)
 
 
-# The mixer's per-head work — convolution, gates, the rule, the gated norm
-# — runs a group of key heads at a time (``lax.map``), each group under
-# its own checkpoint: the float32 blocks of a chunk (A, its inverse, the
-# decays) and the states the backward pass reads then exist for one group
-# at a time, at the price of one more forward of the group in the backward
-# pass. Groups of a model: gcd(key heads, _HEAD_GROUPS). The XLA form's
-# only: the kernels keep those blocks in VMEM, and the mixer then runs all
-# heads at once with nothing run twice (a checkpoint around the
-# convolution, the gates and the norms — their float32 copies of q, k, v
-# and o are 1.8 GB at a 16,384-token row — cost 3.8 % of the Qwen3-Next
-# cell's rate, PERF.md §6 PR 53, and the cell's peak did not need it).
+def rule_with_norms(q: jnp.ndarray,  # [B, T, G, dk] as the convolution
+                    k: jnp.ndarray,  # leaves them: NOT normalised
+                    v: jnp.ndarray,  # [B, T, H, dv]
+                    z: jnp.ndarray,  # [B, T, H · dv] the output's gate
+                    w: jnp.ndarray,  # [dv] the gated norm's weight
+                    g: jnp.ndarray, beta: jnp.ndarray,  # [B, T, H]
+                    seg: jnp.ndarray, chunk: int, eps: float,
+                    how: str) -> jnp.ndarray:
+    """The mixer between its convolution and its output projection as the
+    ONE kernel pair: q̂, k̂ l2-normalised on the way in, the rule, and ``y =
+    rms(o) · w ⊙ silu(z)`` [B, T, H · dv] on the way out in the compute
+    dtype — the arithmetic and the rounding points of :func:`gdn_mixer`'s
+    XLA text, with no array by head outside the kernels. ``how``: "pallas"
+    | "pallas_interpret", as :func:`_rule_impl` answered (the XLA form of
+    this entry is the mixer's own text)."""
+    T = q.shape[1]
+    _RULE_IMPL[how] += 1
+    pad = -T % chunk
+    if pad:  # as gated_delta_rule: a zero row norms to zero and writes none
+        q, k, v, z, g, beta = (
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            for a in (q, k, v, z, g, beta))
+        seg = jnp.pad(seg, ((0, 0), (0, pad)))
+    cd, f32 = v.dtype, jnp.float32
+    return _normed_kernel(q.astype(cd), k.astype(cd), v, z.astype(cd), w,
+                          g.astype(f32), beta.astype(f32), seg, chunk,
+                          float(eps), how)[:, :T]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _normed_kernel(q, k, v, z, w, g, beta, seg, chunk, eps, how):
+    """The kernels with the mixer's norms inside: q, k raw, T a whole
+    number of chunks; y [B, T, H · dv] in the compute dtype."""
+    from areal_tpu.ops.pallas import gated_delta_rule as kernel
+
+    with jax.named_scope("gdn_rule"):
+        return kernel.rule_fwd(q, k, v, g, beta, seg, chunk,
+                               interpret=how == "pallas_interpret",
+                               norms=(z, w, L2_EPS, eps))[0]
+
+
+def _normed_kernel_fwd(q, k, v, z, w, g, beta, seg, chunk, eps, how):
+    from areal_tpu.ops.pallas import gated_delta_rule as kernel
+
+    with jax.named_scope("gdn_rule"):
+        y, states = kernel.rule_fwd(q, k, v, g, beta, seg, chunk, keep=True,
+                                    interpret=how == "pallas_interpret",
+                                    norms=(z, w, L2_EPS, eps))
+    return y, (q, k, v, z, w, g, beta, seg, states)
+
+
+def _normed_kernel_bwd(chunk, eps, how, res, dy):
+    from areal_tpu.ops.pallas import gated_delta_rule as kernel
+
+    q, k, v, z, w, g, beta, seg, states = res
+    with jax.named_scope("gdn_rule"):
+        dq, dk, dv, dg, dbeta, dz, dw = kernel.rule_bwd(
+            q, k, v, g, beta, seg, states, dy, chunk,
+            interpret=how == "pallas_interpret", norms=(z, w, L2_EPS, eps))
+    return dq, dk, dv, dz, dw.astype(w.dtype), dg, dbeta, None
+
+
+_normed_kernel.defvjp(_normed_kernel_fwd, _normed_kernel_bwd)
+
+
+# On the XLA path the mixer's per-head work — convolution, gates, the
+# rule, the gated norm — runs a group of key heads at a time (``lax.map``),
+# each group under its own checkpoint: the float32 blocks of a chunk (A,
+# its inverse, the decays) and the states the backward pass reads then
+# exist for one group at a time, at the price of one more forward of the
+# group in the backward pass. Groups of a model: gcd(key heads,
+# _HEAD_GROUPS). The XLA form's only: the kernels keep those blocks in
+# VMEM, and the mixer then runs all heads at once with nothing run twice —
+# and with NOTHING by head outside the kernels (:func:`rule_with_norms`):
+# at 16 / 32 heads at once XLA tiled the (heads, 128) views of q, k and o
+# for the two norms and copied the [T, 2048] / [T, 4096] arrays into that
+# tiling and back (13.7 % of the Qwen3-Next cell's busy time, more than
+# the rule), and kept float32 copies of q, k and o for the backward (1.8
+# GB at a 16,384-token row); the kernels hold one key head's lanes a grid
+# step, where a head's norm is a lane reduction (PERF.md §6, PR 53 / 55).
 _HEAD_GROUPS = 4
 
 
@@ -344,7 +430,9 @@ def gdn_mixer(u: jnp.ndarray,  # [B, T, D] the normed residual stream
     seg = (jnp.ones((B_, T), jnp.int32) if segment_ids is None
            else segment_ids)
     _GEOMETRY[(B_, T, gdn.chunk_size, G, H, dk, dv)] += 1
-    kernel = _rule_impl(impl, gdn.chunk_size, G, H, dk, dv, u.dtype) != "xla"
+    how = _rule_impl(impl, gdn.chunk_size, G, H, dk, dv, u.dtype)
+    kernel = how != "xla"
+    _MIXER_NORMS["kernel" if kernel else "xla"] += 1
     n = 1 if kernel else math.gcd(G, _HEAD_GROUPS)
     Gn, Hn = G // n, H // n
 
@@ -371,6 +459,13 @@ def gdn_mixer(u: jnp.ndarray,  # [B, T, D] the normed residual stream
             beta = jax.nn.sigmoid(b.astype(f32))
             g = -jnp.exp(A_log.astype(f32)) * jax.nn.softplus(
                 a.astype(f32) + dt_bias.astype(f32))
+        if kernel:  # both norms inside the kernels: nothing by head here
+            with jax.named_scope("gdn_rule"):
+                return rule_with_norms(
+                    q.reshape(B_, T, Gn, dk), k.reshape(B_, T, Gn, dk),
+                    v.reshape(B_, T, Hn, dv), z, lp["gdn_norm"], g, beta,
+                    seg, gdn.chunk_size, eps, how)
+        with jax.named_scope("gdn_gates"):
             q = (l2_normalize(q.reshape(B_, T, Gn, dk)) * dk ** -0.5
                  ).astype(u.dtype)
             k = l2_normalize(k.reshape(B_, T, Gn, dk)).astype(u.dtype)

@@ -66,6 +66,29 @@ backward writes d c and d β into a tile of its own.
    sums over a token's channels, made on the MXU against 0/1 rows (so
    they come out with tokens in the lanes).
 
+**The mixer's two norms inside** (``norms``, a static flag of the same two
+kernels: what ``models/gdn.rule_with_norms`` runs). A grid step holds ONE
+key head's 128 lanes of q and k and its value heads' 128 lanes each of o,
+so both of the mixer's per-head normalisations are lane reductions over
+blocks that are in VMEM anyway, and no XLA op sees q, k or o by head:
+
+ - on the way in q and k arrive as the convolution leaves them and are
+   l2-normalised — ``x · rsqrt(Σ x² + eps)`` in float32, q times ``dk **
+   -0.5`` — and rounded to the compute dtype where the mixer rounds, for
+   all the step's chunks at once (:func:`_prepare`; the backward, a chunk
+   at a time in its loop);
+ - on the way out the forward keeps the step's o in VMEM and, after the
+   states' chain, writes ``y = ((o · rsqrt(mean(o²) + eps) · w).astype(cd)
+   · silu(z)).astype(cd)`` a value head — z [R, T, H·dv] and the norm's
+   weight are two more inputs — in the COMPUTE dtype: no float32 o in HBM;
+ - the backward takes d y, REBUILDS a chunk's o from the kept state and the
+   blocks it rebuilds anyway (``e ⊙ (q S₀) + P Δ``: one more product a
+   chunk, nothing kept for it), forms d o through the gate and the norm,
+   writes d z and the weight's gradient (a block a (row, key head), summed
+   over the row's steps where it stays) and sends d q̂, d k̂ through the l2
+   norm: ``dx = (dx̂ − u (u · dx̂)) · rsqrt(Σ x² + eps)``, ``u`` the
+   unrounded unit vector.
+
 The kernels' device ops are named ``gdn_rule_fwd`` / ``gdn_rule_bwd``
 under the caller's scope (not jitted by themselves: the benchmark reads
 the rule by its scope). CPU/testing: ``interpret=True``;
@@ -292,39 +315,77 @@ def _end_blocks(tile, cols, Q: int, r: int, dv: int):
         e_r=e_r, t_r=t_r)
 
 
-def _prepare(q_ref, k_ref, gate_ref, Q: int, r: int, with_q: bool):
+def _l2_parts(x, eps: float):
+    """A head's raw q or k [.., Q, dk] as the convolution leaves it -> (x ·
+    rsqrt(Σ x² + eps), the rsqrt [.., Q, 1]): float32, a lane reduction a
+    token (``gdn.l2_normalize``)."""
+    xf = x.astype(jnp.float32)
+    rs = jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + eps)
+    return xf * rs, rs
+
+
+def _prepare(q_ref, k_ref, gate_ref, Q: int, r: int, with_q: bool, l2_eps):
     """The part of a step no state enters, for all its chunks at once
     ([chunks, ., .] arrays: one basic block, the chunks' chains of products
-    side by side): (the gate tiles, their transposes, k, [k; k], each
-    chunk's pair ``[M₀ | M₁]`` in the compute dtype and — ``with_q`` —
-    ``q·kᵀ`` under each head's decays, paired the same way, else None)."""
+    side by side): (the gate tiles, their transposes, q — ``with_q``, else
+    None —, k, each chunk's pair ``[M₀ | M₁]`` in the compute dtype and —
+    ``with_q`` — ``q·kᵀ`` under each head's decays, paired the same way).
+    With ``l2_eps`` q and k arrive raw and are l2-normalised here (q times
+    ``dk ** -0.5``), rounded to the compute dtype as the mixer rounds."""
     cd = q_ref.dtype
     exact = cd == jnp.float32
     nc = gate_ref.shape[2]
+    dk = k_ref.shape[2]
     tiles = gate_ref[0, 0]  # [chunks, 8, 128]
     cols = jnp.swapaxes(tiles, 1, 2)
-    k = k_ref[0].reshape(nc, Q, k_ref.shape[2])
+    k = k_ref[0].reshape(nc, Q, dk)
+    if l2_eps is not None:
+        k = _l2_parts(k, l2_eps)[0].astype(cd)
     k2 = jnp.concatenate([k, k], axis=1)  # k·kᵀ twice along the lanes
     b_c, D_lo, D_up = _pair_blocks(tiles, cols, Q, r)
     M = _inverses(b_c * _dots("nik,njk->nij", k, k2, exact) * D_lo,
                   exact).astype(cd)
-    P = None
+    q = P = None
     if with_q:
-        q = q_ref[0].reshape(nc, Q, q_ref.shape[2])
+        q = q_ref[0].reshape(nc, Q, dk)
+        if l2_eps is not None:
+            q = (_l2_parts(q, l2_eps)[0] * dk ** -0.5).astype(cd)
         P = (_dots("nik,njk->nij", q, k2, exact) * D_up).astype(cd)
-    return tiles, cols, k, M, P
+    return tiles, cols, q, k, M, P
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, gate_ref, o_ref, *rest, Q: int, r: int,
-                keep: bool):
+def _head_means(x, dv: int):
+    """float32 x [.., Q, r · dv], a value head its own dv lanes -> the mean
+    over each head's lanes, laid out as x (a lane reduction a head)."""
+    means = [jnp.broadcast_to(
+        jnp.mean(x[..., h * dv:(h + 1) * dv], axis=-1, keepdims=True),
+        x.shape[:-1] + (dv,)) for h in range(x.shape[-1] // dv)]
+    return means[0] if len(means) == 1 else jnp.concatenate(means, axis=-1)
+
+
+def _rms_parts(o, eps: float, dv: int):
+    """float32 o [.., Q, r · dv] -> (o · rstd, rstd laid out as o): the RMS
+    norm's statistics a head and token, ``rsqrt(mean(o²) + eps)``."""
+    rstd = jax.lax.rsqrt(_head_means(o * o, dv) + eps)
+    return o * rstd, rstd
+
+
+def _fwd_kernel(*refs, Q: int, r: int, keep: bool, norms):
     """A step of the forward: first everything no state enters, for all the
     step's chunks at once — with ``U = M (β v)``, ``W = M (β e k)`` (so
     that ``Δ = U − W S₀``): ``G = (t ⊙ k)ᵀ W``, ``C = (t ⊙ k)ᵀ U``, ``q̃ =
     e ⊙ q − P W`` and ``P U`` —; then the states' chain, ONE product a
     chunk and value head: ``[G; q̃] · S₀``, ``S₁ = κ S₀ + C − G S₀``, ``o
-    = q̃ S₀ + P U``."""
-    s_ref = rest[0] if keep else None
-    state, gq_ref, c_ref, pu_ref, kap_ref = rest[-5:]
+    = q̃ S₀ + P U``. With ``norms`` (l2 eps, rms eps) q and k arrive raw and
+    are normalised on the way in (:func:`_prepare`), z and the gated norm's
+    weight are two more inputs, and what leaves is the mixer's ``y`` in the
+    compute dtype: the step's o stay in VMEM (``pu_ref``) and are normed
+    and gated for all its chunks at once after the states' chain."""
+    n_in = 6 if norms else 4
+    q_ref, k_ref, v_ref, gate_ref = refs[:4]
+    o_ref = refs[n_in]
+    s_ref = refs[n_in + 1] if keep else None
+    state, gq_ref, c_ref, pu_ref, kap_ref = refs[-5:]
     z = pl.program_id(2)
     nc = gate_ref.shape[2]
     dk, dv = q_ref.shape[2], v_ref.shape[2] // r
@@ -336,9 +397,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, gate_ref, o_ref, *rest, Q: int, r: int,
     def _():
         state[...] = jnp.zeros(state.shape, state.dtype)
 
-    tiles, cols, k, M, P = _prepare(q_ref, k_ref, gate_ref, Q, r, True)
+    tiles, cols, q, k, M, P = _prepare(q_ref, k_ref, gate_ref, Q, r, True,
+                                       norms and norms[0])
     hd = _end_blocks(tiles, cols, Q, r, dv)
-    kf, qf = k.astype(f32), q_ref[0].reshape(nc, Q, dk).astype(f32)
+    kf, qf = k.astype(f32), q.astype(f32)
     if r > 1:  # a head's copy on its own lanes
         kf, qf = (jnp.concatenate([a] * r, axis=2) for a in (kf, qf))
     v = v_ref[0].reshape(nc, Q, W).astype(f32)
@@ -371,17 +433,35 @@ def _fwd_kernel(q_ref, k_ref, v_ref, gate_ref, o_ref, *rest, Q: int, r: int,
                 for h in range(r)]  # [G; q̃] · S₀
         out = jnp.concatenate(outs, axis=1) if r > 1 else outs[0]
         state[...] = kap_ref[c, :1, :] * S0 + c_ref[c] - out[:dk]
-        o_ref[0, rows, :] = out[dk:] + pu_ref[c]
+        if norms:
+            pu_ref[c] = out[dk:] + pu_ref[c]
+        else:
+            o_ref[0, rows, :] = out[dk:] + pu_ref[c]
         return carry
 
     jax.lax.fori_loop(0, nc, chunk, 0)
+    if norms:
+        z_ref, w_ref = refs[4:6]
+        y = (_rms_parts(pu_ref[...], norms[1], dv)[0] * w_ref[...]).astype(cd)
+        gate = jax.nn.silu(z_ref[0].reshape(nc, Q, W).astype(f32))
+        o_ref[0] = (y.astype(f32) * gate).astype(cd).reshape(nc * Q, W)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, s_ref, do_ref, dq_ref, dk_ref,
-                dv_ref, dgate_ref, dstate, m_ref, *, Q: int, r: int):
+def _bwd_kernel(*refs, Q: int, r: int, norms):
+    """A step of the backward (the module's docstring). With ``norms`` q
+    and k arrive raw, ``do_ref`` holds the cotangent of the mixer's ``y``,
+    z and the gated norm's weight are two more inputs and d z and the
+    weight's gradient (summed over a row's steps in its block) two more
+    outputs: a chunk's o is rebuilt from the kept state and the blocks the
+    chunk rebuilds anyway (one more product), ``do`` formed through the
+    gated norm, and d q̂, d k̂ leave through the l2 norm."""
+    n_in = 8 if norms else 6
+    q_ref, k_ref, v_ref, gate_ref, s_ref, do_ref = refs[:6]
+    dq_ref, dk_ref, dv_ref, dgate_ref = refs[n_in:n_in + 4]
+    dstate, m_ref = refs[-2:]
     zr = pl.program_id(2)
     nc = gate_ref.shape[2]
-    dv = v_ref.shape[2] // r
+    dk, dv = q_ref.shape[2], v_ref.shape[2] // r
     cd, f32 = q_ref.dtype, jnp.float32
     exact = cd == f32
 
@@ -389,8 +469,16 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, s_ref, do_ref, dq_ref, dk_ref,
     def _():
         dstate[...] = jnp.zeros(dstate.shape, f32)
 
-    *_, M, _ = _prepare(q_ref, k_ref, gate_ref, Q, r, False)
+    *_, M, _ = _prepare(q_ref, k_ref, gate_ref, Q, r, False,
+                        norms and norms[0])
     m_ref[...] = M
+    if norms:
+        z_ref, w_ref = refs[6:8]
+        dz_ref, dw_ref = refs[n_in + 4:n_in + 6]
+
+        @pl.when(zr == 0)
+        def _():
+            dw_ref[...] = jnp.zeros(dw_ref.shape, f32)
 
     sub = jax.lax.broadcasted_iota(jnp.int32, (SUBLANE, Q), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (SUBLANE, Q), 1)
@@ -405,6 +493,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, s_ref, do_ref, dq_ref, dk_ref,
         c = nc - 1 - ci
         rows = pl.ds(pl.multiple_of(c * Q, Q), Q)
         q, k = q_ref[0, rows, :], k_ref[0, rows, :]
+        if norms:
+            (uq, rq), (uk, rk) = (_l2_parts(x, norms[0]) for x in (q, k))
+            q, k = (uq * dk ** -0.5).astype(cd), uk.astype(cd)
         kq = jnp.concatenate([k, q], axis=0)
         k2 = jnp.concatenate([k, k], axis=0)
         kf = k.astype(f32)
@@ -427,6 +518,18 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, s_ref, do_ref, dq_ref, dk_ref,
         P = qk * D_up
         # ---- Δ enters o through P and the leaving state through t ⊙ k
         do, dS1 = do_ref[0, rows, :].astype(f32), dstate[...]
+        if norms:  # do is d y: through the gate and the norm to d o
+            z, w = z_ref[0, rows, :].astype(f32), w_ref[...]
+            o = hd["e"] * qS + _dot(P.astype(cd), deltas, None, exact)
+            n, rstd = _rms_parts(o, norms[1], dv)
+            sig = jax.nn.sigmoid(z)
+            dz_ref[0, rows, :] = (
+                do * (n * w).astype(cd).astype(f32)
+                * (sig * (1.0 + z * (1.0 - sig)))).astype(dz_ref.dtype)
+            dy = (do * (z * sig)).astype(cd).astype(f32)  # as y was rounded
+            carry = carry + jnp.sum(dy * n, axis=0, keepdims=True)
+            dn = dy * w
+            do = rstd * (dn - n * _head_means(dn * n, dv))
         doc, dS1c = do.astype(cd), dS1.astype(cd)
         d_delta = (_diagonal(_dot(P.astype(cd), doc, _TN, exact), r, dv)
                    + hd["t"] * _dot(k, dS1c, None, exact))
@@ -443,13 +546,17 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, s_ref, do_ref, dq_ref, dk_ref,
         dtks = [dtk[h * Q:(h + 1) * Q] for h in range(r)]
         # the products against a pair sum over its heads by themselves
         halves = (_dot(dqk, q, _TN, exact) + _dot(dkk, k, _TN, exact))
-        dq_ref[0, rows, :] = (_dot(g_qS, S0c, _NT, exact)
-                              + _dot(dqk, k2, None, exact)).astype(
-                                  dq_ref.dtype)
+        dqry = _dot(g_qS, S0c, _NT, exact) + _dot(dqk, k2, None, exact)
         dkey = (_dot(g_kS, S0c, _NT, exact) + _dot(dkk, k2, None, exact)
                 + halves[:Q] + halves[Q:])
         for h in range(r):
             dkey = dkey + hd["t_c"][h] * dtks[h]
+        if norms:  # d x = (d x̂ − u (u · d x̂)) · rsqrt(Σ x² + eps)
+            dqry, dkey = (
+                rs * (d - u * jnp.sum(u * d, axis=1, keepdims=True))
+                for d, u, rs in ((dqry, uq, rq * dk ** -0.5),
+                                 (dkey, uk, rk)))
+        dq_ref[0, rows, :] = dqry.astype(dq_ref.dtype)
         dk_ref[0, rows, :] = dkey.astype(dk_ref.dtype)
         # ---- sums over a token's channels, tokens in the lanes; row 5 h +
         #      0 Σ_j Zs, 1 Σ_j Yp, 2 d e, 3 d t, 4 d β through R
@@ -479,7 +586,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, s_ref, do_ref, dq_ref, dk_ref,
             kq, jnp.concatenate([g_kS, g_qS], axis=0), _TN, exact)
         return carry
 
-    jax.lax.fori_loop(0, nc, chunk, 0)
+    dw = jax.lax.fori_loop(0, nc, chunk,
+                           jnp.zeros((1, r * dv), f32) if norms else 0)
+    if norms:
+        dw_ref[0, 0] += jnp.broadcast_to(dw, dw_ref.shape[2:])
 
 
 def _params(interpret: bool):
@@ -553,61 +663,95 @@ def _specs(dims, Q: int, reverse: bool):
     return key, val, gate, st, steps
 
 
+def _norm_operands(norms, dims):
+    """``norms`` = (z [R, T, H · dv], the gated norm's weight [dv], the l2
+    norm's epsilon, the RMS norm's) -> (z, the weight a key head's value
+    heads wide [1, r · dv] float32, its BlockSpec, the two epsilons)."""
+    z, w, l2_eps, rms_eps = norms
+    r = dims[6]
+    w = jnp.tile(w.astype(jnp.float32), r)[None]
+    spec = pl.BlockSpec(w.shape, lambda b, j, z, *_: (0, 0))
+    return z, w, spec, (float(l2_eps), float(rms_eps))
+
+
 def rule_fwd(q, k, v, g, beta, seg, chunk: int, keep: bool = False,
-             interpret: bool = False):
+             interpret: bool = False, norms=None):
     """q, k [R, T, G, dk] and v [R, T, H, dv] in the compute dtype; g, beta
     [R, T, H] float32; seg [R, T] int; T a whole number of chunks. Returns
     (o [R, T, H, dv] float32, the state entering each chunk [R, chunks, G,
-    dk, r · dv] in the compute dtype or, without ``keep``, None)."""
+    dk, r · dv] in the compute dtype or, without ``keep``, None). With
+    ``norms`` (:func:`_norm_operands`) q and k are the mixer's RAW ones and
+    the first result is its ``y`` [R, T, H · dv] in the compute dtype."""
     dims = _dims(q, v, chunk)
     R, T, G, H, dk, dv, r, Z, nc = dims
     key, val, gate, st, steps = _specs(dims, chunk, reverse=False)
     tile = gate_tiles(g, beta, seg, chunk, G)
     f32 = jnp.float32
+    more, more_specs, eps = (), [], None
+    if norms is not None:
+        z, w, w_spec, eps = _norm_operands(norms, dims)
+        more, more_specs = (z, w), [val, w_spec]
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel, Q=chunk, r=r, keep=keep),
+        functools.partial(_fwd_kernel, Q=chunk, r=r, keep=keep, norms=eps),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0, grid=(R, G, steps),
-            in_specs=[key, key, val, gate],
+            in_specs=[key, key, val, gate] + more_specs,
             out_specs=[val] + ([st] if keep else []),
             scratch_shapes=[pltpu.VMEM((dk, r * dv), f32),
                             pltpu.VMEM((nc, r, dk + chunk, dk), q.dtype),
                             pltpu.VMEM((nc, dk, r * dv), f32),
                             pltpu.VMEM((nc, chunk, r * dv), f32),
                             pltpu.VMEM((nc, SUBLANE, r * dv), f32)]),
-        out_shape=[jax.ShapeDtypeStruct((R, T, H * dv), f32)] + (
+        out_shape=[jax.ShapeDtypeStruct((R, T, H * dv),
+                                        f32 if eps is None else q.dtype)] + (
             [jax.ShapeDtypeStruct((R, Z, G, dk, r * dv), q.dtype)]
             if keep else []),
         name=FWD_NAME, **_params(interpret),
     )(q.reshape(R, T, G * dk), k.reshape(R, T, G * dk),
-      v.reshape(R, T, H * dv), tile)
-    return out[0].reshape(R, T, H, dv), (out[1] if keep else None)
+      v.reshape(R, T, H * dv), tile, *more)
+    o = out[0] if norms is not None else out[0].reshape(R, T, H, dv)
+    return o, (out[1] if keep else None)
 
 
 def rule_bwd(q, k, v, g, beta, seg, states, do, chunk: int,
-             interpret: bool = False):
+             interpret: bool = False, norms=None):
     """Gradients (dq, dk, dv in the compute dtype; dg, dbeta [R, T, H]
-    float32) from the forward's operands, its kept states and do."""
+    float32) from the forward's operands, its kept states and do. With
+    ``norms`` (as :func:`rule_fwd`'s) ``do`` is the cotangent of ``y``, dq
+    and dk are those of the raw q and k, and two more follow: dz in the
+    compute dtype and the norm's weight's gradient [dv] float32."""
     dims = _dims(q, v, chunk)
     R, T, G, H, dk, dv, r, Z, nc = dims
     key, val, gate, st, steps = _specs(dims, chunk, reverse=True)
     tile = gate_tiles(g, beta, seg, chunk, G)
     cd = q.dtype
-    dq, dkey, dval, dgate = pl.pallas_call(
-        functools.partial(_bwd_kernel, Q=chunk, r=r),
+    more, more_specs, more_out, more_shapes, eps = (), [], [], [], None
+    if norms is not None:
+        z, w, w_spec, eps = _norm_operands(norms, dims)
+        more, more_specs = (z, w), [val, w_spec]
+        # the weight's gradient: a block a (row, key head), summed over
+        # the row's steps where it stays
+        more_out = [val, pl.BlockSpec((1, 1, SUBLANE, r * dv),
+                                      lambda b, j, z, *_: (b, j, 0, 0))]
+        more_shapes = [
+            jax.ShapeDtypeStruct((R, T, H * dv), cd),
+            jax.ShapeDtypeStruct((R, G, SUBLANE, r * dv), jnp.float32)]
+    dq, dkey, dval, dgate, *rest = pl.pallas_call(
+        functools.partial(_bwd_kernel, Q=chunk, r=r, norms=eps),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0, grid=(R, G, steps),
-            in_specs=[key, key, val, gate, st, val],
-            out_specs=[key, key, val, gate],
+            in_specs=[key, key, val, gate, st, val] + more_specs,
+            out_specs=[key, key, val, gate] + more_out,
             scratch_shapes=[pltpu.VMEM((dk, r * dv), jnp.float32),
                             pltpu.VMEM((nc, chunk, 2 * chunk), cd)]),
         out_shape=[jax.ShapeDtypeStruct((R, T, G * dk), cd),
                    jax.ShapeDtypeStruct((R, T, G * dk), cd),
                    jax.ShapeDtypeStruct((R, T, H * dv), cd),
-                   jax.ShapeDtypeStruct(tile.shape, jnp.float32)],
+                   jax.ShapeDtypeStruct(tile.shape, jnp.float32)
+                   ] + more_shapes,
         name=BWD_NAME, **_params(interpret),
     )(q.reshape(R, T, G * dk), k.reshape(R, T, G * dk),
-      v.reshape(R, T, H * dv), tile, states, do.reshape(R, T, H * dv))
+      v.reshape(R, T, H * dv), tile, states, do.reshape(R, T, H * dv), *more)
 
     def tokens(a):  # [R, G, Z, r, Q] -> [R, Z, Q, H]
         return a.transpose(0, 2, 4, 1, 3).reshape(R, Z, chunk, H)
@@ -618,5 +762,9 @@ def rule_bwd(q, k, v, g, beta, seg, states, do, chunk: int,
     dg = (jnp.sum(dcs, axis=2, keepdims=True) - jnp.cumsum(dcs, axis=2)
           + dcs).reshape(R, T, H)
     dbeta = tokens(dgate[:, :, :, r:2 * r, :chunk]).reshape(R, T, H)
-    return (dq.reshape(q.shape), dkey.reshape(k.shape), dval.reshape(v.shape),
-            dg, dbeta)
+    grads = (dq.reshape(q.shape), dkey.reshape(k.shape),
+             dval.reshape(v.shape), dg, dbeta)
+    if norms is not None:
+        dz, dw = rest
+        grads += (dz, jnp.sum(dw[:, :, 0].reshape(R * G * r, dv), axis=0))
+    return grads

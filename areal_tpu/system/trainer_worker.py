@@ -372,6 +372,9 @@ class TrainerWorker:
             # the same of the gated delta rules (ops/pallas/
             # gated_delta_rule.py, or the XLA form of models/gdn.py)
             gdn_rule_impl=gdn.rule_impl_counts(),
+            # {"kernel" | "xla": mixers traced}: where a mixer's l2 norms
+            # and gated RMS norm ran (inside the rule's kernels, or XLA's)
+            gdn_mixer_norms=gdn.mixer_norm_counts(),
             # {"rows x length/dD nN/impl": scans traced}: a model's selective
             # scans (S6), and which form each runs as
             s6_geometry={"%dx%d/d%dn%d/%s" % geom: n

@@ -9,7 +9,11 @@ twice in one chunk; a row that is no whole number of chunks; trailing
 padding; ``A_log`` drawn LOW, so that the state is remembered across
 chunks and grid steps; the dispatch's three answers and its counters; and
 the control no cell's ``correct`` sees but ``rule_error`` — the carried
-state narrowed to bfloat16 — which this file must refuse.
+state narrowed to bfloat16 — which this file must refuse. And the kernels
+WITH the mixer's two norms inside (``gdn.rule_with_norms``: raw q and k
+in, the gated, normed ``y`` out) against the XLA mixer's ends — l2 norm,
+rule, gated RMS norm — output and the gradients of q, k, v, g, β, z and
+the norm's weight.
 """
 
 import functools
@@ -149,6 +153,112 @@ def test_the_kernel_in_bfloat16_is_as_near_as_the_xla_form(which, T, G, low):
             name, worst(a, s), worst(x_, s))
 
 
+ENDS_GRADS = GRADS + ("z", "w")
+EPS = 1e-6  # the model's rms_norm_eps
+
+
+def ends_inputs(T, G, r, seed, dtype, low=True):
+    """The mixer's arrays between its convolution and its output
+    projection, one row: q, k RAW (silu of a normal draw, as the
+    convolution leaves them; token 70's q and k and token 7's k all zero:
+    the epsilon), v, g, β as :func:`inputs`, the gate z and the norm's
+    weight."""
+    _, _, v, g, beta = inputs(T, G, seed=seed, dtype=dtype, low=low, r=r)
+    ks = jax.random.split(jax.random.PRNGKey(seed + 100), 4)
+    q, k = (jax.nn.silu(2.0 * jax.random.normal(kk, (1, T, G, D)))
+            for kk in ks[:2])
+    q = q.at[0, 70].set(0.0)
+    k = k.at[0, 70].set(0.0).at[0, 7].set(0.0)
+    z = 2.0 * jax.random.normal(ks[2], (1, T, G * r * D))
+    w = 1.0 + 0.3 * jax.random.normal(ks[3], (D,))
+    return (q.astype(dtype), k.astype(dtype), v, z.astype(dtype),
+            w.astype(dtype), g, beta)
+
+
+def xla_ends(q, k, v, z, w, g, beta, seg, impl="xla"):
+    """``gdn.gdn_mixer``'s text between ``gdn_conv`` and ``gdn_out_proj``."""
+    f32, cd = jnp.float32, v.dtype
+    q = (gdn.l2_normalize(q) * D ** -0.5).astype(cd)
+    k = gdn.l2_normalize(k).astype(cd)
+    o = gdn.gated_delta_rule(q, k, v, g, beta, seg, Q, impl)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + EPS)
+    y = (o * w.astype(f32)).astype(cd)
+    return (y.reshape(z.shape).astype(f32)
+            * jax.nn.silu(z.astype(f32))).astype(cd)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def ends(how, q, k, v, z, w, g, beta, seg, wt):
+    """((Σ wt · y, y), the seven gradients) of the mixer's ends: the
+    kernels with their norms (``how`` "pallas_interpret"), the XLA text
+    around the XLA rule ("xla") or around the un-normed kernels
+    ("xla+pallas_interpret")."""
+    def loss(q, k, v, g, beta, z, w):
+        if how == "pallas_interpret":
+            y = gdn.rule_with_norms(q, k, v, z, w, g, beta, seg, Q, EPS, how)
+        else:
+            y = xla_ends(q, k, v, z, w, g, beta, seg, how.split("+")[-1])
+        y = y.astype(jnp.float32) * (seg > 0)[..., None]
+        return jnp.sum(y * wt), y
+
+    return jax.value_and_grad(loss, argnums=tuple(range(7)), has_aux=True)(
+        q, k, v, g, beta, z, w)
+
+
+# layout, row length, key heads, value heads a key head
+ENDS_CASES = [("first", 256, 1, 2), ("twice", 384, 2, 2),
+              ("padding", 300, 2, 2), ("second", 256, 1, 1),
+              ("twice", 200, 2, 1)]
+
+
+@pytest.mark.parametrize("which,T,G,r", ENDS_CASES)
+def test_the_norms_inside_the_kernels_equal_the_mixers_ends_in_float32(
+        which, T, G, r):
+    """Raw q, k in and the gated, normed y out: the output and the seven
+    gradients at float32's own distance from the XLA text around the XLA
+    rule; an all-zero q / k row leaves through the epsilons alone."""
+    args = ends_inputs(T, G, r, seed=T + G + r, dtype=jnp.float32)
+    seg = jnp.asarray(layout(which, T))
+    wt = jax.random.normal(jax.random.PRNGKey(9), (1, T, G * r * D))
+    with jax.default_matmul_precision("highest"):
+        (_, y), got = ends("pallas_interpret", *args, seg, wt)
+        (_, y_xla), want = ends("xla", *args, seg, wt)
+    assert y.shape == (1, T, G * r * D)
+    assert not np.asarray(y[0, 70]).any()  # q = 0: o = 0, whatever rstd
+    assert worst(y, y_xla) < 2e-5
+    for name, a, x_ in zip(ENDS_GRADS, got, want):
+        assert a.shape == x_.shape and a.dtype == x_.dtype, name
+        assert np.isfinite(np.asarray(a)).all(), name
+        assert worst(a, x_) < 2e-4, (name, worst(a, x_))
+
+
+@pytest.mark.parametrize("which,T,G,r,low", [
+    ("second", 256, 1, 2, True), ("padding", 300, 2, 1, False)])
+def test_the_norms_inside_the_kernels_in_bfloat16_are_as_near_as_the_xla_text(
+        which, T, G, r, low):
+    """bfloat16 operands: y and the gradients are as near the float32 ends
+    (XLA, "highest", the same rounded operands) as the XLA text around
+    the un-normed kernels is — within twice its distance and a bfloat16
+    ulp of room —, and y leaves in bfloat16."""
+    bf, f32 = jnp.bfloat16, jnp.float32
+    args = ends_inputs(T, G, r, seed=T, dtype=bf, low=low)
+    seg = jnp.asarray(layout(which, T))
+    wt = jax.random.normal(jax.random.PRNGKey(9), (1, T, G * r * D))
+    (_, y), got = ends("pallas_interpret", *args, seg, wt)
+    (_, y_xla), xla = ends("xla+pallas_interpret", *args, seg, wt)
+    q, k, v, z, w, g, beta = args
+    with jax.default_matmul_precision("highest"):
+        (_, y_32), want = ends("xla", *(a.astype(f32) for a in args), seg, wt)
+    assert gdn.rule_with_norms(q, k, v, z, w, g, beta, seg, Q, EPS,
+                               "pallas_interpret").dtype == bf
+    assert worst(y, y_32) < 2 * worst(y_xla, y_32) + 2 ** -8
+    for name, a, x_, s in zip(ENDS_GRADS, got, xla, want):
+        assert a.dtype == x_.dtype, name
+        assert np.isfinite(np.asarray(a, np.float32)).all(), name
+        assert worst(a, s) < 2 * worst(x_, s) + 2 ** -8, (
+            name, worst(a, s), worst(x_, s))
+
+
 def test_one_value_head_a_key_head_rides_half_a_pair():
     """r = 1: the second head's lanes of every pair are empty."""
     T, G = 200, 2
@@ -267,6 +377,7 @@ def test_the_mixer_runs_the_kernel_all_heads_at_once_and_counts_it():
 
     geometry = dict(gdn.geometry_counts())
     counts = dict(gdn.rule_impl_counts())
+    norms = gdn.mixer_norm_counts()
     with jax.default_matmul_precision("highest"):
         got = jax.jit(jax.value_and_grad(
             functools.partial(loss, "pallas_interpret"), argnums=(0, 1)))(
@@ -276,9 +387,14 @@ def test_the_mixer_runs_the_kernel_all_heads_at_once_and_counts_it():
         assert gdn.rule_impl_counts()["pallas_interpret"] == counts.get(
             "pallas_interpret", 0) + 1
         assert gdn.rule_impl_counts().get("xla", 0) == counts.get("xla", 0)
+        # ... with both norms inside the kernels, and says so
+        assert gdn.mixer_norm_counts()["kernel"] == norms.get("kernel", 0) + 1
+        assert gdn.mixer_norm_counts().get("xla", 0) == norms.get("xla", 0)
         want = jax.jit(jax.value_and_grad(
             functools.partial(loss, "reference"), argnums=(0, 1)))(u, lp)
+    assert gdn.mixer_norm_counts()["xla"] == norms.get("xla", 0) + 1
     assert 0.0 < gdn.rule_kernel_frac() <= 1.0
+    assert 0.0 < gdn.norms_in_kernel_frac() < 1.0
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert worst(a, b) < 1e-4
     # ... one forward and one backward kernel under scope ``gdn_rule``:

@@ -595,3 +595,54 @@ def test_document_starts_inside_a_chunk_are_a_gauge_of_the_train_step():
     inside = sum(0 < col and col % 16 != 0 for mb in ub.mbs
                  for _, col in mb.layout.placements)
     assert got == pytest.approx(inside / rows) and inside > 0
+
+
+def test_where_the_mixers_norms_ran_is_a_gauge_of_the_train_step():
+    """``train/gdn_kernel_frac`` and ``train/gdn_norms_in_kernel_frac``: of
+    the rules and the mixers traced, the share on the Pallas kernel pair
+    and the share whose two norms ran inside it — gauges and attributes of
+    the ``train/fwd_bwd`` span (here the tiny model is off the kernels'
+    lane grid: its own mixers count ``xla``)."""
+    from areal_tpu.api.data import MicroBatchSpec, SequenceSample
+    from areal_tpu.api.model import FinetuneSpec
+    from areal_tpu.api.train_config import OptimizerConfig, TelemetryConfig
+    from areal_tpu.backend.jax_train import JaxTrainEngine
+    from areal_tpu.base import telemetry
+
+    cfg, params = model()
+    eng = JaxTrainEngine(cfg, params, OptimizerConfig(type="sgd", lr=1e-2),
+                         FinetuneSpec(1, 8, 4), compute_dtype="float32",
+                         length_bucket=16, rows_bucket=1, seqs_bucket=4)
+    lens = [9, 12, 7, 14]
+    rng = np.random.RandomState(0)
+    sample = SequenceSample.from_default(
+        ids=[f"s{i}" for i in range(len(lens))],
+        data={"packed_input_ids": rng.randint(
+            2, 97, sum(lens)).astype(np.int32),
+            "loss_mask": np.ones(sum(lens), np.float32)},
+        seqlens=lens)
+
+    def sq_loss(logits, batch):
+        w = (batch["segment_ids"] > 0).astype(jnp.float32)
+        lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        return jnp.sum(jnp.sum(lp * lp, axis=-1) * w), {"n": jnp.sum(w)}
+
+    before = gdn.mixer_norm_counts().get("xla", 0)
+    telemetry.configure("t", "t", "trainer", 0,
+                        TelemetryConfig(enabled=True), push=False)
+    try:
+        for _ in range(2):  # the first step traces; the second reads
+            eng.train_batch(sample, MicroBatchSpec(max_tokens_per_mb=48),
+                            sq_loss, lambda mb: mb.n_tokens)
+        snap = telemetry.get().snapshot()
+    finally:
+        telemetry.shutdown()
+    assert gdn.mixer_norm_counts()["xla"] > before
+    gauges = snap["gauges"]
+    assert gauges["train/gdn_kernel_frac"] == gdn.rule_kernel_frac()
+    assert gauges["train/gdn_norms_in_kernel_frac"] == pytest.approx(
+        gdn.norms_in_kernel_frac())
+    assert 0.0 <= gauges["train/gdn_norms_in_kernel_frac"] < 1.0
+    spans = [s for s in snap["spans"] if s["name"] == "train/fwd_bwd"]
+    assert spans[-1]["attrs"]["gdn_norms_in_kernel_frac"] == gauges[
+        "train/gdn_norms_in_kernel_frac"]

@@ -550,6 +550,7 @@ def _compile_all():
 
     rules = sum(gdnmod.geometry_counts().values())
     impls = dict(gdnmod.rule_impl_counts())
+    norms = gdnmod.mixer_norm_counts()
     cell = jax.jit(qnext_grad).lower(params, tok, tok, tok).compile()
     record("qnext-cell", cell)
     out["qnext-cell"].update(
@@ -559,6 +560,9 @@ def _compile_all():
                    if n - impls.get(k, 0)},
         rule_kernels=[n for n in ("gdn_rule_fwd", "gdn_rule_bwd")
                       if n in cell.as_text()],
+        mixer_norms={k: n - norms.get(k, 0)
+                     for k, n in gdnmod.mixer_norm_counts().items()
+                     if n - norms.get(k, 0)},
         param_bytes=18 * transformer.param_count(qnext))
     return out
 
@@ -841,14 +845,17 @@ def test_the_qwen3_next_cut_compiles_inside_the_memory_it_leaves(compiled):
     # ... and it runs the kernel pair (the backward re-runs the forward)
     assert got["rule_impl"] == {"pallas": 1}
     assert got["rule_kernels"] == ["gdn_rule_fwd", "gdn_rule_bwd"]
+    # ... with the mixer's two norms inside them
+    assert got["mixer_norms"] == {"kernel": 1}
     # attention: forward twice, dKV, dQ; the experts' grouped GEMMs
     assert got["custom_calls"] >= 4
     # 4.70 GB in the XLA form (a quarter of the heads at a time under a
-    # checkpoint); 6.47 GB since the kernels run all heads at once and the
-    # mixer keeps what its convolution, gates and norms leave for the
-    # backward (a checkpoint around them holds 4.99 GB and costs the cell
-    # 3.8 % of its rate: PERF.md section 6, PR 53)
-    assert got["temp_bytes"] < 6.6e9
+    # checkpoint); 6.47 GB when the kernels ran all heads at once and the
+    # mixer kept what its convolution, gates and norms left for the
+    # backward (a checkpoint around them held 4.99 GB and cost the cell
+    # 3.8 % of its rate: PERF.md section 6, PR 53); 5.39 GB since the
+    # norms run inside the kernels (PR 55): no float32 q, k or o by head
+    assert got["temp_bytes"] < 5.5e9
     gradient = got["param_bytes"] // 9  # 2 B a parameter
     assert got["param_bytes"] + gradient + got["temp_bytes"] < 15.0e9
 

@@ -11,6 +11,16 @@ inside a chunk). Beside each time the least time the chip's peaks allow
     python tools/gdn_rule_sweep.py --parity   (chip: numbers, no times)
     python tools/gdn_rule_sweep.py --compile  (here: compiles the kernels
                                                for a described v5e)
+    python tools/gdn_rule_sweep.py --parts ends   (chip; --parity and
+                                                   --compile as above)
+
+``--parts ends`` times the MIXER'S ENDS alone — everything between
+``gdn_conv`` and ``gdn_out_proj``: the l2 norms of q and k, the rule, the
+gated RMS norm times silu(z) — from the arrays as the mixer has them
+(tokens major, heads in the lanes, raw q and k): ``xla-norms`` is the
+mixer's XLA text around the kernels (what ran before PR 55), ``in-kernel``
+the kernels with both norms inside (``gdn.rule_with_norms``; left out on
+a tree that has no such entry, so the tool runs on the parent too).
 
 Prints one JSON line a case: device milliseconds a call from a profiler
 capture of ``--reps`` calls (and its largest ops). ``--parity`` instead
@@ -36,6 +46,8 @@ sys.path.insert(0, os.path.join(REPO, "tools"))
 G, H, D, Q = 16, 32, 128, 64
 LENGTHS = (14336, 8704)
 NAMES = ("o", "dq", "dk", "dv", "dg", "dbeta")
+ENDS_NAMES = ("y", "dq", "dk", "dv", "dg", "dbeta", "dz", "dw")
+EPS = 1e-6  # the configuration's rms_norm_eps
 
 
 def main() -> int:
@@ -45,6 +57,7 @@ def main() -> int:
                     default=[2, 4, 8])
     ap.add_argument("--lengths", type=int, nargs="*", default=list(LENGTHS))
     ap.add_argument("--skip-xla", action="store_true")
+    ap.add_argument("--parts", choices=("rule", "ends"), default="rule")
     ap.add_argument("--parity", action="store_true")
     ap.add_argument("--compile", action="store_true")
     ap.add_argument("--low", action="store_true",
@@ -81,7 +94,89 @@ def main() -> int:
         out.write(line + "\n")
         out.flush()
 
-    bf = jnp.bfloat16
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def worst(got, want):
+        return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+    def ends_fn(how, impl, seg, dtype=bf, grads=True, aux=False):
+        """The mixer's ends from flat arrays, jitted: y, or the gradients
+        of Σ wt · y (``aux``: with y) — the mixer's XLA text around
+        ``gdn.gated_delta_rule(impl)`` ("xla-norms") or
+        ``gdn.rule_with_norms`` ("in-kernel")."""
+        def loss(q, k, v, g, beta, z, w, wt):
+            T = q.shape[1]
+            q, k, v, z, w = (x.astype(dtype) for x in (q, k, v, z, w))
+            q, k = (x.reshape(1, T, G, D) for x in (q, k))
+            v = v.reshape(1, T, H, D)
+            if how == "in-kernel":
+                y = gdn.rule_with_norms(q, k, v, z, w, g, beta, seg, Q, EPS,
+                                        impl)
+            else:
+                q = (gdn.l2_normalize(q) * D ** -0.5).astype(dtype)
+                k = gdn.l2_normalize(k).astype(dtype)
+                o = gdn.gated_delta_rule(q, k, v, g, beta, seg, Q, impl)
+                o = o * jax.lax.rsqrt(
+                    jnp.mean(o * o, axis=-1, keepdims=True) + EPS)
+                y = (o * w.astype(f32)).astype(dtype)
+                y = (y.reshape(1, T, H * D).astype(f32)
+                     * jax.nn.silu(z.astype(f32))).astype(dtype)
+            return jnp.sum(y.astype(f32) * wt), y
+
+        if not grads:
+            return jax.jit(lambda *xs: loss(*xs)[1])
+        if aux:
+            return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(7)),
+                                              has_aux=True))
+        return jax.jit(jax.grad(lambda *xs: loss(*xs)[0],
+                                argnums=tuple(range(7))))
+
+    def ends(T, v, g, beta, seg, key):
+        """``--parts ends`` at one row length."""
+        ks = jax.random.split(key, 5)
+        q, k = (jax.nn.silu(2.0 * jax.random.normal(kk, (1, T, G * D))
+                            ).astype(bf) for kk in ks[:2])
+        z = (2.0 * jax.random.normal(ks[2], (1, T, H * D))).astype(bf)
+        w = (1.0 + 0.3 * jax.random.normal(ks[3], (D,))).astype(bf)
+        wt = jax.random.normal(ks[4], (1, T, H * D))
+        args = (q, k, v.reshape(1, T, H * D), g, beta, z, w, wt)
+        impl = ("pallas" if a.compile or jax.default_backend() == "tpu"
+                else "pallas_interpret")
+        hows = ["xla-norms"] + (
+            ["in-kernel"] if hasattr(gdn, "rule_with_norms") else [])
+        if a.parity:
+            def y_and_grads(how, impl, dtype):
+                (_, y), grads = ends_fn(how, impl, seg, dtype, aux=True)(
+                    *args)
+                return [np.asarray(x, np.float32) for x in (y, *grads)]
+
+            with jax.default_matmul_precision("highest"):
+                exact = y_and_grads("xla-norms", "xla", f32)
+            got = {how: y_and_grads(how, impl, bf) for how in hows}
+            emit(length=T, parts="ends", impl=impl, low=a.low,
+                 finite={how: all(bool(np.isfinite(x).all()) for x in xs)
+                         for how, xs in got.items()},
+                 **{f"{how}_vs_float32": dict(zip(
+                     ENDS_NAMES, (worst(x, e) for x, e in zip(xs, exact))))
+                    for how, xs in got.items()})
+            return
+        for how in hows:
+            rec = dict(length=T, parts="ends", impl=how)
+            if a.compile:
+                shapes = [jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=chip) for x in args]
+                t = time.perf_counter()
+                c = ends_fn(how, impl, seg).lower(*shapes).compile()
+                emit(**rec, compile_s=time.perf_counter() - t,
+                     kernels=c.as_text().count("tpu_custom_call"),
+                     temp_mb=c.memory_analysis().temp_size_in_bytes / 1e6)
+                continue
+            f, f_ops = device_ms(ends_fn(how, impl, seg, grads=False), args,
+                                 a.reps)
+            fb, fb_ops = device_ms(ends_fn(how, impl, seg), args, a.reps)
+            emit(**rec, fwd_ms=f, fwd_bwd_ms=fb, fwd_ops=f_ops,
+                 fwd_bwd_ops=fb_ops)
+
     for T in a.lengths:
         ks = jax.random.split(jax.random.PRNGKey(0), 7)
         q = (gdn.l2_normalize(jax.random.normal(ks[0], (1, T, G, D)))
@@ -97,6 +192,9 @@ def main() -> int:
         cut = (T * 4 // 5) // Q * Q + 23  # the second document's start
         seg = jnp.asarray(np.where(np.arange(T) < cut, 1, 2), jnp.int32)[None]
         args = (q, k, v, g, beta, w)
+        if a.parts == "ends":
+            ends(T, v, g, beta, seg, jax.random.PRNGKey(T))
+            continue
 
         def fwd(impl):
             return jax.jit(lambda q, k, v, g, beta, w: gdn.gated_delta_rule(
@@ -122,9 +220,6 @@ def main() -> int:
             def o_and_grads(impl, dtype):
                 (_, o), grads = both(impl, dtype, aux=True)(*args)
                 return [np.asarray(x, np.float32) for x in (o, *grads)]
-
-            def worst(got, want):
-                return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
 
             got, xla = o_and_grads(how, bf), o_and_grads("xla", bf)
             with jax.default_matmul_precision("highest"):
