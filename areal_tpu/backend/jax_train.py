@@ -985,6 +985,9 @@ class JaxTrainEngine(TrainableEngine):
                 telemetry.set_gauge(
                     "train/gdn_resets_in_chunk_per_row",
                     mbu.resets_in_chunk_per_row(mbs, self.cfg.gdn.chunk_size))
+            if self.cfg.shortconv is not None:
+                telemetry.set_gauge("train/shortconv_resets_per_row",
+                                    mbu.shortconv_resets_per_row(mbs))
             self._gauge_blocks_needed("train", mbs)
         R, L = mbs[0].layout.shape
         pp_on, ring_on = ppl.pp_engagement(self.mesh, self.cfg, R, L)

@@ -220,6 +220,15 @@ def resets_in_chunk_per_row(mbs: List[MicroBatch], chunk: int) -> float:
     return (inside / rows) if rows else 0.0
 
 
+def shortconv_resets_per_row(mbs: List[MicroBatch]) -> float:
+    """Document starts at which a short convolution's taps are cut (a
+    start behind another document of its row) over the rows that hold any
+    document, of a micro-batch split. Exported as the
+    ``train/shortconv_resets_per_row`` gauge."""
+    per_row = docs_per_row(mbs)  # a row's first document cuts nothing
+    return per_row - 1.0 if per_row else 0.0
+
+
 def make_microbatch(
     sample: SequenceSample,
     token_key: str = "packed_input_ids",

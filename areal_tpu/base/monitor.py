@@ -148,13 +148,16 @@ def model_flops_per_token(
     projections, and the rule in chunks of Q tokens a value head — the two
     [Q, Q] products and the inverse's 2 log2(Q) - 1 of them inside a
     chunk, the two triangular applications and the five products against
-    the carried state."""
+    the carried state; a short-convolution block's likewise
+    (:func:`_shortconv_flops`)."""
     flops = transformer_flops_per_token(
         cfg.n_layers, cfg.hidden_dim, cfg.q_dim, cfg.kv_dim,
         cfg.intermediate_dim, 1 if cfg.is_critic else cfg.vocab_size,
         avg_seqlen, backward=backward, remat=remat,
         moe=getattr(cfg, "moe", None),
     )
+    factor = 1.0 if not backward else 4.0 if remat else 3.0
+    flops += _shortconv_flops(cfg, avg_seqlen) * factor
     gdn = getattr(cfg, "gdn", None)
     n_gdn = cfg.layer_kinds.count("gdn") if gdn is not None else 0
     if not n_gdn:
@@ -168,8 +171,36 @@ def model_flops_per_token(
         + Q * dv))
     mixer = (2 * d * (gdn.qkvz_dim + gdn.ba_dim) + 2 * gdn.value_dim * d
              + rule)
-    factor = 1.0 if not backward else 4.0 if remat else 3.0
     return flops + n_gdn * (mixer - attention) * factor
+
+
+def _shortconv_flops(cfg, avg_seqlen: float) -> float:
+    """What a model with short-convolution blocks (``cfg.shortconv``)
+    differs by from the count above, a token's forward pass: each such
+    block's mixer — ``[B | C | x]`` and the out-projection, two gates and
+    K taps a channel — in place of attention's, and on each of its blocks
+    that run the dense MLP (the leading ones) that MLP in place of the
+    routed experts. 0 for any other model."""
+    sc = getattr(cfg, "shortconv", None)
+    if sc is None:
+        return 0.0
+    from areal_tpu.models.config import CONV, attention_kind, has_dense_ffn
+
+    d = cfg.hidden_dim
+    attention = (2 * d * (cfg.q_dim + 2 * cfg.kv_dim) + 2 * cfg.q_dim * d
+                 + 2 * 2 * cfg.q_dim * avg_seqlen)
+    mixer = 2 * d * 3 * d + 2 * d * d + (2 + 2 * sc.kernel) * d
+    n_conv = sum(attention_kind(k) == CONV for k in cfg.layer_kinds)
+    extra = n_conv * (mixer - attention)
+    moe = cfg.moe
+    if moe is not None:
+        experts = (moe.top_k * moe.num_experts / moe.n_routed * 3 * 2 * d
+                   * (moe.routed_intermediate_dim or cfg.intermediate_dim)
+                   + 2 * d * moe.n_routed
+                   + 3 * 2 * d * (moe.shared_intermediate_dim or 0))
+        n_dense = sum(map(has_dense_ffn, cfg.layer_kinds))
+        extra += n_dense * (3 * 2 * d * cfg.intermediate_dim - experts)
+    return extra
 
 
 class FlopsCounter:

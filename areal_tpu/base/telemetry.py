@@ -304,6 +304,10 @@ LATENT_MOE_SCOPES = ("latent_down", "latent_up", "shared_expert")
 # shared expert, inside "shared_expert".
 GDN_SCOPES = ("gdn_in_proj", "gdn_conv", "gdn_gates", "gdn_rule",
               "gdn_gate_norm", "gdn_out_proj", "shared_expert_gate")
+# A doubly gated short convolution (lfm2_moe; models/shortconv.py): the
+# in-projection [B | C | x], both gates with the taps between them, the
+# out-projection; no DEVICE_SCOPES name lies between them and "layer_scan".
+SHORTCONV_SCOPES = ("shortconv_in_proj", "shortconv", "shortconv_out_proj")
 
 
 def _annotation(name: str, attrs: Dict[str, Any]):

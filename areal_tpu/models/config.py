@@ -36,12 +36,17 @@ SSD = "ssd"
 # (models/gdn.py), then the model's FFN — the expert layer where it has
 # one (qwen3_next's ``linear_attention`` layers, beside FULL blocks).
 GDN = "gdn"
+# A whole block whose mixer is a doubly gated short convolution
+# (models/shortconv.py: no head, no state, no position), then the model's
+# FFN — its dense MLP on the leading blocks (CONV + ``DENSE_SUFFIX``), the
+# expert layer after them (lfm2_moe's ``conv`` layers, beside FULL blocks).
+CONV = "conv"
 # Whole blocks whose mixer is not self-attention: no K/V cache decodes
 # them, and their parameter shapes are their kind's own.
-BLOCK_MIXER_KINDS = SAMBAY_KINDS + (SSD, GDN)
+BLOCK_MIXER_KINDS = SAMBAY_KINDS + (SSD, GDN, CONV)
 # Of those, the blocks that run no attention at all: no q/k/v/o, no q/k
 # norm, no attention gate (a CROSS block still has q and o).
-ATTENTION_FREE_KINDS = (S6, GMU, SSD, GDN)
+ATTENTION_FREE_KINDS = (S6, GMU, SSD, GDN, CONV)
 # What a layer hands on to later layers, by the name the readers ask for.
 MEMORY, SHARED_KV = "memory", "kv"
 # A whole block's FFN kind (HF ``mlp_layer_types``): the model's dense MLP
@@ -101,6 +106,12 @@ class MoEConfig:
     # of its own logit; the choice is the top-k of score + ``router_bias``,
     # a buffer that takes no gradient; the gates are the chosen SCORES).
     router_score: str = "softmax"
+    # What ``init_params`` draws ``router_bias`` at, N(0, this) (this
+    # repo's key ``expert_bias_init_std`` of the lfm2_moe mapping; every
+    # other weight of the layer, and the bias elsewhere, N(0, 0.02)). A
+    # trained bias keeps the experts evenly chosen; a drawn one skews them,
+    # and the load of a SHARE of the layer swings with the draw.
+    router_bias_init_std: float = 0.02
     # multiplies the (renormalised) gates (HF ``routed_scaling_factor``)
     routed_scaling_factor: float = 1.0
     # Experts that work in a LATENT width (HF ``moe_latent_size``): the
@@ -198,6 +209,15 @@ class GDNConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class ShortConvConfig:
+    """A doubly gated short convolution's sizes (HF lfm2 / lfm2_moe key
+    ``conv_L_cache``): ``kernel`` taps, depthwise over the model's hidden
+    width, no bias, no activation (models/shortconv.py)."""
+
+    kernel: int = 3
+
+
+@dataclasses.dataclass(frozen=True)
 class S6Config:
     """A Mamba-1 (selective scan, S6) mixer's sizes: ``d_inner`` channels,
     each with ``state_dim`` states whose decay is its own (``A`` is
@@ -267,6 +287,7 @@ class TransformerConfig:
     ssm: Optional[SSMConfig] = None  # the MAMBA layers' / SSD blocks' mixer
     s6: Optional[S6Config] = None  # the S6 blocks' mixer (GMU reads its width)
     gdn: Optional[GDNConfig] = None  # the GDN blocks' mixer
+    shortconv: Optional[ShortConvConfig] = None  # the CONV blocks' mixer
     # sliding window attention (mistral/gemma2); None = full attention
     sliding_window: Optional[int] = None
     # The kind of each layer: FULL or SLIDING (HF ``layer_types``), or one
@@ -404,7 +425,7 @@ class TransformerConfig:
         """Layers no K/V cache can decode: a mixer alone, a state-space
         block, or a layer that reads what another layer made."""
         return self.has_mixer_layers or any(
-            k in BLOCK_MIXER_KINDS for k in self.layer_kinds)
+            attention_kind(k) in BLOCK_MIXER_KINDS for k in self.layer_kinds)
 
     @property
     def is_hybrid(self) -> bool:
@@ -413,7 +434,7 @@ class TransformerConfig:
         run a dense MLP and some the experts: ``params["layers"]`` is
         then a tree per KIND, each
         stacked over that kind's layers."""
-        return any(k in MIXER_KINDS or k in BLOCK_MIXER_KINDS
+        return any(k in MIXER_KINDS or attention_kind(k) in BLOCK_MIXER_KINDS
                    or has_dense_ffn(k) for k in self.layer_kinds)
 
     @property
@@ -453,6 +474,10 @@ class TransformerConfig:
         if layer == self.kv_source:
             return SHARED_KV
         return None
+
+    def has_mixer(self, kind: str) -> bool:
+        """Whether any whole block's mixer is ``kind``, whatever its FFN."""
+        return kind in map(attention_kind, self.layer_kinds)
 
     def n_layers_of(self, kind: str) -> int:
         return self.layer_kinds.count(kind)

@@ -40,7 +40,10 @@ def decode_refusal(cfg: TransformerConfig) -> Optional[str]:
     alone — a state-space layer among them — a selective-scan block, or
     a layer that reads another layer's memory or K/V has no cache to
     decode from; a Gated DeltaNet block its own name (its state is a
-    recurrent matrix and its convolution's last taps: ``gdn.DECODE_REFUSAL``).
+    recurrent matrix and its convolution's last taps: ``gdn.DECODE_REFUSAL``),
+    a short-convolution block likewise (the last ``conv_L_cache - 1`` tokens
+    of ``B ⊙ x`` beside the attention blocks' K/V:
+    ``shortconv.DECODE_REFUSAL``).
     Every entry point below prefills through ``forward``, which raises it."""
     return transformer.decode_refusal(cfg)
 

@@ -5,7 +5,7 @@ Parity target: the reference's per-family converter registry
 ``realhf/api/from_hf/{llama,qwen2,qwen3,gemma,gpt2,mistral,mixtral}.py``).
 Families covered: llama, qwen2 (qwen2.5), qwen3, mistral, gemma, gpt2,
 mixtral, qwen3_moe, olmoe, mellum, nemotron_h, afmoe, phi4flash,
-granitemoehybrid.
+granitemoehybrid, qwen3_next, lfm2_moe.
 
 Weights are stacked on a leading layer axis (see models/transformer.py), so
 conversion transposes HF's ``[out, in]`` linear layout to ``[in, out]`` and
@@ -28,6 +28,7 @@ import numpy as np
 from areal_tpu.base import logging
 from areal_tpu.models.config import (
     ATTENTION_ONLY,
+    CONV,
     CROSS,
     DENSE_FFN,
     FULL,
@@ -43,6 +44,7 @@ from areal_tpu.models.config import (
     MoEConfig,
     RopeConfig,
     S6Config,
+    ShortConvConfig,
     SSMConfig,
     TransformerConfig,
     attention_kind,
@@ -653,6 +655,88 @@ def _qwen3_next_config(hf_config: Any) -> TransformerConfig:
             first_expert=first_expert,
         ),
         hf_family="qwen3_next",
+    )
+
+
+# lfm2_moe: HF ``layer_types`` to the block kinds.
+_LFM2_LAYER_TYPES = {"conv": CONV, "full_attention": FULL}
+# Keys of the family that no block here runs, by name: (key, the value
+# that is run, why any other is refused).
+LFM2_MOE_REFUSALS = (
+    ("conv_bias", False, "conv_bias: a bias on the short convolution and "
+     "its two projections"),
+    ("use_expert_bias", True, "use_expert_bias: a sigmoid router without "
+     "its choice bias"),
+    ("rope_scaling", None, "rope_scaling: a scaled RoPE"),
+)
+
+
+@register_hf_family("lfm2_moe")
+def _lfm2_moe_config(hf_config: Any) -> TransformerConfig:
+    """LFM2-MoE (LiquidAI, ``Lfm2MoeForCausalLM``): whole blocks under
+    plain RMSNorms at ``norm_eps``, the mixer by ``layer_types`` — ``conv``
+    a doubly gated short convolution of ``conv_L_cache`` taps
+    (models/shortconv.py), ``full_attention`` GQA with q and k normed a
+    head before RoPE (``rope_parameters``), no bias — then the FFN: a
+    dense SwiGLU of ``intermediate_size`` on the first ``num_dense_layers``
+    blocks, on the others ``num_experts`` routed experts of
+    ``moe_intermediate_size`` (sigmoid scores, the choice by score +
+    ``expert_bias``, the chosen scores renormalised where
+    ``norm_topk_prob``, times ``routed_scaling_factor``; no shared expert,
+    no dropped token, no auxiliary loss). The head is the embedding's
+    transpose unless ``tie_word_embeddings`` is false. A SHARE holds
+    ``num_experts`` of ``num_routed_experts`` (:func:`_expert_share`); a
+    config cut in depth keeps the first layers' types.
+    ``expert_bias_init_std`` (this repo's key, not the publisher's) is
+    what a fresh ``expert_bias`` is drawn at."""
+    for key, run, why in LFM2_MOE_REFUSALS:
+        if getattr(hf_config, key, None) not in (None, run):
+            raise NotImplementedError(why)
+    kw = _base_kwargs(hf_config)
+    n = kw["n_layers"]
+    types = tuple(getattr(hf_config, "layer_types"))[:n]
+    if len(types) != n or set(types) - set(_LFM2_LAYER_TYPES):
+        raise NotImplementedError(
+            f"layer_types {types!r} for {n} layers "
+            f"({sorted(_LFM2_LAYER_TYPES)} are supported)")
+    rope = getattr(hf_config, "rope_parameters", None)
+    if rope is not None:
+        rope = _rope_config(rope)
+        if rope.factor is not None:
+            raise NotImplementedError("rope_scaling: a scaled RoPE")
+        kw["rotary_base"] = rope.base
+    kw["rms_norm_eps"] = getattr(hf_config, "norm_eps", 1e-5)
+    kw["tie_word_embeddings"] = bool(
+        getattr(hf_config, "tie_word_embeddings", True))
+    dense = int(getattr(hf_config, "num_dense_layers", 0) or 0)
+    held = hf_config.num_experts
+    routed, first = _expert_share(hf_config, held)
+    return TransformerConfig(
+        **kw,
+        layer_types=tuple(_LFM2_LAYER_TYPES[t] for t in types),
+        mlp_layer_types=tuple(
+            DENSE_FFN if i < dense else SPARSE_FFN for i in range(n))
+        if dense else None,
+        use_qk_norm=True,
+        max_position_embeddings=getattr(
+            hf_config, "max_position_embeddings", None),
+        shortconv=ShortConvConfig(kernel=int(hf_config.conv_L_cache)),
+        moe=MoEConfig(
+            num_experts=held,
+            top_k=hf_config.num_experts_per_tok,
+            capacity_factor=None,
+            routed_intermediate_dim=hf_config.moe_intermediate_size,
+            aux_loss_coeff=0.0,
+            norm_topk_prob=bool(getattr(hf_config, "norm_topk_prob", True)),
+            router_experts=routed,
+            first_expert=first,
+            router_score="sigmoid",
+            routed_scaling_factor=float(
+                getattr(hf_config, "routed_scaling_factor", 1.0)),
+            router_bias_init_std=float(
+                getattr(hf_config, "expert_bias_init_std", 0.02)),
+        ),
+        hf_family="lfm2_moe",
     )
 
 
@@ -1369,6 +1453,86 @@ def _qwen3_next_from_sd(
     }
 
 
+# lfm2_moe: (pytree key, HF name under ``model.layers.{i}.``, transpose).
+# A block holds the leaves of its kind; the depthwise convolution is
+# ``[channels, 1, K]``, tap K - 1 on the token itself.
+_LFM2_MOE_NAMES = [
+    ("ln1", "operator_norm.weight", False),
+    ("ln2", "ffn_norm.weight", False),
+    ("sc_in", "conv.in_proj.weight", True),
+    ("sc_out", "conv.out_proj.weight", True),
+    ("wq", "self_attn.q_proj.weight", True),
+    ("wk", "self_attn.k_proj.weight", True),
+    ("wv", "self_attn.v_proj.weight", True),
+    ("wo", "self_attn.out_proj.weight", True),
+    ("q_norm", "self_attn.q_layernorm.weight", False),
+    ("k_norm", "self_attn.k_layernorm.weight", False),
+    ("w_gate", "feed_forward.w1.weight", True),
+    ("w_up", "feed_forward.w3.weight", True),
+    ("w_down", "feed_forward.w2.weight", True),
+    ("router", "feed_forward.gate.weight", True),
+    ("router_bias", "feed_forward.expert_bias", False),
+]
+_LFM2_MOE_EXPERTS = {"e_gate": "feed_forward.experts.{e}.w1.weight",
+                     "e_up": "feed_forward.experts.{e}.w3.weight",
+                     "e_down": "feed_forward.experts.{e}.w2.weight"}
+_LFM2_CONV = "conv.conv.weight"
+
+
+def _lfm2_moe_to_sd(
+    params: Dict[str, Any], cfg: TransformerConfig
+) -> Dict[str, np.ndarray]:
+    sd = {
+        "model.embed_tokens.weight": np.asarray(params["embedding"]),
+        "model.embedding_norm.weight": np.asarray(params["final_ln"]),
+    }
+    if not cfg.tie_word_embeddings:
+        sd["lm_head.weight"] = np.asarray(params["lm_head"]).T
+    for i, _, lp in _layers_in_order(params, cfg):
+        pre = f"model.layers.{i}."
+        for key, name, tr in _LFM2_MOE_NAMES:
+            if key in lp:
+                sd[pre + name] = lp[key].T if tr else lp[key]
+        if "sc_conv" in lp:
+            sd[pre + _LFM2_CONV] = lp["sc_conv"].T[:, None, :]
+        for key, name in _LFM2_MOE_EXPERTS.items():
+            if key in lp:
+                for e in range(cfg.moe.num_experts):
+                    sd[pre + name.format(e=e)] = lp[key][e].T
+    return sd
+
+
+def _lfm2_moe_from_sd(
+    sd: Dict[str, Any], cfg: TransformerConfig, dtype: str
+) -> Dict[str, Any]:
+    per_kind: Dict[str, Dict[str, list]] = {}
+    for i, kind in enumerate(cfg.layer_kinds):
+        pre = f"model.layers.{i}."
+        lp = per_kind.setdefault(kind, {})
+        for key, name, tr in _LFM2_MOE_NAMES:
+            if pre + name in sd:
+                w = _np(sd[pre + name])
+                lp.setdefault(key, []).append(w.T if tr else w)
+        if pre + _LFM2_CONV in sd:
+            lp.setdefault("sc_conv", []).append(
+                _np(sd[pre + _LFM2_CONV])[:, 0, :].T)
+        for key, name in _LFM2_MOE_EXPERTS.items():
+            if pre + name.format(e=0) in sd:
+                lp.setdefault(key, []).append(np.stack([
+                    _np(sd[pre + name.format(e=e)]).T
+                    for e in range(cfg.moe.num_experts)]))
+    params = {
+        "embedding": _np(sd["model.embed_tokens.weight"]).astype(dtype),
+        "layers": {kind: {k: np.stack(v).astype(dtype)
+                          for k, v in lp.items()}
+                   for kind, lp in per_kind.items()},
+        "final_ln": _np(sd["model.embedding_norm.weight"]).astype(dtype),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = _np(sd["lm_head.weight"]).T.astype(dtype)
+    return params
+
+
 def params_from_hf_state_dict(
     sd: Dict[str, Any], cfg: TransformerConfig, dtype: str = "float32"
 ) -> Dict[str, Any]:
@@ -1385,6 +1549,8 @@ def params_from_hf_state_dict(
         return _granitemoehybrid_from_sd(sd, cfg, dtype)
     if cfg.hf_family == "qwen3_next":
         return _qwen3_next_from_sd(sd, cfg, dtype)
+    if cfg.hf_family == "lfm2_moe":
+        return _lfm2_moe_from_sd(sd, cfg, dtype)
     return _llama_from_sd(sd, cfg, dtype)
 
 
@@ -1404,6 +1570,8 @@ def params_to_hf_state_dict(
         return _granitemoehybrid_to_sd(params, cfg)
     if cfg.hf_family == "qwen3_next":
         return _qwen3_next_to_sd(params, cfg)
+    if cfg.hf_family == "lfm2_moe":
+        return _lfm2_moe_to_sd(params, cfg)
     return _llama_to_sd(params, cfg)
 
 
@@ -1425,6 +1593,7 @@ _HF_ARCH = {
     "phi4flash": "Phi4FlashForCausalLM",
     "granitemoehybrid": "GraniteMoeHybridForCausalLM",
     "qwen3_next": "Qwen3NextForCausalLM",
+    "lfm2_moe": "Lfm2MoeForCausalLM",
 }
 
 
@@ -1456,6 +1625,8 @@ def hf_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
         return _granitemoehybrid_config_dict(cfg)
     if fam == "qwen3_next":
         return _qwen3_next_config_dict(cfg)
+    if fam == "lfm2_moe":
+        return _lfm2_moe_config_dict(cfg)
     d: Dict[str, Any] = {
         "model_type": fam,
         "architectures": [_HF_ARCH.get(fam, "LlamaForCausalLM")],
@@ -1699,6 +1870,44 @@ def _qwen3_next_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
         d["num_routed_experts"] = moe.n_routed
         d["expert_shard_count"] = moe.n_routed // moe.num_experts
         d["expert_shard_index"] = moe.first_expert // moe.num_experts
+    return d
+
+
+def _lfm2_moe_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
+    """The inverse of :func:`_lfm2_moe_config`."""
+    moe = cfg.moe
+    names = {kind: name for name, kind in _LFM2_LAYER_TYPES.items()}
+    d = {
+        "model_type": "lfm2_moe",
+        "architectures": [_HF_ARCH["lfm2_moe"]],
+        "num_hidden_layers": cfg.n_layers,
+        "num_dense_layers": (cfg.mlp_layer_types or ()).count(DENSE_FFN),
+        "layer_types": [names[attention_kind(k)] for k in cfg.layer_kinds],
+        "hidden_size": cfg.hidden_dim,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "intermediate_size": cfg.intermediate_dim,
+        "moe_intermediate_size": moe.routed_intermediate_dim,
+        "vocab_size": cfg.vocab_size,
+        "norm_eps": cfg.rms_norm_eps,
+        "rope_parameters": _rope_dict(RopeConfig(base=cfg.rotary_base)),
+        "conv_L_cache": cfg.shortconv.kernel,
+        "conv_bias": False,
+        "tie_word_embeddings": cfg.tie_word_embeddings,
+        "max_position_embeddings": cfg.max_position_embeddings or 128000,
+        "num_experts": moe.num_experts,
+        "num_experts_per_tok": moe.top_k,
+        "use_expert_bias": True,
+        "norm_topk_prob": moe.norm_topk_prob,
+        "routed_scaling_factor": moe.routed_scaling_factor,
+        "torch_dtype": "float32",
+    }
+    if moe.is_share:
+        d["num_routed_experts"] = moe.n_routed
+        d["expert_shard_count"] = moe.n_routed // moe.num_experts
+        d["expert_shard_index"] = moe.first_expert // moe.num_experts
+    if moe.router_bias_init_std != 0.02:
+        d["expert_bias_init_std"] = moe.router_bias_init_std
     return d
 
 

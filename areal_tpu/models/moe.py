@@ -1118,7 +1118,9 @@ def init_moe_params(cfg, key: jnp.ndarray, dtype,
     # One key per weight actually initialized — adding a weight grows the
     # split instead of silently reusing a neighbour's key.
     ks = dict(zip(shapes, jax.random.split(key, len(shapes))))
+    scale = {"router_bias": cfg.moe.router_bias_init_std}
     return {
-        name: (jax.random.normal(ks[name], (n,) + shape) * 0.02).astype(dtype)
+        name: (jax.random.normal(ks[name], (n,) + shape)
+               * scale.get(name, 0.02)).astype(dtype)
         for name, shape in shapes.items()
     }

@@ -36,7 +36,9 @@ but mellum and nemotron_h. Two sorts of pattern:
    CROSS blocks; granitemoehybrid's SSD blocks — the Mamba-2 mixer, then
    the dense MLP — beside FULL ones; qwen3_next's GDN blocks — a Gated
    DeltaNet mixer, models/gdn.py, then the expert layer — beside FULL
-   ones).
+   ones; lfm2_moe's CONV blocks — a doubly gated short convolution,
+   models/shortconv.py, then the dense MLP on the leading blocks and the
+   expert layer after them — beside FULL ones).
 """
 
 from __future__ import annotations
@@ -50,6 +52,7 @@ import jax.numpy as jnp
 from areal_tpu.models.config import (
     ATTENTION_FREE_KINDS,
     ATTENTION_ONLY,
+    CONV,
     CROSS,
     FULL,
     GDN,
@@ -129,12 +132,12 @@ def _init_block_layers(cfg: TransformerConfig, n: int, keys, dtype,
                        ) -> Dict[str, jnp.ndarray]:
     """``n`` whole blocks (a mixer + FFN) stacked ``[n, ...]``: attention,
     or by ``kind`` an S6 mixer, a Mamba-2 mixer (SSD), a gated memory unit
-    (two matrices and no scan) or cross attention (q and o alone: its K/V
-    are another
-    layer's); the FFN is the expert layer where the model has one, unless
+    (two matrices and no scan), a doubly gated short convolution (CONV)
+    or cross attention (q and o alone: its K/V are another layer's); the FFN is the expert layer where the model has one, unless
     ``dense_ffn``."""
     d = cfg.hidden_dim
     qd, kvd, f = cfg.q_dim, cfg.kv_dim, cfg.intermediate_dim
+    kind = attention_kind(kind)
     attends = kind not in ATTENTION_FREE_KINDS
     one = _norm_init(cfg)
 
@@ -157,6 +160,11 @@ def _init_block_layers(cfg: TransformerConfig, n: int, keys, dtype,
         from areal_tpu.models import gdn as gdnmod
 
         layers.update(gdnmod.init_gdn_params(cfg.gdn, n, d, keys[13], dtype))
+    elif kind == CONV:
+        from areal_tpu.models import shortconv
+
+        layers.update(shortconv.init_shortconv_params(
+            cfg.shortconv, n, d, keys[13], dtype))
     elif kind == GMU:
         layers["gmu_in"] = nrm(keys[13], (n, d, cfg.s6.d_inner))
         layers["gmu_out"] = nrm(keys[14], (n, cfg.s6.d_inner, d))
@@ -454,6 +462,10 @@ def _block(
             attn, new_kv = gdnmod.gdn_mixer(
                 x, lp, cfg.gdn, cfg.rms_norm_eps, segment_ids,
                 attn_impl), None
+        elif akind == CONV:
+            from areal_tpu.models import shortconv
+
+            attn, new_kv = shortconv.shortconv_mixer(x, lp, segment_ids), None
         elif akind == S6:
             attn, new_kv = ssmmod.s6_mixer(x, lp, cfg.s6, segment_ids,
                                            attn_impl)
@@ -602,12 +614,16 @@ DECODE_REFUSAL = (
 
 def decode_refusal(cfg: TransformerConfig) -> Optional[str]:
     """Why this model has no decode mode, by name, or None: the Gated
-    DeltaNet blocks' own reason where it has them, ``DECODE_REFUSAL`` for
-    any other layer no K/V cache can decode."""
+    DeltaNet or short-convolution blocks' own reason where it has them,
+    ``DECODE_REFUSAL`` for any other layer no K/V cache can decode."""
     if GDN in cfg.layer_kinds:
         from areal_tpu.models.gdn import DECODE_REFUSAL as gdn_refusal
 
         return gdn_refusal
+    if cfg.has_mixer(CONV):
+        from areal_tpu.models.shortconv import DECODE_REFUSAL as conv_refusal
+
+        return conv_refusal
     return DECODE_REFUSAL if cfg.has_cacheless_layers else None
 
 
@@ -1010,13 +1026,17 @@ def _block_matmul_widths(cfg: TransformerConfig, dense_ffn: bool,
     keeps the router's logits and its shared expert's pair. By ``kind``
     the mixer's are an S6 mixer's (in-projection, [δ | B | C], Δ), a
     Mamba-2 mixer's two projections, a Gated DeltaNet mixer's three, a
-    gated memory unit's one, or cross attention's q and o."""
+    short convolution's two, a gated memory unit's one, or cross
+    attention's q and o."""
+    kind = attention_kind(kind)
     if kind == S6:
         widths = 3 * cfg.s6.d_inner + cfg.s6.x_proj_dim + cfg.hidden_dim
     elif kind == SSD:  # the scan's einsums carry batch dimensions
         widths = cfg.ssm.in_proj_dim + cfg.hidden_dim
     elif kind == GDN:  # the rule's einsums carry batch dimensions
         widths = cfg.gdn.qkvz_dim + cfg.gdn.ba_dim + cfg.hidden_dim
+    elif kind == CONV:  # [B | C | x], and the out-projection
+        widths = 4 * cfg.hidden_dim
     elif kind == GMU:
         widths = cfg.s6.d_inner + cfg.hidden_dim
     elif kind == CROSS:
@@ -1334,6 +1354,7 @@ def _block_param_count(cfg: TransformerConfig, dense_ffn: bool,
     from areal_tpu.models import moe as moemod
 
     d, f = cfg.hidden_dim, cfg.intermediate_dim
+    kind = attention_kind(kind)
     if kind == S6:
         s6 = cfg.s6
         attn = (d * 2 * s6.d_inner + s6.d_inner * (
@@ -1345,6 +1366,10 @@ def _block_param_count(cfg: TransformerConfig, dense_ffn: bool,
         from areal_tpu.models.gdn import gdn_param_count
 
         attn = gdn_param_count(cfg.gdn, d)
+    elif kind == CONV:
+        from areal_tpu.models.shortconv import shortconv_param_count
+
+        attn = shortconv_param_count(cfg.shortconv, d)
     elif kind == GMU:
         attn = 2 * d * cfg.s6.d_inner
     elif kind == CROSS:

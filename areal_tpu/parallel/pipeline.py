@@ -62,7 +62,7 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from areal_tpu.base import logging, telemetry
-from areal_tpu.models.config import GDN, SLIDING, TransformerConfig
+from areal_tpu.models.config import CONV, GDN, SLIDING, TransformerConfig
 from areal_tpu.parallel import ring as ring_mod
 from areal_tpu.parallel import sharding as psh
 
@@ -86,6 +86,10 @@ _FALLBACK_HINTS = {
     "gated_delta_rule": "Gated DeltaNet blocks beside attention blocks: "
                         "a tree per kind has no one stacked axis to split "
                         "over pp, and a period's stages cost unequally",
+    "short_convolution": "short-convolution blocks beside attention "
+                         "blocks, dense blocks before expert blocks: a tree "
+                         "per kind has no one stacked axis to split over "
+                         "pp, and a period's stages cost unequally",
     "mixer_layers": "layers that are one mixer each (state-space, expert, "
                     "attention) make stages of unequal cost and have no "
                     "one stacked tree to split over pp",
@@ -133,6 +137,8 @@ def pick_pp_microbatches(
         return _fallback("cross_layer_state")
     if GDN in cfg.layer_kinds:
         return _fallback("gated_delta_rule")
+    if cfg.has_mixer(CONV):
+        return _fallback("short_convolution")
     if cfg.is_hybrid:
         return _fallback("mixer_layers")
     sp = mesh.shape.get("sp", 1)

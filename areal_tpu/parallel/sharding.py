@@ -28,8 +28,8 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from areal_tpu.models.config import (
-    ATTENTION_FREE_KINDS, CROSS, FULL, GDN, GMU, S6, SSD,
-    TransformerConfig)
+    ATTENTION_FREE_KINDS, CONV, CROSS, FULL, GDN, GMU, S6, SSD,
+    TransformerConfig, attention_kind)
 from areal_tpu.parallel.mesh import DATA_AXES
 
 Params = Dict[str, Any]
@@ -77,7 +77,8 @@ def _block_partition_specs(cfg: TransformerConfig, zero, lead,
     ``lead`` ("pp", or None: a kind's stack of a tree per kind); the FFN
     is the expert layer where the model has one, unless ``dense_ffn``;
     the mixer by ``kind``: attention (a cross layer has q and o alone),
-    an S6 or a Mamba-2 (SSD) mixer or a gated memory unit — their
+    an S6 or a Mamba-2 (SSD) mixer, a short convolution (CONV) or a gated
+    memory unit — their
     matrices ZeRO-3 on the hidden dim, the channels whole (the scan and
     the memory it hands on are not split; nor are the heads under the
     one B/C group and the gated norm that spans them all)."""
@@ -88,9 +89,15 @@ def _block_partition_specs(cfg: TransformerConfig, zero, lead,
         "w_up": P(lead, zero, "tp"),
         "w_down": P(lead, "tp", zero),
     }
+    kind = attention_kind(kind)
     attends = kind not in ATTENTION_FREE_KINDS
     if kind == SSD:
         layers.update(_mamba_specs(lead, zero))
+    elif kind == CONV:  # projections ZeRO-3, the channels and taps whole
+        layers.update({
+            "sc_in": P(lead, zero, None), "sc_out": P(lead, None, zero),
+            "sc_conv": P(lead, None, None),
+        })
     elif kind == GDN:  # as the Mamba-2 mixer: matrices ZeRO-3, heads whole
         layers.update({
             "gdn_qkvz": P(lead, zero, None), "gdn_ba": P(lead, zero, None),

@@ -330,7 +330,7 @@ class TrainerWorker:
         run is judged by (base/monitor.log_device_report)."""
         from areal_tpu.base import monitor
         from areal_tpu.ops import attention, native
-        from areal_tpu.models import gdn, moe, ssm
+        from areal_tpu.models import gdn, moe, shortconv, ssm
         from areal_tpu.ops.pallas import window_attention
 
         monitor.log_device_report(
@@ -366,6 +366,11 @@ class TrainerWorker:
             # model's Gated DeltaNet blocks (models/gdn.py)
             gdn_geometry={"%dx%d/%d/k%dv%d/%dx%d" % geom: n
                           for geom, n in gdn.geometry_counts().items()},
+            # {"rows x length/channels/taps": convolutions traced}: a
+            # model's short-convolution blocks (models/shortconv.py)
+            shortconv_geometry={
+                "%dx%d/c%d/k%d" % geom: n
+                for geom, n in shortconv.geometry_counts().items()},
             # {"pallas" | "pallas_interpret" | "xla": scans traced}: what
             # runs them (the kernel of ops/pallas/ssd_scan.py, or einsums)
             ssm_scan_impl=ssm.scan_impl_counts(),

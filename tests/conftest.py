@@ -32,6 +32,22 @@ def _seed_everything():
     yield
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _forget_a_parity_files_programs(request):
+    """Every executable XLA's CPU compiler has made keeps a few memory
+    mappings, a process may hold 65,530 (``vm.max_map_count``), and the
+    parity files compile by the thousand (each eager op of a gradient is
+    a program): a worker that ran several of them died inside the compiler
+    ("LLVM compilation error: Cannot allocate memory", then a segmentation
+    fault: ROADMAP D12). ``jax.clear_caches()`` gives the mappings back, so
+    a parity file leaves its worker as light as it found it."""
+    yield
+    if request.module.__name__.endswith("_parity"):
+        import jax
+
+        jax.clear_caches()
+
+
 @pytest.fixture()
 def tmp_name_resolve(tmp_path):
     from areal_tpu.base import name_resolve

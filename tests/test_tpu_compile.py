@@ -102,6 +102,12 @@ QNEXT_GRID = (1, 16384)
 # key heads, value heads, head size, chunk), bfloat16 as the cell times it
 # and float32 as its ``rule_error`` calls it.
 GDN_RULE = (1, 16384, 16, 32, 128, 64)
+# The LFM2-MoE cell's cut (configs/lfm2-24b-a2b.json): its grad program's
+# largest micro-batch — the largest grid the packer makes of the cell's
+# traffic (traffic/train-toolcall-16k.json, ``compile_grid``) — at the
+# published widths. A child process and a fixture of its own
+# (``compiled_lfm2``), so that no other case waits for it.
+LFM2_GRID = (2, 7168)
 SSD_SCANS = {"ssd-scan-granite": (1, 7040, 32, 64, 1, 128, 256),
              "ssd-scan-nemotron": (1, 4096, 16, 64, 1, 128, 128)}
 
@@ -588,6 +594,73 @@ def compiled(shared_run_dir, libtpu_lock):
     return results
 
 
+def _compile_lfm2():
+    """Child process: the LFM2-MoE cell's cut, the whole model's forward +
+    backward on its largest grid under full remat, against a described
+    v5e:2x2."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs in /tmp
+    sys.path.insert(0, REPO)
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from areal_tpu.models import shortconv, transformer
+    from benchmark import weights
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this image
+        return {"skip": f"cannot describe a v5e:2x2 topology here: {e}"}
+    jax.config.update("jax_enable_compilation_cache", False)
+    chip = SingleDeviceSharding(topo.devices[0])
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "lfm2-24b-a2b.json")) as f:
+        lfm2 = weights.model_config(json.load(f))
+    shapes = jax.eval_shape(
+        lambda: transformer.init_params(lfm2, jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, jnp.bfloat16, sharding=chip), shapes)
+    tok = jax.ShapeDtypeStruct(LFM2_GRID, jnp.int32, sharding=chip)
+
+    def lfm2_grad(p, tokens, pos, seg):
+        def loss(p):
+            y, _ = transformer.forward(
+                p, lfm2, tokens, pos, segment_ids=seg, attn_impl="pallas",
+                remat="full", return_kv=False, return_hidden=True)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        return jax.value_and_grad(loss)(p)
+
+    got = jax.jit(lfm2_grad).lower(params, tok, tok, tok).compile()
+    return {
+        "custom_calls": got.as_text().count("tpu_custom_call"),
+        "temp_bytes": got.memory_analysis().temp_size_in_bytes,
+        "convs_traced": sum(shortconv.geometry_counts().values()),
+        "param_bytes": 18 * transformer.param_count(lfm2)}
+
+
+@pytest.fixture(scope="module")
+def compiled_lfm2(shared_run_dir, libtpu_lock):
+    """:func:`_compile_lfm2`'s result, from one child process a test run
+    (as ``compiled``)."""
+    path = shared_run_dir / "tpu_compile_lfm2.json"
+    with libtpu_lock():
+        if not path.exists():
+            r = subprocess.run(
+                [sys.executable, os.path.abspath(__file__), "lfm2"],
+                env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                capture_output=True, text=True, timeout=600,
+            )
+            assert r.returncode == 0, r.stderr[-3000:]
+            path.write_text(r.stdout.splitlines()[-1])
+        results = json.loads(path.read_text())
+    if "skip" in results:
+        pytest.skip(results["skip"])
+    return results
+
+
 @pytest.mark.parametrize("T", WINDOW_T)
 def test_window_attention_compiles_for_v5e(compiled, T):
     """The windowed kernel's forward, dKV and dQ at the published heads
@@ -742,7 +815,8 @@ def test_programs_that_hold_no_grouped_gemm_kernel(compiled, name):
 
 
 if __name__ == "__main__":
-    print(json.dumps(_compile_all()))
+    print(json.dumps(_compile_lfm2() if sys.argv[1:] == ["lfm2"]
+                     else _compile_all()))
 
 
 @pytest.mark.parametrize("T", LATENT_T)
@@ -872,3 +946,22 @@ def test_the_sambay_cell_compiles_at_the_published_widths(compiled):
     # fused backward)
     assert got["custom_calls"] >= 6 + 4 + 2 * 3
     assert got["temp_bytes"] < 2.4e9
+
+
+def test_the_lfm2_cut_compiles_inside_the_memory_it_leaves(compiled_lfm2):
+    """The grad program of the cut (a dense block and the period A c c c,
+    8 of 64 experts held: 469.3 M parameters) on 2 x 7168, the largest
+    grid the packer makes of the cell's traffic, at the published widths,
+    beside the state: 18 B a parameter, the bf16 gradient the program
+    returns, its temporaries — the number the micro-batch was chosen by
+    (configs/lfm2-24b-a2b.json, ``deployment``)."""
+    got = compiled_lfm2
+    # one convolution a run of short-convolution blocks: the dense block's
+    # and the three expert blocks' (scanned)
+    assert got["convs_traced"] == 2
+    # attention: forward twice, dKV, dQ; the experts' grouped GEMMs
+    assert got["custom_calls"] >= 4
+    # 2.91 GB: the dense FFN's [14,336, 11,776] gate / up / product
+    assert got["temp_bytes"] < 3.1e9
+    gradient = got["param_bytes"] // 9  # 2 B a parameter
+    assert got["param_bytes"] + gradient + got["temp_bytes"] < 12.5e9
