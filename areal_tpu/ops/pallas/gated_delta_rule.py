@@ -58,13 +58,20 @@ backward writes d c and d β into a tile of its own.
    in the compute dtype (the state enters every product rounded to it;
    the carried one is float32);
  - backward (:func:`rule_bwd`): the same grid with the chunks reversed,
-   ``dS`` as its carry; the step's ``M`` are made as in the forward, then a
-   loop over the chunks rebuilds each chunk's blocks from q, k, v, the
-   tile and the kept state. The inverse needs no cotangent of its own:
-   with ``dR = Mᵀ dΔ``, ``dA = −Mᵀ (dΔ Rᵀ) Mᵀ = −dR Δᵀ``. The decays'
-   gradient is row sums less column sums of ``dA ⊙ A + dP ⊙ P`` plus three
-   sums over a token's channels, made on the MXU against 0/1 rows (so
-   they come out with tokens in the lanes).
+   ``dS`` as its carry, in the forward's phases. With ``W``, ``G``, ``q̃``
+   as the forward's, a chunk's ``dS₀ = κ dS₁ − Gᵀ dS₁ + q̃ᵀ do``: FIRST,
+   for all the step's chunks at once, the blocks again from q, k, v, the
+   tiles and the kept states (``Δ = M (β (v − e ⊙ k S₀))`` rides the
+   product that makes ``W``), then ``Gᵀ`` and ``q̃ᵀ do`` into VMEM
+   scratches; THEN the chain from the step's last chunk to its first, one
+   product a chunk and value head, each chunk's ``dS₁`` left in a scratch;
+   LAST every gradient from those ``dS₁``, for all the chunks at once. The
+   inverse needs no cotangent of its own: with ``dR = Mᵀ dΔ``, ``dA = −Mᵀ
+   (dΔ Rᵀ) Mᵀ = −dR Δᵀ``. The decays' gradient is row sums less column
+   sums of ``dA ⊙ A + dP ⊙ P`` plus three sums over a token's channels,
+   made on the MXU against 0/1 rows (so they come out with tokens in the
+   lanes). A loop that walked the chunks and did all of this inside took
+   9.99 ms a 14,336-token row where this takes 5.58 (PERF.md §6, PR 57).
 
 **The mixer's two norms inside** (``norms``, a static flag of the same two
 kernels: what ``models/gdn.rule_with_norms`` runs). A grid step holds ONE
@@ -75,15 +82,16 @@ blocks that are in VMEM anyway, and no XLA op sees q, k or o by head:
  - on the way in q and k arrive as the convolution leaves them and are
    l2-normalised — ``x · rsqrt(Σ x² + eps)`` in float32, q times ``dk **
    -0.5`` — and rounded to the compute dtype where the mixer rounds, for
-   all the step's chunks at once (:func:`_prepare`; the backward, a chunk
-   at a time in its loop);
+   all the step's chunks at once (:func:`_prepare`, once in either
+   kernel);
  - on the way out the forward keeps the step's o in VMEM and, after the
    states' chain, writes ``y = ((o · rsqrt(mean(o²) + eps) · w).astype(cd)
    · silu(z)).astype(cd)`` a value head — z [R, T, H·dv] and the norm's
    weight are two more inputs — in the COMPUTE dtype: no float32 o in HBM;
- - the backward takes d y, REBUILDS a chunk's o from the kept state and the
-   blocks it rebuilds anyway (``e ⊙ (q S₀) + P Δ``: one more product a
-   chunk, nothing kept for it), forms d o through the gate and the norm,
+ - the backward takes d y, REBUILDS the chunks' o from the kept states and
+   the blocks it rebuilds anyway (``e ⊙ (q S₀) + P Δ``: ``P Δ`` rides the
+   product that makes ``P W``, nothing kept for it), forms d o through the
+   gate and the norm,
    writes d z and the weight's gradient (a block a (row, key head), summed
    over the row's steps where it stays) and sends d q̂, d k̂ through the l2
    norm: ``dx = (dx̂ − u (u · dx̂)) · rsqrt(Σ x² + eps)``, ``u`` the
@@ -115,6 +123,11 @@ FWD_NAME, BWD_NAME = "gdn_rule_fwd", "gdn_rule_bwd"
 # x 14,336 the forward / backward kernels read 3.89 / 8.68 ms at 4, 2.87 /
 # 7.68 at 8, 2.77 / 7.45 at 16 (whose first phase holds twice the VMEM).
 CHUNKS_PER_STEP = 8
+# The backward's own, since all three of its phases hold a step's chunks
+# (PERF.md §6, PR 57; with the norms inside, 1 x 14,336): 9.90 ms at 2, 7.09
+# at 4, 5.64 at 8, 5.53 at 16 — which compiles in 6 s for 8's 2.6 and does
+# not divide the 136 chunks of the cell's other row.
+BWD_CHUNKS_PER_STEP = 8
 VMEM_LIMIT = 64 * 1024 * 1024
 
 _NT = (((1,), (1,)), ((), ()))  # a · bᵀ
@@ -134,10 +147,10 @@ def supported(chunk: int, k_heads: int, v_heads: int, dk: int, dv: int,
                                      jnp.dtype(jnp.bfloat16)))
 
 
-def chunks_per_step(chunks: int) -> int:
-    """The most chunks a step (<= ``CHUNKS_PER_STEP``, a power of two) that
-    divide a row's."""
-    n = CHUNKS_PER_STEP
+def chunks_per_step(chunks: int, backward: bool = False) -> int:
+    """The most chunks a step (<= ``CHUNKS_PER_STEP``, or the backward's
+    ``BWD_CHUNKS_PER_STEP``; a power of two) that divide a row's."""
+    n = BWD_CHUNKS_PER_STEP if backward else CHUNKS_PER_STEP
     while chunks % n:
         n //= 2
     return n
@@ -183,11 +196,12 @@ def _blocks(x, r: int, width: int):
 
 
 def _diagonal(x, r: int, width: int):
-    """[2 Q, r · width] -> [Q, r · width]: block (h, h) of each head."""
-    Q = x.shape[0] // 2
+    """[.., 2 Q, r · width] -> [.., Q, r · width]: block (h, h) of each
+    head."""
+    Q = x.shape[-2] // 2
     if r == 1:
-        return x[:Q]
-    return jnp.concatenate([x[:Q, :width], x[Q:, width:]], axis=1)
+        return x[..., :Q, :]
+    return jnp.concatenate([x[..., :Q, :width], x[..., Q:, width:]], axis=-1)
 
 
 def _products(a, b, exact: bool):
@@ -232,23 +246,24 @@ def _inverses(A, exact: bool):
 
 
 def _lane_sums(F, ranges, exact: bool):
-    """float32 F [Q, W] -> [16, Q] float32 whose row a is the sum of F over
-    the lanes ``ranges[a]`` = (first, width), a token a lane: one 0/1
-    product, F as three bfloat16 parts (or at ``HIGHEST``)."""
-    shape = (2 * SUBLANE, F.shape[1])
-    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    """float32 F [chunks, Q, W] -> [chunks, 16, Q] float32 whose row a is
+    the sum of F over the lanes ``ranges[a]`` = (first, width), a token a
+    lane: one 0/1 product a chunk, F as three bfloat16 parts (or at
+    ``HIGHEST``)."""
+    shape = (F.shape[0], 2 * SUBLANE, F.shape[2])
+    lane = _lane(shape)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     sel = jnp.zeros(shape, jnp.bool_)
     for a, (first, width) in enumerate(ranges):
         sel = sel | ((row == a) & (lane >= first) & (lane < first + width))
     if exact:
-        return _dot(sel.astype(jnp.float32), F, _NT, True)
+        return _dots("naw,nqw->naq", sel.astype(jnp.float32), F, True)
     pick = sel.astype(jnp.bfloat16)
     out = None
     for _ in range(3):
         part = F.astype(jnp.bfloat16)
         F = F - part.astype(jnp.float32)
-        d = _dot(pick, part, _NT)
+        d = _dots("naw,nqw->naq", pick, part, False)
         out = d if out is None else out + d
     return out
 
@@ -324,14 +339,19 @@ def _l2_parts(x, eps: float):
     return xf * rs, rs
 
 
-def _prepare(q_ref, k_ref, gate_ref, Q: int, r: int, with_q: bool, l2_eps):
-    """The part of a step no state enters, for all its chunks at once
-    ([chunks, ., .] arrays: one basic block, the chunks' chains of products
-    side by side): (the gate tiles, their transposes, q — ``with_q``, else
-    None —, k, each chunk's pair ``[M₀ | M₁]`` in the compute dtype and —
-    ``with_q`` — ``q·kᵀ`` under each head's decays, paired the same way).
-    With ``l2_eps`` q and k arrive raw and are l2-normalised here (q times
-    ``dk ** -0.5``), rounded to the compute dtype as the mixer rounds."""
+def _prepare(q_ref, k_ref, gate_ref, Q: int, r: int, l2_eps):
+    """The part of a step that neither a state nor a cotangent enters, for
+    all its chunks at once ([chunks, ., .] arrays: one basic block, the
+    chunks' chains of products side by side): the gate tiles and their
+    transposes (``tiles``, ``cols``), ``q`` and ``k`` in the compute dtype,
+    ``k2`` = [k; k] (``·k2ᵀ`` makes a product against k twice along the
+    lanes), β down the sublanes and the two masked decay blocks of each
+    head, paired (``b_c``, ``D_lo``, ``D_up``), ``kk`` = k·kᵀ, each chunk's
+    pair ``M`` = [M₀ | M₁] in the compute dtype and ``P`` = q·kᵀ under each
+    head's decays, float32. With ``l2_eps`` q and k arrive raw and are
+    l2-normalised here (q times ``dk ** -0.5``), rounded to the compute
+    dtype as the mixer rounds; ``l2`` then holds the float32 unit vectors
+    and the rsqrt of q and of k (:func:`_l2_parts`)."""
     cd = q_ref.dtype
     exact = cd == jnp.float32
     nc = gate_ref.shape[2]
@@ -340,18 +360,21 @@ def _prepare(q_ref, k_ref, gate_ref, Q: int, r: int, with_q: bool, l2_eps):
     cols = jnp.swapaxes(tiles, 1, 2)
     k = k_ref[0].reshape(nc, Q, dk)
     if l2_eps is not None:
-        k = _l2_parts(k, l2_eps)[0].astype(cd)
-    k2 = jnp.concatenate([k, k], axis=1)  # k·kᵀ twice along the lanes
+        uk, rk = _l2_parts(k, l2_eps)
+        k = uk.astype(cd)
+    k2 = jnp.concatenate([k, k], axis=1)
     b_c, D_lo, D_up = _pair_blocks(tiles, cols, Q, r)
-    M = _inverses(b_c * _dots("nik,njk->nij", k, k2, exact) * D_lo,
-                  exact).astype(cd)
-    q = P = None
-    if with_q:
-        q = q_ref[0].reshape(nc, Q, dk)
-        if l2_eps is not None:
-            q = (_l2_parts(q, l2_eps)[0] * dk ** -0.5).astype(cd)
-        P = (_dots("nik,njk->nij", q, k2, exact) * D_up).astype(cd)
-    return tiles, cols, q, k, M, P
+    kk = _dots("nik,njk->nij", k, k2, exact)
+    M = _inverses(b_c * kk * D_lo, exact).astype(cd)
+    q = q_ref[0].reshape(nc, Q, dk)
+    l2 = None
+    if l2_eps is not None:
+        uq, rq = _l2_parts(q, l2_eps)
+        q = (uq * dk ** -0.5).astype(cd)
+        l2 = (uq, rq, uk, rk)
+    P = _dots("nik,njk->nij", q, k2, exact) * D_up
+    return dict(tiles=tiles, cols=cols, q=q, k=k, k2=k2, b_c=b_c, D_lo=D_lo,
+                D_up=D_up, kk=kk, M=M, P=P, l2=l2)
 
 
 def _head_means(x, dv: int):
@@ -397,9 +420,9 @@ def _fwd_kernel(*refs, Q: int, r: int, keep: bool, norms):
     def _():
         state[...] = jnp.zeros(state.shape, state.dtype)
 
-    tiles, cols, q, k, M, P = _prepare(q_ref, k_ref, gate_ref, Q, r, True,
-                                       norms and norms[0])
-    hd = _end_blocks(tiles, cols, Q, r, dv)
+    pre = _prepare(q_ref, k_ref, gate_ref, Q, r, norms and norms[0])
+    q, k, M, P = pre["q"], pre["k"], pre["M"], pre["P"].astype(cd)
+    hd = _end_blocks(pre["tiles"], pre["cols"], Q, r, dv)
     kf, qf = k.astype(f32), q.astype(f32)
     if r > 1:  # a head's copy on its own lanes
         kf, qf = (jnp.concatenate([a] * r, axis=2) for a in (kf, qf))
@@ -448,20 +471,25 @@ def _fwd_kernel(*refs, Q: int, r: int, keep: bool, norms):
 
 
 def _bwd_kernel(*refs, Q: int, r: int, norms):
-    """A step of the backward (the module's docstring). With ``norms`` q
-    and k arrive raw, ``do_ref`` holds the cotangent of the mixer's ``y``,
-    z and the gated norm's weight are two more inputs and d z and the
-    weight's gradient (summed over a row's steps in its block) two more
-    outputs: a chunk's o is rebuilt from the kept state and the blocks the
-    chunk rebuilds anyway (one more product), ``do`` formed through the
-    gated norm, and d q̂, d k̂ leave through the l2 norm."""
+    """A step of the backward, in the forward's phases (the module's
+    docstring): everything no ``dS`` enters for all the step's chunks at
+    once, the ``dS`` chain with ONE product a chunk and value head, then
+    every gradient from the chain's ``dS`` for all the chunks at once. With
+    ``norms`` q and k arrive raw, ``do_ref`` holds the cotangent of the
+    mixer's ``y``, z and the gated norm's weight are two more inputs and d
+    z and the weight's gradient (summed over a row's steps in its block)
+    two more outputs: the chunks' o are rebuilt from the kept states and
+    the blocks the step makes anyway (``P Δ`` rides the product that makes
+    ``P W``), ``do`` formed through the gated norm, and d q̂, d k̂ leave
+    through the l2 norm."""
     n_in = 8 if norms else 6
     q_ref, k_ref, v_ref, gate_ref, s_ref, do_ref = refs[:6]
     dq_ref, dk_ref, dv_ref, dgate_ref = refs[n_in:n_in + 4]
-    dstate, m_ref = refs[-2:]
+    dstate, gt_ref, c_ref, kap_ref, ds_ref = refs[-5:]
     zr = pl.program_id(2)
     nc = gate_ref.shape[2]
     dk, dv = q_ref.shape[2], v_ref.shape[2] // r
+    W = r * dv
     cd, f32 = q_ref.dtype, jnp.float32
     exact = cd == f32
 
@@ -469,10 +497,32 @@ def _bwd_kernel(*refs, Q: int, r: int, norms):
     def _():
         dstate[...] = jnp.zeros(dstate.shape, f32)
 
-    *_, M, _ = _prepare(q_ref, k_ref, gate_ref, Q, r, False,
-                        norms and norms[0])
-    m_ref[...] = M
-    if norms:
+    # ---- the forward's chunks again, from the kept states
+    pre = _prepare(q_ref, k_ref, gate_ref, Q, r, norms and norms[0])
+    q, k, k2, M, P, b_c, D_lo, D_up = (pre[a] for a in (
+        "q", "k", "k2", "M", "P", "b_c", "D_lo", "D_up"))
+    Pc = P.astype(cd)
+    hd = _end_blocks(pre["tiles"], pre["cols"], Q, r, dv)
+    kf = k.astype(f32)
+    kw, qw = kf, q.astype(f32)
+    if r > 1:  # a head's copy on its own lanes
+        kw, qw = (jnp.concatenate([a] * r, axis=2) for a in (kw, qw))
+    S0c = s_ref[0, :, 0]  # [chunks, dk, r · dv]
+    kq = jnp.concatenate([k, q], axis=1)
+    kqS = _dots("nqk,nkw->nqw", kq, S0c, exact)
+    kS, qS = kqS[:, :Q], kqS[:, Q:]
+    Rv = v_ref[0].reshape(nc, Q, W).astype(f32) - hd["e"] * kS  # R = β ⊙ Rv
+    DW = _dots("nij,njk->nik", M, jnp.concatenate(
+        [_blocks((hd["b"] * Rv).astype(cd), r, dv),
+         _blocks((hd["b"] * hd["e"] * kw).astype(cd), r, dv)], axis=2),
+        exact)  # [chunks, Q, 2 W]: Δ | W
+    deltas = _blocks(DW[..., :W].astype(cd), r, dv)
+    Ws = _blocks(DW[..., W:].astype(cd), r, dv)
+    PWD = _dots("nij,njk->nik", Pc,
+                jnp.concatenate([Ws, deltas], axis=2) if norms else Ws,
+                exact)  # P W (| P Δ)
+    do = do_ref[0].reshape(nc, Q, W).astype(f32)
+    if norms:  # do is d y: through the gate and the norm to d o
         z_ref, w_ref = refs[6:8]
         dz_ref, dw_ref = refs[n_in + 4:n_in + 6]
 
@@ -480,116 +530,105 @@ def _bwd_kernel(*refs, Q: int, r: int, norms):
         def _():
             dw_ref[...] = jnp.zeros(dw_ref.shape, f32)
 
-    sub = jax.lax.broadcasted_iota(jnp.int32, (SUBLANE, Q), 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (SUBLANE, Q), 1)
-    # the lanes of :func:`_lane_sums`' operand [Zs | Yp | d e | d t | d β]
-    # (a pair, a pair, three of a head's dv lanes) that make row 5 h + a
-    W = r * dv
+        z, w = z_ref[0].reshape(nc, Q, W).astype(f32), w_ref[...]
+        n, rstd = _rms_parts(hd["e"] * qS + PWD[..., W:], norms[1], dv)
+        sig = jax.nn.sigmoid(z)
+        dz_ref[0] = (do * (n * w).astype(cd).astype(f32)
+                     * (sig * (1.0 + z * (1.0 - sig)))
+                     ).astype(dz_ref.dtype).reshape(nc * Q, W)
+        dy = (do * (z * sig)).astype(cd).astype(f32)  # as y was rounded
+        dw_ref[0, 0] += jnp.broadcast_to(
+            jnp.sum(jnp.sum(dy * n, axis=0), axis=0, keepdims=True),
+            dw_ref.shape[2:])
+        dn = dy * w
+        do = rstd * (dn - n * _head_means(dn * n, dv))
+    doc = do.astype(cd)
+    # ---- what ties a chunk's dS₀ to its dS₁: Gᵀ, κ and q̃ᵀ do
+    GT = _dots("nqw,nqk->nwk", (hd["t"] * DW[..., W:]).astype(cd), k, exact)
+    qe = (hd["e"] * qw - PWD[..., :W]).astype(cd)  # q̃
+    for h in range(r):
+        at = slice(h * dv, (h + 1) * dv)
+        gt_ref[:, h] = GT[:, at, :].astype(cd)
+        c_ref[:, :, at] = _dots("nqk,nqw->nkw", qe[..., at], doc[..., at],
+                                exact)
+    kap_ref[...] = jnp.broadcast_to(hd["kappa"], kap_ref.shape)
+
+    def chunk(ci, carry):  # dS₀ = κ dS₁ − Gᵀ dS₁ + q̃ᵀ do
+        c = nc - 1 - ci
+        dS1 = dstate[...]
+        ds_ref[c] = dS1
+        dS1c = dS1.astype(cd)
+        outs = [_dot(gt_ref[c, h], dS1c[:, h * dv:(h + 1) * dv], None, exact)
+                for h in range(r)]
+        out = jnp.concatenate(outs, axis=1) if r > 1 else outs[0]
+        dstate[...] = kap_ref[c, :1, :] * dS1 + c_ref[c] - out
+        return carry
+
+    jax.lax.fori_loop(0, nc, chunk, 0)
+    # ---- Δ enters o through P and the leaving state through t ⊙ k
+    dS1 = ds_ref[...]  # [chunks, dk, r · dv]: d of the state LEAVING each
+    dS1c = dS1.astype(cd)
+    d_delta = (_diagonal(_dots("nij,niw->njw", Pc, doc, exact), r, dv)
+               + hd["t"] * _dots("nqk,nkw->nqw", k, dS1c, exact))
+    dP = _dots("nqw,njw->nqj", doc, deltas, exact)  # [chunks, Q, 2 Q]
+    dR = _diagonal(_dots("nij,niw->njw", M, d_delta.astype(cd), exact),
+                   r, dv)
+    dA = -_dots("nqw,njw->nqj", dR.astype(cd), deltas, exact)
+    Yp = dA * pre["kk"] * D_lo  # d β's part through A, a row sum
+    Zs = dP * P + b_c * Yp  # d (c_i − c_j) of both decay blocks
+    dqk, dkk = (dP * D_up).astype(cd), (b_c * dA * D_lo).astype(cd)
+    dv_ref[0] = (hd["b"] * dR).astype(dv_ref.dtype).reshape(nc * Q, W)
+    g_kS = (-(hd["b"] * hd["e"]) * dR).astype(cd)
+    g_qS = (hd["e"] * do).astype(cd)
+    dtk = _dots("njw,nkw->njk", deltas, dS1c, exact)  # Δ_h · dS₁_hᵀ
+    dtks = [dtk[:, h * Q:(h + 1) * Q] for h in range(r)]
+    # the products against a pair sum over its heads by themselves; k's
+    # rows above q's, as their right factors are shared
+    pair = jnp.concatenate([dkk, dqk], axis=1)  # [chunks, 2 Q, 2 Q]
+    halves = _dots("nij,nik->njk", pair, kq, exact)
+    both = (_dots("nqw,nkw->nqk", jnp.concatenate([g_kS, g_qS], axis=1), S0c,
+                  exact) + _dots("nqj,njk->nqk", pair, k2, exact))
+    dqry, dkey = both[:, Q:], both[:, :Q] + halves[:, :Q] + halves[:, Q:]
+    for h in range(r):
+        dkey = dkey + hd["t_c"][h] * dtks[h]
+    if norms:  # d x = (d x̂ − u (u · d x̂)) · rsqrt(Σ x² + eps)
+        uq, rq, uk, rk = pre["l2"]
+        dqry, dkey = (
+            rs * (d - u * jnp.sum(u * d, axis=2, keepdims=True))
+            for d, u, rs in ((dqry, uq, rq * dk ** -0.5), (dkey, uk, rk)))
+    dq_ref[0] = dqry.astype(dq_ref.dtype).reshape(nc * Q, dk)
+    dk_ref[0] = dkey.astype(dk_ref.dtype).reshape(nc * Q, dk)
+    # ---- sums over a token's channels, tokens in the lanes: the lanes of
+    #      :func:`_lane_sums`' operand [Zs | Yp | d e | d t | d β] (a pair,
+    #      a pair, three of a head's dv lanes) that make row 5 h + 0 Σ_j
+    #      Zs, 1 Σ_j Yp, 2 d e, 3 d t, 4 d β through R
     ranges = [(first + h * width, width) for h in range(r)
               for first, width in ((0, Q), (2 * Q, Q), (4 * Q, dv),
                                    (4 * Q + W, dv), (4 * Q + 2 * W, dv))]
-
-    def chunk(ci, carry):
-        c = nc - 1 - ci
-        rows = pl.ds(pl.multiple_of(c * Q, Q), Q)
-        q, k = q_ref[0, rows, :], k_ref[0, rows, :]
-        if norms:
-            (uq, rq), (uk, rk) = (_l2_parts(x, norms[0]) for x in (q, k))
-            q, k = (uq * dk ** -0.5).astype(cd), uk.astype(cd)
-        kq = jnp.concatenate([k, q], axis=0)
-        k2 = jnp.concatenate([k, k], axis=0)
-        kf = k.astype(f32)
-        tile = gate_ref[0, 0, c]
-        cols = tile.T
-        b_c, D_lo, D_up = _pair_blocks(tile, cols, Q, r)
-        hd = _end_blocks(tile, cols, Q, r, dv)
-        kk, qk = _dot(k, k2, _NT, exact), _dot(q, k2, _NT, exact)
-        # ---- the forward's chunk again, from the kept state
-        S0c = s_ref[0, c, 0]
-        S0 = S0c.astype(f32)
-        v = v_ref[0, rows, :].astype(f32)
-        M = m_ref[c]
-        kqS = _dot(kq, S0c, None, exact)
-        kS, qS = kqS[:Q], kqS[Q:]
-        Rv = v - hd["e"] * kS  # R = β ⊙ Rv
-        delta = _dot(M, _blocks((hd["b"] * Rv).astype(cd), r, dv), None,
-                     exact).astype(cd)
-        deltas = _blocks(delta, r, dv)
-        P = qk * D_up
-        # ---- Δ enters o through P and the leaving state through t ⊙ k
-        do, dS1 = do_ref[0, rows, :].astype(f32), dstate[...]
-        if norms:  # do is d y: through the gate and the norm to d o
-            z, w = z_ref[0, rows, :].astype(f32), w_ref[...]
-            o = hd["e"] * qS + _dot(P.astype(cd), deltas, None, exact)
-            n, rstd = _rms_parts(o, norms[1], dv)
-            sig = jax.nn.sigmoid(z)
-            dz_ref[0, rows, :] = (
-                do * (n * w).astype(cd).astype(f32)
-                * (sig * (1.0 + z * (1.0 - sig)))).astype(dz_ref.dtype)
-            dy = (do * (z * sig)).astype(cd).astype(f32)  # as y was rounded
-            carry = carry + jnp.sum(dy * n, axis=0, keepdims=True)
-            dn = dy * w
-            do = rstd * (dn - n * _head_means(dn * n, dv))
-        doc, dS1c = do.astype(cd), dS1.astype(cd)
-        d_delta = (_diagonal(_dot(P.astype(cd), doc, _TN, exact), r, dv)
-                   + hd["t"] * _dot(k, dS1c, None, exact))
-        dP = _dot(doc, deltas, _NT, exact)  # [Q, 2 Q], a pair
-        dR = _diagonal(_dot(M, d_delta.astype(cd), _TN, exact), r, dv)
-        dA = -_dot(dR.astype(cd), deltas, _NT, exact)
-        Yp = dA * kk * D_lo  # d β's part through A, a row sum
-        Zs = dP * P + b_c * Yp  # d (c_i − c_j) of both decay blocks
-        dqk, dkk = (dP * D_up).astype(cd), (b_c * dA * D_lo).astype(cd)
-        dv_ref[0, rows, :] = (hd["b"] * dR).astype(dv_ref.dtype)
-        g_kS = (-(hd["b"] * hd["e"]) * dR).astype(cd)
-        g_qS = (hd["e"] * do).astype(cd)
-        dtk = _dot(deltas, dS1c, _NT, exact)  # [2 Q, dk]: Δ_h · dS₁_hᵀ
-        dtks = [dtk[h * Q:(h + 1) * Q] for h in range(r)]
-        # the products against a pair sum over its heads by themselves
-        halves = (_dot(dqk, q, _TN, exact) + _dot(dkk, k, _TN, exact))
-        dqry = _dot(g_qS, S0c, _NT, exact) + _dot(dqk, k2, None, exact)
-        dkey = (_dot(g_kS, S0c, _NT, exact) + _dot(dkk, k2, None, exact)
-                + halves[:Q] + halves[Q:])
-        for h in range(r):
-            dkey = dkey + hd["t_c"][h] * dtks[h]
-        if norms:  # d x = (d x̂ − u (u · d x̂)) · rsqrt(Σ x² + eps)
-            dqry, dkey = (
-                rs * (d - u * jnp.sum(u * d, axis=1, keepdims=True))
-                for d, u, rs in ((dqry, uq, rq * dk ** -0.5),
-                                 (dkey, uk, rk)))
-        dq_ref[0, rows, :] = dqry.astype(dq_ref.dtype)
-        dk_ref[0, rows, :] = dkey.astype(dk_ref.dtype)
-        # ---- sums over a token's channels, tokens in the lanes; row 5 h +
-        #      0 Σ_j Zs, 1 Σ_j Yp, 2 d e, 3 d t, 4 d β through R
-        sums = _lane_sums(jnp.concatenate(
-            [Zs, Yp, do * qS - hd["b"] * dR * kS,
-             jnp.concatenate([kf * d for d in dtks], axis=1) if r > 1
-             else kf * dtks[0], dR * Rv], axis=1), ranges, exact)
-        down = jnp.sum(Zs, axis=0, keepdims=True)  # [1, 2 Q]
-        held = jnp.sum(dS1 * S0, axis=0, keepdims=True)  # [1, r · dv]
-        out = jnp.zeros((SUBLANE, Q), f32)
-        for h in range(r):
-            at = 5 * h
-            d_kappa = jnp.sum(held[:, h * dv:(h + 1) * dv], axis=1,
-                              keepdims=True)  # [1, 1]
-            dt_t = sums[at + 3:at + 4] * hd["t_r"][h]
-            d_last = (jnp.sum(dt_t, axis=1, keepdims=True)
-                      + d_kappa * hd["kappa"][:, h * dv:h * dv + 1])
-            d_c = (sums[at:at + 1] - down[:, h * Q:(h + 1) * Q]
-                   + sums[at + 2:at + 3] * hd["e_r"][h] - dt_t)
-            d_c = d_c + jnp.where(lane[:1] == Q - 1, d_last, 0.0)
-            out = jnp.where(sub == h, d_c, out)
-            out = jnp.where(sub == r + h,
-                            sums[at + 1:at + 2] + sums[at + 4:at + 5], out)
-        dgate_ref[0, 0, c] = jnp.concatenate(
-            [out, jnp.zeros((SUBLANE, LANE - Q), f32)], axis=1)
-        dstate[...] = hd["kappa"] * dS1 + _dot(
-            kq, jnp.concatenate([g_kS, g_qS], axis=0), _TN, exact)
-        return carry
-
-    dw = jax.lax.fori_loop(0, nc, chunk,
-                           jnp.zeros((1, r * dv), f32) if norms else 0)
-    if norms:
-        dw_ref[0, 0] += jnp.broadcast_to(dw, dw_ref.shape[2:])
+    sums = _lane_sums(jnp.concatenate(
+        [Zs, Yp, do * qS - hd["b"] * dR * kS,
+         jnp.concatenate([kf * d for d in dtks], axis=2) if r > 1
+         else kf * dtks[0], dR * Rv], axis=2), ranges, exact)
+    down = jnp.sum(Zs, axis=1, keepdims=True)  # [chunks, 1, 2 Q]
+    held = jnp.sum(dS1 * S0c.astype(f32), axis=1, keepdims=True)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (nc, SUBLANE, Q), 1)
+    last = _lane((nc, 1, Q)) == Q - 1
+    out = jnp.zeros((nc, SUBLANE, Q), f32)
+    for h in range(r):
+        at = 5 * h
+        d_kappa = jnp.sum(held[..., h * dv:(h + 1) * dv], axis=2,
+                          keepdims=True)  # [chunks, 1, 1]
+        dt_t = sums[:, at + 3:at + 4] * hd["t_r"][h]
+        d_last = (jnp.sum(dt_t, axis=2, keepdims=True)
+                  + d_kappa * hd["kappa"][..., h * dv:h * dv + 1])
+        d_c = (sums[:, at:at + 1] - down[..., h * Q:(h + 1) * Q]
+               + sums[:, at + 2:at + 3] * hd["e_r"][h] - dt_t)
+        d_c = d_c + jnp.where(last, d_last, 0.0)
+        out = jnp.where(sub == h, d_c, out)
+        out = jnp.where(sub == r + h,
+                        sums[:, at + 1:at + 2] + sums[:, at + 4:at + 5], out)
+    dgate_ref[0, 0] = jnp.concatenate(
+        [out, jnp.zeros((nc, SUBLANE, LANE - Q), f32)], axis=2)
 
 
 def _params(interpret: bool):
@@ -640,11 +679,11 @@ def gate_tiles(g, beta, seg, chunk: int, k_heads: int):
          every(prev), every(last)], axis=3)
 
 
-def _dims(q, v, chunk: int):
+def _dims(q, v, chunk: int, backward: bool = False):
     R, T, G, dk = q.shape
     H, dv = v.shape[2:]
     Z = T // chunk
-    return R, T, G, H, dk, dv, H // G, Z, chunks_per_step(Z)
+    return R, T, G, H, dk, dv, H // G, Z, chunks_per_step(Z, backward)
 
 
 def _specs(dims, Q: int, reverse: bool):
@@ -720,7 +759,7 @@ def rule_bwd(q, k, v, g, beta, seg, states, do, chunk: int,
     ``norms`` (as :func:`rule_fwd`'s) ``do`` is the cotangent of ``y``, dq
     and dk are those of the raw q and k, and two more follow: dz in the
     compute dtype and the norm's weight's gradient [dv] float32."""
-    dims = _dims(q, v, chunk)
+    dims = _dims(q, v, chunk, backward=True)
     R, T, G, H, dk, dv, r, Z, nc = dims
     key, val, gate, st, steps = _specs(dims, chunk, reverse=True)
     tile = gate_tiles(g, beta, seg, chunk, G)
@@ -743,7 +782,10 @@ def rule_bwd(q, k, v, g, beta, seg, states, do, chunk: int,
             in_specs=[key, key, val, gate, st, val] + more_specs,
             out_specs=[key, key, val, gate] + more_out,
             scratch_shapes=[pltpu.VMEM((dk, r * dv), jnp.float32),
-                            pltpu.VMEM((nc, chunk, 2 * chunk), cd)]),
+                            pltpu.VMEM((nc, r, dv, dk), cd),
+                            pltpu.VMEM((nc, dk, r * dv), jnp.float32),
+                            pltpu.VMEM((nc, SUBLANE, r * dv), jnp.float32),
+                            pltpu.VMEM((nc, dk, r * dv), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((R, T, G * dk), cd),
                    jax.ShapeDtypeStruct((R, T, G * dk), cd),
                    jax.ShapeDtypeStruct((R, T, H * dv), cd),

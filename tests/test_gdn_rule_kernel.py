@@ -103,9 +103,11 @@ def worst(got, want):
 
 
 # layout, row length (256: one step of 4 chunks; 384: 3 steps of 2; 320: 5
-# steps of 1; 300: no whole number of chunks), key heads
+# steps of 1; 300: no whole number of chunks; 1024: 2 steps of 8, the state
+# and its cotangent carried from one whole step into the next), key heads
 CASES = [("first", 256, 1), ("second", 256, 1), ("grid", 256, 1),
-         ("twice", 384, 2), ("padding", 320, 1), ("padding", 300, 2)]
+         ("twice", 384, 2), ("padding", 320, 1), ("padding", 300, 2),
+         ("twice", 1024, 1)]
 
 
 @pytest.mark.parametrize("which,T,G", CASES)
@@ -232,6 +234,58 @@ def test_the_norms_inside_the_kernels_equal_the_mixers_ends_in_float32(
         assert worst(a, x_) < 2e-4, (name, worst(a, x_))
 
 
+def sequential_ends(q, k, v, z, w, g, beta, seg, wt):
+    """(Σ wt · y, y) of the mixer's ends around the token-by-token
+    recurrence (:func:`sequential`), float32; ``seg`` a numpy array."""
+    _, o = sequential(gdn.l2_normalize(q) * D ** -0.5, gdn.l2_normalize(k),
+                      v, g, beta, seg, 0.0)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + EPS)
+    y = (o * w).reshape(z.shape) * jax.nn.silu(z)
+    return jnp.sum(y * wt), y
+
+
+# layout, row length, key heads, value heads a key head (2: the cell's 16 /
+# 32 grouping), the backward's chunks a step at most — (256, 8): one step of
+# 4 chunks; (512, 8): one step of 8; (1024, 8): 2 steps of 8, dS carried from
+# a whole step into the one before it; (384, 8): 3 steps of 2; (300, 8): no
+# whole number of chunks, 5 steps of 1; (512, 1) and (512, 2): the backward
+# walks 8 and 4 steps where the forward that left the states took one
+BWD_CASES = [("first", 256, 1, 2, 8), ("second", 512, 1, 2, 8),
+             ("twice", 1024, 1, 2, 8), ("twice", 384, 2, 2, 8),
+             ("padding", 300, 2, 2, 8), ("second", 512, 2, 2, 1),
+             ("twice", 512, 1, 2, 2), ("twice", 200, 2, 1, 8)]
+
+
+@pytest.mark.parametrize("which,T,G,r,most", BWD_CASES)
+def test_the_backward_equals_the_recurrences_in_float32(
+        which, T, G, r, most, monkeypatch):
+    """The backward kernel against the float32 token-by-token recurrence
+    inside the mixer's ends, not only against the chunked XLA form: every
+    cotangent — dq, dk, dv, dg, dβ, the output gate's and the norm
+    weight's — where a document begins inside a chunk, the row is no whole
+    number of steps of 8 chunks, a step holds one chunk or several, and the
+    backward's steps are not the forward's."""
+    monkeypatch.setattr(kernel, "BWD_CHUNKS_PER_STEP", most)
+    assert kernel.chunks_per_step(-(-T // Q), backward=True) == min(
+        most, kernel.chunks_per_step(-(-T // Q)))
+    args = ends_inputs(T, G, r, seed=T + G + r + most, dtype=jnp.float32)
+    seg = layout(which, T)
+    wt = jax.random.normal(jax.random.PRNGKey(9), (1, T, G * r * D))
+    jax.clear_caches()  # ``ends`` was traced at another chunks a step
+    with jax.default_matmul_precision("highest"):
+        (_, y), got = ends("pallas_interpret", *args, jnp.asarray(seg), wt)
+        q, k, v, z, w, g, beta = args
+        (_, y_seq), want = jax.value_and_grad(
+            lambda q, k, v, g, beta, z, w: sequential_ends(
+                q, k, v, z, w, g, beta, seg, wt),
+            argnums=tuple(range(7)), has_aux=True)(q, k, v, g, beta, z, w)
+    jax.clear_caches()
+    assert worst(y, y_seq) < 2e-5
+    for name, a, s_ in zip(ENDS_GRADS, got, want):
+        assert a.shape == s_.shape and a.dtype == s_.dtype, name
+        assert worst(a, s_) < 2e-4, (name, worst(a, s_))
+
+
 @pytest.mark.parametrize("which,T,G,r,low", [
     ("second", 256, 1, 2, True), ("padding", 300, 2, 1, False)])
 def test_the_norms_inside_the_kernels_in_bfloat16_are_as_near_as_the_xla_text(
@@ -345,6 +399,9 @@ def test_what_the_kernel_takes_and_the_dispatchs_three_answers(monkeypatch):
     assert not kernel.supported(64, 16, 32, 128, 128, jnp.float16)
     assert [kernel.chunks_per_step(z) for z in (224, 136, 6, 5)] == [
         8, 8, 2, 1]
+    assert [kernel.chunks_per_step(z, backward=True)
+            for z in (224, 136, 6, 5)] == [
+        min(n, kernel.BWD_CHUNKS_PER_STEP) for n in (8, 8, 2, 1)]
     cell = (64, 16, 32, 128, 128, bf)
     assert gdn._rule_impl("pallas_interpret", *cell) == "pallas_interpret"
     assert gdn._rule_impl("pallas", *cell) == "pallas"

@@ -102,6 +102,10 @@ QNEXT_GRID = (1, 16384)
 # key heads, value heads, head size, chunk), bfloat16 as the cell times it
 # and float32 as its ``rule_error`` calls it.
 GDN_RULE = (1, 16384, 16, 32, 128, 64)
+# ... at the grad program's row and at the cell's own two; at those two the
+# backward kernel ALONE too, with the mixer's norms inside as the cell runs
+# it: its program's temporaries and its body's size.
+GDN_RULE_T = (16384, 14336, 8704)
 # The LFM2-MoE cell's cut (configs/lfm2-24b-a2b.json): its grad program's
 # largest micro-batch — the largest grid the packer makes of the cell's
 # traffic (traffic/train-toolcall-16k.json, ``compile_grid``) — at the
@@ -544,15 +548,46 @@ def _compile_all():
         return jnp.sum(gdnmod.gated_delta_rule(q, k, v, g, beta, seg, chunk,
                                                "pallas") ** 2)
 
-    R, T, G, H, D, Q = GDN_RULE
-    for name, dt in (("gdn-rule-bfloat16", jnp.bfloat16),
-                     ("gdn-rule-float32", jnp.float32)):
-        record(name, jax.jit(
-            jax.value_and_grad(rule_loss, argnums=(0, 1, 2, 3, 4)),
-            static_argnums=6).lower(
-                f32(R, T, G, D, dtype=dt), f32(R, T, G, D, dtype=dt),
-                f32(R, T, H, D, dtype=dt), f32(R, T, H), f32(R, T, H),
-                f32(R, T, dtype=jnp.int32), Q).compile())
+    from areal_tpu.ops.pallas import gated_delta_rule as rule_kernel
+
+    def rule_bwd_alone(q, k, v, g, beta, seg, states, dy, z, w):
+        return rule_kernel.rule_bwd(q, k, v, g, beta, seg, states, dy, Q,
+                                    norms=(z, w, 1e-6, 1e-6))
+
+    def body_eqns(jaxpr, inside=False):
+        """Equations of the Pallas kernels' bodies in ``jaxpr``, nested
+        ones counted: what every program that holds the kernel traces and
+        lowers again."""
+        n = 0
+        for eqn in jaxpr.eqns:
+            kernel = inside or eqn.primitive.name == "pallas_call"
+            n += inside
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                n += body_eqns(sub, kernel)
+        return n
+
+    R, _, G, H, D, Q = GDN_RULE
+    for dt in (jnp.bfloat16, jnp.float32):
+        for T in GDN_RULE_T:
+            name = f"gdn-rule-{jnp.dtype(dt).name}-{T}"
+            rule = (f32(R, T, G, D, dtype=dt), f32(R, T, G, D, dtype=dt),
+                    f32(R, T, H, D, dtype=dt), f32(R, T, H), f32(R, T, H),
+                    f32(R, T, dtype=jnp.int32))
+            record(name, jax.jit(
+                jax.value_and_grad(rule_loss, argnums=(0, 1, 2, 3, 4)),
+                static_argnums=6).lower(*rule, Q).compile())
+            if T == GDN_RULE[1]:
+                continue
+            alone = rule + (
+                f32(R, T // Q, G, D, H // G * D, dtype=dt),
+                f32(R, T, H * D, dtype=dt), f32(R, T, H * D, dtype=dt),
+                f32(D, dtype=dt))
+            bwd = jax.jit(rule_bwd_alone).lower(*alone).compile()
+            out[name].update(
+                bwd_custom_calls=bwd.as_text().count("tpu_custom_call"),
+                bwd_temp_bytes=bwd.memory_analysis().temp_size_in_bytes,
+                bwd_body_eqns=body_eqns(
+                    jax.make_jaxpr(rule_bwd_alone)(*alone).jaxpr))
 
     rules = sum(gdnmod.geometry_counts().values())
     impls = dict(gdnmod.rule_impl_counts())
@@ -893,19 +928,31 @@ def test_the_causal_kernel_compiles_at_heads_of_256(compiled, T):
     assert got["temp_bytes"] < 0.6e9
 
 
+@pytest.mark.parametrize("T", GDN_RULE_T)
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-def test_the_gated_delta_rule_kernels_compile_for_v5e(compiled, dtype):
+def test_the_gated_delta_rule_kernels_compile_for_v5e(compiled, dtype, T):
     """Forward and backward of the rule at the Qwen3-Next cell's geometry
-    (1 x 16,384, 16 / 32 heads of 128, chunk 64): two Mosaic kernels inside
-    the VMEM they ask for and nothing [chunk, chunk] beside them — the
-    temporaries are o in float32, the state entering each chunk in the
-    compute dtype, the gradients and the gates' tiles: under what a dozen
-    float32 [64, 64] blocks a chunk and value head take, which the XLA
-    form writes."""
-    got = compiled[f"gdn-rule-{dtype}"]
+    (1 x 16,384, 14,336 and 8,704, 16 / 32 heads of 128, chunk 64): two
+    Mosaic kernels inside the VMEM they ask for and nothing [chunk, chunk]
+    beside them — the temporaries are o in float32, the state entering each
+    chunk in the compute dtype, the gradients and the gates' tiles: under
+    what a dozen float32 [64, 64] blocks a chunk and value head take, which
+    the XLA form writes. At the cell's own rows the backward alone, with
+    the norms inside: ONE kernel, beside it less than the gradients it
+    returns would take in float32 (the gates' tiles, and the copies that
+    give dq, dk and dv their heads: 206 MB at 14,336 in bfloat16, 485 MB in
+    float32), and a body of under 1,000 equations (842 in bfloat16 when
+    its chunks' work became [chunks, ., .] arrays, 833 when a loop walked
+    them: a body that grows by unrolling costs every program that holds it
+    its set-up)."""
+    got = compiled[f"gdn-rule-{dtype}-{T}"]
     assert got["custom_calls"] == 2
-    R, T, G, H, D, Q = GDN_RULE
+    R, _, G, H, D, Q = GDN_RULE
     assert got["temp_bytes"] < R * T * H * Q * 4 * 12
+    if T != GDN_RULE[1]:
+        assert got["bwd_custom_calls"] == 1
+        assert got["bwd_temp_bytes"] < R * T * (G + H) * D * 2 * 4
+        assert 0 < got["bwd_body_eqns"] < 1000
 
 
 def test_the_qwen3_next_cut_compiles_inside_the_memory_it_leaves(compiled):

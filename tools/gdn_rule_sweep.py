@@ -1,7 +1,8 @@
-"""Chip sweep behind the gated delta rule kernels' one constant
-(``ops/pallas/gated_delta_rule.CHUNKS_PER_STEP``): ``models/gdn.
-gated_delta_rule`` alone — forward, and forward + backward — as the XLA
-form and as the Pallas kernel pair at each chunks-a-step candidate, at the
+"""Chip sweep behind the gated delta rule kernels' two constants
+(``ops/pallas/gated_delta_rule.CHUNKS_PER_STEP`` and the backward's
+``BWD_CHUNKS_PER_STEP``, ``--chunks-a-step`` / ``--bwd-chunks-a-step``):
+``models/gdn.gated_delta_rule`` alone — forward, and forward + backward —
+as the XLA form and as the Pallas kernel pair at each candidate, at the
 Qwen3-Next cell's rows (1 x 14,336 and 1 x 8,704, 16 key / 32 value heads
 of 128, chunk 64, bfloat16, two documents a row the second of which starts
 inside a chunk). Beside each time the least time the chip's peaks allow
@@ -23,7 +24,8 @@ the kernels with both norms inside (``gdn.rule_with_norms``; left out on
 a tree that has no such entry, so the tool runs on the parent too).
 
 Prints one JSON line a case: device milliseconds a call from a profiler
-capture of ``--reps`` calls (and its largest ops). ``--parity`` instead
+capture of ``--reps`` calls (its largest ops, and the two kernels' own:
+``gdn_rule_fwd_ms`` / ``gdn_rule_bwd_ms``). ``--parity`` instead
 runs the COMPILED kernels as shipped against the XLA form on the same
 operands, bfloat16 and float32 (the latter under "highest", as the cell's
 ``rule_error`` does), and both against the XLA form in float32: the worst
@@ -55,6 +57,10 @@ def main() -> int:
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--chunks-a-step", type=int, nargs="*",
                     default=[2, 4, 8])
+    ap.add_argument("--bwd-chunks-a-step", type=int, nargs="*", default=[],
+                    help="the backward kernel's own chunks a step (a tree "
+                         "that has ``BWD_CHUNKS_PER_STEP``); default: as "
+                         "shipped")
     ap.add_argument("--lengths", type=int, nargs="*", default=list(LENGTHS))
     ap.add_argument("--skip-xla", action="store_true")
     ap.add_argument("--parts", choices=("rule", "ends"), default="rule")
@@ -95,6 +101,26 @@ def main() -> int:
         out.flush()
 
     bf, f32 = jnp.bfloat16, jnp.float32
+    shipped = (kernel.CHUNKS_PER_STEP,
+               getattr(kernel, "BWD_CHUNKS_PER_STEP", None))
+
+    def chunks_a_step(fwd=None, bwd=None):
+        """Set the kernels' constants (None: as shipped; an older tree has
+        the forward's alone, which its backward follows)."""
+        kernel.CHUNKS_PER_STEP = fwd or shipped[0]
+        if shipped[1] is not None:
+            kernel.BWD_CHUNKS_PER_STEP = bwd or shipped[1]
+        jax.clear_caches()
+        return dict(fwd_chunks_a_step=kernel.CHUNKS_PER_STEP,
+                    bwd_chunks_a_step=getattr(
+                        kernel, "BWD_CHUNKS_PER_STEP", kernel.CHUNKS_PER_STEP))
+
+    def kernel_ms(ops):
+        """The two kernels' own device ms a call, from a capture's largest
+        ops."""
+        return {f"{name}_ms": sum(ms for op, ms in ops.items()
+                                  if op.split(".")[0] == name)
+                for name in (kernel.FWD_NAME, kernel.BWD_NAME)}
 
     def worst(got, want):
         return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
@@ -160,8 +186,10 @@ def main() -> int:
                      ENDS_NAMES, (worst(x, e) for x, e in zip(xs, exact))))
                     for how, xs in got.items()})
             return
-        for how in hows:
-            rec = dict(length=T, parts="ends", impl=how)
+        for how, bwd in [(how, n) for how in hows
+                         for n in (a.bwd_chunks_a_step or [None])]:
+            rec = dict(length=T, parts="ends", impl=how, **chunks_a_step(
+                bwd=bwd))
             if a.compile:
                 shapes = [jax.ShapeDtypeStruct(x.shape, x.dtype,
                                                sharding=chip) for x in args]
@@ -174,8 +202,8 @@ def main() -> int:
             f, f_ops = device_ms(ends_fn(how, impl, seg, grads=False), args,
                                  a.reps)
             fb, fb_ops = device_ms(ends_fn(how, impl, seg), args, a.reps)
-            emit(**rec, fwd_ms=f, fwd_bwd_ms=fb, fwd_ops=f_ops,
-                 fwd_bwd_ops=fb_ops)
+            emit(**rec, fwd_ms=f, fwd_bwd_ms=fb, **kernel_ms(fb_ops),
+                 fwd_ops=f_ops, fwd_bwd_ops=fb_ops)
 
     for T in a.lengths:
         ks = jax.random.split(jax.random.PRNGKey(0), 7)
@@ -237,13 +265,14 @@ def main() -> int:
         for backward in (False, True):
             ops, nbytes = gdn_cost.gdn_rule_cost(1, T, G, H, D, D, backward)
             least[backward] = 1e3 * peaks.least_time(ops, nbytes, kind)[0]
-        cases = ([] if a.skip_xla else [("xla", "xla", None)]) + [
-            (f"pallas-{n}", "pallas", n) for n in a.chunks_a_step]
-        for label, impl, n in cases:
-            if n is not None:
-                kernel.CHUNKS_PER_STEP = n
-                jax.clear_caches()
+        cases = ([] if a.skip_xla else [("xla", "xla", None, None)]) + [
+            (f"pallas-{n}" + (f"-bwd-{m}" if m else ""), "pallas", n, m)
+            for n in a.chunks_a_step
+            for m in (a.bwd_chunks_a_step or [None])]
+        for label, impl, n, m in cases:
             rec = dict(length=T, impl=label)
+            if n is not None:
+                rec.update(chunks_a_step(n, m))
             if a.compile:
                 if impl != "pallas":
                     continue
@@ -265,6 +294,7 @@ def main() -> int:
                  least_fwd_bwd_ms=least[False] + least[True],
                  fwd_roofline_pct=100 * least[False] / f,
                  fwd_bwd_roofline_pct=100 * (least[False] + least[True]) / fb,
+                 **(kernel_ms(fb_ops) if impl == "pallas" else {}),
                  fwd_ops=f_ops, fwd_bwd_ops=fb_ops)
     return 0
 
