@@ -1175,6 +1175,17 @@ class JaxTrainEngine(TrainableEngine):
             if frac is not None:
                 telemetry.set_gauge("train/gdn_norms_in_kernel_frac", frac)
                 span_attrs["gdn_norms_in_kernel_frac"] = frac
+        if self.cfg.mla is not None:
+            # what this grid's remat entry keeps of one latent-attention
+            # branch, a token (transformer.attention_kept_bytes_per_token)
+            kept = transformer.attention_kept_bytes_per_token(
+                self.cfg, self._remat_for(ub.R, ub.L),
+                self.compute_dtype.itemsize,
+                kernel=kernel_padded_len(
+                    self.attn_impl, ub.L,
+                    head_dim=self.cfg.head_dim) is not None)
+            telemetry.set_gauge("train/mla_kept_bytes_per_token", kept)
+            span_attrs["mla_kept_bytes_per_token"] = kept
         with telemetry.span("train/fwd_bwd", n_mbs=len(idxs),
                             grid=f"{ub.R}x{ub.L}",
                             remat=str(self._remat_for(ub.R, ub.L)),

@@ -149,7 +149,8 @@ def model_flops_per_token(
     [Q, Q] products and the inverse's 2 log2(Q) - 1 of them inside a
     chunk, the two triangular applications and the five products against
     the carried state; a short-convolution block's likewise
-    (:func:`_shortconv_flops`)."""
+    (:func:`_shortconv_flops`), and latent attention's five projections in
+    place of q/k/v/o (:func:`_mla_flops`)."""
     flops = transformer_flops_per_token(
         cfg.n_layers, cfg.hidden_dim, cfg.q_dim, cfg.kv_dim,
         cfg.intermediate_dim, 1 if cfg.is_critic else cfg.vocab_size,
@@ -157,7 +158,7 @@ def model_flops_per_token(
         moe=getattr(cfg, "moe", None),
     )
     factor = 1.0 if not backward else 4.0 if remat else 3.0
-    flops += _shortconv_flops(cfg, avg_seqlen) * factor
+    flops += (_shortconv_flops(cfg, avg_seqlen) + _mla_flops(cfg)) * factor
     gdn = getattr(cfg, "gdn", None)
     n_gdn = cfg.layer_kinds.count("gdn") if gdn is not None else 0
     if not n_gdn:
@@ -184,23 +185,50 @@ def _shortconv_flops(cfg, avg_seqlen: float) -> float:
     sc = getattr(cfg, "shortconv", None)
     if sc is None:
         return 0.0
-    from areal_tpu.models.config import CONV, attention_kind, has_dense_ffn
+    from areal_tpu.models.config import CONV, attention_kind
 
     d = cfg.hidden_dim
     attention = (2 * d * (cfg.q_dim + 2 * cfg.kv_dim) + 2 * cfg.q_dim * d
                  + 2 * 2 * cfg.q_dim * avg_seqlen)
     mixer = 2 * d * 3 * d + 2 * d * d + (2 + 2 * sc.kernel) * d
     n_conv = sum(attention_kind(k) == CONV for k in cfg.layer_kinds)
-    extra = n_conv * (mixer - attention)
-    moe = cfg.moe
-    if moe is not None:
-        experts = (moe.top_k * moe.num_experts / moe.n_routed * 3 * 2 * d
-                   * (moe.routed_intermediate_dim or cfg.intermediate_dim)
-                   + 2 * d * moe.n_routed
-                   + 3 * 2 * d * (moe.shared_intermediate_dim or 0))
-        n_dense = sum(map(has_dense_ffn, cfg.layer_kinds))
-        extra += n_dense * (3 * 2 * d * cfg.intermediate_dim - experts)
-    return extra
+    return n_conv * (mixer - attention) + _dense_block_flops(cfg)
+
+
+def _dense_block_flops(cfg) -> float:
+    """What the blocks that run the dense MLP in a model with experts (the
+    leading ones) differ by from the count above, a token's forward pass:
+    that MLP in place of the routed experts, the router and the shared
+    expert. 0 where every block runs the experts."""
+    from areal_tpu.models.config import has_dense_ffn
+
+    moe, d = cfg.moe, cfg.hidden_dim
+    if moe is None:
+        return 0.0
+    experts = (moe.top_k * moe.num_experts / moe.n_routed * 3 * 2 * d
+               * (moe.routed_intermediate_dim or cfg.intermediate_dim)
+               + 2 * d * moe.n_routed
+               + 3 * 2 * d * (moe.shared_intermediate_dim or 0))
+    n_dense = sum(map(has_dense_ffn, cfg.layer_kinds))
+    return n_dense * (3 * 2 * d * cfg.intermediate_dim - experts)
+
+
+def _mla_flops(cfg) -> float:
+    """What a model with latent attention (``cfg.mla``) differs by from
+    the count above, a token's forward pass: every block's five
+    projections (both latents, both expansions, o_proj) in place of
+    q/k/v/o — the attention proper is counted as any block's, at
+    ``q_dim`` — and its leading dense blocks' MLP
+    (:func:`_dense_block_flops`). 0 for any other model."""
+    mla = getattr(cfg, "mla", None)
+    if mla is None:
+        return 0.0
+    from areal_tpu.models.mla import flops_per_token
+
+    d = cfg.hidden_dim
+    qkvo = 2 * d * (cfg.q_dim + 2 * cfg.kv_dim) + 2 * cfg.q_dim * d
+    return (cfg.n_layers * (flops_per_token(mla, d, cfg.n_q_heads) - qkvo)
+            + _dense_block_flops(cfg))
 
 
 class FlopsCounter:

@@ -308,6 +308,12 @@ GDN_SCOPES = ("gdn_in_proj", "gdn_conv", "gdn_gates", "gdn_rule",
 # in-projection [B | C | x], both gates with the taps between them, the
 # out-projection; no DEVICE_SCOPES name lies between them and "layer_scan".
 SHORTCONV_SCOPES = ("shortconv_in_proj", "shortconv", "shortconv_out_proj")
+# Latent attention's projection path (glm4_moe_lite; models/mla.py), in
+# place of "qkv_proj" and "rope": both q matmuls with the latent's norm,
+# the k/v down-projection with its norm, the up-projection, and the
+# assembly (the narrow RoPE, the shared rotary key's broadcast, both
+# concatenates); "attention" and "o_proj" follow as for any block.
+MLA_SCOPES = ("mla_q_proj", "mla_kv_down", "mla_kv_up", "mla_assemble")
 
 
 def _annotation(name: str, attrs: Dict[str, Any]):

@@ -218,6 +218,38 @@ class ShortConvConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention's five sizes (HF deepseek_v2 / _v3 /
+    glm4_moe_lite keys of the same names; models/mla.py). A query or key
+    head is ``[nope | rope]`` — ``qk_nope_head_dim`` dims that carry no
+    position, then ``qk_rope_head_dim`` that RoPE turns, the key's rotary
+    part ONE vector a token shared by every head — and
+    ``TransformerConfig.head_dim`` is their sum; queries come through a
+    normed latent of ``q_lora_rank``, keys' nope parts and values through
+    ONE normed latent of ``kv_lora_rank``."""
+
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def kv_a_dim(self) -> int:
+        """[c_kv | k_rope]: the down-projection's output."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def kv_b_head_dim(self) -> int:
+        """[k_nope | v]: one head of the up-projection's output."""
+        return self.qk_nope_head_dim + self.v_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
 class S6Config:
     """A Mamba-1 (selective scan, S6) mixer's sizes: ``d_inner`` channels,
     each with ``state_dim`` states whose decay is its own (``A`` is
@@ -288,6 +320,10 @@ class TransformerConfig:
     s6: Optional[S6Config] = None  # the S6 blocks' mixer (GMU reads its width)
     gdn: Optional[GDNConfig] = None  # the GDN blocks' mixer
     shortconv: Optional[ShortConvConfig] = None  # the CONV blocks' mixer
+    # Latent attention in every attention block: q, k and v are not three
+    # projections of the hidden state (models/mla.py); ``head_dim`` is the
+    # query / key head's ``nope + rope``, ``n_kv_heads == n_q_heads``.
+    mla: Optional[MLAConfig] = None
     # sliding window attention (mistral/gemma2); None = full attention
     sliding_window: Optional[int] = None
     # The kind of each layer: FULL or SLIDING (HF ``layer_types``), or one
@@ -351,6 +387,11 @@ class TransformerConfig:
     residual_multiplier: float = 1.0
     attention_multiplier: Optional[float] = None
     logits_scaling: float = 1.0
+    # Multi-token-prediction modules the family publishes behind its last
+    # block (HF ``num_nextn_predict_layers``): carried through config.json
+    # both ways; nothing of them is built, and their weights
+    # (``model.layers.<n_layers>.*`` on) are skipped on load by name.
+    n_nextn_predict_layers: int = 0
     # HF family tag driving weight-name mapping + config.json emission
     # (models/hf.py); None for fabricated test configs.
     hf_family: Optional[str] = None
@@ -374,7 +415,11 @@ class TransformerConfig:
 
     @property
     def rotary_dim(self) -> int:
-        """Dims of a head that RoPE turns (the table's width)."""
+        """Dims of a head that RoPE turns (the table's width): the first
+        ``partial_rotary_factor`` of them, or latent attention's LAST
+        ``qk_rope_head_dim`` (models/mla.py turns them itself)."""
+        if self.mla is not None:
+            return self.mla.qk_rope_head_dim
         return int(self.head_dim * self.partial_rotary_factor)
 
     @property
@@ -423,8 +468,9 @@ class TransformerConfig:
     @property
     def has_cacheless_layers(self) -> bool:
         """Layers no K/V cache can decode: a mixer alone, a state-space
-        block, or a layer that reads what another layer made."""
-        return self.has_mixer_layers or any(
+        block, a layer that reads what another layer made, or latent
+        attention (its cache is the latent's)."""
+        return self.mla is not None or self.has_mixer_layers or any(
             attention_kind(k) in BLOCK_MIXER_KINDS for k in self.layer_kinds)
 
     @property

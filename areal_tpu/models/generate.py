@@ -43,7 +43,10 @@ def decode_refusal(cfg: TransformerConfig) -> Optional[str]:
     recurrent matrix and its convolution's last taps: ``gdn.DECODE_REFUSAL``),
     a short-convolution block likewise (the last ``conv_L_cache - 1`` tokens
     of ``B ⊙ x`` beside the attention blocks' K/V:
-    ``shortconv.DECODE_REFUSAL``).
+    ``shortconv.DECODE_REFUSAL``), latent attention likewise (a cache of
+    the kv latent and the shared rotary key with the up-projections
+    absorbed, and separate prefill and decode paths:
+    ``mla.DECODE_REFUSAL``, ``latent_attention_decode_cache``).
     Every entry point below prefills through ``forward``, which raises it."""
     return transformer.decode_refusal(cfg)
 

@@ -5,7 +5,7 @@ Parity target: the reference's per-family converter registry
 ``realhf/api/from_hf/{llama,qwen2,qwen3,gemma,gpt2,mistral,mixtral}.py``).
 Families covered: llama, qwen2 (qwen2.5), qwen3, mistral, gemma, gpt2,
 mixtral, qwen3_moe, olmoe, mellum, nemotron_h, afmoe, phi4flash,
-granitemoehybrid, qwen3_next, lfm2_moe.
+granitemoehybrid, qwen3_next, lfm2_moe, glm4_moe_lite.
 
 Weights are stacked on a leading layer axis (see models/transformer.py), so
 conversion transposes HF's ``[out, in]`` linear layout to ``[in, out]`` and
@@ -41,6 +41,7 @@ from areal_tpu.models.config import (
     SPARSE_FFN,
     SSD,
     GDNConfig,
+    MLAConfig,
     MoEConfig,
     RopeConfig,
     S6Config,
@@ -740,6 +741,102 @@ def _lfm2_moe_config(hf_config: Any) -> TransformerConfig:
     )
 
 
+# glm4_moe_lite: keys of the family that no block here runs, by name: (key,
+# the values that are run, why any other is refused).
+GLM4_MOE_LITE_REFUSALS = (
+    ("n_group", (None, 1), "n_group: group-limited routing"),
+    ("topk_group", (None, 1), "topk_group: group-limited routing"),
+    ("topk_method", (None, "noaux_tc"), "topk_method: a choice other than "
+     "the top-k of sigmoid score + e_score_correction_bias"),
+    ("rope_scaling", (None,), "rope_scaling: a scaled RoPE (YaRN's factor "
+     "on the softmax scale of latent attention)"),
+    ("attention_bias", (None, False), "attention_bias: a bias on latent "
+     "attention's projections"),
+    ("partial_rotary_factor", (None, 1, 1.0), "partial_rotary_factor: a "
+     "rotary part narrower than qk_rope_head_dim"),
+)
+
+
+@register_hf_family("glm4_moe_lite")
+def _glm4_moe_lite_config(hf_config: Any) -> TransformerConfig:
+    """GLM-4.7-Flash (zai-org, ``Glm4MoeLiteForCausalLM``; deepseek_v3's
+    attention and expert layer under GLM's names): whole pre-norm blocks
+    under plain RMSNorms, EVERY block's attention multi-head latent
+    attention (``q_lora_rank``, ``kv_lora_rank``, ``qk_nope_head_dim`` +
+    ``qk_rope_head_dim`` a query / key head, ``v_head_dim`` a value head:
+    models/mla.py), RoPE at ``rope_theta`` over the rotary part; a dense
+    SwiGLU of ``intermediate_size`` on the first ``first_k_dense_replace``
+    blocks, on the others ``n_routed_experts`` routed experts of
+    ``moe_intermediate_size`` (sigmoid scores, the choice by score +
+    ``e_score_correction_bias``, the chosen scores renormalised where
+    ``norm_topk_prob``, times ``routed_scaling_factor``) beside
+    ``n_shared_experts`` shared ones, ungated and unscaled; no token
+    dropped, no auxiliary loss; an untied head. A SHARE holds
+    ``n_routed_experts`` of ``num_routed_experts`` (:func:`_expert_share`).
+    ``num_nextn_predict_layers`` (the multi-token-prediction modules
+    behind the last block) is carried through and nothing of it is built:
+    their weights are skipped on load by name. ``expert_bias_init_std``
+    (this repo's key) is what a fresh choice bias is drawn at."""
+    for key, run, why in GLM4_MOE_LITE_REFUSALS:
+        if getattr(hf_config, key, None) not in run:
+            raise NotImplementedError(why)
+    if getattr(hf_config, "q_lora_rank", None) is None:
+        from areal_tpu.models.mla import Q_LATENT_REFUSAL
+
+        raise NotImplementedError(Q_LATENT_REFUSAL)
+    mla = MLAConfig(
+        q_lora_rank=int(hf_config.q_lora_rank),
+        kv_lora_rank=int(hf_config.kv_lora_rank),
+        qk_nope_head_dim=int(hf_config.qk_nope_head_dim),
+        qk_rope_head_dim=int(hf_config.qk_rope_head_dim),
+        v_head_dim=int(hf_config.v_head_dim))
+    if mla.v_head_dim != mla.qk_head_dim:
+        from areal_tpu.models.mla import VALUE_WIDTH_REFUSAL
+
+        raise NotImplementedError(VALUE_WIDTH_REFUSAL)
+    kw = _base_kwargs(hf_config)
+    heads = hf_config.num_attention_heads
+    if kw["n_kv_heads"] != heads:
+        raise NotImplementedError(
+            "num_key_value_heads != num_attention_heads under latent "
+            "attention (the up-projection makes a key a query head)")
+    kw["head_dim"] = mla.qk_head_dim
+    n = kw["n_layers"]
+    dense = min(int(getattr(hf_config, "first_k_dense_replace", 0) or 0), n)
+    held = hf_config.n_routed_experts
+    routed, first = _expert_share(hf_config, held)
+    shared = (getattr(hf_config, "n_shared_experts", 0) or 0
+              ) * hf_config.moe_intermediate_size
+    return TransformerConfig(
+        **kw,
+        mla=mla,
+        mlp_layer_types=tuple(
+            DENSE_FFN if i < dense else SPARSE_FFN for i in range(n))
+        if dense else None,
+        max_position_embeddings=getattr(
+            hf_config, "max_position_embeddings", None),
+        moe=MoEConfig(
+            num_experts=held,
+            top_k=hf_config.num_experts_per_tok,
+            capacity_factor=None,
+            routed_intermediate_dim=hf_config.moe_intermediate_size,
+            shared_intermediate_dim=shared or None,
+            aux_loss_coeff=0.0,
+            norm_topk_prob=bool(getattr(hf_config, "norm_topk_prob", True)),
+            router_experts=routed,
+            first_expert=first,
+            router_score="sigmoid",
+            routed_scaling_factor=float(
+                getattr(hf_config, "routed_scaling_factor", 1.0)),
+            router_bias_init_std=float(
+                getattr(hf_config, "expert_bias_init_std", 0.02)),
+        ),
+        n_nextn_predict_layers=int(
+            getattr(hf_config, "num_nextn_predict_layers", 0) or 0),
+        hf_family="glm4_moe_lite",
+    )
+
+
 def config_from_hf(hf_config: Any) -> TransformerConfig:
     """Build a TransformerConfig from a transformers PretrainedConfig."""
     mt = getattr(hf_config, "model_type", "llama")
@@ -1089,8 +1186,13 @@ _AFMOE_EXPERTS = {"e_gate": "mlp.experts.{e}.gate_proj.weight",
 
 
 def _afmoe_to_sd(
-    params: Dict[str, Any], cfg: TransformerConfig
+    params: Dict[str, Any], cfg: TransformerConfig, names=None,
 ) -> Dict[str, np.ndarray]:
+    """``names``: another family's table of the same form whose experts'
+    and top-level names are afmoe's (glm4_moe_lite: the blocks that are
+    built, no ``model.layers.<n_layers>.*`` of a multi-token-prediction
+    module)."""
+    names = names or _AFMOE_NAMES
     sd = {
         "model.embed_tokens.weight": np.asarray(params["embedding"]),
         "model.norm.weight": np.asarray(params["final_ln"]),
@@ -1098,7 +1200,7 @@ def _afmoe_to_sd(
     }
     for i, _, lp in _layers_in_order(params, cfg):
         pre = f"model.layers.{i}."
-        for key, name, tr in _AFMOE_NAMES:
+        for key, name, tr in names:
             if key in lp:
                 sd[pre + name] = lp[key].T if tr else lp[key]
         for key, name in _AFMOE_EXPERTS.items():
@@ -1109,13 +1211,18 @@ def _afmoe_to_sd(
 
 
 def _afmoe_from_sd(
-    sd: Dict[str, Any], cfg: TransformerConfig, dtype: str
+    sd: Dict[str, Any], cfg: TransformerConfig, dtype: str, names=None,
 ) -> Dict[str, Any]:
+    """Reads blocks 0 .. n_layers - 1 by ``names`` (as
+    :func:`_afmoe_to_sd`): ``model.layers.<n>.*`` for n from ``n_layers``
+    on (glm4_moe_lite's multi-token-prediction modules) are skipped by
+    name, as ``transformers`` skips them."""
+    names = names or _AFMOE_NAMES
     per_kind: Dict[str, Dict[str, list]] = {}
     for i, kind in enumerate(cfg.layer_kinds):
         pre = f"model.layers.{i}."
         lp = per_kind.setdefault(kind if cfg.is_hybrid else "", {})
-        for key, name, tr in _AFMOE_NAMES:
+        for key, name, tr in names:
             if pre + name in sd:
                 w = _np(sd[pre + name])
                 lp.setdefault(key, []).append(w.T if tr else w)
@@ -1533,6 +1640,32 @@ def _lfm2_moe_from_sd(
     return params
 
 
+# glm4_moe_lite: (pytree key, HF name under ``model.layers.{i}.``,
+# transpose). ``kv_b_proj``'s rows are by head, ``[k_nope | v]`` a head —
+# the layout ``wkv_b``'s columns have (models/mla.py), as ``q_b_proj``'s
+# ``[q_nope | q_rope]`` a head is ``wq_b``'s. The experts' names and the
+# codec are afmoe's (``_AFMOE_EXPERTS``, ``_afmoe_to_sd`` / ``_from_sd``).
+_GLM4_MOE_LITE_NAMES = [
+    ("ln1", "input_layernorm.weight", False),
+    ("ln2", "post_attention_layernorm.weight", False),
+    ("wq_a", "self_attn.q_a_proj.weight", True),
+    ("q_a_norm", "self_attn.q_a_layernorm.weight", False),
+    ("wq_b", "self_attn.q_b_proj.weight", True),
+    ("wkv_a", "self_attn.kv_a_proj_with_mqa.weight", True),
+    ("kv_a_norm", "self_attn.kv_a_layernorm.weight", False),
+    ("wkv_b", "self_attn.kv_b_proj.weight", True),
+    ("wo", "self_attn.o_proj.weight", True),
+    ("w_gate", "mlp.gate_proj.weight", True),
+    ("w_up", "mlp.up_proj.weight", True),
+    ("w_down", "mlp.down_proj.weight", True),
+    ("router", "mlp.gate.weight", True),
+    ("router_bias", "mlp.gate.e_score_correction_bias", False),
+    ("s_gate", "mlp.shared_experts.gate_proj.weight", True),
+    ("s_up", "mlp.shared_experts.up_proj.weight", True),
+    ("s_down", "mlp.shared_experts.down_proj.weight", True),
+]
+
+
 def params_from_hf_state_dict(
     sd: Dict[str, Any], cfg: TransformerConfig, dtype: str = "float32"
 ) -> Dict[str, Any]:
@@ -1551,6 +1684,8 @@ def params_from_hf_state_dict(
         return _qwen3_next_from_sd(sd, cfg, dtype)
     if cfg.hf_family == "lfm2_moe":
         return _lfm2_moe_from_sd(sd, cfg, dtype)
+    if cfg.hf_family == "glm4_moe_lite":
+        return _afmoe_from_sd(sd, cfg, dtype, _GLM4_MOE_LITE_NAMES)
     return _llama_from_sd(sd, cfg, dtype)
 
 
@@ -1572,6 +1707,8 @@ def params_to_hf_state_dict(
         return _qwen3_next_to_sd(params, cfg)
     if cfg.hf_family == "lfm2_moe":
         return _lfm2_moe_to_sd(params, cfg)
+    if cfg.hf_family == "glm4_moe_lite":
+        return _afmoe_to_sd(params, cfg, _GLM4_MOE_LITE_NAMES)
     return _llama_to_sd(params, cfg)
 
 
@@ -1594,6 +1731,7 @@ _HF_ARCH = {
     "granitemoehybrid": "GraniteMoeHybridForCausalLM",
     "qwen3_next": "Qwen3NextForCausalLM",
     "lfm2_moe": "Lfm2MoeForCausalLM",
+    "glm4_moe_lite": "Glm4MoeLiteForCausalLM",
 }
 
 
@@ -1627,6 +1765,8 @@ def hf_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
         return _qwen3_next_config_dict(cfg)
     if fam == "lfm2_moe":
         return _lfm2_moe_config_dict(cfg)
+    if fam == "glm4_moe_lite":
+        return _glm4_moe_lite_config_dict(cfg)
     d: Dict[str, Any] = {
         "model_type": fam,
         "architectures": [_HF_ARCH.get(fam, "LlamaForCausalLM")],
@@ -1900,6 +2040,53 @@ def _lfm2_moe_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
         "use_expert_bias": True,
         "norm_topk_prob": moe.norm_topk_prob,
         "routed_scaling_factor": moe.routed_scaling_factor,
+        "torch_dtype": "float32",
+    }
+    if moe.is_share:
+        d["num_routed_experts"] = moe.n_routed
+        d["expert_shard_count"] = moe.n_routed // moe.num_experts
+        d["expert_shard_index"] = moe.first_expert // moe.num_experts
+    if moe.router_bias_init_std != 0.02:
+        d["expert_bias_init_std"] = moe.router_bias_init_std
+    return d
+
+
+def _glm4_moe_lite_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
+    """The inverse of :func:`_glm4_moe_lite_config`."""
+    moe, mla = cfg.moe, cfg.mla
+    d = {
+        "model_type": "glm4_moe_lite",
+        "architectures": [_HF_ARCH["glm4_moe_lite"]],
+        "num_hidden_layers": cfg.n_layers,
+        "first_k_dense_replace": (cfg.mlp_layer_types or ()).count(DENSE_FFN),
+        "hidden_size": cfg.hidden_dim,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "q_lora_rank": mla.q_lora_rank,
+        "kv_lora_rank": mla.kv_lora_rank,
+        "qk_nope_head_dim": mla.qk_nope_head_dim,
+        "qk_rope_head_dim": mla.qk_rope_head_dim,
+        "v_head_dim": mla.v_head_dim,
+        "intermediate_size": cfg.intermediate_dim,
+        "moe_intermediate_size": moe.routed_intermediate_dim,
+        "vocab_size": cfg.vocab_size,
+        "hidden_act": "silu",
+        "attention_bias": False,
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "rope_theta": cfg.rotary_base,
+        "rope_scaling": None,
+        "partial_rotary_factor": 1,
+        "tie_word_embeddings": cfg.tie_word_embeddings,
+        "max_position_embeddings": cfg.max_position_embeddings or 202752,
+        "n_routed_experts": moe.num_experts,
+        "n_shared_experts": (moe.shared_intermediate_dim or 0)
+        // moe.routed_intermediate_dim,
+        "num_experts_per_tok": moe.top_k,
+        "topk_method": "noaux_tc",
+        "n_group": 1, "topk_group": 1,
+        "norm_topk_prob": moe.norm_topk_prob,
+        "routed_scaling_factor": moe.routed_scaling_factor,
+        "num_nextn_predict_layers": cfg.n_nextn_predict_layers,
         "torch_dtype": "float32",
     }
     if moe.is_share:

@@ -168,6 +168,13 @@ def _init_block_layers(cfg: TransformerConfig, n: int, keys, dtype,
     elif kind == GMU:
         layers["gmu_in"] = nrm(keys[13], (n, d, cfg.s6.d_inner))
         layers["gmu_out"] = nrm(keys[14], (n, cfg.s6.d_inner, d))
+    elif cfg.mla is not None:
+        from areal_tpu.models import mla as mlamod
+
+        mlamod.check(cfg.mla, cfg.head_dim)
+        layers.update(mlamod.init_mla_params(
+            cfg.mla, n, d, cfg.n_q_heads, keys[13], dtype))
+        layers["wo"] = nrm(keys[3], (n, qd, d))
     else:
         layers["wq"] = nrm(keys[0], (n, d, qd))
         layers["wo"] = nrm(keys[3], (n, qd, d))
@@ -445,7 +452,6 @@ def _block(
         cos, sin = cos[akind], sin[akind]
     if isinstance(kv_valid, dict):
         kv_valid = kv_valid[akind]
-    dh = cfg.head_dim
 
     # The jax.named_scope names below are the device-side names of a
     # profiler capture (base/telemetry.DEVICE_SCOPES): metadata on the
@@ -480,6 +486,66 @@ def _block(
                                                "hidden"), lp,
                           new_kv, segment_ids, rng, allow_ep, ring_ctx,
                           attn_impl, decode=False)
+    if cfg.mla is not None:
+        # latent attention: q, k, v through their latents, assembled and
+        # turned under scopes of their own (models/mla.py)
+        from areal_tpu.models import mla as mlamod
+
+        assert cache_kv is None, mlamod.DECODE_REFUSAL
+        assert not (cfg.gated_attention or cfg.differential_attention
+                    or cfg.use_qk_norm or akind == CROSS)
+        rope = cfg.pos_embedding == "rope" and cos is not None
+        q, k, v = mlamod.mla_qkv(
+            x, lp, cfg.mla, cfg.n_q_heads, cfg.rms_norm_eps,
+            cos if rope else None, sin)
+    else:
+        q, k, v, gate = _qkv(cfg, akind, x, lp, cos, sin, shared, cache_kv)
+
+    with jax.named_scope("cross_attention" if akind == CROSS
+                         else "attention"):
+        attn, new_kv = _attend(
+            cfg, q, k, v, segment_ids, positions, cache_kv,
+            cache_write_index, kv_valid, attn_impl, allow_ring, ring_ctx,
+            kind,
+        )
+    if cfg.differential_attention:
+        with jax.named_scope("diff_attn_combine"):
+            f32 = jnp.float32
+            lam = (jnp.exp(jnp.sum(lp["lambda_q1"].astype(f32)
+                                   * lp["lambda_k1"].astype(f32)))
+                   - jnp.exp(jnp.sum(lp["lambda_q2"].astype(f32)
+                                     * lp["lambda_k2"].astype(f32))))
+            layer = lp.get("_layer", layer_index) + cfg.first_layer_index
+            lam_init = 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(layer, f32))
+            attn = differential_combine(
+                attn, cfg.n_kv_heads, lam + lam_init, lam_init, lp["subln"],
+                cfg.rms_norm_eps)
+
+    hid = "hidden" if cache_kv is None else "hidden_decode"
+    with jax.named_scope("cross_attention" if akind == CROSS else "o_proj"):
+        attn = attn.reshape(B, T, cfg.q_dim)
+        if cfg.gated_attention:
+            with jax.named_scope("attn_gate"):
+                attn = attn * jax.nn.sigmoid(gate)
+        attn = attn @ lp["wo"]
+        if "bo" in lp:
+            attn = attn + lp["bo"]
+        if cfg.sandwich_norm:
+            with jax.named_scope("post_attn_norm"):
+                attn = _norm(cfg, attn, lp, "ln1_post")
+        h = constrain(_residual(cfg, h, attn), hid)
+    return _block_ffn(cfg, kind, h, lp, new_kv, segment_ids, rng, allow_ep,
+                      ring_ctx, attn_impl, decode=cache_kv is not None)
+
+
+def _qkv(cfg: TransformerConfig, akind: str, x, lp, cos, sin, shared,
+         cache_kv):
+    """A block's three projections of the normed stream ``x`` (a cross
+    layer's K/V are another layer's), the q/k norm, the attention gate's
+    logits and RoPE: (q, k, v, gate), heads split."""
+    B, T, _ = x.shape
+    dh = cfg.head_dim
+    gate = None
     with jax.named_scope("cross_attention" if akind == CROSS else "qkv_proj"):
         q = x @ lp["wq"]
         if akind == CROSS:  # K and V are another layer's, as it made them
@@ -519,42 +585,7 @@ def _block(
         with jax.named_scope("rope"):
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
-
-    with jax.named_scope("cross_attention" if akind == CROSS
-                         else "attention"):
-        attn, new_kv = _attend(
-            cfg, q, k, v, segment_ids, positions, cache_kv,
-            cache_write_index, kv_valid, attn_impl, allow_ring, ring_ctx,
-            kind,
-        )
-    if cfg.differential_attention:
-        with jax.named_scope("diff_attn_combine"):
-            f32 = jnp.float32
-            lam = (jnp.exp(jnp.sum(lp["lambda_q1"].astype(f32)
-                                   * lp["lambda_k1"].astype(f32)))
-                   - jnp.exp(jnp.sum(lp["lambda_q2"].astype(f32)
-                                     * lp["lambda_k2"].astype(f32))))
-            layer = lp.get("_layer", layer_index) + cfg.first_layer_index
-            lam_init = 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(layer, f32))
-            attn = differential_combine(
-                attn, cfg.n_kv_heads, lam + lam_init, lam_init, lp["subln"],
-                cfg.rms_norm_eps)
-
-    hid = "hidden" if cache_kv is None else "hidden_decode"
-    with jax.named_scope("cross_attention" if akind == CROSS else "o_proj"):
-        attn = attn.reshape(B, T, cfg.q_dim)
-        if cfg.gated_attention:
-            with jax.named_scope("attn_gate"):
-                attn = attn * jax.nn.sigmoid(gate)
-        attn = attn @ lp["wo"]
-        if "bo" in lp:
-            attn = attn + lp["bo"]
-        if cfg.sandwich_norm:
-            with jax.named_scope("post_attn_norm"):
-                attn = _norm(cfg, attn, lp, "ln1_post")
-        h = constrain(_residual(cfg, h, attn), hid)
-    return _block_ffn(cfg, kind, h, lp, new_kv, segment_ids, rng, allow_ep,
-                      ring_ctx, attn_impl, decode=cache_kv is not None)
+    return q, k, v, gate
 
 
 def _block_ffn(cfg: TransformerConfig, kind: str, h, lp, new_kv,
@@ -615,7 +646,12 @@ DECODE_REFUSAL = (
 def decode_refusal(cfg: TransformerConfig) -> Optional[str]:
     """Why this model has no decode mode, by name, or None: the Gated
     DeltaNet or short-convolution blocks' own reason where it has them,
+    latent attention's likewise (its cache is the latent's, not K/V's),
     ``DECODE_REFUSAL`` for any other layer no K/V cache can decode."""
+    if cfg.mla is not None:
+        from areal_tpu.models.mla import DECODE_REFUSAL as mla_refusal
+
+        return mla_refusal
     if GDN in cfg.layer_kinds:
         from areal_tpu.models.gdn import DECODE_REFUSAL as gdn_refusal
 
@@ -1018,7 +1054,7 @@ def _maybe_checkpoint(body, remat):
 
 
 def _block_matmul_widths(cfg: TransformerConfig, dense_ffn: bool,
-                         kind: str = FULL) -> int:
+                         kind: str = FULL, mixer_only: bool = False) -> int:
     """Widths of a whole block's matmul outputs that its backward reads:
     q/k/v (and the attention gate), o_proj, and the MLP's matmuls into
     the hidden width (gate and up, or up) — nothing in the backward reads
@@ -1026,8 +1062,10 @@ def _block_matmul_widths(cfg: TransformerConfig, dense_ffn: bool,
     keeps the router's logits and its shared expert's pair. By ``kind``
     the mixer's are an S6 mixer's (in-projection, [δ | B | C], Δ), a
     Mamba-2 mixer's two projections, a Gated DeltaNet mixer's three, a
-    short convolution's two, a gated memory unit's one, or cross
-    attention's q and o."""
+    short convolution's two, a gated memory unit's one, cross
+    attention's q and o, or latent attention's five (the two latents and
+    their expansions, never the assembled q and k: models/mla.py).
+    ``mixer_only``: the mixer's (the attention branch's) alone."""
     kind = attention_kind(kind)
     if kind == S6:
         widths = 3 * cfg.s6.d_inner + cfg.s6.x_proj_dim + cfg.hidden_dim
@@ -1041,10 +1079,16 @@ def _block_matmul_widths(cfg: TransformerConfig, dense_ffn: bool,
         widths = cfg.s6.d_inner + cfg.hidden_dim
     elif kind == CROSS:
         widths = cfg.q_dim + cfg.hidden_dim
+    elif cfg.mla is not None:  # both latents, both expansions, o_proj
+        from areal_tpu.models.mla import matmul_widths
+
+        widths = matmul_widths(cfg.mla, cfg.n_q_heads) + cfg.hidden_dim
     else:
         widths = cfg.q_dim + 2 * cfg.kv_dim + cfg.hidden_dim
     if cfg.gated_attention and kind not in ATTENTION_FREE_KINDS:
         widths += cfg.q_dim
+    if mixer_only:
+        return widths
     if cfg.sandwich_norm:  # the post-norm reads the FFN's last matmul
         widths += cfg.hidden_dim
     if cfg.moe is None or dense_ffn:
@@ -1114,6 +1158,28 @@ def remat_kept_bytes(
     kept = {"full": cfg.n_layers * full}
     kept["attention"] = kept["full"] + attention
     kept["matmuls"] = kept["attention"] + cfg.n_layers * matmuls
+    return kept
+
+
+def attention_kept_bytes_per_token(
+    cfg: TransformerConfig, entry, itemsize: int, kernel: bool,
+) -> int:
+    """Of :func:`remat_kept_bytes`, what ONE full-attention block's
+    attention branch keeps a token under ``entry`` (False: no remat, not
+    reckoned, 0): nothing under "full"; the kernel's output and statistic
+    under "attention" (where the kernel runs: ``kernel``); under "matmuls"
+    the branch's matmul outputs too — for latent attention both latents
+    and both expansions, never the assembled q and k. The gauge
+    ``train/mla_kept_bytes_per_token``."""
+    from areal_tpu.ops.pallas.window_attention import LANE
+
+    if entry in (False, "full"):
+        return 0
+    lanes = -(-cfg.head_dim // LANE) * LANE
+    kept = cfg.n_q_heads * (lanes * itemsize + 4) if kernel else 0
+    if entry == "matmuls":
+        kept += itemsize * _block_matmul_widths(cfg, False, FULL,
+                                                mixer_only=True)
     return kept
 
 
@@ -1374,6 +1440,10 @@ def _block_param_count(cfg: TransformerConfig, dense_ffn: bool,
         attn = 2 * d * cfg.s6.d_inner
     elif kind == CROSS:
         attn = 2 * d * cfg.q_dim
+    elif cfg.mla is not None:
+        from areal_tpu.models.mla import mla_param_count
+
+        attn = mla_param_count(cfg.mla, d, cfg.n_q_heads)
     else:
         attn = d * (cfg.q_dim + 2 * cfg.kv_dim) + cfg.q_dim * d
     attends = kind not in ATTENTION_FREE_KINDS

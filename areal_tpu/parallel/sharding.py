@@ -116,6 +116,17 @@ def _block_partition_specs(cfg: TransformerConfig, zero, lead,
     elif kind == GMU:
         layers["gmu_in"] = P(lead, zero, None)
         layers["gmu_out"] = P(lead, None, zero)
+    elif cfg.mla is not None:
+        # latent attention (models/mla.py): the down-projections ZeRO-3 on
+        # the hidden dim, the latents whole; the up-projections' columns
+        # and o_proj's rows are by head, so "tp" splits heads as it does
+        # for wq / wo; the latent norms replicated
+        layers.update({
+            "wq_a": P(lead, zero, None), "q_a_norm": P(lead, None),
+            "wq_b": P(lead, zero, "tp"),
+            "wkv_a": P(lead, zero, None), "kv_a_norm": P(lead, None),
+            "wkv_b": P(lead, zero, "tp"), "wo": P(lead, "tp", zero),
+        })
     else:
         layers["wq"] = P(lead, zero, "tp")
         layers["wo"] = P(lead, "tp", zero)

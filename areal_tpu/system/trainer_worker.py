@@ -330,7 +330,7 @@ class TrainerWorker:
         run is judged by (base/monitor.log_device_report)."""
         from areal_tpu.base import monitor
         from areal_tpu.ops import attention, native
-        from areal_tpu.models import gdn, moe, shortconv, ssm
+        from areal_tpu.models import gdn, mla, moe, shortconv, ssm
         from areal_tpu.ops.pallas import window_attention
 
         monitor.log_device_report(
@@ -371,6 +371,10 @@ class TrainerWorker:
             shortconv_geometry={
                 "%dx%d/c%d/k%d" % geom: n
                 for geom, n in shortconv.geometry_counts().items()},
+            # {"rows x length/heads/q latent, kv latent/nope+rope/v":
+            # assemblies traced}: latent attention (models/mla.py)
+            mla_geometry={"%dx%d/h%d/q%dkv%d/%d+%d/v%d" % geom: n
+                          for geom, n in mla.geometry_counts().items()},
             # {"pallas" | "pallas_interpret" | "xla": scans traced}: what
             # runs them (the kernel of ops/pallas/ssd_scan.py, or einsums)
             ssm_scan_impl=ssm.scan_impl_counts(),

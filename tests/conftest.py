@@ -32,6 +32,20 @@ def _seed_everything():
     yield
 
 
+@pytest.fixture(autouse=True)
+def _no_label_left_by_another_test():
+    """``compile_watch.label`` is a thread-local that lives until the next
+    store: an engine that dispatched ``infer_forward`` in one test leaves
+    its grid's label for whatever test the worker runs next, and
+    ``test_compile_watch``'s fed-in ``infer_forward`` compile then reads
+    it (seen once under six workers, PR 58: which tests share a worker
+    moves with every file added). A test starts with none."""
+    from areal_tpu.base import compile_watch
+
+    vars(compile_watch._LABEL).pop("value", None)
+    yield
+
+
 @pytest.fixture(scope="module", autouse=True)
 def _forget_a_parity_files_programs(request):
     """Every executable XLA's CPU compiler has made keeps a few memory

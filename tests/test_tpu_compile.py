@@ -112,6 +112,10 @@ GDN_RULE_T = (16384, 14336, 8704)
 # published widths. A child process and a fixture of its own
 # (``compiled_lfm2``), so that no other case waits for it.
 LFM2_GRID = (2, 7168)
+# The GLM-4.7-Flash cell's cut (configs/glm-4.7-flash.json) likewise: its
+# largest grid is one row (traffic/train-swe-agent-16k.json); child and
+# fixture ``compiled_glm``.
+GLM_GRID = (1, 14336)
 SSD_SCANS = {"ssd-scan-granite": (1, 7040, 32, 64, 1, 128, 256),
              "ssd-scan-nemotron": (1, 4096, 16, 64, 1, 128, 128)}
 
@@ -676,6 +680,74 @@ def _compile_lfm2():
         "param_bytes": 18 * transformer.param_count(lfm2)}
 
 
+def _compile_glm():
+    """Child process: the GLM-4.7-Flash cell's cut, the whole model's
+    forward + backward on its largest grid under full remat, against a
+    described v5e:2x2."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs in /tmp
+    sys.path.insert(0, REPO)
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from areal_tpu.models import mla, transformer
+    from benchmark import weights
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this image
+        return {"skip": f"cannot describe a v5e:2x2 topology here: {e}"}
+    jax.config.update("jax_enable_compilation_cache", False)
+    chip = SingleDeviceSharding(topo.devices[0])
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "glm-4.7-flash.json")) as f:
+        glm = weights.model_config(json.load(f))
+    shapes = jax.eval_shape(
+        lambda: transformer.init_params(glm, jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, jnp.bfloat16, sharding=chip), shapes)
+    tok = jax.ShapeDtypeStruct(GLM_GRID, jnp.int32, sharding=chip)
+
+    def glm_grad(p, tokens, pos, seg):
+        def loss(p):
+            y, _ = transformer.forward(
+                p, glm, tokens, pos, segment_ids=seg, attn_impl="pallas",
+                remat="full", return_kv=False, return_hidden=True)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        return jax.value_and_grad(loss)(p)
+
+    got = jax.jit(glm_grad).lower(params, tok, tok, tok).compile()
+    return {
+        "custom_calls": got.as_text().count("tpu_custom_call"),
+        "temp_bytes": got.memory_analysis().temp_size_in_bytes,
+        "assemblies_traced": {"%dx%d/h%d/q%dkv%d/%d+%d/v%d" % g: n
+                              for g, n in mla.geometry_counts().items()},
+        "param_bytes": 18 * transformer.param_count(glm)}
+
+
+@pytest.fixture(scope="module")
+def compiled_glm(shared_run_dir, libtpu_lock):
+    """:func:`_compile_glm`'s result, from one child process a test run
+    (as ``compiled``)."""
+    path = shared_run_dir / "tpu_compile_glm.json"
+    with libtpu_lock():
+        if not path.exists():
+            r = subprocess.run(
+                [sys.executable, os.path.abspath(__file__), "glm"],
+                env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                capture_output=True, text=True, timeout=600,
+            )
+            assert r.returncode == 0, r.stderr[-3000:]
+            path.write_text(r.stdout.splitlines()[-1])
+        results = json.loads(path.read_text())
+    if "skip" in results:
+        pytest.skip(results["skip"])
+    return results
+
+
 @pytest.fixture(scope="module")
 def compiled_lfm2(shared_run_dir, libtpu_lock):
     """:func:`_compile_lfm2`'s result, from one child process a test run
@@ -851,6 +923,7 @@ def test_programs_that_hold_no_grouped_gemm_kernel(compiled, name):
 
 if __name__ == "__main__":
     print(json.dumps(_compile_lfm2() if sys.argv[1:] == ["lfm2"]
+                     else _compile_glm() if sys.argv[1:] == ["glm"]
                      else _compile_all()))
 
 
@@ -1012,3 +1085,25 @@ def test_the_lfm2_cut_compiles_inside_the_memory_it_leaves(compiled_lfm2):
     assert got["temp_bytes"] < 3.1e9
     gradient = got["param_bytes"] // 9  # 2 B a parameter
     assert got["param_bytes"] + gradient + got["temp_bytes"] < 12.5e9
+
+
+def test_the_glm_cut_compiles_inside_the_memory_it_leaves(compiled_glm):
+    """The grad program of the cut (a dense block and four expert blocks,
+    latent attention in each, 8 of 64 experts held: 591.7 M parameters) on
+    1 x 14,336, the largest grid the packer makes of the cell's traffic,
+    at the published widths, beside the state: 18 B a parameter, the bf16
+    gradient the program returns, its temporaries — the number the
+    micro-batch was chosen by (configs/glm-4.7-flash.json,
+    ``deployment``)."""
+    got = compiled_glm
+    # one assembly a run of blocks (the dense block's, the expert blocks'
+    # scanned); the checkpoint's re-run forward is the same trace
+    assert got["assemblies_traced"] == {
+        "1x14336/h20/q768kv512/192+64/v256": 2}
+    # attention a run: forward twice, dKV, dQ; the experts' grouped GEMMs
+    assert got["custom_calls"] >= 8
+    # 3.33 GB
+    assert got["temp_bytes"] < 3.5e9
+    gradient = got["param_bytes"] // 9  # 2 B a parameter
+    assert got["param_bytes"] == 10_650_387_456
+    assert got["param_bytes"] + gradient + got["temp_bytes"] < 15.5e9
