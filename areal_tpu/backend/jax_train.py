@@ -1083,9 +1083,37 @@ class JaxTrainEngine(TrainableEngine):
         code."""
         key = (loss_fn, with_carry, R, remat)
         if key not in self._grad_fns:
+            micro = self._get_micro_grad_fn(loss_fn, R, remat)
 
             def train_grad_sliced(params, grids, seq, mb_idx, denom, scale,
                                   aux_scale, carry=None):
+                (loss, stats), grads = micro(params, grids, seq, mb_idx,
+                                             denom, aux_scale)
+                return _accumulate(loss, stats, grads, scale, carry)
+
+            donate = (7,) if with_carry else ()
+            self._grad_fns[key] = compile_watch.watched_jit(
+                "train/grad_sliced",
+                jax.jit(train_grad_sliced, donate_argnums=donate),
+            )
+        return self._grad_fns[key]
+
+    def _get_micro_grad_fn(self, loss_fn: LossFn, R: int, remat) -> Callable:
+        """((loss, stats), grads) of micro-batch ``mb_idx`` of an uploaded
+        batch, jitted by itself: what the two grad programs of a packed
+        grid share. jax keeps a jitted function's trace by its arguments'
+        shapes, so the program traced second finds the whole model's
+        forward and backward — most of a warm start — traced already and
+        traces its tail alone. ``inline``: the kept trace is copied into
+        the program's own, which lowers to the text a single trace gave
+        (tests/test_mellum_parity.py holds it to the commit before), so
+        the programs that run, and their entries in the persistent cache,
+        are the ones each variant had when it traced the model itself."""
+        key = ("micro", loss_fn, R, remat)
+        if key not in self._grad_fns:
+
+            def train_grad_micro(params, grids, seq, mb_idx, denom,
+                                 aux_scale):
                 batch = {
                     k: jax.lax.dynamic_slice_in_dim(g, mb_idx * R, R, 0)
                     for k, g in grids.items()
@@ -1094,15 +1122,10 @@ class JaxTrainEngine(TrainableEngine):
                     batch[k] = jax.lax.dynamic_index_in_dim(
                         v, mb_idx, 0, keepdims=False
                     )
-                (loss, stats), grads = self._loss_and_grads(
+                return self._loss_and_grads(
                     loss_fn, remat, params, batch, denom, aux_scale)
-                return _accumulate(loss, stats, grads, scale, carry)
 
-            donate = (7,) if with_carry else ()
-            self._grad_fns[key] = compile_watch.watched_jit(
-                "train/grad_sliced",
-                jax.jit(train_grad_sliced, donate_argnums=donate),
-            )
+            self._grad_fns[key] = jax.jit(train_grad_micro, inline=True)
         return self._grad_fns[key]
 
     def train_uniform(

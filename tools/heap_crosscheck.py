@@ -9,7 +9,9 @@ shapes of every grad program dispatched, and afterwards — outside the
 cell's run — compiles each of them again ahead of time:
 ``jit(train_grad_sliced).lower(<the same shapes>).compile()
 .memory_analysis()``. It prints, per grid, what the ledger filed under
-that grid's label beside the ahead-of-time figures; they must be equal.
+that grid's label beside the ahead-of-time figures; they must be equal
+(``peak_bytes - argument_bytes - output_bytes + alias_bytes`` is the heap
+alive at the program's fullest moment: what resident bytes are added to).
 This is the one place that compiles a program twice: the engine and the
 ledger never do. Chip only (the drivers refuse another platform) but for
 ``--tiny``, the CPU rehearsal; exit 1 where a grid disagrees or the ledger
@@ -92,7 +94,8 @@ def main() -> int:
 
     filed = compile_watch.executables("train_grad_sliced")
     fields = {k: compile_watch.MEMORY_FIELDS[k] for k in (
-        "temp_bytes", "argument_bytes", "output_bytes", "alias_bytes")}
+        "temp_bytes", "argument_bytes", "output_bytes", "alias_bytes",
+        "peak_bytes")}
     lines, bad = [], 0
     for (R, L, with_carry), (engine, loss_fn, shapes) in seen.items():
         remat = engine._remat_for(R, L)
