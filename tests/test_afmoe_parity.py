@@ -12,6 +12,8 @@ Both sides compute in float32 here, so they differ by the order of float32
 sums only: 2e-4 on logits of order 1 (tests/test_mellum_parity.py).
 """
 
+import functools
+import json
 import types
 
 import jax
@@ -46,6 +48,12 @@ HF_KEYS = {
 SHARE_KEYS = {**HF_KEYS, "num_experts": 2, "num_routed_experts": 8,
               "expert_shard_count": 4, "expert_shard_index": 1}
 KEYS = {"whole": HF_KEYS, "share": SHARE_KEYS}
+# five blocks — a dense one, three sliding expert blocks and a full one,
+# every kind of block — for what needs no more depth than that
+CUT_LAYERS = {"num_hidden_layers": 5, "num_dense_layers": 1,
+              "layer_types": [S, S, S, S, FA]}
+CUT = {**HF_KEYS, **CUT_LAYERS}
+CUT_KEYS = {which: {**keys, **CUT_LAYERS} for which, keys in KEYS.items()}
 TOL = dict(atol=2e-4, rtol=2e-4)
 NORMS = ("ln1", "ln2", "ln1_post", "ln2_post", "q_norm", "k_norm",
          "final_ln")
@@ -57,8 +65,14 @@ def model(keys, seed=0, scale=0.3):
     """(config, float32 params): init_params with the matrices scaled up
     (so that every mechanism matters; the embedding less, it is scaled by
     sqrt(hidden) again), the norm weights random around 1 and the router's
-    choice bias random."""
-    cfg = hf.config_from_hf(types.SimpleNamespace(**keys))
+    choice bias random. Built once a set of keys: no test writes into the
+    tree it gets."""
+    return _model(json.dumps(keys, sort_keys=True), seed, scale)
+
+
+@functools.lru_cache(maxsize=None)
+def _model(keys, seed, scale):
+    cfg = hf.config_from_hf(types.SimpleNamespace(**json.loads(keys)))
     flat = hf.flatten_pytree(
         transformer.init_params(cfg, jax.random.PRNGKey(seed)))
     rngs = jax.random.split(jax.random.PRNGKey(seed + 1), len(flat))
@@ -192,12 +206,13 @@ def test_logits_match_the_reference(which):
 
 @pytest.mark.parametrize("which", sorted(KEYS))
 def test_ppo_loss_and_gradients_match_the_reference(which):
-    cfg, params = model(KEYS[which])
+    keys = CUT_KEYS[which]
+    cfg, params = model(keys)
     tok = tokens(1)
-    got_l, got_g = jax.value_and_grad(
-        lambda p: ppo_loss(system_logits(p, cfg, tok, "full"), tok))(params)
-    want_l, want_g = jax.value_and_grad(
-        lambda p: ppo_loss(ref.logits(p, KEYS[which], tok), tok))(params)
+    got_l, got_g = jax.jit(jax.value_and_grad(
+        lambda p: ppo_loss(system_logits(p, cfg, tok, "full"), tok)))(params)
+    want_l, want_g = jax.jit(jax.value_and_grad(
+        lambda p: ppo_loss(ref.logits(p, keys, tok), tok)))(params)
     assert float(got_l) == pytest.approx(float(want_l), abs=1e-5)
     got_g, want_g = hf.flatten_pytree(got_g), hf.flatten_pytree(want_g)
     assert sorted(got_g) == sorted(want_g)
@@ -305,8 +320,6 @@ PUBLISHED = {**HF_KEYS, "num_hidden_layers": 32, "hidden_size": 32,
              "num_attention_heads": 2, "num_key_value_heads": 1,
              "intermediate_size": 48, "moe_intermediate_size": 16,
              "layer_types": [S, S, S, FA] * 8}
-CUT = {**HF_KEYS, "num_hidden_layers": 5, "num_dense_layers": 1,
-       "layer_types": [S, S, S, S, FA]}
 
 
 @pytest.mark.parametrize("which", ["cut", "published"])
@@ -330,20 +343,23 @@ def test_scan_over_the_per_kind_tree_equals_a_loop_over_layers(which):
     ropes = transformer.rope_tables_by_kind(cfg, pos)
     cos = {k: v[0] for k, v in ropes.items()}
     sin = {k: v[1] for k, v in ropes.items()}
+    # one program a KIND of block, run a layer at a time
+    block = jax.jit(transformer._block, static_argnums=(0, 10),
+                    static_argnames="kind")
     h, loads, seen = h0, [], {}
     for kind in cfg.layer_kinds:
         j = seen.get(kind, 0)
         seen[kind] = j + 1
         lp = jax.tree.map(lambda a: a[j], params["layers"][kind])
-        h, _, a = transformer._block(
+        h, _, a = block(
             cfg, h, lp, cos, sin, seg, pos, None, None, None, "reference",
             kind=kind)
         if a is not None:
             loads.append(a["expert_load"])
     for remat in (False, "full"):
-        got, aux = transformer.apply_layer_stack(
-            cfg, h0, params["layers"], cos, sin, seg, pos,
-            attn_impl="reference", remat=remat)
+        got, aux = jax.jit(functools.partial(
+            transformer.apply_layer_stack, cfg, attn_impl="reference",
+            remat=remat))(h0, params["layers"], cos, sin, seg, pos)
         np.testing.assert_allclose(got, h, atol=2e-3, rtol=1e-4)
         # the expert blocks' outputs come back stacked in layer order
         np.testing.assert_allclose(aux["expert_load"], jnp.stack(loads),
@@ -374,8 +390,9 @@ def test_decode_through_the_cache_matches_the_packed_forward(which):
     cache — one cache of ``[n_layers, ...]`` whatever a block's FFN, cut
     by kind for the scan: every step's logits against the packed forward
     over the sequence so far, and the last against the reference."""
-    cfg, params = model(KEYS[which])
-    P, N = 13, 6
+    keys = CUT_KEYS[which]
+    cfg, params = model(keys)
+    P, N = 13, 4
     seq = [int(t) for t in np.asarray(tokens(5, P))]
     assert gen.decode_refusal(cfg) is None
     state = gen.prefill_state(params, cfg, jnp.asarray([seq], jnp.int32),
@@ -385,8 +402,9 @@ def test_decode_through_the_cache_matches_the_packed_forward(which):
     kv = {"k": state["kv_k"], "v": state["kv_v"]}
     assert kv["k"].shape[0] == cfg.n_layers
     slots = jnp.arange(P + N + 1)
+    packed = jax.jit(lambda p, tok: system_logits(p, cfg, tok))
     for step in range(N):
-        want = system_logits(params, cfg, jnp.asarray(seq, jnp.int32))[-1]
+        want = packed(params, jnp.asarray(seq, jnp.int32))[-1]
         np.testing.assert_allclose(logits, want, **TOL)
         seq.append(int(jnp.argmax(want)))
         n = len(seq) - 1  # slot of the token being fed
@@ -398,7 +416,7 @@ def test_decode_through_the_cache_matches_the_packed_forward(which):
                 cfg, (slots <= n)[None], (n - slots)[None]))
         logits = out[0, 0]
     np.testing.assert_allclose(
-        logits, ref.logits(params, KEYS[which],
+        logits, ref.logits(params, keys,
                            jnp.asarray(seq, jnp.int32))[-1], **TOL)
 
 

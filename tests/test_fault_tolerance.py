@@ -207,13 +207,16 @@ def test_fanout_evicts_unresponsive_server_within_budget():
 
     async def main():
         acks = []
+        # the hung handler waits for the test's end, not for the clock: the
+        # runner's clean-up waits a handler out
+        test_over = asyncio.Event()
 
         async def ok_update(req):
             acks.append(await req.json())
             return web.json_response({"ok": True})
 
         async def hang(req):
-            await asyncio.sleep(60)
+            await test_over.wait()
 
         live_app = web.Application()
         live_app.router.add_post("/update_weights", ok_update)
@@ -258,6 +261,7 @@ def test_fanout_evicts_unresponsive_server_within_budget():
             for _ in range(4):  # no further routing to the evicted server
                 assert mgr._pick_server() == live_url
         finally:
+            test_over.set()
             await live_runner.cleanup()
             await hung_runner.cleanup()
 

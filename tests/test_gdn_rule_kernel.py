@@ -122,9 +122,9 @@ def test_the_kernel_equals_the_recurrence_in_float32(which, T, G):
     with jax.default_matmul_precision("highest"):
         (_, o), got = chunked("pallas_interpret", *args, jnp.asarray(seg), w)
         (_, o_xla), xla = chunked("xla", *args, jnp.asarray(seg), w)
-        (_, o_seq), want = jax.value_and_grad(
+        (_, o_seq), want = jax.jit(jax.value_and_grad(
             lambda *a: sequential(*a, seg, w), argnums=(0, 1, 2, 3, 4),
-            has_aux=True)(*args)
+            has_aux=True))(*args)
     assert gdn.rule_impl_counts()["pallas_interpret"] >= before
     assert worst(o, o_seq) < 2e-5 and worst(o, o_xla) < 2e-5
     for name, a, x_, s in zip(GRADS, got, xla, want):
@@ -144,9 +144,9 @@ def test_the_kernel_in_bfloat16_is_as_near_as_the_xla_form(which, T, G, low):
     (_, o), got = chunked("pallas_interpret", *args, jnp.asarray(seg), w)
     (_, o_xla), xla = chunked("xla", *args, jnp.asarray(seg), w)
     with jax.default_matmul_precision("highest"):
-        (_, o_seq), want = jax.value_and_grad(
+        (_, o_seq), want = jax.jit(jax.value_and_grad(
             lambda *a: sequential(*a, seg, w), argnums=(0, 1, 2, 3, 4),
-            has_aux=True)(*args)
+            has_aux=True))(*args)
     assert o.dtype == jnp.float32 and got[0].dtype == jnp.bfloat16
     assert worst(o, o_seq) < 2 * worst(o_xla, o_seq) + 2 ** -8
     for name, a, x_, s in zip(GRADS, got, xla, want):
@@ -275,10 +275,10 @@ def test_the_backward_equals_the_recurrences_in_float32(
     with jax.default_matmul_precision("highest"):
         (_, y), got = ends("pallas_interpret", *args, jnp.asarray(seg), wt)
         q, k, v, z, w, g, beta = args
-        (_, y_seq), want = jax.value_and_grad(
+        (_, y_seq), want = jax.jit(jax.value_and_grad(
             lambda q, k, v, g, beta, z, w: sequential_ends(
                 q, k, v, z, w, g, beta, seg, wt),
-            argnums=tuple(range(7)), has_aux=True)(q, k, v, g, beta, z, w)
+            argnums=tuple(range(7)), has_aux=True))(q, k, v, g, beta, z, w)
     jax.clear_caches()
     assert worst(y, y_seq) < 2e-5
     for name, a, s_ in zip(ENDS_GRADS, got, want):
@@ -321,9 +321,9 @@ def test_one_value_head_a_key_head_rides_half_a_pair():
     w = jax.random.normal(jax.random.PRNGKey(9), (1, T, G, D))
     with jax.default_matmul_precision("highest"):
         (_, o), got = chunked("pallas_interpret", *args, jnp.asarray(seg), w)
-        (_, o_seq), want = jax.value_and_grad(
+        (_, o_seq), want = jax.jit(jax.value_and_grad(
             lambda *a: sequential(*a, seg, w), argnums=(0, 1, 2, 3, 4),
-            has_aux=True)(*args)
+            has_aux=True))(*args)
     assert worst(o, o_seq) < 2e-5
     for name, a, s in zip(GRADS, got, want):
         assert worst(a, s) < 1e-4, (name, worst(a, s))
@@ -343,9 +343,9 @@ def test_keys_that_resemble_each_other_do_not_break_the_inverse():
     with jax.default_matmul_precision("highest"):
         (_, o), got = chunked("pallas_interpret", *args, jnp.asarray(seg), w)
         (_, o_xla), _ = chunked("xla", *args, jnp.asarray(seg), w)
-        (_, o_seq), want = jax.value_and_grad(
+        (_, o_seq), want = jax.jit(jax.value_and_grad(
             lambda *a: sequential(*a, seg, w), argnums=(0, 1, 2, 3, 4),
-            has_aux=True)(*args)
+            has_aux=True))(*args)
     assert worst(o, o_seq) < 2e-5
     for name, a, s in zip(GRADS, got, want):
         assert worst(a, s) < 2e-4, (name, worst(a, s))

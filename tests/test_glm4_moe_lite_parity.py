@@ -26,7 +26,6 @@ import pytest
 from areal_tpu.models import hf, mla, moe, transformer
 from areal_tpu.models.config import FULL, MLAConfig
 from benchmark import reference_glm4_moe_lite as ref
-from test_tpu_compile import compiled_glm  # noqa: F401 — that file's fixture
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HF_KEYS = {
@@ -655,14 +654,6 @@ def test_where_the_block_goes_and_where_it_is_refused_by_name(where):
         assert full["wq_b"][2] == full["wkv_b"][2] == full["wo"][1] == "tp"
         assert full["wq_a"][2] is None and full["wkv_a"][2] is None
         assert full["q_a_norm"] == full["kv_a_norm"] == P(None, None)
-
-
-def test_the_cuts_grad_program_compiles_for_the_chip(compiled_glm):
-    """``tests/test_tpu_compile.py``'s case of this cut, asked for HERE
-    too: that file's children compile one behind another at the end of a
-    run (libtpu admits one process), and this one's 50 s are done, and
-    kept for its own test, while nothing waits for the lock."""
-    assert compiled_glm["temp_bytes"] > 0
 
 
 # ---- (g) what the benchmark and the operator read ----

@@ -14,6 +14,8 @@ reset left off move logits by 1e-2 and more.
 """
 
 import dataclasses
+import functools
+import json
 import types
 
 import jax
@@ -42,6 +44,10 @@ HF_KEYS = {
     "rms_norm_eps": 1e-5, "attention_bias": False, "hidden_act": "silu",
     "tie_word_embeddings": True, "max_position_embeddings": 4096,
 }
+# the period's first six layers — five Mamba blocks, scanned as one run,
+# and the attention block: every kind of block, for what is a property
+# of the blocks and not of the period
+SIX = {**HF_KEYS, "num_hidden_layers": 6}
 TOL = dict(atol=3e-4, rtol=3e-4)
 NORMS = ("ln1", "ln2", "final_ln", "norm")
 AS_DRAWN = ("conv_w", "dt_bias", "A_log")
@@ -52,8 +58,14 @@ def model(keys=HF_KEYS, seed=0, scale=0.3):
     """(config, float32 params): init_params with the matrices scaled up
     (so that every mixer matters; the embedding stays as drawn: it is
     read times 12), the norm weights random around 1, the convolution's
-    bias and the skip ``D`` random, the decay's parameters as drawn."""
-    cfg = hf.config_from_hf(types.SimpleNamespace(**keys))
+    bias and the skip ``D`` random, the decay's parameters as drawn.
+    Built once a set of keys: no test writes into the tree it gets."""
+    return _model(json.dumps(keys, sort_keys=True), seed, scale)
+
+
+@functools.lru_cache(maxsize=None)
+def _model(keys, seed, scale):
+    cfg = hf.config_from_hf(types.SimpleNamespace(**json.loads(keys)))
     flat = hf.flatten_pytree(
         transformer.init_params(cfg, jax.random.PRNGKey(seed)))
     rngs = jax.random.split(jax.random.PRNGKey(seed + 1), len(flat))
@@ -208,7 +220,7 @@ def test_the_engines_ppo_logprobs_match_the_reference_a_document(chunk):
     that document alone."""
     from areal_tpu.backend.jax_train import JaxTrainEngine
 
-    cfg, params = model()
+    cfg, params = model(SIX)
     T = 64
     rows = [packed_row(lens, T, seed) for lens, seed in (
         ((21, 37), 20), ((11, 14, 10, 19), 30), ((30, 5, 27), 40))]
@@ -220,13 +232,13 @@ def test_the_engines_ppo_logprobs_match_the_reference_a_document(chunk):
     eng = JaxTrainEngine(cfg, params, compute_dtype="float32",
                          logprob_chunk=chunk)
     got, _ = eng._forward_token_logprobs(eng.params, batch)
+    alone = jax.jit(lambda p, doc: ref.token_logprobs(p, SIX, doc))
     for r, (_, seg, docs) in enumerate(rows):
         col = 0
         for doc in docs:
             n = len(doc)
             np.testing.assert_allclose(
-                got[r, col + 1:col + n],
-                ref.token_logprobs(params, HF_KEYS, doc), **TOL)
+                got[r, col + 1:col + n], alone(params, doc), **TOL)
             assert float(got[r, col]) == 0.0  # a document's first token
             col += n
 
@@ -234,11 +246,11 @@ def test_the_engines_ppo_logprobs_match_the_reference_a_document(chunk):
 def test_loss_and_every_gradient_match_the_reference():
     cfg, params = model()
     tok = tokens(1)
-    got_l, got_g = jax.value_and_grad(
+    got_l, got_g = jax.jit(jax.value_and_grad(
         lambda p: mean_logprob(system_logits(p, cfg, tok, remat="full"),
-                               tok))(params)
-    want_l, want_g = jax.value_and_grad(
-        lambda p: -ref.loss(p, HF_KEYS, tok))(params)
+                               tok)))(params)
+    want_l, want_g = jax.jit(jax.value_and_grad(
+        lambda p: -ref.loss(p, HF_KEYS, tok)))(params)
     np.testing.assert_allclose(got_l, want_l, atol=1e-5, rtol=1e-5)
     got, want = hf.flatten_pytree(got_g), hf.flatten_pytree(want_g)
     assert sorted(got) == sorted(want)
@@ -253,21 +265,23 @@ def test_loss_and_every_gradient_match_the_reference():
 def test_the_tied_embeddings_gradient_is_the_sum_of_its_two_uses():
     """``E`` is read times 12 going in and over 8 coming out: its gradient
     is the sum of the gradient through each use alone."""
-    cfg, params = model()
+    cfg, params = model(SIX)
     tok = tokens(2, 29)
     untied = dataclasses.replace(cfg, tie_word_embeddings=False)
 
     def loss(p):
         return mean_logprob(system_logits(p, untied, tok), tok)
 
-    both = jax.grad(lambda p: mean_logprob(system_logits(p, cfg, tok), tok))(
-        params)["embedding"]
-    g = jax.grad(loss)({**params, "lm_head": params["embedding"].T})
+    both = jax.jit(jax.grad(
+        lambda p: mean_logprob(system_logits(p, cfg, tok), tok)))(
+            params)["embedding"]
+    g = jax.jit(jax.grad(loss))({**params, "lm_head": params["embedding"].T})
     assert float(jnp.abs(g["embedding"]).max()) > 1e-6
     assert float(jnp.abs(g["lm_head"]).max()) > 1e-6
     np.testing.assert_allclose(both, g["embedding"] + g["lm_head"].T,
                                rtol=1e-4, atol=1e-7)
-    want = jax.grad(lambda p: -ref.loss(p, HF_KEYS, tok))(params)["embedding"]
+    want = jax.jit(jax.grad(lambda p: -ref.loss(p, SIX, tok)))(
+        params)["embedding"]
     np.testing.assert_allclose(both, want, rtol=2e-3, atol=2e-3 * float(
         jnp.abs(want).max()))
 
@@ -300,14 +314,14 @@ def test_a_wrong_reference_is_told_apart(which, monkeypatch):
 def test_a_document_behind_others_equals_the_document_alone(lens):
     """2 to 4 documents a row, every boundary inside a chunk of 8: the
     scan, the convolution and attention stop at it."""
-    cfg, params = model()
+    cfg, params = model(SIX)
     assert all(sum(lens[:i]) % cfg.ssm.chunk_size for i in range(1, len(lens)))
     row, seg, docs = packed_row(lens, 64)
     packed = system_logits(params, cfg, row, seg)[0]
     col = 0
     for doc in docs:
         np.testing.assert_allclose(packed[col:col + len(doc)],
-                                   ref.logits(params, HF_KEYS, doc), **TOL)
+                                   ref.logits(params, SIX, doc), **TOL)
         col += len(doc)
     # and with the boundaries left off it is another model
     merged = system_logits(params, cfg, row, (seg > 0).astype(jnp.int32))[0]
@@ -330,25 +344,35 @@ def unrolled(cfg, params, h, seg):
     return h
 
 
-@pytest.mark.parametrize("remat", [False, "full", "matmuls"])
-def test_the_scanned_runs_equal_the_unrolled_layers(remat):
+def weigh(out):
+    return jnp.sum(out * jnp.cos(jnp.arange(out.size).reshape(out.shape)))
+
+
+@functools.lru_cache(maxsize=None)
+def two_periods():
+    """Two periods of the pattern, a row of two documents, and the
+    unrolled layers' loss and gradients on it (once for every ``remat``)."""
     cfg, params = model({**HF_KEYS, "num_hidden_layers": 20})
     h0 = params["embedding"][tokens(4, 64)][None]
     seg = jnp.asarray([[1] * 30 + [2] * 34], jnp.int32)
-    pos = jnp.arange(64)[None]
+    want = jax.jit(jax.value_and_grad(
+        lambda p, h: weigh(unrolled(cfg, p, h, seg)), argnums=(0, 1)))(
+            params, h0)
+    return cfg, params, h0, seg, want
 
-    def weigh(out):
-        return jnp.sum(out * jnp.cos(jnp.arange(out.size).reshape(out.shape)))
+
+@pytest.mark.parametrize("remat", [False, "full", "matmuls"])
+def test_the_scanned_runs_equal_the_unrolled_layers(remat):
+    cfg, params, h0, seg, (want_l, want_g) = two_periods()
+    pos = jnp.arange(64)[None]
 
     def scanned(p, h):
         return weigh(transformer.apply_layer_stack(
             cfg, h, p["layers"], None, None, seg, pos,
             attn_impl="reference", remat=remat)[0])
 
-    got_l, got_g = jax.value_and_grad(scanned, argnums=(0, 1))(params, h0)
-    want_l, want_g = jax.value_and_grad(
-        lambda p, h: weigh(unrolled(cfg, p, h, seg)), argnums=(0, 1))(
-            params, h0)
+    got_l, got_g = jax.jit(jax.value_and_grad(scanned, argnums=(0, 1)))(
+        params, h0)
     np.testing.assert_allclose(got_l, want_l, rtol=1e-4)
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got_g),
                             jax.tree.leaves(want_g)):

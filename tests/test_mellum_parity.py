@@ -14,6 +14,8 @@ not renormalised move logits by 1e-2 and more on these weights.
 
 import hashlib
 import math
+import functools
+import json
 import types
 
 import jax
@@ -54,8 +56,14 @@ NORMS = ("ln1", "ln2", "final_ln")
 def model(keys, seed=0, scale=0.3):
     """(config, float32 params): init_params with the matrices scaled up
     (so that attention and the experts matter) and the norm weights random
-    around 1."""
-    cfg = hf.config_from_hf(types.SimpleNamespace(**keys))
+    around 1. Built once a set of keys: no test writes into the tree it
+    gets."""
+    return _model(json.dumps(keys, sort_keys=True), seed, scale)
+
+
+@functools.lru_cache(maxsize=None)
+def _model(keys, seed, scale):
+    cfg = hf.config_from_hf(types.SimpleNamespace(**json.loads(keys)))
     flat = hf.flatten_pytree(
         transformer.init_params(cfg, jax.random.PRNGKey(seed)))
     rngs = jax.random.split(jax.random.PRNGKey(seed + 1), len(flat))
@@ -85,6 +93,11 @@ def mean_logprob(logits, tok):
 
 
 KEYS = {"whole": HF_KEYS, "share": SHARE_KEYS}
+# one period of the pattern (three sliding blocks, one full): what the
+# gradients and a decode through the cache need of it
+ONE_PERIOD = {which: {**keys, "num_hidden_layers": 4,
+                      "layer_types": keys["layer_types"][:4]}
+              for which, keys in KEYS.items()}
 
 
 # ---- (a) the program against the reference ----
@@ -118,12 +131,13 @@ def test_logits_match_the_reference(which):
 
 @pytest.mark.parametrize("which", sorted(KEYS))
 def test_loss_and_gradients_match_the_reference(which):
-    cfg, params = model(KEYS[which])
+    keys = ONE_PERIOD[which]
+    cfg, params = model(keys)
     tok = tokens(1)
-    got_l, got_g = jax.value_and_grad(
-        lambda p: mean_logprob(system_logits(p, cfg, tok), tok))(params)
-    want_l, want_g = jax.value_and_grad(
-        lambda p: mean_logprob(ref.logits(p, KEYS[which], tok), tok))(params)
+    got_l, got_g = jax.jit(jax.value_and_grad(
+        lambda p: mean_logprob(system_logits(p, cfg, tok), tok)))(params)
+    want_l, want_g = jax.jit(jax.value_and_grad(
+        lambda p: mean_logprob(ref.logits(p, keys, tok), tok)))(params)
     assert float(got_l) == pytest.approx(float(want_l), abs=1e-5)
     got_g, want_g = hf.flatten_pytree(got_g), hf.flatten_pytree(want_g)
     assert sorted(got_g) == sorted(want_g)
@@ -266,14 +280,17 @@ def test_scan_over_periods_equals_a_loop_over_layers():
     ropes = transformer.rope_tables_by_kind(cfg, pos)
     cos = {k: v[0] for k, v in ropes.items()}
     sin = {k: v[1] for k, v in ropes.items()}
+    # one program a KIND of block, run a layer at a time
+    block = jax.jit(transformer._block, static_argnums=(0, 10),
+                    static_argnames="kind")
     for remat in (False, "full"):
-        got, aux = transformer.apply_layer_stack(
-            cfg, h0, params["layers"], cos, sin, seg, pos,
-            attn_impl="reference", remat=remat)
+        got, aux = jax.jit(functools.partial(
+            transformer.apply_layer_stack, cfg, attn_impl="reference",
+            remat=remat))(h0, params["layers"], cos, sin, seg, pos)
         h, loads = h0, []
         for i, kind in enumerate(cfg.layer_kinds):
             lp = jax.tree.map(lambda a: a[i], params["layers"])
-            h, _, a = transformer._block(
+            h, _, a = block(
                 cfg, h, lp, cos, sin, seg, pos, None, None, None,
                 "reference", kind=kind)
             loads.append(a["expert_load"])
@@ -392,8 +409,9 @@ def test_decode_through_the_cache_matches_the_packed_forward(which):
     reference) over the sequence so far — the cache keeps every slot, a
     sliding layer reads the last 8 of them, each kind turns its own RoPE
     table."""
-    cfg, params = model(KEYS[which])
-    P, N = 13, 6
+    keys = ONE_PERIOD[which]
+    cfg, params = model(keys)
+    P, N = 13, 4
     seq = [int(t) for t in np.asarray(tokens(5, P))]
     state = gen.prefill_state(params, cfg, jnp.asarray([seq], jnp.int32),
                               jnp.asarray([P], jnp.int32), P + N + 1,
@@ -401,8 +419,9 @@ def test_decode_through_the_cache_matches_the_packed_forward(which):
     logits = state["last_logits"][0]
     kv = {"k": state["kv_k"], "v": state["kv_v"]}
     slots = jnp.arange(P + N + 1)
+    packed = jax.jit(lambda p, tok: system_logits(p, cfg, tok))
     for step in range(N):
-        want = system_logits(params, cfg, jnp.asarray(seq, jnp.int32))[-1]
+        want = packed(params, jnp.asarray(seq, jnp.int32))[-1]
         np.testing.assert_allclose(logits, want, **TOL)
         seq.append(int(jnp.argmax(want)))
         n = len(seq) - 1  # slot of the token being fed
@@ -414,7 +433,7 @@ def test_decode_through_the_cache_matches_the_packed_forward(which):
                 cfg, (slots <= n)[None], (n - slots)[None]))
         logits = out[0, 0]
     np.testing.assert_allclose(
-        logits, ref.logits(params, KEYS[which],
+        logits, ref.logits(params, keys,
                            jnp.asarray(seq, jnp.int32))[-1], **TOL)
 
 

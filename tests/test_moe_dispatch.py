@@ -67,10 +67,10 @@ def test_grouped_matches_einsum_fwd_and_grad(E, k, cf):
                     aux_loss_coeff=1e-2, z_loss_coeff=1e-3,
                     shared_intermediate_dim=24)
 
-    (lg, ag), gg = jax.value_and_grad(
-        _loss_fn(moe, x, mask, "grouped"), has_aux=True)(lp)
-    (le, ae), ge = jax.value_and_grad(
-        _loss_fn(moe, x, mask, "einsum"), has_aux=True)(lp)
+    (lg, ag), gg = jax.jit(jax.value_and_grad(
+        _loss_fn(moe, x, mask, "grouped"), has_aux=True))(lp)
+    (le, ae), ge = jax.jit(jax.value_and_grad(
+        _loss_fn(moe, x, mask, "einsum"), has_aux=True))(lp)
 
     assert float(lg) == pytest.approx(float(le), rel=1e-5, abs=1e-6)
     assert float(ag["dropped_frac"]) == pytest.approx(
@@ -129,9 +129,10 @@ def test_ep_matches_replicated(spec):
         y, aux = moemod.moe_mlp(x, lp, moe, mesh=mesh)
         return jnp.sum(y * y) + aux["aux_total"], aux
 
-    (l_ep, a_ep), g_ep = jax.value_and_grad(loss_ep, has_aux=True)(lp)
-    (l_ref, a_ref), g_ref = jax.value_and_grad(
-        _loss_fn(moe, x, None, "grouped"), has_aux=True)(lp)
+    (l_ep, a_ep), g_ep = jax.jit(
+        jax.value_and_grad(loss_ep, has_aux=True))(lp)
+    (l_ref, a_ref), g_ref = jax.jit(jax.value_and_grad(
+        _loss_fn(moe, x, None, "grouped"), has_aux=True))(lp)
 
     assert float(l_ep) == pytest.approx(float(l_ref), rel=1e-5)
     assert float(a_ep["dropped_frac"]) == pytest.approx(
@@ -311,10 +312,10 @@ def test_ep_dropless_matches_replicated_under_any_skew(spec):
             y, aux = moemod.moe_mlp(x, lp, moe)
             return jnp.sum(y * y) + aux["aux_total"], (y, aux)
 
-        (l_ep, (y_ep, a_ep)), g_ep = jax.value_and_grad(
-            loss_ep, has_aux=True)(lp)
-        (l_1, (y_1, a_1)), g_1 = jax.value_and_grad(
-            loss_one, has_aux=True)(lp)
+        (l_ep, (y_ep, a_ep)), g_ep = jax.jit(jax.value_and_grad(
+            loss_ep, has_aux=True))(lp)
+        (l_1, (y_1, a_1)), g_1 = jax.jit(jax.value_and_grad(
+            loss_one, has_aux=True))(lp)
         assert float(a_ep["dropped_frac"]) == float(a_1["dropped_frac"]) == 0
         assert float(a_ep["routed_rows"]) == B * T * 2
         np.testing.assert_allclose(y_ep, y_1, rtol=1e-5, atol=1e-6)

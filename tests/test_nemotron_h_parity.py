@@ -15,6 +15,8 @@ are not scaled, a choice without its bias or ``silu`` in place of
 ``relu²`` move logits by 1e-2 and more on these weights.
 """
 
+import functools
+import json
 import types
 
 import jax
@@ -56,8 +58,14 @@ def model(keys, seed=0, scale=0.3):
     """(config, float32 params): init_params with the matrices scaled up
     (so that every mixer matters), the norm weights random around 1, the
     convolution's bias, the skip ``D`` and the router's choice bias
-    random, and the decay's parameters as the program draws them."""
-    cfg = hf.config_from_hf(types.SimpleNamespace(**keys))
+    random, and the decay's parameters as the program draws them. Built
+    once a set of keys: no test writes into the tree it gets."""
+    return _model(json.dumps(keys, sort_keys=True), seed, scale)
+
+
+@functools.lru_cache(maxsize=None)
+def _model(keys, seed, scale):
+    cfg = hf.config_from_hf(types.SimpleNamespace(**json.loads(keys)))
     flat = hf.flatten_pytree(
         transformer.init_params(cfg, jax.random.PRNGKey(seed)))
     rngs = jax.random.split(jax.random.PRNGKey(seed + 1), len(flat))
@@ -135,11 +143,11 @@ def test_logits_match_the_reference(which):
 def test_loss_and_gradients_match_the_reference(which):
     cfg, params = model(KEYS[which])
     tok = tokens(1)
-    got_l, got_g = jax.value_and_grad(
-        lambda p: mean_logprob(system_logits(p, cfg, tok, "full"), tok))(
+    got_l, got_g = jax.jit(jax.value_and_grad(
+        lambda p: mean_logprob(system_logits(p, cfg, tok, "full"), tok)))(
             params)
-    want_l, want_g = jax.value_and_grad(
-        lambda p: mean_logprob(ref.logits(p, KEYS[which], tok), tok))(params)
+    want_l, want_g = jax.jit(jax.value_and_grad(
+        lambda p: mean_logprob(ref.logits(p, KEYS[which], tok), tok)))(params)
     assert float(got_l) == pytest.approx(float(want_l), abs=1e-5)
     got_g, want_g = hf.flatten_pytree(got_g), hf.flatten_pytree(want_g)
     assert sorted(got_g) == sorted(want_g)
@@ -216,13 +224,22 @@ def test_a_document_packed_later_in_a_row_gives_what_it_gives_alone(
     def alone(p):
         return system_logits(p, cfg, docs[place], remat)
 
-    np.testing.assert_allclose(packed(params), alone(params), **TOL)
+    def logits_and_grads(logits_of):
+        """ONE program: the document's logits and every gradient of its
+        mean logprob."""
+        def loss(p):
+            logits = logits_of(p)
+            return mean_logprob(logits, docs[place]), logits
+
+        (_, logits), grads = jax.jit(
+            jax.value_and_grad(loss, has_aux=True))(params)
+        return logits, hf.flatten_pytree(grads)
+
+    l_packed, g_packed = logits_and_grads(packed)
+    l_alone, g_alone = logits_and_grads(alone)
+    np.testing.assert_allclose(l_packed, l_alone, **TOL)
     np.testing.assert_allclose(
-        packed(params), ref.logits(params, keys, docs[place]), **TOL)
-    g_packed = hf.flatten_pytree(jax.grad(
-        lambda p: mean_logprob(packed(p), docs[place]))(params))
-    g_alone = hf.flatten_pytree(jax.grad(
-        lambda p: mean_logprob(alone(p), docs[place]))(params))
+        l_packed, ref.logits(params, keys, docs[place]), **TOL)
     for name in g_alone:
         scale = float(jnp.max(jnp.abs(g_alone[name]))) or 1.0
         np.testing.assert_allclose(g_packed[name], g_alone[name],

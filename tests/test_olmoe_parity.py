@@ -126,10 +126,10 @@ def test_forward_logits_match_the_reference():
 def test_ppo_loss_and_gradients_match_the_reference():
     cfg, params = model()
     tok = tokens(1)
-    l_sys, g_sys = jax.value_and_grad(
-        lambda p: ppo_loss(system_logits(p, cfg, tok)[0], tok))(params)
-    l_ref, g_ref = jax.value_and_grad(
-        lambda p: ppo_loss(reference_logits(p, tok), tok))(params)
+    l_sys, g_sys = jax.jit(jax.value_and_grad(
+        lambda p: ppo_loss(system_logits(p, cfg, tok)[0], tok)))(params)
+    l_ref, g_ref = jax.jit(jax.value_and_grad(
+        lambda p: ppo_loss(reference_logits(p, tok), tok)))(params)
     assert float(l_sys) == pytest.approx(float(l_ref), rel=1e-5, abs=1e-6)
     flat_s, flat_r = hf.flatten_pytree(g_sys), hf.flatten_pytree(g_ref)
     assert set(flat_s) == set(flat_r)
@@ -177,7 +177,8 @@ def test_e4_matches_one_device_and_the_reference(skew):
     with psh.activation_sharding(mesh):
         (l_ep, (lg_ep, aux)), g_ep = jax.jit(
             jax.value_and_grad(run, has_aux=True))(sharded)
-    (l_1, (lg_1, aux_1)), g_1 = jax.value_and_grad(run, has_aux=True)(params)
+    (l_1, (lg_1, aux_1)), g_1 = jax.jit(
+        jax.value_and_grad(run, has_aux=True))(params)
     np.testing.assert_allclose(lg_ep, lg_1, **TOL)
     np.testing.assert_allclose(lg_ep, reference_logits(params, tok), **TOL)
     assert float(l_ep) == pytest.approx(float(l_1), rel=1e-5, abs=1e-6)

@@ -12,6 +12,8 @@ layers on the wrong layer's K/V, the memory taken behind the gate, Delta
 without its bias or the window off move logits by 1e-2 and more.
 """
 
+import functools
+import json
 import types
 
 import jax
@@ -47,8 +49,14 @@ def model(keys=HF_KEYS, seed=0, scale=0.3):
     """(config, float32 params): init_params with the matrices scaled up
     (so that every mixer matters), the norm weights random around 1,
     every bias and the skip ``D`` random, lambda's vectors large enough
-    for lambda to leave lambda_init, the decay's parameters as drawn."""
-    cfg = hf.config_from_hf(types.SimpleNamespace(**keys))
+    for lambda to leave lambda_init, the decay's parameters as drawn.
+    Built once a set of keys: no test writes into the tree it gets."""
+    return _model(json.dumps(keys, sort_keys=True), seed, scale)
+
+
+@functools.lru_cache(maxsize=None)
+def _model(keys, seed, scale):
+    cfg = hf.config_from_hf(types.SimpleNamespace(**json.loads(keys)))
     flat = hf.flatten_pytree(
         transformer.init_params(cfg, jax.random.PRNGKey(seed)))
     rngs = jax.random.split(jax.random.PRNGKey(seed + 1), len(flat))
@@ -161,11 +169,11 @@ def test_logits_match_the_reference():
 def test_loss_and_gradients_match_the_reference():
     cfg, params = model()
     tok = tokens(1)
-    got_l, got_g = jax.value_and_grad(
+    got_l, got_g = jax.jit(jax.value_and_grad(
         lambda p: mean_logprob(system_logits(p, cfg, tok, remat="full"),
-                               tok))(params)
-    want_l, want_g = jax.value_and_grad(
-        lambda p: -ref.loss(p, HF_KEYS, tok))(params)
+                               tok)))(params)
+    want_l, want_g = jax.jit(jax.value_and_grad(
+        lambda p: -ref.loss(p, HF_KEYS, tok)))(params)
     np.testing.assert_allclose(got_l, want_l, atol=1e-5, rtol=1e-5)
     got, want = hf.flatten_pytree(got_g), hf.flatten_pytree(want_g)
     assert sorted(got) == sorted(want)
@@ -253,24 +261,34 @@ def unrolled(cfg, params, h, seg, impl="reference"):
     return h
 
 
-@pytest.mark.parametrize("remat", [False, "full", "attention", "matmuls"])
-def test_the_scanned_runs_equal_the_unrolled_layers(remat):
+def weigh(out):
+    return jnp.sum(out * jnp.cos(jnp.arange(out.size).reshape(out.shape)))
+
+
+@functools.lru_cache(maxsize=None)
+def a_row_of_two_documents():
+    """The model, a row of two documents, and the unrolled layers' loss and
+    gradients on it (once for every ``remat``)."""
     cfg, params = model()
     h0 = params["embedding"][tokens(4, 64)][None]
     seg = jnp.asarray([[1] * 30 + [2] * 34], jnp.int32)
+    want = jax.jit(jax.value_and_grad(
+        lambda p, h: weigh(unrolled(cfg, p, h, seg)), argnums=(0, 1)))(
+            params, h0)
+    return cfg, params, h0, seg, want
+
+
+@pytest.mark.parametrize("remat", [False, "full", "attention", "matmuls"])
+def test_the_scanned_runs_equal_the_unrolled_layers(remat):
+    cfg, params, h0, seg, (want_l, want_g) = a_row_of_two_documents()
 
     def scanned(p, h):
-        out, _ = transformer.apply_layer_stack(
+        return weigh(transformer.apply_layer_stack(
             cfg, h, p["layers"], None, None, seg, POS,
-            attn_impl="reference", remat=remat)
-        return jnp.sum(out * jnp.cos(jnp.arange(out.size).reshape(out.shape)))
+            attn_impl="reference", remat=remat)[0])
 
-    def by_hand(p, h):
-        out = unrolled(cfg, p, h, seg)
-        return jnp.sum(out * jnp.cos(jnp.arange(out.size).reshape(out.shape)))
-
-    got_l, got_g = jax.value_and_grad(scanned, argnums=(0, 1))(params, h0)
-    want_l, want_g = jax.value_and_grad(by_hand, argnums=(0, 1))(params, h0)
+    got_l, got_g = jax.jit(jax.value_and_grad(scanned, argnums=(0, 1)))(
+        params, h0)
     np.testing.assert_allclose(got_l, want_l, rtol=1e-4)
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got_g),
                             jax.tree.leaves(want_g)):
@@ -326,6 +344,8 @@ def test_a_sources_gradient_sums_over_every_reader(what):
 
     _, made = run({})
     zero = jax.tree.map(jnp.zeros_like, made)
+    # eager: as one program each, XLA's other order of the float32 sums
+    # is outside the tolerance below
     each = jax.grad(lambda eps: run(eps)[0])({i: zero for i in readers})
     total = jax.grad(lambda e: run({i: e for i in readers})[0])(zero)
     for got, *parts in zip(jax.tree.leaves(total),
@@ -355,15 +375,19 @@ def test_what_a_source_hands_on_is_kept_once():
 
 def recurrence(x, dt, A, Bm, Cm, D, seg):
     """A token at a time, the state zeroed at a document's first token."""
-    h = jnp.zeros((x.shape[0],) + A.shape)
-    ys = []
-    for t in range(x.shape[1]):
-        first = seg[:, t] != (seg[:, t - 1] if t else -1)
-        h = jnp.where(first[:, None, None], 0.0, h)
-        h = (jnp.exp(dt[:, t, :, None] * A) * h
-             + (dt[:, t] * x[:, t])[..., None] * Bm[:, t, None, :])
-        ys.append(jnp.einsum("bdn,bn->bd", h, Cm[:, t]) + D * x[:, t])
-    return jnp.stack(ys, 1)
+    first = seg != jnp.concatenate(
+        [jnp.full_like(seg[:, :1], -1), seg[:, :-1]], 1)
+
+    def token(h, at):
+        x_t, dt_t, B_t, C_t, first_t = at
+        h = jnp.where(first_t[:, None, None], 0.0, h)
+        h = (jnp.exp(dt_t[..., None] * A) * h
+             + (dt_t * x_t)[..., None] * B_t[:, None, :])
+        return h, jnp.einsum("bdn,bn->bd", h, C_t) + D * x_t
+
+    by_token = [jnp.moveaxis(a, 1, 0) for a in (x, dt, Bm, Cm, first)]
+    ys = jax.lax.scan(token, jnp.zeros((x.shape[0],) + A.shape), by_token)[1]
+    return jnp.moveaxis(ys, 0, 1)
 
 
 @pytest.mark.parametrize("impl,T", [("reference", 150), ("reference", 128),
@@ -383,11 +407,11 @@ def test_the_chunked_scan_and_its_backward_match_the_recurrence(impl, T):
                       jnp.int32)
     w = jax.random.normal(k[6], (B_, T, Dn)) * (seg > 0)[..., None]
     args = (x, dt, A, Bm, Cm, D)
-    want, want_g = jax.value_and_grad(
-        lambda *a: jnp.sum(recurrence(*a, seg) * w), argnums=range(6))(*args)
-    got, got_g = jax.value_and_grad(
+    want, want_g = jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(recurrence(*a, seg) * w), argnums=range(6)))(*args)
+    got, got_g = jax.jit(jax.value_and_grad(
         lambda *a: jnp.sum(ssm.selective_scan(*a, seg, impl) * w),
-        argnums=range(6))(*args)
+        argnums=range(6)))(*args)
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for name, g, wg in zip("x dt A B C D".split(), got_g, want_g):
         if g.ndim == 3:  # padding's gradient is nobody's

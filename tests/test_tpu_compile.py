@@ -9,20 +9,43 @@ a described topology ``jax.default_backend()`` is still ``cpu``, so
 passes is not a chip run.
 
 libtpu admits ONE process at a time (``/tmp/libtpu_lockfile``), so the
-compiles run in one child process — this file, executed as a script, under
-conftest's ``libtpu_lock`` — whose results every test (and every xdist
-worker of the run) shares; the pytest processes themselves never load
-libtpu. The whole 24-layer train step is
-compiled the same way by a scratch script, not here (its set-up
-materialises 0.5B parameters).
+compiles run in child processes — this file, executed as a script: with no
+argument every case of ``_compile_all``, with ``lfm2`` / ``glm`` that
+cell's cut alone — and the pytest processes themselves never load libtpu.
+Nothing here starts a child. ``tests/conftest.py`` owns the one mechanism
+(``tests/compile_chain.py``):
+
+- ``DESCRIBED_CHIP_CHILDREN`` there names the children: fixture name ->
+  (command, time limit). A test takes the fixture (``compiled``,
+  ``compiled_lfm2``, ``compiled_glm``) and gets the JSON object its child
+  printed last. A child for a new configuration is ONE entry in that table
+  (and its ``_compile_<name>`` here): no new fixture, no test placed in
+  another file to schedule it.
+- The children a collection holds readers of are started at the run's
+  START, by whichever xdist worker is first, as one detached chain that
+  holds conftest's ``libtpu_lock`` for its length; the readers (and every
+  other holder of that lock) are collected LAST, so by the time a worker
+  reaches one, some ten minutes of chain have had the whole run to finish
+  in and the case reads a file. ``pytest tests/test_tpu_compile.py -k
+  lfm2`` starts that child alone; a run of an unrelated file starts none.
+- Results live in the run's shared temp directory
+  (``<basetemp>/../<name>.json``, written by rename; the child's output
+  beside it as ``<name>.stdout`` / ``.stderr``, the chain's own lines in
+  ``compile_chain.log``): ``returncode``, ``seconds`` and ``stderr`` of
+  the child, and under ``results`` what it printed — with ``seconds`` a
+  case, so which compile costs what needs no profiler. A child that fails
+  or runs out of its time makes every reader of it FAIL with its stderr.
+
+The whole 24-layer train step is compiled the same way by a scratch
+script, not here (its set-up materialises 0.5B parameters).
 """
 
 import functools
 import json
 import os
 import re
-import subprocess
 import sys
+import time
 
 import pytest
 
@@ -109,12 +132,12 @@ GDN_RULE_T = (16384, 14336, 8704)
 # The LFM2-MoE cell's cut (configs/lfm2-24b-a2b.json): its grad program's
 # largest micro-batch — the largest grid the packer makes of the cell's
 # traffic (traffic/train-toolcall-16k.json, ``compile_grid``) — at the
-# published widths. A child process and a fixture of its own
-# (``compiled_lfm2``), so that no other case waits for it.
+# published widths. A child of its own (``compiled_lfm2`` in conftest's
+# ``DESCRIBED_CHIP_CHILDREN``): a failure here costs no other case.
 LFM2_GRID = (2, 7168)
 # The GLM-4.7-Flash cell's cut (configs/glm-4.7-flash.json) likewise: its
-# largest grid is one row (traffic/train-swe-agent-16k.json); child and
-# fixture ``compiled_glm``.
+# largest grid is one row (traffic/train-swe-agent-16k.json); child
+# ``compiled_glm``.
 GLM_GRID = (1, 14336)
 SSD_SCANS = {"ssd-scan-granite": (1, 7040, 32, 64, 1, 128, 256),
              "ssd-scan-nemotron": (1, 4096, 16, 64, 1, 128, 128)}
@@ -150,9 +173,19 @@ def _compile_all():
     narrow = wa._narrowed
     wa._narrowed = lambda *a: narrowed.append(1) or narrow(*a)
 
+    last = time.monotonic()
+
+    def lap():
+        """Seconds since the last call: what the case that ends here took,
+        its tracing and lowering included."""
+        nonlocal last
+        was, last = last, time.monotonic()
+        return round(last - was, 2)
+
     def record(name, compiled):
         text = compiled.as_text()
         out[name] = {
+            "seconds": lap(),
             "narrowed": len(narrowed),
             "custom_calls": text.count("tpu_custom_call"),
             "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
@@ -588,6 +621,7 @@ def _compile_all():
                 f32(D, dtype=dt))
             bwd = jax.jit(rule_bwd_alone).lower(*alone).compile()
             out[name].update(
+                bwd_seconds=lap(),
                 bwd_custom_calls=bwd.as_text().count("tpu_custom_call"),
                 bwd_temp_bytes=bwd.memory_analysis().temp_size_in_bytes,
                 bwd_body_eqns=body_eqns(
@@ -610,27 +644,6 @@ def _compile_all():
                      if n - norms.get(k, 0)},
         param_bytes=18 * transformer.param_count(qnext))
     return out
-
-
-@pytest.fixture(scope="module")
-def compiled(shared_run_dir, libtpu_lock):
-    """{case: result} from ONE child process per test run: the first
-    caller (xdist workers included) runs it under the libtpu lock, the rest
-    read its result file from the run's shared temp directory."""
-    path = shared_run_dir / "tpu_compile.json"
-    with libtpu_lock():
-        if not path.exists():
-            r = subprocess.run(
-                [sys.executable, os.path.abspath(__file__)],
-                env=dict(os.environ, JAX_PLATFORMS="cpu"),
-                capture_output=True, text=True, timeout=900,
-            )
-            assert r.returncode == 0, r.stderr[-3000:]
-            path.write_text(r.stdout.splitlines()[-1])
-        results = json.loads(path.read_text())
-    if "skip" in results:
-        pytest.skip(results["skip"])
-    return results
 
 
 def _compile_lfm2():
@@ -672,8 +685,10 @@ def _compile_lfm2():
 
         return jax.value_and_grad(loss)(p)
 
+    began = time.monotonic()
     got = jax.jit(lfm2_grad).lower(params, tok, tok, tok).compile()
     return {
+        "seconds": round(time.monotonic() - began, 2),
         "custom_calls": got.as_text().count("tpu_custom_call"),
         "temp_bytes": got.memory_analysis().temp_size_in_bytes,
         "convs_traced": sum(shortconv.geometry_counts().values()),
@@ -719,53 +734,15 @@ def _compile_glm():
 
         return jax.value_and_grad(loss)(p)
 
+    began = time.monotonic()
     got = jax.jit(glm_grad).lower(params, tok, tok, tok).compile()
     return {
+        "seconds": round(time.monotonic() - began, 2),
         "custom_calls": got.as_text().count("tpu_custom_call"),
         "temp_bytes": got.memory_analysis().temp_size_in_bytes,
         "assemblies_traced": {"%dx%d/h%d/q%dkv%d/%d+%d/v%d" % g: n
                               for g, n in mla.geometry_counts().items()},
         "param_bytes": 18 * transformer.param_count(glm)}
-
-
-@pytest.fixture(scope="module")
-def compiled_glm(shared_run_dir, libtpu_lock):
-    """:func:`_compile_glm`'s result, from one child process a test run
-    (as ``compiled``)."""
-    path = shared_run_dir / "tpu_compile_glm.json"
-    with libtpu_lock():
-        if not path.exists():
-            r = subprocess.run(
-                [sys.executable, os.path.abspath(__file__), "glm"],
-                env=dict(os.environ, JAX_PLATFORMS="cpu"),
-                capture_output=True, text=True, timeout=600,
-            )
-            assert r.returncode == 0, r.stderr[-3000:]
-            path.write_text(r.stdout.splitlines()[-1])
-        results = json.loads(path.read_text())
-    if "skip" in results:
-        pytest.skip(results["skip"])
-    return results
-
-
-@pytest.fixture(scope="module")
-def compiled_lfm2(shared_run_dir, libtpu_lock):
-    """:func:`_compile_lfm2`'s result, from one child process a test run
-    (as ``compiled``)."""
-    path = shared_run_dir / "tpu_compile_lfm2.json"
-    with libtpu_lock():
-        if not path.exists():
-            r = subprocess.run(
-                [sys.executable, os.path.abspath(__file__), "lfm2"],
-                env=dict(os.environ, JAX_PLATFORMS="cpu"),
-                capture_output=True, text=True, timeout=600,
-            )
-            assert r.returncode == 0, r.stderr[-3000:]
-            path.write_text(r.stdout.splitlines()[-1])
-        results = json.loads(path.read_text())
-    if "skip" in results:
-        pytest.skip(results["skip"])
-    return results
 
 
 @pytest.mark.parametrize("T", WINDOW_T)
