@@ -738,10 +738,11 @@ class JaxTrainEngine(TrainableEngine):
         [R, L]: its rows split over the data axes (an axis that splits
         the sequence or the widths is not counted: an over-estimate)."""
         rows = self._rows_on_chip(R)
+        group = self.cfg.group_size
         full = kernel_padded_len(self.attn_impl, L,
-                                 head_dim=self.cfg.head_dim)
+                                 head_dim=self.cfg.head_dim, group=group)
         window = kernel_padded_len(self.attn_impl, L, self.cfg.sliding_window,
-                                   self.cfg.head_dim)
+                                   self.cfg.head_dim, group)
         return transformer.remat_kept_bytes(
             self.cfg, rows * L, self.compute_dtype.itemsize,
             full_tokens=rows * (full or 0),
@@ -963,8 +964,8 @@ class JaxTrainEngine(TrainableEngine):
         needed = static = 0
         for window, layers in windows.items():
             for seg in grids:
-                n, s = window_attention.count_needed(seg, window,
-                                                     self.cfg.head_dim)
+                n, s = window_attention.count_needed(
+                    seg, window, self.cfg.head_dim, self.cfg.group_size)
                 needed += layers * n
                 static += layers * s
         telemetry.set_gauge(f"{role}/attn_blocks_needed_frac",

@@ -131,18 +131,19 @@ def _wants_kernel(impl: str) -> bool:
 
 def kernel_padded_len(
     impl: str, length: int, sliding_window: Optional[int] = None,
-    head_dim: int = 128,
+    head_dim: int = 128, group: int = 1,
 ) -> Optional[int]:
     """The padded row length at which packed causal self-attention over
     rows of ``length`` tokens runs its Pallas kernel (the grouped-head
-    one, under a ``sliding_window`` or without) — what the kernel's output
-    and softmax statistic span; None where :func:`packed_attention` takes
-    the XLA reference."""
+    one, under a ``sliding_window`` or without; heads wider than 128 take
+    their blocks by ``group``, the query heads a key/value head) — what
+    the kernel's output and softmax statistic span; None where
+    :func:`packed_attention` takes the XLA reference."""
     if not _wants_kernel(impl):
         return None
     from areal_tpu.ops.pallas import window_attention as wa
 
-    return wa.padded_len(length, sliding_window, head_dim)
+    return wa.padded_len(length, sliding_window, head_dim, group)
 
 
 def packed_attention(

@@ -32,17 +32,25 @@ kernel call). Wrapped with areal_tpu's packed-batch semantics:
    ``0 * (1 / 0)`` (a block of nothing but padding, which ran no key
    block: its softmax statistic is -inf, and no backward block reads it);
  - head_dim is padded up to the lane width (128) when needed, and the row
-   up to a multiple of the tile of :func:`pick_tile`, with segment id 0.
+   up to a multiple of the blocks of :func:`geometry`, with segment id 0.
+
+Which blocks a call runs is :func:`geometry`'s choice from what the call
+sees — the row, the window, the head size, the query heads a key/value
+head — out of tables measured on the chip (tools/window_tile_sweep.py):
+``TILE_COST`` under a window and ``CAUSAL_TILE_COST`` without at heads up
+to 128 (square tiles, one cost a tile), ``WIDE_BLOCKS`` at wider heads (a
+shape a kernel: forward, dKV, dQ).
 
 The kernels' device ops are named ``splash_mqa_{fwd,dkv,dq}_segmented_*``
-(a full-causal call runs no ``dq``: its backward is the one fused ``dkv``
-kernel). Per compiled step,
+(a call whose backward is the ONE fused ``dkv`` kernel runs no ``dq``: a
+full-causal call at heads up to 128). Per compiled step,
 :func:`geometry_counts` says which (length, padded length, tile, window)
 each WINDOWED call was traced with, and how many key blocks its static
 mask visits against a causal kernel's; :func:`causal_geometry_counts`
-which (length, padded length, tile) each full-causal call was traced
-with; :func:`needed_counts` what the packed grids the engine ran needed of
-those static blocks (counted on the host, by :func:`count_needed`).
+which (length, padded length, :class:`Blocks`) each full-causal call was
+traced with — the table entry it took; :func:`needed_counts` what the
+packed grids the engine ran needed of those static blocks (counted on the
+host, by :func:`count_needed`).
 
 CPU/testing: ``interpret=True`` runs the kernels in Pallas's plain
 interpreter (tests/test_window_attention.py) — the TPU interpreter of
@@ -54,6 +62,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import math
 from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -91,7 +100,7 @@ TILE_COST = {1024: 0.8599, 512: 1.0016, 256: 1.9394}
 # The same for a full-causal call (no window): ns per row · query token ·
 # visited key token of 2 forward passes + 1 forward-and-backward THROUGH
 # THE WRAPPER (its layout glue included), at the blocks of
-# :func:`_block_sizes` — measured on a TPU v5e, 1 row x 6144 tokens in one
+# :func:`geometry` — measured on a TPU v5e, 1 row x 6144 tokens in one
 # document, 14 query / 2 key-value heads of 64 in 128 lanes, bf16
 # (tools/window_tile_sweep.py --window 0; PERF.md §5, PR 45); the ratios
 # held at 3072 and 7680 tokens, at 32 / 4 and 16 / 16 heads of 128. Tile
@@ -102,39 +111,47 @@ CAUSAL_TILE_COST = {1024: 0.2804, 768: 0.3314, 512: 0.3593, 256: 0.8073,
 # A causal call's key block is computed 512 keys at a time where that
 # divides it (tile 1024: 5-6 % cheaper than whole).
 _CAUSAL_KV_COMPUTE = 512
-# Heads wider than the lane width (qwen3_next: 256) run at tiles up to
-# this: at tile 1024 the backward of 8 grouped query heads of 256 asks for
-# more than the chip's 16 MB of scoped VMEM — the fused kernel for 17.7 MB
-# at a row of 14,336 (my chip run, PR 52), the dKV kernel alone at some
-# lengths (11,776: a compile for a described v5e). The tables above were
-# measured at heads of 64 and 128; their RATIOS are used at 256 too,
-# unmeasured there (ROADMAP R6).
-_WIDE_HEAD_MAX_TILE = 512
+# Heads wider than the lane width under a window — unmeasured, no model
+# has them — and wide heads WIDE_BLOCKS has no entry for run square tiles
+# up to this, computed whole, dKV and dQ kernels: what compiled for every
+# wide shape so far (PR 52).
+_WIDE_FALLBACK_TILE = 512
 
 
 def _round_up(n: int, tile: int) -> int:
     return -(-n // tile) * tile
 
 
-def _static_blocks(n: int, tile: int, window: Optional[int]) -> np.ndarray:
-    """bool [n, n]: the blocks the static mask leaves — query block i
-    reaches back to key i*tile - window + 1, or with no window to key 0."""
+def _static_blocks(n: int, tile: int, window: Optional[int],
+                   block_kv: Optional[int] = None) -> np.ndarray:
+    """bool [n, key blocks]: the blocks the static mask leaves — query
+    block i reaches back to key i*tile - window + 1, or with no window to
+    key 0. Key blocks of ``block_kv`` tokens where that is not the query
+    block's ``tile`` (a causal mask only): every key block that starts at
+    or before query block i's last token."""
     i = np.arange(n)[:, None]
+    if block_kv not in (None, tile):
+        assert window is None, "a windowed call runs square blocks"
+        return np.arange(n * tile // block_kv) * block_kv < (i + 1) * tile
     j = i.T
     if window is None:
         return j <= i
     return (j <= i) & (j >= np.maximum(i * tile - window + 1, 0) // tile)
 
 
-def blocks_visited(n_pad: int, tile: int,
-                   window: Optional[int]) -> Tuple[int, int]:
+def blocks_visited(n_pad: int, tile: int, window: Optional[int],
+                   block_kv: Optional[int] = None) -> Tuple[int, int]:
     """(key blocks the static mask of a call over a padded row of
-    ``n_pad`` tokens visits at ``tile``, key blocks a causal mask would)."""
+    ``n_pad`` tokens visits at ``tile`` (key blocks of ``block_kv``
+    tokens; default: of ``tile``), key blocks a causal mask would)."""
     n = n_pad // tile
-    return int(_static_blocks(n, tile, window).sum()), n * (n + 1) // 2
+    causal = _static_blocks(n, tile, None, block_kv)
+    return int(_static_blocks(n, tile, window, block_kv).sum()), int(
+        causal.sum())
 
 
-def blocks_needed(segment_ids, tile: int, window: Optional[int] = None):
+def blocks_needed(segment_ids, tile: int, window: Optional[int] = None,
+                  block_kv: Optional[int] = None):
     """bool [..., n, n] over the ``tile``-token blocks of a packed row
     (``segment_ids`` [..., n * tile], 0 = padding; numpy or traced): query
     block i needs key block j when j is in the static mask's range for i
@@ -143,26 +160,37 @@ def blocks_needed(segment_ids, tile: int, window: Optional[int] = None):
     its row, so the earliest document of block i — its first real token's
     — reaches back into block j < i exactly when block j ENDS in it: the
     needed blocks are one range up to i. A query block of nothing but
-    padding needs no block."""
+    padding needs no block. With key blocks of ``block_kv`` tokens
+    ([..., n, key blocks]) the same rule: a key block that ends before
+    query block i starts is needed when it ends in i's earliest document,
+    and one that shares tokens with i when it holds a real one."""
     xp = jnp if isinstance(segment_ids, jax.Array) else np
     rows = segment_ids.reshape(*segment_ids.shape[:-1], -1, tile)
     real = rows > 0
     first = xp.take_along_axis(  # [..., n, 1]
         rows, xp.argmax(real, axis=-1)[..., None], axis=-1)
-    last = rows[..., None, :, -1]  # [..., 1, n]
     n = rows.shape[-2]
+    if block_kv not in (None, tile):  # (square blocks keep their own graph)
+        keys = segment_ids.reshape(*segment_ids.shape[:-1], -1, block_kv)
+        i, j = np.indices((n, keys.shape[-2]))
+        own = (j * block_kv < (i + 1) * tile) & ((j + 1) * block_kv > i * tile)
+        return (_static_blocks(n, tile, window, block_kv)
+                & real.any(axis=-1)[..., None]
+                & ((own & (keys > 0).any(axis=-1)[..., None, :])
+                   | (keys[..., None, :, -1] == first)))
+    last = rows[..., None, :, -1]  # [..., 1, n]
     return (_static_blocks(n, tile, window) & real.any(axis=-1)[..., None]
             & (np.eye(n, dtype=bool) | (last == first)))
 
 
 def pick_tile(n: int, window: Optional[int] = None,
-              head_dim: int = LANE) -> int:
+              max_tile: Optional[int] = None) -> int:
     """The tile a row of n tokens runs: the one whose visited blocks at
     the padded length cost least, ``visited · t² · c(t)``; ties to the
-    larger; at heads wider than the lanes, of the tiles that fit."""
+    larger; of the tiles up to ``max_tile`` where one is given."""
     costs = CAUSAL_TILE_COST if window is None else TILE_COST
-    if head_dim > LANE:
-        costs = {t: c for t, c in costs.items() if t <= _WIDE_HEAD_MAX_TILE}
+    if max_tile is not None:
+        costs = {t: c for t, c in costs.items() if t <= max_tile}
 
     def cost(t):
         visited, _ = blocks_visited(_round_up(n, t), t, window)
@@ -171,13 +199,119 @@ def pick_tile(n: int, window: Optional[int] = None,
     return min(costs, key=cost)
 
 
-def padded_len(n: int, window: Optional[int] = None,
-               head_dim: int = LANE) -> Optional[int]:
+class Blocks(NamedTuple):
+    """The geometry one call runs. Each kernel's (query block, key block
+    fetched, key block computed at a time) — ``dq`` has no third, and
+    ``dq`` None is the ONE fused backward kernel: 5 matmuls a block pair
+    where dKV + dQ do 7; dQ leaves it a partial sum a key block, in the
+    compute dtype, [key blocks, heads, row, head_dim], and is added up
+    outside. These fix the GRID; which of its steps run is the static
+    mask's schedule narrowed by the row's segment ids (:func:`_narrowed`):
+    a skipped step costs a grid step and no DMA, and the fused backward
+    still writes that step's dQ partial (zeros)."""
+    fwd: Tuple[int, int, int]
+    dkv: Tuple[int, int, int]
+    dq: Optional[Tuple[int, int]]
+
+    @property
+    def tile(self) -> int:
+        """What the row is padded to a multiple of: every block's."""
+        return math.lcm(*self.fwd[:2], *self.dkv[:2], *(self.dq or ()))
+
+    def sizes(self) -> _splash.BlockSizes:
+        # A backward query block lies inside ONE forward query block: a
+        # forward block of nothing but padding ran no key block and left
+        # its softmax statistic -inf, which only a backward block of
+        # nothing but padding — it runs no block either — may hold.
+        assert all(self.fwd[0] % kernel[0] == 0
+                   for kernel in (self.dkv, self.dq) if kernel), self
+        dq = {} if self.dq is None else dict(block_q_dq=self.dq[0],
+                                             block_kv_dq=self.dq[1])
+        return _splash.BlockSizes(
+            block_q=self.fwd[0], block_kv=self.fwd[1],
+            block_kv_compute=self.fwd[2],
+            block_q_dkv=self.dkv[0], block_kv_dkv=self.dkv[1],
+            block_kv_dkv_compute=self.dkv[2],
+            use_fused_bwd_kernel=self.dq is None, **dq)
+
+    def label(self) -> str:
+        """``f<QxKVxC>.kv<QxKVxC>.q<QxKV>|fused``."""
+        x = "x".join
+        return ".".join((
+            "f" + x(map(str, self.fwd)), "kv" + x(map(str, self.dkv)),
+            "fused" if self.dq is None else "q" + x(map(str, self.dq))))
+
+
+def _square(tile: int, compute: Optional[int] = None,
+            fused: bool = False) -> Blocks:
+    kernel = (tile, tile, compute or tile)
+    return Blocks(kernel, kernel, None if fused else (tile, tile))
+
+
+# Heads wider than the lanes under a causal mask, {(head_dim, query heads
+# a key/value head): blocks} — each kernel's own best shape of those the
+# chip's 16 MB of scoped VMEM takes, measured on a TPU v5e by each
+# kernel's DEVICE time, bf16, at the rows and document layouts of the two
+# cells that have such heads: glm-4.7-flash's latent attention (20 / 20
+# heads; 14,336 = 8,937 + 5,357, 13,440 = 13,356 and = 6,525 x 2) and
+# qwen3-next's gated attention (16 / 2; 14,336 = 11,737 + 2,488, 8,704 =
+# 5,176 + 3,519): tools/window_tile_sweep.py --window 0 --head-dim 256
+# --device; PERF.md §5, PR 61. Blocks of 1024 whose keys are computed 256
+# at a time take the forward 16-25 % under the square 512 computed whole
+# that PR 52 shipped because it compiled (the [block_q, compute] float32
+# temporaries were what overflowed), the dKV kernel 3-15 % and the dQ
+# kernel 4-14 %; with eight grouped heads the backward's query block is
+# better at 512. The dKV + dQ pair, not the fused backward: its dQ partial
+# sums — [key blocks, heads, row, head_dim], every head of the call at
+# once — are 2.06 GB at 20 heads over 14,336 tokens and 1.64 GB at 2 x 8
+# (key blocks of 1024), and the tighter cell has ~1.0 GB of room (PERF.md
+# §6, PR 61: it was 10 % under the pair where memory is no object).
+WIDE_BLOCKS = {
+    (256, 1): Blocks((1024, 1024, 256), (1024, 1024, 512), (1024, 1024)),
+    (256, 8): Blocks((1024, 1024, 256), (512, 1024, 256), (512, 1024)),
+}
+
+
+def _wide_blocks(n: int, head_dim: int, group: int) -> Blocks:
+    """The entry measured at exactly this head size and group; the
+    fallback's square pair where there is none — add an entry when such a
+    model arrives and is swept — or the row is under two of the entry's
+    blocks (it was measured at rows of 8.7k-14k tokens, and would pad a
+    short row to 1024)."""
+    blocks = WIDE_BLOCKS.get((head_dim, group))
+    if blocks is None or n < 2 * blocks.tile:
+        return _square(pick_tile(n, None, _WIDE_FALLBACK_TILE))
+    return blocks
+
+
+def geometry(n: int, window: Optional[int] = None, head_dim: int = LANE,
+             group: int = 1) -> Blocks:
+    """The blocks a call over rows of n tokens runs, as each table was
+    measured. Heads up to the lane width: square tiles of
+    :func:`pick_tile`; under a window computed whole by dKV and dQ kernels
+    (the fused kernel's grid would hold every causal block), under a
+    causal mask the key block computed ``_CAUSAL_KV_COMPUTE`` keys at a
+    time and the fused backward. Wider heads under a causal mask: the
+    measured entry of :func:`_wide_blocks` for ``head_dim`` and the
+    ``group`` of query heads a key/value head; under a window the
+    fallback's square tiles."""
+    if head_dim > LANE and window is None:
+        return _wide_blocks(n, head_dim, group)
+    tile = pick_tile(n, window, _WIDE_FALLBACK_TILE if head_dim > LANE
+                     else None)
+    if window is not None:
+        return _square(tile)
+    compute = tile if tile % _CAUSAL_KV_COMPUTE else _CAUSAL_KV_COMPUTE
+    return _square(tile, compute, fused=True)
+
+
+def padded_len(n: int, window: Optional[int] = None, head_dim: int = LANE,
+               group: int = 1) -> Optional[int]:
     """The padded length the kernel runs a row of n tokens at; None when
     n is not a multiple of 128 (the caller takes the reference)."""
     if n % LANE:
         return None
-    return _round_up(n, pick_tile(n, window, head_dim))
+    return _round_up(n, geometry(n, window, head_dim, group).tile)
 
 
 # Which (length, padded length, tile, window) each WINDOWED call TRACED
@@ -186,9 +320,11 @@ def padded_len(n: int, window: Optional[int] = None,
 # {label: {(n, n_pad, tile, window): [calls, visited, causal]}}.
 _GEOMETRY: Dict[str, Dict[Tuple[int, int, int, int], list]] = (
     collections.defaultdict(dict))
-# Which (length, padded length, tile) each FULL-CAUSAL call traced with —
-# a count of its own: readers of the windowed one take its calls for a
-# sliding layer's. {label: {(n, n_pad, tile): calls}}.
+# Which (length, padded length, blocks) each FULL-CAUSAL call traced with
+# — the :class:`Blocks` that ran: each kernel's blocks, the fused backward
+# or the pair; a count of its own: readers of the windowed one take its
+# calls for a sliding layer's.
+# {label: {(n, n_pad, blocks): calls}}.
 _CAUSAL_GEOMETRY: Dict[str, collections.Counter] = collections.defaultdict(
     collections.Counter)
 
@@ -207,26 +343,28 @@ def geometry_counts() -> Dict[str, Dict[Tuple[int, int, int, int], Dict]]:
     }
 
 
-def causal_geometry_counts() -> Dict[str, Dict[Tuple[int, int, int], int]]:
+def causal_geometry_counts() -> Dict[str, Dict[Tuple[int, int, Blocks], int]]:
     return {label: dict(c) for label, c in _CAUSAL_GEOMETRY.items()}
 
 
 def count_needed(segment_ids: np.ndarray,  # [R, L] on the host
                  window: Optional[int] = None,
-                 head_dim: int = LANE) -> Tuple[int, int]:
+                 head_dim: int = LANE, group: int = 1) -> Tuple[int, int]:
     """(key blocks the rows of a packed grid need, key blocks the static
-    mask visits) at the tile and padded length :func:`window_attention`
-    runs them at — the same :func:`blocks_needed` the kernel's schedule is
-    narrowed by; a row of ONE block keeps the static schedule. Added to
-    :func:`needed_counts` under the grid's shape."""
+    mask visits) at the forward kernel's blocks and the padded length
+    :func:`window_attention` runs them at — the same :func:`blocks_needed`
+    the kernel's schedule is narrowed by; a row of ONE block keeps the
+    static schedule. Added to :func:`needed_counts` under the grid's
+    shape."""
     R, L = segment_ids.shape
-    tile = pick_tile(L, window, head_dim)
+    blocks = geometry(L, window, head_dim, group)
+    tile, (block_q, block_kv, _) = blocks.tile, blocks.fwd
     n_pad = _round_up(L, tile)
-    visited = needed = R * blocks_visited(n_pad, tile, window)[0]
+    visited = needed = R * blocks_visited(n_pad, block_q, window, block_kv)[0]
     if n_pad > tile:
         needed = int(blocks_needed(
-            np.pad(segment_ids, [(0, 0), (0, n_pad - L)]), tile,
-            window).sum())
+            np.pad(segment_ids, [(0, 0), (0, n_pad - L)]), block_q,
+            window, block_kv).sum())
     c = _NEEDED.setdefault((R, L, n_pad, tile, window or 0), [0, 0, 0])
     c[0] += 1
     c[1] += needed
@@ -241,47 +379,18 @@ def needed_counts() -> Dict[Tuple[int, int, int, int, int], Dict]:
             for g, c in _NEEDED.items()}
 
 
-def _count(n: int, n_pad: int, tile: int, window: Optional[int]) -> None:
+def _count(n: int, n_pad: int, blocks: Blocks,
+           window: Optional[int]) -> None:
     label = _attention.active_label()
     if window is None:
-        _CAUSAL_GEOMETRY[label][(n, n_pad, tile)] += 1
+        _CAUSAL_GEOMETRY[label][(n, n_pad, blocks)] += 1
         return
+    tile = blocks.tile
     visited, causal = blocks_visited(n_pad, tile, window)
     c = _GEOMETRY[label].setdefault((n, n_pad, tile, window), [0, 0, 0])
     c[0] += 1
     c[1] += visited
     c[2] += causal
-
-
-def _block_sizes(tile: int, window: Optional[int],
-                 head_dim: int = LANE) -> _splash.BlockSizes:
-    """The kernels' blocks at ``tile``, as each table was measured: square
-    and computed whole, dKV and dQ kernels, under a window; under a causal
-    mask the key block computed ``_CAUSAL_KV_COMPUTE`` keys at a time and
-    ONE fused backward kernel (5 matmuls a block pair where dKV + dQ do
-    7; dQ leaves it a partial sum a key block, in the compute dtype, and
-    is added up outside — under a window its grid would hold every causal
-    block, so the windowed call keeps the two kernels). These fix the
-    GRID; which of its steps run is the static mask's schedule narrowed by
-    the row's segment ids (:func:`_narrowed`): a skipped step costs a grid
-    step and no DMA, and the fused backward still writes that step's dQ
-    partial (zeros)."""
-    if window is not None or head_dim > LANE:
-        # heads wider than the lanes keep the two kernels under a causal
-        # mask too: the fused kernel's dQ partial sums, one a key block,
-        # are [key blocks, heads, row, head_dim] — 4.3 GB at 16 heads of
-        # 256 over a row of 16,384 at tile 512
-        return _splash.BlockSizes(
-            block_q=tile, block_kv=tile, block_kv_compute=tile,
-            block_q_dkv=tile, block_kv_dkv=tile, block_kv_dkv_compute=tile,
-            block_q_dq=tile, block_kv_dq=tile,
-        )
-    compute = tile if tile % _CAUSAL_KV_COMPUTE else _CAUSAL_KV_COMPUTE
-    return _splash.BlockSizes(
-        block_q=tile, block_kv=tile, block_kv_compute=compute,
-        block_q_dkv=tile, block_kv_dkv=tile, block_kv_dkv_compute=compute,
-        use_fused_bwd_kernel=True,
-    )
 
 
 class _Walk(NamedTuple):
@@ -299,24 +408,39 @@ class _Walk(NamedTuple):
     by_key: bool
 
 
-def _walk(info, by_key: bool) -> Optional[_Walk]:
+def _walk(info, by_key: bool, n_pad: int, block_q: int,
+          block_kv: int) -> Optional[_Walk]:
     """An entry (r, c) of a forward / dQ mask info is query block r
     against the key block its ``data_next`` names; of a dKV info
     (``by_key``) the query block its ``data_next`` names against key
     block c (the other side of either may be shrunk to the blocks the
-    static mask visits)."""
+    static mask visits). The kernel's blocks need not be square: a pair
+    indexes ``blocks_needed(ids, block_q, window, block_kv)``."""
     if info is None:
         return None
     block, data = (np.asarray(x)[0].astype(np.int32)
                    for x in (info.block_mask, info.data_next))
     r, c = np.indices(block.shape)
     qi, ki = (data, c) if by_key else (r, data)
-    n = block.shape[1 if by_key else 0]  # blocks a side
+    nq, nk = n_pad // block_q, n_pad // block_kv
+    base = nq if by_key else nk  # above every ``data_next`` entry
     order = (lambda x: x.T.reshape(-1)) if by_key else (
         lambda x: x.reshape(-1))
     live = order(block > 0)
-    return _Walk(live, order(block), np.where(live, order(qi * n + ki), 0),
-                 np.arange(block.size) * n + order(data), n, by_key)
+    return _Walk(live, order(block), np.where(live, order(qi * nk + ki), 0),
+                 np.arange(block.size) * base + order(data), base, by_key)
+
+
+def _infos(kernel):
+    return kernel.fwd_mask_info, kernel.dq_mask_info, kernel.dkv_mask_info
+
+
+def _shapes(sizes: _splash.BlockSizes):
+    """(query block, key block) of the forward, the dQ and the dKV
+    kernel."""
+    return ((sizes.block_q, sizes.block_kv),
+            (sizes.block_q_dq, sizes.block_kv_dq),
+            (sizes.block_q_dkv, sizes.block_kv_dkv))
 
 
 @functools.lru_cache(maxsize=None)
@@ -336,9 +460,9 @@ def _kernel(n_pad: int, window: Optional[int], group: int,
             _mask.MultiHeadMask([mask] * group), block_sizes=sizes,
             residual_checkpoint_name=RESIDUALS, interpret=interpret,
         )
-    return kernel, (_walk(kernel.fwd_mask_info, False),
-                    _walk(kernel.dq_mask_info, False),
-                    _walk(kernel.dkv_mask_info, True))
+    return kernel, tuple(
+        _walk(info, by_key, n_pad, *shape) for info, by_key, shape in zip(
+            _infos(kernel), (False, False, True), _shapes(sizes)))
 
 
 def _narrow(walk: _Walk, needed: jnp.ndarray, like):
@@ -372,10 +496,17 @@ def _schedules(segment_ids: jnp.ndarray,  # [n_pad]: one packed row
     forward, its backward, its recomputation, a program after the other)
     bind it as one equation."""
     kernel, walks = _kernel(n_pad, window, group, sizes, interpret)
-    needed = blocks_needed(segment_ids, sizes.block_q, window)
-    infos = (kernel.fwd_mask_info, kernel.dq_mask_info, kernel.dkv_mask_info)
-    return tuple(None if walk is None else _narrow(walk, needed, info)
-                 for info, walk in zip(infos, walks))
+    needed = {}  # one matrix a block shape: square blocks share it
+
+    def narrow(walk, info, shape):
+        if shape not in needed:
+            needed[shape] = blocks_needed(segment_ids, shape[0], window,
+                                          shape[1])
+        return _narrow(walk, needed[shape], info)
+
+    return tuple(None if walk is None else narrow(walk, info, shape)
+                 for walk, info, shape in zip(walks, _infos(kernel),
+                                              _shapes(sizes)))
 
 
 def _narrowed(segment_ids: jnp.ndarray, *geometry):
@@ -384,10 +515,10 @@ def _narrowed(segment_ids: jnp.ndarray, *geometry):
     the row needs: the mask function and the segment ids still mask
     INSIDE a block, as under the static schedule."""
     kernel, _ = _kernel(*geometry)
-    infos = (kernel.fwd_mask_info, kernel.dq_mask_info, kernel.dkv_mask_info)
     return _splash.SplashAttentionKernel(
         *(info and info._replace(block_mask=now[0], data_next=now[1])
-          for info, now in zip(infos, _schedules(segment_ids, *geometry))),
+          for info, now in zip(_infos(kernel),
+                               _schedules(segment_ids, *geometry))),
         **kernel.kwargs)
 
 
@@ -412,12 +543,13 @@ def window_attention(
             f"row length T={T} is no multiple of 128; "
             "ops/attention.packed_attention routes such shapes to the "
             "reference")
-    tile = pick_tile(T, window, D)
+    G = Hq // Hkv
+    blocks = geometry(T, window, D, G)
+    tile = blocks.tile
     T_pad = _round_up(T, tile)
-    _count(T, T_pad, tile, window)
+    _count(T, T_pad, blocks, window)
     if scale is None:
         scale = D ** -0.5
-    G = Hq // Hkv
 
     # [B, T, H, D] -> [B, Hkv, G, T, D] / [B, Hkv, T, D], heads padded to
     # the lane width and the row to its tile; the kernel takes no scale.
@@ -438,12 +570,12 @@ def window_attention(
     seg = _splash.SegmentIds(q=pad_ids(q_segment_ids).astype(jnp.int32),
                              kv=pad_ids(kv_segment_ids).astype(jnp.int32))
 
-    geometry = (T_pad, window, G, _block_sizes(tile, window, D), interpret)
+    kernel = (T_pad, window, G, blocks.sizes(), interpret)
 
     def row(q, k, v, seg):  # one packed row, a key/value head at a time
         # A row of ONE block has nothing to skip: the static schedule.
-        kern = (_kernel(*geometry)[0] if T_pad == tile
-                else _narrowed(seg.q, *geometry))
+        kern = (_kernel(*kernel)[0] if T_pad == tile
+                else _narrowed(seg.q, *kernel))
         return jax.vmap(kern, in_axes=(0, 0, 0, None))(q, k, v, seg)
 
     out = jax.vmap(row)(qt, kt, vt, seg)  # [B, Hkv, G, T_pad, D+]

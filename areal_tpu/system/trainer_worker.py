@@ -336,10 +336,12 @@ class TrainerWorker:
         monitor.log_device_report(
             logger, f"trainer{self.cfg.dist_rank}", stage=stage,
             attention=attention.dispatch_counts(),
-            # {label: {"length>padded/tile": calls traced}}: the kernel's
-            # full-causal calls (the "pallas" count above)
+            # {label: {"length>padded/blocks": calls traced}}: the kernel's
+            # full-causal calls (the "pallas" count above) and the
+            # geometry each ran (window_attention.Blocks.label)
             causal_geometry={
-                label: {"%d>%d/%d" % geom: n for geom, n in counts.items()}
+                label: {"%d>%d/%s" % (n, n_pad, blocks.label()): calls
+                        for (n, n_pad, blocks), calls in counts.items()}
                 for label, counts in
                 window_attention.causal_geometry_counts().items()
             },

@@ -203,7 +203,9 @@ def test_the_padded_length_is_the_kernels(L):
     assert attn.kernel_padded_len("auto", L) is None  # the CPU's reference
     assert L_pad % tile == 0 and L <= L_pad < L + tile
     assert _traced(L, L, f"tile-{L}") == {"pallas": 1}
-    assert wa.causal_geometry_counts()[f"tile-{L}"] == {(L, L_pad, tile): 1}
+    assert wa.causal_geometry_counts()[f"tile-{L}"] == {
+        (L, L_pad, wa.geometry(L)): 1}
+    assert wa.geometry(L).tile == tile
     assert f"tile-{L}" not in wa.geometry_counts()
 
 

@@ -120,6 +120,14 @@ SAMBAY_GRID = (1, 8192)
 # key-value heads of 256 (rows of the cell's two grids) and its grad
 # program's packed grid, at the published widths.
 WIDE_HEAD_T = (14336, 8704)
+# ... and the GLM-4.7-Flash cell's latent attention at the kernel: 20 / 20
+# heads of 256 at the rows of its two grids. {case: (row, query heads,
+# key/value heads, the program's temporaries at most)} — q, k, v and
+# their gradients padded to the blocks and the outputs' layouts: 0.24-0.34
+# GB, 0.90 GB where 13,440 pads to 14,336 at 20 heads
+WIDE_HEADS = {**{f"causal-256-{T}": (T, 16, 2, 0.6e9) for T in WIDE_HEAD_T},
+              "causal-256-glm-14336": (14336, 20, 20, 0.6e9),
+              "causal-256-glm-13440": (13440, 20, 20, 1.0e9)}
 QNEXT_GRID = (1, 16384)
 # ... and its gated delta rule alone, forward + backward: (rows, length,
 # key heads, value heads, head size, chunk), bfloat16 as the cell times it
@@ -540,8 +548,9 @@ def _compile_all():
     out["sambay-cell"]["scans_traced"] = sum(
         ssmmod.s6_geometry_counts().values()) - scans
 
-    # The causal kernel at heads of 256: forward, dKV and dQ.
-    for T in WIDE_HEAD_T:
+    # The causal kernel at heads of 256, forward and backward, at the
+    # blocks the wide-head table picks for the shape.
+    for name, (T, hq, hkv, _) in WIDE_HEADS.items():
         def wide_loss(q, k, v, seg):
             o = wa.window_attention(q, k, v, seg, seg)
             return jnp.sum(o.astype(jnp.float32) ** 2)
@@ -549,15 +558,19 @@ def _compile_all():
         def wide(*shape, dtype=jnp.bfloat16):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-        compiled = jax.jit(
-            jax.value_and_grad(wide_loss, argnums=(0, 1, 2))).lower(
-                wide(1, T, 16, 256), wide(1, T, 2, 256), wide(1, T, 2, 256),
-                wide(1, T, dtype=jnp.int32)).compile()
-        record(f"causal-256-{T}", compiled)
-        out[f"causal-256-{T}"].update(
+        with attn_ops.dispatch_label(name):
+            compiled = jax.jit(
+                jax.value_and_grad(wide_loss, argnums=(0, 1, 2))).lower(
+                    wide(1, T, hq, 256), wide(1, T, hkv, 256),
+                    wide(1, T, hkv, 256),
+                    wide(1, T, dtype=jnp.int32)).compile()
+        record(name, compiled)
+        # the blocks the traced call ran, by the kernel's own counter
+        (_, _, blocks), = wa.causal_geometry_counts()[name]
+        out[name].update(
             splash_kernels=sorted(
                 k for k in splash_names if k in compiled.as_text()),
-            tile=wa.pick_tile(T, None, 256))
+            blocks=blocks.label())
 
     # The Qwen3-Next cell's cut (configs/qwen3-next-80b-a3b.json): the
     # whole model's forward + backward on a 1 x 16,384 row under full remat.
@@ -965,17 +978,24 @@ def test_the_ssd_scan_kernels_compile_for_v5e(compiled, name):
     assert got["temp_bytes"] < R * T * H * Q * 4
 
 
-@pytest.mark.parametrize("T", WIDE_HEAD_T)
-def test_the_causal_kernel_compiles_at_heads_of_256(compiled, T):
-    """Qwen3-Next's attention block: 16 query / 2 key-value heads of 256.
-    Tile 512 and the two backward kernels, not the fused one: at tile 1024
-    the backward asks for more than the chip's scoped VMEM, and the fused
-    kernel's dQ partial sums (one a key block) for gigabytes."""
-    got = compiled[f"causal-256-{T}"]
-    assert got["tile"] == 512
+@pytest.mark.parametrize("name", sorted(WIDE_HEADS))
+def test_the_causal_kernel_compiles_at_heads_of_256(compiled, name):
+    """Qwen3-Next's attention block (16 query / 2 key-value heads of 256)
+    and GLM-4.7-Flash's latent attention as the kernel sees it (20 / 20),
+    at the rows of their cells: what the chip's compiler took — by the
+    kernel's own count of the call it traced — is the wide-head table's
+    entry for the shape, forward, dKV and dQ kernels (a geometry that asks
+    for more than the chip's 16 MB of scoped VMEM fails the child); the
+    fused kernel's dQ partial sums (one a key block) would be gigabytes of
+    temporaries."""
+    from areal_tpu.ops.pallas import window_attention as wa
+
+    _, hq, hkv, temp_bound = WIDE_HEADS[name]
+    got = compiled[name]
+    assert got["blocks"] == wa.WIDE_BLOCKS[256, hq // hkv].label()
     assert got["splash_kernels"] == ["splash_mqa_dkv", "splash_mqa_dq",
                                      "splash_mqa_fwd"]
-    assert got["temp_bytes"] < 0.6e9
+    assert got["temp_bytes"] < temp_bound
 
 
 @pytest.mark.parametrize("T", GDN_RULE_T)

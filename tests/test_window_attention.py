@@ -173,10 +173,12 @@ def test_pick_tile_rule_without_a_window(n):
     if n in CAUSAL_GEOMETRY:
         assert (tile, n_pad) == CAUSAL_GEOMETRY[n]
     # the blocks are those the table was measured at
-    sizes = wa._block_sizes(tile, None)
+    blocks = wa.geometry(n)
+    sizes = blocks.sizes()
+    assert blocks.tile == tile
     assert sizes.use_fused_bwd_kernel and sizes.block_q == sizes.block_kv
     assert sizes.block_kv_compute == (512 if tile == 1024 else tile)
-    assert not wa._block_sizes(tile, 1024).use_fused_bwd_kernel
+    assert not wa.geometry(n, 1024).sizes().use_fused_bwd_kernel
 
 
 def test_the_shipped_blocks_of_a_long_row(monkeypatch):
@@ -243,15 +245,18 @@ def test_geometry_counts_under_the_active_label(monkeypatch):
 
 
 def test_causal_calls_have_a_geometry_count_of_their_own(monkeypatch):
-    """A full-causal call is counted by (length, padded length, tile) —
-    and NOT among the windowed calls, which readers take for a sliding
-    layer's."""
+    """A full-causal call is counted by (length, padded length, the
+    blocks that ran) — and NOT among the windowed calls, which readers
+    take for a sliding layer's."""
     monkeypatch.setattr(wa, "CAUSAL_TILE_COST", {256: 1.0})
     q, k, v, seg, _, _, _ = make("causal_padded_row")
     with attention.dispatch_label("test-causal"):
         jax.eval_shape(lambda *a: wa.window_attention(
             *a, seg, seg, interpret=True), q, k, v)
-    assert wa.causal_geometry_counts()["test-causal"] == {(384, 512, 256): 1}
+    blocks = wa.Blocks((256, 256, 256), (256, 256, 256), None)
+    assert wa.causal_geometry_counts()["test-causal"] == {
+        (384, 512, blocks): 1}
+    assert blocks.label() == "f256x256x256.kv256x256x256.fused"
     assert "test-causal" not in wa.geometry_counts()
 
 
@@ -438,6 +443,117 @@ def test_skipped_blocks_change_nothing(monkeypatch, layout, window, heads):
         assert float(jnp.abs(g)[~real].max(initial=0.0)) == 0.0, name
 
 
+# Every KIND of geometry the wide-head table picks (window_attention.
+# WIDE_BLOCKS, heads of 256) or its measurement timed
+# (tools/window_tile_sweep.py --fused 1), at toy tiles: (blocks, query
+# heads, key/value heads) — groups of one (GLM's latent attention) and of
+# eight (Qwen3-Next).
+WIDE_KINDS = {
+    # a query block of two key blocks, each kernel a shape of its own
+    "nonsquare_pair_g1": (wa.Blocks(
+        (256, 128, 128), (128, 256, 128), (256, 128)), 2, 2),
+    # ... and a dQ key block of two query blocks
+    "nonsquare_pair_g8": (wa.Blocks(
+        (256, 128, 128), (128, 128, 128), (128, 256)), 8, 1),
+    # the key block fetched computed half at a time
+    "subblocked_pair_g1": (wa.Blocks(
+        (256, 256, 128), (256, 256, 128), (256, 256)), 2, 2),
+    "subblocked_pair_g8": (wa.Blocks(
+        (128, 256, 128), (128, 256, 128), (128, 128)), 8, 1),
+    # the fused backward, dQ a partial sum a key block, added up outside:
+    # no entry has it (its partial sums do not fit the cells' rows), the
+    # sweep timed it through these same Blocks
+    "fused_g1": (wa.Blocks((256, 256, 128), (256, 128, 128), None), 4, 4),
+    "fused_g8": (wa.Blocks((256, 256, 128), (128, 256, 128), None), 16, 2),
+    # today's fallback: square, computed whole, dKV and dQ kernels
+    "square_pair_g1": (wa._square(128), 2, 2),
+}
+
+
+# (row, head_dim, group, window) -> what the call runs: the entry
+# (head_dim, group) of WIDE_BLOCKS, or the fallback's square tile
+WIDE_PICKS = {
+    "glm_14336": ((14336, 256, 1, None), (256, 1)),
+    "glm_13440": ((13440, 256, 1, None), (256, 1)),
+    "qnext_14336": ((14336, 256, 8, None), (256, 8)),
+    "qnext_8704": ((8704, 256, 8, None), (256, 8)),
+    "glm_4096": ((4096, 256, 1, None), (256, 1)),
+    # unmeasured: another group, another head size, a short row, a
+    # window — the square pair
+    "group_2": ((8192, 256, 2, None), 512),
+    "group_4_of_192": ((8192, 192, 4, None), 512),
+    "group_16": ((8192, 256, 16, None), 512),
+    "short_row": ((1920, 256, 1, None), 512),
+    "heads_of_512": ((8192, 512, 1, None), 512),
+    "windowed": ((8192, 256, 8, 1024), 512),
+}
+
+
+@pytest.mark.parametrize("name", sorted(WIDE_PICKS))
+def test_the_wide_head_table_is_picked_by_what_the_call_sees(name):
+    """Heads wider than the lanes: the entry measured at exactly this
+    head size and group — the dKV + dQ pair — and the square pair of PR 52
+    where nothing was measured; the padded length the engine's remat plan
+    reads is the pick's."""
+    (n, head_dim, group, window), want = WIDE_PICKS[name]
+    got = wa.geometry(n, window, head_dim, group)
+    assert got == (wa._square(want) if isinstance(want, int)
+                   else wa.WIDE_BLOCKS[want])
+    assert got.dq is not None
+    got.sizes()  # the blocks are ones the kernels take
+    assert wa.padded_len(n, window, head_dim, group) == -(-n // got.tile) * (
+        got.tile)
+    assert attention.kernel_padded_len(
+        "pallas", n, window, head_dim, group) == wa.padded_len(
+            n, window, head_dim, group)
+
+
+def test_a_backward_query_block_lies_inside_a_forward_one():
+    """What the forward leaves of a query block of nothing but padding
+    (-inf) is read by no backward block that runs: no geometry may give a
+    backward kernel a query block that spans two of the forward's."""
+    with pytest.raises(AssertionError):
+        wa.Blocks((128, 256, 128), (256, 256, 128), None).sizes()
+    with pytest.raises(AssertionError):
+        wa.Blocks((512, 512, 512), (512, 512, 512), (1024, 512)).sizes()
+
+
+@pytest.mark.parametrize("kind", sorted(WIDE_KINDS))
+def test_wide_heads_at_every_kind_of_geometry(monkeypatch, kind):
+    """Heads of 256 over a 512-token row of two documents and padding:
+    outputs, dQ, dK and dV are the reference's — at the tolerance of the
+    narrow heads' fused cases — finite, zero in padding rows, and EQUAL
+    bit for bit to what the same blocks give under the static schedule
+    (non-square blocks narrow by ``blocks_needed`` at their own shape)."""
+    blocks, Hq, Hkv = WIDE_KINDS[kind]
+    monkeypatch.setattr(wa, "_wide_blocks", lambda *a: blocks)
+    T, D = 512, 256
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q, k, v, w = (jax.random.normal(kk, (1, T, h, D), jnp.float32)
+                  for kk, h in zip(ks, (Hq, Hkv, Hkv, Hq)))
+    seg = jnp.asarray(_row(T, [150, 210])[None])
+    mask = segment_mask(seg, seg, causal=True)
+    want = _out_and_grads(
+        lambda *a: attention_reference(*a, mask), q, k, v, w)
+
+    def kernel(*a):
+        return wa.window_attention(*a, seg, seg, interpret=True)
+
+    with attention.dispatch_label(f"wide-{kind}"):
+        got = _out_and_grads(kernel, q, k, v, w)
+    assert wa.causal_geometry_counts()[f"wide-{kind}"] == {
+        (T, T, blocks): 1}
+    monkeypatch.setattr(wa, "_narrowed",
+                        lambda seg, *geometry: wa._kernel(*geometry)[0])
+    static = _out_and_grads(kernel, q, k, v, w)
+    real = np.asarray(seg > 0)
+    for g, r, s, name in zip(got, want, static, ("out", "dq", "dk", "dv")):
+        assert bool(jnp.isfinite(g).all()), name
+        np.testing.assert_allclose(g, r, atol=5e-5, rtol=5e-5, err_msg=name)
+        np.testing.assert_array_equal(g, s, err_msg=name)
+        assert float(jnp.abs(g)[~real].max(initial=0.0)) == 0.0, name
+
+
 @pytest.mark.parametrize("layout", ["five_docs", "whole_pad_blocks"])
 def test_skipped_blocks_under_a_value_wider_than_q_and_k(monkeypatch,
                                                          layout):
@@ -504,6 +620,33 @@ def test_blocks_needed_leaves_out_no_pair_the_mask_allows(seed):
     np.testing.assert_array_equal(np.asarray(traced), needed)
 
 
+@pytest.mark.parametrize("seed", range(12))
+def test_blocks_needed_with_key_blocks_of_their_own(seed):
+    """Non-square blocks under a causal mask, on random contiguous
+    layouts: the needed blocks are EXACTLY those that hold a (query, key)
+    pair ``segment_mask`` allows, each one of the static mask's, and a
+    traced row gives what a numpy row gives."""
+    rng = np.random.default_rng(100 + seed)
+    block_q, block_kv = [(16, 8), (8, 16), (32, 8), (8, 32)][seed % 4]
+    big = max(block_q, block_kv)
+    n = int(rng.integers(2, 8))
+    seg = _random_row(rng, n, big)
+    nq, nk = n * big // block_q, n * big // block_kv
+    needed = wa.blocks_needed(seg, block_q, None, block_kv)
+    mask = np.asarray(segment_mask(seg[None], seg[None], causal=True))[0, 0]
+    allowed = mask.reshape(nq, block_q, nk, block_kv).any(axis=(1, 3))
+    assert needed.shape == (nq, nk) and needed.dtype == bool
+    np.testing.assert_array_equal(needed, allowed)
+    static = np.tril(np.ones((n * big,) * 2, bool)).reshape(
+        nq, block_q, nk, block_kv).any(axis=(1, 3))
+    assert not (needed & ~static).any()
+    assert wa.blocks_visited(n * big, block_q, None, block_kv) == (
+        int(static.sum()),) * 2
+    traced = jax.jit(
+        lambda s: wa.blocks_needed(s, block_q, None, block_kv))(seg)
+    np.testing.assert_array_equal(np.asarray(traced), needed)
+
+
 # (row length, documents, window): blocks needed / the static mask's at
 # the tile and padded length the kernel runs (ISSUE 46's hand counts)
 HAND_COUNTS = {
@@ -553,7 +696,8 @@ def test_the_narrowed_schedule_runs_the_needed_blocks_and_no_other(
     tile, n = 128, T // 128
     seg = _row(T, docs[0])
     needed = wa.blocks_needed(seg, tile, window)
-    geometry = (T, window, 1, wa._block_sizes(tile, window), True)
+    geometry = (T, window, 1,
+                wa._square(tile, fused=window is None).sizes(), True)
     static, _ = wa._kernel(*geometry)
     narrow = wa._narrowed(jnp.asarray(seg), *geometry)
     infos = [(static.fwd_mask_info, narrow.fwd_mask_info, False),

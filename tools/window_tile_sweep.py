@@ -1,6 +1,8 @@
 """Time the grouped-head attention wrapper alone, a tile at a time — the
-measurement behind ``ops/pallas/window_attention.TILE_COST`` (windowed)
-and ``CAUSAL_TILE_COST`` (``--window 0``: full causal).
+measurement behind ``ops/pallas/window_attention.TILE_COST`` (windowed),
+``CAUSAL_TILE_COST`` (``--window 0``: full causal) and, with ``--window 0
+--head-dim 256 --device``, the wide-head table ``WIDE_BLOCKS`` (heads of
+256 at the GLM cell's 20 / 20 and the Qwen3-Next cell's 16 / 2 heads).
 
     python tools/window_tile_sweep.py            # on the chip
     python tools/window_tile_sweep.py --compile  # here, for a described v5e
@@ -11,17 +13,26 @@ of ``--documents`` is the lengths of a row's documents, comma-separated
 (``1876,288,511``; what is left of the row is padding; default: the row is
 one document): the kernel skips the key blocks a query block's documents
 do not reach, so a cell's table is measured at its layout.
+``--shapes LENGTH:DOCUMENTS ...`` gives each length its own layout in
+place of the product of the two.
 Per block shape of ``--blocks`` (``Q``, ``QxKV`` or ``QxKVxCOMPUTE``: the
-query block, the key block fetched, the key block computed at a time) and
-per backward of ``--fused`` (0 = dKV and dQ kernels, 1 = the one fused
-kernel): the forward alone (what an inference pass and a recomputation
-run) and forward + backward (the residual-saving forward and the
-backward), through the wrapper — its layout glue included — host clock
-around ``block_until_ready`` over ``--iters`` calls; then c = (2 forward
-+ 1 forward-and-backward) / (rows x query tokens x visited key tokens at
-the padded length), in ns — visited by the STATIC mask
+query block, the key block fetched, the key block computed at a time; or
+``FWD/DKV/DQ``, a shape a kernel, the last one ignored under a fused
+backward) and per backward of ``--fused`` (0 = dKV and dQ kernels, 1 =
+the one fused kernel): the forward alone (what an inference pass and a
+recomputation run) and forward + backward (the residual-saving forward
+and the backward), through the wrapper — its layout glue included —
+host clock around ``block_until_ready`` over ``--iters`` calls; then c =
+(2 forward + 1 forward-and-backward) / (rows x query tokens x visited key
+tokens at the padded length), in ns — visited by the STATIC mask
 (``blocks_visited``); ``needed`` is what the layout leaves of them
-(``blocks_needed``).
+(``blocks_needed``). ``--device`` adds DEVICE ms a call from a profiler
+capture, the whole call's and each ``splash_mqa_*`` kernel's own
+(``fwd_device`` of the forward alone, ``fwd_bwd_device`` of forward +
+backward): the three kernels' blocks are separate fields, so each
+kernel's best shape is read from its own op. ``--compile`` also prints the
+forward + backward program's temporary bytes (``temp_bytes``: the fused
+kernel's dQ partial sums live there).
 Prints one JSON line per shape and writes them to
 ``chiprun_out/<--out>.jsonl``.
 """
@@ -29,8 +40,9 @@ Prints one JSON line per shape and writes them to
 from __future__ import annotations
 
 import argparse
+import functools
+import itertools
 import json
-import math
 import os
 import sys
 import time
@@ -41,10 +53,6 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.experimental.pallas.ops.tpu.splash_attention import (  # noqa: E402
-    splash_attention_kernel as splash,
-)
-
 from areal_tpu.ops.pallas import window_attention as wa  # noqa: E402
 
 
@@ -57,17 +65,28 @@ def timed(fn, args, iters):
     return (time.perf_counter() - t0) / iters
 
 
-def block_sizes(spec: str, fused: bool) -> splash.BlockSizes:
-    """``Q``, ``QxKV`` or ``QxKVxCOMPUTE`` for all three kernels."""
-    parts = [int(x) for x in spec.split("x")]
-    bq = parts[0]
-    bkv = parts[1] if len(parts) > 1 else bq
-    compute = parts[2] if len(parts) > 2 else bkv
-    dq = {} if fused else {"block_q_dq": bq, "block_kv_dq": bkv}
-    return splash.BlockSizes(
-        block_q=bq, block_kv=bkv, block_kv_compute=compute,
-        block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=compute,
-        use_fused_bwd_kernel=fused, **dq)
+def blocks_of(spec: str, fused: bool) -> "wa.Blocks":
+    """``Q``, ``QxKV`` or ``QxKVxCOMPUTE`` for all three kernels, or
+    ``FWD/DKV/DQ`` a kernel."""
+    def one(part):
+        parts = [int(x) for x in part.split("x")]
+        bq = parts[0]
+        bkv = parts[1] if len(parts) > 1 else bq
+        return bq, bkv, parts[2] if len(parts) > 2 else bkv
+
+    kernels = [one(part) for part in spec.split("/")]
+    fwd, dkv, dq = (kernels * 3)[:3] if len(kernels) == 1 else kernels
+    return wa.Blocks(fwd, dkv, None if fused else dq[:2])
+
+
+def kernels_ms(ops):
+    """{kernel: its own device ms a call} of a capture's ops."""
+    out = {}
+    for op, ms in ops.items():
+        if op.startswith("splash_mqa_"):
+            kind = op.split("_")[2]
+            out[kind] = out.get(kind, 0.0) + ms
+    return {k: round(v, 4) for k, v in out.items()}
 
 
 def main() -> int:
@@ -82,6 +101,11 @@ def main() -> int:
     ap.add_argument("--blocks", "--tiles", nargs="+",
                     default=["256", "512", "1024", "2048"])
     ap.add_argument("--fused", type=int, nargs="+", default=[0])
+    ap.add_argument("--shapes", nargs="+", default=None,
+                    help="LENGTH:DOCUMENTS pairs, in place of the product "
+                         "of --length and --documents")
+    ap.add_argument("--device", action="store_true",
+                    help="device ms from a profiler capture")
     ap.add_argument("--heads", type=int, nargs=2, default=[32, 4])
     ap.add_argument("--head-dim", type=int, default=128)
     ap.add_argument("--iters", type=int, default=20)
@@ -90,7 +114,6 @@ def main() -> int:
     args = ap.parse_args()
     hq, hkv = args.heads
     window = args.window or None
-    table = "CAUSAL_TILE_COST" if window is None else "TILE_COST"
     sharding = None
     if args.compile:
         from jax.experimental import topologies
@@ -99,9 +122,17 @@ def main() -> int:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
         sharding = SingleDeviceSharding(topo.devices[0])
-    lines = []
     R = args.rows
-    for L, layout in ((L, d) for L in args.length for d in args.documents):
+    out_path = f"chiprun_out/{args.out}.jsonl"
+    if not args.compile:
+        os.makedirs("chiprun_out", exist_ok=True)
+        open(out_path, "w").close()
+    shapes_of = ([(int(s.split(":")[0]), s.split(":")[1])
+                  for s in args.shapes] if args.shapes else
+                 [(L, d) for L in args.length for d in args.documents])
+    if args.device:
+        from ssd_scan_sweep import device_ms
+    for L, layout in shapes_of:
         docs = [L] if layout is None else [int(n) for n in layout.split(",")]
         row = np.repeat(np.arange(1, len(docs) + 1), docs)
         row = np.pad(row, (0, L - len(row))).astype(np.int32)
@@ -126,8 +157,10 @@ def main() -> int:
             try:
                 if args.compile:
                     for f in (fwd, both):
-                        f.lower(*shapes, seg_shape).compile()
+                        done = f.lower(*shapes, seg_shape).compile()
                     line["compiled"] = True
+                    line["temp_bytes"] = (
+                        done.memory_analysis().temp_size_in_bytes)
                 else:
                     t_f = timed(fwd, (q, k, v, seg), args.iters)
                     t_fb = timed(both, (q, k, v, seg), args.iters)
@@ -135,33 +168,36 @@ def main() -> int:
                     line.update(fwd_ms=t_f * 1e3, fwd_bwd_ms=t_fb * 1e3,
                                 step_ms=step * 1e3,
                                 c_ns=step * 1e9 / (R * visited_tokens))
+                    if args.device:
+                        for name, f in (("fwd", fwd), ("fwd_bwd", both)):
+                            ms, ops = device_ms(f, (q, k, v, seg),
+                                                min(args.iters, 5), top=64)
+                            line[f"{name}_device"] = {
+                                "ms": round(ms, 4), **kernels_ms(ops)}
             except Exception as e:  # a tile the compiler refuses
-                line["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+                # the head names the kernel, the tail the VMEM asked for
+                line["error"] = (f"{type(e).__name__}: {str(e)[:300]} ... "
+                                 f"{str(e)[-400:]}")
             print(json.dumps(line), flush=True)
-            lines.append(line)
+            if not args.compile:
+                with open(out_path, "a") as f:
+                    f.write(json.dumps(line) + "\n")
 
-        for spec in args.blocks:
-            for fused in args.fused:
-                sizes = block_sizes(spec, bool(fused))
-                # the row is padded to a multiple of both blocks, and the
-                # blocks visited are counted at the larger
-                tile = math.lcm(sizes.block_q, sizes.block_kv)
-                setattr(wa, table, {tile: 1.0})
-                wa._block_sizes = lambda t, w, d=None, sizes=sizes: sizes
-                n_pad = wa.padded_len(L, window)
-                visited, _ = wa.blocks_visited(n_pad, tile, window)
-                # (a tree from before the kernel skipped blocks has none)
-                needed = int(wa.blocks_needed(
-                    np.pad(row, (0, n_pad - L)), tile, window).sum()
-                ) if hasattr(wa, "blocks_needed") else visited
-                run("grouped", lambda q, k, v, s, s2: wa.window_attention(
-                    q, k, v, s, s2, window=window), visited * tile * tile,
-                    blocks=spec, fused_bwd=fused, padded=n_pad,
-                    visited=visited, needed=needed)
-    if not args.compile:
-        os.makedirs("chiprun_out", exist_ok=True)
-        with open(f"chiprun_out/{args.out}.jsonl", "w") as f:
-            f.writelines(json.dumps(x) + "\n" for x in lines)
+        for spec, fused in itertools.product(args.blocks, args.fused):
+            blocks = blocks_of(spec, bool(fused))
+            # the row is padded to a multiple of every block, and the
+            # blocks visited are counted at the forward kernel's
+            wa.geometry = lambda *a, blocks=blocks, **kw: blocks
+            n_pad = wa.padded_len(L, window)
+            bq, bkv, _ = blocks.fwd
+            visited, _ = wa.blocks_visited(n_pad, bq, window, bkv)
+            needed = int(wa.blocks_needed(
+                np.pad(row, (0, n_pad - L)), bq, window, bkv).sum())
+            run("grouped",
+                functools.partial(wa.window_attention, window=window),
+                visited * bq * bkv, blocks=spec, fused_bwd=fused,
+                geometry=blocks.label(), padded=n_pad,
+                visited=visited, needed=needed)
     return 0
 
 
