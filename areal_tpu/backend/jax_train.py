@@ -986,6 +986,10 @@ class JaxTrainEngine(TrainableEngine):
                 telemetry.set_gauge(
                     "train/gdn_resets_in_chunk_per_row",
                     mbu.resets_in_chunk_per_row(mbs, self.cfg.gdn.chunk_size))
+            if self.cfg.kda is not None:
+                telemetry.set_gauge(
+                    "train/kda_resets_in_chunk_per_row",
+                    mbu.resets_in_chunk_per_row(mbs, self.cfg.kda.chunk_size))
             if self.cfg.shortconv is not None:
                 telemetry.set_gauge("train/shortconv_resets_per_row",
                                     mbu.shortconv_resets_per_row(mbs))
@@ -1184,6 +1188,15 @@ class JaxTrainEngine(TrainableEngine):
             if frac is not None:
                 telemetry.set_gauge("train/ssd_kernel_frac", frac)
                 span_attrs["ssd_kernel_frac"] = frac
+        if self.cfg.kda is not None:
+            from areal_tpu.models import kda as kdamod
+
+            # the share of the traced channel-decay rules that run the
+            # kernel pair (models/kda.rule_impl_counts)
+            frac = kdamod.rule_kernel_frac()
+            if frac is not None:
+                telemetry.set_gauge("train/kda_kernel_frac", frac)
+                span_attrs["kda_kernel_frac"] = frac
         if self.cfg.gdn is not None:
             from areal_tpu.models import gdn as gdnmod
 

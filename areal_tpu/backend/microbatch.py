@@ -211,7 +211,8 @@ def resets_in_chunk_per_row(mbs: List[MicroBatch], chunk: int) -> float:
     the chunk grid, the row's first document not counted) over the rows
     that hold any document, of a micro-batch split: how often a chunk of
     the gated delta rule masks its triangular matrices and the state it
-    reads. Exported as the ``train/gdn_resets_in_chunk_per_row`` gauge."""
+    reads. Exported as the ``train/gdn_resets_in_chunk_per_row`` (and
+    ``train/kda_resets_in_chunk_per_row``) gauge."""
     from areal_tpu.models.gdn import resets_in_chunk
 
     inside = sum(resets_in_chunk((col for _, col in mb.layout.placements),

@@ -158,7 +158,8 @@ def model_flops_per_token(
         moe=getattr(cfg, "moe", None),
     )
     factor = 1.0 if not backward else 4.0 if remat else 3.0
-    flops += (_shortconv_flops(cfg, avg_seqlen) + _mla_flops(cfg)) * factor
+    flops += (_shortconv_flops(cfg, avg_seqlen) + _mla_flops(cfg)
+              + _kda_flops(cfg, avg_seqlen)) * factor
     gdn = getattr(cfg, "gdn", None)
     n_gdn = cfg.layer_kinds.count("gdn") if gdn is not None else 0
     if not n_gdn:
@@ -227,8 +228,30 @@ def _mla_flops(cfg) -> float:
 
     d = cfg.hidden_dim
     qkvo = 2 * d * (cfg.q_dim + 2 * cfg.kv_dim) + 2 * cfg.q_dim * d
-    return (cfg.n_layers * (flops_per_token(mla, d, cfg.n_q_heads) - qkvo)
+    n_attn = sum(cfg.attention_windows().values())
+    return (n_attn * (flops_per_token(mla, d, cfg.n_q_heads) - qkvo)
             + _dense_block_flops(cfg))
+
+
+def _kda_flops(cfg, avg_seqlen: float) -> float:
+    """What a model with Kimi Delta Attention blocks (``cfg.kda``) differs
+    by from the count above, a token's forward pass: each such block's
+    mixer — its matrices, and the rule in chunks of Q tokens a head as
+    :func:`model_flops_per_token` counts a Gated DeltaNet's — in place of
+    attention's. 0 for any other model."""
+    kda = getattr(cfg, "kda", None)
+    if kda is None:
+        return 0.0
+    from areal_tpu.models.config import KDA, attention_kind
+    from areal_tpu.models.kda import kda_param_count
+
+    d, Q, dh = cfg.hidden_dim, kda.chunk_size, kda.head_dim
+    attention = (2 * d * (cfg.q_dim + 2 * cfg.kv_dim) + 2 * cfg.q_dim * d
+                 + 2 * 2 * cfg.q_dim * avg_seqlen)
+    rule = kda.n_heads * 2 * (2 * Q * dh + (2 * math.log2(Q) - 1) * Q * Q
+                              + 2 * Q * dh + 3 * dh * dh + Q * dh)
+    n_kda = sum(attention_kind(k) == KDA for k in cfg.layer_kinds)
+    return n_kda * (2 * kda_param_count(kda, d) + rule - attention)
 
 
 class FlopsCounter:

@@ -304,12 +304,21 @@ LATENT_MOE_SCOPES = ("latent_down", "latent_up", "shared_expert")
 # shared expert, inside "shared_expert".
 GDN_SCOPES = ("gdn_in_proj", "gdn_conv", "gdn_gates", "gdn_rule",
               "gdn_gate_norm", "gdn_out_proj", "shared_expert_gate")
+# A Kimi Delta Attention mixer (kimi_linear; models/kda.py), one scope a
+# stage: both in-projections, the three convolutions with their SiLU, the
+# gates (beta, the decay a channel through its bottleneck, the l2 norms of
+# q and k), the rule (the kernels ``kda_rule_fwd`` / ``kda_rule_bwd``), the
+# gated output norm with its gate's expansion, and the out-projection; no
+# DEVICE_SCOPES name lies between them and "layer_scan".
+KDA_SCOPES = ("kda_in_proj", "kda_conv", "kda_gates", "kda_rule",
+              "kda_gate_norm", "kda_out_proj")
 # A doubly gated short convolution (lfm2_moe; models/shortconv.py): the
 # in-projection [B | C | x], both gates with the taps between them, the
 # out-projection; no DEVICE_SCOPES name lies between them and "layer_scan".
 SHORTCONV_SCOPES = ("shortconv_in_proj", "shortconv", "shortconv_out_proj")
-# Latent attention's projection path (glm4_moe_lite; models/mla.py), in
-# place of "qkv_proj" and "rope": both q matmuls with the latent's norm,
+# Latent attention's projection path (glm4_moe_lite, kimi_linear;
+# models/mla.py), in place of "qkv_proj" and "rope": both q matmuls with
+# the latent's norm (without a query latent the ONE full-rank product),
 # the k/v down-projection with its norm, the up-projection, and the
 # assembly (the narrow RoPE, the shared rotary key's broadcast, both
 # concatenates); "attention" and "o_proj" follow as for any block.

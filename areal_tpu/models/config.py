@@ -36,6 +36,12 @@ SSD = "ssd"
 # (models/gdn.py), then the model's FFN — the expert layer where it has
 # one (qwen3_next's ``linear_attention`` layers, beside FULL blocks).
 GDN = "gdn"
+# A whole block whose mixer is a Kimi Delta Attention mixer (models/kda.py:
+# the delta rule with a decay a key channel), then the model's FFN — its
+# dense MLP on the leading blocks (KDA + ``DENSE_SUFFIX``), the expert
+# layer after them (kimi_linear's ``kda_layers``, beside FULL blocks of
+# latent attention).
+KDA = "kda"
 # A whole block whose mixer is a doubly gated short convolution
 # (models/shortconv.py: no head, no state, no position), then the model's
 # FFN — its dense MLP on the leading blocks (CONV + ``DENSE_SUFFIX``), the
@@ -43,10 +49,10 @@ GDN = "gdn"
 CONV = "conv"
 # Whole blocks whose mixer is not self-attention: no K/V cache decodes
 # them, and their parameter shapes are their kind's own.
-BLOCK_MIXER_KINDS = SAMBAY_KINDS + (SSD, GDN, CONV)
+BLOCK_MIXER_KINDS = SAMBAY_KINDS + (SSD, GDN, KDA, CONV)
 # Of those, the blocks that run no attention at all: no q/k/v/o, no q/k
 # norm, no attention gate (a CROSS block still has q and o).
-ATTENTION_FREE_KINDS = (S6, GMU, SSD, GDN, CONV)
+ATTENTION_FREE_KINDS = (S6, GMU, SSD, GDN, KDA, CONV)
 # What a layer hands on to later layers, by the name the readers ask for.
 MEMORY, SHARED_KV = "memory", "kv"
 # A whole block's FFN kind (HF ``mlp_layer_types``): the model's dense MLP
@@ -209,6 +215,32 @@ class GDNConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class KDAConfig:
+    """A Kimi Delta Attention mixer's sizes (HF kimi_linear's
+    ``linear_attn_config``: ``num_heads``, ``head_dim`` — key and value
+    alike —, ``short_conv_kernel_size``). The decay's and the output
+    gate's projections go through a bottleneck of ``gate_rank`` (the
+    family's: one head's width); the rule runs in chunks of ``chunk_size``
+    tokens (the program's choice: no key of the model)."""
+
+    n_heads: int
+    head_dim: int
+    conv_kernel: int = 4
+    chunk_size: int = 64
+
+    @property
+    def gate_rank(self) -> int:
+        """The bottleneck of the decay's and the output gate's projections:
+        one head's width in this family (no key of the model sets it)."""
+        return self.head_dim
+
+    @property
+    def gates_a_dim(self) -> int:
+        """[b | f | z]: β a head, and the two gates' bottlenecks."""
+        return self.n_heads + 2 * self.gate_rank
+
+
+@dataclasses.dataclass(frozen=True)
 class ShortConvConfig:
     """A doubly gated short convolution's sizes (HF lfm2 / lfm2_moe key
     ``conv_L_cache``): ``kernel`` taps, depthwise over the model's hidden
@@ -319,10 +351,13 @@ class TransformerConfig:
     ssm: Optional[SSMConfig] = None  # the MAMBA layers' / SSD blocks' mixer
     s6: Optional[S6Config] = None  # the S6 blocks' mixer (GMU reads its width)
     gdn: Optional[GDNConfig] = None  # the GDN blocks' mixer
+    kda: Optional[KDAConfig] = None  # the KDA blocks' mixer
     shortconv: Optional[ShortConvConfig] = None  # the CONV blocks' mixer
-    # Latent attention in every attention block: q, k and v are not three
+    # Latent attention in every ATTENTION block (the blocks of a mixer
+    # kind beside them are their kind's): q, k and v are not three
     # projections of the hidden state (models/mla.py); ``head_dim`` is the
-    # query / key head's ``nope + rope``, ``n_kv_heads == n_q_heads``.
+    # query / key head's ``nope + rope``, ``n_kv_heads == n_q_heads``; a
+    # value head is ``mla.v_head_dim`` wide.
     mla: Optional[MLAConfig] = None
     # sliding window attention (mistral/gemma2); None = full attention
     sliding_window: Optional[int] = None
@@ -361,6 +396,11 @@ class TransformerConfig:
     # The published index of this model's layer 0 (a cut in depth that
     # starts inside the published stack).
     first_layer_index: int = 0
+    # The published (1-based) block numbers this model's layers are, of a
+    # cut in depth that is NOT one contiguous run (kimi_linear's key
+    # ``held_layers`` of this repo: block 1 and one whole period further
+    # in); None = layers ``first_layer_index`` on, in order.
+    held_layers: Optional[Tuple[int, ...]] = None
     # Sandwich norms (afmoe): a second norm on each branch's OUTPUT before
     # it is added to the residual stream (``ln1_post``, ``ln2_post``).
     sandwich_norm: bool = False
@@ -406,6 +446,14 @@ class TransformerConfig:
         return self.n_kv_heads * self.head_dim
 
     @property
+    def o_dim(self) -> int:
+        """The attention output's width, ``wo``'s rows: a value head a
+        query head (latent attention's may be narrower than the key)."""
+        if self.mla is not None:
+            return self.n_q_heads * self.mla.v_head_dim
+        return self.q_dim
+
+    @property
     def q_norm_dim(self) -> int:
         return self.q_dim if self.qk_norm_extent == "proj" else self.head_dim
 
@@ -419,6 +467,8 @@ class TransformerConfig:
         ``partial_rotary_factor`` of them, or latent attention's LAST
         ``qk_rope_head_dim`` (models/mla.py turns them itself)."""
         if self.mla is not None:
+            if self.pos_embedding != "rope":  # the shared key un-rotated
+                return 0
             return self.mla.qk_rope_head_dim
         return int(self.head_dim * self.partial_rotary_factor)
 

@@ -263,8 +263,19 @@ def gated_delta_rule(q: jnp.ndarray,  # [B, T, G, dk], l2-normed and scaled
     qe = (qv * enters[..., None]).astype(cd)
     kd = (kv * to_end[..., None]).astype(cd)
 
-    # ---- chunk to chunk: Δ = U − W S;  o = q̂ e^c S + P Δ;
-    #      S ← keeps · S + (k̂ e^{c_Q − c})ᵀ Δ
+    return _carry_states(U, W, qe, kd, P, keeps, T)
+
+
+def _carry_states(U, W, qe, kd, P, keeps, T: int) -> jnp.ndarray:
+    """Chunk to chunk: ``Δ = U − W S``; ``o = qe S + P Δ``; ``S ← keeps · S
+    + kdᵀ Δ`` — the blocks [B, Z, G, r, Q, .] a chunk, ``keeps`` a scalar
+    a chunk and head ([B, Z, G, r]) or one a key channel ([B, Z, G, r,
+    dk]). Returns o [B, T, H, dv] float32."""
+    B_, Z, G, r, Q, dv = U.shape
+    dk = W.shape[-1]
+    cd, f32 = W.dtype, jnp.float32
+    by_channel = keeps.ndim == 5
+
     def step(S, xs):  # S [B, G, r, dk, dv] float32
         U_z, W_z, qe_z, kd_z, P_z, keeps_z = xs
         Sc = S.astype(cd)
@@ -275,7 +286,8 @@ def gated_delta_rule(q: jnp.ndarray,  # [B, T, G, dk], l2-normed and scaled
                         preferred_element_type=f32)
              + jnp.einsum("bgrij,bgrjv->bgriv", P_z, dc,
                           preferred_element_type=f32))
-        S = keeps_z[..., None, None] * S + jnp.einsum(
+        kept = keeps_z[..., None] if by_channel else keeps_z[..., None, None]
+        S = kept * S + jnp.einsum(
             "bgrik,bgriv->bgrkv", kd_z, dc, preferred_element_type=f32)
         return S, o
 
@@ -283,7 +295,7 @@ def gated_delta_rule(q: jnp.ndarray,  # [B, T, G, dk], l2-normed and scaled
         step, jnp.zeros((B_, G, r, dk, dv), f32),
         tuple(jnp.moveaxis(a, 1, 0) for a in (U, W, qe, kd, P, keeps)))
     # [Z, B, G, r, Q, dv] -> [B, T, H, dv]
-    o = jnp.moveaxis(o, (0, 4), (1, 2)).reshape(B_, Z * Q, H, dv)
+    o = jnp.moveaxis(o, (0, 4), (1, 2)).reshape(B_, Z * Q, G * r, dv)
     return o[:, :T]
 
 

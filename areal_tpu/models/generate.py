@@ -41,6 +41,8 @@ def decode_refusal(cfg: TransformerConfig) -> Optional[str]:
     a layer that reads another layer's memory or K/V has no cache to
     decode from; a Gated DeltaNet block its own name (its state is a
     recurrent matrix and its convolution's last taps: ``gdn.DECODE_REFUSAL``),
+    a Kimi Delta Attention block likewise (``kda.DECODE_REFUSAL``,
+    ``channel_decay_rule_decode_state``),
     a short-convolution block likewise (the last ``conv_L_cache - 1`` tokens
     of ``B ⊙ x`` beside the attention blocks' K/V:
     ``shortconv.DECODE_REFUSAL``), latent attention likewise (a cache of

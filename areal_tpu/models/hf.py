@@ -5,7 +5,7 @@ Parity target: the reference's per-family converter registry
 ``realhf/api/from_hf/{llama,qwen2,qwen3,gemma,gpt2,mistral,mixtral}.py``).
 Families covered: llama, qwen2 (qwen2.5), qwen3, mistral, gemma, gpt2,
 mixtral, qwen3_moe, olmoe, mellum, nemotron_h, afmoe, phi4flash,
-granitemoehybrid, qwen3_next, lfm2_moe, glm4_moe_lite.
+granitemoehybrid, qwen3_next, lfm2_moe, glm4_moe_lite, kimi_linear.
 
 Weights are stacked on a leading layer axis (see models/transformer.py), so
 conversion transposes HF's ``[out, in]`` linear layout to ``[in, out]`` and
@@ -21,7 +21,7 @@ import dataclasses
 import json
 import os
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -34,6 +34,7 @@ from areal_tpu.models.config import (
     FULL,
     GDN,
     GMU,
+    KDA,
     MAMBA,
     MOE_ONLY,
     S6,
@@ -41,6 +42,7 @@ from areal_tpu.models.config import (
     SPARSE_FFN,
     SSD,
     GDNConfig,
+    KDAConfig,
     MLAConfig,
     MoEConfig,
     RopeConfig,
@@ -757,6 +759,19 @@ GLM4_MOE_LITE_REFUSALS = (
 )
 
 
+def _mla_config(hf_config: Any) -> MLAConfig:
+    """Latent attention's five sizes from the keys deepseek_v3,
+    glm4_moe_lite and kimi_linear share; ``q_lora_rank`` null = no query
+    latent."""
+    rank = getattr(hf_config, "q_lora_rank", None)
+    return MLAConfig(
+        q_lora_rank=None if rank is None else int(rank),
+        kv_lora_rank=int(hf_config.kv_lora_rank),
+        qk_nope_head_dim=int(hf_config.qk_nope_head_dim),
+        qk_rope_head_dim=int(hf_config.qk_rope_head_dim),
+        v_head_dim=int(hf_config.v_head_dim))
+
+
 @register_hf_family("glm4_moe_lite")
 def _glm4_moe_lite_config(hf_config: Any) -> TransformerConfig:
     """GLM-4.7-Flash (zai-org, ``Glm4MoeLiteForCausalLM``; deepseek_v3's
@@ -780,20 +795,7 @@ def _glm4_moe_lite_config(hf_config: Any) -> TransformerConfig:
     for key, run, why in GLM4_MOE_LITE_REFUSALS:
         if getattr(hf_config, key, None) not in run:
             raise NotImplementedError(why)
-    if getattr(hf_config, "q_lora_rank", None) is None:
-        from areal_tpu.models.mla import Q_LATENT_REFUSAL
-
-        raise NotImplementedError(Q_LATENT_REFUSAL)
-    mla = MLAConfig(
-        q_lora_rank=int(hf_config.q_lora_rank),
-        kv_lora_rank=int(hf_config.kv_lora_rank),
-        qk_nope_head_dim=int(hf_config.qk_nope_head_dim),
-        qk_rope_head_dim=int(hf_config.qk_rope_head_dim),
-        v_head_dim=int(hf_config.v_head_dim))
-    if mla.v_head_dim != mla.qk_head_dim:
-        from areal_tpu.models.mla import VALUE_WIDTH_REFUSAL
-
-        raise NotImplementedError(VALUE_WIDTH_REFUSAL)
+    mla = _mla_config(hf_config)
     kw = _base_kwargs(hf_config)
     heads = hf_config.num_attention_heads
     if kw["n_kv_heads"] != heads:
@@ -834,6 +836,111 @@ def _glm4_moe_lite_config(hf_config: Any) -> TransformerConfig:
         n_nextn_predict_layers=int(
             getattr(hf_config, "num_nextn_predict_layers", 0) or 0),
         hf_family="glm4_moe_lite",
+    )
+
+
+# kimi_linear: keys of the family that no block here runs, by name: (key,
+# the values that are run, why any other is refused).
+KIMI_LINEAR_REFUSALS = (
+    ("num_expert_group", (None, 1), "num_expert_group: group-limited "
+     "routing"),
+    ("topk_group", (None, 1), "topk_group: group-limited routing"),
+    ("moe_router_activation_func", (None, "sigmoid"),
+     "moe_router_activation_func: a router score other than the sigmoid"),
+    ("moe_layer_freq", (None, 1), "moe_layer_freq: an expert layer every "
+     "n-th block only"),
+    ("mla_use_nope", (True,), "mla_use_nope false: a rotated latent "
+     "attention in this family"),
+    ("rope_scaling", (None,), "rope_scaling: a scaled RoPE"),
+    ("hidden_act", (None, "silu"), "hidden_act: experts that are not SwiGLU"),
+)
+
+
+def _kimi_held_layers(hf_config: Any, n: int) -> Tuple[int, ...]:
+    """The published (1-based) block numbers of the model's ``n`` layers:
+    the first ``n`` of this repo's key ``held_layers``, else 1 .. n."""
+    held = tuple(int(i) for i in (getattr(hf_config, "held_layers", None)
+                                  or range(1, n + 1)))[:n]
+    if len(held) != n or list(held) != sorted(set(held)) or held[0] < 1:
+        raise ValueError(f"held_layers {held!r} for {n} layers")
+    return held
+
+
+@register_hf_family("kimi_linear")
+def _kimi_linear_config(hf_config: Any) -> TransformerConfig:
+    """Kimi-Linear (moonshotai, ``KimiLinearForCausalLM``): whole pre-norm
+    blocks under plain RMSNorms. By ``linear_attn_config`` (1-based block
+    numbers) a block mixes with Kimi Delta Attention (``kda_layers``:
+    models/kda.py, ``num_heads`` heads of ``head_dim``, convolutions of
+    ``short_conv_kernel_size`` taps) or with latent attention
+    (``full_attn_layers``: models/mla.py WITHOUT a query latent —
+    ``q_lora_rank`` null —, without any position embedding —
+    ``mla_use_nope`` —, and with ``v_head_dim`` narrower than the key's
+    ``qk_nope_head_dim + qk_rope_head_dim``); a dense SwiGLU of
+    ``intermediate_size`` on the first ``first_k_dense_replace`` blocks, on
+    the others ``num_experts`` routed experts of ``moe_intermediate_size``
+    (sigmoid scores, the choice by score + ``e_score_correction_bias``,
+    the chosen scores renormalised where ``moe_renormalize``, times
+    ``routed_scaling_factor``) beside ``num_shared_experts`` shared ones,
+    ungated and unscaled; an untied head. A SHARE holds ``num_experts`` of
+    ``num_routed_experts`` (:func:`_expert_share`); a cut in depth holds
+    the published blocks ``held_layers`` (this repo's key)."""
+    for key, run, why in KIMI_LINEAR_REFUSALS:
+        if getattr(hf_config, key, None) not in run:
+            raise NotImplementedError(why)
+    mla = _mla_config(hf_config)
+    kw = _base_kwargs(hf_config)
+    heads = hf_config.num_attention_heads
+    if kw["n_kv_heads"] != heads:
+        raise NotImplementedError(
+            "num_key_value_heads != num_attention_heads under latent "
+            "attention (the up-projection makes a key a query head)")
+    kw["head_dim"] = mla.qk_head_dim
+    n = kw["n_layers"]
+    lin = hf_config.linear_attn_config
+    kda_at, full_at = set(lin["kda_layers"]), set(lin["full_attn_layers"])
+    held = _kimi_held_layers(hf_config, n)
+    if any(i not in kda_at | full_at or i in kda_at & full_at for i in held):
+        raise ValueError(
+            f"blocks {held!r} are not each one of kda_layers / "
+            "full_attn_layers")
+    dense = int(getattr(hf_config, "first_k_dense_replace", 0) or 0)
+    n_held = hf_config.num_experts
+    routed, first = _expert_share(hf_config, n_held)
+    shared = (getattr(hf_config, "num_shared_experts", 0) or 0
+              ) * hf_config.moe_intermediate_size
+    return TransformerConfig(
+        **kw,
+        mla=mla,
+        pos_embedding="none",
+        kda=KDAConfig(
+            n_heads=int(lin["num_heads"]), head_dim=int(lin["head_dim"]),
+            conv_kernel=int(lin.get("short_conv_kernel_size", 4))),
+        layer_types=tuple(KDA if i in kda_at else FULL for i in held),
+        mlp_layer_types=tuple(
+            DENSE_FFN if i <= dense else SPARSE_FFN for i in held)
+        if any(i <= dense for i in held) else None,
+        held_layers=held if held != tuple(range(1, n + 1)) else None,
+        max_position_embeddings=getattr(hf_config, "model_max_length", None),
+        moe=MoEConfig(
+            num_experts=n_held,
+            top_k=hf_config.num_experts_per_token,
+            capacity_factor=None,
+            routed_intermediate_dim=hf_config.moe_intermediate_size,
+            shared_intermediate_dim=shared or None,
+            aux_loss_coeff=0.0,
+            norm_topk_prob=bool(getattr(hf_config, "moe_renormalize", True)),
+            router_experts=routed,
+            first_expert=first,
+            router_score="sigmoid",
+            routed_scaling_factor=float(
+                getattr(hf_config, "routed_scaling_factor", 1.0)),
+            router_bias_init_std=float(
+                getattr(hf_config, "expert_bias_init_std", 0.02)),
+        ),
+        n_nextn_predict_layers=int(
+            getattr(hf_config, "num_nextn_predict_layers", 0) or 0),
+        hf_family="kimi_linear",
     )
 
 
@@ -1666,6 +1773,124 @@ _GLM4_MOE_LITE_NAMES = [
 ]
 
 
+# kimi_linear: (pytree key, HF name under ``model.layers.{i}.``, transpose)
+# — the names AS RECALLED (benchmark/configs/kimi-linear-48b-a3b.json,
+# ``assumed``). A block holds the leaves of its kind; the KDA mixer's
+# three projections, three depthwise convolutions (``[channels, 1, K]``)
+# and three narrow projections are ONE matrix each here and are split and
+# joined below; ``A_log`` is HF's ``[1, 1, H, 1]``.
+_KIMI_LINEAR_NAMES = [
+    ("ln1", "input_layernorm.weight", False),
+    ("ln2", "post_attention_layernorm.weight", False),
+    ("kda_f_b", "self_attn.f_b_proj.weight", True),
+    ("kda_g_b", "self_attn.g_b_proj.weight", True),
+    ("kda_dt_bias", "self_attn.dt_bias", False),
+    ("kda_norm", "self_attn.o_norm.weight", False),
+    ("kda_out", "self_attn.o_proj.weight", True),
+    ("wq", "self_attn.q_proj.weight", True),
+    ("wkv_a", "self_attn.kv_a_proj_with_mqa.weight", True),
+    ("kv_a_norm", "self_attn.kv_a_layernorm.weight", False),
+    ("wkv_b", "self_attn.kv_b_proj.weight", True),
+    ("wo", "self_attn.o_proj.weight", True),
+    ("w_gate", "mlp.gate_proj.weight", True),
+    ("w_up", "mlp.up_proj.weight", True),
+    ("w_down", "mlp.down_proj.weight", True),
+    ("router", "block_sparse_moe.gate.weight", True),
+    ("router_bias", "block_sparse_moe.gate.e_score_correction_bias", False),
+    ("s_gate", "block_sparse_moe.shared_experts.gate_proj.weight", True),
+    ("s_up", "block_sparse_moe.shared_experts.up_proj.weight", True),
+    ("s_down", "block_sparse_moe.shared_experts.down_proj.weight", True),
+]
+_KIMI_LINEAR_EXPERTS = {"e_gate": "block_sparse_moe.experts.{e}.w1.weight",
+                        "e_up": "block_sparse_moe.experts.{e}.w3.weight",
+                        "e_down": "block_sparse_moe.experts.{e}.w2.weight"}
+_KIMI_QKV = ("q", "k", "v")
+_KIMI_GATES_A = ("b_proj", "f_a_proj", "g_a_proj")
+
+
+def _kimi_layer_numbers(cfg: TransformerConfig) -> List[int]:
+    """HF's 0-based ``model.layers`` index of each layer."""
+    held = cfg.held_layers or range(1, cfg.n_layers + 1)
+    return [i - 1 for i in held]
+
+
+def _kimi_linear_to_sd(
+    params: Dict[str, Any], cfg: TransformerConfig
+) -> Dict[str, np.ndarray]:
+    sd = {
+        "model.embed_tokens.weight": np.asarray(params["embedding"]),
+        "model.norm.weight": np.asarray(params["final_ln"]),
+        "lm_head.weight": np.asarray(params["lm_head"]).T,
+    }
+    at = _kimi_layer_numbers(cfg)
+    for i, kind, lp in _layers_in_order(params, cfg):
+        pre = f"model.layers.{at[i]}."
+        for key, name, tr in _KIMI_LINEAR_NAMES:
+            if key in lp:
+                sd[pre + name] = lp[key].T if tr else lp[key]
+        for key, name in _KIMI_LINEAR_EXPERTS.items():
+            if key in lp:
+                for e in range(cfg.moe.num_experts):
+                    sd[pre + name.format(e=e)] = lp[key][e].T
+        if attention_kind(kind) != KDA:
+            continue
+        kda = cfg.kda
+        for x, w, c in zip(_KIMI_QKV, np.split(lp["kda_qkv"], 3, axis=1),
+                           np.split(lp["kda_conv"], 3, axis=1)):
+            sd[pre + f"self_attn.{x}_proj.weight"] = w.T
+            sd[pre + f"self_attn.{x}_conv1d.weight"] = c.T[:, None, :]
+        for name, w in zip(_KIMI_GATES_A, np.split(
+                lp["kda_gates_a"], [kda.n_heads, kda.n_heads + kda.gate_rank],
+                axis=1)):
+            sd[pre + f"self_attn.{name}.weight"] = w.T
+        sd[pre + "self_attn.A_log"] = lp["kda_A_log"].reshape(1, 1, -1, 1)
+    return sd
+
+
+def _kimi_linear_from_sd(
+    sd: Dict[str, Any], cfg: TransformerConfig, dtype: str
+) -> Dict[str, Any]:
+    per_kind: Dict[str, Dict[str, list]] = {}
+    for kind, at in zip(cfg.layer_kinds, _kimi_layer_numbers(cfg)):
+        pre = f"model.layers.{at}."
+        lp = per_kind.setdefault(kind, {})
+        is_kda = attention_kind(kind) == KDA
+        for key, name, tr in _KIMI_LINEAR_NAMES:
+            # ``self_attn.o_proj`` is a KDA block's ``kda_out`` and an
+            # attention block's ``wo``
+            if key.startswith("kda_") != is_kda and "self_attn." in name:
+                continue
+            if pre + name in sd:
+                w = _np(sd[pre + name])
+                lp.setdefault(key, []).append(w.T if tr else w)
+        for key, name in _KIMI_LINEAR_EXPERTS.items():
+            if pre + name.format(e=0) in sd:
+                lp.setdefault(key, []).append(np.stack([
+                    _np(sd[pre + name.format(e=e)]).T
+                    for e in range(cfg.moe.num_experts)]))
+        if not is_kda:
+            continue
+        lp.setdefault("kda_qkv", []).append(np.concatenate(
+            [_np(sd[pre + f"self_attn.{x}_proj.weight"]).T
+             for x in _KIMI_QKV], axis=1))
+        lp.setdefault("kda_conv", []).append(np.concatenate(
+            [_np(sd[pre + f"self_attn.{x}_conv1d.weight"])[:, 0, :].T
+             for x in _KIMI_QKV], axis=1))
+        lp.setdefault("kda_gates_a", []).append(np.concatenate(
+            [_np(sd[pre + f"self_attn.{name}.weight"]).T
+             for name in _KIMI_GATES_A], axis=1))
+        lp.setdefault("kda_A_log", []).append(
+            _np(sd[pre + "self_attn.A_log"]).reshape(-1))
+    return {
+        "embedding": _np(sd["model.embed_tokens.weight"]).astype(dtype),
+        "layers": {kind: {k: np.stack(v).astype(dtype)
+                          for k, v in lp.items()}
+                   for kind, lp in per_kind.items()},
+        "final_ln": _np(sd["model.norm.weight"]).astype(dtype),
+        "lm_head": _np(sd["lm_head.weight"]).T.astype(dtype),
+    }
+
+
 def params_from_hf_state_dict(
     sd: Dict[str, Any], cfg: TransformerConfig, dtype: str = "float32"
 ) -> Dict[str, Any]:
@@ -1686,6 +1911,8 @@ def params_from_hf_state_dict(
         return _lfm2_moe_from_sd(sd, cfg, dtype)
     if cfg.hf_family == "glm4_moe_lite":
         return _afmoe_from_sd(sd, cfg, dtype, _GLM4_MOE_LITE_NAMES)
+    if cfg.hf_family == "kimi_linear":
+        return _kimi_linear_from_sd(sd, cfg, dtype)
     return _llama_from_sd(sd, cfg, dtype)
 
 
@@ -1709,6 +1936,8 @@ def params_to_hf_state_dict(
         return _lfm2_moe_to_sd(params, cfg)
     if cfg.hf_family == "glm4_moe_lite":
         return _afmoe_to_sd(params, cfg, _GLM4_MOE_LITE_NAMES)
+    if cfg.hf_family == "kimi_linear":
+        return _kimi_linear_to_sd(params, cfg)
     return _llama_to_sd(params, cfg)
 
 
@@ -1732,6 +1961,7 @@ _HF_ARCH = {
     "qwen3_next": "Qwen3NextForCausalLM",
     "lfm2_moe": "Lfm2MoeForCausalLM",
     "glm4_moe_lite": "Glm4MoeLiteForCausalLM",
+    "kimi_linear": "KimiLinearForCausalLM",
 }
 
 
@@ -1767,6 +1997,8 @@ def hf_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
         return _lfm2_moe_config_dict(cfg)
     if fam == "glm4_moe_lite":
         return _glm4_moe_lite_config_dict(cfg)
+    if fam == "kimi_linear":
+        return _kimi_linear_config_dict(cfg)
     d: Dict[str, Any] = {
         "model_type": fam,
         "architectures": [_HF_ARCH.get(fam, "LlamaForCausalLM")],
@@ -2089,6 +2321,66 @@ def _glm4_moe_lite_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
         "num_nextn_predict_layers": cfg.n_nextn_predict_layers,
         "torch_dtype": "float32",
     }
+    if moe.is_share:
+        d["num_routed_experts"] = moe.n_routed
+        d["expert_shard_count"] = moe.n_routed // moe.num_experts
+        d["expert_shard_index"] = moe.first_expert // moe.num_experts
+    if moe.router_bias_init_std != 0.02:
+        d["expert_bias_init_std"] = moe.router_bias_init_std
+    return d
+
+
+def _kimi_linear_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
+    """The inverse of :func:`_kimi_linear_config`. ``linear_attn_config``
+    lists the blocks this model holds, by their published numbers."""
+    moe, mla, kda = cfg.moe, cfg.mla, cfg.kda
+    held = cfg.held_layers or tuple(range(1, cfg.n_layers + 1))
+    kinds = [attention_kind(k) for k in cfg.layer_kinds]
+    dense = [i for i, f in zip(held, cfg.mlp_layer_types or ())
+             if f == DENSE_FFN]
+    d = {
+        "model_type": "kimi_linear",
+        "architectures": [_HF_ARCH["kimi_linear"]],
+        "num_hidden_layers": cfg.n_layers,
+        "first_k_dense_replace": max(dense, default=0),
+        "hidden_size": cfg.hidden_dim,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "head_dim": cfg.hidden_dim // cfg.n_q_heads,
+        "q_lora_rank": mla.q_lora_rank,
+        "kv_lora_rank": mla.kv_lora_rank,
+        "qk_nope_head_dim": mla.qk_nope_head_dim,
+        "qk_rope_head_dim": mla.qk_rope_head_dim,
+        "v_head_dim": mla.v_head_dim,
+        "mla_use_nope": True,
+        "linear_attn_config": {
+            "kda_layers": [i for i, k in zip(held, kinds) if k == KDA],
+            "full_attn_layers": [i for i, k in zip(held, kinds) if k != KDA],
+            "num_heads": kda.n_heads, "head_dim": kda.head_dim,
+            "short_conv_kernel_size": kda.conv_kernel},
+        "intermediate_size": cfg.intermediate_dim,
+        "moe_intermediate_size": moe.routed_intermediate_dim,
+        "vocab_size": cfg.vocab_size,
+        "hidden_act": "silu",
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "rope_theta": cfg.rotary_base,
+        "rope_scaling": None,
+        "tie_word_embeddings": cfg.tie_word_embeddings,
+        "model_max_length": cfg.max_position_embeddings or 1048576,
+        "num_experts": moe.num_experts,
+        "num_shared_experts": (moe.shared_intermediate_dim or 0)
+        // moe.routed_intermediate_dim,
+        "num_experts_per_token": moe.top_k,
+        "moe_router_activation_func": "sigmoid",
+        "moe_renormalize": moe.norm_topk_prob,
+        "moe_layer_freq": 1,
+        "num_expert_group": 1, "topk_group": 1, "use_grouped_topk": True,
+        "routed_scaling_factor": moe.routed_scaling_factor,
+        "num_nextn_predict_layers": cfg.n_nextn_predict_layers,
+        "torch_dtype": "float32",
+    }
+    if cfg.held_layers is not None:
+        d["held_layers"] = list(cfg.held_layers)
     if moe.is_share:
         d["num_routed_experts"] = moe.n_routed
         d["expert_shard_count"] = moe.n_routed // moe.num_experts

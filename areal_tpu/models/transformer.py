@@ -56,6 +56,7 @@ from areal_tpu.models.config import (
     CROSS,
     FULL,
     GDN,
+    KDA,
     GMU,
     MAMBA,
     MEMORY,
@@ -160,6 +161,10 @@ def _init_block_layers(cfg: TransformerConfig, n: int, keys, dtype,
         from areal_tpu.models import gdn as gdnmod
 
         layers.update(gdnmod.init_gdn_params(cfg.gdn, n, d, keys[13], dtype))
+    elif kind == KDA:
+        from areal_tpu.models import kda as kdamod
+
+        layers.update(kdamod.init_kda_params(cfg.kda, n, d, keys[13], dtype))
     elif kind == CONV:
         from areal_tpu.models import shortconv
 
@@ -174,7 +179,7 @@ def _init_block_layers(cfg: TransformerConfig, n: int, keys, dtype,
         mlamod.check(cfg.mla, cfg.head_dim)
         layers.update(mlamod.init_mla_params(
             cfg.mla, n, d, cfg.n_q_heads, keys[13], dtype))
-        layers["wo"] = nrm(keys[3], (n, qd, d))
+        layers["wo"] = nrm(keys[3], (n, cfg.o_dim, d))
     else:
         layers["wq"] = nrm(keys[0], (n, d, qd))
         layers["wo"] = nrm(keys[3], (n, qd, d))
@@ -468,6 +473,12 @@ def _block(
             attn, new_kv = gdnmod.gdn_mixer(
                 x, lp, cfg.gdn, cfg.rms_norm_eps, segment_ids,
                 attn_impl), None
+        elif akind == KDA:
+            from areal_tpu.models import kda as kdamod
+
+            attn, new_kv = kdamod.kda_mixer(
+                x, lp, cfg.kda, cfg.rms_norm_eps, segment_ids,
+                attn_impl), None
         elif akind == CONV:
             from areal_tpu.models import shortconv
 
@@ -523,7 +534,7 @@ def _block(
 
     hid = "hidden" if cache_kv is None else "hidden_decode"
     with jax.named_scope("cross_attention" if akind == CROSS else "o_proj"):
-        attn = attn.reshape(B, T, cfg.q_dim)
+        attn = attn.reshape(B, T, cfg.o_dim)
         if cfg.gated_attention:
             with jax.named_scope("attn_gate"):
                 attn = attn * jax.nn.sigmoid(gate)
@@ -644,10 +655,15 @@ DECODE_REFUSAL = (
 
 
 def decode_refusal(cfg: TransformerConfig) -> Optional[str]:
-    """Why this model has no decode mode, by name, or None: the Gated
-    DeltaNet or short-convolution blocks' own reason where it has them,
-    latent attention's likewise (its cache is the latent's, not K/V's),
-    ``DECODE_REFUSAL`` for any other layer no K/V cache can decode."""
+    """Why this model has no decode mode, by name, or None: the delta-rule
+    (Gated DeltaNet, Kimi Delta Attention) or short-convolution blocks'
+    own reason where it has them, latent attention's likewise (its cache
+    is the latent's, not K/V's), ``DECODE_REFUSAL`` for any other layer no
+    K/V cache can decode."""
+    if cfg.has_mixer(KDA):
+        from areal_tpu.models.kda import DECODE_REFUSAL as kda_refusal
+
+        return kda_refusal
     if cfg.mla is not None:
         from areal_tpu.models.mla import DECODE_REFUSAL as mla_refusal
 
@@ -1062,7 +1078,7 @@ def _block_matmul_widths(cfg: TransformerConfig, dense_ffn: bool,
     keeps the router's logits and its shared expert's pair. By ``kind``
     the mixer's are an S6 mixer's (in-projection, [δ | B | C], Δ), a
     Mamba-2 mixer's two projections, a Gated DeltaNet mixer's three, a
-    short convolution's two, a gated memory unit's one, cross
+    Kimi Delta Attention mixer's five, a short convolution's two, a gated memory unit's one, cross
     attention's q and o, or latent attention's five (the two latents and
     their expansions, never the assembled q and k: models/mla.py).
     ``mixer_only``: the mixer's (the attention branch's) alone."""
@@ -1073,6 +1089,10 @@ def _block_matmul_widths(cfg: TransformerConfig, dense_ffn: bool,
         widths = cfg.ssm.in_proj_dim + cfg.hidden_dim
     elif kind == GDN:  # the rule's einsums carry batch dimensions
         widths = cfg.gdn.qkvz_dim + cfg.gdn.ba_dim + cfg.hidden_dim
+    elif kind == KDA:  # both in-projections, both gates, the out-projection
+        from areal_tpu.models.kda import matmul_widths as kda_widths
+
+        widths = kda_widths(cfg.kda) + cfg.hidden_dim
     elif kind == CONV:  # [B | C | x], and the out-projection
         widths = 4 * cfg.hidden_dim
     elif kind == GMU:
@@ -1116,7 +1136,7 @@ def remat_kept_bytes(
     full = tokens * cfg.hidden_dim * itemsize
     # The kernel writes heads padded to the lane width, and one float32
     # statistic a head (a logsumexp).
-    lanes = -(-cfg.head_dim // LANE) * LANE
+    lanes = -(-(cfg.o_dim // cfg.n_q_heads) // LANE) * LANE
     n_sliding = cfg.layer_kinds.count(SLIDING)
     per_token = cfg.n_q_heads * (lanes * itemsize + 4)
     causal, window = full_tokens * per_token, window_tokens * per_token
@@ -1175,7 +1195,7 @@ def attention_kept_bytes_per_token(
 
     if entry in (False, "full"):
         return 0
-    lanes = -(-cfg.head_dim // LANE) * LANE
+    lanes = -(-(cfg.o_dim // cfg.n_q_heads) // LANE) * LANE
     kept = cfg.n_q_heads * (lanes * itemsize + 4) if kernel else 0
     if entry == "matmuls":
         kept += itemsize * _block_matmul_widths(cfg, False, FULL,
@@ -1432,6 +1452,10 @@ def _block_param_count(cfg: TransformerConfig, dense_ffn: bool,
         from areal_tpu.models.gdn import gdn_param_count
 
         attn = gdn_param_count(cfg.gdn, d)
+    elif kind == KDA:
+        from areal_tpu.models.kda import kda_param_count
+
+        attn = kda_param_count(cfg.kda, d)
     elif kind == CONV:
         from areal_tpu.models.shortconv import shortconv_param_count
 

@@ -31,8 +31,13 @@ kernel call). Wrapped with areal_tpu's packed-batch semantics:
    query attended of the padding before it (a block with real tokens) or
    ``0 * (1 / 0)`` (a block of nothing but padding, which ran no key
    block: its softmax statistic is -inf, and no backward block reads it);
- - head_dim is padded up to the lane width (128) when needed, and the row
-   up to a multiple of the blocks of :func:`geometry`, with segment id 0.
+ - a head is padded up to whole lanes (128) when needed — 64 to 128, 192
+   to 256 —, the VALUE by its own width where it is narrower than the key
+   (latent attention's 128 under a key of 192: the kernel takes two
+   widths, and no lane of a value padded to the key's is multiplied;
+   :func:`head_width_counts` says what each call was handed and ran), and
+   the row up to a multiple of the blocks of :func:`geometry`, with
+   segment id 0.
 
 Which blocks a call runs is :func:`geometry`'s choice from what the call
 sees — the row, the window, the head size, the query heads a key/value
@@ -251,7 +256,7 @@ def _square(tile: int, compute: Optional[int] = None,
 # Heads wider than the lanes under a causal mask, {(head_dim, query heads
 # a key/value head): blocks} — each kernel's own best shape of those the
 # chip's 16 MB of scoped VMEM takes, measured on a TPU v5e by each
-# kernel's DEVICE time, bf16, at the rows and document layouts of the two
+# kernel's DEVICE time, bf16, at the rows and document layouts of the
 # cells that have such heads: glm-4.7-flash's latent attention (20 / 20
 # heads; 14,336 = 8,937 + 5,357, 13,440 = 13,356 and = 6,525 x 2) and
 # qwen3-next's gated attention (16 / 2; 14,336 = 11,737 + 2,488, 8,704 =
@@ -269,6 +274,16 @@ def _square(tile: int, compute: Optional[int] = None,
 WIDE_BLOCKS = {
     (256, 1): Blocks((1024, 1024, 256), (1024, 1024, 512), (1024, 1024)),
     (256, 8): Blocks((1024, 1024, 256), (512, 1024, 256), (512, 1024)),
+    # A key of 192 in 256 lanes over a value of 128 in its own
+    # (kimi_linear's latent attention, 32 / 32 heads; 8,192 = 1,658 + 6,480
+    # and 7,552 = 5,062 + 1,682; tools/window_tile_sweep.py --window 0
+    # --head-dim 192 --value-dim 128 --device; PERF.md §6, PR 63): the
+    # (256, 1) entry's shapes are the best here too — 41.6 / 32.3 ms for two
+    # forwards and a forward + backward against the square 512's 45.4 /
+    # 36.6; a forward key block of 2048 is 7 % slower, a dKV / dQ key block
+    # of 2048 overflows VMEM at 7,552, a backward query block of 512 is
+    # 2-3 % slower.
+    (192, 1): Blocks((1024, 1024, 256), (1024, 1024, 512), (1024, 1024)),
 }
 
 
@@ -327,6 +342,10 @@ _GEOMETRY: Dict[str, Dict[Tuple[int, int, int, int], list]] = (
 # {label: {(n, n_pad, blocks): calls}}.
 _CAUSAL_GEOMETRY: Dict[str, collections.Counter] = collections.defaultdict(
     collections.Counter)
+# The head widths each full-causal geometry was handed and ran: {label:
+# {(n, n_pad, blocks): (key width, its lanes, value width, its lanes)}}.
+_HEAD_WIDTHS: Dict[str, Dict[Tuple, Tuple[int, int, int, int]]] = (
+    collections.defaultdict(dict))
 
 
 # What the packed grids a step ran needed of the static mask's blocks, by
@@ -345,6 +364,13 @@ def geometry_counts() -> Dict[str, Dict[Tuple[int, int, int, int], Dict]]:
 
 def causal_geometry_counts() -> Dict[str, Dict[Tuple[int, int, Blocks], int]]:
     return {label: dict(c) for label, c in _CAUSAL_GEOMETRY.items()}
+
+
+def head_width_counts() -> Dict[str, Dict[Tuple, Tuple[int, int, int, int]]]:
+    """{label: {(n, n_pad, blocks): (the key's width as handed, the lanes
+    the kernel ran it in, the value's width, its lanes)}} of the
+    full-causal calls: a value narrower than its key keeps its own lanes."""
+    return {label: dict(w) for label, w in _HEAD_WIDTHS.items()}
 
 
 def count_needed(segment_ids: np.ndarray,  # [R, L] on the host
@@ -380,10 +406,11 @@ def needed_counts() -> Dict[Tuple[int, int, int, int, int], Dict]:
 
 
 def _count(n: int, n_pad: int, blocks: Blocks,
-           window: Optional[int]) -> None:
+           window: Optional[int], widths: Tuple[int, int, int, int]) -> None:
     label = _attention.active_label()
     if window is None:
         _CAUSAL_GEOMETRY[label][(n, n_pad, blocks)] += 1
+        _HEAD_WIDTHS[label][(n, n_pad, blocks)] = widths
         return
     tile = blocks.tile
     visited, causal = blocks_visited(n_pad, tile, window)
@@ -526,7 +553,7 @@ def _narrowed(segment_ids: jnp.ndarray, *geometry):
 def window_attention(
     q: jnp.ndarray,  # [B, T, Hq, D]
     k: jnp.ndarray,  # [B, T, Hkv, D]
-    v: jnp.ndarray,  # [B, T, Hkv, D]
+    v: jnp.ndarray,  # [B, T, Hkv, Dv], Dv <= D
     q_segment_ids: jnp.ndarray,  # [B, T] int, 0 = pad
     kv_segment_ids: jnp.ndarray,  # [B, T]
     window: Optional[int] = None,  # None: full causal
@@ -534,7 +561,7 @@ def window_attention(
     interpret: bool = False,
 ) -> jnp.ndarray:
     B, T, Hq, D = q.shape
-    Hkv = k.shape[2]
+    Hkv, Dv = k.shape[2], v.shape[3]
     if k.shape[1] != T:
         raise ValueError("window_attention is self-attention over one "
                          f"packed row: T={T}, S={k.shape[1]}")
@@ -547,15 +574,18 @@ def window_attention(
     blocks = geometry(T, window, D, G)
     tile = blocks.tile
     T_pad = _round_up(T, tile)
-    _count(T, T_pad, blocks, window)
+    _count(T, T_pad, blocks, window,
+           (D, _round_up(D, LANE), Dv, _round_up(Dv, LANE)))
     if scale is None:
         scale = D ** -0.5
 
     # [B, T, H, D] -> [B, Hkv, G, T, D] / [B, Hkv, T, D], heads padded to
-    # the lane width and the row to its tile; the kernel takes no scale.
-    lanes, more = max(LANE - D, 0), T_pad - T
+    # whole lanes — the value by its own width — and the row to its tile;
+    # the kernel takes no scale.
+    more = T_pad - T
 
     def pad(x):  # [..., L, D]
+        lanes = -x.shape[-1] % LANE
         if not (more or lanes):
             return x
         return jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, more), (0, lanes)])
@@ -579,7 +609,8 @@ def window_attention(
         return jax.vmap(kern, in_axes=(0, 0, 0, None))(q, k, v, seg)
 
     out = jax.vmap(row)(qt, kt, vt, seg)  # [B, Hkv, G, T_pad, D+]
-    out = out[:, :, :, :T, :D].transpose(0, 3, 1, 2, 4).reshape(B, T, Hq, D)
+    out = out[:, :, :, :T, :Dv].transpose(0, 3, 1, 2, 4).reshape(
+        B, T, Hq, Dv)
     # A padding query's row is zero: inside a block of real tokens it
     # attended the padding before it, and a block of nothing but padding
     # ran no key block, so its row is 0 * (1 / 0).

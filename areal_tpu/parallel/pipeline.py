@@ -62,7 +62,9 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from areal_tpu.base import logging, telemetry
-from areal_tpu.models.config import CONV, GDN, SLIDING, TransformerConfig
+from areal_tpu.models.config import (
+    CONV, GDN, KDA, SLIDING, TransformerConfig,
+)
 from areal_tpu.parallel import ring as ring_mod
 from areal_tpu.parallel import sharding as psh
 
@@ -86,6 +88,11 @@ _FALLBACK_HINTS = {
     "gated_delta_rule": "Gated DeltaNet blocks beside attention blocks: "
                         "a tree per kind has no one stacked axis to split "
                         "over pp, and a period's stages cost unequally",
+    "channel_decay_rule": "delta-rule blocks with a decay a key channel "
+                          "beside latent-attention blocks, a dense block "
+                          "before expert blocks: a tree per kind has no "
+                          "one stacked axis to split over pp, and a "
+                          "period's stages cost unequally",
     "short_convolution": "short-convolution blocks beside attention "
                          "blocks, dense blocks before expert blocks: a tree "
                          "per kind has no one stacked axis to split over "
@@ -137,6 +144,8 @@ def pick_pp_microbatches(
         return _fallback("cross_layer_state")
     if GDN in cfg.layer_kinds:
         return _fallback("gated_delta_rule")
+    if cfg.has_mixer(KDA):
+        return _fallback("channel_decay_rule")
     if cfg.has_mixer(CONV):
         return _fallback("short_convolution")
     if cfg.is_hybrid:

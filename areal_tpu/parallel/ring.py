@@ -53,7 +53,7 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.models.config import CONV, GDN, MAMBA, S6, SSD
+from areal_tpu.models.config import CONV, GDN, KDA, MAMBA, S6, SSD
 from areal_tpu.parallel.mesh import DATA_AXES
 
 _NEG_INF = -1e30
@@ -355,6 +355,11 @@ RING_REFUSALS = {
                         "value head from chunk to chunk in order: a rank's "
                         "first state is the rank before's last, and its "
                         "convolution reads the rank before's last taps",
+    "channel_decay_rule": "the delta rule with a decay a key channel "
+                          "carries a state matrix a head from chunk to "
+                          "chunk in order, as the gated delta rule does, "
+                          "and its three convolutions read the rank "
+                          "before's last taps",
     "short_convolution": "a short convolution's taps read the tokens just "
                          "before a token: a rank's first tokens need the "
                          "rank before's last conv_L_cache - 1 of B ⊙ x",
@@ -376,6 +381,8 @@ def ring_refusal(cfg, kind: Optional[str] = None) -> Optional[str]:
         return "selective_scan"
     if GDN in cfg.layer_kinds:
         return "gated_delta_rule"
+    if cfg.has_mixer(KDA):
+        return "channel_decay_rule"
     if cfg.has_mixer(CONV):
         return "short_convolution"
     kinds = cfg.layer_kinds if kind is None else (kind,)

@@ -28,7 +28,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from areal_tpu.models.config import (
-    ATTENTION_FREE_KINDS, CONV, CROSS, FULL, GDN, GMU, S6, SSD,
+    ATTENTION_FREE_KINDS, CONV, CROSS, FULL, GDN, GMU, KDA, S6, SSD,
     TransformerConfig, attention_kind)
 from areal_tpu.parallel.mesh import DATA_AXES
 
@@ -105,6 +105,15 @@ def _block_partition_specs(cfg: TransformerConfig, zero, lead,
             "gdn_dt_bias": P(lead, None), "gdn_A_log": P(lead, None),
             "gdn_norm": P(lead, None),
         })
+    elif kind == KDA:  # likewise: matrices ZeRO-3 on the hidden dim, the
+        # heads, the gates' bottlenecks and the taps whole
+        layers.update({
+            "kda_qkv": P(lead, zero, None), "kda_gates_a": P(lead, zero, None),
+            "kda_out": P(lead, None, zero), "kda_conv": P(lead, None, None),
+            "kda_f_b": P(lead, None, None), "kda_g_b": P(lead, None, None),
+            "kda_dt_bias": P(lead, None), "kda_A_log": P(lead, None),
+            "kda_norm": P(lead, None),
+        })
     elif kind == S6:
         layers.update({
             "in_proj": P(lead, zero, None), "out_proj": P(lead, None, zero),
@@ -121,9 +130,11 @@ def _block_partition_specs(cfg: TransformerConfig, zero, lead,
         # the hidden dim, the latents whole; the up-projections' columns
         # and o_proj's rows are by head, so "tp" splits heads as it does
         # for wq / wo; the latent norms replicated
-        layers.update({
+        query = {"wq": P(lead, zero, "tp")} if cfg.mla.q_lora_rank is None else {
             "wq_a": P(lead, zero, None), "q_a_norm": P(lead, None),
-            "wq_b": P(lead, zero, "tp"),
+            "wq_b": P(lead, zero, "tp")}
+        layers.update({
+            **query,
             "wkv_a": P(lead, zero, None), "kv_a_norm": P(lead, None),
             "wkv_b": P(lead, zero, "tp"), "wo": P(lead, "tp", zero),
         })

@@ -108,6 +108,7 @@ METRIC_CATALOG = frozenset({
     # train engine counters/gauges (backend/jax_train.py)
     "train/tokens", "train/optimizer_steps", "train/pack_fill",
     "train/docs_per_row", "train/gdn_resets_in_chunk_per_row",
+    "train/kda_resets_in_chunk_per_row",
     "train/shortconv_resets_per_row", "train/mla_kept_bytes_per_token",
     # parallelism engagement (parallel/pipeline.py gates, exported per
     # batch by backend/jax_train.py): 0/1 gauges for whether the pipeline

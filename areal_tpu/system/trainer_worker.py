@@ -330,18 +330,23 @@ class TrainerWorker:
         run is judged by (base/monitor.log_device_report)."""
         from areal_tpu.base import monitor
         from areal_tpu.ops import attention, native
-        from areal_tpu.models import gdn, mla, moe, shortconv, ssm
+        from areal_tpu.models import gdn, kda, mla, moe, shortconv, ssm
         from areal_tpu.ops.pallas import window_attention
 
+        widths = window_attention.head_width_counts()
         monitor.log_device_report(
             logger, f"trainer{self.cfg.dist_rank}", stage=stage,
             attention=attention.dispatch_counts(),
             # {label: {"length>padded/blocks": calls traced}}: the kernel's
             # full-causal calls (the "pallas" count above) and the
             # geometry each ran (window_attention.Blocks.label)
+            # — with "/k<width>><lanes>.v<width>><lanes>" where the
+            # value is narrower than the key and keeps its own lanes
             causal_geometry={
-                label: {"%d>%d/%s" % (n, n_pad, blocks.label()): calls
-                        for (n, n_pad, blocks), calls in counts.items()}
+                label: {"%d>%d/%s" % (g[0], g[1], g[2].label()) + (
+                    "/k%d>%d.v%d>%d" % widths[label][g]
+                    if widths[label][g][0] != widths[label][g][2] else ""
+                ): calls for g, calls in counts.items()}
                 for label, counts in
                 window_attention.causal_geometry_counts().items()
             },
@@ -368,13 +373,20 @@ class TrainerWorker:
             # model's Gated DeltaNet blocks (models/gdn.py)
             gdn_geometry={"%dx%d/%d/k%dv%d/%dx%d" % geom: n
                           for geom, n in gdn.geometry_counts().items()},
+            # {"rows x length/chunk/hH/head width/gate rank": rules traced}:
+            # a model's Kimi Delta Attention blocks (models/kda.py), and
+            # what runs them (ops/pallas/kda_rule.py, or the XLA form)
+            kda_geometry={"%dx%d/%d/h%d/%d/r%d" % geom: n
+                          for geom, n in kda.geometry_counts().items()},
+            kda_rule_impl=kda.rule_impl_counts(),
             # {"rows x length/channels/taps": convolutions traced}: a
             # model's short-convolution blocks (models/shortconv.py)
             shortconv_geometry={
                 "%dx%d/c%d/k%d" % geom: n
                 for geom, n in shortconv.geometry_counts().items()},
-            # {"rows x length/heads/q latent, kv latent/nope+rope/v":
-            # assemblies traced}: latent attention (models/mla.py)
+            # {"rows x length/heads/q latent (0: none, one full-rank
+            # projection), kv latent/nope+rope/v": assemblies traced}:
+            # latent attention (models/mla.py)
             mla_geometry={"%dx%d/h%d/q%dkv%d/%d+%d/v%d" % geom: n
                           for geom, n in mla.geometry_counts().items()},
             # {"pallas" | "pallas_interpret" | "xla": scans traced}: what

@@ -132,6 +132,7 @@ DESCRIBED_CHIP_CHILDREN = {
     "compiled": (_TPU_COMPILE, 1100),
     "compiled_lfm2": (_TPU_COMPILE + ["lfm2"], 600),
     "compiled_glm": (_TPU_COMPILE + ["glm"], 600),
+    "compiled_kimi": (_TPU_COMPILE + ["kimi"], 600),
 }
 _CHAIN = pytest.StashKey[dict]()
 

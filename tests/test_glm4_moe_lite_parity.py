@@ -186,14 +186,27 @@ def test_the_config_goes_out_and_comes_back():
     ("rope_scaling", {"type": "yarn", "factor": 4.0}, "rope_scaling"),
     ("attention_bias", True, "attention_bias"),
     ("partial_rotary_factor", 0.5, "partial_rotary_factor"),
-    ("q_lora_rank", None, "latent_attention_full_rank_query"),
-    ("v_head_dim", 12, "latent_attention_value_width"),
     ("num_key_value_heads", 2, "num_key_value_heads"),
 ])
 def test_keys_of_the_family_that_are_not_built_are_refused_by_name(
         key, value, name):
     with pytest.raises(NotImplementedError, match=name):
         hf.config_from_hf(types.SimpleNamespace(**{**HF_KEYS, key: value}))
+
+
+@pytest.mark.parametrize("key,value", [("q_lora_rank", None),
+                                       ("v_head_dim", 12)])
+def test_a_full_rank_query_and_a_narrower_value_are_built(key, value):
+    """What was refused by name until PR 63 (``latent_attention_full_rank_
+    query``, ``latent_attention_value_width``): the family's model without
+    a query latent, or with a value head narrower than its key, runs, and
+    the packed forward is finite at the value's width."""
+    cfg = hf.config_from_hf(types.SimpleNamespace(**{**HF_KEYS, key: value}))
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    lp = params["layers"][FULL]
+    assert ("wq" in lp) == (cfg.mla.q_lora_rank is None)
+    assert lp["wo"].shape[1] == 4 * cfg.mla.v_head_dim == cfg.o_dim
+    assert bool(jnp.isfinite(system_logits(params, cfg, tokens())).all())
 
 
 def test_parameter_count_at_the_published_widths():

@@ -1,0 +1,225 @@
+"""The channel-decay delta rule's Pallas kernels (``ops/pallas/kda_rule.py``)
+in Pallas's interpreter on the CPU, at the widths the Kimi-Linear cell runs
+(heads of 128, chunks of 64): the forward and every gradient against the
+token-by-token float32 recurrence (``benchmark/reference_kimi_linear.
+delta_rule``, a document at a time) and against the XLA form
+(``models/kda._rule_xla``); documents that
+start inside a chunk, on the chunk grid, in a grid step's second chunk and
+twice in one chunk; a row that is no whole number of chunks; trailing
+padding; decays drawn LOW, so that the state is remembered across chunks and
+grid steps, and decays so STRONG that ``k ⊙ e^{-c}`` — the factor a
+reference outside the chunk's sub-blocks would need — overflows float32;
+the dispatch's answers and its counters; a Gated DeltaNet rule (the decay
+averaged over a head's channels), which this file must refuse; and that the
+scalar-decay rule's traced program is what it was.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from areal_tpu.models import gdn, kda
+from areal_tpu.models.config import KDAConfig
+from areal_tpu.ops.pallas import kda_rule as kernel
+from benchmark import reference_kimi_linear as ref
+
+D, Q = 128, 64  # head size, chunk
+GRADS = ("q", "k", "v", "g", "beta")
+
+
+def inputs(T, H, seed=0, dtype=jnp.float32, strong=1.0):
+    """One row. Decays of -0.002 to -0.7 a token and channel (the state
+    lasts hundreds of tokens on some channels and a few on others), times
+    ``strong``."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = gdn.l2_normalize(jax.random.normal(ks[0], (1, T, H, D))) * D ** -0.5
+    k = gdn.l2_normalize(jax.random.normal(ks[1], (1, T, H, D)))
+    v = jax.random.normal(ks[2], (1, T, H, D))
+    rate = jnp.exp(jax.random.uniform(ks[3], (H, D), minval=np.log(0.002),
+                                      maxval=np.log(0.5)))
+    g = -rate * jax.nn.softplus(
+        jax.random.normal(ks[4], (1, T, H, D)) + 1.0) * strong
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (1, T, H)))
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
+
+
+def layout(which, T):
+    """Segment ids [1, T] (tests/test_gdn_rule_kernel.py's layouts)."""
+    cuts = {"first": [23, T], "second": [Q + 6, T], "grid": [2 * Q, T],
+            "twice": [Q + 5, Q + 40, T], "padding": [Q - 9, T - Q // 2]}[which]
+    seg = np.zeros(T, np.int32)
+    start = 0
+    for i, end in enumerate(cuts):
+        seg[start:end] = i + 1
+        start = end
+    return jnp.asarray(seg)[None]
+
+
+def by_document(q, k, v, g, beta, seg):
+    """The reference's recurrence on each document alone (float32)."""
+    out = np.zeros(v.shape, np.float32)
+    ids = np.asarray(seg[0])
+    with jax.default_matmul_precision("highest"):
+        for s in sorted(set(ids[ids > 0])):
+            at = np.where(ids == s)[0]
+            lo, hi = at[0], at[-1] + 1
+            out[0, lo:hi] = np.asarray(ref.delta_rule(
+                *(a[0, lo:hi].astype(jnp.float32)
+                  for a in (q, k, v, g, beta))))
+    return jnp.asarray(out)
+
+
+def rule(how):
+    return lambda q, k, v, g, b, seg: kda.channel_decay_rule(
+        q, k, v, g, b, seg, Q, how)
+
+
+def real(seg):
+    return (seg > 0)[..., None, None]
+
+
+@pytest.mark.parametrize("which", ["first", "second", "grid", "twice",
+                                   "padding"])
+def test_forward_against_the_token_scan(which):
+    T = 4 * Q
+    a = inputs(T, 2, seed=1)
+    seg = layout(which, T)
+    want = by_document(*a, seg)
+    for how in ("pallas_interpret", "xla"):
+        got = rule(how)(*a, seg)
+        assert float(jnp.max(jnp.abs((got - want) * real(seg)))) < 2e-6, how
+
+
+def test_a_row_that_is_no_whole_number_of_chunks_and_several_steps():
+    """9 chunks and a half: more than one grid step, the last one short."""
+    T = 9 * Q + 31
+    a = inputs(T, 1, seed=2)
+    seg = jnp.ones((1, T), jnp.int32).at[0, 300:].set(2)
+    want = by_document(*a, seg)
+    got = rule("pallas_interpret")(*a, seg)
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-6
+
+
+@pytest.mark.parametrize("strong", [1.0, 200.0])
+def test_gradients_against_the_token_scan(strong):
+    """Every gradient; at ``strong`` 200 a chunk's cumulated decay passes
+    -3000 on some channels: ``e^{+3000}`` is what a factor ``k ⊙ e^{-c}``
+    would be, and every number here must stay finite."""
+    T = 3 * Q
+    a = inputs(T, 2, seed=3, strong=strong)
+    seg = layout("second", T)
+    assert float(jnp.min(jnp.cumsum(a[3], axis=1))) < (
+        -3000 if strong > 1 else -1)
+
+    def loss(fn):
+        return lambda *x: jnp.sum(jnp.sin(fn(*x)) * real(seg))
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(loss(lambda *x: _scan_row(*x, seg)),
+                        argnums=range(5))(*a)
+    for how in ("pallas_interpret", "xla"):
+        got = jax.grad(loss(lambda *x: rule(how)(*x, seg)),
+                       argnums=range(5))(*a)
+        for name, x, w in zip(GRADS, got, want):
+            assert bool(jnp.isfinite(x).all()), (how, name)
+            scale = float(jnp.max(jnp.abs(w))) + 1e-6
+            assert float(jnp.max(jnp.abs(x - w))) < 2e-5 * max(scale, 1.0), (
+                how, name)
+
+
+def _scan_row(q, k, v, g, beta, seg):
+    """The recurrence with the state zeroed at a document's first token,
+    differentiable (the reference's, a row at a time)."""
+    H = q.shape[2]
+
+    def step(carry, x):
+        S, prev = carry
+        q, k, v, g, b, s = x
+        S = jnp.where(s != prev, 0.0, S)
+        S = jnp.exp(g)[:, :, None] * S
+        d = b[:, None] * (v - jnp.einsum("hkv,hk->hv", S, k))
+        S = S + k[:, :, None] * d[:, None, :]
+        return (S, s), jnp.einsum("hkv,hk->hv", S, q)
+
+    _, o = jax.lax.scan(step, (jnp.zeros((H, D, D)), jnp.int32(-1)),
+                        (q[0], k[0], v[0], g[0], beta[0], seg[0]))
+    return o[None]
+
+
+def test_bfloat16_operands():
+    T = 4 * Q
+    a = inputs(T, 2, seed=4, dtype=jnp.bfloat16)
+    seg = layout("first", T)
+    want = by_document(*a, seg)
+    got = rule("pallas_interpret")(*a, seg)
+    assert got.dtype == jnp.float32
+    assert float(jnp.max(jnp.abs((got - want) * real(seg)))) < 4e-3
+    grads = jax.grad(lambda *x: jnp.sum(rule("pallas_interpret")(*x, seg)),
+                     argnums=range(5))(*a)
+    assert [x.dtype for x in grads[:3]] == [jnp.bfloat16] * 3
+    assert all(bool(jnp.isfinite(x.astype(jnp.float32)).all()) for x in grads)
+
+
+def test_a_decay_averaged_over_a_heads_channels_is_refused():
+    """A Gated DeltaNet rule in KDA's place: the same inputs with ``g``
+    averaged over a head's channels move the output by orders more than
+    the tolerance above."""
+    T = 4 * Q
+    q, k, v, g, beta = inputs(T, 2, seed=5)
+    seg = layout("grid", T)
+    want = by_document(q, k, v, g, beta, seg)
+    flat = jnp.broadcast_to(jnp.mean(g, -1, keepdims=True), g.shape)
+    got = rule("pallas_interpret")(q, k, v, flat, beta, seg)
+    assert float(jnp.max(jnp.abs(got - want))) > 1e-2
+
+
+def test_dispatch_and_counters():
+    cfg = KDAConfig(n_heads=4, head_dim=128)
+    assert kda._rule_impl("auto", cfg, jnp.bfloat16) == "xla"  # a CPU
+    assert kda._rule_impl("pallas_interpret", cfg, jnp.float32) == (
+        "pallas_interpret")
+    assert kda._rule_impl("pallas_interpret",
+                          KDAConfig(n_heads=4, head_dim=64),
+                          jnp.float32) == "xla"
+    assert kda._rule_impl("pallas_interpret",
+                          KDAConfig(n_heads=4, head_dim=128, chunk_size=32),
+                          jnp.float32) == "xla"
+    assert not kernel.supported(64, 4, 128, 128, jnp.float16)
+    before = dict(kda.rule_impl_counts())
+    a = inputs(Q, 1, seed=6)
+    rule("pallas_interpret")(*a, jnp.ones((1, Q), jnp.int32))
+    after = kda.rule_impl_counts()
+    assert after["pallas_interpret"] == before.get("pallas_interpret", 0) + 1
+    assert 0.0 < kda.rule_kernel_frac() <= 1.0
+
+
+def test_the_kernels_names_and_scope():
+    """The benchmark reads the rule by scope ``kda_rule`` and the kernels
+    by ``kda_rule_fwd`` / ``kda_rule_bwd``."""
+    a = inputs(Q, 1, seed=7)
+    seg = jnp.ones((1, Q), jnp.int32)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda *x: jnp.sum(rule("pallas_interpret")(*x, seg)),
+        argnums=range(5)))(*a))
+    assert "kda_rule_fwd" in text and "kda_rule_bwd" in text
+    assert (kernel.FWD_NAME, kernel.BWD_NAME) == ("kda_rule_fwd",
+                                                  "kda_rule_bwd")
+
+
+def test_the_scalar_decay_rules_traced_program_is_unchanged():
+    """A ``gdn`` model's rule (one decay a value head) traces to the
+    equations it traced to before the decay could be a channel's (the
+    parent's tree gives the same jaxpr text for these shapes: 91
+    equations)."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    T, G, H, d = 128, 2, 4, 16
+    args = (jax.random.normal(ks[0], (1, T, G, d)),
+            jax.random.normal(ks[1], (1, T, G, d)),
+            jax.random.normal(ks[2], (1, T, H, d)),
+            -jax.nn.softplus(jax.random.normal(ks[3], (1, T, H))),
+            jax.nn.sigmoid(jax.random.normal(ks[4], (1, T, H))),
+            jnp.ones((1, T), jnp.int32))
+    jaxpr = jax.make_jaxpr(
+        lambda *a: gdn.gated_delta_rule(*a, 64, "reference"))(*args)
+    assert len(jaxpr.jaxpr.eqns) == 91

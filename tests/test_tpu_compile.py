@@ -10,15 +10,15 @@ passes is not a chip run.
 
 libtpu admits ONE process at a time (``/tmp/libtpu_lockfile``), so the
 compiles run in child processes — this file, executed as a script: with no
-argument every case of ``_compile_all``, with ``lfm2`` / ``glm`` that
-cell's cut alone — and the pytest processes themselves never load libtpu.
+argument every case of ``_compile_all``, with ``lfm2`` / ``glm`` / ``kimi``
+that cell's cut alone — and the pytest processes themselves never load libtpu.
 Nothing here starts a child. ``tests/conftest.py`` owns the one mechanism
 (``tests/compile_chain.py``):
 
 - ``DESCRIBED_CHIP_CHILDREN`` there names the children: fixture name ->
   (command, time limit). A test takes the fixture (``compiled``,
-  ``compiled_lfm2``, ``compiled_glm``) and gets the JSON object its child
-  printed last. A child for a new configuration is ONE entry in that table
+  ``compiled_lfm2``, ``compiled_glm``, ``compiled_kimi``) and gets the JSON
+  object its child printed last. A child for a new configuration is ONE entry in that table
   (and its ``_compile_<name>`` here): no new fixture, no test placed in
   another file to schedule it.
 - The children a collection holds readers of are started at the run's
@@ -147,6 +147,11 @@ LFM2_GRID = (2, 7168)
 # largest grid is one row (traffic/train-swe-agent-16k.json); child
 # ``compiled_glm``.
 GLM_GRID = (1, 14336)
+# The Kimi-Linear cell's cut (configs/kimi-linear-48b-a3b.json) likewise,
+# on the grid of its traffic whose grad program needs most (two rows of
+# 7,552, 15,104 tokens of at most 16,384): child ``kimi``, fixture
+# ``compiled_kimi``.
+KIMI_GRID = (2, 7552)
 SSD_SCANS = {"ssd-scan-granite": (1, 7040, 32, 64, 1, 128, 256),
              "ssd-scan-nemotron": (1, 4096, 16, 64, 1, 128, 128)}
 
@@ -758,6 +763,66 @@ def _compile_glm():
         "param_bytes": 18 * transformer.param_count(glm)}
 
 
+def _compile_kimi():
+    """Child process: the Kimi-Linear cell's cut, the whole model's forward
+    + backward on its largest grid under full remat, against a described
+    v5e:2x2."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs in /tmp
+    sys.path.insert(0, REPO)
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from areal_tpu.models import kda, mla, transformer
+    from areal_tpu.ops.pallas import window_attention as wa
+    from benchmark import weights
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this image
+        return {"skip": f"cannot describe a v5e:2x2 topology here: {e}"}
+    jax.config.update("jax_enable_compilation_cache", False)
+    chip = SingleDeviceSharding(topo.devices[0])
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "kimi-linear-48b-a3b.json")) as f:
+        kimi = weights.model_config(json.load(f))
+    shapes = jax.eval_shape(
+        lambda: transformer.init_params(kimi, jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, jnp.bfloat16, sharding=chip), shapes)
+    tok = jax.ShapeDtypeStruct(KIMI_GRID, jnp.int32, sharding=chip)
+
+    def kimi_grad(p, tokens, pos, seg):
+        def loss(p):
+            y, _ = transformer.forward(
+                p, kimi, tokens, pos, segment_ids=seg, attn_impl="pallas",
+                remat="full", return_kv=False, return_hidden=True)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        return jax.value_and_grad(loss)(p)
+
+    began = time.monotonic()
+    got = jax.jit(kimi_grad).lower(params, tok, tok, tok).compile()
+    text = got.as_text()
+    (widths,) = {w for by in wa.head_width_counts().values()
+                 for w in by.values()}
+    return {
+        "seconds": round(time.monotonic() - began, 2),
+        "custom_calls": text.count("tpu_custom_call"),
+        "temp_bytes": got.memory_analysis().temp_size_in_bytes,
+        "rule_kernels": [n for n in ("kda_rule_fwd", "kda_rule_bwd")
+                         if n in text],
+        "rule_impl": kda.rule_impl_counts(),
+        "rules_traced": {"%dx%d/%d/h%d/%d/r%d" % g: n
+                         for g, n in kda.geometry_counts().items()},
+        "assemblies_traced": {"%dx%d/h%d/q%dkv%d/%d+%d/v%d" % g: n
+                              for g, n in mla.geometry_counts().items()},
+        "head_widths": list(widths),
+        "param_bytes": 18 * transformer.param_count(kimi)}
+
+
 @pytest.mark.parametrize("T", WINDOW_T)
 def test_window_attention_compiles_for_v5e(compiled, T):
     """The windowed kernel's forward, dKV and dQ at the published heads
@@ -914,6 +979,7 @@ def test_programs_that_hold_no_grouped_gemm_kernel(compiled, name):
 if __name__ == "__main__":
     print(json.dumps(_compile_lfm2() if sys.argv[1:] == ["lfm2"]
                      else _compile_glm() if sys.argv[1:] == ["glm"]
+                     else _compile_kimi() if sys.argv[1:] == ["kimi"]
                      else _compile_all()))
 
 
@@ -1082,6 +1148,30 @@ def test_the_lfm2_cut_compiles_inside_the_memory_it_leaves(compiled_lfm2):
     assert got["temp_bytes"] < 3.1e9
     gradient = got["param_bytes"] // 9  # 2 B a parameter
     assert got["param_bytes"] + gradient + got["temp_bytes"] < 12.5e9
+
+
+def test_the_kimi_cut_compiles_inside_the_memory_it_leaves(compiled_kimi):
+    """The grad program of the cut (block 1 and the period 5-8: four KDA
+    mixers, one un-rotated latent attention, 8 of 256 experts held: 602.4 M
+    parameters) on 2 x 7,552, the grid of the cell's traffic that needs
+    most, at the published widths: every rule the kernel pair, the
+    attention kernel handed a key of 192 in 256 lanes and a value of 128
+    in its own. Whether the micro-batch FITS is not read off this program
+    (the whole tree's gradient at once, returned beside its temporaries:
+    6.18 GB + 1.20): the engine's own ``train_grad_sliced`` of this grid
+    takes 5.92 GB with head and gradient and runs on the chip beside the
+    10.84 GB (PERF.md section 6, PR 63) — what is held here is that the
+    temporaries do not grow."""
+    got = compiled_kimi
+    assert got["rule_kernels"] == ["kda_rule_fwd", "kda_rule_bwd"]
+    assert set(got["rule_impl"]) == {"pallas"}
+    # one rule a run of KDA blocks (block 1's, the expert blocks')
+    assert got["rules_traced"] == {"2x7552/64/h32/128/r128": 2}
+    assert got["assemblies_traced"] == {"2x7552/h32/q0kv512/128+64/v128": 1}
+    assert got["head_widths"] == [192, 256, 128, 128]
+    # 6.18 GB
+    assert got["temp_bytes"] < 6.4e9
+    assert got["param_bytes"] == 10_843_819_776
 
 
 def test_the_glm_cut_compiles_inside_the_memory_it_leaves(compiled_glm):

@@ -782,3 +782,37 @@ def test_the_engines_gauge_is_the_kernels_count(monkeypatch, pattern, want):
                 assert "train/attn_blocks_needed_frac" not in gauges
     finally:
         telemetry.shutdown()
+
+
+@pytest.mark.parametrize("widths", [(192, 128), (64, 32)])
+def test_a_value_narrower_than_its_key_keeps_its_own_lanes(monkeypatch,
+                                                          widths):
+    """Latent attention's call without a query latent (a key of 192 over a
+    value of 128; and 64 over 32 inside one lane tile): the key is padded
+    to whole lanes, the value to ITS own, outputs and gradients are the
+    reference's at the value's width, and ``head_width_counts`` says what
+    the kernel was handed and ran."""
+    D, Dv = widths
+    T, H = 256, 2
+    monkeypatch.setattr(wa, "_wide_blocks",
+                        lambda *a: wa._square(256))
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q, k = (jax.random.normal(kk, (1, T, H, D), jnp.float32)
+            for kk in ks[:2])
+    v, w = (jax.random.normal(kk, (1, T, H, Dv), jnp.float32)
+            for kk in ks[2:])
+    seg = jnp.asarray(_row(T, [100, 120])[None])
+    mask = segment_mask(seg, seg, causal=True)
+    want = _out_and_grads(
+        lambda *a: attention_reference(*a, mask), q, k, v, w)
+    label = f"narrow-value-{D}"
+    with attention.dispatch_label(label):
+        got = _out_and_grads(
+            lambda *a: wa.window_attention(*a, seg, seg, interpret=True),
+            q, k, v, w)
+    assert got[0].shape == (1, T, H, Dv) and got[3].shape == v.shape
+    for g, r, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        np.testing.assert_allclose(g, r, atol=5e-5, rtol=5e-5, err_msg=name)
+    (seen,) = wa.head_width_counts()[label].values()
+    lanes = -(-D // 128) * 128
+    assert seen == (D, lanes, Dv, 128)

@@ -2,7 +2,8 @@
 measurement behind ``ops/pallas/window_attention.TILE_COST`` (windowed),
 ``CAUSAL_TILE_COST`` (``--window 0``: full causal) and, with ``--window 0
 --head-dim 256 --device``, the wide-head table ``WIDE_BLOCKS`` (heads of
-256 at the GLM cell's 20 / 20 and the Qwen3-Next cell's 16 / 2 heads).
+256 at the GLM cell's 20 / 20 and the Qwen3-Next cell's 16 / 2 heads; with
+``--head-dim 192 --value-dim 128`` the Kimi-Linear cell's 32 / 32).
 
     python tools/window_tile_sweep.py            # on the chip
     python tools/window_tile_sweep.py --compile  # here, for a described v5e
@@ -108,6 +109,9 @@ def main() -> int:
                     help="device ms from a profiler capture")
     ap.add_argument("--heads", type=int, nargs=2, default=[32, 4])
     ap.add_argument("--head-dim", type=int, default=128)
+    ap.add_argument("--value-dim", type=int, default=None,
+                    help="the value head's width where it is not the "
+                    "key's (latent attention's 128 under a key of 192)")
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--out", default="window_tile_sweep")
     ap.add_argument("--compile", action="store_true")
@@ -136,9 +140,10 @@ def main() -> int:
         docs = [L] if layout is None else [int(n) for n in layout.split(",")]
         row = np.repeat(np.arange(1, len(docs) + 1), docs)
         row = np.pad(row, (0, L - len(row))).astype(np.int32)
-        shapes = [jax.ShapeDtypeStruct((R, L, h, args.head_dim), jnp.bfloat16,
+        shapes = [jax.ShapeDtypeStruct((R, L, h, d), jnp.bfloat16,
                                        sharding=sharding)
-                  for h in (hq, hkv, hkv)]
+                  for h, d in ((hq, args.head_dim), (hkv, args.head_dim),
+                               (hkv, args.value_dim or args.head_dim))]
         seg_shape = jax.ShapeDtypeStruct((R, L), jnp.int32, sharding=sharding)
         if not args.compile:
             keys = jax.random.split(jax.random.PRNGKey(0), 3)
@@ -153,6 +158,7 @@ def main() -> int:
                     jnp.float32).sum(), argnums=(0, 1, 2)))
             line = {"kernel": name, "rows": R, "length": L,
                     "heads": [hq, hkv], "head_dim": args.head_dim,
+                    "value_dim": args.value_dim or args.head_dim,
                     "window": args.window, "documents": docs, **what}
             try:
                 if args.compile:
