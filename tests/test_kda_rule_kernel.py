@@ -5,7 +5,10 @@ token-by-token float32 recurrence (``benchmark/reference_kimi_linear.
 delta_rule``, a document at a time) and against the XLA form
 (``models/kda._rule_xla``); documents that
 start inside a chunk, on the chunk grid, in a grid step's second chunk and
-twice in one chunk; a row that is no whole number of chunks; trailing
+twice in one chunk; a row that is no whole number of chunks; rows of 11 and
+17 chunks at 8 a grid step (a last step of 3 chunks and of 1, a document
+that starts inside it and one that ends where a step ends; what the step's
+blocks hold past the row's end must reach nothing); trailing
 padding; decays drawn LOW, so that the state is remembered across chunks and
 grid steps, and decays so STRONG that ``k ⊙ e^{-c}`` — the factor a
 reference outside the chunk's sub-blocks would need — overflows float32;
@@ -99,6 +102,51 @@ def test_a_row_that_is_no_whole_number_of_chunks_and_several_steps():
     want = by_document(*a, seg)
     got = rule("pallas_interpret")(*a, seg)
     assert float(jnp.max(jnp.abs(got - want))) < 2e-6
+
+
+@pytest.mark.parametrize("chunks,strong", [(11, 1.0), (17, 1.0), (11, 200.0)])
+def test_a_short_last_step_against_the_token_scan(chunks, strong):
+    """Two rows of ``chunks`` chunks at 8 a grid step: the last step holds 3
+    chunks of the row (or 1) and reaches 5 (or 7) past its end. Row 0: a
+    document that ends exactly where the first step ends and one that
+    starts inside the short step; row 1: a document's start inside the
+    first step's chunks and trailing padding. The forward and the gradient
+    of all five operands against the token scan, and the counter says 8."""
+    T, step = chunks * Q, 8 * Q
+    rows = [inputs(T, 1, seed=10 + r, strong=strong) for r in range(2)]
+    a = tuple(jnp.concatenate(x) for x in zip(*rows))
+    last = T - (T - 1) % step - 1  # the short step's first token
+    seg = np.zeros((2, T), np.int32)
+    seg[0, :step], seg[0, step:last + 20], seg[0, last + 20:] = 1, 2, 3
+    seg[1, :300], seg[1, 300:T - 40] = 1, 2
+    seg = jnp.asarray(seg)
+    before = dict(kernel.step_counts())
+
+    def loss(fn):
+        def f(*x):
+            o = fn(*x)
+            return jnp.sum(jnp.sin(o) * real(seg)), o
+        return jax.jit(jax.value_and_grad(f, argnums=range(5), has_aux=True))
+
+    def scan(*x):
+        return jnp.concatenate([
+            _scan_row(*(b[r:r + 1] for b in x), seg[r:r + 1])
+            for r in range(2)])
+
+    with jax.default_matmul_precision("highest"):
+        (_, want), gwant = loss(scan)(*a)
+    (_, got), grads = loss(lambda *x: rule("pallas_interpret")(*x, seg))(*a)
+    assert float(jnp.max(jnp.abs((got - want) * real(seg)))) < 2e-6
+    for name, x, w in zip(GRADS, grads, gwant):
+        assert bool(jnp.isfinite(x).all()), name
+        scale = max(float(jnp.max(jnp.abs(w))), 1.0)
+        assert float(jnp.max(jnp.abs(x - w))) < 2e-5 * scale, name
+    after = kernel.step_counts()
+    ran = {k: n - before.get(k, 0) for k, n in after.items()
+           if n > before.get(k, 0)}
+    assert ran == {(2, T, 1, 8, kernel.BWD_CHUNKS_PER_STEP): 2}, ran
+    assert kernel.steps_of(chunks) == (8, kernel.BWD_CHUNKS_PER_STEP)
+    assert kernel.steps_of(3) == (2, 2) and kernel.steps_of(1) == (1, 1)
 
 
 @pytest.mark.parametrize("strong", [1.0, 200.0])

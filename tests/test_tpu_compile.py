@@ -775,6 +775,7 @@ def _compile_kimi():
     from jax.sharding import SingleDeviceSharding
 
     from areal_tpu.models import kda, mla, transformer
+    from areal_tpu.ops.pallas import kda_rule
     from areal_tpu.ops.pallas import window_attention as wa
     from benchmark import weights
 
@@ -815,6 +816,8 @@ def _compile_kimi():
         "rule_kernels": [n for n in ("kda_rule_fwd", "kda_rule_bwd")
                          if n in text],
         "rule_impl": kda.rule_impl_counts(),
+        "rule_steps": ["%dx%d/h%d/fwd%d/bwd%d" % g
+                       for g in kda_rule.step_counts()],
         "rules_traced": {"%dx%d/%d/h%d/%d/r%d" % g: n
                          for g, n in kda.geometry_counts().items()},
         "assemblies_traced": {"%dx%d/h%d/q%dkv%d/%d+%d/v%d" % g: n
@@ -1156,7 +1159,8 @@ def test_the_kimi_cut_compiles_inside_the_memory_it_leaves(compiled_kimi):
     parameters) on 2 x 7,552, the grid of the cell's traffic that needs
     most, at the published widths: every rule the kernel pair, the
     attention kernel handed a key of 192 in 256 lanes and a value of 128
-    in its own. Whether the micro-batch FITS is not read off this program
+    in its own, the rule's kernels at 8 chunks a grid step though 118 chunks
+    are no whole number of steps. Whether the micro-batch FITS is not read off this program
     (the whole tree's gradient at once, returned beside its temporaries:
     6.18 GB + 1.20): the engine's own ``train_grad_sliced`` of this grid
     takes 5.92 GB with head and gradient and runs on the chip beside the
@@ -1165,6 +1169,9 @@ def test_the_kimi_cut_compiles_inside_the_memory_it_leaves(compiled_kimi):
     got = compiled_kimi
     assert got["rule_kernels"] == ["kda_rule_fwd", "kda_rule_bwd"]
     assert set(got["rule_impl"]) == {"pallas"}
+    # a row of 118 chunks (2 x 59), a head group of 8: 8 chunks a grid
+    # step of both kernels, the last step short; never the 2 that divide
+    assert got["rule_steps"] == ["2x7552/h8/fwd8/bwd8"]
     # one rule a run of KDA blocks (block 1's, the expert blocks')
     assert got["rules_traced"] == {"2x7552/64/h32/128/r128": 2}
     assert got["assemblies_traced"] == {"2x7552/h32/q0kv512/128+64/v128": 1}

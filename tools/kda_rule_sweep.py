@@ -1,17 +1,26 @@
 #!/usr/bin/env python3
 """The delta rule with a decay a key channel (ops/pallas/kda_rule.py) on
-the chip: the compiled kernel pair against the XLA form of the same chunk
-algebra (models/kda._rule_xla) and against
-the recurrence a token at a time, and forward / forward + backward times
-of both forms at the Kimi-Linear cell's grids.
+the chip: the compiled kernel pair against the recurrence a token at a time
+(and, with ``--xla``, the XLA form of the same chunk algebra,
+models/kda._rule_xla), forward / forward + backward times at the
+Kimi-Linear cell's grids, the chunks a grid step of either kernel, and
+what the parts of a step cost.
 
     chiprun -- python tools/kda_rule_sweep.py            # parity + times
+    chiprun -- python tools/kda_rule_sweep.py --ablate   # a step's parts
+    chiprun -- python tools/kda_rule_sweep.py --steps 4 8 16
     python tools/kda_rule_sweep.py --compile             # described v5e, here
 
-One JSON line a case on stdout (appended to chiprun_out/kda_rule_sweep.jsonl).
-Times are a host clock around ``--iters`` calls in a row, the device
-finished (a call is tens of milliseconds: the ~0.7 ms round trip is in
-the noise); heads run a group of 8 at a time, as the mixer runs them.
+A shape is ``[rows x]tokens:document,document`` (the documents of every
+row). One JSON line a case on stdout (appended to
+chiprun_out/kda_rule_sweep_pr64.jsonl), each with the module's
+``step_counts()``. Times are a host clock around ``--iters`` calls in a
+row, the device finished (a call is milliseconds: the ~0.7 ms round trip
+is in the noise of ten); heads run a group of 8 at a time, as the mixer
+runs them. ``--ablate`` is the TOOL's: it replaces one part of the module
+at a time by a stand-in of no work (the results are then wrong, the time
+is what is read) — the decay blocks (or their three finest levels), the
+inverse, the states' chain, all three.
 """
 
 from __future__ import annotations
@@ -24,56 +33,119 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+OUT = "chiprun_out/kda_rule_sweep_pr64.jsonl"
+# The cell's grids (6 x 1 x 10,752 and 4 x 2 x 7,552 a step) beside the
+# 8,192 tokens PR 63 read.
+SHAPES = ["8192:1658,6480", "10752:3321,4403,3011", "2x7552:5062,1682"]
 
-def inputs(jnp, jax, T, H, D, docs, dtype, strong, seed=0):
+
+def parse(shape):
+    grid, docs = shape.split(":")
+    rows, _, T = grid.rpartition("x")
+    return int(rows or 1), int(T), [int(x) for x in docs.split(",")]
+
+
+def inputs(jnp, jax, R, T, H, D, docs, dtype, strong, seed=0):
     from areal_tpu.models import gdn
 
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    q = (gdn.l2_normalize(jax.random.normal(ks[0], (1, T, H, D)))
+    q = (gdn.l2_normalize(jax.random.normal(ks[0], (R, T, H, D)))
          * D ** -0.5).astype(dtype)
-    k = gdn.l2_normalize(jax.random.normal(ks[1], (1, T, H, D))).astype(dtype)
-    v = jax.random.normal(ks[2], (1, T, H, D)).astype(dtype)
-    g = -jax.nn.softplus(jax.random.normal(ks[3], (1, T, H, D)) - 3) * strong
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, T, H)))
+    k = gdn.l2_normalize(jax.random.normal(ks[1], (R, T, H, D))).astype(dtype)
+    v = jax.random.normal(ks[2], (R, T, H, D)).astype(dtype)
+    g = -jax.nn.softplus(jax.random.normal(ks[3], (R, T, H, D)) - 3) * strong
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (R, T, H)))
     starts = [0]
     for n in docs:
         starts.append(starts[-1] + n)
     pos = jnp.arange(T)
     seg = sum((pos >= s).astype(jnp.int32) for s in starts[:-1])
-    seg = jnp.where(pos < starts[-1], seg, 0)[None]
+    seg = jnp.broadcast_to(jnp.where(pos < starts[-1], seg, 0), (R, T))
     return q, k, v, g, beta, seg
 
 
 def token_scan(jax, jnp, q, k, v, g, beta, seg):
     q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
-    H, dk = q.shape[2:]
+    R, _, H, dk = q.shape
 
     def step(carry, x):
         S, prev = carry
         q, k, v, g, b, s = x
-        S = jnp.where(s != prev, 0.0, S)
-        S = jnp.exp(g)[:, :, None] * S
-        d = b[:, None] * (v - jnp.einsum("hkv,hk->hv", S, k))
-        S = S + k[:, :, None] * d[:, None, :]
-        return (S, s), jnp.einsum("hkv,hk->hv", S, q)
+        S = jnp.where((s != prev)[:, None, None, None], 0.0, S)
+        S = jnp.exp(g)[..., None] * S
+        d = b[..., None] * (v - jnp.einsum("rhkv,rhk->rhv", S, k))
+        S = S + k[..., None] * d[:, :, None, :]
+        return (S, s), jnp.einsum("rhkv,rhk->rhv", S, q)
 
-    _, o = jax.lax.scan(step, (jnp.zeros((H, dk, dk)), jnp.int32(-1)),
-                        (q[0], k[0], v[0], g[0], beta[0], seg[0]))
-    return o[None]
+    _, o = jax.lax.scan(
+        step, (jnp.zeros((R, H, dk, dk)), jnp.full((R,), -1, jnp.int32)),
+        tuple(jnp.swapaxes(a, 0, 1) for a in (q, k, v, g, beta, seg)))
+    return jnp.swapaxes(o, 0, 1)
+
+
+def ablations(jnp, rule):
+    """{name: the module's functions that a stand-in of no work replaces}."""
+    halvings = rule._halvings
+
+    def blocks(c, qf, kf, kbf, cd, exact):
+        z = jnp.zeros(c.shape[:2] + c.shape[1:2], jnp.float32)
+        return z, z
+
+    def product(spec, a, b, exact):
+        ins, out = spec.split("->")
+        dims = {x: d for s, t in zip(ins.split(","), (a, b))
+                for x, d in zip(s, t.shape)}
+        return (jnp.zeros([dims[x] for x in out], jnp.float32)
+                + jnp.sum(a.astype(jnp.float32)) * 0
+                + jnp.sum(b.astype(jnp.float32)) * 0)
+
+    def column(kappa, width):
+        return jnp.zeros(kappa.shape[:1] + (kappa.shape[2], width),
+                         jnp.float32) + jnp.max(kappa)
+
+    def inverses(A, exact):
+        return A
+
+    def walk(nc, chunk):
+        return None
+
+    parts = {"decay_blocks": ("_decay_blocks", blocks),
+             "inverse": ("_unit_inverses", inverses),
+             "chain": ("_walk", walk)}
+    cases = {"whole": []}
+    cases.update({"no_" + n: [p] for n, p in parts.items()})
+    # the decay blocks' levels w = 8, 16, 32 alone: three products of six
+    cases["levels_from_8"] = [("_halvings", lambda c: halvings(c)[3:])]
+    cases["none_of_the_three"] = list(parts.values())
+    # ... and of what then remains: the cumulated decay (a float32 product),
+    # κ down the sublanes (a transpose), the six products of U, W, G, C, P W
+    # and P U
+    rest = {"cumulate": ("_cumulate", lambda g: g),
+            "column": ("_column", column), "products": ("_product", product)}
+    for n, p in rest.items():
+        cases["none_nor_" + n] = list(parts.values()) + [p]
+    cases["none_nor_any"] = list(parts.values()) + list(rest.values())
+    return cases
 
 
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--compile", action="store_true")
+    ap.add_argument("--ablate", action="store_true")
+    ap.add_argument("--xla", action="store_true")
+    ap.add_argument("--interpret", action="store_true",
+                    help="rehearse on the CPU, at a tiny shape")
+    ap.add_argument("--steps", type=int, nargs="*", default=[])
     ap.add_argument("--heads", type=int, default=8)
-    ap.add_argument("--iters", type=int, default=5)
-    ap.add_argument("--shapes", nargs="*", default=[
-        "8192:1658,6480", "7552:5062,1682"])
+    ap.add_argument("--iters", type=int, default=10)
+    ap.add_argument("--shapes", nargs="*", default=SHAPES)
+    ap.add_argument("--dtypes", nargs="*", default=["bfloat16", "float32"])
     args = ap.parse_args()
     import jax
     import jax.numpy as jnp
 
-    from areal_tpu.models import gdn, kda
+    from areal_tpu.models import kda
+    from areal_tpu.ops.pallas import kda_rule as rule
 
     if args.compile:
         from jax.experimental import topologies
@@ -87,73 +159,137 @@ def main() -> int:
     os.makedirs("chiprun_out", exist_ok=True)
 
     def emit(line):
+        line["step_counts"] = [list(k) + [n]
+                               for k, n in rule.step_counts().items()]
+        line["device"] = ("described v5e" if args.compile
+                          else jax.devices()[0].device_kind)
+        head_chunks = line.pop("head_chunks", None)
+        if "kernel_fwd_ms" in line:
+            line["fwd_us_head_chunk"] = round(
+                1e3 * line["kernel_fwd_ms"] / head_chunks, 3)
         print(json.dumps(line), flush=True)
-        with open("chiprun_out/kda_rule_sweep.jsonl", "a") as f:
-            f.write(json.dumps(line) + "\n")
+        if not args.interpret:  # a rehearsal's times are no one's numbers
+            with open(OUT, "a") as f:
+                f.write(json.dumps(line) + "\n")
 
     def loss(fn):
         return lambda q, k, v, g, b, seg: jnp.sum(
             jnp.sin(fn(q, k, v, g, b, seg).astype(jnp.float32)))
 
     def kernel(q, k, v, g, b, seg):
-        return kda.channel_decay_rule(q, k, v, g, b, seg, 64, "pallas")
+        return kda.channel_decay_rule(
+            q, k, v, g, b, seg, 64,
+            "pallas_interpret" if args.interpret else "pallas")
 
     def xla(q, k, v, g, b, seg):
         return kda.channel_decay_rule(q, k, v, g, b, seg, 64, "xla")
 
+    def grad_of(fn):
+        return jax.jit(jax.grad(loss(fn), argnums=(0, 1, 2, 3, 4)))
+
+    def timed(f, a):
+        jax.block_until_ready(f(*a))
+        t0 = time.perf_counter()
+        for _ in range(args.iters):
+            out = f(*a)
+        jax.block_until_ready(out)
+        return round(1e3 * (time.perf_counter() - t0) / args.iters, 3)
+
+    def times(a, line, between=lambda: None):
+        """A fresh trace of the kernel pair (the module as it stands) into
+        ``line``, or what the chip's compiler refused of it."""
+        try:
+            line["kernel_fwd_ms"] = timed(jax.jit(lambda *x: kernel(*x)), a)
+            between()
+            line["kernel_grad_ms"] = timed(grad_of(lambda *x: kernel(*x)), a)
+        except Exception as e:  # noqa: BLE001 — the record is the point
+            line["error"] = repr(e)[:300]
+
     for shape in args.shapes:
-        T, docs = shape.split(":")
-        T, docs = int(T), [int(x) for x in docs.split(",")]
-        for dtype in (jnp.bfloat16, jnp.float32):
-            name = jnp.dtype(dtype).name
-            if args.compile:
+        R, T, docs = parse(shape)
+        chunks = R * H * -(-T // 64)
+        if args.compile:
+            for dtype in (jnp.bfloat16, jnp.float32):
                 sds = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (
-                    ((1, T, H, D), dtype), ((1, T, H, D), dtype),
-                    ((1, T, H, D), dtype), ((1, T, H, D), jnp.float32),
-                    ((1, T, H), jnp.float32), ((1, T), jnp.int32))]
+                    ((R, T, H, D), dtype), ((R, T, H, D), dtype),
+                    ((R, T, H, D), dtype), ((R, T, H, D), jnp.float32),
+                    ((R, T, H), jnp.float32), ((R, T), jnp.int32))]
                 t0 = time.time()
                 c = jax.jit(jax.value_and_grad(
                     loss(kernel), argnums=(0, 1, 2, 3, 4))).lower(
                         *sds).compile()
-                emit({"compile": shape, "dtype": name,
+                emit({"compile": shape, "dtype": jnp.dtype(dtype).name,
                       "seconds": round(time.time() - t0, 2),
                       "temp_bytes": c.memory_analysis().temp_size_in_bytes,
-                      "kernels": [n for n in ("kda_rule_fwd", "kda_rule_bwd")
+                      "kernels": [n for n in (rule.FWD_NAME, rule.BWD_NAME)
                                   if n in c.as_text()]})
-                continue
+            continue
+        if args.ablate or args.steps:
+            a = inputs(jnp, jax, R, T, H, D, docs, jnp.bfloat16, 1.0)
+            for name, parts in (ablations(jnp, rule).items()
+                                if args.ablate else ()):
+                kept = [(n, getattr(rule, n)) for n, _ in parts]
+                for n, stand_in in parts:
+                    setattr(rule, n, stand_in)
+                line = {"ablate": name, "shape": shape, "dtype": "bfloat16",
+                        "head_chunks": chunks}
+                try:
+                    times(a, line)
+                finally:
+                    for n, f in kept:
+                        setattr(rule, n, f)
+                emit(line)
+            kept = rule.CHUNKS_PER_STEP, rule.BWD_CHUNKS_PER_STEP
+            for n in args.steps:
+                rule.CHUNKS_PER_STEP = rule.BWD_CHUNKS_PER_STEP = n
+                line = {"chunks_per_step": n, "shape": shape,
+                        "dtype": "bfloat16", "head_chunks": chunks}
+                # the backward kernel alone at n: the forward's constant back
+
+                def forward_back():
+                    rule.CHUNKS_PER_STEP = kept[0]
+
+                times(a, line, forward_back)
+                emit(line)
+            rule.CHUNKS_PER_STEP, rule.BWD_CHUNKS_PER_STEP = kept
+            continue
+        for dtype in (jnp.dtype(d) for d in args.dtypes):
             for strong in (1.0, 60.0):
-                a = inputs(jnp, jax, T, H, D, docs, dtype, strong)
+                a = inputs(jnp, jax, R, T, H, D, docs, dtype, strong)
                 real = (a[5] > 0)[..., None, None]
-                line = {"shape": shape, "dtype": name, "decay_x": strong}
-                fwd = {"kernel": jax.jit(kernel), "xla": jax.jit(xla)}
-                grad = {n: jax.jit(jax.grad(loss(f), argnums=(0, 1, 2, 3, 4)))
-                        for n, f in (("kernel", kernel), ("xla", xla))}
+                line = {"shape": shape, "dtype": jnp.dtype(dtype).name,
+                        "decay_x": strong, "head_chunks": chunks}
+                forms = {"kernel": kernel}
+                if args.xla:
+                    forms["xla"] = xla
+                # the scan's backward keeps a state a token: 0.5 MB each
+                with_grads = R * T <= 8192
                 with jax.default_matmul_precision("highest"):
                     want = jax.jit(lambda *a: token_scan(jax, jnp, *a))(*a)
-                    gwant = jax.jit(jax.grad(
-                        loss(lambda *a: token_scan(jax, jnp, *a)),
-                        argnums=(0, 1, 2, 3, 4)))(*a)
-                for n in fwd:
-                    o = fwd[n](*a)
-                    gs = grad[n](*a)
+                    if with_grads:
+                        gwant = grad_of(
+                            lambda *a: token_scan(jax, jnp, *a))(*a)
+                for n, f in forms.items():
+                    o = jax.jit(f)(*a)
+                    gs = grad_of(f)(*a)
                     line[n + "_fwd_err"] = float(jnp.max(jnp.abs(
                         (o - want) * real)))
+                    line[n + "_fwd_median_rel_err"] = float(
+                        jnp.median(jnp.abs(o - want)[a[5] > 0])
+                        / jnp.median(jnp.abs(want)[a[5] > 0]))
                     line[n + "_finite"] = bool(all(
                         jnp.isfinite(x.astype(jnp.float32)).all()
                         for x in (o,) + tuple(gs)))
-                    line[n + "_grad_err"] = [float(jnp.max(jnp.abs(
-                        x.astype(jnp.float32) - w))) for x, w in zip(gs, gwant)]
-                    for what, f in (("fwd", fwd[n]), ("grad", grad[n])):
-                        jax.block_until_ready(f(*a))
-                        t0 = time.perf_counter()
-                        for _ in range(args.iters):
-                            out = f(*a)
-                        jax.block_until_ready(out)
-                        line[f"{n}_{what}_ms"] = round(
-                            1e3 * (time.perf_counter() - t0) / args.iters, 3)
+                    if with_grads:
+                        line[n + "_grad_err"] = [
+                            float(jnp.max(jnp.abs(x.astype(jnp.float32) - w)))
+                            for x, w in zip(gs, gwant)]
+                    line[n + "_fwd_ms"] = timed(jax.jit(f), a)
+                    line[n + "_grad_ms"] = timed(grad_of(f), a)
                 line["scale"] = float(jnp.max(jnp.abs(want)))
-                line["grad_scale"] = [float(jnp.max(jnp.abs(w)))
-                                      for w in gwant]
+                if with_grads:
+                    line["grad_scale"] = [float(jnp.max(jnp.abs(w)))
+                                          for w in gwant]
                 emit(line)
     return 0
 
