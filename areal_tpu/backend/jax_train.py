@@ -1197,6 +1197,13 @@ class JaxTrainEngine(TrainableEngine):
             if frac is not None:
                 telemetry.set_gauge("train/kda_kernel_frac", frac)
                 span_attrs["kda_kernel_frac"] = frac
+            # ... and of the traced mixers, those whose ends (β, the l2
+            # norms, the decay's activation, the gated norm) ran inside
+            # that pair, all heads at once (models/kda.mixer_norm_counts)
+            frac = kdamod.norms_in_kernel_frac()
+            if frac is not None:
+                telemetry.set_gauge("train/kda_norms_in_kernel_frac", frac)
+                span_attrs["kda_norms_in_kernel_frac"] = frac
         if self.cfg.gdn is not None:
             from areal_tpu.models import gdn as gdnmod
 

@@ -7,6 +7,17 @@ chunks: on a TPU, at heads of 128, the Pallas kernel pair of
 scan under blocks of its own (a decay a channel does not leave the chunk's
 products as a factor).
 
+Where the kernels run, the mixer between its convolution and its
+out-projection IS the kernel pair (:func:`rule_with_ends`, all heads at
+once, no kernel run twice: :func:`_mixer_in_kernels`): the SiLU, β's
+products, both l2 norms, the decay's activation and the gated norm run
+inside ``kda_rule_fwd`` / ``kda_rule_bwd`` on a head's 128 lanes, and XLA
+holds no array by head, no float32 g or o (:func:`mixer_norm_counts` says
+which mixers did). Elsewhere the XLA text below, a group of heads at a
+time under a checkpoint of its own. :func:`channel_decay_rule` is the RULE
+alone either way (l2-normed operands in, float32 o out): what the tests
+and the benchmark's ``rule_error`` hold to the token scan.
+
 One mixer, ``u = norm(h)`` [B, T, D] (models/transformer.py adds the
 residual and the block's FFN); ``H`` heads, key and value both ``dh``; the
 two gates come through a bottleneck of ``gate_rank``:
@@ -29,7 +40,10 @@ document's first token and a convolution tap that would read across a
 document's start reads 0, both masks on what is multiplied.
 
 Device scopes (base/telemetry.KDA_SCOPES): ``kda_in_proj``, ``kda_conv``,
-``kda_gates``, ``kda_rule``, ``kda_gate_norm``, ``kda_out_proj``.
+``kda_gates``, ``kda_rule``, ``kda_gate_norm``, ``kda_out_proj`` (on the
+kernel path nothing runs under ``kda_gates`` / ``kda_gate_norm``: the
+gates' two expansions are matmuls of ``kda_in_proj``, the rest is inside
+the kernels under ``kda_rule``).
 :func:`geometry_counts` is the trace-time count of the rules a compiled
 program holds.
 """
@@ -47,6 +61,7 @@ import jax.numpy as jnp
 from areal_tpu.models.config import KDAConfig
 from areal_tpu.models.gdn import (
     _HEAD_GROUPS,
+    L2_EPS,
     _carry_states,
     _unit_lower_inverse,
     l2_normalize,
@@ -83,6 +98,23 @@ def rule_kernel_frac() -> Optional[float]:
     None before the first trace."""
     total = sum(_RULE_IMPL.values())
     return (total - _RULE_IMPL["xla"]) / total if total else None
+
+
+# Where each traced mixer's per-head work ran (:func:`kda_mixer`): "kernel"
+# — β, the l2 norms, the decay's activation and the gated norm inside the
+# rule's kernels, all heads at once (:func:`rule_with_ends`) — or "xla".
+_MIXER_NORMS: collections.Counter = collections.Counter()
+
+
+def mixer_norm_counts() -> Dict[str, int]:
+    return dict(_MIXER_NORMS)
+
+
+def norms_in_kernel_frac() -> Optional[float]:
+    """Of the mixers traced so far, the share whose ends ran inside the
+    rule's kernels; None before the first trace."""
+    total = sum(_MIXER_NORMS.values())
+    return _MIXER_NORMS["kernel"] / total if total else None
 
 
 def param_shapes(kda: KDAConfig, hidden_dim: int,
@@ -304,6 +336,146 @@ def _rule_kernel_bwd(chunk, how, res, do):
 _rule_kernel.defvjp(_rule_kernel_fwd, _rule_kernel_bwd)
 
 
+def rule_with_ends(x: jnp.ndarray,  # [B, T, H · 3 dh]: a head's [q | k | v]
+                   a: jnp.ndarray,  # [B, T, H · dh]: f · kda_f_b
+                   gate: jnp.ndarray,  # [B, T, H · dh]: z · kda_g_b
+                   beta: jnp.ndarray,  # [B, T, H] float32
+                   A_log: jnp.ndarray, dt_bias: jnp.ndarray,  # [H], [H · dh]
+                   norm: jnp.ndarray,  # [dh] the gated norm's weight
+                   seg: jnp.ndarray, chunk: int, eps: float,
+                   how: str) -> jnp.ndarray:
+    """The mixer between its convolution and its out-projection as the ONE
+    kernel pair (``kda_rule.mixer_fwd`` / ``mixer_bwd``): the SiLU behind
+    the convolution, the two l2 norms, the decay's activation, β's two
+    products, the rule, and ``y = rms(o) · norm ⊙ sigmoid(gate)`` [B, T, H ·
+    dh] in the compute dtype — the arithmetic and the rounding points of
+    :func:`kda_mixer`'s XLA text with no array by head, no float32 g or o
+    and no ``kb`` / ``vb`` outside the kernels. ``how``: "pallas" |
+    "pallas_interpret", as :func:`_rule_impl` answered."""
+    return _ends((x, a, gate), _as_given, beta, A_log, dt_bias, norm, seg,
+                 chunk, eps, how)
+
+
+def _ends(ops, make, beta, A_log, dt_bias, norm, seg, chunk: int, eps: float,
+          how: str) -> jnp.ndarray:
+    """:func:`rule_with_ends` on the x, a and gate that ``make(*ops, seg)``
+    makes — in the forward and AGAIN in the backward: what is kept between
+    the passes is ``ops`` (:func:`_conv_and_gates`)."""
+    from areal_tpu.ops.pallas import kda_rule as kernel
+
+    _RULE_IMPL[how] += 1
+    with jax.named_scope("kda_rule"):
+        par = kernel.mixer_parameters(A_log, dt_bias, norm)
+    y = _ends_kernel(ops, beta.astype(jnp.float32), par, seg, make, chunk,
+                     (L2_EPS, float(eps)), how)
+    return y[:, :seg.shape[1]]
+
+
+def _as_given(x, a, gate, seg):
+    return x, a, gate
+
+
+def _kernel_operands(make, ops, beta, seg, chunk: int):
+    """(x, a, gate, β) as the kernels take them: made of ``ops``, the row a
+    whole number of chunks (a token of zeros norms to zero and writes
+    nothing); and the row's segment ids likewise."""
+    x, a, gate = make(*ops, seg)
+    pad = -x.shape[1] % chunk
+    if pad:
+        x, a, gate, beta = (jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
+                            for v in (x, a, gate, beta))
+        seg = jnp.pad(seg, ((0, 0), (0, pad)))
+    return (x, a, gate, beta), seg
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _ends_kernel(ops, beta, par, seg, make, chunk, eps, how):
+    from areal_tpu.ops.pallas import kda_rule as kernel
+
+    made, segs = _kernel_operands(make, ops, beta, seg, chunk)
+    with jax.named_scope("kda_rule"):
+        return kernel.mixer_fwd(*made, par, segs, chunk, eps,
+                                interpret=how == "pallas_interpret")[0]
+
+
+def _ends_kernel_fwd(ops, beta, par, seg, make, chunk, eps, how):
+    from areal_tpu.ops.pallas import kda_rule as kernel
+
+    made, segs = _kernel_operands(make, ops, beta, seg, chunk)
+    with jax.named_scope("kda_rule"):
+        y, states = kernel.mixer_fwd(*made, par, segs, chunk, eps, keep=True,
+                                     interpret=how == "pallas_interpret")
+    return y, (ops, beta, par, seg, states)
+
+
+def _ends_kernel_bwd(make, chunk, eps, how, res, dy):
+    from areal_tpu.ops.pallas import kda_rule as kernel
+
+    ops, beta, par, seg, states = res
+    # as ``jax.checkpoint`` does: made again only once dy is there (else
+    # XLA finds the forward's own and keeps them alive in between)
+    ops, dy = jax.lax.optimization_barrier((ops, dy))
+    made, pull, segs = jax.vjp(
+        lambda ops, beta: _kernel_operands(make, ops, beta, seg, chunk),
+        ops, beta, has_aux=True)
+    with jax.named_scope("kda_rule"):
+        *d_made, d_par = kernel.mixer_bwd(
+            *made, par, segs, states, dy, chunk, eps,
+            interpret=how == "pallas_interpret")
+    return pull(tuple(d_made)) + (d_par, None)
+
+
+_ends_kernel.defvjp(_ends_kernel_fwd, _ends_kernel_bwd)
+
+
+def _heads_together(w: jnp.ndarray, H: int) -> jnp.ndarray:
+    """A WEIGHT's columns [.., q | k | v] (H · dh each) -> a head's [q | k |
+    v] side by side, [.., H · 3 dh]: what it multiplies then comes out in
+    the kernels' layout (a head's three 128-lane blocks are ONE block of
+    an operand and of its cotangent), and no activation is moved."""
+    by = w.reshape(w.shape[:-1] + (3, H, w.shape[-1] // (3 * H)))
+    return jnp.swapaxes(by, -3, -2).reshape(w.shape)
+
+
+def _conv_and_gates(x, conv, f, z, f_b, g_b, seg):
+    """The kernels' three wide operands from what the in-projections leave:
+    the convolution over all of [q | k | v] (its SiLU is the kernels') and
+    the two gates' expansions. :func:`_ends` runs this in BOTH passes: of
+    a mixer's [T, 5 · H · dh] of kernel operands only the projection ([T, 3
+    · H · dh], which the convolution's backward reads anyway) is alive
+    while the block's FFN runs its backward — with the operands kept the 2
+    x 7,552 grid's carried grad program booked 6.47 GB where the head
+    groups' checkpoint had it at 5.58, made again 5.61 (PERF.md §6, PR
+    65)."""
+    with jax.named_scope("kda_conv"):
+        x = causal_conv(x, conv, 0.0, seg)
+    with jax.named_scope("kda_in_proj"):
+        return x, f @ f_b, z @ g_b
+
+
+def _mixer_in_kernels(u, lp, kda: KDAConfig, eps: float, seg, how: str):
+    """:func:`kda_mixer` where the rule runs as the kernel pair: all heads
+    at once, no kernel run twice and nothing by head outside the kernels —
+    two in-projections, ONE convolution over [B, T, 3 · H · dh], the two
+    gates' expansions as two matmuls, :func:`rule_with_ends`' kernels, the
+    out-projection. Which kernel a block's backward re-runs is its remat
+    entry's to say (the head groups of the XLA form bound float32 arrays
+    that do not exist here; of XLA's own only the convolution and the
+    gates' expansions run once more: :func:`_conv_and_gates`)."""
+    H, rank = kda.n_heads, kda.gate_rank
+    with jax.named_scope("kda_in_proj"):
+        x = u @ _heads_together(lp["kda_qkv"], H)
+        b, f, z = jnp.split(u @ lp["kda_gates_a"], [H, H + rank], axis=-1)
+    with jax.named_scope("kda_rule"):
+        beta = jax.nn.sigmoid(b.astype(jnp.float32))
+    y = _ends((x, _heads_together(lp["kda_conv"], H), f, z, lp["kda_f_b"],
+               lp["kda_g_b"]), _conv_and_gates, beta, lp["kda_A_log"],
+              lp["kda_dt_bias"], lp["kda_norm"], seg, kda.chunk_size, eps,
+              how)
+    with jax.named_scope("kda_out_proj"):
+        return y @ lp["kda_out"]
+
+
 def kda_mixer(u: jnp.ndarray,  # [B, T, D] the normed residual stream
               lp: Dict[str, jnp.ndarray],  # this layer's parameters
               kda: KDAConfig, eps: float,
@@ -318,13 +490,16 @@ def kda_mixer(u: jnp.ndarray,  # [B, T, D] the normed residual stream
            else segment_ids)
     _GEOMETRY[(B_, T, kda.chunk_size, H, dh, rank)] += 1
     how = _rule_impl(impl, kda, u.dtype)
-    # The per-head work runs a group of heads at a time, each under its own
-    # checkpoint (models/gdn.py, ``_HEAD_GROUPS``) — on the kernel path
-    # too: a decay a CHANNEL makes g, its pre-activation, the float32 o and
-    # the kernels' operands and cotangents [T, H · dh] arrays each, 3.6 GB
-    # of them a mixer at a 16,384-token row where all 32 heads run at once
-    # (PERF.md §6, PR 63); a group at a time they are a quarter, at the
-    # price of one more forward of the group in the backward pass.
+    _MIXER_NORMS["xla" if how == "xla" else "kernel"] += 1
+    if how != "xla":
+        return _mixer_in_kernels(u, lp, kda, eps, seg, how)
+    # The XLA form's per-head work runs a group of heads at a time, each
+    # under its own checkpoint (models/gdn.py, ``_HEAD_GROUPS``): a decay a
+    # CHANNEL makes g, its pre-activation, the float32 o and the rule's
+    # operands and cotangents [T, H · dh] arrays each, 3.6 GB of them a
+    # mixer at a 16,384-token row where all 32 heads run at once (PERF.md
+    # §6, PR 63); a group at a time they are a quarter, at the price of one
+    # more forward of the group in the backward pass.
     n = math.gcd(H, _HEAD_GROUPS)
     Hn = H // n
 

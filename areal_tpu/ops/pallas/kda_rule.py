@@ -6,9 +6,10 @@ for packed segment batches.
 (S [dk, dv] float32 a head, zero before a document's first token; ``g_t``
 [dk] <= 0) — the chunked algorithm of ``models/gdn.gated_delta_rule`` at a
 decay a channel, one grid step a (row, head, run of chunks), the chunk axis
-innermost and sequential. β never enters the kernels: the caller hands
-them ``kb = β ⊙ k`` and ``vb = β ⊙ v`` beside ``k``, and β's gradient is
-XLA's through those two products. With ``c`` the cumulated ``g`` inside a
+innermost and sequential. The chunk algebra never sees β: it takes ``kb =
+β ⊙ k`` and ``vb = β ⊙ v`` beside ``k`` (the plain entry's caller makes
+them, and β's gradient is XLA's through those two products; the mixer's
+entry makes them inside, below). With ``c`` the cumulated ``g`` inside a
 chunk [Q, dk] and ``S₀`` the state entering it:
 
     A_ij = Σ_d kb_id k_jd e^{c_id − c_jd}    j < i, one document; else exactly 0
@@ -77,6 +78,40 @@ multiplies: :func:`_product`) and the decay blocks' (the same levels and
 factors; the decays' own is ``kb ⊙ dkb + q ⊙ dq − k ⊙ dk`` over the terms
 that pass an exponent, so no reference row needs a gradient).
 
+**The mixer's ends inside** (``ends``, a static flag of the same two
+kernels: :func:`mixer_fwd` / :func:`mixer_bwd`, what
+``models/kda.rule_with_ends`` runs — ``gated_delta_rule.py``'s ``norms``
+at a decay a channel). A grid step holds ONE head's 128 lanes of
+everything, so all the mixer does by head between its convolution and its
+out-projection is elementwise work and lane reductions on blocks that are
+in VMEM anyway, and no XLA op sees an array by head (nor a float32 g or o,
+nor ``kb`` / ``vb``):
+
+ - operands as the matmuls and the convolution leave them, flat, a head a
+   lane block: x [R, T, H · 3 dk] — a head's [q | k | v] side by side (the
+   mixer permutes the WEIGHTS' columns, so one block is the head's three
+   and d x leaves as one array) —, the decay's pre-activation ``a`` and the
+   output gate [R, T, H · dk], all in the compute dtype; β rides row 4 of a
+   per-head copy of the mask tile (:func:`mask_tiles`); ``-exp(A_log)``,
+   ``dt_bias`` and the gated norm's weight ride one [8, 128] tile a head
+   (:func:`mixer_parameters`);
+ - on the way in (:func:`_ends_in`, for all the step's chunks at once): the
+   SiLU, the two l2 norms in float32 (q times ``dk ** -0.5``), ``g = rate ⊙
+   softplus(a + dt_bias)`` in float32, then ``kb``, ``vb`` — each rounded
+   where ``models/kda.kda_mixer``'s XLA text rounds;
+ - on the way out the forward keeps the step's o in VMEM and, after the
+   states' chain, writes ``y = (rms(o) · w).astype(cd) ⊙ sigmoid(gate)`` in
+   the compute dtype (:func:`_gated_norm`);
+ - the backward takes d y, REBUILDS the chunks' o from the kept states and
+   the blocks it rebuilds anyway (``q̃ S₀ + P U``: one more product a step,
+   nothing kept for it), pulls d y back through :func:`_gated_norm` and the
+   rule's cotangents through β's products and :func:`_ends_in` — both
+   under ``jax.vjp`` in the kernel body, as :func:`_blocks` is, so the two
+   passes cannot disagree on a rounding point — and writes d x, d a, d gate
+   in the compute dtype, d β a token a lane into a tile (a lane sum on the
+   MXU: ``gated_delta_rule._lane_sums``) and the parameter tile's
+   gradient, summed over a row's steps where it stays.
+
 The kernels' device ops are named ``kda_rule_fwd`` / ``kda_rule_bwd`` under
 the caller's scope (not jitted by themselves: the benchmark reads the rule
 by its scope ``kda_rule``). :func:`step_counts` is the trace-time count of
@@ -102,7 +137,10 @@ from areal_tpu.ops.pallas.gated_delta_rule import (  # noqa: F401
     _dot,
     _dots,
     _inverses,
+    _l2_parts,
+    _lane_sums,
     _params,
+    _rms_parts,
     fits_device,
 )
 
@@ -122,7 +160,10 @@ CHUNKS_PER_STEP = 8
 # The backward's own (all three of its phases hold a step's chunks).
 BWD_CHUNKS_PER_STEP = 8
 # The rows of a chunk's mask tile (:func:`mask_tiles`).
-_SEG, _ENTERS, _TO_END, _KEEPS = 0, 1, 2, 3
+_SEG, _ENTERS, _TO_END, _KEEPS, _BETA = 0, 1, 2, 3, 4
+# The rows of the mixer's parameter tile (:func:`mixer_fwd`), a head's 128
+# lanes of each: ``-exp(A_log)``, ``dt_bias``, the gated norm's weight.
+_RATE, _DT_BIAS, _NORM = 0, 1, 2
 _STEPS: collections.Counter = collections.Counter()
 
 
@@ -342,14 +383,56 @@ def _blocks(q, k, kb, vb, g, tiles, exact: bool):
     return G, C, qt, _product("nij,njk->nik", P, Uc, exact), kappa
 
 
-def mask_tiles(seg, chunk: int, chunks: int = 0):
+@jax.custom_vjp
+def _softplus(x):
+    return jnp.maximum(x, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(x)))
+
+
+_softplus.defvjp(lambda x: (_softplus(x), x),
+                 lambda x, ct: (ct * jax.nn.sigmoid(x),))
+
+
+def _ends_in(qr, kr, vr, a, rate, dt_bias, l2_eps: float, cd):
+    """The mixer's way INTO the rule, for all the step's chunks at once:
+    qr, kr, vr [n, Q, dk] as the convolution leaves them and ``a`` [n, Q,
+    dk] as the decay's matmul leaves it, in the compute dtype; ``rate``
+    (``-exp(A_log)``) and ``dt_bias`` [1, dk] float32 -> q, k, v in the
+    compute dtype and g float32: the SiLU, the two l2 norms (float32, a
+    lane reduction a token; q times ``dk ** -0.5``) and the decay's
+    activation, each rounded where ``models/kda.kda_mixer``'s XLA text
+    rounds. Differentiable in everything (the backward kernel's
+    ``jax.vjp``); a token of zeros gives q = k = v = 0."""
+    f32 = jnp.float32
+
+    def silu(x):
+        x = x.astype(f32)
+        return (x * jax.nn.sigmoid(x)).astype(cd)
+
+    q = (_l2_parts(silu(qr), l2_eps)[0] * qr.shape[-1] ** -0.5).astype(cd)
+    k = _l2_parts(silu(kr), l2_eps)[0].astype(cd)
+    return q, k, silu(vr), rate * _softplus(a.astype(f32) + dt_bias)
+
+
+def _gated_norm(o, gate, w, eps: float, cd):
+    """... and OUT of it: o [n, Q, dv] float32, ``gate`` [n, Q, dv] as the
+    gate's matmul leaves it, ``w`` [1, dv] float32 -> the mixer's ``y =
+    (rms(o) · w).astype(cd) ⊙ sigmoid(gate)`` in the compute dtype."""
+    f32 = jnp.float32
+    y = (_rms_parts(o, eps, o.shape[-1])[0] * w).astype(cd)
+    return (y.astype(f32) * jax.nn.sigmoid(gate.astype(f32))).astype(cd)
+
+
+def mask_tiles(seg, chunk: int, chunks: int = 0, beta=None):
     """seg [R, T] (T whole chunks) -> [R, chunks, 8, 128] float32, a
     chunk's tile: row 0 the segment ids on the first ``chunk`` lanes; 1
     the tokens (1.0) still in the document the row was in before the chunk
     (none in the row's first chunk); 2 those of the document the chunk
     ends in; 3 on every lane whether those two documents are one. With
     ``chunks`` past the row's own (whole grid steps), chunks of segment 0
-    behind it."""
+    behind it. With ``beta`` [R, T, H] a tile a HEAD, [R, H, chunks, 8,
+    128], whose row 4 holds the head's β, a token a lane (what
+    ``gated_delta_rule.gate_tiles`` does for its gates: the transpose the
+    kernels take of a tile anyway hands them β down the sublanes)."""
     R, T = seg.shape
     Q = chunk
     Z = max(chunks, T // Q)
@@ -359,16 +442,24 @@ def mask_tiles(seg, chunk: int, chunks: int = 0):
     last = segz[:, :, -1]
     prev = jnp.pad(last, ((0, 0), (1, 0)), constant_values=-1)[:, :Z]
 
-    def lanes(a):  # [R, Z, Q] -> [R, Z, 1, 128]
-        return jnp.pad(a.astype(f32), ((0, 0), (0, 0), (0, LANE - Q))
-                       )[:, :, None]
+    def lanes(a):  # [.., Z, Q] -> [.., Z, 1, 128]
+        return jnp.pad(a.astype(f32), ((0, 0),) * (a.ndim - 1)
+                       + ((0, LANE - Q),))[..., None, :]
 
     keeps = jnp.broadcast_to((last == prev).astype(f32)[:, :, None, None],
                              (R, Z, 1, LANE))
-    return jnp.concatenate(
+    masks = jnp.concatenate(
         [lanes(segz), lanes(segz == prev[..., None]),
-         lanes(segz == last[..., None]), keeps,
-         jnp.zeros((R, Z, SUBLANE - 4, LANE), f32)], axis=2)
+         lanes(segz == last[..., None]), keeps], axis=2)
+    if beta is None:
+        return jnp.concatenate(
+            [masks, jnp.zeros((R, Z, SUBLANE - 4, LANE), f32)], axis=2)
+    H = beta.shape[2]
+    b = jnp.pad(beta.astype(f32), ((0, 0), (0, Z * Q - T), (0, 0)))
+    b = jnp.moveaxis(b.reshape(R, Z, Q, H), 3, 1)  # [R, H, Z, Q]
+    return jnp.concatenate(
+        [jnp.broadcast_to(masks[:, None], (R, H) + masks.shape[1:]),
+         lanes(b), jnp.zeros((R, H, Z, SUBLANE - 5, LANE), f32)], axis=3)
 
 
 def _chunks(ref, nc: int):
@@ -402,16 +493,37 @@ def _column(kappa, width: int):
     return jnp.broadcast_to(col[:, :, :1], (n, dk, width))
 
 
-def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, m_ref, o_ref, *rest,
-                Q: int, Z: int, keep: bool):
+def _beta(tiles, Q: int):
+    """A head's β of the step's chunks down the sublanes [n, Q, 1], from
+    its tiles [n, 8, 128] (:func:`mask_tiles` with ``beta``)."""
+    return jnp.swapaxes(tiles, 1, 2)[:, :Q, _BETA:_BETA + 1]
+
+
+def _by_beta(k, v, b, cd):
+    """``kb = β ⊙ k``, ``vb = β ⊙ v``, rounded where
+    ``models/kda.channel_decay_rule`` rounds them."""
+    f32 = jnp.float32
+    return (k.astype(f32) * b).astype(cd), (v.astype(f32) * b).astype(cd)
+
+
+def _fwd_kernel(*refs, Q: int, Z: int, keep: bool, ends):
     """A step of the forward: :func:`_blocks` for all its chunks at once,
     then the states' chain, ONE product a chunk: ``[G; q̃] · S₀``, ``S₁ =
-    κ ⊙ S₀ + C − G S₀``, ``o = q̃ S₀ + P U``."""
-    s_ref = rest[0] if keep else None
-    state, gq_ref, c_ref, pu_ref, kap_ref = rest[-5:]
-    nc = m_ref.shape[1]
-    dk, dv = q_ref.shape[2], vb_ref.shape[2]
-    cd = q_ref.dtype
+    κ ⊙ S₀ + C − G S₀``, ``o = q̃ S₀ + P U``. With ``ends`` (the l2 norms'
+    epsilon and the gated norm's: the mixer's entry, :func:`mixer_fwd`) the
+    operands are the mixer's — a head's [q | k | v] as the convolution
+    leaves them, the decay's and the output gate's pre-activations, the
+    head's tiles with β and its parameter tile — :func:`_ends_in` makes the
+    rule's from them, the step's o stay in VMEM (``pu_ref``) and what
+    leaves, after the states' chain, is the mixer's ``y``
+    (:func:`_gated_norm`)."""
+    n_in = 6 if ends is None else 5
+    m_ref, o_ref = refs[5 if ends is None else 3], refs[n_in]
+    s_ref = refs[n_in + 1] if keep else None
+    state, gq_ref, c_ref, pu_ref, kap_ref = refs[-5:]
+    nc = m_ref.shape[-3]
+    dk = dv = state.shape[0]
+    cd = gq_ref.dtype
     exact = cd == jnp.float32
     z = pl.program_id(2)
 
@@ -419,9 +531,17 @@ def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, m_ref, o_ref, *rest,
     def _():
         state[...] = jnp.zeros(state.shape, state.dtype)
 
-    ops = _real([_chunks(r, nc) for r in (q_ref, k_ref, kb_ref, vb_ref,
-                                          g_ref)], z, Z)
-    G, C, qt, PU, kappa = _blocks(*ops, m_ref[0], exact)
+    if ends is None:
+        tiles = m_ref[0]
+        ops = _real([_chunks(r, nc) for r in refs[:5]], z, Z)
+    else:
+        tiles, par = m_ref[0, 0], refs[4][...]
+        x, a, gate = _real([_chunks(r, nc) for r in refs[:3]], z, Z)
+        q, k, v, g = _ends_in(
+            x[..., :dk], x[..., dk:2 * dk], x[..., 2 * dk:], a,
+            par[_RATE:_RATE + 1], par[_DT_BIAS:_DT_BIAS + 1], ends[0], cd)
+        ops = (q, k) + _by_beta(k, v, _beta(tiles, Q), cd) + (g,)
+    G, C, qt, PU, kappa = _blocks(*ops, tiles, exact)
     gq_ref[:, :dk, :] = G.astype(cd)
     gq_ref[:, dk:, :] = qt.astype(cd)
     c_ref[...] = C
@@ -436,25 +556,44 @@ def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, m_ref, o_ref, *rest,
             s_ref[0, ci, 0] = S0c
         out = _dot(gq_ref[ci], S0c, None, exact)  # [G; q̃] · S₀
         state[...] = kap_ref[ci] * S0 + c_ref[ci] - out[:dk]
-        o_ref[0, rows, :] = out[dk:] + pu_ref[ci]
+        if ends is None:
+            o_ref[0, rows, :] = out[dk:] + pu_ref[ci]
+        else:
+            pu_ref[ci] = out[dk:] + pu_ref[ci]
         return carry
 
     _walk(nc, chunk)
+    if ends is not None:
+        y = _gated_norm(pu_ref[...], gate, par[_NORM:_NORM + 1], ends[1],
+                        cd)
+        o_ref[0] = y.reshape(o_ref.shape[1:])
 
 
-def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, m_ref, s_ref, do_ref,
-                dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref,
-                dstate, gt_ref, qd_ref, kap_ref, ds_ref, *, Z: int):
+def _bwd_kernel(*refs, Z: int, ends):
     """A step of the backward, in the forward's phases, the chunks
     reversed: :func:`_blocks` again for all the step's chunks at once,
     under ``jax.vjp``; the chain ``dS₀ = κ ⊙ dS₁ − Gᵀ dS₁ + q̃ᵀ do`` from
     the step's last chunk to its first, ONE product a chunk, each chunk's
     ``dS₁`` left in a scratch; last the cotangents of ``G``, ``C``, ``q̃``,
     ``P U`` and ``κ`` from those ``dS₁`` and the kept states, pulled back
-    to every operand for all the chunks at once."""
-    nc = m_ref.shape[1]
-    dk, dv = q_ref.shape[2], vb_ref.shape[2]
-    cd, f32 = q_ref.dtype, jnp.float32
+    to every operand for all the chunks at once. With ``ends`` (as the
+    forward's) the operands are the mixer's and ``do_ref`` holds the
+    cotangent of its ``y``: the rule's operands come from
+    :func:`_ends_in` under a ``jax.vjp`` of its own, the chunks' o are
+    REBUILT from the kept states and the blocks (``q̃ S₀ + P U``), ``do``
+    is formed through :func:`_gated_norm`'s vjp, and the rule's
+    cotangents leave through β's two products (β's own a lane sum on the
+    MXU, a token a lane, into a tile) and the first vjp: d [q | k | v], d
+    a, d gate in the compute dtype, and the parameter tile's gradient
+    summed over the row's steps where it stays."""
+    n_in = 8 if ends is None else 7
+    m_ref = refs[5 if ends is None else 3]
+    s_ref, do_ref = refs[n_in - 2:n_in]
+    outs = refs[n_in:n_in + 5]
+    dstate, gt_ref, qd_ref, kap_ref, ds_ref = refs[-5:]
+    nc = m_ref.shape[-3]
+    dk = dv = dstate.shape[0]
+    cd, f32 = gt_ref.dtype, jnp.float32
     exact = cd == f32
     zr = pl.program_id(2)
     step = pl.num_programs(2) - 1 - zr
@@ -463,15 +602,35 @@ def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, m_ref, s_ref, do_ref,
     def _():
         dstate[...] = jnp.zeros(dstate.shape, f32)
 
-    q, k, kb, vb, g, do, S0c = _real(
-        [_chunks(r, nc) for r in (q_ref, k_ref, kb_ref, vb_ref, g_ref,
-                                  do_ref)] + [s_ref[0, :, 0]], step, Z)
-    tiles = m_ref[0]
-    (G, _, qt, _, kappa), pull = jax.vjp(
-        lambda *a: _blocks(*a, tiles, exact), q, k, kb, vb, g)
+    if ends is None:
+        tiles = m_ref[0]
+        q, k, kb, vb, g, do, S0c = _real(
+            [_chunks(r, nc) for r in refs[:5] + (do_ref,)]
+            + [s_ref[0, :, 0]], step, Z)
+    else:
+        tiles, par = m_ref[0, 0], refs[4][...]
+        Q = do_ref.shape[1] // nc
+        x, a, gate, dy, S0c = _real(
+            [_chunks(r, nc) for r in refs[:3] + (do_ref,)]
+            + [s_ref[0, :, 0]], step, Z)
+        (q, k, v, g), pull_in = jax.vjp(
+            lambda *xs: _ends_in(*xs, ends[0], cd),
+            x[..., :dk], x[..., dk:2 * dk], x[..., 2 * dk:], a,
+            par[_RATE:_RATE + 1], par[_DT_BIAS:_DT_BIAS + 1])
+        b = _beta(tiles, Q)
+        kb, vb = _by_beta(k, v, b, cd)
+    (G, _, qt, PU, kappa), pull = jax.vjp(
+        lambda *xs: _blocks(*xs, tiles, exact), q, k, kb, vb, g)
+    qtc = qt.astype(cd)
+    if ends is not None:  # dy through the gate and the norm to do
+        o = _dots("nqk,nkw->nqw", qtc, S0c, exact) + PU
+        _, pull_out = jax.vjp(
+            lambda *xs: _gated_norm(*xs, ends[1], cd), o, gate,
+            par[_NORM:_NORM + 1])
+        do, dgate, dw = pull_out(dy)
     doc = do.astype(cd)
     gt_ref[...] = jnp.swapaxes(G, 1, 2).astype(cd)
-    qd_ref[...] = _dots("nqk,nqw->nkw", qt.astype(cd), doc, exact)
+    qd_ref[...] = _dots("nqk,nqw->nkw", qtc, doc, exact)
     kap_ref[...] = _column(kappa, dv)
 
     def chunk(i, carry):  # the step's chunks from its last to its first
@@ -490,8 +649,32 @@ def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, m_ref, s_ref, do_ref,
     d_kappa = jnp.sum(jnp.where(_own(held.shape), held, 0.0), axis=1,
                       keepdims=True)
     grads = pull((-held, dS1, both[:, dk:], do.astype(f32), d_kappa))
-    for ref, d in zip((dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref), grads):
-        ref[0] = d.reshape(ref.shape[1:])
+    if ends is None:
+        for ref, d in zip(outs, grads):
+            ref[0] = d.reshape(ref.shape[1:])
+        return
+    dx_ref, da_ref, dgate_ref, dtile_ref, dpar_ref = outs
+
+    @pl.when(zr == 0)
+    def _():
+        dpar_ref[...] = jnp.zeros(dpar_ref.shape, f32)
+
+    dq, dkey, dkb, dvb, dg = grads
+    dkb, dvb = dkb.astype(f32), dvb.astype(f32)
+    dqr, dkr, dvr, da, d_rate, d_bias = pull_in(
+        (dq, (dkey.astype(f32) + b * dkb).astype(cd), (b * dvb).astype(cd),
+         dg))
+    for i, d in enumerate((dqr, dkr, dvr)):
+        dx_ref[0, :, i * dk:(i + 1) * dk] = d.reshape(nc * Q, dk)
+    da_ref[0] = da.reshape(da_ref.shape[1:])
+    dgate_ref[0] = dgate.reshape(dgate_ref.shape[1:])
+    # d β = Σ_d (dkb ⊙ k + dvb ⊙ v), a token a lane (row 0 of the tile)
+    d_beta = _lane_sums(dkb * k.astype(f32) + dvb * v.astype(f32),
+                        [(0, dk)], exact)[:, :SUBLANE]
+    dtile_ref[0, 0] = jnp.concatenate(
+        [d_beta, jnp.zeros((nc, SUBLANE, LANE - Q), f32)], axis=2)
+    dpar_ref[0] += jnp.concatenate(
+        [d_rate, d_bias, dw, jnp.zeros((SUBLANE - 3, dk), f32)], axis=0)
 
 
 def _specs(H: int, dk: int, dv: int, Q: int, Z: int, nc: int, reverse: bool):
@@ -506,6 +689,24 @@ def _specs(H: int, dk: int, dv: int, Q: int, Z: int, nc: int, reverse: bool):
                         lambda b, h, z: (b, at(z), 0, 0))
     st = pl.BlockSpec((1, nc, 1, dk, dv), lambda b, h, z: (b, at(z), h, 0, 0))
     return key, val, mask, st, steps
+
+
+def _mixer_specs(dk: int, Q: int, Z: int, nc: int, reverse: bool):
+    """The mixer's entry: a head's [q | k | v] (3 dk lanes of [R, T, H · 3
+    dk]), a head's dk lanes of a [R, T, H · dk] array, its tiles of [R, H,
+    chunks, 8, 128], its parameter tile of [8, H · dk], that tile's
+    gradient a row [R, 8, H · dk], the kept states; the grid's steps."""
+    key, _, _, st, steps = _specs(0, dk, dk, Q, Z, nc, reverse)
+
+    def at(z):  # as :func:`_specs`
+        return steps - 1 - z if reverse else z
+
+    x = pl.BlockSpec((1, nc * Q, 3 * dk), lambda b, h, z: (b, at(z), h))
+    tile = pl.BlockSpec((1, 1, nc, SUBLANE, LANE),
+                        lambda b, h, z: (b, h, at(z), 0, 0))
+    par = pl.BlockSpec((SUBLANE, dk), lambda b, h, z: (0, h))
+    dpar = pl.BlockSpec((1, SUBLANE, dk), lambda b, h, z: (b, 0, h))
+    return x, key, tile, par, dpar, st, steps
 
 
 def _own_precision(fn):
@@ -554,7 +755,7 @@ def rule_fwd(q, k, kb, vb, g, seg, chunk: int, keep: bool = False,
     _STEPS[(R, T, H) + steps_of(Z)] += 1
     key, val, mask, st, steps = _specs(H, dk, dv, chunk, Z, nc, False)
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel, Q=chunk, Z=Z, keep=keep),
+        functools.partial(_fwd_kernel, Q=chunk, Z=Z, keep=keep, ends=None),
         grid=(R, H, steps),
         in_specs=[key, key, key, val, key, mask],
         out_specs=[val] + ([st] if keep else []),
@@ -587,7 +788,7 @@ def rule_bwd(q, k, kb, vb, g, seg, states, do, chunk: int,
     cd, f32 = q.dtype, jnp.float32
     flat_k, flat_v = (R, T, H * dk), (R, T, H * dv)
     dq, dkey, dkb, dvb, dg = pl.pallas_call(
-        functools.partial(_bwd_kernel, Z=Z),
+        functools.partial(_bwd_kernel, Z=Z, ends=None),
         grid=(R, H, steps),
         in_specs=[key, key, key, val, key, mask, st, val],
         out_specs=[key, key, key, val, key],
@@ -605,3 +806,91 @@ def rule_bwd(q, k, kb, vb, g, seg, states, do, chunk: int,
       mask_tiles(seg, chunk, steps * nc), states, do.reshape(flat_v))
     return (dq.reshape(q.shape), dkey.reshape(k.shape), dkb.reshape(kb.shape),
             dvb.reshape(vb.shape), dg.reshape(g.shape))
+
+
+def _mixer_dims(x, beta, chunk: int):
+    R, T, W3 = x.shape
+    H = beta.shape[2]
+    return R, T, H, W3 // (3 * H), T // chunk
+
+
+def mixer_parameters(A_log, dt_bias, norm):
+    """A_log [H], dt_bias [H · dk], the gated norm's weight [dk] -> the
+    parameter tile of :func:`mixer_fwd`, [8, H · dk] float32: row 0
+    ``-exp(A_log)`` on each of a head's lanes, 1 ``dt_bias``, 2 the norm's
+    weight under every head (jax differentiates this; the kernels return
+    the tile's gradient)."""
+    f32 = jnp.float32
+    H, W = A_log.shape[0], dt_bias.shape[0]
+    rows = jnp.stack([jnp.repeat(-jnp.exp(A_log.astype(f32)), W // H),
+                      dt_bias.astype(f32), jnp.tile(norm.astype(f32), H)])
+    return jnp.pad(rows, ((0, SUBLANE - 3), (0, 0)))
+
+
+@_own_precision
+def mixer_fwd(x, a, gate, beta, par, seg, chunk: int, eps,
+              keep: bool = False, interpret: bool = False):
+    """The mixer between its convolution and its out-projection, the SAME
+    forward kernel with the ends inside (``ends``): x [R, T, H · 3 dk], a
+    head's [q | k | v] side by side as the convolution leaves them, a and
+    gate [R, T, H · dk] as the decay's and the output gate's matmuls leave
+    them, all in the compute dtype; beta [R, T, H] float32; ``par``
+    (:func:`mixer_parameters`); seg [R, T] int; T a whole number of chunks;
+    ``eps`` (the l2 norms' epsilon, the gated norm's). Returns (y [R, T, H · dk] in the compute dtype, the state entering
+    each chunk or, without ``keep``, None)."""
+    R, T, H, dk, Z = _mixer_dims(x, beta, chunk)
+    nc = steps_of(Z)[0]
+    _STEPS[(R, T, H) + steps_of(Z)] += 1
+    xs, key, tile, ps, _, st, steps = _mixer_specs(dk, chunk, Z, nc, False)
+    cd, f32 = x.dtype, jnp.float32
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, Q=chunk, Z=Z, keep=keep,
+                          ends=tuple(map(float, eps))),
+        grid=(R, H, steps),
+        in_specs=[xs, key, key, tile, ps],
+        out_specs=[key] + ([st] if keep else []),
+        scratch_shapes=[pltpu.VMEM((dk, dk), f32),
+                        pltpu.VMEM((nc, dk + chunk, dk), cd),
+                        pltpu.VMEM((nc, dk, dk), f32),
+                        pltpu.VMEM((nc, chunk, dk), f32),
+                        pltpu.VMEM((nc, dk, dk), f32)],
+        out_shape=[jax.ShapeDtypeStruct((R, T, H * dk), cd)] + (
+            [jax.ShapeDtypeStruct((R, Z, H, dk, dk), cd)] if keep else []),
+        name=FWD_NAME, **_params(interpret),
+    )(x, a, gate, mask_tiles(seg, chunk, steps * nc, beta), par)
+    return out[0], (out[1] if keep else None)
+
+
+@_own_precision
+def mixer_bwd(x, a, gate, beta, par, seg, states, dy, chunk: int, eps,
+              interpret: bool = False):
+    """(dx, da, dgate in the compute dtype, dbeta [R, T, H] float32 and the
+    parameter tile's gradient [8, H · dk] float32) from :func:`mixer_fwd`'s
+    operands, its kept states and the cotangent of ``y``."""
+    R, T, H, dk, Z = _mixer_dims(x, beta, chunk)
+    nc = steps_of(Z)[1]
+    _STEPS[(R, T, H) + steps_of(Z)] += 1
+    xs, key, tile, ps, dps, st, steps = _mixer_specs(dk, chunk, Z, nc, True)
+    cd, f32 = x.dtype, jnp.float32
+    tiles = mask_tiles(seg, chunk, steps * nc, beta)
+    dx, da, dgate, dtile, dpar = pl.pallas_call(
+        functools.partial(_bwd_kernel, Z=Z, ends=tuple(map(float, eps))),
+        grid=(R, H, steps),
+        in_specs=[xs, key, key, tile, ps, st, key],
+        out_specs=[xs, key, key, tile, dps],
+        scratch_shapes=[pltpu.VMEM((dk, dk), f32),
+                        pltpu.VMEM((nc, dk, dk), cd),
+                        pltpu.VMEM((nc, dk, dk), f32),
+                        pltpu.VMEM((nc, dk, dk), f32),
+                        pltpu.VMEM((nc, dk, dk), f32)],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, cd),
+                   jax.ShapeDtypeStruct(a.shape, cd),
+                   jax.ShapeDtypeStruct(gate.shape, cd),
+                   jax.ShapeDtypeStruct(tiles.shape, f32),
+                   jax.ShapeDtypeStruct((R,) + par.shape, f32)],
+        name=BWD_NAME, **_params(interpret),
+    )(x, a, gate, tiles, par, states, dy)
+    # a token a lane of each chunk's tile, row 0 -> [R, T, H]
+    dbeta = jnp.moveaxis(dtile[:, :, :, 0, :chunk], 1, 3).reshape(
+        R, -1, H)[:, :T]
+    return dx, da, dgate, dbeta, jnp.sum(dpar, axis=0)

@@ -379,6 +379,10 @@ class TrainerWorker:
             kda_geometry={"%dx%d/%d/h%d/%d/r%d" % geom: n
                           for geom, n in kda.geometry_counts().items()},
             kda_rule_impl=kda.rule_impl_counts(),
+            # {"kernel" | "xla": mixers traced}: where their per-head ends
+            # ran (inside the kernel pair, all heads at once; or XLA's
+            # grouped text)
+            kda_mixer_norms=kda.mixer_norm_counts(),
             # {"rows x length/channels/taps": convolutions traced}: a
             # model's short-convolution blocks (models/shortconv.py)
             shortconv_geometry={

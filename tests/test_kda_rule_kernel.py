@@ -14,7 +14,12 @@ grid steps, and decays so STRONG that ``k ⊙ e^{-c}`` — the factor a
 reference outside the chunk's sub-blocks would need — overflows float32;
 the dispatch's answers and its counters; a Gated DeltaNet rule (the decay
 averaged over a head's channels), which this file must refuse; and that the
-scalar-decay rule's traced program is what it was.
+scalar-decay rule's traced program is what it was. And the MIXER'S entry of
+the same two kernels (``kda.rule_with_ends``: the SiLU, both l2 norms, the
+decay's activation, β's products and the gated norm inside them) against
+the mixer's XLA text between its convolution and its out-projection:
+forward and the gradient of every operand and parameter, float32 and
+bfloat16, on the same layouts; and where ``kda_mixer`` takes which.
 """
 
 import jax
@@ -253,6 +258,158 @@ def test_the_kernels_names_and_scope():
     assert "kda_rule_fwd" in text and "kda_rule_bwd" in text
     assert (kernel.FWD_NAME, kernel.BWD_NAME) == ("kda_rule_fwd",
                                                   "kda_rule_bwd")
+
+
+ENDS = ("x", "a", "gate", "beta", "A_log", "dt_bias", "norm")
+EPS = 1e-5
+
+
+def ends_inputs(R, T, H, seg, seed=0, strong=1.0):
+    """The mixer's arrays behind its convolution and its gates' matmuls
+    (a head's [q | k | v] side by side), zero where the row is padding;
+    decays of -0.001 to -1.6 a token, times ``strong``."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    x = 2.0 * jax.random.normal(ks[0], (R, T, H * 3 * D))
+    a = jax.random.normal(ks[1], (R, T, H * D))
+    gate = 2.0 * jax.random.normal(ks[2], (R, T, H * D))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[3], (R, T, H)))
+    p = kda.init_kda_params(KDAConfig(n_heads=H, head_dim=D), 1, 8, ks[4],
+                            jnp.float32)
+    A_log = p["kda_A_log"][0] + np.log(strong)
+    norm = 1.0 + 0.3 * jax.random.normal(ks[5], (D,))
+    live = (seg > 0)[..., None]
+    return (x * live, a * live, gate * live, beta, A_log,
+            p["kda_dt_bias"][0], norm)
+
+
+def xla_ends(x, a, gate, beta, A_log, dt_bias, norm, seg):
+    """``kda_mixer``'s XLA text between ``kda_conv`` and ``kda_out_proj``,
+    all heads at once, in the operands' dtype."""
+    R, T, _ = x.shape
+    H = beta.shape[2]
+    cd, f32 = x.dtype, jnp.float32
+    q, k, v = (jax.nn.silu(x).reshape(R, T, H, 3, D)[:, :, :, i]
+               for i in range(3))
+    g = -jnp.exp(A_log.astype(f32))[:, None] * jax.nn.softplus(
+        a.astype(f32).reshape(R, T, H, D) + dt_bias.astype(f32).reshape(H, D))
+    q = (gdn.l2_normalize(q) * D ** -0.5).astype(cd)
+    k = gdn.l2_normalize(k).astype(cd)
+    o = kda.channel_decay_rule(q, k, v, g, beta, seg, Q, "xla")
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + EPS)
+    y = (o * norm.astype(f32)).astype(cd)
+    return (y.reshape(R, T, H * D).astype(f32)
+            * jax.nn.sigmoid(gate.astype(f32))).astype(cd)
+
+
+def fused_ends(*ops_and_seg):
+    *ops, seg = ops_and_seg
+    return kda.rule_with_ends(*ops, seg, Q, EPS, "pallas_interpret")
+
+
+def ends_grads(fn, ops, seg, dtype):
+    """(y, the gradients of Σ sin(y) over the row's tokens) in float32."""
+    ops = tuple(o.astype(dtype) if i < 3 else o for i, o in enumerate(ops))
+
+    def loss(*xs):
+        y = fn(*xs, seg).astype(jnp.float32)
+        return jnp.sum(jnp.sin(y) * (seg > 0)[..., None]), y
+
+    (_, y), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=range(7), has_aux=True))(*ops)
+    return [np.asarray(v, np.float32) for v in (y, *grads)]
+
+
+def ends_layout(which):
+    """(rows, tokens, heads, segment ids) of a case."""
+    if which == "packed":  # a document's start inside the second chunk
+        return 1, 4 * Q, 2, layout("second", 4 * Q)
+    T = 11 * Q  # 8 chunks a grid step: a last step of 3
+    seg = np.zeros((2, T), np.int32)
+    seg[0, :8 * Q], seg[0, 8 * Q:9 * Q + 20], seg[0, 9 * Q + 20:] = 1, 2, 3
+    seg[1, :300], seg[1, 300:T - 40] = 1, 2
+    if which == "short":
+        return 1, T, 1, jnp.asarray(seg[:1])
+    return 2, T, 1, jnp.asarray(seg)
+
+
+@pytest.mark.parametrize("which,strong", [
+    ("packed", 1.0), ("short", 1.0), ("rows", 1.0), ("rows", 200.0)])
+def test_the_mixers_entry_against_its_xla_text(which, strong):
+    """The fused entry in float32: y and the gradient of each of its
+    seven operands (the parameters' through ``mixer_parameters``) equal
+    the XLA text's, the short last step and the strongest decays
+    included."""
+    R, T, H, seg = ends_layout(which)
+    ops = ends_inputs(R, T, H, seg, seed=20, strong=strong)
+    before = dict(kernel.step_counts())
+    got = ends_grads(fused_ends, ops, seg, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = ends_grads(xla_ends, ops, seg, jnp.float32)
+    for name, x, w in zip(("y",) + ENDS, got, want):
+        assert np.isfinite(x).all(), name
+        scale = max(float(np.max(np.abs(w))), 1.0)
+        assert float(np.max(np.abs(x - w))) < 5e-5 * scale, name
+    after = kernel.step_counts()
+    ran = {k: n - before.get(k, 0) for k, n in after.items()
+           if n > before.get(k, 0)}
+    nc = min(8, T // Q)
+    assert ran == {(R, T, H, nc, nc): 2}, ran
+
+
+def test_the_mixers_entry_in_bfloat16():
+    """bfloat16 operands: y and the operands' cotangents leave in
+    bfloat16, β's and the parameters' in float32, and every one is as
+    close to the float32 text as the XLA text in bfloat16 is (max |a − b| /
+    max |b|; the two forms read 0.003-0.03 on these)."""
+    R, T, H, seg = ends_layout("rows")
+    ops = ends_inputs(R, T, H, seg, seed=21)
+    with jax.default_matmul_precision("highest"):
+        want = ends_grads(xla_ends, ops, seg, jnp.float32)
+    got = ends_grads(fused_ends, ops, seg, jnp.bfloat16)
+    xla = ends_grads(xla_ends, ops, seg, jnp.bfloat16)
+    for name, x, other, w in zip(("y",) + ENDS, got, xla, want):
+        assert np.isfinite(x).all(), name
+        far = np.max(np.abs(x - w)) / np.max(np.abs(w))
+        assert far < max(0.05, 2 * np.max(np.abs(other - w))
+                         / np.max(np.abs(w))), (name, far)
+    cot = jax.grad(lambda *xs: jnp.sum(fused_ends(*xs, seg).astype(
+        jnp.float32)), argnums=range(7))(
+            *(o.astype(jnp.bfloat16) for o in ops[:3]), *ops[3:])
+    assert [c.dtype for c in cot] == [jnp.bfloat16] * 3 + [jnp.float32] * 4
+
+
+def test_a_token_of_zeros_norms_to_zero_and_writes_nothing():
+    """A row that is no whole number of chunks (the entry pads it with
+    zeros) with trailing padding: the padded tokens' y is exactly 0 and
+    the real ones' is the XLA text's."""
+    T = 2 * Q + 31
+    seg = jnp.ones((1, T), jnp.int32).at[0, T - 17:].set(0)
+    ops = ends_inputs(1, T, 1, seg, seed=22)
+    got = fused_ends(*ops, seg)
+    assert got.shape == (1, T, D)
+    assert float(jnp.max(jnp.abs(got[0, T - 17:]))) == 0.0
+    want = xla_ends(*ops, seg)
+    assert float(jnp.max(jnp.abs((got - want) * (seg > 0)[..., None]))) < 5e-6
+
+
+def test_where_the_mixer_runs_its_ends():
+    """``kda_mixer`` on the kernel path runs the fused entry, all heads
+    at once (``mixer_norm_counts`` ``kernel``, ONE kernel call at the
+    model's heads); on the XLA path the grouped text (``xla``)."""
+    cfg = KDAConfig(n_heads=2, head_dim=D)
+    lp = jax.tree.map(lambda a: a[0], kda.init_kda_params(
+        cfg, 1, 16, jax.random.PRNGKey(0), jnp.float32))
+    u = jax.random.normal(jax.random.PRNGKey(1), (1, Q, 16))
+    norms, steps = dict(kda.mixer_norm_counts()), dict(kernel.step_counts())
+    y = kda.kda_mixer(u, lp, cfg, EPS, None, "pallas_interpret")
+    y2 = kda.kda_mixer(u, lp, cfg, EPS, None, "reference")
+    np.testing.assert_allclose(y, y2, rtol=1e-4, atol=1e-6)
+    after = kda.mixer_norm_counts()
+    assert {k: after[k] - norms.get(k, 0) for k in after} == {
+        "kernel": 1, "xla": 1}
+    assert kernel.step_counts()[(1, Q, 2, 1, 1)] == steps.get(
+        (1, Q, 2, 1, 1), 0) + 1
+    assert 0.0 < kda.norms_in_kernel_frac() < 1.0
 
 
 def test_the_scalar_decay_rules_traced_program_is_unchanged():

@@ -275,10 +275,13 @@ def test_a_packed_row_of_documents_equals_each_alone(lens, width):
     assert all(bool(jnp.isfinite(g).all()) for g in jax.tree.leaves(grads))
 
 
-def test_the_mixer_with_the_kernels_interpreted_equals_the_xla_form():
-    """At heads of 128 the mixer's kernel path (interpreted here) and its
-    XLA form give the same ``y`` and the same gradients, a group of heads
-    at a time either way."""
+@pytest.mark.parametrize("entry", ["full", "attention"])
+def test_the_mixer_with_the_kernels_interpreted_equals_the_xla_form(entry):
+    """At heads of 128 the mixer's kernel path (interpreted here: the
+    fused entry, all heads at once, no checkpoint of its own) and its XLA
+    form (a group of heads at a time under a checkpoint) give the same
+    ``y`` and the same gradient of ``u`` and of every parameter, under
+    either remat entry of the block around them."""
     from areal_tpu.models.config import KDAConfig
 
     cfg = KDAConfig(n_heads=4, head_dim=128)
@@ -288,16 +291,21 @@ def test_the_mixer_with_the_kernels_interpreted_equals_the_xla_form():
           for k, v in lp.items()}
     u = jax.random.normal(jax.random.PRNGKey(1), (1, 160, 64))
     seg = jnp.asarray([[1] * 70 + [2] * 83 + [0] * 7], jnp.int32)
+    norms = dict(kda.mixer_norm_counts())
 
     def run(impl):
+        mixer = transformer._maybe_checkpoint(
+            lambda u, lp: kda.kda_mixer(u, lp, cfg, 1e-5, seg, impl), entry)
         return jax.value_and_grad(lambda u, lp: jnp.sum(jnp.sin(
-            kda.kda_mixer(u, lp, cfg, 1e-5, seg, impl))), argnums=(0, 1))(
-                u, lp)
+            mixer(u, lp))), argnums=(0, 1))(u, lp)
 
     (y, (du, dlp)), (y2, (du2, dlp2)) = run("pallas_interpret"), run(
         "reference")
+    after = kda.mixer_norm_counts()
+    assert all(after[k] > norms.get(k, 0) for k in ("kernel", "xla"))
     np.testing.assert_allclose(y, y2, rtol=1e-5)
     np.testing.assert_allclose(du, du2, atol=2e-5 * float(jnp.abs(du2).max()))
+    assert set(dlp) == set(dlp2) == set(kda.param_shapes(cfg, 64))
     for name in dlp:
         np.testing.assert_allclose(
             dlp[name], dlp2[name],
@@ -478,3 +486,54 @@ def test_the_gauges_of_the_train_step(monkeypatch):
     assert mbu.resets_in_chunk_per_row(mbs, 64) == 1.0
     frac = kda.rule_kernel_frac()
     assert frac is None or 0.0 <= frac <= 1.0
+
+
+def test_where_the_mixers_ends_ran_is_a_gauge_of_the_train_step():
+    """``train/kda_kernel_frac`` and ``train/kda_norms_in_kernel_frac``: of
+    the rules and the mixers traced, the share on the Pallas kernel pair
+    and the share whose ends ran inside it — gauges and attributes of the
+    ``train/fwd_bwd`` span (the tiny model is off the kernels' lane grid:
+    its own mixers count ``xla``)."""
+    from areal_tpu.api.data import MicroBatchSpec, SequenceSample
+    from areal_tpu.api.model import FinetuneSpec
+    from areal_tpu.api.train_config import OptimizerConfig, TelemetryConfig
+    from areal_tpu.backend.jax_train import JaxTrainEngine
+    from areal_tpu.base import telemetry
+
+    cfg, params = model()
+    eng = JaxTrainEngine(cfg, params, OptimizerConfig(type="sgd", lr=1e-2),
+                         FinetuneSpec(1, 8, 4), compute_dtype="float32",
+                         length_bucket=16, rows_bucket=1, seqs_bucket=4)
+    lens = [9, 12, 7, 14]
+    rng = np.random.RandomState(0)
+    sample = SequenceSample.from_default(
+        ids=[f"s{i}" for i in range(len(lens))],
+        data={"packed_input_ids": rng.randint(
+            2, 97, sum(lens)).astype(np.int32),
+            "loss_mask": np.ones(sum(lens), np.float32)},
+        seqlens=lens)
+
+    def sq_loss(logits, batch):
+        w = (batch["segment_ids"] > 0).astype(jnp.float32)
+        lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        return jnp.sum(jnp.sum(lp * lp, axis=-1) * w), {"n": jnp.sum(w)}
+
+    before = kda.mixer_norm_counts().get("xla", 0)
+    telemetry.configure("t", "t", "trainer", 0,
+                        TelemetryConfig(enabled=True), push=False)
+    try:
+        for _ in range(2):  # the first step traces; the second reads
+            eng.train_batch(sample, MicroBatchSpec(max_tokens_per_mb=48),
+                            sq_loss, lambda mb: mb.n_tokens)
+        snap = telemetry.get().snapshot()
+    finally:
+        telemetry.shutdown()
+    assert kda.mixer_norm_counts()["xla"] > before
+    gauges = snap["gauges"]
+    assert gauges["train/kda_kernel_frac"] == kda.rule_kernel_frac()
+    assert gauges["train/kda_norms_in_kernel_frac"] == pytest.approx(
+        kda.norms_in_kernel_frac())
+    assert 0.0 <= gauges["train/kda_norms_in_kernel_frac"] < 1.0
+    spans = [s for s in snap["spans"] if s["name"] == "train/fwd_bwd"]
+    assert spans[-1]["attrs"]["kda_norms_in_kernel_frac"] == gauges[
+        "train/kda_norms_in_kernel_frac"]

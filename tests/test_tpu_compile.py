@@ -816,6 +816,7 @@ def _compile_kimi():
         "rule_kernels": [n for n in ("kda_rule_fwd", "kda_rule_bwd")
                          if n in text],
         "rule_impl": kda.rule_impl_counts(),
+        "mixer_norms": kda.mixer_norm_counts(),
         "rule_steps": ["%dx%d/h%d/fwd%d/bwd%d" % g
                        for g in kda_rule.step_counts()],
         "rules_traced": {"%dx%d/%d/h%d/%d/r%d" % g: n
@@ -1160,24 +1161,31 @@ def test_the_kimi_cut_compiles_inside_the_memory_it_leaves(compiled_kimi):
     most, at the published widths: every rule the kernel pair, the
     attention kernel handed a key of 192 in 256 lanes and a value of 128
     in its own, the rule's kernels at 8 chunks a grid step though 118 chunks
-    are no whole number of steps. Whether the micro-batch FITS is not read off this program
-    (the whole tree's gradient at once, returned beside its temporaries:
-    6.18 GB + 1.20): the engine's own ``train_grad_sliced`` of this grid
-    takes 5.92 GB with head and gradient and runs on the chip beside the
-    10.84 GB (PERF.md section 6, PR 63) — what is held here is that the
-    temporaries do not grow."""
+    are no whole number of steps, every mixer's ends inside them and all 32
+    heads in one call (PR 65: a forward call a run of KDA blocks fewer than
+    the head groups' checkpoint cost, 72 -> 70 custom calls, and 1.2 GB
+    fewer temporaries). Whether the micro-batch FITS is not read off this
+    program (the whole tree's gradient at once, returned beside its
+    temporaries: 4.96 GB + 1.20): the engine's own ``train_grad_sliced`` of
+    this grid runs on the chip beside the 10.84 GB (PERF.md section 6, PR
+    63 / 65) — what is held here is that the temporaries do not grow."""
     got = compiled_kimi
     assert got["rule_kernels"] == ["kda_rule_fwd", "kda_rule_bwd"]
     assert set(got["rule_impl"]) == {"pallas"}
-    # a row of 118 chunks (2 x 59), a head group of 8: 8 chunks a grid
-    # step of both kernels, the last step short; never the 2 that divide
-    assert got["rule_steps"] == ["2x7552/h8/fwd8/bwd8"]
+    # one mixer a run of KDA blocks, its ends in the kernels
+    assert got["mixer_norms"] == {"kernel": 2}
+    # a row of 118 chunks (2 x 59), all 32 heads: 8 chunks a grid step of
+    # both kernels, the last step short; never the 2 that divide
+    assert got["rule_steps"] == ["2x7552/h32/fwd8/bwd8"]
+    # a run's rule: forward, the forward its block's backward re-runs,
+    # backward — and no forward of a head group's own
+    assert got["custom_calls"] == 70
     # one rule a run of KDA blocks (block 1's, the expert blocks')
     assert got["rules_traced"] == {"2x7552/64/h32/128/r128": 2}
     assert got["assemblies_traced"] == {"2x7552/h32/q0kv512/128+64/v128": 1}
     assert got["head_widths"] == [192, 256, 128, 128]
-    # 6.18 GB
-    assert got["temp_bytes"] < 6.4e9
+    # 4.96 GB (6.18 with a group of 8 heads at a time and XLA's ends)
+    assert got["temp_bytes"] < 5.2e9
     assert got["param_bytes"] == 10_843_819_776
 
 

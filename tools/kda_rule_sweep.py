@@ -9,11 +9,25 @@ what the parts of a step cost.
     chiprun -- python tools/kda_rule_sweep.py            # parity + times
     chiprun -- python tools/kda_rule_sweep.py --ablate   # a step's parts
     chiprun -- python tools/kda_rule_sweep.py --steps 4 8 16
+    chiprun -- python tools/kda_rule_sweep.py --parts ends [--parity]
     python tools/kda_rule_sweep.py --compile             # described v5e, here
+
+``--parts ends`` times the MIXER between its convolution and its
+out-projection (models/kda.py), all 32 heads, from the arrays as the mixer
+has them — the convolution's output, the two gates' pre-activations, β's
+logits — both ways: ``xla-ends`` is the mixer's XLA text around the plain
+kernels, a group of 8 heads at a time under ``lax.map(checkpoint(..))``
+(what ran before PR 65), ``in-kernel`` the fused entry
+(``kda.rule_with_ends``; left out on a tree that has none, so the tool runs
+on the parent too). DEVICE milliseconds a call from a profiler capture,
+forward and forward + backward, with the two kernels' own and the largest
+ops; ``--parity`` instead gives each form's distance in bfloat16 from the
+XLA text in float32 (max |a − b| over max |b|, y and every gradient);
+``--compile`` its temporaries for a described v5e.
 
 A shape is ``[rows x]tokens:document,document`` (the documents of every
 row). One JSON line a case on stdout (appended to
-chiprun_out/kda_rule_sweep_pr64.jsonl), each with the module's
+chiprun_out/kda_rule_sweep_pr65.jsonl), each with the module's
 ``step_counts()``. Times are a host clock around ``--iters`` calls in a
 row, the device finished (a call is milliseconds: the ~0.7 ms round trip
 is in the noise of ten); heads run a group of 8 at a time, as the mixer
@@ -33,7 +47,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-OUT = "chiprun_out/kda_rule_sweep_pr64.jsonl"
+OUT = "chiprun_out/kda_rule_sweep_pr65.jsonl"
 # The cell's grids (6 x 1 x 10,752 and 4 x 2 x 7,552 a step) beside the
 # 8,192 tokens PR 63 read.
 SHAPES = ["8192:1658,6480", "10752:3321,4403,3011", "2x7552:5062,1682"]
@@ -55,13 +69,17 @@ def inputs(jnp, jax, R, T, H, D, docs, dtype, strong, seed=0):
     v = jax.random.normal(ks[2], (R, T, H, D)).astype(dtype)
     g = -jax.nn.softplus(jax.random.normal(ks[3], (R, T, H, D)) - 3) * strong
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (R, T, H)))
+    return q, k, v, g, beta, segments(jnp, R, T, docs)
+
+
+def segments(jnp, R, T, docs):
+    """Segment ids [R, T]: every row the documents ``docs``, padding behind."""
     starts = [0]
     for n in docs:
         starts.append(starts[-1] + n)
     pos = jnp.arange(T)
     seg = sum((pos >= s).astype(jnp.int32) for s in starts[:-1])
-    seg = jnp.broadcast_to(jnp.where(pos < starts[-1], seg, 0), (R, T))
-    return q, k, v, g, beta, seg
+    return jnp.broadcast_to(jnp.where(pos < starts[-1], seg, 0), (R, T))
 
 
 def token_scan(jax, jnp, q, k, v, g, beta, seg):
@@ -81,6 +99,175 @@ def token_scan(jax, jnp, q, k, v, g, beta, seg):
         step, (jnp.zeros((R, H, dk, dk)), jnp.full((R,), -1, jnp.int32)),
         tuple(jnp.swapaxes(a, 0, 1) for a in (q, k, v, g, beta, seg)))
     return jnp.swapaxes(o, 0, 1)
+
+
+ENDS_NAMES = ("y", "dx", "da", "dgate", "db", "dA_log", "ddt_bias", "dnorm")
+EPS = 1e-5  # the configuration's rms_norm_eps
+
+
+def ends_inputs(jax, jnp, R, T, H, D, docs, seed=0):
+    """The mixer's arrays behind its convolution and its gates' matmuls,
+    bfloat16, [q | k | v] as the parent's projection lays them out."""
+    bf = jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    x = (2.0 * jax.random.normal(ks[0], (R, T, 3 * H * D))).astype(bf)
+    a = (jax.random.normal(ks[1], (R, T, H * D)) - 2.0).astype(bf)
+    gate = (2.0 * jax.random.normal(ks[2], (R, T, H * D))).astype(bf)
+    b = jax.random.normal(ks[3], (R, T, H)).astype(bf)
+    A_log = jnp.log(jax.random.uniform(ks[4], (H,), minval=1.0, maxval=16.0))
+    dt_bias = jax.random.normal(ks[5], (H * D,)) - 3.0
+    norm = 1.0 + 0.3 * jax.random.normal(ks[6], (D,))
+    wt = jax.random.normal(ks[7], (R, T, H * D))
+    return x, a, gate, b, A_log, dt_bias, norm, wt
+
+
+def ends_fn(jax, jnp, kda, how, impl, seg, H, D, dtype, grads=True):
+    """The mixer between convolution and out-projection, jitted: y, or
+    ((Σ wt · y, y), its gradients)."""
+    from areal_tpu.models.gdn import _HEAD_GROUPS, l2_normalize
+
+    f32 = jnp.float32
+
+    def xla_ends(x, a, gate, b, A_log, dt_bias, norm):
+        """``kda_mixer``'s text before PR 65, from the convolution's
+        output on: a group of heads at a time under a checkpoint."""
+        R, T, _ = x.shape
+        n = _HEAD_GROUPS
+        Hn = H // n
+
+        def by_group(v, parts=1):
+            w = v.shape[-1] // (parts * n)
+            v = v.reshape(v.shape[:-1] + (parts, n, w))
+            return jnp.moveaxis(v, -2, 0).reshape((n,) + v.shape[:-3]
+                                                  + (parts * w,))
+
+        def heads(xs):
+            x, a, gate, b, A_log, dt_bias = xs
+            q, k, v = (t.reshape(R, T, Hn, D) for t in jnp.split(
+                jax.nn.silu(x), 3, axis=-1))
+            beta = jax.nn.sigmoid(b.astype(f32))
+            g = -jnp.exp(A_log.astype(f32))[:, None] * jax.nn.softplus(
+                a.astype(f32).reshape(R, T, Hn, D)
+                + dt_bias.astype(f32).reshape(Hn, D))
+            q = (l2_normalize(q) * D ** -0.5).astype(dtype)
+            k = l2_normalize(k).astype(dtype)
+            o = kda.channel_decay_rule(q, k, v, g, beta, seg, 64, impl)
+            o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                                  + EPS)
+            y = (o * norm.astype(f32)).astype(dtype)
+            return (y.reshape(R, T, Hn * D).astype(f32)
+                    * jax.nn.sigmoid(gate.astype(f32))).astype(dtype)
+
+        y = jax.lax.map(jax.checkpoint(heads), (
+            by_group(x, 3), by_group(a), by_group(gate), by_group(b),
+            by_group(A_log), by_group(dt_bias)))
+        return jnp.moveaxis(y, 0, 2).reshape(R, T, H * D)
+
+    def loss(x, a, gate, b, A_log, dt_bias, norm, wt):
+        x, a, gate, b = (t.astype(dtype) for t in (x, a, gate, b))
+        if how == "in-kernel":
+            y = kda.rule_with_ends(
+                x, a, gate, jax.nn.sigmoid(b.astype(f32)), A_log, dt_bias,
+                norm, seg, 64, EPS, impl)
+        else:
+            y = xla_ends(x, a, gate, b, A_log, dt_bias, norm)
+        return jnp.sum(y.astype(f32) * wt), y
+
+    if not grads:
+        return jax.jit(lambda *xs: loss(*xs)[1])
+    # y comes back beside the gradients: a train step needs the forward's
+    # result, and without it XLA drops the grouped form's first forward
+    return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(7)),
+                                      has_aux=True))
+
+
+def heads_together(jnp, x, H, back=False):
+    """[.., q | k | v] <-> a head's [q | k | v] side by side (the fused
+    entry's layout), on the tool's random activations."""
+    shape = (H, 3) if back else (3, H)
+    by = x.reshape(x.shape[:-1] + shape + (x.shape[-1] // (3 * H),))
+    return jnp.swapaxes(by, -3, -2).reshape(x.shape)
+
+
+def ends(args, jax, jnp, kda, rule, emit, chip):
+    """``--parts ends`` at every shape."""
+    import numpy as np
+
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.abspath(__file__))))
+    from ssd_scan_sweep import device_ms
+
+    H, D = 32, 128
+    impl = ("pallas_interpret" if args.interpret else "pallas")
+    hows = ["xla-ends"] + (
+        ["in-kernel"] if hasattr(kda, "rule_with_ends") else [])
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def placed(how, xs):  # the fused entry takes a head's [q | k | v]
+        return ((heads_together(jnp, xs[0], H),) + tuple(xs[1:])
+                if how == "in-kernel" else xs)
+
+    def kernel_ms(ops):
+        return {f"{name}_ms": sum(ms for op, ms in ops.items()
+                                  if op.split(".")[0] == name)
+                for name in (rule.FWD_NAME, rule.BWD_NAME)}
+
+    for shape in args.shapes:
+        R, T, docs = parse(shape)
+        xs = ends_inputs(jax, jnp, R, T, H, D, docs)
+        seg = segments(jnp, R, T, docs)
+        if args.parity:
+            def y_and_grads(how, impl, dtype):
+                (_, y), gs = ends_fn(jax, jnp, kda, how, impl, seg, H, D,
+                                     dtype)(*placed(how, xs))
+                gs = list(gs)
+                if how == "in-kernel":
+                    gs[0] = heads_together(jnp, gs[0], H, back=True)
+                return [np.asarray(v, np.float32) for v in (y, *gs)]
+
+            with jax.default_matmul_precision("highest"):
+                exact = y_and_grads("xla-ends", "xla", f32)
+            got = {how: y_and_grads(how, impl, bf) for how in hows}
+            emit({"parts": "ends", "parity": shape, "impl": impl,
+                  "finite": {how: all(bool(np.isfinite(v).all()) for v in vs)
+                             for how, vs in got.items()},
+                  **{f"{how}_vs_float32": dict(zip(ENDS_NAMES, (
+                      float(np.max(np.abs(v - e)) / np.max(np.abs(e)))
+                      for v, e in zip(vs, exact))))
+                     for how, vs in got.items()},
+                  **{f"{how}_y_median_rel_err": float(
+                      np.median(np.abs(vs[0] - exact[0]))
+                      / np.median(np.abs(exact[0])))
+                     for how, vs in got.items()}})
+            continue
+        for how in hows:
+            line = {"parts": "ends", "shape": shape, "impl": how,
+                    "heads": H, "dtype": "bfloat16"}
+            placed_xs = placed(how, xs)
+            if args.compile:
+                sds = [jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=chip)
+                       for v in placed_xs]
+                t0 = time.time()
+                c = ends_fn(jax, jnp, kda, how, "pallas", seg, H, D, bf
+                            ).lower(*sds).compile()
+                emit({**line, "seconds": round(time.time() - t0, 2),
+                      "kernels": c.as_text().count("tpu_custom_call"),
+                      "temp_mb": c.memory_analysis().temp_size_in_bytes
+                      / 1e6})
+                continue
+            try:
+                f, f_ops = device_ms(ends_fn(
+                    jax, jnp, kda, how, impl, seg, H, D, bf, grads=False),
+                    placed_xs, args.iters)
+                fb, fb_ops = device_ms(ends_fn(
+                    jax, jnp, kda, how, impl, seg, H, D, bf), placed_xs,
+                    args.iters)
+            except Exception as e:  # noqa: BLE001 — the record is the point
+                emit({**line, "error": repr(e)[-400:]})
+                continue
+            emit({**line, "fwd_ms": f, "fwd_bwd_ms": fb, **kernel_ms(fb_ops),
+                  "fwd_kernel_ms": kernel_ms(f_ops)[rule.FWD_NAME + "_ms"],
+                  "fwd_ops": f_ops, "fwd_bwd_ops": fb_ops})
 
 
 def ablations(jnp, rule):
@@ -135,6 +322,9 @@ def main() -> int:
     ap.add_argument("--xla", action="store_true")
     ap.add_argument("--interpret", action="store_true",
                     help="rehearse on the CPU, at a tiny shape")
+    ap.add_argument("--parts", choices=("rule", "ends"), default="rule")
+    ap.add_argument("--parity", action="store_true",
+                    help="with --parts ends: distances, no times")
     ap.add_argument("--steps", type=int, nargs="*", default=[])
     ap.add_argument("--heads", type=int, default=8)
     ap.add_argument("--iters", type=int, default=10)
@@ -147,6 +337,7 @@ def main() -> int:
     from areal_tpu.models import kda
     from areal_tpu.ops.pallas import kda_rule as rule
 
+    chip = None
     if args.compile:
         from jax.experimental import topologies
         from jax.sharding import SingleDeviceSharding
@@ -205,6 +396,9 @@ def main() -> int:
         except Exception as e:  # noqa: BLE001 — the record is the point
             line["error"] = repr(e)[:300]
 
+    if args.parts == "ends":
+        ends(args, jax, jnp, kda, rule, emit, chip)
+        return 0
     for shape in args.shapes:
         R, T, docs = parse(shape)
         chunks = R * H * -(-T // 64)
