@@ -534,6 +534,9 @@ class PPOActorInterface(ModelInterface):
             k: v if k[4:] in SUMMED_AUX else v / max(n_steps, 1)
             for k, v in agg.items() if k.startswith("moe_")
         }
+        # a learned selection's exact counts (models/dsa.py): sums
+        moe_stats.update({k: v for k, v in agg.items()
+                          if k.startswith("dsa_")})
         rewards_np = np.asarray(data.data["rewards"], np.float32).reshape(-1)
         return {
             **moe_stats,

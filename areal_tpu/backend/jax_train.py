@@ -41,6 +41,7 @@ from areal_tpu.api.model import (
 from areal_tpu.backend import microbatch as mbu
 from areal_tpu.base import compile_watch, logging, telemetry
 from areal_tpu.models import generate as genmod
+from areal_tpu.models import dsa as dsa_mod
 from areal_tpu.models import moe as moe_mod
 from areal_tpu.models import transformer
 from areal_tpu.models.config import TransformerConfig, has_dense_ffn
@@ -134,11 +135,14 @@ def add_decayed_weights(weight_decay: float) -> optax.GradientTransformation:
     """``optax.add_decayed_weights`` — its (empty) state, its arithmetic —
     that passes over a model's buffers (``moe.BUFFER_LEAVES``: the
     router's choice bias takes no gradient and is the publisher's to
-    move), so that what the optimizer adds to such a leaf is exactly 0."""
+    move; ``dsa.BUFFER_SUBTREES``: a learned selection's indexer, whole
+    matrices under a subtree, takes none either), so that what the
+    optimizer adds to such a leaf is exactly 0."""
 
     def update(updates, state, params):
         def one(path, g, p):
-            if getattr(path[-1], "key", None) in moe_mod.BUFFER_LEAVES:
+            if (getattr(path[-1], "key", None) in moe_mod.BUFFER_LEAVES
+                    or dsa_mod.is_buffer(path)):
                 return g
             return g + weight_decay * p
 
@@ -225,6 +229,9 @@ def _accumulate(loss, stats, grads, scale, carry):
 # "moe_" statistics that add up over a step's micro-batches; the others
 # are per-micro-batch means carried as sums.
 _MOE_SUMMED = tuple(f"moe_{k}" for k in moe_mod.SUMMED_AUX)
+# A learned selection's exact counts (models/dsa.py): int32, summed over a
+# step's micro-batches under their own names.
+_DSA_GAUGES = tuple((k, f"train/{k}") for k in dsa_mod.SUMMED_AUX)
 
 
 def _moe_step_stats(fetched: Dict[str, Any], n_mbs: int) -> Dict[str, float]:
@@ -638,9 +645,11 @@ class JaxTrainEngine(TrainableEngine):
                 # MoE balancing losses (reference utils/moe.py aux
                 # tracker), surfaced under a reserved "moe_" prefix
                 # (train_batch divides the stats by the mb count).
-                loss = loss + aux["aux_total"] * aux_scale
+                if "aux_total" in aux:
+                    loss = loss + aux["aux_total"] * aux_scale
                 stats = dict(stats, **{
-                    f"moe_{k}": v for k, v in aux.items()
+                    k if k in dsa_mod.SUMMED_AUX else f"moe_{k}": v
+                    for k, v in aux.items()
                 })
             return loss, stats
 
@@ -739,8 +748,11 @@ class JaxTrainEngine(TrainableEngine):
         the sequence or the widths is not counted: an over-estimate)."""
         rows = self._rows_on_chip(R)
         group = self.cfg.group_size
-        full = kernel_padded_len(self.attn_impl, L,
-                                 head_dim=self.cfg.head_dim, group=group)
+        if self.cfg.dsa is not None:  # the selection's kernels' own tile
+            full = dsa_mod.kernel_padded_len(self.attn_impl, L)
+        else:
+            full = kernel_padded_len(self.attn_impl, L,
+                                     head_dim=self.cfg.head_dim, group=group)
         window = kernel_padded_len(self.attn_impl, L, self.cfg.sliding_window,
                                    self.cfg.head_dim, group)
         return transformer.remat_kept_bytes(
@@ -1246,23 +1258,34 @@ class JaxTrainEngine(TrainableEngine):
                 ]
                 carry = self._dispatch_grad(loss_fn, args, carry,
                                             ub.R, ub.L)
+        drop: Tuple[str, ...] = ()
+        if self.cfg.dsa is not None and not dsa_mod.counts_fit(
+                n for i in idxs for n in ub.mbs[i].layout.seqlens):
+            # the int32 sums of this step wrapped: no count, not a wrong one
+            logger.warning(
+                "a step of %d tokens has more than %d causal pairs: its "
+                "dsa_* counts are dropped (int32 on the device)",
+                sum(ub.mbs[i].n_tokens for i in idxs), dsa_mod.COUNT_MAX)
+            drop = dsa_mod.SUMMED_AUX
         return self._apply_and_fetch(
             carry, rule, cap, extra_fetch, n_mbs=len(idxs),
             total_tokens=float(sum(ub.mbs[i].n_tokens for i in idxs)),
-            total_w=total_w,
+            total_w=total_w, drop=drop,
         )
 
     def _apply_and_fetch(
         self, carry, rule, cap: float,
         extra_fetch: Optional[Dict[str, jnp.ndarray]],
         n_mbs: int, total_tokens: float, total_w: float,
+        drop: Tuple[str, ...] = (),
     ) -> Dict[str, float]:
         """The end of an optimizer step, shared by train_uniform and
         train_batch: dispatch the apply, then the step's ONE blocking
         fetch of every scalar, then host-side stats. Nothing syncs between
         the last grad dispatch and the fetch — the device's own timeline
         (programs ``train_grad*`` / ``train_apply`` in a capture) gives
-        the gradient / apply split."""
+        the gradient / apply split. ``drop``: statistics the step must not
+        report (sums that did not fit their type)."""
         loss_acc, stats_acc, grads_acc = carry
         with telemetry.span("train/optimizer"):
             with telemetry.span("train/apply_dispatch"), self._mesh_ctx():
@@ -1286,10 +1309,15 @@ class JaxTrainEngine(TrainableEngine):
                     **stats_acc, **(extra_fetch or {}), "loss": loss_acc,
                     "grad_norm": gnorm, "update_applied": applied,
                 })
+                for k in drop:
+                    fetched.pop(k, None)
             # The span that closes the step carries the step's routing
             # health (MoE models; nothing for a dense one).
             with telemetry.span("train/finish_stats",
-                                **_moe_step_stats(fetched, n_mbs)):
+                                **_moe_step_stats(fetched, n_mbs),
+                                **{k: float(fetched[k])
+                                   for k in dsa_mod.SUMMED_AUX
+                                   if k in fetched}):
                 # A skipped (early-stopped) update must not advance the LR
                 # schedule: optax's internal count is an array leaf and
                 # was reverted by the gate; keep the host-side mirror in
@@ -1358,6 +1386,12 @@ class JaxTrainEngine(TrainableEngine):
         ):
             if stat in out:
                 telemetry.set_gauge(gauge, out[stat])
+        if "dsa_queries" in out:
+            for stat, gauge in _DSA_GAUGES:
+                telemetry.set_gauge(gauge, out[stat])
+            telemetry.set_gauge(
+                "train/dsa_selecting_query_frac",
+                out["dsa_selecting_queries"] / max(out["dsa_queries"], 1.0))
         if "moe_walked_rows" in out:
             for rows in ("local", "bound", "walked"):
                 self.moe_rows[rows] = (self.moe_rows.get(rows, 0.0)

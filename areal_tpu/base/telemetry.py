@@ -323,6 +323,15 @@ SHORTCONV_SCOPES = ("shortconv_in_proj", "shortconv", "shortconv_out_proj")
 # assembly (the narrow RoPE, the shared rotary key's broadcast, both
 # concatenates); "attention" and "o_proj" follow as for any block.
 MLA_SCOPES = ("mla_q_proj", "mla_kv_down", "mla_kv_up", "mla_assemble")
+# Attention under a learned selection (KeyeVL2; models/dsa.py), nested
+# INSIDE "attention" (a reader that knows only DEVICE_SCOPES still sees
+# attention): the indexer's three projections with the key's norm and both
+# rotations, the scores' layout (on the XLA path the [T, S] scores: the
+# kernels make their tiles' scores themselves), the selection (the kernel
+# ``dsa_select``: scores and both bisections), and the attention under the
+# mask (``dsa_attend_{fwd,dq,dkv}``, each making its tiles' mask again).
+DSA_SCOPES = ("dsa_index_proj", "dsa_index_scores", "dsa_select",
+              "dsa_attention")
 
 
 def _annotation(name: str, attrs: Dict[str, Any]):

@@ -282,6 +282,28 @@ class MLAConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class SparseAttnConfig:
+    """Learned sparse attention's sizes (HF KeyeVL2's ``sa_config``:
+    ``indexer_num_heads``, ``indexer_head_dim``, ``topk``; ONE indexer key
+    head, ``indexer_num_kv_heads`` 1; models/dsa.py): a lightning indexer
+    of ``n_heads`` query heads of ``head_dim`` scores every earlier token
+    of the document, and the ``top_k`` best are the only keys a query
+    attends. ``q_tile`` / ``kv_tile`` (``q_chunk_size`` /
+    ``kv_chunk_size``) are carried as the tiling they are read as: the
+    blocks in which the scores are made, which change no result."""
+
+    n_heads: int
+    head_dim: int
+    top_k: int
+    q_tile: int = 512
+    kv_tile: int = 512
+
+    @property
+    def q_dim(self) -> int:
+        return self.n_heads * self.head_dim
+
+
+@dataclasses.dataclass(frozen=True)
 class S6Config:
     """A Mamba-1 (selective scan, S6) mixer's sizes: ``d_inner`` channels,
     each with ``state_dim`` states whose decay is its own (``A`` is
@@ -359,6 +381,10 @@ class TransformerConfig:
     # query / key head's ``nope + rope``, ``n_kv_heads == n_q_heads``; a
     # value head is ``mla.v_head_dim`` wide.
     mla: Optional[MLAConfig] = None
+    # Learned sparse attention in every FULL block (models/dsa.py): an
+    # indexer beside q, k, v picks the keys a query attends. The blocks
+    # stay FULL: no layer kind of its own.
+    dsa: Optional[SparseAttnConfig] = None
     # sliding window attention (mistral/gemma2); None = full attention
     sliding_window: Optional[int] = None
     # The kind of each layer: FULL or SLIDING (HF ``layer_types``), or one
@@ -519,8 +545,10 @@ class TransformerConfig:
     def has_cacheless_layers(self) -> bool:
         """Layers no K/V cache can decode: a mixer alone, a state-space
         block, a layer that reads what another layer made, or latent
-        attention (its cache is the latent's)."""
-        return self.mla is not None or self.has_mixer_layers or any(
+        attention (its cache is the latent's), or attention under a learned
+        selection (the indexer's key has no cache)."""
+        return (self.mla is not None or self.dsa is not None
+                or self.has_mixer_layers) or any(
             attention_kind(k) in BLOCK_MIXER_KINDS for k in self.layer_kinds)
 
     @property

@@ -5,7 +5,8 @@ Parity target: the reference's per-family converter registry
 ``realhf/api/from_hf/{llama,qwen2,qwen3,gemma,gpt2,mistral,mixtral}.py``).
 Families covered: llama, qwen2 (qwen2.5), qwen3, mistral, gemma, gpt2,
 mixtral, qwen3_moe, olmoe, mellum, nemotron_h, afmoe, phi4flash,
-granitemoehybrid, qwen3_next, lfm2_moe, glm4_moe_lite, kimi_linear.
+granitemoehybrid, qwen3_next, lfm2_moe, glm4_moe_lite, kimi_linear,
+KeyeVL2.
 
 Weights are stacked on a leading layer axis (see models/transformer.py), so
 conversion transposes HF's ``[out, in]`` linear layout to ``[in, out]`` and
@@ -48,6 +49,7 @@ from areal_tpu.models.config import (
     RopeConfig,
     S6Config,
     ShortConvConfig,
+    SparseAttnConfig,
     SSMConfig,
     TransformerConfig,
     attention_kind,
@@ -165,6 +167,107 @@ def _qwen3_moe_config(hf_config: Any) -> TransformerConfig:
             norm_topk_prob=getattr(hf_config, "norm_topk_prob", True),
         ),
         hf_family="qwen3_moe",
+    )
+
+
+# KeyeVL2: keys of the family that no block here runs, by name: (key, the
+# values that are run, why any other is refused).
+KEYE_VL2_REFUSALS = (
+    ("sliding_window", (None,), "sliding_window: a learned selection under "
+     "a sliding window (the indexer would rank a window's keys) is not "
+     "built"),
+    ("use_sliding_window", (None, False), "use_sliding_window: see "
+     "sliding_window"),
+)
+MROPE_REFUSAL = (
+    "mrope_unequal_streams: the three position streams of mrope_section "
+    "differ on a token (an image patch's temporal / height / width "
+    "positions); without the vision tower every token's are equal and the "
+    "rotation is the one-dimensional one, which is what runs")
+
+
+def mrope_positions(position_ids) -> np.ndarray:
+    """The ONE position stream of M-RoPE position ids ``[3, ..., T]``
+    (temporal, height, width) where the three are equal on every token —
+    text, which is all that runs without a vision tower; refused by name
+    (``MROPE_REFUSAL``) where they differ."""
+    ids = np.asarray(position_ids)
+    if ids.ndim < 2 or ids.shape[0] != 3:
+        raise ValueError(f"M-RoPE position ids are [3, ..., T]: {ids.shape}")
+    if not (np.array_equal(ids[0], ids[1]) and np.array_equal(ids[0], ids[2])):
+        raise NotImplementedError(MROPE_REFUSAL)
+    return ids[0]
+
+
+@register_hf_family("KeyeVL2")
+def _keye_vl2_config(hf_config: Any) -> TransformerConfig:
+    """Keye-VL-2.0 (Kwai-Keye, ``model_type`` KeyeVL2), the LANGUAGE MODEL
+    (the vision tower is not built): qwen3_moe's blocks — GQA with a
+    per-head q / k RMSNorm, RoPE at ``rope_theta``, an expert layer of
+    ``num_experts`` softmax-routed experts of ``moe_intermediate_size`` in
+    every block (top-k gates renormalised, no shared expert, no dropped
+    token, no auxiliary loss unless ``router_aux_loss_coef`` says so) —
+    with LEARNED SPARSE ATTENTION in every block (``sa_config``:
+    ``indexer_num_heads`` x ``indexer_head_dim`` query heads over ONE key
+    head, ``topk`` keys a query; ``q_chunk_size`` / ``kv_chunk_size``
+    carried as the tiling they are read as: models/dsa.py).
+    ``rope_scaling``'s ``mrope_section`` splits the rotary frequencies
+    over three position streams that are equal on every token that is no
+    image patch (:func:`mrope_positions`): the rotation that runs is the
+    one-dimensional one; a ``rope_type`` other than ``default`` is
+    refused. A SHARE holds ``num_experts`` of ``num_routed_experts``
+    (:func:`_expert_share`)."""
+    for key, run, why in KEYE_VL2_REFUSALS:
+        if getattr(hf_config, key, None) not in run:
+            raise NotImplementedError(why)
+    scaling = getattr(hf_config, "rope_scaling", None) or {}
+    kind = _get(scaling, "rope_type", _get(scaling, "type", "default"))
+    if kind != "default":
+        raise NotImplementedError(
+            f"rope_scaling.rope_type {kind!r}: KeyeVL2 runs the plain "
+            "table under mrope_section only")
+    kw = _base_kwargs(hf_config)
+    section = _get(scaling, "mrope_section")
+    if section is not None and sum(section) != kw["head_dim"] // 2:
+        raise ValueError(
+            f"mrope_section {list(section)} does not split the "
+            f"{kw['head_dim'] // 2} rotary frequencies of a head")
+    sa = hf_config.sa_config
+    if _get(sa, "indexer_num_kv_heads", 1) != 1:
+        raise NotImplementedError(
+            "sa_config.indexer_num_kv_heads != 1: the indexer's key is ONE "
+            "head every query head of it reads")
+    if set(getattr(hf_config, "mlp_only_layers", None) or ()) or getattr(
+            hf_config, "decoder_sparse_step", 1) != 1:
+        raise NotImplementedError(
+            "mlp_only_layers / decoder_sparse_step: KeyeVL2 blocks that "
+            "run a dense MLP are not read")
+    held = hf_config.num_experts
+    routed, first = _expert_share(hf_config, held)
+    return TransformerConfig(
+        **kw,
+        use_qk_norm=True,
+        max_position_embeddings=getattr(
+            hf_config, "max_position_embeddings", None),
+        dsa=SparseAttnConfig(
+            n_heads=int(_get(sa, "indexer_num_heads")),
+            head_dim=int(_get(sa, "indexer_head_dim")),
+            top_k=int(_get(sa, "topk")),
+            q_tile=int(_get(sa, "q_chunk_size", 512)),
+            kv_tile=int(_get(sa, "kv_chunk_size", 512)),
+        ),
+        moe=MoEConfig(
+            num_experts=held,
+            top_k=hf_config.num_experts_per_tok,
+            capacity_factor=None,
+            routed_intermediate_dim=hf_config.moe_intermediate_size,
+            aux_loss_coeff=float(
+                getattr(hf_config, "router_aux_loss_coef", 0.0) or 0.0),
+            norm_topk_prob=bool(getattr(hf_config, "norm_topk_prob", True)),
+            router_experts=routed,
+            first_expert=first,
+        ),
+        hf_family="KeyeVL2",
     )
 
 
@@ -995,6 +1098,19 @@ def _llama_mapping(cfg: TransformerConfig) -> List[tuple]:
     return m
 
 
+# A learned selection's indexer (models/dsa.py), under the block's
+# ``indexer`` subtree: (leaf, HF name, transpose). ASSUMED names (the
+# publisher's modelling code is not on this machine): DeepSeek-V3.2's
+# ``self_attn.indexer.*`` with a full-rank ``wq``.
+_INDEXER_NAMES = [
+    ("wq", "model.layers.{i}.self_attn.indexer.wq.weight", True),
+    ("wk", "model.layers.{i}.self_attn.indexer.wk.weight", True),
+    ("ww", "model.layers.{i}.self_attn.indexer.weights_proj.weight", True),
+    ("k_norm", "model.layers.{i}.self_attn.indexer.k_norm.weight", False),
+    ("k_norm_b", "model.layers.{i}.self_attn.indexer.k_norm.bias", False),
+]
+
+
 def _moe_names(cfg: TransformerConfig) -> Dict[str, str]:
     if cfg.hf_family == "mixtral":
         return {
@@ -1030,6 +1146,9 @@ def _llama_from_sd(
     layers: Dict[str, np.ndarray] = {}
     for key, fmt, tr in _llama_mapping(cfg):
         layers[key] = stack(fmt, transpose=tr)
+    if cfg.dsa is not None:
+        layers["indexer"] = {key: stack(fmt, transpose=tr)
+                             for key, fmt, tr in _INDEXER_NAMES}
     if cfg.moe is not None:
         names = _moe_names(cfg)
         E = cfg.moe.num_experts
@@ -1065,7 +1184,8 @@ def _llama_from_sd(
 def _llama_to_sd(
     params: Dict[str, Any], cfg: TransformerConfig
 ) -> Dict[str, np.ndarray]:
-    layers = {k: np.asarray(v) for k, v in params["layers"].items()}
+    layers = {k: v if isinstance(v, dict) else np.asarray(v)
+              for k, v in params["layers"].items()}
     if cfg.scale_embeddings:  # undo the gemma (w + 1) fold
         layers = dict(layers)
         for k in ("ln1", "ln2"):
@@ -1080,6 +1200,11 @@ def _llama_to_sd(
         for i in range(cfg.n_layers):
             wi = w[i]
             sd[fmt.format(i=i)] = wi.T if tr and wi.ndim == 2 else wi
+    if cfg.dsa is not None:
+        for key, fmt, tr in _INDEXER_NAMES:
+            w = np.asarray(layers["indexer"][key])
+            for i in range(cfg.n_layers):
+                sd[fmt.format(i=i)] = w[i].T if tr else w[i]
     if cfg.moe is not None:
         names = _moe_names(cfg)
         for i in range(cfg.n_layers):
@@ -1962,6 +2087,7 @@ _HF_ARCH = {
     "lfm2_moe": "Lfm2MoeForCausalLM",
     "glm4_moe_lite": "Glm4MoeLiteForCausalLM",
     "kimi_linear": "KimiLinearForCausalLM",
+    "KeyeVL2": "KeyeVL2ForConditionalGeneration",
 }
 
 
@@ -2038,9 +2164,13 @@ def hf_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
                 d["intermediate_size"] = width
                 d["attention_bias"] = False
                 d["clip_qkv"] = None
-            elif fam == "mellum":
+            elif fam in ("mellum", "KeyeVL2"):
                 d["moe_intermediate_size"] = width
-                d["mlp_layer_types"] = ["sparse"] * cfg.n_layers
+                if fam == "mellum":
+                    d["mlp_layer_types"] = ["sparse"] * cfg.n_layers
+                else:
+                    d["decoder_sparse_step"] = 1
+                    d["mlp_only_layers"] = []
                 if cfg.moe.is_share:
                     shards = cfg.moe.n_routed // cfg.moe.num_experts
                     d["num_routed_experts"] = cfg.moe.n_routed
@@ -2051,6 +2181,22 @@ def hf_config_dict(cfg: TransformerConfig) -> Dict[str, Any]:
                 d["moe_intermediate_size"] = width
                 d["decoder_sparse_step"] = 1
                 d["mlp_only_layers"] = []
+    if fam == "KeyeVL2":
+        sa = cfg.dsa
+        d["torch_dtype"] = "bfloat16"
+        d["attention_bias"] = False
+        d["use_sliding_window"] = False
+        d["sliding_window"] = None
+        half = cfg.head_dim // 2  # a quarter, then the rest in two
+        first = half // 4
+        d["rope_scaling"] = {
+            "mrope_section": [first, (half - first) // 2,
+                              half - first - (half - first) // 2],
+            "rope_type": "default", "type": "default"}
+        d["sa_config"] = {
+            "indexer_head_dim": sa.head_dim, "indexer_num_heads": sa.n_heads,
+            "indexer_num_kv_heads": 1, "kv_chunk_size": sa.kv_tile,
+            "q_chunk_size": sa.q_tile, "topk": sa.top_k}
     if fam == "mellum":
         names = {kind: name for name, kind in _HF_LAYER_TYPES.items()}
         del d["rope_theta"]
@@ -2639,6 +2785,8 @@ def config_from_dict(cd: Dict[str, Any]) -> TransformerConfig:
         cd["moe"] = MoEConfig(**cd["moe"])
     if cd.get("ssm"):
         cd["ssm"] = SSMConfig(**cd["ssm"])
+    if cd.get("dsa"):
+        cd["dsa"] = SparseAttnConfig(**cd["dsa"])
     for key in ("layer_types", "mlp_layer_types"):
         if cd.get(key) is not None:
             cd[key] = tuple(cd[key])

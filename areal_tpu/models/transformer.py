@@ -226,6 +226,13 @@ def _init_block_layers(cfg: TransformerConfig, n: int, keys, dtype,
         layers["ln2_b"] = jnp.zeros((n, d), dtype)
     if cfg.gated_attention and attends:
         layers["wg"] = nrm(keys[12], (n, d, qd))
+    if cfg.dsa is not None and attends:
+        from areal_tpu.models import dsa as dsamod
+
+        assert cfg.mla is None and kind == FULL, (
+            "a learned selection over plain full attention only")
+        layers[dsamod.INDEXER] = dsamod.init_indexer_params(
+            cfg.dsa, n, d, keys[14], dtype)
     if cfg.sandwich_norm:
         layers["ln1_post"] = jnp.ones((n, d), dtype)
         layers["ln2_post"] = jnp.ones((n, d), dtype)
@@ -512,13 +519,28 @@ def _block(
     else:
         q, k, v, gate = _qkv(cfg, akind, x, lp, cos, sin, shared, cache_kv)
 
-    with jax.named_scope("cross_attention" if akind == CROSS
-                         else "attention"):
-        attn, new_kv = _attend(
-            cfg, q, k, v, segment_ids, positions, cache_kv,
-            cache_write_index, kv_valid, attn_impl, allow_ring, ring_ctx,
-            kind,
-        )
+    counts = None
+    if cfg.dsa is not None:
+        # a learned selection of keys (models/dsa.py): the indexer beside
+        # q, k, v, and attention under the mask it gives
+        from areal_tpu.models import dsa as dsamod
+
+        assert cache_kv is None, dsamod.DECODE_REFUSAL
+        assert ring_ctx is None, dsamod.RING_REFUSAL
+        with jax.named_scope("attention"):
+            attn, counts = dsamod.attend(
+                cfg, x, lp[dsamod.INDEXER], q, k, v, segment_ids, positions,
+                attn_impl, cfg.rope_of(kind)
+                if cfg.pos_embedding == "rope" else None)
+        new_kv = (k, v)
+    else:
+        with jax.named_scope("cross_attention" if akind == CROSS
+                             else "attention"):
+            attn, new_kv = _attend(
+                cfg, q, k, v, segment_ids, positions, cache_kv,
+                cache_write_index, kv_valid, attn_impl, allow_ring, ring_ctx,
+                kind,
+            )
     if cfg.differential_attention:
         with jax.named_scope("diff_attn_combine"):
             f32 = jnp.float32
@@ -545,8 +567,12 @@ def _block(
             with jax.named_scope("post_attn_norm"):
                 attn = _norm(cfg, attn, lp, "ln1_post")
         h = constrain(_residual(cfg, h, attn), hid)
-    return _block_ffn(cfg, kind, h, lp, new_kv, segment_ids, rng, allow_ep,
-                      ring_ctx, attn_impl, decode=cache_kv is not None)
+    h, new_kv, aux = _block_ffn(
+        cfg, kind, h, lp, new_kv, segment_ids, rng, allow_ep, ring_ctx,
+        attn_impl, decode=cache_kv is not None)
+    if counts is not None:  # the selection's exact counts ride the aux
+        aux = {**(aux or {}), **counts}
+    return h, new_kv, aux
 
 
 def _qkv(cfg: TransformerConfig, akind: str, x, lp, cos, sin, shared,
@@ -658,8 +684,10 @@ def decode_refusal(cfg: TransformerConfig) -> Optional[str]:
     """Why this model has no decode mode, by name, or None: the delta-rule
     (Gated DeltaNet, Kimi Delta Attention) or short-convolution blocks'
     own reason where it has them, latent attention's likewise (its cache
-    is the latent's, not K/V's), ``DECODE_REFUSAL`` for any other layer no
-    K/V cache can decode."""
+    is the latent's, not K/V's), a learned selection's likewise (the
+    indexer's key has no cache: ``dsa.DECODE_REFUSAL``,
+    ``sparse_attention_indexer_cache``), ``DECODE_REFUSAL`` for any other
+    layer no K/V cache can decode."""
     if cfg.has_mixer(KDA):
         from areal_tpu.models.kda import DECODE_REFUSAL as kda_refusal
 
@@ -668,6 +696,10 @@ def decode_refusal(cfg: TransformerConfig) -> Optional[str]:
         from areal_tpu.models.mla import DECODE_REFUSAL as mla_refusal
 
         return mla_refusal
+    if cfg.dsa is not None:
+        from areal_tpu.models.dsa import DECODE_REFUSAL as dsa_refusal
+
+        return dsa_refusal
     if GDN in cfg.layer_kinds:
         from areal_tpu.models.gdn import DECODE_REFUSAL as gdn_refusal
 
@@ -874,7 +906,8 @@ def _scan_layers(cfg: TransformerConfig, layer: Callable, h, xs, remat=False):
         def body(h, x):
             return layer(kinds[0], h, x)
 
-        return jax.lax.scan(_maybe_checkpoint(body, remat), h, xs)
+        return jax.lax.scan(
+            _maybe_checkpoint(body, remat, cfg.dsa is not None), h, xs)
     P = len(kinds)
     L = jax.tree_util.tree_leaves(xs)[0].shape[0]
     assert L % P == 0, f"{L} layers are no whole number of {P}-layer periods"
@@ -1044,14 +1077,23 @@ def _scan_mixer_layers(cfg: TransformerConfig, layer: Callable, h, xs,
 REMAT_ENTRIES = ("full", "attention", "matmuls")
 
 
-def _remat_policy(entry: str):
+def _remat_policy(entry: str, selection: bool = False):
+    """``selection``: a model under a learned selection of keys
+    (models/dsa.py) keeps, under EVERY entry, what a selection is a
+    function of — the indexer's inputs and a query's threshold pair — so
+    that the backward compares the bits the forward compared."""
     from areal_tpu.ops.pallas.window_attention import RESIDUALS
 
     policies = jax.checkpoint_policies
+    names = ()
+    if selection:
+        from areal_tpu.models.dsa import SELECTION
+
+        names = (SELECTION,)
     if entry == "full":
-        return None
+        return policies.save_only_these_names(*names) if names else None
     # The grouped-head (splash) kernel names its output and statistic.
-    kernels = policies.save_only_these_names(RESIDUALS)
+    kernels = policies.save_only_these_names(RESIDUALS, *names)
     if entry == "attention":
         return kernels
     if entry == "matmuls":
@@ -1060,13 +1102,14 @@ def _remat_policy(entry: str):
     raise ValueError(f"remat={entry!r}: not one of {REMAT_ENTRIES}")
 
 
-def _maybe_checkpoint(body, remat):
+def _maybe_checkpoint(body, remat, selection: bool = False):
     """``remat``: False (keep everything) | True (= "full") | an entry of
     ``REMAT_ENTRIES``."""
     if not remat:
         return body
     return jax.checkpoint(
-        body, policy=_remat_policy("full" if remat is True else remat))
+        body, policy=_remat_policy("full" if remat is True else remat,
+                                   selection))
 
 
 def _block_matmul_widths(cfg: TransformerConfig, dense_ffn: bool,
@@ -1107,6 +1150,10 @@ def _block_matmul_widths(cfg: TransformerConfig, dense_ffn: bool,
         widths = cfg.q_dim + 2 * cfg.kv_dim + cfg.hidden_dim
     if cfg.gated_attention and kind not in ATTENTION_FREE_KINDS:
         widths += cfg.q_dim
+    if cfg.dsa is not None and kind == FULL:  # the indexer's three
+        from areal_tpu.models.dsa import matmul_widths as dsa_widths
+
+        widths += dsa_widths(cfg.dsa)
     if mixer_only:
         return widths
     if cfg.sandwich_norm:  # the post-norm reads the FFN's last matmul
@@ -1175,6 +1222,10 @@ def remat_kept_bytes(
         return kept
     matmuls = tokens * _block_matmul_widths(cfg, False) * itemsize
     attention = (cfg.n_layers - n_sliding) * causal + n_sliding * window
+    if cfg.dsa is not None:  # what a selection is a function of: always
+        from areal_tpu.models.dsa import selection_kept_bytes
+
+        full += tokens * selection_kept_bytes(cfg.dsa, itemsize)
     kept = {"full": cfg.n_layers * full}
     kept["attention"] = kept["full"] + attention
     kept["matmuls"] = kept["attention"] + cfg.n_layers * matmuls
@@ -1193,10 +1244,16 @@ def attention_kept_bytes_per_token(
     ``train/mla_kept_bytes_per_token``."""
     from areal_tpu.ops.pallas.window_attention import LANE
 
+    selection = 0
+    if cfg.dsa is not None and entry:  # under every entry
+        from areal_tpu.models.dsa import selection_kept_bytes
+
+        selection = selection_kept_bytes(cfg.dsa, itemsize)
     if entry in (False, "full"):
-        return 0
+        return selection
     lanes = -(-(cfg.o_dim // cfg.n_q_heads) // LANE) * LANE
-    kept = cfg.n_q_heads * (lanes * itemsize + 4) if kernel else 0
+    kept = selection + (
+        cfg.n_q_heads * (lanes * itemsize + 4) if kernel else 0)
     if entry == "matmuls":
         kept += itemsize * _block_matmul_widths(cfg, False, FULL,
                                                 mixer_only=True)
@@ -1316,16 +1373,17 @@ def forward(
     # (the reference's aux tracker accumulates every MoE layer's loss);
     # the diagnostic stats are reported as layer means — vector stats
     # (the [E] expert_load histogram) mean over the layer axis only.
-    aux = (
-        {
-            k: (jnp.sum(v) if k == "aux_total"
-                else jnp.mean(v, axis=0) if v.ndim > 1
-                else jnp.mean(v))
-            for k, v in aux.items()
-        }
-        if aux is not None
-        else {}
-    )
+    def over_layers(k, v):
+        if k.startswith("dsa_"):  # an exact count every layer agrees on
+            from areal_tpu.models.dsa import reduce_layers
+
+            return reduce_layers(v)
+        if k == "aux_total":
+            return jnp.sum(v)
+        return jnp.mean(v, axis=0) if v.ndim > 1 else jnp.mean(v)
+
+    aux = ({k: over_layers(k, v) for k, v in aux.items()}
+           if aux is not None else {})
 
     with jax.named_scope("final_norm"):
         if cfg.norm_type == "layer":
@@ -1475,6 +1533,10 @@ def _block_param_count(cfg: TransformerConfig, dense_ffn: bool,
         attn += 6 * cfg.head_dim
     if cfg.gated_attention and attends:
         attn += d * cfg.q_dim
+    if cfg.dsa is not None and kind == FULL:
+        from areal_tpu.models.dsa import indexer_param_count
+
+        attn += indexer_param_count(cfg.dsa, d)
     norms = (4 if cfg.sandwich_norm else 2) * d
     if cfg.use_qk_norm and attends:
         norms += cfg.q_norm_dim + cfg.k_norm_dim

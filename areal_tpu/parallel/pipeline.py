@@ -93,6 +93,9 @@ _FALLBACK_HINTS = {
                           "before expert blocks: a tree per kind has no "
                           "one stacked axis to split over pp, and a "
                           "period's stages cost unequally",
+    "learned_sparse_attention": "attention under a learned selection "
+                                "reports exact counts a layer, which no "
+                                "stage's aux carries",
     "short_convolution": "short-convolution blocks beside attention "
                          "blocks, dense blocks before expert blocks: a tree "
                          "per kind has no one stacked axis to split over "
@@ -148,6 +151,8 @@ def pick_pp_microbatches(
         return _fallback("channel_decay_rule")
     if cfg.has_mixer(CONV):
         return _fallback("short_convolution")
+    if cfg.dsa is not None:  # the selection's counts ride no stage's aux
+        return _fallback("learned_sparse_attention")
     if cfg.is_hybrid:
         return _fallback("mixer_layers")
     sp = mesh.shape.get("sp", 1)

@@ -363,6 +363,9 @@ RING_REFUSALS = {
     "short_convolution": "a short convolution's taps read the tokens just "
                          "before a token: a rank's first tokens need the "
                          "rank before's last conv_L_cache - 1 of B ⊙ x",
+    "learned_sparse_attention": "a learned selection ranks ALL the keys of "
+                                "a query's document: a ring step sees a "
+                                "slice of them and cannot",
     "selective_scan": "the selective scan (S6) is a recurrence over the "
                       "row's tokens in order: a rank's first state is the "
                       "rank before's last",
@@ -385,6 +388,8 @@ def ring_refusal(cfg, kind: Optional[str] = None) -> Optional[str]:
         return "channel_decay_rule"
     if cfg.has_mixer(CONV):
         return "short_convolution"
+    if cfg.dsa is not None:
+        return "learned_sparse_attention"
     kinds = cfg.layer_kinds if kind is None else (kind,)
     windowed = any(cfg.window_of(k) is not None for k in kinds)
     return "sliding_window" if windowed else None

@@ -163,6 +163,13 @@ def _block_partition_specs(cfg: TransformerConfig, zero, lead,
         layers["ln2_b"] = P(lead, None)
     if cfg.gated_attention and attends:
         layers["wg"] = P(lead, zero, "tp")
+    if cfg.dsa is not None and attends:
+        # the indexer (models/dsa.py): matrices ZeRO-3 on the hidden dim,
+        # its heads whole (one selection for every head of the block)
+        layers["indexer"] = {
+            "wq": P(lead, zero, None), "wk": P(lead, zero, None),
+            "ww": P(lead, zero, None), "k_norm": P(lead, None),
+            "k_norm_b": P(lead, None)}
     if cfg.sandwich_norm:
         layers["ln1_post"] = P(lead, None)
         layers["ln2_post"] = P(lead, None)

@@ -110,6 +110,9 @@ METRIC_CATALOG = frozenset({
     "train/docs_per_row", "train/gdn_resets_in_chunk_per_row",
     "train/kda_resets_in_chunk_per_row",
     "train/shortconv_resets_per_row", "train/mla_kept_bytes_per_token",
+    "train/dsa_selected_pairs", "train/dsa_causal_pairs",
+    "train/dsa_selecting_queries", "train/dsa_queries",
+    "train/dsa_selecting_query_frac",
     # parallelism engagement (parallel/pipeline.py gates, exported per
     # batch by backend/jax_train.py): 0/1 gauges for whether the pipeline
     # schedule and ring attention actually engaged, plus the per-reason

@@ -330,7 +330,7 @@ class TrainerWorker:
         run is judged by (base/monitor.log_device_report)."""
         from areal_tpu.base import monitor
         from areal_tpu.ops import attention, native
-        from areal_tpu.models import gdn, kda, mla, moe, shortconv, ssm
+        from areal_tpu.models import dsa, gdn, kda, mla, moe, shortconv, ssm
         from areal_tpu.ops.pallas import window_attention
 
         widths = window_attention.head_width_counts()
@@ -393,6 +393,13 @@ class TrainerWorker:
             # latent attention (models/mla.py)
             mla_geometry={"%dx%d/h%d/q%dkv%d/%d+%d/v%d" % geom: n
                           for geom, n in mla.geometry_counts().items()},
+            # {"row/padded row/q tile, kv tile/top-k": calls traced}:
+            # attention under a learned selection (models/dsa.py), and
+            # what runs it ("kernel": ops/pallas/sparse_attention.py; or
+            # "xla")
+            dsa_geometry={"%d/%d/q%dkv%d/k%d" % geom: n
+                          for geom, n in dsa.geometry_counts().items()},
+            dsa_impl=dsa.impl_counts(),
             # {"pallas" | "pallas_interpret" | "xla": scans traced}: what
             # runs them (the kernel of ops/pallas/ssd_scan.py, or einsums)
             ssm_scan_impl=ssm.scan_impl_counts(),

@@ -133,6 +133,7 @@ DESCRIBED_CHIP_CHILDREN = {
     "compiled_lfm2": (_TPU_COMPILE + ["lfm2"], 600),
     "compiled_glm": (_TPU_COMPILE + ["glm"], 600),
     "compiled_kimi": (_TPU_COMPILE + ["kimi"], 600),
+    "compiled_keye": (_TPU_COMPILE + ["keye"], 600),
 }
 _CHAIN = pytest.StashKey[dict]()
 

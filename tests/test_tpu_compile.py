@@ -11,13 +11,14 @@ passes is not a chip run.
 libtpu admits ONE process at a time (``/tmp/libtpu_lockfile``), so the
 compiles run in child processes — this file, executed as a script: with no
 argument every case of ``_compile_all``, with ``lfm2`` / ``glm`` / ``kimi``
-that cell's cut alone — and the pytest processes themselves never load libtpu.
+/ ``keye`` that cell's cut alone — and the pytest processes themselves never load libtpu.
 Nothing here starts a child. ``tests/conftest.py`` owns the one mechanism
 (``tests/compile_chain.py``):
 
 - ``DESCRIBED_CHIP_CHILDREN`` there names the children: fixture name ->
   (command, time limit). A test takes the fixture (``compiled``,
-  ``compiled_lfm2``, ``compiled_glm``, ``compiled_kimi``) and gets the JSON
+  ``compiled_lfm2``, ``compiled_glm``, ``compiled_kimi``,
+  ``compiled_keye``) and gets the JSON
   object its child printed last. A child for a new configuration is ONE entry in that table
   (and its ``_compile_<name>`` here): no new fixture, no test placed in
   another file to schedule it.
@@ -152,6 +153,10 @@ GLM_GRID = (1, 14336)
 # 7,552, 15,104 tokens of at most 16,384): child ``kimi``, fixture
 # ``compiled_kimi``.
 KIMI_GRID = (2, 7552)
+# The Keye-VL-2.0 cell's cut (configs/keye-vl-2.0-30b-a3b.json) likewise,
+# on the grid of its traffic that holds most tokens (two rows of 7,808):
+# child ``keye``, fixture ``compiled_keye``.
+KEYE_GRID = (2, 7808)
 SSD_SCANS = {"ssd-scan-granite": (1, 7040, 32, 64, 1, 128, 256),
              "ssd-scan-nemotron": (1, 4096, 16, 64, 1, 128, 128)}
 
@@ -827,6 +832,63 @@ def _compile_kimi():
         "param_bytes": 18 * transformer.param_count(kimi)}
 
 
+def _compile_keye():
+    """Child process: the Keye-VL-2.0 cell's cut, the whole model's forward
+    + backward on its fullest grid under full remat, against a described
+    v5e:2x2."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs in /tmp
+    sys.path.insert(0, REPO)
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from areal_tpu.models import dsa, transformer
+    from areal_tpu.ops import attention
+    from benchmark import weights
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this image
+        return {"skip": f"cannot describe a v5e:2x2 topology here: {e}"}
+    jax.config.update("jax_enable_compilation_cache", False)
+    chip = SingleDeviceSharding(topo.devices[0])
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "keye-vl-2.0-30b-a3b.json")) as f:
+        keye = weights.model_config(json.load(f))
+    shapes = jax.eval_shape(
+        lambda: transformer.init_params(keye, jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, jnp.bfloat16, sharding=chip), shapes)
+    tok = jax.ShapeDtypeStruct(KEYE_GRID, jnp.int32, sharding=chip)
+
+    def keye_grad(p, tokens, pos, seg):
+        def loss(p):
+            y, _ = transformer.forward(
+                p, keye, tokens, pos, segment_ids=seg, attn_impl="pallas",
+                remat="full", return_kv=False, return_hidden=True)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        return jax.value_and_grad(loss)(p)
+
+    began = time.monotonic()
+    with attention.dispatch_label("keye"):
+        got = jax.jit(keye_grad).lower(params, tok, tok, tok).compile()
+    text = got.as_text()
+    return {
+        "seconds": round(time.monotonic() - began, 2),
+        "custom_calls": text.count("tpu_custom_call"),
+        "temp_bytes": got.memory_analysis().temp_size_in_bytes,
+        "kernels": [n for n in ("dsa_select", "dsa_attend_fwd",
+                                "dsa_attend_dq", "dsa_attend_dkv")
+                    if n in text],
+        "dispatch": attention.dispatch_counts().get("keye"),
+        "impl": dsa.impl_counts(),
+        "geometry": ["%d/%d/q%dkv%d/k%d" % g for g in dsa.geometry_counts()],
+        "param_bytes": 18 * transformer.param_count(keye)}
+
+
 @pytest.mark.parametrize("T", WINDOW_T)
 def test_window_attention_compiles_for_v5e(compiled, T):
     """The windowed kernel's forward, dKV and dQ at the published heads
@@ -984,6 +1046,7 @@ if __name__ == "__main__":
     print(json.dumps(_compile_lfm2() if sys.argv[1:] == ["lfm2"]
                      else _compile_glm() if sys.argv[1:] == ["glm"]
                      else _compile_kimi() if sys.argv[1:] == ["kimi"]
+                     else _compile_keye() if sys.argv[1:] == ["keye"]
                      else _compile_all()))
 
 
@@ -1187,6 +1250,29 @@ def test_the_kimi_cut_compiles_inside_the_memory_it_leaves(compiled_kimi):
     # 4.96 GB (6.18 with a group of 8 heads at a time and XLA's ends)
     assert got["temp_bytes"] < 5.2e9
     assert got["param_bytes"] == 10_843_819_776
+
+
+def test_the_keye_cut_compiles_inside_the_memory_it_leaves(compiled_keye):
+    """The grad program of the cut (6 blocks under a learned selection, 8
+    of 128 experts held: 432.7 M parameters) on 2 x 7,808, the grid of the
+    cell's traffic that holds most tokens, at the published widths: every
+    block's attention traced as ``sparse`` and run by the four kernels of
+    ops/pallas/sparse_attention.py (the selection's scratch of a query
+    tile's scores against a row of 8,192 keys fits the chip's fast memory:
+    the compiler refuses a kernel that does not), rows padded to whole
+    tiles of 512. Whether the micro-batch FITS is the engine's own program
+    on the chip (PERF.md section 5, PR 66); held here is that the
+    temporaries do not grow."""
+    got = compiled_keye
+    assert got["kernels"] == ["dsa_select", "dsa_attend_fwd",
+                              "dsa_attend_dq", "dsa_attend_dkv"]
+    assert got["dispatch"] == {"sparse": 1} and got["impl"] == {"kernel": 1}
+    assert got["geometry"] == ["7808/8192/q256kv512/k2048"]
+    # 4.94 GB
+    assert got["temp_bytes"] < 5.2e9
+    assert got["param_bytes"] == 7_788_556_800
+    gradient = got["param_bytes"] // 9  # 2 B a parameter
+    assert got["param_bytes"] + gradient + got["temp_bytes"] < 14.5e9
 
 
 def test_the_glm_cut_compiles_inside_the_memory_it_leaves(compiled_glm):

@@ -5,13 +5,16 @@ cell draws them (benchmark/weights.make_params, traffic.make_train_batches),
 one inference forward of the first 2 x 8,192 tokens of the first batch a
 seed, ``local_rows / routed_rows`` summed over the expert layers, over the
 even router's share. The measurement behind a driver's ``LOCAL_SHARE_BAND``
-(benchmark/drivers/train_kimi_linear.py; PERF.md section 6, PR 63).
+(benchmark/drivers/train_kimi_linear.py; PERF.md section 6, PR 63;
+``--workload keye-vl-2.0-30b-a3b.train-video-reason-16k``: drivers/
+train_keye_vl2.py, PERF.md section 2, PR 66).
 
     chiprun -- python tools/share_spread.py <seed> <seed> ...   # ~4 s a seed
     JAX_PLATFORMS=cpu python tools/share_spread.py --tiny 1 2 3  # rehearsal
 """
 import argparse
 import dataclasses
+import importlib
 import json
 import os
 import sys
@@ -37,6 +40,9 @@ def main() -> int:
     from benchmark import harness, traffic, weights
 
     r = harness.resolve_cell(args.workload)
+    # what the cell's driver draws its embedding at, over the program's
+    embed_scale = getattr(importlib.import_module(
+        "benchmark.drivers." + r["traffic"]["driver"]), "EMBED_SCALE", 1.0)
     cfg_file, shape = dict(r["config"]), r["traffic"]["shape"]
     rows, T = 2, 8192
     if args.tiny:
@@ -72,10 +78,12 @@ def main() -> int:
             jnp.asarray(np.pad(a, (0, short))[:rows * T].reshape(rows, T))
             for a in (ids, seg, pos))
         params = weights.make_params(cfg, seed)
+        params["embedding"] = params["embedding"] * embed_scale
         aux = jax.device_get(routed(params, tok, pos, seg))
         del params
         share = float(aux["local_rows"] / aux["routed_rows"])
-        lines.append({"seed": seed, "share": share, "x_even": share / even,
+        lines.append({"workload": args.workload, "seed": seed,
+                      "share": share, "x_even": share / even,
                       "load_ratio": float(aux["expert_load_ratio"]),
                       "s": round(time.time() - began, 1)})
         print(json.dumps(lines[-1]), flush=True)
